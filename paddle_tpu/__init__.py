@@ -25,6 +25,13 @@ distributed pserver/master generation) designed TPU-first on JAX/XLA:
 """
 
 from . import core
+from .core import compile_cache as _compile_cache
+
+# one persistent compile cache for every executable the package builds,
+# placed from outside (JAX_COMPILATION_CACHE_DIR) or at
+# <checkout>/.jax_cache — core/compile_cache.py
+_compile_cache.configure()
+
 from .core import (
     Program,
     Variable,

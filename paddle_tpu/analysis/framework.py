@@ -297,10 +297,7 @@ class CheckContext:
         # and the comm checks see an empty module
         jitted = exe._compile(
             self.program, feed_names, fetch_names, state_names)
-        # fsdp meshes lower with sharding-invariant RNG in production
-        # (Executor._rng_invariant_ctx) — the lint trace must match
-        with exe._rng_invariant_ctx():
-            traced = jitted.trace(state, *feed_vals)
+        traced = jitted.trace(state, *feed_vals)
         # the trace populated the executor's remat plan — snapshot it
         # before anything retraces
         self._cache["remat_plan"] = list(
@@ -334,11 +331,8 @@ class CheckContext:
 
     @property
     def compiled(self):
-        def build():
-            exe = self.prepared[0]
-            with exe._rng_invariant_ctx():
-                return self.traced.lower().compile()
-        return self._get("compiled", build)
+        return self._get("compiled",
+                         lambda: self.traced.lower().compile())
 
     @property
     def hlo_text(self):

@@ -13,6 +13,7 @@ offload scan body (``core/memaudit`` re-exports them for compatibility).
 """
 
 import numpy as np
+from jax.extend import core as _jex_core
 
 __all__ = [
     "KERNEL_RESIDUAL_TAG", "BLOCK_INPUT_TAG",
@@ -40,28 +41,13 @@ _LOW_PRECISION = ("bfloat16", "float16")
 REDUCE_ACCUM_MIN_ELEMS = 4096
 
 
-def _jaxpr_types():
-    """(ClosedJaxpr, Jaxpr) from the supported ``jax.extend.core``
-    location, falling back to the legacy ``jax.core`` aliases on older
-    releases."""
-    try:
-        from jax.extend import core as _jex_core
-
-        return _jex_core.ClosedJaxpr, _jex_core.Jaxpr
-    except (ImportError, AttributeError):
-        import jax
-
-        return jax.core.ClosedJaxpr, jax.core.Jaxpr
-
-
 def _sub_jaxprs(eqn):
-    closed_t, jaxpr_t = _jaxpr_types()
     for v in eqn.params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for x in vs:
-            if isinstance(x, closed_t):
+            if isinstance(x, _jex_core.ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jaxpr_t):
+            elif isinstance(x, _jex_core.Jaxpr):
                 yield x
 
 
@@ -84,8 +70,7 @@ def _carry_accumulations(eqn):
     body = params.get("jaxpr")
     if body is None:
         return []
-    closed_t, _ = _jaxpr_types()
-    if isinstance(body, closed_t):
+    if isinstance(body, _jex_core.ClosedJaxpr):
         body = body.jaxpr
     nc = int(params.get("num_consts", 0))
     k = int(params.get("num_carry", 0))
@@ -172,8 +157,7 @@ def walk_report(jaxpr, layer_counts=()):
     probes (the BENCH_r05 shape detector accepts several hypotheses —
     e.g. the caller's hint plus every scan-group repeat count).
     """
-    closed_t, _ = _jaxpr_types()
-    if isinstance(jaxpr, closed_t):
+    if isinstance(jaxpr, _jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     layer_counts = tuple(sorted({int(c) for c in layer_counts if c}))
     report = {

@@ -1,0 +1,57 @@
+"""The one persistent XLA compile cache (README "Running").
+
+Every executable this package builds — ``Executor._aot_compile``, the
+serving engine's ``lower().compile()``, plain ``jax.jit`` — goes through
+JAX's persistent compilation cache, so a second process on the same
+machine loads the 12-layer step instead of compiling it again.
+
+Where it lives is decided from outside: if ``JAX_COMPILATION_CACHE_DIR``
+is set, JAX reads it itself and nothing is set in code.  Otherwise the
+cache is ``<checkout>/.jax_cache``, derived from this file's location —
+a fixed path, never a temporary name, a pid or the time, because a
+directory that moves between runs never hits.  Every executable is kept,
+however quick its compile, so that what a run leaves behind does not
+depend on a timing.
+
+A process pinned to the CPU (``JAX_PLATFORMS=cpu``: the tests) gets no
+cache from here: XLA:CPU executables are cheap to rebuild, and its AOT
+loader logs a machine-feature error on every cache hit.
+"""
+
+import os
+
+import jax
+
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def cache_dir():
+    """The directory compiled executables persist in."""
+    return os.environ.get(_ENV) or os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def configure():
+    """Point JAX at :func:`cache_dir` unless the environment already
+    did or pins the CPU.  Called once, at package import; reads the
+    environment only, so no backend is initialized."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu":
+        return
+    if not os.environ.get(_ENV):
+        jax.config.update("jax_compilation_cache_dir", cache_dir())
+    # JAX persists only what took a second to compile, so an executable
+    # near that line is written by one run and not by the next; persist
+    # everything, and a second run adds no entry (chip_smoke.py counts)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
+def entry_count():
+    """Executables currently in the cache (JAX writes one ``*-cache``
+    file per entry); 0 when the directory does not exist yet."""
+    try:
+        return sum(1 for n in os.listdir(cache_dir())
+                   if n.endswith("-cache"))
+    except FileNotFoundError:
+        return 0

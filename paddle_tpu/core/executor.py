@@ -193,31 +193,6 @@ def _accum_carry_spec(lead):
     return PartitionSpec(*([None] * lead + ["dp"]))
 
 
-def _ensure_barrier_batch_rule():
-    """``jax.lax.optimization_barrier`` has no batching rule in this jax
-    (0.4.x) — vmapping a barrier-remat segment (the comm-aware
-    accumulation loop vmaps the microbatch forward+backward over device
-    groups) dies with NotImplementedError and silently forfeits local
-    accumulation.  The barrier is identity per operand, so the rule is
-    the trivial pass-through; upstream jax added exactly this later.
-    Registered once, only if absent."""
-    try:
-        from jax._src.lax import lax as _llax
-        from jax.interpreters import batching
-
-        prim = getattr(_llax, "optimization_barrier_p", None)
-        if prim is not None and prim not in batching.primitive_batchers:
-            def _rule(args, dims, **params):
-                return prim.bind(*args, **params), dims
-
-            batching.primitive_batchers[prim] = _rule
-    except Exception:  # noqa: BLE001 — newer jax ships its own rule
-        pass
-
-
-_ensure_barrier_batch_rule()
-
-
 def _remat_segment(seg_fn, env, param_names=()):
     """``jax.checkpoint``-equivalent for one forward segment whose backward
     recompute is made DATA-DEPENDENT on the incoming cotangents via
@@ -340,11 +315,16 @@ class LoweringCtx:
     """Passed to raw (control-flow) op implementations so they can lower
     sub-blocks with the same machinery."""
 
-    def __init__(self, executor, program, step_key):
+    def __init__(self, executor, program, step_key, batch_axis="dp"):
         self.executor = executor
         self.program = program
         self.step_key = step_key
         self._op_counter = 0
+        # the mesh axis that splits the activations' leading (batch)
+        # dim at this point of the trace — what a kernel op's shard_map
+        # names in its specs.  None inside the local-accumulation lanes,
+        # where the enclosing vmap holds ``dp``
+        self.batch_axis = batch_axis
 
     def next_op_key(self):
         """A fresh deterministic PRNG key for one random-op instance."""
@@ -552,34 +532,6 @@ class Executor:
             return False
         return axis_size(self.mesh, "fsdp") > 1
 
-    def _rng_invariant_ctx(self):
-        """Sharding-invariant RNG for compiles on an ``fsdp`` mesh.
-
-        The legacy (non-partitionable) threefry lowering produces
-        DIFFERENT values when a random op's output is sharded — an
-        FSDP-sharded weight would be *initialized differently* than its
-        replicated spelling, breaking the bit-exactness contract the
-        kill switches are gated on.  The partitionable lowering derives
-        each element from its global counter regardless of
-        partitioning, so values never depend on the layout.  Scoped to
-        meshes WITH an fsdp axis (the only place random outputs shard)
-        and deliberately independent of ``PADDLE_TPU_FSDP`` — both
-        spellings of the bit-exactness comparison must lower the same
-        way; everything off the fsdp mesh keeps the legacy stream
-        (tests pin scan-vs-unrolled dropout bit-exactness on it)."""
-        import contextlib
-
-        from ..parallel.mesh import axis_size
-
-        if axis_size(self.mesh, "fsdp") > 1:
-            try:
-                from jax._src.config import threefry_partitionable
-
-                return threefry_partitionable(True)
-            except Exception:  # noqa: BLE001 — newer jax: already on
-                pass
-        return contextlib.nullcontext()
-
     def _aot_compile(self, jitted, args, label, program=None,
                      fetch_names=()):
         """Explicit ``lower().compile()`` instead of first-call jit, so
@@ -605,8 +557,7 @@ class Executor:
 
         _kreg.reset_selected()
         t0 = time.perf_counter()
-        with self._rng_invariant_ctx():
-            compiled = jitted.lower(*args).compile()
+        compiled = jitted.lower(*args).compile()
         dt = time.perf_counter() - t0
         kernel_backends = _kreg.selected_backends()
         reg.counter(
@@ -1921,7 +1872,8 @@ class Executor:
         def one_micro(gacc, xs):
             i, feeds_k = xs
             fctx = LoweringCtx(
-                self, program, jax.random.fold_in(step_key, i + 1))
+                self, program, jax.random.fold_in(step_key, i + 1),
+                batch_axis=None)  # the lane vmap below holds dp
             fwd = make_fwd(fctx)
 
             def lane(feeds_lane):
@@ -1929,7 +1881,10 @@ class Executor:
                 e0.update(feeds_lane)
                 return jax.grad(fwd, has_aux=True)(tparams, e0)
 
-            g, aux = jax.vmap(lane)(feeds_k)
+            # spmd_axis_name: the group axis IS the dp split, so a
+            # kernel op's shard_map inside a lane gets dp on the lane
+            # dim instead of an all-gathered copy of every lane
+            g, aux = jax.vmap(lane, spmd_axis_name="dp")(feeds_k)
             # the [ndp, ...] f32 carry shards ONLY its group axis over
             # dp; an FSDP weight's dW deliberately stays replicated
             # over fsdp through the loops (an fsdp-sharded constraint

@@ -33,14 +33,12 @@ class TPUPlace(Place):
         self.device_id = device_id
 
     def get_device(self):
-        devs = _accelerator_devices()
-        return devs[self.device_id]
-
-
-def _accelerator_devices():
-    devs = jax.devices()
-    accel = [d for d in devs if d.platform != "cpu"]
-    return accel or devs
+        accel = [d for d in jax.devices() if d.platform != "cpu"]
+        if not accel:
+            raise RuntimeError(
+                f"TPUPlace({self.device_id}): JAX found no accelerator "
+                f"(devices: {jax.devices()})")
+        return accel[self.device_id]
 
 
 def is_compiled_with_tpu():

@@ -66,6 +66,7 @@ bit-exact (docs/serving.md "Paged KV cache").
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas_attention import LSE_LANES
 from .registry import register_kernel
 from .triton_attention import _default_interpret, _gpu_available
 from .xla_ref import NEG_INF
@@ -158,16 +159,27 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
 
 # -- pallas_tpu: scalar-prefetch block streaming -----------------------------
 
+
 def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
                            interpret=None):
-    """``PrefetchScalarGridSpec`` kernel: grid ``(S, NB)``, the block
-    TABLE is the scalar-prefetch argument consumed by the K/V BlockSpec
-    index maps, so grid step ``(s, nb)`` streams physical block
-    ``table[s, nb]`` into VMEM.  TPU grids run sequentially, so the
-    online-softmax state carries across ``nb`` steps in VMEM scratch
-    and the output writes once at the last step.  ``block_step`` is
-    accepted for signature parity and ignored — this spelling streams
-    exactly one block per grid step by construction."""
+    """``PrefetchScalarGridSpec`` kernel: grid ``(S, NB)``; the block
+    TABLE and the query POSITIONS are the scalar-prefetch arguments
+    (SMEM).  The table feeds the K/V BlockSpec index maps, so grid step
+    ``(s, nb)`` streams physical block ``table[s, nb]`` into VMEM
+    (consecutive trash entries name the same block and are not
+    re-fetched).  TPU grids run sequentially, so the online-softmax
+    state carries across ``nb`` steps in VMEM scratch and the output
+    writes once at the last step.
+
+    The block stays in the pool's own ``[B, h, dh]`` layout (tokens on
+    the untiled axis, heads on sublanes, ``dh`` on lanes) and the math
+    is VPU-only: scores are a lane reduction of ``k * q`` per window
+    position, the softmax reduces over the untiled token axis, and
+    ``p * v`` accumulates the same way — no transpose, no in-kernel
+    relayout, no MXU shape Mosaic could refuse.  Decode is bound by the
+    K/V bytes, not these flops.  ``block_step`` is accepted for
+    signature parity and ignored — this spelling streams exactly one
+    block per grid step by construction."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -177,61 +189,62 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     S, W, h, dh = q.shape
     B = pool_k.shape[1]
     NB = table.shape[1]
-    T = NB * B
     scale = 1.0 / float(dh) ** 0.5
 
-    def kernel(tbl, q_ref, k_ref, v_ref, pos_ref, o_ref,
+    def kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref,
                m_ref, l_ref, acc_ref):
         del tbl  # consumed by the index maps, not the body
+        s_id = pl.program_id(0)
         nb = pl.program_id(1)
 
+        # per-(window, head) softmax statistics sit lane-replicated in
+        # scratch, the flash kernels' convention: a [h, 1] row is below
+        # Mosaic's minimum lane tile
         @pl.when(nb == 0)
         def _init():
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        qw = q_ref[0]                                      # [W, h, dh]
-        kb = k_ref[0]                                      # [B, h, dh]
-        vb = v_ref[0]
-        pw = pos_ref[0]                                    # [W]
-        s = jnp.einsum("whd,bhd->whb", qw, kb,
-                       preferred_element_type=jnp.float32) * scale
-        tok = nb * B + jax.lax.broadcasted_iota(jnp.int32, (1, 1, B), 2)
-        keep = tok <= pw[:, None, None]
-        s = jnp.where(keep, s, NEG_INF)
-        m = m_ref[...]
-        m2 = jnp.maximum(m, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m - m2)
-        p = jnp.exp(s - m2[..., None])
-        m_ref[...] = m2
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + jnp.einsum(
-            "whb,bhd->whd", p, vb.astype(jnp.float32))
+        kb = k_ref[0].astype(jnp.float32)                  # [B, h, dh]
+        vb = v_ref[0].astype(jnp.float32)
+        tok = nb * B + jax.lax.broadcasted_iota(jnp.int32, (B, h, 1), 0)
+        for w in range(W):
+            qw = q_ref[0, w].astype(jnp.float32)           # [h, dh]
+            s = jnp.sum(kb * qw[None], axis=-1, keepdims=True) * scale
+            s = jnp.where(tok <= pos_ref[s_id, w], s, NEG_INF)  # [B, h, 1]
+            m = m_ref[w][:, :1]                            # [h, 1]
+            m2 = jnp.maximum(m, jnp.max(s, axis=0))
+            alpha = jnp.exp(m - m2)
+            p = jnp.exp(s - m2[None])
+            l2 = l_ref[w][:, :1] * alpha + jnp.sum(p, axis=0)
+            acc_ref[w] = acc_ref[w] * alpha + jnp.sum(p * vb, axis=0)
+            m_ref[w] = jnp.broadcast_to(m2, (h, LSE_LANES))
+            l_ref[w] = jnp.broadcast_to(l2, (h, LSE_LANES))
 
         @pl.when(nb == NB - 1)
         def _finish():
-            l = l_ref[...]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[...] = (acc_ref[...]
-                          / l_safe[..., None]).astype(o_ref.dtype)[None]
+            for w in range(W):
+                l = l_ref[w][:, :1]
+                l_safe = jnp.where(l == 0.0, 1.0, l)
+                o_ref[0, w] = (acc_ref[w] / l_safe).astype(o_ref.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(S, NB),
         in_specs=[
-            pl.BlockSpec((1, W, h, dh), lambda s, nb, tbl: (s, 0, 0, 0)),
+            pl.BlockSpec((1, W, h, dh),
+                         lambda s, nb, tbl, pos: (s, 0, 0, 0)),
             pl.BlockSpec((1, B, h, dh),
-                         lambda s, nb, tbl: (tbl[s, nb], 0, 0, 0)),
+                         lambda s, nb, tbl, pos: (tbl[s, nb], 0, 0, 0)),
             pl.BlockSpec((1, B, h, dh),
-                         lambda s, nb, tbl: (tbl[s, nb], 0, 0, 0)),
-            pl.BlockSpec((1, W), lambda s, nb, tbl: (s, 0)),
+                         lambda s, nb, tbl, pos: (tbl[s, nb], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, W, h, dh), lambda s, nb, tbl: (s, 0, 0, 0)),
+            (1, W, h, dh), lambda s, nb, tbl, pos: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((W, h), jnp.float32),
-            pltpu.VMEM((W, h), jnp.float32),
+            pltpu.VMEM((W, h, LSE_LANES), jnp.float32),
+            pltpu.VMEM((W, h, LSE_LANES), jnp.float32),
             pltpu.VMEM((W, h, dh), jnp.float32),
         ],
     )
@@ -239,10 +252,10 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, W, h, dh), q.dtype),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary", "arbitrary"))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=bool(interpret),
-    )(table.astype(jnp.int32), q, pool_k, pool_v, pos.astype(jnp.int32))
+    )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v)
 
 
 def _tpu_available():

@@ -17,7 +17,7 @@ required keys (``metric``/``value``) — each with a reason string.
 
 Regression detection (``history``) builds one trajectory per tracked
 metric (higher-is-better: img/s, tok/s, MFU, plus serving tok/s/speedup
-when the driver runs bench.py with ``BENCH_SERVING=1``; the
+where a row carries them; the
 ``_LOWER_IS_BETTER`` family — cost-model error ``gpt_attr_model_err_pct``
 — inverts the direction) ordered by round and flags any value more than
 ``threshold`` (default 10%) below the best seen so far (above, for the

@@ -45,45 +45,50 @@ _CPU_NOMINAL_PEAK = 1e12
 _CPU_NOMINAL_BW = 50e9
 
 
+def _lookup(table, device, what):
+    """The chip-spec ``table`` entry for ``device``: a CPU gets None
+    (callers apply their nominal constant), an accelerator whose
+    ``device_kind`` is not in the table is an error — a peak it does
+    not know is not a default."""
+    kind = getattr(device, "device_kind", "")
+    for sub, value in table:
+        if sub in kind.lower():
+            return value
+    if getattr(device, "platform", "cpu") == "cpu":
+        return None
+    raise ValueError(
+        f"no {what} known for accelerator device_kind {kind!r}: add it "
+        f"to paddle_tpu/observability/hardware.py with its source")
+
+
 def device_peak_flops(device=None):
-    """Peak bf16 FLOP/s for one device.  Resolution order: the chip-spec
-    table by device_kind, then the BENCH_PEAK_FLOPS env override for
-    unknown accelerators, then a nominal CPU constant
-    (PT_CPU_PEAK_FLOPS) so MFU is always computable."""
+    """Peak bf16 FLOP/s for one device: the chip-spec table by
+    device_kind; a nominal constant (PT_CPU_PEAK_FLOPS) on a CPU so MFU
+    stays computable there; an unknown accelerator raises."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for sub, peak in PEAK_BF16:
-        if sub in kind:
-            return peak
-    if getattr(device, "platform", "") == "cpu":
+    peak = _lookup(PEAK_BF16, device, "bf16 peak FLOP/s")
+    if peak is None:
         return float(os.environ.get("PT_CPU_PEAK_FLOPS",
                                     _CPU_NOMINAL_PEAK))
-    return float(os.environ.get("BENCH_PEAK_FLOPS", 197e12))
+    return peak
 
 
 def device_hbm_bandwidth(device=None):
     """HBM bandwidth in bytes/s for one device — the memory axis of
-    the attribution roofline.  Chip-spec table by device_kind, then the
-    BENCH_HBM_BW env override for unknown accelerators, then a nominal
-    CPU constant (PT_CPU_HBM_BW) so the estimate is always computable
-    (CPU figures are only meaningful relative to each other)."""
+    the attribution roofline.  Chip-spec table by device_kind; a nominal
+    constant (PT_CPU_HBM_BW) on a CPU (CPU figures are only meaningful
+    relative to each other); an unknown accelerator raises."""
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.devices()[0]
-        except Exception:
-            device = None
-    kind = getattr(device, "device_kind", "").lower()
-    for sub, bw in HBM_BW:
-        if sub in kind:
-            return bw
-    if getattr(device, "platform", "cpu") == "cpu":
+        device = jax.devices()[0]
+    bw = _lookup(HBM_BW, device, "HBM bandwidth")
+    if bw is None:
         return float(os.environ.get("PT_CPU_HBM_BW", _CPU_NOMINAL_BW))
-    return float(os.environ.get("BENCH_HBM_BW", 819e9))
+    return bw
 
 
 def total_peak_flops(mesh=None, device=None):

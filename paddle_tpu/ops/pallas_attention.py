@@ -49,6 +49,20 @@ NEG_INF = -1e30
 LSE_LANES = 128  # Mosaic min lane tile (in-kernel m/l scratch width);
 # lse ITSELF is stored narrow: [bq, 1] kernel outputs, 2-D [bh, t] residuals
 
+# Scoped-VMEM ceiling for the flash kernels.  Mosaic's default is 16 MiB;
+# at the flagship geometry (1024x1024 blocks, d_head 128, bf16) the
+# forward's f32 score/probability tiles put its stack at 16.29 MiB and
+# libtpu 0.0.34 refuses the compile (chip run, PR 21).  A v5e core has
+# 128 MiB of VMEM; half of it leaves the backward's four block^2 f32
+# tiles room without shrinking the measured block sizes.
+VMEM_LIMIT_BYTES = 64 << 20
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
 # causal diagonal sub-tile width: straddling (diagonal) blocks are computed
 # as a static grid of (DIAG_W x DIAG_W) sub-tiles and sub-tiles entirely
 # above the diagonal are NEVER computed — the forward waste of a causal
@@ -377,6 +391,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((n_lse_rows, t_q, 1), jnp.float32),
         ],
         scratch_shapes=scratch,
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(q, k, v)
     # lse leaves the kernel [b*h, t_q, 1] but is squeezed to 2-D [b*h, t_q]
@@ -855,6 +870,7 @@ def _flash_bwd_fused(q, k, v, o, lse, do, sm_scale, causal, block_q,
         scratch_shapes=[pltpu.VMEM((S, block_k, d_sub), jnp.float32),
                         pltpu.VMEM((S, block_k, d_sub), jnp.float32),
                         pltpu.VMEM((S, block_q, d_sub), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(*args)
     dq = jnp.sum(dq_part.astype(jnp.float32), axis=0).astype(q.dtype)
@@ -907,6 +923,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[pltpu.VMEM((S, block_q, d_sub), jnp.float32),
                         pltpu.VMEM((S, block_q, LSE_LANES), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(*dq_args)[0]
 
@@ -929,6 +946,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((S, block_k, d_sub), jnp.float32),
                         pltpu.VMEM((S, block_k, d_sub), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(*dkv_args)
     return dq, dk, dv
@@ -1174,23 +1192,63 @@ def attention_reference(q, k, v, causal=False, sm_scale=None):
 from ..core.registry import register_op
 
 
+def _kernel_mesh(_ctx, op_class, backend, batch, manual=False):
+    """``(mesh, batch_axis)`` when this op's kernel call must run inside
+    a ``shard_map`` over the executor's mesh, else ``(None, None)``.
+
+    GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot
+    be automatically partitioned"), so on a mesh a NATIVELY compiled
+    kernel runs manual over every mesh axis: the batch splits over
+    ``batch_axis`` and the operands are replicated over the axes the
+    specs do not name (compute is replicated along ``fsdp``, as in the
+    GSPMD spelling).  An interpreted kernel (off-TPU) lowers to plain
+    XLA ops that GSPMD partitions itself, so it stays unwrapped unless
+    the op asks with ``manual`` (the tensor-parallel recipes, which need
+    their own collectives).
+
+    ``batch_axis`` is the mesh axis the activations' leading dim is
+    split over at this point of the trace: ``dp``, or None where an
+    enclosing vmap already holds it (``LoweringCtx.batch_axis``, the
+    local-accumulation lanes) or ``batch`` does not divide."""
+    mesh = _ctx_mesh(_ctx)
+    if mesh is None or mesh.size == 1:
+        return None, None
+    if not manual:
+        from ..kernels import resolve_name
+
+        if not (jax.default_backend() == "tpu"
+                and resolve_name(op_class, backend) == "pallas_tpu"):
+            return None, None
+    axis = getattr(_ctx, "batch_axis", None)
+    if axis not in mesh.axis_names or batch % mesh.shape[axis]:
+        axis = None
+    return mesh, axis
+
+
+def _ctx_mesh(_ctx):
+    return getattr(getattr(_ctx, "executor", None), "mesh", None)
+
+
 @register_op("flash_attention")
 def flash_attention_op(Q, K, V, causal=False, sm_scale=0.0, block_q=1024,
-                       block_k=1024, backend="", **_):
+                       block_k=1024, backend="", _ctx=None, **_):
     scale = None if not sm_scale else float(sm_scale)
-    return {"Out": flash_attention(Q, K, V, causal=causal, sm_scale=scale,
-                                   block_q=int(block_q),
-                                   block_k=int(block_k),
-                                   backend=backend or None)}
+    backend = backend or None
 
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=causal, sm_scale=scale,
+                               block_q=int(block_q), block_k=int(block_k),
+                               backend=backend)
 
-def _tp_axis(_ctx):
-    """(mesh, tp_size) when the executor runs under a mesh with a 'tp'
-    axis — the signal for the ops to enter their shard_map paths."""
-    mesh = getattr(getattr(_ctx, "executor", None), "mesh", None)
-    if mesh is None or "tp" not in mesh.axis_names:
-        return None, 1
-    return mesh, int(mesh.shape["tp"])
+    mesh, db = _kernel_mesh(_ctx, "flash_attention", backend, Q.shape[0])
+    if mesh is not None:
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        spec = P(db, None, None, None)
+        attend = shard_map(attend, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=False)
+    return {"Out": attend(Q, K, V)}
 
 
 @register_op("flash_attention_packed")
@@ -1205,45 +1263,46 @@ def flash_attention_packed_op(Q, K, V, n_head=None, causal=False,
     block_q, block_k = int(block_q), int(block_k)
     scale = None if not sm_scale else float(sm_scale)
     backend = backend or None
-    mesh, tp = _tp_axis(_ctx)
-    if tp > 1 and n_head % tp == 0:
-        # Head-sharded tensor parallelism: the packed feature dim IS the
-        # head dim, so a 'tp' shard of [b, t, h*d] holds h/tp whole
-        # heads and attention needs NO cross-shard communication — each
-        # shard runs the kernel on its local heads (the shard_map-over-
-        # heads recipe; GSPMD cannot partition an opaque custom call, so
-        # without this it would all-gather the tp-sharded activations).
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
+    from ..parallel.mesh import axis_size
 
-        db = "dp" if "dp" in mesh.axis_names else None
-        spec = P(db, None, "tp")
-        local_heads = n_head // tp
-        d_head = Q.shape[-1] // n_head
+    tp = axis_size(_ctx_mesh(_ctx), "tp")
+    # Head-sharded tensor parallelism: the packed feature dim IS the
+    # head dim, so a 'tp' shard of [b, t, h*d] holds h/tp whole heads
+    # and attention needs NO cross-shard communication — each shard
+    # runs the kernel on its local heads
+    head_tp = tp > 1 and n_head % tp == 0
+    mesh, db = _kernel_mesh(_ctx, "flash_attention", backend, Q.shape[0],
+                            manual=head_tp)
+    if mesh is None:
+        return {"Out": flash_attention_packed(
+            Q, K, V, n_head, causal=causal, sm_scale=scale,
+            block_q=block_q, block_k=block_k, backend=backend)}
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
 
-        def local(q, k, v):
-            if packed_sub_heads(local_heads, d_head) is None:
-                # the GLOBAL head count packs but the per-shard count
-                # does not (e.g. d_head=64, n_head=6, tp=2 -> 3 local
-                # heads can't pair): run the shard through the 4-D
-                # kernel — transposes on the local shard beat a trace
-                # error
-                b, t, hd = q.shape
-                r4 = lambda x: x.reshape(b, t, local_heads, d_head)
-                o = flash_attention(
-                    r4(q), r4(k), r4(v), causal=causal, sm_scale=scale,
-                    block_q=block_q, block_k=block_k, backend=backend)
-                return o.reshape(b, t, hd)
-            return flash_attention_packed(
-                q, k, v, local_heads, causal=causal, sm_scale=scale,
+    spec = P(db, None, "tp" if head_tp else None)
+    local_heads = n_head // tp if head_tp else n_head
+    d_head = Q.shape[-1] // n_head
+
+    def local(q, k, v):
+        if packed_sub_heads(local_heads, d_head) is None:
+            # the GLOBAL head count packs but the per-shard count does
+            # not (e.g. d_head=64, n_head=6, tp=2 -> 3 local heads can't
+            # pair): run the shard through the 4-D kernel — transposes
+            # on the local shard beat a trace error
+            b, t, hd = q.shape
+            r4 = lambda x: x.reshape(b, t, local_heads, d_head)
+            o = flash_attention(
+                r4(q), r4(k), r4(v), causal=causal, sm_scale=scale,
                 block_q=block_q, block_k=block_k, backend=backend)
+            return o.reshape(b, t, hd)
+        return flash_attention_packed(
+            q, k, v, local_heads, causal=causal, sm_scale=scale,
+            block_q=block_q, block_k=block_k, backend=backend)
 
-        out = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec, check_rep=False)(Q, K, V)
-        return {"Out": out}
-    return {"Out": flash_attention_packed(
-        Q, K, V, n_head, causal=causal, sm_scale=scale,
-        block_q=block_q, block_k=block_k, backend=backend)}
+    out = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                    out_specs=spec, check_vma=False)(Q, K, V)
+    return {"Out": out}
 
 
 # -- kernel-registry registration (docs/kernels.md) --------------------------

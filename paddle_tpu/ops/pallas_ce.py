@@ -41,6 +41,22 @@ from .pallas_attention import _pick_block
 LANES = 128  # Mosaic min lane tile; per-row stats are lane-replicated
 
 
+def _pick_vocab_block(v, cap):
+    """The largest vocab tile <= ``cap`` the TPU lowering accepts: the
+    whole vocabulary, or a multiple of the 128-lane tile that divides it
+    (interpret mode would take any divisor; the chip refuses them)."""
+    if v <= cap:
+        return v
+    for bv in range(cap - cap % LANES, 0, -LANES):
+        if v % bv == 0:
+            return bv
+    raise ValueError(
+        f"fused_softmax_ce_head: vocab={v} has no tile <= {cap} that is "
+        f"a multiple of {LANES} and divides it — use a vocabulary that "
+        f"is a multiple of {LANES}, or the unfused "
+        f"softmax_with_cross_entropy head")
+
+
 def _ce_fwd_kernel(x_ref, w_ref, y_ref, loss_ref, lse_ref,
                    m_scr, l_scr, pick_scr, *, block_v, nv):
     """One (row-block, vocab-block) grid cell; vocab is the innermost grid
@@ -146,7 +162,7 @@ def _ce_fwd(x, w, y, block_n, block_v, interpret):
     n, d = x.shape
     v = w.shape[1]
     bn = _pick_block(n, block_n)
-    bv = _pick_block(v, block_v)
+    bv = _pick_vocab_block(v, block_v)
     nv = v // bv
     y2 = y.reshape(n, 1)
 
@@ -185,7 +201,7 @@ def _ce_bwd(x, w, y, lse, g, block_n, block_v, interpret):
     n, d = x.shape
     v = w.shape[1]
     bn = _pick_block(n, block_n)
-    bv = _pick_block(v, block_v)
+    bv = _pick_vocab_block(v, block_v)
     nn_ = n // bn
     nv = v // bv
     y2 = y.reshape(n, 1)
@@ -356,7 +372,7 @@ def _auto_blocks(n, d, v, ix, iw, block_n, block_v, block_v_fwd,
         bn = _pick_block(n, bn_cap)
         bv_c = bv_cap
         while True:
-            bv = _pick_block(v, bv_c)
+            bv = _pick_vocab_block(v, bv_c)
             if _vmem_est(kernel, bn, bv, d, ix, iw) <= budget:
                 return bn, bv
             if bv_c > 128:
@@ -437,11 +453,24 @@ def fused_softmax_ce_head_op(X, W, Label, block_n=512, block_v=1024,
     lbl = Label
     if lbl.ndim == X.ndim and lbl.shape[-1] == 1:
         lbl = lbl.reshape(lbl.shape[:-1])
-    from .pallas_attention import _tp_axis
+    from ..parallel.mesh import axis_size
+    from .pallas_attention import _ctx_mesh, _kernel_mesh
 
-    mesh, tp = _tp_axis(_ctx)
-    v = W.shape[1]
-    if tp > 1 and v % tp == 0:
+    blocks = dict(block_n=block_n, block_v=block_v,
+                  block_v_fwd=block_v_fwd, backend=backend)
+    tp = axis_size(_ctx_mesh(_ctx), "tp")
+    vocab_tp = tp > 1 and W.shape[1] % tp == 0
+    mesh, db = _kernel_mesh(_ctx, "fused_ce", backend, X.shape[0],
+                            manual=vocab_tp)
+    if mesh is None:
+        return {"Loss": fused_softmax_ce_head(X, W, lbl, **blocks)[
+            ..., None]}
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def local(x, w, y):
+        if not vocab_tp:
+            return fused_softmax_ce_head(x, w, y, **blocks)
         # Vocab-sharded tensor parallelism: each shard runs the fused
         # kernel over its vocab slice (labels localized by the shard
         # offset) and the global softmax is recovered by a cross-shard
@@ -451,39 +480,27 @@ def fused_softmax_ce_head_op(X, W, Label, block_n=512, block_v=1024,
         #   loss = lse - psum(in_shard ? (lse_s - loss_s) : 0)
         # Differentiable end to end (loss_s/lse_s carry the kernel's
         # custom VJP; the merge is plain JAX).
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
+        vs = w.shape[1]
+        off = jax.lax.axis_index("tp") * vs
+        y = y.astype(jnp.int32)
+        in_s = ((y >= off) & (y < off + vs))
+        y_loc = jnp.clip(y - off, 0, vs - 1)
+        loss_s, lse_s = fused_softmax_ce_head_with_lse(
+            x, w, y_loc, **blocks)
+        picked = jnp.where(in_s, lse_s - loss_s, 0.0)
+        # the max shift is numerical stabilization only (it cancels
+        # algebraically) — stop_gradient keeps the merge on psum's
+        # differentiation path (pmax has no JVP rule)
+        m = jax.lax.pmax(jax.lax.stop_gradient(lse_s), "tp")
+        lse = jnp.log(jax.lax.psum(jnp.exp(lse_s - m), "tp")) + m
+        return lse - jax.lax.psum(picked, "tp")
 
-        db = "dp" if "dp" in mesh.axis_names else None
-        xspec = P(*([db] + [None] * (X.ndim - 1)))
-        lspec = P(*([db] + [None] * (lbl.ndim - 1)))
-
-        def local(x, w, y):
-            vs = w.shape[1]
-            off = jax.lax.axis_index("tp") * vs
-            y = y.astype(jnp.int32)
-            in_s = ((y >= off) & (y < off + vs))
-            y_loc = jnp.clip(y - off, 0, vs - 1)
-            loss_s, lse_s = fused_softmax_ce_head_with_lse(
-                x, w, y_loc, block_n=block_n, block_v=block_v,
-                block_v_fwd=block_v_fwd, backend=backend)
-            picked = jnp.where(in_s, lse_s - loss_s, 0.0)
-            # the max shift is numerical stabilization only (it cancels
-            # algebraically) — stop_gradient keeps the merge on psum's
-            # differentiation path (pmax has no JVP rule)
-            m = jax.lax.pmax(jax.lax.stop_gradient(lse_s), "tp")
-            lse = jnp.log(jax.lax.psum(jnp.exp(lse_s - m), "tp")) + m
-            return lse - jax.lax.psum(picked, "tp")
-
-        loss = shard_map(
-            local, mesh=mesh,
-            in_specs=(xspec, P(None, "tp"), lspec),
-            out_specs=lspec, check_rep=False)(X, W, lbl)
-        return {"Loss": loss[..., None]}
-    loss = fused_softmax_ce_head(X, W, lbl, block_n=block_n,
-                                 block_v=block_v,
-                                 block_v_fwd=block_v_fwd,
-                                 backend=backend)
+    xspec = P(*([db] + [None] * (X.ndim - 1)))
+    lspec = P(*([db] + [None] * (lbl.ndim - 1)))
+    loss = shard_map(
+        local, mesh=mesh,
+        in_specs=(xspec, P(None, "tp" if vocab_tp else None), lspec),
+        out_specs=lspec, check_vma=False)(X, W, lbl)
     return {"Loss": loss[..., None]}
 
 

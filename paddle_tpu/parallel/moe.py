@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 __all__ = ["init_moe_params", "moe_ffn"]
 

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline", "pipeline_lm", "stack_stage_params"]
 

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 
 def _attn_block(q, k, v, bias=None, scale=None):

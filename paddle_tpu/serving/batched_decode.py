@@ -95,20 +95,20 @@ def _paged_attention(qh, pool_k, pool_v, table, pos):
     with online softmax — ``qh [S, W, h, dh]``, ``pos [S, W]`` →
     ``[S, W, h, dh]``.  The tuned block-iteration geometry and backend
     come from the ``op=paged_attention`` cache entry when one exists
-    (cached-mode lookup: a miss never compiles, an unavailable
-    persisted backend degrades to auto)."""
+    (cached-mode lookup: a miss never compiles)."""
     from .. import tune
-    from ..kernels import resolve
+    from ..kernels import KernelUnavailable, resolve
 
     T = table.shape[1] * pool_k.shape[1]
     h, dh = qh.shape[-2], qh.shape[-1]
-    try:
-        cfg = tune.paged_attention_config(T, dh, h, str(qh.dtype)) or {}
-    except Exception:  # noqa: BLE001 — tuning must never break decode
-        cfg = {}
+    cfg = tune.paged_attention_config(T, dh, h, str(qh.dtype)) or {}
     try:
         ker = resolve("paged_attention", backend=cfg.get("backend"))
-    except Exception:  # noqa: BLE001 — stale persisted backend -> auto
+    except (KernelUnavailable, ValueError):
+        # a persisted backend name this host cannot serve (a tune cache
+        # written elsewhere) degrades to auto.  Resolution compiles
+        # nothing: a kernel the compiler refuses fails later, at the
+        # engine's lower().compile(), and reaches the caller
         ker = resolve("paged_attention")
     return ker.impl.call(qh, pool_k, pool_v, table, pos,
                          block_step=cfg.get("block_step"))

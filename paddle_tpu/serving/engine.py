@@ -354,6 +354,8 @@ class ServingEngine:
         # can see WHICH attention spelling each compile used — paged
         # kernel vs the PADDLE_TPU_PAGED_ATTN=0 gather fallback)
         self.kernel_backends = {}
+        # entry-point label -> seconds its one lower().compile() took
+        self.compile_seconds = {}
         self._thread = None
         self._stop = threading.Event()
         self._error = None                # fatal error: engine is dead
@@ -641,8 +643,9 @@ class ServingEngine:
         in the ``serving.hbm_high_water_bytes`` / ``serving.temp_bytes``
         gauges; later calls reuse the executable.  Every call site feeds
         fixed shapes (bucketed prefill, the decode chunk), so the AOT
-        executable serves all of them.  Backends without AOT fall back
-        to the plain jit callable."""
+        executable serves all of them.  A compile error (a kernel the
+        compiler refuses) propagates: the engine aborts rather than
+        serve through some other spelling."""
         from .. import kernels as _kernels
         from ..analysis.hlo_tools import compiled_memory_stats
 
@@ -654,21 +657,18 @@ class ServingEngine:
             # latency predictor invoke this first, outside the timed
             # window — an EMA seeded with a one-time compile wall would
             # shed every arrival against a regime that no longer exists
-            if box.get("c") is not None:
+            if "c" in box:
                 return
             _kernels.reset_selected()
-            try:
-                c = fn.lower(*args).compile()
-            except Exception:
-                box["c"] = fn  # no AOT on this backend: plain jit
-                return
-            finally:
-                # which kernel spelling this executable traced with —
-                # per entry point, so operators can tell a paged-kernel
-                # compile from a PADDLE_TPU_PAGED_ATTN=0 gather compile
-                sel = _kernels.selected_backends()
-                if sel:
-                    self.kernel_backends[label] = sel
+            t0 = time.perf_counter()
+            c = fn.lower(*args).compile()
+            self.compile_seconds[label] = time.perf_counter() - t0
+            # which kernel spelling this executable traced with — per
+            # entry point, so operators can tell a paged-kernel compile
+            # from a PADDLE_TPU_PAGED_ATTN=0 gather compile
+            sel = _kernels.selected_backends()
+            if sel:
+                self.kernel_backends[label] = sel
             box["c"] = c
             if _bd._paged_attn_on() and "paged_attention" in sel:
                 self._reg.counter(
@@ -693,17 +693,9 @@ class ServingEngine:
             prepare(*args)
             return box["c"](*args)
 
-        def cache_size():
-            # executable count, same contract as jit's _cache_size():
-            # the compile-bound tests assert exactly one per entry point
-            c = box.get("c")
-            if c is None:
-                return 0
-            if c is fn:
-                return fn._cache_size()
-            return 1
-
-        call._cache_size = cache_size
+        # executable count, same contract as jit's _cache_size(): the
+        # compile-bound tests assert exactly one per entry point
+        call._cache_size = lambda: int("c" in box)
         call.prepare = prepare
         return call
 

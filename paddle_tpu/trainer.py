@@ -407,13 +407,10 @@ class Trainer:
     def _peak_flops(self):
         """Aggregate peak FLOP/s of the devices a step runs on (cached)."""
         if self._peak_flops_cache is None:
-            try:
-                device = (self.exe.place.get_device()
-                          if self.exe.place is not None else None)
-                self._peak_flops_cache = _hardware.total_peak_flops(
-                    mesh=self.exe.mesh, device=device)
-            except Exception:
-                self._peak_flops_cache = 0.0  # unknown: MFU stays None
+            device = (self.exe.place.get_device()
+                      if self.exe.place is not None else None)
+            self._peak_flops_cache = _hardware.total_peak_flops(
+                mesh=self.exe.mesh, device=device)
         return self._peak_flops_cache
 
     def _step_telemetry(self, wall, feed, n_batches=1):

@@ -218,10 +218,7 @@ def main():
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = launch.global_mesh({"dp": total})
 

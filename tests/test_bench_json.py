@@ -27,7 +27,6 @@ def flagship_env(monkeypatch):
     monkeypatch.setenv("BENCH_MODELS", "resnet,gpt")
     monkeypatch.delenv("BENCH_SMOKE", raising=False)
     monkeypatch.delenv("BENCH_INFER", raising=False)
-    monkeypatch.delenv("BENCH_SERVING", raising=False)
 
 
 def _run_main(capsys):
@@ -109,82 +108,6 @@ def test_infer_rows_behind_env_guard(flagship_env, monkeypatch, capsys):
     assert row["extra"]["infer_capi"].startswith("FAILED:")
 
 
-def test_serving_rows_behind_env_guard(flagship_env, monkeypatch, capsys):
-    """BENCH_SERVING=1 folds the continuous-batching throughput row into
-    extra under the serving_* keys --bench-history tracks."""
-    monkeypatch.setattr(bench, "_gate_flash", lambda: {})
-    monkeypatch.setattr(bench, "grad_numeric_gates", lambda: {})
-    monkeypatch.setattr(bench, "_gate_mem", lambda: {})
-
-    calls = []
-
-    def fake_rows(extra):
-        calls.append(True)
-        extra["serving_tok_s"] = 1300.0
-        extra["serving_speedup"] = 1.9
-        return []
-
-    monkeypatch.setattr(bench, "serving_rows", fake_rows)
-    rc, row = _run_main(capsys)
-    assert not calls  # guard off -> not invoked
-    monkeypatch.setenv("BENCH_SERVING", "1")
-    rc, row = _run_main(capsys)
-    assert calls and rc == 0
-    assert row["extra"]["serving_tok_s"] == 1300.0
-    assert row["extra"]["serving_speedup"] == 1.9
-
-
-def test_serving_rows_parses_subprocess_row(monkeypatch):
-    """serving_rows extracts the tracked keys from the smoke row's last
-    stdout line; a nonzero rc / error row is isolated like a gate."""
-    import subprocess
-
-    class _P:
-        def __init__(self, rc, out):
-            self.returncode, self.stdout, self.stderr = rc, out, ""
-
-    good = json.dumps({"metric": "serving_tok_s", "tok_s": 1332.7,
-                       "speedup": 1.92, "ttft_p50_ms": 121.0,
-                       "queue_wait_p50_ms": 106.2})
-    monkeypatch.setattr(subprocess, "run",
-                        lambda *a, **k: _P(0, "noise\n" + good + "\n"))
-    extra = {}
-    assert bench.serving_rows(extra) == []
-    assert extra == {"serving_tok_s": 1332.7, "serving_speedup": 1.92,
-                     "serving_ttft_p50_ms": 121.0,
-                     "serving_queue_wait_p50_ms": 106.2}
-
-    bad = json.dumps({"metric": "serving_tok_s", "error": "boom"})
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k: _P(1, bad))
-    extra = {}
-    assert bench.serving_rows(extra) == ["serving_smoke"]
-    assert extra["serving_smoke"].startswith("FAILED:")
-
-    # a row that parses but has no numeric tok_s would silently END the
-    # serving trajectory in --bench-history (regression flagging never
-    # sees a disappeared metric) — it must fail loudly instead
-    renamed = json.dumps({"metric": "serving_tok_s",
-                          "tokens_per_s": 1332.7})
-    monkeypatch.setattr(subprocess, "run",
-                        lambda *a, **k: _P(0, renamed))
-    extra = {}
-    assert bench.serving_rows(extra) == ["serving_smoke"]
-    assert "no numeric tok_s" in extra["serving_smoke"]
-
-    # crash before any row printed: the rc + stderr tail must surface,
-    # not an IndexError from parsing empty stdout
-    class _PErr(_P):
-        def __init__(self):
-            super().__init__(1, "")
-            self.stderr = "Traceback ...\nImportError: no jax\n"
-
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k: _PErr())
-    extra = {}
-    assert bench.serving_rows(extra) == ["serving_smoke"]
-    assert "rc=1" in extra["serving_smoke"]
-    assert "ImportError" in extra["serving_smoke"]
-
-
 def test_smoke_fallback_when_no_accelerator(monkeypatch, capsys):
     """No accelerator: the CPU smoke row still prints one parseable JSON
     line (the pre-existing contract, kept)."""
@@ -261,7 +184,6 @@ def test_floor_oom_still_ships_row_with_gate(monkeypatch, capsys):
     monkeypatch.setenv("BENCH_MODELS", "resnet,gpt")
     monkeypatch.delenv("BENCH_SMOKE", raising=False)
     monkeypatch.delenv("BENCH_INFER", raising=False)
-    monkeypatch.delenv("BENCH_SERVING", raising=False)
     monkeypatch.setenv("BENCH_GPT_SEQ", "8192")
     rc, row = _run_main(capsys)
     assert rc != 0

@@ -225,9 +225,23 @@ def test_block_chooser_preserves_flagship_and_shrinks_big_dmodel():
 
     assert _auto_blocks(32768, 768, 32768, 2, 2, 512, 1024, 2048) == (
         512, 1024, 2048)
-    bn, bv, bvf = _auto_blocks(4096, 2048, 50000, 2, 2, 512, 1024, 2048)
-    assert bn >= 8 and 50000 % bv == 0 and 50000 % bvf == 0
+    bn, bv, bvf = _auto_blocks(4096, 2048, 50304, 2, 2, 512, 1024, 2048)
+    assert bn >= 8 and 50304 % bv == 0 and 50304 % bvf == 0
     assert bv < 1024 and bvf < 2048  # shrank to fit
+
+
+def test_block_chooser_returns_only_tiles_the_chip_accepts():
+    """A vocab tile is the whole vocabulary or a multiple of 128 that
+    divides it (interpret mode takes any divisor, the TPU lowering does
+    not); a vocabulary with no such tile raises, naming it."""
+    from paddle_tpu.ops.pallas_ce import _auto_blocks
+
+    _, bv, bvf = _auto_blocks(32768, 768, 50304, 2, 2, 512, 1024, 2048)
+    assert bv % 128 == 0 and bvf % 128 == 0
+    assert 50304 % bv == 0 and 50304 % bvf == 0
+    assert _auto_blocks(64, 32, 61, 4, 4, 512, 1024, 2048)[1:] == (61, 61)
+    with pytest.raises(ValueError, match="vocab=50257"):
+        _auto_blocks(32768, 768, 50257, 2, 2, 512, 1024, 2048)
 
 
 @pytest.mark.slow
@@ -241,7 +255,7 @@ def test_fused_ce_d2048_v50k_interpret_matches_reference():
         fused_softmax_ce_head, fused_softmax_ce_head_reference)
 
     rng = np.random.default_rng(9)
-    n, d, v = 16, 2048, 50000
+    n, d, v = 16, 2048, 50304
     x = jnp.asarray(rng.normal(size=(n, d)) * 0.1, jnp.float32)
     w = jnp.asarray(rng.normal(size=(d, v)) * 0.02, jnp.float32)
     y = jnp.asarray(rng.integers(0, v, (n,)), jnp.int32)

@@ -17,29 +17,12 @@ VOCAB, LAYERS, HEADS, DMODEL, SEQ = 64, 2, 2, 32, 16
 
 
 def _train(mesh, tp_shard, steps=4, seed=3, n_head=HEADS):
-    # Sharding-invariant RNG for BOTH spellings of the comparison: the
-    # legacy threefry lowering derives different values for SHARDED
-    # random outputs, so the tp row-sharded weights (att_out.w, ffn2.w
-    # under tp_rules' P('tp', None)) would be *initialized* differently
-    # than the unsharded reference — a 1e-2-level loss offset at step 1
-    # that lr=0.1 then amplifies (the long-standing tier-1 failure this
-    # pins down).  The partitionable lowering derives every element from
-    # its global counter regardless of layout, so sharded init ==
-    # unsharded init and the test measures what it claims: tp TRAINING
-    # numerics, not PRNG lowering artifacts.  Scoped here (not
-    # process-wide) for the same reason Executor._rng_invariant_ctx is
-    # scoped to fsdp meshes — other suites pin legacy-stream values.
-    try:
-        from jax._src.config import threefry_partitionable
-    except Exception:  # newer jax: partitionable is the default
-        import contextlib
-
-        threefry_partitionable = lambda _on: contextlib.nullcontext()  # noqa: E731
-    with threefry_partitionable(True):
-        return _train_inner(mesh, tp_shard, steps, seed, n_head)
-
-
-def _train_inner(mesh, tp_shard, steps, seed, n_head):
+    # Sharded init == unsharded init because JAX's threefry lowering is
+    # partitionable (the default): every element derives from its
+    # global counter regardless of layout, so the tp row-sharded weights
+    # (att_out.w, ffn2.w under tp_rules' P('tp', None)) start from the
+    # same values as the reference and the test measures tp TRAINING
+    # numerics, not PRNG lowering.
     pt.core.unique_name.reset()
     main, startup = pt.Program(), pt.Program()
     main.random_seed = 7
