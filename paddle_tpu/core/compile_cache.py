@@ -16,11 +16,22 @@ depend on a timing.
 A process pinned to the CPU (``JAX_PLATFORMS=cpu``: the tests) gets no
 cache from here: XLA:CPU executables are cheap to rebuild, and its AOT
 loader logs a machine-feature error on every cache hit.
+
+What compiling costs, whichever entry point did it, is counted from
+JAX's own ``jax.monitoring`` events into the global metrics registry:
+``compile.cache_hits`` / ``compile.cache_misses`` (executables loaded
+from, and written to, the persistent cache), ``compile.trace_seconds``
+(jaxpr tracing), ``compile.lower_seconds`` (jaxpr to StableHLO),
+``compile.backend_seconds`` (XLA, or the cache lookup and load in its
+place) and ``compile.cache_load_seconds`` (the load alone).
 """
 
 import os
 
 import jax
+from jax import monitoring
+
+from ..observability import metrics as _metrics
 
 _ENV = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -32,10 +43,51 @@ def cache_dir():
     return os.environ.get(_ENV) or os.path.join(_CHECKOUT, ".jax_cache")
 
 
+_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+_SECONDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        "compile.lower_seconds",
+    "/jax/core/compile/backend_compile_duration": "compile.backend_seconds",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "compile.cache_load_seconds",
+}
+_listening = False
+
+
+def _listen():
+    """Feed JAX's compile events into the global registry, once."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    reg = _metrics.get_registry()
+    for name in (*_COUNTS.values(), *_SECONDS.values()):
+        reg.counter(name)  # a run that compiled nothing reads 0, not absent
+
+    def on_event(event, **_):
+        name = _COUNTS.get(event)
+        if name is not None:
+            reg.counter(name).inc()
+
+    def on_duration(event, duration_secs, **_):
+        name = _SECONDS.get(event)
+        if name is not None:
+            reg.counter(name).inc(max(0.0, duration_secs))
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
+
+
 def configure():
     """Point JAX at :func:`cache_dir` unless the environment already
-    did or pins the CPU.  Called once, at package import; reads the
-    environment only, so no backend is initialized."""
+    did or pins the CPU, and count what compiling costs.  Called once,
+    at package import; reads the environment only, so no backend is
+    initialized."""
+    _listen()
     if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu":
         return
     if not os.environ.get(_ENV):

@@ -34,6 +34,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..observability import metrics as _obs
+from ..observability import trace as _trace
 from ..analysis.jaxpr_tools import BLOCK_INPUT_TAG, KERNEL_RESIDUAL_TAG
 from .program import Program, Parameter, default_main_program, GRAD_SUFFIX
 from .registry import get_op_impl
@@ -593,7 +594,6 @@ class Executor:
             peak = high or (memstats["output_bytes"] + temp)
             if peak:
                 cost["compiled_peak_bytes"] = int(peak)
-                reg.gauge("executor.compiled_peak_bytes").set_max(peak)
             cost["temp_bytes"] = temp
             cost["hbm_high_water_bytes"] = high
             reg.gauge(
@@ -826,8 +826,6 @@ class Executor:
                         "executor.nan_trips",
                         help="NaN/Inf aborts caught by nan_guard / "
                              "check_nan_inf").inc()
-                    from ..observability import trace as _trace
-
                     _trace.get_tracer().instant(
                         "nan_guard_trip", cat="executor", var=name)
                     # post-mortem: the flight bundle carries the recent
@@ -891,17 +889,36 @@ class Executor:
         scope=None,
         return_numpy=True,
     ):
-        (program, scope, feed_names, fetch_names, feed_vals, state_names,
-         state, feed_sig) = self._prepare(program, feed, fetch_list, scope)
-        entry, cache_hit = self._run_entry(
-            program, feed_names, fetch_names, state_names, state,
-            feed_vals, feed_sig)
-        step, cost = entry
-        self.last_step_cost = dict(cost, cache_hit=cache_hit)
+        # one host interval per call: on the timeline, in a profiler
+        # session's trace, and as the histogram executor.run_seconds.  The
+        # step is dispatched, not awaited.  The parts run in a frame of
+        # their own so that the interval also holds its teardown: the
+        # step's donated input state is released there.
+        with _trace.get_tracer().span(
+                "executor.run", cat="executor", registry=_obs.get_registry(),
+                histogram="executor.run_seconds"):
+            return self._run(program, feed, fetch_list, scope, return_numpy)
 
-        new_state, fetches = step(state, *feed_vals)
-        return self._finish(scope, new_state, fetch_names, fetches,
-                            return_numpy)
+    def _run(self, program, feed, fetch_list, scope, return_numpy):
+        # the parts are timeline and annotation only (timer=False): the
+        # aggregate is executor.run_seconds, and a host_timer.executor.*
+        # beside host_timer.trainer.dispatch would count the same seconds
+        tracer = _trace.get_tracer()
+        with tracer.span("executor.prepare", cat="executor", timer=False):
+            (program, scope, feed_names, fetch_names, feed_vals, state_names,
+             state, feed_sig) = self._prepare(program, feed, fetch_list, scope)
+        with tracer.span("executor.dispatch", cat="executor",
+                         timer=False) as sp:
+            entry, cache_hit = self._run_entry(
+                program, feed_names, fetch_names, state_names, state,
+                feed_vals, feed_sig)
+            sp.set(cache_hit=cache_hit)
+            step, cost = entry
+            self.last_step_cost = dict(cost, cache_hit=cache_hit)
+            new_state, fetches = step(state, *feed_vals)
+        with tracer.span("executor.finish", cat="executor", timer=False):
+            return self._finish(scope, new_state, fetch_names, fetches,
+                                return_numpy)
 
     # ------------------------------------------------------------------
     def compile_only(self, program=None, feed=None, fetch_list=None,
@@ -1771,10 +1788,6 @@ class Executor:
                 out = self._accum_grads_local(
                     program, block, env, tparams, make_fwd, accum,
                     step_key, bw, mbs, full_b, ndp, passthrough)
-                reg.counter(
-                    "executor.accum_local_steps",
-                    help="steps compiled with boundary-reduced (local) "
-                         "gradient accumulation").inc()
                 return out
             except Exception as exc:  # trace failure: reference spelling
                 reg.counter(

@@ -255,6 +255,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=bool(interpret),
+        name="paged_attention",
     )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v)
 
 
@@ -334,6 +335,7 @@ def paged_attention_triton(q, pool_k, pool_v, table, pos, block_step=None,
         out_specs=pl.BlockSpec((1, W, h, dh), lambda s: (s, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((S, W, h, dh), q.dtype),
         interpret=bool(interpret),
+        name="paged_attention_triton",
     )(q, pool_k, pool_v, table.astype(jnp.int32), pos.astype(jnp.int32))
 
 

@@ -53,6 +53,7 @@ def decode_gather(pool, table, interpret=None):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, NB, B, h, dh), pool.dtype),
         interpret=bool(interpret),
+        name="decode_gather",
     )(table.astype(jnp.int32), pool)
     return out.reshape(S, NB * B, h, dh)
 

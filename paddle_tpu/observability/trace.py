@@ -1,4 +1,5 @@
-"""Span-based tracing runtime with Chrome-trace / Perfetto export.
+"""Span-based tracing runtime: one span primitive for the host timeline,
+the device trace and the counters.
 
 The reference wraps every executor op in a ``platform/profiler``
 RecordEvent and aggregates them with ParseEvents; paddle_tpu's PR-1
@@ -10,19 +11,37 @@ that timeline:
 * **Spans** — nested named intervals with a category and key/value
   attributes (``tracer.span("trainer.dispatch", cat="trainer",
   batch=3)``), thread-safe (per-thread nesting stacks, one locked
-  bounded event buffer), recorded with ``time.perf_counter``.
+  bounded event buffer).  A live span reads ``time.perf_counter`` once
+  per edge and hands that one pair (``span.t0``, ``span.t1``) to every
+  consumer: the event buffer, a duration histogram, a self-seconds
+  counter, and the caller.
+* **Profiler annotations** — a live span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name and attributes, so
+  inside any profiler session (``jax.profiler.start_trace``,
+  ``Trainer.train(trace_dir=)``, ``profiler()``) the program's spans
+  lie on the host plane of the ``.xplane.pb``, in the device trace's
+  clock, on the thread that did the work.  Outside a session the
+  annotation is a flag check.
+* **Counters** — ``span(..., registry=reg, histogram="x.seconds")``
+  observes the duration under that name in that registry, and
+  ``counter=("x.driver_seconds", {"phase": "decode"})`` adds the span's
+  SELF seconds (its duration less its child spans' on the same thread)
+  to that counter, so a span and its children sum to the span's wall
+  time.  Either one is the span's aggregate: it does not fold into
+  ``host_timer.`` as well.
 * **Instants** — zero-duration markers (``tracer.instant(
   "nan_guard_trip", var="fc_0.w")``) for events like a debug_nans
   abort.
 * **Retroactive spans** — ``tracer.add_span(name, t0, t1, lane=...)``
-  emits an interval from timestamps recorded elsewhere; the serving
-  engine uses this to lay each finished request's span tree
-  (queue -> prefill -> decode chunks) on its own virtual timeline lane.
-* **One aggregation path** — every finished span ALSO observes its
-  duration into the global metrics registry as ``host_timer.<name>``,
-  the same namespace ``profiler.timer`` uses, so ``print_profiler``
-  tables, Prometheus exposition and the JSONL run log read the same
-  numbers as the timeline.
+  re-presents an interval from timestamps recorded elsewhere (never a
+  profiler annotation); the serving engine lays each finished request's
+  span tree (queue -> prefill -> decode chunks) on its own virtual
+  timeline lane with it.
+* **One aggregation path** — a finished span with no histogram or
+  counter of its own observes its duration into the tracer's registry as
+  ``host_timer.<name>``, the same namespace ``profiler.timer`` uses, so
+  ``print_profiler`` tables, Prometheus exposition and the JSONL run
+  log read the same numbers as the timeline.
 * **Export** — ``tracer.save(path)`` (or module-level ``trace.save``)
   writes Chrome-trace JSON (``{"traceEvents": [...]}``): complete
   ``ph="X"`` events with ``ts``/``dur`` in microseconds plus
@@ -30,9 +49,15 @@ that timeline:
   https://ui.perfetto.dev, or ``about:tracing``.
 
 Disabled mode: ``PADDLE_TPU_TRACE=0`` (or ``Tracer(enabled=False)``)
-makes ``span()`` return one shared reusable null context manager — no
-allocation, no lock, no clock read — so production loops can leave the
-call sites in place at near-zero overhead.
+turns the event buffer and the ``host_timer.`` fold-in off.  A span that
+feeds nothing else is then one shared reusable null context manager —
+no allocation, no lock, no clock read.  A span that names a histogram
+or a counter stays live (annotation, clock pair, its metrics): what an
+operator's dashboard or a benchmark reader takes from the program does
+not depend on the flag.  ``span(..., event=False)`` is the same for one
+span: annotation and metrics, no event — for a span that recurs while
+nothing happens (the serving driver's idle wait) and would otherwise
+push the last burst out of the bounded buffer.
 
 The event buffer is bounded (``PADDLE_TPU_TRACE_EVENTS``, default
 100k); when full the oldest events drop and ``tracer.dropped`` counts
@@ -43,6 +68,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from . import metrics as _metrics
 
@@ -82,29 +109,47 @@ def _env_enabled():
 
 class _Span:
     """A live span handle (the object ``with tracer.span(...)`` yields).
-    ``set(**attrs)`` attaches attributes after entry."""
+    ``set(**attrs)`` attaches attributes after entry; after exit ``t0``,
+    ``t1`` and ``seconds`` hold the span's one clock pair for whatever
+    else the caller feeds from the same interval."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_timer", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_timer", "_registry",
+                 "_histogram", "_counter", "_event", "_ann", "_child",
+                 "t0", "t1")
 
-    def __init__(self, tracer, name, cat, args, timer=True):
+    def __init__(self, tracer, name, cat, args, timer=True, registry=None,
+                 histogram=None, counter=None, event=True):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
         self._timer = timer
+        self._registry = registry
+        self._histogram = histogram
+        self._counter = counter
+        self._event = event
 
     def set(self, **attrs):
         self.args.update(attrs)
+        self._ann.set_metadata(**attrs)
         return self
 
+    @property
+    def seconds(self):
+        return self.t1 - self.t0
+
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._ann = _Annotation(self.name, **self.args)
+        self._ann.__enter__()
+        self._child = 0.0
+        self._tracer._stack().append(self)
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._tracer._record(self.name, self.cat, self._t0, t1, self.args,
-                             timer=self._timer)
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)  # before the bookkeeping: the same interval
+        self._tracer._finish(self)
         return False
 
 
@@ -114,7 +159,8 @@ class Tracer:
     enabled     None (default) reads ``PADDLE_TPU_TRACE`` (on unless
                 "0"); True/False pins it.
     registry    metrics registry receiving ``host_timer.<name>``
-                duration histograms (default: the global one); None
+                duration histograms and whatever a span names without a
+                registry of its own (default: the global one); None
                 disables the fold-in.
     max_events  bounded buffer size (default ``PADDLE_TPU_TRACE_EVENTS``
                 or 100000); oldest events drop when full.
@@ -188,18 +234,59 @@ class Tracer:
         if timer and self._registry is not None:
             self._registry.histogram(TIMER_PREFIX + name).observe(t1 - t0)
 
+    def _stack(self):
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
+    def _finish(self, sp):
+        """Hand a closed span's one clock pair to each of its consumers."""
+        stack = self._tls.stack
+        stack.remove(sp)  # the top, unless spans were closed out of order
+        seconds = sp.t1 - sp.t0
+        if stack:
+            stack[-1]._child += seconds
+        reg = sp._registry
+        if reg is None:
+            reg = (self._registry if self._registry is not None
+                   else _metrics.get_registry())
+        if sp._histogram is not None:
+            reg.histogram(sp._histogram).observe(seconds)
+        if sp._counter is not None:
+            name, labels = sp._counter
+            reg.counter(name, **labels).inc(max(0.0, seconds - sp._child))
+        if self.enabled and sp._event:
+            # a span that names a histogram or a counter has its
+            # aggregate: no host_timer duplicate of the same seconds
+            self._record(sp.name, sp.cat, sp.t0, sp.t1, sp.args,
+                         timer=(sp._timer and sp._histogram is None
+                                and sp._counter is None))
+
     # -- public API -------------------------------------------------------
-    def span(self, name, cat="host", timer=True, **attrs):
-        """Context manager recording a nested interval.  Disabled mode
-        returns one shared null context: no allocation, no clock read.
-        ``timer=False`` keeps the span timeline-only (no ``host_timer.``
-        fold-in) — for spans that RE-present an interval other spans or
-        timers already observe (e.g. a parent whose children cover the
-        same window), which would otherwise multi-count the same wall
-        seconds in the aggregate view."""
-        if not self.enabled:
+    def span(self, name, cat="host", timer=True, registry=None,
+             histogram=None, counter=None, event=True, **attrs):
+        """Context manager recording a nested interval, also a
+        ``jax.profiler.TraceAnnotation`` of the same name and attributes.
+
+        ``histogram`` names the histogram that observes the duration and
+        ``counter`` a ``(name, labels)`` counter that gains the span's
+        self seconds, both in ``registry`` (default: the tracer's).
+        ``event=False`` keeps the span out of the event buffer
+        (annotation and metrics only): for one that recurs while
+        nothing happens, which would wrap the bounded buffer.  A
+        span that names neither folds into ``host_timer.<name>`` while
+        the event buffer is on; ``timer=False`` keeps it timeline-only
+        — for spans that RE-present an interval other spans or timers
+        already observe (e.g. a parent whose children cover the same
+        window), which would otherwise multi-count the same wall
+        seconds in the aggregate view.  Disabled mode returns one shared
+        null context (no allocation, no clock read) unless the span
+        names a histogram or a counter."""
+        if not ((self.enabled and event) or histogram or counter):
             return _NULL_CTX
-        return _Span(self, name, cat, attrs, timer=timer)
+        return _Span(self, name, cat, attrs, timer=timer, registry=registry,
+                     histogram=histogram, counter=counter, event=event)
 
     def instant(self, name, cat="host", **attrs):
         """Zero-duration marker (Chrome ``ph="i"``), e.g. a nan trip."""
@@ -214,8 +301,9 @@ class Tracer:
 
     def add_span(self, name, t0, t1, cat="host", lane=None, timer=True,
                  **attrs):
-        """Record a span retroactively from ``time.perf_counter``
-        timestamps captured elsewhere.  ``lane`` places it on a virtual
+        """Re-present an interval from ``time.perf_counter`` timestamps
+        captured elsewhere (a live span's ``t0``/``t1``): event buffer
+        only, never a profiler annotation.  ``lane`` places it on a virtual
         timeline (see :meth:`lane`) instead of the calling thread.
         ``timer=False`` skips the ``host_timer.`` fold-in — for spans
         that RE-present an interval some other span or histogram
@@ -297,8 +385,8 @@ def tracing_enabled():
 
 
 # module-level conveniences over the global tracer ----------------------
-def span(name, cat="host", timer=True, **attrs):
-    return get_tracer().span(name, cat=cat, timer=timer, **attrs)
+def span(name, **kwargs):
+    return get_tracer().span(name, **kwargs)
 
 
 def instant(name, cat="host", **attrs):

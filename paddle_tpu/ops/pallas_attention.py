@@ -393,6 +393,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     # lse leaves the kernel [b*h, t_q, 1] but is squeezed to 2-D [b*h, t_q]
     # immediately: a trailing size-1 dim gets tile-padded back to 128
@@ -872,6 +873,7 @@ def _flash_bwd_fused(q, k, v, o, lse, do, sm_scale, causal, block_q,
                         pltpu.VMEM((S, block_q, d_sub), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_fused",
     )(*args)
     dq = jnp.sum(dq_part.astype(jnp.float32), axis=0).astype(q.dtype)
     return dq, dk, dv
@@ -925,6 +927,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                         pltpu.VMEM((S, block_q, LSE_LANES), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_args)[0]
 
     kspec2 = pl.BlockSpec((1, block_k, width), lambda i, kb, jq: kix(i, kb))
@@ -948,6 +951,7 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                         pltpu.VMEM((S, block_k, d_sub), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_args)
     return dq, dk, dv
 

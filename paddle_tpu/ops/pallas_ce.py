@@ -188,6 +188,7 @@ def _ce_fwd(x, w, y, block_n, block_v, interpret):
             pltpu.VMEM((bn, LANES), jnp.float32),  # picked label logit
         ],
         interpret=interpret,
+        name="fused_ce_fwd",
     )(x, w, y2)
     # squeeze to 1-D immediately: the [n, 1] kernel buffers get tile-
     # padded to 128 lanes by XLA's layout; the 1-D forms are compact
@@ -219,6 +220,7 @@ def _ce_bwd(x, w, y, lse, g, block_n, block_v, interpret):
         out_shape=[jax.ShapeDtypeStruct((n, d), x.dtype)],
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_dx",
     )(x, w, y2, lse, g2)[0]
 
     xspec2 = pl.BlockSpec((bn, d), lambda jv, jn: (jn, 0))
@@ -232,6 +234,7 @@ def _ce_bwd(x, w, y, lse, g, block_n, block_v, interpret):
         out_shape=[jax.ShapeDtypeStruct((d, v), w.dtype)],
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_dw",
     )(x, w, y2, lse, g2)[0]
     return dx, dw
 

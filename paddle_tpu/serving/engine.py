@@ -337,6 +337,9 @@ class ServingEngine:
         self._table = np.zeros((self.max_slots, self.blocks_per_slot),
                                np.int32)
         self._slot_blocks = [None] * self.max_slots  # bids a slot holds
+        # when each slot's request last advanced (first token, then the
+        # end of every chunk): serving.stalled_seconds counts from it
+        self._slot_advanced = [0.0] * self.max_slots
         self._spec = (_spec.SpecState(self, draft_params, draft_n_layer,
                                       spec_k) if spec_on else None)
 
@@ -412,6 +415,44 @@ class ServingEngine:
         # installed via trace.set_tracer() after the engine exists (the
         # test pattern) still receives the request span trees
         return _trace.get_tracer()
+
+    def _span(self, name, phase, of=None, histogram=None, event=True,
+              **attrs):
+        """One driver-loop span: a timeline event and profiler annotation
+        whose self seconds land in ``serving.driver_seconds{phase=...}``
+        of the engine's registry — the driver thread is always inside
+        one (``serving.idle``, or a ``serving.step`` whose own seconds
+        are ``phase=loop`` and whose children are the rest), so the
+        phases sum to its wall time.  ``of`` tells a fetch inside a
+        prefill from one inside a decode chunk."""
+        labels = {"phase": phase} if of is None else {"phase": phase,
+                                                       "of": of}
+        return self._tracer.span(
+            name, cat="serving", registry=self._reg, histogram=histogram,
+            counter=("serving.driver_seconds", labels), event=event,
+            **attrs)
+
+    def _account_stall(self, t0, t1):
+        """A decode chunk ran over ``[t0, t1]``: every live request was
+        last advanced at its previous chunk's end (or its first token),
+        waited until ``t0`` while the driver did something else, and is
+        advanced again at ``t1``."""
+        stalled = live = 0.0
+        for s, req in enumerate(self._slots):
+            if req is not None:
+                stalled += t0 - self._slot_advanced[s]
+                live += t1 - self._slot_advanced[s]
+                self._slot_advanced[s] = t1
+        self._reg.counter(
+            "serving.stalled_seconds",
+            help="seconds decoding requests waited between their chunks "
+                 "while the driver did something else (admission, "
+                 "another request's prefill, emit)").inc(max(0.0, stalled))
+        self._reg.counter(
+            "serving.live_seconds",
+            help="seconds decoding requests spent from one advance to "
+                 "the next (stalled + their chunks): stalled_seconds' "
+                 "denominator").inc(max(0.0, live))
 
     # -- request intake ---------------------------------------------------
     def submit(self, prompt, max_new_tokens=16, eos_id=None,
@@ -501,9 +542,12 @@ class ServingEngine:
         hanging) and further ``submit``/``step`` calls raise."""
         if self._error is not None:
             raise RuntimeError("serving engine aborted") from self._error
-        with self._dlock:
+        # the step's own seconds (phase=loop) are what lies between its
+        # phase spans: the lock, the table upload, the stall accounting
+        with self._span("serving.step", "loop"), self._dlock:
             try:
-                finished = self._admit()
+                with self._span("serving.admit", "admit"):
+                    finished = self._admit()
                 if self.active_slots:
                     finished += self._decode()
             except Exception as e:
@@ -597,7 +641,18 @@ class ServingEngine:
             try:
                 while not self._stop.is_set():
                     if self.idle:
-                        time.sleep(0.001)
+                        # closed every ~20 ms, so that the idle counter
+                        # and a profiler session that starts mid-stretch
+                        # are at most that far behind; counter and
+                        # annotation only, or an idle engine would wrap
+                        # the event buffer and drop its last burst (on
+                        # the timeline idle is the gap between steps)
+                        with self._span("serving.idle", "idle",
+                                        event=False):
+                            for _ in range(20):
+                                time.sleep(0.001)
+                                if not self.idle or self._stop.is_set():
+                                    break
                         continue
                     self.step()
             except BaseException as e:  # noqa: BLE001 — supervision:
@@ -772,25 +827,26 @@ class ServingEngine:
         tbl = jnp.asarray(self._table)
         self._decode_fn.prepare(self._p, self._pk, self._pv, self._last,
                                 self._pos, tbl)
-        t0 = time.perf_counter()
-        (self._pk, self._pv, self._last, self._pos,
-         toks) = self._decode_fn(self._p, self._pk, self._pv, self._last,
-                                 self._pos, tbl)
-        toks = np.asarray(toks)  # host sync: [chunk, S]
-        t1 = time.perf_counter()
+        # the chunk call and its blocking token fetch: one span, whose
+        # clock pair is the per-chunk-call latency histogram (ISSUE 7
+        # TTFT/TPOT decomposition), the per-step wall, the predictor's
+        # sample and every live request's stall accounting
+        with self._span("serving.decode_chunk", "decode",
+                        histogram="serving.decode_chunk",
+                        steps=self.decode_chunk,
+                        active=self.active_slots) as sp:
+            (self._pk, self._pv, self._last, self._pos,
+             toks) = self._decode_fn(self._p, self._pk, self._pv,
+                                     self._last, self._pos, tbl)
+            with self._span("serving.fetch", "fetch", of="decode"):
+                toks = np.asarray(toks)  # host sync: [chunk, S]
+        t0, t1 = sp.t0, sp.t1
         wall = t1 - t0
         self._reg.histogram("serving.step_seconds").observe(
             wall / self.decode_chunk)
-        # per-chunk-call latency (ISSUE 7 TTFT/TPOT decomposition) + the
-        # driver-thread timeline span; every live request also records
-        # this window for its own lane (emitted at finish)
-        self._reg.histogram("serving.decode_chunk").observe(wall)
         self.predictor.observe_chunk(wall, self.decode_chunk)
-        tracer = self._tracer
-        tracer.add_span("serving.decode_chunk", t0, t1,
-                        cat="serving", steps=self.decode_chunk,
-                        active=self.active_slots)
-        if tracer.enabled:
+        self._account_stall(t0, t1)
+        if self._tracer.enabled:
             # per-request chunk windows feed only the finish-time lane
             # emission, which is skipped when tracing is off — don't
             # grow the lists on the disabled hot path
@@ -799,19 +855,20 @@ class ServingEngine:
                     req.chunks.append((t0, t1))
         emitted = 0
         finished = 0
-        now = time.perf_counter()
-        for j in range(self.decode_chunk):
-            for s, req in enumerate(self._slots):
-                if req is None:
-                    continue
-                tok = int(toks[j, s])
-                req.tokens.append(tok)
-                emitted += 1
-                if ((req.eos_id is not None and tok == req.eos_id)
-                        or len(req.tokens) >= req.max_new):
-                    self._release_slot(s)
-                    self._finish(req, now)
-                    finished += 1
+        with self._span("serving.emit", "emit") as em:
+            now = em.t0
+            for j in range(self.decode_chunk):
+                for s, req in enumerate(self._slots):
+                    if req is None:
+                        continue
+                    tok = int(toks[j, s])
+                    req.tokens.append(tok)
+                    emitted += 1
+                    if ((req.eos_id is not None and tok == req.eos_id)
+                            or len(req.tokens) >= req.max_new):
+                        self._release_slot(s)
+                        self._finish(req, now)
+                        finished += 1
         self._reg.counter("serving.tokens").inc(emitted)
         if wall > 0:
             self._reg.gauge("serving.tok_s").set(emitted / wall)
@@ -861,84 +918,92 @@ class ServingEngine:
             jnp.asarray(np.zeros((S, k + 1), np.int32)),
             jnp.asarray(pos_h), jnp.asarray(limit_h),
             jnp.asarray(self._table))
-        t0 = time.perf_counter()
-        drafts = sp.propose(self, last_h, pos_h)       # [k, S] host
-        t_d = time.perf_counter()
-        self._reg.gauge(
-            "serving.spec_draft_ms",
-            help="draft propose wall time per speculative round (ms)",
-        ).set((t_d - t0) * 1000.0)
-        # fault injection point (PADDLE_TPU_FAULT=slot_death:n): in
-        # speculative mode the decode-point death fires MID-VERIFY —
-        # between propose and commit, the widest window of in-flight
-        # scratch state.  The killed slot's real AND draft chains are
-        # reclaimed (_release_slot), its table rows zero, and the
-        # verify below runs with its write limit dropped to -1, so the
-        # dead slot scatters only into the trash block.
-        if _faults.maybe_fault("serving.decode") == "slot_death":
-            self._kill_one_slot()
-            if not self.active_slots:
-                return 0
-        for s in range(S):
-            if self._slots[s] is None:
-                limit_h[s] = -1
-        U = np.zeros((S, k + 1), np.int32)
-        U[:, 0] = last_h
-        U[:, 1:] = drafts.T
-        (self._pk, self._pv, greedy) = sp.verify_fn(self)(
-            self._p, self._pk, self._pv, jnp.asarray(U),
-            jnp.asarray(pos_h), jnp.asarray(limit_h),
-            jnp.asarray(self._table))
-        greedy = np.asarray(greedy)                    # host sync [S, k+1]
-        t1 = time.perf_counter()
+        # one live span per round (the speculative engine's decode
+        # phase): propose, verify and the blocking fetch of the verdict
+        with self._span("serving.spec_round", "decode",
+                        histogram="serving.decode_chunk", k=k,
+                        active=self.active_slots) as rnd:
+            drafts = sp.propose(self, last_h, pos_h)       # [k, S] host
+            self._reg.gauge(
+                "serving.spec_draft_ms",
+                help="draft propose wall time per speculative round (ms)",
+            ).set((time.perf_counter() - rnd.t0) * 1000.0)
+            # fault injection point (PADDLE_TPU_FAULT=slot_death:n): in
+            # speculative mode the decode-point death fires MID-VERIFY —
+            # between propose and commit, the widest window of in-flight
+            # scratch state.  The killed slot's real AND draft chains are
+            # reclaimed (_release_slot), its table rows zero, and the
+            # verify below runs with its write limit dropped to -1, so
+            # the dead slot scatters only into the trash block.
+            if _faults.maybe_fault("serving.decode") == "slot_death":
+                self._kill_one_slot()
+                if not self.active_slots:
+                    return 0
+            for s in range(S):
+                if self._slots[s] is None:
+                    limit_h[s] = -1
+            U = np.zeros((S, k + 1), np.int32)
+            U[:, 0] = last_h
+            U[:, 1:] = drafts.T
+            (self._pk, self._pv, greedy) = sp.verify_fn(self)(
+                self._p, self._pk, self._pv, jnp.asarray(U),
+                jnp.asarray(pos_h), jnp.asarray(limit_h),
+                jnp.asarray(self._table))
+            with self._span("serving.fetch", "fetch", of="decode"):
+                greedy = np.asarray(greedy)            # host sync [S, k+1]
+        t0, t1 = rnd.t0, rnd.t1
         wall = t1 - t0
         active = self.active_slots
-        tracer = self._tracer
-        if tracer.enabled:
+        self._account_stall(t0, t1)
+        if self._tracer.enabled:
             for req in self._slots:
                 if req is not None:
                     req.chunks.append((t0, t1))
         emitted = 0
         finished = 0
         round_acc = 0
-        now = time.perf_counter()
-        for s, req in enumerate(self._slots):
-            if req is None:
-                continue
-            remaining = req.max_new - len(req.tokens)
-            commit, n_matched = _spec.accept_greedy(
-                drafts[:, s], greedy[s], remaining)
-            done = False
-            appended = 0
-            for tok in commit:
-                req.tokens.append(tok)
-                emitted += 1
-                appended += 1
-                if ((req.eos_id is not None and tok == req.eos_id)
-                        or len(req.tokens) >= req.max_new):
-                    done = True
-                    break
-            acc = min(n_matched, appended)
-            # acceptance is judged over the draft tokens that COULD
-            # have committed (the request's remaining window), not the
-            # full k — end-of-request rounds would otherwise dilute the
-            # rate and make the predictor's steps-per-round estimate,
-            # and the reported draft quality, look worse than they are
-            eff = min(k, max(0, remaining - 1))
-            sp.proposed += eff
-            sp.accepted += acc
-            round_acc += acc
-            req.spec_proposed += eff
-            req.spec_accepted += acc
-            if done:
-                self._release_slot(s)
-                self._finish(req, now)
-                finished += 1
-            else:
-                # the draft KV is valid through the new frontier - 1;
-                # scratch blocks past it held rejected-token state
-                pos2 = req.prompt.shape[0] + len(req.tokens) - 1
-                sp.rollback(self, s, (int(pos2) - 1) // B + 1)
+        with self._span("serving.emit", "emit") as em:
+            now = em.t0
+            for s, req in enumerate(self._slots):
+                if req is None:
+                    continue
+                remaining = req.max_new - len(req.tokens)
+                commit, n_matched = _spec.accept_greedy(
+                    drafts[:, s], greedy[s], remaining)
+                done = False
+                appended = 0
+                for tok in commit:
+                    req.tokens.append(tok)
+                    emitted += 1
+                    appended += 1
+                    if ((req.eos_id is not None and tok == req.eos_id)
+                            or len(req.tokens) >= req.max_new):
+                        done = True
+                        break
+                acc = min(n_matched, appended)
+                # acceptance is judged over the draft tokens that COULD
+                # have committed (the request's remaining window), not the
+                # full k — end-of-request rounds would otherwise dilute the
+                # rate and make the predictor's steps-per-round estimate,
+                # and the reported draft quality, look worse than they are
+                eff = min(k, max(0, remaining - 1))
+                sp.proposed += eff
+                sp.accepted += acc
+                req.spec_proposed += eff
+                req.spec_accepted += acc
+                round_acc += acc
+                if done:
+                    self._release_slot(s)
+                    self._finish(req, now)
+                    finished += 1
+                else:
+                    # the draft KV is valid through the new frontier - 1;
+                    # scratch blocks past it held rejected-token state
+                    pos2 = req.prompt.shape[0] + len(req.tokens) - 1
+                    sp.rollback(self, s, (int(pos2) - 1) // B + 1)
+            # what the round committed is known here, after its span
+            # (the chunk-latency sample) closed: its emit span says it
+            em.set(emitted=emitted, accepted=round_acc)
         self._reg.counter("serving.tokens").inc(emitted)
         if wall > 0:
             self._reg.gauge("serving.tok_s").set(emitted / wall)
@@ -948,7 +1013,6 @@ class ServingEngine:
                 help="draft tokens accepted / proposed since the last "
                      "accounting reset",
             ).set(sp.accepted / sp.proposed)
-        self._reg.histogram("serving.decode_chunk").observe(wall)
         self._reg.histogram("serving.step_seconds").observe(
             wall / (k + 1))
         if active:
@@ -963,9 +1027,6 @@ class ServingEngine:
             else:
                 steps = emitted / active
             self.predictor.observe_chunk(wall, max(1, int(round(steps))))
-        tracer.add_span("serving.spec_round", t0, t1, cat="serving",
-                        k=k, active=active, emitted=emitted,
-                        accepted=round_acc)
         self._reg.gauge("serving.blocks_in_use").set(
             self.kv_pool.blocks_in_use)
         self._reg.gauge("serving.slots_active").set(self.active_slots)
@@ -1131,15 +1192,18 @@ class ServingEngine:
                    np.int32(slot), jnp.asarray(row), jnp.asarray(padded),
                    np.int32(start), np.int32(suffix), np.int32(cow_src),
                    np.int32(cow_dst))
-        t_p0 = time.perf_counter()
-        (self._pk, self._pv, self._last, self._pos,
-         first) = fn(self._p, self._pk, self._pv, self._last, self._pos,
-                     np.int32(slot), jnp.asarray(row),
-                     jnp.asarray(padded), np.int32(start),
-                     np.int32(suffix), np.int32(cow_src),
-                     np.int32(cow_dst))
-        first = int(np.asarray(first))  # host sync
-        now = time.perf_counter()
+        with self._span("serving.prefill", "prefill",
+                        histogram="serving.prefill_seconds", rid=req.rid,
+                        bucket=bucket, slot=slot, prefix_hit=start) as sp:
+            (self._pk, self._pv, self._last, self._pos,
+             first) = fn(self._p, self._pk, self._pv, self._last,
+                         self._pos, np.int32(slot), jnp.asarray(row),
+                         jnp.asarray(padded), np.int32(start),
+                         np.int32(suffix), np.int32(cow_src),
+                         np.int32(cow_dst))
+            with self._span("serving.fetch", "fetch", of="prefill"):
+                first = int(np.asarray(first))  # host sync
+        t_p0, now = sp.t0, sp.t1
         # the CoW source was held only for the copy window
         if cow is not None:
             pool.deref(cow[0])
@@ -1171,13 +1235,9 @@ class ServingEngine:
             trie.insert(req.prompt, [int(b) for b in row[:p_len
                                                          // self.block_tokens]])
         req.prefill_t0, req.prefill_t1 = t_p0, now
-        self._reg.histogram("serving.prefill_seconds").observe(now - t_p0)
         self.predictor.observe_prefill(bucket, now - t_p0)
-        self._tracer.add_span("serving.prefill", t_p0, now,
-                              cat="serving", rid=req.rid,
-                              bucket=bucket, slot=slot,
-                              prefix_hit=start)
         req.first_token_t = now
+        self._slot_advanced[slot] = now
         req.tokens.append(first)
         self._reg.counter("serving.admitted").inc()
         self._reg.counter("serving.tokens").inc()

@@ -121,15 +121,27 @@ def test_tpu_place_raises_without_an_accelerator():
     assert pt.CPUPlace().get_device().platform == "cpu"
 
 
-def _lowers_for_tpu(fn, *shapes):
+def _lowers_for_tpu(fn, *shapes, names):
+    """Cross-lowers ``fn`` for TPU and finds one Mosaic call per kernel
+    name in ``names``, each under that name: the call's location
+    (``jit(f)/transpose(jvp(fused_ce_dw))/pallas_call``) is what the TPU
+    compiler names the HLO instruction after (``%transpose_jvp_fused_ce_
+    dw__.3``), and the instruction text is all a device trace says of
+    where an operation comes from."""
+    import re
+
     args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
     text = jax.jit(fn).trace(*args).lower(
-        lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" in text
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert text.count("@tpu_custom_call") == len(names)
+    calls = re.findall(r'loc\("([^"]*)/pallas_call"', text)
+    for name in names:
+        assert any(re.search(rf"\b{name}\b", c) for c in calls), (name, calls)
 
 
-def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry():
+def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry(monkeypatch):
     from paddle_tpu import tune
+    from paddle_tpu.ops import pallas_attention
     from paddle_tpu.kernels.paged_attention import paged_attention_pallas
     from paddle_tpu.kernels.pallas_gather import decode_gather
     from paddle_tpu.ops.pallas_attention import (
@@ -147,13 +159,22 @@ def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry():
             q, k, v, h, causal=True, interpret=False).astype(jnp.float32))
 
     _lowers_for_tpu(jax.grad(flash_loss, (0, 1, 2)),
-                    *[((b, t, dm), bf16)] * 3)
+                    *[((b, t, dm), bf16)] * 3,
+                    names=("flash_fwd", "flash_bwd_fused"))
+
+    # long sequences (dq partials over budget) split the backward in two
+    monkeypatch.setattr(pallas_attention, "FUSED_BWD_PARTIAL_BYTES", 0)
+    _lowers_for_tpu(jax.grad(flash_loss, (0, 1, 2)),
+                    *[((b, t, dm), bf16)] * 3,
+                    names=("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    monkeypatch.undo()
 
     def ce_loss(x, w, y):
         return jnp.sum(_pallas_ce(x, w, y, interpret=False))
 
     _lowers_for_tpu(jax.grad(ce_loss, (0, 1)), ((b * t, dm), bf16),
-                    ((dm, vocab), bf16), ((b * t,), i32))
+                    ((dm, vocab), bf16), ((b * t,), i32),
+                    names=("fused_ce_fwd", "fused_ce_dx", "fused_ce_dw"))
 
     nb = chip_smoke.MAX_LEN // chip_smoke.BLOCK_TOKENS
     pool = ((1 + chip_smoke.SLOTS * nb + 2 * nb, chip_smoke.BLOCK_TOKENS,
@@ -164,9 +185,11 @@ def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry():
         _lowers_for_tpu(
             lambda *a: paged_attention_pallas(*a, interpret=False),
             ((slots, width, h, dm // h), bf16), pool, pool,
-            ((slots, nb), i32), ((slots, width), i32))
+            ((slots, nb), i32), ((slots, width), i32),
+            names=("paged_attention",))
     _lowers_for_tpu(lambda p, tb: decode_gather(p, tb, interpret=False),
-                    pool, ((chip_smoke.SLOTS, nb), i32))
+                    pool, ((chip_smoke.SLOTS, nb), i32),
+                    names=("decode_gather",))
 
 
 @pytest.mark.parametrize("recipe", ["fsdp", "tp"])
