@@ -26,11 +26,15 @@ decode step saturated across many requests:
 * **Continuous batching** — queued requests are admitted into free
   slots BETWEEN decode chunks, not at batch boundaries: a long request
   never holds the batch hostage, a short one never waits for stragglers.
-* **Bucketed prefill** — the NON-CACHED prompt suffix pads to the
-  nearest power-of-two bucket, so the compile cache is bounded by the
-  bucket set (TVM-style static shape buckets), never by the request
-  count: total executables = ``len(used prefill buckets) + 1`` decode
-  chunk — the copy-on-write fork rides inside the prefill executable.
+* **Bucketed prefill** — the NON-CACHED prompt suffix is one
+  teacher-forced window forward through the block table, padded to the
+  nearest power-of-two bucket up to the piece width
+  (``batched_decode.PREFILL_PIECE``); a longer suffix runs as
+  consecutive pieces inside the same admission.  The compile cache is
+  bounded by the widths (TVM-style static shape buckets), never by the
+  request count or the prompt length: total executables =
+  ``len(used widths) + 1`` decode chunk — the copy-on-write fork rides
+  inside the prefill executable.
 * **Chunked decode** — ``decode_chunk`` steps run per device call
   (one ``lax.scan``), amortizing dispatch + host sync.  EOS is detected
   on the host after the chunk.
@@ -42,9 +46,10 @@ decode step saturated across many requests:
   tokens nobody receives on time.  ``scheduler="fifo"`` keeps the PR-2
   policy as the benchmark baseline.
 
-Greedy decode through the engine is token-identical to running each
-request alone through ``transformer.generate`` — prefix reuse on or off
-(same per-row math; see ``batched_decode``).  Telemetry flows through
+Greedy decode through the engine computes each request as
+``transformer.generate`` would alone — prefix reuse on or off (same
+per-row math and dtypes, token-identical in f32; see ``batched_decode``
+and docs/serving.md "Numerics contract").  Telemetry flows through
 the global observability registry under ``serving.*``; with tracing
 enabled every finished request lays a span tree on its own timeline
 lane (submit -> queue -> prefill(bucket, prefix_hit) -> per-decode-
@@ -182,8 +187,9 @@ class ServingEngine:
              default) consults the autotune cache (workload key
              ``op=serving_decode``, docs/autotune.md) and falls back
              to 4 on a miss; an explicit value always wins.
-    min_bucket    smallest prefill bucket; prompt SUFFIXES (after prefix
-             reuse) pad to the nearest power-of-two multiple of it
+    min_bucket    narrowest prefill window; prompt SUFFIXES (after prefix
+             reuse) pad to the nearest power-of-two multiple of it up
+             to the piece width, and run as several pieces beyond it
              (compile-count bound).  ``None`` consults the same tuned
              entry; miss falls back to 8.
     block_tokens  tokens per physical KV block (paging granularity —
@@ -277,6 +283,8 @@ class ServingEngine:
             raise ValueError("decode_chunk and min_bucket must be >= 1")
         self.decode_chunk = int(decode_chunk)
         self.min_bucket = int(min_bucket)
+        # the widest prefill window (never narrower than a bucket)
+        self._piece = max(self.min_bucket, _bd.PREFILL_PIECE)
         table_len = np.asarray(params["pos_emb.w.w"]).shape[0]
         if self.max_len > table_len:
             raise ValueError(
@@ -350,7 +358,7 @@ class ServingEngine:
         self._qlock = threading.Lock()    # queue/completed/counters
         self._dlock = threading.RLock()   # the device state (one driver)
         self._next_rid = 0
-        self._prefill_fns = {}            # suffix bucket -> compiled fn
+        self._prefill_fns = {}            # window width -> compiled fn
         self._decode_fn = None
         # entry-point label -> the kernel-backend selections the kernel
         # registry recorded while that executable traced (so operators
@@ -754,14 +762,62 @@ class ServingEngine:
         call.prepare = prepare
         return call
 
+    def _piece_widths(self, n):
+        """Window widths that prefill a suffix of ``n`` tokens: whole
+        pieces of the piece width (``batched_decode.PREFILL_PIECE``),
+        then the remainder in the smallest power-of-two multiple of
+        ``min_bucket`` that covers it, capped at the piece width and at
+        ``max_len``."""
+        full, rem = divmod(max(int(n), 0), self._piece)
+        widths = [self._piece] * full
+        if rem or not full:
+            b = self.min_bucket
+            while b < rem:
+                b *= 2
+            widths.append(min(b, self._piece, self.max_len))
+        return widths
+
     def bucket_for(self, p_len):
-        """Prefill bucket for a (suffix) length: the smallest
-        power-of-two multiple of ``min_bucket`` that covers it, capped
-        at ``max_len``."""
-        b = self.min_bucket
-        while b < p_len:
-            b *= 2
-        return min(b, self.max_len)
+        """Padded tokens prefill computes for a (suffix) length: the
+        sum of its window widths.  Up to the piece width that is the
+        one power-of-two bucket; two lengths with the same value run
+        the same executables."""
+        return sum(self._piece_widths(p_len))
+
+    def _pieces(self, toks, start):
+        """The window calls that prefill ``toks`` at positions
+        ``start..``: ``[(width, tokens [width] padded, position of the
+        first token, real tokens)]``."""
+        import jax.numpy as jnp
+
+        out, off = [], 0
+        for w in self._piece_widths(len(toks)):
+            n = min(w, len(toks) - off)
+            padded = np.zeros(w, np.int32)
+            padded[:n] = toks[off:off + n]
+            out.append((w, jnp.asarray(padded), start + off, n))
+            off += n
+        return out
+
+    def _run_pieces(self, fn_of, params, pk, pv, slot, row, pieces,
+                    cow=(0, 0), compile_only=False):
+        """Dispatch ``pieces`` in order through the prefill executables
+        ``fn_of(width)``, each attending what the earlier ones wrote;
+        the CoW fork rides in the first.  Nothing is fetched: returns
+        ``(pool_k', pool_v', first_tok)`` of the LAST piece, still on
+        the device.  ``compile_only`` builds what is not compiled yet
+        and runs nothing."""
+        first = None
+        for i, (w, toks, at, n) in enumerate(pieces):
+            src, dst = cow if i == 0 else (0, 0)
+            args = (params, pk, pv, self._last, self._pos,
+                    np.int32(slot), row, toks, np.int32(at), np.int32(n),
+                    np.int32(src), np.int32(dst))
+            if compile_only:
+                fn_of(w).prepare(*args)
+            else:
+                pk, pv, self._last, self._pos, first = fn_of(w)(*args)
+        return pk, pv, first
 
     def _prefill_fn(self, bucket):
         fn = self._prefill_fns.get(bucket)
@@ -774,7 +830,7 @@ class ServingEngine:
             self._prefill_fns[bucket] = fn
             self._reg.counter(
                 "serving.prefill_compiles",
-                help="prefill executables built (one per shape bucket)",
+                help="prefill executables built (one per window width)",
             ).inc()
         return fn
 
@@ -1180,27 +1236,24 @@ class ServingEngine:
                 help="prefix-cache blocks forked copy-on-write").inc()
         start = int(hit)
         suffix = p_len - start
-        bucket = self.bucket_for(suffix)
+        pieces = self._pieces(req.prompt[start:], start)
+        bucket = sum(w for w, *_ in pieces)
         req.bucket = bucket
         req.prefix_hit = start
-        fn = self._prefill_fn(bucket)
-        padded = np.zeros(bucket, np.int32)
-        padded[:suffix] = req.prompt[start:]
-        # this bucket's one-time AOT compile lands here, outside the
-        # timed window the predictor consumes
-        fn.prepare(self._p, self._pk, self._pv, self._last, self._pos,
-                   np.int32(slot), jnp.asarray(row), jnp.asarray(padded),
-                   np.int32(start), np.int32(suffix), np.int32(cow_src),
-                   np.int32(cow_dst))
+        row_d = jnp.asarray(row)
+        # a width's one-time AOT compile lands here, outside the timed
+        # window the predictor consumes
+        self._run_pieces(self._prefill_fn, self._p, self._pk, self._pv,
+                         slot, row_d, pieces, compile_only=True)
+        # ONE span per admission, whatever the number of pieces; only
+        # the last piece's first token is fetched
         with self._span("serving.prefill", "prefill",
                         histogram="serving.prefill_seconds", rid=req.rid,
-                        bucket=bucket, slot=slot, prefix_hit=start) as sp:
-            (self._pk, self._pv, self._last, self._pos,
-             first) = fn(self._p, self._pk, self._pv, self._last,
-                         self._pos, np.int32(slot), jnp.asarray(row),
-                         jnp.asarray(padded), np.int32(start),
-                         np.int32(suffix), np.int32(cow_src),
-                         np.int32(cow_dst))
+                        bucket=bucket, pieces=len(pieces), slot=slot,
+                        prefix_hit=start) as sp:
+            self._pk, self._pv, first = self._run_pieces(
+                self._prefill_fn, self._p, self._pk, self._pv, slot,
+                row_d, pieces, cow=(cow_src, cow_dst))
             with self._span("serving.fetch", "fetch", of="prefill"):
                 first = int(np.asarray(first))  # host sync
         t_p0, now = sp.t0, sp.t1
@@ -1243,9 +1296,18 @@ class ServingEngine:
         self._reg.counter("serving.tokens").inc()
         self._reg.counter(
             "serving.prefill_tokens",
-            help="prompt-suffix tokens actually scanned by prefill "
-                 "(bucket-padded; prefix hits subtract from this)",
+            help="prompt-suffix tokens prefill computed (padded to the "
+                 "window widths; prefix hits subtract from this)",
         ).inc(bucket)
+        self._reg.counter(
+            "serving.prefill_real_tokens",
+            help="prompt-suffix tokens prefill computed, padding left "
+                 "out").inc(suffix)
+        for w, *_ in pieces:
+            self._reg.counter(
+                "serving.prefill_pieces", width=w,
+                help="prefill window calls dispatched, by width (an "
+                     "admission is one or more pieces)").inc()
         self._reg.histogram("serving.ttft_seconds").observe(
             now - req.submit_t)
         with self._qlock:
@@ -1322,12 +1384,14 @@ class ServingEngine:
         for nm in ("serving.slo_violations", "serving.goodput_tok_s",
                    "serving.shed_total", "serving.prefix_hit_rate",
                    "serving.prefix_hit_tokens", "serving.prefill_tokens",
-                   "serving.cow_copies", "serving.spec_accept_rate",
-                   "serving.spec_draft_ms",
+                   "serving.prefill_real_tokens", "serving.cow_copies",
+                   "serving.spec_accept_rate", "serving.spec_draft_ms",
                    "serving.spec_rollback_blocks"):
             m = self._reg.get(nm)
             if m is not None:
                 m.reset()
+        for m in self._reg.metrics("serving.prefill_pieces"):
+            m.reset()
 
     def _judge_slo(self, req, now):
         """SLO verdict at completion: a TTFT or e2e budget breach counts

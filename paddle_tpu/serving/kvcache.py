@@ -20,7 +20,7 @@ layout):
   per-slot block table row; position ``t`` lives at
   ``(table[t // B], t % B)``.  Decode gathers K/V through the table
   inside the compiled step (``batched_decode``), so the executable
-  count stays ``used_buckets + 1`` — the table is data, not shape.
+  count stays ``used_widths + 1`` — the table is data, not shape.
 * **Prefix reuse** — ``PrefixTrie``: a trie over FULL-block token
   chunks.  A request whose prompt starts with an already-cached chain
   shares those physical blocks (refcount, zero copy, zero prefill

@@ -81,8 +81,10 @@ class TtftPredictor:
 
     def prefill_s(self, bucket):
         """Prefill estimate for a bucket; an unseen bucket scales the
-        nearest observed one by the bucket ratio (prefill wall is
-        linear in scanned tokens)."""
+        nearest observed one by the bucket ratio.  Since prefill is a
+        window forward its wall grows slower than its tokens inside
+        one piece, so for a LARGER unseen bucket this errs high until
+        that bucket's first admission is observed."""
         if bucket in self._prefill:
             return self._prefill[bucket]
         if not self._prefill:

@@ -301,21 +301,15 @@ class SpecState:
         p_len = req.prompt.shape[0]
         self.ensure_chain(engine, slot,
                           -(-p_len // engine.block_tokens))
-        bucket = engine.bucket_for(p_len)
-        padded = np.zeros(bucket, np.int32)
-        padded[:p_len] = req.prompt
-        fn = self.prefill_fn(engine, bucket)
         # the draft touches only the first draft_n_layer pool arrays;
         # the target's deeper layers pass around the call untouched.
         # last/pos are donated scratch in spec mode (the round rebuilds
         # both from host mirrors); the draft's writes to them are noise
         nl = self.n_layer
-        (pk, pv, engine._last, engine._pos,
-         _first) = fn(self.p, engine._pk[:nl], engine._pv[:nl],
-                      engine._last, engine._pos, np.int32(slot),
-                      jnp.asarray(self.table[slot]), jnp.asarray(padded),
-                      np.int32(0), np.int32(p_len), np.int32(0),
-                      np.int32(0))
+        pk, pv, _first = engine._run_pieces(
+            lambda width: self.prefill_fn(engine, width), self.p,
+            engine._pk[:nl], engine._pv[:nl], slot,
+            jnp.asarray(self.table[slot]), engine._pieces(req.prompt, 0))
         engine._pk = tuple(pk) + engine._pk[nl:]
         engine._pv = tuple(pv) + engine._pv[nl:]
 

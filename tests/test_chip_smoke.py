@@ -179,9 +179,10 @@ def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry(monkeypatch):
     nb = chip_smoke.MAX_LEN // chip_smoke.BLOCK_TOKENS
     pool = ((1 + chip_smoke.SLOTS * nb + 2 * nb, chip_smoke.BLOCK_TOKENS,
              h, dm // h), bf16)
-    # decode, a verify window, and the batch-1 prefill step
+    # decode, a verify window, and a prefill piece too narrow to attend
+    # densely (batched_decode.DENSE_WINDOW)
     for slots, width in ((chip_smoke.SLOTS, 1), (chip_smoke.SLOTS, 4),
-                         (1, 1)):
+                         (1, 4)):
         _lowers_for_tpu(
             lambda *a: paged_attention_pallas(*a, interpret=False),
             ((slots, width, h, dm // h), bf16), pool, pool,
@@ -190,6 +191,46 @@ def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry(monkeypatch):
     _lowers_for_tpu(lambda p, tb: decode_gather(p, tb, interpret=False),
                     pool, ((chip_smoke.SLOTS, nb), i32),
                     names=("decode_gather",))
+
+
+@pytest.mark.parametrize("width", [4, 16, 128])
+def test_prefill_cross_lowers_for_tpu_dense_from_the_wide_window_up(
+        width, monkeypatch):
+    """The whole prefill executable, traced as the chip would (backend
+    "tpu", kernels native) at the smoke's block geometry: a window of
+    ``DENSE_WINDOW`` rows or more holds no Mosaic call at all (the chain
+    is gathered once and attended on the MXU), a narrower one holds the
+    streaming kernel once per layer."""
+    from paddle_tpu.serving import batched_decode as _bd
+
+    n_layer, h, dm, vocab = 2, 2, 256, 512
+    nb = chip_smoke.MAX_LEN // chip_smoke.BLOCK_TOKENS
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    p = {"tok_emb.w": sds((vocab, dm), bf16),
+         "pos_emb.w.w": sds((chip_smoke.MAX_LEN, dm), bf16),
+         "ln_f.scale": sds((dm,), bf16), "ln_f.bias": sds((dm,), bf16),
+         "lm_head.w": sds((dm, vocab), bf16)}
+    for i in range(n_layer):
+        for nm, (a, b) in (("att_q", (dm, dm)), ("att_k", (dm, dm)),
+                           ("att_v", (dm, dm)), ("att_out", (dm, dm)),
+                           ("ffn1", (dm, 4 * dm)), ("ffn2", (4 * dm, dm))):
+            p[f"block{i}_{nm}.w"] = sds((a, b), bf16)
+            p[f"block{i}_{nm}.b"] = sds((b,), bf16)
+        for ln in ("ln1", "ln2"):
+            p[f"block{i}_{ln}.scale"] = sds((dm,), bf16)
+            p[f"block{i}_{ln}.bias"] = sds((dm,), bf16)
+    pool = tuple(sds((1 + 3 * nb, chip_smoke.BLOCK_TOKENS, h, dm // h), bf16)
+                 for _ in range(n_layer))
+    scalar = sds((), i32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn = _bd.make_prefill(n_layer, h, dm, width, donate=False)
+    text = fn.trace(p, pool, pool, sds((3,), i32), sds((3,), i32), scalar,
+                    sds((nb,), i32), sds((width,), i32), scalar, scalar,
+                    scalar, scalar).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("@tpu_custom_call") == (
+        0 if width >= _bd.DENSE_WINDOW else n_layer)
 
 
 @pytest.mark.parametrize("recipe", ["fsdp", "tp"])

@@ -1,0 +1,282 @@
+"""Prefill as a window forward through the block table
+(``serving/batched_decode.py``): the K/V rows it writes and the logits
+that seed decode equal those of ``paged_step_logits`` stepped token by
+token on a copy of the same pool, whatever the padding, the number of
+pieces, the cached prefix in front or the copy-on-write fork; nothing
+outside the slot's own blocks and the trash block changes; the LM head
+runs on one row; and the engine counts pieces, real and padded tokens."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.kernels import oracle_tol
+from paddle_tpu.observability import trace
+from paddle_tpu.observability.metrics import MetricsRegistry
+from paddle_tpu.serving import ServingEngine
+from paddle_tpu.serving import batched_decode as _bd
+
+VOCAB, NL, NH, DM, T, B = 61, 2, 2, 32, 64, 4
+EPS = 1e-5
+PIECE = 8           # the piece width these tests give the engine
+NB = T // B
+
+
+def _params(dtype):
+    """Random weights under the serving names: the comparison is between
+    two spellings of one forward, so nothing has to be trained."""
+    rng = np.random.default_rng(11)
+
+    def w(*shape, scale=0.2):
+        return jnp.asarray(rng.normal(0.0, scale, shape), dtype)
+
+    p = {"tok_emb.w": w(VOCAB, DM), "pos_emb.w.w": w(T, DM),
+         "ln_f.scale": 1 + w(DM), "ln_f.bias": w(DM),
+         "lm_head.w": w(DM, VOCAB)}
+    for i in range(NL):
+        for nm, shape in (("att_q", (DM, DM)), ("att_k", (DM, DM)),
+                          ("att_v", (DM, DM)), ("att_out", (DM, DM)),
+                          ("ffn1", (DM, 4 * DM)), ("ffn2", (4 * DM, DM))):
+            p[f"block{i}_{nm}.w"] = w(*shape)
+            p[f"block{i}_{nm}.b"] = w(shape[1], scale=0.05)
+        for ln in ("ln1", "ln2"):
+            p[f"block{i}_{ln}.scale"] = 1 + w(DM)
+            p[f"block{i}_{ln}.bias"] = w(DM)
+    return p
+
+
+def _engine(params, monkeypatch, **kw):
+    monkeypatch.setattr(_bd, "PREFILL_PIECE", PIECE)
+    reg = MetricsRegistry()
+    eng = ServingEngine(params, NL, NH, DM, max_len=T, max_slots=3,
+                        block_tokens=B, decode_chunk=4, min_bucket=4,
+                        donate=False, registry=reg, **kw)
+    return eng, reg
+
+
+def _noise_pool(eng, seed):
+    """A pool full of finite garbage: whatever prefill must not read has
+    to be masked, and whatever it must not write has to stay as it is."""
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(0.0, 1.0, c.shape), c.dtype)
+                 for c in eng._pk)
+
+
+@jax.jit
+def _step(p, tok, t, pk, pv, row):
+    return _bd.paged_step_logits(p, tok, t, pk, pv, row[None], NL, NH, DM,
+                                 EPS)
+
+
+@jax.jit
+def _window(p, pk, pv, toks, at, last, row):
+    return _bd._window_forward(p, pk, pv, toks[None], at[None], last[None],
+                               row[None], NL, NH, DM, EPS)
+
+
+def _step_through(p, pk, pv, row, toks, start):
+    """The reference: one ``paged_step_logits`` call per token."""
+    logits = None
+    for j, tok in enumerate(toks):
+        logits, pk, pv = _step(p, jnp.asarray([tok], jnp.int32),
+                               jnp.asarray([start + j], jnp.int32), pk, pv,
+                               row)
+    return logits[0], pk, pv
+
+
+def _window_logits(eng, pk, pv, row, toks, start):
+    """The same pieces the engine dispatches, through the one window
+    forward, with the logits (the executable keeps only their argmax)."""
+    logits = None
+    for _w, padded, at, n in eng._pieces(toks, start):
+        x, pk, pv = _window(eng._p, pk, pv, padded, jnp.int32(at),
+                            jnp.int32(at + n - 1), row)
+        logits = _bd._head_logits(eng._p, x[0, n - 1], EPS)
+    return logits
+
+
+# suffix, start, fork: (name, tokens behind the cached prefix, cached
+# prefix tokens, whether the prefix ends inside a block that is forked)
+CASES = [
+    ("padding_rows", 5, 0, False),          # one piece of 8, 3 rows padding
+    ("exactly_a_bucket", 8, 0, False),      # one piece, no padding
+    ("two_pieces_and_a_remainder", 19, 0, False),   # 8 + 8 + 4 (3 real)
+    ("behind_a_cached_prefix", 6, 8, False),        # start on a block edge
+    ("cow_fork", 11, 6, True),              # start inside a forked block
+]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name,suffix,start,fork", CASES,
+                         ids=[c[0] for c in CASES])
+def test_window_prefill_equals_the_token_steps(monkeypatch, dtype, name,
+                                               suffix, start, fork):
+    eng, _reg = _engine(_params(dtype), monkeypatch)
+    rng = np.random.default_rng(suffix * 31 + start)
+    prompt = rng.integers(1, VOCAB, start + suffix, dtype=np.int32)
+    # the slot's chain: blocks 9.. in a shuffled order, the rest trash
+    n_blocks = -(-(start + suffix + 4) // B)
+    chain = 9 + rng.permutation(n_blocks + 2)[:n_blocks]
+    row_h = np.zeros(NB, np.int32)
+    row_h[:n_blocks] = chain
+    shared = start // B                 # whole blocks of the prefix
+    cow = (0, 0)
+    pk, pv = _noise_pool(eng, 3), _noise_pool(eng, 4)
+    if start:
+        # the cached prefix, as an earlier request's steps left it; with
+        # a fork its last, partial block lives in another chain's block
+        src_row = row_h.copy()
+        if fork:
+            src_row[shared] = 7
+            cow = (7, int(chain[shared]))
+        _, pk, pv = _step_through(eng._p, pk, pv, jnp.asarray(src_row),
+                                  prompt[:start], 0)
+    row = jnp.asarray(row_h)
+    own = set(int(b) for b in chain[shared:])
+    pk0, pv0 = pk, pv
+
+    # reference: the fork as a plain block copy, then token by token
+    rk = tuple(c.at[cow[1]].set(c[cow[0]]) for c in pk)
+    rv = tuple(c.at[cow[1]].set(c[cow[0]]) for c in pv)
+    ref_logits, rk, rv = _step_through(eng._p, rk, rv, row, prompt[start:],
+                                       start)
+
+    pieces = eng._pieces(prompt[start:], start)
+    assert [w for w, *_ in pieces] == eng._piece_widths(suffix)
+    assert sum(n for *_, n in pieces) == suffix
+    gk, gv, first = eng._run_pieces(eng._prefill_fn, eng._p, pk, pv, 1, row,
+                                    pieces, cow=cow)
+
+    tol = (1e-5 if dtype == "float32"
+           else oracle_tol("paged_attention", dtype))
+    f32 = lambda a: np.asarray(a, np.float32)
+
+    def close(got, ref):
+        # the oracle suites' measure: the largest error over the
+        # largest reference magnitude
+        assert np.abs(got - ref).max() <= tol * np.abs(ref).max()
+
+    for got, ref, before in zip(gk + gv, rk + rv, pk0 + pv0):
+        got, ref, before = f32(got), f32(ref), f32(before)
+        # every real position of the suffix holds the steps' K/V row
+        at = np.arange(start, start + suffix)
+        close(got[row_h[at // B], at % B], ref[row_h[at // B], at % B])
+        # nothing but the slot's own blocks and the trash block moved:
+        # not the shared prefix, not the fork's source, not a neighbour
+        untouched = [b for b in range(got.shape[0])
+                     if b not in own and b != 0]
+        np.testing.assert_array_equal(got[untouched], before[untouched])
+    logits = _window_logits(
+        eng, tuple(c.at[cow[1]].set(c[cow[0]]) for c in pk0),
+        tuple(c.at[cow[1]].set(c[cow[0]]) for c in pv0), row,
+        prompt[start:], start)
+    close(f32(logits), f32(ref_logits))
+    assert int(first) == int(np.argmax(f32(ref_logits)))
+    # the slot's scalars are seeded for decode
+    assert int(eng._last[1]) == int(first)
+    assert int(eng._pos[1]) == start + suffix
+
+
+@pytest.mark.parametrize("width", [4, 8])
+def test_prefill_runs_the_lm_head_on_one_row(width):
+    """The lowered prefill of any width holds exactly one matmul against
+    ``lm_head.w`` ([d, vocab]; no other operand has that shape), and its
+    left operand has one row."""
+    p = _params("float32")
+    pool = tuple(jnp.zeros((12, B, NH, DM // NH), jnp.float32)
+                 for _ in range(NL))
+    fn = _bd.make_prefill(NL, NH, DM, width, eps=EPS, donate=False)
+    i32 = lambda v: np.int32(v)
+    text = fn.lower(p, pool, pool, jnp.zeros(3, jnp.int32),
+                    jnp.zeros(3, jnp.int32), i32(1),
+                    jnp.zeros(NB, jnp.int32), jnp.zeros(width, jnp.int32),
+                    i32(0), i32(width - 1), i32(0), i32(0)).as_text()
+    dots = re.findall(r"stablehlo\.dot_general.*?:\s*\((tensor<[^>]*>), "
+                      r"(tensor<[^>]*>)\)", text)
+    head = [(a, b) for a, b in dots if b == f"tensor<{DM}x{VOCAB}xf32>"]
+    assert head == [(f"tensor<1x{DM}xf32>", f"tensor<{DM}x{VOCAB}xf32>")]
+    # the trunk's matmuls are window-wide: the weights are read once
+    assert (f"tensor<1x{width}x{DM}xf32>",
+            f"tensor<{DM}x{DM}xf32>") in dots
+
+
+def test_wide_windows_attend_densely_narrow_ones_stream(monkeypatch):
+    """The attention spelling follows the window's width at trace time:
+    from ``DENSE_WINDOW`` rows up one step over the whole chain of the
+    ``xla_ref`` spelling, below it whatever the registry resolves with
+    its own default geometry."""
+    from paddle_tpu import kernels
+
+    calls = []
+    real = kernels.resolve
+
+    def spy(op, backend=None, **kw):
+        ker = real(op, backend=backend, **kw)
+
+        class Impl:
+            @staticmethod
+            def call(q, *a, block_step=None, **k):
+                calls.append((q.shape[1], backend, block_step))
+                return ker.impl.call(q, *a, block_step=block_step, **k)
+
+        return type("K", (), {"impl": Impl, "backend": ker.backend})
+
+    monkeypatch.setattr(kernels, "resolve", spy)
+    pool = jnp.zeros((6, B, NH, DM // NH), jnp.float32)
+    table = jnp.zeros((1, NB), jnp.int32)
+    for w in (1, _bd.DENSE_WINDOW - 1, _bd.DENSE_WINDOW, 4 * _bd.DENSE_WINDOW):
+        q = jnp.zeros((1, w, NH, DM // NH), jnp.float32)
+        _bd._paged_attention(q, pool, pool, table,
+                             jnp.zeros((1, w), jnp.int32))
+    assert calls == [(1, None, None), (_bd.DENSE_WINDOW - 1, None, None),
+                     (_bd.DENSE_WINDOW, "xla_ref", NB),
+                     (4 * _bd.DENSE_WINDOW, "xla_ref", NB)]
+
+
+def test_engine_counts_pieces_real_and_padded_tokens(monkeypatch):
+    """After admissions of known suffix lengths the counters read what
+    the lengths imply, one ``serving.prefill`` span covers an admission
+    whatever its pieces, and no executable is wider than a piece."""
+    eng, reg = _engine(_params("float32"), monkeypatch, prefix_reuse=False)
+    lens = [3, 8, 13, 19, 24]     # 4 | 8 | 8+8 | 8+8+4 | 8+8+8
+    widths = {4: 2, 8: 8}
+    t = trace.Tracer(enabled=True, registry=None)
+    old = trace.set_tracer(t)
+    try:
+        rng = np.random.default_rng(5)
+        eng.generate_many([rng.integers(1, VOCAB, n, dtype=np.int32)
+                           for n in lens], max_new_tokens=3)
+    finally:
+        trace.set_tracer(old)
+    st = eng.stats()
+    assert {k: v for k, v in st.items()
+            if k.startswith("serving.prefill_pieces")} == {
+        f"serving.prefill_pieces{{width={w}}}": n
+        for w, n in widths.items()}
+    assert st["serving.prefill_real_tokens"] == sum(lens)
+    assert st["serving.prefill_tokens"] == sum(
+        w * n for w, n in widths.items()) == sum(
+        eng.bucket_for(n) for n in lens)
+    spans = t.events(name="serving.prefill")
+    assert len(spans) == len(lens) == st["serving.prefill_seconds"]["count"]
+    assert sorted(e["args"]["pieces"] for e in spans) == [1, 1, 2, 3, 3]
+    assert sorted(eng._prefill_fns) == sorted(widths)
+    assert st["serving.prefill_compiles"] == len(widths)
+    # the accounting window re-opens with the other prefill counters
+    eng.reset_slo_accounting()
+    st = eng.stats()
+    assert st["serving.prefill_real_tokens"] == 0
+    assert all(v == 0 for k, v in st.items()
+               if k.startswith("serving.prefill_pieces"))
+
+
+@pytest.mark.parametrize("n,widths", [
+    (1, [4]), (4, [4]), (5, [8]), (8, [8]), (9, [8, 4]), (16, [8, 8]),
+    (21, [8, 8, 8]), (29, [8, 8, 8, 8])])
+def test_piece_widths_are_buckets_up_to_the_piece(monkeypatch, n, widths):
+    eng, _reg = _engine(_params("float32"), monkeypatch)
+    assert eng._piece_widths(n) == widths
+    assert eng.bucket_for(n) == sum(widths)

@@ -101,8 +101,11 @@ def test_eos_evicts_slot_early(params):
 
 
 def test_bucketed_prefill_bounds_compiles(params):
-    """50+ mixed-length requests: executables == used prefill buckets + 1
-    decode chunk, regardless of request count."""
+    """50+ mixed-length requests: the prefill executables are window
+    widths up to the piece width, one compile each, plus 1 decode chunk,
+    regardless of request count; none compiles after the warm pass."""
+    from paddle_tpu.serving import batched_decode as _bd
+
     eng = _engine(params, max_slots=8, min_bucket=4)
     rng = np.random.default_rng(2)
     n = 52
@@ -110,16 +113,25 @@ def test_bucketed_prefill_bounds_compiles(params):
     prompts = [rng.integers(1, VOCAB, (int(l),)) for l in lens]
     outs = eng.generate_many(prompts, max_new_tokens=4)
     assert len(outs) == n
-    buckets = {eng.bucket_for(int(l)) for l in lens}
+    widths = {w for l in lens for w in eng._piece_widths(int(l))}
+    assert widths == {eng.bucket_for(int(l)) for l in lens}  # one piece
+    assert widths <= {4, 8, 16, 32, 64, 128} and max(widths) <= min(
+        _bd.PREFILL_PIECE, T)
     st = eng.stats()
-    assert st["serving.prefill_compiles"] == len(buckets) <= 3
+    assert st["serving.prefill_compiles"] == len(widths) <= 3
     assert st["serving.decode_compiles"] == 1
     assert st["serving.admitted"] == n
     assert st["serving.completed"] == n
     # the counters must reflect REAL jit-cache entries: one executable
-    # per bucket callable / per decode chunk, no silent retraces
+    # per width callable / per decode chunk, no silent retraces
     assert eng._decode_fn._cache_size() == 1
-    assert sorted(eng._prefill_fns) == sorted(buckets)
+    assert sorted(eng._prefill_fns) == sorted(widths)
+    compiled = dict(eng.compile_seconds)
+    # a second wave over the same widths compiles nothing
+    eng.generate_many([rng.integers(1, VOCAB, (int(l),))
+                       for l in rng.integers(1, 14, 12)], max_new_tokens=4)
+    assert eng.compile_seconds == compiled
+    assert eng.stats()["serving.prefill_compiles"] == len(widths)
     assert all(f._cache_size() == 1 for f in eng._prefill_fns.values())
 
 
