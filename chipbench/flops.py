@@ -2,13 +2,17 @@
 
 Every function here takes sizes (from a configuration file and a traffic
 file) and returns counts; nothing is read from the program, so a PR that
-changes the program cannot change what its work is worth.  Peaks come
-from ``peaks.json`` beside this file, keyed by ``device_kind``; a device
-that is not in the table is an error, never a default.
+changes the program cannot change what its work is worth.  What a model
+is made of comes from its family's ``sizes(config)``
+(``chipbench/families/``), never from a configuration's own keys.  Peaks
+come from ``peaks.json`` beside this file, keyed by ``device_kind``; a
+device that is not in the table is an error, never a default.
 """
 
 import json
 import os
+
+from . import families
 
 _PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "peaks.json")
@@ -27,16 +31,13 @@ def peaks(device_kind):
 
 def vocab_rows(config):
     """Rows the embedding table and the head hold (the padded vocabulary)."""
-    return int(config.get("changed", {}).get("vocab_rows",
-                                             config["vocab_size"]))
+    return families.sizes(config)["vocab_rows"]
 
 
 def matmul_params(config):
-    """Parameters that multiply every token: the blocks' four attention
-    projections and two FFN matrices, and the head.  Embedding rows are
-    gathered, not multiplied, and biases and LayerNorms are O(d)."""
-    d, f, n_layer = config["n_embd"], config["n_inner"], config["n_layer"]
-    return n_layer * (4 * d * d + 2 * d * f) + d * vocab_rows(config)
+    """Matmul parameters applied to every token: the blocks' projections
+    and FFN matrices, each as often as it runs, and the head."""
+    return families.sizes(config)["matmul_params"]
 
 
 def train_flops_per_token(config, seq_len):
@@ -44,15 +45,18 @@ def train_flops_per_token(config, seq_len):
 
     6 per matmul parameter (2 forward, 4 backward), plus causal
     attention: QK^T and PV are 2 * 2 * d operations per (query, key)
-    pair forward and twice that backward, a token of a length-t sequence
-    attends (t + 1) / 2 keys on average, so 6 * L * d * t.  PaLM's
+    pair forward and twice that backward (d the heads' width together),
+    a token of a length-t sequence attends (t + 1) / 2 keys on average,
+    so 6 * L * d * t over the L attention applications.  PaLM's
     appendix counts 12 * L * d * t, every pair of the square: half of
     those pairs are masked and no causal kernel has to compute them, so
     the smaller figure is used and utilization is never flattered.
     Recomputed operations (remat) are not counted.
     """
-    d, n_layer = config["n_embd"], config["n_layer"]
-    return 6 * matmul_params(config) + 6 * n_layer * d * seq_len
+    size = families.sizes(config)
+    d = size["heads"] * size["head_dim"]
+    return (6 * size["matmul_params"]
+            + 6 * size["attention_passes"] * d * seq_len)
 
 
 def flash_fwd(batch, n_head, head_dim, seq_len, itemsize=2):
@@ -84,16 +88,20 @@ def roofline_seconds(ops, nbytes, peak):
 
 
 def kv_bytes_per_token(config, itemsize=2):
-    """K and V of one cached token across all layers."""
-    return 2 * config["n_layer"] * config["n_embd"] * itemsize
+    """K and V of one cached token across all its planes."""
+    size = families.sizes(config)
+    return (2 * size["kv_planes"] * size["kv_heads"] * size["head_dim"]
+            * itemsize)
 
 
 def paged_attention_live(config, contexts, itemsize=2):
     """(operations, bytes) of attending one new token per entry of
     ``contexts`` (each entry the number of tokens attended, itself
     included) across all layers, reading only the live tokens' K and V:
-    2 * 2 * d operations and 2 * d * itemsize bytes per attended token
-    per layer."""
+    2 * 2 * d operations per attended token per attention application
+    and 2 * d * itemsize bytes per attended token per plane."""
+    size = families.sizes(config)
     attended = int(sum(contexts))
-    ops = 4 * config["n_layer"] * config["n_embd"] * attended
+    ops = (4 * size["attention_passes"] * size["heads"] * size["head_dim"]
+           * attended)
     return ops, attended * kv_bytes_per_token(config, itemsize)

@@ -10,7 +10,7 @@ they return: forward (o [b, t, d], lse [b * h, t, 1]), backward (dq in
 two parts [2, b, t, d], dk, dv).  A kernel that returns other shapes is
 not found and the metric is left out, not guessed."""
 
-from chipbench import flops, trace_reduce
+from chipbench import families, flops, trace_reduce
 
 NAME = "flash_roofline"
 LAYER = "Kernels"
@@ -21,16 +21,17 @@ RUNNERS = ("train",)
 
 
 def _shape(cfg, mix):
-    return (mix["sequences_per_step"] // mix["micro_steps"], cfg["n_head"],
-            cfg["n_embd"] // cfg["n_head"], mix["seq_len"])
+    size = families.sizes(cfg)
+    return (mix["sequences_per_step"] // mix["micro_steps"], size["heads"],
+            size["head_dim"], mix["seq_len"])
 
 
 def kernels(cfg, mix):
-    b, h, _, t = _shape(cfg, mix)
-    x = f"bf16[{b},{t},{cfg['n_embd']}]"
+    b, h, dh, t = _shape(cfg, mix)
+    x = f"bf16[{b},{t},{h * dh}]"
     call = 'custom_call_target="tpu_custom_call"'
     return {"flash_fwd": (call, f"= ({x}", f"f32[{b * h},{t},1]", ") custom-call("),
-            "flash_bwd": (call, f"= (bf16[2,{b},{t},{cfg['n_embd']}]",
+            "flash_bwd": (call, f"= (bf16[2,{b},{t},{h * dh}]",
                           ") custom-call(")}
 
 

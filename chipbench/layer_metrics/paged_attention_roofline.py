@@ -11,7 +11,7 @@ found by the pool's shape among their operands ([blocks, block_tokens,
 heads, 128], the block count from the engine geometry in the traffic
 file): decode steps and prefill's single-slot steps alike."""
 
-from chipbench import flops, trace_reduce
+from chipbench import families, flops, trace_reduce
 
 NAME = "paged_attention_roofline"
 LAYER = "Kernels"
@@ -26,8 +26,9 @@ def kernels(cfg, mix):
     per_slot = -(-eng["max_len"] // eng["block_tokens"])
     cache = eng.get("cache_blocks", 2 * per_slot)  # the engine's default
     blocks = 1 + eng["max_slots"] * per_slot + cache
-    pool = (f"bf16[{blocks},{eng['block_tokens']},{cfg['n_head']},"
-            f"{cfg['n_embd'] // cfg['n_head']}]")
+    size = families.sizes(cfg)
+    pool = (f"bf16[{blocks},{eng['block_tokens']},{size['kv_heads']},"
+            f"{size['head_dim']}]")
     return {"paged_attention": ('custom_call_target="tpu_custom_call"',
                                 pool, "s32[")}
 

@@ -1,8 +1,9 @@
 """The serving runner: open-loop load on ``ServingEngine``.
 
-Makes bf16 weights on the device from ``--seed`` in one jitted call,
-builds the engine with the geometry the traffic file gives (everything
-else at the engine's defaults), warms every prefill bucket the mix can
+The configuration's family module (``chipbench/families/``) makes the
+weights on the device from ``--seed`` in one jitted call and builds the
+engine with the geometry the traffic file gives (everything else at the
+engine's defaults); the runner warms every prefill bucket the mix can
 reach and serves the shared heads once, then submits the schedule of
 ``chipbench/traffic.py`` at its due times from this one thread.  Each
 request is timed from when it was due, not from when it was sent.
@@ -13,7 +14,7 @@ import time
 
 import numpy as np
 
-from .. import device, reference, traffic, weights
+from .. import device, families, traffic
 from ..stats import quantile_hd
 
 
@@ -51,7 +52,7 @@ def _warm_up(eng, mix, sched, vocab):
     return len(prompts)
 
 
-def _check(cfg, params, positions, sample, margin):
+def _check(family, cfg, params, positions, sample, margin):
     """Prompts come back unchanged, and the reference's full forward over
     prompt + output rates every generated token within ``margin`` of its
     own maximum.  Returns (ok, worst margin seen)."""
@@ -63,9 +64,7 @@ def _check(cfg, params, positions, sample, margin):
             return False, float("inf")
         padded = np.zeros((1, positions), np.int32)
         padded[0, :len(full)] = full
-        lg = np.asarray(reference.logits(
-            params, padded, cfg["n_layer"], cfg["n_head"],
-            cfg["layer_norm_epsilon"]))[0]
+        lg = np.asarray(family.logits(params, padded, cfg))[0]
         at = lg[n_p - 1:len(full) - 1]
         gap = at.max(axis=-1) - at[np.arange(len(at)), full[n_p:]]
         worst = max(worst, float(gap.max()))
@@ -77,17 +76,15 @@ def run(cell, seed, seconds, tracer):
     """One run of a serving cell; see ``chipbench/run.py`` for the shape
     of what comes back."""
     import jax
-    import paddle_tpu as pt
     from paddle_tpu.observability.metrics import MetricsRegistry
 
     cfg, mix = cell["config"], cell["traffic"]
+    family = families.of(cfg, "serve")
     geometry = dict(mix["engine"])
     positions = geometry["max_len"]
-    params = weights.make_params(cfg, positions, seed)
+    params = family.make_params(cfg, positions, seed)
     reg = MetricsRegistry()
-    eng = pt.serving.ServingEngine(
-        params, cfg["n_layer"], cfg["n_head"], cfg["n_embd"],
-        eps=cfg["layer_norm_epsilon"], registry=reg, **geometry)
+    eng = family.serving_engine(params, cfg, reg, geometry)
     sched = traffic.serve_schedule(mix, cfg["vocab_size"], seed, seconds)
     n = len(sched["prompts"])
 
@@ -176,7 +173,8 @@ def run(cell, seed, seconds, tracer):
     rng = np.random.default_rng(traffic.seed_words(seed, 4))
     sample = ([done[i] for i in rng.choice(
         len(done), min(check["sample"], len(done)), replace=False)])
-    ok, worst = _check(cfg, params, positions, sample, check["logit_margin"])
+    ok, worst = _check(family, cfg, params, positions, sample,
+                       check["logit_margin"])
     correct = (ok and bool(done) and compiled_in_window == 0)
     return {
         "correct": bool(correct), "attempted": n, "failed": failed,

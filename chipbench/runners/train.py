@@ -1,13 +1,13 @@
 """The training runner: ``Program`` -> ``Executor.run`` on seeded batches.
 
-Builds the configuration with ``transformer.build(fused_head=True)`` and
-the recipe the traffic file names (remat policy, micro-batches, mesh
-axes), runs the startup program and sets the weights from ``--seed``, checks
-the first loss against ``chipbench/reference.py`` on the same weights
-and tokens (the labels of that one step are the reference's own most
-likely tokens: ``reference.greedy_loss`` says why), warms up, and then
-trains for the window on a new seeded batch fed from the host every
-step.
+Builds the configuration's Program through its family module
+(``chipbench/families/``) and applies the recipe the traffic file names
+(remat policy, micro-batches, mesh axes), runs the startup program and
+sets the weights from ``--seed``, checks the first loss against the
+family's plain reference on the same weights and tokens (the labels of
+that one step are the reference's own most likely tokens: its
+``greedy_loss`` says why), warms up, and then trains for the window on a
+new seeded batch fed from the host every step.
 """
 
 import statistics
@@ -15,21 +15,14 @@ import time
 
 import numpy as np
 
-from .. import device, flops, reference, traffic, weights
+from .. import device, families, traffic
 
 
-def _build(pt, cfg, mix, mesh):
-    from paddle_tpu.models import transformer
-
+def _build(pt, family, cfg, mix, mesh):
     pt.core.unique_name.reset()
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
-        outs = transformer.build(
-            vocab_size=flops.vocab_rows(cfg), n_layer=cfg["n_layer"],
-            n_head=cfg["n_head"], d_model=cfg["n_embd"],
-            d_ff=cfg["n_inner"], max_len=mix["seq_len"], dropout_rate=0.0,
-            dtype=cfg["compute_dtype"], fused_head=True,
-            learning_rate=mix["learning_rate"])
+        avg_cost = family.training_program(cfg, mix)
         # the PR-10 recipe's order: remat first (the scan body is where
         # in-loop gathers live), then accumulation, then placement
         if mix["memory_optimize"] != "none":
@@ -41,7 +34,7 @@ def _build(pt, cfg, mix, mesh):
                 pt.parallel.data_parallel(main, "dp", programs=(startup,))
             if "fsdp" in mesh.shape:
                 pt.parallel.shard_fsdp(main, programs=(startup,))
-    return main, startup, outs["avg_cost"]
+    return main, startup, avg_cost
 
 
 def run(cell, seed, seconds, tracer):
@@ -51,11 +44,12 @@ def run(cell, seed, seconds, tracer):
     import paddle_tpu as pt
 
     cfg, mix = cell["config"], cell["traffic"]
+    family = families.of(cfg, "train")
     devices = jax.devices()[:cell["chips"]]
     mesh = None
     if mix.get("mesh"):
         mesh = pt.parallel.make_mesh(dict(mix["mesh"]), devices=devices)
-    main, startup, avg_cost = _build(pt, cfg, mix, mesh)
+    main, startup, avg_cost = _build(pt, family, cfg, mix, mesh)
     scope = pt.Scope()
     exe = pt.Executor(mesh=mesh)
     compile_s = {}
@@ -74,7 +68,7 @@ def run(cell, seed, seconds, tracer):
     # seed of its own would make it a new executable for every --seed;
     # it runs as built (optimizer state, shapes, placement) and the
     # weights are then set from --seed, as a loaded checkpoint would be
-    made = weights.make_params(cfg, seq_len, seed)
+    made = family.make_params(cfg, seq_len, seed)
     params = {}
     for p in main.all_parameters():
         old = scope.get(p.name)
@@ -89,9 +83,7 @@ def run(cell, seed, seconds, tracer):
     # the first step overwrites them: its greedy labels are that step's
     # labels, its loss on them what the program's first loss is held to
     feed0 = feed_of(0)
-    labels0, ref_loss = reference.greedy_loss(
-        params, feed0["tokens"], cfg["n_layer"], cfg["n_head"],
-        cfg["layer_norm_epsilon"])
+    labels0, ref_loss = family.greedy_loss(params, feed0["tokens"], cfg)
     feed0["labels"] = np.asarray(labels0, feed0["labels"].dtype)
     if feed0["labels"].max() >= cfg["vocab_size"]:
         raise RuntimeError("the reference chose a padded id")

@@ -57,6 +57,26 @@ def test_paged_attention_counts_by_hand():
     assert bound == "memory"
 
 
+@pytest.mark.parametrize("name, rows, matmul, train_2k, kv, live", [
+    ("cerebras-gpt-590m", 50304, 586_874_880, 3_860_987_904, 110_592,
+     (77_414_400, 77_414_400)),
+    ("cerebras-gpt-1.3b", 50304, 1_310_982_144, 8_469_872_640, 196_608,
+     (137_625_600, 137_625_600)),
+])
+def test_counts_through_the_family_are_the_parents(name, rows, matmul,
+                                                   train_2k, kv, live):
+    """What ``flops.py`` returned at commit cdabbda, when it read
+    ``n_layer`` / ``n_embd`` / ``n_inner`` itself (PR 27 moved the sizes
+    behind ``chipbench/families/``)."""
+    c = _config(name)
+    assert "family" not in c  # absent means gpt2: the files did not change
+    assert flops.vocab_rows(c) == rows
+    assert flops.matmul_params(c) == matmul
+    assert flops.train_flops_per_token(c, 2048) == train_2k
+    assert flops.kv_bytes_per_token(c) == kv
+    assert flops.paged_attention_live(c, [100, 600]) == live
+
+
 def test_unknown_device_is_an_error():
     with pytest.raises(KeyError):
         flops.peaks("TPU v9 imaginary")
