@@ -17,7 +17,7 @@ five; a family that only serves, or only trains, leaves the other out):
 4. the plain reference: ``logits(params, tokens, cfg)`` (serving) and
    ``greedy_loss(params, tokens, cfg)`` (training);
 5. ``sizes(cfg)``: what shape-only code needs, as one dict of integers:
-   ``d_model``; ``heads``, ``kv_heads`` and ``head_dim``; ``vocab_rows``
+   ``d_model``; ``heads`` and ``head_dim``; ``vocab_rows``
    (rows of the vocabulary held); ``matmul_params`` (matmul parameters
    APPLIED per token: a weight used four times a token counts four
    times); ``kv_planes`` (K/V planes a token holds: layers x times each
@@ -30,16 +30,17 @@ import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 DEFAULT = "gpt2"
-SERVE = ("make_params", "serving_engine", "logits", "sizes")
-TRAIN = ("make_params", "training_program", "greedy_loss", "sizes")
-NEEDS = {"serve": SERVE, "train": TRAIN}
+# what each runner takes from a family module
+NEEDS = {"serve": ("make_params", "serving_engine", "logits", "sizes"),
+         "train": ("make_params", "training_program", "greedy_loss",
+                   "sizes")}
 
 
 def load(name, directory=None):
     """The module ``<directory>/<name>.py``.  With no directory: the
     module of that name already loaded, else the one beside this file."""
     key = "chipbench.families." + name
-    if directory is None and key in sys.modules:
+    if directory is None and sys.modules.get(key) is not None:
         return sys.modules[key]
     path = os.path.join(directory or HERE, name + ".py")
     if not os.path.isfile(path):

@@ -49,7 +49,7 @@ def sizes(cfg):
     d, f, n_layer = cfg["n_embd"], cfg["n_inner"], cfg["n_layer"]
     rows = int(cfg.get("changed", {}).get("vocab_rows", cfg["vocab_size"]))
     return {
-        "d_model": d, "heads": cfg["n_head"], "kv_heads": cfg["n_head"],
+        "d_model": d, "heads": cfg["n_head"],
         "head_dim": d // cfg["n_head"], "vocab_rows": rows,
         # the blocks' four attention projections and two FFN matrices,
         # and the head; embedding rows are gathered, not multiplied, and

@@ -90,7 +90,7 @@ def roofline_seconds(ops, nbytes, peak):
 def kv_bytes_per_token(config, itemsize=2):
     """K and V of one cached token across all its planes."""
     size = families.sizes(config)
-    return (2 * size["kv_planes"] * size["kv_heads"] * size["head_dim"]
+    return (2 * size["kv_planes"] * size["heads"] * size["head_dim"]
             * itemsize)
 
 

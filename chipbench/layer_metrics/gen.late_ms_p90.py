@@ -6,7 +6,7 @@ from chipbench import stats
 NAME = "gen.late_ms_p90"
 LAYER = "Entry points"
 UNIT = "ms"
-MOVES = "ttft_p90_ms"
+MOVES = "serve_tokens_per_s"
 SOURCE = "host_clock"
 RUNNERS = ("serve",)
 

@@ -27,7 +27,7 @@ def kernels(cfg, mix):
     cache = eng.get("cache_blocks", 2 * per_slot)  # the engine's default
     blocks = 1 + eng["max_slots"] * per_slot + cache
     size = families.sizes(cfg)
-    pool = (f"bf16[{blocks},{eng['block_tokens']},{size['kv_heads']},"
+    pool = (f"bf16[{blocks},{eng['block_tokens']},{size['heads']},"
             f"{size['head_dim']}]")
     return {"paged_attention": ('custom_call_target="tpu_custom_call"',
                                 pool, "s32[")}

@@ -11,7 +11,7 @@ for tens of seconds; left in, it would halve the share."""
 NAME = "sched.driver_prefill_share"
 LAYER = "Serving scheduler"
 UNIT = "%"
-MOVES = "ttft_p90_ms"
+MOVES = "tpot_p90_ms"
 SOURCE = "program_counter"
 RUNNERS = ("serve",)
 

@@ -5,7 +5,7 @@ runner re-opens after the warm pass."""
 NAME = "sched.prefix_hit_share"
 LAYER = "Serving scheduler"
 UNIT = "%"
-MOVES = "ttft_p90_ms"
+MOVES = "tpot_p90_ms"
 SOURCE = "program_counter"
 RUNNERS = ("serve",)
 

@@ -7,7 +7,7 @@ import statistics
 NAME = "sched.queue_wait_p50_ms"
 LAYER = "Serving scheduler"
 UNIT = "ms"
-MOVES = "ttft_p90_ms"
+MOVES = "tpot_p90_ms"
 SOURCE = "program_span"
 RUNNERS = ("serve",)
 

@@ -7,7 +7,7 @@ import statistics
 NAME = "step.prefill_ms_per_token"
 LAYER = "Decode/prefill step"
 UNIT = "ms"
-MOVES = "ttft_p90_ms"
+MOVES = "tpot_p90_ms"
 SOURCE = "program_span"
 RUNNERS = ("serve",)
 

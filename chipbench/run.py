@@ -119,6 +119,13 @@ def layer_metrics(cell, facts):
     return out
 
 
+def compared_lines(result):
+    """Each number the check compared, beside its limit: the end of
+    standard error, which a record of a run that was not correct keeps."""
+    return "\n".join(f"chipbench: compared {name} = {value!r}, limit {limit!r}"
+                     for name, value, limit in result["compared"])
+
+
 def result_line(result, metrics, device, breakdown=None):
     line = {"correct": bool(result["correct"]),
             "attempted": int(result["attempted"]),
@@ -171,6 +178,7 @@ def main(argv=None):
                       if k not in ("requests", "stats")
                       and not k.endswith("_seen")}, default=str),
           file=sys.stderr)
+    print(compared_lines(result), file=sys.stderr, flush=True)
     if not args.trace:
         values = dict(result["end_to_end"],
                       setup_s=result["window_start"] - _T_PROCESS)

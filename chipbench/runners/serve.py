@@ -186,6 +186,8 @@ def run(cell, seed, seconds, tracer):
             "serve_tokens_per_s": tokens_in_window / seconds,
         },
         "memory_peak_bytes": peak,
+        "compared": [("worst_logit_margin", worst, check["logit_margin"]),
+                     ("compiled_in_window", compiled_in_window, 0)],
         "facts": {
             "runner": "serve", "requests": requests, "seconds": seconds,
             "stats": stats, "compile_seconds": compile_s,

@@ -151,6 +151,8 @@ def run(cell, seed, seconds, tracer):
         "window_start": t_start,
         "end_to_end": {"train_tokens_per_s": rate},
         "memory_peak_bytes": peak,
+        "compared": [("loss_err", loss_err, check["loss_abs_tol"]),
+                     ("loss_fell", fell, True)],
         "facts": {
             "runner": "train", "steps": steps, "elapsed_s": elapsed,
             "tokens_per_step": tokens_per_step,

@@ -30,7 +30,7 @@ def test_each_configuration_resolves_to_its_family(config):
         for name in families.NEEDS[runner]:
             assert callable(getattr(family, name)), (cell, name)
     size = families.sizes(cfg)
-    assert set(size) == {"d_model", "heads", "kv_heads", "head_dim",
+    assert set(size) == {"d_model", "heads", "head_dim",
                          "vocab_rows", "matmul_params", "kv_planes",
                          "attention_passes"}
     assert all(isinstance(v, int) and v > 0 for v in size.values()), size
