@@ -178,6 +178,7 @@ def measure_decode_memory(params, cfg):
     from paddle_tpu.analysis.hlo_tools import compiled_memory_stats
     from paddle_tpu.observability.attribution import attribute_hlo
     from paddle_tpu.serving import batched_decode as _bd
+    from paddle_tpu.serving.arch import Gpt2
 
     nl, nh, dm = cfg["n_layer"], cfg["n_head"], cfg["d_model"]
     S, bt = cfg["slots"], cfg["block_tokens"]
@@ -200,7 +201,7 @@ def measure_decode_memory(params, cfg):
     try:
         for env, suffix in (("1", ""), ("0", "_gather")):
             os.environ["PADDLE_TPU_PAGED_ATTN"] = env
-            fn = _bd.make_decode_chunk(nl, nh, dm, cfg["chunk"],
+            fn = _bd.make_decode_chunk(Gpt2(nl, nh, dm), cfg["chunk"],
                                        donate=False)
             c = fn.lower(pdev, pk, pv, tok, t, table).compile()
             stats = compiled_memory_stats(c)

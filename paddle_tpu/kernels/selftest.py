@@ -393,6 +393,7 @@ def _check_paged_oracle(failures):
     import paddle_tpu as pt
     from paddle_tpu.models import transformer
     from paddle_tpu.serving import batched_decode as _bd
+    from paddle_tpu.serving.arch import Gpt2
 
     pt.core.unique_name.reset()
     main, startup = pt.Program(), pt.Program()
@@ -423,7 +424,7 @@ def _check_paged_oracle(failures):
         outs = {}
         for env in ("0", "1"):
             os.environ["PADDLE_TPU_PAGED_ATTN"] = env
-            fn = _bd.make_decode_chunk(1, 2, 32, 2, donate=False)
+            fn = _bd.make_decode_chunk(Gpt2(1, 2, 32), 2, donate=False)
             # the compiled module keeps op metadata (source_file /
             # named_scope op_name); the StableHLO dump does not
             text = fn.lower(pdev, pk, pv, tok, t, tbl2).compile() \

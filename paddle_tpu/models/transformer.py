@@ -139,7 +139,14 @@ def infer_compute_dtype(params):
     downgrade the whole decode and its KV caches — hence the scan is
     restricted to the block/head weights that actually feed the MXU.
     Falls back to any >=2-D floating weight when no block/head names
-    match (renamed or weight-tied heads), then float32."""
+    match (renamed or weight-tied heads), then float32.
+
+    Every architecture the serving engine runs (``serving/arch.py``)
+    names its layers' matrices ``block{i}_<name>.w`` and its head
+    ``lm_head.w`` (the looped RMSNorm/rotary stack too:
+    ``block{i}_att_q.w`` ... ``block{i}_ffn_down.w``), so this answers
+    for all of them; norm scales end in ``.scale`` and an exit gate is
+    ``exit_gate.w``, outside the scan."""
     import numpy as np
 
     import jax.numpy as jnp

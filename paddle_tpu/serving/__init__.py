@@ -11,10 +11,12 @@ chunks (continuous batching) ordered by the SLO scheduler
 (``scheduler``: least predicted-TTFT slack, e2e-doomed requests shed),
 power-of-two shape-bucketed SUFFIX prefill so compile count is bounded
 by the bucket set, and full ``serving.*`` telemetry through the
-observability registry.
+observability registry.  What a model is made of reaches it as one
+``arch.Architecture`` (the GPT-2 block, or a looped RMSNorm / rotary /
+gated-FFN stack with a K/V plane per pass).
 """
 
-from . import batched_decode, kvcache, scheduler, speculative
+from . import arch, batched_decode, kvcache, scheduler, speculative
 from .engine import Request, ServingEngine
 from .kvcache import BlockPool, PoolExhausted, PrefixTrie
 from .scheduler import (FifoScheduler, SheddedRequest, SloScheduler,
@@ -22,8 +24,8 @@ from .scheduler import (FifoScheduler, SheddedRequest, SloScheduler,
 from .speculative import depth_draft, spec_enabled
 
 __all__ = [
-    "Request", "ServingEngine", "batched_decode", "kvcache", "scheduler",
-    "speculative", "depth_draft", "spec_enabled",
+    "Request", "ServingEngine", "arch", "batched_decode", "kvcache",
+    "scheduler", "speculative", "depth_draft", "spec_enabled",
     "BlockPool", "PoolExhausted", "PrefixTrie",
     "FifoScheduler", "SheddedRequest", "SloScheduler", "TtftPredictor",
 ]

@@ -1,8 +1,16 @@
 """Paged slot-batched KV-cache decode — the pure-JAX compute under
 ``paddle_tpu.serving``.
 
+What a model is made of comes from ``serving/arch.py``: an
+``Architecture`` gives the attention geometry, the number of K/V planes
+and the forward (embedding, stack, head), written once and fed a decode
+step's rows or a window's through the cache interface
+``_attend_through`` builds.  This file knows blocks, tables and windows.
+
 KV lives in a physical block pool: per layer one
-``[num_blocks, block_tokens, h, dh]`` array, and each slot's logical
+``[passes * num_blocks, block_tokens, h, dh]`` array (``passes`` is 1
+unless the stack runs several times over the same weights; pass ``p``
+then owns blocks ``p * num_blocks ..``, see ``_attend_through``), and each slot's logical
 sequence is a chain of block ids in a per-slot BLOCK TABLE row
 (``[max_slots, blocks_per_slot]`` int32, host-managed by
 ``serving.kvcache``).  Position ``t`` of slot ``s`` lives at
@@ -20,7 +28,8 @@ gathers THROUGH the table, so:
   past their ``limit`` (prefill bucket padding, a verify window
   overhanging its request) write garbage there and nowhere else.
 
-Two forwards, three compiled entry points built once per engine:
+One forward fed two ways, three compiled entry points built once per
+engine:
 
 * ``paged_step_logits`` — ONE token per slot for S slots; under
   ``make_decode_chunk`` a ``lax.scan`` of ``chunk`` batched steps
@@ -75,6 +84,8 @@ import os
 
 import jax
 import jax.numpy as jnp
+
+from .arch import STACK_SCOPE
 
 __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
            "make_verify_window", "PREFILL_PIECE", "DENSE_WINDOW"]
@@ -152,92 +163,96 @@ def _paged_attention(qh, pool_k, pool_v, table, pos):
                          block_step=cfg.get("block_step"))
 
 
-def _ln(x, scale, bias, eps):
-    # statistics in f32 even under bf16 compute (mean/var cancellation) —
-    # mirrors transformer.generate's ln exactly
-    x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.var(x32, axis=-1, keepdims=True)
-    xn = ((x32 - mu) / jnp.sqrt(var + eps)).astype(x.dtype)
-    return xn * scale + bias
+def _attend_through(arch, table, blk, off, pos):
+    """The cache interface an architecture's ``stack`` calls (``arch.py``):
+    ``attend(planes, layer, i_pass, q, k, v) -> (ctx, planes')`` writes
+    the rows' K/V into the plane of ``(i_pass, layer)`` at ``(blk,
+    off)``, then attends that plane through ``table`` masked ``<= pos``.
 
+    ``planes = (pool_k, pool_v)``, one array a layer.  A stack that runs
+    ``arch.passes`` times folds its passes into the block axis: layer
+    ``l``'s array holds ``passes * num_blocks`` blocks and pass ``p``
+    reads and writes block ``b`` at ``b + p * num_blocks``, so a block id
+    names the same positions in every plane, every pass has a trash block
+    of its own (``p * num_blocks``), no plane is ever sliced out or
+    copied and the kernels see an ordinary pool and table.  ``pos``
+    ``[S]`` is a decode step (rows ``[S, ...]``), ``[S, W]`` a window."""
+    step = pos.ndim == 1
+    pos4 = pos[:, None] if step else pos
 
-def paged_step_logits(p, tok, t, pool_k, pool_v, table, n_layer, n_head,
-                      d_model, eps=1e-5):
-    """One decode step for S independent slots through the block table.
-
-    tok [S] int32 current tokens, t [S] int32 per-slot positions,
-    pool_k/pool_v tuples of n_layer [num_blocks, B, h, dh], table
-    [S, NB] int32 block ids (logical capacity T = NB * B).  Writes each
-    slot's K/V at ``(table[s, t_s // B], t_s % B)`` (clamped — overrun
-    slots land in whatever their last table entry maps to, by
-    construction the trash block or an already-consumed position),
-    attends over the gathered chain masked ``<= t_s``, and returns
-    ``(logits [S, vocab] f32, pool_k', pool_v')``.
-    """
-    S = tok.shape[0]
-    NB = table.shape[1]
-    B = pool_k[0].shape[1]
-    T = NB * B
-    dh = d_model // n_head
-    rows = jnp.arange(S)
-    tw = jnp.clip(t, 0, T - 1)
-    blk = table[rows, tw // B]      # [S] physical write block
-    off = tw % B
-    x = p["tok_emb.w"][tok] + p["pos_emb.w.w"][tw]          # [S, d]
-    pk_out, pv_out = [], []
-    for i in range(n_layer):
-        w = lambda nm: p[f"block{i}_{nm}"]
-        h = _ln(x, w("ln1.scale"), w("ln1.bias"), eps)
-        q = h @ w("att_q.w") + w("att_q.b")
-        k = h @ w("att_k.w") + w("att_k.b")
-        v = h @ w("att_v.w") + w("att_v.b")
-        qh = q.reshape(S, n_head, dh)
-        kh = k.reshape(S, n_head, dh)
-        vh = v.reshape(S, n_head, dh)
-        # per-slot scatter through the table: slot s writes its own
-        # (block, offset); distinct live slots own distinct blocks, so
-        # the only possible collision is overrun garbage in the trash
-        # block — content nobody ever attends
-        pk = pool_k[i].at[blk, off].set(kh)
-        pv = pool_v[i].at[blk, off].set(vh)
-        pk_out.append(pk)
-        pv_out.append(pv)
+    def attend(planes, layer, i_pass, qh, kh, vh):
+        pool_k, pool_v = planes
+        tbl, b = table, blk
+        if arch.passes > 1:
+            shift = i_pass * (pool_k[layer].shape[0] // arch.passes)
+            tbl, b = table + shift, blk + shift
+        # every write lands before the attention below: the
+        # write-before-attend discipline, one scatter per plane
+        # (distinct live positions, disjoint per-slot blocks, overruns
+        # and rows past their limit in the trash block — content nobody
+        # ever attends)
+        pk = pool_k[layer].at[b, off].set(kh)
+        pv = pool_v[layer].at[b, off].set(vh)
+        q4 = qh[:, None] if step else qh
         if _paged_attn_on():
-            # attend THROUGH the table: paged_attention streams blocks
-            # with online softmax, the [S, T, h, dh] view never exists
-            ctx = _paged_attention(
-                qh[:, None], pk, pv, table,
-                t[:, None])[:, 0].reshape(S, d_model)
+            # attend THROUGH the table: row j attends <= pos_j inside
+            # the paged_attention op class, the [S, T, h, dh] view never
+            # exists
+            ctx = _paged_attention(q4, pk, pv, tbl, pos4)
         else:
             # kill-switch spelling (PADDLE_TPU_PAGED_ATTN=0): gather
             # each slot's logical view [S, T, h, dh] through the
-            # registry-routed decode_gather kernel, dense softmax —
-            # bit-exact with the pre-paged-attention engine
-            ck = _gather_kv(pk, table)
-            cv = _gather_kv(pv, table)
-            s = jnp.einsum("shd,sThd->shT", qh, ck,
+            # registry-routed decode_gather kernel, dense softmax
+            ck = _gather_kv(pk, tbl)
+            cv = _gather_kv(pv, tbl)
+            T = ck.shape[1]
+            s = jnp.einsum("swhd,sThd->swhT", q4, ck,
                            preferred_element_type=jnp.float32)
-            s = s / jnp.sqrt(float(dh))
-            mask = jnp.arange(T)[None, None, :] <= t[:, None, None]
+            s = s / jnp.sqrt(float(arch.head_dim))
+            # one causal mask covers the cached chain AND the
+            # in-window positions
+            mask = (jnp.arange(T)[None, None, None, :]
+                    <= pos4[:, :, None, None])
             s = jnp.where(mask, s, -1e30)
             a = jax.nn.softmax(s, axis=-1).astype(ck.dtype)
-            ctx = jnp.einsum("shT,sThd->shd", a, cv).reshape(S, d_model)
-        x = x + ctx @ w("att_out.w") + w("att_out.b")
-        h2 = _ln(x, w("ln2.scale"), w("ln2.bias"), eps)
-        # exact erf gelu, matching transformer.generate and the gelu op
-        ff = jax.nn.gelu(h2 @ w("ffn1.w") + w("ffn1.b"), approximate=False)
-        x = x + ff @ w("ffn2.w") + w("ffn2.b")
-    x = _ln(x, p["ln_f.scale"], p["ln_f.bias"], eps)
-    logits = jnp.matmul(x, p["lm_head.w"],
-                        preferred_element_type=jnp.float32)
-    return logits, tuple(pk_out), tuple(pv_out)
+            ctx = jnp.einsum("swhT,sThd->swhd", a, cv)
+        if step:
+            ctx = ctx[:, 0]
+        return ctx, (pool_k[:layer] + (pk,) + pool_k[layer + 1:],
+                     pool_v[:layer] + (pv,) + pool_v[layer + 1:])
+
+    return attend
 
 
-def make_decode_chunk(n_layer, n_head, d_model, chunk, eps=1e-5,
-                      donate=True):
+def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch):
+    """One decode step for S independent slots through the block table.
+
+    tok [S] int32 current tokens, t [S] int32 per-slot positions,
+    pool_k/pool_v tuples of one array a layer ``[passes * num_blocks, B,
+    h, dh]``, table [S, NB] int32 block ids (logical capacity T = NB *
+    B); ``arch`` the model, an ``arch.Architecture``.
+    Writes each slot's K/V at ``(table[s, t_s // B], t_s % B)`` in every
+    plane (clamped — overrun slots land in whatever their last table
+    entry maps to, by construction the trash block or an
+    already-consumed position), attends the chain masked ``<= t_s``, and
+    returns ``(logits [S, vocab] f32, pool_k', pool_v')``.
+    """
+    S = tok.shape[0]
+    B = pool_k[0].shape[1]
+    T = table.shape[1] * B
+    tw = jnp.clip(t, 0, T - 1)
+    blk = table[jnp.arange(S), tw // B]      # [S] physical write block
+    x = arch.embed(p, tok, tw)                               # [S, d]
+    with jax.named_scope(STACK_SCOPE):
+        x, (pool_k, pool_v) = arch.stack(
+            p, x, tw, (pool_k, pool_v),
+            _attend_through(arch, table, blk, tw % B, t))
+    return arch.head(p, x), pool_k, pool_v
+
+
+def make_decode_chunk(arch, chunk, donate=True):
     """Build the batched decode executable: ``chunk`` greedy steps for
-    every slot in one device call.
+    every slot in one device call, for ``arch`` (an ``Architecture``).
 
     ``fn(params, pool_k, pool_v, last_tok, pos, table) -> (pool_k',
     pool_v', last_tok', pos', toks [chunk, S] int32)`` — ``toks[j]`` is
@@ -250,8 +265,8 @@ def make_decode_chunk(n_layer, n_head, d_model, chunk, eps=1e-5,
     def decode_chunk(p, pool_k, pool_v, last_tok, pos, table):
         def body(carry, _):
             pk, pv, tok, t = carry
-            logits, pk, pv = paged_step_logits(
-                p, tok, t, pk, pv, table, n_layer, n_head, d_model, eps)
+            logits, pk, pv = paged_step_logits(p, tok, t, pk, pv, table,
+                                               arch)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (pk, pv, nxt, t + 1), nxt
 
@@ -263,15 +278,15 @@ def make_decode_chunk(n_layer, n_head, d_model, chunk, eps=1e-5,
                    donate_argnums=(1, 2, 3, 4) if donate else ())
 
 
-def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, n_layer,
-                    n_head, d_model, eps):
+def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch):
     """The teacher-forced WINDOW forward both ``make_verify_window``
     and ``make_prefill`` are built on: ``toks [S, W]`` consumed at
     logical positions ``pos_s + j`` in ONE pass — per layer one
     ``[S*W, d] @ [d, .]`` matmul per projection (the weights are read
     once for W tokens), all W K/V rows scattered through the table
     BEFORE attention, window row ``j`` attending keys ``<= pos_s + j``
-    (the cached chain plus the in-window rows before it).
+    (the cached chain plus the in-window rows before it).  The forward
+    itself is ``arch``'s, the same lines the decode step runs.
 
     ``limit[s]`` is the last logical position slot ``s`` may write:
     rows beyond it (a verify window overhanging the end of a request, a
@@ -280,77 +295,39 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, n_layer,
     race a real row for a clamped table entry.  Their outputs are
     garbage nobody reads.
 
-    Returns ``(x [S, W, d], pool_k', pool_v')`` — the trunk's output
-    BEFORE the final LayerNorm: the callers differ in which rows they
-    put through the head (``_head_logits``).
+    Returns ``(x [S, W, d], pool_k', pool_v')`` — the stack's output,
+    what ``arch.head`` consumes: the callers differ in which rows they
+    put through the head.
     """
     S, W = toks.shape
-    NB = table.shape[1]
     B = pool_k[0].shape[1]
-    T = NB * B
-    dh = d_model // n_head
-    rows = jnp.arange(S)
+    T = table.shape[1] * B
     P = pos[:, None] + jnp.arange(W)[None, :]                # [S, W]
     Pw = jnp.clip(P, 0, T - 1)
     writable = P <= limit[:, None]
-    blk = jnp.where(writable, table[rows[:, None], Pw // B], 0)
-    off = Pw % B
-    x = p["tok_emb.w"][toks] + p["pos_emb.w.w"][Pw]          # [S, W, d]
-    for i in range(n_layer):
-        w = lambda nm: p[f"block{i}_{nm}"]
-        h = _ln(x, w("ln1.scale"), w("ln1.bias"), eps)
-        q = h @ w("att_q.w") + w("att_q.b")
-        kk = h @ w("att_k.w") + w("att_k.b")
-        v = h @ w("att_v.w") + w("att_v.b")
-        qh = q.reshape(S, W, n_head, dh)
-        kh = kk.reshape(S, W, n_head, dh)
-        vh = v.reshape(S, W, n_head, dh)
-        # all W writes land before the gather below — the same
-        # write-before-attend discipline as the sequential step,
-        # collapsed into one scatter (distinct live positions,
-        # disjoint per-slot blocks, overruns trashed via `limit`)
-        pk = pool_k[i].at[blk, off].set(kh)
-        pv = pool_v[i].at[blk, off].set(vh)
-        pool_k = pool_k[:i] + (pk,) + pool_k[i + 1:]
-        pool_v = pool_v[:i] + (pv,) + pool_v[i + 1:]
-        if _paged_attn_on():
-            # window row j attends <= pos + j — the same causal
-            # invariant, enforced inside the paged_attention op class
-            # instead of over a gathered view
-            ctx = _paged_attention(qh, pk, pv, table, P).reshape(
-                S, W, d_model)
-        else:
-            ck = _gather_kv(pk, table)                       # [S, T, h, dh]
-            cv = _gather_kv(pv, table)
-            s = jnp.einsum("swhd,sThd->swhT", qh, ck,
-                           preferred_element_type=jnp.float32)
-            s = s / jnp.sqrt(float(dh))
-            # one causal mask covers the cached chain AND the
-            # in-window positions: window row j attends <= pos + j
-            mask = (jnp.arange(T)[None, None, None, :]
-                    <= P[:, :, None, None])
-            s = jnp.where(mask, s, -1e30)
-            a = jax.nn.softmax(s, axis=-1).astype(ck.dtype)
-            ctx = jnp.einsum("swhT,sThd->swhd", a, cv).reshape(
-                S, W, d_model)
-        x = x + ctx @ w("att_out.w") + w("att_out.b")
-        h2 = _ln(x, w("ln2.scale"), w("ln2.bias"), eps)
-        ff = jax.nn.gelu(h2 @ w("ffn1.w") + w("ffn1.b"),
-                         approximate=False)
-        x = x + ff @ w("ffn2.w") + w("ffn2.b")
+    blk = jnp.where(writable, table[jnp.arange(S)[:, None], Pw // B], 0)
+    x = arch.embed(p, toks, Pw)                              # [S, W, d]
+    with jax.named_scope(STACK_SCOPE):
+        x, (pool_k, pool_v) = arch.stack(
+            p, x, Pw, (pool_k, pool_v),
+            _attend_through(arch, table, blk, Pw % B, P))
     return x, pool_k, pool_v
 
 
-def _head_logits(p, x, eps):
-    """Final LayerNorm + LM head over the rows of ``x [..., d]``, f32
-    logits."""
-    x = _ln(x, p["ln_f.scale"], p["ln_f.bias"], eps)
-    return jnp.matmul(x, p["lm_head.w"],
-                      preferred_element_type=jnp.float32)
+def _copy_block(planes, src, dst, passes):
+    """Block ``src`` copied whole onto ``dst`` in every plane: once for
+    each pass folded into an array's block axis."""
+    per = planes[0].shape[0] // passes
+    out = []
+    for c in planes:
+        c = c.at[dst].set(c[src])
+        for i in range(1, passes):
+            c = c.at[dst + i * per].set(c[src + i * per])
+        out.append(c)
+    return tuple(out)
 
 
-def make_verify_window(n_layer, n_head, d_model, k, eps=1e-5,
-                       donate=True):
+def make_verify_window(arch, k, donate=True):
     """Build the speculative VERIFY executable: one teacher-forced
     target forward over a ``W = k + 1``-token window for every slot.
 
@@ -376,17 +353,14 @@ def make_verify_window(n_layer, n_head, d_model, k, eps=1e-5,
             raise ValueError(f"verify window built for k={k} got "
                              f"{toks.shape[1]} tokens a slot")
         x, pool_k, pool_v = _window_forward(
-            p, pool_k, pool_v, toks, pos, limit, table, n_layer, n_head,
-            d_model, eps)
-        greedy = jnp.argmax(_head_logits(p, x, eps),
-                            axis=-1).astype(jnp.int32)
+            p, pool_k, pool_v, toks, pos, limit, table, arch)
+        greedy = jnp.argmax(arch.head(p, x), axis=-1).astype(jnp.int32)
         return pool_k, pool_v, greedy
 
     return jax.jit(verify, donate_argnums=(1, 2) if donate else ())
 
 
-def make_prefill(n_layer, n_head, d_model, bucket, eps=1e-5,
-                 donate=True):
+def make_prefill(arch, bucket, donate=True):
     """Build the prefill executable for one window WIDTH (a suffix
     bucket of at most ``PREFILL_PIECE`` tokens).
 
@@ -420,14 +394,14 @@ def make_prefill(n_layer, n_head, d_model, bucket, eps=1e-5,
         # copy-on-write fork: duplicate the whole source block; the
         # shared tokens are the live prefix, the tail is garbage the
         # window / decode overwrites before ever attending it
-        pool_k = tuple(c.at[cow_dst].set(c[cow_src]) for c in pool_k)
-        pool_v = tuple(c.at[cow_dst].set(c[cow_src]) for c in pool_v)
+        pool_k = _copy_block(pool_k, cow_src, cow_dst, arch.passes)
+        pool_v = _copy_block(pool_v, cow_src, cow_dst, arch.passes)
         end = start + length
         x, pool_k, pool_v = _window_forward(
             p, pool_k, pool_v, toks[None], start[None], (end - 1)[None],
-            table_row[None], n_layer, n_head, d_model, eps)
+            table_row[None], arch)
         row = jax.lax.dynamic_slice_in_dim(x[0], length - 1, 1)  # [1, d]
-        first = jnp.argmax(_head_logits(p, row, eps)[0]).astype(jnp.int32)
+        first = jnp.argmax(arch.head(p, row)[0]).astype(jnp.int32)
         last_tok = last_tok.at[slot].set(first)
         pos = pos.at[slot].set(end)
         return pool_k, pool_v, last_tok, pos, first

@@ -66,6 +66,7 @@ from ..observability import flight as _flight
 from ..observability import metrics as _obs
 from ..observability import trace as _trace
 from ..resilience import faults as _faults
+from . import arch as _arch
 from . import batched_decode as _bd
 from . import kvcache as _kv
 from . import scheduler as _sched
@@ -180,6 +181,13 @@ class ServingEngine:
              ``transformer.extract_params()``); cast once to
              ``compute_dtype`` (default: the dtype the block/lm_head
              matmul weights imply — bf16-trained weights serve in bf16).
+    n_layer / n_head / d_model / eps   the GPT-2 block of
+             ``transformer.build`` (the positional spelling), OR
+    arch     a ``serving.arch.Architecture``: heads and head size, the
+             K/V planes a token holds (``n_layer * passes``), and the
+             forward the compiled entry points run (docs/serving.md
+             "Architectures").  ``ServingEngine(params, arch=Gpt2(L, h,
+             d, eps), ...)`` is the positional spelling exactly.
     max_len  per-slot logical KV capacity; every request needs
              ``len(prompt) + max_new_tokens <= max_len``.
     max_slots     concurrent sequences in the batched step.
@@ -237,20 +245,30 @@ class ServingEngine:
     producers calling ``submit`` concurrently.
     """
 
-    def __init__(self, params, n_layer, n_head, d_model, max_len=128,
+    def __init__(self, params, n_layer=None, n_head=None, d_model=None,
+                 max_len=128,
                  max_slots=8, decode_chunk=None, min_bucket=None,
                  eos_id=None, compute_dtype=None, eps=1e-5, donate=True,
                  registry=None, ttft_slo_s=None, e2e_slo_s=None,
                  block_tokens=16, cache_blocks=None, prefix_reuse=True,
                  scheduler="slo", draft_params=None, draft_n_layer=None,
-                 draft_n_head=None, spec_k=None):
+                 draft_n_head=None, spec_k=None, arch=None):
         import jax
         import jax.numpy as jnp
 
         from ..models.transformer import infer_compute_dtype
 
-        if d_model % n_head:
-            raise ValueError(f"d_model {d_model} % n_head {n_head} != 0")
+        if arch is None:
+            if None in (n_layer, n_head, d_model):
+                raise ValueError(
+                    "ServingEngine needs an architecture: arch=..., or "
+                    "the GPT-2 block's n_layer, n_head, d_model")
+            arch = _arch.Gpt2(n_layer, n_head, d_model, eps)
+        elif (n_layer, n_head, d_model) != (None, None, None):
+            raise ValueError("ServingEngine takes arch=... or n_layer, "
+                             "n_head, d_model, not both")
+        self.arch = arch
+        n_layer, n_head, d_model = arch.n_layer, arch.n_head, arch.d_model
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1: {max_slots}")
         if block_tokens < 1:
@@ -258,7 +276,6 @@ class ServingEngine:
         self.n_layer, self.n_head, self.d_model = n_layer, n_head, d_model
         self.max_len, self.max_slots = int(max_len), int(max_slots)
         self.eos_id = eos_id
-        self._eps = eps
         self._donate = donate
         if ttft_slo_s is not None and ttft_slo_s <= 0:
             raise ValueError(f"ttft_slo_s must be > 0: {ttft_slo_s}")
@@ -285,11 +302,7 @@ class ServingEngine:
         self.min_bucket = int(min_bucket)
         # the widest prefill window (never narrower than a bucket)
         self._piece = max(self.min_bucket, _bd.PREFILL_PIECE)
-        table_len = np.asarray(params["pos_emb.w.w"]).shape[0]
-        if self.max_len > table_len:
-            raise ValueError(
-                f"max_len {self.max_len} exceeds the trained position-"
-                f"embedding table ({table_len} positions)")
+        arch.check_params(params, self.max_len)
         self._p = jax.device_put(
             {k: jnp.asarray(v, self.compute_dtype)
              for k, v in params.items()})
@@ -302,8 +315,8 @@ class ServingEngine:
         spec_on = draft_params is not None and _spec.spec_enabled()
         if spec_on:
             draft_n_layer = _spec.validate_draft(
-                params, draft_params, n_layer, n_head, d_model,
-                self.max_len, draft_n_layer=draft_n_layer,
+                params, draft_params, arch, self.max_len,
+                draft_n_layer=draft_n_layer,
                 draft_n_head=draft_n_head)
             if spec_k is None:
                 spec_k = int(self._tuned_spec().get(
@@ -323,7 +336,12 @@ class ServingEngine:
         # trie evicts its unreferenced tail (kvcache.py invariants).
         # Speculative mode reserves a second worst-case chain per slot
         # for the draft's scratch blocks, so a propose round can never
-        # starve admission.
+        # starve admission.  A block id names the same block_tokens
+        # positions in every one of the arch.kv_planes planes, so one
+        # block costs kv_planes * 2 * block_tokens * d_model * itemsize
+        # bytes (48 MiB for 192 planes of 32 x 2048 bf16, 6 MiB for 24):
+        # the pool's bytes, not its block count, are what fills a chip
+        # (gauge serving.kv_pool_bytes)
         num_blocks = (1 + self.max_slots * self.blocks_per_slot
                       + self.cache_blocks)
         if spec_on:
@@ -332,13 +350,15 @@ class ServingEngine:
         self.prefix_trie = (_kv.PrefixTrie(self.kv_pool, self.cache_blocks)
                             if prefix_reuse else None)
         self.prefix_reuse = bool(prefix_reuse)
-        dh = d_model // n_head
-        self._pk = tuple(
-            jnp.zeros((num_blocks, self.block_tokens, n_head, dh),
-                      self.compute_dtype) for _ in range(n_layer))
-        self._pv = tuple(
-            jnp.zeros((num_blocks, self.block_tokens, n_head, dh),
-                      self.compute_dtype) for _ in range(n_layer))
+        # one array a layer; a stack that runs arch.passes times keeps
+        # each pass's plane in its own num_blocks of the block axis
+        # (batched_decode._attend_through)
+        shape = (arch.passes * num_blocks, self.block_tokens, n_head,
+                 arch.head_dim)
+        self._pk = tuple(jnp.zeros(shape, self.compute_dtype)
+                         for _ in range(n_layer))
+        self._pv = tuple(jnp.zeros(shape, self.compute_dtype)
+                         for _ in range(n_layer))
         self._last = jnp.zeros((self.max_slots,), jnp.int32)
         self._pos = jnp.zeros((self.max_slots,), jnp.int32)
         # host-side block table: unused entries -> trash block 0
@@ -390,6 +410,25 @@ class ServingEngine:
             help="physical KV blocks in the paged pool (excl. trash)",
         ).set(num_blocks - 1)
         self._reg.gauge("serving.blocks_in_use").set(0)
+        itemsize = self.compute_dtype.itemsize
+        self._reg.gauge(
+            "serving.kv_planes",
+            help="K/V planes a cached token holds (layers x passes of "
+                 "the stack)").set(arch.kv_planes)
+        self._reg.gauge(
+            "serving.stack_passes",
+            help="times the stack runs over the same weights for one "
+                 "token").set(arch.passes)
+        self._reg.gauge(
+            "serving.kv_bytes_per_token",
+            help="K and V bytes of one cached token across its planes",
+        ).set(arch.kv_bytes_per_token(itemsize))
+        self._reg.gauge(
+            "serving.kv_pool_bytes",
+            help="bytes the paged pool holds on the device: planes x "
+                 "blocks (trash included) x block bytes",
+        ).set(arch.kv_planes * num_blocks
+              * arch.kv_block_bytes(self.block_tokens, itemsize))
 
     def _tuned_geometry(self):
         """The tuned ``op=serving_decode`` config for this engine's
@@ -823,8 +862,7 @@ class ServingEngine:
         fn = self._prefill_fns.get(bucket)
         if fn is None:
             fn = self._aot_with_mem_telemetry(
-                _bd.make_prefill(self.n_layer, self.n_head, self.d_model,
-                                 bucket, eps=self._eps,
+                _bd.make_prefill(self.arch, bucket=bucket,
                                  donate=self._donate),
                 label=f"prefill_{bucket}")
             self._prefill_fns[bucket] = fn
@@ -861,9 +899,8 @@ class ServingEngine:
             return self._spec_decode()
         if self._decode_fn is None:
             self._decode_fn = self._aot_with_mem_telemetry(
-                _bd.make_decode_chunk(
-                    self.n_layer, self.n_head, self.d_model,
-                    self.decode_chunk, eps=self._eps, donate=self._donate),
+                _bd.make_decode_chunk(self.arch, chunk=self.decode_chunk,
+                                      donate=self._donate),
                 label="decode")
             self._reg.counter(
                 "serving.decode_compiles",
@@ -890,7 +927,8 @@ class ServingEngine:
         with self._span("serving.decode_chunk", "decode",
                         histogram="serving.decode_chunk",
                         steps=self.decode_chunk,
-                        active=self.active_slots) as sp:
+                        active=self.active_slots,
+                        passes=self.arch.passes) as sp:
             (self._pk, self._pv, self._last, self._pos,
              toks) = self._decode_fn(self._p, self._pk, self._pv,
                                      self._last, self._pos, tbl)
@@ -1250,7 +1288,7 @@ class ServingEngine:
         with self._span("serving.prefill", "prefill",
                         histogram="serving.prefill_seconds", rid=req.rid,
                         bucket=bucket, pieces=len(pieces), slot=slot,
-                        prefix_hit=start) as sp:
+                        prefix_hit=start, passes=self.arch.passes) as sp:
             self._pk, self._pv, first = self._run_pieces(
                 self._prefill_fn, self._p, self._pk, self._pv, slot,
                 row_d, pieces, cow=(cow_src, cow_dst))

@@ -50,6 +50,7 @@ import os
 import numpy as np
 
 from . import batched_decode as _bd
+from .arch import Gpt2
 
 __all__ = ["DEFAULT_SPEC_K", "spec_enabled", "draft_depth",
            "depth_draft", "validate_draft", "accept_greedy",
@@ -101,12 +102,22 @@ def depth_draft(params, n_layers):
     return out
 
 
-def validate_draft(params, draft_params, n_layer, n_head, d_model,
-                   max_len, draft_n_layer=None, draft_n_head=None):
+def validate_draft(params, draft_params, arch, max_len,
+                   draft_n_layer=None, draft_n_head=None):
     """Geometry checks at engine construction — the draft shares the
     target's paged pool arrays and tokenizer, so mismatches must fail
     LOUDLY here, not as silent garbage tokens at serve time.  Returns
-    the validated ``draft_n_layer``."""
+    the validated ``draft_n_layer``.  ``arch`` is the target's
+    ``serving.arch.Architecture``: anything but the GPT-2 block (a
+    looped stack, whose pool arrays hold a plane per pass) is refused."""
+    if not isinstance(arch, Gpt2):
+        raise ValueError(
+            f"speculative decoding serves the GPT-2 block only: the "
+            f"target is {arch.name!r} ({arch.passes} passes over "
+            f"{arch.n_layer} layers); a draft that rides the first pool "
+            f"arrays of a looped target, and a verify window over it, "
+            f"are not written")
+    n_layer, n_head, d_model = arch.n_layer, arch.n_head, arch.d_model
     t_vocab = int(np.asarray(params["tok_emb.w"]).shape[0])
     d_vocab = int(np.asarray(draft_params["tok_emb.w"]).shape[0])
     if t_vocab != d_vocab:
@@ -185,6 +196,9 @@ class SpecState:
             raise ValueError(f"spec_k must be >= 1: {k}")
         self.k = int(k)
         self.n_layer = int(draft_n_layer)
+        # the draft is the target's block, fewer layers of it
+        self.arch = Gpt2(self.n_layer, engine.n_head, engine.d_model,
+                         engine.arch.eps)
         self.p = jax.device_put(
             {kk: jnp.asarray(v, engine.compute_dtype)
              for kk, v in draft_params.items()})
@@ -212,9 +226,8 @@ class SpecState:
         accepted round leaves the draft cache current)."""
         if self._chunk_fn is None:
             self._chunk_fn = engine._aot_with_mem_telemetry(
-                _bd.make_decode_chunk(
-                    self.n_layer, engine.n_head, engine.d_model,
-                    self.k + 1, eps=engine._eps, donate=engine._donate),
+                _bd.make_decode_chunk(self.arch, self.k + 1,
+                                      donate=engine._donate),
                 label="spec_draft")
             self._compile_counter(engine).inc()
         return self._chunk_fn
@@ -222,9 +235,8 @@ class SpecState:
     def verify_fn(self, engine):
         if self._verify_fn is None:
             self._verify_fn = engine._aot_with_mem_telemetry(
-                _bd.make_verify_window(
-                    engine.n_layer, engine.n_head, engine.d_model,
-                    self.k, eps=engine._eps, donate=engine._donate),
+                _bd.make_verify_window(engine.arch, self.k,
+                                       donate=engine._donate),
                 label="spec_verify")
             self._compile_counter(engine).inc()
         return self._verify_fn
@@ -233,8 +245,7 @@ class SpecState:
         fn = self._prefill_fns.get(bucket)
         if fn is None:
             fn = engine._aot_with_mem_telemetry(
-                _bd.make_prefill(self.n_layer, engine.n_head,
-                                 engine.d_model, bucket, eps=engine._eps,
+                _bd.make_prefill(self.arch, bucket,
                                  donate=engine._donate),
                 label=f"spec_prefill_{bucket}")
             self._prefill_fns[bucket] = fn

@@ -202,6 +202,7 @@ def test_prefill_cross_lowers_for_tpu_dense_from_the_wide_window_up(
     is gathered once and attended on the MXU), a narrower one holds the
     streaming kernel once per layer."""
     from paddle_tpu.serving import batched_decode as _bd
+    from paddle_tpu.serving.arch import Gpt2
 
     n_layer, h, dm, vocab = 2, 2, 256, 512
     nb = chip_smoke.MAX_LEN // chip_smoke.BLOCK_TOKENS
@@ -224,7 +225,7 @@ def test_prefill_cross_lowers_for_tpu_dense_from_the_wide_window_up(
                  for _ in range(n_layer))
     scalar = sds((), i32)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    fn = _bd.make_prefill(n_layer, h, dm, width, donate=False)
+    fn = _bd.make_prefill(Gpt2(n_layer, h, dm), width, donate=False)
     text = fn.trace(p, pool, pool, sds((3,), i32), sds((3,), i32), scalar,
                     sds((nb,), i32), sds((width,), i32), scalar, scalar,
                     scalar, scalar).lower(

@@ -251,6 +251,39 @@ def test_served_equals_single_stream_with_prefix_traffic(dtype, reuse):
         assert eng.prefix_trie is None
 
 
+def test_architecture_object_serves_prefix_traffic_like_positional():
+    """Prefix hits and a copy-on-write fork through an engine built
+    from ``arch=Gpt2(...)``: token-identical to the positional engine,
+    same trie and pool accounting."""
+    from paddle_tpu.serving.arch import Gpt2
+
+    params = _make_params()
+    rng = np.random.default_rng(9)
+    base = rng.integers(1, VOCAB, (12,)).astype(np.int32)
+    prompts = [base.copy(), base.copy(),
+               np.concatenate([base[:6],
+                               rng.integers(1, VOCAB, (5,)).astype(np.int32)])]
+    kw = dict(max_len=T, max_slots=3, decode_chunk=5, min_bucket=4,
+              block_tokens=4)
+    outs, stats = [], []
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    for eng in (ServingEngine(params, NL, NH, DM,
+                              registry=MetricsRegistry(), **kw),
+                ServingEngine(params, arch=Gpt2(NL, NH, DM),
+                              registry=MetricsRegistry(), **kw)):
+        out = eng.generate_many(prompts[:1], max_new_tokens=8)
+        outs.append(out + eng.generate_many(prompts[1:], max_new_tokens=8))
+        st = eng.stats()
+        stats.append({k: st[k] for k in (
+            "serving.prefix_hit_tokens", "serving.cow_copies",
+            "serving.blocks_in_use", "serving.kv_blocks_total",
+            "serving.kv_pool_bytes")})
+    for a, b in zip(*outs):
+        np.testing.assert_array_equal(a, b)
+    assert stats[0] == stats[1] and stats[0]["serving.cow_copies"] >= 1
+
+
 # -- engine-level: paged-attention kill switch -------------------------------
 
 @pytest.mark.slow

@@ -18,11 +18,13 @@ from paddle_tpu.observability import trace
 from paddle_tpu.observability.metrics import MetricsRegistry
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving import batched_decode as _bd
+from paddle_tpu.serving.arch import Gpt2
 
 VOCAB, NL, NH, DM, T, B = 61, 2, 2, 32, 64, 4
 EPS = 1e-5
 PIECE = 8           # the piece width these tests give the engine
 NB = T // B
+ARCH = Gpt2(NL, NH, DM, EPS)
 
 
 def _params(dtype):
@@ -67,14 +69,13 @@ def _noise_pool(eng, seed):
 
 @jax.jit
 def _step(p, tok, t, pk, pv, row):
-    return _bd.paged_step_logits(p, tok, t, pk, pv, row[None], NL, NH, DM,
-                                 EPS)
+    return _bd.paged_step_logits(p, tok, t, pk, pv, row[None], ARCH)
 
 
 @jax.jit
 def _window(p, pk, pv, toks, at, last, row):
     return _bd._window_forward(p, pk, pv, toks[None], at[None], last[None],
-                               row[None], NL, NH, DM, EPS)
+                               row[None], ARCH)
 
 
 def _step_through(p, pk, pv, row, toks, start):
@@ -94,7 +95,7 @@ def _window_logits(eng, pk, pv, row, toks, start):
     for _w, padded, at, n in eng._pieces(toks, start):
         x, pk, pv = _window(eng._p, pk, pv, padded, jnp.int32(at),
                             jnp.int32(at + n - 1), row)
-        logits = _bd._head_logits(eng._p, x[0, n - 1], EPS)
+        logits = eng.arch.head(eng._p, x[0, n - 1])
     return logits
 
 
@@ -188,7 +189,7 @@ def test_prefill_runs_the_lm_head_on_one_row(width):
     p = _params("float32")
     pool = tuple(jnp.zeros((12, B, NH, DM // NH), jnp.float32)
                  for _ in range(NL))
-    fn = _bd.make_prefill(NL, NH, DM, width, eps=EPS, donate=False)
+    fn = _bd.make_prefill(ARCH, width, donate=False)
     i32 = lambda v: np.int32(v)
     text = fn.lower(p, pool, pool, jnp.zeros(3, jnp.int32),
                     jnp.zeros(3, jnp.int32), i32(1),

@@ -153,6 +153,30 @@ def test_batched_decode_token_identical_to_single_stream(params):
         np.testing.assert_array_equal(o, np.asarray(ref)[0][: len(p) + m])
 
 
+def test_engine_from_architecture_object_equals_positional(params):
+    """``ServingEngine(params, arch=Gpt2(...))`` is the positional
+    constructor exactly: same tokens, same pool, same gauges."""
+    from paddle_tpu.serving.arch import Gpt2
+
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, VOCAB, (l,)) for l in (3, 9, 1, 6)]
+    outs = []
+    for eng in (_engine(params, max_slots=2),
+                ServingEngine(params, arch=Gpt2(NL, NH, DM), max_len=T,
+                              max_slots=2, decode_chunk=4, min_bucket=4)):
+        outs.append(eng.generate_many(prompts, max_new_tokens=7))
+        assert (eng.n_layer, eng.n_head, eng.d_model) == (NL, NH, DM)
+        assert len(eng._pk) == NL and eng._pk[0].shape == (
+            eng.kv_pool.num_blocks, eng.block_tokens, NH, DM // NH)
+        st = eng.stats()
+        assert st["serving.kv_planes"] == NL
+        assert st["serving.stack_passes"] == 1
+        assert st["serving.kv_pool_bytes"] == sum(
+            a.nbytes for a in eng._pk + eng._pv)
+    for a, b in zip(*outs):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_bf16_weights_serve_in_bf16_and_match(params):
     """bf16 block weights: the engine infers bf16 compute (cache
     discipline) and still matches the single-stream bf16 decode."""
