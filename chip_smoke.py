@@ -276,8 +276,18 @@ def serve_phase(pt, dims, params):
         assert ((r >= 0) & (r < dims["vocab"])).all()
     stats = eng.stats()
     paged = eng.kernel_backends
-    assert paged and all(sel == {"paged_attention": "pallas_tpu"}
-                         for sel in paged.values()), paged
+    # decode and the narrow windows stream blocks through the Mosaic
+    # kernel; a prefill window of DENSE_WINDOW rows or more gathers the
+    # chain once and attends it densely (the xla_ref spelling)
+    from paddle_tpu.serving.batched_decode import DENSE_WINDOW
+
+    def expected(label):
+        wide = (label.startswith("prefill_")
+                and int(label.rsplit("_", 1)[1]) >= DENSE_WINDOW)
+        return {"paged_attention": "xla_ref" if wide else "pallas_tpu"}
+
+    assert paged and all(sel == expected(label)
+                         for label, sel in paged.items()), paged
     assert stats["serving.paged_attn_compiles"] >= 1, stats
     assert stats["serving.prefix_hit_rate"] > 0, stats
     assert eng.kv_pool.blocks_in_use == len(eng.prefix_trie), (

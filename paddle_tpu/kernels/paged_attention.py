@@ -45,14 +45,19 @@ Backends:
   ``block_step`` physical blocks per step (``[S, block_step*B, h,
   dh]`` in flight — the tuned block-iteration geometry,
   ``tune.paged_attention_config``).  The universal numerics reference.
-* ``pallas_tpu`` — ``PrefetchScalarGridSpec`` scalar prefetch (the
-  ``pallas_gather.py`` spelling): the table feeds the K/V BlockSpec
-  index maps, so each sequential grid step DMAs exactly one physical
-  block into VMEM while ``(m, l, acc)`` carry in VMEM scratch.
-  Registered available on real TPU only (off-TPU the interpret-mode
-  grid would replace one fused XLA loop with a per-block Python loop);
-  the oracle suite still covers the kernel logic on CPU by forcing
-  ``interpret=True``.
+* ``pallas_tpu`` — ``PrefetchScalarGridSpec`` scalar prefetch of table
+  and positions; grid ``(S,)``, one step a slot, whose body loops over
+  the LIVE entries of that slot's chain only: ``n_s = clip(max_w
+  pos[s, w] // B + 1, 0, NB)`` (every entry from ``n_s`` on is masked
+  for every row, so it is neither fetched nor computed), each block
+  copied from the pool where it lies into a two-deep VMEM buffer while
+  ``(m, l, acc)`` carry in VMEM scratch.  A row with ``pos < 0`` has no
+  visible key and returns zeros; a slot of such rows (a dead slot, as
+  ``batched_decode._attend_through`` names it) costs one empty grid
+  step.  Registered available on real TPU only (off-TPU the
+  interpret-mode grid would replace one fused XLA loop with a per-block
+  Python loop); the oracle suite still covers the kernel logic on CPU by
+  forcing ``interpret=True``.
 * ``triton`` — the GPU decomposition of ``triton_attention.py``: a
   parallel grid over independent slots, the block-chain reduction as a
   ``lax.fori_loop`` inside the kernel with ``pl.load`` +
@@ -160,26 +165,54 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
 # -- pallas_tpu: scalar-prefetch block streaming -----------------------------
 
 
+def _block_is_sliceable(pool):
+    """Whether Mosaic lets a kernel slice one ``[B, h, dh]`` block out of
+    the pool where it lies (``memref_slice`` on an un-blocked operand).
+    It refuses a packed (sub-32-bit) pool whose head count does not fill
+    its sublane tiles: "Slice shape along dimension 2 must be aligned to
+    tiling (8), but is 12" (bf16, 12 heads; 6 and 20 alike)."""
+    return pool.dtype.itemsize >= 4 or pool.shape[2] % 8 == 0
+
+
 def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
                            interpret=None):
-    """``PrefetchScalarGridSpec`` kernel: grid ``(S, NB)``; the block
-    TABLE and the query POSITIONS are the scalar-prefetch arguments
-    (SMEM).  The table feeds the K/V BlockSpec index maps, so grid step
-    ``(s, nb)`` streams physical block ``table[s, nb]`` into VMEM
-    (consecutive trash entries name the same block and are not
-    re-fetched).  TPU grids run sequentially, so the online-softmax
-    state carries across ``nb`` steps in VMEM scratch and the output
-    writes once at the last step.
+    """The Mosaic kernel: it visits the LIVE entries of each slot's chain
+    and no others.  The block TABLE and the query POSITIONS are the
+    scalar-prefetch arguments (SMEM).  Slot ``s`` has ``n_s = clip(max_w
+    pos[s, w] // B + 1, 0, NB)`` table entries that hold a key some row
+    of its window may attend; every entry from ``n_s`` on is masked for
+    every row, so it is neither fetched nor computed.  A masked block
+    adds ``p = 0`` and scales by ``alpha = exp(m - m) = 1``: leaving it
+    out changes no bit of a row with at least one visible key.  A row
+    with ``pos < 0`` has none and returns ZEROS (``l == 0 -> 1`` over an
+    ``acc`` of zeros); a slot whose rows are all negative costs one empty
+    grid step.  That is how the serving step names a dead slot
+    (``batched_decode._attend_through``).
+
+    Grid ``(S,)``, one step a slot, whose body LOOPS ``n_s`` times over
+    the slot's own chain: the pools stay where they are (``pl.ANY``: no
+    BlockSpec, no pool-sized copy), iteration ``i`` copies block
+    ``table[s, i]`` of K and of V into one half of a two-deep VMEM buffer
+    (``make_async_copy``; block ``i + 1`` is in flight while block ``i``
+    is attended) and folds it into the f32 ``(m, l, acc)`` online softmax
+    in VMEM scratch; the output writes once after the loop.
+
+    Where Mosaic cannot slice a block out of the pool
+    (``_block_is_sliceable``: bf16 with 6 or 12 heads) the same body runs
+    under a grid ``(S, NB)`` instead: the table feeds the K/V BlockSpec
+    index maps, clamped to the chain's last live entry so that the steps
+    past it re-name one block and fetch nothing, and the body is skipped
+    from ``n_s`` on.  Such a step still costs its share of the grid's
+    pipeline, which the loop does not pay.
 
     The block stays in the pool's own ``[B, h, dh]`` layout (tokens on
     the untiled axis, heads on sublanes, ``dh`` on lanes) and the math
     is VPU-only: scores are a lane reduction of ``k * q`` per window
     position, the softmax reduces over the untiled token axis, and
     ``p * v`` accumulates the same way — no transpose, no in-kernel
-    relayout, no MXU shape Mosaic could refuse.  Decode is bound by the
-    K/V bytes, not these flops.  ``block_step`` is accepted for
-    signature parity and ignored — this spelling streams exactly one
-    block per grid step by construction."""
+    relayout, no MXU shape Mosaic could refuse.  ``block_step`` is
+    accepted for signature parity and ignored — this spelling streams
+    exactly one block per iteration by construction."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -191,24 +224,28 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     NB = table.shape[1]
     scale = 1.0 / float(dh) ** 0.5
 
-    def kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref):
-        del tbl  # consumed by the index maps, not the body
-        s_id = pl.program_id(0)
-        nb = pl.program_id(1)
+    def live_entries(pos_ref, s_id):
+        top = pos_ref[s_id, 0]
+        for w in range(1, W):
+            top = jnp.maximum(top, pos_ref[s_id, w])
+        # floor(top / B) + 1, and 0 for a window with no row at a
+        # position >= 0
+        return jnp.minimum(jax.lax.div(jnp.maximum(top, -1) + B, B), NB)
 
-        # per-(window, head) softmax statistics sit lane-replicated in
-        # scratch, the flash kernels' convention: a [h, 1] row is below
-        # Mosaic's minimum lane tile
-        @pl.when(nb == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+    # per-(window, head) softmax statistics sit lane-replicated in
+    # scratch, the flash kernels' convention: a [h, 1] row is below
+    # Mosaic's minimum lane tile; ``st`` below is these three refs,
+    # ``(m_ref, l_ref, acc_ref)``
+    def init(m_ref, l_ref, acc_ref):
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        kb = k_ref[0].astype(jnp.float32)                  # [B, h, dh]
-        vb = v_ref[0].astype(jnp.float32)
-        tok = nb * B + jax.lax.broadcasted_iota(jnp.int32, (B, h, 1), 0)
+    def fold(i, kb, vb, s_id, pos_ref, q_ref, m_ref, l_ref, acc_ref):
+        """Block ``i`` of slot ``s_id``'s chain into the slot's state."""
+        kb = kb.astype(jnp.float32)                        # [B, h, dh]
+        vb = vb.astype(jnp.float32)
+        tok = i * B + jax.lax.broadcasted_iota(jnp.int32, (B, h, 1), 0)
         for w in range(W):
             qw = q_ref[0, w].astype(jnp.float32)           # [h, dh]
             s = jnp.sum(kb * qw[None], axis=-1, keepdims=True) * scale
@@ -222,38 +259,91 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             m_ref[w] = jnp.broadcast_to(m2, (h, LSE_LANES))
             l_ref[w] = jnp.broadcast_to(l2, (h, LSE_LANES))
 
+    def finish(o_ref, m_ref, l_ref, acc_ref):
+        del m_ref
+        for w in range(W):
+            l = l_ref[w][:, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, w] = (acc_ref[w] / l_safe).astype(o_ref.dtype)
+
+    def loop_kernel(tbl, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                    k_buf, v_buf, sem, *st):
+        s_id = pl.program_id(0)
+        n = live_entries(pos_ref, s_id)
+
+        def fetch(i, half):
+            blk = tbl[s_id, i]
+            return (pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[half],
+                                          sem.at[0, half]),
+                    pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[half],
+                                          sem.at[1, half]))
+
+        init(*st)
+
+        @pl.when(n > 0)
+        def _first():
+            for c in fetch(0, 0):
+                c.start()
+
+        def block(i, _):
+            half = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n)
+            def _next():
+                for c in fetch(i + 1, 1 - half):
+                    c.start()
+
+            for c in fetch(i, half):
+                c.wait()
+            fold(i, k_buf[half], v_buf[half], s_id, pos_ref, q_ref, *st)
+
+        jax.lax.fori_loop(0, n, block, None)
+        finish(o_ref, *st)
+
+    def grid_kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref, *st):
+        del tbl  # consumed by the index maps, not the body
+        s_id = pl.program_id(0)
+        nb = pl.program_id(1)
+
+        @pl.when(nb == 0)
+        def _init():
+            init(*st)
+
+        @pl.when(nb < live_entries(pos_ref, s_id))
+        def _live():
+            fold(nb, k_ref[0], v_ref[0], s_id, pos_ref, q_ref, *st)
+
         @pl.when(nb == NB - 1)
         def _finish():
-            for w in range(W):
-                l = l_ref[w][:, :1]
-                l_safe = jnp.where(l == 0.0, 1.0, l)
-                o_ref[0, w] = (acc_ref[w] / l_safe).astype(o_ref.dtype)
+            finish(o_ref, *st)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, NB),
-        in_specs=[
-            pl.BlockSpec((1, W, h, dh),
-                         lambda s, nb, tbl, pos: (s, 0, 0, 0)),
-            pl.BlockSpec((1, B, h, dh),
-                         lambda s, nb, tbl, pos: (tbl[s, nb], 0, 0, 0)),
-            pl.BlockSpec((1, B, h, dh),
-                         lambda s, nb, tbl, pos: (tbl[s, nb], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, W, h, dh), lambda s, nb, tbl, pos: (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((W, h, LSE_LANES), jnp.float32),
-            pltpu.VMEM((W, h, LSE_LANES), jnp.float32),
-            pltpu.VMEM((W, h, dh), jnp.float32),
-        ],
-    )
+    stats = [pltpu.VMEM((W, h, LSE_LANES), jnp.float32),
+             pltpu.VMEM((W, h, LSE_LANES), jnp.float32),
+             pltpu.VMEM((W, h, dh), jnp.float32)]
+    if _block_is_sliceable(pool_k):
+        kernel, grid, semantics = loop_kernel, (S,), ("parallel",)
+        kv_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM((2, B, h, dh), pool_k.dtype),
+                   pltpu.VMEM((2, B, h, dh), pool_v.dtype),
+                   pltpu.SemaphoreType.DMA((2, 2))] + stats
+    else:
+        def last_live(s, nb, tbl, pos):
+            i = jnp.minimum(nb, jnp.maximum(live_entries(pos, s) - 1, 0))
+            return (tbl[s, i], 0, 0, 0)
+
+        kernel, grid, semantics = grid_kernel, (S, NB), ("parallel",
+                                                         "arbitrary")
+        kv_spec = pl.BlockSpec((1, B, h, dh), last_live)
+        scratch = stats
+    row = pl.BlockSpec((1, W, h, dh), lambda s, *_: (s, 0, 0, 0))
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=[row, kv_spec, kv_spec],
+            out_specs=row, scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((S, W, h, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=semantics),
         interpret=bool(interpret),
         name="paged_attention",
     )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v)

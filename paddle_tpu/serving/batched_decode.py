@@ -176,9 +176,19 @@ def _attend_through(arch, table, blk, off, pos):
     names the same positions in every plane, every pass has a trash block
     of its own (``p * num_blocks``), no plane is ever sliced out or
     copied and the kernels see an ordinary pool and table.  ``pos``
-    ``[S]`` is a decode step (rows ``[S, ...]``), ``[S, W]`` a window."""
+    ``[S]`` is a decode step (rows ``[S, ...]``), ``[S, W]`` a window.
+
+    A DEAD slot attends nothing: its rows are handed to attention at
+    ``pos = -1``, for which the Mosaic kernel fetches no block and
+    returns zeros.  Dead is read off the UNSHIFTED table, ``table[:, 0]
+    == 0``: a live slot's first entry is a real block from admission on
+    and ``ServingEngine._release_slot`` zeroes the row, whereas the
+    ``pos`` a decode chunk carries on the device goes stale for a
+    released slot (it keeps counting).  Writes (``blk``, ``off``) are
+    untouched: a dead slot's land in the trash block as before."""
     step = pos.ndim == 1
     pos4 = pos[:, None] if step else pos
+    pos4 = jnp.where((table[:, 0] == 0)[:, None], -1, pos4)
 
     def attend(planes, layer, i_pass, qh, kh, vh):
         pool_k, pool_v = planes

@@ -501,6 +501,26 @@ class ServingEngine:
                  "the next (stalled + their chunks): stalled_seconds' "
                  "denominator").inc(max(0.0, live))
 
+    def _count_paged_entries(self):
+        """A decode chunk is about to run: of the ``max_slots x
+        blocks_per_slot`` table entries each paged-attention call spans,
+        how many hold a key its first step attends (position ``prompt +
+        tokens - 1`` and everything before it, in the live slots only)."""
+        B = self.block_tokens
+        live = sum(-(-(req.prompt.shape[0] + len(req.tokens)) // B)
+                   for req in self._slots if req is not None)
+        self._reg.counter(
+            "serving.paged_entries_live",
+            help="block-table entries a paged-attention call had to "
+                 "visit, summed over decode chunks (live slots, up to "
+                 "each one's position at the chunk's start)").inc(live)
+        self._reg.counter(
+            "serving.paged_entries_total",
+            help="block-table entries a paged-attention call spans "
+                 "(max_slots x blocks_per_slot), summed over decode "
+                 "chunks: paged_entries_live's denominator").inc(
+                     self.max_slots * self.blocks_per_slot)
+
     # -- request intake ---------------------------------------------------
     def submit(self, prompt, max_new_tokens=16, eos_id=None,
                ttft_slo_s=None, e2e_slo_s=None, sheddable=True):
@@ -920,6 +940,7 @@ class ServingEngine:
         tbl = jnp.asarray(self._table)
         self._decode_fn.prepare(self._p, self._pk, self._pv, self._last,
                                 self._pos, tbl)
+        self._count_paged_entries()
         # the chunk call and its blocking token fetch: one span, whose
         # clock pair is the per-chunk-call latency histogram (ISSUE 7
         # TTFT/TPOT decomposition), the per-step wall, the predictor's

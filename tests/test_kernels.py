@@ -319,6 +319,89 @@ def test_paged_masking_ignores_future_and_trash_content():
     assert bool(jnp.array_equal(base, again))
 
 
+# the Mosaic kernel visits the live entries of the live chains only
+# (kernels/paged_attention.py): each case is (W, passes, rows of pos;
+# None marks a dead slot: table row 0, pos -1), over S=4 slots of NB=4
+# blocks of B=4 tokens (T = 16)
+_LIVE_CASES = {
+    "dead_slot_between_live": (1, 1, [[5], None, [9], [14]]),
+    "block_edges_and_stale_pos": (1, 1, [[3], [4], [15], [21]]),
+    "window_rows_in_different_blocks": (
+        3, 1, [[2, 3, 4], [7, 8, 9], None, [13, 14, 15]]),
+    "table_shifted_into_second_pass": (1, 2, [[6], None, [11], [0]]),
+    "every_chain_full": (1, 1, [[15], [15], [15], [15]]),
+}
+# float32 with 2 heads takes the kernel's loop over the chain, bfloat16
+# with 6 heads the grid Mosaic needs where it cannot slice the pool
+_LIVE_FORMS = [("float32", 2), ("bfloat16", 6)]
+
+
+def _live_case(name, dtype, h, seed=3):
+    w, passes, rows = _LIVE_CASES[name]
+    rng = np.random.default_rng(seed)
+    S, NB, B, dh = 4, 4, 4, 16
+    dt = jnp.dtype(dtype)
+    num_blocks = 1 + S * NB
+    shape = (passes * num_blocks, B, h, dh)
+    pool_k = np.asarray(rng.normal(size=shape) * 0.5, np.float32)
+    pool_v = np.asarray(rng.normal(size=shape) * 0.5, np.float32)
+    table = 1 + np.arange(S * NB, dtype=np.int32).reshape(S, NB)
+    live = np.array([r is not None for r in rows])
+    table[~live] = 0
+    table += (passes - 1) * num_blocks
+    pos = np.array([r if r is not None else [-1] * w for r in rows],
+                   np.int32)
+    q = jnp.asarray(rng.normal(size=(S, w, h, dh)) * 0.5, dt)
+    # the blocks a call has to visit: entries up to the furthest row's
+    # position in the live slots; everything else in the pool is fair
+    # game for garbage
+    visited = np.zeros(shape[0], bool)
+    for s in np.flatnonzero(live):
+        n = min(NB, int(pos[s].max()) // B + 1)
+        visited[table[s, :n]] = True
+    return (q, jnp.asarray(pool_k, dt), jnp.asarray(pool_v, dt),
+            jnp.asarray(table), jnp.asarray(pos), live, visited)
+
+
+@pytest.mark.parametrize("dtype,h", _LIVE_FORMS)
+@pytest.mark.parametrize("case", list(_LIVE_CASES))
+def test_paged_mosaic_live_rows_match_the_oracles(case, dtype, h):
+    """Live rows of the Mosaic kernel (interpret) match ``xla_ref`` and
+    the dense gather+softmax spelling; a dead slot's rows are zeros."""
+    from paddle_tpu.kernels.paged_attention import (
+        paged_attention_pallas, paged_attention_ref)
+
+    q, pk, pv, tbl, pos, live, _ = _live_case(case, dtype, h)
+    got = paged_attention_pallas(q, pk, pv, tbl, pos, interpret=True)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    tol = oracle_tol("paged_attention", dtype, "fwd")
+    for ref in (paged_attention_ref(q, pk, pv, tbl, pos),
+                _paged_dense(q, pk, pv, tbl, pos)):
+        assert _rel_err(got[live], ref[live]) <= tol
+    assert not np.asarray(got, np.float32)[~live].any()
+
+
+@pytest.mark.parametrize("dtype,h", _LIVE_FORMS)
+@pytest.mark.parametrize("case", list(_LIVE_CASES))
+def test_paged_mosaic_never_touches_what_it_need_not_visit(case, dtype, h):
+    """The proof of the skip: NaN in every block the call must not visit
+    (table entries past a chain's live length, dead slots' rows, the
+    trash block, another pass's plane) leaves every live row finite and
+    bit-identical.  ``p = 0`` times a NaN value is NaN, so a block that
+    was only MASKED would show."""
+    from paddle_tpu.kernels.paged_attention import paged_attention_pallas
+
+    q, pk, pv, tbl, pos, live, visited = _live_case(case, dtype, h)
+    base = paged_attention_pallas(q, pk, pv, tbl, pos, interpret=True)
+    assert not visited.all()
+    poison = jnp.asarray(~visited)[:, None, None, None]
+    again = paged_attention_pallas(
+        q, jnp.where(poison, jnp.nan, pk), jnp.where(poison, jnp.nan, pv),
+        tbl, pos, interpret=True)
+    assert bool(jnp.all(jnp.isfinite(again.astype(jnp.float32))))
+    assert bool(jnp.array_equal(base[live], again[live]))
+
+
 @pytest.mark.parametrize("backend", ["pallas_tpu", "xla_ref"])
 def test_bit_exact_run_to_run_within_backend(backend):
     impl = _impl_or_skip("flash_attention", backend)

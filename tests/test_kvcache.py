@@ -284,6 +284,71 @@ def test_architecture_object_serves_prefix_traffic_like_positional():
     assert stats[0] == stats[1] and stats[0]["serving.cow_copies"] >= 1
 
 
+# -- engine-level: dead slots and the entries a paged call must visit --------
+
+_CHUNK, _SLOTS = 4, 3
+_MAX_NEW = (3, 11, 7)
+
+
+def _serve_with_a_slot_released_mid_run(params):
+    """Three requests admitted together into an engine of three slots;
+    the first finishes after one chunk, so its slot rides dead (table
+    row 0, attention at ``pos = -1``) through the others' later chunks.
+    Asserts the single-stream tokens; returns the engine and prompts."""
+    eng = ServingEngine(params, NL, NH, DM, max_len=T, max_slots=_SLOTS,
+                        decode_chunk=_CHUNK, min_bucket=4, block_tokens=4,
+                        prefix_reuse=False)
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(1, VOCAB, (l,)).astype(np.int32)
+               for l in (9, 5, 12)]
+    outs = eng.generate_many(prompts, max_new_tokens=list(_MAX_NEW))
+    for p, o, m in zip(prompts, outs, _MAX_NEW):
+        ref, _ = transformer.generate(params, p[None], max_len=T,
+                                      n_layer=NL, n_head=NH, d_model=DM,
+                                      return_logits=False)
+        np.testing.assert_array_equal(o, np.asarray(ref)[0][: len(p) + m])
+    return eng, prompts
+
+
+def test_engine_counts_the_table_entries_a_paged_call_must_visit():
+    """``serving.paged_entries_live`` / ``_total`` equal the arithmetic
+    over the requests' lengths: at each chunk's start a live request
+    holds ``ceil((prompt + tokens) / B)`` entries with a key it attends,
+    of ``max_slots x blocks_per_slot`` in the table; the tokens are the
+    single-stream tokens although a slot dies mid-run."""
+    eng, prompts = _serve_with_a_slot_released_mid_run(_make_params())
+    B = eng.block_tokens
+    # prefill leaves one token; every chunk adds ``_CHUNK`` more until
+    # max_new is reached
+    live, chunks = 0, 0
+    for p, m in zip(prompts, _MAX_NEW):
+        k = 0
+        while 1 + k * _CHUNK < m:
+            live += -(-(len(p) + 1 + k * _CHUNK) // B)
+            k += 1
+        chunks = max(chunks, k)
+    st = eng.stats()
+    assert st["serving.paged_entries_live"] == live
+    assert st["serving.paged_entries_total"] == chunks * _SLOTS * (T // B)
+    assert 0 < live < st["serving.paged_entries_total"]
+
+
+def test_engine_serves_single_stream_tokens_through_the_mosaic_kernel(
+        monkeypatch):
+    """The same traffic with every attention call routed to the
+    Mosaic kernel (interpret mode; off the chip the registry would
+    resolve ``xla_ref``): the dead slot's ``pos = -1`` rows and the
+    chains' unvisited tails change no token."""
+    from paddle_tpu.kernels.paged_attention import paged_attention_pallas
+    from paddle_tpu.serving import batched_decode
+
+    monkeypatch.setattr(
+        batched_decode, "_paged_attention",
+        lambda qh, pk, pv, table, pos: paged_attention_pallas(
+            qh, pk, pv, table, pos, interpret=True))
+    _serve_with_a_slot_released_mid_run(_make_params())
+
+
 # -- engine-level: paged-attention kill switch -------------------------------
 
 @pytest.mark.slow

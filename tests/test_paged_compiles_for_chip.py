@@ -1,0 +1,60 @@
+"""The Mosaic paged-attention kernel compiled for a TPU v5e that is
+described and not attached, at the widths the serving cells and the
+chip smoke run: what interpret mode cannot show (Mosaic refuses to slice
+a block out of a bf16 pool with 6 or 12 heads, so those take the grid
+form; ``kernels/paged_attention._block_is_sliceable``).  Nothing runs:
+a compile that passes is no chip run.  One file, the topology inside a
+fixture (one process may hold the TPU's library)."""
+
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (slots, window, table entries a slot, pool blocks, heads)
+GEOMETRIES = {
+    "agent_turns_decode": (24, 1, 24, 705, 16),
+    "reason_decode_folded_pool": (10, 1, 16, 708, 16),
+    "verify_window": (24, 4, 24, 705, 16),
+    "narrow_prefill_piece": (1, 4, 24, 705, 16),
+    "smoke_6_heads_grid_form": (32, 4, 16, 545, 6),
+    "cgpt590m_12_heads_grid_form": (8, 1, 16, 200, 12),
+}
+
+
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_paged_kernel_compiles_for_v5e(geometry, one_chip):
+    from paddle_tpu.kernels.paged_attention import (
+        _block_is_sliceable, paged_attention_pallas)
+
+    S, W, NB, blocks, h = GEOMETRIES[geometry]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((blocks, 32, h, 128), jnp.bfloat16)
+    assert _block_is_sliceable(pool) == ("grid_form" not in geometry)
+    compiled = jax.jit(
+        lambda *a: paged_attention_pallas(*a, interpret=False)).lower(
+            arg((S, W, h, 128), jnp.bfloat16), pool, pool,
+            arg((S, NB), jnp.int32), arg((S, W), jnp.int32)).compile()
+    # the pools enter the kernel in place: no pool-sized temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert "paged_attention" in compiled.as_text()
