@@ -59,22 +59,13 @@ bench discipline: never die without a parseable row):
     prefill_compiles / decode_compiles / buckets   the compile bound:
                        executables == used prefill buckets + 1 decode
                        chunk, independent of request count
-    serving_decode_hbm_bytes / serving_attn_bytes   the compiled decode
-                       chunk's HBM high-water and attention-class HLO
-                       bytes through the paged-attention kernel, with
-                       ``_gather`` counterparts from the
-                       ``PADDLE_TPU_PAGED_ATTN=0`` gather spelling at
-                       the same geometry — the smoke asserts paged is
-                       strictly lower on both
 
 ``--smoke`` is the CI gate (tools/tier1.sh): a CPU-sized config that
 ASSERTS the engine beats the sequential baseline, SLO goodput beats
 FIFO goodput, prefix reuse hits (``prefix_hit_rate > 0``) with strictly
 fewer prefill tokens than the reuse-OFF spelling, the compile bound
-holds, the speculative pass beats the SLO pass's goodput with zero
-scratch-block leak, and the paged-attention decode chunk compiles to
-strictly lower HBM high-water AND attention-class bytes than the
-gather spelling.
+holds, and the speculative pass beats the SLO pass's goodput with zero
+scratch-block leak.
 
 Usage:
     python benchmarks/serving.py --smoke
@@ -160,65 +151,6 @@ def make_workload(rng, n, classes, vocab, prefix_len):
     return work
 
 
-def measure_decode_memory(params, cfg):
-    """Compile the decode chunk at the bench geometry TWICE — once
-    through the paged-attention kernel, once through the
-    ``PADDLE_TPU_PAGED_ATTN=0`` gather+softmax spelling — and read the
-    compiled cost analysis for each: HBM high-water
-    (``memory_analysis``) and the attention-class HLO bytes (the
-    ``paged_attention`` / ``decode_gather`` buckets of
-    ``attribute_hlo``).  This is the tentpole's receipt: the gather
-    spelling materializes the [S, T, h, dh] KV view per layer, the
-    paged kernel streams pool blocks — both numbers must be strictly
-    lower on the paged side at the same geometry.  AOT-only (nothing
-    executes); compiles are separate from (and never donate into) the
-    timed engine passes."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.analysis.hlo_tools import compiled_memory_stats
-    from paddle_tpu.observability.attribution import attribute_hlo
-    from paddle_tpu.serving import batched_decode as _bd
-    from paddle_tpu.serving.arch import Gpt2
-
-    nl, nh, dm = cfg["n_layer"], cfg["n_head"], cfg["d_model"]
-    S, bt = cfg["slots"], cfg["block_tokens"]
-    nb_chain = -(-cfg["max_len"] // bt)
-    num_blocks = 1 + S * nb_chain
-    dh = dm // nh
-    pdev = {k: jnp.asarray(v) for k, v in params.items()}
-    dt = jnp.dtype(cfg["dtype"])
-    pk = tuple(jnp.zeros((num_blocks, bt, nh, dh), dt)
-               for _ in range(nl))
-    pv = tuple(jnp.zeros((num_blocks, bt, nh, dh), dt)
-               for _ in range(nl))
-    tok = jnp.zeros((S,), jnp.int32)
-    t = jnp.full((S,), cfg["max_len"] // 2, jnp.int32)
-    table = jnp.asarray(
-        1 + np.arange(S * nb_chain).reshape(S, nb_chain), jnp.int32)
-
-    prev = os.environ.get("PADDLE_TPU_PAGED_ATTN")
-    out = {}
-    try:
-        for env, suffix in (("1", ""), ("0", "_gather")):
-            os.environ["PADDLE_TPU_PAGED_ATTN"] = env
-            fn = _bd.make_decode_chunk(Gpt2(nl, nh, dm), cfg["chunk"],
-                                       donate=False)
-            c = fn.lower(pdev, pk, pv, tok, t, table).compile()
-            stats = compiled_memory_stats(c)
-            att = attribute_hlo(c.as_text())
-            attn = sum(att["classes"].get(k, {}).get("bytes", 0)
-                       for k in ("paged_attention", "decode_gather"))
-            out["serving_decode_hbm_bytes" + suffix] = int(
-                stats.get("hbm_high_water_bytes", 0))
-            out["serving_attn_bytes" + suffix] = int(attn)
-    finally:
-        if prev is None:
-            os.environ.pop("PADDLE_TPU_PAGED_ATTN", None)
-        else:
-            os.environ["PADDLE_TPU_PAGED_ATTN"] = prev
-    return out
-
-
 def run_baseline(params, cfg, work):
     """Sequential single-request serving on the pre-engine path: one
     ``transformer.generate`` call per request (its exact total length),
@@ -257,7 +189,7 @@ def run_baseline(params, cfg, work):
 
 def run_engine(params, cfg, work, arrivals, *, scheduler, prefix_reuse,
                ttft_slo_s=None, e2e_slo_s=None, draft_params=None,
-               spec_k=None):
+               spec_k=4):
     """One timed engine pass under the given policy.  Returns
     throughput + per-request latency from the handles plus the engine's
     ``serving.*`` counters for the timed window.  Compiles (prefill
@@ -286,7 +218,7 @@ def run_engine(params, cfg, work, arrivals, *, scheduler, prefix_reuse,
     # a speculative engine warms with enough decode room for full
     # propose/verify windows — the predictor's steps-per-round estimate
     # must see representative rounds, not 2-token-capped ones
-    warm_new = 2 if draft_params is None else 2 * ((spec_k or 4) + 1)
+    warm_new = 2 if draft_params is None else 2 * (spec_k + 1)
     for i in range(min(2 * n_classes, len(work))):
         eng.generate_many([work[i][0]], max_new_tokens=warm_new)
     # drop the warm pass's latency observations (its first decode chunk
@@ -444,15 +376,6 @@ def main():
                               cfg["d_model"], cfg["max_len"], cfg["dtype"])
         work = make_workload(rng, cfg["requests"], cfg["classes"],
                              cfg["vocab"], cfg["prefix_len"])
-        log("decode-chunk memory A/B: paged attention vs the "
-            "PADDLE_TPU_PAGED_ATTN=0 gather spelling ...")
-        row.update(measure_decode_memory(params, cfg))
-        log(f"  paged : hbm_high_water "
-            f"{row['serving_decode_hbm_bytes']:,} B, attn bytes "
-            f"{row['serving_attn_bytes']:,}")
-        log(f"  gather: hbm_high_water "
-            f"{row['serving_decode_hbm_bytes_gather']:,} B, attn bytes "
-            f"{row['serving_attn_bytes_gather']:,}")
         # ONE Poisson arrival schedule, shared by both engine passes so
         # the FIFO-vs-SLO comparison sees identical load
         arrivals = rng.exponential(1.0 / rate, size=len(work))
@@ -601,14 +524,6 @@ def main():
                     > row["spec_base_goodput_under_slo"]), \
                 (f"speculative decoding did not beat the non-spec SLO "
                  f"pass's goodput on the same arrival schedule: {row}")
-            assert (row["serving_decode_hbm_bytes"]
-                    < row["serving_decode_hbm_bytes_gather"]), \
-                (f"paged attention did not lower the decode chunk's "
-                 f"compiled HBM high-water: {row}")
-            assert (row["serving_attn_bytes"]
-                    < row["serving_attn_bytes_gather"]), \
-                (f"paged attention did not lower the attention-class "
-                 f"HLO bytes: {row}")
     except Exception as e:  # noqa: BLE001 — the row must still print
         row["error"] = f"{type(e).__name__}: {e}"
         print(json.dumps(row))

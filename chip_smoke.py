@@ -279,7 +279,7 @@ def serve_phase(pt, dims, params):
     # decode and the narrow windows stream blocks through the Mosaic
     # kernel; a prefill window of DENSE_WINDOW rows or more gathers the
     # chain once and attends it densely (the xla_ref spelling)
-    from paddle_tpu.serving.batched_decode import DENSE_WINDOW
+    from paddle_tpu.kernels.paged_attention import DENSE_WINDOW
 
     def expected(label):
         wide = (label.startswith("prefill_")
@@ -288,7 +288,6 @@ def serve_phase(pt, dims, params):
 
     assert paged and all(sel == expected(label)
                          for label, sel in paged.items()), paged
-    assert stats["serving.paged_attn_compiles"] >= 1, stats
     assert stats["serving.prefix_hit_rate"] > 0, stats
     assert eng.kv_pool.blocks_in_use == len(eng.prefix_trie), (
         eng.kv_pool.blocks_in_use, len(eng.prefix_trie))

@@ -1513,7 +1513,7 @@ def cmd_kernels_selftest(args=None):
     """``python -m paddle_tpu --kernels-selftest``: the multi-backend
     kernel registry's CI gate (docs/kernels.md) — registry resolution
     and override precedence on this host, oracle parity for every
-    available backend (plus the Mosaic/triton kernels force-run in
+    available backend (plus the Mosaic kernels force-run in
     interpret mode) against the pure-XLA reference within the
     documented ``ORACLE_TOL`` bounds (f32+bf16, causal/non-causal,
     d_head 64/128, grads through the custom-vjp, run-to-run

@@ -1,19 +1,18 @@
 """paddle_tpu.kernels — the multi-backend kernel registry
 (docs/kernels.md, ROADMAP item 2).
 
-One op, three targets, one numerics oracle: every fused op class
-(``flash_attention``, ``fused_ce``, ``decode_gather``,
-``paged_attention``) resolves through
-:mod:`.registry` to one of ``pallas_tpu`` (the Mosaic kernels — native
-on TPU, interpret mode in CPU tests), ``triton`` (the same block
-schedules lowered GPU-style — :mod:`.triton_attention` /
-:mod:`.triton_ce`), or ``xla_ref`` (:mod:`.xla_ref` — the shape-
-complete pure-XLA reference every backend is tested against, with the
-documented cross-backend tolerances in ``ORACLE_TOL``).
+One op, two targets, one numerics oracle: every fused op class
+(``flash_attention``, ``fused_ce``, ``paged_attention``) resolves
+through :mod:`.registry` to ``pallas_tpu`` (the Mosaic kernels — native
+on TPU, interpret mode in CPU tests) or ``xla_ref`` (:mod:`.xla_ref` —
+the shape-complete pure-XLA reference every backend is tested against,
+with the documented cross-backend tolerances in ``ORACLE_TOL``).  How a
+serving row attends through the block table (streaming kernel or dense
+gather, by window width) is :func:`.paged_attention.attend`'s to decide.
 
-Selection: ``PADDLE_TPU_KERNEL_BACKEND=auto|pallas_tpu|triton|xla_ref``
+Selection: ``PADDLE_TPU_KERNEL_BACKEND=auto|pallas_tpu|xla_ref``
 (global), ``PADDLE_TPU_KERNEL_BACKEND_<OP>`` (per op class), explicit
-``backend=`` call-site arguments, or the tuned winner's persisted
+``backend=`` call-site arguments, or the training tuner's persisted
 kernel choice — precedence and fallback semantics in
 :mod:`.registry`.  CI: ``python -m paddle_tpu --kernels-selftest``
 (tools/tier1.sh) and ``tests/test_kernels.py``.
@@ -27,8 +26,6 @@ from .registry import (
     selected_backends, timed_run, timed_run_active)
 from .xla_ref import ORACLE_TOL, oracle_tol
 from . import xla_ref  # registers the oracle backend
-from . import triton_attention, triton_ce  # register the GPU backends
-from . import pallas_gather  # registers the TPU decode gather
 from . import paged_attention  # registers the paged-attention op class
 
 __all__ = [

@@ -1,16 +1,27 @@
-"""Block-table-aware paged attention — the fourth op class.
+"""Block-table-aware paged attention: how a row attends through the
+block table, decided here and nowhere else.
 
 The serving engine's paged KV cache (``serving/kvcache.py``) stores
-each slot's KV as a chain of physical blocks named by a block table;
-until this op class existed every decode step materialized the full
-logical view ``pool[table] -> [S, T, h, dh]`` through ``decode_gather``
-before attention ran, so HBM traffic and peak memory scaled with the
-padded table capacity ``T``, not the tokens actually live in a chain.
-``paged_attention`` attends THROUGH the table instead: online-softmax
-block by block, one physical block (or a small group) in flight at a
-time, the gathered view never built.
+each slot's KV as a chain of physical blocks named by a block table.
+``paged_attention`` attends THROUGH the table: online softmax block by
+block, one physical block (or a small group) in flight at a time, the
+logical view ``pool[table] -> [S, T, h, dh]`` never built.
 
-Calling convention (all backends)::
+The serving step (``serving/batched_decode._attend_through``) calls ONE
+function, :func:`attend`, which chooses the spelling from what it can
+observe at trace time and from nothing else (no environment variable,
+no tuner, no file):
+
+* a window of ``DENSE_WINDOW`` rows or more (a prefill piece) gathers
+  the chain ONCE and attends it densely: the ``xla_ref`` spelling with
+  one step over the whole chain;
+* a narrower window (decode's ``W = 1``, a speculative ``k + 1``, a
+  narrow prefill piece) streams blocks through whatever the registry
+  resolves for ``paged_attention``: the Mosaic kernel on a TPU (its loop
+  or its grid form by ``_block_is_sliceable``), the ``xla_ref`` scan
+  elsewhere.
+
+Calling convention (both backends)::
 
     call(q, pool_k, pool_v, table, pos, block_step=None,
          interpret=None) -> ctx
@@ -21,30 +32,27 @@ Calling convention (all backends)::
     pool_v  [num_blocks, B, h, dh]   the physical V pool
     table   [S, NB] int32   per-slot block chain (block 0 = trash)
     pos     [S, W]  int32   absolute position of each query; key token
-                            ``j`` participates iff ``j <= pos`` — the
-                            same write-before-attend mask the gather
-                            spelling applies, so trash-block garbage,
-                            bucket padding and CoW tails all carry
-                            exactly zero attention weight
+                            ``j`` participates iff ``j <= pos`` (the
+                            write-before-attend mask), so trash-block
+                            garbage, bucket padding and CoW tails all
+                            carry exactly zero attention weight
     ctx     [S, W, h, dh]   in ``q.dtype``
 
 Numerics conventions match the flash kernels (f32 scores via
 ``preferred_element_type``, ``NEG_INF`` masking, f32 ``(m, l, acc)``
 online-softmax state, one normalization at the end with the
 ``l == 0 -> 1`` guard, output cast to the input dtype).  The blocked
-reassociation means results differ from the dense gather+softmax
-spelling within ``ORACLE_TOL["paged_attention", ...]``; within one
-backend the op is bit-exact run to run.  Token position ``nb*B + b``
-of slot ``s`` lives at ``(table[s, nb], b)`` — block 0 never needs
-zeroing because its token positions in an unused table entry are
-always ``> pos``.
+reassociation means the two backends differ within
+``ORACLE_TOL["paged_attention", ...]``; within one backend the op is
+bit-exact run to run.  Token position ``nb*B + b`` of slot ``s`` lives
+at ``(table[s, nb], b)`` — block 0 never needs zeroing because its
+token positions in an unused table entry are always ``> pos``.
 
 Backends:
 
 * ``xla_ref`` — a ``lax.scan`` over table entries, gathering
   ``block_step`` physical blocks per step (``[S, block_step*B, h,
-  dh]`` in flight — the tuned block-iteration geometry,
-  ``tune.paged_attention_config``).  The universal numerics reference.
+  dh]`` in flight).  The universal numerics reference.
 * ``pallas_tpu`` — ``PrefetchScalarGridSpec`` scalar prefetch of table
   and positions; grid ``(S,)``, one step a slot, whose body loops over
   the LIVE entries of that slot's chain only: ``n_s = clip(max_w
@@ -58,30 +66,48 @@ Backends:
   interpret-mode grid would replace one fused XLA loop with a per-block
   Python loop); the oracle suite still covers the kernel logic on CPU by
   forcing ``interpret=True``.
-* ``triton`` — the GPU decomposition of ``triton_attention.py``: a
-  parallel grid over independent slots, the block-chain reduction as a
-  ``lax.fori_loop`` inside the kernel with ``pl.load`` +
-  ``pl.dslice`` dynamic block fetches.  Interpret-verified on CPU.
-
-``serving/batched_decode.py`` routes here when ``PADDLE_TPU_PAGED_ATTN``
-is on (the default); ``=0`` restores the gather+flash spelling
-bit-exact (docs/serving.md "Paged KV cache").
 """
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.pallas_attention import LSE_LANES
-from .registry import register_kernel
-from .triton_attention import _default_interpret, _gpu_available
+from .registry import register_kernel, resolve
 from .xla_ref import NEG_INF
+
+__all__ = ["attend", "DENSE_WINDOW", "paged_attention_ref",
+           "paged_attention_pallas"]
+
+# From this window width up a window gathers its slot's chain once and
+# attends it densely instead of streaming blocks.  W rows then share one
+# read of K and V and the scores are MXU matmuls, where the streaming
+# kernels repeat their per-block body once per window row; and the
+# Mosaic kernel cannot run wide at all (18.6 MB of scoped VMEM at W = 64;
+# PERF.md, PR 26).
+DENSE_WINDOW = 8
+
+
+def attend(q, pool_k, pool_v, table, pos):
+    """One layer's attention THROUGH the block table, the one call the
+    serving step makes: ``q [S, W, h, dh]``, ``pos [S, W]`` ->
+    ``[S, W, h, dh]``.
+
+    The spelling follows the window's width and the platform, both seen
+    at trace time (module docstring): ``W >= DENSE_WINDOW`` is the
+    ``xla_ref`` spelling with ONE step over the whole chain; a narrower
+    window streams blocks with online softmax through the backend the
+    registry resolves."""
+    if q.shape[1] >= DENSE_WINDOW:
+        return resolve("paged_attention", backend="xla_ref").impl.call(
+            q, pool_k, pool_v, table, pos, block_step=table.shape[1])
+    return resolve("paged_attention").impl.call(q, pool_k, pool_v, table,
+                                                pos)
 
 
 def _normalize_block_step(block_step, nb, w=1):
     if block_step is None:
-        # measured default (tune_paged_attention owns the per-workload
-        # override): single-token decode (W=1) is fastest streaming one
-        # block per step and that is also where the memory win lives;
+        # measured default: single-token decode (W=1) is fastest
+        # streaming one block per step, where the memory win also lives;
         # multi-token windows (the speculative verify, W=k+1) pay the
         # scan's sequential dispatch W times over and win by consuming
         # the whole chain in one wide step instead
@@ -360,75 +386,6 @@ def _tpu_available():
                    f"XLA oracle is the efficient spelling here")
 
 
-# -- triton: parallel slots, fori_loop block chain ---------------------------
-
-def paged_attention_triton(q, pool_k, pool_v, table, pos, block_step=None,
-                           interpret=None):
-    """GPU-style decomposition (``triton_attention.py`` structure): the
-    grid covers only independent cells (one slot each — slots share
-    nothing), and the block-chain reduction runs INSIDE the kernel as a
-    ``lax.fori_loop`` whose body ``pl.load``s the physical block the
-    table names via a dynamic ``pl.dslice``.  ``block_step`` is
-    accepted for signature parity and ignored — the loop consumes one
-    physical block per iteration."""
-    import jax.experimental.pallas as pl
-
-    del block_step
-    interpret = _default_interpret(interpret)
-    S, W, h, dh = q.shape
-    num_blocks, B = pool_k.shape[0], pool_k.shape[1]
-    NB = table.shape[1]
-    scale = 1.0 / float(dh) ** 0.5
-
-    def kernel(q_ref, k_ref, v_ref, tbl_ref, pos_ref, o_ref):
-        qw = q_ref[0]                                      # [W, h, dh]
-        pw = pos_ref[0]                                    # [W]
-
-        def body(nb, carry):
-            m, l, acc = carry
-            blk = pl.load(tbl_ref, (pl.dslice(0, 1),
-                                    pl.dslice(nb, 1)))[0, 0]
-            kb = pl.load(k_ref, (pl.dslice(blk, 1), slice(None),
-                                 slice(None), slice(None)))[0]
-            vb = pl.load(v_ref, (pl.dslice(blk, 1), slice(None),
-                                 slice(None), slice(None)))[0]
-            s = jnp.einsum("whd,bhd->whb", qw, kb,
-                           preferred_element_type=jnp.float32) * scale
-            tok = nb * B + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, B), 2)
-            s = jnp.where(tok <= pw[:, None, None], s, NEG_INF)
-            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
-            alpha = jnp.exp(m - m2)
-            p = jnp.exp(s - m2[..., None])
-            l2 = l * alpha + jnp.sum(p, axis=-1)
-            acc2 = acc * alpha[..., None] + jnp.einsum(
-                "whb,bhd->whd", p, vb.astype(jnp.float32))
-            return m2, l2, acc2
-
-        m0 = jnp.full((W, h), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((W, h), jnp.float32)
-        a0 = jnp.zeros((W, h, dh), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(0, NB, body, (m0, l0, a0))
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc / l_safe[..., None]).astype(o_ref.dtype)[None]
-
-    return pl.pallas_call(
-        kernel,
-        grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, W, h, dh), lambda s: (s, 0, 0, 0)),
-            pl.BlockSpec((num_blocks, B, h, dh), lambda s: (0, 0, 0, 0)),
-            pl.BlockSpec((num_blocks, B, h, dh), lambda s: (0, 0, 0, 0)),
-            pl.BlockSpec((1, NB), lambda s: (s, 0)),
-            pl.BlockSpec((1, W), lambda s: (s, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, W, h, dh), lambda s: (s, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, W, h, dh), q.dtype),
-        interpret=bool(interpret),
-        name="paged_attention_triton",
-    )(q, pool_k, pool_v, table.astype(jnp.int32), pos.astype(jnp.int32))
-
-
 # -- registration ------------------------------------------------------------
 
 class _PagedXlaRef:
@@ -439,12 +396,6 @@ class _PagedPallasTpu:
     call = staticmethod(paged_attention_pallas)
 
 
-class _PagedTriton:
-    call = staticmethod(paged_attention_triton)
-
-
 register_kernel("paged_attention", "xla_ref", _PagedXlaRef)
 register_kernel("paged_attention", "pallas_tpu", _PagedPallasTpu,
                 available=_tpu_available)
-register_kernel("paged_attention", "triton", _PagedTriton,
-                available=_gpu_available)

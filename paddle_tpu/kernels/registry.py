@@ -4,18 +4,13 @@ The paper's framework survived a hardware transition because op
 SEMANTICS were separated from op IMPLEMENTATION — layer-graph ops were
 re-lowered per device.  This module is that separation for the fused
 kernels: each op CLASS (flash attention fwd/bwd, the fused CE/LSE head,
-the paged serving decode gather) registers up to three backends and
-every call site resolves through ONE selection path:
+paged attention on the serving block table) registers up to two
+backends and every call site resolves through ONE selection path:
 
 * ``pallas_tpu`` — the Mosaic kernels (``ops/pallas_attention.py``,
   ``ops/pallas_ce.py``).  Native on TPU; off-TPU they run in Pallas
   interpret mode (slow, but the exact kernel logic — the CPU test
   path).
-* ``triton`` — the same block schedules lowered GPU-style
-  (``kernels/triton_attention.py`` / ``triton_ce.py``: parallel grid
-  over independent blocks, the reduction loop INSIDE the kernel body —
-  TPU grids are sequential with carried scratch, GPU grids are not).
-  Available only where a GPU exists; elsewhere it skips with a reason.
 * ``xla_ref`` — the shape-complete pure-XLA reference
   (``kernels/xla_ref.py``): causal/non-causal, d_head 64/128, packed
   layouts, lse outputs, grads through the same custom-vjp algebra.
@@ -32,9 +27,9 @@ Selection precedence (the registry unit suite pins this):
 2. per-op env ``PADDLE_TPU_KERNEL_BACKEND_<OP>`` (op class upper-cased,
    e.g. ``PADDLE_TPU_KERNEL_BACKEND_FLASH_ATTENTION=xla_ref``) — same
    strictness as an explicit argument;
-3. global env ``PADDLE_TPU_KERNEL_BACKEND=auto|pallas_tpu|triton|
-   xla_ref`` — unavailable/unregistered degrades to auto with the
-   fallback counted (``kernels.env_fallbacks``) so a fleet-wide env pin
+3. global env ``PADDLE_TPU_KERNEL_BACKEND=auto|pallas_tpu|xla_ref``
+   — unavailable/unregistered degrades to auto with the fallback
+   counted (``kernels.env_fallbacks``) so a fleet-wide env pin
    never crashes the one op that lacks the backend;
 4. ``auto`` — the per-platform preference order (:data:`AUTO_ORDER`):
    first registered AND available backend wins.
@@ -61,7 +56,7 @@ __all__ = [
     "TIMED_RUN_ENV", "GLOBAL_ENV",
 ]
 
-BACKENDS = ("pallas_tpu", "triton", "xla_ref")
+BACKENDS = ("pallas_tpu", "xla_ref")
 
 GLOBAL_ENV = "PADDLE_TPU_KERNEL_BACKEND"
 TIMED_RUN_ENV = "PADDLE_TPU_TIMED_RUN"
@@ -76,9 +71,6 @@ TIMED_RUN_ENV = "PADDLE_TPU_TIMED_RUN"
 # PADDLE_TPU_KERNEL_BACKEND=xla_ref (docs/kernels.md).
 AUTO_ORDER = {
     "tpu": ("pallas_tpu", "xla_ref"),
-    "gpu": ("triton", "xla_ref"),
-    "cuda": ("triton", "xla_ref"),
-    "rocm": ("triton", "xla_ref"),
     "cpu": ("pallas_tpu", "xla_ref"),
 }
 _DEFAULT_ORDER = ("xla_ref",)
@@ -86,7 +78,8 @@ _DEFAULT_ORDER = ("xla_ref",)
 
 class KernelUnavailable(RuntimeError):
     """An explicitly requested backend is registered for the op class
-    but not available on this host (e.g. ``triton`` with no GPU).
+    but not available on this host (e.g. ``pallas_tpu`` paged attention
+    off the TPU).
     ``.reason`` carries the availability probe's explanation — test
     suites turn it into a skip, resolution fallbacks record it."""
 
@@ -294,7 +287,8 @@ def forced_backend(backend, op_class=None):
     classes, or one) — how the autotuner measures a backend candidate
     and how tests pin routing without env mutation.  Non-strict: an op
     the backend cannot serve falls back to auto (counted), so forcing
-    ``triton`` on a CPU host measures what auto would actually run.
+    ``pallas_tpu`` paged attention off the TPU measures what auto would
+    actually run.
     Explicit ``backend=`` call-site arguments still win."""
     if backend is not None:
         _validate(str(backend).strip().lower())

@@ -7,7 +7,7 @@ What it proves on THIS host, accelerator or not:
    override precedence holds (explicit arg > per-op env > global env >
    auto), unknown backends raise, unavailable explicit backends raise
    with a reason, a global-env pin an op cannot serve degrades to auto;
-2. oracle parity — every backend AVAILABLE here (plus the GPU/TPU
+2. oracle parity — every backend AVAILABLE here (plus the Mosaic
    kernels force-run in interpret mode, so the kernel logic itself is
    exercised even on a CPU-only host) matches the xla_ref oracle
    within the documented ``ORACLE_TOL`` bounds, f32 + bf16, causal +
@@ -17,9 +17,7 @@ What it proves on THIS host, accelerator or not:
    ``paged_attention`` op class (interpret-forced where unavailable)
    matches a dense gather+softmax reference within ``ORACLE_TOL``
    over ragged chains (fully-cached one-token prefill, a CoW fork,
-   trash-block garbage), is bit-exact run-to-run, and the
-   ``PADDLE_TPU_PAGED_ATTN`` kill switch provably toggles which
-   spelling the serving decode chunk compiles;
+   trash-block garbage) and is bit-exact run-to-run;
 4. the xla_ref acceptance bar — ``PADDLE_TPU_KERNEL_BACKEND=xla_ref``
    runs the full GPT trainer path under EVERY memory_optimize policy
    with ZERO Pallas calls in the traced jaxpr and a finite loss;
@@ -51,8 +49,7 @@ def _check_registry(failures):
     ops = registered_op_classes()
     print(f"registry: op classes {ops} on platform "
           f"{jax.default_backend()!r}")
-    if sorted(ops) != ["decode_gather", "flash_attention", "fused_ce",
-                       "paged_attention"]:
+    if sorted(ops) != ["flash_attention", "fused_ce", "paged_attention"]:
         failures.append(f"unexpected op classes: {ops}")
     for op in ops:
         auto = resolve_name(op)
@@ -93,14 +90,16 @@ def _check_registry(failures):
         except KernelUnavailable as e:
             if not e.reason:
                 failures.append(f"unavailable backend {b} has no reason")
-    # a global-env pin an op cannot serve degrades to auto (triton has
-    # no decode_gather registration anywhere)
-    os.environ["PADDLE_TPU_KERNEL_BACKEND"] = "triton"
+    # a global-env pin an op cannot serve degrades to auto (the Mosaic
+    # paged kernel is unavailable off the TPU)
+    os.environ["PADDLE_TPU_KERNEL_BACKEND"] = "pallas_tpu"
     try:
-        name = resolve_name("decode_gather")
-        if name not in ("pallas_tpu", "xla_ref"):
+        name = resolve_name("paged_attention")
+        want = ("pallas_tpu" if jax.default_backend() == "tpu"
+                else "xla_ref")
+        if name != want:
             failures.append(
-                f"global-env fallback resolved decode_gather to {name}")
+                f"global-env fallback resolved paged_attention to {name}")
     finally:
         os.environ.pop("PADDLE_TPU_KERNEL_BACKEND", None)
     # the tuner's forced hook routes without env mutation
@@ -113,9 +112,8 @@ def _check_registry(failures):
 def _flash_impls():
     """(name, fn(q4, k4, v4, causal) -> o) for every backend whose
     kernel logic can run on this host — available ones as the registry
-    would run them, plus interpret-forced Mosaic/triton kernels on
-    hosts where they are 'unavailable' (the logic is still the thing
-    under test)."""
+    would run them (off-TPU the Mosaic backend is available in
+    interpret mode: the kernel logic is what runs either way)."""
     from . import available_backends, get_kernel
 
     avail = {b: ok for b, ok, _ in available_backends("flash_attention")}
@@ -129,15 +127,8 @@ def _flash_impls():
         # single-block kernel in which the cross-block online-softmax
         # carry — the thing under test — is dead code
         if ok:
-            # off-TPU the available Mosaic backend IS interpret mode —
-            # the kernel logic is what runs either way
             out.append((b, lambda q, k, v, c, i=impl: i.call(
                 q, k, v, causal=c, block_q=64, block_k=64)))
-        elif b == "triton":
-            out.append((b + "(interpret)",
-                        lambda q, k, v, c, i=impl: i.call(
-                            q, k, v, causal=c, block_q=64, block_k=64,
-                            interpret=True)))
     return out
 
 
@@ -194,7 +185,7 @@ def _check_oracle(failures):
                         f"{err:.2e} > {tol}")
     print("flash parity ok")
 
-    # fused CE: available backends + interpret-forced triton vs oracle
+    # fused CE: available backends vs oracle
     from . import available_backends
 
     ce_oracle = get_kernel("fused_ce", "xla_ref").impl
@@ -210,10 +201,6 @@ def _check_oracle(failures):
         if ok:
             ce_impls.append((bk, lambda x, w, y, i=impl: i.call(
                 x, w, y, **blks)))
-        elif bk == "triton":
-            ce_impls.append((bk + "(interpret)",
-                             lambda x, w, y, i=impl: i.call(
-                                 x, w, y, interpret=True, **blks)))
     for dt in (jnp.float32, jnp.bfloat16):
         dt_name = str(jnp.dtype(dt))
         n, dm, vocab = 128, 64, 512
@@ -241,18 +228,6 @@ def _check_oracle(failures):
                                     f"grad err {err:.2e} > {tol}")
     print("ce parity ok")
 
-    # decode gather: bit-exact in every dtype (it moves bits)
-    from .pallas_gather import decode_gather as pallas_decode_gather
-
-    gather_oracle = get_kernel("decode_gather", "xla_ref").impl
-    pool = jnp.asarray(rng.normal(size=(7, 4, 2, 8)), jnp.float32)
-    table = jnp.asarray(rng.integers(0, 7, (3, 5)), jnp.int32)
-    ref = gather_oracle.call(pool, table)
-    got = pallas_decode_gather(pool, table, interpret=True)
-    if not bool(jnp.array_equal(ref, got)):
-        failures.append("decode_gather pallas(interpret) not bit-exact")
-    print("gather parity ok (bit-exact)")
-
     # run-to-run bit-exactness WITHIN a backend: one compiled fn, same
     # inputs, twice -> identical bits
     import jax as _jax
@@ -271,7 +246,7 @@ def _check_oracle(failures):
 def _paged_impls():
     """(name, fn(q, pk, pv, table, pos) -> ctx) for every backend whose
     paged-attention logic can run on this host — available ones as the
-    registry would run them, plus the GPU/TPU kernels force-run in
+    registry would run them, plus the Mosaic kernel force-run in
     interpret mode (the blocked online-softmax logic is the thing under
     test, accelerator or not)."""
     from . import available_backends, get_kernel
@@ -304,13 +279,13 @@ def _paged_dense_ref(q, pool_k, pool_v, table, pos):
     to avoid), dense-mask past ``pos``, one softmax — all f32."""
     import jax.numpy as jnp
 
-    from .xla_ref import NEG_INF, decode_gather
+    from .xla_ref import NEG_INF
 
     S, NB = table.shape
     B = pool_k.shape[1]
-    dh = q.shape[-1]
-    kg = decode_gather(pool_k, table).astype(jnp.float32)
-    vg = decode_gather(pool_v, table).astype(jnp.float32)
+    h, dh = q.shape[-2:]
+    kg = pool_k[table].reshape(S, NB * B, h, dh).astype(jnp.float32)
+    vg = pool_v[table].reshape(S, NB * B, h, dh).astype(jnp.float32)
     s = jnp.einsum("swhd,sthd->swht", q.astype(jnp.float32), kg)
     s = s / jnp.sqrt(jnp.float32(dh))
     tok = jnp.arange(NB * B, dtype=jnp.int32)
@@ -385,80 +360,6 @@ def _check_paged_oracle(failures):
         if not bool(jnp.array_equal(a, b2)):
             failures.append(f"paged {name}: not bit-exact run-to-run")
     print("paged run-to-run bit-exactness ok")
-
-    # the PADDLE_TPU_PAGED_ATTN kill switch: =0 compiles the serving
-    # decode step through decode_gather (the pre-paged spelling,
-    # bit-exact with itself across compiles), =1 through the paged
-    # kernel; both spellings agree numerically
-    import paddle_tpu as pt
-    from paddle_tpu.models import transformer
-    from paddle_tpu.serving import batched_decode as _bd
-    from paddle_tpu.serving.arch import Gpt2
-
-    pt.core.unique_name.reset()
-    main, startup = pt.Program(), pt.Program()
-    with pt.program_guard(main, startup):
-        transformer.build(vocab_size=64, n_layer=1, n_head=2,
-                          d_model=32, max_len=16, dropout_rate=0.0)
-    scope = pt.core.scope.Scope()
-    pt.core.scope._scope_stack.append(scope)
-    try:
-        exe = pt.Executor()
-        exe.run(startup, scope=scope)
-        params = transformer.extract_params(program=main, scope=scope)
-    finally:
-        pt.core.scope._scope_stack.pop()
-    pdev = {k: jnp.asarray(v) for k, v in params.items()}
-    S2, NB2, B2 = 2, 4, 4
-    nb2 = 1 + S2 * NB2
-    pk = (jnp.asarray(rng.normal(size=(nb2, B2, 2, 16)) * 0.1,
-                      jnp.float32),)
-    pv = (jnp.asarray(rng.normal(size=(nb2, B2, 2, 16)) * 0.1,
-                      jnp.float32),)
-    tok = jnp.asarray([3, 5], jnp.int32)
-    t = jnp.asarray([6, 9], jnp.int32)
-    tbl2 = jnp.asarray(1 + np.arange(S2 * NB2).reshape(S2, NB2),
-                       np.int32)
-    prev = os.environ.get("PADDLE_TPU_PAGED_ATTN")
-    try:
-        outs = {}
-        for env in ("0", "1"):
-            os.environ["PADDLE_TPU_PAGED_ATTN"] = env
-            fn = _bd.make_decode_chunk(Gpt2(1, 2, 32), 2, donate=False)
-            # the compiled module keeps op metadata (source_file /
-            # named_scope op_name); the StableHLO dump does not
-            text = fn.lower(pdev, pk, pv, tok, t, tbl2).compile() \
-                     .as_text()
-            spelled = ("decode_gather" in text if env == "0"
-                       else "paged_attention" in text)
-            if not spelled:
-                failures.append(
-                    f"PADDLE_TPU_PAGED_ATTN={env}: expected spelling "
-                    f"absent from the lowered decode chunk")
-            outs[env] = fn(pdev, pk, pv, tok, t, tbl2)
-            again = fn(pdev, pk, pv, tok, t, tbl2)
-            for a, b2_ in zip(jax.tree_util.tree_leaves(outs[env]),
-                              jax.tree_util.tree_leaves(again)):
-                if not bool(jnp.array_equal(a, b2_)):
-                    failures.append(
-                        f"PADDLE_TPU_PAGED_ATTN={env}: decode chunk "
-                        f"not bit-exact across calls")
-                    break
-        # outputs are (pool_k', pool_v', last', pos', toks): greedy
-        # token equality is the spelling-equivalence bar (float pools
-        # may differ in reassociation low bits between the spellings)
-        toks0, toks1 = outs["0"][4], outs["1"][4]
-        if not bool(jnp.array_equal(toks0, toks1)):
-            failures.append(
-                "kill switch: paged vs gather decode chunks sampled "
-                "different tokens")
-    finally:
-        if prev is None:
-            os.environ.pop("PADDLE_TPU_PAGED_ATTN", None)
-        else:
-            os.environ["PADDLE_TPU_PAGED_ATTN"] = prev
-    print("kill switch ok: =0 compiles decode_gather, =1 compiles "
-          "paged_attention, same tokens")
 
 
 def _check_xla_ref_trainer(failures):

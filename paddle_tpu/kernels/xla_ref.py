@@ -2,7 +2,8 @@
 
 Shape-complete, first-class implementations of every registered op
 class: flash attention (causal/non-causal, any d_head, packed layouts,
-lse outputs), the fused CE/LSE head, and the paged decode gather.  No
+lse outputs) and the fused CE/LSE head (paged attention's block-scan
+oracle lives with its kernel, ``kernels/paged_attention.py``).  No
 ``pallas_call`` ever appears in a program routed here
 (``PADDLE_TPU_KERNEL_BACKEND=xla_ref`` runs the full GPT trainer path —
 every ``memory_optimize`` policy — with zero Pallas calls in the
@@ -44,9 +45,6 @@ ORACLE_TOL = {
     ("flash_attention", "bfloat16"): {"fwd": 2e-2, "grad": 5e-2},
     ("fused_ce", "float32"): {"fwd": 2e-4, "grad": 1e-3},
     ("fused_ce", "bfloat16"): {"fwd": 2e-2, "grad": 5e-2},
-    # a gather moves bits, it does not compute: exact in every dtype
-    ("decode_gather", "float32"): {"fwd": 0.0, "grad": 0.0},
-    ("decode_gather", "bfloat16"): {"fwd": 0.0, "grad": 0.0},
     # paged attention is inference-only (no VJP): fwd bounds match
     # flash_attention — the same blocked online-softmax reassociation
     # against the same dense-softmax reference, per block chain
@@ -304,28 +302,6 @@ def fused_softmax_ce_head_with_lse(x, w, labels, block_n=None,
     return _ce_core_lse(x, w, labels.astype(jnp.int32))
 
 
-# -- paged decode gather -----------------------------------------------------
-
-def decode_gather(pool, table):
-    """``pool [num_blocks, B, h, dh]``, ``table [S, NB]`` int32 ->
-    each slot's logical KV view ``[S, NB*B, h, dh]`` — the advanced-
-    indexing spelling (an XLA gather) that MATERIALIZES the per-slot
-    view in HBM.  Since the ``paged_attention`` op class landed this is
-    the kill-switch / oracle spelling (``PADDLE_TPU_PAGED_ATTN=0``) and
-    the parity reference the selftest checks the blocked kernels
-    against; the serving hot path streams pool blocks through
-    ``paged_attention`` instead and never builds this view.  The
-    ``named_scope`` keys HLO attribution: every op XLA fuses out of
-    this gather lands in the ``decode_gather`` class, so serving
-    benches can put a number on exactly the traffic the paged kernel
-    deletes."""
-    S, NB = table.shape
-    B = pool.shape[1]
-    with jax.named_scope("decode_gather"):
-        return pool[table].reshape(S, NB * B, pool.shape[2],
-                                   pool.shape[3])
-
-
 # -- registration ------------------------------------------------------------
 
 class _FlashXlaRef:
@@ -339,10 +315,5 @@ class _CeXlaRef:
     call_with_lse = staticmethod(fused_softmax_ce_head_with_lse)
 
 
-class _GatherXlaRef:
-    call = staticmethod(decode_gather)
-
-
 register_kernel("flash_attention", "xla_ref", _FlashXlaRef)
 register_kernel("fused_ce", "xla_ref", _CeXlaRef)
-register_kernel("decode_gather", "xla_ref", _GatherXlaRef)

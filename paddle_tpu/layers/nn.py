@@ -929,8 +929,8 @@ def softmax(x, name=None):
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
                     block_k=None, backend=None, name=None):
-    """Fused blockwise attention (registry-routed: Pallas TPU kernel,
-    triton lowering, or the pure-XLA reference — docs/kernels.md).
+    """Fused blockwise attention (registry-routed: Pallas TPU kernel
+    or the pure-XLA reference — docs/kernels.md).
     q [b, t_q, h, d], k/v [b, t_k, h, d] -> [b, t_q, h, d].
     ``block_q``/``block_k`` tune the kernel tiles (kernel defaults when
     omitted); ``backend`` pins the kernel backend for this op."""

@@ -104,7 +104,6 @@ _OP_RE = re.compile(
     r"[.\d]*\(")
 _SHAPE_RE = re.compile(r"(\w+)\[([0-9,]*)\]")
 _SRC_RE = re.compile(r'source_file="([^"]*)"')
-_OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 _CONV_LABELS_RE = re.compile(r"dim_labels=\w+_(\w+)->\w+")
@@ -182,21 +181,14 @@ def _conv_flops(result_text, operand_text, tail):
     return 2 * res[0][0] * (rhs_numel // out_f)
 
 
-def _classify(opcode, src_file, is_custom_call, target="", op_name=""):
+def _classify(opcode, src_file, is_custom_call, target=""):
     """The op class an HLO line attributes to (kernel membership wins:
     an interpreted Pallas kernel's dots belong to the kernel, not to
     the generic matmul bucket)."""
     if src_file and "paged_attention" in src_file:
         # the blocked online-softmax attention over the KV block table
-        # (kernels/paged_attention.py) — its own bucket so serving
-        # benches can A/B it against the decode_gather spelling
+        # (kernels/paged_attention.py) — its own bucket
         return "paged_attention"
-    if "decode_gather" in op_name:
-        # the PADDLE_TPU_PAGED_ATTN=0 spelling: the [S,T,h,dh] KV view
-        # materialized by pool[table] (kernels.xla_ref.decode_gather
-        # wraps it in a named_scope, so the fusions XLA carves out of
-        # the gather keep the marker in their op_name)
-        return "decode_gather"
     if src_file and ("pallas_attention" in src_file
                     or "pallas_ce" in src_file):
         return "pallas"
@@ -273,9 +265,7 @@ def attribute_hlo(text, peak_flops=None, hbm_bw=None):
         if is_cc:
             tm = re.search(r'custom_call_target="([^"]*)"', line)
             target = tm.group(1) if tm else ""
-        om = _OPNAME_RE.search(line)
-        op_name = om.group(1) if om else ""
-        cls = _classify(opcode, src_file, is_cc, target, op_name)
+        cls = _classify(opcode, src_file, is_cc, target)
 
         flops = 0
         transcendentals = 0

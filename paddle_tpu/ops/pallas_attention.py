@@ -1040,7 +1040,7 @@ def _pallas_flash_attention(q, k, v, causal=False, sm_scale=None,
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=1024,
                     block_k=1024, interpret=None, backend=None):
     """Fused attention, routed through the kernel registry
-    (docs/kernels.md): ``backend`` picks pallas_tpu | triton | xla_ref
+    (docs/kernels.md): ``backend`` picks pallas_tpu | xla_ref
     explicitly, None resolves env overrides then the platform's auto
     order.  q [b, t_q, h, d], k/v [b, t_k, h, d] -> [b, t_q, h, d];
     differentiable through every backend (each carries the same
@@ -1142,9 +1142,9 @@ def flash_attention_packed(q, k, v, n_head, causal=False, sm_scale=None,
     heads per slice — the kernels run two independent softmax states over
     the 64-lane halves, so d_head-64 models dodge the transpose tax too),
     or ``n_head == 1``.  Other widths raise; callers use
-    ``flash_attention``.  Registry-routed: the triton/xla_ref backends
-    are shape-complete here (their head split is a reshape, not a
-    Mosaic lane slice), so every head width works off the TPU path."""
+    ``flash_attention``.  Registry-routed: the xla_ref backend is
+    shape-complete here (its head split is a reshape, not a Mosaic
+    lane slice), so every head width works off the TPU path."""
     name, impl = _resolve_backend(backend)
     if name != "pallas_tpu":
         return impl.call_packed(q, k, v, n_head, causal=causal,

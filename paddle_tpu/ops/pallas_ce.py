@@ -408,7 +408,7 @@ def fused_softmax_ce_head(x, w, labels, block_n=512, block_v=1024,
     oracle backend materializes them — that is its point).
     Differentiable in x and w (custom VJP in every backend); routed
     through the kernel registry (docs/kernels.md) — ``backend`` picks
-    pallas_tpu | triton | xla_ref explicitly, None resolves env
+    pallas_tpu | xla_ref explicitly, None resolves env
     overrides then the platform auto order.
 
     Block args are UPPER bounds: the chooser shrinks them per kernel to

@@ -36,8 +36,6 @@ engine:
   between host syncs.  Attention consumes the block table DIRECTLY
   through the ``paged_attention`` op class (online softmax block by
   block — the ``[S, T, h, dh]`` gathered view never materializes).
-  ``PADDLE_TPU_PAGED_ATTN=0`` restores the ``decode_gather`` +
-  dense-softmax spelling bit-exact (the kill switch / oracle path).
 * ``_window_forward`` — a teacher-forced ``[S, W]`` WINDOW in one
   pass: per layer one ``[S*W, d]`` matmul per projection (the weights
   are read once for W tokens), all W K/V rows written through the
@@ -62,8 +60,10 @@ engine:
     every row put through the head.  The table is data, so speculative
     decode adds exactly one executable per engine, never one per ``k``.
 
-  Inside a window of ``DENSE_WINDOW`` rows or more, attention gathers
-  the chain once and attends it densely (``_paged_attention``).
+  HOW a row attends through the table (which kernel, streaming or
+  dense) is not decided here: ``_attend_through`` hands ``(q, pool,
+  table, pos)`` to ``kernels.paged_attention.attend`` and that module
+  owns the choice.
 
 Correctness discipline (unchanged from the contiguous engine): every op
 is row-wise per slot and window row, each position's K/V is written
@@ -80,87 +80,19 @@ contract"; ``tests/test_prefill_window.py`` pins K/V rows and
 first-token logits against the token steps in both dtypes).
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 
+from ..kernels import paged_attention as _paged
 from .arch import STACK_SCOPE
 
 __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
-           "make_verify_window", "PREFILL_PIECE", "DENSE_WINDOW"]
+           "make_verify_window", "PREFILL_PIECE"]
 
 # the widest window one prefill call computes; a longer suffix is
 # prefilled as consecutive pieces of this width plus one bucketed
 # remainder (PERF.md, PR 26, has the chip's comparison of 64/128/256)
 PREFILL_PIECE = 128
-
-# from this window width up attention gathers the slot's chain once and
-# attends it densely instead of streaming blocks (``_paged_attention``)
-DENSE_WINDOW = 8
-
-
-def _paged_attn_on():
-    """The ``PADDLE_TPU_PAGED_ATTN`` kill switch (default ON).  Read at
-    TRACE time, so an engine built under ``=0`` compiles the
-    gather+dense-softmax spelling verbatim — bit-exact with the
-    pre-paged-attention engine."""
-    return os.environ.get("PADDLE_TPU_PAGED_ATTN", "1").lower() not in (
-        "0", "", "false", "off", "no")
-
-
-def _gather_kv(pool, table):
-    """The block-table gather, routed through the kernel registry
-    (``decode_gather`` op class, docs/kernels.md): the XLA
-    advanced-indexing gather off-TPU, the scalar-prefetch Pallas kernel
-    on TPU.  Bit-exact across backends — a gather moves bits.
-
-    Since the ``paged_attention`` op class landed this is the
-    KILL-SWITCH / ORACLE spelling, not the fast path: attention
-    normally consumes the table directly (``_paged_attention`` below)
-    and the ``[S, T, h, dh]`` view this gather materializes exists only
-    under ``PADDLE_TPU_PAGED_ATTN=0`` (rollback) and in the reference
-    suites that pin the paged kernels' numerics against it."""
-    from ..kernels import resolve
-
-    return resolve("decode_gather").impl.call(pool, table)
-
-
-def _paged_attention(qh, pool_k, pool_v, table, pos):
-    """One layer's attention THROUGH the block table: resolve the
-    ``paged_attention`` op class (docs/kernels.md) — ``qh [S, W, h,
-    dh]``, ``pos [S, W]`` → ``[S, W, h, dh]``.
-
-    The spelling follows the window's width, seen at trace time.  A
-    NARROW window (decode's ``W = 1``, a speculative ``k + 1``) streams
-    blocks with online softmax through whatever the registry resolves,
-    with the tuned block-iteration geometry and backend of the
-    ``op=paged_attention`` cache entry when one exists (cached-mode
-    lookup: a miss never compiles).  A window of ``DENSE_WINDOW`` rows
-    or more (a prefill piece) gathers the chain ONCE and attends it
-    densely, the ``xla_ref`` spelling with one step over the whole
-    chain: W rows share one read of K and V and the scores are MXU
-    matmuls, where the streaming kernels repeat their per-block body
-    once per window row."""
-    from .. import tune
-    from ..kernels import KernelUnavailable, resolve
-
-    if qh.shape[1] >= DENSE_WINDOW:
-        return resolve("paged_attention", backend="xla_ref").impl.call(
-            qh, pool_k, pool_v, table, pos, block_step=table.shape[1])
-    T = table.shape[1] * pool_k.shape[1]
-    h, dh = qh.shape[-2], qh.shape[-1]
-    cfg = tune.paged_attention_config(T, dh, h, str(qh.dtype)) or {}
-    try:
-        ker = resolve("paged_attention", backend=cfg.get("backend"))
-    except (KernelUnavailable, ValueError):
-        # a persisted backend name this host cannot serve (a tune cache
-        # written elsewhere) degrades to auto.  Resolution compiles
-        # nothing: a kernel the compiler refuses fails later, at the
-        # engine's lower().compile(), and reaches the caller
-        ker = resolve("paged_attention")
-    return ker.impl.call(qh, pool_k, pool_v, table, pos,
-                         block_step=cfg.get("block_step"))
 
 
 def _attend_through(arch, table, blk, off, pos):
@@ -203,29 +135,9 @@ def _attend_through(arch, table, blk, off, pos):
         # ever attends)
         pk = pool_k[layer].at[b, off].set(kh)
         pv = pool_v[layer].at[b, off].set(vh)
-        q4 = qh[:, None] if step else qh
-        if _paged_attn_on():
-            # attend THROUGH the table: row j attends <= pos_j inside
-            # the paged_attention op class, the [S, T, h, dh] view never
-            # exists
-            ctx = _paged_attention(q4, pk, pv, tbl, pos4)
-        else:
-            # kill-switch spelling (PADDLE_TPU_PAGED_ATTN=0): gather
-            # each slot's logical view [S, T, h, dh] through the
-            # registry-routed decode_gather kernel, dense softmax
-            ck = _gather_kv(pk, tbl)
-            cv = _gather_kv(pv, tbl)
-            T = ck.shape[1]
-            s = jnp.einsum("swhd,sThd->swhT", q4, ck,
-                           preferred_element_type=jnp.float32)
-            s = s / jnp.sqrt(float(arch.head_dim))
-            # one causal mask covers the cached chain AND the
-            # in-window positions
-            mask = (jnp.arange(T)[None, None, None, :]
-                    <= pos4[:, :, None, None])
-            s = jnp.where(mask, s, -1e30)
-            a = jax.nn.softmax(s, axis=-1).astype(ck.dtype)
-            ctx = jnp.einsum("swhT,sThd->swhd", a, cv)
+        # attend THROUGH the table: row j attends <= pos_j inside the
+        # paged_attention op class, the [S, T, h, dh] view never exists
+        ctx = _paged.attend(qh[:, None] if step else qh, pk, pv, tbl, pos4)
         if step:
             ctx = ctx[:, 0]
         return ctx, (pool_k[:layer] + (pk,) + pool_k[layer + 1:],

@@ -191,15 +191,12 @@ class ServingEngine:
     max_len  per-slot logical KV capacity; every request needs
              ``len(prompt) + max_new_tokens <= max_len``.
     max_slots     concurrent sequences in the batched step.
-    decode_chunk  decode steps fused per device call.  ``None`` (the
-             default) consults the autotune cache (workload key
-             ``op=serving_decode``, docs/autotune.md) and falls back
-             to 4 on a miss; an explicit value always wins.
+    decode_chunk  decode steps fused per device call (tokens reach
+             the host in chunks of this many).
     min_bucket    narrowest prefill window; prompt SUFFIXES (after prefix
              reuse) pad to the nearest power-of-two multiple of it up
              to the piece width, and run as several pieces beyond it
-             (compile-count bound).  ``None`` consults the same tuned
-             entry; miss falls back to 8.
+             (compile-count bound).
     block_tokens  tokens per physical KV block (paging granularity —
              also the prefix-sharing granularity: only whole blocks are
              shared, a partial overlap forks copy-on-write).
@@ -228,9 +225,7 @@ class ServingEngine:
              (default: inferred depth / the target's ``n_head``; a
              differing head count is rejected — the draft shares the
              target's paged pool arrays).
-    spec_k   draft tokens proposed per round.  ``None`` consults the
-             tuned ``op=spec_decode`` entry (docs/autotune.md) and
-             falls back to 4; an explicit value always wins.
+    spec_k   draft tokens proposed per round.
     ttft_slo_s / e2e_slo_s   per-request latency budgets (seconds),
              overridable per request in ``submit``.  When set, every
              finished request is judged at finish time
@@ -247,12 +242,12 @@ class ServingEngine:
 
     def __init__(self, params, n_layer=None, n_head=None, d_model=None,
                  max_len=128,
-                 max_slots=8, decode_chunk=None, min_bucket=None,
+                 max_slots=8, decode_chunk=4, min_bucket=8,
                  eos_id=None, compute_dtype=None, eps=1e-5, donate=True,
                  registry=None, ttft_slo_s=None, e2e_slo_s=None,
                  block_tokens=16, cache_blocks=None, prefix_reuse=True,
                  scheduler="slo", draft_params=None, draft_n_layer=None,
-                 draft_n_head=None, spec_k=None, arch=None):
+                 draft_n_head=None, spec_k=4, arch=None):
         import jax
         import jax.numpy as jnp
 
@@ -288,14 +283,6 @@ class ServingEngine:
         if compute_dtype is None:
             compute_dtype = infer_compute_dtype(params)
         self.compute_dtype = jnp.dtype(compute_dtype)
-        # decode chunk / bucket geometry: explicit args win; defaults
-        # consult the tuned op=serving_decode entry (docs/autotune.md)
-        if decode_chunk is None or min_bucket is None:
-            cfg = self._tuned_geometry()
-            if decode_chunk is None:
-                decode_chunk = int(cfg.get("chunk", 4))
-            if min_bucket is None:
-                min_bucket = int(cfg.get("min_bucket", 8))
         if decode_chunk < 1 or min_bucket < 1:
             raise ValueError("decode_chunk and min_bucket must be >= 1")
         self.decode_chunk = int(decode_chunk)
@@ -318,9 +305,6 @@ class ServingEngine:
                 params, draft_params, arch, self.max_len,
                 draft_n_layer=draft_n_layer,
                 draft_n_head=draft_n_head)
-            if spec_k is None:
-                spec_k = int(self._tuned_spec().get(
-                    "k", _spec.DEFAULT_SPEC_K))
         self.spec_k = int(spec_k) if spec_on else None
 
         # -- paged KV state (kvcache.py): pool arrays + host accounting
@@ -382,8 +366,7 @@ class ServingEngine:
         self._decode_fn = None
         # entry-point label -> the kernel-backend selections the kernel
         # registry recorded while that executable traced (so operators
-        # can see WHICH attention spelling each compile used — paged
-        # kernel vs the PADDLE_TPU_PAGED_ATTN=0 gather fallback)
+        # can see WHICH paged-attention backend each compile used)
         self.kernel_backends = {}
         # entry-point label -> seconds its one lower().compile() took
         self.compile_seconds = {}
@@ -429,32 +412,6 @@ class ServingEngine:
                  "blocks (trash included) x block bytes",
         ).set(arch.kv_planes * num_blocks
               * arch.kv_block_bytes(self.block_tokens, itemsize))
-
-    def _tuned_geometry(self):
-        """The tuned ``op=serving_decode`` config for this engine's
-        shape, or {} (defaults apply).  Never raises — serving must
-        construct even when the tune package is unhappy."""
-        try:
-            from .. import tune
-
-            return tune.serving_decode_config(
-                self.max_len, self.d_model // self.n_head, self.n_head,
-                self.compute_dtype) or {}
-        except Exception:  # noqa: BLE001 — lookup is best-effort
-            return {}
-
-    def _tuned_spec(self):
-        """The tuned ``op=spec_decode`` config (the draft window ``k``)
-        for this engine's shape, or {} — same never-raises contract as
-        :meth:`_tuned_geometry`."""
-        try:
-            from .. import tune
-
-            return tune.spec_decode_config(
-                self.max_len, self.d_model // self.n_head, self.n_head,
-                self.compute_dtype) or {}
-        except Exception:  # noqa: BLE001 — lookup is best-effort
-            return {}
 
     @property
     def _tracer(self):
@@ -785,20 +742,12 @@ class ServingEngine:
             t0 = time.perf_counter()
             c = fn.lower(*args).compile()
             self.compile_seconds[label] = time.perf_counter() - t0
-            # which kernel spelling this executable traced with — per
-            # entry point, so operators can tell a paged-kernel compile
-            # from a PADDLE_TPU_PAGED_ATTN=0 gather compile
+            # which kernel backend this executable traced with, per
+            # entry point
             sel = _kernels.selected_backends()
             if sel:
                 self.kernel_backends[label] = sel
             box["c"] = c
-            if _bd._paged_attn_on() and "paged_attention" in sel:
-                self._reg.counter(
-                    "serving.paged_attn_compiles",
-                    help="serving executables compiled through the "
-                         "paged_attention kernel (vs the "
-                         "PADDLE_TPU_PAGED_ATTN=0 gather spelling)",
-                ).inc()
             stats = compiled_memory_stats(c)
             if stats:
                 self._reg.gauge(

@@ -38,11 +38,6 @@ is.  Speculative decoding restructures the schedule, not the math:
 Kill switch: ``PADDLE_TPU_SPEC=0`` (or ``off``/``false``) makes the
 engine ignore ``draft_params`` entirely — no validation, no extra pool
 blocks, no draft executables — bit-identical to the plain engine.
-
-The draft window ``k`` is a tuned dimension: ``tune.tune_spec_decode``
-measures candidates end-to-end and persists the winner under the
-workload key ``op=spec_decode`` (docs/autotune.md); the engine consults
-the cache when constructed without an explicit ``spec_k``.
 """
 
 import os
@@ -52,14 +47,9 @@ import numpy as np
 from . import batched_decode as _bd
 from .arch import Gpt2
 
-__all__ = ["DEFAULT_SPEC_K", "spec_enabled", "draft_depth",
+__all__ = ["spec_enabled", "draft_depth",
            "depth_draft", "validate_draft", "accept_greedy",
            "SpecState"]
-
-# hand-picked default draft window when neither the caller nor the
-# tune cache (op=spec_decode) supplies one
-DEFAULT_SPEC_K = 4
-
 
 def spec_enabled():
     """The ``PADDLE_TPU_SPEC`` kill switch: False for ``0`` / ``off`` /

@@ -29,6 +29,10 @@ traffic counts in the metrics registry (``tune.cache_hits`` /
 ``tune.cache_misses`` / ``tune.searches``) and is folded into
 ``Executor.last_step_cost``.
 
+The serving path reads none of this: ``ServingEngine`` takes its
+geometry from its arguments and ``kernels.paged_attention.attend``
+chooses its spelling from what it observes.
+
 CI: ``python -m paddle_tpu --tune-selftest`` (tools/tier1.sh).
 """
 
@@ -45,26 +49,19 @@ from .costmodel import (
     model_status, reset_model)
 from .space import (
     POLICY_ORDER, WorkloadKey, attention_candidates,
-    estimate_gpt_step_hbm, paged_attention_candidates, prune_static,
-    schedule_candidates, serving_candidates, spec_candidates)
+    estimate_gpt_step_hbm, prune_static, schedule_candidates)
 from .search import (
     PreflightRejected, flagship_dims, flagship_static_demo,
-    tune_gpt_step, tune_paged_attention, tune_serving_decode,
-    tune_spec_decode)
+    tune_gpt_step)
 
 __all__ = [
     "CACHE_SCHEMA_VERSION", "TuneCache", "cache_path",
     "geometry_fingerprint", "get_cache", "reset_cache",
     "POLICY_ORDER", "WorkloadKey", "attention_candidates",
-    "estimate_gpt_step_hbm", "paged_attention_candidates",
-    "prune_static", "schedule_candidates",
-    "serving_candidates", "spec_candidates", "PreflightRejected",
-    "flagship_dims", "flagship_static_demo", "tune_gpt_step",
-    "tune_paged_attention", "tune_serving_decode", "tune_spec_decode",
-    "tune_mode", "attention_config", "schedule_config_for",
-    "serving_decode_config", "spec_decode_config",
-    "paged_attention_config",
-    "forced_attention_config", "tune_stats",
+    "estimate_gpt_step_hbm", "prune_static", "schedule_candidates",
+    "PreflightRejected", "flagship_dims", "flagship_static_demo",
+    "tune_gpt_step", "tune_mode", "attention_config",
+    "schedule_config_for", "forced_attention_config", "tune_stats",
     "COSTMODEL_SCHEMA_VERSION", "CostModel", "costmodel_enabled",
     "costmodel_path", "fit_and_save", "fit_cost_model", "get_model",
     "model_status", "reset_model",
@@ -149,46 +146,6 @@ def schedule_config_for(seq_len, d_head, n_head, dtype):
     ``memory_optimize(policy="auto")`` and bench.py's flagship path."""
     return _cache_lookup("gpt_step", seq_len, d_head, n_head, dtype,
                          remat="auto")
-
-
-def serving_decode_config(max_len, d_head, n_head, dtype):
-    """Hot-path lookup for ``serving.ServingEngine``: the tuned decode
-    chunk size + prefill bucket geometry ``{"chunk", "min_bucket"}``
-    for one serving shape (workload key ``op=serving_decode``, keyed on
-    the slot KV capacity ``max_len``), or None — the engine keeps its
-    hand-picked defaults.  Explicit constructor arguments always win
-    (the engine only calls this when given no geometry)."""
-    if max_len is None or int(max_len) <= 0:
-        return None
-    return _cache_lookup("serving_decode", max_len, d_head, n_head,
-                         dtype, remat="-")
-
-
-def paged_attention_config(seq_len, d_head, n_head, dtype):
-    """Hot-path lookup for ``serving.batched_decode``'s paged
-    attention: the tuned ``{"backend", "block_step"}`` for one slot KV
-    capacity (workload key ``op=paged_attention``, keyed on the logical
-    capacity ``T = NB * block_tokens`` like the other serving ops), or
-    None — the kernel keeps its defaults (auto backend, one table entry
-    per scan step).  Consulted at TRACE time, so a tuned entry costs
-    one lookup per compile, never per step."""
-    if seq_len is None or int(seq_len) <= 0:
-        return None
-    return _cache_lookup("paged_attention", seq_len, d_head, n_head,
-                         dtype, remat="-")
-
-
-def spec_decode_config(max_len, d_head, n_head, dtype):
-    """Hot-path lookup for ``serving.ServingEngine``'s speculative
-    draft window: the tuned ``{"k"}`` for one serving shape (workload
-    key ``op=spec_decode``, keyed on the slot KV capacity ``max_len``
-    like ``serving_decode``), or None — the engine keeps the
-    hand-picked default.  Explicit ``spec_k`` always wins (the engine
-    only calls this when given a draft but no window)."""
-    if max_len is None or int(max_len) <= 0:
-        return None
-    return _cache_lookup("spec_decode", max_len, d_head, n_head,
-                         dtype, remat="-")
 
 
 def program_schedule_config(program):

@@ -12,14 +12,14 @@ their measured numbers::
                          "block_q": 512, "block_k": 1024, ...},
             "measured": {"median_s": 4.91, "tok_s": 120133.0, ...},
             "searched_at": 1754200000.0},
-        "op=spec_decode|t=96|dh=64|h=8|dt=float32|plat=cpu|remat=-": {
-            "config":   {"k": 4},
-            "measured": {"median_s": 0.41, "accept_rate": 0.81, ...},
-            "searched_at": 1754300000.0}}}
+        "op=flash|t=16384|dh=128|h=6|dt=bfloat16|plat=tpu|remat=-": {
+            "config":   {"block_q": 512, "block_k": 1024, ...},
+            "measured": {"median_s": 4.91, ...},
+            "searched_at": 1754200000.0}}}
 
-Ops currently cached: ``gpt_step`` (training schedule), ``flash``
-(attention kernel geometry), ``serving_decode`` (engine chunk/bucket),
-``spec_decode`` (speculative draft window k).
+Ops currently cached: ``gpt_step`` (training schedule) and ``flash``
+(attention kernel geometry).  Entries of other ops (a file written
+before the serving path stopped reading the tuner) are never looked up.
 
 Location: ``PADDLE_TPU_TUNE_CACHE`` or ``~/.cache/paddle_tpu/tuned.json``.
 

@@ -21,7 +21,6 @@ registry.
 """
 
 import contextlib
-import functools
 import os
 import time
 
@@ -31,13 +30,11 @@ from ..observability import metrics as _obs
 from ..observability import trace as _trace
 from .cache import get_cache
 from .space import (
-    POLICY_ORDER, WorkloadKey, estimate_gpt_step_hbm,
-    paged_attention_candidates, prune_static, schedule_candidates,
-    serving_candidates, spec_candidates)
+    POLICY_ORDER, WorkloadKey, estimate_gpt_step_hbm, prune_static,
+    schedule_candidates)
 
-__all__ = ["tune_gpt_step", "tune_serving_decode", "tune_spec_decode",
-           "tune_paged_attention", "flagship_static_demo",
-           "flagship_dims", "PreflightRejected"]
+__all__ = ["tune_gpt_step", "flagship_static_demo", "flagship_dims",
+           "PreflightRejected"]
 
 
 class PreflightRejected(Exception):
@@ -372,325 +369,6 @@ def tune_gpt_step(seq_len, n_layer, d_model, n_head, vocab, batch,
                                       "packed", "backend")
                if k in config},
               measured={"from": key.s})
-    cache.save()
-    tracer.instant("tune.winner", cat="tune", key=key.s, **config)
-    report.update(entry=entry, source="search")
-    return report
-
-
-def tune_serving_decode(params, n_layer, n_head, d_model, max_len,
-                        dtype=None, max_slots=4, requests=6, prompt_len=5,
-                        max_new=8, chunks=(2, 4, 8), min_buckets=(4, 8),
-                        max_measure=6, force=False, mode=None, seed=0):
-    """Search (or serve from cache) the serving engine's decode-chunk /
-    prefill-bucket geometry for one model shape — the
-    ``op=serving_decode`` tunable (docs/autotune.md "Adding a tunable
-    op").  Each candidate builds a REAL engine (scheduler/telemetry and
-    all), serves a fixed synthetic workload synchronously, and is timed
-    wall-to-wall; the winner's ``{"chunk", "min_bucket"}`` persists
-    under the workload key ``op=serving_decode|t=<max_len>|...|remat=-``
-    and ``ServingEngine`` consults it whenever the caller passes no
-    explicit geometry.  In mode "cached" (default) a miss NEVER builds
-    an engine — callers keep the hand-picked defaults."""
-    from . import tune_mode  # late: __init__ imports this module
-
-    import jax
-
-    reg = _obs.get_registry()
-    if dtype is None:
-        # key on the dtype the engine will SERVE in, or the persisted
-        # winner lands under a key the engine's lookup never hits
-        from ..models.transformer import infer_compute_dtype
-
-        dtype = str(np.dtype(infer_compute_dtype(params)))
-    key = WorkloadKey("serving_decode", max_len, d_model // n_head,
-                      n_head, dtype, jax.default_backend(), remat="-")
-    mode = mode or tune_mode()
-    report = {"key": key.s, "mode": mode, "entry": None, "source": "miss",
-              "candidates": 0, "measured": []}
-    if mode == "off":
-        report["source"] = "off"
-        return report
-    cache = get_cache()
-    hit = cache.get(key.s)
-    if hit is not None and not force:
-        reg.counter("tune.cache_hits",
-                    help="tuned-config cache lookups served").inc()
-        report.update(entry=hit, source="cache")
-        return report
-    reg.counter("tune.cache_misses",
-                help="tuned-config cache lookups missed").inc()
-    if mode != "search":
-        return report
-
-    reg.counter("tune.searches",
-                help="measured schedule searches executed").inc()
-    from ..serving import ServingEngine
-
-    cands = serving_candidates(max_len, chunks=chunks,
-                               min_buckets=min_buckets)
-    report["candidates"] = len(cands)
-    if max_measure and len(cands) > max_measure:
-        report["truncated_to"] = max_measure
-        cands = cands[:max_measure]
-    rng = np.random.default_rng(seed)
-    vocab = int(np.asarray(params["tok_emb.w"]).shape[0])
-    prompts = [rng.integers(1, vocab, (prompt_len,)).astype(np.int32)
-               for _ in range(requests)]
-    tracer = _trace.get_tracer()
-    measured = []
-    for i, cand in enumerate(cands):
-        with tracer.span("tune.search", cat="tune", key=key.s,
-                         candidate=i, **cand) as sp:
-            eng = ServingEngine(
-                params, n_layer, n_head, d_model, max_len=max_len,
-                max_slots=max_slots, decode_chunk=cand["chunk"],
-                min_bucket=cand["min_bucket"], prefix_reuse=False)
-            eng.generate_many(prompts[:1], max_new_tokens=2)  # compile
-            t0 = time.perf_counter()
-            eng.generate_many(prompts, max_new_tokens=max_new)
-            wall = time.perf_counter() - t0
-            reg.counter("tune.candidates_measured",
-                        help="schedule candidates compiled and timed").inc()
-            tok_s = requests * max_new / wall
-            rec = dict(cand, verdict="measured",
-                       median_s=round(wall, 6), tok_s=round(tok_s, 1))
-            measured.append(rec)
-            sp.set(verdict="measured", median_s=rec["median_s"])
-    report["measured"] = measured
-    if not measured:
-        report["source"] = "exhausted"
-        return report
-    win = min(measured, key=lambda m: m["median_s"])
-    config = {"chunk": win["chunk"], "min_bucket": win["min_bucket"]}
-    meas = {"median_s": win["median_s"], "tok_s": win["tok_s"],
-            "worst_median_s": max(m["median_s"] for m in measured),
-            "measured_candidates": len(measured)}
-    entry = cache.put(key.s, config, measured=meas)
-    cache.save()
-    tracer.instant("tune.winner", cat="tune", key=key.s, **config)
-    report.update(entry=entry, source="search")
-    return report
-
-
-def tune_spec_decode(params, draft_params, n_layer, n_head, d_model,
-                     max_len, dtype=None, draft_n_layer=None,
-                     max_slots=4, requests=6, prompt_len=5, max_new=8,
-                     ks=(1, 2, 3, 4, 6, 8), max_measure=5, force=False,
-                     mode=None, seed=0):
-    """Search (or serve from cache) the speculative draft window ``k``
-    for one serving shape — the ``op=spec_decode`` tunable
-    (docs/autotune.md "Adding a tunable op").  The right ``k`` is a
-    property of the WORKLOAD, not the model alone: it trades k + 1
-    cheap draft steps against one verify forward that amortizes a
-    target weight read over k + 1 positions, scaled by however often
-    this draft actually agrees with this target — so each candidate
-    builds a real speculative engine, serves a fixed synthetic
-    workload, and is timed wall-to-wall.  The winner's ``{"k"}``
-    persists under ``op=spec_decode|t=<max_len>|...|remat=-`` and
-    ``ServingEngine`` consults it when constructed with a draft but no
-    explicit ``spec_k``.  In mode "cached" (default) a miss NEVER
-    builds an engine — the hand-picked default applies."""
-    from . import tune_mode  # late: __init__ imports this module
-
-    import jax
-
-    reg = _obs.get_registry()
-    if dtype is None:
-        from ..models.transformer import infer_compute_dtype
-
-        dtype = str(np.dtype(infer_compute_dtype(params)))
-    key = WorkloadKey("spec_decode", max_len, d_model // n_head,
-                      n_head, dtype, jax.default_backend(), remat="-")
-    mode = mode or tune_mode()
-    report = {"key": key.s, "mode": mode, "entry": None, "source": "miss",
-              "candidates": 0, "measured": []}
-    if mode == "off":
-        report["source"] = "off"
-        return report
-    cache = get_cache()
-    hit = cache.get(key.s)
-    if hit is not None and not force:
-        reg.counter("tune.cache_hits",
-                    help="tuned-config cache lookups served").inc()
-        report.update(entry=hit, source="cache")
-        return report
-    reg.counter("tune.cache_misses",
-                help="tuned-config cache lookups missed").inc()
-    if mode != "search":
-        return report
-
-    reg.counter("tune.searches",
-                help="measured schedule searches executed").inc()
-    from ..serving import ServingEngine
-
-    cands = spec_candidates(max_len, ks=ks)
-    report["candidates"] = len(cands)
-    if max_measure and len(cands) > max_measure:
-        report["truncated_to"] = max_measure
-        cands = cands[:max_measure]
-    rng = np.random.default_rng(seed)
-    vocab = int(np.asarray(params["tok_emb.w"]).shape[0])
-    prompts = [rng.integers(1, vocab, (prompt_len,)).astype(np.int32)
-               for _ in range(requests)]
-    tracer = _trace.get_tracer()
-    measured = []
-    for i, cand in enumerate(cands):
-        with tracer.span("tune.search", cat="tune", key=key.s,
-                         candidate=i, **cand) as sp:
-            eng = ServingEngine(
-                params, n_layer, n_head, d_model, max_len=max_len,
-                max_slots=max_slots, prefix_reuse=False,
-                draft_params=draft_params, draft_n_layer=draft_n_layer,
-                spec_k=cand["k"])
-            eng.generate_many(prompts[:1], max_new_tokens=2)  # compile
-            t0 = time.perf_counter()
-            eng.generate_many(prompts, max_new_tokens=max_new)
-            wall = time.perf_counter() - t0
-            reg.counter("tune.candidates_measured",
-                        help="schedule candidates compiled and timed").inc()
-            tok_s = requests * max_new / wall
-            acc = (eng._spec.accepted / eng._spec.proposed
-                   if eng._spec.proposed else 0.0)
-            rec = dict(cand, verdict="measured",
-                       median_s=round(wall, 6), tok_s=round(tok_s, 1),
-                       accept_rate=round(acc, 4))
-            measured.append(rec)
-            sp.set(verdict="measured", median_s=rec["median_s"])
-    report["measured"] = measured
-    if not measured:
-        report["source"] = "exhausted"
-        return report
-    win = min(measured, key=lambda m: m["median_s"])
-    config = {"k": win["k"]}
-    meas = {"median_s": win["median_s"], "tok_s": win["tok_s"],
-            "accept_rate": win["accept_rate"],
-            "worst_median_s": max(m["median_s"] for m in measured),
-            "measured_candidates": len(measured)}
-    entry = cache.put(key.s, config, measured=meas)
-    cache.save()
-    tracer.instant("tune.winner", cat="tune", key=key.s, **config)
-    report.update(entry=entry, source="search")
-    return report
-
-
-def tune_paged_attention(n_head, d_head, max_len, block_tokens,
-                         dtype="float32", slots=8,
-                         block_steps=(1, 2, 4, 8), backends=None,
-                         max_measure=8, repeats=3, force=False,
-                         mode=None, seed=0):
-    """Search (or serve from cache) the paged-attention block-iteration
-    geometry x backend for one serving shape — the
-    ``op=paged_attention`` tunable (docs/kernels.md "The tuner picks
-    kernels").  Each candidate jits the registry call on a synthetic
-    ragged block pool of the workload geometry (worst-case chain depth
-    ``max_len / block_tokens``, per-slot positions spread across the
-    capacity — the decode-step shape, W=1) under
-    ``kernels.forced_backend`` and is timed median-of-``repeats``; the
-    winner's ``{"backend", "block_step"}`` persists under
-    ``op=paged_attention|t=<max_len>|...|remat=-`` and
-    ``serving.batched_decode`` consults it at trace time.  Unavailable
-    backends skip with the registry's reason.  In mode "cached"
-    (default) a miss NEVER compiles."""
-    from . import tune_mode  # late: __init__ imports this module
-
-    import jax
-
-    reg = _obs.get_registry()
-    key = WorkloadKey("paged_attention", max_len, d_head, n_head,
-                      str(np.dtype(dtype)), jax.default_backend(),
-                      remat="-")
-    mode = mode or tune_mode()
-    report = {"key": key.s, "mode": mode, "entry": None, "source": "miss",
-              "candidates": 0, "measured": []}
-    if mode == "off":
-        report["source"] = "off"
-        return report
-    cache = get_cache()
-    hit = cache.get(key.s)
-    if hit is not None and not force:
-        reg.counter("tune.cache_hits",
-                    help="tuned-config cache lookups served").inc()
-        report.update(entry=hit, source="cache")
-        return report
-    reg.counter("tune.cache_misses",
-                help="tuned-config cache lookups missed").inc()
-    if mode != "search":
-        return report
-
-    reg.counter("tune.searches",
-                help="measured schedule searches executed").inc()
-    import jax.numpy as jnp
-
-    from .. import kernels
-
-    B = int(block_tokens)
-    NB = max(1, int(max_len) // B)
-    if backends is None:
-        backends = tuple(
-            b for b, ok, _ in kernels.available_backends("paged_attention")
-            if ok)
-    cands = paged_attention_candidates(NB, backends=backends,
-                                       block_steps=block_steps)
-    report["candidates"] = len(cands)
-    if max_measure and len(cands) > max_measure:
-        report["truncated_to"] = max_measure
-        cands = cands[:max_measure]
-    rng = np.random.default_rng(seed)
-    S = int(slots)
-    num_blocks = 1 + S * NB
-    dt = jnp.dtype(dtype)
-    q = jnp.asarray(rng.standard_normal((S, 1, n_head, d_head)), dt)
-    pool_k = jnp.asarray(
-        rng.standard_normal((num_blocks, B, n_head, d_head)), dt)
-    pool_v = jnp.asarray(
-        rng.standard_normal((num_blocks, B, n_head, d_head)), dt)
-    table = jnp.asarray(
-        1 + np.arange(S * NB).reshape(S, NB), jnp.int32)
-    # ragged chains: per-slot live positions spread across the capacity
-    pos = jnp.asarray(
-        rng.integers(0, NB * B, (S, 1)), jnp.int32)
-    tracer = _trace.get_tracer()
-    measured = []
-    for i, cand in enumerate(cands):
-        with tracer.span("tune.search", cat="tune", key=key.s,
-                         candidate=i, **cand) as sp:
-            with kernels.forced_backend(cand["backend"],
-                                        op_class="paged_attention"):
-                impl = kernels.resolve("paged_attention").impl
-                fn = jax.jit(functools.partial(
-                    impl.call, block_step=cand["block_step"]))
-                try:
-                    jax.block_until_ready(
-                        fn(q, pool_k, pool_v, table, pos))  # compile
-                except Exception as e:  # noqa: BLE001
-                    rec = dict(cand, verdict="failed", error=str(e))
-                    measured.append(rec)
-                    sp.set(verdict="failed")
-                    continue
-                walls = []
-                for _ in range(int(repeats)):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(
-                        fn(q, pool_k, pool_v, table, pos))
-                    walls.append(time.perf_counter() - t0)
-            reg.counter("tune.candidates_measured",
-                        help="schedule candidates compiled and timed").inc()
-            rec = dict(cand, verdict="measured",
-                       median_s=round(float(np.median(walls)), 6))
-            measured.append(rec)
-            sp.set(verdict="measured", median_s=rec["median_s"])
-    report["measured"] = measured
-    timed = [m for m in measured if m["verdict"] == "measured"]
-    if not timed:
-        report["source"] = "exhausted"
-        return report
-    win = min(timed, key=lambda m: m["median_s"])
-    config = {"backend": win["backend"], "block_step": win["block_step"]}
-    meas = {"median_s": win["median_s"],
-            "worst_median_s": max(m["median_s"] for m in timed),
-            "measured_candidates": len(timed)}
-    entry = cache.put(key.s, config, measured=meas)
     cache.save()
     tracer.instant("tune.winner", cat="tune", key=key.s, **config)
     report.update(entry=entry, source="search")

@@ -32,7 +32,7 @@ from ..ops.pallas_attention import (
 
 __all__ = [
     "WorkloadKey", "attention_candidates", "schedule_candidates",
-    "serving_candidates", "spec_candidates", "prune_static",
+    "prune_static",
     "estimate_gpt_step_hbm", "POLICY_ORDER",
 ]
 
@@ -154,25 +154,6 @@ def attention_candidates(seq_len, d_head, n_head, block_caps=None,
     for b in backends:
         if b == "pallas_tpu":
             out.extend(dict(g, backend=str(b)) for g in geo)
-        elif b == "triton":
-            # the triton lowering clamps blocks to its MAX_BLOCK=128
-            # SRAM tiles and ignores diag_w/packed (it masks every
-            # visited block; packed is a reshape) — candidates above
-            # the clamp would be measured as DUPLICATE kernels and
-            # VMEM-scored for tiles they never allocate, so the
-            # geometry cross is generated at the clamped caps and
-            # deduped
-            caps = tuple(min(int(c), 128)
-                         for c in (block_caps or (256, 512, 1024, 2048)))
-            seen = set()
-            for bq in _block_choices(seq_len, caps):
-                for bk in _block_choices(seq_len, caps):
-                    if (bq, bk) in seen:
-                        continue
-                    seen.add((bq, bk))
-                    out.append({"block_q": bq, "block_k": bk,
-                                "diag_w": None, "packed": None,
-                                "backend": "triton"})
         else:
             # geometry-free backend: one candidate, default blocks so
             # downstream consumers (program build) still have values
@@ -223,72 +204,6 @@ def schedule_candidates(seq_len, d_head, n_head, block_caps=None,
                             c["grad_rs"] = bool(rs)
                         out.append(c)
     return out
-
-
-def serving_candidates(max_len, chunks=(2, 4, 8, 16, 32),
-                       min_buckets=(4, 8, 16)):
-    """The ``op="serving_decode"`` candidate list: the serving engine's
-    decode chunk size x smallest prefill bucket —
-    ``{"chunk", "min_bucket"}`` dicts (docs/autotune.md "Adding a
-    tunable op").  The static prune is pure arithmetic: a chunk larger
-    than the slot capacity wastes whole device calls on any request
-    (every emission past ``max_len`` is discarded), and a min bucket
-    beyond ``max_len`` cannot exist, so neither ever compiles."""
-    out = []
-    for c in chunks:
-        if not 1 <= int(c) <= max_len:
-            continue
-        for b in min_buckets:
-            if 1 <= int(b) <= max_len:
-                out.append({"chunk": int(c), "min_bucket": int(b)})
-    return out
-
-
-def paged_attention_candidates(num_table_blocks,
-                               backends=("xla_ref", "pallas_tpu",
-                                         "triton"),
-                               block_steps=(1, 2, 4, 8)):
-    """The ``op="paged_attention"`` candidate list: block-iteration
-    geometry x registry backend — ``{"backend", "block_step"}`` dicts
-    (docs/kernels.md, docs/autotune.md "Adding a tunable op").
-
-    ``block_step`` is how many table entries the ``xla_ref`` block scan
-    consumes per step (``[S, block_step*B, h, dh]`` in flight): larger
-    steps amortize per-iteration overhead against a bigger live tile —
-    measured, not derived.  The ``pallas_tpu`` and ``triton`` lowerings
-    fix their own iteration shape (one physical block per sequential
-    grid step / per ``fori_loop`` iteration), so like the geometry-free
-    backends in :func:`attention_candidates` each contributes ONE
-    candidate with ``block_step=None``.  The static prune is pure
-    arithmetic: a step beyond the chain length degenerates to the full
-    gather this op class exists to kill."""
-    out = []
-    nb = max(1, int(num_table_blocks))
-    for b in backends:
-        if b == "xla_ref":
-            seen = set()
-            for bs in block_steps:
-                bs = max(1, min(int(bs), nb))
-                if bs in seen:
-                    continue
-                seen.add(bs)
-                out.append({"backend": "xla_ref", "block_step": bs})
-        else:
-            out.append({"backend": str(b), "block_step": None})
-    return out
-
-
-def spec_candidates(max_len, ks=(1, 2, 3, 4, 6, 8)):
-    """The ``op="spec_decode"`` candidate list: the speculative draft
-    window ``k`` — ``{"k"}`` dicts (docs/autotune.md "Adding a tunable
-    op").  The sweet spot balances draft overhead (k + 1 cheap steps)
-    against verify amortization (one target read scores k + 1
-    positions) and scales with the workload's acceptance rate, so it
-    is measured, not derived.  The static prune is pure arithmetic: a
-    window of ``max_len`` or more can never commit fully (a request
-    always holds at least one prompt token), so it only wastes draft
-    steps."""
-    return [{"k": int(k)} for k in ks if 1 <= int(k) < max_len]
 
 
 def _vmem_bytes(cand, d_head, n_head, dtype_size=2):
@@ -370,7 +285,7 @@ def prune_static(seq_len, d_head, n_head, candidates, dtype_size=2,
       arithmetic alone, before any compile."""
     scored, pruned, passthrough = [], [], []
     for c in candidates:
-        if c.get("backend") not in (None, "pallas_tpu", "triton"):
+        if c.get("backend") not in (None, "pallas_tpu"):
             # geometry-free backend candidate (xla_ref): the VMEM and
             # block-schedule roofline models describe the Pallas
             # schedules, not XLA's own tiling — only the HBM bound

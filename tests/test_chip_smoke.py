@@ -143,7 +143,6 @@ def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry(monkeypatch):
     from paddle_tpu import tune
     from paddle_tpu.ops import pallas_attention
     from paddle_tpu.kernels.paged_attention import paged_attention_pallas
-    from paddle_tpu.kernels.pallas_gather import decode_gather
     from paddle_tpu.ops.pallas_attention import (
         _pallas_flash_attention_packed)
     from paddle_tpu.ops.pallas_ce import _pallas_ce
@@ -180,7 +179,7 @@ def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry(monkeypatch):
     pool = ((1 + chip_smoke.SLOTS * nb + 2 * nb, chip_smoke.BLOCK_TOKENS,
              h, dm // h), bf16)
     # decode, a verify window, and a prefill piece too narrow to attend
-    # densely (batched_decode.DENSE_WINDOW)
+    # densely (kernels.paged_attention.DENSE_WINDOW)
     for slots, width in ((chip_smoke.SLOTS, 1), (chip_smoke.SLOTS, 4),
                          (1, 4)):
         _lowers_for_tpu(
@@ -188,9 +187,6 @@ def test_path_kernels_cross_lower_for_tpu_at_smoke_geometry(monkeypatch):
             ((slots, width, h, dm // h), bf16), pool, pool,
             ((slots, nb), i32), ((slots, width), i32),
             names=("paged_attention",))
-    _lowers_for_tpu(lambda p, tb: decode_gather(p, tb, interpret=False),
-                    pool, ((chip_smoke.SLOTS, nb), i32),
-                    names=("decode_gather",))
 
 
 @pytest.mark.parametrize("width", [4, 16, 128])
@@ -201,6 +197,7 @@ def test_prefill_cross_lowers_for_tpu_dense_from_the_wide_window_up(
     ``DENSE_WINDOW`` rows or more holds no Mosaic call at all (the chain
     is gathered once and attended on the MXU), a narrower one holds the
     streaming kernel once per layer."""
+    from paddle_tpu.kernels.paged_attention import DENSE_WINDOW
     from paddle_tpu.serving import batched_decode as _bd
     from paddle_tpu.serving.arch import Gpt2
 
@@ -231,7 +228,7 @@ def test_prefill_cross_lowers_for_tpu_dense_from_the_wide_window_up(
                     scalar, scalar).lower(
         lowering_platforms=("tpu",)).as_text()
     assert text.count("@tpu_custom_call") == (
-        0 if width >= _bd.DENSE_WINDOW else n_layer)
+        0 if width >= DENSE_WINDOW else n_layer)
 
 
 @pytest.mark.parametrize("recipe", ["fsdp", "tp"])

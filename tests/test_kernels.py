@@ -5,8 +5,8 @@ Oracle contract: every registered backend AVAILABLE on this host is
 compared against the ``xla_ref`` reference within the documented
 ``ORACLE_TOL`` bounds (f32 + bf16, causal + non-causal, d_head 64/128,
 grads through the custom-vjp); unavailable backends SKIP with the
-registry's reason.  The GPU (triton) kernels additionally run
-interpret-forced so their logic is covered on CPU-only CI.  Within a
+registry's reason.  The Mosaic paged kernel additionally runs
+interpret-forced so its logic is covered on CPU-only CI.  Within a
 backend the contract is bit-exact run-to-run.
 
 Paged-attention contract: every backend of the ``paged_attention`` op
@@ -14,6 +14,9 @@ class matches an independent dense gather+masked-softmax spelling over
 ragged block chains (CoW fork, trash-padded tail, garbage trash block)
 for W=1 decode and W>1 verify windows; tokens past ``pos`` and the
 trash block are provably inert (corruption leaves output bit-equal).
+The one entry point the serving step calls
+(``kernels.paged_attention.attend``) chooses dense or streaming by the
+window's width and nothing else.
 
 Registry contract: precedence explicit arg > per-op env > global env >
 auto; unknown backends raise ValueError; explicitly requested
@@ -100,46 +103,6 @@ def test_flash_oracle_grads_through_custom_vjp(backend, dtype):
         assert _rel_err(a, r) <= tol
 
 
-def test_flash_triton_interpret_covers_kernel_logic():
-    """On hosts with no GPU the triton backend skips in the registry —
-    but its kernel LOGIC still runs under interpret mode, packed +
-    with_lse + dlse grads included."""
-    impl = get_kernel("flash_attention", "triton").impl
-    oracle = get_kernel("flash_attention", "xla_ref").impl
-    q, k, v = _qkv(jnp.float32, 64, t=64)
-    assert _rel_err(
-        impl.call(q, k, v, causal=True, block_q=32, block_k=32,
-                  interpret=True),
-        oracle.call(q, k, v, causal=True)) <= oracle_tol(
-            "flash_attention", "float32", "fwd")
-    o_t, lse_t = impl.call_with_lse(q, k, v, causal=True,
-                                    interpret=True)
-    o_r, lse_r = oracle.call_with_lse(q, k, v, causal=True)
-    assert _rel_err(lse_t, lse_r) <= 1e-4
-    wgt = jnp.asarray(np.random.default_rng(2).normal(size=q.shape),
-                      jnp.float32)
-
-    def lse_loss(fn, **kw):
-        def f(q, k, v):
-            o, lse = fn(q, k, v, causal=True, **kw)
-            return jnp.sum(o * wgt) + 0.1 * jnp.sum(lse)
-        return f
-
-    gt = jax.grad(lse_loss(impl.call_with_lse, interpret=True),
-                  (0, 1, 2))(q, k, v)
-    gr = jax.grad(lse_loss(oracle.call_with_lse), (0, 1, 2))(q, k, v)
-    for a, r in zip(gt, gr):
-        assert _rel_err(a, r) <= oracle_tol(
-            "flash_attention", "float32", "grad")
-    # packed layout (any head width on the triton path)
-    b, t, h, d = q.shape[0], q.shape[1], q.shape[2], q.shape[3]
-    q2, k2, v2 = (x.reshape(b, t, h * d) for x in (q, k, v))
-    assert _rel_err(
-        impl.call_packed(q2, k2, v2, h, causal=True, interpret=True),
-        oracle.call_packed(q2, k2, v2, h, causal=True)) <= oracle_tol(
-            "flash_attention", "float32", "fwd")
-
-
 @pytest.mark.parametrize("backend", kernels.BACKENDS)
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_ce_oracle_parity_and_grads(backend, dtype):
@@ -166,43 +129,6 @@ def test_ce_oracle_parity_and_grads(backend, dtype):
     tol = oracle_tol("fused_ce", dtype, "grad")
     for a, r in zip(got, ref):
         assert _rel_err(a, r) <= tol
-
-
-def test_ce_triton_interpret_with_lse_grads():
-    impl = get_kernel("fused_ce", "triton").impl
-    oracle = get_kernel("fused_ce", "xla_ref").impl
-    rng = np.random.default_rng(13)
-    n, d, vocab = 64, 32, 128
-    x = jnp.asarray(rng.normal(size=(n, d)) * 0.3, jnp.float32)
-    w = jnp.asarray(rng.normal(size=(d, vocab)) * 0.05, jnp.float32)
-    y = jnp.asarray(rng.integers(0, vocab, (n,)), jnp.int32)
-    gvec = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
-
-    def ml(fn, **kw):
-        def f(x, w):
-            loss, lse = fn(x, w, y, **kw)
-            return jnp.sum(loss * gvec) + 0.1 * jnp.sum(lse)
-        return f
-
-    got = jax.grad(ml(impl.call_with_lse, interpret=True), (0, 1))(x, w)
-    ref = jax.grad(ml(oracle.call_with_lse), (0, 1))(x, w)
-    for a, r in zip(got, ref):
-        assert _rel_err(a, r) <= oracle_tol("fused_ce", "float32",
-                                            "grad")
-
-
-def test_decode_gather_bit_exact_across_backends():
-    from paddle_tpu.kernels.pallas_gather import decode_gather
-
-    oracle = get_kernel("decode_gather", "xla_ref").impl
-    rng = np.random.default_rng(3)
-    for dt in (jnp.float32, jnp.bfloat16):
-        pool = jnp.asarray(rng.normal(size=(9, 4, 2, 8)), dt)
-        table = jnp.asarray(rng.integers(0, 9, (3, 6)), jnp.int32)
-        ref = oracle.call(pool, table)
-        got = decode_gather(pool, table, interpret=True)
-        assert bool(jnp.array_equal(ref, got))
-        assert ref.shape == (3, 24, 2, 8)
 
 
 # -- paged attention oracle suite --------------------------------------------
@@ -232,12 +158,12 @@ def _paged_case(dt, w=1, seed=11):
 
 
 def _paged_dense(q, pool_k, pool_v, table, pos):
-    """Independent spelling: the decode_gather oracle followed by one
-    dense masked softmax — exactly the materialization the paged op
-    class exists to kill."""
-    gather = get_kernel("decode_gather", "xla_ref").impl.call
-    kb = gather(pool_k, table)
-    vb = gather(pool_v, table)
+    """Independent spelling: each slot's logical view gathered inline
+    (``pool[table]``) followed by one dense masked softmax — exactly
+    the materialization the paged op class exists to kill."""
+    S, NB = table.shape
+    kb = pool_k[table].reshape(S, NB * pool_k.shape[1], *pool_k.shape[2:])
+    vb = pool_v[table].reshape(S, NB * pool_v.shape[1], *pool_v.shape[2:])
     s = jnp.einsum("swhd,sthd->swht", q, kb,
                    preferred_element_type=jnp.float32)
     s = s * (1.0 / float(np.sqrt(q.shape[-1])))
@@ -265,10 +191,10 @@ def test_paged_oracle_parity(backend, dtype, w):
         "paged_attention", dtype, "fwd")
 
 
-@pytest.mark.parametrize("backend", ["pallas_tpu", "triton"])
+@pytest.mark.parametrize("backend", ["pallas_tpu"])
 def test_paged_interpret_covers_kernel_logic(backend):
-    """The TPU grid and GPU fori_loop lowerings run interpret-forced so
-    their block-streaming logic is covered on CPU-only CI."""
+    """The Mosaic kernel runs interpret-forced so its block-streaming
+    logic is covered on CPU-only CI."""
     impl = get_kernel("paged_attention", backend).impl
     q, pk, pv, tbl, pos = _paged_case(jnp.float32, w=2)
     assert _rel_err(
@@ -402,6 +328,87 @@ def test_paged_mosaic_never_touches_what_it_need_not_visit(case, dtype, h):
     assert bool(jnp.array_equal(base[live], again[live]))
 
 
+def _primitive_counts(jaxpr, counts=None):
+    """Primitive name -> occurrences, sub-jaxprs (scan and while bodies,
+    pjit) included."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitive_counts(sub, counts)
+    return counts
+
+
+def _entry_case(w, seed=7):
+    """A ragged table with a dead slot under a ``w``-wide window: S = 4
+    slots of NB = 8 blocks of B = 4 tokens (T = 32); each live slot owns
+    the entries its window reaches and trash entries behind them, slot
+    1 is dead (row of trash, ``pos = -1``), the trash block is garbage."""
+    rng = np.random.default_rng(seed)
+    S, NB, B, h, dh = 4, 8, 4, 2, 16
+    base = [3, None, 9, 15]
+    shape = (1 + S * NB, B, h, dh)
+    pool_k = jnp.asarray(rng.normal(size=shape) * 0.5, jnp.float32)
+    pool_v = jnp.asarray(rng.normal(size=shape) * 0.5, jnp.float32)
+    pool_k, pool_v = pool_k.at[0].set(1e3), pool_v.at[0].set(1e3)
+    table = np.zeros((S, NB), np.int32)
+    pos = np.full((S, w), -1, np.int32)
+    for s, b in enumerate(base):
+        if b is None:
+            continue
+        pos[s] = b + np.arange(w)
+        n = (b + w - 1) // B + 1
+        table[s, :n] = 1 + s * NB + np.arange(n)
+    live = np.array([b is not None for b in base])
+    q = jnp.asarray(rng.normal(size=(S, w, h, dh)) * 0.5, jnp.float32)
+    return q, pool_k, pool_v, jnp.asarray(table), jnp.asarray(pos), live
+
+
+@pytest.mark.parametrize("w", [1, 4, 8, 16])
+def test_attend_entry_point_chooses_by_window_width(w, monkeypatch):
+    """``kernels.paged_attention.attend``, the one call the serving step
+    makes, decides by the window's width alone: from ``DENSE_WINDOW``
+    rows up it gathers the chain ONCE (one gather of K, one of V, no
+    loop, no ``pallas_call``: the ``xla_ref`` spelling with one step
+    over the whole chain), narrower it streams blocks through the
+    backend the registry resolves.  The Mosaic backend is made servable
+    here (available, interpreted) so the narrow side runs the kernel."""
+    import functools
+
+    from paddle_tpu.kernels import paged_attention as pa
+
+    mosaic = get_kernel("paged_attention", "pallas_tpu")
+    monkeypatch.setattr(mosaic, "_available", lambda: (True, ""))
+    monkeypatch.setattr(mosaic.impl, "call", staticmethod(functools.partial(
+        pa.paged_attention_pallas, interpret=True)))
+    q, pk, pv, tbl, pos, live = _entry_case(w)
+
+    kernels.reset_selected()
+    counts = _primitive_counts(
+        jax.make_jaxpr(pa.attend)(q, pk, pv, tbl, pos).jaxpr)
+    dense = w >= pa.DENSE_WINDOW
+    assert kernels.selected_backends() == {
+        "paged_attention": "xla_ref" if dense else "pallas_tpu"}
+    if dense:
+        assert counts.get("gather") == 2, counts
+        assert not {"pallas_call", "scan", "while"} & set(counts), counts
+    else:
+        assert counts.get("pallas_call") == 1, counts
+        assert "gather" not in counts, counts
+
+    got = pa.attend(q, pk, pv, tbl, pos)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    assert _rel_err(got[live], _paged_dense(q, pk, pv, tbl, pos)[live]) \
+        <= oracle_tol("paged_attention", "float32", "fwd")
+    assert bool(jnp.all(jnp.isfinite(got)))
+    if not dense:
+        # the Mosaic kernel fetches nothing for a dead slot
+        assert not np.asarray(got)[~live].any()
+
+
 @pytest.mark.parametrize("backend", ["pallas_tpu", "xla_ref"])
 def test_bit_exact_run_to_run_within_backend(backend):
     impl = _impl_or_skip("flash_attention", backend)
@@ -436,21 +443,27 @@ def test_unknown_backend_raises():
             pass
 
 
+def _off_tpu_only():
+    """The registered-but-unavailable backend of the registry unit
+    suite is the Mosaic paged kernel off the TPU."""
+    if get_kernel("paged_attention", "pallas_tpu").availability()[0]:
+        pytest.skip("pallas_tpu paged attention is available here")
+
+
 def test_unavailable_backend_raises_with_reason():
-    unavailable = [b for b, ok, _ in
-                   available_backends("flash_attention") if not ok]
-    if not unavailable:
-        pytest.skip("every flash backend is available on this host")
+    _off_tpu_only()
     with pytest.raises(KernelUnavailable) as ei:
-        resolve_name("flash_attention", unavailable[0])
+        resolve_name("paged_attention", "pallas_tpu")
     assert ei.value.reason
 
 
 def test_global_env_fallback_to_auto(monkeypatch):
-    # triton registers no decode_gather anywhere: a fleet-wide triton
-    # pin must degrade that op to auto instead of crashing serving
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_BACKEND", "triton")
-    assert resolve_name("decode_gather") in ("pallas_tpu", "xla_ref")
+    # off the TPU the Mosaic paged kernel is unavailable: a fleet-wide
+    # pallas_tpu pin must degrade that op to auto instead of crashing
+    # serving
+    _off_tpu_only()
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_BACKEND", "pallas_tpu")
+    assert resolve_name("paged_attention") == "xla_ref"
 
 
 def test_global_env_fallback_counted_once_per_resolution(monkeypatch):
@@ -467,21 +480,39 @@ def test_global_env_fallback_counted_once_per_resolution(monkeypatch):
     def count():
         return int(reg.value("kernels.env_fallbacks") or 0)
 
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_BACKEND", "triton")
+    _off_tpu_only()
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_BACKEND", "pallas_tpu")
     c0 = count()
-    assert resolve_name("decode_gather") in ("pallas_tpu", "xla_ref")
+    assert resolve_name("paged_attention") == "xla_ref"
     assert count() == c0 + 1
-    assert resolve_name("decode_gather") in ("pallas_tpu", "xla_ref")
+    assert resolve_name("paged_attention") == "xla_ref"
     assert count() == c0 + 2
     # a pin the op CAN serve resolves directly: no fallback counted
     monkeypatch.setenv("PADDLE_TPU_KERNEL_BACKEND", "xla_ref")
-    assert resolve_name("decode_gather") == "xla_ref"
+    assert resolve_name("paged_attention") == "xla_ref"
     assert count() == c0 + 2
     # strict sources raise instead of degrading: still no count
     monkeypatch.delenv("PADDLE_TPU_KERNEL_BACKEND")
     with pytest.raises(KernelUnavailable):
-        resolve_name("decode_gather", "triton")
+        resolve_name("paged_attention", "pallas_tpu")
     assert count() == c0 + 2
+
+
+def test_two_backends_three_op_classes_and_any_platform_is_served():
+    """What the registry holds since the GPU lowerings and the gather op
+    class went: two backends, three op classes, an auto order for the
+    TPU and the CPU; a platform with no order of its own is served by
+    the oracle for every op class."""
+    assert kernels.BACKENDS == ("pallas_tpu", "xla_ref")
+    assert sorted(kernels.registered_op_classes()) == [
+        "flash_attention", "fused_ce", "paged_attention"]
+    assert set(kernels.AUTO_ORDER) == {"tpu", "cpu"}
+    for op in kernels.registered_op_classes():
+        assert {b for b, _, _ in available_backends(op)} == set(
+            kernels.BACKENDS)
+        assert resolve_name(op, platform="gpu") == "xla_ref"
+    with pytest.raises(ValueError, match="unknown kernel backend"):
+        resolve_name("flash_attention", "triton")
 
 
 def test_forced_backend_scopes_and_restores():
@@ -679,22 +710,16 @@ def test_tune_search_measures_backend_candidate(tmp_path, monkeypatch):
             seq_len=32, n_layer=1, d_model=32, n_head=2, vocab=61,
             batch=4, dtype="float32", steps=1, warmup=0, repeats=1,
             block_caps=(32,), policies=("none",), accums=(1,),
-            backends=("xla_ref", "triton"), max_measure=3,
+            backends=("xla_ref", "pallas_tpu"), max_measure=3,
             mode="search", force=True)
         assert rep["source"] == "search", rep
         measured = [m for m in rep["measured"]
                     if m.get("verdict") == "measured"]
-        assert any(m.get("backend") == "xla_ref" for m in measured)
-        if jax.default_backend() not in ("gpu", "cuda", "rocm"):
-            # a triton REQUEST on a GPU-less host measures the auto
-            # fallback — the record and any winner must carry the
-            # backend that actually ran, never the unavailable request
-            tr = [m for m in measured
-                  if m.get("backend_requested") == "triton"]
-            assert tr and all(m["backend"] != "triton" for m in tr), (
-                measured)
-        assert rep["entry"]["config"].get("backend") not in (None,
-                                                             "triton")
+        # every candidate's record carries the backend that ran
+        assert {m.get("backend") for m in measured} == {"xla_ref",
+                                                        "pallas_tpu"}
+        assert rep["entry"]["config"].get("backend") in ("xla_ref",
+                                                         "pallas_tpu")
     finally:
         reset_cache()
 
@@ -712,54 +737,3 @@ def test_truncate_survivors_keeps_every_backend():
     report2 = {}
     same = _truncate_survivors(list(survivors), 10, report2)
     assert len(same) == 6 and "truncated_to" not in report2
-
-
-def test_paged_attention_candidates_geometry():
-    from paddle_tpu.tune.space import paged_attention_candidates
-
-    cands = paged_attention_candidates(3)
-    xr = [c for c in cands if c["backend"] == "xla_ref"]
-    # the default steps clamp to the 3-block chain and dedupe:
-    # (1, 2, 4, 8) -> (1, 2, 3)
-    assert sorted(c["block_step"] for c in xr) == [1, 2, 3]
-    fixed = [c for c in cands if c["backend"] != "xla_ref"]
-    # the TPU/GPU lowerings fix their own iteration shape: one
-    # candidate each, no geometry cross
-    assert {c["backend"] for c in fixed} == {"pallas_tpu", "triton"}
-    assert all(c["block_step"] is None for c in fixed)
-
-
-def test_tune_paged_attention_search_and_hot_path_lookup(tmp_path,
-                                                        monkeypatch):
-    """op=paged_attention end to end: a search measures xla_ref
-    block-step candidates on a synthetic ragged pool, persists the
-    winner, and ``tune.paged_attention_config`` (the lookup
-    ``serving.batched_decode`` consults at trace time) serves it from a
-    fresh cache read."""
-    from paddle_tpu import tune
-    from paddle_tpu.tune import reset_cache
-    from paddle_tpu.tune.search import tune_paged_attention
-
-    monkeypatch.setenv("PADDLE_TPU_TUNE_CACHE",
-                       str(tmp_path / "tuned.json"))
-    monkeypatch.setenv("PADDLE_TPU_TUNE", "search")
-    reset_cache()
-    try:
-        rep = tune_paged_attention(
-            n_head=2, d_head=16, max_len=16, block_tokens=4, slots=2,
-            block_steps=(1, 2), backends=("xla_ref",), max_measure=4,
-            repeats=1, force=True, mode="search")
-        assert rep["source"] == "search", rep
-        measured = [m for m in rep["measured"]
-                    if m.get("verdict") == "measured"]
-        assert len(measured) == 2
-        cfg = rep["entry"]["config"]
-        assert cfg["backend"] == "xla_ref"
-        assert cfg["block_step"] in (1, 2)
-        reset_cache()   # force a disk read: the entry persisted
-        got = tune.paged_attention_config(16, 16, 2, "float32")
-        assert got == cfg
-        # cached mode on a MISS never compiles (and never invents)
-        assert tune.paged_attention_config(999, 16, 2, "float32") is None
-    finally:
-        reset_cache()

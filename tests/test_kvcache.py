@@ -1,12 +1,10 @@
 """Paged prefix-reuse KV cache (paddle_tpu/serving/kvcache.py) — block
 pool refcount lifecycle, prefix-trie match/insert/copy-on-write fork,
 LRU eviction under capacity pressure, and the engine-level bit-exact
-served-vs-single-stream identity parameterized over prefix reuse on/off
-and f32/bf16.  The slow tail additionally proves the
-``PADDLE_TPU_PAGED_ATTN`` kill switch: the paged_attention kernel and
-the decode_gather + dense-softmax spelling serve bit-identical tokens,
-including through the speculative verify window.  All on the CPU
-backend (conftest), tiny model shapes."""
+served-vs-single-stream contract parameterized over prefix reuse on/off
+and f32/bf16 (docs/serving.md "Numerics contract": token identity in
+f32, the float32-reference margin in bf16).  All on the CPU backend
+(conftest), tiny model shapes."""
 
 import numpy as np
 import pytest
@@ -205,14 +203,48 @@ def fresh_serving_metrics():
     yield
 
 
+# What a bf16 engine may differ by from the float32 reference on the
+# same weights: the gap between a served token's reference logit and the
+# reference maximum at its position.  Measured on this model over twelve
+# runs (prompt seeds 7-12, reuse on and off; PR 30): the worst sound gap
+# is 0.0063, one near-tie of twenty (the reference's own top two lie
+# 0.0063 apart there; its closest pair on any served path is 0.0035
+# apart) that bf16 rounding resolves the other way, after which the
+# streams agree again; every other served token is the reference's
+# argmax.  A token drawn at random lies a median 1.9-2.0 under the
+# maximum.  0.05 is eight times the worst sound gap and a fortieth of a
+# random token's: it passes a near-tie and fails any other token.
+BF16_LOGIT_MARGIN = 0.05
+
+
+def _reference_gap(params, prompt, served):
+    """The worst gap, over the generated tokens of ``served``, between
+    the token's logit and the maximum under the float32 reference
+    forward of the same weights, teacher-forced on the served tokens
+    (the check of ``chipbench/runners/serve.py``)."""
+    import jax.numpy as jnp
+
+    _, logits = transformer.generate(
+        params, served[None], max_len=len(served), n_layer=NL, n_head=NH,
+        d_model=DM, compute_dtype=jnp.float32)
+    at = np.asarray(logits)[0][len(prompt) - 1:len(served) - 1]
+    new = served[len(prompt):]
+    return float((at.max(axis=-1) - at[np.arange(len(new)), new]).max())
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("reuse", [True, False])
 def test_served_equals_single_stream_with_prefix_traffic(dtype, reuse):
-    """The acceptance bar, now over the PAGED cache: shared-prefix
-    traffic (full-block hits AND copy-on-write forks when reuse is on)
-    through the batched engine produces exactly the tokens of running
-    each request ALONE through transformer.generate — greedy, same
-    weights, prefix reuse on or off, f32 and bf16."""
+    """The acceptance bar over the PAGED cache, as docs/serving.md
+    "Numerics contract" states it: shared-prefix traffic (full-block
+    hits AND copy-on-write forks when reuse is on) through the batched
+    engine produces, in f32, exactly the tokens of running each request
+    ALONE through transformer.generate (greedy, same weights, prefix
+    reuse on or off); in bf16 prefill is a window forward whose
+    roundings may differ from the token steps' in the last place, so
+    every served token's float32-reference logit lies within
+    ``BF16_LOGIT_MARGIN`` of the reference maximum, teacher-forced on
+    the served tokens (a near-tie may resolve either way)."""
     params = _make_params(dtype)
     if dtype == "bfloat16":
         import jax.numpy as jnp
@@ -238,6 +270,12 @@ def test_served_equals_single_stream_with_prefix_traffic(dtype, reuse):
     outs = eng.generate_many(prompts[:2], max_new_tokens=8)
     outs += eng.generate_many(prompts[2:], max_new_tokens=8)
     for p, o in zip(prompts, outs):
+        o = np.asarray(o)
+        assert o.shape == (len(p) + 8,)
+        if dtype == "bfloat16":
+            np.testing.assert_array_equal(o[:len(p)], p)
+            assert _reference_gap(params, p, o) <= BF16_LOGIT_MARGIN
+            continue
         ref, _ = transformer.generate(params, p[None], max_len=T,
                                       n_layer=NL, n_head=NH, d_model=DM,
                                       return_logits=False)
@@ -339,92 +377,13 @@ def test_engine_serves_single_stream_tokens_through_the_mosaic_kernel(
     Mosaic kernel (interpret mode; off the chip the registry would
     resolve ``xla_ref``): the dead slot's ``pos = -1`` rows and the
     chains' unvisited tails change no token."""
-    from paddle_tpu.kernels.paged_attention import paged_attention_pallas
-    from paddle_tpu.serving import batched_decode
+    from paddle_tpu.kernels import paged_attention
 
     monkeypatch.setattr(
-        batched_decode, "_paged_attention",
-        lambda qh, pk, pv, table, pos: paged_attention_pallas(
+        paged_attention, "attend",
+        lambda qh, pk, pv, table, pos: paged_attention.paged_attention_pallas(
             qh, pk, pv, table, pos, interpret=True))
     _serve_with_a_slot_released_mid_run(_make_params())
-
-
-# -- engine-level: paged-attention kill switch -------------------------------
-
-@pytest.mark.slow
-@pytest.mark.parametrize("reuse", [True, False])
-def test_paged_kill_switch_engine_bit_exact(monkeypatch, reuse):
-    """PADDLE_TPU_PAGED_ATTN=0 (the decode_gather + dense-softmax
-    oracle spelling) and =1 (the paged_attention kernel) serve
-    bit-identical tokens, both equal to single-stream generate —
-    prefix reuse on and off, CoW-fork traffic included.  The env var is
-    read at trace time, so each setting gets a fresh engine; the
-    kernel-backend recording proves which spelling actually compiled."""
-    params = _make_params()
-    rng = np.random.default_rng(21)
-    base = rng.integers(1, VOCAB, (11,)).astype(np.int32)
-    prompts = [
-        base.copy(),
-        np.concatenate([base[:6],                      # CoW fork at 6
-                        rng.integers(1, VOCAB, (4,)).astype(np.int32)]),
-        rng.integers(1, VOCAB, (8,)).astype(np.int32),
-    ]
-
-    def serve(env):
-        _obs.get_registry().clear(prefix="serving.")
-        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", env)
-        eng = ServingEngine(params, NL, NH, DM, max_len=T, max_slots=3,
-                            decode_chunk=4, min_bucket=4, block_tokens=4,
-                            prefix_reuse=reuse)
-        return eng.generate_many(prompts, max_new_tokens=7), eng
-
-    paged_outs, paged_eng = serve("1")
-    assert any("paged_attention" in sel
-               for sel in paged_eng.kernel_backends.values())
-    assert paged_eng.stats()["serving.paged_attn_compiles"] >= 1
-    gather_outs, gather_eng = serve("0")
-    assert all("paged_attention" not in sel
-               for sel in gather_eng.kernel_backends.values())
-    assert "serving.paged_attn_compiles" not in gather_eng.stats()
-    for p, a, b in zip(prompts, paged_outs, gather_outs):
-        np.testing.assert_array_equal(a, b)
-        ref, _ = transformer.generate(params, p[None], max_len=T,
-                                      n_layer=NL, n_head=NH, d_model=DM,
-                                      return_logits=False)
-        np.testing.assert_array_equal(a, np.asarray(ref)[0][: len(p) + 7])
-
-
-@pytest.mark.slow
-def test_spec_parity_through_paged_verify_window(monkeypatch):
-    """Speculative decoding scores its draft windows through the paged
-    kernel (W = k+1 is the multi-token shape): committed tokens are
-    identical to the PADDLE_TPU_PAGED_ATTN=0 spec engine and to plain
-    greedy decode, with speculative rounds actually run."""
-    from paddle_tpu.serving import speculative as spec
-
-    params = _make_params()
-    rng = np.random.default_rng(22)
-    prompts = [rng.integers(1, VOCAB, (l,)).astype(np.int32)
-               for l in (5, 9, 7)]
-
-    def serve(env):
-        _obs.get_registry().clear(prefix="serving.")
-        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", env)
-        eng = ServingEngine(params, NL, NH, DM, max_len=T, max_slots=3,
-                            decode_chunk=4, min_bucket=4, block_tokens=4,
-                            draft_params=spec.depth_draft(params, 1),
-                            spec_k=3)
-        outs = eng.generate_many(prompts, max_new_tokens=8)
-        assert eng._spec.proposed > 0
-        return outs
-
-    paged, gather = serve("1"), serve("0")
-    for p, a, b in zip(prompts, paged, gather):
-        np.testing.assert_array_equal(a, b)
-        ref, _ = transformer.generate(params, p[None], max_len=T,
-                                      n_layer=NL, n_head=NH, d_model=DM,
-                                      return_logits=False)
-        np.testing.assert_array_equal(a, np.asarray(ref)[0][: len(p) + 8])
 
 
 def test_engine_pool_accounting_no_leak():

@@ -220,13 +220,16 @@ def test_step_cost_memory_fields_and_gauges():
     memory_analysis, mirrored into the registry gauges."""
     from paddle_tpu.observability.metrics import get_registry
 
+    # the gauges keep the largest step the PROCESS compiled (set_max):
+    # zeroed, they read this program's, whatever the worker ran before
+    reg = get_registry()
+    reg.reset(prefix="executor.")
     main, startup, loss = _build("selective")
     out, exe = _step_outputs(main, startup, loss, steps=1)
     sc = exe.last_step_cost
     assert isinstance(sc["temp_bytes"], int) and sc["temp_bytes"] > 0
     assert isinstance(sc["hbm_high_water_bytes"], int)
     assert sc["hbm_high_water_bytes"] >= sc["temp_bytes"]
-    reg = get_registry()
     assert reg.value("executor.temp_bytes") > 0
     assert reg.value("executor.hbm_high_water_bytes") >= \
         reg.value("executor.temp_bytes")

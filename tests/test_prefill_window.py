@@ -208,11 +208,12 @@ def test_wide_windows_attend_densely_narrow_ones_stream(monkeypatch):
     """The attention spelling follows the window's width at trace time:
     from ``DENSE_WINDOW`` rows up one step over the whole chain of the
     ``xla_ref`` spelling, below it whatever the registry resolves with
-    its own default geometry."""
-    from paddle_tpu import kernels
+    its own default geometry.  The choice lives with the kernel
+    (``kernels.paged_attention.attend``), not in the serving step."""
+    from paddle_tpu.kernels import paged_attention as _pa
 
     calls = []
-    real = kernels.resolve
+    real = _pa.resolve
 
     def spy(op, backend=None, **kw):
         ker = real(op, backend=backend, **kw)
@@ -225,16 +226,15 @@ def test_wide_windows_attend_densely_narrow_ones_stream(monkeypatch):
 
         return type("K", (), {"impl": Impl, "backend": ker.backend})
 
-    monkeypatch.setattr(kernels, "resolve", spy)
+    monkeypatch.setattr(_pa, "resolve", spy)
     pool = jnp.zeros((6, B, NH, DM // NH), jnp.float32)
     table = jnp.zeros((1, NB), jnp.int32)
-    for w in (1, _bd.DENSE_WINDOW - 1, _bd.DENSE_WINDOW, 4 * _bd.DENSE_WINDOW):
+    for w in (1, _pa.DENSE_WINDOW - 1, _pa.DENSE_WINDOW, 4 * _pa.DENSE_WINDOW):
         q = jnp.zeros((1, w, NH, DM // NH), jnp.float32)
-        _bd._paged_attention(q, pool, pool, table,
-                             jnp.zeros((1, w), jnp.int32))
-    assert calls == [(1, None, None), (_bd.DENSE_WINDOW - 1, None, None),
-                     (_bd.DENSE_WINDOW, "xla_ref", NB),
-                     (4 * _bd.DENSE_WINDOW, "xla_ref", NB)]
+        _pa.attend(q, pool, pool, table, jnp.zeros((1, w), jnp.int32))
+    assert calls == [(1, None, None), (_pa.DENSE_WINDOW - 1, None, None),
+                     (_pa.DENSE_WINDOW, "xla_ref", NB),
+                     (4 * _pa.DENSE_WINDOW, "xla_ref", NB)]
 
 
 def test_engine_counts_pieces_real_and_padded_tokens(monkeypatch):

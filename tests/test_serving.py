@@ -657,56 +657,94 @@ def test_slot_death_reclaims_blocks_and_driver_survives(params):
     assert eng.idle
 
 
-# -- tuned decode geometry (op=serving_decode, ISSUE 12 satellite) ----------
+# -- the serving path reads no tuner (PR 30) ---------------------------------
 
-def test_engine_consults_tuned_serving_geometry(params, tmp_path,
-                                                monkeypatch):
-    """docs/autotune.md "Adding a tunable op": a measured
-    tune_serving_decode search persists {chunk, min_bucket} under
-    op=serving_decode, and an engine constructed with NO explicit
-    geometry picks the winner up; explicit arguments still win; the
-    kill switch keeps the hand-picked defaults."""
+def test_engine_ignores_a_planted_tune_cache(params, tmp_path, monkeypatch):
+    """A tune cache left on the machine cannot move the serving path: an
+    engine built under ``PADDLE_TPU_TUNE_CACHE`` pointing at a file that
+    holds ``serving_decode``, ``spec_decode`` and ``paged_attention``
+    entries for exactly its shape keeps the constants every benchmark
+    row ran with and lowers the same decode chunk as one built without
+    the file."""
+    import jax
+    import jax.numpy as jnp
+
     from paddle_tpu import tune
+    from paddle_tpu.serving import batched_decode as bd, depth_draft
+    from paddle_tpu.tune.space import WorkloadKey
 
-    monkeypatch.setenv("PADDLE_TPU_TUNE_CACHE",
-                       str(tmp_path / "tuned.json"))
-    monkeypatch.setenv("PADDLE_TPU_TUNE", "search")
+    def build():
+        eng = ServingEngine(params, NL, NH, DM, max_len=T, max_slots=4,
+                            draft_params=depth_draft(params, 1))
+        fn = bd.make_decode_chunk(eng.arch, eng.decode_chunk, donate=False)
+        text = fn.lower(eng._p, eng._pk, eng._pv, eng._last, eng._pos,
+                        jnp.asarray(eng._table)).as_text()
+        return eng, text
+
+    monkeypatch.setenv("PADDLE_TPU_TUNE", "cached")
+    monkeypatch.setenv("PADDLE_TPU_TUNE_CACHE", str(tmp_path / "none.json"))
     tune.reset_cache()
     try:
-        report = tune.tune_serving_decode(
-            params, NL, NH, DM, max_len=T, max_slots=2, requests=3,
-            prompt_len=4, max_new=4, chunks=(2, 4), min_buckets=(4,),
-            max_measure=4)
-        assert report["source"] == "search"
-        win = report["entry"]["config"]
-        assert set(win) == {"chunk", "min_bucket"}
+        plain, plain_text = build()
 
-        # default-geometry engine resolves the tuned winner
-        monkeypatch.setenv("PADDLE_TPU_TUNE", "cached")
-        eng = _engine(params, decode_chunk=None, min_bucket=None)
-        assert eng.decode_chunk == win["chunk"]
-        assert eng.min_bucket == win["min_bucket"]
+        monkeypatch.setenv("PADDLE_TPU_TUNE_CACHE",
+                           str(tmp_path / "tuned.json"))
+        tune.reset_cache()
+        cache = tune.get_cache()
+        capacity = plain.blocks_per_slot * plain.block_tokens
+        for op, t, config in (
+                ("serving_decode", T, {"chunk": 2, "min_bucket": 16}),
+                ("spec_decode", T, {"k": 2}),
+                ("paged_attention", capacity,
+                 {"backend": "xla_ref", "block_step": 2})):
+            cache.put(WorkloadKey(op, t, DM // NH, NH, "float32",
+                                  jax.default_backend(), remat="-").s,
+                      config)
+        cache.save()
+        tune.reset_cache()
+        assert len(tune.get_cache().entries) == 3  # the file is sound
 
-        # explicit args always win
-        eng2 = _engine(params, decode_chunk=7, min_bucket=16)
-        assert eng2.decode_chunk == 7 and eng2.min_bucket == 16
-
-        # kill switch: hand-picked defaults, no lookup at all
-        monkeypatch.setenv("PADDLE_TPU_TUNE", "off")
-        eng3 = _engine(params, decode_chunk=None, min_bucket=None)
-        assert eng3.decode_chunk == 4 and eng3.min_bucket == 8
-
-        # the search keys on the dtype the engine will SERVE in: bf16
-        # weights must land under dt=bfloat16, the key the engine's
-        # lookup queries (a float32 default would be a silent miss)
-        import jax.numpy as jnp
-
-        monkeypatch.setenv("PADDLE_TPU_TUNE", "cached")
-        p16 = {k: (jnp.asarray(v, jnp.bfloat16)
-                   if (k.startswith("block") or k.startswith("lm_head"))
-                   and k.endswith(".w") else v)
-               for k, v in params.items()}
-        rep16 = tune.tune_serving_decode(p16, NL, NH, DM, max_len=T)
-        assert "dt=bfloat16" in rep16["key"]
+        planted, planted_text = build()
+        for eng in (plain, planted):
+            assert (eng.decode_chunk, eng.min_bucket, eng.spec_k) == (4, 8, 4)
+        assert planted_text == plain_text
     finally:
         tune.reset_cache()
+
+
+def test_serving_package_imports_no_tuner_and_no_kernel_switch():
+    """The arrows point one way (serving -> kernels): no module under
+    ``paddle_tpu/serving/`` imports ``paddle_tpu.tune``, at any level of
+    nesting, and ``batched_decode.py`` imports no ``os`` (it has no
+    environment variable to read: how a row attends through the table
+    is ``kernels.paged_attention.attend``'s to decide)."""
+    import ast
+    import pathlib
+
+    import paddle_tpu.serving as serving
+
+    def imported(path):
+        """Absolute dotted names a file imports, relative ones resolved
+        against ``paddle_tpu.serving``."""
+        out = []
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                out += [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                base = ["paddle_tpu", "serving"]
+                base = base[:len(base) - node.level + 1] if node.level else []
+                mod = ".".join(base + ([node.module] if node.module else []))
+                out += [mod] + [f"{mod}.{a.name}" for a in node.names]
+        return out
+
+    files = sorted(pathlib.Path(serving.__file__).parent.glob("*.py"))
+    assert len(files) >= 7
+    for path in files:
+        names = imported(path)
+        assert names, path
+        tuners = [n for n in names if n == "paddle_tpu.tune"
+                  or n.startswith("paddle_tpu.tune.")]
+        assert not tuners, (path.name, tuners)
+        if path.name == "batched_decode.py":
+            assert not [n for n in names if n.split(".")[0] == "os"], names
+            assert "paddle_tpu.kernels.paged_attention" in names

@@ -277,57 +277,3 @@ def test_slot_death_mid_verify_reclaims_scratch_and_real_chains(params):
     assert st["serving.slot_deaths"] == 1
     assert st["serving.completed"] == 5
     assert eng.idle
-
-
-# -- tuned draft window (op=spec_decode, docs/autotune.md) -------------------
-
-def test_engine_consults_tuned_spec_window(params, tmp_path, monkeypatch):
-    """docs/autotune.md "Adding a tunable op": a measured
-    tune_spec_decode search persists {k} under op=spec_decode, an
-    engine constructed with a draft but NO explicit spec_k picks the
-    winner up; explicit spec_k still wins; the kill switch keeps the
-    hand-picked default; and in cached mode a miss NEVER builds an
-    engine (no measurement on the serving path)."""
-    from paddle_tpu import tune
-
-    draft = spec.depth_draft(params, 1)
-    monkeypatch.setenv("PADDLE_TPU_TUNE_CACHE",
-                       str(tmp_path / "tuned.json"))
-    monkeypatch.setenv("PADDLE_TPU_TUNE", "cached")
-    tune.reset_cache()
-    try:
-        # cached-mode miss: no engine built, no candidates measured
-        miss = tune.tune_spec_decode(params, draft, NL, NH, DM,
-                                     max_len=T)
-        assert miss["source"] == "miss" and miss["entry"] is None
-        assert miss["measured"] == []
-
-        monkeypatch.setenv("PADDLE_TPU_TUNE", "search")
-        report = tune.tune_spec_decode(
-            params, draft, NL, NH, DM, max_len=T, max_slots=2,
-            requests=2, prompt_len=4, max_new=4, ks=(2, 3),
-            max_measure=2)
-        assert report["source"] == "search"
-        win = report["entry"]["config"]
-        assert set(win) == {"k"} and win["k"] in (2, 3)
-
-        # draft-but-no-spec_k engine resolves the tuned winner
-        monkeypatch.setenv("PADDLE_TPU_TUNE", "cached")
-        eng = _engine(params, draft_params=draft)
-        assert eng.spec_k == win["k"]
-
-        # a second lookup is a cache hit, not a re-search
-        again = tune.tune_spec_decode(params, draft, NL, NH, DM,
-                                      max_len=T)
-        assert again["source"] == "cache"
-
-        # explicit spec_k always wins
-        eng2 = _engine(params, draft_params=draft, spec_k=2)
-        assert eng2.spec_k == 2
-
-        # kill switch: hand-picked default, no lookup at all
-        monkeypatch.setenv("PADDLE_TPU_TUNE", "off")
-        eng3 = _engine(params, draft_params=draft)
-        assert eng3.spec_k == spec.DEFAULT_SPEC_K
-    finally:
-        tune.reset_cache()

@@ -130,12 +130,11 @@ if ! timeout -k 10 600 env JAX_PLATFORMS=cpu \
 fi
 # kernel-registry smoke: the multi-backend kernel subsystem — registry
 # resolution + override precedence on this host, oracle parity of every
-# available backend (plus interpret-forced Mosaic/triton kernels)
+# available backend (plus the interpret-forced Mosaic paged kernel)
 # against the pure-XLA reference within the documented tolerances,
 # paged-attention parity over ragged block chains (trash-block masking,
-# CoW forks, the fully-cached one-token prefill) with the
-# PADDLE_TPU_PAGED_ATTN kill switch provably toggling the compiled
-# serving spelling, PADDLE_TPU_KERNEL_BACKEND=xla_ref running the full
+# CoW forks, the fully-cached one-token prefill),
+# PADDLE_TPU_KERNEL_BACKEND=xla_ref running the full
 # GPT trainer path under every memory_optimize policy with ZERO Pallas
 # calls in the jaxpr, and the interpret-mode-in-timed-run lint finding
 # planted and detected (docs/kernels.md)
@@ -245,9 +244,7 @@ for k in ('tok_s', 'baseline_tok_s', 'speedup', 'ttft_p50_ms',
           'goodput_under_slo', 'slo_violations', 'prefix_hit_rate',
           'shed_total', 'fifo_goodput_under_slo', 'prefill_tokens',
           'fifo_prefill_tokens', 'cow_copies',
-          'spec_goodput_under_slo', 'spec_accept_rate', 'spec_speedup',
-          'serving_decode_hbm_bytes', 'serving_attn_bytes',
-          'serving_decode_hbm_bytes_gather', 'serving_attn_bytes_gather'):
+          'spec_goodput_under_slo', 'spec_accept_rate', 'spec_speedup'):
     assert k in row, f'missing field {k}: {row}'
 print('serving smoke:', json.dumps(row))
 "; then
