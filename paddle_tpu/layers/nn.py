@@ -1022,7 +1022,7 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
                 except Exception:  # unavailable/unknown tuned choice
                     backend = None
             if tuned.get("diag_w"):
-                # the winner was MEASURED at this sub-tile width; the
+                # the winner was MEASURED at this strip height; the
                 # kernels read the module global at trace time
                 # (process-wide — last tuned build wins; the
                 # PADDLE_TPU_DIAG_W env pin beats the cache)

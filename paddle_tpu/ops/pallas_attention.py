@@ -11,11 +11,14 @@ Layout: q [b, t_q, h, d], k/v [b, t_k, h, d] (same as parallel.ring_attention,
 whose per-device inner block this kernel accelerates).
 
 Forward: Pallas kernel, grid (batch*head, q-blocks, k-blocks) with the
-k axis innermost; online-softmax state carried in VMEM scratch; causal
-k-blocks above the diagonal are skipped, and the mask select runs only on
-blocks straddling the diagonal.  Backward: custom_vjp into two Pallas
-kernels — dq (q-major grid) and dk/dv (k-major grid) — recomputing p from
-the saved lane-replicated lse, also with causal block skip.
+k axis innermost; online-softmax state carried in VMEM scratch.  Backward:
+custom_vjp into one fused Pallas kernel (k-major grid, dq as per-k-block
+partials) or, past its partials' budget, two — dq (q-major grid) and
+dk/dv (k-major grid) — recomputing p from the saved lse.  All four walk a
+grid cell the same way (``_walk_cell``): a causal cell below the diagonal
+is one full tile, one above it is skipped and fetches nothing, and one
+that straddles it is walked in static strips of DIAG_W q rows, the mask
+select only on the columns the diagonal crosses.
 delta = rowsum(do*o) is computed inside the kernels.  HBM residuals are
 O(t) rows (lse is stored 2-D [bh, t] — 4 B/row; the in-kernel softmax
 state uses 128-lane scratch tiles); VMEM stays O(block^2).
@@ -63,16 +66,22 @@ def _compiler_params():
 
     return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
-# causal diagonal sub-tile width: straddling (diagonal) blocks are computed
-# as a static grid of (DIAG_W x DIAG_W) sub-tiles and sub-tiles entirely
-# above the diagonal are NEVER computed — the forward waste of a causal
-# block pair drops from ~block/2 masked columns per row-block (~20% of all
-# flops at t=4096 with 1024 blocks) to the DIAG_W-wide band along the
-# diagonal (~w/t).  256 keeps the sub-dots MXU-shaped ([256, d] x [d, 256])
-# and the unroll at <= 16 regions per straddling cell.  A process-wide
-# TUNABLE: PADDLE_TPU_DIAG_W pins it (the env knob wins over everything),
-# and the autotune engine (paddle_tpu.tune, docs/autotune.md) sets the
-# module global while measuring a candidate / applying a tuned winner
+
+# Strip height: a causal cell that straddles the diagonal is walked in
+# strips of DIAG_W q rows, each ONE [DIAG_W, visible columns] score tile
+# and one softmax update (``_walk_cell``).  In the diagonal cell of equal
+# blocks a strip's columns are the static range [0, (qs+1) * DIAG_W) and
+# the iota/select mask runs on its last DIAG_W columns only: nothing above
+# the diagonal band is computed and nothing inside the cell is decided at
+# run time.  Wide tiles win on the chip (PERF.md, PR 31: a full 1024 x
+# 1024 cell runs at 66% of the forward's roofline, the same scores in 256
+# x 256 sub-tiles under branches at a fifth of that), so taller strips
+# schedule more masked scores and still run faster.  (The name is from
+# when this was the width of a square sub-tile of the diagonal cells; the
+# tune cache stores it as ``diag_w``.)  A process-wide TUNABLE:
+# PADDLE_TPU_DIAG_W pins it (the env knob wins over everything), and the
+# autotune engine (paddle_tpu.tune, docs/autotune.md) sets the module
+# global while measuring a candidate / applying a tuned winner
 # (apply_tuned_diag_w) — the kernels read it at trace time, so fwd and
 # all three bwd kernels always agree within one compile.
 _DIAG_W_ENV = int(os.environ.get("PADDLE_TPU_DIAG_W", "0") or 0)
@@ -80,9 +89,9 @@ DIAG_W = _DIAG_W_ENV or 256
 
 
 def apply_tuned_diag_w(width):
-    """Apply a tuned causal sub-tile width process-wide (the autotune
-    hot path / search loop).  The PADDLE_TPU_DIAG_W env pin always
-    wins; returns the width actually in effect."""
+    """Apply a tuned strip height process-wide (the autotune hot path /
+    search loop).  The PADDLE_TPU_DIAG_W env pin always wins; returns the
+    height actually in effect."""
     global DIAG_W
     if width and not _DIAG_W_ENV:
         DIAG_W = int(width)
@@ -114,53 +123,165 @@ def packed_sub_heads(n_head, d_head):
     return None
 
 
-def _diag_subtile_live(j, kb, qs, ks, block_q, block_k, wq, wk):
-    """Sub-tile (qs, ks) of straddling cell (j, kb) intersects the allowed
-    causal region (q_pos >= k_pos) — its first k column is at or below the
-    sub-tile's last q row.  Works on both Python ints (flop accounting)
-    and traced program ids (the kernel's pl.when predicates)."""
-    row_last = j * block_q + (qs + 1) * wq - 1
-    col0 = kb * block_k + ks * wk
-    return col0 <= row_last
+def _strip_cols(qs, strip, block_q, block_k):
+    """``(visible, masked)``: how many of a straddling cell's columns
+    strip ``qs`` (``strip`` q rows) takes, from the first on, and how many
+    of the last of those carry the mask.  Equal blocks make the straddling
+    cell the diagonal one, its first row on its first column: the strip
+    sees up to its own last row and the diagonal crosses its last
+    ``strip`` columns.  Unequal blocks put the diagonal anywhere, and the
+    strip takes every column under the mask."""
+    if block_q == block_k:
+        return (qs + 1) * strip, strip
+    return block_k, block_k
 
 
-def _diag_subtile_needs_mask(j, kb, qs, ks, block_q, block_k, wq, wk):
-    """The diagonal passes through sub-tile (qs, ks): its last k column is
-    past the sub-tile's first q row, so the iota/select must run."""
-    row0 = j * block_q + qs * wq
-    col_last = kb * block_k + (ks + 1) * wk - 1
-    return col_last > row0
+def _cell_kind(off, block_q, block_k):
+    """``(full, straddling)`` for the cell whose first q row lies ``off``
+    positions past its first k column (``j * block_q - kb * block_k``;
+    Python ints for the accounting, traced program ids in the kernels):
+    full when even the first row sees the last column, straddling when
+    only some (row, column) pairs are allowed, neither when none is."""
+    full = off >= block_k - 1
+    needed = off > -block_q
+    if isinstance(off, int):
+        return full, needed and not full
+    return full, jnp.logical_and(needed, jnp.logical_not(full))
+
+
+class FlashWalk(tuple):
+    """``(scheduled, useful)`` flops of ``causal_flash_flops``, with what
+    the walk that schedules them costs in bookkeeping as attributes:
+    ``updates_per_row`` (the most softmax updates any q row goes through
+    from its first k block to its last) and ``branches_per_cell`` (the most
+    run-time branches inside one grid cell, the cell's own full | diagonal
+    | skipped choice not counted)."""
+
+    def __new__(cls, scheduled, useful, updates_per_row, branches_per_cell):
+        self = super().__new__(cls, (scheduled, useful))
+        self.updates_per_row = updates_per_row
+        self.branches_per_cell = branches_per_cell
+        return self
 
 
 def causal_flash_flops(t_q, t_k, d, block_q=1024, block_k=1024,
                        diag_w=None, per_head=True):
     """MXU flops the causal forward kernel SCHEDULES for one (batch, head),
-    by simulating exactly the kernel's block/sub-tile skip logic
-    (``_diag_subtile_live`` is shared with the forward AND all three
-    backward kernels, so this accounting IS the grid-shape assertion; the
-    backward schedules the same (row, col) coverage with 5-7 dots per
-    pair instead of 2).  Returns ``(scheduled, useful)`` where useful
-    counts only unmasked (q_pos >= k_pos) score entries; both in flops of
-    the two forward block dots (q@k^T and p@v: 4*d per score entry)."""
+    by simulating exactly the kernel's cell walk (``_cell_kind`` and
+    ``_strip_cols`` are what ``_walk_cell`` runs in the forward AND all
+    three backward kernels, so this accounting IS the grid-shape
+    assertion; the backward schedules the same (row, col) coverage with
+    5-7 dots per pair instead of 2).  Returns a ``FlashWalk``:
+    ``(scheduled, useful)`` where useful counts only unmasked (q_pos >=
+    k_pos) score entries, both in flops of the two forward block dots
+    (q@k^T and p@v: 4*d per score entry), with the walk's softmax updates
+    per q row and branches per cell as attributes."""
     block_q = _pick_block(t_q, block_q)
     block_k = _pick_block(t_k, block_k)
-    wq = _pick_block(block_q, diag_w or DIAG_W)
-    wk = _pick_block(block_k, diag_w or DIAG_W)
-    nq, nk = t_q // block_q, t_k // block_k
-    scheduled = 0
-    for j in range(nq):
-        last_kb = min(((j + 1) * block_q - 1) // block_k, nk - 1)
-        for kb in range(last_kb + 1):
-            if j * block_q >= (kb + 1) * block_k - 1:
-                scheduled += block_q * block_k  # fully unmasked cell
-                continue
-            for qs in range(block_q // wq):
-                for ks in range(block_k // wk):
-                    if _diag_subtile_live(j, kb, qs, ks, block_q,
-                                          block_k, wq, wk):
-                        scheduled += wq * wk
+    strip = _pick_block(block_q, diag_w or DIAG_W)
+    scheduled = updates = 0
+    for j in range(t_q // block_q):
+        cells = 0  # live cells of this q block: an update a row in each
+        for kb in range(t_k // block_k):
+            full, straddling = _cell_kind(j * block_q - kb * block_k,
+                                          block_q, block_k)
+            cells += full or straddling
+            if full:
+                scheduled += block_q * block_k
+            elif straddling:
+                scheduled += strip * sum(
+                    _strip_cols(qs, strip, block_q, block_k)[0]
+                    for qs in range(block_q // strip))
+        updates = max(updates, cells)
     useful = sum(min(r + 1, t_k) for r in range(t_q))
-    return 4 * d * scheduled, 4 * d * useful
+    # _walk_cell branches once a cell (full | straddling | skipped) and
+    # never inside one
+    return FlashWalk(4 * d * scheduled, 4 * d * useful, updates, 0)
+
+
+def _masked_tail(s, mask, masked):
+    """``s`` with the last ``masked`` of its columns put to NEG_INF where
+    ``mask`` ([rows, masked] bool) is false; the columns before them are
+    not touched (no iota, no select)."""
+    if not masked:
+        return s
+    w = s.shape[1] - masked
+    tail = jnp.where(mask, s[:, w:], NEG_INF)
+    return tail if not w else jnp.concatenate([s[:, :w], tail], axis=1)
+
+
+def _walk_cell(tile, causal, off, block_q, block_k):
+    """Walk one grid cell: call ``tile(rows, cols, mask, masked)`` over
+    what the causal mask allows of its [block_q, block_k] score tile, each
+    call one [rows, cols] tile whose last ``masked`` columns take ``mask``
+    (see ``_masked_tail``), every q row in at most one call.  ``off`` is
+    the cell's first q row less its first k column (traced program ids).
+
+    One run-time branch a causal cell: full (one tile, no mask) |
+    straddling | skipped.  A straddling cell is walked in strips of DIAG_W
+    q rows.  With ``block_q == block_k`` it is the diagonal cell (``off``
+    is 0) and all of it is static: strip ``qs`` takes columns ``[0,
+    (qs + 1) * DIAG_W)``, masked on the last DIAG_W, so nothing above the
+    diagonal band is computed.  Unequal blocks put the diagonal anywhere
+    in the cell: there a strip takes every column under a mask placed by
+    ``off``.  ``causal_flash_flops`` counts this walk."""
+    import jax.experimental.pallas as pl
+
+    rows_all, cols_all = slice(0, block_q), slice(0, block_k)
+    if not causal:
+        tile(rows_all, cols_all, None, 0)
+        return
+    full, straddling = _cell_kind(off, block_q, block_k)
+    pl.when(full)(lambda: tile(rows_all, cols_all, None, 0))
+
+    @pl.when(straddling)
+    def _straddling():
+        R = _pick_block(block_q, DIAG_W)
+        W = _strip_cols(0, R, block_q, block_k)[1]
+        # equal blocks: this is the diagonal cell and ``off`` is 0
+        at = 0 if block_q == block_k else off
+        # row less column inside a strip's masked columns, the first of
+        # which is column ``visible - masked`` of the cell
+        rel = (jax.lax.broadcasted_iota(jnp.int32, (R, W), 0)
+               - jax.lax.broadcasted_iota(jnp.int32, (R, W), 1))
+        for qs in range(block_q // R):
+            visible, masked = _strip_cols(qs, R, block_q, block_k)
+            mask = rel >= (visible - masked) - (at + qs * R)
+            tile(slice(qs * R, (qs + 1) * R), slice(0, visible), mask,
+                 masked)
+
+
+def _dot_t(a, b):
+    """a^T @ b (contraction over the rows of both), f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """a @ b^T (contraction over the columns of both)."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _last_k_block(causal, j, block_q, block_k, nk):
+    """The last k block q block ``j`` attends, where a q-major kernel
+    finalizes.  Clamped to nk-1: cross-attention with t_q > t_k has q
+    blocks whose diagonal lies beyond the last k block, and the finalize
+    step must still fire for them."""
+    if not causal:
+        return nk - 1
+    return jnp.minimum(((j + 1) * block_q - 1) // block_k, nk - 1)
+
+
+def _side_by_side(parts, dtype):
+    """The sub-heads' [rows, d] parts as one [rows, S * d] value to store."""
+    return (parts[0] if len(parts) == 1
+            else jnp.concatenate(parts, axis=-1)).astype(dtype)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
@@ -180,11 +301,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     value sub-slices are plain static lane slices (interpret mode and
     Mosaic's masked vector loads both handle them).
 
-    Causal straddling (diagonal) cells run TRIANGULAR: a static grid of
-    DIAG_W-wide sub-tiles in which sub-tiles entirely above the diagonal
-    are never computed (``_diag_subtile_live``) and the iota/select mask
-    runs only on sub-tiles the diagonal actually crosses — the masked
-    half-block flops of the old full-tile + select spelling do not exist.
+    ``_walk_cell`` decides which [rows, cols] tiles of the cell are
+    computed; each is one online-softmax update of its rows' state.
     """
     import jax.experimental.pallas as pl
 
@@ -192,8 +310,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     kb = pl.program_id(2)
     S = sub_heads
     d = q_ref.shape[-1] // S
-    wq = _pick_block(block_q, DIAG_W)
-    wk = _pick_block(block_k, DIAG_W)
 
     @pl.when(kb == 0)
     def _init():
@@ -201,97 +317,32 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr[...])
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
-    if causal:
-        # causal block skip: k blocks strictly above the diagonal touch
-        # no unmasked entries — skip their compute entirely (halves the
-        # causal forward's work).  Clamp to nk-1: cross-attention with
-        # t_q > t_k has q blocks whose diagonal lies beyond the last k
-        # block, and the finalize step must still fire for them.
-        last_kb = jnp.minimum(((j + 1) * block_q - 1) // block_k, nk - 1)
-        needed = kb <= last_kb
-    else:
-        last_kb = nk - 1
-        needed = None
-
-    def _update(sh, rows, s, v_sub):
-        """One online-softmax state update for sub-head ``sh``, q rows
-        ``rows`` (a static slice) and score tile ``s``."""
-        m_prev = m_scr[sh, rows]
-        l_prev = l_scr[sh, rows]
-        m2 = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m2)
-        p = jnp.exp(s - m2[:, :1])
-        l2 = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc2 = acc_scr[sh, rows] * alpha[:, :1] + jax.lax.dot_general(
-            p.astype(v_sub.dtype), v_sub, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[sh, rows] = m2
-        l_scr[sh, rows] = l2
-        acc_scr[sh, rows] = acc2
-
-    def _score(q_sub, k_sub):
-        # MXU feeds stay in the INPUT dtype (bf16 in = 2x the f32 MXU
-        # rate); only the softmax state is f32.  Same convention as the
-        # public TPU flash kernels.
-        return jax.lax.dot_general(
-            q_sub, k_sub, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-
-    def _full_block():
+    def _tile(rows, cols, mask, masked):
         for sh in range(S):
             sl = slice(sh * d, (sh + 1) * d)
-            s = _score(q_ref[0][:, sl], k_ref[0][:, sl])
-            _update(sh, slice(None), s, v_ref[0][:, sl])
+            # MXU feeds stay in the INPUT dtype (bf16 in = 2x the f32 MXU
+            # rate); only the softmax state is f32.  Same convention as
+            # the public TPU flash kernels.
+            s = _masked_tail(
+                _dot_nt(q_ref[0, rows, sl], k_ref[0, cols, sl]) * sm_scale,
+                mask, masked)
+            m_prev = m_scr[sh, rows]
+            m2 = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m2)
+            p = jnp.exp(s - m2[:, :1])
+            v = v_ref[0, cols, sl]
+            l_scr[sh, rows] = (l_scr[sh, rows] * alpha
+                               + jnp.sum(p, axis=-1, keepdims=True))
+            acc_scr[sh, rows] = acc_scr[sh, rows] * alpha[:, :1] + _dot(
+                p.astype(v.dtype), v)
+            m_scr[sh, rows] = m2
 
-    def _diag_block():
-        # triangular straddling cell: only sub-tiles intersecting the
-        # allowed q_pos >= k_pos region are computed
-        for sh in range(S):
-            sl = slice(sh * d, (sh + 1) * d)
-            q = q_ref[0][:, sl]
-            k = k_ref[0][:, sl]
-            v = v_ref[0][:, sl]
-            for qs in range(block_q // wq):
-                rows = slice(qs * wq, (qs + 1) * wq)
-                for ks in range(block_k // wk):
-                    cols = slice(ks * wk, (ks + 1) * wk)
+    # causal block skip: k blocks strictly above the diagonal touch no
+    # unmasked entries and are walked by no tile (halves the causal
+    # forward's work)
+    _walk_cell(_tile, causal, j * block_q - kb * block_k, block_q, block_k)
 
-                    def _sub(masked, rows=rows, cols=cols, qs=qs, ks=ks,
-                             sh=sh, q=q, k=k, v=v):
-                        s = _score(q[rows], k[cols])
-                        if masked:
-                            q_pos = (j * block_q + qs * wq
-                                     + jax.lax.broadcasted_iota(
-                                         jnp.int32, (wq, wk), 0))
-                            k_pos = (kb * block_k + ks * wk
-                                     + jax.lax.broadcasted_iota(
-                                         jnp.int32, (wq, wk), 1))
-                            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-                        _update(sh, rows, s, v[cols])
-
-                    live = _diag_subtile_live(j, kb, qs, ks, block_q,
-                                              block_k, wq, wk)
-                    crossing = _diag_subtile_needs_mask(
-                        j, kb, qs, ks, block_q, block_k, wq, wk)
-                    pl.when(jnp.logical_and(live, crossing))(
-                        lambda _s=_sub: _s(True))
-                    pl.when(jnp.logical_and(
-                        live, jnp.logical_not(crossing)))(
-                        lambda _s=_sub: _s(False))
-
-    if needed is None:
-        _full_block()
-    else:
-        # the diagonal only crosses blocks straddling it; blocks fully
-        # below run the plain full-tile dot with no iota/select at all
-        unmasked = j * block_q >= (kb + 1) * block_k - 1
-        pl.when(jnp.logical_and(needed, unmasked))(_full_block)
-        pl.when(jnp.logical_and(needed, jnp.logical_not(unmasked)))(
-            _diag_block)
-
-    @pl.when(kb == last_kb)
+    @pl.when(kb == _last_k_block(causal, j, block_q, block_k, nk))
     def _finalize():
         lses = []
         outs = []
@@ -348,6 +399,25 @@ def _packed_geom(q, k, n_head, sub_heads=1):
     return b * (h // S), t_q, t_k, width, pix, pix
 
 
+def _live_k_block(kix, causal, block_q, block_k):
+    """The K/V index map of a q-major grid (i, j, kb).  A causal cell
+    above the diagonal is skipped, so it names its row's last live block
+    again: the pipeline sees an unchanged index and fetches nothing."""
+    if not causal:
+        return lambda i, j, kb: kix(i, kb)
+    return lambda i, j, kb: kix(
+        i, jnp.minimum(kb, ((j + 1) * block_q - 1) // block_k))
+
+
+def _live_q_block(causal, block_q, block_k, nq):
+    """The q block a k-major grid cell (i, kb, jq) fetches: skipped cells
+    (q blocks wholly before k block ``kb``) name the first live one."""
+    if not causal:
+        return lambda kb, jq: jq
+    return lambda kb, jq: jnp.minimum(
+        jnp.maximum(jq, (kb * block_k) // block_q), nq - 1)
+
+
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                n_head=None, sub_heads=1):
     import jax.experimental.pallas as pl
@@ -363,6 +433,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk, sub_heads=S,
     )
+    kv_at = _live_k_block(kix, causal, block_q, block_k)
     scratch = [
         pltpu.VMEM((S, block_q, LSE_LANES), jnp.float32),  # m
         pltpu.VMEM((S, block_q, LSE_LANES), jnp.float32),  # l
@@ -379,8 +450,8 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         grid=(bh, t_q // block_q, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, width), lambda i, j, kb: qix(i, j)),
-            pl.BlockSpec((1, block_k, width), lambda i, j, kb: kix(i, kb)),
-            pl.BlockSpec((1, block_k, width), lambda i, j, kb: kix(i, kb)),
+            pl.BlockSpec((1, block_k, width), kv_at),
+            pl.BlockSpec((1, block_k, width), kv_at),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, width), lambda i, j, kb: qix(i, j)),
@@ -403,12 +474,32 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     return o, lse[:, :, 0]
 
 
+def _bwd_p(q, k, lse, sm_scale, mask, masked):
+    """p (f32) of one [rows, cols] tile, recomputed from the saved lse
+    ([rows, 1])."""
+    s = _dot_nt(q, k) * sm_scale
+    return jnp.exp(_masked_tail(s, mask, masked) - lse)
+
+
+def _bwd_ds(p, do, v, delta, sm_scale):
+    """ds (f32) of the tile from its p and delta = rowsum(do * o)
+    ([rows, 1])."""
+    return p * (_dot_nt(do, v) - delta) * sm_scale
+
+
+def _bwd_delta(do, o, dlse):
+    """delta = rowsum(do * o) in f32 ([rows, 1]); an lse cotangent (from
+    callers that consume lse, e.g. ring-attention merges) folds in as
+    ds = p * (dp - delta + dlse) * scale."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    return delta if dlse is None else delta - dlse
+
+
 def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, nk,
                    has_dlse, sub_heads):
     """dq: grid (bh, q-blocks, k-blocks), k innermost; accumulate in VMEM.
-    delta = rowsum(do*o) is computed here (kb==0); an lse cotangent (from
-    callers that consume lse, e.g. ring-attention merges) folds in as
-    ds = p * (dp - delta + dlse) * scale.  ``sub_heads`` > 1: each
+    delta = rowsum(do*o) is computed here (kb==0).  ``sub_heads`` > 1: each
     128-lane slice carries S independent d=64 heads (see the forward
     kernel) — per-sub-head score/delta math, one concatenated dq store."""
     import jax.experimental.pallas as pl
@@ -431,100 +522,26 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, nk,
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
         for sh in range(S):
             sl = slice(sh * d, (sh + 1) * d)
-            d_row = jnp.sum(
-                do_ref[0][:, sl].astype(jnp.float32)
-                * o_ref[0][:, sl].astype(jnp.float32),
-                axis=-1, keepdims=True)
-            if dlse_ref is not None:
-                d_row = d_row - dlse_ref[sh][:, :1]
+            d_row = _bwd_delta(do_ref[0, :, sl], o_ref[0, :, sl],
+                               None if dlse_ref is None else dlse_ref[sh])
             delta_scr[sh] = jnp.broadcast_to(d_row, delta_scr.shape[1:])
 
-    if causal:
-        # clamped like the forward: cross-attention t_q > t_k must still
-        # finalize the q blocks past the last k block
-        last_kb = jnp.minimum(((j + 1) * block_q - 1) // block_k, nk - 1)
-    else:
-        last_kb = nk - 1
-
-    wq = _pick_block(block_q, DIAG_W)
-    wk = _pick_block(block_k, DIAG_W)
-
-    def _sub(sh, rows, cols, q, k, v, do, masked, q0, k0):
-        """One (q-rows, k-cols) sub-tile of the dq math for sub-head sh;
-        ``q0``/``k0`` are the tile's absolute start positions."""
-        lse = lse_ref[sh]
-        delta = delta_scr[sh]
-        s = jax.lax.dot_general(
-            q[rows], k[cols], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if masked:
-            shape = (s.shape[0], s.shape[1])
-            q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-            k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[rows][:, :1])
-        dp = jax.lax.dot_general(
-            do[rows], v[cols], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[rows][:, :1]) * sm_scale).astype(k.dtype)
-        dq_scr[sh, rows] += jax.lax.dot_general(
-            ds, k[cols], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    def _block(masked):
+    def _tile(rows, cols, mask, masked):
         for sh in range(S):
             sl = slice(sh * d, (sh + 1) * d)
-            _sub(sh, slice(None), slice(None), q_ref[0][:, sl],
-                 k_ref[0][:, sl], v_ref[0][:, sl], do_ref[0][:, sl],
-                 masked, j * block_q, kb * block_k)
+            k = k_ref[0, cols, sl]
+            p = _bwd_p(q_ref[0, rows, sl], k, lse_ref[sh, rows], sm_scale,
+                       mask, masked)
+            ds = _bwd_ds(p, do_ref[0, rows, sl], v_ref[0, cols, sl],
+                         delta_scr[sh, rows][:, :1], sm_scale)
+            dq_scr[sh, rows] += _dot(ds.astype(k.dtype), k)
 
-    def _diag_block():
-        # triangular straddling cell (same skip predicate as the forward):
-        # sub-tiles entirely above the diagonal are never computed
-        for sh in range(S):
-            sl = slice(sh * d, (sh + 1) * d)
-            q = q_ref[0][:, sl]
-            k = k_ref[0][:, sl]
-            v = v_ref[0][:, sl]
-            do = do_ref[0][:, sl]
-            for qs in range(block_q // wq):
-                rows = slice(qs * wq, (qs + 1) * wq)
-                for ks in range(block_k // wk):
-                    cols = slice(ks * wk, (ks + 1) * wk)
+    _walk_cell(_tile, causal, j * block_q - kb * block_k, block_q, block_k)
 
-                    def _go(masked, sh=sh, rows=rows, cols=cols, qs=qs,
-                            ks=ks, q=q, k=k, v=v, do=do):
-                        _sub(sh, rows, cols, q, k, v, do, masked,
-                             j * block_q + qs * wq,
-                             kb * block_k + ks * wk)
-
-                    live = _diag_subtile_live(j, kb, qs, ks, block_q,
-                                              block_k, wq, wk)
-                    crossing = _diag_subtile_needs_mask(
-                        j, kb, qs, ks, block_q, block_k, wq, wk)
-                    pl.when(jnp.logical_and(live, crossing))(
-                        lambda _g=_go: _g(True))
-                    pl.when(jnp.logical_and(
-                        live, jnp.logical_not(crossing)))(
-                        lambda _g=_go: _g(False))
-
-    if causal:
-        unmasked = j * block_q >= (kb + 1) * block_k - 1
-        on = kb <= last_kb
-        pl.when(jnp.logical_and(on, unmasked))(lambda: _block(False))
-        pl.when(jnp.logical_and(on, jnp.logical_not(unmasked)))(
-            _diag_block)
-    else:
-        _block(False)
-
-    @pl.when(kb == last_kb)
+    @pl.when(kb == _last_k_block(causal, j, block_q, block_k, nk))
     def _finalize():
-        if S == 1:
-            dq_ref[0] = dq_scr[0].astype(dq_ref.dtype)
-        else:
-            dq_ref[0] = jnp.concatenate(
-                [dq_scr[sh] for sh in range(S)], axis=-1
-            ).astype(dq_ref.dtype)
+        dq_ref[0] = _side_by_side([dq_scr[sh] for sh in range(S)],
+                                  dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, nq,
@@ -550,103 +567,27 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, nq,
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
 
-    wq = _pick_block(block_q, DIAG_W)
-    wk = _pick_block(block_k, DIAG_W)
-
-    def _delta(sh, rows, do, o):
-        """delta = rowsum(do*o) for one sub-head's q rows — computed once
-        per (sub-head, row group), NOT per k sub-tile."""
-        d_row = jnp.sum(
-            do[rows].astype(jnp.float32) * o[rows].astype(jnp.float32),
-            axis=-1, keepdims=True)
-        if dlse_ref is not None:
-            d_row = d_row - dlse_ref[sh][rows][:, :1]
-        return d_row
-
-    def _sub(sh, rows, cols, k, v, q, do, delta, masked, q0, k0):
-        """One (q-rows, k-cols) sub-tile of the dk/dv math: accumulates
-        into the k-row slices of the scratch accumulators."""
-        lse = lse_ref[sh]
-        s = jax.lax.dot_general(
-            q[rows], k[cols], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if masked:
-            shape = (s.shape[0], s.shape[1])
-            q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-            k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[rows][:, :1])
-        dv_scr[sh, cols] += jax.lax.dot_general(
-            p.astype(do.dtype), do[rows], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do[rows], v[cols], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, :1]) * sm_scale).astype(q.dtype)
-        dk_scr[sh, cols] += jax.lax.dot_general(
-            ds, q[rows], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    def _views(sh):
-        sl = slice(sh * d, (sh + 1) * d)
-        return (k_ref[0][:, sl], v_ref[0][:, sl], q_ref[0][:, sl],
-                do_ref[0][:, sl], o_ref[0][:, sl])
-
-    def _block(masked):
+    def _tile(rows, cols, mask, masked):
         for sh in range(S):
-            k, v, q, do, o = _views(sh)
-            delta = _delta(sh, slice(None), do, o)
-            _sub(sh, slice(None), slice(None), k, v, q, do, delta, masked,
-                 jq * block_q, kb * block_k)
+            sl = slice(sh * d, (sh + 1) * d)
+            q, do = q_ref[0, rows, sl], do_ref[0, rows, sl]
+            delta = _bwd_delta(
+                do, o_ref[0, rows, sl],
+                None if dlse_ref is None else dlse_ref[sh, rows])
+            p = _bwd_p(q, k_ref[0, cols, sl], lse_ref[sh, rows], sm_scale,
+                       mask, masked)
+            dv_scr[sh, cols] += _dot_t(p.astype(do.dtype), do)
+            ds = _bwd_ds(p, do, v_ref[0, cols, sl], delta, sm_scale)
+            dk_scr[sh, cols] += _dot_t(ds.astype(q.dtype), q)
 
-    def _diag_block():
-        for sh in range(S):
-            k, v, q, do, o = _views(sh)
-            for qs in range(block_q // wq):
-                rows = slice(qs * wq, (qs + 1) * wq)
-                delta = _delta(sh, rows, do, o)
-                for ks in range(block_k // wk):
-                    cols = slice(ks * wk, (ks + 1) * wk)
-
-                    def _go(masked, sh=sh, rows=rows, cols=cols, qs=qs,
-                            ks=ks, k=k, v=v, q=q, do=do, delta=delta):
-                        _sub(sh, rows, cols, k, v, q, do, delta, masked,
-                             jq * block_q + qs * wq,
-                             kb * block_k + ks * wk)
-
-                    live = _diag_subtile_live(jq, kb, qs, ks, block_q,
-                                              block_k, wq, wk)
-                    crossing = _diag_subtile_needs_mask(
-                        jq, kb, qs, ks, block_q, block_k, wq, wk)
-                    pl.when(jnp.logical_and(live, crossing))(
-                        lambda _g=_go: _g(True))
-                    pl.when(jnp.logical_and(
-                        live, jnp.logical_not(crossing)))(
-                        lambda _g=_go: _g(False))
-
-    if causal:
-        # q block jq touches k block kb iff its last row is at/below the
-        # block diagonal: (jq+1)*bq - 1 >= kb*bk
-        on = jq >= (kb * block_k) // block_q
-        unmasked = jq * block_q >= (kb + 1) * block_k - 1
-        pl.when(jnp.logical_and(on, unmasked))(lambda: _block(False))
-        pl.when(jnp.logical_and(on, jnp.logical_not(unmasked)))(
-            _diag_block)
-    else:
-        _block(False)
+    _walk_cell(_tile, causal, jq * block_q - kb * block_k, block_q, block_k)
 
     @pl.when(jq == nq - 1)
     def _finalize():
-        if S == 1:
-            dk_ref[0] = dk_scr[0].astype(dk_ref.dtype)
-            dv_ref[0] = dv_scr[0].astype(dv_ref.dtype)
-        else:
-            dk_ref[0] = jnp.concatenate(
-                [dk_scr[sh] for sh in range(S)], axis=-1
-            ).astype(dk_ref.dtype)
-            dv_ref[0] = jnp.concatenate(
-                [dv_scr[sh] for sh in range(S)], axis=-1
-            ).astype(dv_ref.dtype)
+        dk_ref[0] = _side_by_side([dk_scr[sh] for sh in range(S)],
+                                  dk_ref.dtype)
+        dv_ref[0] = _side_by_side([dv_scr[sh] for sh in range(S)],
+                                  dv_ref.dtype)
 
 
 def _bwd_fused_kernel(*refs, sm_scale, causal, block_q, block_k, nq,
@@ -657,165 +598,61 @@ def _bwd_fused_kernel(*refs, sm_scale, causal, block_q, block_k, nq,
     emits dk/dv via VMEM accumulators plus dq as per-k-block partials
     ``dq_part[kb]`` that the caller reduces over kb.  Used when the
     partial buffer is small (nk grows with t; the split kernels remain
-    the long-context path)."""
+    the long-context path).  ``_walk_cell`` takes every q row of a live
+    cell through exactly one tile, so a tile's ``ds @ k`` IS those rows'
+    partial and is stored as it is made."""
     import jax.experimental.pallas as pl
 
     if has_dlse:
         (k_ref, v_ref, q_ref, do_ref, o_ref, lse_ref, dlse_ref,
-         dqp_ref, dk_ref, dv_ref, dk_scr, dv_scr, dqp_scr) = refs
+         dqp_ref, dk_ref, dv_ref, dk_scr, dv_scr) = refs
     else:
         (k_ref, v_ref, q_ref, do_ref, o_ref, lse_ref,
-         dqp_ref, dk_ref, dv_ref, dk_scr, dv_scr, dqp_scr) = refs
+         dqp_ref, dk_ref, dv_ref, dk_scr, dv_scr) = refs
         dlse_ref = None
 
     kb = pl.program_id(1)
     jq = pl.program_id(2)
     S = sub_heads
     d = q_ref.shape[-1] // S
-    wq = _pick_block(block_q, DIAG_W)
-    wk = _pick_block(block_k, DIAG_W)
 
     @pl.when(jq == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
 
-    def _views(sh):
-        sl = slice(sh * d, (sh + 1) * d)
-        return (k_ref[0][:, sl], v_ref[0][:, sl], q_ref[0][:, sl],
-                do_ref[0][:, sl], o_ref[0][:, sl])
-
-    def _block(masked):
-        dqps = []
+    def _tile(rows, cols, mask, masked):
+        dqs = []
         for sh in range(S):
-            k, v, q, do, o = _views(sh)
-            lse = lse_ref[sh]
-            delta = jnp.sum(
-                do.astype(jnp.float32) * o.astype(jnp.float32),
-                axis=-1, keepdims=True)
-            if dlse_ref is not None:
-                delta = delta - dlse_ref[sh][:, :1]
-            bq = q.shape[0]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            if masked:
-                q_pos = jq * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 0)
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 1)
-                s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-            p = jnp.exp(s - lse[:, :1])
-            dv_scr[sh] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta[:, :1]) * sm_scale).astype(q.dtype)
-            dk_scr[sh] += jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dqps.append(jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-        dqp_ref[0, 0] = (
-            dqps[0] if S == 1 else jnp.concatenate(dqps, axis=-1)
-        ).astype(dqp_ref.dtype)
+            sl = slice(sh * d, (sh + 1) * d)
+            q, do, k = (q_ref[0, rows, sl], do_ref[0, rows, sl],
+                        k_ref[0, cols, sl])
+            delta = _bwd_delta(
+                do, o_ref[0, rows, sl],
+                None if dlse_ref is None else dlse_ref[sh, rows])
+            p = _bwd_p(q, k, lse_ref[sh, rows], sm_scale, mask, masked)
+            dv_scr[sh, cols] += _dot_t(p.astype(do.dtype), do)
+            ds = _bwd_ds(p, do, v_ref[0, cols, sl], delta,
+                         sm_scale).astype(q.dtype)
+            dk_scr[sh, cols] += _dot_t(ds, q)
+            dqs.append(_dot(ds, k))
+        dqp_ref[0, 0, rows] = _side_by_side(dqs, dqp_ref.dtype)
 
-    def _diag_block():
-        # triangular straddling cell: dq partials accumulate in the f32
-        # dqp scratch across live sub-tiles (skipped sub-tiles leave
-        # their zeros), then one store; dk/dv accumulate into the k-row
-        # slices of their scratches exactly like the split kernel
-        dqp_scr[...] = jnp.zeros_like(dqp_scr[...])
-        for sh in range(S):
-            k, v, q, do, o = _views(sh)
-            for qs in range(block_q // wq):
-                rows = slice(qs * wq, (qs + 1) * wq)
-                # delta once per (sub-head, row group), not per k sub-tile
-                delta0 = jnp.sum(
-                    do[rows].astype(jnp.float32)
-                    * o[rows].astype(jnp.float32),
-                    axis=-1, keepdims=True)
-                if dlse_ref is not None:
-                    delta0 = delta0 - dlse_ref[sh][rows][:, :1]
-                for ks in range(block_k // wk):
-                    cols = slice(ks * wk, (ks + 1) * wk)
-
-                    def _go(masked, sh=sh, rows=rows, cols=cols, qs=qs,
-                            ks=ks, k=k, v=v, q=q, do=do, delta=delta0):
-                        lse = lse_ref[sh]
-                        s = jax.lax.dot_general(
-                            q[rows], k[cols], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-                        if masked:
-                            shape = (s.shape[0], s.shape[1])
-                            q_pos = (jq * block_q + qs * wq
-                                     + jax.lax.broadcasted_iota(
-                                         jnp.int32, shape, 0))
-                            k_pos = (kb * block_k + ks * wk
-                                     + jax.lax.broadcasted_iota(
-                                         jnp.int32, shape, 1))
-                            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-                        p = jnp.exp(s - lse[rows][:, :1])
-                        dv_scr[sh, cols] += jax.lax.dot_general(
-                            p.astype(do.dtype), do[rows],
-                            (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-                        dp = jax.lax.dot_general(
-                            do[rows], v[cols], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-                        ds = (p * (dp - delta[:, :1]) * sm_scale).astype(
-                            q.dtype)
-                        dk_scr[sh, cols] += jax.lax.dot_general(
-                            ds, q[rows], (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-                        dqp_scr[sh, rows] += jax.lax.dot_general(
-                            ds, k[cols], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-
-                    live = _diag_subtile_live(jq, kb, qs, ks, block_q,
-                                              block_k, wq, wk)
-                    crossing = _diag_subtile_needs_mask(
-                        jq, kb, qs, ks, block_q, block_k, wq, wk)
-                    pl.when(jnp.logical_and(live, crossing))(
-                        lambda _g=_go: _g(True))
-                    pl.when(jnp.logical_and(
-                        live, jnp.logical_not(crossing)))(
-                        lambda _g=_go: _g(False))
-        dqp_ref[0, 0] = (
-            dqp_scr[0] if S == 1 else jnp.concatenate(
-                [dqp_scr[sh] for sh in range(S)], axis=-1)
-        ).astype(dqp_ref.dtype)
-
+    off = jq * block_q - kb * block_k
+    _walk_cell(_tile, causal, off, block_q, block_k)
     if causal:
-        on = jq >= (kb * block_k) // block_q
-        unmasked = jq * block_q >= (kb + 1) * block_k - 1
-        pl.when(jnp.logical_and(on, unmasked))(lambda: _block(False))
-        pl.when(jnp.logical_and(on, jnp.logical_not(unmasked)))(
-            _diag_block)
-
         # skipped cells still own their dq_part block — zero it so the
         # caller's reduce over kb sees no garbage
-        @pl.when(jnp.logical_not(on))
+        @pl.when(off <= -block_q)
         def _zero():
             dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
-    else:
-        _block(False)
 
     @pl.when(jq == nq - 1)
     def _finalize():
-        if S == 1:
-            dk_ref[0] = dk_scr[0].astype(dk_ref.dtype)
-            dv_ref[0] = dv_scr[0].astype(dv_ref.dtype)
-        else:
-            dk_ref[0] = jnp.concatenate(
-                [dk_scr[sh] for sh in range(S)], axis=-1
-            ).astype(dk_ref.dtype)
-            dv_ref[0] = jnp.concatenate(
-                [dv_scr[sh] for sh in range(S)], axis=-1
-            ).astype(dv_ref.dtype)
+        dk_ref[0] = _side_by_side([dk_scr[sh] for sh in range(S)],
+                                  dk_ref.dtype)
+        dv_ref[0] = _side_by_side([dv_scr[sh] for sh in range(S)],
+                                  dv_ref.dtype)
 
 
 # fused-backward dq partials budget: [nk, bh, t, d] must stay under this
@@ -838,9 +675,12 @@ def _flash_bwd_fused(q, k, v, o, lse, do, sm_scale, causal, block_q,
     nk = t_k // block_k
     has_dlse = dlse is not None
 
+    live = _live_q_block(causal, block_q, block_k, nq)
     kspec = pl.BlockSpec((1, block_k, width), lambda i, kb, jq: kix(i, kb))
-    qspec = pl.BlockSpec((1, block_q, width), lambda i, kb, jq: qix(i, jq))
-    qstat = pl.BlockSpec((S, block_q, 1), lambda i, kb, jq: (i, jq, 0))
+    qspec = pl.BlockSpec((1, block_q, width),
+                         lambda i, kb, jq: qix(i, live(kb, jq)))
+    qstat = pl.BlockSpec((S, block_q, 1),
+                         lambda i, kb, jq: (i, live(kb, jq), 0))
     in_specs = [kspec, kspec, qspec, qspec, qspec, qstat]
     args = [k, v, q, do, o, lse]
     if has_dlse:
@@ -869,8 +709,7 @@ def _flash_bwd_fused(q, k, v, o, lse, do, sm_scale, causal, block_q,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((S, block_k, d_sub), jnp.float32),
-                        pltpu.VMEM((S, block_k, d_sub), jnp.float32),
-                        pltpu.VMEM((S, block_q, d_sub), jnp.float32)],
+                        pltpu.VMEM((S, block_k, d_sub), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_bwd_fused",
@@ -908,7 +747,8 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                                 n_head=n_head, sub_heads=S)
 
     qspec = pl.BlockSpec((1, block_q, width), lambda i, j, kb: qix(i, j))
-    kspec = pl.BlockSpec((1, block_k, width), lambda i, j, kb: kix(i, kb))
+    kspec = pl.BlockSpec((1, block_k, width),
+                         _live_k_block(kix, causal, block_q, block_k))
     qstat = pl.BlockSpec((S, block_q, 1), lambda i, j, kb: (i, j, 0))
     dq_in_specs = [qspec, kspec, kspec, qspec, qspec, qstat]
     dq_args = [q, k, v, do, o, lse]
@@ -930,9 +770,12 @@ def _flash_bwd(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
         name="flash_bwd_dq",
     )(*dq_args)[0]
 
+    live = _live_q_block(causal, block_q, block_k, nq)
     kspec2 = pl.BlockSpec((1, block_k, width), lambda i, kb, jq: kix(i, kb))
-    qspec2 = pl.BlockSpec((1, block_q, width), lambda i, kb, jq: qix(i, jq))
-    qstat2 = pl.BlockSpec((S, block_q, 1), lambda i, kb, jq: (i, jq, 0))
+    qspec2 = pl.BlockSpec((1, block_q, width),
+                          lambda i, kb, jq: qix(i, live(kb, jq)))
+    qstat2 = pl.BlockSpec((S, block_q, 1),
+                          lambda i, kb, jq: (i, live(kb, jq), 0))
     dkv_in_specs = [kspec2, kspec2, qspec2, qspec2, qspec2, qstat2]
     dkv_args = [k, v, q, do, o, lse]
     if has_dlse:

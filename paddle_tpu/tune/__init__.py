@@ -2,7 +2,7 @@
 
 Every performance knob that decides whether a configuration compiles
 and how fast it runs — flash ``block_q``/``block_k``, the ``DIAG_W``
-causal sub-tile width, packed ``sub_heads`` routing, the remat/offload
+diagonal strip height, packed ``sub_heads`` routing, the remat/offload
 policy, gradient accumulation — used to be hand-picked and global.
 This package makes them MEASURED, per workload key
 ``(op, seq_len, d_head, n_heads, dtype, platform, remat)``:

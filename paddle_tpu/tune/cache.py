@@ -63,7 +63,7 @@ def cache_path():
 
 def geometry_fingerprint():
     """Content hash of the kernel-geometry decision surface.  Any change
-    to the diagonal sub-tile width, the packed-head routing table, the
+    to the causal cell walk, the packed-head routing table, the
     block-picking rule, or the flash backward residual contract changes
     the hash — and invalidates every cached schedule measured against
     the old geometry."""
@@ -71,14 +71,15 @@ def geometry_fingerprint():
 
     basis = (
         CACHE_SCHEMA_VERSION,
-        # NOT pa.DIAG_W: the sub-tile width is itself a tunable the
+        # NOT pa.DIAG_W: the strip height is itself a tunable the
         # cache stores (and applies via apply_tuned_diag_w) — hashing
         # its current value would make a tuned cache invalidate itself.
-        # The diagonal SCHEME is covered by sampling its decision rule:
-        tuple(bool(pa._diag_subtile_live(j, kb, qs, ks, 1024, 1024,
-                                         256, 256))
-              for j in (0, 1, 3) for kb in (0, 1, 3)
-              for qs in (0, 3) for ks in (0, 3)),
+        # The cell WALK is covered by sampling what it schedules:
+        tuple((tuple(w), w.updates_per_row, w.branches_per_cell)
+              for w in (pa.causal_flash_flops(t, t, 128, bq, bk, diag_w=256)
+                        for t, bq, bk in ((2048, 1024, 1024),
+                                          (4096, 1024, 1024),
+                                          (4096, 512, 1024)))),
         pa.LSE_LANES,
         tuple(pa.FLASH_BWD_RESIDUALS),
         # the packed-head routing table over the geometries that matter

@@ -44,7 +44,7 @@ class PreflightRejected(Exception):
 
 @contextlib.contextmanager
 def _diag_w(width):
-    """Temporarily pin the causal diagonal sub-tile width while a
+    """Temporarily pin the causal diagonal strip height while a
     candidate compiles (the kernels read ``pallas_attention.DIAG_W`` at
     trace time; the search is single-threaded).  A PADDLE_TPU_DIAG_W
     env pin wins — candidates then all run at the pinned width."""
@@ -254,7 +254,7 @@ def tune_gpt_step(seq_len, n_layer, d_model, n_head, vocab, batch,
     from ..ops import pallas_attention as pa
 
     if pa._DIAG_W_ENV:
-        # env-pinned sub-tile width: every candidate runs (and is
+        # env-pinned strip height: every candidate runs (and is
         # labeled) at the pin — anything else would cache a config
         # measured at a width it does not record
         diag_ws = (pa._DIAG_W_ENV,)

@@ -3,8 +3,8 @@
 Two tunable workload kinds:
 
 - ``op="flash"`` — kernel geometry for one attention shape:
-  ``block_q`` / ``block_k`` flash tiles, the ``DIAG_W`` causal sub-tile
-  width, and the packed-vs-4-D head routing.
+  ``block_q`` / ``block_k`` flash tiles, the ``DIAG_W`` strip height of the
+  causal diagonal cells, and the packed-vs-4-D head routing.
 - ``op="gpt_step"`` — the whole training-step schedule at one sequence
   length: the flash geometry PLUS the remat/offload policy and the
   gradient-accumulation factor (the two capacity levers that decide
@@ -276,7 +276,7 @@ def prune_static(seq_len, d_head, n_head, candidates, dtype_size=2,
       budget (a too-big block pair fails Mosaic at compile time — or
       worse, compiles and thrashes).
     - Roofline: ``causal_flash_flops`` simulates the kernel's exact
-      block/sub-tile skip logic; a candidate scheduling more than
+      cell and strip walk; a candidate scheduling more than
       ``roofline_slack`` x the best candidate's scheduled flops cannot
       win on the MXU and is rejected unmeasured.
     - HBM (optional): when ``hbm_budget`` and an ``hbm_model(cand)``

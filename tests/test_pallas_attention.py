@@ -398,18 +398,21 @@ def test_flash_packed_d64_split_bwd_matches_fused(monkeypatch):
 
 
 def test_causal_triangular_no_masked_half_flops():
-    """Flop accounting via the kernel's OWN sub-tile skip predicate
-    (``_diag_subtile_live`` is shared between the kernel and
-    ``causal_flash_flops``): the masked halves of diagonal blocks are
-    never scheduled — only the DIAG_W-wide band along the diagonal
-    remains, and no scheduled sub-tile lies fully above the diagonal."""
+    """Flop accounting via the kernel's OWN cell walk (``_cell_kind`` and
+    ``_strip_cols`` are what ``_walk_cell`` runs and what
+    ``causal_flash_flops`` counts): the masked halves of diagonal blocks
+    are never scheduled — only the DIAG_W-high steps along the diagonal
+    remain, no scheduled column lies wholly above a strip's last row, and
+    the walk's bookkeeping (softmax updates a q row, branches inside a
+    cell) is pinned at the geometries that train."""
     from paddle_tpu.ops.pallas_attention import (
-        DIAG_W, causal_flash_flops, _diag_subtile_live)
+        causal_flash_flops, _cell_kind, _strip_cols)
 
     # flagship geometry: t=4096, 1024 blocks.  Old full-tile + select
     # spelling scheduled ~1.25x the useful flops; triangular must be
     # within the diagonal band bound (~1 + DIAG_W/t + slack).
-    sched, useful = causal_flash_flops(4096, 4096, 128, 1024, 1024)
+    walk = causal_flash_flops(4096, 4096, 128, 1024, 1024, diag_w=256)
+    sched, useful = walk
     assert sched / useful < 1.08, sched / useful
     # old spelling for comparison: every cell at/below the block diagonal
     # fully computed
@@ -417,25 +420,46 @@ def test_causal_triangular_no_masked_half_flops():
     old = sum(min(((j + 1) * 1024 - 1) // 1024, nk - 1) + 1
               for j in range(nq)) * 1024 * 1024 * 4 * 128
     assert sched < 0.9 * old
+    # one update a row in each of its q block's live cells (256 x 256
+    # sub-tiles made up to 7 a row here, under 32 branches a diagonal
+    # cell), and no branch inside a cell
+    assert (walk.updates_per_row, walk.branches_per_cell) == (4, 0)
+    # the training cell's call: t=2048, two k blocks
+    walk = causal_flash_flops(2048, 2048, 128, 1024, 1024, diag_w=256)
+    assert 1.12 < walk[0] / walk[1] < 1.13
+    assert (walk.updates_per_row, walk.branches_per_cell) == (2, 0)
+    # taller strips: fewer, wider tiles for more masked scores
+    tall = causal_flash_flops(2048, 2048, 128, 1024, 1024, diag_w=512)
+    assert walk[0] < tall[0] and tall[1] == walk[1]
+    assert tall.updates_per_row == 2
 
-    # grid-shape assertion: a sub-tile fully above the diagonal is never
-    # live, and every unmasked sub-tile below it is
+    # grid-shape assertion: a cell is full, straddling or skipped by where
+    # the diagonal lies, and a strip of the diagonal cell takes exactly
+    # the columns up to its last row, the mask on those past its first
     bq = bk = 1024
-    w = DIAG_W
+    w = 256
     for j in range(4):
         for kb in range(4):
-            for qs in range(bq // w):
-                for ks in range(bk // w):
-                    row_last = j * bq + (qs + 1) * w - 1
-                    col0 = kb * bk + ks * w
-                    assert _diag_subtile_live(
-                        j, kb, qs, ks, bq, bk, w, w) == (col0 <= row_last)
+            full, straddling = _cell_kind(j * bq - kb * bk, bq, bk)
+            assert (full, straddling) == (kb < j, kb == j)
+    for qs in range(bq // w):
+        visible, masked = _strip_cols(qs, w, bq, bk)
+        row0, row_last = qs * w, (qs + 1) * w - 1
+        assert visible - 1 == row_last          # last column taken
+        assert visible - masked == row0         # first masked column
+    # unequal blocks: the diagonal may lie anywhere in a straddling cell,
+    # a strip takes every column and masks them all
+    assert _strip_cols(1, 256, 512, 1024) == (1024, 1024)
+    assert _cell_kind(512 - 0, 512, 1024) == (False, True)
+    assert _cell_kind(1536 - 0, 512, 1024) == (True, False)
+    assert _cell_kind(0 - 1024, 512, 1024) == (False, False)
 
 
 def test_causal_triangular_multi_subtile_matches_reference(monkeypatch):
-    """Force the multi-sub-tile triangular path (DIAG_W smaller than the
-    block) and check the forward against the dense reference — the
-    sub-tiled online softmax must reduce to the same attention."""
+    """Force several strips a diagonal cell (DIAG_W smaller than the
+    block) and check the forward against the dense reference — a strip's
+    one update over the columns it sees, masked on the last DIAG_W, must
+    reduce to the same attention."""
     import paddle_tpu.ops.pallas_attention as pa
 
     monkeypatch.setattr(pa, "DIAG_W", 32)
@@ -447,17 +471,20 @@ def test_causal_triangular_multi_subtile_matches_reference(monkeypatch):
     ref = attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
-    # uneven aspect: q blocks narrower than k blocks
-    o2 = pa.flash_attention(q, k, v, causal=True, block_q=64, block_k=128)
-    np.testing.assert_allclose(np.asarray(o2), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    # uneven aspect, both ways: the diagonal lies anywhere in a
+    # straddling cell and the strips' mask follows the program ids
+    for bq, bk in ((64, 128), (128, 32)):
+        o2 = pa.flash_attention(q, k, v, causal=True, block_q=bq,
+                                block_k=bk)
+        np.testing.assert_allclose(np.asarray(o2), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
 
 
 def test_causal_triangular_multi_subtile_grads(monkeypatch):
-    """Gradients through the sub-tiled diagonal cells of BOTH backward
-    spellings (fused, and split dq/dkv with the partial budget forced to
-    0), vs the dense reference — the triangular pass covers the whole
-    causal step, not just the forward."""
+    """Gradients through the strips of the diagonal cells of BOTH
+    backward spellings (fused, and split dq/dkv with the partial budget
+    forced to 0), vs the dense reference — the triangular pass covers the
+    whole causal step, not just the forward."""
     import paddle_tpu.ops.pallas_attention as pa
 
     monkeypatch.setattr(pa, "DIAG_W", 32)
@@ -542,3 +569,87 @@ def test_packed_op_tp_odd_local_heads_falls_back_to_4d():
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.reshape(b, t, h * d)),
                                atol=2e-5, rtol=2e-5)
+
+
+# The cell walk (``_walk_cell``) at every kind of call that reaches it:
+# (api, t_q, t_k, heads, head width, dtype, causal, block_q, block_k,
+# strip height)
+WALKS = {
+    "causal_several_strips": ("4d", 128, 128, 2, 16, "float32", True,
+                              64, 64, 16),
+    "causal_one_strip": ("4d", 128, 128, 2, 16, "float32", True, 64, 64,
+                         64),
+    "noncausal": ("4d", 128, 128, 2, 16, "float32", False, 64, 64, 16),
+    "packed_d128_bf16_strips": ("packed", 128, 128, 2, 128, "bfloat16",
+                                True, 64, 64, 16),
+    "paired_d64_strips": ("packed", 128, 128, 2, 64, "float32", True, 64,
+                          64, 16),
+    "tq_gt_tk_causal": ("4d", 128, 64, 2, 16, "float32", True, 32, 32, 8),
+    "tq_lt_tk_causal": ("4d", 64, 128, 2, 16, "float32", True, 32, 32, 8),
+    "block_q_lt_block_k": ("4d", 128, 128, 2, 16, "float32", True, 32, 64,
+                           16),
+    "block_q_gt_block_k": ("4d", 128, 128, 2, 16, "float32", True, 64, 32,
+                           16),
+    "dlse_path": ("lse", 128, 128, 2, 16, "float32", True, 64, 64, 16),
+    "dlse_path_noncausal": ("lse", 128, 128, 2, 16, "float32", False, 64,
+                            64, 16),
+}
+
+
+@pytest.mark.parametrize("backward", ["fused", "split"])
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_cell_walk_matches_reference(walk, backward, monkeypatch):
+    """Values and gradients of the strip walk against the dense
+    reference, through the fused backward and through the split dq / dkv
+    kernels (the partial budget forced to 0)."""
+    import paddle_tpu.ops.pallas_attention as pa
+
+    api, tq, tk, h, d, dtype, causal, bq, bk, strip = WALKS[walk]
+    monkeypatch.setattr(pa, "DIAG_W", strip)
+    if backward == "split":
+        monkeypatch.setattr(pa, "FUSED_BWD_PARTIAL_BYTES", 0)
+    rng = np.random.default_rng(29)
+    qf, kf, vf = (jnp.asarray(rng.normal(size=(1, t, h, d)) * 0.5,
+                              jnp.float32) for t in (tq, tk, tk))
+    kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True)
+
+    def dense(q, k, v):
+        o = attention_reference(q, k, v, causal=causal)
+        if api != "lse":
+            return o
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((tq, tk), bool)), s, -1e30)
+        return o, jax.scipy.special.logsumexp(s, axis=-1)
+
+    def flash(q, k, v):
+        if api == "4d":
+            return pa.flash_attention(q, k, v, **kw)
+        if api == "lse":
+            return pa.flash_attention_with_lse(q, k, v, **kw)
+        pk = lambda x: x.reshape(1, x.shape[1], h * d)
+        return pa.flash_attention_packed(pk(q), pk(k), pk(v), h,
+                                         **kw).reshape(q.shape)
+
+    def loss(fn):
+        def f(q, k, v):
+            out = fn(q, k, v)
+            o, lse = out if api == "lse" else (out, jnp.zeros(()))
+            o = o.astype(jnp.float32)
+            return jnp.sum(o * jnp.cos(o)) + jnp.sum(jnp.sin(lse))
+        return f
+
+    args = tuple(x.astype(dtype) for x in (qf, kf, vf))
+    tol = dict(atol=2e-5, rtol=2e-4) if dtype == "float32" else dict(
+        atol=4e-2, rtol=4e-2)
+    got, ref = flash(*args), dense(qf, kf, vf)
+    for a, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(r), **tol)
+    g_got = jax.grad(loss(flash), (0, 1, 2))(*args)
+    g_ref = jax.grad(loss(dense), (0, 1, 2))(qf, kf, vf)
+    for a, r, nm in zip(g_got, g_ref, "qkv"):
+        scale = max(float(jnp.abs(r).max()), 1.0)
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32) / scale, np.asarray(r) / scale,
+            err_msg=f"grad wrt {nm}", **tol)
