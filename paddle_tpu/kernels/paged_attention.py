@@ -7,7 +7,7 @@ each slot's KV as a chain of physical blocks named by a block table.
 block, one physical block (or a small group) in flight at a time, the
 logical view ``pool[table] -> [S, T, h, dh]`` never built.
 
-The serving step (``serving/batched_decode._attend_through``) calls ONE
+The serving step (``serving/batched_decode._Cache``) calls ONE
 function, :func:`attend`, which chooses the spelling from what it can
 observe at trace time and from nothing else (no environment variable,
 no tuner, no file):
@@ -24,19 +24,36 @@ no tuner, no file):
 Calling convention (both backends)::
 
     call(q, pool_k, pool_v, table, pos, block_step=None,
-         interpret=None) -> ctx
+         interpret=None, group=1, window=None, scale=None,
+         out_dtype=None) -> ctx
 
     q       [S, W, h, dh]   query window (W=1 for plain decode,
                             W=k+1 for the speculative verify window)
-    pool_k  [num_blocks, B, h, dh]   the physical K pool (one layer)
-    pool_v  [num_blocks, B, h, dh]   the physical V pool
+    pool_k  [num_blocks, B, hk, dh]  the physical K pool (one plane);
+                            ``h = hk * group`` (rows past ``hk``, which
+                            ``pool_rows`` may add, hold nothing)
+    pool_v  [num_blocks, B, hk, dh]  the physical V pool
     table   [S, NB] int32   per-slot block chain (block 0 = trash)
     pos     [S, W]  int32   absolute position of each query; key token
                             ``j`` participates iff ``j <= pos`` (the
                             write-before-attend mask), so trash-block
                             garbage, bucket padding and CoW tails all
                             carry exactly zero attention weight
-    ctx     [S, W, h, dh]   in ``q.dtype``
+    group   Python int      query heads a K/V head: query head ``i``
+                            reads K/V head ``i // group``
+    window  Python int | None   the LOWER bound: with a window key ``j``
+                            participates iff ``pos - window < j <= pos``
+                            (a query sees itself and the ``window - 1``
+                            keys before it)
+    scale   Python float | None   scores' scale, ``1 / sqrt(dh)`` if None
+    ctx     [S, W, h, dh]   in ``out_dtype`` (``q.dtype`` if None)
+
+``group``, ``window``, ``scale`` and ``out_dtype`` are Python constants:
+at their defaults a call lowers to the program it always lowered to.  A
+K/V group is folded into the window by both backends (``_fold_group``):
+the ``group`` query heads of one K/V head become ``group`` window rows
+of ``hk`` heads at the same position, so the kernels below see ``h ==
+hk`` always.
 
 Numerics conventions match the flash kernels (f32 scores via
 ``preferred_element_type``, ``NEG_INF`` masking, f32 ``(m, l, acc)``
@@ -55,13 +72,16 @@ Backends:
   dh]`` in flight).  The universal numerics reference.
 * ``pallas_tpu`` — ``PrefetchScalarGridSpec`` scalar prefetch of table
   and positions; grid ``(S,)``, one step a slot, whose body loops over
-  the LIVE entries of that slot's chain only: ``n_s = clip(max_w
-  pos[s, w] // B + 1, 0, NB)`` (every entry from ``n_s`` on is masked
-  for every row, so it is neither fetched nor computed), each block
+  the LIVE entries of that slot's chain only: from ``f_s = max(min_w
+  pos[s, w] - window + 1, 0) // B`` (0 without a window: every entry
+  before ``f_s`` lies under every row's lower bound) up to ``n_s =
+  clip(max_w pos[s, w] // B + 1, 0, NB)`` (every entry from ``n_s`` on
+  is masked for every row); the others are neither fetched nor
+  computed, each block
   copied from the pool where it lies into a two-deep VMEM buffer while
   ``(m, l, acc)`` carry in VMEM scratch.  A row with ``pos < 0`` has no
   visible key and returns zeros; a slot of such rows (a dead slot, as
-  ``batched_decode._attend_through`` names it) costs one empty grid
+  ``batched_decode._Cache`` names it) costs one empty grid
   step.  Registered available on real TPU only (off-TPU the
   interpret-mode grid would replace one fused XLA loop with a per-block
   Python loop); the oracle suite still covers the kernel logic on CPU by
@@ -76,7 +96,7 @@ from .registry import register_kernel, resolve
 from .xla_ref import NEG_INF
 
 __all__ = ["attend", "DENSE_WINDOW", "paged_attention_ref",
-           "paged_attention_pallas"]
+           "paged_attention_pallas", "pool_rows"]
 
 # From this window width up a window gathers its slot's chain once and
 # attends it densely instead of streaming blocks.  W rows then share one
@@ -87,21 +107,66 @@ __all__ = ["attend", "DENSE_WINDOW", "paged_attention_ref",
 DENSE_WINDOW = 8
 
 
-def attend(q, pool_k, pool_v, table, pos):
+def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
+           out_dtype=None):
     """One layer's attention THROUGH the block table, the one call the
     serving step makes: ``q [S, W, h, dh]``, ``pos [S, W]`` ->
-    ``[S, W, h, dh]``.
+    ``[S, W, h, dh]``; ``group``, ``window``, ``scale`` and
+    ``out_dtype`` as the module docstring has them.
 
     The spelling follows the window's width and the platform, both seen
     at trace time (module docstring): ``W >= DENSE_WINDOW`` is the
-    ``xla_ref`` spelling with ONE step over the whole chain; a narrower
-    window streams blocks with online softmax through the backend the
-    registry resolves."""
+    ``xla_ref`` spelling with ONE step over the whole chain (a lower
+    bound is a mask there); a narrower window streams blocks with online
+    softmax through the backend the registry resolves (the Mosaic loop
+    starts at the window's first block)."""
+    how = dict(group=group, window=window, scale=scale, out_dtype=out_dtype)
     if q.shape[1] >= DENSE_WINDOW:
         return resolve("paged_attention", backend="xla_ref").impl.call(
-            q, pool_k, pool_v, table, pos, block_step=table.shape[1])
+            q, pool_k, pool_v, table, pos, block_step=table.shape[1], **how)
     return resolve("paged_attention").impl.call(q, pool_k, pool_v, table,
-                                                pos)
+                                                pos, **how)
+
+
+def pool_rows(heads, dtype):
+    """Rows a pool block needs on its head axis for ``heads`` K/V heads
+    so that the Mosaic kernel can slice a block out of the pool where it
+    lies (``_block_is_sliceable``) and run its loop form: ``heads``, or
+    the next multiple of 8 for a packed dtype.  The rows added hold
+    nothing: never written, their scores attend zeros."""
+    if jnp.dtype(dtype).itemsize >= 4 or heads % 8 == 0:
+        return heads
+    return -(-heads // 8) * 8
+
+
+def _fold_group(q, pos, group, rows):
+    """``q [S, W, hk * group, dh]`` -> ``[S, W * group, rows, dh]``, ``pos``
+    repeated to match, and the inverse to apply to the context: query
+    head ``i`` reads K/V head ``i // group``, so the ``group`` heads of
+    one K/V head are ``group`` window rows at the same position (heads
+    past ``hk``, where the pool has more ``rows``, are zeros).  With one
+    head a K/V head and no row to spare nothing is traced."""
+    S, W, h, dh = q.shape
+    hk = h // group
+    if hk * group != h or hk > rows:
+        raise ValueError(f"paged_attention: {h} query heads in groups of "
+                         f"{group} over a pool of {rows} K/V rows")
+    if group > 1:
+        q = q.reshape(S, W, hk, group, dh).transpose(0, 1, 3, 2, 4)
+        q = q.reshape(S, W * group, hk, dh)
+        pos = jnp.repeat(pos, group, axis=1)
+    if rows > hk:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, rows - hk), (0, 0)))
+
+    def unfold(ctx):
+        if rows > hk:
+            ctx = ctx[:, :, :hk]
+        if group > 1:
+            ctx = ctx.reshape(S, W, group, hk, dh)
+            ctx = ctx.transpose(0, 1, 3, 2, 4).reshape(S, W, h, dh)
+        return ctx
+
+    return q, pos, unfold
 
 
 def _normalize_block_step(block_step, nb, w=1):
@@ -118,13 +183,17 @@ def _normalize_block_step(block_step, nb, w=1):
 # -- xla_ref: the block-scan oracle ------------------------------------------
 
 def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
-                        interpret=None):
+                        interpret=None, group=1, window=None, scale=None,
+                        out_dtype=None):
     """The oracle spelling: ``lax.scan`` over the block chain with
     online-softmax carry — per step only ``block_step`` physical blocks
     are gathered (``[S, block_step*B, h, dh]``), never the ``T``-wide
-    view.  ``interpret`` is accepted for signature parity and ignored
-    (no Pallas here)."""
+    view.  A lower bound (``window``) is one more term of the mask.
+    ``interpret`` is accepted for signature parity and ignored (no
+    Pallas here)."""
     del interpret
+    q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
+    out_dtype = q.dtype if out_dtype is None else out_dtype
     S, W, h, dh = q.shape
     B = pool_k.shape[1]
     NB = table.shape[1]
@@ -137,8 +206,22 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
         # (>= T) are unconditionally masked below
         tbl = jnp.concatenate(
             [tbl, jnp.zeros((S, pad), jnp.int32)], axis=1)
-    scale = 1.0 / float(dh) ** 0.5
+    if scale is None:
+        scale = 1.0 / float(dh) ** 0.5
     off = jnp.arange(bs * B, dtype=jnp.int32)
+
+    def visible(tok):
+        """``[S, W, 1, n]``: which of the keys at positions ``tok [n]``
+        each row attends."""
+        keep = ((tok[None, None, None, :] <= pos[:, :, None, None])
+                & (tok < T)[None, None, None, :])
+        if window is not None:
+            keep &= tok[None, None, None, :] > (pos[:, :, None, None]
+                                                - window)
+        return keep
+
+    def done(ctx):
+        return unfold(ctx.astype(out_dtype))
 
     if (NB + pad) // bs == 1:
         # one step consumes the whole chain: skip the scan and its
@@ -150,14 +233,12 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
         vb = pool_v[tbl].reshape(S, (NB + pad) * B, h, dh)
         s = jnp.einsum("swhd,sthd->swht", q, kb,
                        preferred_element_type=jnp.float32) * scale
-        keep = ((off[None, None, None, :] <= pos[:, :, None, None])
-                & (off < T)[None, None, None, :])
-        s = jnp.where(keep, s, NEG_INF)
+        s = jnp.where(visible(off), s, NEG_INF)
         p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
         l = jnp.sum(p, axis=-1)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         ctx = jnp.einsum("swht,sthd->swhd", p, vb.astype(jnp.float32))
-        return (ctx / l_safe[..., None]).astype(q.dtype)
+        return done(ctx / l_safe[..., None])
 
     def step(carry, i):
         m, l, acc = carry
@@ -167,9 +248,7 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
         tok = i * (bs * B) + off                                # [bs*B]
         s = jnp.einsum("swhd,sthd->swht", q, kb,
                        preferred_element_type=jnp.float32) * scale
-        keep = ((tok[None, None, None, :] <= pos[:, :, None, None])
-                & (tok < T)[None, None, None, :])
-        s = jnp.where(keep, s, NEG_INF)
+        s = jnp.where(visible(tok), s, NEG_INF)
         m2 = jnp.maximum(m, jnp.max(s, axis=-1))
         alpha = jnp.exp(m - m2)
         p = jnp.exp(s - m2[..., None])
@@ -185,7 +264,7 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, a0), jnp.arange(nsteps, dtype=jnp.int32))
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    return (acc / l_safe[..., None]).astype(q.dtype)
+    return done(acc / l_safe[..., None])
 
 
 # -- pallas_tpu: scalar-prefetch block streaming -----------------------------
@@ -201,7 +280,8 @@ def _block_is_sliceable(pool):
 
 
 def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
-                           interpret=None):
+                           interpret=None, group=1, window=None, scale=None,
+                           out_dtype=None):
     """The Mosaic kernel: it visits the LIVE entries of each slot's chain
     and no others.  The block TABLE and the query POSITIONS are the
     scalar-prefetch arguments (SMEM).  Slot ``s`` has ``n_s = clip(max_w
@@ -213,9 +293,14 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     with ``pos < 0`` has none and returns ZEROS (``l == 0 -> 1`` over an
     ``acc`` of zeros); a slot whose rows are all negative costs one empty
     grid step.  That is how the serving step names a dead slot
-    (``batched_decode._attend_through``).
+    (``batched_decode._Cache``).  With a ``window`` the chain
+    also has a FIRST live entry, ``f_s = max(min_w pos[s, w] - window +
+    1, 0) // B``: every key of an entry before it lies under every row's
+    lower bound, so the loop starts there and the entries before it are
+    neither fetched nor computed either (in the grid form: skipped, with
+    the index map clamped to ``[f_s, n_s)`` so that nothing is fetched).
 
-    Grid ``(S,)``, one step a slot, whose body LOOPS ``n_s`` times over
+    Grid ``(S,)``, one step a slot, whose body LOOPS over ``[f_s, n_s)`` of
     the slot's own chain: the pools stay where they are (``pl.ANY``: no
     BlockSpec, no pool-sized copy), iteration ``i`` copies block
     ``table[s, i]`` of K and of V into one half of a two-deep VMEM buffer
@@ -245,10 +330,13 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     del block_step
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
+    out_dtype = q.dtype if out_dtype is None else out_dtype
     S, W, h, dh = q.shape
     B = pool_k.shape[1]
     NB = table.shape[1]
-    scale = 1.0 / float(dh) ** 0.5
+    if scale is None:
+        scale = 1.0 / float(dh) ** 0.5
 
     def live_entries(pos_ref, s_id):
         top = pos_ref[s_id, 0]
@@ -257,6 +345,16 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         # floor(top / B) + 1, and 0 for a window with no row at a
         # position >= 0
         return jnp.minimum(jax.lax.div(jnp.maximum(top, -1) + B, B), NB)
+
+    def first_entry(pos_ref, s_id):
+        """The entry that holds the lowest key any row's lower bound
+        lets through; the Python constant 0 without a window."""
+        if window is None:
+            return 0
+        low = pos_ref[s_id, 0]
+        for w in range(1, W):
+            low = jnp.minimum(low, pos_ref[s_id, w])
+        return jax.lax.div(jnp.maximum(low - window + 1, 0), B)
 
     # per-(window, head) softmax statistics sit lane-replicated in
     # scratch, the flash kernels' convention: a [h, 1] row is below
@@ -275,7 +373,10 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         for w in range(W):
             qw = q_ref[0, w].astype(jnp.float32)           # [h, dh]
             s = jnp.sum(kb * qw[None], axis=-1, keepdims=True) * scale
-            s = jnp.where(tok <= pos_ref[s_id, w], s, NEG_INF)  # [B, h, 1]
+            keep = tok <= pos_ref[s_id, w]
+            if window is not None:
+                keep &= tok > pos_ref[s_id, w] - window
+            s = jnp.where(keep, s, NEG_INF)                 # [B, h, 1]
             m = m_ref[w][:, :1]                            # [h, 1]
             m2 = jnp.maximum(m, jnp.max(s, axis=0))
             alpha = jnp.exp(m - m2)
@@ -296,6 +397,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
                     k_buf, v_buf, sem, *st):
         s_id = pl.program_id(0)
         n = live_entries(pos_ref, s_id)
+        first = first_entry(pos_ref, s_id)
 
         def fetch(i, half):
             blk = tbl[s_id, i]
@@ -308,11 +410,11 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
 
         @pl.when(n > 0)
         def _first():
-            for c in fetch(0, 0):
+            for c in fetch(first, 0):
                 c.start()
 
         def block(i, _):
-            half = jax.lax.rem(i, 2)
+            half = jax.lax.rem(i if window is None else i - first, 2)
 
             @pl.when(i + 1 < n)
             def _next():
@@ -323,7 +425,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
                 c.wait()
             fold(i, k_buf[half], v_buf[half], s_id, pos_ref, q_ref, *st)
 
-        jax.lax.fori_loop(0, n, block, None)
+        jax.lax.fori_loop(first, n, block, None)
         finish(o_ref, *st)
 
     def grid_kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref, *st):
@@ -335,7 +437,11 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         def _init():
             init(*st)
 
-        @pl.when(nb < live_entries(pos_ref, s_id))
+        live = nb < live_entries(pos_ref, s_id)
+        if window is not None:
+            live &= nb >= first_entry(pos_ref, s_id)
+
+        @pl.when(live)
         def _live():
             fold(nb, k_ref[0], v_ref[0], s_id, pos_ref, q_ref, *st)
 
@@ -355,6 +461,8 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     else:
         def last_live(s, nb, tbl, pos):
             i = jnp.minimum(nb, jnp.maximum(live_entries(pos, s) - 1, 0))
+            if window is not None:
+                i = jnp.maximum(i, first_entry(pos, s))
             return (tbl[s, i], 0, 0, 0)
 
         kernel, grid, semantics = grid_kernel, (S, NB), ("parallel",
@@ -362,17 +470,18 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         kv_spec = pl.BlockSpec((1, B, h, dh), last_live)
         scratch = stats
     row = pl.BlockSpec((1, W, h, dh), lambda s, *_: (s, 0, 0, 0))
-    return pl.pallas_call(
+    ctx = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid, in_specs=[row, kv_spec, kv_spec],
             out_specs=row, scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((S, W, h, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, W, h, dh), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         interpret=bool(interpret),
         name="paged_attention",
     )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v)
+    return unfold(ctx)
 
 
 def _tpu_available():

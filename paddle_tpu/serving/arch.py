@@ -4,10 +4,15 @@ The engine (``serving/engine.py``) and its compiled entry points
 (``serving/batched_decode.py``) know blocks, tables, slots and windows;
 what a model is made of they take from here:
 
-* the attention geometry (``n_head``, ``head_dim``) and the number of
-  K/V planes a cached token holds (``kv_planes = n_layer * passes``: a
-  stack that runs ``passes`` times over the same weights caches its own
-  K and V in every pass), which size the pool;
+* the attention geometry (``n_head``, ``kv_heads``, ``head_dim``) and
+  the K/V planes a cached token holds: ``planes`` lists the pool arrays,
+  each with its lower bound (``window``) or ``None``, and ``kv_planes =
+  len(planes) * passes`` (one array a layer unless the architecture says
+  otherwise; a stack that runs ``passes`` times over the same weights
+  caches its own K and V in every pass), which size the pool;
+* the state a slot holds BESIDE the pool (``state_spec``): per-slot
+  arrays of fixed shape that never grow with the context, what a
+  recurrent layer carries from one token to the next;
 * the forward, written ONCE per architecture as a function of
   (parameters, rows, positions, a cache interface): ``embed`` makes the
   rows, ``stack`` runs the layers and ``head`` turns rows into float32
@@ -15,18 +20,34 @@ what a model is made of they take from here:
   decode step feeds ``[S, d]``, a window ``[S, W, d]``), so the decode
   chunk, prefill and the speculative verify window run the same lines.
 
-The cache interface is one callable the entry points build::
+The cache interface is one object the entry points build
+(``batched_decode._Cache``), called for attention::
 
-    ctx, planes = attend(planes, layer, pass_idx, q, k, v)
+    ctx, planes = attend(planes, plane, pass_idx, q, k, v, **how)
 
-It writes ``k`` and ``v`` (``[..., n_head, head_dim]``) into the plane of
-``(pass_idx, layer)`` through the block table, attends everything that
-plane holds up to each row's position, and returns the context in
-``q``'s shape together with the updated planes.  ``pass_idx`` is the
-Python integer 0 in a stack that runs once, and may be a traced scalar
-inside a loop over passes.
+It writes ``k`` and ``v`` (``[..., kv_heads, head_dim]``) into pool
+array ``plane`` (of pass ``pass_idx``) through the block table, attends
+everything that plane holds up to each row's position, and returns the
+context in ``q``'s shape together with the updated planes.  ``pass_idx``
+is the Python integer 0 in a stack that runs once, and may be a traced
+scalar inside a loop over passes.  ``how`` is what
+``kernels.paged_attention.attend`` takes beyond the table: ``group``,
+``window``, ``scale``, ``out_dtype``.  With ``k`` and ``v`` ``None``
+nothing is written: a READ-ONLY attend of a plane another layer wrote.
 
-Two architectures are here: ``Gpt2`` (the block of
+It has a STATE side for per-slot recurrent state, ``planes``' third
+member, one tuple of arrays per state layer::
+
+    rows = attend.state(planes, i)        # each [S, ...]: the slots' rows
+    planes = attend.put_state(planes, i, rows')
+    attend.valid                          # [S] or [S, W] bool
+
+``state`` gives the rows of the slots this call computes (zeros where a
+prompt's first piece starts), ``put_state`` writes them back, and
+``valid`` says which rows are real: a recurrence advances its state over
+those ONLY (bucket padding, a dead slot's rows leave it as it was).
+
+Three architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -34,14 +55,22 @@ rotary positions, a gated SiLU FFN, no biases, the whole stack run
 ``passes`` times over the same weights with the final norm closing every
 pass and an exit gate whose weights are held: the Ouro / LoopLM layout,
 arXiv:2510.25741).  ``models/ouro_reference.py`` is the second one's
-plain reference.
+plain reference.  ``SambaY`` is a stack that is NOT one repeated block:
+the decoder-hybrid-decoder of arXiv:2507.06607 with differential
+attention (arXiv:2410.05258), five kinds of mixer in a fixed order,
+fewer K/V heads than heads, window and full planes, one plane read by
+several layers, and Mamba state beside the pool;
+``models/sambay_reference.py`` is its plain reference.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "STACK_SCOPE"]
+from ..kernels import paged_attention as _paged
+
+__all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY",
+           "STACK_SCOPE"]
 
 # the jax.named_scope every architecture's stack runs under (op_name
 # metadata of the lowered program: stack vs. embedding, head and argmax)
@@ -69,19 +98,57 @@ class Architecture:
         return self.d_model // self.n_head
 
     @property
+    def kv_heads(self):
+        """K/V heads a plane holds of each position (``n_head`` unless
+        query heads share them)."""
+        return self.n_head
+
+    @property
+    def planes(self):
+        """One entry a pool array: the plane's lower bound (a position
+        attends itself and the ``window - 1`` before it) or ``None`` for
+        a plane attended whole.  One full plane a layer by default."""
+        return (None,) * self.n_layer
+
+    @property
     def kv_planes(self):
         """K/V planes one cached token holds: a plane of its own for
-        every (pass, layer)."""
-        return self.n_layer * self.passes
+        every (pass, pool array)."""
+        return len(self.planes) * self.passes
+
+    @property
+    def plane_reads(self):
+        """The paged-attention calls one token makes, as ``((window,
+        calls), ...)``: every plane once unless layers share one."""
+        reads = {}
+        for window in self.planes:
+            reads[window] = reads.get(window, 0) + self.passes
+        return tuple(reads.items())
+
+    def pool_block_shape(self, block_tokens, dtype):
+        """``[block_tokens, rows, lanes]`` of one block of a pool array
+        in ``dtype``."""
+        return (block_tokens, self.kv_heads, self.head_dim)
 
     def kv_block_bytes(self, block_tokens, itemsize):
         """Bytes one block of one plane holds: K and V of
         ``block_tokens`` positions."""
-        return 2 * block_tokens * self.d_model * itemsize
+        return 2 * block_tokens * self.kv_heads * self.head_dim * itemsize
 
     def kv_bytes_per_token(self, itemsize):
         """K and V of one cached token across all its planes."""
-        return 2 * self.kv_planes * self.d_model * itemsize
+        return self.kv_planes * self.kv_block_bytes(1, itemsize)
+
+    def state_spec(self, dtype):
+        """Per-slot state beside the pool: one tuple of ``(shape,
+        dtype)`` per state layer (the engine holds ``[max_slots, *shape]``
+        of each); nothing by default.  ``dtype`` is the compute dtype."""
+        return ()
+
+    def state_bytes_per_slot(self, dtype):
+        return sum(int(np.prod(shape)) * jnp.dtype(dt).itemsize
+                   for layer in self.state_spec(dtype)
+                   for shape, dt in layer)
 
     def heads(self, x):
         """``[..., d] -> [..., n_head, head_dim]``."""
@@ -267,4 +334,266 @@ class LoopedRmsRope(Architecture):
     def head(self, p, x):
         # the final norm already closed the last pass
         return jnp.matmul(x, p["lm_head.w"],
+                          preferred_element_type=jnp.float32)
+
+
+class SambaY(Architecture):
+    """The decoder-hybrid-decoder layout (SambaY, arXiv:2507.06607) with
+    differential attention (arXiv:2410.05258): ``n_layer`` pre-LayerNorm
+    layers, each a mixer and a gated SiLU MLP, the mixer by layer index
+    (``half = n_layer // 2``):
+
+    ====================  =====================  ========================
+    layer ``i``           mixer                  holds
+    ====================  =====================  ========================
+    even, ``<= half``     Mamba-1                per-slot state (no K/V)
+    odd, ``< half``       differential           a K/V plane, ``window``
+                          attention, windowed
+    ``half + 1``          differential           a K/V plane, whole
+                          attention, full
+    even, ``> half``      gated memory unit      nothing (reads layer
+                                                 ``half``'s scan output of
+                                                 the same token)
+    odd, ``> half + 1``   cross differential     nothing (READS layer
+                          attention              ``half + 1``'s plane)
+    ====================  =====================  ========================
+
+    ``models/sambay_reference.py`` writes the equations down; this class
+    computes the same mathematics through the cache interface.
+
+    **How a pair goes through the paged kernel.**  Heads pair as
+    neighbours: query pair ``a`` is heads ``(2a, 2a + 1)``, K pair ``c``
+    is K/V heads ``(2c, 2c + 1)``, the joined value ``v_c`` is those two
+    V heads side by side, and pair ``a`` reads ``c = a // (n_head //
+    kv_heads)``.  A plane therefore holds ``kv_heads / 2`` ROWS of ``2 *
+    head_dim`` lanes, K row ``(k1_c | k2_c)`` and V row ``v_c``, and
+    every row a query attends is one ``[rows, 2 * head_dim]`` tile with
+    no lane left empty (at heads of 64 a ``[.., kv_heads, 64]`` pool
+    would leave half of every lane tile empty, and the compiler copies
+    such a pool at every call: ``tests/test_paged_compiles_for_chip``).
+    A query pair becomes TWO query rows of the same width, ``(q1_a |
+    0)`` and ``(0 | q2_a)``: a full-lane dot with the K row is then the
+    half's score, each row has a softmax of its own and both weigh the
+    whole ``v_c``.  That is ``attend(..., group=2 * n_head // kv_heads,
+    scale=head_dim ** -0.5, out_dtype=float32)`` of the one paged kernel
+    every architecture calls; ``A1 v - lambda A2 v``, the RMSNorm over
+    ``2 * head_dim`` and ``(1 - lambda_0)`` follow here in float32.
+
+    Parameter names: ``tok_emb.w [V, d]`` (also the head: tied),
+    ``ln_f.scale/.bias``; per layer ``block{i}_ln1.scale/.bias``,
+    ``ln2.scale/.bias``, ``ffn_gu.w [d, 2f]`` (gate | up),
+    ``ffn_down.w [f, d]``; a Mamba layer ``ssm_in.w [d, 2n]`` (a | z),
+    ``ssm_conv.w [n, taps]``, ``ssm_conv.b [n]``, ``ssm_x.w [n, r + 2s]``
+    (delta | B | C), ``ssm_dt.w [r, n]``, ``ssm_dt.b [n]``,
+    ``ssm_A_log.w [n, s]``, ``ssm_D.w [n]``, ``ssm_out.w [n, d]``; an
+    attention layer ``att_qkv.w [d, d + 2 kv]``, ``att_qkv.b`` (a cross
+    layer ``att_q.w [d, d]``, ``att_q.b``), ``att_out.w [d, d]``,
+    ``att_out.b``, ``att_lambda_q1/_k1/_q2/_k2.w [head_dim]``,
+    ``att_subln.scale [2 head_dim]``; a gated memory unit ``gmu_in.w
+    [d, n]``, ``gmu_out.w [n, d]``.
+    """
+
+    name = "sambay"
+
+    def __init__(self, n_layer, n_head, kv_heads, d_model, window,
+                 d_inner, d_state=16, conv_taps=4, dt_rank=None, eps=1e-5):
+        super().__init__(n_layer, n_head, d_model)
+        if n_layer < 6 or n_layer % 2:
+            raise ValueError(
+                f"{self.name}: n_layer {n_layer} must be even and >= 6 "
+                f"(a self-decoder with a Mamba and a window layer, the "
+                f"full layer, a memory unit and a cross layer)")
+        if kv_heads % 2 or n_head % kv_heads:
+            raise ValueError(
+                f"{self.name}: differential attention pairs heads: "
+                f"kv_heads {kv_heads} must be even and divide n_head "
+                f"{n_head}")
+        self._kv_heads, self.window = int(kv_heads), int(window)
+        self.d_inner, self.d_state = int(d_inner), int(d_state)
+        self.conv_taps = int(conv_taps)
+        self.dt_rank = int(dt_rank or -(-d_model // 16))
+        self.eps = eps
+        half = n_layer // 2
+        self.memory_layer, self.full_layer = half, half + 1
+        kinds = []
+        for i in range(n_layer):
+            if i % 2 == 0:
+                kinds.append("mamba" if i <= half else "gmu")
+            elif i < half:
+                kinds.append("window")
+            else:
+                kinds.append("full" if i == half + 1 else "cross")
+        self.kinds = tuple(kinds)
+        # pool array of each layer that owns one, state index of each
+        # Mamba layer; the cross layers read the full layer's array
+        owners = [i for i, k in enumerate(kinds) if k in ("window", "full")]
+        self.plane_of = {i: n for n, i in enumerate(owners)}
+        self.state_of = {i: n for n, i in enumerate(
+            i for i, k in enumerate(kinds) if k == "mamba")}
+
+    @property
+    def kv_heads(self):
+        return self._kv_heads
+
+    @property
+    def planes(self):
+        return tuple(self.window if self.kinds[i] == "window" else None
+                     for i in self.plane_of)
+
+    @property
+    def plane_reads(self):
+        return ((self.window, self.kinds.count("window")),
+                (None, 1 + self.kinds.count("cross")))
+
+    def pool_block_shape(self, block_tokens, dtype):
+        # a row is a PAIR of K/V heads (class docstring)
+        return (block_tokens,
+                _paged.pool_rows(self.kv_heads // 2, dtype),
+                2 * self.head_dim)
+
+    def state_spec(self, dtype):
+        one = (((self.d_inner, self.d_state), jnp.float32),
+               ((self.conv_taps - 1, self.d_inner), jnp.dtype(dtype)))
+        return (one,) * len(self.state_of)
+
+    def check_params(self, params, max_len):
+        last = self.n_layer - 1
+        need = ["tok_emb.w", "ln_f.scale", "ln_f.bias",
+                f"block{last}_ffn_down.w", f"block{last}_att_q.w",
+                "block0_ssm_A_log.w", "block1_att_qkv.w",
+                f"block{self.full_layer}_att_subln.scale",
+                f"block{last - 1}_gmu_out.w"]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+
+    def embed(self, p, toks, pos):
+        return p["tok_emb.w"][toks]          # no positional encoding
+
+    # -- the mixers -------------------------------------------------------
+    def _mamba(self, w, h, planes, cache, n_state):
+        """Mamba-1 over the rows ``h [S, d]`` (a decode step) or ``[S,
+        W, d]`` (a window): returns ``(out, y, planes)``, ``y`` the scan
+        output before gating (float32), the memory of layer ``half``."""
+        f32 = jnp.float32
+        step = h.ndim == 2
+        hw = h[:, None] if step else h                      # [S, W, d]
+        valid = cache.valid[:, None] if step else cache.valid
+        S, W, _ = hw.shape
+        n, taps = self.d_inner, self.conv_taps
+        s, conv = cache.state(planes, n_state)
+        az = hw @ w("ssm_in.w")
+        a, z = az[..., :n], az[..., n:]
+        # causal depthwise conv over (the slot's last taps-1 rows, a)
+        xs = jnp.concatenate([conv, a], axis=1)             # [S, taps-1+W, n]
+        cw = w("ssm_conv.w").astype(f32)
+        ac = w("ssm_conv.b").astype(f32) + sum(
+            xs[:, k:k + W].astype(f32) * cw[:, k] for k in range(taps))
+        # the rows the NEXT call sees: the taps-1 that end at the last
+        # real row (real rows are a prefix of the window); with no real
+        # row that is ``conv`` itself
+        n_real = jnp.sum(valid, axis=1).astype(jnp.int32)
+        conv = jax.vmap(lambda rows, k: jax.lax.dynamic_slice_in_dim(
+            rows, k, taps - 1, axis=0))(xs, n_real)
+        a = jax.nn.silu(ac)                                 # [S, W, n] f32
+        r, ds = self.dt_rank, self.d_state
+        dbc = a.astype(h.dtype) @ w("ssm_x.w")
+        delta = jax.nn.softplus(
+            (dbc[..., :r] @ w("ssm_dt.w")).astype(f32)
+            + w("ssm_dt.b").astype(f32))
+        # a row that is not real advances nothing: exp(0 A) = 1, 0 a B = 0
+        delta = jnp.where(valid[..., None], delta, 0.0)
+        Bm = dbc[..., r:r + ds].astype(f32)
+        Cm = dbc[..., r + ds:].astype(f32)
+        A = -jnp.exp(w("ssm_A_log.w").astype(f32))          # [n, s]
+
+        def one(s, row):
+            d_t, a_t, b_t, c_t = row                        # [S, n] / [S, s]
+            s = (jnp.exp(d_t[..., None] * A) * s
+                 + (d_t * a_t)[..., None] * b_t[:, None, :])
+            return s, jnp.sum(s * c_t[:, None, :], axis=-1)
+
+        if W == 1:
+            s, y = one(s, (delta[:, 0], a[:, 0], Bm[:, 0], Cm[:, 0]))
+            y = y[:, None]
+        else:
+            s, y = jax.lax.scan(one, s, tuple(
+                jnp.moveaxis(v, 1, 0) for v in (delta, a, Bm, Cm)))
+            y = jnp.moveaxis(y, 0, 1)
+        y = y + w("ssm_D.w").astype(f32) * a                # [S, W, n] f32
+        out = (y * jax.nn.silu(z.astype(f32))).astype(h.dtype) @ w("ssm_out.w")
+        planes = cache.put_state(planes, n_state, (s, conv))
+        if step:
+            out, y = out[:, 0], y[:, 0]
+        return out, y, planes
+
+    def _diff_attention(self, w, i, h, planes, cache, plane, own, window):
+        """Differential attention of layer ``i`` over pool array
+        ``plane``: its own K and V written first (``own``), or another
+        layer's read."""
+        f32 = jnp.float32
+        d, dh = self.d_model, self.head_dim
+        kv = self.kv_heads * dh
+        lead = h.shape[:-1]
+        if own:
+            qkv = h @ w("att_qkv.w") + w("att_qkv.b")
+            q = qkv[..., :d]
+            k = qkv[..., d:d + kv].reshape(*lead, self.kv_heads // 2, 2 * dh)
+            v = qkv[..., d + kv:].reshape(*lead, self.kv_heads // 2, 2 * dh)
+        else:
+            q, k, v = h @ w("att_q.w") + w("att_q.b"), None, None
+        pairs = self.n_head // 2
+        # (q1_a | q2_a) -> the rows (q1_a | 0) and (0 | q2_a)
+        lane_half = jnp.arange(2 * dh) // dh == jnp.arange(2)[:, None]
+        rows = jnp.where(lane_half, q.reshape(*lead, pairs, 1, 2 * dh), 0)
+        ctx, planes = cache(
+            planes, plane, 0, rows.reshape(*lead, 2 * pairs, 2 * dh), k, v,
+            group=2 * self.n_head // self.kv_heads, window=window,
+            scale=dh ** -0.5, out_dtype=f32)
+        ctx = ctx.reshape(*lead, pairs, 2, 2 * dh)
+        lam0 = 0.8 - 0.6 * float(np.exp(-0.3 * i))
+        lam = (jnp.exp(jnp.sum(w("att_lambda_q1.w").astype(f32)
+                               * w("att_lambda_k1.w").astype(f32)))
+               - jnp.exp(jnp.sum(w("att_lambda_q2.w").astype(f32)
+                                 * w("att_lambda_k2.w").astype(f32)))
+               + lam0)
+        o = ctx[..., 0, :] - lam * ctx[..., 1, :]
+        o = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                        keepdims=True) + self.eps)
+             * w("att_subln.scale").astype(f32) * (1.0 - lam0))
+        return (o.reshape(*lead, d).astype(h.dtype) @ w("att_out.w")
+                + w("att_out.b")), planes
+
+    def stack(self, p, x, pos, planes, attend):
+        eps, memory = self.eps, None
+        for i, kind in enumerate(self.kinds):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            h = _ln(x, w("ln1.scale"), w("ln1.bias"), eps)
+            if kind == "mamba":
+                mix, y, planes = self._mamba(w, h, planes, attend,
+                                             self.state_of[i])
+                if i == self.memory_layer:
+                    memory = y
+            elif kind == "gmu":
+                gate = jax.nn.silu((h @ w("gmu_in.w")).astype(jnp.float32))
+                mix = (memory * gate).astype(x.dtype) @ w("gmu_out.w")
+            else:
+                own = kind != "cross"
+                mix, planes = self._diff_attention(
+                    w, i, h, planes, attend,
+                    self.plane_of[i if own else self.full_layer], own,
+                    self.window if kind == "window" else None)
+            x = x + mix
+            h2 = _ln(x, w("ln2.scale"), w("ln2.bias"), eps)
+            gu = h2 @ w("ffn_gu.w")
+            f = gu.shape[-1] // 2
+            ff = (gu[..., f:].astype(jnp.float32)
+                  * jax.nn.silu(gu[..., :f].astype(jnp.float32)))
+            x = x + ff.astype(x.dtype) @ w("ffn_down.w")
+        return x, planes
+
+    def head(self, p, x):
+        x = _ln(x, p["ln_f.scale"], p["ln_f.bias"], self.eps)
+        return jnp.einsum("...d,vd->...v", x, p["tok_emb.w"],
                           preferred_element_type=jnp.float32)

@@ -4,13 +4,15 @@
 What a model is made of comes from ``serving/arch.py``: an
 ``Architecture`` gives the attention geometry, the number of K/V planes
 and the forward (embedding, stack, head), written once and fed a decode
-step's rows or a window's through the cache interface
-``_attend_through`` builds.  This file knows blocks, tables and windows.
+step's rows or a window's through the cache interface ``_Cache``
+(attention through the block table, and the slots' rows of whatever
+per-slot state the architecture holds beside the pool).  This file
+knows blocks, tables and windows.
 
 KV lives in a physical block pool: per layer one
 ``[passes * num_blocks, block_tokens, h, dh]`` array (``passes`` is 1
 unless the stack runs several times over the same weights; pass ``p``
-then owns blocks ``p * num_blocks ..``, see ``_attend_through``), and each slot's logical
+then owns blocks ``p * num_blocks ..``, see ``_Cache``), and each slot's logical
 sequence is a chain of block ids in a per-slot BLOCK TABLE row
 (``[max_slots, blocks_per_slot]`` int32, host-managed by
 ``serving.kvcache``).  Position ``t`` of slot ``s`` lives at
@@ -61,8 +63,8 @@ engine:
     decode adds exactly one executable per engine, never one per ``k``.
 
   HOW a row attends through the table (which kernel, streaming or
-  dense) is not decided here: ``_attend_through`` hands ``(q, pool,
-  table, pos)`` to ``kernels.paged_attention.attend`` and that module
+  dense) is not decided here: ``_Cache`` hands ``(q, pool, table,
+  pos)`` to ``kernels.paged_attention.attend`` and that module
   owns the choice.
 
 Correctness discipline (unchanged from the contiguous engine): every op
@@ -95,20 +97,26 @@ __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
 PREFILL_PIECE = 128
 
 
-def _attend_through(arch, table, blk, off, pos):
-    """The cache interface an architecture's ``stack`` calls (``arch.py``):
-    ``attend(planes, layer, i_pass, q, k, v) -> (ctx, planes')`` writes
-    the rows' K/V into the plane of ``(i_pass, layer)`` at ``(blk,
-    off)``, then attends that plane through ``table`` masked ``<= pos``.
+class _Cache:
+    """The cache interface an architecture's ``stack`` receives
+    (``arch.py``): called, ``attend(planes, plane, i_pass, q, k, v,
+    **how) -> (ctx, planes')`` writes the rows' K/V into pool array
+    ``plane`` (of pass ``i_pass``) at ``(blk, off)``, then attends that
+    plane through ``table`` masked ``<= pos`` (and by ``how``'s lower
+    bound); with ``k`` and ``v`` ``None`` it writes nothing and attends
+    what another layer wrote.  Its state side (``state``, ``put_state``,
+    ``valid``) reads and writes the slots' rows of the per-slot state.
 
-    ``planes = (pool_k, pool_v)``, one array a layer.  A stack that runs
-    ``arch.passes`` times folds its passes into the block axis: layer
-    ``l``'s array holds ``passes * num_blocks`` blocks and pass ``p``
-    reads and writes block ``b`` at ``b + p * num_blocks``, so a block id
-    names the same positions in every plane, every pass has a trash block
-    of its own (``p * num_blocks``), no plane is ever sliced out or
-    copied and the kernels see an ordinary pool and table.  ``pos``
-    ``[S]`` is a decode step (rows ``[S, ...]``), ``[S, W]`` a window.
+    ``planes = (pool_k, pool_v, state)``: one pool array a plane, one
+    tuple of ``[max_slots, ...]`` arrays a state layer (``()`` for an
+    architecture that holds none).  A stack that runs ``arch.passes``
+    times folds its passes into the block axis: a plane's array holds
+    ``passes * num_blocks`` blocks and pass ``p`` reads and writes block
+    ``b`` at ``b + p * num_blocks``, so a block id names the same
+    positions in every plane, every pass has a trash block of its own
+    (``p * num_blocks``), no plane is ever sliced out or copied and the
+    kernels see an ordinary pool and table.  ``pos`` ``[S]`` is a decode
+    step (rows ``[S, ...]``), ``[S, W]`` a window.
 
     A DEAD slot attends nothing: its rows are handed to attention at
     ``pos = -1``, for which the Mosaic kernel fetches no block and
@@ -117,36 +125,81 @@ def _attend_through(arch, table, blk, off, pos):
     and ``ServingEngine._release_slot`` zeroes the row, whereas the
     ``pos`` a decode chunk carries on the device goes stale for a
     released slot (it keeps counting).  Writes (``blk``, ``off``) are
-    untouched: a dead slot's land in the trash block as before."""
-    step = pos.ndim == 1
-    pos4 = pos[:, None] if step else pos
-    pos4 = jnp.where((table[:, 0] == 0)[:, None], -1, pos4)
+    untouched: a dead slot's land in the trash block as before.
 
-    def attend(planes, layer, i_pass, qh, kh, vh):
-        pool_k, pool_v = planes
-        tbl, b = table, blk
-        if arch.passes > 1:
-            shift = i_pass * (pool_k[layer].shape[0] // arch.passes)
-            tbl, b = table + shift, blk + shift
-        # every write lands before the attention below: the
-        # write-before-attend discipline, one scatter per plane
-        # (distinct live positions, disjoint per-slot blocks, overruns
-        # and rows past their limit in the trash block — content nobody
-        # ever attends)
-        pk = pool_k[layer].at[b, off].set(kh)
-        pv = pool_v[layer].at[b, off].set(vh)
+    ``valid`` (``pos``'s shape, bool) marks the rows that are real: a
+    window's rows up to its ``limit`` (``writable``), a live slot's
+    step.  ``slot`` (prefill: the one slot the ``S = 1`` window belongs
+    to) selects the state row, and a window that starts a prompt (``pos
+    == 0``) starts from zero state whatever the slot's last request
+    left; without ``slot`` the state arrays' rows ARE the call's slots.
+    The state side traces nothing unless an architecture calls it."""
+
+    def __init__(self, arch, table, blk, off, pos, writable=None,
+                 slot=None):
+        self.arch, self.table, self.blk, self.off = arch, table, blk, off
+        self.pos, self.writable, self.slot = pos, writable, slot
+        self.step = pos.ndim == 1
+        pos4 = pos[:, None] if self.step else pos
+        self.pos4 = jnp.where((table[:, 0] == 0)[:, None], -1, pos4)
+
+    @property
+    def valid(self):
+        if self.writable is not None:
+            return self.writable
+        return self.table[:, 0] != 0
+
+    def __call__(self, planes, plane, i_pass, qh, kh, vh, **how):
+        pool_k, pool_v = planes[:2]
+        tbl, b = self.table, self.blk
+        if self.arch.passes > 1:
+            shift = i_pass * (pool_k[plane].shape[0] // self.arch.passes)
+            tbl, b = tbl + shift, b + shift
+        pk, pv = pool_k[plane], pool_v[plane]
+        if kh is not None:
+            # every write lands before the attention below: the
+            # write-before-attend discipline, one scatter per plane
+            # (distinct live positions, disjoint per-slot blocks, overruns
+            # and rows past their limit in the trash block — content
+            # nobody ever attends)
+            if kh.shape[-2] == pk.shape[2]:
+                pk = pk.at[b, self.off].set(kh)
+                pv = pv.at[b, self.off].set(vh)
+            else:   # rows kernels.paged_attention.pool_rows added stay 0
+                pk = pk.at[b, self.off, :kh.shape[-2]].set(kh)
+                pv = pv.at[b, self.off, :vh.shape[-2]].set(vh)
         # attend THROUGH the table: row j attends <= pos_j inside the
         # paged_attention op class, the [S, T, h, dh] view never exists
-        ctx = _paged.attend(qh[:, None] if step else qh, pk, pv, tbl, pos4)
-        if step:
+        ctx = _paged.attend(qh[:, None] if self.step else qh, pk, pv, tbl,
+                            self.pos4, **how)
+        if self.step:
             ctx = ctx[:, 0]
-        return ctx, (pool_k[:layer] + (pk,) + pool_k[layer + 1:],
-                     pool_v[:layer] + (pv,) + pool_v[layer + 1:])
+        if kh is None:
+            return ctx, planes
+        return ctx, (pool_k[:plane] + (pk,) + pool_k[plane + 1:],
+                     pool_v[:plane] + (pv,) + pool_v[plane + 1:]) + planes[2:]
 
-    return attend
+    def state(self, planes, i):
+        rows = planes[2][i]
+        if self.slot is not None:
+            rows = tuple(jax.lax.dynamic_index_in_dim(a, self.slot, 0)
+                         for a in rows)
+            fresh = self.pos[:, 0] == 0
+            rows = tuple(jnp.where(
+                fresh.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a)
+                for a in rows)
+        return rows
+
+    def put_state(self, planes, i, rows):
+        old = planes[2][i]
+        rows = tuple(r.astype(a.dtype) for r, a in zip(rows, old))
+        if self.slot is not None:
+            rows = tuple(jax.lax.dynamic_update_slice_in_dim(
+                a, r, self.slot, 0) for r, a in zip(rows, old))
+        return planes[:2] + (planes[2][:i] + (rows,) + planes[2][i + 1:],)
 
 
-def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch):
+def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     """One decode step for S independent slots through the block table.
 
     tok [S] int32 current tokens, t [S] int32 per-slot positions,
@@ -156,8 +209,10 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch):
     Writes each slot's K/V at ``(table[s, t_s // B], t_s % B)`` in every
     plane (clamped — overrun slots land in whatever their last table
     entry maps to, by construction the trash block or an
-    already-consumed position), attends the chain masked ``<= t_s``, and
-    returns ``(logits [S, vocab] f32, pool_k', pool_v')``.
+    already-consumed position), attends the chain masked ``<= t_s``,
+    advances the per-slot ``state`` of the live slots (``table[:, 0] !=
+    0``) and returns ``(logits [S, vocab] f32, pool_k', pool_v',
+    state')``.
     """
     S = tok.shape[0]
     B = pool_k[0].shape[1]
@@ -166,41 +221,53 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch):
     blk = table[jnp.arange(S), tw // B]      # [S] physical write block
     x = arch.embed(p, tok, tw)                               # [S, d]
     with jax.named_scope(STACK_SCOPE):
-        x, (pool_k, pool_v) = arch.stack(
-            p, x, tw, (pool_k, pool_v),
-            _attend_through(arch, table, blk, tw % B, t))
-    return arch.head(p, x), pool_k, pool_v
+        x, (pool_k, pool_v, state) = arch.stack(
+            p, x, tw, (pool_k, pool_v, state),
+            _Cache(arch, table, blk, tw % B, t))
+    return arch.head(p, x), pool_k, pool_v, state
 
 
 def make_decode_chunk(arch, chunk, donate=True):
     """Build the batched decode executable: ``chunk`` greedy steps for
     every slot in one device call, for ``arch`` (an ``Architecture``).
 
-    ``fn(params, pool_k, pool_v, last_tok, pos, table) -> (pool_k',
-    pool_v', last_tok', pos', toks [chunk, S] int32)`` — ``toks[j]`` is
-    the token each slot emitted at its ``pos+j``'th position.  The pool
-    and slot scalars are donated (updated in place on TPU); the table is
-    a small host-fed int32 array (data, not donated).  Callers must
-    replace their references with the outputs.
+    ``fn(params, pool_k, pool_v, last_tok, pos, table, state=()) ->
+    (pool_k', pool_v', last_tok', pos', toks [chunk, S] int32, state')``
+    — ``toks[j]`` is the token each slot emitted at its ``pos+j``'th
+    position.  The pool, the slot scalars and the per-slot ``state``
+    (``arch.state_spec``; ``()`` and no argument of the lowered program
+    for an architecture that holds none) are donated (updated in place
+    on TPU); the table is a small host-fed int32 array (data, not
+    donated).  Callers must replace their references with the outputs.
     """
 
-    def decode_chunk(p, pool_k, pool_v, last_tok, pos, table):
+    def decode_chunk(p, pool_k, pool_v, last_tok, pos, table, state=()):
         def body(carry, _):
-            pk, pv, tok, t = carry
-            logits, pk, pv = paged_step_logits(p, tok, t, pk, pv, table,
-                                               arch)
+            pk, pv, st, tok, t = carry
+            logits, pk, pv, st = paged_step_logits(p, tok, t, pk, pv,
+                                                   table, arch, st)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (pk, pv, nxt, t + 1), nxt
+            return (pk, pv, st, nxt, t + 1), nxt
 
-        (pk, pv, tok, t), toks = jax.lax.scan(
-            body, (pool_k, pool_v, last_tok, pos), None, length=chunk)
-        return pk, pv, tok, t, toks
+        (pk, pv, state, tok, t), toks = jax.lax.scan(
+            body, (pool_k, pool_v, state, last_tok, pos), None,
+            length=chunk)
+        return pk, pv, tok, t, toks, state
 
-    return jax.jit(decode_chunk,
-                   donate_argnums=(1, 2, 3, 4) if donate else ())
+    return jax.jit(decode_chunk, donate_argnums=_donated(
+        arch, donate, (1, 2, 3, 4), 6))
 
 
-def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch):
+def _donated(arch, donate, argnums, state_argnum):
+    """The donated arguments of an entry point: ``argnums`` and, where
+    the architecture holds per-slot state, the state argument."""
+    if not donate:
+        return ()
+    return argnums + ((state_argnum,) if arch.state_spec("float32") else ())
+
+
+def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch,
+                    state=(), slot=None):
     """The teacher-forced WINDOW forward both ``make_verify_window``
     and ``make_prefill`` are built on: ``toks [S, W]`` consumed at
     logical positions ``pos_s + j`` in ONE pass — per layer one
@@ -217,9 +284,14 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch):
     race a real row for a clamped table entry.  Their outputs are
     garbage nobody reads.
 
-    Returns ``(x [S, W, d], pool_k', pool_v')`` — the stack's output,
-    what ``arch.head`` consumes: the callers differ in which rows they
-    put through the head.
+    Per-slot ``state`` advances over the rows up to ``limit`` only.
+    With ``slot`` (prefill: ``S = 1``) the window belongs to that slot:
+    its state row is read and written back, starting from zeros where
+    the window starts a prompt (``pos == 0``).
+
+    Returns ``(x [S, W, d], pool_k', pool_v', state')`` — the stack's
+    output, what ``arch.head`` consumes: the callers differ in which
+    rows they put through the head.
     """
     S, W = toks.shape
     B = pool_k[0].shape[1]
@@ -230,10 +302,10 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch):
     blk = jnp.where(writable, table[jnp.arange(S)[:, None], Pw // B], 0)
     x = arch.embed(p, toks, Pw)                              # [S, W, d]
     with jax.named_scope(STACK_SCOPE):
-        x, (pool_k, pool_v) = arch.stack(
-            p, x, Pw, (pool_k, pool_v),
-            _attend_through(arch, table, blk, Pw % B, P))
-    return x, pool_k, pool_v
+        x, (pool_k, pool_v, state) = arch.stack(
+            p, x, Pw, (pool_k, pool_v, state),
+            _Cache(arch, table, blk, Pw % B, P, writable, slot))
+    return x, pool_k, pool_v, state
 
 
 def _copy_block(planes, src, dst, passes):
@@ -274,7 +346,7 @@ def make_verify_window(arch, k, donate=True):
         if toks.shape[1] != k + 1:
             raise ValueError(f"verify window built for k={k} got "
                              f"{toks.shape[1]} tokens a slot")
-        x, pool_k, pool_v = _window_forward(
+        x, pool_k, pool_v, _ = _window_forward(
             p, pool_k, pool_v, toks, pos, limit, table, arch)
         greedy = jnp.argmax(arch.head(p, x), axis=-1).astype(jnp.int32)
         return pool_k, pool_v, greedy
@@ -287,8 +359,9 @@ def make_prefill(arch, bucket, donate=True):
     bucket of at most ``PREFILL_PIECE`` tokens).
 
     ``fn(params, pool_k, pool_v, last_tok, pos, slot, table_row [NB],
-    toks [bucket], start, length, cow_src, cow_dst) -> (pool_k',
-    pool_v', last_tok', pos', first_tok)`` — first copies block
+    toks [bucket], start, length, cow_src, cow_dst, state=()) ->
+    (pool_k', pool_v', last_tok', pos', first_tok, state')`` — first
+    copies block
     ``cow_src`` onto ``cow_dst`` whole (the copy-on-write fork; the
     no-fork spelling passes ``0, 0``, trash onto trash), then runs ONE
     window forward (``_window_forward`` at ``S = 1``) over the padded
@@ -305,11 +378,14 @@ def make_prefill(arch, bucket, donate=True):
 
     Rows past ``length`` are padding: their K/V go to the trash block
     (``limit = start + length - 1``) and their outputs are never read;
-    a real row never attends them (mask ``<= start + j``).
+    a real row never attends them (mask ``<= start + j``), and the
+    slot's row of the per-slot ``state`` advances over the real rows
+    only, from zeros where ``start == 0`` and else from what the earlier
+    piece wrote.
     """
 
     def prefill(p, pool_k, pool_v, last_tok, pos, slot, table_row,
-                toks, start, length, cow_src, cow_dst):
+                toks, start, length, cow_src, cow_dst, state=()):
         if toks.shape != (bucket,):
             raise ValueError(f"prefill built for width {bucket} got "
                              f"tokens of shape {toks.shape}")
@@ -319,13 +395,14 @@ def make_prefill(arch, bucket, donate=True):
         pool_k = _copy_block(pool_k, cow_src, cow_dst, arch.passes)
         pool_v = _copy_block(pool_v, cow_src, cow_dst, arch.passes)
         end = start + length
-        x, pool_k, pool_v = _window_forward(
+        x, pool_k, pool_v, state = _window_forward(
             p, pool_k, pool_v, toks[None], start[None], (end - 1)[None],
-            table_row[None], arch)
+            table_row[None], arch, state, slot)
         row = jax.lax.dynamic_slice_in_dim(x[0], length - 1, 1)  # [1, d]
         first = jnp.argmax(arch.head(p, row)[0]).astype(jnp.int32)
         last_tok = last_tok.at[slot].set(first)
         pos = pos.at[slot].set(end)
-        return pool_k, pool_v, last_tok, pos, first
+        return pool_k, pool_v, last_tok, pos, first, state
 
-    return jax.jit(prefill, donate_argnums=(1, 2, 3, 4) if donate else ())
+    return jax.jit(prefill, donate_argnums=_donated(
+        arch, donate, (1, 2, 3, 4), 12))

@@ -184,9 +184,11 @@ class ServingEngine:
     n_layer / n_head / d_model / eps   the GPT-2 block of
              ``transformer.build`` (the positional spelling), OR
     arch     a ``serving.arch.Architecture``: heads and head size, the
-             K/V planes a token holds (``n_layer * passes``), and the
-             forward the compiled entry points run (docs/serving.md
-             "Architectures").  ``ServingEngine(params, arch=Gpt2(L, h,
+             K/V planes a token holds (``n_layer * passes`` unless it
+             lists them), the per-slot state it holds beside the pool,
+             and the forward the compiled entry points run
+             (docs/serving.md "Architectures").  One that holds
+             recurrent state refuses ``prefix_reuse=True`` and a draft.  ``ServingEngine(params, arch=Gpt2(L, h,
              d, eps), ...)`` is the positional spelling exactly.
     max_len  per-slot logical KV capacity; every request needs
              ``len(prompt) + max_new_tokens <= max_len``.
@@ -290,6 +292,15 @@ class ServingEngine:
         # the widest prefill window (never narrower than a bucket)
         self._piece = max(self.min_bucket, _bd.PREFILL_PIECE)
         arch.check_params(params, self.max_len)
+        state_spec = arch.state_spec(self.compute_dtype)
+        if state_spec and prefix_reuse:
+            raise ValueError(
+                f"prefix_reuse=True cannot serve {arch.name!r}: "
+                f"{len(state_spec)} of its layers hold recurrent state "
+                f"beside the pool, and a request that skips a cached "
+                f"prefix needs that state AT THE HIT'S BOUNDARY; the "
+                f"trie keeps K/V blocks only (no state snapshot at block "
+                f"boundaries).  Pass prefix_reuse=False")
         self._p = jax.device_put(
             {k: jnp.asarray(v, self.compute_dtype)
              for k, v in params.items()})
@@ -334,15 +345,23 @@ class ServingEngine:
         self.prefix_trie = (_kv.PrefixTrie(self.kv_pool, self.cache_blocks)
                             if prefix_reuse else None)
         self.prefix_reuse = bool(prefix_reuse)
-        # one array a layer; a stack that runs arch.passes times keeps
-        # each pass's plane in its own num_blocks of the block axis
-        # (batched_decode._attend_through)
-        shape = (arch.passes * num_blocks, self.block_tokens, n_head,
-                 arch.head_dim)
+        # one array a plane of arch.planes (a layer, unless the
+        # architecture lists them otherwise); a stack that runs
+        # arch.passes times keeps each pass's plane in its own num_blocks
+        # of the block axis (batched_decode._Cache)
+        shape = (arch.passes * num_blocks,) + tuple(
+            arch.pool_block_shape(self.block_tokens, self.compute_dtype))
         self._pk = tuple(jnp.zeros(shape, self.compute_dtype)
-                         for _ in range(n_layer))
+                         for _ in arch.planes)
         self._pv = tuple(jnp.zeros(shape, self.compute_dtype)
-                         for _ in range(n_layer))
+                         for _ in arch.planes)
+        # what a slot holds BESIDE the pool (recurrent state): arrays
+        # indexed by slot, donated through the same executables; a
+        # prompt's first prefill piece starts its row from zeros, so a
+        # released slot's row needs no clearing
+        self._state = tuple(
+            tuple(jnp.zeros((self.max_slots,) + tuple(shp), dt)
+                  for shp, dt in layer) for layer in state_spec)
         self._last = jnp.zeros((self.max_slots,), jnp.int32)
         self._pos = jnp.zeros((self.max_slots,), jnp.int32)
         # host-side block table: unused entries -> trash block 0
@@ -398,6 +417,32 @@ class ServingEngine:
             "serving.kv_planes",
             help="K/V planes a cached token holds (layers x passes of "
                  "the stack)").set(arch.kv_planes)
+        for kind, n in (("window", sum(w is not None for w in arch.planes)),
+                        ("full", sum(w is None for w in arch.planes))):
+            self._reg.gauge(
+                "serving.kv_planes", kind=kind,
+                help="of them, planes attended under a lower bound "
+                     "(window) and planes attended whole (full)",
+            ).set(n * arch.passes)
+        self._reg.gauge(
+            "serving.kv_heads",
+            help="K/V heads a plane holds of each position").set(
+                arch.kv_heads)
+        self._reads_per_token = sum(n for _, n in arch.plane_reads)
+        self._reg.gauge(
+            "serving.plane_reads_per_token",
+            help="paged-attention calls one token makes (a plane that "
+                 "several layers read counts once a reader)").set(
+                     self._reads_per_token)
+        state_slot = arch.state_bytes_per_slot(self.compute_dtype)
+        self._reg.gauge(
+            "serving.state_bytes_per_slot",
+            help="bytes of recurrent state a slot holds beside the pool "
+                 "(fixed, whatever the context)").set(state_slot)
+        self._reg.gauge(
+            "serving.state_bytes",
+            help="bytes the per-slot state arrays hold on the device",
+        ).set(state_slot * self.max_slots)
         self._reg.gauge(
             "serving.stack_passes",
             help="times the stack runs over the same weights for one "
@@ -410,8 +455,11 @@ class ServingEngine:
             "serving.kv_pool_bytes",
             help="bytes the paged pool holds on the device: planes x "
                  "blocks (trash included) x block bytes",
-        ).set(arch.kv_planes * num_blocks
-              * arch.kv_block_bytes(self.block_tokens, itemsize))
+        ).set(sum(a.nbytes for a in self._pk + self._pv))
+        # (window, calls, bytes a cached position) of the paged calls a
+        # token makes, for _count_paged_entries
+        self._plane_reads = [(w, n, arch.kv_block_bytes(1, itemsize))
+                             for w, n in arch.plane_reads]
 
     @property
     def _tracer(self):
@@ -462,21 +510,38 @@ class ServingEngine:
         """A decode chunk is about to run: of the ``max_slots x
         blocks_per_slot`` table entries each paged-attention call spans,
         how many hold a key its first step attends (position ``prompt +
-        tokens - 1`` and everything before it, in the live slots only)."""
+        tokens - 1`` and everything before it down to the plane's lower
+        bound, in the live slots only), as the mean over the calls a
+        token makes; and the K/V bytes those calls have to read."""
         B = self.block_tokens
-        live = sum(-(-(req.prompt.shape[0] + len(req.tokens)) // B)
-                   for req in self._slots if req is not None)
+        live = streamed = 0
+        for req in self._slots:
+            if req is None:
+                continue
+            ctx = req.prompt.shape[0] + len(req.tokens)   # keys attended
+            for window, n, token_bytes in self._plane_reads:
+                first = 0 if window is None else max(ctx - window, 0)
+                live += n * ((ctx - 1) // B - first // B + 1)
+                streamed += n * (ctx - first) * token_bytes
         self._reg.counter(
             "serving.paged_entries_live",
             help="block-table entries a paged-attention call had to "
-                 "visit, summed over decode chunks (live slots, up to "
-                 "each one's position at the chunk's start)").inc(live)
+                 "visit, summed over decode chunks (live slots, from "
+                 "the plane's lower bound up to each one's position at "
+                 "the chunk's start; the mean over a token's calls)",
+        ).inc(live / self._reads_per_token)
         self._reg.counter(
             "serving.paged_entries_total",
             help="block-table entries a paged-attention call spans "
                  "(max_slots x blocks_per_slot), summed over decode "
                  "chunks: paged_entries_live's denominator").inc(
                      self.max_slots * self.blocks_per_slot)
+        self._reg.counter(
+            "serving.paged_bytes_streamed",
+            help="K/V bytes the paged-attention calls of one decode "
+                 "step have to read for the live slots (clipped to each "
+                 "plane's window, a shared plane once a reader), at "
+                 "every decode chunk's first step").inc(streamed)
 
     # -- request intake ---------------------------------------------------
     def submit(self, prompt, max_new_tokens=16, eos_id=None,
@@ -820,11 +885,12 @@ class ServingEngine:
             src, dst = cow if i == 0 else (0, 0)
             args = (params, pk, pv, self._last, self._pos,
                     np.int32(slot), row, toks, np.int32(at), np.int32(n),
-                    np.int32(src), np.int32(dst))
+                    np.int32(src), np.int32(dst), self._state)
             if compile_only:
                 fn_of(w).prepare(*args)
             else:
-                pk, pv, self._last, self._pos, first = fn_of(w)(*args)
+                (pk, pv, self._last, self._pos, first,
+                 self._state) = fn_of(w)(*args)
         return pk, pv, first
 
     def _prefill_fn(self, bucket):
@@ -888,7 +954,7 @@ class ServingEngine:
         # predictor consumes
         tbl = jnp.asarray(self._table)
         self._decode_fn.prepare(self._p, self._pk, self._pv, self._last,
-                                self._pos, tbl)
+                                self._pos, tbl, self._state)
         self._count_paged_entries()
         # the chunk call and its blocking token fetch: one span, whose
         # clock pair is the per-chunk-call latency histogram (ISSUE 7
@@ -898,10 +964,13 @@ class ServingEngine:
                         histogram="serving.decode_chunk",
                         steps=self.decode_chunk,
                         active=self.active_slots,
-                        passes=self.arch.passes) as sp:
-            (self._pk, self._pv, self._last, self._pos,
-             toks) = self._decode_fn(self._p, self._pk, self._pv,
-                                     self._last, self._pos, tbl)
+                        passes=self.arch.passes,
+                        state_layers=len(self._state),
+                        plane_reads=self._reads_per_token) as sp:
+            (self._pk, self._pv, self._last, self._pos, toks,
+             self._state) = self._decode_fn(
+                 self._p, self._pk, self._pv, self._last, self._pos, tbl,
+                 self._state)
             with self._span("serving.fetch", "fetch", of="decode"):
                 toks = np.asarray(toks)  # host sync: [chunk, S]
         t0, t1 = sp.t0, sp.t1
@@ -1258,7 +1327,9 @@ class ServingEngine:
         with self._span("serving.prefill", "prefill",
                         histogram="serving.prefill_seconds", rid=req.rid,
                         bucket=bucket, pieces=len(pieces), slot=slot,
-                        prefix_hit=start, passes=self.arch.passes) as sp:
+                        prefix_hit=start, passes=self.arch.passes,
+                        state_layers=len(self._state),
+                        plane_reads=self._reads_per_token) as sp:
             self._pk, self._pv, first = self._run_pieces(
                 self._prefill_fn, self._p, self._pk, self._pv, slot,
                 row_d, pieces, cow=(cow_src, cow_dst))

@@ -100,6 +100,13 @@ def validate_draft(params, draft_params, arch, max_len,
     the validated ``draft_n_layer``.  ``arch`` is the target's
     ``serving.arch.Architecture``: anything but the GPT-2 block (a
     looped stack, whose pool arrays hold a plane per pass) is refused."""
+    if arch.state_spec("float32"):
+        raise ValueError(
+            f"speculative decoding cannot serve {arch.name!r}: its layers "
+            f"hold recurrent state beside the pool, and a REJECTED draft "
+            f"token needs that state rolled back to the committed "
+            f"frontier; dropping scratch blocks rolls back K/V only and "
+            f"no state rollback is written")
     if not isinstance(arch, Gpt2):
         raise ValueError(
             f"speculative decoding serves the GPT-2 block only: the "
@@ -323,10 +330,10 @@ class SpecState:
 
         fn = self.chunk_fn(engine)
         nl = self.n_layer
-        (pk, pv, engine._last, engine._pos,
-         toks) = fn(self.p, engine._pk[:nl], engine._pv[:nl],
-                    jnp.asarray(last_h), jnp.asarray(pos_h),
-                    jnp.asarray(self.table))
+        (pk, pv, engine._last, engine._pos, toks,
+         _) = fn(self.p, engine._pk[:nl], engine._pv[:nl],
+                 jnp.asarray(last_h), jnp.asarray(pos_h),
+                 jnp.asarray(self.table))
         engine._pk = tuple(pk) + engine._pk[nl:]
         engine._pv = tuple(pv) + engine._pv[nl:]
         return np.asarray(toks)[:self.k]

@@ -69,13 +69,13 @@ def _noise_pool(eng, seed):
 
 @jax.jit
 def _step(p, tok, t, pk, pv, row):
-    return _bd.paged_step_logits(p, tok, t, pk, pv, row[None], ARCH)
+    return _bd.paged_step_logits(p, tok, t, pk, pv, row[None], ARCH)[:3]
 
 
 @jax.jit
 def _window(p, pk, pv, toks, at, last, row):
     return _bd._window_forward(p, pk, pv, toks[None], at[None], last[None],
-                               row[None], ARCH)
+                               row[None], ARCH)[:3]
 
 
 def _step_through(p, pk, pv, row, toks, start):
