@@ -1,0 +1,199 @@
+"""The paged-attention kernel alone on the chip, one JSON line a geometry:
+device microseconds a call (summed over the Mosaic calls of a profiler
+trace), microseconds a live block, and the call's share of its roofline
+(the K/V bytes the masks let through and the operations over them,
+``chipbench/flops.py`` and ``chipbench/hybrid_bytes.py``; the chip's
+peaks from its table).
+
+    chiprun -- python3 benchmarks/paged_walk.py [--only agent_turns,verify_window] \
+        [--calls 20] [--out chiprun_out/paged_walk.jsonl]
+
+The geometries are the serving cells' calls and the callers no cell
+runs: ``agent_turns`` (24 slots x 24 entries, 16 heads of 128, one row
+a block), ``reason_decode`` (one pass's plane of the folded pool, 10
+slots), ``think_decode``'s full and window-512 planes (48 slots, a K/V
+group of 4 folded into four rows a block), the speculative verify window
+(5 rows at consecutive positions) and the 12-head pool that takes the
+grid form, alone and under a verify window.  Live slots and their
+contexts are drawn from ``--seed`` in the range each cell's traffic
+reaches; a dead slot has a row of trash and ``pos = -1``.  Refuses unless JAX finds a TPU: a number from a CPU
+run is no device metric.
+"""
+
+import argparse
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from flash_walk import _timed  # noqa: E402 - device seconds of the Mosaic calls
+
+# name -> slots, query rows before the group is folded, table entries a
+# slot, pool blocks, rows a pool block has, lanes a row, query rows a K/V
+# row, lower bound, live slots, their contexts (tokens attended, the new
+# one included), and the configuration whose sizes count the bytes
+GEOMETRIES = {
+    "agent_turns": dict(S=24, W=1, NB=24, blocks=705, rows=16, dh=128,
+                        group=1, window=None, live=5, ctx=(520, 760),
+                        config="cerebras-gpt-1.3b"),
+    "reason_decode": dict(S=10, W=1, NB=16, blocks=708, rows=16, dh=128,
+                          group=1, window=None, live=5, ctx=(80, 500),
+                          config="ouro-2.6b"),
+    "think_decode_full": dict(S=48, W=1, NB=64, blocks=3073, rows=16,
+                              dh=128, group=4, window=None, live=20,
+                              ctx=(160, 2000),
+                              config="phi-4-mini-flash-reasoning"),
+    "think_decode_window": dict(S=48, W=1, NB=64, blocks=3073, rows=16,
+                                dh=128, group=4, window=512, live=20,
+                                ctx=(160, 2000),
+                                config="phi-4-mini-flash-reasoning"),
+    "verify_window": dict(S=24, W=5, NB=24, blocks=705, rows=16, dh=128,
+                          group=1, window=None, live=5, ctx=(520, 760),
+                          config="cerebras-gpt-1.3b"),
+    "grid_12_heads": dict(S=8, W=1, NB=16, blocks=200, rows=12, dh=128,
+                          group=1, window=None, live=6, ctx=(64, 500),
+                          config="cerebras-gpt-590m"),
+    "grid_12_heads_verify": dict(S=8, W=5, NB=16, blocks=200, rows=12,
+                                 dh=128, group=1, window=None, live=6,
+                                 ctx=(64, 500), config="cerebras-gpt-590m"),
+}
+BLOCK_TOKENS = 32
+
+
+def _config(name):
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "chipbench", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+def case(name, seed):
+    """The call's arguments as numpy arrays, and what it has to visit:
+    ``(q, pool_k, pool_v, table, pos, how, live_blocks, attended)``."""
+    import numpy as np
+
+    g = GEOMETRIES[name]
+    S, W, NB, B = g["S"], g["W"], g["NB"], BLOCK_TOKENS
+    rng = np.random.default_rng(seed)
+    shape = (g["blocks"], B, g["rows"], g["dh"])
+    pool_k = rng.standard_normal(shape, np.float32) * 0.5
+    pool_v = rng.standard_normal(shape, np.float32) * 0.5
+    heads = _kv_rows(g) * g["group"]
+    q = rng.standard_normal((S, W, heads, g["dh"]), np.float32) * 0.5
+    table = np.zeros((S, NB), np.int32)
+    pos = np.full((S, W), -1, np.int32)
+    free = rng.permutation(np.arange(1, g["blocks"]))
+    live_blocks = attended = 0
+    for s in rng.choice(S, g["live"], replace=False):
+        ctx = int(rng.integers(g["ctx"][0], g["ctx"][1] + 1))
+        ctx = min(ctx, NB * B)
+        pos[s] = ctx - W + np.arange(W)
+        n = (ctx - 1) // B + 1
+        table[s, :n], free = free[:n], free[n:]
+        for at in pos[s]:
+            low = 0 if g["window"] is None else max(at - g["window"] + 1, 0)
+            attended += int(at) + 1 - low
+        low = (0 if g["window"] is None
+               else max(int(pos[s].min()) - g["window"] + 1, 0))
+        live_blocks += n - low // B
+    how = dict(group=g["group"], window=g["window"])
+    return q, pool_k, pool_v, table, pos, how, live_blocks, attended
+
+
+def _kv_rows(g):
+    """Rows of a block that hold K/V (``pool_rows`` may have added
+    empty ones): a pair of the hybrid's K/V heads a row."""
+    if g["group"] == 1:
+        return g["rows"]
+    from chipbench import hybrid_bytes
+
+    return hybrid_bytes.sizes(_config(g["config"]))["kv_heads"] // 2
+
+
+def least_seconds(name, attended, peak):
+    """The least time the chip could take for one call: the K/V of the
+    positions the masks let through, once a query row of the window
+    (every row of a K/V group reads the same keys: once), and the
+    operations over them."""
+    from chipbench import families, flops, hybrid_bytes
+
+    g = GEOMETRIES[name]
+    cfg = _config(g["config"])
+    if g["group"] > 1:
+        size = hybrid_bytes.sizes(cfg)
+        ops = 6 * size["heads"] * size["head_dim"] * attended
+        nbytes = hybrid_bytes.plane_token_bytes(cfg) * attended
+    else:
+        size = families.sizes(cfg)
+        ops = 4 * size["heads"] * size["head_dim"] * attended
+        nbytes = (flops.kv_bytes_per_token(cfg) // size["kv_planes"]
+                  * attended / g["W"])
+    return flops.roofline_seconds(ops, nbytes, peak)
+
+
+def measure(name, calls, peak, seed):
+    import jax
+    import jax.numpy as jnp
+
+    import numpy as np
+
+    from paddle_tpu.kernels.paged_attention import (
+        paged_attention_pallas, paged_attention_ref)
+
+    q, pk, pv, table, pos, how, live_blocks, attended = case(name, seed)
+    fn = jax.jit(lambda *a: paged_attention_pallas(
+        *a, interpret=False, out_dtype=jnp.float32, **how))
+    args = (jnp.asarray(q, jnp.bfloat16), jnp.asarray(pk, jnp.bfloat16),
+            jnp.asarray(pv, jnp.bfloat16), jnp.asarray(table),
+            jnp.asarray(pos))
+    us = _timed(fn, args, calls)
+    # the live slots' rows against the block-scan oracle, on the chip
+    live = pos.max(axis=1) >= 0
+    got = np.asarray(fn(*args))[live]
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(lambda *a: paged_attention_ref(
+            *a, out_dtype=jnp.float32, **how))(*args))[live]
+    err = float(np.abs(got - want).max() / np.abs(want).max())
+    least, bound = least_seconds(name, attended, peak)
+    g = GEOMETRIES[name]
+    return {"geometry": name, **{k: g[k] for k in (
+        "S", "W", "NB", "rows", "dh", "group", "window", "live")},
+        "live_blocks": live_blocks, "rows_a_block": g["W"] * g["group"],
+        "us_a_call": us, "us_a_live_block": us / live_blocks,
+        "roofline_pct": 100.0 * least * 1e6 / us, "bound": bound,
+        "rel_err_vs_xla_ref": err}
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--only", default="",
+                    help="comma-separated geometry names (default: all)")
+    ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=33)
+    ap.add_argument("--out", default="chiprun_out/paged_walk.jsonl")
+    args = ap.parse_args()
+
+    import jax
+
+    from chipbench import flops
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit(f"paged_walk times the chip; JAX found {dev.platform}")
+    peak = flops.peaks(dev.device_kind)
+    names = [n for n in args.only.split(",") if n] or list(GEOMETRIES)
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "a") as f:
+        for name in names:
+            try:
+                line = measure(name, args.calls, peak, args.seed)
+            except Exception as e:  # noqa: BLE001 - a geometry Mosaic refuses
+                line = {"geometry": name, "error": repr(e)[:400]}
+            line["device"] = dev.device_kind
+            text = json.dumps(line)
+            print(text, flush=True)
+            f.write(text + "\n")
+
+
+if __name__ == "__main__":
+    main()

@@ -79,7 +79,12 @@ Backends:
   is masked for every row); the others are neither fetched nor
   computed, each block
   copied from the pool where it lies into a two-deep VMEM buffer while
-  ``(m, l, acc)`` carry in VMEM scratch.  A row with ``pos < 0`` has no
+  ``(m, l, acc)`` carry in VMEM scratch.  A live block is folded ONCE
+  for all the rows of the window (a K/V group's rows among them): their
+  scores are one MXU pass, and the mask, the running maximum, the
+  ``exp`` and the sums are one update over one array that holds the rows
+  side by side on its lanes; only ``p * v`` is a row's own work
+  (``softmax_updates``).  A row with ``pos < 0`` has no
   visible key and returns zeros; a slot of such rows (a dead slot, as
   ``batched_decode._Cache`` names it) costs one empty grid
   step.  Registered available on real TPU only (off-TPU the
@@ -96,12 +101,12 @@ from .registry import register_kernel, resolve
 from .xla_ref import NEG_INF
 
 __all__ = ["attend", "DENSE_WINDOW", "paged_attention_ref",
-           "paged_attention_pallas", "pool_rows"]
+           "paged_attention_pallas", "pool_rows", "softmax_updates"]
 
 # From this window width up a window gathers its slot's chain once and
 # attends it densely instead of streaming blocks.  W rows then share one
-# read of K and V and the scores are MXU matmuls, where the streaming
-# kernels repeat their per-block body once per window row; and the
+# read of K and V and both products are MXU matmuls, where the streaming
+# kernels weigh the values once per window row on the VPU; and the
 # Mosaic kernel cannot run wide at all (18.6 MB of scoped VMEM at W = 64;
 # PERF.md, PR 26).
 DENSE_WINDOW = 8
@@ -137,6 +142,19 @@ def pool_rows(heads, dtype):
     if jnp.dtype(dtype).itemsize >= 4 or heads % 8 == 0:
         return heads
     return -(-heads // 8) * 8
+
+
+def softmax_updates(rows):
+    """Online-softmax updates the Mosaic kernel makes for ONE live block
+    that ``rows`` query rows attend (the window's rows, a K/V group
+    folded in): one, however many the rows, because they sit side by
+    side on the lanes of one array and share the mask, the maximum, the
+    ``exp`` and the sums.  Stated here, as a function of the folded
+    width, for whoever counts the kernel's work
+    (``serving.paged_updates_live``): the engine does not guess."""
+    if rows < 1:
+        raise ValueError(f"paged_attention: {rows} rows a block")
+    return 1
 
 
 def _fold_group(q, pos, group, rows):
@@ -291,7 +309,8 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     adds ``p = 0`` and scales by ``alpha = exp(m - m) = 1``: leaving it
     out changes no bit of a row with at least one visible key.  A row
     with ``pos < 0`` has none and returns ZEROS (``l == 0 -> 1`` over an
-    ``acc`` of zeros); a slot whose rows are all negative costs one empty
+    ``acc`` of zeros; beside rows that do see keys, by its position at
+    the end); a slot whose rows are all negative costs one empty
     grid step.  That is how the serving step names a dead slot
     (``batched_decode._Cache``).  With a ``window`` the chain
     also has a FIRST live entry, ``f_s = max(min_w pos[s, w] - window +
@@ -317,13 +336,32 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     pipeline, which the loop does not pay.
 
     The block stays in the pool's own ``[B, h, dh]`` layout (tokens on
-    the untiled axis, heads on sublanes, ``dh`` on lanes) and the math
-    is VPU-only: scores are a lane reduction of ``k * q`` per window
-    position, the softmax reduces over the untiled token axis, and
-    ``p * v`` accumulates the same way — no transpose, no in-kernel
-    relayout, no MXU shape Mosaic could refuse.  ``block_step`` is
-    accepted for signature parity and ignored — this spelling streams
-    exactly one block per iteration by construction."""
+    the untiled axis, heads on sublanes, ``dh`` on lanes), and it is
+    folded ONCE for all ``W`` rows of the window (the rows a K/V group
+    folded in among them).  Scores are one MXU pass, ``[B * h, dh] x
+    [dh, W * h]`` with the queries as the stationary operand (f32 out of
+    exact products; ``HIGHEST`` for a float32 pool): row ``w``'s score
+    against head ``j`` is at lane ``w * h + j`` of sublane ``j``, the
+    own-head diagonal, and everything off it is masked.  The scale, the
+    position mask (each lane its own row's position, so the rows of a
+    verify window keep their own masks and the rows of a K/V group share
+    one), the maximum over tokens, ``exp`` and the sum then run ONCE a
+    block over that ``[B, h, W * h]`` array, and ``m`` and ``l`` in
+    scratch hold row ``w`` of head ``j`` at the same lane.  Only ``p *
+    v`` is a row's own work, on the VPU in f32: the row's lanes of ``p``
+    summed into a lane-replicated ``[B, h, 1]`` column (one lane
+    reduction a register, the one cross-lane step a row still costs)
+    times ``v``, summed over the untiled token axis.  No transpose, no
+    bf16 arithmetic, no key left out.  With ONE row (``W = 1`` and no
+    group: plain decode) there is nothing to share and the body is the
+    per-row program it always was: the score a lane reduction of ``k *
+    q``, ``m`` and ``l`` lane-replicated (measured alone on the chip the
+    shared body is 1.5% faster there in the loop form and 15% slower in
+    the grid form, whose 12 heads do not fill a sublane tile and have to
+    be repacked for the MXU: ``benchmarks/paged_walk.py``, PERF.md PR
+    33).  ``block_step`` is accepted for signature parity and ignored:
+    this spelling streams exactly one block per iteration by
+    construction."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -356,42 +394,99 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             low = jnp.minimum(low, pos_ref[s_id, w])
         return jax.lax.div(jnp.maximum(low - window + 1, 0), B)
 
-    # per-(window, head) softmax statistics sit lane-replicated in
-    # scratch, the flash kernels' convention: a [h, 1] row is below
-    # Mosaic's minimum lane tile; ``st`` below is these three refs,
-    # ``(m_ref, l_ref, acc_ref)``
+    # One row a block (plain decode with a K/V head a query head) has
+    # nothing to share: it keeps the per-row program this kernel always
+    # was, statistics lane-replicated in scratch.  From two rows up the
+    # rows share ONE update a block: row w of head j sits at lane
+    # ``w * h + j`` of ``[.., h, N]`` arrays (own-head diagonal), and so
+    # do ``m`` and ``l`` in scratch.  ``st`` below is the three refs
+    # ``(m_ref, l_ref, acc_ref)``.
+    N = W * h
+    f32 = jnp.float32
+
     def init(m_ref, l_ref, acc_ref):
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def own_lanes(w, ndim=2):
+        """Row ``w``'s lanes of an ``ndim``-d ``[.., h, N]`` array (ones
+        before the last two axes): head ``j`` at lane ``w * h + j`` of
+        sublane ``j``."""
+        shape = (1,) * (ndim - 2) + (h, N)
+        return (jax.lax.broadcasted_iota(jnp.int32, shape, ndim - 1)
+                == w * h + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                    ndim - 2))
+
+    def column(x, w):
+        """Row ``w``'s ``[.., h, 1]`` column of ``x [.., h, N]``: its
+        lanes summed (one non-zero each), the result lane-replicated."""
+        return jnp.sum(jnp.where(own_lanes(w, x.ndim), x, 0.0), axis=-1,
+                       keepdims=True)
+
     def fold(i, kb, vb, s_id, pos_ref, q_ref, m_ref, l_ref, acc_ref):
-        """Block ``i`` of slot ``s_id``'s chain into the slot's state."""
-        kb = kb.astype(jnp.float32)                        # [B, h, dh]
-        vb = vb.astype(jnp.float32)
-        tok = i * B + jax.lax.broadcasted_iota(jnp.int32, (B, h, 1), 0)
-        for w in range(W):
-            qw = q_ref[0, w].astype(jnp.float32)           # [h, dh]
+        """Block ``i`` of slot ``s_id``'s chain into the slot's state,
+        once for all the rows of the window."""
+        if W == 1:
+            kb = kb.astype(f32)                            # [B, h, dh]
+            vb = vb.astype(f32)
+            tok = i * B + jax.lax.broadcasted_iota(jnp.int32, (B, h, 1), 0)
+            qw = q_ref[0, 0].astype(f32)                   # [h, dh]
             s = jnp.sum(kb * qw[None], axis=-1, keepdims=True) * scale
-            keep = tok <= pos_ref[s_id, w]
+            keep = tok <= pos_ref[s_id, 0]
             if window is not None:
-                keep &= tok > pos_ref[s_id, w] - window
+                keep &= tok > pos_ref[s_id, 0] - window
             s = jnp.where(keep, s, NEG_INF)                 # [B, h, 1]
-            m = m_ref[w][:, :1]                            # [h, 1]
+            m = m_ref[0][:, :1]                            # [h, 1]
             m2 = jnp.maximum(m, jnp.max(s, axis=0))
             alpha = jnp.exp(m - m2)
             p = jnp.exp(s - m2[None])
-            l2 = l_ref[w][:, :1] * alpha + jnp.sum(p, axis=0)
-            acc_ref[w] = acc_ref[w] * alpha + jnp.sum(p * vb, axis=0)
-            m_ref[w] = jnp.broadcast_to(m2, (h, LSE_LANES))
-            l_ref[w] = jnp.broadcast_to(l2, (h, LSE_LANES))
+            l2 = l_ref[0][:, :1] * alpha + jnp.sum(p, axis=0)
+            acc_ref[0] = acc_ref[0] * alpha + jnp.sum(p * vb, axis=0)
+            m_ref[0] = jnp.broadcast_to(m2, (h, LSE_LANES))
+            l_ref[0] = jnp.broadcast_to(l2, (h, LSE_LANES))
+            return
+        # scores of every row against every head in ONE MXU pass, f32
+        # out of exact products; a row's own head is the diagonal
+        dt = jnp.promote_types(kb.dtype, q_ref.dtype)
+        s = jax.lax.dot_general(
+            kb.reshape(B * h, dh).astype(dt),
+            q_ref[0].reshape(N, dh).astype(dt), (((1,), (1,)), ((), ())),
+            preferred_element_type=f32,
+            precision=(jax.lax.Precision.HIGHEST if dt == f32 else None))
+        s = s.reshape(B, h, N) * scale
+        # each lane's position less the block's first token; -1 off the
+        # diagonal, which is under every token: masked
+        at = jnp.full((1, h, N), -1, jnp.int32)
+        for w in range(W):
+            at = jnp.where(own_lanes(w, 3), pos_ref[s_id, w] - i * B, at)
+        tok = jax.lax.broadcasted_iota(jnp.int32, (B, h, N), 0)
+        keep = tok <= at
+        if window is not None:
+            keep &= tok > at - window
+        s = jnp.where(keep, s, NEG_INF)                     # [B, h, N]
+        m = m_ref[...]                                     # [h, N]
+        m2 = jnp.maximum(m, jnp.max(s, axis=0))
+        alpha = jnp.exp(m - m2)
+        p = jnp.exp(s - m2[None])
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0)
+        m_ref[...] = m2
+        # p * v is a row's own work: its lane of p over the lanes of v
+        vb = vb.astype(f32)                                # [B, h, dh]
+        for w in range(W):
+            acc_ref[w] = (acc_ref[w] * column(alpha, w)
+                          + jnp.sum(column(p, w) * vb, axis=0))
 
-    def finish(o_ref, m_ref, l_ref, acc_ref):
+    def finish(s_id, pos_ref, o_ref, m_ref, l_ref, acc_ref):
         del m_ref
         for w in range(W):
-            l = l_ref[w][:, :1]
+            l = l_ref[0][:, :1] if W == 1 else column(l_ref[...], w)
             l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, w] = (acc_ref[w] / l_safe).astype(o_ref.dtype)
+            out = acc_ref[w] / l_safe
+            if W > 1:
+                # a row with no visible key beside rows that have some
+                out = jnp.where(pos_ref[s_id, w] >= 0, out, 0.0)
+            o_ref[0, w] = out.astype(o_ref.dtype)
 
     def loop_kernel(tbl, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                     k_buf, v_buf, sem, *st):
@@ -426,7 +521,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             fold(i, k_buf[half], v_buf[half], s_id, pos_ref, q_ref, *st)
 
         jax.lax.fori_loop(first, n, block, None)
-        finish(o_ref, *st)
+        finish(s_id, pos_ref, o_ref, *st)
 
     def grid_kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref, *st):
         del tbl  # consumed by the index maps, not the body
@@ -447,10 +542,10 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
 
         @pl.when(nb == NB - 1)
         def _finish():
-            finish(o_ref, *st)
+            finish(s_id, pos_ref, o_ref, *st)
 
-    stats = [pltpu.VMEM((W, h, LSE_LANES), jnp.float32),
-             pltpu.VMEM((W, h, LSE_LANES), jnp.float32),
+    stat = (1, h, LSE_LANES) if W == 1 else (h, N)
+    stats = [pltpu.VMEM(stat, jnp.float32), pltpu.VMEM(stat, jnp.float32),
              pltpu.VMEM((W, h, dh), jnp.float32)]
     if _block_is_sliceable(pool_k):
         kernel, grid, semantics = loop_kernel, (S,), ("parallel",)

@@ -125,6 +125,14 @@ class Architecture:
             reads[window] = reads.get(window, 0) + self.passes
         return tuple(reads.items())
 
+    @property
+    def rows_per_entry(self):
+        """Query rows a decode call sends through each block it attends:
+        the ``group`` its ``attend`` names (query heads a K/V row, which
+        the paged kernel folds into its window), 1 where every query
+        head has a K/V head of its own."""
+        return 1
+
     def pool_block_shape(self, block_tokens, dtype):
         """``[block_tokens, rows, lanes]`` of one block of a pool array
         in ``dtype``."""
@@ -445,6 +453,11 @@ class SambaY(Architecture):
         return ((self.window, self.kinds.count("window")),
                 (None, 1 + self.kinds.count("cross")))
 
+    @property
+    def rows_per_entry(self):
+        # two query pairs a K/V row, the two halves of a pair a row each
+        return 2 * self.n_head // self.kv_heads
+
     def pool_block_shape(self, block_tokens, dtype):
         # a row is a PAIR of K/V heads (class docstring)
         return (block_tokens,
@@ -549,7 +562,7 @@ class SambaY(Architecture):
         rows = jnp.where(lane_half, q.reshape(*lead, pairs, 1, 2 * dh), 0)
         ctx, planes = cache(
             planes, plane, 0, rows.reshape(*lead, 2 * pairs, 2 * dh), k, v,
-            group=2 * self.n_head // self.kv_heads, window=window,
+            group=self.rows_per_entry, window=window,
             scale=dh ** -0.5, out_dtype=f32)
         ctx = ctx.reshape(*lead, pairs, 2, 2 * dh)
         lam0 = 0.8 - 0.6 * float(np.exp(-0.3 * i))

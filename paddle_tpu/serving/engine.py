@@ -62,6 +62,7 @@ import time
 
 import numpy as np
 
+from ..kernels import paged_attention as _paged
 from ..observability import flight as _flight
 from ..observability import metrics as _obs
 from ..observability import trace as _trace
@@ -512,8 +513,11 @@ class ServingEngine:
         how many hold a key its first step attends (position ``prompt +
         tokens - 1`` and everything before it down to the plane's lower
         bound, in the live slots only), as the mean over the calls a
-        token makes; and the K/V bytes those calls have to read."""
+        token makes; the query rows a call sends through each and the
+        softmax updates the kernel makes for them; and the K/V bytes
+        those calls have to read."""
         B = self.block_tokens
+        rows = self.arch.rows_per_entry
         live = streamed = 0
         for req in self._slots:
             if req is None:
@@ -530,6 +534,21 @@ class ServingEngine:
                  "the plane's lower bound up to each one's position at "
                  "the chunk's start; the mean over a token's calls)",
         ).inc(live / self._reads_per_token)
+        self._reg.counter(
+            "serving.paged_rows_live",
+            help="query rows the paged-attention calls sent through the "
+                 "entries of paged_entries_live: a decode call folds the "
+                 "query heads of one K/V row into that many rows of its "
+                 "window (arch.rows_per_entry)",
+        ).inc(live / self._reads_per_token * rows)
+        self._reg.counter(
+            "serving.paged_updates_live",
+            help="online-softmax updates the paged kernel made for the "
+                 "entries of paged_entries_live "
+                 "(kernels.paged_attention.softmax_updates of the rows a "
+                 "call sends through an entry): paged_rows_live's "
+                 "denominator",
+        ).inc(live / self._reads_per_token * _paged.softmax_updates(rows))
         self._reg.counter(
             "serving.paged_entries_total",
             help="block-table entries a paged-attention call spans "

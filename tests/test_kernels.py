@@ -340,6 +340,31 @@ _GROUP_WINDOW_CASES = {
 }
 
 
+def _windowed_truth(q, pk, pv, table, pos, group, window, scale):
+    """The dense truth, in numpy, from the values the backends see: row
+    ``r`` of slot ``s`` attends keys ``max(0, pos - window + 1) .. pos``
+    of its chain, query head ``i`` the K/V head ``i // group``; a row
+    with ``pos < 0`` stays zeros."""
+    S, NB = table.shape
+    dh = q.shape[-1]
+    k32 = np.asarray(pk, np.float32)[table].reshape(S, -1, pk.shape[2], dh)
+    v32 = np.asarray(pv, np.float32)[table].reshape(S, -1, pv.shape[2], dh)
+    q32 = np.asarray(q, np.float32)
+    want = np.zeros(q.shape, np.float32)
+    for s_ in range(S):
+        for r in range(q.shape[1]):
+            at = int(pos[s_, r])
+            if at < 0:
+                continue
+            lo = 0 if window is None else max(0, at - window + 1)
+            for i in range(q.shape[2]):
+                sc = k32[s_, lo:at + 1, i // group] @ q32[s_, r, i] * scale
+                a = np.exp(sc - sc.max())
+                want[s_, r, i] = (a / a.sum()) @ v32[s_, lo:at + 1,
+                                                     i // group]
+    return want
+
+
 def _group_window_case(name, dtype, hk, w, seed=5):
     group, window, extra = _GROUP_WINDOW_CASES[name]
     rng = np.random.default_rng(seed)
@@ -354,22 +379,7 @@ def _group_window_case(name, dtype, hk, w, seed=5):
     pos = np.where(last < 0, -1, last - (w - 1) + np.arange(w)[None, :])
     q = jnp.asarray(rng.normal(size=(S, w, hk * group, dh)) * 0.5, dt)
     pk, pv = jnp.asarray(pool_k, dt), jnp.asarray(pool_v, dt)
-    # the dense truth, from the values the backends see
-    k32 = np.asarray(pk, np.float32)[table].reshape(S, NB * B, -1, dh)
-    v32 = np.asarray(pv, np.float32)[table].reshape(S, NB * B, -1, dh)
-    q32 = np.asarray(q, np.float32)
-    want = np.zeros(q.shape, np.float32)
-    for s_ in range(S):
-        for r in range(w):
-            at = int(pos[s_, r])
-            if at < 0:
-                continue
-            lo = 0 if window is None else max(0, at - window + 1)
-            for i in range(hk * group):
-                sc = k32[s_, lo:at + 1, i // group] @ q32[s_, r, i] * 0.3
-                a = np.exp(sc - sc.max())
-                want[s_, r, i] = (a / a.sum()) @ v32[s_, lo:at + 1,
-                                                     i // group]
+    want = _windowed_truth(q, pk, pv, table, pos, group, window, 0.3)
     # blocks some row's bounds let through
     visited = np.zeros(shape[0], bool)
     for s_ in range(S):
@@ -441,6 +451,100 @@ def test_paged_mosaic_window_starts_at_its_first_block(case, dtype, hk):
         tbl, pos, interpret=True, **how)
     assert bool(jnp.all(jnp.isfinite(again.astype(jnp.float32))))
     assert bool(jnp.array_equal(base[live], again[live]))
+
+
+# the shared fold (PR 33): a live block is folded ONCE for all the rows
+# of the window (a K/V group's rows included): the rows side by side on
+# the lanes of one array, one softmax update for all of them.  Three slots over
+# NB = 16 blocks of B = 8 tokens (T = 128): slot 0's window ends at
+# position 70 with its rows at DIFFERENT positions (a verify window: no
+# two rows share a mask), slot 1 has a row with ``pos < 0`` beside live
+# ones (dead where W = 1), slot 2's window ends at the chain's last
+# position.
+def _shared_fold_case(w, group, window, dtype, hk, seed=13):
+    rng = np.random.default_rng(seed)
+    S, NB, B, dh = 3, 16, 8, 16
+    dt = jnp.dtype(dtype)
+    shape = (1 + S * NB, B, hk, dh)
+    pool_k = np.asarray(rng.normal(size=shape) * 0.5, np.float32)
+    pool_v = np.asarray(rng.normal(size=shape) * 0.5, np.float32)
+    pool_k[0] = pool_v[0] = 1e3                      # the trash block
+    table = 1 + np.arange(S * NB, dtype=np.int32).reshape(S, NB)
+    last = np.array([70, 37, NB * B - 1])
+    pos = last[:, None] - (w - 1) + np.arange(w)[None, :]
+    pos[1, 0] = -1
+    table[0, 70 // B + 1:] = 0
+    table[1, 37 // B + 1:] = 0
+    if w == 1:
+        table[1] = 0
+    q = jnp.asarray(rng.normal(size=(S, w, hk * group, dh)) * 0.5, dt)
+    pk, pv = jnp.asarray(pool_k, dt), jnp.asarray(pool_v, dt)
+    want = _windowed_truth(q, pk, pv, table, pos, group, window,
+                           dh ** -0.5)             # the kernels' default
+    return (q, pk, pv, jnp.asarray(table), jnp.asarray(pos, jnp.int32),
+            dict(group=group, window=window), jnp.asarray(want), pos >= 0)
+
+
+@pytest.mark.parametrize("dtype,hk", _LIVE_FORMS)
+@pytest.mark.parametrize("window", [None, 48, 512])
+@pytest.mark.parametrize("group", [1, 2, 4])
+@pytest.mark.parametrize("w", [1, 2, 4, 5])
+def test_paged_shared_fold_matches_the_oracle_and_the_dense_truth(
+        w, group, window, dtype, hk):
+    """Every row of every window width, K/V group and lower bound, in
+    the loop form and the grid form: the Mosaic kernel (interpret)
+    against the dense truth and against ``xla_ref``; a row with ``pos <
+    0`` is zeros although its neighbours in the window are live."""
+    from paddle_tpu.kernels.paged_attention import (
+        paged_attention_pallas, paged_attention_ref)
+
+    q, pk, pv, tbl, pos, how, want, live = _shared_fold_case(
+        w, group, window, dtype, hk)
+    got = paged_attention_pallas(q, pk, pv, tbl, pos, interpret=True,
+                                 out_dtype=jnp.float32, **how)
+    assert got.shape == q.shape and got.dtype == jnp.float32
+    tol = oracle_tol("paged_attention", dtype, "fwd")
+    ref = paged_attention_ref(q, pk, pv, tbl, pos, out_dtype=jnp.float32,
+                              **how)
+    assert live.any() and not live.all()
+    assert _rel_err(got[live], want[live]) <= tol
+    assert _rel_err(got[live], ref[live]) <= tol
+    assert not np.asarray(got)[~live].any()
+
+
+@pytest.mark.parametrize("dtype,hk", _LIVE_FORMS)
+@pytest.mark.parametrize("w,group", [(1, 4), (5, 1), (2, 2)])
+def test_paged_shared_fold_is_bit_exact_run_to_run(w, group, dtype, hk):
+    from paddle_tpu.kernels.paged_attention import paged_attention_pallas
+
+    q, pk, pv, tbl, pos, how, _, _ = _shared_fold_case(w, group, 48, dtype,
+                                                       hk)
+    a = paged_attention_pallas(q, pk, pv, tbl, pos, interpret=True, **how)
+    b = paged_attention_pallas(q, pk, pv, tbl, pos, interpret=True, **how)
+    assert bool(jnp.array_equal(a, b))
+    assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("dtype,hk", _LIVE_FORMS)
+@pytest.mark.parametrize("w,group", [(1, 1), (2, 1), (1, 4), (5, 1), (3, 2)])
+def test_paged_mosaic_makes_one_softmax_update_a_block(w, group, dtype, hk):
+    """What the fold shares, read off the traced kernel: however many
+    rows attend a block (window rows x K/V group) the body holds TWO
+    ``exp`` (``alpha`` and ``p``), where a per-row body holds two a row;
+    from two rows up the scores are ONE ``dot_general``; one row keeps
+    the per-row program (a product and a lane reduction, no matmul),
+    which is what every ``W = 1`` caller lowered to before."""
+    from paddle_tpu.kernels.paged_attention import (
+        paged_attention_pallas, softmax_updates)
+
+    q, pk, pv, tbl, pos, how, _, _ = _shared_fold_case(w, group, 48, dtype,
+                                                       hk)
+    counts = _primitive_counts(jax.make_jaxpr(
+        lambda *a: paged_attention_pallas(*a, interpret=True, **how))(
+            q, pk, pv, tbl, pos).jaxpr)
+    assert counts.get("pallas_call") == 1
+    assert counts.get("exp") == 2 * softmax_updates(w * group), counts
+    assert counts.get("dot_general", 0) == (0 if w * group == 1 else 1)
 
 
 def test_paged_defaults_lower_to_the_program_they_always_did():

@@ -49,6 +49,13 @@ GEOMETRIES = {
     # four masked half-rows of query a K/V row (serving.arch.SambaY)
     "think_decode_full_plane": (48, 1, 64, 3073, 16, 128, 4, None),
     "think_decode_window_plane": (48, 1, 64, 3073, 16, 128, 4, 512),
+    # more than one row a block folds the block once for all of them,
+    # scores as one MXU pass (PR 33): the widest windows `attend` streams
+    # (DENSE_WINDOW - 1 rows), lanes past one tile, the grid form
+    "verify_window_5_rows": (24, 5, 24, 705, 16, 128, 1, None),
+    "widest_streamed_window": (24, 7, 24, 705, 16, 128, 1, None),
+    "group_4_under_a_verify_window": (48, 5, 64, 3073, 16, 128, 4, 512),
+    "cgpt590m_verify_window_grid_form": (8, 5, 16, 200, 12, 128, 1, None),
 }
 
 

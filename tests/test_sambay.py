@@ -328,6 +328,12 @@ def test_gauges_spans_and_the_streamed_bytes(params, monkeypatch):
     # (8..15), the full plane 3 and 4; the mean over the four calls
     assert st["serving.paged_entries_live"] == (2 * (2 + 2) + 2 * (3 + 4)) / 4
     assert st["serving.paged_entries_total"] == 2 * 2 * (T // B)
+    # the query heads of a K/V row go through an entry as rows of the
+    # kernel's window, which folds the block once for all of them
+    assert eng.arch.rows_per_entry == 2 * eng.arch.n_head // eng.arch.kv_heads
+    assert st["serving.paged_rows_live"] == (
+        st["serving.paged_entries_live"] * eng.arch.rows_per_entry)
+    assert st["serving.paged_updates_live"] == st["serving.paged_entries_live"]
 
 
 @pytest.mark.parametrize("refused", ["prefix_reuse", "draft"])

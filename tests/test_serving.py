@@ -177,6 +177,35 @@ def test_engine_from_architecture_object_equals_positional(params):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("rows,updates", [(1, 1), (4, 1), (28, 1)])
+def test_engine_counts_rows_and_softmax_updates_of_live_entries(
+        params, rows, updates):
+    """``serving.paged_rows_live`` is the live table entries times the
+    query rows an architecture's decode call sends through each
+    (``arch.rows_per_entry``, the K/V group the kernel folds into its
+    window); ``serving.paged_updates_live`` the softmax updates the paged
+    kernel makes for them, as its own module states them
+    (``softmax_updates``: one for all the rows of a block)."""
+    from paddle_tpu.kernels.paged_attention import softmax_updates
+    from paddle_tpu.serving.arch import Gpt2
+
+    class Grouped(Gpt2):
+        rows_per_entry = rows
+
+    assert Gpt2(NL, NH, DM).rows_per_entry == 1
+    assert softmax_updates(rows) == updates
+    eng = ServingEngine(params, arch=Grouped(NL, NH, DM), max_len=T,
+                        max_slots=2, decode_chunk=4, min_bucket=4,
+                        block_tokens=4, prefix_reuse=False)
+    eng.generate_many([np.arange(1, 6), np.arange(1, 10)],
+                      max_new_tokens=5)
+    st = eng.stats()
+    # one chunk: 5 + 1 and 9 + 1 tokens in entries of 4 positions
+    assert st["serving.paged_entries_live"] == 2 + 3
+    assert st["serving.paged_rows_live"] == (2 + 3) * rows
+    assert st["serving.paged_updates_live"] == (2 + 3) * updates
+
+
 def test_bf16_weights_serve_in_bf16_and_match(params):
     """bf16 block weights: the engine infers bf16 compute (cache
     discipline) and still matches the single-stream bf16 decode."""
