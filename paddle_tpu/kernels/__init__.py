@@ -2,7 +2,8 @@
 (docs/kernels.md, ROADMAP item 2).
 
 One op, two targets, one numerics oracle: every fused op class
-(``flash_attention``, ``fused_ce``, ``paged_attention``) resolves
+(``flash_attention``, ``fused_ce``, ``paged_attention``,
+``grouped_matmul``) resolves
 through :mod:`.registry` to ``pallas_tpu`` (the Mosaic kernels — native
 on TPU, interpret mode in CPU tests) or ``xla_ref`` (:mod:`.xla_ref` —
 the shape-complete pure-XLA reference every backend is tested against,
@@ -27,6 +28,7 @@ from .registry import (
 from .xla_ref import ORACLE_TOL, oracle_tol
 from . import xla_ref  # registers the oracle backend
 from . import paged_attention  # registers the paged-attention op class
+from . import grouped_matmul  # registers the grouped matrix product
 
 __all__ = [
     "AUTO_ORDER", "BACKENDS", "GLOBAL_ENV", "TIMED_RUN_ENV",

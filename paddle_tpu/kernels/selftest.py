@@ -49,7 +49,8 @@ def _check_registry(failures):
     ops = registered_op_classes()
     print(f"registry: op classes {ops} on platform "
           f"{jax.default_backend()!r}")
-    if sorted(ops) != ["flash_attention", "fused_ce", "paged_attention"]:
+    if sorted(ops) != ["flash_attention", "fused_ce", "grouped_matmul",
+                       "paged_attention"]:
         failures.append(f"unexpected op classes: {ops}")
     for op in ops:
         auto = resolve_name(op)

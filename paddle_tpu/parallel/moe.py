@@ -10,6 +10,15 @@ per-expert capacity (static shapes — XLA requirement); overflow tokens
 fall through the residual path.  A load-balancing auxiliary loss
 (mean gate fraction × mean routed fraction per expert) is returned for
 the trainer to add to the objective.
+
+This is the PROGRAM path's layer: softmax top-k gating with a fixed
+capacity, so tokens past an expert's capacity are DROPPED, and the
+exchange between devices is part of it.  The serving engine's routed
+layer is another one and shares nothing with it: dropless, told which
+share of the router's experts it holds, sigmoid scores with a selecting
+bias, the held experts' rows through a grouped matrix product
+(``serving/arch.py``: ``GatedMoE`` and ``route``;
+``kernels/grouped_matmul.py``; ``docs/serving.md``, "The routed layer").
 """
 
 import jax

@@ -47,7 +47,15 @@ prompt's first piece starts), ``put_state`` writes them back, and
 ``valid`` says which rows are real: a recurrence advances its state over
 those ONLY (bucket padding, a dead slot's rows leave it as it was).
 
-Three architectures are here: ``Gpt2`` (the block of
+It also takes the COUNTS a step reports beside its tokens::
+
+    attend.tally(counts)                  # int32 [len(arch.count_names)]
+
+adds to what the compiled decode chunk and prefill piece return as their
+last output (``arch.count_names`` names the entries; an architecture
+with none never calls it and its programs have no such output).
+
+Four architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -60,7 +68,14 @@ the decoder-hybrid-decoder of arXiv:2507.06607 with differential
 attention (arXiv:2410.05258), five kinds of mixer in a fixed order,
 fewer K/V heads than heads, window and full planes, one plane read by
 several layers, and Mamba state beside the pool;
-``models/sambay_reference.py`` is its plain reference.
+``models/sambay_reference.py`` is its plain reference.  ``GatedMoE`` is
+a stack whose layers differ in BOTH halves: rotary window or
+position-free full attention, gated lane by lane, query heads wider
+than ``d_model / n_head`` over fewer K/V heads; and a dense FFN or a
+ROUTED one, a shared expert beside the share ``(first, count)`` of the
+router's experts that this chip holds, every row routed over all of
+them and none dropped (``route``, ``kernels/grouped_matmul.py``);
+``models/gated_moe_reference.py`` is its plain reference.
 """
 
 import jax
@@ -68,9 +83,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import paged_attention as _paged
+from ..kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 
-__all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY",
-           "STACK_SCOPE"]
+__all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
+           "route", "STACK_SCOPE"]
 
 # the jax.named_scope every architecture's stack runs under (op_name
 # metadata of the lowered program: stack vs. embedding, head and argmax)
@@ -83,19 +99,31 @@ class Architecture:
     ``head`` and ``check_params``."""
 
     name = "architecture"
+    # what the counts a step reports beside its tokens are
+    # (``attend.tally``); none by default
+    count_names = ()
+    # layers whose FFN is routed over experts, and the experts such a
+    # layer holds here (``GatedMoE``); span attributes of the engine
+    moe_layers = 0
+    experts_held = 0
 
-    def __init__(self, n_layer, n_head, d_model, passes=1):
-        if d_model % n_head:
-            raise ValueError(f"d_model {d_model} % n_head {n_head} != 0")
-        if n_layer < 1 or passes < 1:
-            raise ValueError(f"n_layer {n_layer} and passes {passes} "
-                             f"must be >= 1")
+    def __init__(self, n_layer, n_head, d_model, passes=1, head_dim=None):
+        if head_dim is None:
+            # heads that split the model's width between them
+            if d_model % n_head:
+                raise ValueError(
+                    f"d_model {d_model} % n_head {n_head} != 0: heads that "
+                    f"are not d_model / n_head wide are the architecture's "
+                    f"to state (head_dim=)")
+            head_dim = d_model // n_head
+        if n_layer < 1 or passes < 1 or head_dim < 1:
+            raise ValueError(f"n_layer {n_layer}, passes {passes} and "
+                             f"head_dim {head_dim} must be >= 1")
         self.n_layer, self.n_head = int(n_layer), int(n_head)
         self.d_model, self.passes = int(d_model), int(passes)
-
-    @property
-    def head_dim(self):
-        return self.d_model // self.n_head
+        # a fact of the architecture: query heads may be wider (or
+        # narrower) than d_model / n_head
+        self.head_dim = int(head_dim)
 
     @property
     def kv_heads(self):
@@ -158,8 +186,14 @@ class Architecture:
                    for layer in self.state_spec(dtype)
                    for shape, dt in layer)
 
+    def gauges(self, params):
+        """``{name: (value, help)}`` of what the architecture wants set
+        as ``serving.<name>`` gauges beside the engine's own, given the
+        engine's parameters; nothing by default."""
+        return {}
+
     def heads(self, x):
-        """``[..., d] -> [..., n_head, head_dim]``."""
+        """``[..., n_head * head_dim] -> [..., n_head, head_dim]``."""
         return x.reshape(*x.shape[:-1], self.n_head, self.head_dim)
 
     # -- what a subclass answers ------------------------------------------
@@ -244,6 +278,21 @@ def _rms(x, scale, eps):
     return (x32 * jax.lax.rsqrt(ms + eps)).astype(x.dtype) * scale
 
 
+def _rope_angles(pos, head_dim, theta):
+    """``(cos, sin)`` ``[..., 1, head_dim]`` float32 of positions ``pos``
+    for ``_rope``."""
+    inv = 1.0 / (theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    ang = pos.astype(jnp.float32)[..., None] * inv              # [..., dh/2]
+    ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _gated_silu(x, gate, up, down):
+    """The gated SiLU FFN ``(silu(x W_gate) * (x W_up)) W_down``."""
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
 def _rope(x, cos, sin):
     """Rotate-half rotary position: dimension ``i`` pairs with
     ``i + head_dim / 2``.  ``x [..., h, dh]``, ``cos``/``sin``
@@ -305,12 +354,7 @@ class LoopedRmsRope(Architecture):
         return p["tok_emb.w"][toks]
 
     def _angles(self, pos):
-        dh = self.head_dim
-        inv = 1.0 / (self.rope_theta ** (
-            jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
-        ang = pos.astype(jnp.float32)[..., None] * inv          # [..., dh/2]
-        ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]
-        return jnp.cos(ang), jnp.sin(ang)
+        return _rope_angles(pos, self.head_dim, self.rope_theta)
 
     def one_pass(self, p, i_pass, x, rope, planes, attend):
         """The ``n_layer`` blocks and the closing norm, once."""
@@ -610,3 +654,272 @@ class SambaY(Architecture):
         x = _ln(x, p["ln_f.scale"], p["ln_f.bias"], self.eps)
         return jnp.einsum("...d,vd->...v", x, p["tok_emb.w"],
                           preferred_element_type=jnp.float32)
+
+
+def route(h, w_router, bias, top_k, scale):
+    """The routing of a sigmoid top-k router, written once: rows ``h
+    [..., d]``, ``w_router [d, E]``, ``bias [E]`` -> ``(sel [..., top_k]
+    int32, w [..., top_k] float32)``.
+
+    Scores are ``sigmoid(h W_r)`` in float32 (the product accumulates in
+    float32 whatever the rows' dtype); the bias SELECTS only (``top_k``
+    of ``score + bias``) and is not in the weight; the weights are the
+    selected scores over their sum (``+ 1e-20``) times ``scale``,
+    normalised over all ``top_k`` whether or not this chip holds the
+    expert.  No capacity, no group limit, nothing dropped."""
+    s = jax.nn.sigmoid(jnp.matmul(h, w_router,
+                                  preferred_element_type=jnp.float32))
+    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, sel, axis=-1)
+    w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * scale
+    return sel.astype(jnp.int32), w
+
+
+class GatedMoE(Architecture):
+    """Sandwich-normed layers of gated grouped-query attention and a
+    dense or ROUTED gated-SiLU FFN (the ``afmoe`` layout of Arcee's
+    Trinity models; ``models/gated_moe_reference.py`` writes the
+    equations down and lists what the published configuration has no
+    key for).
+
+    ``layer_types[i]`` is ``"window"`` (rotary positions on q and k, a
+    query sees itself and the ``window - 1`` keys before it) or
+    ``"full"`` (NO positional encoding, causal); every layer owns one
+    K/V plane of ``kv_heads`` heads of ``head_dim`` lanes, and query
+    head ``a`` reads K/V head ``a // (n_head // kv_heads)``.  q and k
+    are RMS-normed over a head's lanes (before the rotation), and the
+    joined heads are gated lane by lane, ``ctx * sigmoid(h W_g)``,
+    before the output projection.  The table is scaled by ``sqrt(d)``.
+
+    The first ``dense_layers`` layers have a dense FFN; the others a
+    routed one.  ``experts = (first, count)`` is THIS chip's share of the
+    ``router_width`` experts a routed layer has: every row is routed
+    over all ``router_width`` (``route``: sigmoid scores, a bias that
+    selects only, ``top_k`` of them, weights normalised over the
+    selected and scaled by ``route_scale``), the experts ``first ..
+    first + count - 1`` add their weighted part for the rows that
+    selected them, and the shared expert adds its own for every row.
+    What the experts held elsewhere would add is left out, here and in
+    the reference alike; at ``(0, router_width)`` it is the whole model.
+    No capacity and no dropped token: the rows are gathered by expert
+    and go through ``kernels.grouped_matmul`` (one buffer of ``rows x
+    top_k`` rows whatever the routing: the worst case, every selection
+    held; the kernel reads the matrices of the experts that got a row).
+
+    The stack tallies, a step (``count_names``): the live rows, the
+    row-expert pairs that fell on a held expert, the held experts with
+    at least one live row, and the held experts a step could have
+    touched at most (``count``: the last's denominator), each summed
+    over the routed layers.
+
+    Parameter names: ``tok_emb.w [V, d]``, ``norm_f.scale``,
+    ``lm_head.w [d, V]`` (untied); per layer ``block{i}_norm1.scale``
+    (before attention), ``att_q.w`` and ``att_gate.w [d, n_head *
+    head_dim]``, ``att_k.w`` and ``att_v.w [d, kv_heads * head_dim]``,
+    ``att_qnorm.scale`` and ``att_knorm.scale [head_dim]``, ``att_out.w
+    [n_head * head_dim, d]``, ``norm2.scale`` (after attention),
+    ``norm3.scale`` (before the FFN), ``norm4.scale`` (after it); a
+    dense layer ``ffn_gate.w``, ``ffn_up.w [d, f]``, ``ffn_down.w [f,
+    d]``; a routed layer ``router.w [d, router_width]``, ``router.bias
+    [router_width]``, ``shared_gate.w``, ``shared_up.w [d, e]``,
+    ``shared_down.w [e, d]`` and the held experts stacked,
+    ``experts_gate.w``, ``experts_up.w [count, d, e]``,
+    ``experts_down.w [count, e, d]``.  No biases on the matrices.
+    """
+
+    name = "gated_moe"
+    count_names = ("moe_rows", "moe_assignments_held", "moe_experts_touched",
+                   "moe_expert_visits")
+
+    def __init__(self, layer_types, n_head, kv_heads, head_dim, d_model,
+                 window, dense_layers, router_width, top_k, experts,
+                 route_scale=1.0, eps=1e-5, rope_theta=10000.0):
+        super().__init__(len(layer_types), n_head, d_model,
+                         head_dim=head_dim)
+        bad = sorted(set(layer_types) - {"window", "full"})
+        if bad:
+            raise ValueError(f"{self.name}: layer types {bad}; a layer "
+                             f"is 'window' or 'full'")
+        if n_head % kv_heads:
+            raise ValueError(f"{self.name}: kv_heads {kv_heads} must "
+                             f"divide n_head {n_head}")
+        if self.head_dim % 2:
+            raise ValueError(f"rotary positions need an even head_dim, "
+                             f"got {self.head_dim}")
+        first, count = (int(v) for v in experts)
+        if not (0 <= first and 1 <= count
+                and first + count <= router_width):
+            raise ValueError(
+                f"{self.name}: the share ({first}, {count}) is not "
+                f"inside a router of {router_width} experts")
+        if not 1 <= top_k <= count:
+            # the buffer of gathered rows is rows x top_k: a row's
+            # selections are distinct experts, so no more of them than
+            # top_k (and than count) can be held
+            raise ValueError(f"{self.name}: top_k {top_k} must lie in "
+                             f"[1, experts held {count}]")
+        if not 0 <= dense_layers <= self.n_layer:
+            raise ValueError(f"{self.name}: dense_layers {dense_layers} "
+                             f"of {self.n_layer} layers")
+        self.layer_types = tuple(layer_types)
+        self._kv_heads, self.window = int(kv_heads), int(window)
+        self.dense_layers = int(dense_layers)
+        self.router_width, self.top_k = int(router_width), int(top_k)
+        self.experts = (first, count)
+        self.route_scale = float(route_scale)
+        self.eps, self.rope_theta = eps, float(rope_theta)
+
+    @property
+    def kv_heads(self):
+        return self._kv_heads
+
+    @property
+    def planes(self):
+        return tuple(self.window if kind == "window" else None
+                     for kind in self.layer_types)
+
+    @property
+    def rows_per_entry(self):
+        return self.n_head // self.kv_heads
+
+    @property
+    def moe_layers(self):
+        return self.n_layer - self.dense_layers
+
+    @property
+    def experts_held(self):
+        return self.experts[1] if self.moe_layers else 0
+
+    def gauges(self, params):
+        if not self.moe_layers:
+            return {}
+        last = self.n_layer - 1
+        one_expert = sum(
+            int(np.prod(np.shape(params[f"block{last}_experts_{m}.w"])[1:]))
+            * params[f"block{last}_experts_{m}.w"].dtype.itemsize
+            for m in ("gate", "up", "down"))
+        return {
+            "moe_layers": (self.moe_layers, "layers whose FFN is routed"),
+            "moe_experts_held": (
+                self.experts_held, "routed experts a routed layer holds "
+                "HERE (the chip's share of the router's)"),
+            "moe_router_width": (
+                self.router_width, "experts a row is routed over (held "
+                "here or not)"),
+            "moe_top_k": (self.top_k, "experts a row selects"),
+            "moe_expert_bytes": (
+                one_expert, "bytes of ONE routed expert's three matrices"),
+        }
+
+    def pool_block_shape(self, block_tokens, dtype):
+        return (block_tokens, _paged.pool_rows(self.kv_heads, dtype),
+                self.head_dim)
+
+    def check_params(self, params, max_len):
+        last = self.n_layer - 1
+        need = ["tok_emb.w", "norm_f.scale", "lm_head.w",
+                f"block{last}_att_gate.w", f"block{last}_att_qnorm.scale",
+                f"block{last}_norm4.scale"]
+        if self.dense_layers:
+            need.append("block0_ffn_down.w")
+        if self.moe_layers:
+            need += [f"block{last}_router.w", f"block{last}_router.bias",
+                     f"block{last}_shared_down.w",
+                     f"block{last}_experts_down.w"]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+        if self.moe_layers:
+            held = np.shape(params[f"block{last}_experts_down.w"])[0]
+            width = np.shape(params[f"block{last}_router.w"])[1]
+            if (held, width) != (self.experts[1], self.router_width):
+                raise ValueError(
+                    f"{self.name}: parameters hold {held} experts under a "
+                    f"router of {width}; the architecture says "
+                    f"{self.experts[1]} of {self.router_width}")
+
+    def embed(self, p, toks, pos):
+        table = p["tok_emb.w"]
+        return table[toks] * jnp.asarray(self.d_model ** 0.5, table.dtype)
+
+    def _attention(self, w, i, x, rope, planes, attend):
+        f32 = jnp.float32
+        kind = self.layer_types[i]
+        a = _rms(x, w("norm1.scale"), self.eps)
+        lead = a.shape[:-1]
+        kv = (*lead, self.kv_heads, self.head_dim)
+        q = _rms(self.heads(a @ w("att_q.w")), w("att_qnorm.scale"),
+                 self.eps)
+        k = _rms((a @ w("att_k.w")).reshape(kv), w("att_knorm.scale"),
+                 self.eps)
+        v = (a @ w("att_v.w")).reshape(kv)
+        if kind == "window":
+            q, k = _rope(q, *rope), _rope(k, *rope)
+        ctx, planes = attend(
+            planes, i, 0, q, k, v, group=self.rows_per_entry,
+            window=self.window if kind == "window" else None)
+        gate = jax.nn.sigmoid((a @ w("att_gate.w")).astype(f32))
+        o = (ctx.reshape(*lead, -1).astype(f32) * gate).astype(x.dtype)
+        return _rms(o @ w("att_out.w"), w("norm2.scale"), self.eps), planes
+
+    def _routed(self, w, h, attend):
+        """The routed FFN over rows ``h [..., d]``: ``(y, counts)``."""
+        f32, i32 = jnp.float32, jnp.int32
+        first, count = self.experts
+        k, d = self.top_k, h.shape[-1]
+        rows = h.reshape(-1, d)
+        valid = attend.valid.reshape(-1)
+        with jax.named_scope("serving.moe_route"):
+            sel, weight = route(rows, w("router.w"), w("router.bias"), k,
+                                self.route_scale)
+            # a pair (row, selection) is HELD where the selected expert
+            # is one of this chip's and the row is real: a dead slot's
+            # row, a window's padding touch no expert
+            held = ((sel >= first) & (sel < first + count)
+                    & valid[:, None])
+            expert = jnp.where(held, sel - first, count).reshape(-1)
+            # the held pairs first, those of one expert together
+            order = jnp.argsort(expert, stable=True)
+            sizes = jnp.sum(expert[:, None] == jnp.arange(count, dtype=i32),
+                            axis=0, dtype=i32)
+            gathered = rows[order // k]
+        with jax.named_scope("serving.moe_experts"):
+            act = (jax.nn.silu(_grouped_matmul(gathered, w("experts_gate.w"),
+                                               sizes))
+                   * _grouped_matmul(gathered, w("experts_up.w"), sizes))
+            out = _grouped_matmul(act, w("experts_down.w"), sizes)
+            # back to (row, selection) order, weighted; a pair that is
+            # not held has a zero row of the product and a zero weight
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=order.dtype))
+            y = jnp.sum(out[back].reshape(-1, k, d).astype(f32)
+                        * jnp.where(held, weight, 0.0)[..., None], axis=1)
+        with jax.named_scope("serving.moe_shared"):
+            shared = _gated_silu(h, w("shared_gate.w"), w("shared_up.w"),
+                                 w("shared_down.w"))
+        counts = jnp.stack([jnp.sum(valid, dtype=i32),
+                            jnp.sum(held, dtype=i32),
+                            jnp.sum(sizes > 0, dtype=i32),
+                            jnp.asarray(count, i32)])
+        return shared + y.astype(h.dtype).reshape(h.shape), counts
+
+    def stack(self, p, x, pos, planes, attend):
+        rope = _rope_angles(pos, self.head_dim, self.rope_theta)
+        for i in range(self.n_layer):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            a, planes = self._attention(w, i, x, rope, planes, attend)
+            x = x + a
+            m = _rms(x, w("norm3.scale"), self.eps)
+            if i < self.dense_layers:
+                ff = _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
+                                 w("ffn_down.w"))
+            else:
+                ff, counts = self._routed(w, m, attend)
+                attend.tally(counts)
+            x = x + _rms(ff, w("norm4.scale"), self.eps)
+        return x, planes
+
+    def head(self, p, x):
+        return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
+                          p["lm_head.w"], preferred_element_type=jnp.float32)

@@ -133,12 +133,17 @@ class _Cache:
     to) selects the state row, and a window that starts a prompt (``pos
     == 0``) starts from zero state whatever the slot's last request
     left; without ``slot`` the state arrays' rows ARE the call's slots.
-    The state side traces nothing unless an architecture calls it."""
+    The state side traces nothing unless an architecture calls it.
+
+    ``tally(counts)`` adds an int32 vector (``arch.count_names`` says
+    what its entries are) to ``counts``, which the entry points return
+    beside their tokens; ``()`` for a stack that tallies nothing."""
 
     def __init__(self, arch, table, blk, off, pos, writable=None,
                  slot=None):
         self.arch, self.table, self.blk, self.off = arch, table, blk, off
         self.pos, self.writable, self.slot = pos, writable, slot
+        self.counts = ()
         self.step = pos.ndim == 1
         pos4 = pos[:, None] if self.step else pos
         self.pos4 = jnp.where((table[:, 0] == 0)[:, None], -1, pos4)
@@ -179,6 +184,10 @@ class _Cache:
         return ctx, (pool_k[:plane] + (pk,) + pool_k[plane + 1:],
                      pool_v[:plane] + (pv,) + pool_v[plane + 1:]) + planes[2:]
 
+    def tally(self, counts):
+        self.counts = (counts if isinstance(self.counts, tuple)
+                       else self.counts + counts)
+
     def state(self, planes, i):
         rows = planes[2][i]
         if self.slot is not None:
@@ -212,7 +221,7 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     already-consumed position), attends the chain masked ``<= t_s``,
     advances the per-slot ``state`` of the live slots (``table[:, 0] !=
     0``) and returns ``(logits [S, vocab] f32, pool_k', pool_v',
-    state')``.
+    state', counts)``, ``counts`` what the stack tallied (``_Cache``).
     """
     S = tok.shape[0]
     B = pool_k[0].shape[1]
@@ -220,11 +229,11 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     tw = jnp.clip(t, 0, T - 1)
     blk = table[jnp.arange(S), tw // B]      # [S] physical write block
     x = arch.embed(p, tok, tw)                               # [S, d]
+    cache = _Cache(arch, table, blk, tw % B, t)
     with jax.named_scope(STACK_SCOPE):
         x, (pool_k, pool_v, state) = arch.stack(
-            p, x, tw, (pool_k, pool_v, state),
-            _Cache(arch, table, blk, tw % B, t))
-    return arch.head(p, x), pool_k, pool_v, state
+            p, x, tw, (pool_k, pool_v, state), cache)
+    return arch.head(p, x), pool_k, pool_v, state, cache.counts
 
 
 def make_decode_chunk(arch, chunk, donate=True):
@@ -232,9 +241,12 @@ def make_decode_chunk(arch, chunk, donate=True):
     every slot in one device call, for ``arch`` (an ``Architecture``).
 
     ``fn(params, pool_k, pool_v, last_tok, pos, table, state=()) ->
-    (pool_k', pool_v', last_tok', pos', toks [chunk, S] int32, state')``
-    — ``toks[j]`` is the token each slot emitted at its ``pos+j``'th
-    position.  The pool, the slot scalars and the per-slot ``state``
+    (pool_k', pool_v', last_tok', pos', toks [chunk, S] int32, state',
+    counts)`` — ``toks[j]`` is the token each slot emitted at its
+    ``pos+j``'th position; ``counts`` is what the stack tallied, summed
+    over the chunk's steps (int32 ``[len(arch.count_names)]``; ``()`` and
+    no output of the lowered program for an architecture that tallies
+    nothing).  The pool, the slot scalars and the per-slot ``state``
     (``arch.state_spec``; ``()`` and no argument of the lowered program
     for an architecture that holds none) are donated (updated in place
     on TPU); the table is a small host-fed int32 array (data, not
@@ -244,15 +256,16 @@ def make_decode_chunk(arch, chunk, donate=True):
     def decode_chunk(p, pool_k, pool_v, last_tok, pos, table, state=()):
         def body(carry, _):
             pk, pv, st, tok, t = carry
-            logits, pk, pv, st = paged_step_logits(p, tok, t, pk, pv,
-                                                   table, arch, st)
+            logits, pk, pv, st, counts = paged_step_logits(
+                p, tok, t, pk, pv, table, arch, st)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (pk, pv, st, nxt, t + 1), nxt
+            return (pk, pv, st, nxt, t + 1), (nxt, counts)
 
-        (pk, pv, state, tok, t), toks = jax.lax.scan(
+        (pk, pv, state, tok, t), (toks, counts) = jax.lax.scan(
             body, (pool_k, pool_v, state, last_tok, pos), None,
             length=chunk)
-        return pk, pv, tok, t, toks, state
+        counts = jax.tree.map(lambda c: jnp.sum(c, axis=0), counts)
+        return pk, pv, tok, t, toks, state, counts
 
     return jax.jit(decode_chunk, donate_argnums=_donated(
         arch, donate, (1, 2, 3, 4), 6))
@@ -289,9 +302,10 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch,
     its state row is read and written back, starting from zeros where
     the window starts a prompt (``pos == 0``).
 
-    Returns ``(x [S, W, d], pool_k', pool_v', state')`` — the stack's
-    output, what ``arch.head`` consumes: the callers differ in which
-    rows they put through the head.
+    Returns ``(x [S, W, d], pool_k', pool_v', state', counts)`` — the
+    stack's output, what ``arch.head`` consumes (the callers differ in
+    which rows they put through the head), and what the stack tallied
+    over the rows up to ``limit``.
     """
     S, W = toks.shape
     B = pool_k[0].shape[1]
@@ -301,11 +315,11 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch,
     writable = P <= limit[:, None]
     blk = jnp.where(writable, table[jnp.arange(S)[:, None], Pw // B], 0)
     x = arch.embed(p, toks, Pw)                              # [S, W, d]
+    cache = _Cache(arch, table, blk, Pw % B, P, writable, slot)
     with jax.named_scope(STACK_SCOPE):
         x, (pool_k, pool_v, state) = arch.stack(
-            p, x, Pw, (pool_k, pool_v, state),
-            _Cache(arch, table, blk, Pw % B, P, writable, slot))
-    return x, pool_k, pool_v, state
+            p, x, Pw, (pool_k, pool_v, state), cache)
+    return x, pool_k, pool_v, state, cache.counts
 
 
 def _copy_block(planes, src, dst, passes):
@@ -346,7 +360,7 @@ def make_verify_window(arch, k, donate=True):
         if toks.shape[1] != k + 1:
             raise ValueError(f"verify window built for k={k} got "
                              f"{toks.shape[1]} tokens a slot")
-        x, pool_k, pool_v, _ = _window_forward(
+        x, pool_k, pool_v, _, _ = _window_forward(
             p, pool_k, pool_v, toks, pos, limit, table, arch)
         greedy = jnp.argmax(arch.head(p, x), axis=-1).astype(jnp.int32)
         return pool_k, pool_v, greedy
@@ -360,8 +374,8 @@ def make_prefill(arch, bucket, donate=True):
 
     ``fn(params, pool_k, pool_v, last_tok, pos, slot, table_row [NB],
     toks [bucket], start, length, cow_src, cow_dst, state=()) ->
-    (pool_k', pool_v', last_tok', pos', first_tok, state')`` — first
-    copies block
+    (pool_k', pool_v', last_tok', pos', first_tok, state', counts)`` —
+    first copies block
     ``cow_src`` onto ``cow_dst`` whole (the copy-on-write fork; the
     no-fork spelling passes ``0, 0``, trash onto trash), then runs ONE
     window forward (``_window_forward`` at ``S = 1``) over the padded
@@ -395,14 +409,14 @@ def make_prefill(arch, bucket, donate=True):
         pool_k = _copy_block(pool_k, cow_src, cow_dst, arch.passes)
         pool_v = _copy_block(pool_v, cow_src, cow_dst, arch.passes)
         end = start + length
-        x, pool_k, pool_v, state = _window_forward(
+        x, pool_k, pool_v, state, counts = _window_forward(
             p, pool_k, pool_v, toks[None], start[None], (end - 1)[None],
             table_row[None], arch, state, slot)
         row = jax.lax.dynamic_slice_in_dim(x[0], length - 1, 1)  # [1, d]
         first = jnp.argmax(arch.head(p, row)[0]).astype(jnp.int32)
         last_tok = last_tok.at[slot].set(first)
         pos = pos.at[slot].set(end)
-        return pool_k, pool_v, last_tok, pos, first, state
+        return pool_k, pool_v, last_tok, pos, first, state, counts
 
     return jax.jit(prefill, donate_argnums=_donated(
         arch, donate, (1, 2, 3, 4), 12))

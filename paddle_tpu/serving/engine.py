@@ -334,8 +334,8 @@ class ServingEngine:
         # for the draft's scratch blocks, so a propose round can never
         # starve admission.  A block id names the same block_tokens
         # positions in every one of the arch.kv_planes planes, so one
-        # block costs kv_planes * 2 * block_tokens * d_model * itemsize
-        # bytes (48 MiB for 192 planes of 32 x 2048 bf16, 6 MiB for 24):
+        # block costs kv_planes * 2 * block_tokens * kv_heads * head_dim
+        # * itemsize bytes (48 MiB for 192 planes of 32 x 2048 bf16, 6 MiB for 24):
         # the pool's bytes, not its block count, are what fills a chip
         # (gauge serving.kv_pool_bytes)
         num_blocks = (1 + self.max_slots * self.blocks_per_slot
@@ -457,6 +457,10 @@ class ServingEngine:
             help="bytes the paged pool holds on the device: planes x "
                  "blocks (trash included) x block bytes",
         ).set(sum(a.nbytes for a in self._pk + self._pv))
+        # what the architecture itself wants shown (a routed FFN's share
+        # of the experts: arch.GatedMoE); nothing for most
+        for name, (value, text) in arch.gauges(self._p).items():
+            self._reg.gauge("serving." + name, help=text).set(value)
         # (window, calls, bytes a cached position) of the paged calls a
         # token makes, for _count_paged_entries
         self._plane_reads = [(w, n, arch.kv_block_bytes(1, itemsize))
@@ -561,6 +565,21 @@ class ServingEngine:
                  "step have to read for the live slots (clipped to each "
                  "plane's window, a shared plane once a reader), at "
                  "every decode chunk's first step").inc(streamed)
+
+    def _count_tallies(self, phase, counts):
+        """What the compiled steps of one decode chunk or one
+        admission's prefill pieces tallied beside their tokens
+        (``arch.count_names``; a list of device vectors), fed to the
+        counters ``serving.<name>{phase}``."""
+        total = np.sum([np.asarray(c) for c in counts], axis=0)
+        for name, value in zip(self.arch.count_names, total):
+            self._reg.counter(
+                "serving." + name, phase=phase,
+                help="what the architecture's stack tallied a step, "
+                     "summed over steps (arch.count_names; arch.GatedMoE: "
+                     "live rows, row-expert pairs on a held expert, held "
+                     "experts with a live row, held experts visited, each "
+                     "x routed layers)").inc(int(value))
 
     # -- request intake ---------------------------------------------------
     def submit(self, prompt, max_new_tokens=16, eos_id=None,
@@ -892,13 +911,14 @@ class ServingEngine:
         return out
 
     def _run_pieces(self, fn_of, params, pk, pv, slot, row, pieces,
-                    cow=(0, 0), compile_only=False):
+                    cow=(0, 0), compile_only=False, tally=None):
         """Dispatch ``pieces`` in order through the prefill executables
         ``fn_of(width)``, each attending what the earlier ones wrote;
         the CoW fork rides in the first.  Nothing is fetched: returns
         ``(pool_k', pool_v', first_tok)`` of the LAST piece, still on
         the device.  ``compile_only`` builds what is not compiled yet
-        and runs nothing."""
+        and runs nothing.  ``tally`` (a list) receives what each piece's
+        stack counted, on the device too."""
         first = None
         for i, (w, toks, at, n) in enumerate(pieces):
             src, dst = cow if i == 0 else (0, 0)
@@ -909,7 +929,9 @@ class ServingEngine:
                 fn_of(w).prepare(*args)
             else:
                 (pk, pv, self._last, self._pos, first,
-                 self._state) = fn_of(w)(*args)
+                 self._state, counts) = fn_of(w)(*args)
+                if tally is not None:
+                    tally.append(counts)
         return pk, pv, first
 
     def _prefill_fn(self, bucket):
@@ -985,13 +1007,17 @@ class ServingEngine:
                         active=self.active_slots,
                         passes=self.arch.passes,
                         state_layers=len(self._state),
-                        plane_reads=self._reads_per_token) as sp:
+                        plane_reads=self._reads_per_token,
+                        moe_layers=self.arch.moe_layers,
+                        experts_held=self.arch.experts_held) as sp:
             (self._pk, self._pv, self._last, self._pos, toks,
-             self._state) = self._decode_fn(
+             self._state, counts) = self._decode_fn(
                  self._p, self._pk, self._pv, self._last, self._pos, tbl,
                  self._state)
             with self._span("serving.fetch", "fetch", of="decode"):
                 toks = np.asarray(toks)  # host sync: [chunk, S]
+                if self.arch.count_names:
+                    self._count_tallies("decode", [counts])
         t0, t1 = sp.t0, sp.t1
         wall = t1 - t0
         self._reg.histogram("serving.step_seconds").observe(
@@ -1348,12 +1374,17 @@ class ServingEngine:
                         bucket=bucket, pieces=len(pieces), slot=slot,
                         prefix_hit=start, passes=self.arch.passes,
                         state_layers=len(self._state),
-                        plane_reads=self._reads_per_token) as sp:
+                        plane_reads=self._reads_per_token,
+                        moe_layers=self.arch.moe_layers,
+                        experts_held=self.arch.experts_held) as sp:
+            tally = []
             self._pk, self._pv, first = self._run_pieces(
                 self._prefill_fn, self._p, self._pk, self._pv, slot,
-                row_d, pieces, cow=(cow_src, cow_dst))
+                row_d, pieces, cow=(cow_src, cow_dst), tally=tally)
             with self._span("serving.fetch", "fetch", of="prefill"):
                 first = int(np.asarray(first))  # host sync
+                if self.arch.count_names:
+                    self._count_tallies("prefill", tally)
         t_p0, now = sp.t0, sp.t1
         # the CoW source was held only for the copy window
         if cow is not None:

@@ -136,9 +136,9 @@ def validate_draft(params, draft_params, arch, max_len,
     dnh = n_head if draft_n_head is None else int(draft_n_head)
     if dnh != n_head:
         raise ValueError(
-            f"speculative draft d_head {d_model // dnh} (n_head {dnh}) "
-            f"!= target d_head {d_model // n_head} (n_head {n_head}): "
-            f"the shared pool block shape is [B, n_head, d_head]")
+            f"speculative draft n_head {dnh} != target n_head {n_head} "
+            f"(heads of {arch.head_dim} lanes, the architecture's own): "
+            f"the shared pool block shape is [B, n_head, head_dim]")
     depth = draft_depth(draft_params)
     dnl = depth if draft_n_layer is None else int(draft_n_layer)
     if not 1 <= dnl <= depth:
@@ -331,7 +331,7 @@ class SpecState:
         fn = self.chunk_fn(engine)
         nl = self.n_layer
         (pk, pv, engine._last, engine._pos, toks,
-         _) = fn(self.p, engine._pk[:nl], engine._pv[:nl],
+         _, _) = fn(self.p, engine._pk[:nl], engine._pv[:nl],
                  jnp.asarray(last_h), jnp.asarray(pos_h),
                  jnp.asarray(self.table))
         engine._pk = tuple(pk) + engine._pk[nl:]

@@ -1,0 +1,543 @@
+"""``serving.arch.GatedMoE`` against its plain reference
+(``models/gated_moe_reference.py``) at a small size: gated
+grouped-query attention on window and full planes with heads WIDER than
+``d_model / n_head``, a dense FFN and routed ones over a share of the
+router's experts.  Float32 through the cache has to agree with the
+reference's full forward at every generated position; bfloat16 is judged
+as the benchmark judges it."""
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from paddle_tpu.models import gated_moe_reference as ref  # noqa: E402
+from paddle_tpu.observability import trace  # noqa: E402
+from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
+from paddle_tpu.serving import ServingEngine  # noqa: E402
+from paddle_tpu.serving import arch as arch_mod  # noqa: E402
+from paddle_tpu.serving import batched_decode as _bd  # noqa: E402
+from paddle_tpu.serving.arch import GatedMoE  # noqa: E402
+
+# heads of 32 where d / heads is 16; 16 experts, top 4, 4 held (4..7)
+TINY = {"d": 64, "heads": 4, "kv_heads": 2, "dh": 32, "f": 128, "e": 48,
+        "experts": 16, "top_k": 4, "share": (4, 4), "window": 8,
+        "types": ("window", "window", "window", "window", "full"),
+        "dense": 1, "rows": 128, "scale": 2.448}
+T, B, PIECE = 48, 4, 8
+TOL = 2e-4
+# bfloat16 engine against the float32 reference on the same bfloat16
+# weights, judged by the margin of each generated token under the
+# reference's maximum: five layers of width 64 round to some 0.05 logits
+# of deviation 1.4; 0.25 is five times the worst seen (0.047)
+BF16_MARGIN = 0.25
+
+def _init(key, z, dtype, experts=None):
+    """Seeded weights under ``GatedMoE``'s names: matrices at 0.2 (a
+    width of 64 then gives activations of order one), the router at 0.3
+    so that its scores spread without saturating, gains near one before a sub-layer and
+    ``1 / sqrt(2 layers)`` after it; ``experts`` stacked per layer."""
+    n = len(z["types"])
+    experts = z["experts"] if experts is None else experts
+    keys = iter(jax.random.split(key, 24 * n + 4))
+    d, dh, e = z["d"], z["dh"], z["e"]
+    q, kv = z["heads"] * dh, z["kv_heads"] * dh
+
+    def normal(*shape, scale=0.2):
+        return (scale * jax.random.normal(next(keys), shape)).astype(dtype)
+
+    branch = (2 * n) ** -0.5
+    p = {"tok_emb.w": normal(z["rows"], d, scale=0.1),
+         "norm_f.scale": 1 + normal(d), "lm_head.w": normal(d, z["rows"])}
+    for i in range(n):
+        b = f"block{i}_"
+        p.update({
+            b + "norm1.scale": 1 + normal(d), b + "norm3.scale": 1 + normal(d),
+            b + "norm2.scale": branch * (1 + normal(d)),
+            b + "norm4.scale": branch * (1 + normal(d)),
+            b + "att_q.w": normal(d, q), b + "att_gate.w": normal(d, q),
+            b + "att_k.w": normal(d, kv), b + "att_v.w": normal(d, kv),
+            b + "att_out.w": normal(q, d),
+            b + "att_qnorm.scale": 1 + normal(dh),
+            b + "att_knorm.scale": 1 + normal(dh)})
+        if i < z["dense"]:
+            p.update({b + "ffn_gate.w": normal(d, z["f"]),
+                      b + "ffn_up.w": normal(d, z["f"]),
+                      b + "ffn_down.w": normal(z["f"], d)})
+        else:
+            p.update({
+                b + "router.w": normal(d, z["experts"], scale=0.3),
+                b + "router.bias": normal(z["experts"], scale=0.05),
+                b + "shared_gate.w": normal(d, e),
+                b + "shared_up.w": normal(d, e),
+                b + "shared_down.w": normal(e, d),
+                b + "experts_gate.w": normal(experts, d, e),
+                b + "experts_up.w": normal(experts, d, e),
+                b + "experts_down.w": normal(experts, e, d)})
+    return p
+
+
+def _share(p, first, count):
+    """The parameters a chip holding experts ``first .. first + count -
+    1`` has: the stacked experts sliced, everything else whole."""
+    return {k: (v[first:first + count] if "_experts_" in k else v)
+            for k, v in p.items()}
+
+
+@pytest.fixture(scope="module")
+def uncut():
+    """All 16 experts, float32."""
+    return _init(jax.random.PRNGKey(34), TINY, jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def params(uncut):
+    held = _share(uncut, *TINY["share"])
+    return {"float32": held,
+            "bfloat16": {k: v.astype(jnp.bfloat16) for k, v in held.items()}}
+
+
+def _arch(share=TINY["share"], z=TINY):
+    return GatedMoE(z["types"], z["heads"], z["kv_heads"], z["dh"], z["d"],
+                    window=z["window"], dense_layers=z["dense"],
+                    router_width=z["experts"], top_k=z["top_k"],
+                    experts=share, route_scale=z["scale"])
+
+
+def _engine(p, monkeypatch, **kw):
+    monkeypatch.setattr(_bd, "PREFILL_PIECE", PIECE)
+    reg = MetricsRegistry()
+    kw.setdefault("max_slots", 2)
+    kw.setdefault("prefix_reuse", False)
+    eng = ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
+                        decode_chunk=4, min_bucket=4, donate=False,
+                        registry=reg, **kw)
+    return eng, reg
+
+
+def _reference(p, tokens, share=TINY["share"], **switches):
+    z = TINY
+    return np.asarray(ref.forward(
+        p, np.asarray(tokens)[None], z["types"], z["heads"], z["kv_heads"],
+        z["window"], z["dense"], z["top_k"], share, z["scale"],
+        **switches))[0]
+
+
+def _through_the_cache(eng, prompts, n_new):
+    """Each prompt into a slot of its own, prefilled in the pieces the
+    engine would dispatch (bucket padding and all), then ``n_new``
+    greedy decode steps for ALL slots at once.  Returns per slot
+    (tokens, logits at every position from the prompt's last on) and
+    the counts every call tallied."""
+    arch = eng.arch
+    S, nb = len(prompts), T // B
+    table = jnp.asarray(1 + np.arange(S * nb).reshape(S, nb), jnp.int32)
+
+    @jax.jit
+    def window(p, pk, pv, toks, at, n, row):
+        x, pk, pv, _, counts = _bd._window_forward(
+            p, pk, pv, toks[None], at[None], (at + n - 1)[None], row[None],
+            arch)
+        return arch.head(p, x[0])[n - 1], pk, pv, counts
+
+    @jax.jit
+    def step(p, pk, pv, tok, at):
+        lg, pk, pv, _, counts = _bd.paged_step_logits(p, tok, at, pk, pv,
+                                                      table, arch)
+        return lg, pk, pv, counts
+
+    pk, pv = eng._pk, eng._pv
+    logits, tallied = [[] for _ in prompts], []
+    for s, prompt in enumerate(prompts):
+        pieces = eng._pieces(np.asarray(prompt), 0)
+        assert len(pieces) >= 2 and pieces[-1][0] > pieces[-1][3]
+        for _w, padded, at, n in pieces:
+            lg, pk, pv, counts = window(eng._p, pk, pv, padded,
+                                        jnp.int32(at), jnp.int32(n), table[s])
+            tallied.append(("prefill", n, np.asarray(counts)))
+        logits[s].append(lg)
+    toks = [list(p_) for p_ in prompts]
+    for _ in range(n_new):
+        last = jnp.asarray([int(jnp.argmax(l[-1])) for l in logits],
+                           jnp.int32)
+        at = jnp.asarray([len(t_) for t_ in toks], jnp.int32)
+        for s in range(S):
+            toks[s].append(int(last[s]))
+        lg, pk, pv, counts = step(eng._p, pk, pv, last, at)
+        tallied.append(("decode", S, np.asarray(counts)))
+        for s in range(S):
+            logits[s].append(lg[s])
+    return ([(np.asarray(t_), np.asarray(jnp.stack(l), np.float32))
+             for t_, l in zip(toks, logits)], tallied)
+
+
+PROMPTS = [np.arange(3, 3 + 21) % 128, (7 * np.arange(11) + 5) % 128]
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    """The float32 and bfloat16 engines' logits through the cache, made
+    once: two slots, prompts of 21 and 11 tokens (pieces 8 + 8 + 8 with
+    3 rows of padding: the dense spelling of ``attend``; and 8 + 4 with
+    1: a piece narrower than ``DENSE_WINDOW``, streamed), 14 decode
+    steps, so that both contexts pass the window of 8 and a block
+    boundary."""
+    mp = pytest.MonkeyPatch()
+    try:
+        out = {}
+        for dt in ("float32", "bfloat16"):
+            eng, _ = _engine(params[dt], mp)
+            out[dt] = _through_the_cache(eng, PROMPTS, 14)
+        return out
+    finally:
+        mp.undo()
+
+
+def _positions(prompt_len, lg):
+    return slice(prompt_len - 1, prompt_len - 1 + len(lg))
+
+
+def test_float32_through_the_cache_agrees_with_the_reference(served, params):
+    """Prefill pieces wider and narrower than ``DENSE_WINDOW``, decode
+    steps, contexts that pass the window: logits at every position."""
+    for (toks, lg), prompt in zip(served["float32"][0], PROMPTS):
+        assert len(toks) > TINY["window"] + len(prompt) // 2
+        want = _reference(params["float32"], toks)[_positions(len(prompt), lg)]
+        assert np.abs(lg - want).max() < TOL
+
+
+def _reference_counts(p, served_dtype):
+    """What each call of ``_through_the_cache`` should have tallied, from
+    the float32 reference's own selections at the same positions: ``[(n
+    rows x layers, pairs on a held expert, held experts touched, held
+    experts x layers)]`` in the calls' order."""
+    first, count = TINY["share"]
+    layers = len(TINY["types"]) - TINY["dense"]
+    runs, tallied = served_dtype
+    sels = []
+    for toks, _ in runs:
+        seen = []
+        _reference(p, toks, seen=seen)
+        sels.append(np.stack([np.asarray(s)[0] for s in seen]))  # [L, t, k]
+
+    def tally(sel, n):                                           # [L, n, k]
+        held = (sel >= first) & (sel < first + count)
+        return [n * layers, int(held.sum()),
+                sum(len(np.unique(sel[l][held[l]])) for l in range(layers)),
+                count * layers]
+
+    out, calls = [], iter(tallied)
+    for s, prompt in enumerate(PROMPTS):        # the prefill pieces
+        at = 0
+        while at < len(prompt):
+            phase, n, _ = next(calls)
+            assert phase == "prefill"
+            out.append(tally(sels[s][:, at:at + n], n))
+            at += n
+    for j, (phase, n, _) in enumerate(calls):   # the decode steps
+        assert phase == "decode"
+        out.append(tally(np.stack(
+            [sels[s][:, len(PROMPTS[s]) + j] for s in range(len(PROMPTS))],
+            axis=1), n))
+    return out
+
+
+def test_bfloat16_through_the_cache_stays_within_the_margin(served, params):
+    """Judged as the benchmark's check judges it: each generated token
+    within the margin of the reference's maximum.  A near-tie at the
+    fourth score can flip a selection under rounding, and a row that
+    gained or lost a held expert computed a different function (an error
+    of 0.3 to 1.8 logits here, against 0.1 elsewhere).  Such a step shows
+    in its own tally: where a decode step's count of pairs on a held
+    expert differs from the count of the reference's selections at the
+    same positions, the step's tokens are left out, and counted."""
+    p = params["bfloat16"]
+    runs, tallied = served["bfloat16"]
+    want_counts = _reference_counts(p, served["bfloat16"])
+    steps = [got[2][1] == want[1] for got, want in zip(tallied, want_counts)
+             if got[0] == "decode"]
+    compared = left_out = 0
+    for (toks, lg), prompt in zip(runs, PROMPTS):
+        want = _reference(p, toks)[_positions(len(prompt), lg)]
+        gen = toks[len(prompt):]
+        gap = want[:len(gen)].max(-1) - want[np.arange(len(gen)), gen]
+        # token j + 1 was chosen from the logits of decode step j
+        keep = np.array([True] + steps[:len(gen) - 1])
+        compared += int(keep.sum())
+        left_out += int((~keep).sum())
+        assert gap[keep].max() < BF16_MARGIN, gap[keep].max()
+    assert compared >= 20 and left_out <= 8, (compared, left_out)
+
+
+OMISSIONS = {
+    "routed_part_left_out": dict(routed=False),
+    "route_norm_and_scale_left_out": dict(route_norm=False),
+    "attention_gate_left_out": dict(attention_gate=False),
+    "rotary_moved_to_the_full_layer": dict(rotary_on="full"),
+    "window_bound_left_out": dict(windowed=False),
+}
+
+
+@pytest.mark.parametrize("omission", list(OMISSIONS))
+def test_each_line_left_out_fails_the_float32_comparison(served, params,
+                                                         omission):
+    worst = 0.0
+    for (toks, lg), prompt in zip(served["float32"][0], PROMPTS):
+        want = _reference(params["float32"], toks, **OMISSIONS[omission])
+        worst = max(worst, float(np.abs(
+            lg - want[_positions(len(prompt), lg)]).max()))
+    assert worst > 200 * TOL, worst
+
+
+def test_the_counts_a_step_returns_equal_a_numpy_count(served, params):
+    """Every call's tally (live rows, pairs on a held expert, held
+    experts touched, held experts visited; summed over the routed
+    layers) against a count of the reference's own selections at the
+    same positions."""
+    want = _reference_counts(params["float32"], served["float32"])
+    got = [list(counts) for _, _, counts in served["float32"][1]]
+    assert got == want
+
+
+class _Rows:
+    """The cache interface's ``valid`` for a routed layer called on its
+    own."""
+
+    def __init__(self, valid):
+        self.valid = valid
+
+
+def _routed_alone(p, i, x, share, valid=None):
+    """``GatedMoE._routed`` of layer ``i`` on rows ``x [n, d]`` for the
+    share ``share`` of the uncut parameters ``p``."""
+    arch = _arch(share)
+    held = _share(p, *share)
+    rows = _Rows(jnp.ones(x.shape[:-1], bool) if valid is None else valid)
+    h = arch_mod._rms(x, held[f"block{i}_norm3.scale"], arch.eps)
+    y, counts = arch._routed(lambda nm: held[f"block{i}_{nm}"], h, rows)
+    return np.asarray(y), np.asarray(counts)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(uncut):
+    """The routed parts of all four shares (4 x 4 experts) and the
+    shared expert counted once are the uncut reference's layer output:
+    what a chip leaves out is exactly what the other chips hold."""
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, TINY["d"]))
+    z, i = TINY, 2
+    whole = np.asarray(ref.routed_ffn(uncut, i, x[None], z["top_k"],
+                                      (0, z["experts"]), z["scale"]))[0]
+    shared = np.asarray(ref.routed_ffn(
+        uncut, i, x[None], z["top_k"], (0, z["experts"]), z["scale"],
+        routed=False))[0]
+    parts, pairs = [], 0
+    for first in range(0, z["experts"], 4):
+        y, counts = _routed_alone(uncut, i, x, (first, 4))
+        parts.append(y - shared)
+        pairs += counts[1]
+    assert pairs == 24 * z["top_k"]          # every pair is some chip's
+    assert np.abs(shared + sum(parts) - whole).max() < TOL
+    # the one definition of routing selects what the reference selects
+    h = arch_mod._rms(x, uncut[f"block{i}_norm3.scale"], 1e-5)
+    sel, weight = arch_mod.route(h, uncut[f"block{i}_router.w"],
+                                 uncut[f"block{i}_router.bias"], z["top_k"],
+                                 z["scale"])
+    _, want_sel, want_weight, _ = ref._route(
+        x[None], {k: uncut[f"block{i}_{k}"] for k in ref._ROUTE_KEYS},
+        top_k=z["top_k"], scale=z["scale"], norm=True, eps=1e-5)
+    assert np.array_equal(np.asarray(sel), np.asarray(want_sel)[0])
+    assert np.abs(np.asarray(weight) - np.asarray(want_weight)[0]).max() < 1e-6
+    assert np.allclose(np.asarray(weight).sum(-1), z["scale"], atol=1e-5)
+
+
+def test_the_margin_of_a_selection_against_a_count_of_every_pair():
+    """``ref._margin``: how far a row's selection is from one that
+    differs in a held expert, against the least over every (selected,
+    left out) pair of which one is held, of the difference of their
+    ``s + b`` over the sigmoid's slope at the worst selected expert;
+    ``inf`` where no pair is."""
+    rng = np.random.default_rng(34)
+    s = 1 / (1 + np.exp(-rng.normal(size=(3, 11, 16)))).astype(np.float32)
+    bias = (0.05 * rng.normal(size=16)).astype(np.float32)
+    c = s + bias
+    sel = np.argsort(-c, axis=-1)[..., :4]
+    for first, count in ((4, 4), (0, 16), (12, 4), (0, 1)):
+        got = np.asarray(ref._margin(jnp.asarray(s), jnp.asarray(bias),
+                                     jnp.asarray(sel), first=first,
+                                     count=count))
+        held = lambda e: first <= e < first + count
+        want = np.full(s.shape[:2], np.inf, np.float32)
+        for b, t in np.ndindex(*s.shape[:2]):
+            inside = sel[b, t].tolist()
+            at_last = s[b, t, inside[-1]]
+            for i in inside:
+                for j in set(range(16)) - set(inside):
+                    if held(i) or held(j):
+                        want[b, t] = min(want[b, t], (c[b, t, i] - c[b, t, j])
+                                         / (at_last * (1 - at_last)))
+        assert np.allclose(got, want, rtol=1e-5), (first, count)
+        assert (got > 0).all()
+    # no pair has a held expert: a share that holds nothing
+    assert np.isinf(np.asarray(ref._margin(
+        jnp.asarray(s), jnp.asarray(bias), jnp.asarray(sel), first=16,
+        count=0))).all()
+
+
+@pytest.mark.parametrize("routing", ["all_rows_to_one_held_set",
+                                     "an_expert_with_no_row",
+                                     "no_row_to_any_held_expert"])
+def test_no_token_is_dropped_however_uneven_the_routing(uncut, routing):
+    """No capacity: with every row selecting the SAME four held experts
+    the buffer of gathered rows is full and every pair is computed; an
+    expert no row selects, and a share no row selects, change nothing."""
+    z, i, share = TINY, 3, TINY["share"]
+    p = dict(uncut)
+    bias = np.zeros(z["experts"], np.float32)
+    if routing == "all_rows_to_one_held_set":
+        bias[4:8] = 10.0
+    elif routing == "an_expert_with_no_row":
+        bias[5] = -10.0
+    else:
+        bias[4:8] = -10.0
+    p[f"block{i}_router.bias"] = jnp.asarray(bias)
+    x = jax.random.normal(jax.random.PRNGKey(2), (40, z["d"]))
+    valid = jnp.arange(40) < 37              # three rows of padding
+    want = np.asarray(ref.routed_ffn(_share(p, *share), i, x[None],
+                                     z["top_k"], share, z["scale"]))[0]
+    y, counts = _routed_alone(p, i, x, share, valid)
+    assert np.abs(y - want)[:37].max() < TOL
+    if routing == "all_rows_to_one_held_set":
+        assert list(counts) == [37, 37 * 4, 4, 4]
+    elif routing == "no_row_to_any_held_expert":
+        assert list(counts) == [37, 0, 0, 4]
+    else:
+        assert counts[2] <= 3
+    # a padding row touches no expert: the shared expert alone
+    alone = np.asarray(ref.routed_ffn(_share(p, *share), i, x[None],
+                                      z["top_k"], share, z["scale"],
+                                      routed=False))[0]
+    assert np.abs(y - alone)[37:].max() < TOL
+
+
+@pytest.mark.parametrize("dtype,limit", [("float32", 1e-3),
+                                         ("bfloat16", BF16_MARGIN)])
+def test_engine_serves_three_requests_over_two_slots(params, monkeypatch,
+                                                     dtype, limit):
+    """The whole engine: admission, pieces, decode chunks, a slot
+    released and admitted again; the routing counters against the
+    engine's own bookkeeping."""
+    eng, reg = _engine(params[dtype], monkeypatch)
+    prompts = [PROMPTS[0], PROMPTS[1], (5 * np.arange(17) + 1) % 128]
+    tracer = trace.Tracer(enabled=True)
+    old = trace.get_tracer()
+    trace.set_tracer(tracer)
+    try:
+        outs = eng.generate_many(prompts, max_new_tokens=[9, 14, 12])
+    finally:
+        trace.set_tracer(old)
+    for prompt, full in zip(prompts, outs):
+        n_p = len(prompt)
+        assert np.array_equal(full[:n_p], prompt)
+        want = _reference(params[dtype], full)[n_p - 1:len(full) - 1]
+        gap = want.max(-1) - want[np.arange(len(want)), full[n_p:]]
+        if dtype == "float32":
+            assert gap.max() < limit, gap.max()
+        else:   # a flipped selection may cost a token its margin
+            assert np.median(gap) < limit, np.median(gap)
+    st = eng.stats()
+    assert st["serving.completed"] == 3 and eng.kv_pool.blocks_in_use == 0
+    layers, held = 4, TINY["share"][1]
+    assert st["serving.moe_layers"] == layers
+    assert st["serving.moe_experts_held"] == held
+    assert st["serving.moe_router_width"] == 16
+    assert st["serving.moe_top_k"] == 4
+    item = 4 if dtype == "float32" else 2
+    assert st["serving.moe_expert_bytes"] == 3 * 64 * 48 * item
+    chunks = [e["args"] for e in tracer.events()
+              if e["name"] == "serving.decode_chunk"]
+    fills = [e["args"] for e in tracer.events()
+             if e["name"] == "serving.prefill"]
+    assert all(a["moe_layers"] == layers and a["experts_held"] == held
+               for a in chunks + fills)
+    assert st["serving.moe_rows{phase=decode}"] == layers * sum(
+        a["active"] * a["steps"] for a in chunks)
+    assert st["serving.moe_rows{phase=prefill}"] == layers * sum(
+        len(p_) for p_ in prompts)
+    assert st["serving.moe_expert_visits{phase=decode}"] == (
+        held * layers * sum(a["steps"] for a in chunks))
+    assert st["serving.moe_expert_visits{phase=prefill}"] == (
+        held * layers * sum(a["pieces"] for a in fills))
+    for phase in ("decode", "prefill"):
+        rows = st[f"serving.moe_rows{{phase={phase}}}"]
+        pairs = st[f"serving.moe_assignments_held{{phase={phase}}}"]
+        touched = st[f"serving.moe_experts_touched{{phase={phase}}}"]
+        assert 0 < pairs <= rows * 4
+        assert 0 < touched <= min(
+            pairs, st[f"serving.moe_expert_visits{{phase={phase}}}"])
+
+
+def test_a_prefix_hit_over_window_and_full_planes(params, monkeypatch):
+    """No recurrent state, every plane holds the slot's whole chain:
+    ``prefix_reuse`` is allowed, and a request that skips a cached
+    prefix (whole blocks shared, the partial one forked copy-on-write)
+    decodes what it decodes alone."""
+    p = params["float32"]
+    eng, reg = _engine(p, monkeypatch, prefix_reuse=True, cache_blocks=12)
+    head = (3 * np.arange(18) + 2) % 128
+    first = np.concatenate([head, [9, 8, 7]])
+    second = np.concatenate([head, [1, 2, 3, 4, 5]])
+    eng.generate_many([first], max_new_tokens=[6])
+    out, = eng.generate_many([second], max_new_tokens=[10])
+    st = eng.stats()
+    assert st["serving.prefix_hit_rate"] > 0
+    assert st.get("serving.cow_copies", 0) >= 1
+    want = _reference(p, out)[len(second) - 1:len(out) - 1]
+    gap = want.max(-1) - want[np.arange(len(want)), out[len(second):]]
+    assert gap.max() < 1e-3, gap.max()
+    assert len(out) > len(second) + TINY["window"] // 2
+
+
+def test_a_draft_model_is_refused(params):
+    p = params["float32"]
+    with pytest.raises(ValueError, match="speculative decoding serves the "
+                       "GPT-2 block only.*'gated_moe'"):
+        ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
+                      draft_params=dict(p))
+
+
+def test_heads_wider_than_d_model_over_n_head_build_an_engine(params):
+    """The head size is the architecture's own: 4 heads of 32 on a model
+    64 wide (d / heads would be 16), and 5 heads, which do not divide
+    it."""
+    eng = ServingEngine(params["float32"], arch=_arch(), max_len=T,
+                        block_tokens=B, prefix_reuse=False,
+                        registry=MetricsRegistry())
+    assert eng.arch.head_dim == 32 != eng.arch.d_model // eng.arch.n_head
+    assert [a.shape for a in eng._pk] == [(1 + 8 * (T // B), B, 2, 32)] * 5
+    st = eng.stats()
+    assert st["serving.kv_heads"] == 2
+    assert st["serving.kv_bytes_per_token"] == 5 * 2 * 2 * 32 * 4
+    assert st["serving.kv_planes{kind=window}"] == 4
+    assert st["serving.kv_planes{kind=full}"] == 1
+    assert eng.arch.rows_per_entry == 2
+    odd = GatedMoE(("full",), 5, 1, 16, 64, window=8, dense_layers=1,
+                   router_width=4, top_k=1, experts=(0, 1))
+    assert odd.head_dim == 16 and odd.heads(jnp.zeros((3, 80))).shape == (
+        3, 5, 16)
+    with pytest.raises(ValueError, match="head_dim="):
+        arch_mod.Architecture(2, 5, 64)
+
+
+def test_the_published_layer_equations_count_398p6b_parameters():
+    """The layout is the model's: the layer equations at the published
+    sizes give the card's 400B total, 13B applied a token."""
+    d, q, kv, e, f, rows = 3072, 48 * 128, 8 * 128, 3072, 12288, 200192
+    attention = 2 * d * q + 2 * d * kv + q * d            # q, gate, k, v, out
+    expert = 3 * d * e
+    routed = attention + d * 256 + expert
+    total = 54 * (routed + 256 * expert) + 6 * (attention + 3 * d * f) \
+        + 2 * rows * d
+    applied = 54 * (routed + 4 * expert) + 6 * (attention + 3 * d * f) \
+        + rows * d
+    assert abs(total - 398.6e9) < 0.1e9, total
+    assert abs(applied - 12.76e9) < 0.02e9, applied

@@ -650,6 +650,81 @@ def test_attend_entry_point_chooses_by_window_width(w, monkeypatch):
         assert not np.asarray(got)[~live].any()
 
 
+# -- the grouped matrix product (kernels/grouped_matmul.py) -------------------
+
+# (rows, k, n, rows of each group): empty groups, rows that belong to no
+# group (the sum falls short of the rows), a group across row tiles of 32,
+# every row in one group, no row in any
+_GROUPED_CASES = {
+    "uneven_with_an_empty_group": (64, 32, 48, [10, 0, 30, 5]),
+    "groups_across_row_tiles": (256, 64, 256, [0, 130, 0, 1, 100]),
+    "every_row_in_one_group": (96, 32, 128, [0, 96, 0]),
+    "no_row_in_any_group": (48, 32, 128, [0, 0, 0]),
+    "rows_not_a_multiple_of_the_tile": (40, 32, 128, [7, 0, 20]),
+}
+
+
+def _grouped_truth(lhs, rhs, sizes):
+    want = np.zeros((lhs.shape[0], rhs.shape[2]), np.float32)
+    at = 0
+    for g, n in enumerate(sizes):
+        want[at:at + n] = (np.asarray(lhs[at:at + n], np.float32)
+                           @ np.asarray(rhs[g], np.float32))
+        at += n
+    return want
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_GROUPED_CASES))
+def test_grouped_matmul_backends_agree_with_the_truth(case, dtype):
+    """Both backends against a NumPy loop over the groups, and against
+    each other within ``ORACLE_TOL``; the rows of no group come back
+    zero, a group with no row costs nothing and changes nothing."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    m, k, n, sizes = _GROUPED_CASES[case]
+    rng = np.random.default_rng(34)
+    lhs = jnp.asarray(rng.normal(size=(m, k)), dtype)
+    rhs = jnp.asarray(rng.normal(size=(len(sizes), k, n)) / np.sqrt(k), dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+    want = _grouped_truth(lhs, rhs, sizes)
+    ref = np.asarray(get_kernel("grouped_matmul", "xla_ref").impl.call(
+        lhs, rhs, gs), np.float32)
+    mosaic = np.asarray(jax.jit(
+        lambda *a: gm.grouped_matmul_pallas(*a, interpret=True, block_m=32))(
+        lhs, rhs, gs), np.float32)
+    tol = oracle_tol("grouped_matmul", dtype, "fwd") * max(
+        np.abs(want).max(), 1.0)
+    assert np.abs(ref - want).max() <= tol
+    assert np.abs(mosaic - ref).max() <= tol
+    assert not mosaic[sum(sizes):].any() and not ref[sum(sizes):].any()
+
+
+def test_grouped_matmul_work_items_name_the_pairs_that_hold_a_row():
+    from paddle_tpu.kernels.grouped_matmul import work_items
+
+    group_of, tile_of, n_items, offsets = work_items(
+        jnp.asarray([0, 130, 0, 1, 100], jnp.int32), 256, 32)
+    n = int(n_items)
+    # group 1 holds rows 0..129: tiles 0..4; group 3 row 130: tile 4;
+    # group 4 rows 131..230: tiles 4..7; the empty groups have no item
+    assert list(np.asarray(group_of)[:n]) == [1] * 5 + [3] + [4] * 4
+    assert list(np.asarray(tile_of)[:n]) == [0, 1, 2, 3, 4, 4, 4, 5, 6, 7]
+    assert list(np.asarray(offsets)) == [0, 0, 130, 130, 131, 231]
+    assert group_of.shape == (256 // 32 + 5 - 1,)
+    assert int(work_items(jnp.zeros(3, jnp.int32), 64, 32)[2]) == 0
+
+
+def test_grouped_matmul_resolves_to_the_oracle_off_the_tpu():
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul
+
+    assert resolve_name("grouped_matmul") == "xla_ref"
+    lhs = jnp.ones((8, 4), jnp.float32)
+    out = grouped_matmul(lhs, jnp.ones((2, 4, 3), jnp.float32),
+                         jnp.asarray([3, 2], jnp.int32))
+    assert np.array_equal(np.asarray(out)[:, 0], [4, 4, 4, 4, 4, 0, 0, 0])
+
+
 @pytest.mark.parametrize("backend", ["pallas_tpu", "xla_ref"])
 def test_bit_exact_run_to_run_within_backend(backend):
     impl = _impl_or_skip("flash_attention", backend)
@@ -739,14 +814,14 @@ def test_global_env_fallback_counted_once_per_resolution(monkeypatch):
     assert count() == c0 + 2
 
 
-def test_two_backends_three_op_classes_and_any_platform_is_served():
+def test_two_backends_four_op_classes_and_any_platform_is_served():
     """What the registry holds since the GPU lowerings and the gather op
-    class went: two backends, three op classes, an auto order for the
-    TPU and the CPU; a platform with no order of its own is served by
-    the oracle for every op class."""
+    class went and the grouped matrix product came: two backends, four
+    op classes, an auto order for the TPU and the CPU; a platform with
+    no order of its own is served by the oracle for every op class."""
     assert kernels.BACKENDS == ("pallas_tpu", "xla_ref")
     assert sorted(kernels.registered_op_classes()) == [
-        "flash_attention", "fused_ce", "paged_attention"]
+        "flash_attention", "fused_ce", "grouped_matmul", "paged_attention"]
     assert set(kernels.AUTO_ORDER) == {"tpu", "cpu"}
     for op in kernels.registered_op_classes():
         assert {b for b, _, _ in available_backends(op)} == set(

@@ -96,14 +96,14 @@ def _jitted(eng):
         arch = eng.arch
 
         def window(p, pk, pv, toks, at, n, row):
-            x, pk, pv, _ = _bd._window_forward(
+            x, pk, pv, *_ = _bd._window_forward(
                 p, pk, pv, toks[None], at[None], (at + n - 1)[None],
                 row[None], arch)
             return arch.head(p, x[0])[n - 1], pk, pv
 
         def step(p, pk, pv, tok, at, row):
-            lg, pk, pv, _ = _bd.paged_step_logits(p, tok[None], at[None], pk,
-                                               pv, row[None], arch)
+            lg, pk, pv, *_ = _bd.paged_step_logits(
+                p, tok[None], at[None], pk, pv, row[None], arch)
             return lg[0], pk, pv
 
         eng._test_fns = jax.jit(window), jax.jit(step)

@@ -56,6 +56,13 @@ GEOMETRIES = {
     "widest_streamed_window": (24, 7, 24, 705, 16, 128, 1, None),
     "group_4_under_a_verify_window": (48, 5, 64, 3073, 16, 128, 4, 512),
     "cgpt590m_verify_window_grid_form": (8, 5, 16, 200, 12, 128, 1, None),
+    # 48 query heads of 128 over 8 K/V heads, 96 slots x 2048 positions
+    # (serving.arch.GatedMoE, chat_moe): 8 heads fill the sublane tiles
+    # (the loop form), six query rows a K/V row, a window of 4096
+    "chat_moe_window_plane": (96, 1, 64, 6145, 8, 128, 6, 4096),
+    "chat_moe_full_plane": (96, 1, 64, 6145, 8, 128, 6, None),
+    # chains of up to 128 live blocks a window plane (40 slots x 6144)
+    "group_6_window_4096_long_chains": (40, 1, 192, 7681, 8, 128, 6, 4096),
 }
 
 
@@ -83,3 +90,32 @@ def test_paged_kernel_compiles_for_v5e(geometry, one_chip):
     else:
         assert temp < 1 << 20
     assert "paged_attention" in compiled.as_text()
+
+
+# (rows gathered, k, n, groups): the routed experts of chat_moe, a decode
+# step's 96 x 4 rows and a prefill piece's 128 x 4, and a narrow piece
+GROUPED = {
+    "chat_moe_decode": (384, 3072, 3072, 32),
+    "chat_moe_prefill_piece": (512, 3072, 3072, 32),
+    "chat_moe_narrow_piece": (32, 3072, 3072, 32),
+}
+
+
+@pytest.mark.parametrize("geometry", list(GROUPED))
+def test_grouped_matmul_compiles_for_v5e(geometry, one_chip):
+    """The grouped matrix product with its dynamic grid bound: the
+    matrices enter in place (no temporary the size of an expert), and the
+    Mosaic call carries the kernel's name."""
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul_pallas
+
+    m, k, n, groups = GROUPED[geometry]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: grouped_matmul_pallas(*a, interpret=False)).lower(
+        arg((m, k), jnp.bfloat16), arg((groups, k, n), jnp.bfloat16),
+        arg((groups,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert "grouped_matmul" in compiled.as_text()

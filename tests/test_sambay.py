@@ -148,14 +148,15 @@ def _through_the_cache(eng, prompts, n_new):
 
     @jax.jit
     def window(p, pk, pv, st, toks, at, n, row, slot):
-        x, pk, pv, st = _bd._window_forward(
+        x, pk, pv, st, _ = _bd._window_forward(
             p, pk, pv, toks[None], at[None], (at + n - 1)[None], row[None],
             arch, st, slot)
         return arch.head(p, x[0])[n - 1], pk, pv, st
 
     @jax.jit
     def step(p, pk, pv, st, tok, at):
-        return _bd.paged_step_logits(p, tok, at, pk, pv, table, arch, st)
+        return _bd.paged_step_logits(p, tok, at, pk, pv, table, arch,
+                                     st)[:4]
 
     pk, pv, st = eng._pk, eng._pv, eng._state
     # a slot's last request leaves its state behind: a prompt's first
