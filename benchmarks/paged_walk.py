@@ -12,9 +12,10 @@ The geometries are the serving cells' calls and the callers no cell
 runs: ``agent_turns`` (24 slots x 24 entries, 16 heads of 128, one row
 a block), ``reason_decode`` (one pass's plane of the folded pool, 10
 slots), ``think_decode``'s full and window-512 planes (48 slots, a K/V
-group of 4 folded into four rows a block), the speculative verify window
-(5 rows at consecutive positions) and the 12-head pool that takes the
-grid form, alone and under a verify window.  Live slots and their
+group of 4 folded into four rows a block), ``chat_moe``'s (96 slots, 8
+K/V heads of 128, a group of 6, window 4096 and none), the speculative
+verify window (5 rows at consecutive positions) and the 12-head pool that
+takes the grid form, alone and under a verify window.  Live slots and their
 contexts are drawn from ``--seed`` in the range each cell's traffic
 reaches; a dead slot has a row of trash and ``pos = -1``.  Refuses unless JAX finds a TPU: a number from a CPU
 run is no device metric.
@@ -48,6 +49,12 @@ GEOMETRIES = {
                                 dh=128, group=4, window=512, live=20,
                                 ctx=(160, 2000),
                                 config="phi-4-mini-flash-reasoning"),
+    "chat_moe_full": dict(S=96, W=1, NB=64, blocks=6145, rows=8, dh=128,
+                          group=6, window=None, live=20, ctx=(64, 2000),
+                          config="trinity-large-preview"),
+    "chat_moe_window": dict(S=96, W=1, NB=64, blocks=6145, rows=8, dh=128,
+                            group=6, window=4096, live=20, ctx=(64, 2000),
+                            config="trinity-large-preview"),
     "verify_window": dict(S=24, W=5, NB=24, blocks=705, rows=16, dh=128,
                           group=1, window=None, live=5, ctx=(520, 760),
                           config="cerebras-gpt-1.3b"),
@@ -102,12 +109,14 @@ def case(name, seed):
 
 def _kv_rows(g):
     """Rows of a block that hold K/V (``pool_rows`` may have added
-    empty ones): a pair of the hybrid's K/V heads a row."""
+    empty ones): the hybrid's K/V heads, as many to a row as fill its
+    lanes (a pair of ``think_decode``'s heads of 64)."""
     if g["group"] == 1:
         return g["rows"]
     from chipbench import hybrid_bytes
 
-    return hybrid_bytes.sizes(_config(g["config"]))["kv_heads"] // 2
+    size = hybrid_bytes.sizes(_config(g["config"]))
+    return size["kv_heads"] * size["head_dim"] // g["dh"]
 
 
 def least_seconds(name, attended, peak):

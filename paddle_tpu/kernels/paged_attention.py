@@ -78,16 +78,17 @@ Backends:
   clip(max_w pos[s, w] // B + 1, 0, NB)`` (every entry from ``n_s`` on
   is masked for every row); the others are neither fetched nor
   computed, each block
-  copied from the pool where it lies into a two-deep VMEM buffer while
-  ``(m, l, acc)`` carry in VMEM scratch.  A live block is folded ONCE
-  for all the rows of the window (a K/V group's rows among them): their
-  scores are one MXU pass, and the mask, the running maximum, the
-  ``exp`` and the sums are one update over one array that holds the rows
-  side by side on its lanes; only ``p * v`` is a row's own work
-  (``softmax_updates``).  A row with ``pos < 0`` has no
-  visible key and returns zeros; a slot of such rows (a dead slot, as
-  ``batched_decode._Cache`` names it) costs one empty grid
-  step.  Registered available on real TPU only (off-TPU the
+  copied from the pool where it lies into a VMEM buffer (two deep for
+  one row, ``DEPTH`` for more) while ``(m, l, acc)`` carry in VMEM
+  scratch.  A live block is folded ONCE for all the rows of the window
+  (a K/V group's rows among them): their scores are one MXU pass, the
+  mask, the running maximum, the ``exp`` and the sums are one update
+  over one array that holds the rows on its sublanes, and ``p * v`` is
+  a second MXU pass for all of them (``softmax_updates``); the loop
+  makes a block's scores while the block before it is weighed.  A row
+  with ``pos < 0`` has no visible key and returns zeros; a slot of such
+  rows (a dead slot, as ``batched_decode._Cache`` names it) costs one
+  empty grid step.  Registered available on real TPU only (off-TPU the
   interpret-mode grid would replace one fused XLA loop with a per-block
   Python loop); the oracle suite still covers the kernel logic on CPU by
   forcing ``interpret=True``.
@@ -105,11 +106,19 @@ __all__ = ["attend", "DENSE_WINDOW", "paged_attention_ref",
 
 # From this window width up a window gathers its slot's chain once and
 # attends it densely instead of streaming blocks.  W rows then share one
-# read of K and V and both products are MXU matmuls, where the streaming
-# kernels weigh the values once per window row on the VPU; and the
-# Mosaic kernel cannot run wide at all (18.6 MB of scoped VMEM at W = 64;
-# PERF.md, PR 26).
+# read of K and V and both products are wide MXU matmuls, where the
+# streaming kernels make two small ones a block; and the Mosaic kernel
+# cannot run wide at all (18.6 MB of scoped VMEM at W = 64; PERF.md,
+# PR 26).
 DENSE_WINDOW = 8
+
+# Blocks the Mosaic loop of two rows or more keeps in VMEM: the one whose
+# values are weighed, the one whose scores are made, and two on their way
+# from the pool.  Measured alone on the chip at four rows a block
+# (think_decode's full plane; benchmarks/paged_walk.py, PERF.md PR 35):
+# two buffers 0.70 us a live block (a copy's latency, not its bytes),
+# three or four 0.62, four with the scores a block ahead 0.41, six 0.42.
+DEPTH = 4
 
 
 def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
@@ -147,9 +156,9 @@ def pool_rows(heads, dtype):
 def softmax_updates(rows):
     """Online-softmax updates the Mosaic kernel makes for ONE live block
     that ``rows`` query rows attend (the window's rows, a K/V group
-    folded in): one, however many the rows, because they sit side by
-    side on the lanes of one array and share the mask, the maximum, the
-    ``exp`` and the sums.  Stated here, as a function of the folded
+    folded in): one, however many the rows, because they sit on the
+    sublanes of one array and share the mask, the maximum, the ``exp``
+    and the sums.  Stated here, as a function of the folded
     width, for whoever counts the kernel's work
     (``serving.paged_updates_live``): the engine does not guess."""
     if rows < 1:
@@ -309,8 +318,8 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     adds ``p = 0`` and scales by ``alpha = exp(m - m) = 1``: leaving it
     out changes no bit of a row with at least one visible key.  A row
     with ``pos < 0`` has none and returns ZEROS (``l == 0 -> 1`` over an
-    ``acc`` of zeros; beside rows that do see keys, by its position at
-    the end); a slot whose rows are all negative costs one empty
+    ``acc`` of zeros, beside rows that do see keys too: it weighs every
+    key zero); a slot whose rows are all negative costs one empty
     grid step.  That is how the serving step names a dead slot
     (``batched_decode._Cache``).  With a ``window`` the chain
     also has a FIRST live entry, ``f_s = max(min_w pos[s, w] - window +
@@ -324,8 +333,10 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     BlockSpec, no pool-sized copy), iteration ``i`` copies block
     ``table[s, i]`` of K and of V into one half of a two-deep VMEM buffer
     (``make_async_copy``; block ``i + 1`` is in flight while block ``i``
-    is attended) and folds it into the f32 ``(m, l, acc)`` online softmax
-    in VMEM scratch; the output writes once after the loop.
+    is attended; ``DEPTH`` deep with ``DEPTH - 1`` in flight for two
+    rows or more, below) and folds it into the f32 ``(m, l, acc)``
+    online softmax in VMEM scratch; the output writes once after the
+    loop.
 
     Where Mosaic cannot slice a block out of the pool
     (``_block_is_sliceable``: bf16 with 6 or 12 heads) the same body runs
@@ -338,30 +349,42 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     The block stays in the pool's own ``[B, h, dh]`` layout (tokens on
     the untiled axis, heads on sublanes, ``dh`` on lanes), and it is
     folded ONCE for all ``W`` rows of the window (the rows a K/V group
-    folded in among them).  Scores are one MXU pass, ``[B * h, dh] x
-    [dh, W * h]`` with the queries as the stationary operand (f32 out of
-    exact products; ``HIGHEST`` for a float32 pool): row ``w``'s score
-    against head ``j`` is at lane ``w * h + j`` of sublane ``j``, the
-    own-head diagonal, and everything off it is masked.  The scale, the
-    position mask (each lane its own row's position, so the rows of a
-    verify window keep their own masks and the rows of a K/V group share
-    one), the maximum over tokens, ``exp`` and the sum then run ONCE a
-    block over that ``[B, h, W * h]`` array, and ``m`` and ``l`` in
-    scratch hold row ``w`` of head ``j`` at the same lane.  Only ``p *
-    v`` is a row's own work, on the VPU in f32: the row's lanes of ``p``
-    summed into a lane-replicated ``[B, h, 1]`` column (one lane
-    reduction a register, the one cross-lane step a row still costs)
-    times ``v``, summed over the untiled token axis.  No transpose, no
-    bf16 arithmetic, no key left out.  With ONE row (``W = 1`` and no
-    group: plain decode) there is nothing to share and the body is the
-    per-row program it always was: the score a lane reduction of ``k *
-    q``, ``m`` and ``l`` lane-replicated (measured alone on the chip the
-    shared body is 1.5% faster there in the loop form and 15% slower in
-    the grid form, whose 12 heads do not fill a sublane tile and have to
-    be repacked for the MXU: ``benchmarks/paged_walk.py``, PERF.md PR
-    33).  ``block_step`` is accepted for signature parity and ignored:
-    this spelling streams exactly one block per iteration by
-    construction."""
+    folded in among them), BOTH products on the MXU.  Scores are one
+    pass, ``[N, dh] x [B * h, dh]^T`` with ``N = W * h`` (f32 out of
+    exact products; ``HIGHEST`` for a float32 pool): row ``w`` of head
+    ``j`` is sublane ``w * h + j``, token ``t`` of head ``j'`` is lane
+    ``t * h + j'``, the flash kernels' layout with every lane of another
+    head masked.  The scale, the mask (each sublane its own row's
+    position, so the rows of a verify window keep their own masks and
+    the rows of a K/V group share one; a token bound is a lane bound,
+    ``t <= at`` is ``lane < (at + 1) * h``), the maximum over the lanes,
+    ``exp`` and the sum then run ONCE a block over that ``[N, B * h]``
+    array, and ``m`` and ``l`` in scratch hold one row a sublane,
+    lane-replicated.  Every lane a row does not keep weighs EXACTLY zero
+    (while the row has seen no key its maximum is taken as 0, where
+    ``exp(NEG_INF - NEG_INF)`` would weigh every lane 1), so the values
+    are weighed by ONE more pass for all the rows, ``p [N, B * h] x v [B
+    * h, dh]`` into the f32 ``acc [N, dh]``: whatever a masked token or
+    a row ``pool_rows`` added holds, as long as it is finite, adds zero.
+    That pass keeps ``p`` at float32 accuracy: for a bfloat16 pool ``p``
+    goes as the three bfloat16 pieces that sum to it exactly, stacked on
+    the row axis (their products with ``v`` are exact in f32); for a
+    float32 pool at ``HIGHEST``.  No transpose, no weight rounded to
+    bfloat16, no key left out.  What a block then costs is the LATENCY
+    of its chain (copy, MXU, lane reduction, ``exp``, MXU), not its
+    work, so the loop form keeps ``DEPTH`` copies ahead and two chains
+    in flight (``shared_loop_kernel``: block ``i + 1``'s scores and row
+    maxima are made while block ``i`` is weighed); the grid form, whose
+    pipeline is Pallas's, makes one after the other.  With ONE row (``W
+    = 1`` and no group: plain decode) there is nothing to share and the
+    body is the per-row program it always was: the score a lane
+    reduction of ``k * q``, ``m`` and ``l`` lane-replicated, ``p * v``
+    on the VPU (measured alone on the chip PR 33's shared body was 1.5%
+    faster there in the loop form and 15% slower in the grid form, whose
+    12 heads do not fill a sublane tile and have to be repacked for the
+    MXU: ``benchmarks/paged_walk.py``, PERF.md PR 33).  ``block_step``
+    is accepted for signature parity and ignored: this spelling streams
+    exactly one block per iteration by construction."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -397,10 +420,11 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     # One row a block (plain decode with a K/V head a query head) has
     # nothing to share: it keeps the per-row program this kernel always
     # was, statistics lane-replicated in scratch.  From two rows up the
-    # rows share ONE update a block: row w of head j sits at lane
-    # ``w * h + j`` of ``[.., h, N]`` arrays (own-head diagonal), and so
-    # do ``m`` and ``l`` in scratch.  ``st`` below is the three refs
-    # ``(m_ref, l_ref, acc_ref)``.
+    # rows share ONE update a block and both products are MXU passes:
+    # row w of head j is sublane ``w * h + j`` of ``[N, B * h]`` arrays
+    # whose lane ``t * h + j'`` is token ``t`` of head ``j'``, and so of
+    # ``m``, ``l`` (lane-replicated) and ``acc [N, dh]`` in scratch.
+    # ``st`` below is the three refs ``(m_ref, l_ref, acc_ref)``.
     N = W * h
     f32 = jnp.float32
 
@@ -409,87 +433,111 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def own_lanes(w, ndim=2):
-        """Row ``w``'s lanes of an ``ndim``-d ``[.., h, N]`` array (ones
-        before the last two axes): head ``j`` at lane ``w * h + j`` of
-        sublane ``j``."""
-        shape = (1,) * (ndim - 2) + (h, N)
-        return (jax.lax.broadcasted_iota(jnp.int32, shape, ndim - 1)
-                == w * h + jax.lax.broadcasted_iota(jnp.int32, shape,
-                                                    ndim - 2))
-
-    def column(x, w):
-        """Row ``w``'s ``[.., h, 1]`` column of ``x [.., h, N]``: its
-        lanes summed (one non-zero each), the result lane-replicated."""
-        return jnp.sum(jnp.where(own_lanes(w, x.ndim), x, 0.0), axis=-1,
-                       keepdims=True)
-
-    def fold(i, kb, vb, s_id, pos_ref, q_ref, m_ref, l_ref, acc_ref):
-        """Block ``i`` of slot ``s_id``'s chain into the slot's state,
-        once for all the rows of the window."""
-        if W == 1:
-            kb = kb.astype(f32)                            # [B, h, dh]
-            vb = vb.astype(f32)
-            tok = i * B + jax.lax.broadcasted_iota(jnp.int32, (B, h, 1), 0)
-            qw = q_ref[0, 0].astype(f32)                   # [h, dh]
-            s = jnp.sum(kb * qw[None], axis=-1, keepdims=True) * scale
-            keep = tok <= pos_ref[s_id, 0]
-            if window is not None:
-                keep &= tok > pos_ref[s_id, 0] - window
-            s = jnp.where(keep, s, NEG_INF)                 # [B, h, 1]
-            m = m_ref[0][:, :1]                            # [h, 1]
-            m2 = jnp.maximum(m, jnp.max(s, axis=0))
-            alpha = jnp.exp(m - m2)
-            p = jnp.exp(s - m2[None])
-            l2 = l_ref[0][:, :1] * alpha + jnp.sum(p, axis=0)
-            acc_ref[0] = acc_ref[0] * alpha + jnp.sum(p * vb, axis=0)
-            m_ref[0] = jnp.broadcast_to(m2, (h, LSE_LANES))
-            l_ref[0] = jnp.broadcast_to(l2, (h, LSE_LANES))
-            return
-        # scores of every row against every head in ONE MXU pass, f32
-        # out of exact products; a row's own head is the diagonal
-        dt = jnp.promote_types(kb.dtype, q_ref.dtype)
-        s = jax.lax.dot_general(
-            kb.reshape(B * h, dh).astype(dt),
-            q_ref[0].reshape(N, dh).astype(dt), (((1,), (1,)), ((), ())),
-            preferred_element_type=f32,
-            precision=(jax.lax.Precision.HIGHEST if dt == f32 else None))
-        s = s.reshape(B, h, N) * scale
-        # each lane's position less the block's first token; -1 off the
-        # diagonal, which is under every token: masked
-        at = jnp.full((1, h, N), -1, jnp.int32)
-        for w in range(W):
-            at = jnp.where(own_lanes(w, 3), pos_ref[s_id, w] - i * B, at)
-        tok = jax.lax.broadcasted_iota(jnp.int32, (B, h, N), 0)
-        keep = tok <= at
+    def fold_row(i, kb, vb, s_id, pos_ref, q_ref, m_ref, l_ref, acc_ref):
+        """Block ``i`` into the state of a window of ONE row."""
+        kb = kb.astype(f32)                            # [B, h, dh]
+        vb = vb.astype(f32)
+        tok = i * B + jax.lax.broadcasted_iota(jnp.int32, (B, h, 1), 0)
+        qw = q_ref[0, 0].astype(f32)                   # [h, dh]
+        s = jnp.sum(kb * qw[None], axis=-1, keepdims=True) * scale
+        keep = tok <= pos_ref[s_id, 0]
         if window is not None:
-            keep &= tok > at - window
-        s = jnp.where(keep, s, NEG_INF)                     # [B, h, N]
-        m = m_ref[...]                                     # [h, N]
+            keep &= tok > pos_ref[s_id, 0] - window
+        s = jnp.where(keep, s, NEG_INF)                 # [B, h, 1]
+        m = m_ref[0][:, :1]                            # [h, 1]
         m2 = jnp.maximum(m, jnp.max(s, axis=0))
         alpha = jnp.exp(m - m2)
         p = jnp.exp(s - m2[None])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0)
-        m_ref[...] = m2
-        # p * v is a row's own work: its lane of p over the lanes of v
-        vb = vb.astype(f32)                                # [B, h, dh]
-        for w in range(W):
-            acc_ref[w] = (acc_ref[w] * column(alpha, w)
-                          + jnp.sum(column(p, w) * vb, axis=0))
+        l2 = l_ref[0][:, :1] * alpha + jnp.sum(p, axis=0)
+        acc_ref[0] = acc_ref[0] * alpha + jnp.sum(p * vb, axis=0)
+        m_ref[0] = jnp.broadcast_to(m2, (h, LSE_LANES))
+        l_ref[0] = jnp.broadcast_to(l2, (h, LSE_LANES))
 
-    def finish(s_id, pos_ref, o_ref, m_ref, l_ref, acc_ref):
+    def matmul(a, b, dims):
+        """``a x b`` contracting ``dims`` on the MXU: f32 out of exact
+        products (``HIGHEST`` where an operand is float32)."""
+        return jax.lax.dot_general(
+            a, b, (dims, ((), ())), preferred_element_type=f32,
+            precision=(jax.lax.Precision.HIGHEST if a.dtype == f32
+                       else None))
+
+    def weigh(p, vb):
+        """``p [N, B * h] x vb [B * h, dh]`` in ONE MXU pass at the
+        float32 accuracy of ``p``.  A bfloat16 pool takes ``p`` as the
+        three bfloat16 pieces that sum to it exactly (8 + 8 + 8 bits of
+        its 24), stacked on the row axis: their products with ``vb`` are
+        exact in f32, so nothing is rounded that the VPU kept."""
+        if vb.dtype != jnp.bfloat16:
+            return matmul(p, vb.astype(f32), ((1,), (0,)))
+        pieces = []
+        for _ in range(3):
+            pieces.append(p.astype(jnp.bfloat16))
+            p = p - pieces[-1].astype(f32)
+        out = matmul(jnp.concatenate(pieces, axis=0), vb, ((1,), (0,)))
+        return out[:N] + out[N:2 * N] + out[2 * N:]
+
+    def scores(i, kb, s_id, pos_ref, q_ref):
+        """Every row's masked scores against block ``i`` of slot
+        ``s_id``'s chain, ``[N, B * h]``, and each row's maximum."""
+        # every row against every token and head of the block in ONE MXU
+        # pass; a row's own head is every h-th lane
+        dt = jnp.promote_types(kb.dtype, q_ref.dtype)
+        s = matmul(q_ref[0].reshape(N, dh).astype(dt),
+                   kb.reshape(B * h, dh).astype(dt), ((1,), (1,))) * scale
+        # row (w, j) keeps the lanes of head j whose token its position
+        # lets through: token t <= at is lane < (at + 1) * h
+        row = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
+        top = jnp.full((N, 1), (pos_ref[s_id, 0] + 1 - i * B) * h)
+        for w in range(1, W):
+            top = jnp.where(row >= w * h,
+                            (pos_ref[s_id, w] + 1 - i * B) * h, top)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (N, B * h), 1)
+        keep = (jax.lax.rem(lane, h) == jax.lax.rem(row, h)) & (lane < top)
+        if window is not None:
+            keep &= lane >= top - window * h
+        s = jnp.where(keep, s, NEG_INF)
+        return s, jnp.max(s, axis=-1, keepdims=True)
+
+    def update(s, peak, vb, m_ref, l_ref, acc_ref):
+        """A block's scores ``s`` (their row maxima ``peak``) and values
+        ``vb`` into the state of all the rows: the ONE softmax update."""
+        m = m_ref[...][:, :1]                              # [N, 1]
+        m2 = jnp.maximum(m, peak)
+        alpha = jnp.exp(m - m2)
+        # every lane a row does not keep weighs EXACTLY zero, also while
+        # the row has seen no key (exp(NEG_INF - NEG_INF) would be 1):
+        # the product below sums over all of them
+        p = jnp.exp(s - jnp.where(m2 == NEG_INF, 0.0, m2))
+        l2 = l_ref[...][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + weigh(p, vb.reshape(B * h, dh))
+        m_ref[...] = jnp.broadcast_to(m2, (N, LSE_LANES))
+        l_ref[...] = jnp.broadcast_to(l2, (N, LSE_LANES))
+
+    def fold(i, kb, vb, s_id, pos_ref, q_ref, *st):
+        """Block ``i`` of slot ``s_id``'s chain into the slot's state,
+        once for all the rows of the window."""
+        if W == 1:
+            fold_row(i, kb, vb, s_id, pos_ref, q_ref, *st)
+        else:
+            update(*scores(i, kb, s_id, pos_ref, q_ref), vb, *st)
+
+    def finish(o_ref, m_ref, l_ref, acc_ref):
         del m_ref
-        for w in range(W):
-            l = l_ref[0][:, :1] if W == 1 else column(l_ref[...], w)
+        if W == 1:
+            l = l_ref[0][:, :1]
             l_safe = jnp.where(l == 0.0, 1.0, l)
-            out = acc_ref[w] / l_safe
-            if W > 1:
-                # a row with no visible key beside rows that have some
-                out = jnp.where(pos_ref[s_id, w] >= 0, out, 0.0)
-            o_ref[0, w] = out.astype(o_ref.dtype)
+            out = acc_ref[0] / l_safe
+            o_ref[0, 0] = out.astype(o_ref.dtype)
+            return
+        l = l_ref[...][:, :1]
+        out = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        for w in range(W):
+            o_ref[0, w] = out[w * h:(w + 1) * h]
 
     def loop_kernel(tbl, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                     k_buf, v_buf, sem, *st):
+        """The loop over ``[f_s, n_s)`` for ONE row a block: two buffers,
+        a block folded after it has landed."""
         s_id = pl.program_id(0)
         n = live_entries(pos_ref, s_id)
         first = first_entry(pos_ref, s_id)
@@ -521,7 +569,64 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             fold(i, k_buf[half], v_buf[half], s_id, pos_ref, q_ref, *st)
 
         jax.lax.fori_loop(first, n, block, None)
-        finish(s_id, pos_ref, o_ref, *st)
+        finish(o_ref, *st)
+
+    def shared_loop_kernel(tbl, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                           k_buf, v_buf, sem, s_ref, peak_ref, *st):
+        """The loop over ``[f_s, n_s)`` for two rows or more.  With both
+        products on the MXU a block is a CHAIN of latencies (copy, MXU,
+        lane reduction, ``exp``, MXU) and not much work, so the loop
+        keeps two chains in flight: while block ``i``'s values are
+        weighed, block ``i + 1``'s scores and row maxima are made and
+        left in scratch for the next iteration, and ``DEPTH`` copies run
+        ahead (K is needed a block before V)."""
+        s_id = pl.program_id(0)
+        n = live_entries(pos_ref, s_id)
+        first = first_entry(pos_ref, s_id)
+
+        def copy(i, plane):
+            hbm, buf = ((k_hbm, k_buf), (v_hbm, v_buf))[plane]
+            slot = jax.lax.rem(i - first, DEPTH)
+            return pltpu.make_async_copy(hbm.at[tbl[s_id, i]], buf.at[slot],
+                                         sem.at[plane, slot])
+
+        def score(i):
+            s, peak = scores(i, k_buf[jax.lax.rem(i - first, DEPTH)], s_id,
+                             pos_ref, q_ref)
+            s_ref[...] = s
+            peak_ref[...] = jnp.broadcast_to(peak, (N, LSE_LANES))
+
+        init(*st)
+        for ahead in range(DEPTH - 1):
+            @pl.when(first + ahead < n)
+            def _start(ahead=ahead):
+                for plane in (0, 1):
+                    copy(first + ahead, plane).start()
+
+        @pl.when(first < n)
+        def _first():
+            copy(first, 0).wait()
+            score(first)
+
+        def block(i, _):
+            @pl.when(i + DEPTH - 1 < n)
+            def _ahead():
+                for plane in (0, 1):
+                    copy(i + DEPTH - 1, plane).start()
+
+            @pl.when(i + 1 < n)
+            def _next_k():
+                copy(i + 1, 0).wait()
+
+            copy(i, 1).wait()
+            s, peak = s_ref[...], peak_ref[...][:, :1]
+            # the last block's scores are made once more and dropped:
+            # no branch between the two chains
+            score(jnp.minimum(i + 1, n - 1))
+            update(s, peak, v_buf[jax.lax.rem(i - first, DEPTH)], *st)
+
+        jax.lax.fori_loop(first, n, block, None)
+        finish(o_ref, *st)
 
     def grid_kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref, *st):
         del tbl  # consumed by the index maps, not the body
@@ -542,17 +647,24 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
 
         @pl.when(nb == NB - 1)
         def _finish():
-            finish(s_id, pos_ref, o_ref, *st)
+            finish(o_ref, *st)
 
-    stat = (1, h, LSE_LANES) if W == 1 else (h, N)
+    stat, acc = (((1, h, LSE_LANES), (1, h, dh)) if W == 1
+                 else ((N, LSE_LANES), (N, dh)))
     stats = [pltpu.VMEM(stat, jnp.float32), pltpu.VMEM(stat, jnp.float32),
-             pltpu.VMEM((W, h, dh), jnp.float32)]
+             pltpu.VMEM(acc, jnp.float32)]
     if _block_is_sliceable(pool_k):
-        kernel, grid, semantics = loop_kernel, (S,), ("parallel",)
+        grid, semantics = (S,), ("parallel",)
         kv_spec = pl.BlockSpec(memory_space=pl.ANY)
-        scratch = [pltpu.VMEM((2, B, h, dh), pool_k.dtype),
-                   pltpu.VMEM((2, B, h, dh), pool_v.dtype),
-                   pltpu.SemaphoreType.DMA((2, 2))] + stats
+        if W == 1:
+            kernel, deep, ahead = loop_kernel, 2, []
+        else:
+            kernel, deep = shared_loop_kernel, DEPTH
+            ahead = [pltpu.VMEM((N, B * h), jnp.float32),
+                     pltpu.VMEM((N, LSE_LANES), jnp.float32)]
+        scratch = [pltpu.VMEM((deep, B, h, dh), pool_k.dtype),
+                   pltpu.VMEM((deep, B, h, dh), pool_v.dtype),
+                   pltpu.SemaphoreType.DMA((2, deep))] + ahead + stats
     else:
         def last_live(s, nb, tbl, pos):
             i = jnp.minimum(nb, jnp.maximum(live_entries(pos, s) - 1, 0))

@@ -340,11 +340,14 @@ _GROUP_WINDOW_CASES = {
 }
 
 
-def _windowed_truth(q, pk, pv, table, pos, group, window, scale):
+def _windowed_truth(q, pk, pv, table, pos, group, window, scale,
+                    weights=lambda a: a):
     """The dense truth, in numpy, from the values the backends see: row
     ``r`` of slot ``s`` attends keys ``max(0, pos - window + 1) .. pos``
     of its chain, query head ``i`` the K/V head ``i // group``; a row
-    with ``pos < 0`` stays zeros."""
+    with ``pos < 0`` stays zeros.  ``weights`` is what becomes of the
+    unnormalized weights before they meet the values (a rounding, for a
+    test that has to tell one from none)."""
     S, NB = table.shape
     dh = q.shape[-1]
     k32 = np.asarray(pk, np.float32)[table].reshape(S, -1, pk.shape[2], dh)
@@ -360,8 +363,8 @@ def _windowed_truth(q, pk, pv, table, pos, group, window, scale):
             for i in range(q.shape[2]):
                 sc = k32[s_, lo:at + 1, i // group] @ q32[s_, r, i] * scale
                 a = np.exp(sc - sc.max())
-                want[s_, r, i] = (a / a.sum()) @ v32[s_, lo:at + 1,
-                                                     i // group]
+                want[s_, r, i] = (weights(a) / a.sum()) @ v32[
+                    s_, lo:at + 1, i // group]
     return want
 
 
@@ -531,11 +534,15 @@ def test_paged_mosaic_makes_one_softmax_update_a_block(w, group, dtype, hk):
     """What the fold shares, read off the traced kernel: however many
     rows attend a block (window rows x K/V group) the body holds TWO
     ``exp`` (``alpha`` and ``p``), where a per-row body holds two a row;
-    from two rows up the scores are ONE ``dot_general``; one row keeps
-    the per-row program (a product and a lane reduction, no matmul),
-    which is what every ``W = 1`` caller lowered to before."""
+    from two rows up the scores AND the value product are ONE
+    ``dot_general`` each a block, and their count does not grow with the
+    rows (the loop form traces the scores at two places, the first
+    block's ahead of the loop and the next block's inside it: three in
+    the body, two in the grid form's); one row keeps the per-row program
+    (a product and a lane reduction, no matmul), which is what every ``W
+    = 1`` caller lowered to before."""
     from paddle_tpu.kernels.paged_attention import (
-        paged_attention_pallas, softmax_updates)
+        _block_is_sliceable, paged_attention_pallas, softmax_updates)
 
     q, pk, pv, tbl, pos, how, _, _ = _shared_fold_case(w, group, 48, dtype,
                                                        hk)
@@ -544,7 +551,103 @@ def test_paged_mosaic_makes_one_softmax_update_a_block(w, group, dtype, hk):
             q, pk, pv, tbl, pos).jaxpr)
     assert counts.get("pallas_call") == 1
     assert counts.get("exp") == 2 * softmax_updates(w * group), counts
-    assert counts.get("dot_general", 0) == (0 if w * group == 1 else 1)
+    products = 3 if _block_is_sliceable(pk) else 2
+    assert counts.get("dot_general", 0) == (0 if w * group == 1 else products)
+
+
+# the value product on the MXU (PR 35) keeps the weights' float32: a
+# bfloat16 pool of 8 K/V heads takes the loop form, of 6 the grid form
+_WEIGHT_FORMS = [8, 6]
+# (window rows, K/V group): 2, 4, 6 and 20 rows a block
+_WEIGHT_ROWS = [(2, 1), (1, 4), (1, 6), (5, 4)]
+BF16_MAX = float(jnp.finfo(jnp.bfloat16).max)
+
+
+def _float32_weights_case(w, group, hk, extra=0, seed=17):
+    """``_shared_fold_case``'s three slots over a bfloat16 pool (with
+    ``extra`` rows past its K/V heads) whose scores spread over some 14
+    (weights from 2^-20 to 1, several of a size near the top) against
+    values of magnitude up to 64; a window of 48 gives every chain a
+    first block too.  Also ``unseen``: what no row's mask lets through
+    in the pool (the trash block, the tokens past a chain's end and
+    under every row's lower bound, the rows past ``hk``)."""
+    rng = np.random.default_rng(seed)
+    S, NB, B, dh, window = 3, 16, 8, 16, 48
+    shape = (1 + S * NB, B, hk + extra, dh)
+    pk = jnp.asarray(rng.normal(size=shape) * 1.9, jnp.bfloat16)
+    pv = jnp.asarray(rng.uniform(-64, 64, size=shape), jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(S, w, hk * group, dh)) * 1.9,
+                    jnp.bfloat16)
+    table = 1 + np.arange(S * NB, dtype=np.int32).reshape(S, NB)
+    last = np.array([70, 37, NB * B - 1])
+    pos = last[:, None] - (w - 1) + np.arange(w)[None, :]
+    if w > 1:
+        pos[1, 0] = -1
+    unseen = np.ones(shape[:3], bool)
+    for s_ in range(S):
+        at = pos[s_][pos[s_] >= 0]
+        tok = np.arange(max(0, at.min() - window + 1), at.max() + 1)
+        unseen[table[s_, tok // B], tok % B, :hk] = False
+    how = dict(group=group, window=window)
+    want = _windowed_truth(q, pk, pv, table, pos, group, window, dh ** -0.5)
+    return (q, pk, pv, jnp.asarray(table), jnp.asarray(pos, jnp.int32), how,
+            want, pos >= 0, jnp.asarray(unseen)[..., None])
+
+
+@pytest.mark.parametrize("hk", _WEIGHT_FORMS)
+@pytest.mark.parametrize("w,group", _WEIGHT_ROWS)
+def test_paged_value_product_keeps_float32_weights(w, group, hk):
+    """A bfloat16 pool read out in float32: the Mosaic kernel (interpret)
+    matches the dense truth at the FLOAT32 tolerance, which the same
+    truth with ONE bfloat16 cast of ``p`` misses: the MXU is fed the
+    weights whole."""
+    from paddle_tpu.kernels.paged_attention import (
+        _block_is_sliceable, paged_attention_pallas)
+
+    q, pk, pv, tbl, pos, how, want, live, _ = _float32_weights_case(
+        w, group, hk)
+    assert _block_is_sliceable(pk) == (hk == 8)
+    got = paged_attention_pallas(q, pk, pv, tbl, pos, interpret=True,
+                                 out_dtype=jnp.float32, **how)
+    tol = oracle_tol("paged_attention", "float32", "fwd")
+    assert float(np.abs(want).max()) > 32.0
+    assert _rel_err(got[live], want[live]) <= tol
+    cast = _windowed_truth(
+        q, pk, pv, np.asarray(tbl), np.asarray(pos), scale=q.shape[-1] ** -0.5,
+        weights=lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16),
+                                     np.float32), **how)
+    assert _rel_err(cast[live], want[live]) > tol
+
+
+@pytest.mark.parametrize("hk", _WEIGHT_FORMS)
+@pytest.mark.parametrize("w,group", _WEIGHT_ROWS)
+def test_paged_value_product_gives_garbage_no_weight(w, group, hk):
+    """The largest finite bfloat16 at every place of the pool no row's
+    mask lets through, in blocks the call does visit (tokens past a
+    row's position and under its lower bound, the rows ``pool_rows``
+    added) and in the trash block: the product sums over all of them, a
+    zero weight times garbage stays zero, so no bit of a live row moves
+    and a row with ``pos < 0`` beside live ones stays zeros."""
+    from paddle_tpu.kernels.paged_attention import (
+        _block_is_sliceable, paged_attention_pallas)
+
+    q, pk, pv, tbl, pos, how, want, live, unseen = _float32_weights_case(
+        w, group, hk, extra=1 if hk == 6 else 8)
+    assert _block_is_sliceable(pk) == (hk == 8)
+    assert bool(unseen.any())
+    base = paged_attention_pallas(
+        q, jnp.where(unseen, 0, pk), jnp.where(unseen, 0, pv), tbl, pos,
+        interpret=True, out_dtype=jnp.float32, **how)
+    sign = jnp.where(jnp.arange(pk.shape[1])[None, :, None, None] % 2 == 0,
+                     BF16_MAX, -BF16_MAX).astype(pk.dtype)
+    again = paged_attention_pallas(
+        q, jnp.where(unseen, sign, pk), jnp.where(unseen, -sign, pv), tbl,
+        pos, interpret=True, out_dtype=jnp.float32, **how)
+    assert bool(jnp.all(jnp.isfinite(again)))
+    assert bool(jnp.array_equal(base, again))
+    assert _rel_err(again[live], want[live]) <= oracle_tol(
+        "paged_attention", "float32", "fwd")
+    assert not np.asarray(again)[~live].any()
 
 
 def test_paged_defaults_lower_to_the_program_they_always_did():

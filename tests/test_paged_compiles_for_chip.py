@@ -50,7 +50,8 @@ GEOMETRIES = {
     "think_decode_full_plane": (48, 1, 64, 3073, 16, 128, 4, None),
     "think_decode_window_plane": (48, 1, 64, 3073, 16, 128, 4, 512),
     # more than one row a block folds the block once for all of them,
-    # scores as one MXU pass (PR 33): the widest windows `attend` streams
+    # scores as one MXU pass (PR 33), the values weighed in another (PR
+    # 35): the widest windows `attend` streams
     # (DENSE_WINDOW - 1 rows), lanes past one tile, the grid form
     "verify_window_5_rows": (24, 5, 24, 705, 16, 128, 1, None),
     "widest_streamed_window": (24, 7, 24, 705, 16, 128, 1, None),
@@ -63,6 +64,11 @@ GEOMETRIES = {
     "chat_moe_full_plane": (96, 1, 64, 6145, 8, 128, 6, None),
     # chains of up to 128 live blocks a window plane (40 slots x 6144)
     "group_6_window_4096_long_chains": (40, 1, 192, 7681, 8, 128, 6, 4096),
+    # both products of a block are MXU passes from two rows up (PR 35):
+    # 30 rows a block (240 sublanes of scores, the weights' three
+    # bfloat16 pieces 720), and a float32 pool, whose weights go whole
+    "chat_moe_under_a_verify_window": (96, 5, 64, 6145, 8, 128, 6, 4096),
+    "think_decode_float32_pool": (48, 1, 64, 3073, 16, 128, 4, None),
 }
 
 
@@ -76,7 +82,8 @@ def test_paged_kernel_compiles_for_v5e(geometry, one_chip):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = arg((blocks, 32, hk, dh), jnp.bfloat16)
+    pool = arg((blocks, 32, hk, dh),
+               jnp.float32 if "float32_pool" in geometry else jnp.bfloat16)
     assert _block_is_sliceable(pool) == ("grid_form" not in geometry)
     compiled = jax.jit(
         lambda *a: paged_attention_pallas(
