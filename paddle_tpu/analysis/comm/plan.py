@@ -34,8 +34,8 @@ from ..hlo_tools import (
     ALL_COLLECTIVES,
     GATHER_COLLECTIVES,
     REDUCE_COLLECTIVES,
-    _COMP_RE,
     _collective_bytes,
+    iter_instructions,
     loop_computations,
 )
 
@@ -71,7 +71,6 @@ _REPLICA_GROUPS_RE = re.compile(
     r"replica_groups=(\{.*?\}\}|\{\}|\[[0-9,]+\]<=\[[0-9,]+\]"
     r"(?:T\([0-9,]+\))?)")
 _SOURCE_TARGET_RE = re.compile(r"source_target_pairs=\{([^}]*(?:\},\{[^}]*)*)\}")
-_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 
 
 def _parse_replica_groups(text):
@@ -405,19 +404,13 @@ def extract_comm_plan(text, mesh=None, label=None):
             phase_label = tag
 
     ops = []
-    cur = None
-    for line in text.splitlines():
-        m = _COMP_RE.match(line)
-        if m:
-            cur = m.group(1)
-        head, _, meta = line.partition(" metadata=")
+    for ins in iter_instructions(text):
+        cur, head, op_name = ins.comp, ins.head, ins.op_name
         cm = _COLL_LINE_RE.search(head)
         if not cm:
             continue
         kind, is_start = cm.group(2), bool(cm.group(3))
         nbytes = _collective_bytes(cm.group(1), is_start)
-        op_name_m = _OP_NAME_RE.search(meta)
-        op_name = op_name_m.group(1) if op_name_m else ""
         chan_m = re.search(r"channel_id=(\d+)", head)
         axes = None
         if kind == "collective-permute":

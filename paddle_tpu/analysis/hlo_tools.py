@@ -10,11 +10,13 @@ MEMBERSHIP: a reduce op inside a while body executes once per loop
 iteration, one at top level executes once per step.
 """
 
+import collections
 import re
 
 __all__ = [
     "REDUCE_COLLECTIVES", "GATHER_COLLECTIVES", "ALL_COLLECTIVES",
     "hlo_comm_report", "comm_report", "loop_computations",
+    "iter_instructions", "called_computations",
     "compiled_memory_stats", "shape_pattern",
 ]
 
@@ -45,6 +47,54 @@ _COLL_RE = re.compile(
     r"=\s*(\(?[\w\[\]{},:*/() ]*?)\s*"
     r"\b(" + "|".join(_ALL_COLLECTIVES) + r")((?:-start)?)[.\d]*\(")
 _SHAPE_RE = re.compile(r"(\w+)\[([0-9,]*)\]")
+# ``[ROOT] %name = <shape> opcode(``: the opcode is the first word that
+# follows a space and opens a parenthesis (a tiling ``T(8,128)`` follows
+# a colon, a tuple shape's members follow ``(`` or ``, `` and end in
+# ``]`` or ``}``)
+_INSTR_RE = re.compile(
+    r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s([\w\-]+)\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+Instruction = collections.namedtuple(
+    "Instruction", "comp name shape opcode head op_name root")
+
+
+def iter_instructions(text):
+    """The one line walk over optimized HLO ``text``: an ``Instruction``
+    for every instruction of every computation, in the text's order.
+    ``comp`` is the computation the line belongs to, ``shape`` the
+    result's (layouts and all), ``head`` the line up to its
+    ``metadata=`` (operands and attributes, ``calls=`` / ``body=``
+    among them), ``op_name`` the metadata's (``""`` where the compiler
+    wrote none: copies and async starts it made itself), ``root``
+    whether the line is its computation's ``ROOT``.  The comm report,
+    the CommPlan extractor (``analysis.comm.plan``), the loop membership
+    walk and the device half of the span primitive
+    (``observability.trace.device_scopes``) all read the text through
+    this."""
+    cur = None
+    for line in text.splitlines():
+        m = _COMP_RE.match(line)
+        if m:
+            cur = m.group(1)
+            continue
+        head, _, meta = line.partition(" metadata=")
+        m = _INSTR_RE.match(head)
+        if not m:
+            continue
+        om = _OP_NAME_RE.search(meta)
+        yield Instruction(cur, m.group(2), m.group(3), m.group(4), head,
+                          om.group(1) if om else "", bool(m.group(1)))
+
+
+def called_computations(head):
+    """Names of the computations an instruction's ``head`` refers to
+    (``calls=`` / ``to_apply=`` / ``body=`` / ``condition=`` /
+    ``branch_computations=``)."""
+    refs = list(_CALL_RE.findall(head))
+    for grp in _BRANCH_RE.findall(head):
+        refs += [ref.strip().lstrip("%") for ref in grp.split(",")]
+    return refs
 
 
 def _shape_bytes_list(text):
@@ -85,17 +135,10 @@ def loop_computations(text):
     bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
     bodies |= set(re.findall(r"condition=%?([\w.\-]+)", text))
     edges = {}
-    cur = None
-    for line in text.splitlines():
-        m = _COMP_RE.match(line)
-        if m:
-            cur = m.group(1)
-        head = line.split(" metadata=", 1)[0]
-        for ref in _CALL_RE.findall(head):
-            edges.setdefault(cur, set()).add(ref)
-        for grp in _BRANCH_RE.findall(head):
-            for ref in grp.split(","):
-                edges.setdefault(cur, set()).add(ref.strip().lstrip("%"))
+    for ins in iter_instructions(text):
+        refs = called_computations(ins.head)
+        if refs:
+            edges.setdefault(ins.comp, set()).update(refs)
     in_loop = set()
     frontier = list(bodies)
     while frontier:
@@ -129,19 +172,14 @@ def hlo_comm_report(text):
     # loop membership via the shared call-graph walk (a collective
     # inside a computation CALLED from a while body counts as in-loop)
     in_loop = loop_computations(text)
-    cur = None
     colls = []  # (kind, bytes, computation)
-    for line in text.splitlines():
-        m = _COMP_RE.match(line)
-        if m:
-            cur = m.group(1)
-        head = line.split(" metadata=", 1)[0]
-        cm = _COLL_RE.search(head)
+    for ins in iter_instructions(text):
+        cm = _COLL_RE.search(ins.head)
         if cm:
             colls.append((cm.group(2),
                           _collective_bytes(cm.group(1),
                                             bool(cm.group(3))),
-                          cur))
+                          ins.comp))
 
     report = {
         "collective_ops": {},

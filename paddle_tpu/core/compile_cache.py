@@ -13,6 +13,16 @@ directory that moves between runs never hits.  Every executable is kept,
 however quick its compile, so that what a run leaves behind does not
 depend on a timing.
 
+What is NOT in the cache's key: the ``op_name`` metadata, so neither
+the named scopes a model enters (``observability.trace.KINDS``) nor the
+source lines (``jax_compilation_cache_include_metadata_in_key`` is off,
+JAX's default, and nothing here turns it on: with it every moved line
+would be a cold start).  A tree that only renames or adds scopes loads
+what the tree before it compiled, with THAT tree's scopes in its
+``as_text()``: ``trace.device_scopes()`` then names what the older tree
+named, the ``*.unnamed_busy_share`` metrics read high, and the cure is a
+cold cache (another ``JAX_COMPILATION_CACHE_DIR``), not a knob.
+
 A process pinned to the CPU (``JAX_PLATFORMS=cpu``: the tests) gets no
 cache from here: XLA:CPU executables are cheap to rebuild, and its AOT
 loader logs a machine-feature error on every cache hit.

@@ -235,7 +235,11 @@ def _remat_segment(seg_fn, env, param_names=()):
         env2.update(zip(fkeys, env_f))
         ct2 = dict(ct)
         ct2.update(zip(ckeys, ct_f))
-        _, vjp_fn = jax.vjp(seg_fn, env2)
+        # this re-trace IS the recompute, and JAX writes no remat marker
+        # for it (the wrapper is a custom_vjp, not jax.checkpoint): the
+        # scope says so to whoever reads the device's seconds by phase
+        with jax.named_scope(_trace.RECOMPUTE_SCOPE):
+            _, vjp_fn = jax.vjp(seg_fn, env2)
         (denv,) = vjp_fn(ct2)
         # Tie the outgoing activation cotangents to this segment's weight
         # gradients with a REAL data dependency.  Without it XLA defers
@@ -446,20 +450,26 @@ def run_block_ops(ctx, block, ops, env, inside_grad_prefix=False):
                 n for names in op.outputs.values() for n in names
                 if n in act_specs)
         try:
-            if pin_names:
-                # the pt_shard[vars] scope wraps the WHOLE lowering of
-                # the producing op (not just the constraint): GSPMD
-                # attaches its reshard collectives to these ops'
-                # metadata, which is the provenance the comm analyzer
-                # attributes reshards by.  ALL annotated outputs join
-                # the scope name — provenance matching is a regex
-                # search, so a forbid_reshard pattern on any of them
-                # still fires.
-                with jax.named_scope(
-                        f"pt_shard[{','.join(pin_names)}]"):
+            # every op lowers under the scope of its kind, type and the
+            # name the model gave its layer (the reference's per-op
+            # RecordEvent): op_name metadata, which
+            # observability.trace.device_scopes reads back
+            with _trace.op_scope(op.type,
+                                 next(iter(op.output_names()), "")):
+                if pin_names:
+                    # the pt_shard[vars] scope wraps the WHOLE lowering
+                    # of the producing op (not just the constraint):
+                    # GSPMD attaches its reshard collectives to these
+                    # ops' metadata, which is the provenance the comm
+                    # analyzer attributes reshards by.  ALL annotated
+                    # outputs join the scope name — provenance matching
+                    # is a regex search, so a forbid_reshard pattern on
+                    # any of them still fires.
+                    with jax.named_scope(
+                            f"pt_shard[{','.join(pin_names)}]"):
+                        outs = impl.call(ins, attrs, ctx)
+                else:
                     outs = impl.call(ins, attrs, ctx)
-            else:
-                outs = impl.call(ins, attrs, ctx)
         except Exception as e:
             raise RuntimeError(f"error lowering {op}: {e}") from e
         outs = outs or {}
@@ -560,6 +570,9 @@ class Executor:
         t0 = time.perf_counter()
         compiled = jitted.lower(*args).compile()
         dt = time.perf_counter() - t0
+        # the device half of the span primitive: the executable, for
+        # whoever asks which sub-layer an instruction belongs to
+        _trace.register_executable(label, compiled)
         kernel_backends = _kreg.selected_backends()
         reg.counter(
             "executor.compile_count",

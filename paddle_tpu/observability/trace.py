@@ -62,13 +62,37 @@ push the last burst out of the bounded buffer.
 The event buffer is bounded (``PADDLE_TPU_TRACE_EVENTS``, default
 100k); when full the oldest events drop and ``tracer.dropped`` counts
 them — a flight recorder keeps the most recent window, not the warmup.
+
+**The device half.**  A span inside a jitted program is a
+``jax.named_scope``: it costs nothing at run time and reaches the
+compiled executable as the ``op_name`` metadata of every instruction
+made under it.  The sub-layers of a model are named from ONE vocabulary
+(``KINDS``), where the model is written: ``sublayer(kind)`` on the
+serving path (``serving/arch.py``, ``serving/batched_decode.py``, under
+``STACK_SCOPE``), ``op_scope(op_type, name)`` round every op the
+Executor lowers (the reference's per-op ``RecordEvent``; ``OP_KINDS``
+and ``NAME_KINDS`` map an op to its kind).  The device trace of this
+runtime carries the HLO instruction and not its ``op_name``, so every
+compile site hands its executable to ``register_executable(label,
+compiled)``: one append, nothing parsed.  ``device_scopes()`` builds,
+when someone asks and once per executable, the map from instruction to
+``DeviceScope(kind, phase, path, shape, mixed)`` out of
+``compiled.as_text()``; ``device_seconds_by_scope(xplane_path)`` joins a
+profiler trace to it by (module, instruction) and returns the device's
+seconds under the names the program gave them, with what it could not
+name.  ``docs/observability.md`` ("The device's seconds by sub-layer")
+has the vocabulary and the join's rules.
 """
 
+import bisect
+import collections
 import json
 import os
+import re
 import threading
 import time
 
+import jax
 from jax.profiler import TraceAnnotation as _Annotation
 
 from . import metrics as _metrics
@@ -76,6 +100,10 @@ from . import metrics as _metrics
 __all__ = [
     "Tracer", "get_tracer", "set_tracer", "tracing_enabled",
     "span", "instant", "add_span", "save", "clear",
+    "KINDS", "PHASES", "STACK_SCOPE", "RECOMPUTE_SCOPE", "sublayer",
+    "op_scope", "kind_of_op", "DeviceScope", "register_executable",
+    "device_scopes", "scopes_of_hlo", "join_device_ops",
+    "device_seconds_by_scope",
 ]
 
 # span durations aggregate under the SAME namespace as profiler.timer
@@ -342,7 +370,14 @@ class Tracer:
             meta.append({"ph": "M", "name": "thread_name",
                          "pid": self._pid, "tid": tid,
                          "args": {"name": label}})
-        return {"traceEvents": meta + evs, "displayTimeUnit": "ms"}
+        obj = {"traceEvents": meta + evs, "displayTimeUnit": "ms"}
+        scopes = device_scopes()
+        if scopes:
+            # the map from HLO instruction to named scope of what this
+            # process compiled, so that a saved timeline can be joined
+            # to a device trace later (join_device_ops reads it back)
+            obj["metadata"] = {"device_scopes": scopes}
+        return obj
 
     def save(self, path):
         """Write Chrome-trace JSON; returns the event count (metadata
@@ -404,3 +439,365 @@ def save(path):
 
 def clear():
     return get_tracer().clear()
+
+
+# -- the device half: named scopes, and the map from instruction to scope --
+# the jax.named_scope every architecture's stack runs under on the
+# serving path (stack vs. embedding, head and argmax)
+STACK_SCOPE = "serving.stack_pass"
+# the scope the Executor's own checkpoint equivalent re-traces a segment
+# under inside its backward, where JAX writes no remat marker
+RECOMPUTE_SCOPE = "recompute"
+# the sub-layer kinds, the ONE vocabulary both paths name their work from
+KINDS = (
+    "embed",        # token and position tables
+    "norm",         # LayerNorm / RMSNorm round a sub-layer
+    "attn.proj",    # q/k/v/out projections, attention gates, rotary
+    "attn.core",    # the paged or dense attention call and what the
+                    # architecture does to its output (differential
+                    # lambda arithmetic, sub-layer norm, head gate)
+    "mixer",        # Mamba, gated memory unit: projections, convolution,
+                    # recurrence
+    "ffn",          # dense FFN / gated MLP
+    "moe.route",    # router scores, top-k, sort and gather
+    "moe.experts",  # the grouped products and the weighted scatter back
+    "moe.shared",   # the shared expert
+    "head",         # final norm, logits, argmax or the fused CE
+    "cache",        # K/V writes into the pool, table gathers, state rows
+    "optimizer",    # the update ops
+)
+PHASES = ("forward", "backward", "recompute")
+_KIND_SET = frozenset(KINDS)
+
+
+def sublayer(kind):
+    """``jax.named_scope(kind)`` for a kind of the vocabulary: what a
+    model enters at a sub-layer boundary.  Metadata only: nothing is
+    read at run time and the compiled program is the same."""
+    if kind not in _KIND_SET:
+        raise ValueError(f"{kind!r} is not a sub-layer kind: {KINDS}")
+    return jax.named_scope(kind)
+
+
+# (op type -> kind) and (marker in the layer's name -> kind) for the
+# Program path.  A name decides before a type where it says more (the
+# final LayerNorm and the head's matmul are the head's), else the type,
+# else the markers models/transformer.py gives its layers.
+OP_KINDS = {
+    "layer_norm": "norm", "lookup_table": "embed",
+    "flash_attention": "attn.core", "flash_attention_packed": "attn.core",
+    "fused_softmax_ce_head": "head", "softmax_with_cross_entropy": "head",
+    **dict.fromkeys((
+        "sgd", "momentum", "adagrad", "adam", "adamax", "adadelta",
+        "decayed_adagrad", "rmsprop", "ftrl", "proximal_gd",
+        "proximal_adagrad"), "optimizer"),
+}
+NAME_KINDS = {"ln_f": "head", "lm_head": "head"}
+NAME_MARKERS = (("_att_", "attn.proj"), ("_ffn", "ffn"),
+                ("tok_emb", "embed"), ("pos_emb", "embed"))
+
+
+def kind_of_op(op_type, layer):
+    """The kind of one Program op, from its type and the name the model
+    gave its layer (``block3_ffn1``, ``ln_f``, ``lm_head``); None for
+    an op outside every kind (a reshape, a residual add)."""
+    kind = NAME_KINDS.get(layer) or OP_KINDS.get(op_type)
+    return kind or next(
+        (k for marker, k in NAME_MARKERS if marker in layer), None)
+
+
+def op_scope(op_type, out_name):
+    """The named scope one Program op lowers under: its kind (where it
+    has one), then ``<op type>:<layer>``, the layer being the op's first
+    output less its ``.tmp_N`` (``mul:block3_ffn1``, ``adam:lm_head.w``).
+    Backward and recompute need none of their own: JAX wraps the scope
+    in ``transpose(jvp(...))`` and puts ``rematted_computation`` beside
+    it."""
+    layer = out_name.split(".tmp_")[0]
+    kind = kind_of_op(op_type, layer)
+    label = f"{op_type}:{layer}"
+    return jax.named_scope(f"{kind}/{label}" if kind else label)
+
+
+DeviceScope = collections.namedtuple(
+    "DeviceScope", "kind phase path shape mixed")
+DeviceScope.__doc__ = """What the program said of one HLO instruction:
+``kind`` (of ``KINDS``; None for a named scope outside every kind),
+``phase`` (of ``PHASES``; None where the compiler wrote no ``op_name``:
+its own copies and async starts), ``path`` (the ``op_name`` less the
+primitive), ``shape`` (the result's, layouts dropped: tells two
+executables of one module name apart) and ``mixed`` (the OTHER kinds a
+fusion's body spans)."""
+
+# the executables compiled in this process, newest last: (label,
+# compiled, parsed map or None).  Bounded like the event buffer: a
+# flight recorder keeps the recent ones, and a process that compiles
+# thousands (the tests) keeps no more than these alive.
+MAX_EXECUTABLES = 64
+_executables = collections.deque(maxlen=MAX_EXECUTABLES)
+
+_WRAPPER_RE = re.compile(r"[\w.\-]+\(|\)")
+_LAYOUT_RE = re.compile(r"\{[^{}]*\}")
+# ``%name`` references of an instruction's operands, once the
+# computations it calls are taken out of its text
+_REF_RE = re.compile(r"%([\w.\-]+)")
+_CALLED_RE = re.compile(
+    r"\b(?:calls|body|condition|to_apply)=%?[\w.\-]+"
+    r"|branch_computations=\{[^}]*\}")
+# an ``XLA Ops`` event's name: ``%name = <shape> opcode(``
+_TRACE_OP_RE = re.compile(r"^%?([\w.\-]+) = (.*?) [\w\-]+\(")
+_HOLDERS = frozenset(("while", "conditional", "call"))
+# what does a fusion's work: a matrix unit or a kernel, then a
+# reduction or data movement, then elementwise; what computes nothing
+_OPCODE_RANK = {
+    **dict.fromkeys(("dot", "convolution", "custom-call"), 3),
+    **dict.fromkeys(("reduce", "reduce-window", "scatter", "gather",
+                     "sort", "select-and-scatter", "dynamic-slice",
+                     "dynamic-update-slice"), 2),
+    **dict.fromkeys(("parameter", "constant", "tuple", "get-tuple-element",
+                     "bitcast"), 0),
+}
+
+
+def register_executable(label, compiled):
+    """Keep ``compiled`` (a ``jax.stages.Compiled``) for
+    ``device_scopes``: a reference to the compiled object and nothing
+    else (no argument, pool, weight or engine), one append a compile.
+    Nothing is read of it until someone asks."""
+    _executables.append([str(label), compiled, None])
+
+
+def _classify(op_name):
+    """``(kind, phase, path)`` of an ``op_name``."""
+    if not op_name:
+        return None, None, ""
+    raw = op_name.split("/")
+    parts = [_WRAPPER_RE.sub("", c) for c in raw]
+    kind = next((c for c in reversed(parts) if c in _KIND_SET), None)
+    if RECOMPUTE_SCOPE in parts or "rematted_computation" in parts:
+        phase = "recompute"
+    elif "transpose(" in op_name:
+        phase = "backward"
+    else:
+        phase = "forward"
+    return kind, phase, "/".join(raw[:-1])
+
+
+def scopes_of_hlo(text):
+    """``(module name, {instruction name: DeviceScope})`` of optimized
+    HLO ``text``: every instruction the device trace can show (those of
+    the entry, loop bodies, branches and called computations; not the
+    insides of a fusion or of a reducer).
+
+    A fusion takes the scope of the instruction inside it that does the
+    work: a ``dot``, ``convolution`` or ``custom-call`` before a
+    reduction or a gather/scatter before elementwise, the root on a tie,
+    the first that has a kind; ``mixed`` lists the other kinds its body
+    spans.  ``while``, ``conditional`` and ``call`` hold others and have
+    no kind of their own: their self seconds are the loop's own
+    bookkeeping.  A Mosaic custom call keeps its kernel's name as the
+    instruction's and takes the scope it was called under.  An
+    instruction the COMPILER made and gave no ``op_name`` (a layout
+    copy, an async start and its done, a dot it rewrote) takes the scope
+    of the first instruction of its computation that uses its result,
+    else of the first whose result it uses, else of the ``while`` or
+    ``call`` that holds its computation, and says so at the end of its
+    ``path`` (``<- %user``); one with none of these stays unnamed.  The
+    walk is ``analysis.hlo_tools.iter_instructions``, the comm plan's."""
+    from ..analysis.hlo_tools import called_computations, iter_instructions
+
+    m = re.match(r"HloModule\s+([\w.\-]+)", text)
+    module = m.group(1) if m else ""
+    comps, inner, holder = {}, set(), {}
+    for ins in iter_instructions(text):
+        comps.setdefault(ins.comp, []).append(ins)
+        if ins.opcode == "fusion" or "to_apply=" in ins.head:
+            inner.update(called_computations(ins.head))
+        elif ins.opcode in _HOLDERS:
+            holder.update(dict.fromkeys(called_computations(ins.head), ins))
+    out = {}
+    for comp, instrs in comps.items():
+        if comp in inner:
+            continue
+        for ins in instrs:
+            if not _OPCODE_RANK.get(ins.opcode, 1):
+                continue    # computes nothing: never an event of a trace
+            kind, phase, path = _classify(ins.op_name)
+            mixed = ()
+            if ins.opcode in _HOLDERS:
+                kind = None
+            elif ins.opcode == "fusion":
+                body = [b for c in called_computations(ins.head)
+                        for b in comps.get(c, ())]
+                ranked = sorted(
+                    ((_OPCODE_RANK.get(b.opcode, 1), b.root,
+                      _classify(b.op_name)) for b in body),
+                    key=lambda r: (-r[0], not r[1]))
+                named = [r[2] for r in ranked if r[0] and r[2][0]]
+                if named:
+                    kind, phase, path = named[0]
+                    mixed = tuple(sorted({n[0] for n in named} - {kind}))
+                elif not ins.op_name:
+                    # the fusion's own line says nothing: any name at all
+                    kind, phase, path = next(
+                        (r[2] for r in ranked if r[2][1]), (None, None, ""))
+            out[ins.name] = DeviceScope(
+                kind, phase, path, _LAYOUT_RE.sub("", ins.shape), mixed)
+        _adopt_unnamed(instrs, out)
+    for comp, ins in holder.items():
+        kind, phase, path = _classify(ins.op_name)
+        if phase is not None:
+            for i in comps.get(comp, ()):
+                if i.name in out and out[i.name].phase is None:
+                    out[i.name] = out[i.name]._replace(
+                        kind=kind, phase=phase, path=f"{path} <- %{ins.name}")
+    return module, out
+
+
+def _adopt_unnamed(instrs, out):
+    """Give the instructions of one computation that have no ``op_name``
+    the scope of a neighbour (``scopes_of_hlo``): users first, then
+    operands, through chains of unnamed ones (a start, its done, the
+    fusion that reads it)."""
+    operands = {i.name: [r for r in _REF_RE.findall(
+        _CALLED_RE.sub("", i.head.split(" = ", 1)[-1]))
+        if r != i.name] for i in instrs if i.name in out}
+    users = {}
+    for name, refs in operands.items():
+        for r in refs:
+            users.setdefault(r, []).append(name)
+    unnamed = [n for n in operands if out[n].phase is None]
+    for _ in range(4):
+        left = []
+        for n in unnamed:
+            near = next((m for m in users.get(n, []) + operands[n]
+                         if m in out and out[m].phase is not None), None)
+            if near is None:
+                left.append(n)
+                continue
+            src = out[near]
+            out[n] = out[n]._replace(
+                kind=src.kind, phase=src.phase,
+                path=f"{src.path.split(' <- ')[0]} <- %{near}")
+        if len(left) == len(unnamed):
+            break
+        unnamed = left
+
+
+def device_scopes():
+    """``[{"label", "module", "instructions": {name: DeviceScope}}]`` for
+    the executables registered in this process, oldest first.  LAZY and
+    cached: ``compiled.as_text()`` is read and parsed on the first call
+    after a compile, once per executable; compiling and running steps
+    build nothing."""
+    out = []
+    for entry in list(_executables):
+        if entry[2] is None:
+            module, instructions = scopes_of_hlo(entry[1].as_text() or "")
+            entry[2] = {"label": entry[0], "module": module,
+                        "instructions": instructions}
+        out.append(entry[2])
+    return out
+
+
+def join_device_ops(chips, scopes):
+    """Join device events to a scope map.
+
+    ``chips`` is ``[(modules, ops)]``, a pair per chip: ``modules``
+    ``[(start, end, name)]`` from the trace's ``XLA Modules`` line (the
+    name ends in ``(<fingerprint>)``), ``ops`` ``[(start, end, text)]``
+    from its ``XLA Ops`` line, ``text`` the whole HLO instruction (``%name
+    = shape opcode(...)``); times in any one unit.  ``scopes`` is what
+    ``device_scopes()`` returns (or that, read back from JSON).
+
+    An op belongs to the module event that contains its start; the
+    executable is the one of that module name (where several share a
+    name, as the prefill widths do, the one whose instructions agree
+    with most of the event's by name AND shape).  Seconds are SELF
+    seconds: an op's duration less the ops it holds, so a loop is not
+    counted with its body.  Returns ``{"seconds": {(module, kind,
+    phase): t}, "unnamed": {(module, instruction): t}, "ops": {(module,
+    instruction): (t, kind, phase, path)}, "total": t}`` in
+    the unit given, averaged over the chips: ``kind`` None is work under
+    a named scope outside every kind, ``unnamed`` what has no scope or no
+    match in the map (the instrument's blind share); everything sums to
+    ``total``, the chips' busy time.  An executable in whose map NO
+    instruction has a kind was loaded from a compile cache that a tree
+    without the vocabulary wrote (scope names are not in the cache's
+    key): all its seconds are unnamed."""
+    by_module = {}
+    for entry in scopes:
+        table = entry["instructions"]
+        if any(s[0] for s in table.values()):
+            by_module.setdefault(entry["module"], []).append(table)
+    seconds = collections.Counter()
+    unnamed = collections.Counter()
+    named = {}
+    n = max(1, len(chips))
+    for modules, ops in chips:
+        modules = sorted(modules)
+        starts = [m[0] for m in modules]
+        # self time, as chipbench/trace_reduce.py takes it
+        rows, stack = [], []
+        for start, end, text in sorted(ops, key=lambda e: (e[0], -e[1])):
+            row = [text, end - start, start]
+            while stack and stack[-1][0] <= start:
+                stack.pop()
+            if stack:
+                stack[-1][1][1] -= end - start
+            stack.append((end, row))
+            rows.append(row)
+        per_event = {}
+        for text, self_t, start in rows:
+            i = bisect.bisect_right(starts, start) - 1
+            inside = i >= 0 and start < modules[i][1]
+            m = _TRACE_OP_RE.match(text)
+            name, shape = ((m.group(1), _LAYOUT_RE.sub("", m.group(2)))
+                           if m else (text[:80], ""))
+            per_event.setdefault(modules[i][2] if inside else "", []).append(
+                (name, shape, self_t))
+        for event, items in per_event.items():
+            module = event.split("(")[0]
+            candidates = by_module.get(module, ())
+            table = max(candidates, default={}, key=lambda c: sum(
+                1 for name, shape, _ in items
+                if name in c and c[name][3] == shape))
+            for name, _shape, self_t in items:
+                scope = table.get(name)
+                if scope is None or scope[1] is None:
+                    unnamed[(module, name)] += self_t / n
+                else:
+                    seconds[(module, scope[0],
+                             scope[1])] += self_t / n
+                    was = named.get((module, name), (0.0,))[0]
+                    named[(module, name)] = (was + self_t / n, *scope[:3])
+    return {"seconds": dict(seconds), "unnamed": dict(unnamed),
+            "ops": named,
+            "total": sum(seconds.values()) + sum(unnamed.values())}
+
+
+def device_seconds_by_scope(xplane_path, scopes=None):
+    """The device's seconds in a profiler trace (``.xplane.pb``, from a
+    ``jax.profiler`` session round a live engine or training loop) under
+    the names the program gave its sub-layers: ``join_device_ops`` over
+    every TPU plane of the trace and ``scopes`` (default: this
+    process's ``device_scopes()``), in seconds."""
+    from jax.profiler import ProfileData
+
+    chips = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not re.fullmatch(r"/device:TPU:\d+", plane.name):
+            continue
+        lines = {line.name: line for line in plane.lines}
+        if "XLA Ops" not in lines:
+            continue
+        ops = [(e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9,
+                e.name) for e in lines["XLA Ops"].events]
+        modules = [(e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9,
+                    e.name) for e in
+                   (lines["XLA Modules"].events
+                    if "XLA Modules" in lines else ())]
+        if ops:
+            chips.append((modules, ops))
+    return join_device_ops(
+        chips, device_scopes() if scopes is None else scopes)

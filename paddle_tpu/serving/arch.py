@@ -84,13 +84,14 @@ import numpy as np
 
 from ..kernels import paged_attention as _paged
 from ..kernels.grouped_matmul import grouped_matmul as _grouped_matmul
+# the named scopes of the lowered program (op_name metadata, read by
+# ``observability.trace.device_scopes``): the entry points run a stack
+# under STACK_SCOPE, and every architecture enters ``sublayer(kind)`` at
+# its sub-layer boundaries, the kinds from trace.KINDS
+from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
            "route", "STACK_SCOPE"]
-
-# the jax.named_scope every architecture's stack runs under (op_name
-# metadata of the lowered program: stack vs. embedding, head and argmax)
-STACK_SCOPE = "serving.stack_pass"
 
 
 class Architecture:
@@ -244,30 +245,40 @@ class Gpt2(Architecture):
                 f"embedding table ({table_len} positions)")
 
     def embed(self, p, toks, pos):
-        return p["tok_emb.w"][toks] + p["pos_emb.w.w"][pos]
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks] + p["pos_emb.w.w"][pos]
 
     def stack(self, p, x, pos, planes, attend):
         eps = self.eps
         for i in range(self.n_layer):
             w = lambda nm: p[f"block{i}_{nm}"]
-            h = _ln(x, w("ln1.scale"), w("ln1.bias"), eps)
-            q = h @ w("att_q.w") + w("att_q.b")
-            k = h @ w("att_k.w") + w("att_k.b")
-            v = h @ w("att_v.w") + w("att_v.b")
-            ctx, planes = attend(planes, i, 0, self.heads(q),
-                                 self.heads(k), self.heads(v))
-            x = x + ctx.reshape(x.shape) @ w("att_out.w") + w("att_out.b")
-            h2 = _ln(x, w("ln2.scale"), w("ln2.bias"), eps)
-            # exact erf gelu, matching transformer.generate and the gelu op
-            ff = jax.nn.gelu(h2 @ w("ffn1.w") + w("ffn1.b"),
-                             approximate=False)
-            x = x + ff @ w("ffn2.w") + w("ffn2.b")
+            with sublayer("norm"):
+                h = _ln(x, w("ln1.scale"), w("ln1.bias"), eps)
+            with sublayer("attn.proj"):
+                q = h @ w("att_q.w") + w("att_q.b")
+                k = h @ w("att_k.w") + w("att_k.b")
+                v = h @ w("att_v.w") + w("att_v.b")
+            with sublayer("attn.core"):
+                ctx, planes = attend(planes, i, 0, self.heads(q),
+                                     self.heads(k), self.heads(v))
+            with sublayer("attn.proj"):
+                x = (x + ctx.reshape(x.shape) @ w("att_out.w")
+                     + w("att_out.b"))
+            with sublayer("norm"):
+                h2 = _ln(x, w("ln2.scale"), w("ln2.bias"), eps)
+            with sublayer("ffn"):
+                # exact erf gelu, matching transformer.generate and the
+                # gelu op
+                ff = jax.nn.gelu(h2 @ w("ffn1.w") + w("ffn1.b"),
+                                 approximate=False)
+                x = x + ff @ w("ffn2.w") + w("ffn2.b")
         return x, planes
 
     def head(self, p, x):
-        x = _ln(x, p["ln_f.scale"], p["ln_f.bias"], self.eps)
-        return jnp.matmul(x, p["lm_head.w"],
-                          preferred_element_type=jnp.float32)
+        with sublayer("head"):
+            x = _ln(x, p["ln_f.scale"], p["ln_f.bias"], self.eps)
+            return jnp.matmul(x, p["lm_head.w"],
+                              preferred_element_type=jnp.float32)
 
 
 def _rms(x, scale, eps):
@@ -351,10 +362,12 @@ class LoopedRmsRope(Architecture):
                              f"{', '.join(missing)}")
 
     def embed(self, p, toks, pos):
-        return p["tok_emb.w"][toks]
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks]
 
     def _angles(self, pos):
-        return _rope_angles(pos, self.head_dim, self.rope_theta)
+        with sublayer("attn.proj"):
+            return _rope_angles(pos, self.head_dim, self.rope_theta)
 
     def one_pass(self, p, i_pass, x, rope, planes, attend):
         """The ``n_layer`` blocks and the closing norm, once."""
@@ -362,17 +375,27 @@ class LoopedRmsRope(Architecture):
         cos, sin = rope
         for i in range(self.n_layer):
             w = lambda nm: p[f"block{i}_{nm}"]
-            a = _rms(x, w("norm1.scale"), eps)
-            q = _rope(self.heads(a @ w("att_q.w")), cos, sin)
-            k = _rope(self.heads(a @ w("att_k.w")), cos, sin)
-            v = self.heads(a @ w("att_v.w"))
-            ctx, planes = attend(planes, i, i_pass, q, k, v)
-            x = x + _rms(ctx.reshape(x.shape) @ w("att_out.w"),
-                         w("norm2.scale"), eps)
-            m = _rms(x, w("norm3.scale"), eps)
-            ff = jax.nn.silu(m @ w("ffn_gate.w")) * (m @ w("ffn_up.w"))
-            x = x + _rms(ff @ w("ffn_down.w"), w("norm4.scale"), eps)
-        return _rms(x, p["norm_f.scale"], eps), planes
+            with sublayer("norm"):
+                a = _rms(x, w("norm1.scale"), eps)
+            with sublayer("attn.proj"):
+                q = _rope(self.heads(a @ w("att_q.w")), cos, sin)
+                k = _rope(self.heads(a @ w("att_k.w")), cos, sin)
+                v = self.heads(a @ w("att_v.w"))
+            with sublayer("attn.core"):
+                ctx, planes = attend(planes, i, i_pass, q, k, v)
+            with sublayer("attn.proj"):
+                o = ctx.reshape(x.shape) @ w("att_out.w")
+            with sublayer("norm"):
+                x = x + _rms(o, w("norm2.scale"), eps)
+                m = _rms(x, w("norm3.scale"), eps)
+            with sublayer("ffn"):
+                ff = (jax.nn.silu(m @ w("ffn_gate.w"))
+                      * (m @ w("ffn_up.w"))) @ w("ffn_down.w")
+            with sublayer("norm"):
+                x = x + _rms(ff, w("norm4.scale"), eps)
+        with sublayer("norm"):
+            # the final norm closes every pass and feeds the next
+            return _rms(x, p["norm_f.scale"], eps), planes
 
     def stack(self, p, x, pos, planes, attend):
         rope = self._angles(pos)
@@ -385,8 +408,9 @@ class LoopedRmsRope(Architecture):
 
     def head(self, p, x):
         # the final norm already closed the last pass
-        return jnp.matmul(x, p["lm_head.w"],
-                          preferred_element_type=jnp.float32)
+        with sublayer("head"):
+            return jnp.matmul(x, p["lm_head.w"],
+                              preferred_element_type=jnp.float32)
 
 
 class SambaY(Architecture):
@@ -526,7 +550,8 @@ class SambaY(Architecture):
                              f"{', '.join(missing)}")
 
     def embed(self, p, toks, pos):
-        return p["tok_emb.w"][toks]          # no positional encoding
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks]      # no positional encoding
 
     # -- the mixers -------------------------------------------------------
     def _mamba(self, w, h, planes, cache, n_state):
@@ -593,48 +618,58 @@ class SambaY(Architecture):
         d, dh = self.d_model, self.head_dim
         kv = self.kv_heads * dh
         lead = h.shape[:-1]
-        if own:
-            qkv = h @ w("att_qkv.w") + w("att_qkv.b")
-            q = qkv[..., :d]
-            k = qkv[..., d:d + kv].reshape(*lead, self.kv_heads // 2, 2 * dh)
-            v = qkv[..., d + kv:].reshape(*lead, self.kv_heads // 2, 2 * dh)
-        else:
-            q, k, v = h @ w("att_q.w") + w("att_q.b"), None, None
         pairs = self.n_head // 2
-        # (q1_a | q2_a) -> the rows (q1_a | 0) and (0 | q2_a)
-        lane_half = jnp.arange(2 * dh) // dh == jnp.arange(2)[:, None]
-        rows = jnp.where(lane_half, q.reshape(*lead, pairs, 1, 2 * dh), 0)
-        ctx, planes = cache(
-            planes, plane, 0, rows.reshape(*lead, 2 * pairs, 2 * dh), k, v,
-            group=self.rows_per_entry, window=window,
-            scale=dh ** -0.5, out_dtype=f32)
-        ctx = ctx.reshape(*lead, pairs, 2, 2 * dh)
-        lam0 = 0.8 - 0.6 * float(np.exp(-0.3 * i))
-        lam = (jnp.exp(jnp.sum(w("att_lambda_q1.w").astype(f32)
-                               * w("att_lambda_k1.w").astype(f32)))
-               - jnp.exp(jnp.sum(w("att_lambda_q2.w").astype(f32)
-                                 * w("att_lambda_k2.w").astype(f32)))
-               + lam0)
-        o = ctx[..., 0, :] - lam * ctx[..., 1, :]
-        o = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
-                                        keepdims=True) + self.eps)
-             * w("att_subln.scale").astype(f32) * (1.0 - lam0))
-        return (o.reshape(*lead, d).astype(h.dtype) @ w("att_out.w")
-                + w("att_out.b")), planes
+        with sublayer("attn.proj"):
+            if own:
+                qkv = h @ w("att_qkv.w") + w("att_qkv.b")
+                q = qkv[..., :d]
+                k = qkv[..., d:d + kv].reshape(
+                    *lead, self.kv_heads // 2, 2 * dh)
+                v = qkv[..., d + kv:].reshape(
+                    *lead, self.kv_heads // 2, 2 * dh)
+            else:
+                q, k, v = h @ w("att_q.w") + w("att_q.b"), None, None
+            # (q1_a | q2_a) -> the rows (q1_a | 0) and (0 | q2_a)
+            lane_half = jnp.arange(2 * dh) // dh == jnp.arange(2)[:, None]
+            rows = jnp.where(lane_half,
+                             q.reshape(*lead, pairs, 1, 2 * dh), 0)
+        with sublayer("attn.core"):
+            ctx, planes = cache(
+                planes, plane, 0, rows.reshape(*lead, 2 * pairs, 2 * dh),
+                k, v, group=self.rows_per_entry, window=window,
+                scale=dh ** -0.5, out_dtype=f32)
+            ctx = ctx.reshape(*lead, pairs, 2, 2 * dh)
+            lam0 = 0.8 - 0.6 * float(np.exp(-0.3 * i))
+            lam = (jnp.exp(jnp.sum(w("att_lambda_q1.w").astype(f32)
+                                   * w("att_lambda_k1.w").astype(f32)))
+                   - jnp.exp(jnp.sum(w("att_lambda_q2.w").astype(f32)
+                                     * w("att_lambda_k2.w").astype(f32)))
+                   + lam0)
+            o = ctx[..., 0, :] - lam * ctx[..., 1, :]
+            o = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                            keepdims=True) + self.eps)
+                 * w("att_subln.scale").astype(f32) * (1.0 - lam0))
+        with sublayer("attn.proj"):
+            return (o.reshape(*lead, d).astype(h.dtype) @ w("att_out.w")
+                    + w("att_out.b")), planes
 
     def stack(self, p, x, pos, planes, attend):
         eps, memory = self.eps, None
         for i, kind in enumerate(self.kinds):
             w = lambda nm: p[f"block{i}_{nm}"]
-            h = _ln(x, w("ln1.scale"), w("ln1.bias"), eps)
+            with sublayer("norm"):
+                h = _ln(x, w("ln1.scale"), w("ln1.bias"), eps)
             if kind == "mamba":
-                mix, y, planes = self._mamba(w, h, planes, attend,
-                                             self.state_of[i])
+                with sublayer("mixer"):
+                    mix, y, planes = self._mamba(w, h, planes, attend,
+                                                 self.state_of[i])
                 if i == self.memory_layer:
                     memory = y
             elif kind == "gmu":
-                gate = jax.nn.silu((h @ w("gmu_in.w")).astype(jnp.float32))
-                mix = (memory * gate).astype(x.dtype) @ w("gmu_out.w")
+                with sublayer("mixer"):
+                    gate = jax.nn.silu(
+                        (h @ w("gmu_in.w")).astype(jnp.float32))
+                    mix = (memory * gate).astype(x.dtype) @ w("gmu_out.w")
             else:
                 own = kind != "cross"
                 mix, planes = self._diff_attention(
@@ -642,18 +677,21 @@ class SambaY(Architecture):
                     self.plane_of[i if own else self.full_layer], own,
                     self.window if kind == "window" else None)
             x = x + mix
-            h2 = _ln(x, w("ln2.scale"), w("ln2.bias"), eps)
-            gu = h2 @ w("ffn_gu.w")
-            f = gu.shape[-1] // 2
-            ff = (gu[..., f:].astype(jnp.float32)
-                  * jax.nn.silu(gu[..., :f].astype(jnp.float32)))
-            x = x + ff.astype(x.dtype) @ w("ffn_down.w")
+            with sublayer("norm"):
+                h2 = _ln(x, w("ln2.scale"), w("ln2.bias"), eps)
+            with sublayer("ffn"):
+                gu = h2 @ w("ffn_gu.w")
+                f = gu.shape[-1] // 2
+                ff = (gu[..., f:].astype(jnp.float32)
+                      * jax.nn.silu(gu[..., :f].astype(jnp.float32)))
+                x = x + ff.astype(x.dtype) @ w("ffn_down.w")
         return x, planes
 
     def head(self, p, x):
-        x = _ln(x, p["ln_f.scale"], p["ln_f.bias"], self.eps)
-        return jnp.einsum("...d,vd->...v", x, p["tok_emb.w"],
-                          preferred_element_type=jnp.float32)
+        with sublayer("head"):
+            x = _ln(x, p["ln_f.scale"], p["ln_f.bias"], self.eps)
+            return jnp.einsum("...d,vd->...v", x, p["tok_emb.w"],
+                              preferred_element_type=jnp.float32)
 
 
 def route(h, w_router, bias, top_k, scale):
@@ -841,27 +879,36 @@ class GatedMoE(Architecture):
 
     def embed(self, p, toks, pos):
         table = p["tok_emb.w"]
-        return table[toks] * jnp.asarray(self.d_model ** 0.5, table.dtype)
+        with sublayer("embed"):
+            return table[toks] * jnp.asarray(self.d_model ** 0.5,
+                                             table.dtype)
 
     def _attention(self, w, i, x, rope, planes, attend):
         f32 = jnp.float32
         kind = self.layer_types[i]
-        a = _rms(x, w("norm1.scale"), self.eps)
+        with sublayer("norm"):
+            a = _rms(x, w("norm1.scale"), self.eps)
         lead = a.shape[:-1]
         kv = (*lead, self.kv_heads, self.head_dim)
-        q = _rms(self.heads(a @ w("att_q.w")), w("att_qnorm.scale"),
-                 self.eps)
-        k = _rms((a @ w("att_k.w")).reshape(kv), w("att_knorm.scale"),
-                 self.eps)
-        v = (a @ w("att_v.w")).reshape(kv)
-        if kind == "window":
-            q, k = _rope(q, *rope), _rope(k, *rope)
-        ctx, planes = attend(
-            planes, i, 0, q, k, v, group=self.rows_per_entry,
-            window=self.window if kind == "window" else None)
-        gate = jax.nn.sigmoid((a @ w("att_gate.w")).astype(f32))
-        o = (ctx.reshape(*lead, -1).astype(f32) * gate).astype(x.dtype)
-        return _rms(o @ w("att_out.w"), w("norm2.scale"), self.eps), planes
+        with sublayer("attn.proj"):
+            q = _rms(self.heads(a @ w("att_q.w")), w("att_qnorm.scale"),
+                     self.eps)
+            k = _rms((a @ w("att_k.w")).reshape(kv), w("att_knorm.scale"),
+                     self.eps)
+            v = (a @ w("att_v.w")).reshape(kv)
+            if kind == "window":
+                q, k = _rope(q, *rope), _rope(k, *rope)
+            gate = jax.nn.sigmoid((a @ w("att_gate.w")).astype(f32))
+        with sublayer("attn.core"):
+            ctx, planes = attend(
+                planes, i, 0, q, k, v, group=self.rows_per_entry,
+                window=self.window if kind == "window" else None)
+            # the head gate, lane by lane
+            o = (ctx.reshape(*lead, -1).astype(f32) * gate).astype(x.dtype)
+        with sublayer("attn.proj"):
+            o = o @ w("att_out.w")
+        with sublayer("norm"):
+            return _rms(o, w("norm2.scale"), self.eps), planes
 
     def _routed(self, w, h, attend):
         """The routed FFN over rows ``h [..., d]``: ``(y, counts)``."""
@@ -870,7 +917,7 @@ class GatedMoE(Architecture):
         k, d = self.top_k, h.shape[-1]
         rows = h.reshape(-1, d)
         valid = attend.valid.reshape(-1)
-        with jax.named_scope("serving.moe_route"):
+        with sublayer("moe.route"):
             sel, weight = route(rows, w("router.w"), w("router.bias"), k,
                                 self.route_scale)
             # a pair (row, selection) is HELD where the selected expert
@@ -884,7 +931,7 @@ class GatedMoE(Architecture):
             sizes = jnp.sum(expert[:, None] == jnp.arange(count, dtype=i32),
                             axis=0, dtype=i32)
             gathered = rows[order // k]
-        with jax.named_scope("serving.moe_experts"):
+        with sublayer("moe.experts"):
             act = (jax.nn.silu(_grouped_matmul(gathered, w("experts_gate.w"),
                                                sizes))
                    * _grouped_matmul(gathered, w("experts_up.w"), sizes))
@@ -895,7 +942,7 @@ class GatedMoE(Architecture):
                 jnp.arange(order.shape[0], dtype=order.dtype))
             y = jnp.sum(out[back].reshape(-1, k, d).astype(f32)
                         * jnp.where(held, weight, 0.0)[..., None], axis=1)
-        with jax.named_scope("serving.moe_shared"):
+        with sublayer("moe.shared"):
             shared = _gated_silu(h, w("shared_gate.w"), w("shared_up.w"),
                                  w("shared_down.w"))
         counts = jnp.stack([jnp.sum(valid, dtype=i32),
@@ -905,21 +952,27 @@ class GatedMoE(Architecture):
         return shared + y.astype(h.dtype).reshape(h.shape), counts
 
     def stack(self, p, x, pos, planes, attend):
-        rope = _rope_angles(pos, self.head_dim, self.rope_theta)
+        with sublayer("attn.proj"):
+            rope = _rope_angles(pos, self.head_dim, self.rope_theta)
         for i in range(self.n_layer):
             w = lambda nm: p[f"block{i}_{nm}"]
             a, planes = self._attention(w, i, x, rope, planes, attend)
             x = x + a
-            m = _rms(x, w("norm3.scale"), self.eps)
+            with sublayer("norm"):
+                m = _rms(x, w("norm3.scale"), self.eps)
             if i < self.dense_layers:
-                ff = _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
-                                 w("ffn_down.w"))
+                with sublayer("ffn"):
+                    ff = _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
+                                     w("ffn_down.w"))
             else:
                 ff, counts = self._routed(w, m, attend)
                 attend.tally(counts)
-            x = x + _rms(ff, w("norm4.scale"), self.eps)
+            with sublayer("norm"):
+                x = x + _rms(ff, w("norm4.scale"), self.eps)
         return x, planes
 
     def head(self, p, x):
-        return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
-                          p["lm_head.w"], preferred_element_type=jnp.float32)
+        with sublayer("head"):
+            return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
+                              p["lm_head.w"],
+                              preferred_element_type=jnp.float32)

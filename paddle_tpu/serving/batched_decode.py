@@ -86,7 +86,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import paged_attention as _paged
-from .arch import STACK_SCOPE
+from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
            "make_verify_window", "PREFILL_PIECE"]
@@ -167,12 +167,13 @@ class _Cache:
             # (distinct live positions, disjoint per-slot blocks, overruns
             # and rows past their limit in the trash block — content
             # nobody ever attends)
-            if kh.shape[-2] == pk.shape[2]:
-                pk = pk.at[b, self.off].set(kh)
-                pv = pv.at[b, self.off].set(vh)
-            else:   # rows kernels.paged_attention.pool_rows added stay 0
-                pk = pk.at[b, self.off, :kh.shape[-2]].set(kh)
-                pv = pv.at[b, self.off, :vh.shape[-2]].set(vh)
+            with sublayer("cache"):
+                if kh.shape[-2] == pk.shape[2]:
+                    pk = pk.at[b, self.off].set(kh)
+                    pv = pv.at[b, self.off].set(vh)
+                else:   # rows kernels.paged_attention.pool_rows added stay 0
+                    pk = pk.at[b, self.off, :kh.shape[-2]].set(kh)
+                    pv = pv.at[b, self.off, :vh.shape[-2]].set(vh)
         # attend THROUGH the table: row j attends <= pos_j inside the
         # paged_attention op class, the [S, T, h, dh] view never exists
         ctx = _paged.attend(qh[:, None] if self.step else qh, pk, pv, tbl,
@@ -191,20 +192,22 @@ class _Cache:
     def state(self, planes, i):
         rows = planes[2][i]
         if self.slot is not None:
-            rows = tuple(jax.lax.dynamic_index_in_dim(a, self.slot, 0)
-                         for a in rows)
-            fresh = self.pos[:, 0] == 0
-            rows = tuple(jnp.where(
-                fresh.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a)
-                for a in rows)
+            with sublayer("cache"):
+                rows = tuple(jax.lax.dynamic_index_in_dim(a, self.slot, 0)
+                             for a in rows)
+                fresh = self.pos[:, 0] == 0
+                rows = tuple(jnp.where(
+                    fresh.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a)
+                    for a in rows)
         return rows
 
     def put_state(self, planes, i, rows):
         old = planes[2][i]
-        rows = tuple(r.astype(a.dtype) for r, a in zip(rows, old))
-        if self.slot is not None:
-            rows = tuple(jax.lax.dynamic_update_slice_in_dim(
-                a, r, self.slot, 0) for r, a in zip(rows, old))
+        with sublayer("cache"):
+            rows = tuple(r.astype(a.dtype) for r, a in zip(rows, old))
+            if self.slot is not None:
+                rows = tuple(jax.lax.dynamic_update_slice_in_dim(
+                    a, r, self.slot, 0) for r, a in zip(rows, old))
         return planes[:2] + (planes[2][:i] + (rows,) + planes[2][i + 1:],)
 
 
@@ -227,7 +230,8 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     B = pool_k[0].shape[1]
     T = table.shape[1] * B
     tw = jnp.clip(t, 0, T - 1)
-    blk = table[jnp.arange(S), tw // B]      # [S] physical write block
+    with sublayer("cache"):
+        blk = table[jnp.arange(S), tw // B]  # [S] physical write block
     x = arch.embed(p, tok, tw)                               # [S, d]
     cache = _Cache(arch, table, blk, tw % B, t)
     with jax.named_scope(STACK_SCOPE):
@@ -258,7 +262,8 @@ def make_decode_chunk(arch, chunk, donate=True):
             pk, pv, st, tok, t = carry
             logits, pk, pv, st, counts = paged_step_logits(
                 p, tok, t, pk, pv, table, arch, st)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with sublayer("head"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (pk, pv, st, nxt, t + 1), (nxt, counts)
 
         (pk, pv, state, tok, t), (toks, counts) = jax.lax.scan(
@@ -313,7 +318,9 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch,
     P = pos[:, None] + jnp.arange(W)[None, :]                # [S, W]
     Pw = jnp.clip(P, 0, T - 1)
     writable = P <= limit[:, None]
-    blk = jnp.where(writable, table[jnp.arange(S)[:, None], Pw // B], 0)
+    with sublayer("cache"):
+        blk = jnp.where(writable,
+                        table[jnp.arange(S)[:, None], Pw // B], 0)
     x = arch.embed(p, toks, Pw)                              # [S, W, d]
     cache = _Cache(arch, table, blk, Pw % B, P, writable, slot)
     with jax.named_scope(STACK_SCOPE):
@@ -327,11 +334,12 @@ def _copy_block(planes, src, dst, passes):
     each pass folded into an array's block axis."""
     per = planes[0].shape[0] // passes
     out = []
-    for c in planes:
-        c = c.at[dst].set(c[src])
-        for i in range(1, passes):
-            c = c.at[dst + i * per].set(c[src + i * per])
-        out.append(c)
+    with sublayer("cache"):
+        for c in planes:
+            c = c.at[dst].set(c[src])
+            for i in range(1, passes):
+                c = c.at[dst + i * per].set(c[src + i * per])
+            out.append(c)
     return tuple(out)
 
 
@@ -362,7 +370,9 @@ def make_verify_window(arch, k, donate=True):
                              f"{toks.shape[1]} tokens a slot")
         x, pool_k, pool_v, _, _ = _window_forward(
             p, pool_k, pool_v, toks, pos, limit, table, arch)
-        greedy = jnp.argmax(arch.head(p, x), axis=-1).astype(jnp.int32)
+        logits = arch.head(p, x)
+        with sublayer("head"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return pool_k, pool_v, greedy
 
     return jax.jit(verify, donate_argnums=(1, 2) if donate else ())
@@ -412,8 +422,9 @@ def make_prefill(arch, bucket, donate=True):
         x, pool_k, pool_v, state, counts = _window_forward(
             p, pool_k, pool_v, toks[None], start[None], (end - 1)[None],
             table_row[None], arch, state, slot)
-        row = jax.lax.dynamic_slice_in_dim(x[0], length - 1, 1)  # [1, d]
-        first = jnp.argmax(arch.head(p, row)[0]).astype(jnp.int32)
+        with sublayer("head"):
+            row = jax.lax.dynamic_slice_in_dim(x[0], length - 1, 1)  # [1, d]
+            first = jnp.argmax(arch.head(p, row)[0]).astype(jnp.int32)
         last_tok = last_tok.at[slot].set(first)
         pos = pos.at[slot].set(end)
         return pool_k, pool_v, last_tok, pos, first, state, counts

@@ -851,6 +851,9 @@ class ServingEngine:
             if sel:
                 self.kernel_backends[label] = sel
             box["c"] = c
+            # the device half of the span primitive: the executable, for
+            # whoever asks which sub-layer an instruction belongs to
+            _trace.register_executable(label, c)
             stats = compiled_memory_stats(c)
             if stats:
                 self._reg.gauge(
