@@ -17,8 +17,19 @@ K/V heads of 128, a group of 6, window 4096 and none), the speculative
 verify window (5 rows at consecutive positions) and the 12-head pool that
 takes the grid form, alone and under a verify window.  Live slots and their
 contexts are drawn from ``--seed`` in the range each cell's traffic
-reaches; a dead slot has a row of trash and ``pos = -1``.  Refuses unless JAX finds a TPU: a number from a CPU
-run is no device metric.
+reaches; a dead slot has a row of trash and ``pos = -1``.
+
+``--only writes`` (or any ``write_*`` name) times the K/V WRITE alone
+(``kernels.paged_attention.write`` into a donated pool, the device's busy
+seconds of the trace over the calls): a decode step's rows and a prefill
+piece's at each serving cell's pool, and for ``think_decode``, whose 10
+pair rows do not fill the 16 of its bf16 pool, the partial write it
+replaced (``pool.at[blk, off, :10].set(rows)``: a loop over the written
+rows) beside it.  A step makes one such write for K and one for V of
+every plane: 18 in ``think_decode``.
+
+Refuses unless JAX finds a TPU: a number from a CPU run is no device
+metric.
 """
 
 import argparse
@@ -66,6 +77,28 @@ GEOMETRIES = {
                                  ctx=(64, 500), config="cerebras-gpt-590m"),
 }
 BLOCK_TOKENS = 32
+
+# name -> pool blocks, rows of the pool's head axis, K/V rows written,
+# index shape (slots, or slots x window rows) and the writes a step or a
+# piece makes (K and V of every plane); where the K/V rows do not fill the
+# head axis, `<name>_partial` is the write of part of it beside
+WRITES = {
+    "write_think_decode_step": dict(blocks=3073, rows=16, heads=10,
+                                    index=(48,), a_step=18),
+    "write_think_decode_piece": dict(blocks=3073, rows=16, heads=10,
+                                     index=(1, 128), a_step=18),
+    "write_agent_turns_step": dict(blocks=705, rows=16, heads=16,
+                                   index=(24,), a_step=48),
+    "write_reason_decode_step": dict(blocks=708, rows=16, heads=16,
+                                     index=(10,), a_step=384),
+    "write_chat_moe_step": dict(blocks=6145, rows=8, heads=8, index=(96,),
+                                a_step=10),
+    "write_chat_moe_piece": dict(blocks=6145, rows=8, heads=8,
+                                 index=(1, 128), a_step=10),
+}
+WRITES.update({name + "_partial": dict(g, partial=True)
+               for name, g in list(WRITES.items())
+               if g["heads"] < g["rows"]})
 
 
 def _config(name):
@@ -173,10 +206,80 @@ def measure(name, calls, peak, seed):
         "rel_err_vs_xla_ref": err}
 
 
+def _busy_us(fn, pool, args, calls):
+    """Device microseconds a call of ``pool = fn(pool, *args)``: the
+    busy seconds of a profiler trace over ``calls`` queued calls (a loop
+    and what it holds count once)."""
+    import tempfile
+
+    import jax
+
+    from chipbench import trace_reduce
+
+    pool = jax.block_until_ready(fn(pool, *args))  # compile, warm
+    with tempfile.TemporaryDirectory(prefix="paged_walk") as td:
+        with jax.profiler.trace(td):
+            for _ in range(calls):
+                pool = fn(pool, *args)
+            jax.block_until_ready(pool)
+        chips = trace_reduce.chip_ops(trace_reduce.load(td))
+    (events,) = chips.values()
+    ns = sum(end - start for start, end in trace_reduce.busy_union(events))
+    return 1e-3 * ns / calls, pool
+
+
+def measure_write(name, calls, seed):
+    import jax
+    import jax.numpy as jnp
+
+    import numpy as np
+
+    from paddle_tpu.kernels.paged_attention import write
+
+    g = WRITES[name]
+    rng = np.random.default_rng(seed)
+    index, heads, B = g["index"], g["heads"], BLOCK_TOKENS
+    # distinct blocks, as live slots' are; a window's rows run on through
+    # the blocks of its slot's chain
+    n = int(np.prod(index))
+    if len(index) == 1:
+        blk = rng.permutation(np.arange(1, g["blocks"]))[:n]
+        off = rng.integers(0, B, n)
+    else:
+        at = int(rng.integers(0, B)) + np.arange(index[1])
+        chain = rng.permutation(np.arange(1, g["blocks"]))[:at[-1] // B + 1]
+        blk, off = chain[at // B][None, :], (at % B)[None, :]
+    blk = jnp.asarray(blk.reshape(index), jnp.int32)
+    off = jnp.asarray(off.reshape(index), jnp.int32)
+    rows = jnp.asarray(rng.standard_normal((*index, heads, 128)),
+                       jnp.bfloat16)
+    pool = jnp.zeros((g["blocks"], B, g["rows"], 128), jnp.bfloat16)
+    if g.get("partial"):
+        spelt = lambda p, b, o, r: p.at[b, o, :heads].set(r)  # noqa: E731
+    else:
+        spelt = write
+    us, pool = _busy_us(jax.jit(spelt, donate_argnums=0), pool,
+                        (blk, off, rows), calls)
+    # what was written is there, and nothing else is
+    got = np.asarray(pool[blk, off], np.float32)
+    ok = bool(np.array_equal(got[..., :heads, :],
+                             np.asarray(rows, np.float32))
+              and not got[..., heads:, :].any()
+              and int(jnp.count_nonzero(pool)) == int(
+                  jnp.count_nonzero(rows)))
+    return {"geometry": name, **{k: g[k] for k in ("blocks", "rows", "heads")},
+            "index": list(index), "rows_written": n,
+            "spelling": "partial" if g.get("partial") else "whole",
+            "us_a_write": us, "us_a_row": us / n,
+            "writes_a_step": g["a_step"],
+            "us_a_step": us * g["a_step"], "written_exactly": ok}
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--only", default="",
-                    help="comma-separated geometry names (default: all)")
+                    help="comma-separated geometry names (default: all "
+                         "the kernel's); `writes`: the K/V writes")
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--seed", type=int, default=33)
     ap.add_argument("--out", default="chiprun_out/paged_walk.jsonl")
@@ -191,11 +294,16 @@ def main():
         sys.exit(f"paged_walk times the chip; JAX found {dev.platform}")
     peak = flops.peaks(dev.device_kind)
     names = [n for n in args.only.split(",") if n] or list(GEOMETRIES)
+    if "writes" in names:
+        names = [n for n in names if n != "writes"] + list(WRITES)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "a") as f:
         for name in names:
             try:
-                line = measure(name, args.calls, peak, args.seed)
+                if name in WRITES:
+                    line = measure_write(name, args.calls, args.seed)
+                else:
+                    line = measure(name, args.calls, peak, args.seed)
             except Exception as e:  # noqa: BLE001 - a geometry Mosaic refuses
                 line = {"geometry": name, "error": repr(e)[:400]}
             line["device"] = dev.device_kind

@@ -31,7 +31,7 @@ Calling convention (both backends)::
                             W=k+1 for the speculative verify window)
     pool_k  [num_blocks, B, hk, dh]  the physical K pool (one plane);
                             ``h = hk * group`` (rows past ``hk``, which
-                            ``pool_rows`` may add, hold nothing)
+                            ``pool_rows`` may add, hold zeros)
     pool_v  [num_blocks, B, hk, dh]  the physical V pool
     table   [S, NB] int32   per-slot block chain (block 0 = trash)
     pos     [S, W]  int32   absolute position of each query; key token
@@ -102,7 +102,7 @@ from .registry import register_kernel, resolve
 from .xla_ref import NEG_INF
 
 __all__ = ["attend", "DENSE_WINDOW", "paged_attention_ref",
-           "paged_attention_pallas", "pool_rows", "softmax_updates"]
+           "paged_attention_pallas", "pool_rows", "softmax_updates", "write"]
 
 # From this window width up a window gathers its slot's chain once and
 # attends it densely instead of streaming blocks.  W rows then share one
@@ -147,7 +147,7 @@ def pool_rows(heads, dtype):
     so that the Mosaic kernel can slice a block out of the pool where it
     lies (``_block_is_sliceable``) and run its loop form: ``heads``, or
     the next multiple of 8 for a packed dtype.  The rows added hold
-    nothing: never written, their scores attend zeros."""
+    zeros, which ``write`` puts there, and their scores attend zeros."""
     if jnp.dtype(dtype).itemsize >= 4 or heads % 8 == 0:
         return heads
     return -(-heads // 8) * 8
@@ -364,8 +364,8 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     (while the row has seen no key its maximum is taken as 0, where
     ``exp(NEG_INF - NEG_INF)`` would weigh every lane 1), so the values
     are weighed by ONE more pass for all the rows, ``p [N, B * h] x v [B
-    * h, dh]`` into the f32 ``acc [N, dh]``: whatever a masked token or
-    a row ``pool_rows`` added holds, as long as it is finite, adds zero.
+    * h, dh]`` into the f32 ``acc [N, dh]``: a masked token adds zero as
+    long as it is finite, a row ``pool_rows`` added holds zeros (``write``).
     That pass keeps ``p`` at float32 accuracy: for a bfloat16 pool ``p``
     goes as the three bfloat16 pieces that sum to it exactly, stacked on
     the row axis (their products with ``v`` are exact in f32); for a
@@ -689,6 +689,22 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         name="paged_attention",
     )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v)
     return unfold(ctx)
+
+
+def write(pool, blk, off, rows):
+    """``rows [*blk.shape, heads, dh]`` written into ``pool [blocks, B,
+    hk, dh]`` at ``(blk, off)`` (a decode step's ``[S]`` indices or a
+    window's ``[S, W]``): ONE scatter that covers the pool's whole row.
+    Where ``pool_rows`` gave the pool more rows than ``heads``, the rows
+    past ``heads`` are written as zeros.  A write of PART of the head
+    axis compiles to a serial loop over the written rows with one
+    ``dynamic-update-slice`` each, 4.2 us a row on a v5e where the whole
+    row costs 0.13 (PERF.md, PR 37)."""
+    spare = pool.shape[2] - rows.shape[-2]
+    if spare:
+        rows = jnp.pad(rows, ((0, 0),) * (rows.ndim - 2)
+                       + ((0, spare), (0, 0)))
+    return pool.at[blk, off].set(rows)
 
 
 def _tpu_available():

@@ -101,11 +101,14 @@ class _Cache:
     """The cache interface an architecture's ``stack`` receives
     (``arch.py``): called, ``attend(planes, plane, i_pass, q, k, v,
     **how) -> (ctx, planes')`` writes the rows' K/V into pool array
-    ``plane`` (of pass ``i_pass``) at ``(blk, off)``, then attends that
-    plane through ``table`` masked ``<= pos`` (and by ``how``'s lower
-    bound); with ``k`` and ``v`` ``None`` it writes nothing and attends
-    what another layer wrote.  Its state side (``state``, ``put_state``,
-    ``valid``) reads and writes the slots' rows of the per-slot state.
+    ``plane`` (of pass ``i_pass``) at ``(blk, off)`` (one scatter over
+    the pool's whole row, ``kernels.paged_attention.write``: how a row
+    is written belongs to the module that decides how it is read), then
+    attends that plane through ``table`` masked ``<= pos`` (and by
+    ``how``'s lower bound); with ``k`` and ``v`` ``None`` it writes
+    nothing and attends what another layer wrote.  Its state side
+    (``state``, ``put_state``, ``valid``) reads and writes the slots'
+    rows of the per-slot state.
 
     ``planes = (pool_k, pool_v, state)``: one pool array a plane, one
     tuple of ``[max_slots, ...]`` arrays a state layer (``()`` for an
@@ -166,14 +169,12 @@ class _Cache:
             # write-before-attend discipline, one scatter per plane
             # (distinct live positions, disjoint per-slot blocks, overruns
             # and rows past their limit in the trash block — content
-            # nobody ever attends)
+            # nobody ever attends) that covers the pool's WHOLE row, the
+            # rows kernels.paged_attention.pool_rows added as zeros: a
+            # write of part of the head axis is a serial loop on the chip
             with sublayer("cache"):
-                if kh.shape[-2] == pk.shape[2]:
-                    pk = pk.at[b, self.off].set(kh)
-                    pv = pv.at[b, self.off].set(vh)
-                else:   # rows kernels.paged_attention.pool_rows added stay 0
-                    pk = pk.at[b, self.off, :kh.shape[-2]].set(kh)
-                    pv = pv.at[b, self.off, :vh.shape[-2]].set(vh)
+                pk = _paged.write(pk, b, self.off, kh)
+                pv = _paged.write(pv, b, self.off, vh)
         # attend THROUGH the table: row j attends <= pos_j inside the
         # paged_attention op class, the [S, T, h, dh] view never exists
         ctx = _paged.attend(qh[:, None] if self.step else qh, pk, pv, tbl,

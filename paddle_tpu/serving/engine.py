@@ -453,6 +453,14 @@ class ServingEngine:
             help="K and V bytes of one cached token across its planes",
         ).set(arch.kv_bytes_per_token(itemsize))
         self._reg.gauge(
+            "serving.kv_write_fill",
+            help="rows of a pool row that carry K/V heads / rows a write "
+                 "covers: a write covers the pool's whole row "
+                 "(kernels.paged_attention.write), rows pool_rows added "
+                 "as zeros",
+        ).set(arch.kv_block_bytes(1, itemsize)
+              / (2 * itemsize * int(np.prod(self._pk[0].shape[2:]))))
+        self._reg.gauge(
             "serving.kv_pool_bytes",
             help="bytes the paged pool holds on the device: planes x "
                  "blocks (trash included) x block bytes",

@@ -650,6 +650,131 @@ def test_paged_value_product_gives_garbage_no_weight(w, group, hk):
     assert not np.asarray(again)[~live].any()
 
 
+def _write_case(dtype, heads, index, seed=23):
+    """K/V rows of ``heads`` heads for ``write``: a pool of 1 + S * NB
+    blocks of B = 4 tokens whose head axis is ``pool_rows(heads,
+    dtype)`` (5 -> 8 in bfloat16: rows to spare; float32 and 8 heads:
+    none), S = 4 slots of NB = 3 blocks.  ``index`` "step": one row a
+    slot, slot 1 dead (block 0, the trash block); "window": W = 6 rows a
+    slot that cross a block boundary, slot 1 dead, and slot 3's rows
+    past its ``limit`` in the trash block as ``_window_forward`` routes
+    them."""
+    from paddle_tpu.kernels.paged_attention import pool_rows
+
+    rng = np.random.default_rng(seed)
+    S, NB, B, dh = 4, 3, 4, 16
+    table = 1 + np.arange(S * NB, dtype=np.int32).reshape(S, NB)
+    table[1] = 0
+    start = np.array([5, 0, 2, 3])
+    if index == "step":
+        at = start
+        blk = table[np.arange(S), at // B]
+    else:
+        at = start[:, None] + np.arange(6)[None, :]
+        blk = table[np.arange(S)[:, None], at // B]
+        blk = np.where((np.arange(6) < 4)[None, :] | (np.arange(S) != 3
+                                                       )[:, None], blk, 0)
+    shape = (1 + S * NB, B, pool_rows(heads, dtype), dh)
+    rows = jnp.asarray(rng.normal(size=(*at.shape, heads, dh)), dtype)
+    return (shape, jnp.asarray(blk, jnp.int32),
+            jnp.asarray(at % B, jnp.int32), rows)
+
+
+@pytest.mark.parametrize("index", ["step", "window"])
+@pytest.mark.parametrize("dtype,heads", [("bfloat16", 5), ("bfloat16", 8),
+                                         ("float32", 5)])
+def test_kv_write_is_the_two_spellings_it_replaced(dtype, heads, index):
+    """``write`` into a pool made of zeros against what ``_Cache`` spelt
+    until PR 37 (the whole head axis where the rows fill it, its first
+    ``heads`` rows where ``pool_rows`` added some): every bit of the
+    pool, the trash block included, and one ``scatter`` where the
+    partial spelling traced one too (what differs is what the chip's
+    compiler makes of them: tests/test_paged_compiles_for_chip.py)."""
+    from paddle_tpu.kernels.paged_attention import write
+
+    shape, blk, off, rows = _write_case(dtype, heads, index)
+    pool = jnp.zeros(shape, dtype)
+    assert (shape[2] > heads) == ((dtype, heads) == ("bfloat16", 5))
+    if shape[2] == heads:
+        old = pool.at[blk, off].set(rows)
+        # rows that fill the head axis: the spelling itself, nothing added
+        assert str(jax.make_jaxpr(write)(pool, blk, off, rows)) == str(
+            jax.make_jaxpr(lambda p, b, o, r: p.at[b, o].set(r))(
+                pool, blk, off, rows))
+    else:
+        old = pool.at[blk, off, :heads].set(rows)
+    got = jax.jit(write)(pool, blk, off, rows)
+    assert got.dtype == pool.dtype and got.shape == pool.shape
+    assert bool(jnp.array_equal(got, old))
+    assert _primitive_counts(jax.make_jaxpr(write)(
+        pool, blk, off, rows).jaxpr).get("scatter") == 1
+    # the live rows are where they belong (block 0 is the trash block)
+    b, o = np.asarray(blk), np.asarray(off)
+    live = b != 0
+    assert bool(jnp.array_equal(np.asarray(got)[b[live], o[live], :heads],
+                                np.asarray(rows)[live]))
+
+
+@pytest.mark.parametrize("backend", ["xla_ref", "pallas_tpu_interpret"])
+@pytest.mark.parametrize("index", ["step", "window"])
+def test_kv_write_leaves_zeros_in_the_rows_pool_rows_added(index, backend):
+    """Over a pool filled with finite garbage every written position
+    reads exactly zero from row ``heads`` up (the zeros are WRITTEN, not
+    left), and attention over the chains so written agrees to the bit
+    with the same writes over a pool made of zeros: what no row's mask
+    lets through weighs nothing."""
+    from paddle_tpu.kernels.paged_attention import write
+
+    heads, group = 5, 2
+    shape, _, _, _ = _write_case("bfloat16", heads, index)
+    S, NB, B, dh = 4, 3, 4, shape[-1]
+    rng = np.random.default_rng(29)
+    table = 1 + np.arange(S * NB, dtype=np.int32).reshape(S, NB)
+    table[1] = 0
+    last = np.array([9, -1, 6, 11])
+    w = 1 if index == "step" else 3
+    pos = np.where(last[:, None] < 0, -1,
+                   last[:, None] - (w - 1) + np.arange(w)[None, :])
+    garbage = jnp.asarray(rng.normal(size=shape) * 40.0, jnp.bfloat16)
+    pools = {"zeros": [jnp.zeros(shape, jnp.bfloat16)] * 2,
+             "garbage": [garbage, -garbage]}
+    # every position a live row attends is written, one position a slot
+    # at a time (a decode step) or three (a window)
+    for t in range(0, B * NB, w):
+        at = np.minimum(t + np.arange(w)[None, :], B * NB - 1) + np.zeros(
+            (S, 1), int)
+        keep = at <= last[:, None]
+        blk = np.where(keep, table[np.arange(S)[:, None], at // B], 0)
+        k = jnp.asarray(rng.normal(size=(S, w, heads, dh)), jnp.bfloat16)
+        v = jnp.asarray(rng.normal(size=(S, w, heads, dh)), jnp.bfloat16)
+        if index == "step":
+            blk, at, k, v = blk[:, 0], at[:, 0], k[:, 0], v[:, 0]
+        for name, (pk, pv) in pools.items():
+            pools[name] = [
+                write(pk, jnp.asarray(blk, jnp.int32),
+                      jnp.asarray(at % B, jnp.int32), k),
+                write(pv, jnp.asarray(blk, jnp.int32),
+                      jnp.asarray(at % B, jnp.int32), v)]
+    written = np.zeros(shape[:2], bool)
+    for s_ in range(S):
+        for t in range(max(int(last[s_]) + 1, 0)):
+            written[table[s_, t // B], t % B] = True
+    untouched = ~written
+    untouched[0] = False          # the trash block took the dead rows
+    for pool in pools["garbage"]:
+        assert not np.asarray(pool, np.float32)[written][:, heads:].any()
+        assert np.asarray(pool, np.float32)[untouched][:, heads:].all()
+    q = jnp.asarray(rng.normal(size=(S, w, heads * group, dh)) * 0.5,
+                    jnp.bfloat16)
+    fn = _paged_backends()[backend]
+    out = {name: fn(q, pk, pv, jnp.asarray(table),
+                    jnp.asarray(pos, jnp.int32), group=group)
+           for name, (pk, pv) in pools.items()}
+    assert bool(jnp.all(jnp.isfinite(out["garbage"])))
+    assert bool(jnp.any(out["zeros"] != 0))
+    assert bool(jnp.array_equal(out["zeros"], out["garbage"]))
+
+
 def test_paged_defaults_lower_to_the_program_they_always_did():
     """``group``, ``window``, ``scale`` and ``out_dtype`` are Python
     constants: at their defaults neither backend traces one primitive

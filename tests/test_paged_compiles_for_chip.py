@@ -99,6 +99,54 @@ def test_paged_kernel_compiles_for_v5e(geometry, one_chip):
     assert "paged_attention" in compiled.as_text()
 
 
+# (pool blocks, rows of the pool's head axis, dtype, index shape, K/V
+# rows written): the K/V write of each serving cell, a decode step's
+# slots and a prefill piece's window
+WRITES = {
+    # 10 pairs of heads in the 16 rows pool_rows gives a bf16 pool: the
+    # one cell whose rows do not fill the head axis.  Written as PART of
+    # the axis (`pool.at[blk, off, :10].set(rows)`, until PR 37) both
+    # compile to a `while` over the written rows with one
+    # dynamic-update-slice each: 18 loops a step, 4 us a row on the chip
+    "think_decode_step": (3073, 16, "bfloat16", (48,), 10),
+    "think_decode_prefill_piece": (3073, 16, "bfloat16", (1, 128), 10),
+    "agent_turns_step": (705, 16, "bfloat16", (24,), 16),
+    "agent_turns_prefill_piece": (705, 16, "bfloat16", (1, 128), 16),
+    "reason_decode_step_folded_pool": (708, 16, "bfloat16", (10,), 16),
+    "chat_moe_step": (6145, 8, "bfloat16", (96,), 8),
+    "chat_moe_prefill_piece": (6145, 8, "bfloat16", (1, 128), 8),
+    "verify_window": (705, 16, "bfloat16", (24, 5), 16),
+}
+
+
+@pytest.mark.parametrize("geometry", list(WRITES))
+def test_kv_write_is_one_scatter_in_place_for_v5e(geometry, one_chip):
+    """``kernels.paged_attention.write`` into a donated pool: one scatter
+    fusion that updates the pool where it lies: no loop over the
+    written rows, no ``dynamic-update-slice`` of the pool, no temporary
+    the size of a plane."""
+    import re
+
+    from paddle_tpu.kernels.paged_attention import pool_rows, write
+
+    blocks, rows, dtype, index, heads = WRITES[geometry]
+    assert pool_rows(heads, dtype) == rows
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(write, donate_argnums=0).lower(
+        arg((blocks, 32, rows, 128), dtype), arg(index, jnp.int32),
+        arg(index, jnp.int32), arg((*index, heads, 128), dtype)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bwhile\(", text)
+    pool = re.escape(f"[{blocks},32,{rows},128]")
+    assert not re.search(pool + r"\S* dynamic-update-slice\(", text)
+    assert re.search(r"input_output_alias=\{ \{\}: \(0, \{\}", text)
+    assert re.search(pool + r"\S* scatter\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # (rows gathered, k, n, groups): the routed experts of chat_moe, a decode
 # step's 96 x 4 rows and a prefill piece's 128 x 4, and a narrow piece
 GROUPED = {

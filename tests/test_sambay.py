@@ -300,11 +300,23 @@ def test_gauges_spans_and_the_streamed_bytes(params, monkeypatch):
     assert st["serving.state_bytes_per_slot"] == per_slot
     assert st["serving.state_bytes"] == 2 * per_slot
     assert st["serving.kv_bytes_per_token"] == 3 * 2 * 2 * 16 * 4
+    # a float32 pool has no row to spare: a write covers K/V heads only
+    assert st["serving.kv_write_fill"] == 1.0
     blocks = 1 + 2 * (T // B)
     assert st["serving.kv_pool_bytes"] == 3 * blocks * 2 * B * 2 * 16 * 4
     assert [a.shape for a in eng._pk] == [(blocks, B, 1, 32)] * 3
     assert [[a.shape for a in layer] for layer in eng._state] == [
         [(2, 128, 4), (2, 3, 128)]] * 3
+
+    # twenty K/V heads in bfloat16, the published count: ten pair rows in
+    # the sixteen of a sliceable block, all sixteen written
+    wide = dict(TINY, heads=20, kv_heads=20, d=320)
+    bf16 = ServingEngine(
+        _init(jax.random.PRNGKey(33), wide, jnp.bfloat16), arch=_arch(wide),
+        max_len=T, block_tokens=B, max_slots=2, prefix_reuse=False,
+        donate=False, registry=MetricsRegistry())
+    assert bf16._pk[0].shape[2:] == (16, 32)
+    assert bf16.stats()["serving.kv_write_fill"] == 0.625
 
     tracer = trace.Tracer(enabled=True)
     old = trace.get_tracer()

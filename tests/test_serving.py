@@ -171,6 +171,7 @@ def test_engine_from_architecture_object_equals_positional(params):
         st = eng.stats()
         assert st["serving.kv_planes"] == NL
         assert st["serving.stack_passes"] == 1
+        assert st["serving.kv_write_fill"] == 1.0
         assert st["serving.kv_pool_bytes"] == sum(
             a.nbytes for a in eng._pk + eng._pv)
     for a, b in zip(*outs):
