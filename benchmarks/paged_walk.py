@@ -28,6 +28,15 @@ replaced (``pool.at[blk, off, :10].set(rows)``: a loop over the written
 rows) beside it.  A step makes one such write for K and one for V of
 every plane: 18 in ``think_decode``.
 
+``--only latent`` (or any ``latent_*`` name) times the LATENT plane of
+``dsv2lite.doc_qa_8k`` (``kernels.paged_attention.latent_attention_pallas``:
+12 slots x 288 entries of 32 x 640 lanes, 16 query rows a cached row) at
+one, two, four, eight and sixteen table entries an iteration, against 32 x 576 x
+2 B / 819 GB/s = 0.045 us a live block; and a 128-row prefill piece over
+an 8,300-token chain BOTH ways: absorbed (the queries against the
+gathered latents, what ``serving.arch.LatentMoE`` runs) and expanded
+(the gathered latents through ``W_kvb`` to per-head K and V first).
+
 Refuses unless JAX finds a TPU: a number from a CPU run is no device
 metric.
 """
@@ -99,6 +108,18 @@ WRITES = {
 WRITES.update({name + "_partial": dict(g, partial=True)
                for name, g in list(WRITES.items())
                if g["heads"] < g["rows"]})
+
+
+# the latent plane of dsv2lite.doc_qa_8k: 10 of 12 slots live at
+# contexts the cell's traffic reaches, `blocks` table entries an
+# iteration of the Mosaic loop
+LATENT = {f"latent_doc_qa_decode_{n}_a_copy": dict(
+    S=12, W=1, NB=288, blocks=4609, lanes=640, heads=16, value_lanes=512,
+    live=10, ctx=(8300, 9100), entries=n, config="deepseek-v2-lite")
+    for n in (1, 2, 4, 8, 16)}
+PIECES = {"latent_doc_qa_piece_absorbed": "absorbed",
+          "latent_doc_qa_piece_expanded": "expanded"}
+SCALE = 0.11472
 
 
 def _config(name):
@@ -206,6 +227,131 @@ def measure(name, calls, peak, seed):
         "rel_err_vs_xla_ref": err}
 
 
+def measure_latent(name, calls, peak, seed):
+    import jax
+    import jax.numpy as jnp
+
+    import numpy as np
+
+    from chipbench import latent_bytes
+    from paddle_tpu.kernels.paged_attention import (
+        latent_attention_pallas, paged_attention_ref)
+
+    g = LATENT[name]
+    S, NB, B, L = g["S"], g["NB"], BLOCK_TOKENS, g["lanes"]
+    cfg = _config(g["config"])
+    values = latent_bytes.sizes(cfg)["values_per_position"]
+    rng = np.random.default_rng(seed)
+    pool = np.zeros((g["blocks"], B, L), np.float32)
+    pool[..., :values] = rng.standard_normal(
+        (g["blocks"], B, values), np.float32) * 0.5
+    q = np.zeros((S, g["W"], g["heads"], L), np.float32)
+    q[..., :values] = rng.standard_normal(
+        (S, g["W"], g["heads"], values), np.float32) * 0.5
+    table = np.zeros((S, NB), np.int32)
+    pos = np.full((S, g["W"]), -1, np.int32)
+    free = rng.permutation(np.arange(1, g["blocks"]))
+    contexts = []
+    for s in rng.choice(S, g["live"], replace=False):
+        ctx = int(rng.integers(g["ctx"][0], g["ctx"][1] + 1))
+        n = (ctx - 1) // B + 1
+        table[s, :n], free = free[:n], free[n:]
+        pos[s], contexts = ctx - 1, contexts + [ctx]
+    live_blocks = sum((c - 1) // B + 1 for c in contexts)
+    fn = jax.jit(lambda *a: latent_attention_pallas(
+        *a, g["value_lanes"], scale=SCALE, out_dtype=jnp.float32,
+        interpret=False, blocks=g["entries"]))
+    args = (jnp.asarray(q, jnp.bfloat16), jnp.asarray(pool, jnp.bfloat16),
+            jnp.asarray(table), jnp.asarray(pos))
+    us = _timed(fn, args, calls)
+    live = pos.max(axis=1) >= 0
+    got = np.asarray(fn(*args))[live]
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(lambda q_, p_, t_, at: paged_attention_ref(
+            q_, p_, None, t_, at, value_lanes=g["value_lanes"], scale=SCALE,
+            out_dtype=jnp.float32))(*args))[live]
+    err = float(np.abs(got - want).max() / np.abs(want).max())
+    # ONE plane's call: latent_bytes counts all the planes a token holds
+    least = latent_bytes.least_seconds(cfg, contexts, peak) / (
+        latent_bytes.sizes(cfg)["planes"])
+    return {"geometry": name, **{k: g[k] for k in (
+        "S", "W", "NB", "lanes", "heads", "live", "entries")},
+        "live_blocks": live_blocks, "rows_a_block": g["heads"],
+        "us_a_call": us, "us_a_live_block": us / live_blocks,
+        "roofline_pct": 100.0 * least * 1e6 / us,
+        "rel_err_vs_xla_ref": err}
+
+
+def measure_piece(name, calls, seed):
+    """One layer's attention of a 128-row prefill piece at the end of an
+    8,300-token chain, absorbed or expanded (module docstring); both
+    gather the chain once and attend it densely."""
+    import jax
+    import jax.numpy as jnp
+
+    import tempfile
+
+    import numpy as np
+
+    from chipbench import trace_reduce
+    from paddle_tpu.kernels.paged_attention import attend
+
+    form = PIECES[name]
+    W, NB, B, L, h, rank = 128, 288, BLOCK_TOKENS, 640, 16, 512
+    nope, rope, v = 128, 64, 128
+    rng = np.random.default_rng(seed)
+    pool = np.zeros((4609, B, L), np.float32)
+    pool[..., :rank + rope] = rng.standard_normal(
+        (4609, B, rank + rope), np.float32) * 0.5
+    ctx = 8300
+    table = np.zeros((1, NB), np.int32)
+    n = (ctx - 1) // B + 1
+    table[0, :n] = rng.permutation(np.arange(1, 4609))[:n]
+    pos = jnp.asarray(ctx - W + np.arange(W), jnp.int32)[None]
+    q = jnp.asarray(rng.standard_normal((1, W, h, nope + rope)) * 0.5,
+                    jnp.bfloat16)
+    kvb = jnp.asarray(rng.standard_normal((rank, h, nope + v)) * 0.05,
+                      jnp.bfloat16)
+
+    def absorbed(pool, q, kvb, table, pos):
+        q_lat = jnp.einsum("...hn,rhn->...hr", q[..., :nope], kvb[..., :nope])
+        row = jnp.concatenate(
+            [q_lat, q[..., nope:],
+             jnp.zeros((1, W, h, L - rank - rope), q.dtype)], axis=-1)
+        u = attend(row, pool, None, table, pos, value_lanes=rank, scale=SCALE)
+        return jnp.einsum("...hr,rhv->...hv", u, kvb[..., nope:])
+
+    def expanded(pool, q, kvb, table, pos):
+        rows = pool[table[0]].reshape(NB * B, L)
+        kv = jnp.einsum("tr,rhn->thn", rows[:, :rank], kvb)
+        s = (jnp.einsum("whn,thn->hwt", q[0, :, :, :nope], kv[..., :nope],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("whn,tn->hwt", q[0, :, :, nope:],
+                          rows[:, rank:rank + rope],
+                          preferred_element_type=jnp.float32)) * SCALE
+        keep = jnp.arange(NB * B)[None, None, :] <= pos[0][None, :, None]
+        p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+        return jnp.einsum("hwt,thv->whv", p.astype(q.dtype),
+                          kv[..., nope:])[None]
+
+    fn = jax.jit(absorbed if form == "absorbed" else expanded)
+    args = (jnp.asarray(pool, jnp.bfloat16), q, kvb, jnp.asarray(table), pos)
+    # no Mosaic call to find by name: the device's busy seconds
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory(prefix="paged_walk") as td:
+        with jax.profiler.trace(td):
+            jax.block_until_ready([fn(*args) for _ in range(calls)])
+        (events,) = trace_reduce.chip_ops(trace_reduce.load(td)).values()
+    us = 1e-3 * sum(end - start for start, end in
+                    trace_reduce.busy_union(events)) / calls
+    a, b = (np.asarray(jax.jit(f)(*args), np.float32)
+            for f in (absorbed, expanded))
+    return {"geometry": name, "form": form, "rows": W, "context": ctx,
+            "us_a_layer_piece": us,
+            "absorbed_vs_expanded_rel": float(
+                np.abs(a - b).max() / np.abs(b).max())}
+
+
 def _busy_us(fn, pool, args, calls):
     """Device microseconds a call of ``pool = fn(pool, *args)``: the
     busy seconds of a profiler trace over ``calls`` queued calls (a loop
@@ -296,12 +442,19 @@ def main():
     names = [n for n in args.only.split(",") if n] or list(GEOMETRIES)
     if "writes" in names:
         names = [n for n in names if n != "writes"] + list(WRITES)
+    if "latent" in names:
+        names = ([n for n in names if n != "latent"] + list(LATENT)
+                 + list(PIECES))
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "a") as f:
         for name in names:
             try:
                 if name in WRITES:
                     line = measure_write(name, args.calls, args.seed)
+                elif name in LATENT:
+                    line = measure_latent(name, args.calls, peak, args.seed)
+                elif name in PIECES:
+                    line = measure_piece(name, args.calls, args.seed)
                 else:
                     line = measure(name, args.calls, peak, args.seed)
             except Exception as e:  # noqa: BLE001 - a geometry Mosaic refuses

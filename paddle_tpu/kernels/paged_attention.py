@@ -55,6 +55,28 @@ the ``group`` query heads of one K/V head become ``group`` window rows
 of ``hk`` heads at the same position, so the kernels below see ``h ==
 hk`` always.
 
+**A latent plane** (``pool_v=None``; ``serving.arch.LatentMoE``) is the
+second calling convention of the same functions::
+
+    call(q, pool, None, table, pos, value_lanes=V, scale=, out_dtype=)
+
+    q       [S, W, h, L]    one query row a head, as wide as a cached row
+    pool    [num_blocks, B, L]   ONE array a plane, NO head axis: a
+                            position holds one row (``write`` puts zeros
+                            in the lanes past its values; ``latent_lanes``
+                            rounds ``L`` up to the 128-lane tile, which
+                            Mosaic needs to slice a block out of the pool)
+    ctx     [S, W, h, V]    every head reads the ONE cached row whole;
+                            a position's value is its row's first
+                            ``value_lanes`` lanes
+
+``pool_rows``, ``group`` and ``window`` do not apply (a group or a lower
+bound is refused).  ``attend`` chooses as above: dense from
+``DENSE_WINDOW`` rows up, else the ``xla_ref`` scan or, on a TPU, the
+sibling Mosaic kernel ``latent_attention_pallas`` (HLO name
+``paged_latent_attention``): the loop below with the head mask gone and
+``LATENT_BLOCKS`` table entries an iteration.
+
 Numerics conventions match the flash kernels (f32 scores via
 ``preferred_element_type``, ``NEG_INF`` masking, f32 ``(m, l, acc)``
 online-softmax state, one normalization at the end with the
@@ -101,8 +123,9 @@ from ..ops.pallas_attention import LSE_LANES
 from .registry import register_kernel, resolve
 from .xla_ref import NEG_INF
 
-__all__ = ["attend", "DENSE_WINDOW", "paged_attention_ref",
-           "paged_attention_pallas", "pool_rows", "softmax_updates", "write"]
+__all__ = ["attend", "DENSE_WINDOW", "latent_attention_pallas",
+           "latent_lanes", "paged_attention_ref", "paged_attention_pallas",
+           "pool_rows", "softmax_updates", "write"]
 
 # From this window width up a window gathers its slot's chain once and
 # attends it densely instead of streaming blocks.  W rows then share one
@@ -120,13 +143,25 @@ DENSE_WINDOW = 8
 # three or four 0.62, four with the scores a block ahead 0.41, six 0.42.
 DEPTH = 4
 
+# Table entries of a LATENT plane the Mosaic loop takes in one iteration
+# (``latent_attention_pallas``).  An iteration costs its chain's latency
+# whatever it holds; measured alone on the chip at doc_qa_8k's geometry
+# (16 rows a block of 32 x 640 lanes, chains of 270 live blocks;
+# benchmarks/paged_walk.py, PERF.md PR 40): one entry 0.264 us a live
+# block, two 0.147, four 0.092, eight 0.065, sixteen 0.063 (the bytes
+# alone: 0.045).  Eight: sixteen gains 3% and fetches up to fifteen
+# entries past a chain's end where eight fetch seven.
+LATENT_BLOCKS = 8
+
 
 def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
-           out_dtype=None):
+           out_dtype=None, value_lanes=None):
     """One layer's attention THROUGH the block table, the one call the
     serving step makes: ``q [S, W, h, dh]``, ``pos [S, W]`` ->
     ``[S, W, h, dh]``; ``group``, ``window``, ``scale`` and
-    ``out_dtype`` as the module docstring has them.
+    ``out_dtype`` as the module docstring has them.  With ``pool_v``
+    ``None`` the plane is a LATENT one (module docstring): ``pool_k
+    [blocks, B, L]``, ``q [S, W, h, L]`` -> ``[S, W, h, value_lanes]``.
 
     The spelling follows the window's width and the platform, both seen
     at trace time (module docstring): ``W >= DENSE_WINDOW`` is the
@@ -135,6 +170,8 @@ def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
     softmax through the backend the registry resolves (the Mosaic loop
     starts at the window's first block)."""
     how = dict(group=group, window=window, scale=scale, out_dtype=out_dtype)
+    if pool_v is None:
+        how["value_lanes"] = value_lanes
     if q.shape[1] >= DENSE_WINDOW:
         return resolve("paged_attention", backend="xla_ref").impl.call(
             q, pool_k, pool_v, table, pos, block_step=table.shape[1], **how)
@@ -151,6 +188,16 @@ def pool_rows(heads, dtype):
     if jnp.dtype(dtype).itemsize >= 4 or heads % 8 == 0:
         return heads
     return -(-heads // 8) * 8
+
+
+def latent_lanes(values):
+    """Lanes a LATENT plane stores of a position that holds ``values``
+    values: the next multiple of 128.  The device tiles an array's minor
+    axis in 128 lanes, so a row of 576 occupies 640 in HBM whatever its
+    logical shape says; stating the 640 keeps every slice the kernel
+    makes on a tile's edge, and ``write`` puts zeros in the lanes past
+    the values (a zero lane adds nothing to a score)."""
+    return -(-int(values) // 128) * 128
 
 
 def softmax_updates(rows):
@@ -209,17 +256,50 @@ def _normalize_block_step(block_step, nb, w=1):
 
 # -- xla_ref: the block-scan oracle ------------------------------------------
 
+def _latent_plane(q, pool, group, window, value_lanes):
+    """The checks of a latent call (``pool_v is None``); returns the
+    value lanes."""
+    if pool.ndim != 3 or q.shape[-1] != pool.shape[-1]:
+        raise ValueError(
+            f"paged_attention: a latent plane is [blocks, B, L] and its "
+            f"queries [S, W, h, L]; got {pool.shape} and {q.shape}")
+    if group != 1 or window is not None:
+        raise ValueError("paged_attention: a latent plane has one row all "
+                         "the heads read whole: no group, no window")
+    if not value_lanes or not 0 < value_lanes <= pool.shape[-1]:
+        raise ValueError(f"paged_attention: value_lanes {value_lanes} of a "
+                         f"latent row of {pool.shape[-1]} lanes")
+    return int(value_lanes)
+
+
 def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
                         interpret=None, group=1, window=None, scale=None,
-                        out_dtype=None):
+                        out_dtype=None, value_lanes=None):
     """The oracle spelling: ``lax.scan`` over the block chain with
     online-softmax carry — per step only ``block_step`` physical blocks
     are gathered (``[S, block_step*B, h, dh]``), never the ``T``-wide
     view.  A lower bound (``window``) is one more term of the mask.
     ``interpret`` is accepted for signature parity and ignored (no
-    Pallas here)."""
+    Pallas here).  A latent plane (``pool_v is None``) is the same lines
+    with one cached row for all the heads, its values that row's first
+    ``value_lanes`` lanes."""
     del interpret
-    q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
+    latent = pool_v is None
+    if latent:
+        dv = _latent_plane(q, pool_k, group, window, value_lanes)
+        unfold = lambda ctx: ctx
+        qk, pv = "swhd,std->swht", "swht,std->swhd"
+
+        def gather(blk, n):
+            kb = pool_k[blk].reshape(S, n * B, dh)
+            return kb, kb[..., :dv]
+    else:
+        q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
+        qk, pv, dv = "swhd,sthd->swht", "swht,sthd->swhd", q.shape[-1]
+
+        def gather(blk, n):
+            return (pool_k[blk].reshape(S, n * B, h, dh),
+                    pool_v[blk].reshape(S, n * B, h, dh))
     out_dtype = q.dtype if out_dtype is None else out_dtype
     S, W, h, dh = q.shape
     B = pool_k.shape[1]
@@ -256,24 +336,22 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
         # one gathered [S, bs*B, h, dh] group (same NEG_INF masking,
         # same l==0 guard; this is what the scan would compute, minus
         # the dead alpha/acc-renorm work of a length-1 carry)
-        kb = pool_k[tbl].reshape(S, (NB + pad) * B, h, dh)
-        vb = pool_v[tbl].reshape(S, (NB + pad) * B, h, dh)
-        s = jnp.einsum("swhd,sthd->swht", q, kb,
+        kb, vb = gather(tbl, NB + pad)
+        s = jnp.einsum(qk, q, kb,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(visible(off), s, NEG_INF)
         p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
         l = jnp.sum(p, axis=-1)
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        ctx = jnp.einsum("swht,sthd->swhd", p, vb.astype(jnp.float32))
+        ctx = jnp.einsum(pv, p, vb.astype(jnp.float32))
         return done(ctx / l_safe[..., None])
 
     def step(carry, i):
         m, l, acc = carry
         blk = jax.lax.dynamic_slice_in_dim(tbl, i * bs, bs, 1)  # [S, bs]
-        kb = pool_k[blk].reshape(S, bs * B, h, dh)
-        vb = pool_v[blk].reshape(S, bs * B, h, dh)
+        kb, vb = gather(blk, bs)
         tok = i * (bs * B) + off                                # [bs*B]
-        s = jnp.einsum("swhd,sthd->swht", q, kb,
+        s = jnp.einsum(qk, q, kb,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(visible(tok), s, NEG_INF)
         m2 = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -281,12 +359,12 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
         p = jnp.exp(s - m2[..., None])
         l2 = l * alpha + jnp.sum(p, axis=-1)
         acc2 = acc * alpha[..., None] + jnp.einsum(
-            "swht,sthd->swhd", p, vb.astype(jnp.float32))
+            pv, p, vb.astype(jnp.float32))
         return (m2, l2, acc2), None
 
     m0 = jnp.full((S, W, h), NEG_INF, jnp.float32)
     l0 = jnp.zeros((S, W, h), jnp.float32)
-    a0 = jnp.zeros((S, W, h, dh), jnp.float32)
+    a0 = jnp.zeros((S, W, h, dv), jnp.float32)
     nsteps = (NB + pad) // bs
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, a0), jnp.arange(nsteps, dtype=jnp.int32))
@@ -306,9 +384,37 @@ def _block_is_sliceable(pool):
     return pool.dtype.itemsize >= 4 or pool.shape[2] % 8 == 0
 
 
+def _matmul(a, b, dims):
+    """``a x b`` contracting ``dims`` on the MXU (inside a Mosaic
+    kernel): f32 out of exact products (``HIGHEST`` where an operand is
+    float32)."""
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                   else None))
+
+
+def _weigh(p, vb):
+    """``p [N, tokens] x vb [tokens, dv]`` in ONE MXU pass at the
+    float32 accuracy of ``p``.  A bfloat16 pool takes ``p`` as the three
+    bfloat16 pieces that sum to it exactly (8 + 8 + 8 bits of its 24),
+    stacked on the row axis: their products with ``vb`` are exact in
+    f32, so nothing is rounded that the VPU kept."""
+    f32 = jnp.float32
+    if vb.dtype != jnp.bfloat16:
+        return _matmul(p, vb.astype(f32), ((1,), (0,)))
+    N = p.shape[0]
+    pieces = []
+    for _ in range(3):
+        pieces.append(p.astype(jnp.bfloat16))
+        p = p - pieces[-1].astype(f32)
+    out = _matmul(jnp.concatenate(pieces, axis=0), vb, ((1,), (0,)))
+    return out[:N] + out[N:2 * N] + out[2 * N:]
+
+
 def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
                            interpret=None, group=1, window=None, scale=None,
-                           out_dtype=None):
+                           out_dtype=None, value_lanes=None):
     """The Mosaic kernel: it visits the LIVE entries of each slot's chain
     and no others.  The block TABLE and the query POSITIONS are the
     scalar-prefetch arguments (SMEM).  Slot ``s`` has ``n_s = clip(max_w
@@ -391,6 +497,11 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     del block_step
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if pool_v is None:
+        return latent_attention_pallas(
+            q, pool_k, table, pos,
+            _latent_plane(q, pool_k, group, window, value_lanes),
+            scale=scale, out_dtype=out_dtype, interpret=interpret)
     q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
     out_dtype = q.dtype if out_dtype is None else out_dtype
     S, W, h, dh = q.shape
@@ -453,36 +564,13 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         m_ref[0] = jnp.broadcast_to(m2, (h, LSE_LANES))
         l_ref[0] = jnp.broadcast_to(l2, (h, LSE_LANES))
 
-    def matmul(a, b, dims):
-        """``a x b`` contracting ``dims`` on the MXU: f32 out of exact
-        products (``HIGHEST`` where an operand is float32)."""
-        return jax.lax.dot_general(
-            a, b, (dims, ((), ())), preferred_element_type=f32,
-            precision=(jax.lax.Precision.HIGHEST if a.dtype == f32
-                       else None))
-
-    def weigh(p, vb):
-        """``p [N, B * h] x vb [B * h, dh]`` in ONE MXU pass at the
-        float32 accuracy of ``p``.  A bfloat16 pool takes ``p`` as the
-        three bfloat16 pieces that sum to it exactly (8 + 8 + 8 bits of
-        its 24), stacked on the row axis: their products with ``vb`` are
-        exact in f32, so nothing is rounded that the VPU kept."""
-        if vb.dtype != jnp.bfloat16:
-            return matmul(p, vb.astype(f32), ((1,), (0,)))
-        pieces = []
-        for _ in range(3):
-            pieces.append(p.astype(jnp.bfloat16))
-            p = p - pieces[-1].astype(f32)
-        out = matmul(jnp.concatenate(pieces, axis=0), vb, ((1,), (0,)))
-        return out[:N] + out[N:2 * N] + out[2 * N:]
-
     def scores(i, kb, s_id, pos_ref, q_ref):
         """Every row's masked scores against block ``i`` of slot
         ``s_id``'s chain, ``[N, B * h]``, and each row's maximum."""
         # every row against every token and head of the block in ONE MXU
         # pass; a row's own head is every h-th lane
         dt = jnp.promote_types(kb.dtype, q_ref.dtype)
-        s = matmul(q_ref[0].reshape(N, dh).astype(dt),
+        s = _matmul(q_ref[0].reshape(N, dh).astype(dt),
                    kb.reshape(B * h, dh).astype(dt), ((1,), (1,))) * scale
         # row (w, j) keeps the lanes of head j whose token its position
         # lets through: token t <= at is lane < (at + 1) * h
@@ -509,7 +597,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         # the product below sums over all of them
         p = jnp.exp(s - jnp.where(m2 == NEG_INF, 0.0, m2))
         l2 = l_ref[...][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + weigh(p, vb.reshape(B * h, dh))
+        acc_ref[...] = acc_ref[...] * alpha + _weigh(p, vb.reshape(B * h, dh))
         m_ref[...] = jnp.broadcast_to(m2, (N, LSE_LANES))
         l_ref[...] = jnp.broadcast_to(l2, (N, LSE_LANES))
 
@@ -691,6 +779,151 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     return unfold(ctx)
 
 
+def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
+                            out_dtype=None, interpret=None,
+                            blocks=LATENT_BLOCKS):
+    """The Mosaic kernel of a LATENT plane, a sibling of the loop above
+    under its own name (``paged_latent_attention``): ``pool [blocks, B,
+    L]`` holds ONE row a cached position, every one of the ``h`` query
+    heads of ``q [S, W, h, L]`` reads it whole, and a position's value
+    is its row's first ``value_lanes`` lanes, so nothing is fetched
+    twice and no lane belongs to another head.  ``[S, W, h,
+    value_lanes]`` comes back.
+
+    Grid ``(S,)``, one step a slot, a loop over the slot's LIVE entries
+    (``n_s`` as above; a latent plane has no lower bound) taken
+    ``blocks`` at a time: one iteration copies ``blocks`` table entries
+    side by side into one ``[blocks * B, L]`` buffer (``DEPTH`` such
+    buffers, ``DEPTH - 1`` groups on their way), scores all ``N = W *
+    h`` rows against it in ONE MXU pass ``[N, L] x [blocks * B, L]^T``,
+    masks by token (row ``w * h + a`` keeps ``j <= pos[s, w]``), makes
+    ONE softmax update and weighs the values in a second pass ``p x
+    buffer[:, :value_lanes]`` (``_weigh``: float32 accuracy).  As in the
+    loop above a group's scores are made while the group before it is
+    weighed.  What an iteration costs is the latency of that chain, not
+    its work (PERF.md, PR 35), so several blocks an iteration divide it
+    (``LATENT_BLOCKS`` has the chip's table).  The last group's
+    entries past ``n_s`` are fetched too (whatever the table names
+    there, the trash block for an entry never used: finite values under
+    a zero weight), so that no lane of the buffer holds what was never
+    written.  A slot whose rows are all at ``pos < 0`` fetches nothing
+    and returns zeros."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    out_dtype = q.dtype if out_dtype is None else out_dtype
+    S, W, h, L = q.shape
+    B, NB = pool.shape[1], table.shape[1]
+    G = max(1, min(int(blocks), NB))
+    T, N, dv = G * B, W * h, int(value_lanes)
+    if scale is None:
+        scale = 1.0 / float(L) ** 0.5
+    f32 = jnp.float32
+
+    def kernel(tbl, pos_ref, q_ref, pool_hbm, o_ref, buf, sem, s_ref,
+               peak_ref, m_ref, l_ref, acc_ref):
+        s_id = pl.program_id(0)
+        top = pos_ref[s_id, 0]
+        for w in range(1, W):
+            top = jnp.maximum(top, pos_ref[s_id, w])
+        live = jnp.minimum(jax.lax.div(jnp.maximum(top, -1) + B, B), NB)
+        groups = jax.lax.div(live + G - 1, G)
+
+        def copies(g):
+            slot = jax.lax.rem(g, DEPTH)
+            return [pltpu.make_async_copy(
+                pool_hbm.at[tbl[s_id, jnp.minimum(g * G + j, NB - 1)]],
+                buf.at[slot, pl.ds(j * B, B)], sem.at[slot, j])
+                for j in range(G)]
+
+        def score(g):
+            kb = buf[jax.lax.rem(g, DEPTH)]                    # [T, L]
+            dt = jnp.promote_types(kb.dtype, q_ref.dtype)
+            s = _matmul(q_ref[0].astype(dt), kb.astype(dt),
+                        ((1,), (1,))) * scale                  # [N, T]
+            row = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
+            at = jnp.full((N, 1), pos_ref[s_id, 0])
+            for w in range(1, W):
+                at = jnp.where(row >= w * h, pos_ref[s_id, w], at)
+            tok = g * T + jax.lax.broadcasted_iota(jnp.int32, (N, T), 1)
+            s = jnp.where(tok <= at, s, NEG_INF)
+            s_ref[...] = s
+            peak_ref[...] = jnp.broadcast_to(
+                jnp.max(s, axis=-1, keepdims=True), (N, LSE_LANES))
+
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for ahead in range(DEPTH - 1):
+            @pl.when(ahead < groups)
+            def _start(ahead=ahead):
+                for c in copies(ahead):
+                    c.start()
+
+        @pl.when(groups > 0)
+        def _first():
+            for c in copies(0):
+                c.wait()
+            score(0)
+
+        def group(g, _):
+            @pl.when(g + DEPTH - 1 < groups)
+            def _ahead():
+                for c in copies(g + DEPTH - 1):
+                    c.start()
+
+            @pl.when(g + 1 < groups)
+            def _next():
+                for c in copies(g + 1):
+                    c.wait()
+
+            s, peak = s_ref[...], peak_ref[...][:, :1]
+            # the last group's scores are made once more and dropped: no
+            # branch between the two chains
+            score(jnp.minimum(g + 1, groups - 1))
+            m = m_ref[...][:, :1]
+            m2 = jnp.maximum(m, peak)
+            alpha = jnp.exp(m - m2)
+            # a lane a row does not keep weighs EXACTLY zero, also while
+            # the row has seen no key (the loop above)
+            p = jnp.exp(s - jnp.where(m2 == NEG_INF, 0.0, m2))
+            l2 = (l_ref[...][:, :1] * alpha
+                  + jnp.sum(p, axis=-1, keepdims=True))
+            acc_ref[...] = acc_ref[...] * alpha + _weigh(
+                p, buf[jax.lax.rem(g, DEPTH)][:, :dv])
+            m_ref[...] = jnp.broadcast_to(m2, (N, LSE_LANES))
+            l_ref[...] = jnp.broadcast_to(l2, (N, LSE_LANES))
+
+        jax.lax.fori_loop(0, groups, group, None)
+        l = l_ref[...][:, :1]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+    ctx = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(S,),
+            in_specs=[pl.BlockSpec((1, N, L), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, N, dv), lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((DEPTH, T, L), pool.dtype),
+                pltpu.SemaphoreType.DMA((DEPTH, G)),
+                pltpu.VMEM((N, T), f32), pltpu.VMEM((N, LSE_LANES), f32),
+                pltpu.VMEM((N, LSE_LANES), f32),
+                pltpu.VMEM((N, LSE_LANES), f32), pltpu.VMEM((N, dv), f32)]),
+        out_shape=jax.ShapeDtypeStruct((S, N, dv), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=bool(interpret),
+        name="paged_latent_attention",
+    )(table.astype(jnp.int32), pos.astype(jnp.int32), q.reshape(S, N, L),
+      pool)
+    return ctx.reshape(S, W, h, dv)
+
+
 def write(pool, blk, off, rows):
     """``rows [*blk.shape, heads, dh]`` written into ``pool [blocks, B,
     hk, dh]`` at ``(blk, off)`` (a decode step's ``[S]`` indices or a
@@ -699,7 +932,14 @@ def write(pool, blk, off, rows):
     past ``heads`` are written as zeros.  A write of PART of the head
     axis compiles to a serial loop over the written rows with one
     ``dynamic-update-slice`` each, 4.2 us a row on a v5e where the whole
-    row costs 0.13 (PERF.md, PR 37)."""
+    row costs 0.13 (PERF.md, PR 37).  A LATENT plane (``pool [blocks, B,
+    L]``, ``rows [*blk.shape, values]``) has no head axis: the lanes past
+    the ``values`` a row carries are written as zeros."""
+    if pool.ndim == 3:
+        spare = pool.shape[2] - rows.shape[-1]
+        if spare:
+            rows = jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, spare),))
+        return pool.at[blk, off].set(rows)
     spare = pool.shape[2] - rows.shape[-2]
     if spare:
         rows = jnp.pad(rows, ((0, 0),) * (rows.ndim - 2)

@@ -50,6 +50,12 @@ ORACLE_TOL = {
     # against the same dense-softmax reference, per block chain
     ("paged_attention", "float32"): {"fwd": 2e-4, "grad": None},
     ("paged_attention", "bfloat16"): {"fwd": 2e-2, "grad": None},
+    # the latent plane's kernel (``pool_v=None``) against the same dense
+    # softmax: the same online-softmax reassociation, taken several
+    # blocks an update, and the weights kept at float32 accuracy as
+    # three bfloat16 pieces; the bounds are paged_attention's
+    ("paged_latent_attention", "float32"): {"fwd": 2e-4, "grad": None},
+    ("paged_latent_attention", "bfloat16"): {"fwd": 2e-2, "grad": None},
     # the grouped matrix product is inference-only: the two backends
     # multiply the same rows by the same matrices and differ by the
     # order of one float32 sum over k (float32: a few ulp of a sum of k

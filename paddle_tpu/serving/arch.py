@@ -34,6 +34,9 @@ scalar inside a loop over passes.  ``how`` is what
 ``kernels.paged_attention.attend`` takes beyond the table: ``group``,
 ``window``, ``scale``, ``out_dtype``.  With ``k`` and ``v`` ``None``
 nothing is written: a READ-ONLY attend of a plane another layer wrote.
+With ``v`` alone ``None`` the plane is a latent one (``pool_arrays``
+1): ``k [..., values]`` is the one row written, and ``how`` carries
+``value_lanes``.
 
 It has a STATE side for per-slot recurrent state, ``planes``' third
 member, one tuple of arrays per state layer::
@@ -55,7 +58,7 @@ adds to what the compiled decode chunk and prefill piece return as their
 last output (``arch.count_names`` names the entries; an architecture
 with none never calls it and its programs have no such output).
 
-Four architectures are here: ``Gpt2`` (the block of
+Five architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -76,6 +79,14 @@ ROUTED one, a shared expert beside the share ``(first, count)`` of the
 router's experts that this chip holds, every row routed over all of
 them and none dropped (``route``, ``kernels/grouped_matmul.py``);
 ``models/gated_moe_reference.py`` is its plain reference.
+``LatentMoE`` caches no K and no V at all: a position holds ONE latent
+row a layer (512 + 64 values in the published model) in a pool array
+with no head axis (``pool_arrays`` 1, ``attend(.., pool_v=None,
+value_lanes=..)``), read by every head through queries absorbed into
+the latent's width; its routed layers are ``GatedMoE``'s
+(``routed_ffn``, ``route`` with softmax scores left unnormalised);
+``models/latent_moe_reference.py`` is its plain reference, in the
+per-head form.
 """
 
 import jax
@@ -91,7 +102,8 @@ from ..kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
-           "route", "STACK_SCOPE"]
+           "LatentMoE", "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
+           "MOE_COUNTS", "STACK_SCOPE"]
 
 
 class Architecture:
@@ -107,6 +119,13 @@ class Architecture:
     # layer holds here (``GatedMoE``); span attributes of the engine
     moe_layers = 0
     experts_held = 0
+    # arrays a plane holds: a K and a V array, or ONE where a position's
+    # value is lanes of its key row (a latent plane); and, of the planes,
+    # how many are latent and in which form their attention runs (span
+    # attributes of the engine)
+    pool_arrays = 2
+    latent_planes = 0
+    attn_form = None
 
     def __init__(self, n_layer, n_head, d_model, passes=1, head_dim=None):
         if head_dim is None:
@@ -163,17 +182,27 @@ class Architecture:
         return 1
 
     def pool_block_shape(self, block_tokens, dtype):
-        """``[block_tokens, rows, lanes]`` of one block of a pool array
-        in ``dtype``."""
+        """The shape of one block of a pool array in ``dtype``:
+        ``[block_tokens, rows, lanes]`` (K/V heads on rows), or
+        ``[block_tokens, lanes]`` for a plane with no head axis."""
         return (block_tokens, self.kv_heads, self.head_dim)
 
+    @property
+    def written_values(self):
+        """Values of one position that a write puts into ONE pool array
+        (what ``pool_block_shape`` holds beyond them is zeros)."""
+        return self.kv_heads * self.head_dim
+
     def kv_block_bytes(self, block_tokens, itemsize):
-        """Bytes one block of one plane holds: K and V of
-        ``block_tokens`` positions."""
-        return 2 * block_tokens * self.kv_heads * self.head_dim * itemsize
+        """Bytes one block of one plane holds of what the model caches:
+        ``written_values`` of ``block_tokens`` positions in each of the
+        plane's ``pool_arrays``.  An architecture whose plane is shaped
+        otherwise says so itself."""
+        return (self.pool_arrays * block_tokens * self.written_values
+                * itemsize)
 
     def kv_bytes_per_token(self, itemsize):
-        """K and V of one cached token across all its planes."""
+        """What one cached token holds across all its planes."""
         return self.kv_planes * self.kv_block_bytes(1, itemsize)
 
     def state_spec(self, dtype):
@@ -694,26 +723,165 @@ class SambaY(Architecture):
                               preferred_element_type=jnp.float32)
 
 
-def route(h, w_router, bias, top_k, scale):
-    """The routing of a sigmoid top-k router, written once: rows ``h
-    [..., d]``, ``w_router [d, E]``, ``bias [E]`` -> ``(sel [..., top_k]
+def route(h, w_router, bias, top_k, scale, score="sigmoid", normalise=True):
+    """The routing of a top-k router, written once: rows ``h [..., d]``,
+    ``w_router [d, E]``, ``bias [E]`` or ``None`` -> ``(sel [..., top_k]
     int32, w [..., top_k] float32)``.
 
-    Scores are ``sigmoid(h W_r)`` in float32 (the product accumulates in
-    float32 whatever the rows' dtype); the bias SELECTS only (``top_k``
-    of ``score + bias``) and is not in the weight; the weights are the
-    selected scores over their sum (``+ 1e-20``) times ``scale``,
-    normalised over all ``top_k`` whether or not this chip holds the
-    expert.  No capacity, no group limit, nothing dropped."""
-    s = jax.nn.sigmoid(jnp.matmul(h, w_router,
-                                  preferred_element_type=jnp.float32))
-    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    Scores are ``sigmoid(h W_r)`` or (``score="softmax"``) ``softmax(h
+    W_r)`` over all ``E`` experts, in float32 (the product accumulates
+    in float32 whatever the rows' dtype); the bias SELECTS only
+    (``top_k`` of ``score + bias``) and is not in the weight; the
+    weights are the selected scores, over their sum (``+ 1e-20``) where
+    ``normalise`` (over all ``top_k`` whether or not this chip holds the
+    expert), times ``scale``.  No capacity, no group limit, nothing
+    dropped."""
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"route: score {score!r} is not 'sigmoid' or "
+                         f"'softmax'")
+    act = jax.nn.sigmoid if score == "sigmoid" else jax.nn.softmax
+    s = act(jnp.matmul(h, w_router, preferred_element_type=jnp.float32))
+    _, sel = jax.lax.top_k(
+        s if bias is None else s + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(s, sel, axis=-1)
-    w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * scale
-    return sel.astype(jnp.int32), w
+    if normalise:
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), picked * scale
 
 
-class GatedMoE(Architecture):
+MOE_COUNTS = ("moe_rows", "moe_assignments_held", "moe_experts_touched",
+              "moe_expert_visits")
+
+
+def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
+               normalise=True, bias=True):
+    """The routed FFN both routed architectures run, over rows ``h [...,
+    d]``: ``(y, counts)``.  ``w(name)`` gives the layer's ``router.w
+    [d, width]`` (and ``router.bias`` where ``bias``), ``shared_gate.w``,
+    ``shared_up.w``, ``shared_down.w`` and the held experts stacked,
+    ``experts_gate.w``, ``experts_up.w [count, d, e]``,
+    ``experts_down.w [count, e, d]``; ``experts = (first, count)`` is
+    this chip's share of the router's width; ``score``, ``normalise``
+    and ``scale`` are ``route``'s.
+
+    Every row is routed over all the router's experts, the held ones add
+    their weighted part for the rows that selected them and the shared
+    expert adds its own for every row.  No capacity and no dropped token:
+    the rows are gathered by expert and go through
+    ``kernels.grouped_matmul`` three times (gate, up, down), one buffer
+    of ``rows x top_k`` rows whatever the routing.  ``counts`` is
+    ``MOE_COUNTS``: the live rows, the row-expert pairs that fell on a
+    held expert, the held experts with at least one live row, and
+    ``count``."""
+    f32, i32 = jnp.float32, jnp.int32
+    first, count = experts
+    k, d = top_k, h.shape[-1]
+    rows = h.reshape(-1, d)
+    valid = attend.valid.reshape(-1)
+    with sublayer("moe.route"):
+        sel, weight = route(rows, w("router.w"),
+                            w("router.bias") if bias else None, k, scale,
+                            score, normalise)
+        # a pair (row, selection) is HELD where the selected expert
+        # is one of this chip's and the row is real: a dead slot's
+        # row, a window's padding touch no expert
+        held = ((sel >= first) & (sel < first + count)
+                & valid[:, None])
+        expert = jnp.where(held, sel - first, count).reshape(-1)
+        # the held pairs first, those of one expert together
+        order = jnp.argsort(expert, stable=True)
+        sizes = jnp.sum(expert[:, None] == jnp.arange(count, dtype=i32),
+                        axis=0, dtype=i32)
+        gathered = rows[order // k]
+    with sublayer("moe.experts"):
+        act = (jax.nn.silu(_grouped_matmul(gathered, w("experts_gate.w"),
+                                           sizes))
+               * _grouped_matmul(gathered, w("experts_up.w"), sizes))
+        out = _grouped_matmul(act, w("experts_down.w"), sizes)
+        # back to (row, selection) order, weighted; a pair that is
+        # not held has a zero row of the product and a zero weight
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        y = jnp.sum(out[back].reshape(-1, k, d).astype(f32)
+                    * jnp.where(held, weight, 0.0)[..., None], axis=1)
+    with sublayer("moe.shared"):
+        shared = _gated_silu(h, w("shared_gate.w"), w("shared_up.w"),
+                             w("shared_down.w"))
+    counts = jnp.stack([jnp.sum(valid, dtype=i32),
+                        jnp.sum(held, dtype=i32),
+                        jnp.sum(sizes > 0, dtype=i32),
+                        jnp.asarray(count, i32)])
+    return shared + y.astype(h.dtype).reshape(h.shape), counts
+
+
+def _check_share(name, experts, router_width, top_k):
+    """``(first, count)`` of a routed architecture's share, checked."""
+    first, count = (int(v) for v in experts)
+    if not (0 <= first and 1 <= count and first + count <= router_width):
+        raise ValueError(
+            f"{name}: the share ({first}, {count}) is not "
+            f"inside a router of {router_width} experts")
+    if not 1 <= top_k <= count:
+        # the buffer of gathered rows is rows x top_k: a row's
+        # selections are distinct experts, so no more of them than
+        # top_k (and than count) can be held
+        raise ValueError(f"{name}: top_k {top_k} must lie in "
+                         f"[1, experts held {count}]")
+    return first, count
+
+
+def _moe_gauges(arch, params):
+    """The ``serving.moe_*`` gauges of a routed architecture."""
+    if not arch.moe_layers:
+        return {}
+    last = arch.n_layer - 1
+    one_expert = sum(
+        int(np.prod(np.shape(params[f"block{last}_experts_{m}.w"])[1:]))
+        * params[f"block{last}_experts_{m}.w"].dtype.itemsize
+        for m in ("gate", "up", "down"))
+    return {
+        "moe_layers": (arch.moe_layers, "layers whose FFN is routed"),
+        "moe_experts_held": (
+            arch.experts_held, "routed experts a routed layer holds "
+            "HERE (the chip's share of the router's)"),
+        "moe_router_width": (
+            arch.router_width, "experts a row is routed over (held "
+            "here or not)"),
+        "moe_top_k": (arch.top_k, "experts a row selects"),
+        "moe_expert_bytes": (
+            one_expert, "bytes of ONE routed expert's three matrices"),
+    }
+
+
+class _Routed:
+    """What both routed architectures say of their share of the router's
+    experts (``self.experts = (first, count)``, ``router_width``,
+    ``top_k``, ``dense_layers``): the counts their stacks tally, the
+    span attributes and gauges of the engine, and the check that the
+    parameters hold that share."""
+
+    count_names = MOE_COUNTS
+
+    @property
+    def moe_layers(self):
+        return self.n_layer - self.dense_layers
+
+    @property
+    def experts_held(self):
+        return self.experts[1] if self.moe_layers else 0
+
+    def _check_experts(self, params):
+        last = self.n_layer - 1
+        held = np.shape(params[f"block{last}_experts_down.w"])[0]
+        width = np.shape(params[f"block{last}_router.w"])[1]
+        if (held, width) != (self.experts[1], self.router_width):
+            raise ValueError(
+                f"{self.name}: parameters hold {held} experts under a "
+                f"router of {width}; the architecture says "
+                f"{self.experts[1]} of {self.router_width}")
+
+
+class GatedMoE(_Routed, Architecture):
     """Sandwich-normed layers of gated grouped-query attention and a
     dense or ROUTED gated-SiLU FFN (the ``afmoe`` layout of Arcee's
     Trinity models; ``models/gated_moe_reference.py`` writes the
@@ -766,8 +934,6 @@ class GatedMoE(Architecture):
     """
 
     name = "gated_moe"
-    count_names = ("moe_rows", "moe_assignments_held", "moe_experts_touched",
-                   "moe_expert_visits")
 
     def __init__(self, layer_types, n_head, kv_heads, head_dim, d_model,
                  window, dense_layers, router_width, top_k, experts,
@@ -784,18 +950,7 @@ class GatedMoE(Architecture):
         if self.head_dim % 2:
             raise ValueError(f"rotary positions need an even head_dim, "
                              f"got {self.head_dim}")
-        first, count = (int(v) for v in experts)
-        if not (0 <= first and 1 <= count
-                and first + count <= router_width):
-            raise ValueError(
-                f"{self.name}: the share ({first}, {count}) is not "
-                f"inside a router of {router_width} experts")
-        if not 1 <= top_k <= count:
-            # the buffer of gathered rows is rows x top_k: a row's
-            # selections are distinct experts, so no more of them than
-            # top_k (and than count) can be held
-            raise ValueError(f"{self.name}: top_k {top_k} must lie in "
-                             f"[1, experts held {count}]")
+        experts = _check_share(self.name, experts, router_width, top_k)
         if not 0 <= dense_layers <= self.n_layer:
             raise ValueError(f"{self.name}: dense_layers {dense_layers} "
                              f"of {self.n_layer} layers")
@@ -803,7 +958,7 @@ class GatedMoE(Architecture):
         self._kv_heads, self.window = int(kv_heads), int(window)
         self.dense_layers = int(dense_layers)
         self.router_width, self.top_k = int(router_width), int(top_k)
-        self.experts = (first, count)
+        self.experts = experts
         self.route_scale = float(route_scale)
         self.eps, self.rope_theta = eps, float(rope_theta)
 
@@ -820,34 +975,8 @@ class GatedMoE(Architecture):
     def rows_per_entry(self):
         return self.n_head // self.kv_heads
 
-    @property
-    def moe_layers(self):
-        return self.n_layer - self.dense_layers
-
-    @property
-    def experts_held(self):
-        return self.experts[1] if self.moe_layers else 0
-
     def gauges(self, params):
-        if not self.moe_layers:
-            return {}
-        last = self.n_layer - 1
-        one_expert = sum(
-            int(np.prod(np.shape(params[f"block{last}_experts_{m}.w"])[1:]))
-            * params[f"block{last}_experts_{m}.w"].dtype.itemsize
-            for m in ("gate", "up", "down"))
-        return {
-            "moe_layers": (self.moe_layers, "layers whose FFN is routed"),
-            "moe_experts_held": (
-                self.experts_held, "routed experts a routed layer holds "
-                "HERE (the chip's share of the router's)"),
-            "moe_router_width": (
-                self.router_width, "experts a row is routed over (held "
-                "here or not)"),
-            "moe_top_k": (self.top_k, "experts a row selects"),
-            "moe_expert_bytes": (
-                one_expert, "bytes of ONE routed expert's three matrices"),
-        }
+        return _moe_gauges(self, params)
 
     def pool_block_shape(self, block_tokens, dtype):
         return (block_tokens, _paged.pool_rows(self.kv_heads, dtype),
@@ -869,13 +998,7 @@ class GatedMoE(Architecture):
             raise ValueError(f"{self.name}: parameters lack "
                              f"{', '.join(missing)}")
         if self.moe_layers:
-            held = np.shape(params[f"block{last}_experts_down.w"])[0]
-            width = np.shape(params[f"block{last}_router.w"])[1]
-            if (held, width) != (self.experts[1], self.router_width):
-                raise ValueError(
-                    f"{self.name}: parameters hold {held} experts under a "
-                    f"router of {width}; the architecture says "
-                    f"{self.experts[1]} of {self.router_width}")
+            self._check_experts(params)
 
     def embed(self, p, toks, pos):
         table = p["tok_emb.w"]
@@ -910,47 +1033,6 @@ class GatedMoE(Architecture):
         with sublayer("norm"):
             return _rms(o, w("norm2.scale"), self.eps), planes
 
-    def _routed(self, w, h, attend):
-        """The routed FFN over rows ``h [..., d]``: ``(y, counts)``."""
-        f32, i32 = jnp.float32, jnp.int32
-        first, count = self.experts
-        k, d = self.top_k, h.shape[-1]
-        rows = h.reshape(-1, d)
-        valid = attend.valid.reshape(-1)
-        with sublayer("moe.route"):
-            sel, weight = route(rows, w("router.w"), w("router.bias"), k,
-                                self.route_scale)
-            # a pair (row, selection) is HELD where the selected expert
-            # is one of this chip's and the row is real: a dead slot's
-            # row, a window's padding touch no expert
-            held = ((sel >= first) & (sel < first + count)
-                    & valid[:, None])
-            expert = jnp.where(held, sel - first, count).reshape(-1)
-            # the held pairs first, those of one expert together
-            order = jnp.argsort(expert, stable=True)
-            sizes = jnp.sum(expert[:, None] == jnp.arange(count, dtype=i32),
-                            axis=0, dtype=i32)
-            gathered = rows[order // k]
-        with sublayer("moe.experts"):
-            act = (jax.nn.silu(_grouped_matmul(gathered, w("experts_gate.w"),
-                                               sizes))
-                   * _grouped_matmul(gathered, w("experts_up.w"), sizes))
-            out = _grouped_matmul(act, w("experts_down.w"), sizes)
-            # back to (row, selection) order, weighted; a pair that is
-            # not held has a zero row of the product and a zero weight
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(order.shape[0], dtype=order.dtype))
-            y = jnp.sum(out[back].reshape(-1, k, d).astype(f32)
-                        * jnp.where(held, weight, 0.0)[..., None], axis=1)
-        with sublayer("moe.shared"):
-            shared = _gated_silu(h, w("shared_gate.w"), w("shared_up.w"),
-                                 w("shared_down.w"))
-        counts = jnp.stack([jnp.sum(valid, dtype=i32),
-                            jnp.sum(held, dtype=i32),
-                            jnp.sum(sizes > 0, dtype=i32),
-                            jnp.asarray(count, i32)])
-        return shared + y.astype(h.dtype).reshape(h.shape), counts
-
     def stack(self, p, x, pos, planes, attend):
         with sublayer("attn.proj"):
             rope = _rope_angles(pos, self.head_dim, self.rope_theta)
@@ -965,10 +1047,247 @@ class GatedMoE(Architecture):
                     ff = _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
                                      w("ffn_down.w"))
             else:
-                ff, counts = self._routed(w, m, attend)
+                ff, counts = routed_ffn(w, m, attend, self.experts,
+                                        self.top_k, self.route_scale)
                 attend.tally(counts)
             with sublayer("norm"):
                 x = x + _rms(ff, w("norm4.scale"), self.eps)
+        return x, planes
+
+    def head(self, p, x):
+        with sublayer("head"):
+            return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
+                              p["lm_head.w"],
+                              preferred_element_type=jnp.float32)
+
+
+def yarn_mscale(factor, m):
+    """YaRN's attention scale ``0.1 m ln(factor) + 1`` (1 for a factor
+    of 1 or less)."""
+    return 1.0 if factor <= 1 else 0.1 * m * float(np.log(factor)) + 1.0
+
+
+def yarn_inv_freq(lanes, theta, factor, original, beta_fast, beta_slow):
+    """The ``lanes / 2`` rotary frequencies under YaRN (float32 NumPy):
+    frequency ``i`` is ``theta ** (-2 i / lanes)`` where it turns more
+    than ``beta_fast`` times over the ``original`` positions (kept),
+    that over ``factor`` where it turns fewer than ``beta_slow`` times
+    (interpolated), and a linear blend between the two bounds ``lo``,
+    ``hi`` (floor and ceiling of ``lanes ln(original / (beta 2 pi)) /
+    (2 ln theta)``, clipped to the lanes)."""
+    extra = theta ** (-np.arange(0, lanes, 2, dtype=np.float64) / lanes)
+    if factor <= 1:
+        return extra.astype(np.float32)
+
+    def bound(beta):
+        return lanes * np.log(original / (beta * 2 * np.pi)) / (
+            2 * np.log(theta))
+
+    lo = max(int(np.floor(bound(beta_fast))), 0)
+    hi = min(int(np.ceil(bound(beta_slow))), lanes - 1)
+    ramp = np.clip((np.arange(lanes // 2) - lo)
+                   / (hi - lo if hi > lo else 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (extra / factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+class LatentMoE(_Routed, Architecture):
+    """Pre-normed layers of LATENT attention and a dense or ROUTED
+    gated-SiLU FFN (the ``deepseek_v2`` layout, arXiv:2405.04434;
+    ``models/latent_moe_reference.py`` writes the equations down in
+    their plain per-head form and lists what the published configuration
+    has no key for).
+
+    **What is cached.**  A position holds, a layer, ONE row: the normed
+    latent ``c = RMS_kv(h W_kva[:, :rank])`` (``rank`` values) and the
+    one rotary key ``k_pe = rope(h W_kva[:, rank:])`` (``rope_dim``
+    values) that every head shares: ``rank + rope_dim`` values in one
+    pool array ``[blocks, B, L]`` with no head axis and no V array
+    (``pool_arrays`` 1; ``L = kernels.paged_attention.latent_lanes``:
+    the next multiple of 128, the lanes past the values zeros).
+
+    **How it is attended: absorbed**, decode step and prefill piece
+    alike (``attn_form``).  With ``W_kvb [rank, n_head * (nope_dim +
+    v_dim)]`` read as ``W_UK_a [rank, nope_dim] | W_UV_a [rank, v_dim]``
+    a head, a query head becomes ``q_lat_a = q_nope_a W_UK_a^T`` beside
+    its rotated ``q_pe_a``: one row of ``rank + rope_dim`` lanes whose
+    dot with a cached row IS the head's score, ``(q_nope_a . k_nope_a(j)
+    + q_pe_a . k_pe(j))``.  ``attend(.., pool_v=None, value_lanes=rank)``
+    weighs the cached rows' first ``rank`` lanes, ``u_a = sum_j P_j
+    c(j)``, and ``o_a = u_a W_UV_a``: no per-head K or V of the context
+    is ever made.  At a 128-row piece over 8,300 cached positions the
+    absorbed form multiplies 39 GFLOP a layer where expanding the
+    gathered latents through ``W_kvb`` multiplies 46 (35 of them the
+    expansion, again for every piece), so one form serves both widths.
+
+    Rotary positions take YaRN's frequencies (``yarn_inv_freq``) over
+    the ``rope_dim`` lanes, the halves convention of ``_rope``; scores
+    are scaled by ``(nope_dim + rope_dim) ** -0.5 * yarn_mscale(factor,
+    mscale_all_dim) ** 2``.
+
+    The first ``dense_layers`` layers have a dense FFN, the others the
+    routed one ``GatedMoE`` has (``routed_ffn``): ``route`` with softmax
+    scores, the selected weights NOT normalised, no bias, ``experts =
+    (first, count)`` this chip's share of ``router_width``; the shared
+    experts are one gated MLP.  The stack tallies ``MOE_COUNTS``.
+
+    Parameter names: ``tok_emb.w [V, d]``, ``norm_f.scale``,
+    ``lm_head.w [d, V]`` (untied); per layer ``block{i}_norm1.scale``,
+    ``att_q.w [d, n_head * (nope_dim + rope_dim)]``, ``att_kva.w [d,
+    rank + rope_dim]``, ``att_kvnorm.scale [rank]``, ``att_kvb.w [rank,
+    n_head * (nope_dim + v_dim)]``, ``att_out.w [n_head * v_dim, d]``,
+    ``norm2.scale`` (before the FFN); a dense layer ``ffn_gate.w``,
+    ``ffn_up.w [d, f]``, ``ffn_down.w [f, d]``; a routed layer
+    ``router.w [d, router_width]`` and ``routed_ffn``'s.  No biases.
+    """
+
+    name = "latent_moe"
+    pool_arrays = 1
+    attn_form = "absorbed"
+
+    def __init__(self, n_layer, n_head, d_model, rank, nope_dim, rope_dim,
+                 v_dim, dense_layers, router_width, top_k, experts,
+                 route_scale=1.0, eps=1e-6, rope_theta=10000.0,
+                 rope_factor=1.0, rope_original=4096, beta_fast=32.0,
+                 beta_slow=1.0, mscale=1.0, mscale_all_dim=0.0):
+        super().__init__(n_layer, n_head, d_model,
+                         head_dim=nope_dim + rope_dim)
+        if rope_dim % 2:
+            raise ValueError(f"rotary positions need an even rope_dim, "
+                             f"got {rope_dim}")
+        if not 0 <= dense_layers <= self.n_layer:
+            raise ValueError(f"{self.name}: dense_layers {dense_layers} "
+                             f"of {self.n_layer} layers")
+        self.rank, self.nope_dim = int(rank), int(nope_dim)
+        self.rope_dim, self.v_dim = int(rope_dim), int(v_dim)
+        self.dense_layers = int(dense_layers)
+        self.router_width, self.top_k = int(router_width), int(top_k)
+        self.experts = _check_share(self.name, experts, router_width, top_k)
+        self.route_scale, self.eps = float(route_scale), eps
+        self.inv_freq = yarn_inv_freq(self.rope_dim, float(rope_theta),
+                                      rope_factor, rope_original, beta_fast,
+                                      beta_slow)
+        # cos and sin times mscale / mscale_all_dim (1 where they are
+        # alike), the scores times mscale_all_dim's square
+        self.rope_gain = (yarn_mscale(rope_factor, mscale)
+                          / yarn_mscale(rope_factor, mscale_all_dim))
+        self.scale = (self.head_dim ** -0.5
+                      * yarn_mscale(rope_factor, mscale_all_dim) ** 2)
+        self.lanes = _paged.latent_lanes(self.written_values)
+
+    @property
+    def kv_heads(self):
+        # ONE cached row, which every head reads
+        return 1
+
+    @property
+    def rows_per_entry(self):
+        return self.n_head
+
+    @property
+    def latent_planes(self):
+        return self.n_layer
+
+    @property
+    def written_values(self):
+        return self.rank + self.rope_dim
+
+    def pool_block_shape(self, block_tokens, dtype):
+        return (block_tokens, self.lanes)
+
+    def kv_block_bytes(self, block_tokens, itemsize):
+        # what the plane STORES of a position: ``lanes``, of which
+        # ``written_values`` carry the latent and the rotary key
+        return block_tokens * self.lanes * itemsize
+
+    def gauges(self, params):
+        return dict(_moe_gauges(self, params), **{
+            "latent_planes": (self.latent_planes, "planes that cache ONE "
+                              "latent row a position (no head axis, no V "
+                              "array)"),
+            "latent_rank": (self.rank, "lanes of a cached row that are "
+                            "the normed latent: the values"),
+            "latent_rope_lanes": (self.rope_dim, "lanes of a cached row "
+                                  "that are the rotary key all heads share"),
+            "latent_lanes_stored": (self.lanes, "lanes a pool row holds: "
+                                    "latent_rank + latent_rope_lanes and "
+                                    "zeros up to the device's 128-lane "
+                                    "tile"),
+        })
+
+    def check_params(self, params, max_len):
+        last = self.n_layer - 1
+        need = ["tok_emb.w", "norm_f.scale", "lm_head.w",
+                f"block{last}_att_kva.w", f"block{last}_att_kvb.w",
+                f"block{last}_att_kvnorm.scale", f"block{last}_norm2.scale"]
+        if self.dense_layers:
+            need.append("block0_ffn_down.w")
+        if self.moe_layers:
+            need += [f"block{last}_router.w", f"block{last}_shared_down.w",
+                     f"block{last}_experts_down.w"]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+        if self.moe_layers:
+            self._check_experts(params)
+
+    def embed(self, p, toks, pos):
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks]
+
+    def _angles(self, pos):
+        ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(self.inv_freq)
+        ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]
+        return jnp.cos(ang) * self.rope_gain, jnp.sin(ang) * self.rope_gain
+
+    def _attention(self, w, i, x, rope, planes, attend):
+        rank, nope = self.rank, self.nope_dim
+        with sublayer("norm"):
+            a = _rms(x, w("norm1.scale"), self.eps)
+        lead = a.shape[:-1]
+        with sublayer("attn.proj"):
+            q = self.heads(a @ w("att_q.w"))          # [.., h, nope | rope]
+            kva = a @ w("att_kva.w")                  # [.., rank | rope]
+            c = _rms(kva[..., :rank], w("att_kvnorm.scale"), self.eps)
+            k_pe = _rope(kva[..., None, rank:], *rope)[..., 0, :]
+            kvb = w("att_kvb.w").reshape(rank, self.n_head, -1)
+            # absorb W_UK into the query: a row of the cached row's width
+            q_lat = jnp.einsum("...hn,rhn->...hr", q[..., :nope],
+                               kvb[..., :nope])
+            spare = self.lanes - self.written_values
+            q_row = jnp.concatenate(
+                [q_lat, _rope(q[..., nope:], *rope)]
+                + ([jnp.zeros((*lead, self.n_head, spare), q.dtype)]
+                   if spare else []), axis=-1)
+            row = jnp.concatenate([c, k_pe], axis=-1)
+        with sublayer("attn.core"):
+            u, planes = attend(planes, i, 0, q_row, row, None,
+                               value_lanes=rank, scale=self.scale)
+        with sublayer("attn.proj"):
+            o = jnp.einsum("...hr,rhv->...hv", u, kvb[..., nope:])
+            return o.reshape(*lead, -1) @ w("att_out.w"), planes
+
+    def stack(self, p, x, pos, planes, attend):
+        with sublayer("attn.proj"):
+            rope = self._angles(pos)
+        for i in range(self.n_layer):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            a, planes = self._attention(w, i, x, rope, planes, attend)
+            x = x + a
+            with sublayer("norm"):
+                m = _rms(x, w("norm2.scale"), self.eps)
+            if i < self.dense_layers:
+                with sublayer("ffn"):
+                    ff = _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
+                                     w("ffn_down.w"))
+            else:
+                ff, counts = routed_ffn(
+                    w, m, attend, self.experts, self.top_k,
+                    self.route_scale, score="softmax", normalise=False,
+                    bias=False)
+                attend.tally(counts)
+            x = x + ff
         return x, planes
 
     def head(self, p, x):

@@ -110,7 +110,10 @@ class _Cache:
     (``state``, ``put_state``, ``valid``) reads and writes the slots'
     rows of the per-slot state.
 
-    ``planes = (pool_k, pool_v, state)``: one pool array a plane, one
+    ``planes = (pool_k, pool_v, state)``: one pool array a plane
+    (``pool_v`` is ``()`` where a plane is ONE array, ``arch.pool_arrays
+    == 1``: called with ``vh=None, kh=`` the latent row, it writes that
+    array only and hands ``attend`` ``pool_v=None``), one
     tuple of ``[max_slots, ...]`` arrays a state layer (``()`` for an
     architecture that holds none).  A stack that runs ``arch.passes``
     times folds its passes into the block axis: a plane's array holds
@@ -163,7 +166,9 @@ class _Cache:
         if self.arch.passes > 1:
             shift = i_pass * (pool_k[plane].shape[0] // self.arch.passes)
             tbl, b = tbl + shift, b + shift
-        pk, pv = pool_k[plane], pool_v[plane]
+        # a latent plane (arch.pool_arrays == 1) has no V array: pool_v is
+        # () and the one row written is kh
+        pk, pv = pool_k[plane], pool_v[plane] if pool_v else None
         if kh is not None:
             # every write lands before the attention below: the
             # write-before-attend discipline, one scatter per plane
@@ -174,7 +179,8 @@ class _Cache:
             # write of part of the head axis is a serial loop on the chip
             with sublayer("cache"):
                 pk = _paged.write(pk, b, self.off, kh)
-                pv = _paged.write(pv, b, self.off, vh)
+                if pv is not None:
+                    pv = _paged.write(pv, b, self.off, vh)
         # attend THROUGH the table: row j attends <= pos_j inside the
         # paged_attention op class, the [S, T, h, dh] view never exists
         ctx = _paged.attend(qh[:, None] if self.step else qh, pk, pv, tbl,
@@ -184,7 +190,8 @@ class _Cache:
         if kh is None:
             return ctx, planes
         return ctx, (pool_k[:plane] + (pk,) + pool_k[plane + 1:],
-                     pool_v[:plane] + (pv,) + pool_v[plane + 1:]) + planes[2:]
+                     pool_v and pool_v[:plane] + (pv,) + pool_v[plane + 1:]
+                     ) + planes[2:]
 
     def tally(self, counts):
         self.counts = (counts if isinstance(self.counts, tuple)
@@ -332,7 +339,10 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch,
 
 def _copy_block(planes, src, dst, passes):
     """Block ``src`` copied whole onto ``dst`` in every plane: once for
-    each pass folded into an array's block axis."""
+    each pass folded into an array's block axis.  Of a latent plane's
+    missing V arrays (``()``) there is nothing to copy."""
+    if not planes:
+        return planes
     per = planes[0].shape[0] // passes
     out = []
     with sublayer("cache"):

@@ -334,10 +334,12 @@ class ServingEngine:
         # for the draft's scratch blocks, so a propose round can never
         # starve admission.  A block id names the same block_tokens
         # positions in every one of the arch.kv_planes planes, so one
-        # block costs kv_planes * 2 * block_tokens * kv_heads * head_dim
-        # * itemsize bytes (48 MiB for 192 planes of 32 x 2048 bf16, 6 MiB for 24):
-        # the pool's bytes, not its block count, are what fills a chip
-        # (gauge serving.kv_pool_bytes)
+        # block costs kv_planes x what the plane's own shape holds of
+        # block_tokens positions (arch.pool_block_shape in each of its
+        # arch.pool_arrays: 48 MiB for 192 K and V planes of 32 x 2048
+        # bf16, 6 MiB for 24, 1.05 MiB for 27 latent planes of 640
+        # lanes): the pool's bytes, not its block count, are what fills
+        # a chip (gauge serving.kv_pool_bytes)
         num_blocks = (1 + self.max_slots * self.blocks_per_slot
                       + self.cache_blocks)
         if spec_on:
@@ -354,8 +356,10 @@ class ServingEngine:
             arch.pool_block_shape(self.block_tokens, self.compute_dtype))
         self._pk = tuple(jnp.zeros(shape, self.compute_dtype)
                          for _ in arch.planes)
+        # no V array where a position's value is lanes of its key row
         self._pv = tuple(jnp.zeros(shape, self.compute_dtype)
-                         for _ in arch.planes)
+                         for _ in arch.planes[:len(arch.planes)
+                                              * (arch.pool_arrays - 1)])
         # what a slot holds BESIDE the pool (recurrent state): arrays
         # indexed by slot, donated through the same executables; a
         # prompt's first prefill piece starts its row from zeros, so a
@@ -450,16 +454,16 @@ class ServingEngine:
                  "token").set(arch.passes)
         self._reg.gauge(
             "serving.kv_bytes_per_token",
-            help="K and V bytes of one cached token across its planes",
+            help="bytes of one cached token across its planes (K and V, "
+                 "or the one latent row a plane stores)",
         ).set(arch.kv_bytes_per_token(itemsize))
         self._reg.gauge(
             "serving.kv_write_fill",
-            help="rows of a pool row that carry K/V heads / rows a write "
-                 "covers: a write covers the pool's whole row "
-                 "(kernels.paged_attention.write), rows pool_rows added "
-                 "as zeros",
-        ).set(arch.kv_block_bytes(1, itemsize)
-              / (2 * itemsize * int(np.prod(self._pk[0].shape[2:]))))
+            help="values of a pool row that carry what the model caches "
+                 "/ values a write covers: a write covers the pool's "
+                 "whole row (kernels.paged_attention.write), the rows "
+                 "pool_rows added, the lanes latent_lanes added, as zeros",
+        ).set(arch.written_values / int(np.prod(self._pk[0].shape[2:])))
         self._reg.gauge(
             "serving.kv_pool_bytes",
             help="bytes the paged pool holds on the device: planes x "
@@ -473,6 +477,10 @@ class ServingEngine:
         # token makes, for _count_paged_entries
         self._plane_reads = [(w, n, arch.kv_block_bytes(1, itemsize))
                              for w, n in arch.plane_reads]
+        # span attributes of an architecture with latent planes
+        self._latent_attrs = (dict(latent_planes=arch.latent_planes,
+                                   attn_form=arch.attn_form)
+                              if arch.latent_planes else {})
 
     @property
     def _tracer(self):
@@ -530,15 +538,33 @@ class ServingEngine:
         those calls have to read."""
         B = self.block_tokens
         rows = self.arch.rows_per_entry
-        live = streamed = 0
-        for req in self._slots:
-            if req is None:
-                continue
-            ctx = req.prompt.shape[0] + len(req.tokens)   # keys attended
-            for window, n, token_bytes in self._plane_reads:
+        live = streamed = shared = 0
+        contexts = [(s, req.prompt.shape[0] + len(req.tokens))
+                    for s, req in enumerate(self._slots) if req is not None]
+        # only the trie hands two slots one block
+        sharing = self.prefix_trie is not None and len(contexts) > 1
+        for window, n, token_bytes in self._plane_reads:
+            chains = []
+            for s, ctx in contexts:                   # ctx keys attended
                 first = 0 if window is None else max(ctx - window, 0)
                 live += n * ((ctx - 1) // B - first // B + 1)
                 streamed += n * (ctx - first) * token_bytes
+                if sharing:
+                    chains.append(
+                        self._table[s, first // B:(ctx - 1) // B + 1])
+            if sharing:
+                # a slot's chain names a block once, so a block named
+                # twice is named by two slots
+                ids = np.concatenate(chains)
+                shared += n * int(np.sum(np.bincount(ids)[ids] > 1))
+        if self.arch.latent_planes:
+            # every step of the chunk attends one position more, a slot
+            # that finishes inside the chunk rides it out on the device
+            steps = self.decode_chunk
+            self._count_latent_positions(
+                "decode", self.arch.latent_planes * sum(
+                    steps * ctx + steps * (steps - 1) // 2
+                    for _, ctx in contexts))
         self._reg.counter(
             "serving.paged_entries_live",
             help="block-table entries a paged-attention call had to "
@@ -546,6 +572,13 @@ class ServingEngine:
                  "the plane's lower bound up to each one's position at "
                  "the chunk's start; the mean over a token's calls)",
         ).inc(live / self._reads_per_token)
+        self._reg.counter(
+            "serving.paged_entries_shared",
+            help="of paged_entries_live, the entries whose block more "
+                 "than one live slot's table names (a shared prefix of "
+                 "the trie): what a read that fetches a chain once for "
+                 "the slots that share it would not fetch again",
+        ).inc(shared / self._reads_per_token)
         self._reg.counter(
             "serving.paged_rows_live",
             help="query rows the paged-attention calls sent through the "
@@ -573,6 +606,14 @@ class ServingEngine:
                  "step have to read for the live slots (clipped to each "
                  "plane's window, a shared plane once a reader), at "
                  "every decode chunk's first step").inc(streamed)
+
+    def _count_latent_positions(self, phase, positions):
+        self._reg.counter(
+            "serving.latent_positions_read", phase=phase,
+            help="cached positions the latent planes' attention read: "
+                 "positions attended x latent planes, every step of a "
+                 "decode chunk (phase=decode), every real row of a "
+                 "prefill piece (phase=prefill)").inc(positions)
 
     def _count_tallies(self, phase, counts):
         """What the compiled steps of one decode chunk or one
@@ -1020,7 +1061,8 @@ class ServingEngine:
                         state_layers=len(self._state),
                         plane_reads=self._reads_per_token,
                         moe_layers=self.arch.moe_layers,
-                        experts_held=self.arch.experts_held) as sp:
+                        experts_held=self.arch.experts_held,
+                        **self._latent_attrs) as sp:
             (self._pk, self._pv, self._last, self._pos, toks,
              self._state, counts) = self._decode_fn(
                  self._p, self._pk, self._pv, self._last, self._pos, tbl,
@@ -1387,7 +1429,8 @@ class ServingEngine:
                         state_layers=len(self._state),
                         plane_reads=self._reads_per_token,
                         moe_layers=self.arch.moe_layers,
-                        experts_held=self.arch.experts_held) as sp:
+                        experts_held=self.arch.experts_held,
+                        **self._latent_attrs) as sp:
             tally = []
             self._pk, self._pv, first = self._run_pieces(
                 self._prefill_fn, self._p, self._pk, self._pv, slot,
@@ -1396,6 +1439,11 @@ class ServingEngine:
                 first = int(np.asarray(first))  # host sync
                 if self.arch.count_names:
                     self._count_tallies("prefill", tally)
+        if self.arch.latent_planes:
+            # real row j of a piece at ``at`` attends at + j + 1 positions
+            self._count_latent_positions(
+                "prefill", self.arch.latent_planes * sum(
+                    n * at + n * (n + 1) // 2 for _, _, at, n in pieces))
         t_p0, now = sp.t0, sp.t1
         # the CoW source was held only for the copy window
         if cow is not None:

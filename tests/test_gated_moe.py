@@ -309,13 +309,15 @@ class _Rows:
 
 
 def _routed_alone(p, i, x, share, valid=None):
-    """``GatedMoE._routed`` of layer ``i`` on rows ``x [n, d]`` for the
-    share ``share`` of the uncut parameters ``p``."""
+    """``arch.routed_ffn`` as ``GatedMoE`` calls it, layer ``i`` on rows
+    ``x [n, d]`` for the share ``share`` of the uncut parameters ``p``."""
     arch = _arch(share)
     held = _share(p, *share)
     rows = _Rows(jnp.ones(x.shape[:-1], bool) if valid is None else valid)
     h = arch_mod._rms(x, held[f"block{i}_norm3.scale"], arch.eps)
-    y, counts = arch._routed(lambda nm: held[f"block{i}_{nm}"], h, rows)
+    y, counts = arch_mod.routed_ffn(
+        lambda nm: held[f"block{i}_{nm}"], h, rows, arch.experts, arch.top_k,
+        arch.route_scale)
     return np.asarray(y), np.asarray(counts)
 
 

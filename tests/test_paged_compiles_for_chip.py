@@ -99,6 +99,71 @@ def test_paged_kernel_compiles_for_v5e(geometry, one_chip):
     assert "paged_attention" in compiled.as_text()
 
 
+# (slots, window rows, table entries a slot, pool blocks, lanes stored,
+# values of them, value lanes, query rows an entry): the latent plane of
+# serving.arch.LatentMoE at dsv2lite.doc_qa_8k's geometry (12 slots x
+# 9216 positions, 576 values in 640 lanes, 16 heads read each row)
+LATENT = {
+    "doc_qa_decode": (12, 1, 288, 4609, 640, 576, 512, 16),
+    "doc_qa_narrow_prefill_piece": (1, 4, 288, 4609, 640, 576, 512, 16),
+    "doc_qa_widest_streamed_window": (12, 7, 288, 4609, 640, 576, 512, 16),
+}
+
+
+@pytest.mark.parametrize("geometry", list(LATENT))
+def test_latent_kernel_compiles_for_v5e(geometry, one_chip):
+    """The pool enters the kernel in place (no pool-sized temporary), the
+    Mosaic call carries its own name, and the write of a step's rows is
+    one scatter in place."""
+    import re
+
+    from paddle_tpu.kernels.paged_attention import (
+        latent_lanes, paged_attention_pallas, write)
+
+    S, W, NB, blocks, lanes, values, dv, rows = LATENT[geometry]
+    assert latent_lanes(values) == lanes
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((blocks, 32, lanes), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, p, t, at: paged_attention_pallas(
+            q, p, None, t, at, interpret=False, value_lanes=dv,
+            scale=0.11472)).lower(
+            arg((S, W, rows, lanes), jnp.bfloat16), pool,
+            arg((S, NB), jnp.int32), arg((S, W), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert "paged_latent_attention" in compiled.as_text()
+    index = (S,) if W == 1 else (S, W)
+    written = jax.jit(write, donate_argnums=0).lower(
+        pool, arg(index, jnp.int32), arg(index, jnp.int32),
+        arg((*index, values), jnp.bfloat16)).compile()
+    text = written.as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert re.search(re.escape(f"[{blocks},32,{lanes}]") + r"\S* scatter\(",
+                     text)
+    assert re.search(r"input_output_alias=\{ \{\}: \(0, \{\}", text)
+    assert written.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_a_latent_row_of_576_lanes_is_refused_by_mosaic(one_chip):
+    """Why the plane stores 640 lanes: Mosaic slices a block out of the
+    pool on 128-lane tiles only, and the device holds a 576-lane row in
+    640 lanes whatever its logical shape says."""
+    from paddle_tpu.kernels.paged_attention import latent_attention_pallas
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(lambda q, p, t, at: latent_attention_pallas(
+            q, p, t, at, 512, scale=0.1, interpret=False)).lower(
+            arg((12, 1, 16, 576), jnp.bfloat16),
+            arg((4609, 32, 576), jnp.bfloat16), arg((12, 288), jnp.int32),
+            arg((12, 1), jnp.int32)).compile()
+
+
 # (pool blocks, rows of the pool's head axis, dtype, index shape, K/V
 # rows written): the K/V write of each serving cell, a decode step's
 # slots and a prefill piece's window
@@ -153,6 +218,13 @@ GROUPED = {
     "chat_moe_decode": (384, 3072, 3072, 32),
     "chat_moe_prefill_piece": (512, 3072, 3072, 32),
     "chat_moe_narrow_piece": (32, 3072, 3072, 32),
+    # serving.arch.LatentMoE at the published widths, 16 experts held,
+    # top 6: 12 slots x 6 rows a decode step, 128 x 6 a prefill piece;
+    # 1408 is 11 lane tiles, so only 128-wide panels divide it
+    "doc_qa_decode_gate_up": (72, 2048, 1408, 16),
+    "doc_qa_decode_down": (72, 1408, 2048, 16),
+    "doc_qa_prefill_piece_gate_up": (768, 2048, 1408, 16),
+    "doc_qa_prefill_piece_down": (768, 1408, 2048, 16),
 }
 
 
