@@ -262,7 +262,7 @@ def tanh_gelu(ctx):
     not reassociation-stable between unrolled and ``lax.scan`` execution
     on XLA — recompute drifts from the forward at the 1e-3 level, which
     breaks the scan-remat engine's bit-exactness contract (the reason
-    PR 3 moved gelu to the exact erf form)."""
+    PR 3 moved the ``gelu`` op to the exact form)."""
     if not _has_remat(ctx.program):
         return []
     rep = ctx.walk
@@ -274,7 +274,8 @@ def tanh_gelu(ctx):
         f"remat-marked program — tanh's backward is not "
         f"reassociation-stable under scan, so recompute can drift from "
         f"the saved forward",
-        hint="use the exact erf gelu (jax.nn.gelu(approximate=False) — "
-             "this framework's 'gelu' op) or keep tanh segments "
-             "unwrapped (saved, not rematerialized)",
+        hint="use this framework's 'gelu' op (the exact x * Phi(x): one "
+             "float32 erf for a 16-bit input, erfc for a wider one; no "
+             "tanh either way) or keep tanh segments unwrapped (saved, "
+             "not rematerialized)",
         data={"tanh_in_scan": rep["tanh_in_scan"]})]

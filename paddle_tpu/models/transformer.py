@@ -270,8 +270,9 @@ def generate(params, prompt, max_len, n_layer, n_head, d_model,
             ctx = jnp.einsum("bhT,bThd->bhd", a, cv).reshape(b, d_model)
             x = x + ctx @ w("att_out.w") + w("att_out.b")
             h2 = ln(x, w("ln2.scale"), w("ln2.bias"))
-            # approximate=False matches the training program's gelu op
-            # (exact erf form — see ops/activation_ops.py)
+            # approximate=False is the function the training program's
+            # gelu op computes (the exact x * Phi(x); how the op evaluates
+            # it for a 16-bit input: ops/activation_ops.py)
             ff = jax.nn.gelu(h2 @ w("ffn1.w") + w("ffn1.b"),
                              approximate=False)
             x = x + ff @ w("ffn2.w") + w("ffn2.b")

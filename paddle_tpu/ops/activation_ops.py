@@ -36,12 +36,65 @@ _simple("reciprocal", lambda X: 1.0 / X)
 _simple("log", lambda X: jnp.log(X))
 _simple("square", lambda X: jnp.square(X))
 _simple("softplus", lambda X: jax.nn.softplus(X))
-# exact (erf) gelu, not the tanh approximation: the tanh form's backward
-# is not reassociation-stable between unrolled and lax.scan execution on
-# XLA:CPU (measured 1e-3-level grad drift), which would break the
-# scan-remat engine's bit-exactness contract; erf is stable and matches
-# the op test's own erf reference more closely anyway
-_simple("gelu", lambda X: jax.nn.gelu(X, approximate=False))
+
+
+# The exact GELU x * Phi(x), never the tanh approximation: tanh's
+# backward is not reassociation-stable between unrolled and lax.scan
+# execution on XLA:CPU (measured 1e-3-level grad drift), which would
+# break the scan-remat engine's bit-exactness contract; this evaluation
+# has no tanh and no reduction and is the same bits either way
+# (tests/test_ops_gelu.py pins it).
+_SQRT_HALF = 0.7071067811865476
+_INV_SQRT_2PI = 0.3989422804014327
+# below this Phi(x) is under 1e-9 and float32's 1 + erf holds no digit
+# of it (XLA:CPU's erf stops at -1 + 1.8e-7, which would leak
+# 0.5 * x * 1.8e-7: -896 at x = -1e10): Phi is zero there
+_GELU_ZERO_BELOW = -6.0
+
+
+def _normal_cdf(xf):
+    cdf = 0.5 * (1.0 + jax.lax.erf(xf * jnp.float32(_SQRT_HALF)))
+    return jnp.where(xf < _GELU_ZERO_BELOW, 0.0, cdf)
+
+
+@jax.custom_vjp
+def _gelu_16bit(x):
+    """x * Phi(x) of a bfloat16/float16 ``x``: float32 arithmetic (the
+    VPU has no other), ONE erf, one rounding at the end."""
+    xf = x.astype(jnp.float32)
+    return (xf * _normal_cdf(xf)).astype(x.dtype)
+
+
+def _gelu_16bit_fwd(x):
+    return _gelu_16bit(x), x
+
+
+def _gelu_16bit_bwd(x, g):
+    # d/dx x Phi(x) = Phi(x) + x phi(x): one erf and one exp, where AD
+    # through erfc's branches evaluates both of them again
+    xf = x.astype(jnp.float32)
+    pdf = jnp.exp(-0.5 * xf * xf) * jnp.float32(_INV_SQRT_2PI)
+    slope = _normal_cdf(xf) + xf * pdf
+    return ((g.astype(jnp.float32) * slope).astype(x.dtype),)
+
+
+_gelu_16bit.defvjp(_gelu_16bit_fwd, _gelu_16bit_bwd)
+
+
+@register_op("gelu")
+def gelu(X, **_):
+    # one function, evaluated by what the input is: a 16-bit input is
+    # widened to float32 by the compiler whatever is written, and
+    # jax.nn.gelu's 0.5 * x * erfc(-x * sqrt_half) then costs both of
+    # erfc's branches (74 wide operations an element on a TPU v5e
+    # against 12, an erf among them) AFTER rounding its argument to 16
+    # bits; at 32 bits and above erfc's relative accuracy in the far
+    # tail is visible and kept, to the bit
+    if X.dtype in (jnp.bfloat16, jnp.float16):
+        return {"Out": _gelu_16bit(X)}
+    return {"Out": jax.nn.gelu(X, approximate=False)}
+
+
 _simple("softsign", lambda X: X / (1 + jnp.abs(X)))
 
 
