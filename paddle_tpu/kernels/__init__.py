@@ -3,7 +3,7 @@
 
 One op, two targets, one numerics oracle: every fused op class
 (``flash_attention``, ``fused_ce``, ``paged_attention``,
-``grouped_matmul``) resolves
+``grouped_matmul``, ``retention``) resolves
 through :mod:`.registry` to ``pallas_tpu`` (the Mosaic kernels — native
 on TPU, interpret mode in CPU tests) or ``xla_ref`` (:mod:`.xla_ref` —
 the shape-complete pure-XLA reference every backend is tested against,
@@ -29,6 +29,7 @@ from .xla_ref import ORACLE_TOL, oracle_tol
 from . import xla_ref  # registers the oracle backend
 from . import paged_attention  # registers the paged-attention op class
 from . import grouped_matmul  # registers the grouped matrix product
+from . import retention  # registers power retention's step and chunk
 
 __all__ = [
     "AUTO_ORDER", "BACKENDS", "GLOBAL_ENV", "TIMED_RUN_ENV",

@@ -50,7 +50,7 @@ def _check_registry(failures):
     print(f"registry: op classes {ops} on platform "
           f"{jax.default_backend()!r}")
     if sorted(ops) != ["flash_attention", "fused_ce", "grouped_matmul",
-                       "paged_attention"]:
+                       "paged_attention", "retention"]:
         failures.append(f"unexpected op classes: {ops}")
     for op in ops:
         auto = resolve_name(op)

@@ -58,7 +58,7 @@ adds to what the compiled decode chunk and prefill piece return as their
 last output (``arch.count_names`` names the entries; an architecture
 with none never calls it and its programs have no such output).
 
-Five architectures are here: ``Gpt2`` (the block of
+Six architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -86,7 +86,22 @@ value_lanes=..)``), read by every head through queries absorbed into
 the latent's width; its routed layers are ``GatedMoE``'s
 (``routed_ffn``, ``route`` with softmax scores left unnormalised);
 ``models/latent_moe_reference.py`` is its plain reference, in the
-per-head form.
+per-head form.  ``PowerRetention`` has NO plane at all (``planes ==
+()``): every layer's mixer is power retention (arXiv:2507.04239), whose
+memory of the context is a state of fixed size a slot a K/V head, read
+and written in place for the live slots only (``attend.retain``,
+``kernels/retention.py``); ``models/retention_reference.py`` is its
+plain reference, in the quadratic form.
+
+An architecture whose state is large asks for it IN PLACE::
+
+    y, planes = attend.retain(planes, i, q, k, v, lg, eps=...)
+
+hands state layer ``i``'s arrays WHOLE to ``kernels.retention`` (a
+decode step: every slot's row and ``valid``; a piece: the one slot's
+rows), which advances the live slots' state where it lies and returns
+the rows' outputs; no ``[S, ...]`` copy of the state exists on either
+side, where ``state`` / ``put_state`` gather and scatter one.
 """
 
 import jax
@@ -94,6 +109,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import paged_attention as _paged
+from ..kernels import retention as _retention
 from ..kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 # the named scopes of the lowered program (op_name metadata, read by
 # ``observability.trace.device_scopes``): the entry points run a stack
@@ -102,7 +118,7 @@ from ..kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
-           "LatentMoE", "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
+           "LatentMoE", "PowerRetention", "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
            "MOE_COUNTS", "STACK_SCOPE"]
 
 
@@ -126,6 +142,8 @@ class Architecture:
     pool_arrays = 2
     latent_planes = 0
     attn_form = None
+    # layers whose mixer is power retention (a state, no plane)
+    retention_layers = 0
 
     def __init__(self, n_layer, n_head, d_model, passes=1, head_dim=None):
         if head_dim is None:
@@ -219,7 +237,8 @@ class Architecture:
     def gauges(self, params):
         """``{name: (value, help)}`` of what the architecture wants set
         as ``serving.<name>`` gauges beside the engine's own, given the
-        engine's parameters; nothing by default."""
+        engine's parameters (``name`` may be ``(name, {label: value})``);
+        nothing by default."""
         return {}
 
     def heads(self, x):
@@ -1293,5 +1312,153 @@ class LatentMoE(_Routed, Architecture):
     def head(self, p, x):
         with sublayer("head"):
             return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
+                              p["lm_head.w"],
+                              preferred_element_type=jnp.float32)
+
+
+class PowerRetention(Architecture):
+    """Pre-normed layers of POWER RETENTION and a gated SiLU FFN (the
+    ``brumby`` layout: Qwen3's block with its attention replaced;
+    arXiv:2507.04239; ``models/retention_reference.py`` writes the
+    equations down in the quadratic form and lists what the published
+    configuration has no key for).
+
+    **What a slot holds.**  No K and no V of any position
+    (``planes == ()``: the engine builds no pool and no table): a layer
+    holds, a K/V head, the state ``S`` and the normaliser's ``z`` of
+    ``kernels/retention.py`` (``state_spec``: ``(kv_heads, stored_rows,
+    head_dim)`` and ``(kv_heads, stored_rows)`` float32, whatever the
+    compute dtype), 34 MB a layer at 8 heads of 128, whatever the
+    context.  The five query heads of a K/V head's group read the one
+    state.
+
+    **A layer.**  ``q``, ``k`` are RMS-normed over a head's lanes and
+    rotated (the halves convention of ``_rope``, all lanes), the gate is
+    ``lg = log sigmoid(h W_g + b_g)``, one a K/V head, in float32, and
+    ``attend.retain`` does the rest: a decode step decays the live
+    slots' state, adds ``phi(k) v^T`` and reads it through ``phi(q)``; a
+    prefill piece attends itself in the quadratic form and the state
+    before it through ``phi(q)``.  ``degree`` is 2: the features ``phi``
+    are the degree's, and no other is written down here.
+
+    Parameter names: ``tok_emb.w [V, d]``, ``norm_f.scale``,
+    ``lm_head.w [d, V]`` (untied); per layer ``block{i}_norm1.scale``,
+    ``att_q.w [d, n_head * head_dim]``, ``att_k.w`` and ``att_v.w [d,
+    kv_heads * head_dim]``, ``att_qnorm.scale`` and ``att_knorm.scale
+    [head_dim]``, ``att_gate.w [d, kv_heads]``, ``att_gate.b
+    [kv_heads]``, ``att_out.w [n_head * head_dim, d]``, ``norm2.scale``,
+    ``ffn_gate.w``, ``ffn_up.w [d, f]``, ``ffn_down.w [f, d]``.  No
+    other bias.
+    """
+
+    name = "power_retention"
+    attn_form = "retention"
+
+    def __init__(self, n_layer, n_head, kv_heads, d_model, head_dim, d_ff,
+                 degree=2, eps=1e-6, rope_theta=10000.0, norm_eps=1e-6):
+        super().__init__(n_layer, n_head, d_model, head_dim=head_dim)
+        if degree != 2:
+            raise ValueError(
+                f"{self.name}: degree {degree}: the features "
+                f"kernels.retention.phi makes are the second degree's "
+                f"(the upper triangle of u u^T); no other is written")
+        if n_head % kv_heads or n_head // kv_heads > 7:
+            raise ValueError(
+                f"{self.name}: kv_heads {kv_heads} must divide n_head "
+                f"{n_head} into groups of at most 7 (a K/V head's key row "
+                f"and its query rows share one 8-row tile)")
+        if self.head_dim % 8:
+            raise ValueError(f"{self.name}: head_dim {self.head_dim} must "
+                             f"be a multiple of 8")
+        self._kv_heads, self.d_ff = int(kv_heads), int(d_ff)
+        self.degree, self.eps = int(degree), float(eps)
+        self.norm_eps, self.rope_theta = norm_eps, float(rope_theta)
+
+    @property
+    def kv_heads(self):
+        return self._kv_heads
+
+    @property
+    def planes(self):
+        return ()
+
+    @property
+    def retention_layers(self):
+        return self.n_layer
+
+    def state_spec(self, dtype):
+        rows = _retention.stored_rows(self.head_dim)
+        one = (((self.kv_heads, rows, self.head_dim), jnp.float32),
+               ((self.kv_heads, rows), jnp.float32))
+        return (one,) * self.n_layer
+
+    def gauges(self, params):
+        rows = "features a K/V head's state holds: the upper triangle " \
+               "of a head's lanes (published) and what the layout of " \
+               "cyclic diagonals stores (kernels.retention)"
+        return {
+            "retention_layers": (self.n_layer, "layers whose mixer is "
+                                 "power retention (a state, no K/V plane)"),
+            "retention_degree": (self.degree, "the power of the scores"),
+            ("retention_state_rows", (("kind", "published"),)): (
+                _retention.published_rows(self.head_dim), rows),
+            ("retention_state_rows", (("kind", "stored"),)): (
+                _retention.stored_rows(self.head_dim), rows),
+        }
+
+    def check_params(self, params, max_len):
+        last = self.n_layer - 1
+        need = ["tok_emb.w", "norm_f.scale", "lm_head.w",
+                f"block{last}_att_gate.w", f"block{last}_att_gate.b",
+                f"block{last}_att_qnorm.scale", f"block{last}_norm2.scale",
+                f"block{last}_ffn_down.w"]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+        width = np.shape(params[f"block{last}_ffn_down.w"])[0]
+        if width != self.d_ff:
+            raise ValueError(f"{self.name}: the parameters' FFN is {width} "
+                             f"wide; the architecture says {self.d_ff}")
+
+    def embed(self, p, toks, pos):
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks]
+
+    def stack(self, p, x, pos, planes, attend):
+        f32 = jnp.float32
+        with sublayer("attn.proj"):
+            rope = _rope_angles(pos, self.head_dim, self.rope_theta)
+        for i in range(self.n_layer):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            with sublayer("norm"):
+                a = _rms(x, w("norm1.scale"), self.norm_eps)
+            kv = (*a.shape[:-1], self.kv_heads, self.head_dim)
+            with sublayer("attn.proj"):
+                q = _rope(_rms(self.heads(a @ w("att_q.w")),
+                               w("att_qnorm.scale"), self.norm_eps), *rope)
+                k = _rope(_rms((a @ w("att_k.w")).reshape(kv),
+                               w("att_knorm.scale"), self.norm_eps), *rope)
+                v = (a @ w("att_v.w")).reshape(kv)
+                lg = jax.nn.log_sigmoid(
+                    jnp.matmul(a, w("att_gate.w"),
+                               preferred_element_type=f32)
+                    + w("att_gate.b").astype(f32))
+            with sublayer("attn.core"):
+                y, planes = attend.retain(planes, i, q, k, v, lg,
+                                          eps=self.eps)
+            with sublayer("attn.proj"):
+                x = x + (y.reshape(*x.shape[:-1], -1).astype(x.dtype)
+                         @ w("att_out.w"))
+            with sublayer("norm"):
+                m = _rms(x, w("norm2.scale"), self.norm_eps)
+            with sublayer("ffn"):
+                x = x + _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
+                                    w("ffn_down.w"))
+        return x, planes
+
+    def head(self, p, x):
+        with sublayer("head"):
+            return jnp.matmul(_rms(x, p["norm_f.scale"], self.norm_eps),
                               p["lm_head.w"],
                               preferred_element_type=jnp.float32)

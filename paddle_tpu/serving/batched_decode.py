@@ -86,6 +86,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import paged_attention as _paged
+from ..kernels import retention as _retention
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
@@ -141,23 +142,40 @@ class _Cache:
     left; without ``slot`` the state arrays' rows ARE the call's slots.
     The state side traces nothing unless an architecture calls it.
 
+    ``retain(planes, i, q, k, v, lg, **how) -> (y, planes')`` is the
+    state side IN PLACE (power retention, ``kernels/retention.py``): the
+    arrays of state layer ``i`` go to the kernel whole, with ``valid`` (a
+    step) or ``slot`` and whether the piece starts a prompt (a window),
+    and come back advanced for the live slots only; nothing is gathered
+    by slot and nothing scattered back.
+
+    An architecture with NO plane (``arch.planes == ()``) has no table:
+    ``table``, ``blk`` and ``off`` are ``None``, calling the cache is an
+    error, and a decode step's ``valid`` is ``live`` (``[S]`` bool),
+    which the entry points take from the engine where the others take
+    the table.
+
     ``tally(counts)`` adds an int32 vector (``arch.count_names`` says
     what its entries are) to ``counts``, which the entry points return
     beside their tokens; ``()`` for a stack that tallies nothing."""
 
     def __init__(self, arch, table, blk, off, pos, writable=None,
-                 slot=None):
+                 slot=None, live=None):
         self.arch, self.table, self.blk, self.off = arch, table, blk, off
         self.pos, self.writable, self.slot = pos, writable, slot
+        self.live = live
         self.counts = ()
         self.step = pos.ndim == 1
-        pos4 = pos[:, None] if self.step else pos
-        self.pos4 = jnp.where((table[:, 0] == 0)[:, None], -1, pos4)
+        if table is not None:
+            pos4 = pos[:, None] if self.step else pos
+            self.pos4 = jnp.where((table[:, 0] == 0)[:, None], -1, pos4)
 
     @property
     def valid(self):
         if self.writable is not None:
             return self.writable
+        if self.live is not None:
+            return self.live
         return self.table[:, 0] != 0
 
     def __call__(self, planes, plane, i_pass, qh, kh, vh, **how):
@@ -193,6 +211,23 @@ class _Cache:
                      pool_v and pool_v[:plane] + (pv,) + pool_v[plane + 1:]
                      ) + planes[2:]
 
+    def retain(self, planes, i, q, k, v, lg, **how):
+        S, z = planes[2][i]
+        if self.step:
+            y, S, z = _retention.step(S, z, q, k, v, lg, self.valid, **how)
+        elif self.slot is None:
+            raise ValueError(
+                "retain: a window of several slots (a verify window) has "
+                "no in-place form: kernels.retention.chunk advances ONE "
+                "slot's state over a piece")
+        else:
+            y, S, z = _retention.chunk(
+                S, z, self.slot, self.pos[0, 0] == 0, q[0], k[0], v[0],
+                lg[0], self.valid[0], **how)
+            y = y[None]
+        return y, planes[:2] + (
+            planes[2][:i] + ((S, z),) + planes[2][i + 1:],)
+
     def tally(self, counts):
         self.counts = (counts if isinstance(self.counts, tuple)
                        else self.counts + counts)
@@ -225,7 +260,10 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     tok [S] int32 current tokens, t [S] int32 per-slot positions,
     pool_k/pool_v tuples of one array a layer ``[passes * num_blocks, B,
     h, dh]``, table [S, NB] int32 block ids (logical capacity T = NB *
-    B); ``arch`` the model, an ``arch.Architecture``.
+    B); ``arch`` the model, an ``arch.Architecture``.  For an
+    architecture with NO plane (``pool_k == ()``) ``table`` is ``[S]``
+    int32, nonzero where the slot is live, and positions are bounded by
+    nothing here.
     Writes each slot's K/V at ``(table[s, t_s // B], t_s % B)`` in every
     plane (clamped — overrun slots land in whatever their last table
     entry maps to, by construction the trash block or an
@@ -234,14 +272,19 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     0``) and returns ``(logits [S, vocab] f32, pool_k', pool_v',
     state', counts)``, ``counts`` what the stack tallied (``_Cache``).
     """
-    S = tok.shape[0]
-    B = pool_k[0].shape[1]
-    T = table.shape[1] * B
-    tw = jnp.clip(t, 0, T - 1)
-    with sublayer("cache"):
-        blk = table[jnp.arange(S), tw // B]  # [S] physical write block
-    x = arch.embed(p, tok, tw)                               # [S, d]
-    cache = _Cache(arch, table, blk, tw % B, t)
+    if not arch.planes:
+        tw = jnp.maximum(t, 0)
+        x = arch.embed(p, tok, tw)
+        cache = _Cache(arch, None, None, None, t, live=table != 0)
+    else:
+        S = tok.shape[0]
+        B = pool_k[0].shape[1]
+        T = table.shape[1] * B
+        tw = jnp.clip(t, 0, T - 1)
+        with sublayer("cache"):
+            blk = table[jnp.arange(S), tw // B]  # [S] physical write block
+        x = arch.embed(p, tok, tw)                           # [S, d]
+        cache = _Cache(arch, table, blk, tw % B, t)
     with jax.named_scope(STACK_SCOPE):
         x, (pool_k, pool_v, state) = arch.stack(
             p, x, tw, (pool_k, pool_v, state), cache)
@@ -321,16 +364,23 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch,
     over the rows up to ``limit``.
     """
     S, W = toks.shape
-    B = pool_k[0].shape[1]
-    T = table.shape[1] * B
     P = pos[:, None] + jnp.arange(W)[None, :]                # [S, W]
-    Pw = jnp.clip(P, 0, T - 1)
-    writable = P <= limit[:, None]
-    with sublayer("cache"):
-        blk = jnp.where(writable,
-                        table[jnp.arange(S)[:, None], Pw // B], 0)
-    x = arch.embed(p, toks, Pw)                              # [S, W, d]
-    cache = _Cache(arch, table, blk, Pw % B, P, writable, slot)
+    if not arch.planes:
+        # no plane, no table (``table`` is ignored)
+        Pw = jnp.maximum(P, 0)
+        writable = P <= limit[:, None]
+        x = arch.embed(p, toks, Pw)
+        cache = _Cache(arch, None, None, None, P, writable, slot)
+    else:
+        B = pool_k[0].shape[1]
+        T = table.shape[1] * B
+        Pw = jnp.clip(P, 0, T - 1)
+        writable = P <= limit[:, None]
+        with sublayer("cache"):
+            blk = jnp.where(writable,
+                            table[jnp.arange(S)[:, None], Pw // B], 0)
+        x = arch.embed(p, toks, Pw)                          # [S, W, d]
+        cache = _Cache(arch, table, blk, Pw % B, P, writable, slot)
     with jax.named_scope(STACK_SCOPE):
         x, (pool_k, pool_v, state) = arch.stack(
             p, x, Pw, (pool_k, pool_v, state), cache)

@@ -302,6 +302,11 @@ class ServingEngine:
                 f"prefix needs that state AT THE HIT'S BOUNDARY; the "
                 f"trie keeps K/V blocks only (no state snapshot at block "
                 f"boundaries).  Pass prefix_reuse=False")
+        if not arch.planes and cache_blocks:
+            raise ValueError(
+                f"cache_blocks={cache_blocks} cannot serve {arch.name!r}: "
+                f"it caches no K/V plane, so there is no block pool to "
+                f"give a cache budget.  Pass cache_blocks=0")
         self._p = jax.device_put(
             {k: jnp.asarray(v, self.compute_dtype)
              for k, v in params.items()})
@@ -344,7 +349,13 @@ class ServingEngine:
                       + self.cache_blocks)
         if spec_on:
             num_blocks += self.max_slots * self.blocks_per_slot
-        self.kv_pool = _kv.BlockPool(num_blocks, self.block_tokens)
+        if arch.planes:
+            self.kv_pool = _kv.BlockPool(num_blocks, self.block_tokens)
+        else:
+            # an architecture that caches no K/V plane: no pool, no
+            # table and no block to count; a slot is all a request
+            # holds, and max_len bounds its positions and nothing else
+            num_blocks, self.blocks_per_slot, self.kv_pool = 1, 0, None
         self.prefix_trie = (_kv.PrefixTrie(self.kv_pool, self.cache_blocks)
                             if prefix_reuse else None)
         self.prefix_reuse = bool(prefix_reuse)
@@ -369,9 +380,12 @@ class ServingEngine:
                   for shp, dt in layer) for layer in state_spec)
         self._last = jnp.zeros((self.max_slots,), jnp.int32)
         self._pos = jnp.zeros((self.max_slots,), jnp.int32)
-        # host-side block table: unused entries -> trash block 0
-        self._table = np.zeros((self.max_slots, self.blocks_per_slot),
-                               np.int32)
+        # host-side block table: unused entries -> trash block 0.  With
+        # no plane there is none: the compiled steps take ``[max_slots]``
+        # int32 in its place, nonzero where the slot is live
+        self._table = np.zeros(
+            (self.max_slots, self.blocks_per_slot) if arch.planes
+            else (self.max_slots,), np.int32)
         self._slot_blocks = [None] * self.max_slots  # bids a slot holds
         # when each slot's request last advanced (first token, then the
         # end of every chunk): serving.stalled_seconds counts from it
@@ -463,7 +477,8 @@ class ServingEngine:
                  "/ values a write covers: a write covers the pool's "
                  "whole row (kernels.paged_attention.write), the rows "
                  "pool_rows added, the lanes latent_lanes added, as zeros",
-        ).set(arch.written_values / int(np.prod(self._pk[0].shape[2:])))
+        ).set(arch.written_values / int(np.prod(self._pk[0].shape[2:]))
+              if self._pk else 0.0)
         self._reg.gauge(
             "serving.kv_pool_bytes",
             help="bytes the paged pool holds on the device: planes x "
@@ -472,15 +487,21 @@ class ServingEngine:
         # what the architecture itself wants shown (a routed FFN's share
         # of the experts: arch.GatedMoE); nothing for most
         for name, (value, text) in arch.gauges(self._p).items():
-            self._reg.gauge("serving." + name, help=text).set(value)
+            name, labels = name if isinstance(name, tuple) else (name, ())
+            self._reg.gauge("serving." + name, help=text,
+                            **dict(labels)).set(value)
         # (window, calls, bytes a cached position) of the paged calls a
         # token makes, for _count_paged_entries
         self._plane_reads = [(w, n, arch.kv_block_bytes(1, itemsize))
                              for w, n in arch.plane_reads]
-        # span attributes of an architecture with latent planes
-        self._latent_attrs = (dict(latent_planes=arch.latent_planes,
-                                   attn_form=arch.attn_form)
-                              if arch.latent_planes else {})
+        # span attributes that say in which form attention runs, for an
+        # architecture with latent planes or with retention layers
+        self._form_attrs = (
+            dict(latent_planes=arch.latent_planes, attn_form=arch.attn_form)
+            if arch.latent_planes else
+            dict(retention_layers=arch.retention_layers,
+                 attn_form=arch.attn_form)
+            if arch.retention_layers else {})
 
     @property
     def _tracer(self):
@@ -535,7 +556,19 @@ class ServingEngine:
         bound, in the live slots only), as the mean over the calls a
         token makes; the query rows a call sends through each and the
         softmax updates the kernel makes for them; and the K/V bytes
-        those calls have to read."""
+        those calls have to read.  For retention layers, which have no
+        table: the states the chunk's steps read and write."""
+        if self.arch.retention_layers:
+            self._reg.counter(
+                "serving.retention_slot_steps",
+                help="states a decode chunk's retention calls read and "
+                     "wrote in place: live slots x retention layers x the "
+                     "chunk's steps (a slot that finishes inside a chunk "
+                     "rides it out on the device)").inc(
+                         self.active_slots * self.arch.retention_layers
+                         * self.decode_chunk)
+        if not self.arch.planes:
+            return                  # no table entry, no K/V byte to count
         B = self.block_tokens
         rows = self.arch.rows_per_entry
         live = streamed = shared = 0
@@ -1019,8 +1052,9 @@ class ServingEngine:
             # blocks this slot shared with the trie are now trie-only:
             # re-apply the cache capacity budget
             self.prefix_trie.enforce_budget()
-        self._reg.gauge("serving.blocks_in_use").set(
-            self.kv_pool.blocks_in_use)
+        if self.kv_pool is not None:
+            self._reg.gauge("serving.blocks_in_use").set(
+                self.kv_pool.blocks_in_use)
 
     def _decode(self):
         if self._spec is not None:
@@ -1062,7 +1096,7 @@ class ServingEngine:
                         plane_reads=self._reads_per_token,
                         moe_layers=self.arch.moe_layers,
                         experts_held=self.arch.experts_held,
-                        **self._latent_attrs) as sp:
+                        **self._form_attrs) as sp:
             (self._pk, self._pv, self._last, self._pos, toks,
              self._state, counts) = self._decode_fn(
                  self._p, self._pk, self._pv, self._last, self._pos, tbl,
@@ -1378,37 +1412,39 @@ class ServingEngine:
 
         pool, trie = self.kv_pool, self.prefix_trie
         p_len = req.prompt.shape[0]
-        n_total = -(-(p_len + req.max_new) // self.block_tokens)
-        shared, cow, hit = [], None, 0
-        if trie is not None:
-            shared, cow, hit = trie.match(req.prompt, p_len - 1)
-        # hold every matched block across the eviction/alloc window so
-        # LRU pressure can never free a chain we are about to attend
-        hold = list(shared) + ([cow[0]] if cow else [])
-        for b in hold:
-            pool.ref(b)
-        need = n_total - len(shared)
-        try:
-            if need > pool.free_blocks and trie is not None:
-                trie.evict_lru(need - pool.free_blocks)
-            priv = pool.alloc(need)
-        except _kv.PoolExhausted:
-            for b in hold:
-                pool.deref(b)
-            raise
-        row = np.zeros(self.blocks_per_slot, np.int32)
-        row[:len(shared)] = shared
-        row[len(shared):n_total] = priv
+        # an architecture with no plane holds no block: an empty row
+        shared, priv, cow, hit = [], [], None, 0
         cow_src = cow_dst = 0
-        if cow is not None:
-            # fork the partially-matched cached block copy-on-write:
-            # the fork target is the first private block (logical block
-            # len(shared)); the copy itself rides inside the prefill
-            # executable, so CoW costs zero extra compiles
-            cow_src, cow_dst = cow[0], priv[0]
-            self._reg.counter(
-                "serving.cow_copies",
-                help="prefix-cache blocks forked copy-on-write").inc()
+        row = np.zeros(self.blocks_per_slot, np.int32)
+        if pool is not None:
+            n_total = -(-(p_len + req.max_new) // self.block_tokens)
+            if trie is not None:
+                shared, cow, hit = trie.match(req.prompt, p_len - 1)
+            # hold every matched block across the eviction/alloc window so
+            # LRU pressure can never free a chain we are about to attend
+            hold = list(shared) + ([cow[0]] if cow else [])
+            for b in hold:
+                pool.ref(b)
+            need = n_total - len(shared)
+            try:
+                if need > pool.free_blocks and trie is not None:
+                    trie.evict_lru(need - pool.free_blocks)
+                priv = pool.alloc(need)
+            except _kv.PoolExhausted:
+                for b in hold:
+                    pool.deref(b)
+                raise
+            row[:len(shared)] = shared
+            row[len(shared):n_total] = priv
+            if cow is not None:
+                # fork the partially-matched cached block copy-on-write:
+                # the fork target is the first private block (logical block
+                # len(shared)); the copy itself rides inside the prefill
+                # executable, so CoW costs zero extra compiles
+                cow_src, cow_dst = cow[0], priv[0]
+                self._reg.counter(
+                    "serving.cow_copies",
+                    help="prefix-cache blocks forked copy-on-write").inc()
         start = int(hit)
         suffix = p_len - start
         pieces = self._pieces(req.prompt[start:], start)
@@ -1430,7 +1466,7 @@ class ServingEngine:
                         plane_reads=self._reads_per_token,
                         moe_layers=self.arch.moe_layers,
                         experts_held=self.arch.experts_held,
-                        **self._latent_attrs) as sp:
+                        **self._form_attrs) as sp:
             tally = []
             self._pk, self._pv, first = self._run_pieces(
                 self._prefill_fn, self._p, self._pk, self._pv, slot,
@@ -1448,7 +1484,7 @@ class ServingEngine:
         # the CoW source was held only for the copy window
         if cow is not None:
             pool.deref(cow[0])
-        self._table[slot] = row
+        self._table[slot] = row if pool is not None else 1
         self._slot_blocks[slot] = list(shared) + list(priv)
         if self._spec is not None:
             # draft prefill: scan the FULL prompt through the draft
@@ -1496,6 +1532,12 @@ class ServingEngine:
                 "serving.prefill_pieces", width=w,
                 help="prefill window calls dispatched, by width (an "
                      "admission is one or more pieces)").inc()
+            if self.arch.retention_layers:
+                self._reg.counter(
+                    "serving.retention_piece_rows", width=w,
+                    help="rows of the prefill pieces' retention calls, "
+                         "by piece width (padding included: a call "
+                         "computes its width), a layer").inc(w)
         self._reg.histogram("serving.ttft_seconds").observe(
             now - req.submit_t)
         with self._qlock:
@@ -1511,7 +1553,8 @@ class ServingEngine:
             "serving.prefix_hit_rate",
             help="cumulative prefix-cache hit rate over prompt tokens "
                  "(since the last accounting reset)").set(hit_rate)
-        self._reg.gauge("serving.blocks_in_use").set(pool.blocks_in_use)
+        if pool is not None:
+            self._reg.gauge("serving.blocks_in_use").set(pool.blocks_in_use)
         if ((req.eos_id is not None and first == req.eos_id)
                 or req.max_new == 1):
             self._release_slot(slot)

@@ -1,0 +1,190 @@
+"""``serving.arch.PowerRetention`` through ``ServingEngine`` on the CPU
+at 3 layers x 64 wide: prefill pieces and then decode through the state
+against ``models/retention_reference.py``'s logits (the quadratic form),
+several slots of different lengths at once, a slot reused by a second
+prompt; the engine with no plane builds no pool and no table and refuses
+``prefix_reuse=True`` and a draft, with the reason; the gauges and
+counters; the parameter count of the published model from the layer
+equations, shape-only."""
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+import paddle_tpu as pt  # noqa: E402
+from paddle_tpu.models import retention_reference as ref  # noqa: E402
+from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
+from paddle_tpu.serving.arch import PowerRetention  # noqa: E402
+
+L, H, HK, D, DH, F, V = 3, 6, 2, 64, 16, 128, 97
+THETA = 1e6
+
+
+def make(seed, std=0.08):
+    rng = np.random.default_rng(seed)
+
+    def n(*shape):
+        return (std * rng.normal(size=shape)).astype(np.float32)
+
+    ones = lambda k: np.ones(k, np.float32)                      # noqa: E731
+    p = {"tok_emb.w": n(V, D), "lm_head.w": n(D, V), "norm_f.scale": ones(D)}
+    for i in range(L):
+        b = f"block{i}_"
+        horizon = np.array([8.0, 90.0])
+        p.update({
+            b + "att_q.w": n(D, H * DH), b + "att_k.w": n(D, HK * DH),
+            b + "att_v.w": n(D, HK * DH), b + "att_out.w": n(H * DH, D),
+            b + "att_gate.w": n(D, HK),
+            b + "att_gate.b": np.log(horizon - 1).astype(np.float32),
+            b + "att_qnorm.scale": ones(DH), b + "att_knorm.scale": ones(DH),
+            b + "norm1.scale": ones(D), b + "norm2.scale": ones(D),
+            b + "ffn_gate.w": n(D, F), b + "ffn_up.w": n(D, F),
+            b + "ffn_down.w": n(F, D)})
+    return p
+
+
+def arch():
+    return PowerRetention(L, H, HK, D, DH, F, rope_theta=THETA)
+
+
+def engine(params, reg=None, **kw):
+    return pt.serving.ServingEngine(
+        params, arch=arch(), max_len=400, max_slots=3, prefix_reuse=False,
+        cache_blocks=0, registry=reg or MetricsRegistry(), **kw)
+
+
+def gaps(params, prompts, outs, **switches):
+    """The worst gap, a request, between a generated token's reference
+    logit and the reference's maximum."""
+    worst = []
+    for prompt, full in zip(prompts, outs):
+        full = np.asarray(full)
+        assert np.array_equal(full[:len(prompt)], prompt)
+        lg = np.asarray(ref.forward(params, full[None], L, H, HK, THETA,
+                                    **switches))[0]
+        at = lg[len(prompt) - 1:len(full) - 1]
+        worst.append(float(np.max(
+            at.max(-1) - at[np.arange(len(at)), full[len(prompt):]])))
+    return worst
+
+
+@pytest.fixture(scope="module")
+def served():
+    params = make(0)
+    reg = MetricsRegistry()
+    eng = engine(params, reg, compute_dtype="float32")
+    rng = np.random.default_rng(1)
+    # more prompts than slots, so slots are reused; one and several
+    # pieces, every bucket width, a prompt that ends on a piece boundary
+    prompts = [rng.integers(0, V, n, dtype=np.int32)
+               for n in (300, 5, 140, 17, 128, 61)]
+    outs = eng.generate_many(prompts, max_new_tokens=12)
+    return params, eng, reg, prompts, outs
+
+
+def test_engine_through_pieces_and_decode_is_the_reference(served):
+    params, eng, _, prompts, outs = served
+    # float32 end to end: greedy tokens ARE the reference's argmax
+    assert max(gaps(params, prompts, outs)) <= 1e-4
+
+
+@pytest.mark.parametrize("switch", [
+    {"gate": False}, {"normaliser": False}, {"degree": 1},
+    {"rotary": False}, {"piece": 128}, {"grouped": False}])
+def test_each_line_of_the_layer_is_seen_by_the_comparison(served, switch):
+    params, _, _, prompts, outs = served
+    assert max(gaps(params, prompts, outs, **switch)) > 0.01
+
+
+def test_a_reused_slot_starts_from_zeros(served):
+    params, eng, _, prompts, _ = served
+    # the same prompt alone in a fresh engine gives the same tokens as it
+    # gave in a slot that an earlier, longer request had left its state in
+    again = engine(params, compute_dtype="float32").generate_many(
+        [prompts[3]], max_new_tokens=12)
+    assert np.array_equal(again[0], served[4][3])
+
+
+def test_no_plane_no_pool_no_table(served):
+    _, eng, reg, _, _ = served
+    assert arch().planes == () and arch().kv_planes == 0
+    assert eng.kv_pool is None and eng.prefix_trie is None
+    assert eng._pk == () and eng._pv == ()
+    assert eng._table.shape == (3,) and not eng._table.any()
+    stats = eng.stats()
+    assert stats["serving.kv_planes"] == 0
+    assert stats["serving.kv_pool_bytes"] == 0
+    assert stats["serving.kv_blocks_total"] == 0
+    assert stats["serving.retention_layers"] == L
+    assert stats["serving.retention_degree"] == 2
+    assert stats["serving.retention_state_rows{kind=published}"] == 136
+    assert stats["serving.retention_state_rows{kind=stored}"] == 144
+    per_slot = L * HK * (144 * DH + 144) * 4
+    assert stats["serving.state_bytes_per_slot"] == per_slot
+    assert stats["serving.state_bytes"] == 3 * per_slot
+    assert not any(k.startswith("serving.paged_") for k in stats)
+    assert stats["serving.retention_slot_steps"] > 0
+    # 300 rows are two pieces of 128 and one of 64; 128 is one piece
+    assert stats["serving.retention_piece_rows{width=128}"] == 128 * 4
+    assert stats["serving.retention_piece_rows{width=64}"] == 64 * 2
+    assert stats["serving.retention_piece_rows{width=8}"] == 8
+
+
+def test_state_spec_is_two_float32_arrays_a_layer():
+    spec = arch().state_spec(jnp.bfloat16)
+    assert len(spec) == L
+    assert spec[0] == (((HK, 144, DH), jnp.float32), ((HK, 144), jnp.float32))
+    assert arch().attn_form == "retention" and arch().retention_layers == L
+
+
+def test_refusals_say_why():
+    params = make(0)
+    with pytest.raises(ValueError, match="hold recurrent state"):
+        pt.serving.ServingEngine(params, arch=arch(), max_len=64,
+                                 prefix_reuse=True)
+    with pytest.raises(ValueError, match="no block pool"):
+        pt.serving.ServingEngine(params, arch=arch(), max_len=64,
+                                 prefix_reuse=False, cache_blocks=8)
+    from paddle_tpu.serving.speculative import validate_draft
+
+    with pytest.raises(ValueError, match="state rolled back"):
+        validate_draft(params, params, arch(), 64)
+    with pytest.raises(ValueError, match="degree 3"):
+        PowerRetention(L, H, HK, D, DH, F, degree=3)
+    with pytest.raises(ValueError, match="FFN is 128 wide"):
+        PowerRetention(L, H, HK, D, DH, 256).check_params(params, 64)
+
+
+def test_bfloat16_engine_stays_within_a_margin_of_the_reference():
+    params = {k: np.asarray(jnp.asarray(v, jnp.bfloat16).astype(jnp.float32))
+              for k, v in make(4).items()}
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, V, n, dtype=np.int32) for n in (200, 33)]
+    outs = engine(params, compute_dtype="bfloat16").generate_many(
+        prompts, max_new_tokens=10)
+    assert max(gaps(params, prompts, outs)) < 0.05
+
+
+def test_the_published_model_counts_14_77_billion_parameters():
+    """From the layer equations at the published widths, shape-only: the
+    check that the layout is the model's (the card's 14B; Qwen3-14B's
+    14.8B)."""
+    d, h, hk, dh, f, rows, layers = 5120, 40, 8, 128, 17408, 151936, 40
+    a = PowerRetention(layers, h, hk, d, dh, f, rope_theta=1e6)
+    layer = {"att_q.w": (d, h * dh), "att_k.w": (d, hk * dh),
+             "att_v.w": (d, hk * dh), "att_out.w": (h * dh, d),
+             "att_gate.w": (d, hk), "att_gate.b": (hk,),
+             "att_qnorm.scale": (dh,), "att_knorm.scale": (dh,),
+             "norm1.scale": (d,), "norm2.scale": (d,),
+             "ffn_gate.w": (d, f), "ffn_up.w": (d, f), "ffn_down.w": (f, d)}
+    one = sum(int(np.prod(s)) for s in layer.values())
+    assert one == 330_352_904
+    total = layers * one + 2 * rows * d + d
+    assert total == 14_769_945_920
+    # a slot's state: 34,080,768 B a layer at the published 8,256 rows,
+    # 34,344,960 at the 8,320 the layout stores
+    assert hk * (8256 * dh + 8256) * 4 == 34_080_768
+    assert a.state_bytes_per_slot(jnp.bfloat16) == layers * hk * (
+        8320 * dh + 8320) * 4

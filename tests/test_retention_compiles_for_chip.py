@@ -1,0 +1,73 @@
+"""Power retention's two Mosaic kernels compiled for a TPU v5e that is
+described and not attached, at the geometry `brumby14b.doc_continue`
+runs them (16 slots, 40 query heads over 8 K/V heads of 128, pieces of
+8 to 128 rows): what interpret mode cannot show (a layout, a rotation or
+a transpose Mosaic refuses).  Nothing runs: a compile that passes is no
+chip run.  The state enters in place: no temporary the size of a slot's
+state."""
+
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+SLOTS, HEADS, KV, D = 16, 40, 8, 128
+
+
+def _state(arg):
+    from paddle_tpu.kernels.retention import stored_rows
+
+    return (arg((SLOTS, KV, stored_rows(D), D), jnp.float32),
+            arg((SLOTS, KV, stored_rows(D)), jnp.float32))
+
+
+def test_retention_step_compiles_for_v5e(one_chip):
+    from paddle_tpu.kernels.retention import retention_step_pallas
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: retention_step_pallas(*a, interpret=False),
+        donate_argnums=(0, 1)).lower(
+        *_state(arg), arg((SLOTS, HEADS, D), jnp.bfloat16),
+        arg((SLOTS, KV, D), jnp.bfloat16), arg((SLOTS, KV, D), jnp.bfloat16),
+        arg((SLOTS, KV), jnp.float32), arg((SLOTS,), jnp.bool_)).compile()
+    assert "retention_step" in compiled.as_text()
+    # one slot's state of one layer is 34 MB: nothing of that size is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+@pytest.mark.parametrize("rows", [8, 32, 128])
+def test_retention_chunk_compiles_for_v5e(rows, one_chip):
+    from paddle_tpu.kernels.retention import retention_chunk_pallas
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: retention_chunk_pallas(*a, interpret=False),
+        donate_argnums=(0, 1)).lower(
+        *_state(arg), arg((), jnp.int32), arg((), jnp.bool_),
+        arg((rows, HEADS, D), jnp.bfloat16), arg((rows, KV, D), jnp.bfloat16),
+        arg((rows, KV, D), jnp.bfloat16), arg((rows, KV), jnp.float32),
+        arg((rows,), jnp.bool_)).compile()
+    assert "retention_chunk" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
