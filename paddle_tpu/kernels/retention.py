@@ -40,11 +40,15 @@ donates them:
   by the group's query features and written back to where it came from.
   A dead slot's state is never read and never written.
 * ``chunk(S, z, slot, fresh, q, k, v, lg, valid)``: a piece of up to
-  ``PREFILL_PIECE`` rows of ONE slot (HLO name ``retention_chunk``):
+  ``CHUNK_ROWS`` rows of ONE slot (HLO name ``retention_chunk``):
   rows attend each other in the quadratic form, the state before the
   piece through ``phi(q)``, and the piece leaves the state advanced.
   Rows that are not ``valid`` (bucket padding, a suffix of the piece)
   advance nothing; ``fresh`` starts from zeros whatever the slot held.
+  A prefill window wider than that is the CALLER's walk over
+  consecutive calls (``chunk_rows`` says which, ``serving/
+  batched_decode._Cache.retain`` makes them), the state threaded
+  through: the kernel holds one call's scores in VMEM.
 
 Inference only (no VJP).
 """
@@ -60,7 +64,8 @@ from .registry import register_kernel, resolve
 
 __all__ = ["step", "chunk", "phi", "stored_rows", "published_rows",
            "feature_blocks", "retention_step_ref", "retention_chunk_ref",
-           "retention_step_pallas", "retention_chunk_pallas", "TILE_BLOCKS"]
+           "retention_step_pallas", "retention_chunk_pallas", "TILE_BLOCKS",
+           "CHUNK_ROWS", "chunk_rows"]
 
 # feature blocks ([d, d] float32 tiles of a K/V head's state) a grid step
 # of the step kernel streams: 13 of 65 at d 128 is 852 kB in and as much
@@ -70,6 +75,9 @@ TILE_BLOCKS = 13
 # kernel, in and out and double-buffered: more than Mosaic's 16 MiB
 # default scoped VMEM
 _CHUNK_VMEM_BYTES = 64 << 20
+# the rows of one ``chunk`` call: the kernel keeps a K/V head's scores
+# ``[G * C, C]`` and its decays in VMEM beside the state
+CHUNK_ROWS = 128
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -102,6 +110,14 @@ def phi(u):
                         for r in range(feature_blocks(d))], axis=-2)
     coefs = jnp.asarray(_coefs(d), jnp.float32)
     return coefs[:, None] * u[..., None, :] * rolled
+
+
+def chunk_rows(width):
+    """The rows of the consecutive ``chunk`` calls that advance a state
+    over a window of ``width`` rows: whole calls of ``CHUNK_ROWS``, then
+    the rest."""
+    full, rest = divmod(int(width), CHUNK_ROWS)
+    return [CHUNK_ROWS] * full + ([rest] if rest else [])
 
 
 def step(S, z, q, k, v, lg, valid, eps=1e-6):

@@ -9,8 +9,8 @@ blocks, per-slot block tables, reference-counted prefix reuse with
 copy-on-write forks and LRU cache eviction), admission between decode
 chunks (continuous batching) ordered by the SLO scheduler
 (``scheduler``: least predicted-TTFT slack, e2e-doomed requests shed),
-power-of-two shape-bucketed SUFFIX prefill so compile count is bounded
-by the bucket set, and full ``serving.*`` telemetry through the
+shape-bucketed SUFFIX prefill (a short ladder of window widths, the
+widest past the chip's ridge) so compile count is bounded by the rungs, and full ``serving.*`` telemetry through the
 observability registry.  What a model is made of reaches it as one
 ``arch.Architecture`` (the GPT-2 block, or a looped RMSNorm / rotary /
 gated-FFN stack with a K/V plane per pass).

@@ -24,7 +24,9 @@ gathers THROUGH the table, so:
   recompile);
 * the compiled-executable count is bounded by shapes, never by
   requests or prompt lengths — ONE decode chunk plus one prefill per
-  window WIDTH (the power-of-two buckets up to ``PREFILL_PIECE``);
+  window WIDTH (``prefill_rungs``: from the engine's ``min_bucket`` a
+  factor of ``RUNG_STEP`` apart under the chip's ridge, doublings from
+  there to ``PREFILL_PIECE``);
 * unused table entries point at physical block 0, the trash block:
   overrun steps (a finished slot riding out the chunk) and window rows
   past their ``limit`` (prefill bucket padding, a verify window
@@ -90,12 +92,58 @@ from ..kernels import retention as _retention
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
-           "make_verify_window", "PREFILL_PIECE"]
+           "make_verify_window", "PREFILL_PIECE", "RUNG_STEP",
+           "STREAM_ROWS", "prefill_rungs", "piece_widths"]
 
 # the widest window one prefill call computes; a longer suffix is
-# prefilled as consecutive pieces of this width plus one bucketed
-# remainder (PERF.md, PR 26, has the chip's comparison of 64/128/256)
-PREFILL_PIECE = 128
+# prefilled as consecutive pieces of this width plus one remainder on a
+# narrower rung.  A bf16 matrix costs 2 B a parameter to stream and 2
+# operations a parameter a row to apply, so a window meets a v5e's ridge
+# (197 TFLOP/s over 819 GB/s) at 240 rows: under it a piece is a weight
+# stream with the MXU part empty, whatever the architecture.  512 is 2.1
+# times the ridge (the stream is under half of a whole piece; 1,024 buys
+# no more a token and holds the driver and the dense scores [h, W,
+# max_len] twice as long).  PR 26 compared 64 / 128 / 256 on the chip
+# and found no difference, in a cell whose suffixes were 8-128 tokens:
+# no request could fill a wider piece (PERF.md section 6, PR 43;
+# benchmarks/RESULTS.md "prefill_walk" has a piece's cost by width).
+# Read at trace time, like kernels.paged_attention.DENSE_WINDOW.
+PREFILL_PIECE = 512
+# the rungs of window widths: a factor of RUNG_STEP apart up to
+# STREAM_ROWS, the widest power of two under the ridge (there a piece is
+# a weight stream and a padded row costs no weight byte: two rungs more
+# on a ladder of doublings would be two executables more in every engine
+# that sees short suffixes, 2-10 s each, warm), doublings from there to
+# the piece (past the ridge a padded row costs its operations, and the
+# dense attention, a recurrence and retention's chunk kernel cost theirs
+# at every width: benchmarks/RESULTS.md "prefill_walk" has a hybrid
+# stack's 512-row piece at 3.4 times its 128-row one)
+RUNG_STEP = 4
+STREAM_ROWS = 128
+
+
+def prefill_rungs(min_bucket, max_len):
+    """The window widths an engine may compile, ascending: ``min_bucket``
+    and its multiples a factor of ``RUNG_STEP`` apart up to
+    ``STREAM_ROWS``, doublings above, the piece width the widest, none
+    wider than ``max_len``."""
+    rungs, b = [], int(min_bucket)
+    while b < PREFILL_PIECE:
+        rungs.append(b)
+        b *= RUNG_STEP if b * RUNG_STEP <= STREAM_ROWS else 2
+    rungs.append(max(int(min_bucket), PREFILL_PIECE))
+    return sorted({min(r, int(max_len)) for r in rungs})
+
+
+def piece_widths(n, rungs):
+    """Window widths that prefill a suffix of ``n`` tokens on the ladder
+    ``rungs``: whole pieces of the widest rung, then the remainder in
+    the narrowest rung that covers it."""
+    full, rem = divmod(max(int(n), 0), rungs[-1])
+    widths = [rungs[-1]] * full
+    if rem or not full:
+        widths.append(next(r for r in rungs if r >= rem))
+    return widths
 
 
 class _Cache:
@@ -221,10 +269,18 @@ class _Cache:
                 "no in-place form: kernels.retention.chunk advances ONE "
                 "slot's state over a piece")
         else:
-            y, S, z = _retention.chunk(
-                S, z, self.slot, self.pos[0, 0] == 0, q[0], k[0], v[0],
-                lg[0], self.valid[0], **how)
-            y = y[None]
+            # a window wider than one kernel call walks it in consecutive
+            # calls, the state threaded through in place: only the first
+            # can start a prompt, and each honours its own rows' limit
+            fresh, ys, at = self.pos[0, 0] == 0, [], 0
+            for rows in _retention.chunk_rows(q.shape[1]):
+                cut = slice(at, at + rows)
+                y, S, z = _retention.chunk(
+                    S, z, self.slot, fresh, q[0, cut], k[0, cut],
+                    v[0, cut], lg[0, cut], self.valid[0, cut], **how)
+                ys.append(y)
+                fresh, at = False, at + rows
+            y = (ys[0] if len(ys) == 1 else jnp.concatenate(ys))[None]
         return y, planes[:2] + (
             planes[2][:i] + ((S, z),) + planes[2][i + 1:],)
 
@@ -440,8 +496,8 @@ def make_verify_window(arch, k, donate=True):
 
 
 def make_prefill(arch, bucket, donate=True):
-    """Build the prefill executable for one window WIDTH (a suffix
-    bucket of at most ``PREFILL_PIECE`` tokens).
+    """Build the prefill executable for one window WIDTH (a rung of
+    ``prefill_rungs``: at most ``PREFILL_PIECE`` tokens).
 
     ``fn(params, pool_k, pool_v, last_tok, pos, slot, table_row [NB],
     toks [bucket], start, length, cow_src, cow_dst, state=()) ->

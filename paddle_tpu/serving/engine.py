@@ -28,9 +28,12 @@ decode step saturated across many requests:
   never holds the batch hostage, a short one never waits for stragglers.
 * **Bucketed prefill** — the NON-CACHED prompt suffix is one
   teacher-forced window forward through the block table, padded to the
-  nearest power-of-two bucket up to the piece width
-  (``batched_decode.PREFILL_PIECE``); a longer suffix runs as
-  consecutive pieces inside the same admission.  The compile cache is
+  narrowest rung that covers it (``batched_decode.prefill_rungs``:
+  from ``min_bucket`` a factor of four apart under the chip's ridge,
+  doublings from there to the piece width
+  ``batched_decode.PREFILL_PIECE``, twice the ridge);
+  a longer suffix runs as whole pieces and one remainder inside the
+  same admission.  The compile cache is
   bounded by the widths (TVM-style static shape buckets), never by the
   request count or the prompt length: total executables =
   ``len(used widths) + 1`` decode chunk — the copy-on-write fork rides
@@ -63,6 +66,7 @@ import time
 import numpy as np
 
 from ..kernels import paged_attention as _paged
+from ..kernels import retention as _retention
 from ..observability import flight as _flight
 from ..observability import metrics as _obs
 from ..observability import trace as _trace
@@ -197,9 +201,10 @@ class ServingEngine:
     decode_chunk  decode steps fused per device call (tokens reach
              the host in chunks of this many).
     min_bucket    narrowest prefill window; prompt SUFFIXES (after prefix
-             reuse) pad to the nearest power-of-two multiple of it up
-             to the piece width, and run as several pieces beyond it
-             (compile-count bound).
+             reuse) pad to the narrowest rung that covers them (this
+             and its multiples a factor of four apart up to 128,
+             doublings from there to the piece width), and run as
+             several pieces beyond it (compile-count bound).
     block_tokens  tokens per physical KV block (paging granularity —
              also the prefix-sharing granularity: only whole blocks are
              shared, a partial overlap forks copy-on-write).
@@ -290,8 +295,8 @@ class ServingEngine:
             raise ValueError("decode_chunk and min_bucket must be >= 1")
         self.decode_chunk = int(decode_chunk)
         self.min_bucket = int(min_bucket)
-        # the widest prefill window (never narrower than a bucket)
-        self._piece = max(self.min_bucket, _bd.PREFILL_PIECE)
+        # the window widths prefill may compile; the last is the piece
+        self._rungs = _bd.prefill_rungs(self.min_bucket, self.max_len)
         arch.check_params(params, self.max_len)
         state_spec = arch.state_spec(self.compute_dtype)
         if state_spec and prefix_reuse:
@@ -959,25 +964,15 @@ class ServingEngine:
         return call
 
     def _piece_widths(self, n):
-        """Window widths that prefill a suffix of ``n`` tokens: whole
-        pieces of the piece width (``batched_decode.PREFILL_PIECE``),
-        then the remainder in the smallest power-of-two multiple of
-        ``min_bucket`` that covers it, capped at the piece width and at
-        ``max_len``."""
-        full, rem = divmod(max(int(n), 0), self._piece)
-        widths = [self._piece] * full
-        if rem or not full:
-            b = self.min_bucket
-            while b < rem:
-                b *= 2
-            widths.append(min(b, self._piece, self.max_len))
-        return widths
+        """Window widths that prefill a suffix of ``n`` tokens
+        (``batched_decode.piece_widths`` on this engine's rungs)."""
+        return _bd.piece_widths(n, self._rungs)
 
     def bucket_for(self, p_len):
         """Padded tokens prefill computes for a (suffix) length: the
         sum of its window widths.  Up to the piece width that is the
-        one power-of-two bucket; two lengths with the same value run
-        the same executables."""
+        one rung; two lengths with the same value run the same
+        executables."""
         return sum(self._piece_widths(p_len))
 
     def _pieces(self, toks, start):
@@ -1533,11 +1528,14 @@ class ServingEngine:
                 help="prefill window calls dispatched, by width (an "
                      "admission is one or more pieces)").inc()
             if self.arch.retention_layers:
-                self._reg.counter(
-                    "serving.retention_piece_rows", width=w,
-                    help="rows of the prefill pieces' retention calls, "
-                         "by piece width (padding included: a call "
-                         "computes its width), a layer").inc(w)
+                for rows in _retention.chunk_rows(w):
+                    self._reg.counter(
+                        "serving.retention_piece_rows", width=rows,
+                        help="rows of the prefill pieces' retention "
+                             "calls, by the CALL's width (a piece wider "
+                             "than kernels.retention.CHUNK_ROWS is "
+                             "several; padding included: a call "
+                             "computes its width), a layer").inc(rows)
         self._reg.histogram("serving.ttft_seconds").observe(
             now - req.submit_t)
         with self._qlock:

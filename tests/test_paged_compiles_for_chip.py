@@ -218,6 +218,8 @@ GROUPED = {
     "chat_moe_decode": (384, 3072, 3072, 32),
     "chat_moe_prefill_piece": (512, 3072, 3072, 32),
     "chat_moe_narrow_piece": (32, 3072, 3072, 32),
+    # the widest rung since PR 43: 512 rows x 4
+    "chat_moe_wide_piece": (2048, 3072, 3072, 32),
     # serving.arch.LatentMoE at the published widths, 16 experts held,
     # top 6: 12 slots x 6 rows a decode step, 128 x 6 a prefill piece;
     # 1408 is 11 lane tiles, so only 128-wide panels divide it
@@ -225,6 +227,8 @@ GROUPED = {
     "doc_qa_decode_down": (72, 1408, 2048, 16),
     "doc_qa_prefill_piece_gate_up": (768, 2048, 1408, 16),
     "doc_qa_prefill_piece_down": (768, 1408, 2048, 16),
+    "doc_qa_wide_piece_gate_up": (3072, 2048, 1408, 16),
+    "doc_qa_wide_piece_down": (3072, 1408, 2048, 16),
 }
 
 

@@ -126,10 +126,16 @@ def test_no_plane_no_pool_no_table(served):
     assert stats["serving.state_bytes"] == 3 * per_slot
     assert not any(k.startswith("serving.paged_") for k in stats)
     assert stats["serving.retention_slot_steps"] > 0
-    # 300 rows are two pieces of 128 and one of 64; 128 is one piece
-    assert stats["serving.retention_piece_rows{width=128}"] == 128 * 4
-    assert stats["serving.retention_piece_rows{width=64}"] == 64 * 2
+    # by the retention CALL's width: 300 rows are one piece of 400 (the
+    # widest rung, capped at max_len: three calls of 128 and one of 16),
+    # 140 one of 256 (two calls); 128 and 61 one call of 128; 17 one of
+    # 32; 5 one of 8
+    assert stats["serving.retention_piece_rows{width=128}"] == 128 * 7
+    assert stats["serving.retention_piece_rows{width=16}"] == 16
+    assert stats["serving.retention_piece_rows{width=32}"] == 32
     assert stats["serving.retention_piece_rows{width=8}"] == 8
+    assert stats["serving.prefill_pieces{width=400}"] == 1
+    assert stats["serving.prefill_pieces{width=256}"] == 1
 
 
 def test_state_spec_is_two_float32_arrays_a_layer():

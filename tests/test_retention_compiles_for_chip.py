@@ -71,3 +71,43 @@ def test_retention_chunk_compiles_for_v5e(rows, one_chip):
         arg((rows,), jnp.bool_)).compile()
     assert "retention_chunk" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def test_a_wide_piece_is_consecutive_chunk_calls_in_place_for_v5e(one_chip):
+    """A 512-row prefill window through ``_Cache.retain``: four calls of
+    the 128-row kernel (its VMEM budget and its name are the narrow
+    piece's), the state threaded through them with no copy of it."""
+    from paddle_tpu.kernels import retention as rt
+    from paddle_tpu.serving.batched_decode import _Cache
+
+    rows = 512
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def piece(S, z, slot, start, q, k, v, lg, n):
+        at = jnp.arange(rows)[None]
+        cache = _Cache(None, None, None, None, start + at,
+                       writable=at < n, slot=slot)
+        y, planes = cache.retain(((), (), ((S, z),)), 0, q, k, v, lg)
+        return (y,) + planes[2][0]
+
+    real = rt.chunk
+    rt.chunk = lambda *a, **kw: rt.retention_chunk_pallas(
+        *a, interpret=False, **kw)
+    try:
+        lowered = jax.jit(piece, donate_argnums=(0, 1)).lower(
+            *_state(arg), arg((), jnp.int32), arg((), jnp.int32),
+            arg((1, rows, HEADS, D), jnp.bfloat16),
+            arg((1, rows, KV, D), jnp.bfloat16),
+            arg((1, rows, KV, D), jnp.bfloat16),
+            arg((1, rows, KV), jnp.float32), arg((), jnp.int32))
+    finally:
+        rt.chunk = real
+    compiled = lowered.compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == len(rt.chunk_rows(rows)) == 4
+    # 16 slots' state of a layer is 545 MB and one slot's 34: the four
+    # calls hand it on in place (the rows' own temporaries: 512 x 40 x
+    # 128 float32 outputs, the decays)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
