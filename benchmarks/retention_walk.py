@@ -12,9 +12,14 @@ whether a dead slot's state came back bit-equal.
 The geometry is ``brumby14b.doc_continue``'s: ONE layer's state of 16
 slots (8 K/V heads of 8,320 stored rows of 128 lanes, float32: 545 MB),
 40 query heads; a decode step with 1, 4, 6, 8, 12 and 16 slots live
-(which ones is drawn from ``--seed``), a prefill piece of 32 and of 128
-rows, and one of 64 rows of which 40 are real.  The state is donated and
-threaded from call to call, as the engine does it.
+(which ones is drawn from ``--seed``), a prefill piece of 32, 128, 256
+and 512 rows that continues a prompt, one of 512 rows that STARTS one
+(``fresh``: the state is never read), and pieces of 64 rows of which 40
+and of 512 of which 300 are real.  A piece goes through ``serving/
+batched_decode._Cache.retain``, which hands it to the kernel in the
+calls ``kernels.retention.chunk_rows`` names (one since PR 44, four of
+128 rows a 512-row piece before), ``us_a_piece`` their sum.  The state is
+donated and threaded from call to call, as the engine does it.
 Refuses unless JAX finds a TPU: a number from a CPU run is no device
 metric.
 """
@@ -31,20 +36,23 @@ from flash_walk import _mosaic_seconds  # noqa: E402 - Mosaic calls' seconds
 
 CONFIG = "brumby-14b-base"
 SLOTS = 16
-# name -> ("step", live slots) or ("piece", rows, real rows)
+# name -> ("step", live slots) or ("piece", rows, real rows[, fresh])
 GEOMETRIES = {
     "step_1_live": ("step", 1), "step_4_live": ("step", 4),
     "step_6_live": ("step", 6), "step_8_live": ("step", 8),
     "step_12_live": ("step", 12), "step_16_live": ("step", 16),
     "piece_32": ("piece", 32, 32), "piece_128": ("piece", 128, 128),
     "piece_64_of_which_40": ("piece", 64, 40),
+    "piece_256": ("piece", 256, 256), "piece_512": ("piece", 512, 512),
+    "piece_512_fresh": ("piece", 512, 512, True),
+    "piece_512_of_which_300": ("piece", 512, 300),
 }
 
 
-def _device_us(fn, state, args, calls):
-    """Microseconds a Mosaic call over ``calls`` calls of ``fn(S, z,
-    *args) -> (y, S, z)``, the state threaded; returns it with the last
-    outputs."""
+def _device_us(fn, state, args, calls, each=1):
+    """Microseconds of the ``each`` Mosaic calls of one ``fn(S, z, *args)
+    -> (y, S, z)``, over ``calls`` of them with the state threaded;
+    returns it with the last outputs."""
     import jax
 
     y, *state = fn(*state, *args)  # compile, warm
@@ -55,9 +63,26 @@ def _device_us(fn, state, args, calls):
                 y, *state = fn(*state, *args)
             jax.block_until_ready(y)
         n, seconds = _mosaic_seconds(td)
-    if n != calls:
-        raise RuntimeError(f"{n} Mosaic calls in the trace, {calls} made")
-    return 1e6 * seconds / n, y, state
+    if n != calls * each:
+        raise RuntimeError(f"{n} Mosaic calls in the trace, {calls * each} "
+                           f"made")
+    return 1e6 * seconds / calls, y, state
+
+
+def _piece(S, z, slot, fresh, q, k, v, lg, valid):
+    """A piece as the engine hands it to the kernel: ``_Cache.retain``
+    over a window of one slot, which starts a prompt where its first
+    position is 0."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.batched_decode import _Cache
+
+    at = jnp.arange(q.shape[0])[None]
+    cache = _Cache(None, None, None, None, jnp.where(fresh, 0, 1) + at,
+                   writable=valid[None], slot=slot)
+    y, planes = cache.retain(((), (), ((S, z),)), 0, q[None], k[None],
+                             v[None], lg[None])
+    return (y[0],) + planes[2][0]
 
 
 def measure(name, calls, seed, peak, cfg):
@@ -102,16 +127,17 @@ def measure(name, calls, seed, peak, cfg):
         dead = int(np.flatnonzero(~valid)[0]) if live < SLOTS else None
         out.update(live_slots=live)
     else:
-        n, real = shape
+        n, real, fresh = (shape + [False])[:3]
         slot = int(rng.integers(SLOTS))
-        args = (jnp.int32(slot), jnp.asarray(False), rows(n, h), rows(n, hk),
+        args = (jnp.int32(slot), jnp.asarray(fresh), rows(n, h), rows(n, hk),
                 rows(n, hk), gates(n), jnp.arange(n) < real)
-        fn = jax.jit(rt.retention_chunk_pallas, donate_argnums=(0, 1))
+        each = len(rt.chunk_rows(n))
+        fn = jax.jit(_piece, donate_argnums=(0, 1))
         ref = jax.jit(rt.retention_chunk_ref)
         least = retention_bytes.least_seconds(
             *retention_bytes.piece(cfg, n), peak)
         dead = (slot + 1) % SLOTS
-        out.update(rows=n, real_rows=real)
+        out.update(rows=n, real_rows=real, fresh=fresh, calls_a_piece=each)
     # one call against the xla_ref form on the same state
     state = make(key)
     before = None if dead is None else np.asarray(state[0][dead, 0, :256])
@@ -124,8 +150,10 @@ def measure(name, calls, seed, peak, cfg):
     untouched = None if dead is None else bool(
         np.array_equal(np.asarray(S[dead, 0, :256]), before))
     del want_y, want_S, want_z
-    us, _, _ = _device_us(fn, (S, z), args, calls)
-    out.update(us_a_call=us, roofline_pct=100e6 * least / us,
+    us, _, _ = _device_us(fn, (S, z), args, calls,
+                          1 if kind == "step" else each)
+    out.update(**{"us_a_call" if kind == "step" else "us_a_piece": us},
+               roofline_pct=100e6 * least / us,
                worst_error=err, worst_state_error=max(err_S, err_z),
                dead_slot_untouched=untouched)
     if kind == "step":

@@ -40,15 +40,28 @@ donates them:
   by the group's query features and written back to where it came from.
   A dead slot's state is never read and never written.
 * ``chunk(S, z, slot, fresh, q, k, v, lg, valid)``: a piece of up to
-  ``CHUNK_ROWS`` rows of ONE slot (HLO name ``retention_chunk``):
-  rows attend each other in the quadratic form, the state before the
-  piece through ``phi(q)``, and the piece leaves the state advanced.
-  Rows that are not ``valid`` (bucket padding, a suffix of the piece)
-  advance nothing; ``fresh`` starts from zeros whatever the slot held.
-  A prefill window wider than that is the CALLER's walk over
-  consecutive calls (``chunk_rows`` says which, ``serving/
-  batched_decode._Cache.retain`` makes them), the state threaded
-  through: the kernel holds one call's scores in VMEM.
+  ``CALL_ROWS`` rows of ONE slot in ONE call (HLO name
+  ``retention_chunk``): rows attend each other in the quadratic form,
+  the state before the piece through ``phi(q)``, and the piece leaves the
+  state advanced.  Rows that are not ``valid`` (bucket padding, a suffix
+  of the piece) advance nothing; ``fresh`` starts from zeros whatever the
+  slot held, and never reads it.
+  The kernel walks the call's rows in TILES of ``CHUNK_ROWS`` inside one
+  grid step a K/V head: the head's state is loaded once and written
+  once; its two bfloat16 pieces, the blocks' coefficients ``c_r`` folded
+  in, are made once into VMEM; a tile's scores run against the piece's
+  rows up to its own diagonal; its read of the state accumulates in VMEM
+  over groups of ``TILE_BLOCKS`` feature blocks laid side by side (one
+  MXU contraction of ``TILE_BLOCKS * d`` a group); the decay from the
+  piece's start multiplies the ROWS of that product, so ``phi(q)`` is
+  made from the rows as they came (of two bfloat16 rows it is exactly
+  two bfloat16 pieces); the normaliser's read is ``q^T Z q`` with ``Z
+  [d, d]`` gathered from ``z`` before the call; the state is advanced
+  once, by one contraction over all the piece's rows.
+  ``chunk_rows(width)`` names the calls of a window (one; a window wider
+  than any rung of the prefill ladder is consecutive calls, the state
+  threaded through, which ``serving/batched_decode._Cache.retain``
+  makes).
 
 Inference only (no VJP).
 """
@@ -65,7 +78,7 @@ from .registry import register_kernel, resolve
 __all__ = ["step", "chunk", "phi", "stored_rows", "published_rows",
            "feature_blocks", "retention_step_ref", "retention_chunk_ref",
            "retention_step_pallas", "retention_chunk_pallas", "TILE_BLOCKS",
-           "CHUNK_ROWS", "chunk_rows"]
+           "CHUNK_ROWS", "CALL_ROWS", "chunk_rows"]
 
 # feature blocks ([d, d] float32 tiles of a K/V head's state) a grid step
 # of the step kernel streams: 13 of 65 at d 128 is 852 kB in and as much
@@ -75,9 +88,14 @@ TILE_BLOCKS = 13
 # kernel, in and out and double-buffered: more than Mosaic's 16 MiB
 # default scoped VMEM
 _CHUNK_VMEM_BYTES = 64 << 20
-# the rows of one ``chunk`` call: the kernel keeps a K/V head's scores
-# ``[G * C, C]`` and its decays in VMEM beside the state
+# the rows of a TILE of the chunk kernel's walk over its call's rows: a
+# tile's scores ``[G * CHUNK_ROWS, rows up to its diagonal]`` and its
+# features are what the kernel holds in VMEM beside the state
 CHUNK_ROWS = 128
+# the rows of the widest ``chunk`` call, the widest rung of the prefill
+# ladder (``serving.batched_decode.PREFILL_PIECE``): a call's decays
+# ``[CALL_ROWS, CALL_ROWS]`` float32 sit in VMEM twice
+CALL_ROWS = 512
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -113,11 +131,12 @@ def phi(u):
 
 
 def chunk_rows(width):
-    """The rows of the consecutive ``chunk`` calls that advance a state
-    over a window of ``width`` rows: whole calls of ``CHUNK_ROWS``, then
-    the rest."""
-    full, rest = divmod(int(width), CHUNK_ROWS)
-    return [CHUNK_ROWS] * full + ([rest] if rest else [])
+    """The rows of the ``chunk`` CALLS that advance a state over a window
+    of ``width`` rows: ONE up to ``CALL_ROWS`` (the kernel walks its rows
+    in tiles of ``CHUNK_ROWS`` itself), whole calls of that and then the
+    rest for a window wider than any rung."""
+    full, rest = divmod(int(width), CALL_ROWS)
+    return [CALL_ROWS] * full + ([rest] if rest else [])
 
 
 def step(S, z, q, k, v, lg, valid, eps=1e-6):
@@ -228,26 +247,39 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
+def _pieces(x, n):
+    """``x`` as ``n`` bfloat16 pieces that sum to it to ``8 n`` bits."""
+    out = []
+    for _ in range(n):
+        out.append(x.astype(jnp.bfloat16))
+        x = x - out[-1].astype(jnp.float32)
+    return tuple(out)
+
+
 def _halves(x):
     """``x`` as bfloat16 pieces that sum to it to 16 bits (one piece for
     an array that is bfloat16 already)."""
-    if x.dtype == jnp.bfloat16:
-        return (x,)
-    hi = x.astype(jnp.bfloat16)
-    return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    return (x,) if x.dtype == jnp.bfloat16 else _pieces(x, 2)
+
+
+def _dot_split(ap, bp, dims, order):
+    """The product of two operands given as bfloat16 pieces, largest
+    first: the pieces' products whose ranks sum under ``order``, one MXU
+    pass each."""
+    out = None
+    for i, a in enumerate(ap):
+        for j, b in enumerate(bp):
+            if i + j < order:
+                out = _dot(a, b, dims) if out is None \
+                    else out + _dot(a, b, dims)
+    return out
 
 
 def _dot_hl(a, b, dims):
     """A float32 product on the MXU at 16 bits of each operand: the
     pieces' products but the smallest (three bfloat16 passes for two
     float32 operands)."""
-    ah, bh = _halves(a), _halves(b)
-    out = _dot(ah[0], bh[0], dims)
-    if len(bh) > 1:
-        out = out + _dot(ah[0], bh[1], dims)
-    if len(ah) > 1:
-        out = out + _dot(ah[1], bh[0], dims)
-    return out
+    return _dot_split(_halves(a), _halves(b), dims, 2)
 
 
 def retention_step_pallas(S, z, q, k, v, lg, valid, eps=1e-6,
@@ -369,106 +401,205 @@ def retention_step_pallas(S, z, q, k, v, lg, valid, eps=1e-6,
             zn.reshape(z.shape))
 
 
+def _row_tile(C):
+    """The rows of a tile of the chunk kernel's walk over a call of ``C``
+    rows: the widest multiple of 8 within ``CHUNK_ROWS`` that divides
+    it."""
+    return max(t for t in range(8, min(C, CHUNK_ROWS) + 1, 8) if C % t == 0)
+
+
 def retention_chunk_pallas(S, z, slot, fresh, q, k, v, lg, valid, eps=1e-6,
                            interpret=None):
-    """The Mosaic chunk kernel (module docstring)."""
+    """The Mosaic chunk kernel (module docstring).  One inner ``jit`` for
+    all the layers of a stack: the program that holds them lowers the
+    kernel once, not once a layer."""
+    return _chunk_call(S, z, slot, fresh, q, k, v, lg, valid, eps=float(eps),
+                       interpret=_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _chunk_call(S, z, slot, fresh, q, k, v, lg, valid, *, eps, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    f32 = jnp.float32
+    f32, bf16 = jnp.float32, jnp.bfloat16
     N, hk, _, d = S.shape
     C, R, G = q.shape[0], feature_blocks(d), q.shape[1] // hk
     if C % 8 or d % 8:
         raise ValueError(f"retention_chunk: {C} rows of {d} lanes (both "
                          f"multiples of 8)")
+    T = _row_tile(C)
+    nT, GT = C // T, G * T
+    U = _tile_blocks(R)
+    nG = R // U
     inv_d = 1.0 / d
     norm = 1.0 / math.sqrt(d)
     cum, dec = _piece_decays(lg, valid)
     kz = jnp.where(valid[:, None, None], k, jnp.zeros_like(k))
-    # rows of a K/V head's group together, head-major: [hk, G * C, d]
-    qg = jnp.moveaxis(q.reshape(C, hk, G, d), 0, 2)
-    qb = qg.reshape(hk, G * C, d)
-    # phi is quadratic: sqrt(e) q carries the decay e from the piece's
-    # start into phi(q)
-    qs = (qg.astype(f32) * jnp.exp(0.5 * cum).T[:, None, :, None]).reshape(
+    # a row tile's rows of a K/V head's group together, tile-major and
+    # then head-major: [hk, nT * G * T, d]
+    qt = jnp.transpose(q.reshape(nT, T, hk, G, d), (2, 0, 3, 1, 4)).reshape(
         hk, G * C, d)
     kb, vb = jnp.moveaxis(kz, 0, 1), jnp.moveaxis(v, 0, 1)    # [hk, C, d]
+    # the decay from the piece's start through a row: what the state
+    # before the piece is read through, a factor of the ROW
+    e = jnp.exp(cum).T[:, :, None]                            # [hk, C, 1]
     w = jnp.exp(cum[-1][None] - cum)                          # [C, hk]
     # (w v)^T over the rows, then w itself: one product advances S and z
     vdT = jnp.concatenate(
         [jnp.moveaxis(w[..., None] * v.astype(f32), 0, 2),
          w.T[:, None, :], jnp.zeros((hk, 7, C), f32)], axis=1)
-    aux = jnp.concatenate(
-        [jnp.broadcast_to(jnp.exp(cum[-1])[:, None, None], (hk, 1, d)),
-         jnp.broadcast_to(jnp.where(fresh, 0.0, 1.0).astype(f32),
-                          (hk, 1, d)),
-         jnp.zeros((hk, 6, d), f32)], axis=1)
+    gc = jnp.broadcast_to(jnp.exp(cum[-1])[:, None, None], (hk, 8, d))
     z4 = z.reshape(N, hk, R, d)
-    slot = jnp.asarray(slot, jnp.int32).reshape(1)
-    exact = None if q.dtype == jnp.bfloat16 else _HIGHEST
+    slot = jnp.asarray(slot, jnp.int32)
+    # phi(q) . z as q^T Z q: Z[b, a] holds c_r z[r, a] at r = (a - b) mod
+    # d, the cyclic distance the features pair the lanes a and b at
+    lane = jnp.arange(d)
+    dist = (lane[None, :] - lane[:, None]) % d
+    z0 = jax.lax.dynamic_index_in_dim(z4, slot, 0, False)     # [hk, R, d]
+    zq = jnp.where(dist < R, (jnp.asarray(_coefs(d), f32)[:, None] * z0)[
+        :, jnp.minimum(dist, R - 1), lane[None, :]], 0.0)     # [hk, d, d]
+    head = jnp.stack([slot, jnp.asarray(fresh, jnp.int32)])
+    exact = None if q.dtype == bf16 else _HIGHEST
+    nt = (((1,), (1,)), ((), ()))
 
-    def kernel(slot_ref, qb_ref, qs_ref, kb_ref, vb_ref, dec_ref, vdT_ref,
-               aux_ref, s_ref, z_ref, y_ref, so_ref, zo_ref, zs_ref):
-        gc, keep = aux_ref[0:1, :], aux_ref[1:2, :]           # [1, d]
-        kk = kb_ref[...]
-        s = jax.lax.dot_general(
-            qb_ref[...], kk, (((1,), (1,)), ((), ())), precision=exact,
-            preferred_element_type=f32)                       # [G C, C]
-        a = (s * s * inv_d).reshape(G, C, C) * dec_ref[...][None]
-        a = a.reshape(G * C, C)
-        num = _dot_hl(a, vb_ref[...], ((1,), (0,)))           # [G C, d]
-        den = jnp.sum(a, axis=1, keepdims=True)               # [G C, 1]
-        for r in range(R):
-            zs_ref[r, 0:1, :] = z_ref[r:r + 1, :] * keep
-        qs0, k0 = qs_ref[...], kk.astype(f32)
-        vdT0 = vdT_ref[...]
+    def kernel(head_ref, qt_ref, kb_ref, vb_ref, dec_ref, e_ref, vdT_ref,
+               gc_ref, zq_ref, s_ref, z_ref, y_ref, so_ref, zo_ref,
+               qf_ref, acc_ref, den_ref, sh_ref, sl_ref, zs_ref):
+        cont = head_ref[1] == 0
+        gate = gc_ref[0:1, :]                                 # [1, d]
 
-        def block(r, carry):
-            num, den_in, rq, rk = carry
-            c = jnp.where((r == 0) | (r == R - 1), norm,
-                          norm * math.sqrt(2.0))
-            pq, pk = c * qs0 * rq, c * k0 * rk
-            at = pl.ds(pl.multiple_of(r * d, d), d)
-            S0 = s_ref[at, :] * keep                          # [j, a]
-            z0 = zs_ref[r][0:1, :]
-            num = num + _dot_hl(pq, S0, ((1,), (1,)))
-            den_in = den_in + pq * z0
-            upd = _dot_hl(vdT0, pk, ((1,), (0,)))             # [d + 8, a]
-            so_ref[at, :] = gc * S0 + upd[:d]
-            zs_ref[r, 1:2, :] = gc * z0 + upd[d:d + 1]
-            return (num, den_in, pltpu.roll(rq, 1, 1), pltpu.roll(rk, 1, 1))
+        def coef(r):
+            return jnp.where((r == 0) | (r == R - 1), norm,
+                             norm * math.sqrt(2.0))
 
-        num, den_in, _, _ = jax.lax.fori_loop(
-            0, R, block, (num, jnp.zeros((G * C, d), f32), qs0, k0))
+        def feats(x, g):
+            """Group ``g``'s ``U`` feature blocks of the float32 rows
+            ``x`` less their coefficients, side by side on the lanes, as
+            bfloat16 pieces (both of them exact where the rows were
+            bfloat16: a product of two has 16 bits)."""
+            hi, lo = zip(*(_pieces(x * pltpu.roll(x, g * U + i, 1), 2)
+                           for i in range(U)))
+            return jnp.concatenate(hi, axis=1), jnp.concatenate(lo, axis=1)
+
+        # the piece's rows attend each other in the quadratic form, a
+        # tile up to its own diagonal
+        for i in range(nT):
+            rows, W = slice(i * GT, (i + 1) * GT), (i + 1) * T
+            s = jax.lax.dot_general(
+                qt_ref[rows, :], kb_ref[0:W, :], nt, precision=exact,
+                preferred_element_type=f32)                   # [G T, W]
+            a = (s * s * inv_d).reshape(G, T, W) * dec_ref[
+                i * T:(i + 1) * T, 0:W][None]
+            a = a.reshape(GT, W)
+            y_ref[rows, :] = _dot_hl(a, vb_ref[0:W, :], ((1,), (0,)))
+            den_ref[rows, :] = jnp.sum(a, axis=1, keepdims=True)
+
+        @pl.when(cont)
+        def _():
+            # the state before the piece, read ONCE: its bfloat16 pieces
+            # with the blocks' coefficients, a group's blocks side by
+            # side on the lanes
+            def split(g, carry):
+                for i in range(U):
+                    r = g * U + i
+                    lanes = slice(i * d, (i + 1) * d)
+                    sh_ref[g, :, lanes], sl_ref[g, :, lanes] = _pieces(
+                        coef(r) * s_ref[pl.ds(pl.multiple_of(r * d, d), d),
+                                        :], 2)
+                return carry
+
+            jax.lax.fori_loop(0, nG, split, 0)
+            qf_ref[...] = qt_ref[...].astype(f32)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+            def read(n, carry):
+                rows, g = pl.ds(pl.multiple_of(n // nG * GT, 8), GT), n % nG
+                ph, pw = feats(qf_ref[rows, :], g)
+                sh, sl = sh_ref[g], sl_ref[g]                 # [j, U a]
+                acc_ref[rows, :] += (_dot(ph, sh, nt[0]) + _dot(pw, sh, nt[0])
+                                     + _dot(ph, sl, nt[0]))
+                return carry
+
+            jax.lax.fori_loop(0, nT * nG, read, 0)
+            zp = _pieces(zq_ref[...], 3)
+            for i in range(nT):
+                rows = slice(i * GT, (i + 1) * GT)
+                # a tile's rows are its heads' copies of the same T rows
+                et = jnp.concatenate([e_ref[i * T:(i + 1) * T, :]] * G,
+                                     axis=0)
+                u = _dot_split(_halves(qt_ref[rows, :]), zp,
+                               ((1,), (0,)), 3)
+                y_ref[rows, :] += et * acc_ref[rows, :]
+                den_ref[rows, :] += et * jnp.sum(
+                    qf_ref[rows, :] * u, axis=1, keepdims=True)
+
+        y_ref[...] = y_ref[...] / (den_ref[...] + eps)
+
+        # the state advanced ONCE over all the piece's rows
+        kf = kb_ref[...].astype(f32)
+        vp = _halves(vdT_ref[...])
+
+        def advance(held):
+            def group(g, carry):
+                kp = feats(kf, g)
+                upd = _dot_split(vp, kp, ((1,), (0,)), 2)     # [d + 8, U a]
+                for i in range(U):
+                    r = g * U + i
+                    at = pl.ds(pl.multiple_of(r * d, d), d)
+                    new = coef(r) * upd[:, i * d:(i + 1) * d]
+                    if held:
+                        so_ref[at, :] = gate * s_ref[at, :] + new[:d]
+                        zs_ref[r, 1:2, :] = (gate * zs_ref[r, 0:1, :]
+                                             + new[d:d + 1])
+                    else:
+                        so_ref[at, :] = new[:d]
+                        zs_ref[r, 1:2, :] = new[d:d + 1]
+                return carry
+
+            if held:
+                for r in range(R):
+                    zs_ref[r, 0:1, :] = z_ref[r:r + 1, :]
+            jax.lax.fori_loop(0, nG, group, 0)
+
+        pl.when(cont)(functools.partial(advance, True))
+        # a piece that starts a prompt never reads what the slot held
+        pl.when(jnp.logical_not(cont))(functools.partial(advance, False))
         for r in range(R):
             zo_ref[r:r + 1, :] = zs_ref[r, 1:2, :]
-        den = den + jnp.sum(den_in, axis=1, keepdims=True)
-        y_ref[...] = num / (den + eps)
 
-    head = lambda rows, lanes: pl.BlockSpec(                  # noqa: E731
-        (None, rows, lanes), lambda j, slot: (j, 0, 0))
-    state = lambda rows: pl.BlockSpec(                        # noqa: E731
-        (None, None, rows, d), lambda j, slot: (slot[0], j, 0, 0))
+    rows = lambda n, lanes: pl.BlockSpec(                     # noqa: E731
+        (None, n, lanes), lambda j, head: (j, 0, 0))
+    state = lambda n: pl.BlockSpec(                           # noqa: E731
+        (None, None, n, d), lambda j, head: (head[0], j, 0, 0))
     y, Sn, zn = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(hk,),
-            in_specs=[head(G * C, d), head(G * C, d), head(C, d),
-                      head(C, d), head(C, C), head(d + 8, C), head(8, d),
+            in_specs=[rows(G * C, d), rows(C, d), rows(C, d), rows(C, C),
+                      rows(C, 1), rows(d + 8, C), rows(8, d), rows(d, d),
                       state(R * d), state(R)],
-            out_specs=[head(G * C, d), state(R * d), state(R)],
-            scratch_shapes=[pltpu.VMEM((R, 8, d), f32)]),
+            out_specs=[rows(G * C, d), state(R * d), state(R)],
+            # the rows in float32, their read of the state and their
+            # normaliser; the state's two pieces; z's rows before | after
+            scratch_shapes=[pltpu.VMEM((G * C, d), f32),
+                            pltpu.VMEM((G * C, d), f32),
+                            pltpu.VMEM((G * C, 1), f32),
+                            pltpu.VMEM((nG, d, U * d), bf16),
+                            pltpu.VMEM((nG, d, U * d), bf16),
+                            pltpu.VMEM((R, 8, d), f32)]),
         out_shape=[jax.ShapeDtypeStruct((hk, G * C, d), f32),
                    jax.ShapeDtypeStruct(S.shape, f32),
                    jax.ShapeDtypeStruct(z4.shape, f32)],
-        input_output_aliases={8: 1, 9: 2},
+        input_output_aliases={9: 1, 10: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_CHUNK_VMEM_BYTES),
-        interpret=_interpret(interpret),
+        interpret=interpret,
         name="retention_chunk",
-    )(slot, qb, qs, kb, vb, dec, vdT, aux, S, z4)
-    y = jnp.moveaxis(y.reshape(hk, G, C, d), 2, 0).reshape(C, hk * G, d)
+    )(head, qt, kb, vb, dec, e, vdT, gc, zq, S, z4)
+    y = jnp.transpose(y.reshape(hk, nT, G, T, d), (1, 3, 0, 2, 4)).reshape(
+        C, hk * G, d)
     return y, Sn, zn.reshape(z.shape)
 
 

@@ -269,9 +269,10 @@ class _Cache:
                 "no in-place form: kernels.retention.chunk advances ONE "
                 "slot's state over a piece")
         else:
-            # a window wider than one kernel call walks it in consecutive
-            # calls, the state threaded through in place: only the first
-            # can start a prompt, and each honours its own rows' limit
+            # a window is ONE kernel call (the kernel walks its rows in
+            # tiles); one wider than any rung is consecutive calls, the
+            # state threaded through in place: only the first can start
+            # a prompt, and each honours its own rows' limit
             fresh, ys, at = self.pos[0, 0] == 0, [], 0
             for rows in _retention.chunk_rows(q.shape[1]):
                 cut = slice(at, at + rows)

@@ -1522,20 +1522,26 @@ class ServingEngine:
             "serving.prefill_real_tokens",
             help="prompt-suffix tokens prefill computed, padding left "
                  "out").inc(suffix)
-        for w, *_ in pieces:
+        for w, _, at, _ in pieces:
             self._reg.counter(
                 "serving.prefill_pieces", width=w,
                 help="prefill window calls dispatched, by width (an "
                      "admission is one or more pieces)").inc()
             if self.arch.retention_layers:
-                for rows in _retention.chunk_rows(w):
+                for i, rows in enumerate(_retention.chunk_rows(w)):
                     self._reg.counter(
                         "serving.retention_piece_rows", width=rows,
                         help="rows of the prefill pieces' retention "
-                             "calls, by the CALL's width (a piece wider "
-                             "than kernels.retention.CHUNK_ROWS is "
-                             "several; padding included: a call "
-                             "computes its width), a layer").inc(rows)
+                             "calls, by the CALL's width (a piece is "
+                             "ONE up to kernels.retention.CALL_ROWS; "
+                             "padding included: a call computes its "
+                             "width), a layer").inc(rows)
+                    self._reg.counter(
+                        "serving.retention_calls",
+                        fresh=str(int(at == 0 and i == 0)),
+                        help="the prefill pieces' retention calls a "
+                             "layer; fresh=1 started a prompt and never "
+                             "read the slot's state").inc()
         self._reg.histogram("serving.ttft_seconds").observe(
             now - req.submit_t)
         with self._qlock:

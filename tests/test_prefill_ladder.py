@@ -260,16 +260,19 @@ def test_a_wide_piece_in_bfloat16_stays_within_the_familys_margin(
 # -- power retention inside a wide piece ---------------------------------------
 
 @pytest.mark.parametrize("real", [512, 200, 128, 3])
-def test_retain_over_a_wide_piece_is_the_consecutive_chunk_calls_to_the_bit(
-        real):
+def test_retain_over_a_wide_piece_is_one_chunk_call_to_the_bit(real):
     """``_Cache.retain`` hands a 512-row window to ``kernels.retention.
-    chunk`` in four calls of ``CHUNK_ROWS``, the state threaded through;
-    ``real`` rows are within the limit (200: it falls inside the second
-    call, and the third and fourth advance nothing)."""
+    chunk`` in ONE call (the kernel walks the rows in tiles of
+    ``CHUNK_ROWS`` itself) and equals that call to the bit; four threaded
+    128-row calls, the form before PR 44, give the same within float32
+    rounding; ``real`` rows are within the limit (200: it ends inside
+    the second row tile) and the rows past it advance nothing."""
     W, h, kv, d, slots = 512, 4, 2, 16, 3
-    assert _retention.chunk_rows(W) == [128] * 4
-    assert _retention.chunk_rows(400) == [128, 128, 128, 16]
+    assert _retention.CALL_ROWS == _bd.PREFILL_PIECE == W
+    assert _retention.chunk_rows(W) == [512]
+    assert _retention.chunk_rows(400) == [400]
     assert _retention.chunk_rows(32) == [32]
+    assert _retention.chunk_rows(1200) == [512, 512, 176]
     rng = np.random.default_rng(real)
     f = lambda *s: jnp.asarray(rng.normal(0, 0.5, s), jnp.float32)  # noqa: E731
     q, k, v = f(1, W, h, d), f(1, W, kv, d), f(1, W, kv, d)
@@ -286,11 +289,11 @@ def test_retain_over_a_wide_piece_is_the_consecutive_chunk_calls_to_the_bit(
         y, planes = cache.retain(((), (), ((S, z),)), 0, q, k, v, lg)
         return (y[0],) + planes[2][0]
 
-    @functools.partial(jax.jit, static_argnames="calls")
-    def by_hand(S, z, start, calls=4):
+    @functools.partial(jax.jit, static_argnames=("calls", "rows"))
+    def by_hand(S, z, start, calls=1, rows=W):
         ys, fresh = [], start == 0
         for i in range(calls):
-            cut = slice(128 * i, 128 * (i + 1))
+            cut = slice(rows * i, rows * (i + 1))
             y, S, z = _retention.chunk(
                 S, z, jnp.int32(1), fresh, q[0, cut], k[0, cut], v[0, cut],
                 lg[0, cut], valid[cut])
@@ -298,25 +301,34 @@ def test_retain_over_a_wide_piece_is_the_consecutive_chunk_calls_to_the_bit(
             fresh = False
         return jnp.concatenate(ys), S, z
 
+    close = functools.partial(np.testing.assert_allclose, rtol=1e-6,
+                              atol=1e-6)
     for start in (0, 640):
         y, S, z = retain(S0, z0, jnp.int32(start))
         yh, Sh, zh = by_hand(S0, z0, jnp.int32(start))
         assert np.array_equal(np.asarray(S), np.asarray(Sh))
         assert np.array_equal(np.asarray(z), np.asarray(zh))
         assert np.array_equal(np.asarray(y)[:real], np.asarray(yh)[:real])
+        # four threaded 128-row calls, as a window went before
+        y4, S4, z4 = by_hand(S0, z0, jnp.int32(start), calls=4, rows=128)
+        close(np.asarray(S), np.asarray(S4))
+        close(np.asarray(z), np.asarray(z4))
+        # a row's output is a quotient of two sums taken in another order
+        np.testing.assert_allclose(np.asarray(y)[:real],
+                                   np.asarray(y4)[:real], rtol=1e-5,
+                                   atol=1e-5)
         # the other slots' state is as it was
         for s in (0, 2):
             assert np.array_equal(np.asarray(S[s]), np.asarray(S0[s]))
+            assert np.array_equal(np.asarray(z[s]), np.asarray(z0[s]))
         if start:
             assert not np.array_equal(np.asarray(S[1]), np.asarray(
                 retain(S0, z0, jnp.int32(0))[1][1]))
         if real <= 256:
-            # the calls past the limit advance nothing
-            _, S2, z2 = by_hand(S0, z0, jnp.int32(start), calls=2)
-            np.testing.assert_allclose(np.asarray(S), np.asarray(S2),
-                                       rtol=1e-6, atol=1e-6)
-            np.testing.assert_allclose(np.asarray(z), np.asarray(z2),
-                                       rtol=1e-6, atol=1e-6)
+            # the rows past the limit advance nothing
+            _, S2, z2 = by_hand(S0, z0, jnp.int32(start), calls=2, rows=128)
+            close(np.asarray(S), np.asarray(S2))
+            close(np.asarray(z), np.asarray(z2))
 
 
 # -- a fork and a hit under a wide piece ---------------------------------------

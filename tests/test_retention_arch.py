@@ -126,16 +126,39 @@ def test_no_plane_no_pool_no_table(served):
     assert stats["serving.state_bytes"] == 3 * per_slot
     assert not any(k.startswith("serving.paged_") for k in stats)
     assert stats["serving.retention_slot_steps"] > 0
-    # by the retention CALL's width: 300 rows are one piece of 400 (the
-    # widest rung, capped at max_len: three calls of 128 and one of 16),
-    # 140 one of 256 (two calls); 128 and 61 one call of 128; 17 one of
-    # 32; 5 one of 8
-    assert stats["serving.retention_piece_rows{width=128}"] == 128 * 7
-    assert stats["serving.retention_piece_rows{width=16}"] == 16
+    # by the retention CALL's width, which is the piece's: 300 rows are
+    # one piece of 400 (the widest rung, capped at max_len), 140 one of
+    # 256; 128 and 61 one of 128 each; 17 one of 32; 5 one of 8
+    assert stats["serving.retention_piece_rows{width=400}"] == 400
+    assert stats["serving.retention_piece_rows{width=256}"] == 256
+    assert stats["serving.retention_piece_rows{width=128}"] == 128 * 2
     assert stats["serving.retention_piece_rows{width=32}"] == 32
     assert stats["serving.retention_piece_rows{width=8}"] == 8
+    # every one of them started its prompt: no call read a state
+    assert stats["serving.retention_calls{fresh=1}"] == 6
+    assert "serving.retention_calls{fresh=0}" not in stats
     assert stats["serving.prefill_pieces{width=400}"] == 1
     assert stats["serving.prefill_pieces{width=256}"] == 1
+
+
+def test_retention_calls_count_the_pieces_that_continue_a_prompt(monkeypatch):
+    """A prompt of several pieces: ONE retention call a piece a layer,
+    the first ``fresh`` (it never reads the slot), the rest not."""
+    from paddle_tpu.serving import batched_decode as _bd
+
+    monkeypatch.setattr(_bd, "PREFILL_PIECE", 32)
+    params, reg = make(0), MetricsRegistry()
+    eng = engine(params, reg, compute_dtype="float32")
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, V, n, dtype=np.int32) for n in (70, 20)]
+    outs = eng.generate_many(prompts, max_new_tokens=4)
+    assert max(gaps(params, prompts, outs)) <= 1e-4
+    stats = eng.stats()
+    # 70 tokens: pieces of 32, 32 and 8 rows; 20: one of 32
+    assert stats["serving.retention_calls{fresh=1}"] == 2
+    assert stats["serving.retention_calls{fresh=0}"] == 2
+    assert stats["serving.retention_piece_rows{width=32}"] == 32 * 3
+    assert stats["serving.retention_piece_rows{width=8}"] == 8
 
 
 def test_state_spec_is_two_float32_arrays_a_layer():

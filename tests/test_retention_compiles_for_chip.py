@@ -1,11 +1,12 @@
 """Power retention's two Mosaic kernels compiled for a TPU v5e that is
 described and not attached, at the geometry `brumby14b.doc_continue`
 runs them (16 slots, 40 query heads over 8 K/V heads of 128, pieces of
-8 to 128 rows): what interpret mode cannot show (a layout, a rotation or
+8 to 512 rows, each ONE call): what interpret mode cannot show (a layout, a rotation or
 a transpose Mosaic refuses).  Nothing runs: a compile that passes is no
 chip run.  The state enters in place: no temporary the size of a slot's
 state."""
 
+import math
 import os
 
 import pytest
@@ -55,7 +56,7 @@ def test_retention_step_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
-@pytest.mark.parametrize("rows", [8, 32, 128])
+@pytest.mark.parametrize("rows", [8, 32, 128, 256, 512])
 def test_retention_chunk_compiles_for_v5e(rows, one_chip):
     from paddle_tpu.kernels.retention import retention_chunk_pallas
 
@@ -73,14 +74,14 @@ def test_retention_chunk_compiles_for_v5e(rows, one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
-def test_a_wide_piece_is_consecutive_chunk_calls_in_place_for_v5e(one_chip):
-    """A 512-row prefill window through ``_Cache.retain``: four calls of
-    the 128-row kernel (its VMEM budget and its name are the narrow
-    piece's), the state threaded through them with no copy of it."""
+@pytest.mark.parametrize("rows", [256, 512])
+def test_a_wide_piece_is_one_chunk_call_in_place_for_v5e(rows, one_chip):
+    """A wide prefill window through ``_Cache.retain``: ONE Mosaic call
+    a layer, named ``retention_chunk`` (the kernel walks the rows in
+    tiles of ``CHUNK_ROWS`` itself), the slots' state aliased in place
+    with no copy of it."""
     from paddle_tpu.kernels import retention as rt
     from paddle_tpu.serving.batched_decode import _Cache
-
-    rows = 512
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -105,9 +106,13 @@ def test_a_wide_piece_is_consecutive_chunk_calls_in_place_for_v5e(one_chip):
     finally:
         rt.chunk = real
     compiled = lowered.compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
-        == len(rt.chunk_rows(rows)) == 4
-    # 16 slots' state of a layer is 545 MB and one slot's 34: the four
-    # calls hand it on in place (the rows' own temporaries: 512 x 40 x
-    # 128 float32 outputs, the decays)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == len(rt.chunk_rows(rows)) == 1
+    assert "retention_chunk" in text
+    # 16 slots' state of a layer is 545 MB and one slot's 34: the call
+    # writes it where it read it (the rows' own temporaries: rows x 40 x
+    # 128 float32 outputs, the decays [8, rows, rows])
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        4 * math.prod(a.shape) for a in _state(arg))

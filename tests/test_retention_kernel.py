@@ -2,8 +2,10 @@
 small sizes: the features ``phi`` against the squared dot product, the
 ``xla_ref`` step and chunk against the QUADRATIC form across piece
 boundaries, uneven piece widths and padded rows, the Mosaic kernels in
-interpret mode against ``xla_ref``, and a dead slot's state bit-equal
-after a step.  2 K/V heads of 3 query heads each throughout."""
+interpret mode against ``xla_ref``, a dead slot's state bit-equal
+after a step, and ONE chunk call over 8 to 512 rows (its walk in row
+tiles, a limit inside a tile, a piece that starts a prompt and never
+reads the slot).  2 K/V heads of 3 query heads each throughout."""
 
 import numpy as np
 import pytest
@@ -171,6 +173,107 @@ def test_a_fresh_piece_starts_from_zeros_whatever_the_slot_held():
         b = chunk(*clean, jnp.int32(1), False, q, k, v, lg, valid)
         assert np.array_equal(a[0], b[0])
         assert np.array_equal(np.asarray(a[1])[1], np.asarray(b[1])[1])
+
+
+def _mosaic_chunk(*a):
+    return rt.retention_chunk_pallas(*a, interpret=True)
+
+
+def _one_call(C, real, fresh, d=8, before=24, slots=3, slot=2):
+    """A sequence of ``before + real`` rows: the first ``before`` through
+    ``xla_ref`` (none for a ``fresh`` call), then ONE call of ``C`` rows
+    of which ``real`` are within the limit, through the Mosaic kernel in
+    interpret mode and through ``xla_ref`` -> (both results, the
+    quadratic form's rows of the call, the state before it)."""
+    before = 0 if fresh else before
+    rng = np.random.default_rng(C + real)
+    q, k, v, lg = rows(rng, before + C, d)
+    S = jnp.asarray(rng.normal(size=(slots, HK, rt.stored_rows(d), d)),
+                    jnp.float32)
+    z = jnp.asarray(rng.normal(size=(slots, HK, rt.stored_rows(d))),
+                    jnp.float32)
+    if before:
+        _, S, z = rt.retention_chunk_ref(
+            S, z, jnp.int32(slot), True,
+            *(jnp.asarray(x[:before]) for x in (q, k, v, lg)),
+            jnp.arange(before) < before)
+    args = (jnp.int32(slot), fresh) + tuple(
+        jnp.asarray(x[before:]) for x in (q, k, v, lg)) + (
+        jnp.arange(C) < real,)
+    want = quadratic(*(x[:before + real] for x in (q, k, v, lg)))[before:]
+    return (_mosaic_chunk(S, z, *args), rt.retention_chunk_ref(S, z, *args),
+            want, (np.asarray(S), np.asarray(z)))
+
+
+@pytest.mark.parametrize("fresh", [False, True])
+@pytest.mark.parametrize("C,real", [(8, 8), (32, 32), (128, 128), (256, 256),
+                                    (512, 512), (512, 300)])
+def test_one_chunk_call_walks_its_rows_in_tiles(C, real, fresh):
+    """ONE call of up to ``CALL_ROWS`` rows (four row tiles at 512; a
+    limit of 300 ends inside the third) against ``xla_ref`` and the
+    quadratic form, continuing a prompt and starting one."""
+    assert rt.chunk_rows(C) == [C] and rt.CHUNK_ROWS == 128
+    (y, S, z), (yr, Sr, zr), want, (S0, z0) = _one_call(C, real, fresh)
+    y, yr = np.asarray(y)[:real], np.asarray(yr)[:real]
+    assert np.abs(y - want).max() <= 1e-4 * np.abs(want).max()
+    assert np.abs(y - yr).max() <= 1e-4 * np.abs(yr).max()
+    assert np.abs(S - Sr).max() <= 1e-4 * np.abs(Sr).max()
+    assert np.abs(z - zr).max() <= 1e-4 * np.abs(zr).max()
+    # the other slots hold what they held, to the bit
+    for s in (0, 1):
+        assert np.array_equal(np.asarray(S)[s], S0[s])
+        assert np.array_equal(np.asarray(z)[s], z0[s])
+
+
+@pytest.mark.parametrize("C,real", [(8, 8), (128, 128), (512, 300)])
+def test_a_fresh_call_leaves_the_same_state_whatever_the_slot_held(C, real):
+    """A call that starts a prompt skips the read of the state: what the
+    slot held (here: infinities and NaNs too) reaches neither the rows
+    nor the state it leaves."""
+    d = 8
+    rng = np.random.default_rng(C)
+    q, k, v, lg = (jnp.asarray(x) for x in rows(rng, C, d))
+    valid = jnp.arange(C) < real
+    shape = (2, HK, rt.stored_rows(d), d)
+    dirty = np.full(shape, 5.0, np.float32)
+    dirty[:, :, ::3] = np.inf
+    dirty[:, :, 1::3] = np.nan
+    (y, S, z), (yc, Sc, zc) = (
+        _mosaic_chunk(jnp.asarray(held), jnp.asarray(held[..., 0]),
+                      jnp.int32(1), True, q, k, v, lg, valid)
+        for held in (dirty, np.zeros(shape, np.float32)))
+    assert np.array_equal(np.asarray(y)[:real], np.asarray(yc)[:real])
+    assert np.array_equal(np.asarray(S)[1], np.asarray(Sc)[1])
+    assert np.array_equal(np.asarray(z)[1], np.asarray(zc)[1])
+    assert np.isfinite(np.asarray(S)[1]).all()
+    # and the slot beside it holds its infinities still
+    assert np.array_equal(np.asarray(S)[0], dirty[0], equal_nan=True)
+
+
+@pytest.mark.parametrize("fresh", [False, True])
+def test_one_512_row_call_equals_four_threaded_128_row_calls(fresh):
+    """What a 512-row window was before PR 44: four consecutive calls of
+    one row tile each, the state threaded through."""
+    d, C = 8, 512
+    rng = np.random.default_rng(12)
+    q, k, v, lg = (jnp.asarray(x) for x in rows(rng, C, d))
+    S = jnp.asarray(rng.normal(size=(2, HK, rt.stored_rows(d), d)),
+                    jnp.float32)
+    z = jnp.asarray(rng.normal(size=(2, HK, rt.stored_rows(d))) + 4.0,
+                    jnp.float32)
+    valid = jnp.arange(C) < 300
+    y, S1, z1 = _mosaic_chunk(S, z, jnp.int32(0), fresh, q, k, v, lg, valid)
+    ys, S4, z4 = [], S, z
+    for i in range(4):
+        cut = slice(128 * i, 128 * (i + 1))
+        yi, S4, z4 = _mosaic_chunk(S4, z4, jnp.int32(0), fresh and i == 0,
+                                   q[cut], k[cut], v[cut], lg[cut],
+                                   valid[cut])
+        ys.append(yi)
+    y4 = np.concatenate(ys)[:300]
+    assert np.abs(np.asarray(y)[:300] - y4).max() <= 1e-4 * np.abs(y4).max()
+    assert np.abs(S1 - S4).max() <= 1e-4 * np.abs(S4).max()
+    assert np.abs(z1 - z4).max() <= 1e-4 * np.abs(z4).max()
 
 
 def test_retention_resolves_to_the_oracle_off_the_tpu():
