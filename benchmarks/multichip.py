@@ -9,9 +9,8 @@ per device under ZeRO-1 vs replicated, and weak-scaling efficiency.
 
 Emits exactly ONE parseable JSON line on stdout (everything else goes to
 stderr; failures land as ``"error"`` / ``"gate_<name>": "FAILED: ..."``
-fields and the row still prints — the bench.py error-capture
-discipline).  ``--smoke`` additionally GATES the structural facts that
-are deterministic on the virtual CPU mesh:
+fields and the row still prints).  ``--smoke`` additionally GATES the
+structural facts that are deterministic on the virtual CPU mesh:
 
 * ``gate_zero_sharding``   — accumulator arrays really are dp-sharded
   (``optimizer_state_report`` + the live Adam moment's NamedSharding);
@@ -31,8 +30,7 @@ are deterministic on the virtual CPU mesh:
   figure (and <= replicated / (fsdp_degree/2)) with a non-empty
   boundary reduce class — the true-ZeRO-3 reduce-scatter win
   (docs/parallel.md rule 4).  ``boundary_comm_bytes`` /
-  ``grad_bytes_per_device`` ship in the row for bench-history
-  trajectory tracking.
+  ``grad_bytes_per_device`` ship in the row.
 
 Step times on the virtual CPU mesh share host cores and are indicative
 only; the gates are the contract.
@@ -61,18 +59,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def log(*a):
     print(*a, file=sys.stderr, flush=True)
-
-
-def _stamp(row):
-    """schema_version / run_id / git_sha row identity for
-    ``python -m paddle_tpu --bench-history`` — the stamp contract lives
-    in bench_history.stamp_row; the import guard keeps a broken
-    observability package from killing the row."""
-    try:
-        from paddle_tpu.observability.bench_history import stamp_row
-    except Exception:  # noqa: BLE001 — the stamp must never kill the row
-        return row
-    return stamp_row(row)
 
 
 def _devices_ready(n):
@@ -340,10 +326,9 @@ def run(row, devices=8, smoke=True, steps=None, warmup=None, accum=4,
         # batch, so perfect scaling keeps the step time flat
         row["scaling_efficiency"] = round(t1 / tn, 3) if tn else None
         row["dp1_cost"] = f1["cost"]
-        # param_bytes_* are the FSDP gate's facts: bench_history tracks
-        # param_bytes_per_device as the sharded figure, so the dp-only
-        # run's (fully replicated) values must never ship under the
-        # same metric name
+        # param_bytes_* are the FSDP gate's facts: param_bytes_per_device
+        # is the sharded figure, so the dp-only run's (fully replicated)
+        # values must never ship under the same metric name
         row.update({k: v for k, v in fn_.items()
                     if k not in ("cost", "param_bytes_per_device",
                                  "param_bytes_replicated",
@@ -457,10 +442,10 @@ def run(row, devices=8, smoke=True, steps=None, warmup=None, accum=4,
 
 def run_smoke(devices=8):
     """In-process smoke row (used by __graft_entry__.dryrun_multichip so
-    the MULTICHIP artifact carries scaling numbers, not just OK).  The
-    caller guarantees >= ``devices`` CPU devices.  Always returns a row;
-    gate failures are recorded in it."""
-    row = _stamp({"metric": "multichip_scaling", "mode": "smoke"})
+    its row carries scaling numbers, not just OK).  The caller
+    guarantees >= ``devices`` CPU devices.  Always returns a row; gate
+    failures are recorded in it."""
+    row = {"metric": "multichip_scaling", "mode": "smoke"}
     try:
         run(row, devices=devices, smoke=True)
     except Exception as e:  # noqa: BLE001 — the row must still carry why
@@ -485,8 +470,8 @@ def main(argv=None):
                                 else sys.argv[1:]))
         _provision_env(args.devices)
 
-    row = _stamp({"metric": "multichip_scaling",
-                  "mode": "smoke" if args.smoke else "full"})
+    row = {"metric": "multichip_scaling",
+           "mode": "smoke" if args.smoke else "full"}
     models = [m for m in args.models.split(",") if m]
     if args.smoke:
         models = ["transformer"]

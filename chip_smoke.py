@@ -34,7 +34,7 @@ import traceback
 import numpy as np
 
 SEQ, N_WARM, N_TIMED = 4096, 2, 8
-# serving geometry of benchmarks/serving.py's full configuration
+# serving geometry: 32 slots of 512 positions, chunks of 16 steps
 MAX_LEN, SLOTS, CHUNK, MIN_BUCKET, BLOCK_TOKENS = 512, 32, 16, 16, 32
 HEAD_LEN, MAX_NEW, N_REQUESTS = 64, 32, 8
 # token ids are drawn from the first DATA_VOCAB entries so that ten
@@ -176,7 +176,7 @@ def kernels_phase(dims):
 
 
 def _build_gpt(pt, dims, mesh_recipe=False):
-    """The flagship GPT exactly as bench.py builds it; with
+    """The flagship GPT (``tune.search.flagship_dims``); with
     ``mesh_recipe`` the PR-10 FSDP recipe in its order."""
     from paddle_tpu.models import transformer
 

@@ -20,7 +20,7 @@ import numpy as np
 def build_program():
     """The example's training program (with the data-parallel batch
     annotations but no mesh/devices), built without running — the entry
-    point ``python -m paddle_tpu --lint-selftest`` lints.  Returns
+    point ``python -m paddle_tpu --lint`` loads.  Returns
     (main_program, startup_program, fetch_list)."""
     import paddle_tpu as pt
     from paddle_tpu import parallel

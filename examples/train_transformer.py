@@ -18,7 +18,7 @@ from paddle_tpu.models import transformer
 
 def build_program(vocab=64, seq=64):
     """The example's training program, built without running — the
-    entry point ``python -m paddle_tpu --lint-selftest`` lints.
+    entry point ``python -m paddle_tpu --lint`` loads.
     Returns (main_program, startup_program, fetch_list)."""
     main_prog, startup = pt.Program(), pt.Program()
     with pt.program_guard(main_prog, startup):

@@ -13,10 +13,10 @@ to register a new check.
         print(f)                  # [error] hlo.hbm-preflight @ ...
     report.raise_for_errors()     # or lint(..., strict=True)
 
-CLI: ``python -m paddle_tpu --lint <config.py>`` and
-``python -m paddle_tpu --lint-selftest`` /
-``python -m paddle_tpu --sharding-selftest`` (wired into
-tools/tier1.sh).  The Executor also folds the program- and hlo-level
+CLI: ``python -m paddle_tpu --lint <config.py>``; every check has a
+planted defect and a clean program in ``tests/test_analysis.py`` and
+``tests/test_comm_plan.py``.
+The Executor also folds the program- and hlo-level
 findings of every compile into ``exe.last_step_cost``
 (``lint_findings`` / ``lint_errors`` / ``lint_checks``; kill switch
 ``PADDLE_TPU_LINT=0``) and the trainer JSONL.
@@ -77,12 +77,12 @@ def audit_program(program, feed, fetch_list, scope=None, layer_count=None,
 
     ``absent_shapes``: iterable of shape tuples that must NOT appear in
     the optimized HLO text (e.g. ``(num_layers, t, d_model)`` — the
-    BENCH_r05 failure shape); hit counts land in
+    temp that overflowed round 5's flagship); hit counts land in
     ``report["absent_shape_hits"]``.
 
     The scope must already hold the program's parameters (run the
-    startup program into it first).  CPU-safe: used by the tier-1
-    regression test and ``python -m paddle_tpu --memory-selftest``.
+    startup program into it first).  CPU-safe: used by
+    ``tests/test_memory_engine.py``.
     """
     from .hlo_tools import shape_pattern
     from .jaxpr_tools import jaxpr_report

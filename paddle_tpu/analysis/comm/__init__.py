@@ -13,8 +13,8 @@ constraint-placement invariants of docs/parallel.md are machine-checked
 instead of documented prose.
 
 See docs/analysis.md ("Communication contracts") for the check catalog
-and how to write a contract; ``python -m paddle_tpu
---sharding-selftest`` is the CI gate.
+and how to write a contract; ``tests/test_comm_plan.py`` plants
+each mis-spelling and sweeps the clean ones.
 """
 
 from .plan import (

@@ -22,7 +22,7 @@ builds the artifacts lazily (a program-level-only lint never imports
 jax), runs every enabled check, and returns an ``AnalysisReport``;
 ``strict=True`` raises ``AnalysisError`` when any error-severity finding
 survives.  Nothing here ever *executes* a training step — compile yes,
-run no (the point is catching the BENCH_r05 class of failure before any
+run no (the point is catching an allocator failure before any
 step allocates).
 
 Registering a new check::
@@ -525,8 +525,8 @@ def preflight_hbm(high_water_bytes, budget_bytes, context=""):
     """The static HBM preflight as a pure helper: compare a compiled
     step's ``hbm_high_water_bytes`` against a device budget and return
     the error Finding list ([] when it fits or either figure is
-    unknown).  ``bench.py``'s flagship preflight consumes this — the
-    BENCH_r05 OOM class is flagged before any step executes."""
+    unknown).  The tuner's search consumes this (``tune/search.py``):
+    an allocator failure is flagged before any step executes."""
     if not high_water_bytes or not budget_bytes:
         return []
     if high_water_bytes <= budget_bytes:

@@ -101,7 +101,7 @@ def donation_alias(ctx):
 def hbm_preflight(ctx):
     """The static HBM preflight: the compiled step's own
     ``hbm_high_water_bytes`` against the device's allocator limit (or an
-    explicit ``hbm_budget``) — the BENCH_r05 class of OOM flagged before
+    explicit ``hbm_budget``) — an allocator failure flagged before
     any step executes.  Skipped when neither figure is known (CPU
     reports no bytes_limit)."""
     budget = ctx.hbm_budget

@@ -248,6 +248,6 @@ def compiled_memory_stats(compiled):
 
 def shape_pattern(shape):
     """Regex matching a dims list like ``[6,16384,768]`` in HLO text —
-    the absent-shape probe (e.g. the BENCH_r05 failure shape)."""
+    the absent-shape probe (e.g. round 5's flagship's failing temp)."""
     return re.compile(
         r"\[" + ",".join(str(int(s)) for s in shape) + r"\]")

@@ -8,7 +8,7 @@ from .jaxpr_tools import BLOCK_INPUT_TAG, KERNEL_RESIDUAL_TAG
 # up to one layer's worth of kernel calls (fwd + dq + dkv = 3) may
 # legitimately sit outside the layer scan when a policy's segmentation
 # leaves the first layer out of the uniform group; the failure mode is
-# O(L) unrolled calls (the BENCH_r05 shape), not O(1)
+# O(L) unrolled calls (what overflowed round 5's flagship), not O(1)
 PALLAS_OUTSIDE_SCAN_TOLERANCE = 3
 
 
@@ -18,7 +18,7 @@ def _has_remat(program):
 
 @register_check("jaxpr.scan-locality", level="jaxpr")
 def scan_locality(ctx):
-    """The BENCH_r05 invariant (migrated from
+    """The scan-locality invariant (migrated from
     ``memaudit.jaxpr_report``): under a ``memory_optimize`` policy every
     flash ``pallas_call`` must sit INSIDE a ``lax.scan`` body, and no
     pallas operand/result may carry a leading layer-count axis — the
@@ -35,7 +35,7 @@ def scan_locality(ctx):
             f"pallas operand/result carries a leading layer-count axis "
             f"{rep['layer_stacked_pallas'][:2]} — per-layer kernel "
             f"calls were stacked/hoisted out of the layer scan (the "
-            f"BENCH_r05 OOM shape)",
+            f"shape that runs a capacity config out of memory)",
             hint="the scan-remat engine must own the layer loop: check "
                  "exe.last_remat_plan for fallbacks and run with "
                  "PADDLE_TPU_SCAN_REMAT=strict to fail loudly",
@@ -118,8 +118,8 @@ def kernel_residual(ctx):
 @register_check("jaxpr.kernel-backend", level="jaxpr")
 def kernel_backend(ctx):
     """Interpret-mode kernels in a TIMED run (docs/kernels.md): inside
-    a declared timed-run region (``kernels.timed_run()`` — bench.py
-    wraps its flagship sections; PADDLE_TPU_TIMED_RUN=1) any
+    a declared timed-run region (``kernels.timed_run()`` around work
+    whose time is reported; PADDLE_TPU_TIMED_RUN=1) any
     ``pallas_call`` with ``interpret=True`` is an error — the Pallas
     interpreter is orders of magnitude slower than both hardware and
     the pure-XLA reference, so the "measurement" is a simulation

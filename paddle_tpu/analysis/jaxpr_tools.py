@@ -154,7 +154,7 @@ def walk_report(jaxpr, layer_counts=()):
       and the mesh axes the spec mentions.
 
     ``layer_counts``: leading-dim candidates for the layer-stacked
-    probes (the BENCH_r05 shape detector accepts several hypotheses —
+    probes (the stacked-shape detector accepts several hypotheses —
     e.g. the caller's hint plus every scan-group repeat count).
     """
     if isinstance(jaxpr, _jex_core.ClosedJaxpr):

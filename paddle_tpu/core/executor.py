@@ -119,7 +119,7 @@ def _scan_strict():
     """PADDLE_TPU_SCAN_REMAT=strict: a uniform group that fails to scan
     RAISES (with the classification error) instead of silently falling
     back to the barrier spelling — the guard for capacity configs where
-    an unrolled backward means a runtime HBM OOM (BENCH_r05)."""
+    an unrolled backward means a runtime HBM OOM (round 5's flagship)."""
     return os.environ.get("PADDLE_TPU_SCAN_REMAT", "").lower() == "strict"
 
 
@@ -186,8 +186,8 @@ def _accum_carry_spec(lead):
     """The accumulation carry's pin spec: the GROUP axis shards over
     plain ``dp`` and nothing else (docs/parallel.md constraint-placement
     rule 3 — an fsdp-composed carry makes GSPMD feature-shard the saved
-    residuals into in-loop partial sums).  Module-level so the sharding
-    selftest can plant the composed-spelling defect and prove the
+    residuals into in-loop partial sums).  Module-level so that
+    tests/test_comm_plan.py can plant the composed spelling and prove the
     ``jaxpr.constraint-placement`` check catches it."""
     from jax.sharding import PartitionSpec
 
@@ -743,8 +743,8 @@ class Executor:
                 cost["lint_checks"] = sorted(
                     {f.check for f in findings})[:8]
             if any(f.check == "jaxpr.kernel-backend" for f in findings):
-                # dedicated flag for the timed-run gates (bench,
-                # kernels selftest): lint_checks caps at 8 names, so
+                # dedicated flag for timed-run regions (tests/
+                # test_kernels.py): lint_checks caps at 8 names, so
                 # membership there is not a reliable signal
                 cost["interpret_in_timed_run"] = True
         return compiled, cost
@@ -942,9 +942,9 @@ class Executor:
         a copy of the cost dict — compile_seconds, flops,
         ``hbm_high_water_bytes``, ``temp_bytes`` — so callers can
         preflight a capacity config against the chip's HBM before the
-        first real step allocates (bench.py's flagship fallback uses
-        this to turn a runtime allocator abort into a parseable
-        per-section failure)."""
+        first real step allocates (the tuner's search rejects a
+        candidate this way before it runs a step: ``tune/search.py``
+        ``PreflightRejected``)."""
         (program, scope, feed_names, fetch_names, feed_vals, state_names,
          state, feed_sig) = self._prepare(program, feed, fetch_list, scope)
         entry, cache_hit = self._run_entry(
@@ -1476,7 +1476,7 @@ class Executor:
                                 # segment through the barrier fallback —
                                 # with the REASON recorded (a silent
                                 # fallback at a capacity config is a
-                                # runtime OOM waiting to happen: BENCH_r05)
+                                # runtime OOM waiting to happen)
                                 fctx._op_counter = c0
                                 reason = " ".join(
                                     f"{type(exc).__name__}: {exc}"

@@ -1,7 +1,7 @@
 """Distributed layer — the Go master / pserver generation and the fluid
 send/recv transpiler, rebuilt for the TPU world (SURVEY §L8, §2.6).
 
-Division of labor (BASELINE north star):
+Division of labor (the north star of SURVEY.md):
 * DENSE data parallelism never leaves the pod: it is mesh sharding + ICI
   collectives (paddle_tpu.parallel) — no server in the loop.
 * The DCN-side services here cover what ICI cannot: elastic *data* dispatch

@@ -15,8 +15,8 @@ Selection: ``PADDLE_TPU_KERNEL_BACKEND=auto|pallas_tpu|xla_ref``
 (global), ``PADDLE_TPU_KERNEL_BACKEND_<OP>`` (per op class), explicit
 ``backend=`` call-site arguments, or the training tuner's persisted
 kernel choice — precedence and fallback semantics in
-:mod:`.registry`.  CI: ``python -m paddle_tpu --kernels-selftest``
-(tools/tier1.sh) and ``tests/test_kernels.py``.
+:mod:`.registry`.  Its tests: ``tests/test_kernels.py`` (oracle
+parity, precedence, the xla_ref trainer path).
 """
 
 from . import registry  # must load first: backend modules register into it

@@ -15,8 +15,8 @@ backends and every call site resolves through ONE selection path:
   (``kernels/xla_ref.py``): causal/non-causal, d_head 64/128, packed
   layouts, lse outputs, grads through the same custom-vjp algebra.
   Always available, and the universal numerics ORACLE every other
-  backend is tested against (``tests/test_kernels.py``,
-  ``python -m paddle_tpu --kernels-selftest``).
+  backend is tested against (``tests/test_kernels.py``: every
+  backend, float32 and bfloat16, forward and gradient).
 
 Selection precedence (the registry unit suite pins this):
 
@@ -149,7 +149,7 @@ def get_kernel(op_class, backend):
 
 def available_backends(op_class):
     """``[(backend, ok, reason)]`` for every registered backend of the
-    op class, in ``BACKENDS`` order — the selftest/oracle enumeration."""
+    op class, in ``BACKENDS`` order — the oracle tests' enumeration."""
     per_op = _KERNELS.get(op_class, {})
     out = []
     for b in BACKENDS:
@@ -328,8 +328,8 @@ def timed_run_active():
 
 @contextlib.contextmanager
 def timed_run():
-    """Declare a timed-run region (bench.py wraps its flagship
-    sections): compiles inside it lint interpret-mode Pallas kernels as
+    """Declare a timed-run region (around work whose time will be
+    reported): compiles inside it lint interpret-mode Pallas kernels as
     errors — an interpreted kernel in a timed row is a benchmarking
     bug, not a measurement (docs/kernels.md)."""
     old = os.environ.get(TIMED_RUN_ENV)

@@ -7,7 +7,7 @@ oracle lives with its kernel, ``kernels/paged_attention.py``).  No
 ``pallas_call`` ever appears in a program routed here
 (``PADDLE_TPU_KERNEL_BACKEND=xla_ref`` runs the full GPT trainer path —
 every ``memory_optimize`` policy — with zero Pallas calls in the
-jaxpr; the kernels selftest asserts it).
+jaxpr; ``tests/test_kernels.py`` asserts it).
 
 These are not test stubs: attention and the CE head carry the SAME
 custom-VJP algebra as the Mosaic kernels (backward recomputed from the

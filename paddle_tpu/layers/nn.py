@@ -896,8 +896,8 @@ def flash_attention_packed(q, k, v, n_head, causal=False, sm_scale=None,
     heads are lane slices in the kernel's block index maps
     (ops/pallas_attention.py).  Requires d_head % 128 == 0, d_head == 64
     with even n_head (two heads per lane slice), or n_head 1.
-    ``block_q``/``block_k`` override the kernel tile sizes (the MFU tuning
-    knob bench.py exposes as BENCH_GPT_BLOCK_Q/K)."""
+    ``block_q``/``block_k`` override the kernel tile sizes (the knob the
+    tuner searches: ``tune/space.py``)."""
     helper = LayerHelper("flash_attention_packed", name=name)
     out = helper.create_tmp_variable(q.dtype, q.shape)
     attrs = {"n_head": int(n_head), "causal": bool(causal),
@@ -1045,7 +1045,7 @@ def multi_head_attention(queries, keys, values, d_model, n_head,
         # even n_head — two heads per lane slice — or n_head == 1): the
         # packed kernel takes the projection outputs as-is and no head
         # pack/unpack transposes exist (8% of flagship device time on
-        # the 4-D path — RESULTS.md round 4/5)
+        # the 4-D path — measured in rounds 4 and 5)
         ctx = flash_attention_packed(q, k, v, n_head, causal=causal,
                                      sm_scale=1.0 / float(dh) ** 0.5,
                                      block_q=block_q, block_k=block_k,

@@ -165,7 +165,7 @@ def memory_optimize(input_program=None, num_segments=None, min_segment=2,
 
     ``policy="full"``: the round-2 all-or-nothing behavior — sqrt-N
     liveness-minimal cuts, every segment rematerialized (recomputes flash
-    too; measured −23% on the GPT flagship, RESULTS.md).
+    too; measured −23% on the GPT flagship, round 4).
 
     ``policy="offload"``: the selective saved set, with the per-layer
     scan residuals (the block inputs — the residual stream entering each

@@ -1,6 +1,6 @@
 """AlexNet (reference benchmark config: benchmark/paddle/image/alexnet.py —
 conv1..conv5 with LRN after conv1/conv2, three FC heads with dropout;
-BASELINE rows: 195 ms/batch bs64, 334 ms/batch bs128 on K40m;
+reference rows: 195 ms/batch bs64, 334 ms/batch bs128 on K40m;
 399 img/s bs64 on 2x Xeon 6148 MKL-DNN)."""
 
 from .. import layers, optimizer as opt

@@ -1,4 +1,4 @@
-"""CTR-DNN with large sparse embeddings (BASELINE config 5 — the go/pserver
+"""CTR-DNN with large sparse embeddings (reference benchmark config 5 — the go/pserver
 workload: sparse embedding lookups + dense DNN tower, trained via the
 distributed pserver path for cross-host sparse updates)."""
 

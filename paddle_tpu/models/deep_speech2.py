@@ -1,4 +1,4 @@
-"""DeepSpeech2-style CTC model (BASELINE config 4): conv feature frontend +
+"""DeepSpeech2-style CTC model (reference benchmark config 4): conv feature frontend +
 bidirectional GRU stack + row_conv lookahead + CTC loss (reference ops:
 row_conv_op for the lookahead, warpctc_op for the loss; the model shape
 follows Baidu DS2 as exercised by cuda/hl_sequence kernels)."""

@@ -1,6 +1,6 @@
 """GoogLeNet / Inception-v1 (reference benchmark config:
 benchmark/paddle/image/googlenet.py — 9 inception blocks, avg-pool head;
-BASELINE rows: 1149 ms/batch bs128 on K40m; 250.46 img/s bs64 on
+reference rows: 1149 ms/batch bs128 on K40m; 250.46 img/s bs64 on
 2x Xeon 6148 MKL-DNN). Auxiliary classifier heads (the reference's o1/o2
 branches) are included and summed into the training loss with the paper's
 0.3 weights."""

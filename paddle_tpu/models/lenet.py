@@ -1,5 +1,5 @@
 """MNIST LeNet (reference: fluid/tests/book/test_recognize_digits.py conv
-variant — BASELINE config 1)."""
+variant — reference benchmark config 1)."""
 
 from .. import layers, nets, optimizer as opt
 

@@ -1,6 +1,6 @@
 """ResNet for ImageNet (reference: benchmark/paddle/image/resnet.py —
-ResNet-50/101/152 bottleneck configs; BASELINE config 2 and the bench.py
-flagship).  NCHW; compute dtype bfloat16 by default (MXU-native) with
+ResNet-50/101/152 bottleneck configs; the reference's second benchmark
+config).  NCHW; compute dtype bfloat16 by default (MXU-native) with
 float32 BN statistics and loss."""
 
 from .. import layers, optimizer as opt

@@ -1,6 +1,6 @@
 """Seq2seq + attention NMT (reference: fluid book
 test_machine_translation.py and v2 book 08.machine_translation with
-simple_attention — BASELINE config 3).
+simple_attention — reference benchmark config 3).
 
 Training: encoder GRU over the source, attention decoder scanned over the
 target with StaticRNN (lax.scan under the hood).  Decoding: fixed-width

@@ -1,6 +1,6 @@
 """SmallNet — the CIFAR "quick" net (reference benchmark config:
 benchmark/paddle/image/smallnet_mnist_cifar.py — three 5x5/3x3 convs with
-overlapping pools, fc64 head; BASELINE row: 10.46 ms/batch bs64 K40m)."""
+overlapping pools, fc64 head; reference row: 10.46 ms/batch bs64 K40m)."""
 
 from .. import layers, optimizer as opt
 

@@ -11,7 +11,9 @@ Modules:
 
 * ``metrics``  — Counter/Gauge/Histogram + the global `MetricsRegistry`
   (Prometheus text exposition, optional HTTP endpoint);
-* ``runlog``   — `RunLog` JSONL structured event log + ``read_jsonl``;
+* ``runlog``   — `RunLog` JSONL structured event log + ``read_jsonl``,
+  and `run_stamp` (schema_version / run_id / git sha) a run's records
+  carry;
 * ``hardware`` — chip peak-FLOPs table, `mfu`, `device_memory_stats`,
   `sample_memory` HBM high-water gauges;
 * ``reporter`` — `MetricsReporter`, the Trainer event handler emitting
@@ -19,17 +21,13 @@ Modules:
 * ``trace``    — span-based tracing runtime (`Tracer`: nested spans,
   instants, per-request lanes) with Chrome-trace/Perfetto export; span
   durations fold into the ``host_timer.`` histogram namespace;
-* ``bench_history`` — BENCH_*/MULTICHIP_* artifact trajectory: failed-
-  artifact classification + best-so-far regression flagging (the
-  ``python -m paddle_tpu --bench-history`` CI gate), plus `run_stamp`
-  (schema_version / run_id / git sha) every bench row carries;
 * ``attribution`` — per-op-class performance attribution over every
   compiled step's HLO (flops/bytes/roofline ms per class,
   ``exe.last_attribution``; the learned-cost-model corpus);
-* ``corpus`` — the cross-run measurement store: trainer JSONL, bench/
-  multichip artifacts and tune-cache measured candidates read back
-  into one row shape the learned cost model (``tune/costmodel.py``)
-  fits on — malformed rows classified, never crashed;
+* ``corpus`` — the cross-run measurement store: trainer JSONL and
+  tune-cache measured candidates read back into one row shape the
+  learned cost model (``tune/costmodel.py``) fits on — malformed rows
+  classified, never crashed;
 * ``flight`` — the crash flight recorder: a bounded ring of recent
   step records dumped as one post-mortem JSON bundle on watchdog /
   NaN / OOM / driver-death / trainer-exception trips.
@@ -45,10 +43,9 @@ Quick start::
 """
 
 from . import (
-    attribution, bench_history, corpus, flight, hardware, metrics,
-    reporter, runlog, trace,
+    attribution, corpus, flight, hardware, metrics, reporter, runlog,
+    trace,
 )
-from .bench_history import run_stamp
 from .corpus import Corpus
 from .flight import FlightRecorder, get_recorder, set_recorder
 from .hardware import (
@@ -60,11 +57,11 @@ from .metrics import (
     start_metrics_server,
 )
 from .reporter import MetricsReporter
-from .runlog import RunLog, read_jsonl
+from .runlog import RunLog, read_jsonl, run_stamp
 from .trace import Tracer, get_tracer, set_tracer
 
 __all__ = [
-    "metrics", "runlog", "hardware", "reporter", "trace", "bench_history",
+    "metrics", "runlog", "hardware", "reporter", "trace",
     "attribution", "flight", "corpus", "Corpus",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
     "start_metrics_server", "RunLog", "read_jsonl", "MetricsReporter",

@@ -35,13 +35,13 @@ roofline-estimated ``est_ms`` (the ``tune/space.py`` discipline:
 ``max(flops / peak_flops, bytes / hbm_bw)`` — compute- vs memory-bound
 is which side of the max wins), and ``share`` of the estimated step
 time.  ``coverage`` is the table's flop sum over the executable's own
-``cost_analysis()`` figure — the ≥95% contract the
-``--attribution-selftest`` gate pins.
+``cost_analysis()`` figure — the ≥95% contract that
+``tests/test_attribution.py`` pins.
 
 The Executor runs this on every AOT compile (``exe.last_attribution``,
 kill switch ``PADDLE_TPU_ATTR=0``), folds a compact top-op summary into
-``last_step_cost["attribution"]`` (and thence trainer JSONL + bench
-rows), and ``reconcile()`` reports the roofline model's error against
+``last_step_cost["attribution"]`` (and thence the trainer's
+JSONL), and ``reconcile()`` reports the roofline model's error against
 the measured step wall time — every (workload key, table, measured ms)
 triple is one corpus row for the ROADMAP item-5(c) learned cost model,
 keyed exactly like the tune cache so the two datasets join.
@@ -55,7 +55,7 @@ from . import metrics as _obs
 
 __all__ = [
     "SCHEMA_VERSION", "attribution_enabled", "attribute_hlo",
-    "attribute_compiled", "summarize", "reconcile", "share_table",
+    "attribute_compiled", "summarize", "reconcile",
     "program_workload_key", "normalize_workload_key",
 ]
 
@@ -430,7 +430,7 @@ def program_workload_key(program, remat=None):
         try:
             # which kernel backend the flash op class resolved to at
             # THIS compile's trace (kernels/registry.py) — the |kb=
-            # token that keys corpus rows / bench rows / trainer JSONL
+            # token that keys corpus rows and the trainer's JSONL
             # by which kernel ran, not just the platform
             from ..kernels import selected_backends
 
@@ -511,7 +511,7 @@ def attribute_compiled(compiled, cost=None, program=None, remat=None):
         # opaque custom-calls (TPU Mosaic): fill in the tune/space.py
         # schedule estimate so the kernel class still owns its math —
         # then REDO the roofline so est_ms/bound/share (the figures
-        # bench rows carry and regression attribution diffs) reflect it
+        # the trainer's JSONL carries) reflect it
         est = _flash_estimate(program, pallas["ops"])
         if est:
             pallas["flops"] = est
@@ -533,19 +533,9 @@ def attribute_compiled(compiled, cost=None, program=None, remat=None):
     return att
 
 
-def share_table(att):
-    """``{class: share}`` of an attribution record (the compact form
-    bench artifacts carry and ``bench_history`` diffs)."""
-    if not isinstance(att, dict):
-        return {}
-    return {c: r.get("share") for c, r in (att.get("classes") or {}).items()
-            if isinstance(r, dict) and isinstance(
-                r.get("share"), (int, float))}
-
-
 def summarize(att, top_n=3):
     """The compact summary folded into ``last_step_cost["attribution"]``
-    (and thence trainer JSONL / bench rows): the top-``top_n`` classes
+    (and thence the trainer's JSONL): the top-``top_n`` classes
     by estimated time plus the totals the reconciliation needs, the
     compact per-class ``[flops, bytes, ops, est_ms]`` table a corpus
     row fits on (``observability/corpus.py``), and the cost-model

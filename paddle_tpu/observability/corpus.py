@@ -2,12 +2,11 @@
 fits on (``tune/costmodel.py``; ROADMAP item 4, the TVM lesson in
 PAPERS.md).
 
-Every subsystem already EMITS the measurements: trainer JSONL step
-records carry the attribution summary + measured wall time, bench /
-multichip artifacts carry full per-op-class tables
-(``bench.py _fold_attribution``), and the tune cache stores every
-measured candidate's median step time with its compiled flops/bytes.
-This module reads them all back into ONE append-only row shape::
+The subsystems already EMIT the measurements: trainer JSONL step
+records carry the attribution summary + measured wall time, and the
+tune cache stores every measured candidate's median step time with its
+compiled flops/bytes.  This module reads them back into ONE append-only
+row shape::
 
     {"schema_version": 1, "source": "trainer_jsonl", "workload":
      "op=step|t=128|...|kb=pallas_tpu", "platform": "cpu",
@@ -16,11 +15,10 @@ This module reads them all back into ONE append-only row shape::
      "classes": {cls: {"flops", "bytes", "ops", "est_ms"}},
      "git_sha": ..., "run_id": ..., "step": ...}
 
-Robustness is bench-history style: a truncated JSONL line, a step
-record missing its attribution fields, a non-object artifact JSON — each
-is CLASSIFIED into ``corpus.skipped`` (source, reason) and never
-crashes the ingest.  Duplicate ``(run_id, step, workload)`` rows dedup
-(re-ingesting a file is idempotent).  Workload keys are normalized via
+Robustness is classify, never crash: a truncated JSONL line or a step
+record missing its attribution fields is CLASSIFIED into
+``corpus.skipped`` (source, reason).  Duplicate ``(run_id, step,
+workload)`` rows dedup (re-ingesting a file is idempotent).  Workload keys are normalized via
 ``attribution.normalize_workload_key`` so pre-PR-13 JSONL (no ``|kb=``
 backend token) stays ingestable: old rows join the corpus under
 ``backend="unknown"`` instead of being silently dropped.
@@ -34,10 +32,6 @@ from . import attribution as _attr
 __all__ = ["SCHEMA_VERSION", "Corpus", "workload_field"]
 
 SCHEMA_VERSION = 1
-
-# the attribution prefixes bench.py folds per-model tables under
-_ARTIFACT_PREFIXES = ("gpt_", "resnet_", "")
-
 
 def workload_field(key, name):
     """One ``name=value`` token of a canonical workload-key string, or
@@ -55,8 +49,7 @@ class Corpus:
 
     ``rows``    the accepted measurement rows (append-only);
     ``skipped`` ``(source, reason)`` pairs for everything classified
-                away — the ingest analog of bench-history's failed-
-                artifact reasons.
+                away.
     """
 
     def __init__(self):
@@ -199,79 +192,6 @@ class Corpus:
             return None
         t = sum((c.get("ops") or 0) for c in classes.values())
         return t or None
-
-    # -- bench / multichip / serving artifacts -----------------------------
-    def ingest_artifact(self, path):
-        """Ingest one driver artifact (``BENCH_*.json`` /
-        ``MULTICHIP_*.json`` wrapper): every ``<prefix>attribution``
-        table in the row's extras becomes one corpus row, with the
-        measured step time reconstructed from the shipped
-        ``est_ms``/``err_pct`` pair.  Malformed artifacts classify into
-        ``skipped`` exactly like ``bench_history.classify_artifact``
-        does.  Returns the number of rows accepted."""
-        name = os.path.basename(str(path))
-        try:
-            with open(path, "r", encoding="utf-8") as fh:
-                data = json.load(fh)
-        except (OSError, ValueError) as e:
-            self._skip(name, f"unreadable artifact: {e}")
-            return 0
-        if not isinstance(data, dict):
-            self._skip(name, f"artifact is not a JSON object "
-                             f"({type(data).__name__})")
-            return 0
-        from .bench_history import _row_from_tail
-
-        parsed = data.get("parsed")
-        if not isinstance(parsed, dict):
-            parsed = _row_from_tail(data) or (
-                data if "metric" in data else None)
-        if not isinstance(parsed, dict):
-            self._skip(name, "no parseable row (parsed is null)")
-            return 0
-        extra = parsed.get("extra") or {}
-        if not isinstance(extra, dict):
-            extra = {}
-        accepted = 0
-        found_any = False
-        for prefix in _ARTIFACT_PREFIXES:
-            att = extra.get(prefix + "attribution")
-            if not isinstance(att, dict):
-                continue
-            found_any = True
-            classes = self._compact_classes(att.get("classes"))
-            est = extra.get(prefix + "attr_est_ms")
-            if not isinstance(est, (int, float)):
-                est = att.get("est_ms_total")
-            err = extra.get(prefix + "attr_model_err_pct")
-            measured = None
-            if isinstance(est, (int, float)) and isinstance(
-                    err, (int, float)) and err > -100.0:
-                measured = est / (1.0 + err / 100.0)
-            if measured is None:
-                self._skip(f"{name}:{prefix or 'row'}",
-                           "attribution table has no reconstructable "
-                           "measured time (est_ms/err_pct missing)")
-                continue
-            flops = nbytes = None
-            if classes:
-                flops = sum((c.get("flops") or 0)
-                            for c in classes.values()) or None
-                nbytes = sum((c.get("bytes") or 0)
-                             for c in classes.values()) or None
-            if self.add_row(
-                    f"bench_artifact:{name}:{prefix or 'row'}",
-                    workload=att.get("workload"),
-                    measured_ms=measured, est_ms=est, err_pct=err,
-                    flops=flops, nbytes=nbytes,
-                    ops=self._ops_total(classes), classes=classes,
-                    git_sha=parsed.get("git_sha"),
-                    run_id=parsed.get("run_id") or f"artifact:{name}",
-                    step=None):
-                accepted += 1
-        if not found_any:
-            self._skip(name, "no attribution tables in row extras")
-        return accepted
 
     # -- tune cache --------------------------------------------------------
     def ingest_tune_cache(self, cache=None):

@@ -69,7 +69,7 @@ def flight_enabled():
 def classify_exception(e):
     """The dump reason for an exception escaping a supervised loop:
     ``"oom"`` for allocator failures anywhere in the cause chain (the
-    bench.py ``_is_alloc_failure`` spelling set), ``"nan_trip"`` for
+    ``_ALLOC_MARKS`` spellings), ``"nan_trip"`` for
     the nan-guard's FloatingPointError, else ``"trainer_exception"``."""
     seen = set()
     exc = e

@@ -1,7 +1,7 @@
 """Hardware accounting: chip peak FLOP/s, MFU, and device-memory stats.
 
-The peak table is the single source of truth the bench harness
-(`bench.py chip_peak_flops`) and the trainer's MFU field both read —
+The peak table is the single source of truth the trainer's MFU field
+and the tuner's roofline both read —
 public bf16 chip specs keyed by ``device_kind`` substring.
 
 ``device_memory_stats`` wraps ``jax.Device.memory_stats()`` (None on CPU)
@@ -125,8 +125,8 @@ def device_memory_stats(device=None):
 def device_hbm_bytes(device=None):
     """The device's usable memory capacity in bytes (the allocator's
     ``bytes_limit``), or None when the backend does not report one (CPU).
-    The preflight ceiling bench.py checks a compiled step's
-    ``hbm_high_water_bytes`` against before running a capacity config."""
+    The preflight ceiling a compiled step's ``hbm_high_water_bytes``
+    is checked against before a capacity config runs."""
     stats = device_memory_stats(device)
     limit = stats.get("bytes_limit")
     return int(limit) if limit else None

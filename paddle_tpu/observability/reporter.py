@@ -18,7 +18,7 @@ import time
 
 from . import hardware as _hardware
 from . import metrics as _metrics
-from .runlog import RunLog
+from .runlog import RunLog, run_stamp
 
 __all__ = ["MetricsReporter"]
 
@@ -53,16 +53,10 @@ class MetricsReporter:
         self._loss_window = collections.deque(maxlen=64)
         if self.runlog is not None:
             # the run identity stamp (schema_version/run_id/git_sha —
-            # bench_history.run_stamp) rides the run_meta record so the
+            # runlog.run_stamp) rides the run_meta record so the
             # measurement corpus (observability/corpus.py) can dedup and
             # attribute this file's step rows; caller meta wins on clash
-            try:
-                from .bench_history import run_stamp
-
-                meta = {**run_stamp(), **(run_meta or {})}
-            except Exception:  # noqa: BLE001 — identity never blocks
-                meta = dict(run_meta or {})
-            self.runlog.log("run_meta", **meta)
+            self.runlog.log("run_meta", **{**run_stamp(), **(run_meta or {})})
 
     # -- composition -------------------------------------------------------
     def chain(self, handler):
@@ -125,7 +119,7 @@ class MetricsReporter:
             # roofline-model error: the attribution engine's estimated
             # step ms vs this step's measured wall — the model-quality
             # figure every corpus row ships; ONE formula
-            # (attribution.reconcile) serves the JSONL and bench rows
+            # (attribution.reconcile) serves the JSONL and the corpus
             from . import attribution as _attr
 
             rec = _attr.reconcile(att, wall) if att else None
@@ -166,7 +160,7 @@ class MetricsReporter:
                 lint_errors=sc.get("lint_errors"),
                 lint_checks=sc.get("lint_checks"),
                 # resilience spine (docs/resilience.md): checkpoint
-                # overhead + resume lineage, so bench history can track
+                # overhead + resume lineage, so a run's JSONL shows
                 # what checkpointing costs the step loop.  None until
                 # the first save/resume of the process.
                 checkpoint_save_ms=self._resil_value(
@@ -201,7 +195,7 @@ class MetricsReporter:
                 # compiled step resolved to (docs/kernels.md) — the
                 # attr_workload |kb= token carries the flash choice;
                 # this field carries the full per-op-class map so
-                # bench-history/corpus tooling can segment trajectories
+                # corpus tooling can segment trajectories
                 # by backend
                 kernel_backends=sc.get("kernel_backends"),
             )

@@ -3,7 +3,7 @@
 Every record is one JSON object per line with at least ``event`` (record
 type) and ``ts`` (unix seconds).  The trainer's `MetricsReporter` writes
 ``step`` / ``pass`` / ``run_meta`` records here; anything downstream
-(regression dashboards, MFU sweeps, the driver's BENCH history) parses it
+(regression dashboards, MFU sweeps, the measurement corpus) parses it
 with ``read_jsonl``.  numpy scalars/arrays are coerced to plain JSON so
 call sites can pass fetched values directly.
 """
@@ -12,8 +12,31 @@ import json
 import os
 import threading
 import time
+import uuid
 
-__all__ = ["RunLog", "read_jsonl"]
+__all__ = ["RunLog", "read_jsonl", "run_stamp"]
+
+SCHEMA_VERSION = 1
+
+
+def run_stamp(cwd=None):
+    """The identity a run's records carry: schema version, a fresh run
+    id, and the repo git sha (None outside a checkout)."""
+    sha = None
+    try:
+        import subprocess
+
+        out = subprocess.run(
+            ["git", "rev-parse", "--short=12", "HEAD"],
+            cwd=cwd or os.path.dirname(os.path.dirname(
+                os.path.dirname(os.path.abspath(__file__)))),
+            capture_output=True, text=True, timeout=10)
+        sha = out.stdout.strip() or None if out.returncode == 0 else None
+    except Exception:  # noqa: BLE001 — the stamp must never kill a run
+        sha = None
+    return {"schema_version": SCHEMA_VERSION,
+            "run_id": uuid.uuid4().hex[:12],
+            "git_sha": sha}
 
 
 def _jsonable(v):

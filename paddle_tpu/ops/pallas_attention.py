@@ -366,7 +366,7 @@ def _packed_geom(q, k, n_head, sub_heads=1):
     grid cell's block is a 128-aligned lane slice selected by the INDEX
     MAP ((i // n_slices, ·, i % n_slices) block coords) and no
     [b,t,h,d]<->[bh,t,d] transpose ever exists.  (A 4-D h-sliced BlockSpec
-    is rejected by the Mosaic tiling rules — see RESULTS.md round 4; the
+    is rejected by the Mosaic tiling rules — measured in round 4; the
     lane-slice form is the legal spelling of the same thing.)
 
     ``sub_heads`` (S): heads per 128-lane slice — 1 for d_head % 128 == 0,
@@ -978,7 +978,7 @@ def flash_attention_packed(q, k, v, n_head, causal=False, sm_scale=None,
 
     Numerically identical to ``flash_attention`` on the reshaped 4-D view,
     but the [b,t,h,d]<->[b*h,t,d] pack/unpack transposes — 23 ms/step on
-    the GPT flagship, 8% of device time (RESULTS.md round 4) — never
+    the GPT flagship, 8% of device time (measured in round 4) — never
     exist: each 128-lane slice is selected by the kernels' block index
     maps.  Supported geometries (``packed_sub_heads``): ``d_head % 128 ==
     0`` (one head per slice), ``d_head == 64`` with even ``n_head`` (TWO

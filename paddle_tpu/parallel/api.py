@@ -539,7 +539,7 @@ def optimizer_state_report(program, mesh):
          "vars": {name: {"bytes", "per_device_bytes", "spec"}}}
 
     Pure metadata — no arrays are touched, so it also works pre-startup
-    and is what ``benchmarks/multichip.py`` and the multichip selftest
+    and is what ``benchmarks/multichip.py`` and the multichip tests
     gate (``per_device_bytes <= replicated/4`` on the dp=8 mesh).
     ``sharding_report`` is the generalization covering parameter and
     gradient bytes too."""
@@ -591,8 +591,8 @@ def sharding_report(program, mesh):
     figure), ``per_device_bytes`` under the resolved specs,
     ``replicated_per_device_bytes`` (== total: the kill-switch figure),
     ``sharded_vars`` / ``replicated_vars`` counts and a per-var
-    ``vars`` dict.  Pure metadata — works pre-startup; gated by the
-    multichip selftest (param bytes/device <= replicated/2 on the
+    ``vars`` dict.  Pure metadata — works pre-startup; gated by
+    ``tests/test_fsdp.py`` (param bytes/device <= replicated/2 on the
     fsdp=4 mesh) and ``benchmarks/multichip.py``."""
     from ..core.program import Parameter
 

@@ -2,7 +2,7 @@
 establishes (docs/parallel.md) — the machine-checked form of the prose
 rules, shipped next to the code whose placement discipline they audit.
 
-``--multichip-selftest`` and the sharding selftest evaluate these
+``tests/test_fsdp.py`` and ``tests/test_comm_plan.py`` evaluate these
 against ``exe.last_comm_plan`` instead of hand-rolled reduce-count
 asserts; attach them to a program (``analysis.comm.attach_comm_contract``)
 and every compile's ``hlo.comm-contract`` check enforces them in CI.

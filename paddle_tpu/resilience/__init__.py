@@ -20,8 +20,8 @@ reproduces that capability for the jitted-step world and makes failure a
 
 ``Trainer.train(..., checkpoint_every_n_steps=N, resume=True)`` is the
 consumer: kill-and-resume reproduces the uninterrupted loss trajectory
-bit-exactly (``python -m paddle_tpu --resilience-selftest`` is the
-gate).  See docs/resilience.md.
+bit-exactly (``tests/test_resilience.py`` SIGKILLs a trainer to
+show it).  See docs/resilience.md.
 """
 
 from . import checkpoint

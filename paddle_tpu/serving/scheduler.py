@@ -29,8 +29,8 @@ latency telemetry:
   sort FIFO behind budgeted ones.
 
 ``FifoScheduler`` keeps the PR-2 behavior verbatim — it is the
-benchmark baseline (``benchmarks/serving.py`` runs both policies under
-the same shared-prefix Poisson load and gates SLO goodput > FIFO
+baseline a policy is compared with (both run under the same
+shared-prefix Poisson load; SLO goodput should exceed FIFO
 goodput) and the compatibility spelling (``ServingEngine`` with no
 budgets behaves identically under either).
 """

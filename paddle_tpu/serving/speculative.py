@@ -33,7 +33,7 @@ is.  Speculative decoding restructures the schedule, not the math:
   reads it, so rollback is pure host accounting — no device copy.
   Slot finish / death / abort release the whole scratch chain through
   the engine's ``_release_slot`` discipline: zero leaks, pinned by
-  ``--spec-selftest`` and the fault-injection regression.
+  ``tests/test_speculative.py`` and its fault-injection regression.
 
 Kill switch: ``PADDLE_TPU_SPEC=0`` (or ``off``/``false``) makes the
 engine ignore ``draft_params`` entirely — no validation, no extra pool
@@ -77,7 +77,7 @@ def depth_draft(params, n_layers):
     transformer blocks plus the shared embeddings / final LN / LM head.
     The cheapest honest draft in the ``transformer.build`` family —
     same vocab, same width, same head geometry by construction — used
-    by the selftests and the serving benchmark."""
+    by the tests and ``chip_smoke.py``."""
     if not 1 <= int(n_layers) <= draft_depth(params):
         raise ValueError(
             f"depth_draft: n_layers {n_layers} outside [1, "

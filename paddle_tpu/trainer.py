@@ -169,8 +169,8 @@ class Trainer:
         checkpoint (skipping torn ones, honoring the crash-publish
         ``.old`` fallback), restores params + optimizer state + RNG +
         reader position, and continues such that the loss trajectory is
-        BIT-EXACT vs the uninterrupted run (the ``--resilience-selftest``
-        gate).  ``watchdog_deadline=S`` supervises the step loop: a step
+        BIT-EXACT vs the uninterrupted run (``tests/test_resilience.py``
+        kills it).  ``watchdog_deadline=S`` supervises the step loop: a step
         that makes no progress for S seconds trips the
         ``resilience.watchdog_trips`` counter and a timeline instant."""
         if not self._initialized:

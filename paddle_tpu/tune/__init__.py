@@ -18,8 +18,8 @@ This package makes them MEASURED, per workload key
   ``models.transformer.build`` pick tuned flash geometry when the
   caller passes no explicit blocks, and
   ``memory_optimize(policy="auto")`` resolves the tuned remat policy;
-- explicit arguments and env knobs (``BENCH_GPT_BLOCK_Q/K``,
-  ``PADDLE_TPU_DIAG_W``) always win over the cache.
+- explicit arguments and the ``PADDLE_TPU_DIAG_W`` pin
+  always win over the cache.
 
 Modes (``PADDLE_TPU_TUNE``): ``off``/``0`` — kill switch, the framework
 behaves bit-exactly as if this package did not exist; ``cached``
@@ -33,7 +33,7 @@ The serving path reads none of this: ``ServingEngine`` takes its
 geometry from its arguments and ``kernels.paged_attention.attend``
 chooses its spelling from what it observes.
 
-CI: ``python -m paddle_tpu --tune-selftest`` (tools/tier1.sh).
+Its tests: ``tests/test_tune.py`` (a measured search end to end).
 """
 
 import contextlib
@@ -73,7 +73,7 @@ def tune_mode():
     "cached" — consult the cache, never search in the hot path.  "0" /
     "off" / "false" is the kill switch: no lookup happens at all and
     every knob keeps its hand-picked default (bit-exact parity with the
-    pre-tune framework, pinned by the selftest)."""
+    pre-tune framework, pinned by ``tests/test_tune.py``)."""
     v = os.environ.get("PADDLE_TPU_TUNE", "cached").strip().lower()
     if v in ("0", "off", "false", "no", ""):
         return "off"
@@ -143,7 +143,7 @@ def attention_config(seq_len, d_head, n_head, dtype, causal=True):
 def schedule_config_for(seq_len, d_head, n_head, dtype):
     """The tuned STEP schedule ``{"policy", "accum", "block_q", ...}``
     for one GPT shape, or None — consulted by
-    ``memory_optimize(policy="auto")`` and bench.py's flagship path."""
+    ``memory_optimize(policy="auto")``."""
     return _cache_lookup("gpt_step", seq_len, d_head, n_head, dtype,
                          remat="auto")
 

@@ -125,7 +125,7 @@ def _registry_surface():
 
 def _git_sha():
     try:
-        from ..observability.bench_history import run_stamp
+        from ..observability.runlog import run_stamp
 
         return run_stamp().get("git_sha")
     except Exception:  # noqa: BLE001 — identity must never block caching
@@ -222,7 +222,7 @@ _cache_singleton = []  # [(resolved_path, TuneCache)]
 
 def get_cache():
     """Process-wide cache bound to the CURRENT resolved path — changing
-    ``PADDLE_TPU_TUNE_CACHE`` (tests, the selftest) re-loads."""
+    ``PADDLE_TPU_TUNE_CACHE`` (as the tests do) re-loads."""
     path = cache_path()
     if _cache_singleton and _cache_singleton[0][0] == path:
         return _cache_singleton[0][1]

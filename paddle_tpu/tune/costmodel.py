@@ -31,8 +31,8 @@ schedules the data can't vouch for) calibrates
 Fitting is robust least squares (IRLS with Huber weights, nonnegative
 coefficients, deterministic holdout split — every ``holdout_every``-th
 row).  ``holdout_err_pct`` (median absolute error on held-out rows) is
-stored next to ``analytic_err_pct`` on the SAME rows: the
-``--costmodel-selftest`` CI gate asserts the fitted model strictly
+stored next to ``analytic_err_pct`` on the SAME rows:
+``tests/test_costmodel.py`` asserts that the fitted model strictly
 improves.
 
 Persistence mirrors the tune cache's robustness contract
@@ -451,7 +451,7 @@ def fit_cost_model(rows, holdout_every=4):
             entry["hbm_scale"] = 1.0
         # holdout scoring: fitted vs the analytic estimate RECORDED on
         # the same rows (est_ms is what the analytic roofline said at
-        # measure time — the selftest seeds the corpus pre-fit, so the
+        # measure time — the rows were measured before any fit, so the
         # comparison is apples-to-apples)
         fitted_pairs, analytic_pairs = [], []
         for r in holdout:

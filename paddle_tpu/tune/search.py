@@ -79,10 +79,10 @@ def _zero3_rs_env(value):
 
 
 def flagship_dims():
-    """The GPT flagship model dims (bench.py's BENCH_GPT_* envs win) —
-    the ONE env-default table bench.py and the tune entry points share,
-    so the searched workload key and the flagship run's lookup always
-    agree."""
+    """The GPT flagship model dims (the BENCH_GPT_* envs win) —
+    the ONE env-default table ``chip_smoke.py`` and the tune entry points
+    share, so the searched workload key and the flagship run's lookup
+    always agree."""
     return {
         "n_layer": int(os.environ.get("BENCH_GPT_LAYERS", "12")),
         "d_model": int(os.environ.get("BENCH_GPT_DMODEL", "768")),

@@ -44,7 +44,7 @@ POLICY_ORDER = ("none", "selective", "offload", "compact", "full")
 # calibrated against the measured t=16k figures (selective bs8 ~23.5 GB
 # sat the 16 GiB chip, RESULTS round 3; accum2-no-remat and bs6
 # full-remat both fit under 15.75 GiB while offload at accum=1 did NOT
-# — bench.py memory_gate + BENCH_r05): none keeps everything XLA can't
+# — measured on the chip in round 5): none keeps everything XLA can't
 # free, selective keeps kernel residuals + MXU outputs (~q/k/v/o/
 # att_out/ffn1[4d]/ffn2), offload moves the per-layer block-input
 # residuals to pinned host, compact keeps only kernel residuals +
@@ -319,7 +319,7 @@ def prune_static(seq_len, d_head, n_head, candidates, dtype_size=2,
     # calibrated roofline: when a fitted cost model is loadable, the
     # slack test compares FITTED schedule costs (ms) instead of raw
     # scheduled flops — prediction is monotonic in flops so candidate
-    # ordering is unchanged (the --costmodel-selftest contract); only
+    # ordering is unchanged (tests/test_costmodel.py holds it so); only
     # the ratio moves, because the fitted per-step overhead dilutes
     # small flop deltas.  No model / kill switch -> the flop ratio,
     # exactly as before.

@@ -314,6 +314,28 @@ def test_clean_gpt_zero_findings(policy):
     assert rep.findings == [], [repr(f) for f in rep.findings]
 
 
+_EXAMPLES = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "examples")
+
+
+@pytest.mark.parametrize("script", sorted(
+    f for f in os.listdir(_EXAMPLES) if f.endswith(".py")))
+def test_example_programs_lint_clean(script):
+    """Every ``examples/`` script's ``build_program()`` (what ``python
+    -m paddle_tpu --lint <script>`` loads) has no program-level error
+    or warning."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "pt_example_" + script[:-3], os.path.join(_EXAMPLES, script))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    pt.core.unique_name.reset()
+    main, _startup, fetch = mod.build_program()
+    rep = analysis.lint(main, fetch_list=fetch, levels=("program",))
+    assert not rep.errors and not rep.warnings, rep.ids()
+
+
 # -- framework / registry ---------------------------------------------------
 
 def test_registry_has_seeded_checks():

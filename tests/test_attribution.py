@@ -1,8 +1,7 @@
 """Per-op attribution engine + crash flight recorder (ISSUE 11) —
 HLO-walk table math on planted text, coverage on a real compiled GPT
-step, roofline bound classification, regression attribution over a
-planted two-artifact fixture, flight-bundle dumps via the PR-8 injected
-faults, grad-norm telemetry, and serving goodput accounting."""
+step, roofline bound classification, flight-bundle dumps via the PR-8
+injected faults, grad-norm telemetry, and serving goodput accounting."""
 
 import json
 import math
@@ -16,7 +15,6 @@ import paddle_tpu as pt
 from paddle_tpu import layers
 from paddle_tpu.models import transformer
 from paddle_tpu.observability import attribution as attr
-from paddle_tpu.observability import bench_history as bh
 from paddle_tpu.observability import flight
 from paddle_tpu.observability import metrics as _obs
 
@@ -114,8 +112,7 @@ def gpt_compiled():
 
 def test_attribution_coverage_on_compiled_gpt(gpt_compiled):
     """The real compiled step's table covers >= 95% of the
-    executable's own cost-analysis flops — the selftest contract at
-    test granularity."""
+    executable's own cost-analysis flops."""
     exe, cost = gpt_compiled
     att = exe.last_attribution
     assert att is not None
@@ -181,66 +178,6 @@ def test_reconcile_error_pct():
     assert rec["err_pct"] == -50.0
     assert attr.reconcile(att, None) is None
     assert attr.reconcile({}, 0.01) is None
-
-
-# -- regression attribution over bench history -------------------------------
-
-def _att_extra(shares):
-    return {"classes": {c: {"flops": 1, "bytes": 1, "est_ms": s,
-                            "share": s, "bound": "memory"}
-                        for c, s in shares.items()},
-            "workload": "k", "coverage": 0.99, "est_ms_total": 1.0}
-
-
-def test_regression_attribution_planted_fixture(tmp_path):
-    rows = [
-        ("BENCH_r01.json", 100.0,
-         {"matmul": 0.6, "elementwise": 0.3,
-          "collective.all-reduce": 0.1}),
-        ("BENCH_r02.json", 40.0,
-         {"matmul": 0.34, "elementwise": 0.3,
-          "collective.all-reduce": 0.36}),
-    ]
-    for i, (name, value, shares) in enumerate(rows):
-        (tmp_path / name).write_text(json.dumps({
-            "n": i + 1, "rc": 0, "parsed": {
-                "metric": "gpt_train_tokens_per_sec_per_chip",
-                "value": value, "unit": "tok/s",
-                "extra": {"gpt_attribution": _att_extra(shares)}}}))
-    summary, rws = bh.history(str(tmp_path))
-    assert summary["regressions"]
-    key = "BENCH_r02.json:gpt_train_tokens_per_sec_per_chip"
-    moved = summary["regression_attribution"][key]
-    # the biggest mover is named first: the collective share grew
-    assert moved[0]["op_class"] == "collective.all-reduce"
-    assert moved[0]["delta"] > 0
-    # matmul's share shrank and is also named
-    assert any(m["op_class"] == "matmul" and m["delta"] < 0
-               for m in moved)
-
-
-def test_regression_without_tables_has_no_attribution(tmp_path):
-    for i, v in enumerate((100.0, 40.0)):
-        (tmp_path / f"BENCH_r0{i+1}.json").write_text(json.dumps({
-            "n": i + 1, "rc": 0, "parsed": {
-                "metric": "m", "value": v, "unit": "u"}}))
-    summary, _ = bh.history(str(tmp_path))
-    assert summary["regressions"]
-    assert summary["regression_attribution"] == {}
-
-
-def test_bench_history_tracks_serving_goodput(tmp_path):
-    """serving_goodput_under_slo is a tracked metric: a >10% drop vs
-    best-so-far flags like tok_s does."""
-    for i, v in enumerate((500.0, 300.0)):
-        (tmp_path / f"BENCH_r0{i+1}.json").write_text(json.dumps({
-            "n": i + 1, "rc": 0, "parsed": {
-                "metric": "m", "value": 1.0, "unit": "u",
-                "extra": {"serving_goodput_under_slo": v,
-                          "serving_tok_s": 600.0}}}))
-    summary, _ = bh.history(str(tmp_path))
-    assert any(r["metric"] == "serving_goodput_under_slo"
-               for r in summary["regressions"])
 
 
 # -- flight recorder ---------------------------------------------------------

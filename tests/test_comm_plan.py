@@ -480,6 +480,8 @@ def test_shard_activation_provenance_and_reshard_check():
     blk = main.global_block()
     act = blk.vars["h1.tmp_1"]
     papi.shard_activation(act, P(None, "dp"))  # feature-shard: reshard
+    attach_comm_contract(
+        main, CommContract("no-activation-reshard").forbid_reshard(r"^h1"))
     feed = {"x": np.zeros((8, 16), np.float32),
             "y": np.zeros((8, 1), np.float32)}
     rep = analysis.lint(main, feed=feed, fetch_list=[loss], mesh=mesh,
@@ -488,6 +490,10 @@ def test_shard_activation_provenance_and_reshard_check():
     assert ar and ar[0].severity == "warning"
     assert ar[0].data["var"] == "h1.tmp_1"
     assert ar[0].data["op_count"] > 0
+    # a forbid_reshard contract upgrades it to an error naming the var
+    cc = [f for f in rep.by_check("hlo.comm-contract")
+          if f.severity == "error"]
+    assert cc and "h1.tmp_1" in cc[0].message
     # shard_activation refuses persistables and data feeds
     with pytest.raises(ValueError):
         papi.shard_activation(blk.vars["x"], P("dp"))
@@ -516,6 +522,159 @@ def test_constraint_placement_quiet_on_clean_programs():
     rep = analysis.lint(main, feed=feed, fetch_list=[loss], mesh=mesh,
                         levels=("jaxpr",))
     assert rep.by_check("jaxpr.constraint-placement") == []
+
+
+# -- the GPT step on the dp=2 x fsdp=4 mesh: planted mis-spellings, the plan's
+# fundamentals, and the clean sweep ------------------------------------------
+
+_GPT = dict(vocab_size=128, n_layer=3, n_head=2, d_model=32, max_len=16,
+            dropout_rate=0.0, dtype="float32", learning_rate=1e-2)
+_GPT_ACCUM = 2
+_COMM_CHECKS = (
+    "hlo.comm-contract", "hlo.accidental-reshard", "hlo.axis-attribution",
+    "hlo.inloop-collective", "jaxpr.constraint-placement",
+    "program.spec-conflict",
+)
+
+
+def _gpt_fsdp(policy="selective"):
+    """The PR-10 recipe in its order: remat, accumulation, dp, fsdp."""
+    from paddle_tpu.models import transformer
+
+    pt.core.unique_name.reset()
+    main, startup = pt.Program(), pt.Program()
+    main.random_seed = 7
+    with pt.program_guard(main, startup):
+        outs = transformer.build(**_GPT)
+    pt.memory_optimize(main, policy=policy)
+    pt.gradient_accumulation(main, _GPT_ACCUM)
+    papi.data_parallel(main, "dp", programs=(startup,))
+    papi.shard_fsdp(main, programs=(startup,))
+    return main, startup, outs["avg_cost"]
+
+
+def _gpt_feed():
+    rng = np.random.default_rng(5)
+    toks = rng.integers(0, _GPT["vocab_size"],
+                        (2 * _GPT_ACCUM * 2, _GPT["max_len"])
+                        ).astype(np.int64)
+    lbls = np.roll(toks, -1, axis=1)
+    lbls[:, -1] = -1
+    return {"tokens": toks, "labels": lbls}
+
+
+def _gpt_comm_plan(mesh):
+    main, startup, loss = _gpt_fsdp()
+    scope = pt.Scope()
+    with pt.core.scope.scope_guard(scope):
+        exe = pt.Executor(mesh=mesh)
+        exe.run(startup, scope=scope)
+        exe.compile_only(main, feed=_gpt_feed(), fetch_list=[loss],
+                         scope=scope)
+    return exe.last_comm_plan
+
+
+def _fsdp_composed_carry(lead):
+    return P(*([None] * lead + ["dp"]), "fsdp")
+
+
+def test_symmetric_fsdp_pin_is_an_unblessed_in_scan_constraint(monkeypatch):
+    """docs/parallel.md rule 2's wrong spelling: a plain
+    ``with_sharding_constraint`` where the forward-only custom-vjp pin
+    belongs transposes to itself, so the backward scan inherits it."""
+    from paddle_tpu.core import executor as ex
+
+    def symmetric_pin(sharding, site="fsdp"):
+        return lambda x: jax.lax.with_sharding_constraint(x, sharding)
+
+    monkeypatch.setattr(ex, "_fsdp_fwd_pin", symmetric_pin)
+    main, _startup, loss = _gpt_fsdp()
+    rep = analysis.lint(main, feed=_gpt_feed(), fetch_list=[loss],
+                        mesh=make_mesh({"dp": 2, "fsdp": 4}),
+                        levels=("jaxpr",))
+    errs = [f for f in rep.by_check("jaxpr.constraint-placement")
+            if f.severity == "error"]
+    assert any("fsdp" in (f.data.get("axes") or ())
+               and (f.data.get("scan_depth") or 0) >= 1 for f in errs), [
+        (f.data.get("axes"), f.data.get("scan_depth")) for f in errs]
+
+
+def test_fsdp_composed_accum_carry_strays_off_its_plain_dp_contract(
+        monkeypatch):
+    """Rule 3's wrong spelling: the accumulation carry pinned
+    ``P('dp', 'fsdp')`` errors AT the blessed ``accum_carry`` site."""
+    from paddle_tpu.core import executor as ex
+
+    monkeypatch.setattr(ex, "_accum_carry_spec", _fsdp_composed_carry)
+    main, _startup, loss = _gpt_fsdp()
+    rep = analysis.lint(main, feed=_gpt_feed(), fetch_list=[loss],
+                        mesh=make_mesh({"dp": 2, "fsdp": 4}),
+                        levels=("jaxpr",))
+    errs = [f for f in rep.by_check("jaxpr.constraint-placement")
+            if f.severity == "error" and "accum_carry" in f.location]
+    assert errs and "fsdp" in (errs[0].data.get("axes") or ())
+
+
+def test_gpt_fsdp_plan_axes_phases_and_diff(monkeypatch):
+    """What the plan recovers from the compiled step's replica groups:
+    the weight gathers as in-loop ``all-gather@fsdp`` of the forward
+    scan, the gradient reduction at the boundary over the gradient
+    axes, no collective left without an axis; the clean spelling keeps
+    ``zero3_grad_contract``; and ``comm_diff`` against
+    ``PADDLE_TPU_FSDP=0`` names the gathers FSDP adds."""
+    mesh = make_mesh({"dp": 2, "fsdp": 4})
+    plan_on = _gpt_comm_plan(mesh)
+    gathers = plan_on.select(kind="all-gather", axis="fsdp", in_loop=True)
+    assert gathers and all(o.phase == "fwd-scan" for o in gathers)
+    boundary = plan_on.select(kind="reduce", in_loop=False,
+                              phase="boundary")
+    assert any("dp" in (o.axes or ()) for o in boundary)
+    assert all(o.axes and set(o.axes) <= {"dp", "fsdp"} for o in boundary)
+    assert not plan_on.unattributed()
+    assert pcontracts.zero3_grad_contract(mesh).check(plan_on) == []
+    monkeypatch.setenv("PADDLE_TPU_FSDP", "0")
+    diff = comm_diff(_gpt_comm_plan(mesh), plan_on, "FSDP=0", "FSDP=1")
+    assert any(c["kind"] == "all-gather" and c["axes"] == "fsdp"
+               and c["in_loop"] and c["count_b"] > c["count_a"]
+               for c in diff["changed"]), diff["text"]
+
+
+def test_in_loop_gradient_scatter_breaks_zero3_grad_contract(monkeypatch):
+    """Rule 4's wrong spelling: the ZeRO-3 scatter composed onto the
+    accumulation carry reduces every micro-batch's partial gradient
+    INSIDE the scan; the contract's in-loop forbid fires on the
+    compiled plan's TRAFFIC, whatever site made it."""
+    from paddle_tpu.core import executor as ex
+
+    monkeypatch.setattr(ex, "_accum_carry_spec", _fsdp_composed_carry)
+    mesh = make_mesh({"dp": 2, "fsdp": 4})
+    viol = pcontracts.zero3_grad_contract(mesh).check(_gpt_comm_plan(mesh))
+    forbids = [v for v in viol if v["rule"]["rule"] == "forbid"
+               and v["op_count"] > 0]
+    assert forbids and all("in-loop" in o for o in forbids[0]["ops"])
+
+
+@pytest.mark.parametrize("policy", ["selective", "compact", "full",
+                                    "offload"])
+def test_clean_gpt_comm_sweep(policy, monkeypatch):
+    """Every spelling the switches reach (FSDP on/off x ZeRO on/off)
+    under this remat policy lints to zero error-severity comm findings
+    with the canned training contracts attached."""
+    mesh = make_mesh({"dp": 2, "fsdp": 4})
+    for fsdp in ("1", "0"):
+        for zero in ("1", "0"):
+            monkeypatch.setenv("PADDLE_TPU_FSDP", fsdp)
+            monkeypatch.setenv("PADDLE_TPU_ZERO", zero)
+            main, _startup, loss = _gpt_fsdp(policy)
+            for c in pcontracts.training_step_contract(
+                    mesh, accum=True, fsdp=fsdp == "1",
+                    grad_rs=fsdp == "1"):
+                attach_comm_contract(main, c)
+            rep = analysis.lint(main, feed=_gpt_feed(), fetch_list=[loss],
+                                mesh=mesh, levels=("jaxpr", "hlo"))
+            bad = [f for f in rep if f.check in _COMM_CHECKS
+                   and f.severity == "error"]
+            assert not bad, (fsdp, zero, [repr(f) for f in bad])
 
 
 # -- the schema-versioned --lint --json contract ----------------------------

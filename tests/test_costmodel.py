@@ -1,14 +1,12 @@
 """Learned cost model tests (ISSUE 16): corpus ingestion edge cases
 (truncated JSONL line, missing attribution fields, duplicate
-(run_id, step) dedup, non-object artifact — each CLASSIFIED, never a
-crash), the mixed-vintage workload-key regression (pre-PR-13 JSONL
+(run_id, step) dedup — each CLASSIFIED, never a crash), the mixed-vintage workload-key regression (pre-PR-13 JSONL
 without ``|kb=`` joins under ``backend="unknown"``), the cost-model
 file's tune-cache robustness contract (corrupt / truncated / schema
 mismatch -> analytic defaults + ``tune.costmodel_errors``), fitting on
 synthetic rows (holdout improvement, hbm_scale clamping), the
-``PADDLE_TPU_COSTMODEL=0`` kill switch's bit-exactness, calibrated
-static pruning (ordering preserved), and bench-history's
-lower-is-better trajectory for ``gpt_attr_model_err_pct``."""
+``PADDLE_TPU_COSTMODEL=0`` kill switch's bit-exactness, and calibrated
+static pruning (ordering preserved)."""
 
 import json
 
@@ -16,12 +14,31 @@ import pytest
 
 from paddle_tpu import tune
 from paddle_tpu.observability import attribution as attr
-from paddle_tpu.observability import bench_history
 from paddle_tpu.observability import get_registry
 from paddle_tpu.observability.corpus import Corpus, workload_field
 from paddle_tpu.tune import costmodel as cm
 from paddle_tpu.tune import space as tspace
-from paddle_tpu.tune.costmodel_selftest import _TOY_HLO
+
+# a tiny hand-written HLO module — the deterministic attribution input
+# of the bit-exactness checks (one dot, one fusion whose body op carries
+# flops but no bytes, one reduce — three distinct op classes)
+_TOY_HLO = """\
+HloModule costmodel_toy
+
+%fused_add (a: f32[64,64], b: f32[64,64]) -> f32[64,64] {
+  %a = f32[64,64] parameter(0)
+  %b = f32[64,64] parameter(1)
+  ROOT %add.9 = f32[64,64] add(%a, %b)
+}
+
+ENTRY %main (p0: f32[64,64], p1: f32[64,64]) -> f32[64] {
+  %p0 = f32[64,64] parameter(0)
+  %p1 = f32[64,64] parameter(1)
+  %dot.1 = f32[64,64] dot(%p0, %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %fusion.2 = f32[64,64] fusion(%dot.1, %p1), kind=kLoop, calls=%fused_add
+  ROOT %reduce.3 = f32[64] reduce(%fusion.2, %p1), dimensions={1}
+}
+"""
 
 
 @pytest.fixture
@@ -115,47 +132,6 @@ def test_duplicate_run_id_step_rows_dedup(tmp_path):
     assert len(co) == 2
     assert sum("duplicate (run_id, step)" in r
                for _s, r in co.skipped) == 2
-
-
-def test_nonobject_artifact_classified_not_crashed(tmp_path):
-    """A valid-JSON-but-not-an-object artifact (torn write that still
-    parses) classifies exactly like bench_history does."""
-    p = tmp_path / "BENCH_r03.json"
-    p.write_text("[1, 2, 3]")
-    co = Corpus()
-    assert co.ingest_artifact(p) == 0
-    assert co.skipped == [
-        ("BENCH_r03.json", "artifact is not a JSON object (list)")]
-
-
-def test_artifact_ingest_reconstructs_measured(tmp_path):
-    """A real-shaped bench artifact yields one corpus row with the
-    measured wall reconstructed from the shipped est/err pair."""
-    p = tmp_path / "BENCH_r07.json"
-    p.write_text(json.dumps({"n": 7, "rc": 0, "parsed": {
-        "metric": "gpt_tokens_per_sec_per_chip", "value": 100.0,
-        "run_id": "artrun", "git_sha": "g1", "extra": {
-            "gpt_attribution": {
-                "workload": "op=step|t=128|kb=pallas_tpu",
-                "est_ms_total": 2.5,
-                "classes": {"dot": {"flops": 1e9, "bytes": 2e8,
-                                    "ops": 3, "est_ms": 2.5}}},
-            "gpt_attr_est_ms": 2.5,
-            "gpt_attr_model_err_pct": -50.0}}}))
-    co = Corpus()
-    assert co.ingest_artifact(p) == 1
-    row = co.rows[0]
-    assert row["measured_ms"] == pytest.approx(5.0)  # 2.5 / (1 - 0.5)
-    assert row["run_id"] == "artrun" and row["flops"] == 1e9
-    # err_pct <= -100 is unreconstructable (division blows up): classify
-    p2 = tmp_path / "BENCH_r08.json"
-    p2.write_text(json.dumps({"n": 8, "rc": 0, "parsed": {
-        "metric": "m", "value": 1.0, "extra": {
-            "gpt_attribution": {"est_ms_total": 2.5},
-            "gpt_attr_model_err_pct": -100.0}}}))
-    assert co.ingest_artifact(p2) == 0
-    assert any("no reconstructable measured time" in r
-               for _s, r in co.skipped)
 
 
 def test_corpus_save_load_roundtrip(tmp_path):
@@ -371,6 +347,31 @@ def test_attribute_hlo_kill_switch_bit_exact(tmp_model, monkeypatch):
         baseline, sort_keys=True)
 
 
+@pytest.mark.parametrize("rot", ["garbage", "truncated", "schema"])
+def test_unloadable_model_file_leaves_attribution_as_without_one(
+        tmp_model, rot):
+    """A model file that cannot be loaded changes no estimate: the
+    attribution table is the no-model one byte for byte."""
+    baseline = json.dumps(attr.attribute_hlo(_TOY_HLO), sort_keys=True)
+    plat = cm.current_platform()
+    _plant(tmp_model, plat, _ENTRY)
+    good = tmp_model.read_text()
+    assert json.dumps(attr.attribute_hlo(_TOY_HLO),
+                      sort_keys=True) != baseline
+    tmp_model.write_text({
+        "garbage": "{not json",
+        "truncated": good[: len(good) // 2],
+        "schema": json.dumps({"schema_version": 999, "platforms": {}}),
+    }[rot])
+    cm.reset_model()
+    assert json.dumps(attr.attribute_hlo(_TOY_HLO),
+                      sort_keys=True) == baseline
+    assert cm.get_model().stale_reason is not None
+    tmp_model.write_text(good)
+    cm.reset_model()
+    assert cm.model_status(plat)["mode"] == "fitted"
+
+
 def test_estimate_gpt_step_hbm_scale_and_kill_switch(tmp_model,
                                                      monkeypatch):
     args = dict(n_layer=6, d_model=768, n_head=12, vocab=32000,
@@ -418,44 +419,146 @@ def test_prune_static_calibrated_ordering(tmp_model):
     assert base_surv[0]["block_q"] == surv_loose[0]["block_q"]
 
 
-# -- bench-history: gpt_attr_model_err_pct is lower-is-better -------------
+# -- the loop closed on measured runs: reporter JSONL -> corpus -> fit ->
+# the next compile consults it ---------------------------------------------
 
-def _bench_artifact(dirp, rnd, err_pct):
-    p = dirp / f"BENCH_r{rnd:02d}.json"
-    p.write_text(json.dumps({"n": rnd, "rc": 0, "parsed": {
-        # flag-exempt main metric, held constant: only the cost-model
-        # error trajectory is under test here
-        "metric": "resnet50_train_images_per_sec_per_chip",
-        "value": 100.0, "unit": "img/s/chip",
-        "extra": {"gpt_attr_model_err_pct": err_pct}}}))
-    return p
+_TOY = dict(vocab=61, n_layer=3, n_head=2, d_model=64, batch=4)
 
 
-def test_bench_history_flags_cost_model_drift(tmp_path):
-    """|err| improving 50->40 never flags; worsening to 60 (+50% vs the
-    best-so-far 40) flags with direction=lower_is_better; an
-    artifact:metric ack green-lights exactly that regression."""
-    _bench_artifact(tmp_path, 1, -50.0)  # signed: tracked as |err|
-    _bench_artifact(tmp_path, 2, 40.0)
-    _bench_artifact(tmp_path, 3, 60.0)
-    summary, rows = bench_history.history(str(tmp_path))
-    assert rows[0]["metrics"]["gpt_attr_model_err_pct"] == 50.0
-    regs = [r for r in summary["regressions"]
-            if r["metric"] == "gpt_attr_model_err_pct"]
-    assert len(regs) == 1
-    reg = regs[0]
-    assert reg["artifact"] == "BENCH_r03.json" and reg["value"] == 60.0
-    assert reg["best"] == 40.0 and reg["direction"] == "lower_is_better"
-    assert not summary["ok"]
-    acked, _ = bench_history.history(str(tmp_path), known_failures={
-        "BENCH_r03.json:gpt_attr_model_err_pct": "known CPU-noise round"})
-    assert acked["ok"] and acked["acknowledged"] == [
-        "BENCH_r03.json:gpt_attr_model_err_pct"]
+class EndIteration:
+    """What the trainer hands the reporter a step (it dispatches on the
+    class NAME): the loop below makes the step stream itself, so the
+    production MetricsReporter writes JSONL from measured walls and
+    compiled cost dicts without a trainer around it."""
+
+    def __init__(self, batch_id, cost, wall_time, step_cost, samples):
+        self.pass_id, self.batch_id, self.cost = 0, batch_id, cost
+        self.wall_time, self.step_cost = wall_time, step_cost
+        self.samples, self.throughput = samples, samples / wall_time
+        self.mfu = self.reader_wait = self.grad_norm = None
 
 
-def test_bench_history_improving_error_never_flags(tmp_path):
-    for rnd, err in ((1, 80.0), (2, 50.0), (3, 45.0)):
-        _bench_artifact(tmp_path, rnd, err)
-    summary, _rows = bench_history.history(str(tmp_path))
-    assert summary["ok"] and not summary["regressions"]
-    assert "gpt_attr_model_err_pct" in summary["metrics_tracked"]
+def _measured_run(seq_len, steps, jsonl_path, run_id):
+    """Compile the toy GPT at ``seq_len`` and stream ``steps`` measured
+    steps through a MetricsReporter; returns the last ``last_step_cost``."""
+    import time
+
+    import numpy as np
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer
+    from paddle_tpu.observability import MetricsReporter
+
+    pt.core.unique_name.reset()
+    main_prog, startup = pt.Program(), pt.Program()
+    main_prog.random_seed = 7
+    with pt.program_guard(main_prog, startup):
+        outs = transformer.build(
+            vocab_size=_TOY["vocab"], n_layer=_TOY["n_layer"],
+            n_head=_TOY["n_head"], d_model=_TOY["d_model"],
+            max_len=seq_len, dropout_rate=0.0, dtype="float32",
+            fused_head=True)
+        pt.memory_optimize(main_prog, policy="selective")
+    rng = np.random.default_rng(seq_len)
+    toks = rng.integers(0, _TOY["vocab"],
+                        (_TOY["batch"], seq_len)).astype(np.int64)
+    feed = {"tokens": toks, "labels": np.roll(toks, -1, axis=1)}
+    scope = pt.core.scope.Scope()
+    reporter = MetricsReporter(log_every_n=0, jsonl_path=str(jsonl_path),
+                               run_meta={"run_id": run_id})
+    try:
+        with pt.core.scope.scope_guard(scope):
+            exe = pt.Executor()
+            exe.run(startup, scope=scope)
+            # the first step pays the compile outside the measured walls
+            exe.run(main_prog, feed=feed, fetch_list=[outs["avg_cost"]],
+                    scope=scope)
+            for i in range(steps):
+                t0 = time.perf_counter()
+                loss = exe.run(main_prog, feed=feed,
+                               fetch_list=[outs["avg_cost"]],
+                               scope=scope)[0]
+                reporter(EndIteration(
+                    i, float(np.asarray(loss).ravel()[0]),
+                    time.perf_counter() - t0, dict(exe.last_step_cost),
+                    _TOY["batch"]))
+            return dict(exe.last_step_cost)
+    finally:
+        reporter.close()
+
+
+@pytest.fixture(scope="module")
+def closed_loop(tmp_path_factory):
+    """Two measured runs (t=128, t=64) -> corpus -> ``fit_and_save`` ->
+    a third run compiled AFTER the fit; every file under one temporary
+    directory, both singletons reset around it."""
+    tmp = tmp_path_factory.mktemp("costmodel_loop")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PADDLE_TPU_TUNE_CACHE", str(tmp / "tuned.json"))
+        mp.delenv("PADDLE_TPU_COSTMODEL_PATH", raising=False)
+        mp.delenv("PADDLE_TPU_COSTMODEL", raising=False)
+        tune.reset_cache()
+        cm.reset_model()
+        out = {"demo_base": tune.flagship_static_demo(), "mp": mp}
+        out["cost_a"] = _measured_run(128, 6, tmp / "a.jsonl", "loop-a")
+        _measured_run(64, 6, tmp / "b.jsonl", "loop-b")
+        co = Corpus()
+        out["ingested"] = (co.ingest_trainer_jsonl(tmp / "a.jsonl"),
+                           co.ingest_trainer_jsonl(tmp / "b.jsonl"))
+        out["corpus"] = co
+        out["entry"] = cm.fit_and_save(co).entry(cm.current_platform())
+        out["status"] = cm.model_status()
+        out["cost_c"] = _measured_run(64, 2, tmp / "c.jsonl", "loop-c")
+        out["rows_c"] = [json.loads(ln) for ln in
+                         (tmp / "c.jsonl").read_text().splitlines()
+                         if ln.strip()]
+        yield out
+    tune.reset_cache()
+    cm.reset_model()
+
+
+def test_reporter_jsonl_of_measured_runs_ingests_every_step(closed_loop):
+    co = closed_loop["corpus"]
+    assert closed_loop["ingested"] == (6, 6) and co.skipped == []
+    assert {r["platform"] for r in co.rows} == {cm.current_platform()}
+    assert {r["run_id"] for r in co.rows} == {"loop-a", "loop-b"}
+    # before any fit a compile says so: the field is never absent
+    assert closed_loop["cost_a"]["costmodel"] == {"mode": "analytic"}
+
+
+def test_fit_on_measured_rows_beats_the_analytic_roofline(closed_loop):
+    """Off the accelerator the analytic roofline is some 100x low; the
+    fitted per-step constant must close it on the held-out rows."""
+    e = closed_loop["entry"]
+    assert e is not None and e["train_rows"] >= 8
+    assert e["holdout_err_pct"] < e["analytic_err_pct"]
+    st = closed_loop["status"]
+    assert st["mode"] == "fitted" and st["train_rows"] == e["train_rows"]
+
+
+def test_compile_after_the_fit_records_it_in_cost_and_jsonl(closed_loop):
+    assert closed_loop["cost_c"]["costmodel"]["mode"] == "fitted"
+    steps = [r for r in closed_loop["rows_c"] if r.get("event") == "step"]
+    assert len(steps) == 2
+    assert all(r["costmodel"]["mode"] == "fitted" for r in steps)
+
+
+def test_flagship_prune_selects_the_same_schedule_under_the_fit(closed_loop):
+    """``predict_sched_ms`` is monotone in the flops, so a fit may move
+    estimates, never the order: the t=16k static prune still rejects the
+    round-5 configuration and picks the schedule it picked without a
+    model; with the kill switch the whole demonstration is the same."""
+    base = closed_loop["demo_base"]
+    fitted = tune.flagship_static_demo()
+    assert fitted["gpt_t16k_rejected_r05_config"] is not None
+    assert base["gpt_t16k_selected_policy"] is not None
+    for k in ("gpt_t16k_selected_policy", "gpt_t16k_selected_accum",
+              "gpt_t16k_selected_block_q", "gpt_t16k_selected_block_k"):
+        assert fitted[k] == base[k], k
+    mp = closed_loop["mp"]
+    mp.setenv("PADDLE_TPU_COSTMODEL", "0")
+    try:
+        assert tune.flagship_static_demo() == base
+        assert cm.model_status() == {"mode": "analytic"}
+    finally:
+        mp.delenv("PADDLE_TPU_COSTMODEL")

@@ -506,6 +506,48 @@ def test_zero3_rs_bitexact(case):
         assert np.array_equal(p1[k], p0[k]), k
 
 
+def test_fsdp_step_holds_its_comm_contracts_and_byte_bounds():
+    """What the compiled dp=2 x fsdp=4 step's PLAN and placement say,
+    apart from the bits (the bit-exactness tests above): the canned
+    contracts (in-loop fsdp weight gathers, no in-loop reduce, one
+    boundary gradient reduction) hold; the embedding, positional table
+    and LM head are sharded with their optimizer state (bytes a device
+    at most half the replicated figure); ``PADDLE_TPU_ZERO3_RS=0``
+    leaves the gradients replicated with no reduce-scatter; and
+    ``comm_diff`` names what the scatter moved."""
+    from paddle_tpu.analysis.comm import comm_diff
+    from paddle_tpu.parallel.contracts import (
+        fsdp_scan_contract, one_boundary_reduce_contract)
+
+    mesh = _mesh({"dp": 2, "fsdp": 4})
+    kw = dict(build_kwargs={"accum": 4}, steps=1, grad_fetch=False)
+    *_, plan1, _remat1, rep1, _s1, _m1, _t1, cp1 = _train(
+        mesh, "1", rs="1", **kw)
+    *_, rep0, _s0, _m0, _t0, cp0 = _train(mesh, "1", rs="0", **kw)
+
+    assert plan1["mode"] == "local"
+    assert fsdp_scan_contract(mesh).check(cp1) == []
+    assert one_boundary_reduce_contract(mesh).check(cp1) == []
+    assert cp1.select(kind="all-gather", axis="fsdp", in_loop=True)
+
+    prologue = ("tok_emb.w", "pos_emb.w.w", "lm_head.w")
+    pvars, ovars = rep1["params"]["vars"], rep1["opt_state"]["vars"]
+    held = [pvars[n] for n in prologue] + [
+        v for n in prologue for o, v in ovars.items() if n in o]
+    assert len(held) > len(prologue)  # the tables AND their moments
+    assert 2 * sum(v["per_device_bytes"] for v in held) <= sum(
+        v["bytes"] for v in held)
+
+    assert not cp0.select(kind="reduce-scatter")
+    assert rep0["grads"]["per_device_bytes"] == rep0["grads"]["total_bytes"]
+    assert rep1["grads"]["per_device_bytes"] < rep1["grads"]["total_bytes"]
+    d = comm_diff(cp0, cp1, name_a="replicated", name_b="zero3-rs")
+    assert "reduce-scatter" in {c["kind"] for c in d["changed"]}
+    ar_dp = [c for c in d["changed"] if c["kind"] == "all-reduce"
+             and c["axes"] == "dp" and c["phase"] == "boundary"]
+    assert ar_dp and ar_dp[0]["bytes_b"] < ar_dp[0]["bytes_a"], d["text"]
+
+
 def test_grad_rs_spec_for_rules(monkeypatch):
     """Rule-4 spec resolution: needs the kill switch on, a mesh with
     both dp>1 and fsdp axes, and an fsdp-tagged divisible shape."""

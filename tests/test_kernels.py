@@ -1244,10 +1244,12 @@ def test_selected_backends_recorded_per_compile():
         pt.core.scope._scope_stack.pop()
 
 
-def test_xla_ref_trainer_zero_pallas(monkeypatch):
-    """The acceptance bar at toy scale: env-routed xla_ref GPT training
-    step traces with zero pallas calls (the selftest covers all five
-    memory_optimize policies)."""
+@pytest.mark.parametrize("policy", [None, "selective", "offload",
+                                    "compact", "full"])
+def test_xla_ref_trainer_zero_pallas(monkeypatch, policy):
+    """The acceptance bar at toy scale: under every memory_optimize
+    policy an env-routed xla_ref GPT training step resolves both kernel
+    op classes to xla_ref and traces with zero pallas calls."""
     from paddle_tpu.analysis.jaxpr_tools import walk_report
     from paddle_tpu.models import transformer
 
@@ -1255,11 +1257,12 @@ def test_xla_ref_trainer_zero_pallas(monkeypatch):
     pt.core.unique_name.reset()
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
-        outs = transformer.build(vocab_size=64, n_layer=2, n_head=2,
+        outs = transformer.build(vocab_size=64, n_layer=3, n_head=2,
                                  d_model=32, max_len=16,
                                  dropout_rate=0.0, dtype="float32",
                                  fused_head=True)
-        pt.memory_optimize(main, policy="selective")
+        if policy:
+            pt.memory_optimize(main, policy=policy)
     scope = pt.core.scope.Scope()
     pt.core.scope._scope_stack.append(scope)
     try:
@@ -1269,6 +1272,8 @@ def test_xla_ref_trainer_zero_pallas(monkeypatch):
         loss = exe.run(main, feed={"tokens": toks, "labels": toks},
                        fetch_list=[outs["avg_cost"]], scope=scope)[0]
         assert np.isfinite(np.asarray(loss)).all()
+        kb = exe.last_step_cost["kernel_backends"]
+        assert kb["flash_attention"] == kb["fused_ce"] == "xla_ref"
         state_names = tuple(sorted(
             v.name for v in main.persistable_vars()
             if scope.find_var(v.name) is not None))

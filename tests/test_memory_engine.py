@@ -9,7 +9,7 @@ Pins the three coordinated pieces of the memory engine:
   traced training step keeps its flash ``pallas_call``s inside
   ``lax.scan`` bodies — no per-layer unrolled kernel calls, no pallas
   operand with a leading layer-count axis, and the optimized HLO is
-  free of the exact BENCH_r05 failure shape ``[L, t, d_model]``
+  free of the round-5 flagship's failure shape ``[L, t, d_model]``
   (checked via ``analysis.audit_program`` +
   ``compiled.memory_analysis()``, CPU-safe);
 - **policy="offload"**: marks selective segments plus the program
@@ -18,8 +18,8 @@ Pins the three coordinated pieces of the memory engine:
   switch.
 
 Plus the satellites: ``hbm_high_water_bytes``/``temp_bytes`` in
-``exe.last_step_cost`` and the registry, ``Executor.compile_only``
-preflight, and bench.py's allocator-failure fallback contract.
+``exe.last_step_cost`` and the registry, and the
+``Executor.compile_only`` preflight.
 """
 
 import os
@@ -126,7 +126,7 @@ def test_offload_kill_switch():
         np.testing.assert_array_equal(a, b)
 
 
-# -- backward-scan locality regression (the BENCH_r05 gate) -----------------
+# -- backward-scan locality regression --------------------------------------
 
 @pytest.mark.parametrize("policy",
                          ["selective", "compact", "full", "offload"])
@@ -135,7 +135,7 @@ def test_backward_scan_locality(policy):
     scan-local (at most one un-grouped layer's worth outside — NOT O(L)
     unrolled), no pallas operand/result carries a leading layer-count
     axis, the optimized HLO contains no ``[L, t, d_model]`` buffer (the
-    exact BENCH_r05 temp shape), the scan engine engaged without
+    temp shape that overflowed the round-5 flagship), the scan engine engaged without
     fallback, and memory_analysis reports real figures."""
     main, startup, loss = _build(policy)
     scope = pt.Scope()
@@ -162,7 +162,7 @@ def test_backward_scan_locality(policy):
 
 def test_scan_fallback_records_reason_and_strict_raises():
     """A group the engine cannot classify falls back WITH the reason in
-    the plan (no more silent fallbacks — BENCH_r05's failure class);
+    the plan (no silent fallbacks);
     PADDLE_TPU_SCAN_REMAT=strict turns that into a hard error."""
     main, startup, loss = _build("selective")
     # poison the cached group list with a malformed group so the scan
@@ -259,113 +259,3 @@ def test_compile_only_primes_run_cache():
     finally:
         pt.core.scope._scope_stack.pop()
 
-
-# -- bench flagship fallback (the BENCH_r05 contract) -----------------------
-
-_OOM_DUMP = """RESOURCE_EXHAUSTED: Out of memory while trying to allocate
-  1. Size: 144.00M
-     Operator: op_name="jit(step)/pallas_call"
-     Shape: bf16[6,16384,768]{2,1,0:T(8,128)(2,1)}
-     Allocation type: HLO temp
-  2. Size: 144.00M
-     Operator: op_name="jit(step)/pallas_call"
-     Shape: bf16[6,16384,768]{2,1,0}
-  3. Size: 100.00M
-     Operator: op_name="jit(step)/fusion"
-     Shape: f32[36,16384,1]{2,1,0}
-  4. Size: 90.00M
-     Operator: op_name="x"
-     Shape: bf16[6,16384,768]{2,1,0}
-  5. Size: 80.00M
-     Operator: op_name="y"
-     Shape: bf16[6,16384,768]{2,1,0}
-  6. Size: 70.00M
-     Operator: op_name="z"
-     Shape: bf16[6,16384,768]{2,1,0}
-"""
-
-
-def test_oom_summary_truncates_dump():
-    import bench
-
-    s = bench._oom_summary(_OOM_DUMP)
-    assert s.startswith("top5 temps:")
-    assert "144.00M bf16[6,16384,768]" in s
-    assert "70.00M" not in s  # only the top 5
-    assert len(s) <= 400
-    # arbitrary junk stays bounded too
-    assert len(bench._oom_summary("x" * 10000)) <= 300
-
-
-def test_bench_gpt_falls_back_to_smaller_t(monkeypatch):
-    """An allocator failure at the requested t records
-    gate_flagship_gpt in extra and retries at t/2 — a timed row still
-    ships (the BENCH_r05 'flagship line always prints' contract)."""
-    import bench
-
-    calls = []
-
-    def fake_at(seq, n_chips, mesh_factory, steps, warmup, extra):
-        calls.append(seq)
-        if seq > 8192:
-            raise MemoryError(_OOM_DUMP)
-        extra["gpt_hbm_high_water_bytes"] = 7 << 30
-        return 1234.0, 0.3, 1200.0, 1300.0
-
-    monkeypatch.setattr(bench, "_bench_gpt_at", fake_at)
-    monkeypatch.setenv("BENCH_GPT_SEQ", "16384")
-    extra = {}
-    out = bench.bench_gpt(1, lambda *a: None, 5, 1, extra=extra)
-    assert out[0] == 1234.0
-    assert calls == [16384, 8192]
-    assert extra["gpt_seq"] == 8192
-    assert extra["gpt_seq_fallback"] == 8192
-    assert extra["gate_flagship_gpt"].startswith(
-        "FAILED: RESOURCE_EXHAUSTED at t=16384")
-    assert "top" in extra["gate_flagship_gpt"]
-
-
-def test_bench_gpt_non_oom_errors_propagate(monkeypatch):
-    import bench
-
-    def fake_at(seq, *a):
-        raise ValueError("shape mismatch")
-
-    monkeypatch.setattr(bench, "_bench_gpt_at", fake_at)
-    monkeypatch.setenv("BENCH_GPT_SEQ", "16384")
-    with pytest.raises(ValueError):
-        bench.bench_gpt(1, lambda *a: None, 5, 1, extra={})
-
-
-def test_bench_flagship_gate_failure_flips_rc(monkeypatch, capsys):
-    """A flagship section that fell back still prints the JSON row with
-    its numbers, but the recorded gate_flagship_gpt flips the rc."""
-    import json
-
-    import bench
-
-    class _FakeDev:
-        platform = "tpu"
-
-    monkeypatch.setattr(bench, "detect_devices", lambda: [_FakeDev()])
-    monkeypatch.setattr(bench, "bench_resnet",
-                        lambda *a, **k: (100.0, 90.0, 110.0))
-
-    def fake_gpt(n_chips, mesh_factory, steps, warmup, extra=None):
-        extra["gate_flagship_gpt"] = "FAILED: RESOURCE_EXHAUSTED at t=16384"
-        extra["gpt_seq"] = 8192
-        return 1000.0, 0.31, 900.0, 1100.0
-
-    monkeypatch.setattr(bench, "bench_gpt", fake_gpt)
-    monkeypatch.setattr(bench, "_gate_flash", lambda: {})
-    monkeypatch.setattr(bench, "grad_numeric_gates", lambda: {})
-    monkeypatch.setattr(bench, "_gate_mem", lambda: {})
-    monkeypatch.setenv("BENCH_MODELS", "resnet,gpt")
-    monkeypatch.delenv("BENCH_SMOKE", raising=False)
-    monkeypatch.delenv("BENCH_INFER", raising=False)
-    rc = bench.main()
-    row = json.loads(capsys.readouterr().out.strip())
-    assert rc != 0
-    assert row["value"] == 100.0
-    assert row["extra"]["gpt_mfu"] == 0.31
-    assert row["extra"]["gate_flagship_gpt"].startswith("FAILED")

@@ -2,10 +2,13 @@
 retry/backoff, fault injection, watchdog supervision, the resumable
 reader, full-state checkpoint discovery, the AsyncCheckpointer's
 crashed-publish recovery branches, and trainer kill-and-resume
-bit-exactness (in-process; the subprocess SIGKILL variant is
-``python -m paddle_tpu --resilience-selftest``)."""
+bit-exactness, in-process and across real SIGKILLs of child processes
+(``tests/resilience_children.py``)."""
 
 import os
+import signal
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -480,8 +483,8 @@ def _small_model_and_losses(tmp_path, monkeypatch, fault=None,
 def test_trainer_kill_and_resume_bit_exact(tmp_path, monkeypatch):
     """Full-state step checkpoints + resume reproduce the uninterrupted
     trajectory bit-for-bit: params, optimizer moments, RNG key (dropout
-    masks!) and reader cursor all restored.  The SIGKILL subprocess
-    variant on the 8-device mesh is the --resilience-selftest gate."""
+    masks!) and reader cursor all restored.  The SIGKILL variant on the
+    8-device mesh is ``test_sigkilled_trainer_resumes_bit_exact``."""
     ref = _small_model_and_losses(tmp_path / "ref", monkeypatch)
     assert len(ref["costs"]) == 8 and ref["error"] is None
     part = _small_model_and_losses(tmp_path / "run", monkeypatch,
@@ -698,3 +701,95 @@ def test_reporter_jsonl_carries_resilience_fields(tmp_path, monkeypatch):
         assert k in last, f"missing {k}: {sorted(last)}"
     assert last["checkpoint_saves"] >= 1
     assert last["checkpoint_bytes"] > 0
+
+
+# ------------------------------------------- real kills, one process a mode
+
+_CHILDREN = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "resilience_children.py")
+
+
+def _run_child(mode, workdir, fault=None):
+    """One fresh process of ``resilience_children.py``: eight virtual CPU
+    devices, one summation order (bit-exactness across processes), the
+    fault armed through the environment.  Returns (rc, output)."""
+    env = dict(os.environ)
+    env.pop("PYTHONSAFEPATH", None)
+    env.pop(rfaults.ENV_VAR, None)
+    if fault:
+        env[rfaults.ENV_VAR] = fault
+    env["JAX_PLATFORMS"] = "cpu"
+    flags = [f for f in env.get("XLA_FLAGS", "").split()
+             if not f.startswith("--xla_force_host_platform_device_count")
+             and f != "--xla_cpu_multi_thread_eigen=false"]
+    env["XLA_FLAGS"] = " ".join(flags + [
+        "--xla_force_host_platform_device_count=8",
+        "--xla_cpu_multi_thread_eigen=false"])
+    env["OMP_NUM_THREADS"] = "1"
+    repo = os.path.dirname(os.path.dirname(_CHILDREN))
+    env["PYTHONPATH"] = os.pathsep.join(
+        [repo] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    proc = subprocess.run(
+        [sys.executable, _CHILDREN, mode, str(workdir)], env=env,
+        timeout=600, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+        text=True)
+    return proc.returncode, proc.stdout
+
+
+def _losses(workdir, mode):
+    with open(os.path.join(str(workdir), f"losses_{mode}.txt")) as f:
+        return [line.strip() for line in f if line.strip()]
+
+
+def _printed(out, key):
+    vals = [ln.split()[1] for ln in out.splitlines()
+            if ln.startswith(key + " ")]
+    return vals[-1] if vals else None
+
+
+def test_sigkilled_trainer_resumes_bit_exact(tmp_path):
+    """A dp=8 trainer SIGKILLed mid-pass (no unwinding, the async
+    checkpoint writer dead mid-queue) leaves a bit-exact prefix of the
+    uninterrupted trajectory; the same command with ``resume=True``
+    finds the latest LOADABLE step checkpoint and reproduces the rest of
+    the trajectory bit for bit (losses compared as ``float.hex()``)."""
+    import shutil
+
+    import resilience_children as rc_
+
+    rc, out = _run_child("ref", tmp_path)
+    assert rc == 0, out
+    ref = _losses(tmp_path, "ref")
+    assert len(ref) == rc_.PASSES * rc_.STEPS_PER_PASS
+
+    shutil.rmtree(tmp_path / "ckpt", ignore_errors=True)
+    rc, out = _run_child("crash", tmp_path, fault=f"sigkill:{rc_.KILL_AT}")
+    assert rc == -signal.SIGKILL, out
+    crash = _losses(tmp_path, "crash")
+    assert len(crash) == rc_.KILL_AT - 1
+    assert crash == ref[:len(crash)]
+
+    rc, out = _run_child("resume", tmp_path)
+    assert rc == 0, out
+    resumed_at = int(_printed(out, "RESUMED_AT"))
+    assert rc_.CKPT_EVERY <= resumed_at < rc_.KILL_AT
+    assert _losses(tmp_path, "resume") == ref[resumed_at:]
+
+
+def test_crash_between_publish_renames_falls_back_to_old(tmp_path):
+    """A writer killed BETWEEN the two renames of its second publish
+    (exit code 23) leaves ``latest.old`` as the only complete copy; a
+    fresh process loading ``latest`` gets the state of the last GOOD
+    checkpoint, digest for digest, and its train-state sidecar."""
+    rc, out = _run_child("ckptcrash", tmp_path, fault="ckpt_crash:2")
+    assert rc == 23, out
+    good = _printed(out, "CKPT1_DIGEST")
+    assert good is not None
+    latest = str(tmp_path / "latest")
+    assert not os.path.exists(os.path.join(latest, "__manifest__.pkl"))
+    assert os.path.exists(os.path.join(latest + ".old", "__manifest__.pkl"))
+
+    rc, out = _run_child("ckptverify", tmp_path)
+    assert rc == 0, out
+    assert _printed(out, "RESTORED_DIGEST") == good
+    assert _printed(out, "RESTORED_STEP") == "1"

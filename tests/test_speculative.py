@@ -132,6 +132,24 @@ def test_adversarial_draft_stays_exact(params):
     assert eng.stats()["serving.spec_rollback_blocks"] > 0
 
 
+def test_self_draft_accepts_nearly_every_proposal(params):
+    """The draft IS the target: every proposal the parallel verify
+    window judges should be the token the sequential step would have
+    made, so any numeric drift between the two shows up here as
+    spurious rejections (measured 1.000; the bar leaves room for a
+    near-tie)."""
+    rng = np.random.default_rng(16)
+    prompts = _prompts(rng, 6)
+    eng = _engine(params, prefix_reuse=False, draft_params=params,
+                  spec_k=4)
+    outs = eng.generate_many(prompts, max_new_tokens=12)
+    for o, ref in zip(outs, _refs(params, prompts, 12)):
+        np.testing.assert_array_equal(o, ref)
+    sp = eng._spec
+    assert sp.proposed > 0
+    assert sp.accepted / sp.proposed >= 0.8
+
+
 # -- construction-time geometry validation -----------------------------------
 
 def test_geometry_mismatches_rejected(params):

@@ -1,19 +1,17 @@
 """End-to-end tracing engine (observability/trace.py) — span runtime
 semantics, disabled-mode overhead path, Chrome-trace export, trainer
-step-phase spans, serving request span trees, the bench-history
-regression gate (observability/bench_history.py), and the satellite
+step-phase spans, serving request span trees, and the satellite
 instrumentation (print_profiler JSONL fold-in, nan_guard trip
-accounting, bench row stamps)."""
+accounting, the run identity stamp)."""
 
 import json
-import os
 
 import numpy as np
 import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.models import transformer
-from paddle_tpu.observability import bench_history, get_registry, trace
+from paddle_tpu.observability import get_registry, runlog, trace
 from paddle_tpu.observability.runlog import RunLog, read_jsonl
 from paddle_tpu.serving import ServingEngine
 
@@ -375,123 +373,15 @@ def test_serving_ttft_decomposition(tracer):
     assert ttft <= req.e2e + 1e-6
 
 
-# -- bench history ----------------------------------------------------------
-def _write(d, name, data):
-    with open(os.path.join(str(d), name), "w") as fh:
-        json.dump(data, fh)
-
-
-def _fixture(tmp_path):
-    _write(tmp_path, "BENCH_r01.json",
-           {"n": 1, "rc": 0, "parsed": {"metric": "m", "value": 100.0}})
-    _write(tmp_path, "BENCH_r02.json",
-           {"n": 2, "rc": 0, "parsed": {"metric": "m", "value": 104.0,
-                                        "run_id": "abc", "git_sha": "d"}})
-    _write(tmp_path, "BENCH_r03.json",
-           {"n": 3, "rc": 0, "parsed": {"metric": "m", "value": 42.0}})
-    _write(tmp_path, "BENCH_r04.json",
-           {"n": 4, "rc": 1, "parsed": None})
-    _write(tmp_path, "MULTICHIP_r01.json",
-           {"n_devices": 8, "rc": 0, "ok": True})
-
-
-def test_bench_history_classifies_failed_and_flags_regression(tmp_path):
-    _fixture(tmp_path)
-    summary, rows = bench_history.history(str(tmp_path), threshold=0.1)
-    assert summary["artifacts"] == 5
-    assert summary["failed"] == ["BENCH_r04.json"]
-    assert "rc=1" in summary["failed_reasons"]["BENCH_r04.json"][0] or \
-        any("rc=1" in r for r in summary["failed_reasons"]["BENCH_r04.json"])
-    regs = summary["regressions"]
-    assert len(regs) == 1
-    assert regs[0]["artifact"] == "BENCH_r03.json"
-    assert regs[0]["best"] == 104.0 and regs[0]["value"] == 42.0
-    assert not summary["ok"]
-    # row identity stamps surface in the classification
-    r02 = next(r for r in rows if r["artifact"] == "BENCH_r02.json")
-    assert r02["run_id"] == "abc" and r02["git_sha"] == "d"
-    # small dips below the threshold do NOT flag
-    summary2, _ = bench_history.history(str(tmp_path), threshold=0.7)
-    assert summary2["regressions"] == []
-
-
-def test_bench_history_acknowledged_failures_pass_the_gate(tmp_path):
-    _fixture(tmp_path)
-    # acks are scoped: failures by artifact name, regressions by
-    # artifact:metric — a failure ack must not cover a regression
-    known = {"BENCH_r04.json": "known OOM", "BENCH_r03.json:m": "known dip"}
-    summary, _ = bench_history.history(str(tmp_path), threshold=0.1,
-                                       known_failures=known)
-    assert summary["failed"] == ["BENCH_r04.json"]  # still classified
-    assert len(summary["regressions"]) == 1         # still flagged
-    assert set(summary["acknowledged"]) == {"BENCH_r03.json:m",
-                                            "BENCH_r04.json"}
-    assert summary["ok"]  # ...but the gate passes
-    # a bare-artifact ack does NOT green-light the regression
-    summary2, _ = bench_history.history(
-        str(tmp_path), threshold=0.1,
-        known_failures={"BENCH_r04.json": "known OOM",
-                        "BENCH_r03.json": "stale failure ack"})
-    assert not summary2["ok"]
-
-
-def test_bench_history_regression_exempt_metrics(tmp_path):
-    """Virtual-CPU-mesh scaling_efficiency is indicative only (shared
-    host cores): it shows in the trajectory but never flags."""
-    _write(tmp_path, "MULTICHIP_r01.json",
-           {"n_devices": 8, "rc": 0, "ok": True,
-            "tail": json.dumps({"metric": "multichip_scaling",
-                                "scaling_efficiency": 0.9})})
-    _write(tmp_path, "MULTICHIP_r02.json",
-           {"n_devices": 8, "rc": 0, "ok": True,
-            "tail": json.dumps({"metric": "multichip_scaling",
-                                "scaling_efficiency": 0.2})})  # 78% drop
-    summary, rows = bench_history.history(str(tmp_path), threshold=0.1)
-    assert [r["metrics"] for r in rows] == [
-        {"scaling_efficiency": 0.9}, {"scaling_efficiency": 0.2}]
-    assert "scaling_efficiency" in summary["metrics_tracked"]
-    assert summary["regressions"] == [] and summary["ok"]
-
-
-def test_bench_history_missing_row_keys(tmp_path):
-    _write(tmp_path, "BENCH_r01.json",
-           {"n": 1, "rc": 0, "parsed": {"unit": "img/s"}})
-    summary, rows = bench_history.history(str(tmp_path))
-    assert summary["failed"] == ["BENCH_r01.json"]
-    reasons = " ".join(rows[0]["reasons"])
-    assert "metric" in reasons and "value" in reasons
-
-
-def test_bench_history_non_object_artifact_classifies(tmp_path):
-    """Valid JSON that is not an object (truncated/corrupt write) is a
-    classified rot class, not a gate crash."""
-    (tmp_path / "BENCH_r03.json").write_text("[1, 2]")
-    summary, rows = bench_history.history(str(tmp_path))
-    assert summary["failed"] == ["BENCH_r03.json"]
-    assert rows[0]["round"] == 3
-    assert "not a JSON object" in rows[0]["reasons"][0]
-
-
-def test_repo_artifacts_pass_the_acknowledged_gate():
-    """The tier-1 contract: the REAL repo trajectory passes with the
-    checked-in known-failures file (BENCH_r05 / MULTICHIP_r01 are
-    root-caused and acknowledged, not silently green)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "tools",
-                           "bench_known_failures.json")) as fh:
-        known = json.load(fh)
-    summary, _ = bench_history.history(root, known_failures=known)
-    assert "BENCH_r05.json" in summary["failed"]
-    assert summary["ok"], summary
-
-
+# -- run identity -----------------------------------------------------------
 def test_run_stamp_fields():
-    s = bench_history.run_stamp()
-    assert s["schema_version"] == bench_history.SCHEMA_VERSION == 1
+    s = runlog.run_stamp()
+    assert s["schema_version"] == runlog.SCHEMA_VERSION == 1
     assert len(s["run_id"]) == 12
     # inside this checkout the sha resolves; elsewhere it may be None
     assert s["git_sha"] is None or len(s["git_sha"]) == 12
-    assert s["run_id"] != bench_history.run_stamp()["run_id"]
+    assert s["run_id"] != runlog.run_stamp()["run_id"]
+    assert pt.observability.run_stamp is runlog.run_stamp
 
 
 # -- satellites -------------------------------------------------------------

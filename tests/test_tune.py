@@ -1,11 +1,10 @@
 """Autotune engine tests (ISSUE 9): cache robustness (corrupt /
 truncated / schema-version mismatch / stale kernel-geometry
 fingerprint must each fall back to defaults and re-tune, never crash
-or serve a wrong config), the candidate space + static pruning, the
-hot-path wiring, and the bench-history un-ack logic."""
+or serve a wrong config), the candidate space + static pruning, and
+the hot-path wiring."""
 
 import json
-import os
 
 import numpy as np
 import pytest
@@ -164,9 +163,9 @@ def test_prune_static_roofline_and_vmem():
 
 def test_hbm_model_ordering_matches_measured_reality():
     """The analytic bound must reproduce the measured t=16k facts:
-    selective/offload at accum=1 exceed the 15.75 GiB chip (BENCH_r05),
-    while accum2-no-remat, offload+accum2 and bs6 full-remat fit
-    (bench.py memory_gate)."""
+    selective/offload at accum=1 exceed the 15.75 GiB chip (the round-5
+    flagship's allocator failure), while accum2-no-remat, offload+accum2
+    and bs6 full-remat fit (measured on the chip in round 5)."""
     G = 1 << 30
     est = lambda pol, acc: tspace.estimate_gpt_step_hbm(
         12, 768, 6, 32768, 16384, 6, policy=pol, accum=acc)
@@ -328,135 +327,129 @@ def test_tuned_diag_w_applied_and_env_pin_wins(tmp_cache, monkeypatch):
     assert pa.DIAG_W == 256  # env-pinned: the cache may not move it
 
 
-# -- bench-history: the t16k un-ack machinery ----------------------------
 
-def _write_artifact(d, name, data):
-    with open(os.path.join(d, name), "w") as fh:
-        json.dump(data, fh)
+# -- a measured search, end to end (toy GPT, real compiles) ----------------
 
-
-def test_bench_history_t16k_evidence_resolves_failure(tmp_path):
-    from paddle_tpu.observability import bench_history as bh
-
-    _write_artifact(tmp_path, "BENCH_r05.json", {
-        "n": 5, "rc": 1, "parsed": None,
-        "tail": "Shape: bf16[6,16384,768]... RESOURCE_EXHAUSTED"})
-    _write_artifact(tmp_path, "BENCH_r06.json", {
-        "n": 6, "rc": 0, "parsed": {
-            "metric": "smoke_train_images_per_sec", "value": 900.0,
-            "unit": "img/s",
-            "extra": {"gpt_t16k_selected_policy": "offload",
-                      "gpt_t16k_static_only": True}}})
-    summary, rows = bh.history(str(tmp_path))
-    assert summary["ok"] is True
-    assert "BENCH_r05.json" in summary["resolved"]
-    assert summary["failed"] == ["BENCH_r05.json"]
-    # a stale ack for the resolved artifact flags as a warning, not rot
-    summary2, _ = bh.history(str(tmp_path),
-                             known_failures={"BENCH_r05.json": "old"})
-    assert summary2["ok"] is True
-    assert summary2["stale_acks"] == ["BENCH_r05.json"]
+_TOY = dict(seq_len=128, n_layer=3, d_model=64, n_head=2, vocab=61,
+            batch=8, dtype="float32", fused_head=True)
+_SEARCH = dict(steps=2, warmup=1, repeats=2, block_caps=(64,),
+               diag_ws=(64,), accums=(1,), max_measure=8)
 
 
-def test_bench_history_failure_without_evidence_still_fails(tmp_path):
-    from paddle_tpu.observability import bench_history as bh
+@pytest.fixture(scope="module")
+def searched(tmp_path_factory):
+    """ONE search over the remat policies of the toy GPT, its winner
+    persisted to a cache file of this module's own; yields (report,
+    cache path, MonkeyPatch) with PADDLE_TPU_TUNE=cached left set."""
+    from paddle_tpu.ops import pallas_attention as pa
 
-    _write_artifact(tmp_path, "BENCH_r05.json", {
-        "n": 5, "rc": 1, "parsed": None,
-        "tail": "Shape: bf16[6,16384,768] Allocation type: HLO temp"})
-    summary, _ = bh.history(str(tmp_path))
-    assert summary["ok"] is False  # no evidence round -> ack required
-    # evidence in an EARLIER round does not resolve a later failure
-    _write_artifact(tmp_path, "BENCH_r04.json", {
-        "n": 4, "rc": 0, "parsed": {
-            "metric": "m", "value": 1.0,
-            "extra": {"gpt_t16k_selected_policy": "offload"}}})
-    summary, _ = bh.history(str(tmp_path))
-    assert summary["ok"] is False
-    # a t=16384 mention WITHOUT an allocator signature is NOT the rot
-    # class — a future unrelated t=16k failure must not auto-resolve
-    _write_artifact(tmp_path, "BENCH_r05.json", {
-        "n": 5, "rc": 1, "parsed": None,
-        "tail": "driver crash at step 16384"})
-    summary, _ = bh.history(str(tmp_path))
-    assert summary["ok"] is False
-    _write_artifact(tmp_path, "BENCH_r05.json", {
-        "n": 5, "rc": 1, "parsed": None,
-        "tail": "Shape: bf16[6,16384,768] Allocation type: HLO temp"})
-    # a non-t16k failure class is never evidence-resolved
-    _write_artifact(tmp_path, "BENCH_r06.json", {
-        "n": 6, "rc": 0, "parsed": {
-            "metric": "m", "value": 1.0,
-            "extra": {"gpt_t16k_selected_policy": "offload"}}})
-    _write_artifact(tmp_path, "BENCH_r07.json", {
-        "n": 7, "rc": 1, "parsed": None, "tail": "segfault"})
-    summary, _ = bh.history(str(tmp_path))
-    assert "BENCH_r07.json" not in summary["resolved"]
-    assert summary["ok"] is False
+    path = tmp_path_factory.mktemp("tune") / "tuned.json"
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pa, "DIAG_W", pa.DIAG_W)
+        mp.setenv("PADDLE_TPU_TUNE_CACHE", str(path))
+        mp.setenv("PADDLE_TPU_TUNE", "search")
+        tune.reset_cache()
+        rep = tune.tune_gpt_step(
+            **_TOY, **_SEARCH,
+            policies=("none", "selective", "compact", "full"))
+        mp.setenv("PADDLE_TPU_TUNE", "cached")
+        yield rep, path, mp
+    tune.reset_cache()
 
 
-def test_bench_history_rung_metric_flags_fallback_row(tmp_path):
-    """A t/2 fallback row halves gate_flagship_gpt_seq — the regression
-    flagging catches it (the satellite: a fallback row can never
-    impersonate a true t=16k row)."""
-    from paddle_tpu.observability import bench_history as bh
-
-    _write_artifact(tmp_path, "BENCH_r06.json", {
-        "n": 6, "rc": 0, "parsed": {
-            "metric": "m", "value": 1.0,
-            "extra": {"gate_flagship_gpt_seq": 16384}}})
-    _write_artifact(tmp_path, "BENCH_r07.json", {
-        "n": 7, "rc": 0, "parsed": {
-            "metric": "m", "value": 1.0,
-            "extra": {"gate_flagship_gpt_seq": 8192}}})
-    summary, _ = bh.history(str(tmp_path))
-    regs = [r for r in summary["regressions"]
-            if r["metric"] == "gate_flagship_gpt_seq"]
-    assert regs and regs[0]["artifact"] == "BENCH_r07.json"
-    assert summary["ok"] is False
+def test_search_measures_and_its_winner_beats_the_worst(searched):
+    rep, path, _mp = searched
+    assert rep["source"] == "search" and rep["entry"] is not None
+    measured = [m for m in rep["measured"] if m["verdict"] == "measured"]
+    assert len(measured) >= 2
+    meas = rep["entry"]["measured"]
+    assert meas["median_s"] < meas["worst_median_s"]
+    assert path.exists()
 
 
-def test_bench_history_regression_ack_not_stale_while_flagged(tmp_path):
-    """An 'artifact:metric' ack for a STILL-FLAGGED regression on an
-    otherwise-ok artifact is the normal state — it must not report as
-    stale (following a bogus delete-me warning would break the gate)."""
-    from paddle_tpu.observability import bench_history as bh
+def test_second_invocation_is_a_cache_hit_that_compiles_nothing(searched):
+    from paddle_tpu.observability import get_registry
 
-    _write_artifact(tmp_path, "BENCH_r01.json", {
-        "n": 1, "rc": 0,
-        "parsed": {"metric": "m", "value": 100.0, "unit": "u"}})
-    _write_artifact(tmp_path, "BENCH_r02.json", {
-        "n": 2, "rc": 0,
-        "parsed": {"metric": "m", "value": 50.0, "unit": "u"}})
-    known = {"BENCH_r02.json:m": "known dip, root-caused"}
-    summary, _ = bh.history(str(tmp_path), known_failures=known)
-    assert summary["ok"] is True
-    assert summary["stale_acks"] == []
-    # once the regression heals (value recovers), the ack IS stale
-    _write_artifact(tmp_path, "BENCH_r03.json", {
-        "n": 3, "rc": 0,
-        "parsed": {"metric": "m", "value": 101.0, "unit": "u"}})
-    _write_artifact(tmp_path, "BENCH_r02.json", {
-        "n": 2, "rc": 0,
-        "parsed": {"metric": "m", "value": 99.0, "unit": "u"}})
-    summary, _ = bh.history(str(tmp_path), known_failures=known)
-    assert summary["stale_acks"] == ["BENCH_r02.json:m"]
+    rep, _path, _mp = searched
+    reg = get_registry()
+    c0 = reg.value("executor.compile_count")
+    h0 = reg.value("tune.cache_hits")
+    again = tune.tune_gpt_step(**_TOY)
+    assert again["source"] == "cache"
+    assert again["entry"]["config"] == rep["entry"]["config"]
+    assert reg.value("executor.compile_count") == c0
+    assert reg.value("tune.cache_hits") > h0
 
 
-def test_bench_history_resnet_regression_exempt(tmp_path):
-    """The r04 ResNet dip class (shared-runner noise) is exempt with a
-    recorded reason — it shows in the trajectory, never flags."""
-    from paddle_tpu.observability import bench_history as bh
+def test_preflight_rejects_a_compiled_step_over_budget_before_it_runs():
+    """The search's second gate after the static prune: a candidate
+    whose COMPILED high-water exceeds the budget raises before a step
+    executes (on the CPU every policy of the toy compiles to the same
+    2 MB, under its analytic estimate, so no budget reaches this gate
+    through ``tune_gpt_step``: the candidate is measured directly)."""
+    from paddle_tpu.observability import get_registry
+    from paddle_tpu.tune import search
 
-    m = "resnet50_train_images_per_sec_per_chip"
-    assert m in bh._REGRESSION_EXEMPT
-    assert "noise" in bh._REGRESSION_EXEMPT[m]
-    _write_artifact(tmp_path, "BENCH_r01.json", {
-        "n": 1, "rc": 0,
-        "parsed": {"metric": m, "value": 2403.0, "unit": "img/s"}})
-    _write_artifact(tmp_path, "BENCH_r02.json", {
-        "n": 2, "rc": 0,
-        "parsed": {"metric": m, "value": 1500.0, "unit": "img/s"}})
-    summary, _ = bh.history(str(tmp_path))
-    assert summary["regressions"] == [] and summary["ok"] is True
-    assert m in summary["metrics_tracked"]
+    cand = {"block_q": 64, "block_k": 64, "policy": "none", "accum": 1}
+    steps = get_registry().get("executor.run_seconds")
+    n0 = steps.count if steps is not None else 0
+    with pytest.raises(search.PreflightRejected, match="high-water"):
+        search._measure_candidate(
+            cand, **_TOY, steps=1, warmup=0, repeats=1, budget_bytes=4096,
+            learning_rate=1e-3)
+    steps = get_registry().get("executor.run_seconds")
+    # the startup program ran; the training step did not
+    assert (steps.count if steps is not None else 0) <= n0 + 1
+
+
+def _toy_loss_bits(steps=3):
+    """The toy GPT's loss trajectory under ``memory_optimize('auto')``
+    as float bit patterns, and the executor that made it."""
+    from paddle_tpu.models import transformer
+
+    pt.core.unique_name.reset()
+    main_prog, startup = pt.Program(), pt.Program()
+    main_prog.random_seed = 7
+    with pt.program_guard(main_prog, startup):
+        outs = transformer.build(
+            vocab_size=_TOY["vocab"], n_layer=_TOY["n_layer"],
+            n_head=_TOY["n_head"], d_model=_TOY["d_model"],
+            max_len=_TOY["seq_len"], dropout_rate=0.0,
+            dtype=_TOY["dtype"], fused_head=True)
+        pt.memory_optimize(main_prog, policy="auto")
+    rng = np.random.default_rng(5)
+    toks = rng.integers(0, _TOY["vocab"],
+                        (_TOY["batch"], _TOY["seq_len"])).astype(np.int64)
+    feed = {"tokens": toks, "labels": np.roll(toks, -1, axis=1)}
+    scope = pt.core.scope.Scope()
+    with pt.core.scope.scope_guard(scope):
+        exe = pt.Executor()
+        exe.run(startup, scope=scope)
+        bits = [np.asarray(exe.run(main_prog, feed=feed,
+                                   fetch_list=[outs["avg_cost"]],
+                                   scope=scope)[0], np.float32).tobytes()
+                for _ in range(steps)]
+    return bits, exe
+
+
+def test_kill_switch_trains_bit_exact_to_never_tuned(searched, tmp_path):
+    """PADDLE_TPU_TUNE=0 over a POPULATED cache gives the loss bits of a
+    run that never had a cache; the tuned run consults it."""
+    _rep, path, mp = searched
+    mp.setenv("PADDLE_TPU_TUNE", "0")
+    try:
+        off, exe_off = _toy_loss_bits()
+    finally:
+        mp.setenv("PADDLE_TPU_TUNE", "cached")
+    assert (exe_off.last_step_cost.get("tune") or {}).get("mode") in (
+        None, "off")
+    mp.setenv("PADDLE_TPU_TUNE_CACHE", str(tmp_path / "none" / "tuned.json"))
+    tune.reset_cache()
+    try:
+        never, _exe = _toy_loss_bits()
+    finally:
+        mp.setenv("PADDLE_TPU_TUNE_CACHE", str(path))
+        tune.reset_cache()
+    assert off == never
+    _bits, exe_tuned = _toy_loss_bits(steps=1)
+    assert exe_tuned.last_step_cost["tune"]["cache_hits"] > 0
