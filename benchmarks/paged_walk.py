@@ -37,6 +37,18 @@ an 8,300-token chain BOTH ways: absorbed (the queries against the
 gathered latents, what ``serving.arch.LatentMoE`` runs) and expanded
 (the gathered latents through ``W_kvb`` to per-head K and V first).
 
+``--only mixed`` (or any ``long_reason_*`` name) times the two kinds of
+plane of ``mimo25.long_reason`` (``serving.arch.SinkWindowMoE``: 24 slots
+x 416 entries, ``[blocks, 32, 8, 192|128]``, the key stored at 256
+lanes): the full plane (4 K/V heads in the 8 rows ``pool_rows`` gives,
+16 query heads a K/V head) and the window-128 plane with its sink (8 K/V
+heads, 8 query heads each; the table's entries under a slot's window are
+the trash block, as the engine's window chains leave them), against the
+PUBLISHED bytes (``chipbench/mixed_kv_bytes.py``: 2,560 and 5,120 B a
+position); and one layer's attention of a 512-row prefill piece at the
+end of a 9,000-token chain, both kinds (the dense spelling one K/V head
+at a time).
+
 Refuses unless JAX finds a TPU: a number from a CPU run is no device
 metric.
 """
@@ -120,6 +132,24 @@ LATENT = {f"latent_doc_qa_decode_{n}_a_copy": dict(
 PIECES = {"latent_doc_qa_piece_absorbed": "absorbed",
           "latent_doc_qa_piece_expanded": "expanded"}
 SCALE = 0.11472
+
+# the planes of mimo25.long_reason by kind: 10 of 24 slots live at
+# contexts the cell's traffic reaches; `hk` K/V heads in `rows` pool rows
+MIXED = {
+    "long_reason_full": dict(S=24, W=1, NB=416, blocks=9985, rows=8, hk=4,
+                             group=16, window=None, sink=False, live=10,
+                             ctx=(3000, 12000), kind="full"),
+    "long_reason_window": dict(S=24, W=1, NB=416, blocks=505, rows=8, hk=8,
+                               group=8, window=128, sink=True, live=10,
+                               ctx=(3000, 12000), kind="window"),
+    "long_reason_full_piece": dict(S=1, W=512, NB=416, blocks=9985, rows=8,
+                                   hk=4, group=16, window=None, sink=False,
+                                   live=1, ctx=(9000, 9000), kind="full"),
+    "long_reason_window_piece": dict(S=1, W=512, NB=416, blocks=505, rows=8,
+                                     hk=8, group=8, window=128, sink=True,
+                                     live=1, ctx=(9000, 9000),
+                                     kind="window"),
+}
 
 
 def _config(name):
@@ -282,6 +312,88 @@ def measure_latent(name, calls, peak, seed):
         "rel_err_vs_xla_ref": err}
 
 
+def measure_mixed(name, calls, peak, seed):
+    """One paged call on a plane of ``mimo25.long_reason`` (decode: the
+    Mosaic kernel against the block-scan oracle) or one layer's dense
+    attention of a 512-row piece (``attend``: device busy seconds)."""
+    import jax
+    import jax.numpy as jnp
+
+    import numpy as np
+
+    from chipbench import mixed_kv_bytes
+    from paddle_tpu.kernels import paged_attention as pa
+
+    g = MIXED[name]
+    S, W, NB, B = g["S"], g["W"], g["NB"], BLOCK_TOKENS
+    cfg = _config("mimo-v2.5")
+    rng = np.random.default_rng(seed)
+    pool_k = np.zeros((g["blocks"], B, g["rows"], 256), np.float32)
+    pool_k[:, :, :g["hk"], :192] = rng.standard_normal(
+        (g["blocks"], B, g["hk"], 192), np.float32) * 0.5
+    pool_v = np.zeros((g["blocks"], B, g["rows"], 128), np.float32)
+    pool_v[:, :, :g["hk"]] = rng.standard_normal(
+        (g["blocks"], B, g["hk"], 128), np.float32) * 0.5
+    q = rng.standard_normal((S, W, 64, 192), np.float32) * 0.5
+    sink = (jnp.asarray(rng.uniform(4.0, 6.5, 64), jnp.float32)
+            if g["sink"] else None)
+    table = np.zeros((S, NB), np.int32)
+    pos = np.full((S, W), -1, np.int32)
+    free = rng.permutation(np.arange(1, g["blocks"]))
+    contexts, live_blocks = [], 0
+    for s in rng.choice(S, g["live"], replace=False):
+        ctx = int(rng.integers(g["ctx"][0], g["ctx"][1] + 1))
+        pos[s] = ctx - W + np.arange(W)
+        lo = (0 if g["window"] is None
+              else max(int(pos[s, 0]) - g["window"] + 1, 0) // B)
+        n = (ctx - 1) // B + 1
+        table[s, lo:n], free = free[:n - lo], free[n - lo:]
+        live_blocks += n - lo
+        contexts += [int(at) + 1 for at in pos[s]]
+    how = dict(group=g["group"], window=g["window"], sink=sink)
+    args = (jnp.asarray(q, jnp.bfloat16), jnp.asarray(pool_k, jnp.bfloat16),
+            jnp.asarray(pool_v, jnp.bfloat16), jnp.asarray(table),
+            jnp.asarray(pos))
+    least = sum(max(nbytes / peak["hbm_bytes_per_s"],
+                    ops / peak["bf16_flops_per_s"])
+                for ops, nbytes in (mixed_kv_bytes.paged_call(
+                    cfg, g["kind"], n) for n in contexts))
+    out = {"geometry": name, **{k: g[k] for k in (
+        "S", "W", "NB", "rows", "hk", "group", "window", "sink", "live")},
+        "live_blocks": live_blocks}
+    if W == 1:
+        qs = jnp.pad(args[0], ((0, 0),) * 3 + ((0, 64),))
+        fn = jax.jit(lambda q_, *a: pa.paged_attention_pallas(
+            q_, *a, interpret=False, out_dtype=jnp.float32,
+            scale=192 ** -0.5, **how))
+        us = _timed(fn, (qs,) + args[1:], calls)
+        live = pos.max(axis=1) >= 0
+        got = np.asarray(fn(qs, *args[1:]))[live]
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(lambda q_, *a: pa.paged_attention_ref(
+                q_, *a, out_dtype=jnp.float32, scale=192 ** -0.5,
+                **how))(qs, *args[1:]))[live]
+        out.update(rel_err_vs_xla_ref=float(
+            np.abs(got - want).max() / np.abs(want).max()),
+            us_a_live_block=us / live_blocks)
+    else:
+        # a piece's rows all read the chain once: the bytes of its last
+        # row, the operations of all of them
+        ops = sum(mixed_kv_bytes.paged_call(cfg, g["kind"], n)[0]
+                  for n in contexts)
+        nbytes = mixed_kv_bytes.paged_call(cfg, g["kind"], contexts[-1])[1]
+        if g["window"]:
+            nbytes = (W + g["window"]) * mixed_kv_bytes.position_bytes(
+                cfg, "window")
+        least = max(nbytes / peak["hbm_bytes_per_s"],
+                    ops / peak["bf16_flops_per_s"])
+        fn = jax.jit(lambda *a: pa.attend(*a, **how))
+        us, _ = _busy_us(lambda last, *a: fn(*a), None, args, calls)
+        out.update(us_a_row=us / W)
+    out.update(us_a_call=us, roofline_pct=100.0 * least * 1e6 / us)
+    return out
+
+
 def measure_piece(name, calls, seed):
     """One layer's attention of a 128-row prefill piece at the end of an
     8,300-token chain, absorbed or expanded (module docstring); both
@@ -442,6 +554,8 @@ def main():
     names = [n for n in args.only.split(",") if n] or list(GEOMETRIES)
     if "writes" in names:
         names = [n for n in names if n != "writes"] + list(WRITES)
+    if "mixed" in names:
+        names = [n for n in names if n != "mixed"] + list(MIXED)
     if "latent" in names:
         names = ([n for n in names if n != "latent"] + list(LATENT)
                  + list(PIECES))
@@ -453,6 +567,8 @@ def main():
                     line = measure_write(name, args.calls, args.seed)
                 elif name in LATENT:
                     line = measure_latent(name, args.calls, peak, args.seed)
+                elif name in MIXED:
+                    line = measure_mixed(name, args.calls, peak, args.seed)
                 elif name in PIECES:
                     line = measure_piece(name, args.calls, args.seed)
                 else:

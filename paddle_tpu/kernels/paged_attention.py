@@ -55,6 +55,21 @@ the ``group`` query heads of one K/V head become ``group`` window rows
 of ``hk`` heads at the same position, so the kernels below see ``h ==
 hk`` always.
 
+**A plane whose K and V arrays differ in lanes, and a sink**
+(``serving.arch.SinkWindowMoE``): ``pool_v [num_blocks, B, hk, dv]`` may
+have other lanes than ``pool_k [.., dk]``; scores run over ``dk``,
+``ctx`` is ``[S, W, h, dv]``.  A key of fewer lanes than the K array
+stores (192 in ``key_lanes(192) = 256``) is ``attend``'s to pad, query
+and all, with the scale taken from the query's own width.  ``sink [h]``
+float32 (a Python ``None`` by default: nothing is traced) is one logit a
+query head that joins every row's softmax: ``p_j = exp(s_j - m) /
+(sum_j exp(s_j - m) + exp(sink - m))``, ``m = max(max_j s_j, sink)``; it
+takes mass and adds no value, so the online softmax simply STARTS at it
+(maximum ``sink``, sum 1, ``acc`` 0).  A window plane's table may name
+the trash block at every entry under its lower bound (an engine that
+gave those blocks back: ``kvcache.WindowChains``): no spelling attends
+them.
+
 **A latent plane** (``pool_v=None``; ``serving.arch.LatentMoE``) is the
 second calling convention of the same functions::
 
@@ -123,8 +138,8 @@ from ..ops.pallas_attention import LSE_LANES
 from .registry import register_kernel, resolve
 from .xla_ref import NEG_INF
 
-__all__ = ["attend", "DENSE_WINDOW", "latent_attention_pallas",
-           "latent_lanes", "paged_attention_ref", "paged_attention_pallas",
+__all__ = ["attend", "DENSE_WINDOW", "DENSE_SCORE_BYTES", "key_lanes",
+           "latent_attention_pallas", "latent_lanes", "paged_attention_ref", "paged_attention_pallas",
            "pool_rows", "softmax_updates", "write"]
 
 # From this window width up a window gathers its slot's chain once and
@@ -134,6 +149,17 @@ __all__ = ["attend", "DENSE_WINDOW", "latent_attention_pallas",
 # cannot run wide at all (18.6 MB of scoped VMEM at W = 64; PERF.md,
 # PR 26).
 DENSE_WINDOW = 8
+
+# A dense window makes float32 scores ``[S, W x group, rows, NB x B]``
+# over the whole chain (the pool's rows, those ``pool_rows`` added among
+# them).  Past this many bytes (a 512-row piece of 64 query heads over a
+# chain of 13,312 positions is 1.7 GB a layer, 3.5 GB with the rows
+# ``pool_rows`` pads a 4-head plane to) it goes one K/V head at a time
+# over the heads the plane really has, and a plane with a lower bound
+# gathers the entries its window can see and no others
+# (``_dense_by_head``).  Read at trace time, like ``DENSE_WINDOW``; the
+# widest dense window of the cells that never pass it is 0.3 GB.
+DENSE_SCORE_BYTES = 1 << 30
 
 # Blocks the Mosaic loop of two rows or more keeps in VMEM: the one whose
 # values are weighed, the one whose scores are made, and two on their way
@@ -155,7 +181,7 @@ LATENT_BLOCKS = 8
 
 
 def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
-           out_dtype=None, value_lanes=None):
+           out_dtype=None, value_lanes=None, sink=None):
     """One layer's attention THROUGH the block table, the one call the
     serving step makes: ``q [S, W, h, dh]``, ``pos [S, W]`` ->
     ``[S, W, h, dh]``; ``group``, ``window``, ``scale`` and
@@ -168,11 +194,32 @@ def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
     ``xla_ref`` spelling with ONE step over the whole chain (a lower
     bound is a mask there); a narrower window streams blocks with online
     softmax through the backend the registry resolves (the Mosaic loop
-    starts at the window's first block)."""
+    starts at the window's first block).
+
+    A K array may store MORE lanes than a key has (``key_lanes``: a key
+    of 192 lanes in 256) and a V array other lanes than the K array: the
+    query is padded with zeros to the K array's lanes, scores are scaled
+    by the query's OWN width unless ``scale`` says otherwise, and the
+    context has the V array's lanes.  ``sink [h]`` float32 is one more
+    logit a query head in every row's softmax, which takes mass and adds
+    no value (module docstring).  A dense window whose float32 scores
+    over the whole chain would pass ``DENSE_SCORE_BYTES`` goes one K/V
+    head at a time, over the window's own entries where the plane has a
+    lower bound (``_dense_by_head``)."""
     how = dict(group=group, window=window, scale=scale, out_dtype=out_dtype)
     if pool_v is None:
         how["value_lanes"] = value_lanes
+    elif q.shape[-1] < pool_k.shape[-1]:
+        if scale is None:
+            how["scale"] = 1.0 / float(q.shape[-1]) ** 0.5
+        q = jnp.pad(q, ((0, 0),) * 3
+                    + ((0, pool_k.shape[-1] - q.shape[-1]),))
+    if sink is not None:
+        how["sink"] = sink
     if q.shape[1] >= DENSE_WINDOW:
+        if pool_v is not None and _dense_score_bytes(
+                q, pool_k, table, group) > DENSE_SCORE_BYTES:
+            return _dense_by_head(q, pool_k, pool_v, table, pos, **how)
         return resolve("paged_attention", backend="xla_ref").impl.call(
             q, pool_k, pool_v, table, pos, block_step=table.shape[1], **how)
     return resolve("paged_attention").impl.call(q, pool_k, pool_v, table,
@@ -198,6 +245,16 @@ def latent_lanes(values):
     makes on a tile's edge, and ``write`` puts zeros in the lanes past
     the values (a zero lane adds nothing to a score)."""
     return -(-int(values) // 128) * 128
+
+
+def key_lanes(lanes):
+    """Lanes a K array stores of a key of ``lanes`` lanes: the next
+    multiple of the 128-lane tile (a key of 192 occupies 256 whatever the
+    logical shape says, and Mosaic refuses to slice part of a tile:
+    PERF.md, PR 40).  ``write`` puts zeros in the lanes past the key and
+    ``attend`` pads the query to match, so a spare lane adds nothing to a
+    score."""
+    return latent_lanes(lanes)
 
 
 def softmax_updates(rows):
@@ -236,11 +293,89 @@ def _fold_group(q, pos, group, rows):
         if rows > hk:
             ctx = ctx[:, :, :hk]
         if group > 1:
-            ctx = ctx.reshape(S, W, group, hk, dh)
-            ctx = ctx.transpose(0, 1, 3, 2, 4).reshape(S, W, h, dh)
+            ctx = ctx.reshape(S, W, group, hk, ctx.shape[-1])
+            ctx = ctx.transpose(0, 1, 3, 2, 4).reshape(S, W, h, -1)
         return ctx
 
     return q, pos, unfold
+
+
+def _fold_sink(sink, group, rows, W):
+    """``sink [hk * group]`` as ``_fold_group`` folds the heads it
+    belongs to: ``[W * group, rows]`` float32, row ``w * group + g`` of
+    K/V head ``j`` is query head ``j * group + g`` (zeros for the rows
+    past ``hk``, whose queries are zeros too)."""
+    sk = sink.astype(jnp.float32).reshape(-1, group).T          # [group, hk]
+    if rows > sk.shape[1]:
+        sk = jnp.pad(sk, ((0, 0), (0, rows - sk.shape[1])))
+    return jnp.tile(sk, (W, 1))
+
+
+def _dense_score_bytes(q, pool_k, table, group):
+    """Bytes of the float32 scores the one-step dense spelling makes."""
+    S, W = q.shape[:2]
+    return (4 * S * W * group * pool_k.shape[2]
+            * table.shape[1] * pool_k.shape[1])
+
+
+def _dense_by_head(q, pool_k, pool_v, table, pos, group=1, window=None,
+                   scale=None, out_dtype=None, sink=None):
+    """The dense spelling for a window whose scores over the whole chain
+    would not fit (``DENSE_SCORE_BYTES``): the same masked softmax, ONE
+    K/V head at a time (``lax.map``: a head's scores ``[S, W, group,
+    T]`` are made, weighed and dropped before the next head's) over the
+    ``hk = h / group`` heads the plane has, not the rows ``pool_rows``
+    padded it to.  A plane with a lower bound gathers, a slot, the
+    ``(W + window - 2) // B + 2`` table entries that hold a key some row
+    of the window can see (from the entry of ``pos[s, 0] - window + 1``:
+    the rows of a window ascend) and no others: the entries under it may
+    name blocks given back long ago."""
+    S, W, h, dk = q.shape
+    B, NB, dv = pool_k.shape[1], table.shape[1], pool_v.shape[-1]
+    hk = h // group
+    if hk * group != h or hk > pool_k.shape[2]:
+        raise ValueError(f"paged_attention: {h} query heads in groups of "
+                         f"{group} over a pool of {pool_k.shape[2]} K/V rows")
+    out_dtype = q.dtype if out_dtype is None else out_dtype
+    if scale is None:
+        scale = 1.0 / float(dk) ** 0.5
+    tbl, n = table.astype(jnp.int32), NB
+    first = jnp.zeros((S,), jnp.int32)
+    if window is not None:
+        n = min(NB, (W + window - 2) // B + 2)
+        first = jnp.clip(jnp.maximum(pos[:, 0] - window + 1, 0) // B,
+                         0, NB - n)
+        tbl = jax.vmap(lambda row, f: jax.lax.dynamic_slice_in_dim(
+            row, f, n))(tbl, first)
+    T = n * B
+    kb = pool_k[tbl][:, :, :, :hk].reshape(S, T, hk, dk)
+    vb = pool_v[tbl][:, :, :, :hk].reshape(S, T, hk, dv)
+    tok = first[:, None] * B + jnp.arange(T, dtype=jnp.int32)[None]  # [S, T]
+    keep = tok[:, None, :] <= pos[:, :, None]                  # [S, W, T]
+    if window is not None:
+        keep &= tok[:, None, :] > pos[:, :, None] - window
+    sk = (jnp.zeros((hk, group), jnp.float32) if sink is None
+          else sink.astype(jnp.float32).reshape(hk, group))
+
+    def one(head):
+        qh, kh, vh, sh = head         # [S, W, g, dk], [S, T, dk | dv], [g]
+        s = jnp.einsum("swgd,std->swgt", qh, kh,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(keep[:, :, None, :], s, NEG_INF)
+        m = jnp.max(s, axis=-1)
+        if sink is not None:
+            m = jnp.maximum(m, sh)
+        p = jnp.exp(s - m[..., None])
+        l = jnp.sum(p, axis=-1)
+        if sink is not None:
+            l = l + jnp.exp(sh - m)
+        ctx = jnp.einsum("swgt,std->swgd", p, vh.astype(jnp.float32))
+        return ctx / jnp.where(l == 0.0, 1.0, l)[..., None]
+
+    ctx = jax.lax.map(one, (
+        jnp.moveaxis(q.reshape(S, W, hk, group, dk), 2, 0),
+        jnp.moveaxis(kb, 2, 0), jnp.moveaxis(vb, 2, 0), sk))
+    return jnp.moveaxis(ctx, 0, 2).reshape(S, W, h, dv).astype(out_dtype)
 
 
 def _normalize_block_step(block_step, nb, w=1):
@@ -274,7 +409,7 @@ def _latent_plane(q, pool, group, window, value_lanes):
 
 def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
                         interpret=None, group=1, window=None, scale=None,
-                        out_dtype=None, value_lanes=None):
+                        out_dtype=None, value_lanes=None, sink=None):
     """The oracle spelling: ``lax.scan`` over the block chain with
     online-softmax carry — per step only ``block_step`` physical blocks
     are gathered (``[S, block_step*B, h, dh]``), never the ``T``-wide
@@ -282,9 +417,15 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
     ``interpret`` is accepted for signature parity and ignored (no
     Pallas here).  A latent plane (``pool_v is None``) is the same lines
     with one cached row for all the heads, its values that row's first
-    ``value_lanes`` lanes."""
+    ``value_lanes`` lanes.  A ``sink`` is where the online softmax
+    STARTS: a row's maximum at its head's sink logit and its sum at 1,
+    as if it had seen one key with that score and a zero value."""
     del interpret
     latent = pool_v is None
+    if sink is not None:
+        if latent:
+            raise ValueError("paged_attention: a latent plane has no sink")
+        sink = _fold_sink(sink, group, pool_k.shape[2], q.shape[1])[None]
     if latent:
         dv = _latent_plane(q, pool_k, group, window, value_lanes)
         unfold = lambda ctx: ctx
@@ -295,11 +436,11 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
             return kb, kb[..., :dv]
     else:
         q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
-        qk, pv, dv = "swhd,sthd->swht", "swht,sthd->swhd", q.shape[-1]
+        qk, pv, dv = "swhd,sthd->swht", "swht,sthd->swhd", pool_v.shape[-1]
 
         def gather(blk, n):
             return (pool_k[blk].reshape(S, n * B, h, dh),
-                    pool_v[blk].reshape(S, n * B, h, dh))
+                    pool_v[blk].reshape(S, n * B, h, dv))
     out_dtype = q.dtype if out_dtype is None else out_dtype
     S, W, h, dh = q.shape
     B = pool_k.shape[1]
@@ -340,8 +481,13 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
         s = jnp.einsum(qk, q, kb,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(visible(off), s, NEG_INF)
-        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        l = jnp.sum(p, axis=-1)
+        if sink is None:
+            p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            l = jnp.sum(p, axis=-1)
+        else:
+            m = jnp.maximum(jnp.max(s, axis=-1), sink)
+            p = jnp.exp(s - m[..., None])
+            l = jnp.sum(p, axis=-1) + jnp.exp(sink - m)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         ctx = jnp.einsum(pv, p, vb.astype(jnp.float32))
         return done(ctx / l_safe[..., None])
@@ -364,6 +510,8 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
 
     m0 = jnp.full((S, W, h), NEG_INF, jnp.float32)
     l0 = jnp.zeros((S, W, h), jnp.float32)
+    if sink is not None:
+        m0, l0 = jnp.broadcast_to(sink, (S, W, h)), jnp.ones_like(l0)
     a0 = jnp.zeros((S, W, h, dv), jnp.float32)
     nsteps = (NB + pad) // bs
     (m, l, acc), _ = jax.lax.scan(
@@ -414,7 +562,7 @@ def _weigh(p, vb):
 
 def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
                            interpret=None, group=1, window=None, scale=None,
-                           out_dtype=None, value_lanes=None):
+                           out_dtype=None, value_lanes=None, sink=None):
     """The Mosaic kernel: it visits the LIVE entries of each slot's chain
     and no others.  The block TABLE and the query POSITIONS are the
     scalar-prefetch arguments (SMEM).  Slot ``s`` has ``n_s = clip(max_w
@@ -490,7 +638,15 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     12 heads do not fill a sublane tile and have to be repacked for the
     MXU: ``benchmarks/paged_walk.py``, PERF.md PR 33).  ``block_step``
     is accepted for signature parity and ignored: this spelling streams
-    exactly one block per iteration by construction."""
+    exactly one block per iteration by construction.
+
+    The V array may have other lanes than the K array (``dv`` of
+    ``pool_v``; scores over ``dh``, values, ``acc`` and the output over
+    ``dv``).  A ``sink [h]`` (one logit a query head, folded as the
+    group is) enters as one more input ``[N, LSE_LANES]`` float32, a row
+    a sublane: ``init`` starts a row's maximum there and its sum at 1,
+    so the sink takes its share of every row's mass and no value row is
+    ever read for it."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -502,9 +658,12 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             q, pool_k, table, pos,
             _latent_plane(q, pool_k, group, window, value_lanes),
             scale=scale, out_dtype=out_dtype, interpret=interpret)
+    if sink is not None:
+        sink = _fold_sink(sink, group, pool_k.shape[2], q.shape[1])
     q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
     out_dtype = q.dtype if out_dtype is None else out_dtype
     S, W, h, dh = q.shape
+    dv = pool_v.shape[-1]
     B = pool_k.shape[1]
     NB = table.shape[1]
     if scale is None:
@@ -539,10 +698,26 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     N = W * h
     f32 = jnp.float32
 
-    def init(m_ref, l_ref, acc_ref):
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def init(m_ref, l_ref, acc_ref, sink_ref=None):
+        if sink_ref is None:
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+        else:
+            # the sink: a key every row has already seen, with no value
+            m_ref[...] = sink_ref[...].reshape(m_ref.shape)
+            l_ref[...] = jnp.ones_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def with_sink(kernel):
+        """``kernel`` with the sink's ref, which follows the pools among
+        the inputs, taken out of its arguments and handed to ``init``."""
+        if sink is None:
+            return kernel
+
+        def entry(tbl, pos_ref, q_ref, k, v, sink_ref, *rest):
+            return kernel(tbl, pos_ref, q_ref, k, v, *rest,
+                          sink_ref=sink_ref)
+        return entry
 
     def fold_row(i, kb, vb, s_id, pos_ref, q_ref, m_ref, l_ref, acc_ref):
         """Block ``i`` into the state of a window of ONE row."""
@@ -597,7 +772,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         # the product below sums over all of them
         p = jnp.exp(s - jnp.where(m2 == NEG_INF, 0.0, m2))
         l2 = l_ref[...][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + _weigh(p, vb.reshape(B * h, dh))
+        acc_ref[...] = acc_ref[...] * alpha + _weigh(p, vb.reshape(B * h, dv))
         m_ref[...] = jnp.broadcast_to(m2, (N, LSE_LANES))
         l_ref[...] = jnp.broadcast_to(l2, (N, LSE_LANES))
 
@@ -623,7 +798,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             o_ref[0, w] = out[w * h:(w + 1) * h]
 
     def loop_kernel(tbl, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-                    k_buf, v_buf, sem, *st):
+                    k_buf, v_buf, sem, *st, sink_ref=None):
         """The loop over ``[f_s, n_s)`` for ONE row a block: two buffers,
         a block folded after it has landed."""
         s_id = pl.program_id(0)
@@ -637,7 +812,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
                     pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[half],
                                           sem.at[1, half]))
 
-        init(*st)
+        init(*st, sink_ref)
 
         @pl.when(n > 0)
         def _first():
@@ -660,7 +835,8 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         finish(o_ref, *st)
 
     def shared_loop_kernel(tbl, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-                           k_buf, v_buf, sem, s_ref, peak_ref, *st):
+                           k_buf, v_buf, sem, s_ref, peak_ref, *st,
+                           sink_ref=None):
         """The loop over ``[f_s, n_s)`` for two rows or more.  With both
         products on the MXU a block is a CHAIN of latencies (copy, MXU,
         lane reduction, ``exp``, MXU) and not much work, so the loop
@@ -684,7 +860,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             s_ref[...] = s
             peak_ref[...] = jnp.broadcast_to(peak, (N, LSE_LANES))
 
-        init(*st)
+        init(*st, sink_ref)
         for ahead in range(DEPTH - 1):
             @pl.when(first + ahead < n)
             def _start(ahead=ahead):
@@ -716,14 +892,15 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         jax.lax.fori_loop(first, n, block, None)
         finish(o_ref, *st)
 
-    def grid_kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref, *st):
+    def grid_kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref, *st,
+                    sink_ref=None):
         del tbl  # consumed by the index maps, not the body
         s_id = pl.program_id(0)
         nb = pl.program_id(1)
 
         @pl.when(nb == 0)
         def _init():
-            init(*st)
+            init(*st, sink_ref)
 
         live = nb < live_entries(pos_ref, s_id)
         if window is not None:
@@ -737,8 +914,8 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         def _finish():
             finish(o_ref, *st)
 
-    stat, acc = (((1, h, LSE_LANES), (1, h, dh)) if W == 1
-                 else ((N, LSE_LANES), (N, dh)))
+    stat, acc = (((1, h, LSE_LANES), (1, h, dv)) if W == 1
+                 else ((N, LSE_LANES), (N, dv)))
     stats = [pltpu.VMEM(stat, jnp.float32), pltpu.VMEM(stat, jnp.float32),
              pltpu.VMEM(acc, jnp.float32)]
     if _block_is_sliceable(pool_k):
@@ -751,7 +928,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             ahead = [pltpu.VMEM((N, B * h), jnp.float32),
                      pltpu.VMEM((N, LSE_LANES), jnp.float32)]
         scratch = [pltpu.VMEM((deep, B, h, dh), pool_k.dtype),
-                   pltpu.VMEM((deep, B, h, dh), pool_v.dtype),
+                   pltpu.VMEM((deep, B, h, dv), pool_v.dtype),
                    pltpu.SemaphoreType.DMA((2, deep))] + ahead + stats
     else:
         def last_live(s, nb, tbl, pos):
@@ -763,19 +940,29 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         kernel, grid, semantics = grid_kernel, (S, NB), ("parallel",
                                                          "arbitrary")
         kv_spec = pl.BlockSpec((1, B, h, dh), last_live)
+        v_spec = pl.BlockSpec((1, B, h, dv), last_live)
         scratch = stats
+    if _block_is_sliceable(pool_k):
+        v_spec = kv_spec
     row = pl.BlockSpec((1, W, h, dh), lambda s, *_: (s, 0, 0, 0))
+    out_row = pl.BlockSpec((1, W, h, dv), lambda s, *_: (s, 0, 0, 0))
+    extra, extra_specs = (), []
+    if sink is not None:
+        extra = (jnp.broadcast_to(sink.reshape(N, 1), (N, LSE_LANES)),)
+        extra_specs = [pl.BlockSpec((N, LSE_LANES), lambda s, *_: (0, 0))]
     ctx = pl.pallas_call(
-        kernel,
+        with_sink(kernel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid, in_specs=[row, kv_spec, kv_spec],
-            out_specs=row, scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((S, W, h, dh), out_dtype),
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=[row, kv_spec, v_spec] + extra_specs,
+            out_specs=out_row, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((S, W, h, dv), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         interpret=bool(interpret),
         name="paged_attention",
-    )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v)
+    )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v,
+      *extra)
     return unfold(ctx)
 
 
@@ -934,16 +1121,18 @@ def write(pool, blk, off, rows):
     ``dynamic-update-slice`` each, 4.2 us a row on a v5e where the whole
     row costs 0.13 (PERF.md, PR 37).  A LATENT plane (``pool [blocks, B,
     L]``, ``rows [*blk.shape, values]``) has no head axis: the lanes past
-    the ``values`` a row carries are written as zeros."""
+    the ``values`` a row carries are written as zeros; so are the lanes a
+    K array stores past its key (``key_lanes``)."""
     if pool.ndim == 3:
         spare = pool.shape[2] - rows.shape[-1]
         if spare:
             rows = jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, spare),))
         return pool.at[blk, off].set(rows)
-    spare = pool.shape[2] - rows.shape[-2]
-    if spare:
+    spare, lanes = (pool.shape[2] - rows.shape[-2],
+                    pool.shape[3] - rows.shape[-1])
+    if spare or lanes:
         rows = jnp.pad(rows, ((0, 0),) * (rows.ndim - 2)
-                       + ((0, spare), (0, 0)))
+                       + ((0, spare), (0, lanes)))
     return pool.at[blk, off].set(rows)
 
 

@@ -58,7 +58,7 @@ adds to what the compiled decode chunk and prefill piece return as their
 last output (``arch.count_names`` names the entries; an architecture
 with none never calls it and its programs have no such output).
 
-Six architectures are here: ``Gpt2`` (the block of
+Seven architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -91,7 +91,14 @@ per-head form.  ``PowerRetention`` has NO plane at all (``planes ==
 memory of the context is a state of fixed size a slot a K/V head, read
 and written in place for the live slots only (``attend.retain``,
 ``kernels/retention.py``); ``models/retention_reference.py`` is its
-plain reference, in the quadratic form.
+plain reference, in the quadratic form.  ``SinkWindowMoE`` is the first
+whose planes are NOT alike: full planes of few K/V heads beside window
+planes of more, keys of more lanes than values, a learned sink on the
+window planes; what the others state once (``kv_heads``,
+``pool_block_shape``, ``written_values``, ``kv_block_bytes``,
+``rows_per_entry``) it states a plane (``plane_*``), and its window
+planes' chains may hold only their window (``window_chains``);
+``models/sink_window_moe_reference.py`` is its plain reference.
 
 An architecture whose state is large asks for it IN PLACE::
 
@@ -118,7 +125,7 @@ from ..kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
-           "LatentMoE", "PowerRetention", "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
+           "LatentMoE", "PowerRetention", "SinkWindowMoE", "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
            "MOE_COUNTS", "STACK_SCOPE"]
 
 
@@ -144,6 +151,8 @@ class Architecture:
     attn_form = None
     # layers whose mixer is power retention (a state, no plane)
     retention_layers = 0
+    # planes whose attention has a learned sink logit a query head
+    sink_planes = 0
 
     def __init__(self, n_layer, n_head, d_model, passes=1, head_dim=None):
         if head_dim is None:
@@ -221,7 +230,43 @@ class Architecture:
 
     def kv_bytes_per_token(self, itemsize):
         """What one cached token holds across all its planes."""
-        return self.kv_planes * self.kv_block_bytes(1, itemsize)
+        return self.passes * sum(self.plane_block_bytes(i, 1, itemsize)
+                                 for i in range(len(self.planes)))
+
+    # -- the facts of ONE plane: the architecture's own unless a plane
+    # says otherwise (``SinkWindowMoE``: full planes of 4 K/V heads beside
+    # window planes of 8, keys of more lanes than values) ----------------
+    # whether the window planes' chains may hold ONLY their window (the
+    # engine gives a block back once every later query's lower bound has
+    # passed it: ``kvcache.WindowChains``) or stay whole, as the chains
+    # of an engine with a prefix trie or a draft always do
+    window_chains = False
+
+    def plane_kv_heads(self, plane):
+        return self.kv_heads
+
+    def plane_rows_per_entry(self, plane):
+        return self.rows_per_entry
+
+    def plane_block_shapes(self, plane, block_tokens, dtype):
+        """The block shapes of plane ``plane``'s pool arrays: ``(K, V)``,
+        or ``(rows,)`` for a plane of ONE array."""
+        return (tuple(self.pool_block_shape(block_tokens, dtype)),
+                ) * self.pool_arrays
+
+    def plane_written_values(self, plane):
+        """Values of one position a write puts into EACH of the plane's
+        pool arrays."""
+        return (self.written_values,) * self.pool_arrays
+
+    def plane_block_bytes(self, plane, block_tokens, itemsize):
+        return self.kv_block_bytes(block_tokens, itemsize)
+
+    def chain_kind(self, plane):
+        """Which of an engine's chains plane ``plane`` is written and
+        read through where it keeps two (``window_chains``): 0 the whole
+        chain, 1 the window planes'."""
+        return int(self.planes[plane] is not None)
 
     def state_spec(self, dtype):
         """Per-slot state beside the pool: one tuple of ``(shape,
@@ -773,7 +818,7 @@ MOE_COUNTS = ("moe_rows", "moe_assignments_held", "moe_experts_touched",
 
 
 def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
-               normalise=True, bias=True):
+               normalise=True, bias=True, shared=True):
     """The routed FFN both routed architectures run, over rows ``h [...,
     d]``: ``(y, counts)``.  ``w(name)`` gives the layer's ``router.w
     [d, width]`` (and ``router.bias`` where ``bias``), ``shared_gate.w``,
@@ -781,7 +826,9 @@ def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
     ``experts_gate.w``, ``experts_up.w [count, d, e]``,
     ``experts_down.w [count, e, d]``; ``experts = (first, count)`` is
     this chip's share of the router's width; ``score``, ``normalise``
-    and ``scale`` are ``route``'s.
+    and ``scale`` are ``route``'s.  ``shared`` is the architecture's
+    statement: one gated MLP added for every row (``shared_*.w``), or
+    (``False``) none, and then no such matrix is read.
 
     Every row is routed over all the router's experts, the held ones add
     their weighted part for the rows that selected them and the shared
@@ -823,14 +870,17 @@ def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
             jnp.arange(order.shape[0], dtype=order.dtype))
         y = jnp.sum(out[back].reshape(-1, k, d).astype(f32)
                     * jnp.where(held, weight, 0.0)[..., None], axis=1)
-    with sublayer("moe.shared"):
-        shared = _gated_silu(h, w("shared_gate.w"), w("shared_up.w"),
-                             w("shared_down.w"))
+    part = None
+    if shared:
+        with sublayer("moe.shared"):
+            part = _gated_silu(h, w("shared_gate.w"), w("shared_up.w"),
+                               w("shared_down.w"))
     counts = jnp.stack([jnp.sum(valid, dtype=i32),
                         jnp.sum(held, dtype=i32),
                         jnp.sum(sizes > 0, dtype=i32),
                         jnp.asarray(count, i32)])
-    return shared + y.astype(h.dtype).reshape(h.shape), counts
+    y = y.astype(h.dtype).reshape(h.shape)
+    return (y if part is None else part + y), counts
 
 
 def _check_share(name, experts, router_width, top_k):
@@ -1305,6 +1355,242 @@ class LatentMoE(_Routed, Architecture):
                     w, m, attend, self.experts, self.top_k,
                     self.route_scale, score="softmax", normalise=False,
                     bias=False)
+                attend.tally(counts)
+            x = x + ff
+        return x, planes
+
+    def head(self, p, x):
+        with sublayer("head"):
+            return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
+                              p["lm_head.w"],
+                              preferred_element_type=jnp.float32)
+
+
+class SinkWindowMoE(_Routed, Architecture):
+    """Pre-normed layers of grouped-query attention in TWO geometries
+    and a dense or ROUTED gated-SiLU FFN with no shared expert (the
+    ``mimo_v2`` layout of Xiaomi's MiMo-V2 models;
+    ``models/sink_window_moe_reference.py`` writes the equations down
+    and lists what the published configuration has no key for).
+
+    ``layer_types[i]`` is ``"full"`` (causal over the whole context,
+    ``kv_heads`` K/V heads, rotary ``rope_theta``) or ``"window"`` (a
+    query sees itself and the ``window - 1`` keys before it,
+    ``window_kv_heads`` K/V heads, rotary ``window_rope_theta``, and a
+    learned SINK: one logit a query head that joins every row's softmax,
+    takes its share of the mass and adds no value).  Every layer has
+    ``n_head`` query heads of ``head_dim`` lanes over keys of
+    ``head_dim`` and VALUES of ``v_dim`` lanes (192 over 128 in the
+    published model); q and k are rotated in their first
+    ``rotary_lanes`` lanes only (the halves convention of ``_rope`` over
+    those lanes), the others are position-free; v is scaled by
+    ``value_scale`` before it is cached; scores are scaled by
+    ``head_dim ** -0.5``; no q/k norm, no gate, no bias.
+
+    **Planes that are not alike** (the ``plane_*`` facts): a full plane
+    holds ``kv_heads`` rows a position and a window plane
+    ``window_kv_heads``, each ``pool_rows`` of them; a plane's K array
+    stores ``kernels.paged_attention.key_lanes(head_dim)`` lanes (256
+    for 192: the lanes past the key zeros, which ``write`` puts there
+    and ``attend`` pads the query to) and its V array ``v_dim``.
+    ``window_chains``: an engine without a prefix trie and without a
+    draft keeps, of a window plane, the blocks its window can still see
+    and gives the others back (``kvcache.WindowChains``).
+
+    The first ``dense_layers`` layers have a dense FFN, the others the
+    routed one (``routed_ffn`` with ``shared=False``): ``route`` with
+    sigmoid scores, a bias that selects only, ``top_k`` of
+    ``router_width``, the weights normalised over the selected
+    (``norm_topk``) and not scaled; ``experts = (first, count)`` is this
+    chip's share.  The stack tallies ``MOE_COUNTS``.
+
+    Parameter names: ``tok_emb.w [V, d]`` (not scaled), ``norm_f.scale``,
+    ``lm_head.w [d, V]`` (untied); per layer ``block{i}_norm1.scale``,
+    ``att_qkv.w [d, n_head * head_dim + hk * head_dim + hk * v_dim]`` (q
+    | k | v, ``hk`` the layer's K/V heads), ``att_out.w [n_head * v_dim,
+    d]``, a window layer ``att_sink.b [n_head]``; ``norm2.scale``; a
+    dense layer ``ffn_gate.w``, ``ffn_up.w [d, f]``, ``ffn_down.w [f,
+    d]``; a routed layer ``router.w [d, router_width]``, ``router.bias
+    [router_width]``, ``experts_gate.w``, ``experts_up.w [count, d,
+    e]``, ``experts_down.w [count, e, d]``.
+    """
+
+    name = "sink_window_moe"
+    window_chains = True
+
+    def __init__(self, layer_types, n_head, kv_heads, window_kv_heads,
+                 head_dim, v_dim, d_model, window, rotary_lanes,
+                 dense_layers, router_width, top_k, experts,
+                 value_scale=1.0, norm_topk=True, eps=1e-5,
+                 rope_theta=10000000.0, window_rope_theta=10000.0):
+        super().__init__(len(layer_types), n_head, d_model,
+                         head_dim=head_dim)
+        bad = sorted(set(layer_types) - {"window", "full"})
+        if bad:
+            raise ValueError(f"{self.name}: layer types {bad}; a layer "
+                             f"is 'window' or 'full'")
+        for hk in (kv_heads, window_kv_heads):
+            if n_head % hk:
+                raise ValueError(f"{self.name}: K/V heads {hk} must "
+                                 f"divide n_head {n_head}")
+        if rotary_lanes % 2 or not 0 < rotary_lanes <= self.head_dim:
+            raise ValueError(f"{self.name}: rotary_lanes {rotary_lanes} "
+                             f"must be even and within head_dim "
+                             f"{self.head_dim}")
+        if not 0 <= dense_layers <= self.n_layer:
+            raise ValueError(f"{self.name}: dense_layers {dense_layers} "
+                             f"of {self.n_layer} layers")
+        self.layer_types = tuple(layer_types)
+        self._kv = {"full": int(kv_heads), "window": int(window_kv_heads)}
+        self.v_dim, self.window = int(v_dim), int(window)
+        self.rotary_lanes = int(rotary_lanes)
+        self.dense_layers = int(dense_layers)
+        self.router_width, self.top_k = int(router_width), int(top_k)
+        self.experts = _check_share(self.name, experts, router_width, top_k)
+        self.value_scale, self.norm_topk = float(value_scale), bool(norm_topk)
+        self.eps = eps
+        self._theta = {"full": float(rope_theta),
+                       "window": float(window_rope_theta)}
+
+    @property
+    def kv_heads(self):
+        """The FULL planes' K/V heads; a window plane states its own
+        (``plane_kv_heads``)."""
+        return self._kv["full"]
+
+    @property
+    def planes(self):
+        return tuple(self.window if kind == "window" else None
+                     for kind in self.layer_types)
+
+    @property
+    def sink_planes(self):
+        return self.layer_types.count("window")
+
+    def plane_kv_heads(self, plane):
+        return self._kv[self.layer_types[plane]]
+
+    def plane_rows_per_entry(self, plane):
+        return self.n_head // self.plane_kv_heads(plane)
+
+    def plane_block_shapes(self, plane, block_tokens, dtype):
+        rows = _paged.pool_rows(self.plane_kv_heads(plane), dtype)
+        return ((block_tokens, rows, _paged.key_lanes(self.head_dim)),
+                (block_tokens, rows, self.v_dim))
+
+    def plane_written_values(self, plane):
+        hk = self.plane_kv_heads(plane)
+        return (hk * self.head_dim, hk * self.v_dim)
+
+    def plane_block_bytes(self, plane, block_tokens, itemsize):
+        # what the model caches of a position: the published lanes
+        return block_tokens * sum(self.plane_written_values(plane)) * itemsize
+
+    def gauges(self, params):
+        lanes = ("lanes of one K/V head's row: what the model caches "
+                 "(form=published) and what the pool array stores "
+                 "(form=stored: the key's lanes up to the 128-lane tile, "
+                 "kernels.paged_attention.key_lanes)")
+        out = dict(_moe_gauges(self, params))
+        for array, pub, stored in (
+                ("k", self.head_dim, _paged.key_lanes(self.head_dim)),
+                ("v", self.v_dim, self.v_dim)):
+            out[("kv_lanes", (("array", array), ("form", "published")))] = (
+                pub, lanes)
+            out[("kv_lanes", (("array", array), ("form", "stored")))] = (
+                stored, lanes)
+        out["attn_sink_planes"] = (
+            self.sink_planes, "planes whose attention has a learned sink "
+            "logit a query head (mass, no value)")
+        return out
+
+    def check_params(self, params, max_len):
+        last = self.n_layer - 1
+        need = ["tok_emb.w", "norm_f.scale", "lm_head.w",
+                f"block{last}_att_qkv.w", f"block{last}_att_out.w",
+                f"block{last}_norm2.scale"]
+        need += [f"block{i}_att_sink.b"
+                 for i, kind in enumerate(self.layer_types)
+                 if kind == "window"]
+        if self.dense_layers:
+            need.append("block0_ffn_down.w")
+        if self.moe_layers:
+            need += [f"block{last}_router.w", f"block{last}_router.bias",
+                     f"block{last}_experts_down.w"]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+        for i, kind in enumerate(self.layer_types):
+            hk = self._kv[kind]
+            want = self.n_head * self.head_dim + hk * (self.head_dim
+                                                       + self.v_dim)
+            got = np.shape(params[f"block{i}_att_qkv.w"])[1]
+            if got != want:
+                raise ValueError(
+                    f"{self.name}: layer {i} ({kind}) projects to {got} "
+                    f"lanes; q | k | v of {self.n_head} x {self.head_dim} "
+                    f"over {hk} x ({self.head_dim} | {self.v_dim}) is "
+                    f"{want}")
+        if self.moe_layers:
+            self._check_experts(params)
+
+    def embed(self, p, toks, pos):
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks]
+
+    def _rotate(self, x, rope):
+        """Rotary on the first ``rotary_lanes`` lanes, the rest as is."""
+        r = self.rotary_lanes
+        if r == x.shape[-1]:
+            return _rope(x, *rope)
+        return jnp.concatenate([_rope(x[..., :r], *rope), x[..., r:]],
+                               axis=-1)
+
+    def _attention(self, w, i, x, rope, planes, attend):
+        kind = self.layer_types[i]
+        hk, dh, dv = self._kv[kind], self.head_dim, self.v_dim
+        with sublayer("norm"):
+            a = _rms(x, w("norm1.scale"), self.eps)
+        lead = a.shape[:-1]
+        with sublayer("attn.proj"):
+            qkv = a @ w("att_qkv.w")
+            nq, nk = self.n_head * dh, hk * dh
+            q = self._rotate(qkv[..., :nq].reshape(*lead, self.n_head, dh),
+                             rope)
+            k = self._rotate(qkv[..., nq:nq + nk].reshape(*lead, hk, dh),
+                             rope)
+            v = qkv[..., nq + nk:].reshape(*lead, hk, dv)
+        with sublayer("attn.core"):
+            v = v * jnp.asarray(self.value_scale, v.dtype)
+            how = dict(group=self.n_head // hk, scale=dh ** -0.5)
+            if kind == "window":
+                how.update(window=self.window,
+                           sink=w("att_sink.b").astype(jnp.float32))
+            ctx, planes = attend(planes, i, 0, q, k, v, **how)
+        with sublayer("attn.proj"):
+            return ctx.reshape(*lead, -1) @ w("att_out.w"), planes
+
+    def stack(self, p, x, pos, planes, attend):
+        with sublayer("attn.proj"):
+            ropes = {kind: _rope_angles(pos, self.rotary_lanes, theta)
+                     for kind, theta in self._theta.items()
+                     if kind in self.layer_types}
+        for i in range(self.n_layer):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            a, planes = self._attention(
+                w, i, x, ropes[self.layer_types[i]], planes, attend)
+            x = x + a
+            with sublayer("norm"):
+                m = _rms(x, w("norm2.scale"), self.eps)
+            if i < self.dense_layers:
+                with sublayer("ffn"):
+                    ff = _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
+                                     w("ffn_down.w"))
+            else:
+                ff, counts = routed_ffn(
+                    w, m, attend, self.experts, self.top_k,
+                    normalise=self.norm_topk, shared=False)
                 attend.tally(counts)
             x = x + ff
         return x, planes

@@ -182,6 +182,12 @@ class _Cache:
     released slot (it keeps counting).  Writes (``blk``, ``off``) are
     untouched: a dead slot's land in the trash block as before.
 
+    TWO KINDS OF CHAIN.  Where the engine keeps the window planes'
+    chains apart (``kvcache.WindowChains``) ``table`` is ``[S, 2, NB]``
+    and ``blk`` a pair: kind 0 the whole chains (dead is read off it),
+    kind 1 the window planes', whose entries under a slot's window are
+    the trash block; a call goes through ``arch.chain_kind(plane)``'s.
+
     ``valid`` (``pos``'s shape, bool) marks the rows that are real: a
     window's rows up to its ``limit`` (``writable``), a live slot's
     step.  ``slot`` (prefill: the one slot the ``S = 1`` window belongs
@@ -209,14 +215,20 @@ class _Cache:
 
     def __init__(self, arch, table, blk, off, pos, writable=None,
                  slot=None, live=None):
-        self.arch, self.table, self.blk, self.off = arch, table, blk, off
+        self.arch, self.off = arch, off
         self.pos, self.writable, self.slot = pos, writable, slot
         self.live = live
         self.counts = ()
         self.step = pos.ndim == 1
+        # two kinds of chain (``[S, 2, NB]``): a table and the rows'
+        # write blocks a kind, the whole chains first
+        self.tables = (None if table is None else (table,) if table.ndim == 2
+                       else tuple(table[:, k] for k in range(table.shape[1])))
+        self.blks = blk if isinstance(blk, tuple) else (blk,)
+        self.table = None if table is None else self.tables[0]
         if table is not None:
             pos4 = pos[:, None] if self.step else pos
-            self.pos4 = jnp.where((table[:, 0] == 0)[:, None], -1, pos4)
+            self.pos4 = jnp.where((self.table[:, 0] == 0)[:, None], -1, pos4)
 
     @property
     def valid(self):
@@ -228,7 +240,8 @@ class _Cache:
 
     def __call__(self, planes, plane, i_pass, qh, kh, vh, **how):
         pool_k, pool_v = planes[:2]
-        tbl, b = self.table, self.blk
+        kind = self.arch.chain_kind(plane) if len(self.tables) > 1 else 0
+        tbl, b = self.tables[kind], self.blks[kind]
         if self.arch.passes > 1:
             shift = i_pass * (pool_k[plane].shape[0] // self.arch.passes)
             tbl, b = tbl + shift, b + shift
@@ -311,13 +324,26 @@ class _Cache:
         return planes[:2] + (planes[2][:i] + (rows,) + planes[2][i + 1:],)
 
 
+def _blocks(table, rows, entry, writable=None):
+    """The physical blocks rows are written to, ``table[rows, entry]``
+    (the trash block where not ``writable``); one array a kind of chain
+    where ``table`` is ``[S, kinds, NB]``."""
+    def one(tbl):
+        blk = tbl[rows, entry]
+        return blk if writable is None else jnp.where(writable, blk, 0)
+
+    if table.ndim == 2:
+        return one(table)
+    return tuple(one(table[:, k]) for k in range(table.shape[1]))
+
+
 def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     """One decode step for S independent slots through the block table.
 
     tok [S] int32 current tokens, t [S] int32 per-slot positions,
     pool_k/pool_v tuples of one array a layer ``[passes * num_blocks, B,
     h, dh]``, table [S, NB] int32 block ids (logical capacity T = NB *
-    B); ``arch`` the model, an ``arch.Architecture``.  For an
+    B; ``[S, 2, NB]`` under two kinds of chain, ``_Cache``); ``arch`` the model, an ``arch.Architecture``.  For an
     architecture with NO plane (``pool_k == ()``) ``table`` is ``[S]``
     int32, nonzero where the slot is live, and positions are bounded by
     nothing here.
@@ -336,10 +362,10 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     else:
         S = tok.shape[0]
         B = pool_k[0].shape[1]
-        T = table.shape[1] * B
+        T = table.shape[-1] * B
         tw = jnp.clip(t, 0, T - 1)
         with sublayer("cache"):
-            blk = table[jnp.arange(S), tw // B]  # [S] physical write block
+            blk = _blocks(table, jnp.arange(S), tw // B)  # [S] write block
         x = arch.embed(p, tok, tw)                           # [S, d]
         cache = _Cache(arch, table, blk, tw % B, t)
     with jax.named_scope(STACK_SCOPE):
@@ -430,12 +456,11 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch,
         cache = _Cache(arch, None, None, None, P, writable, slot)
     else:
         B = pool_k[0].shape[1]
-        T = table.shape[1] * B
+        T = table.shape[-1] * B
         Pw = jnp.clip(P, 0, T - 1)
         writable = P <= limit[:, None]
         with sublayer("cache"):
-            blk = jnp.where(writable,
-                            table[jnp.arange(S)[:, None], Pw // B], 0)
+            blk = _blocks(table, jnp.arange(S)[:, None], Pw // B, writable)
         x = arch.embed(p, toks, Pw)                          # [S, W, d]
         cache = _Cache(arch, table, blk, Pw % B, P, writable, slot)
     with jax.named_scope(STACK_SCOPE):

@@ -354,6 +354,32 @@ class ServingEngine:
                       + self.cache_blocks)
         if spec_on:
             num_blocks += self.max_slots * self.blocks_per_slot
+        # TWO KINDS OF CHAIN.  A plane attended under a lower bound
+        # needs, of a slot's positions, the last ``window`` only.  Where
+        # the architecture says its window planes may hold just that
+        # (arch.window_chains) and nothing needs them whole (a prefix hit
+        # has to find the head's last window in them, a draft shares the
+        # target's planes), they get a pool and a table of their own: a
+        # slot holds there the blocks its window can still see and what
+        # the rows in flight write, at most window_blocks of them, and
+        # gives the others back (kvcache.WindowChains).  A block id then
+        # names positions in the planes of its own kind only.  Admission
+        # can ALWAYS allocate: a whole chain of the full planes as
+        # before, and max_slots x window_blocks of the window planes'.
+        windows = [w for w in arch.planes if w is not None]
+        self._windowed = bool(
+            arch.window_chains and not prefix_reuse and not spec_on
+            and windows and len(windows) < len(arch.planes))
+        self.window_chains = None
+        if self._windowed:
+            self.window_blocks_per_slot = _kv.window_blocks(
+                max(windows), max(self._rungs[-1], self.decode_chunk),
+                self.block_tokens)
+            self.window_chains = _kv.WindowChains(
+                _kv.BlockPool(
+                    1 + self.max_slots * self.window_blocks_per_slot,
+                    self.block_tokens),
+                self.max_slots, self.blocks_per_slot, max(windows))
         if arch.planes:
             self.kv_pool = _kv.BlockPool(num_blocks, self.block_tokens)
         else:
@@ -367,15 +393,24 @@ class ServingEngine:
         # one array a plane of arch.planes (a layer, unless the
         # architecture lists them otherwise); a stack that runs
         # arch.passes times keeps each pass's plane in its own num_blocks
-        # of the block axis (batched_decode._Cache)
-        shape = (arch.passes * num_blocks,) + tuple(
-            arch.pool_block_shape(self.block_tokens, self.compute_dtype))
-        self._pk = tuple(jnp.zeros(shape, self.compute_dtype)
-                         for _ in arch.planes)
+        # of the block axis (batched_decode._Cache).  A plane states its
+        # own block shapes (arch.plane_block_shapes: K/V heads and lanes
+        # may differ by plane, a K array's lanes from its V array's) and,
+        # under two kinds of chain, has the blocks of its kind's pool
+        def blocks_of(i):
+            if self._windowed and arch.chain_kind(i):
+                return self.window_chains.pool.num_blocks
+            return num_blocks
+
+        shapes = [tuple((arch.passes * blocks_of(i),) + tuple(shape)
+                        for shape in arch.plane_block_shapes(
+                            i, self.block_tokens, self.compute_dtype))
+                  for i in range(len(arch.planes))]
+        self._pk = tuple(jnp.zeros(shape[0], self.compute_dtype)
+                         for shape in shapes)
         # no V array where a position's value is lanes of its key row
-        self._pv = tuple(jnp.zeros(shape, self.compute_dtype)
-                         for _ in arch.planes[:len(arch.planes)
-                                              * (arch.pool_arrays - 1)])
+        self._pv = tuple(jnp.zeros(shape[1], self.compute_dtype)
+                         for shape in shapes if len(shape) > 1)
         # what a slot holds BESIDE the pool (recurrent state): arrays
         # indexed by slot, donated through the same executables; a
         # prompt's first prefill piece starts its row from zeros, so a
@@ -428,15 +463,16 @@ class ServingEngine:
         self._prompt_tokens = 0
 
         self._reg = registry or _obs.get_registry()
+        itemsize = self.compute_dtype.itemsize
         self._reg.gauge("serving.slots_total").set(self.max_slots)
         self._reg.gauge("serving.slots_active").set(0)
         self._reg.gauge("serving.queue_depth").set(0)
         self._reg.gauge(
             "serving.kv_blocks_total",
             help="physical KV blocks in the paged pool (excl. trash)",
-        ).set(num_blocks - 1)
+        ).set(num_blocks - 1 + (
+            self.window_chains.pool.num_blocks - 1 if self._windowed else 0))
         self._reg.gauge("serving.blocks_in_use").set(0)
-        itemsize = self.compute_dtype.itemsize
         self._reg.gauge(
             "serving.kv_planes",
             help="K/V planes a cached token holds (layers x passes of "
@@ -452,6 +488,32 @@ class ServingEngine:
             "serving.kv_heads",
             help="K/V heads a plane holds of each position").set(
                 arch.kv_heads)
+        # by kind of plane, where the planes are not alike; per token: a
+        # full plane a position, a window plane a slot's constant
+        by_kind = {}
+        for i, w in enumerate(arch.planes):
+            by_kind.setdefault("full" if w is None else "window", i)
+        for kind, i in by_kind.items():
+            self._reg.gauge(
+                "serving.kv_heads", kind=kind,
+                help="K/V heads a plane of this kind holds of each "
+                     "position").set(arch.plane_kv_heads(i))
+            one = arch.plane_block_bytes(i, 1, itemsize)
+            self._reg.gauge(
+                "serving.kv_bytes_per_token", kind=kind,
+                help="bytes of what the model caches in ONE plane of this "
+                     "kind: kind=full a cached position; kind=window, "
+                     "under window chains, what a SLOT holds whatever its "
+                     "context (the window's positions), else a position",
+            ).set(one * (arch.planes[i] if self._windowed
+                         and arch.planes[i] else 1))
+        if self._windowed:
+            self._reg.gauge(
+                "serving.window_blocks_per_slot",
+                help="blocks a slot can hold at most in the window planes' "
+                     "chain: the window and the widest run of rows in "
+                     "flight (a prefill piece), kvcache.window_blocks",
+            ).set(self.window_blocks_per_slot)
         self._reads_per_token = sum(n for _, n in arch.plane_reads)
         self._reg.gauge(
             "serving.plane_reads_per_token",
@@ -482,7 +544,9 @@ class ServingEngine:
                  "/ values a write covers: a write covers the pool's "
                  "whole row (kernels.paged_attention.write), the rows "
                  "pool_rows added, the lanes latent_lanes added, as zeros",
-        ).set(arch.written_values / int(np.prod(self._pk[0].shape[2:]))
+        ).set(sum(sum(arch.plane_written_values(i))
+                  for i in range(len(arch.planes)))
+              / sum(int(np.prod(a.shape[2:])) for a in self._pk + self._pv)
               if self._pk else 0.0)
         self._reg.gauge(
             "serving.kv_pool_bytes",
@@ -497,8 +561,15 @@ class ServingEngine:
                             **dict(labels)).set(value)
         # (window, calls, bytes a cached position) of the paged calls a
         # token makes, for _count_paged_entries
-        self._plane_reads = [(w, n, arch.kv_block_bytes(1, itemsize))
-                             for w, n in arch.plane_reads]
+        # and the query rows a call sends through an entry, each the
+        # fact of a plane with that bound
+        bound = {}
+        for i, w in enumerate(arch.planes):
+            bound.setdefault(w, i)
+        self._plane_reads = [
+            (w, n, arch.plane_block_bytes(bound[w], 1, itemsize),
+             arch.plane_rows_per_entry(bound[w]))
+            for w, n in arch.plane_reads]
         # span attributes that say in which form attention runs, for an
         # architecture with latent planes or with retention layers
         self._form_attrs = (
@@ -506,7 +577,10 @@ class ServingEngine:
             if arch.latent_planes else
             dict(retention_layers=arch.retention_layers,
                  attn_form=arch.attn_form)
-            if arch.retention_layers else {})
+            if arch.retention_layers else
+            dict(kv_kinds=1 + self._windowed,
+                 sink_planes=arch.sink_planes)
+            if arch.sink_planes else {})
 
     @property
     def _tracer(self):
@@ -575,17 +649,21 @@ class ServingEngine:
         if not self.arch.planes:
             return                  # no table entry, no K/V byte to count
         B = self.block_tokens
-        rows = self.arch.rows_per_entry
-        live = streamed = shared = 0
+        live = streamed = shared = rows_live = updates = 0
+        live_by_kind = {"full": 0, "window": 0}
         contexts = [(s, req.prompt.shape[0] + len(req.tokens))
                     for s, req in enumerate(self._slots) if req is not None]
         # only the trie hands two slots one block
         sharing = self.prefix_trie is not None and len(contexts) > 1
-        for window, n, token_bytes in self._plane_reads:
+        for window, n, token_bytes, rows in self._plane_reads:
             chains = []
             for s, ctx in contexts:                   # ctx keys attended
                 first = 0 if window is None else max(ctx - window, 0)
-                live += n * ((ctx - 1) // B - first // B + 1)
+                entries = n * ((ctx - 1) // B - first // B + 1)
+                live += entries
+                live_by_kind["full" if window is None else "window"] += entries
+                rows_live += entries * rows
+                updates += entries * _paged.softmax_updates(rows)
                 streamed += n * (ctx - first) * token_bytes
                 if sharing:
                     chains.append(
@@ -610,6 +688,29 @@ class ServingEngine:
                  "the plane's lower bound up to each one's position at "
                  "the chunk's start; the mean over a token's calls)",
         ).inc(live / self._reads_per_token)
+        if self._windowed:
+            for kind, entries in live_by_kind.items():
+                self._reg.counter(
+                    "serving.paged_entries_live", kind=kind,
+                    help="of paged_entries_live, the entries of the calls "
+                         "on planes of this kind (their sum, not the mean "
+                         "over a token's calls)").inc(entries)
+            # what the window planes' chains hold now, and what whole
+            # chains would hold of the same slots: a block for every
+            # entry up to each slot's position
+            self._reg.counter(
+                "serving.window_blocks_held",
+                help="blocks the live slots hold in the window planes' "
+                     "chain, summed over decode chunks").inc(
+                         sum(self.window_chains.held(s)
+                             for s, _ in contexts))
+            self._reg.counter(
+                "serving.window_blocks_whole",
+                help="blocks whole chains would hold of the same slots "
+                     "(every entry up to each slot's position), summed "
+                     "over decode chunks: window_blocks_held's "
+                     "denominator").inc(
+                         sum((ctx - 1) // B + 1 for _, ctx in contexts))
         self._reg.counter(
             "serving.paged_entries_shared",
             help="of paged_entries_live, the entries whose block more "
@@ -623,7 +724,7 @@ class ServingEngine:
                  "entries of paged_entries_live: a decode call folds the "
                  "query heads of one K/V row into that many rows of its "
                  "window (arch.rows_per_entry)",
-        ).inc(live / self._reads_per_token * rows)
+        ).inc(rows_live / self._reads_per_token)
         self._reg.counter(
             "serving.paged_updates_live",
             help="online-softmax updates the paged kernel made for the "
@@ -631,7 +732,7 @@ class ServingEngine:
                  "(kernels.paged_attention.softmax_updates of the rows a "
                  "call sends through an entry): paged_rows_live's "
                  "denominator",
-        ).inc(live / self._reads_per_token * _paged.softmax_updates(rows))
+        ).inc(updates / self._reads_per_token)
         self._reg.counter(
             "serving.paged_entries_total",
             help="block-table entries a paged-attention call spans "
@@ -783,6 +884,8 @@ class ServingEngine:
                 for b in self._slot_blocks[s] or ():
                     self.kv_pool.deref(b)
                 self._slot_blocks[s] = None
+                if self._windowed:
+                    self.window_chains.release(s)
                 if self._spec is not None:
                     self._spec.release(self, s)
             self._table[:] = 0
@@ -996,14 +1099,17 @@ class ServingEngine:
         ``fn_of(width)``, each attending what the earlier ones wrote;
         the CoW fork rides in the first.  Nothing is fetched: returns
         ``(pool_k', pool_v', first_tok)`` of the LAST piece, still on
-        the device.  ``compile_only`` builds what is not compiled yet
+        the device.  ``row`` is the slot's table row, or a list of them,
+        one a piece (two kinds of chain: the window planes' row moves
+        from piece to piece).  ``compile_only`` builds what is not compiled yet
         and runs nothing.  ``tally`` (a list) receives what each piece's
         stack counted, on the device too."""
         first = None
+        rows = row if isinstance(row, list) else [row] * len(pieces)
         for i, (w, toks, at, n) in enumerate(pieces):
             src, dst = cow if i == 0 else (0, 0)
             args = (params, pk, pv, self._last, self._pos,
-                    np.int32(slot), row, toks, np.int32(at), np.int32(n),
+                    np.int32(slot), rows[i], toks, np.int32(at), np.int32(n),
                     np.int32(src), np.int32(dst), self._state)
             if compile_only:
                 fn_of(w).prepare(*args)
@@ -1028,6 +1134,34 @@ class ServingEngine:
             ).inc()
         return fn
 
+    def _device_table(self):
+        """The block table a decode chunk reads: ``[max_slots, NB]``, or
+        ``[max_slots, 2, NB]`` under two kinds of chain (kind 0 the whole
+        chains, kind 1 the window planes': ``arch.chain_kind``)."""
+        import jax.numpy as jnp
+
+        if not self._windowed:
+            return jnp.asarray(self._table)
+        return jnp.asarray(np.stack(
+            [self._table, self.window_chains.table], axis=1))
+
+    def _advance_window(self, slot, first_pos, last_pos):
+        """Rows ``first_pos .. last_pos`` of ``slot`` are next: its
+        window chain gives back what no later row can see and allocates
+        what these write (``kvcache.WindowChains.advance``)."""
+        freed = self.window_chains.advance(slot, first_pos, last_pos)
+        if freed:
+            self._reg.counter(
+                "serving.window_blocks_released",
+                help="blocks of the window planes' chain given back while "
+                     "their slot went on: every later query's lower bound "
+                     "had passed them").inc(freed)
+
+    def _blocks_in_use(self):
+        return (self.kv_pool.blocks_in_use
+                + (self.window_chains.pool.blocks_in_use
+                   if self._windowed else 0))
+
     def _release_slot(self, slot):
         """Return a slot and every KV block it references to the pool
         (shared blocks just drop one ref; private ones free).  The
@@ -1038,6 +1172,8 @@ class ServingEngine:
             self.kv_pool.deref(b)
         self._slot_blocks[slot] = None
         self._table[slot] = 0
+        if self._windowed:
+            self.window_chains.release(slot)
         if self._spec is not None:
             # the slot's draft scratch chain obeys the same discipline
             self._spec.release(self, slot)
@@ -1049,7 +1185,7 @@ class ServingEngine:
             self.prefix_trie.enforce_budget()
         if self.kv_pool is not None:
             self._reg.gauge("serving.blocks_in_use").set(
-                self.kv_pool.blocks_in_use)
+                self._blocks_in_use())
 
     def _decode(self):
         if self._spec is not None:
@@ -1074,7 +1210,19 @@ class ServingEngine:
                 return 0
         # one-time AOT compile lands here, outside the timed window the
         # predictor consumes
-        tbl = jnp.asarray(self._table)
+        if self._windowed:
+            # the chunk's steps write positions pos .. pos + chunk - 1 of
+            # each live slot (none past its request's last): the window
+            # chains move there first
+            for s, req in enumerate(self._slots):
+                if req is not None:
+                    at = req.prompt.shape[0] + len(req.tokens) - 1
+                    self._advance_window(
+                        s, at, min(at + self.decode_chunk,
+                                   req.prompt.shape[0] + req.max_new) - 1)
+            self._reg.gauge("serving.blocks_in_use").set(
+                self._blocks_in_use())
+        tbl = self._device_table()
         self._decode_fn.prepare(self._p, self._pk, self._pv, self._last,
                                 self._pos, tbl, self._state)
         self._count_paged_entries()
@@ -1446,7 +1594,19 @@ class ServingEngine:
         bucket = sum(w for w, *_ in pieces)
         req.bucket = bucket
         req.prefix_hit = start
-        row_d = jnp.asarray(row)
+        if self._windowed:
+            # a row a piece: before each piece the window chain gives
+            # back what its first row cannot see and allocates what its
+            # rows write, so a later piece's row names, at a higher
+            # entry, a block an earlier piece's named (the device runs
+            # the pieces in order)
+            row_d = []
+            for _, _, at, n in pieces:
+                self._advance_window(slot, at, at + n - 1)
+                row_d.append(jnp.asarray(np.stack(
+                    [row, self.window_chains.table[slot]])))
+        else:
+            row_d = jnp.asarray(row)
         # a width's one-time AOT compile lands here, outside the timed
         # window the predictor consumes
         self._run_pieces(self._prefill_fn, self._p, self._pk, self._pv,
@@ -1558,7 +1718,8 @@ class ServingEngine:
             help="cumulative prefix-cache hit rate over prompt tokens "
                  "(since the last accounting reset)").set(hit_rate)
         if pool is not None:
-            self._reg.gauge("serving.blocks_in_use").set(pool.blocks_in_use)
+            self._reg.gauge("serving.blocks_in_use").set(
+                self._blocks_in_use())
         if ((req.eos_id is not None and first == req.eos_id)
                 or req.max_new == 1):
             self._release_slot(slot)

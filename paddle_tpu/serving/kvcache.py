@@ -56,7 +56,8 @@ single-stream identity survives reuse (the acceptance gate).
 
 import numpy as np
 
-__all__ = ["BlockPool", "PoolExhausted", "PrefixTrie"]
+__all__ = ["BlockPool", "PoolExhausted", "PrefixTrie", "WindowChains",
+           "window_blocks"]
 
 
 class PoolExhausted(RuntimeError):
@@ -132,6 +133,85 @@ class BlockPool:
         self._ref[bid] -= 1
         if self._ref[bid] == 0:
             self._free.append(bid)
+
+
+def window_blocks(window, span, block_tokens):
+    """Blocks a slot's WINDOW chain can hold at most: rows at positions
+    ``p .. p + span - 1`` are written and the first attends from ``p -
+    window + 1``, which ``window + span - 1`` positions cover in at most
+    this many blocks wherever ``p`` falls in its block."""
+    return (window + span - 2) // block_tokens + 2
+
+
+class WindowChains:
+    """The chains of the planes that are attended under a lower bound,
+    held beside the whole chains of the planes attended whole: the
+    second kind of chain of one engine.
+
+    A block id of THIS pool names ``block_tokens`` positions in every
+    window plane (and nothing in a full plane, whose ids are the other
+    pool's).  The table has the whole chain's geometry, ``[max_slots,
+    blocks_per_slot]``, entry ``e`` for positions ``e * B .. (e + 1) * B
+    - 1``; a slot HOLDS the entries ``[lo, hi)`` only, every other entry
+    is the trash block.  ``advance`` is called before rows are computed:
+    it gives back every held block whose last position lies under the
+    lower bound of the first of those rows (every later query's bound is
+    higher: nothing will attend it again) and allocates up to the block
+    the last of them is written to.  What the kernels see is an ordinary
+    table: a released entry lies under every row's lower bound, where
+    the Mosaic loop never fetches and the masks of the other spellings
+    weigh whatever the trash block holds zero.
+
+    Invariants (``tests/test_window_chains.py``): a block of the pool is
+    free, or held by exactly one slot whose table row names it at
+    exactly one entry; a slot holds at most ``window_blocks(window,
+    span, B)`` blocks when every ``advance`` spans at most ``span``
+    rows, so a pool of ``1 + max_slots x`` that many can ALWAYS serve an
+    ``advance``."""
+
+    def __init__(self, pool, max_slots, blocks_per_slot, window):
+        self.pool, self.window = pool, int(window)
+        self.table = np.zeros((int(max_slots), int(blocks_per_slot)),
+                              np.int32)
+        self._held = [(0, 0)] * int(max_slots)      # [lo, hi) of entries
+        self.released = 0
+
+    def held(self, slot):
+        """Blocks slot ``slot`` holds."""
+        lo, hi = self._held[slot]
+        return hi - lo
+
+    def advance(self, slot, first_pos, last_pos):
+        """Rows at positions ``first_pos .. last_pos`` of ``slot`` are
+        about to be written and to attend.  Returns the blocks given
+        back."""
+        B = self.pool.block_tokens
+        row = self.table[slot]
+        lo, hi = self._held[slot]
+        new_lo = max(first_pos - self.window + 1, 0) // B
+        new_hi = min(last_pos // B + 1, row.shape[0])
+        freed = 0
+        for e in range(lo, min(new_lo, hi)):
+            self.pool.deref(int(row[e]))
+            row[e] = 0
+            freed += 1
+        lo, at = max(lo, min(new_lo, hi)), max(hi, new_lo)
+        if new_hi > at:
+            row[at:new_hi] = self.pool.alloc(new_hi - at)
+            if lo == hi:
+                lo = at
+            hi = new_hi
+        self._held[slot] = (lo, hi)
+        self.released += freed
+        return freed
+
+    def release(self, slot):
+        """Give back everything ``slot`` holds."""
+        lo, hi = self._held[slot]
+        for e in range(lo, hi):
+            self.pool.deref(int(self.table[slot, e]))
+        self.table[slot] = 0
+        self._held[slot] = (0, 0)
 
 
 class _Node:

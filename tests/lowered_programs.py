@@ -1,0 +1,120 @@
+"""What the six architectures that are NOT ``SinkWindowMoE`` lower to:
+the StableHLO text of their decode chunk and of a wide and a narrow
+prefill piece at the tiny size of each family's own test
+(``chipbench/tests/test_<family>_family.py::TINY``), hashed; and the
+jaxpr of the Mosaic paged kernels, which the CPU's programs do not hold
+(``mosaic``).
+
+    python tests/lowered_programs.py [root]
+
+prints ``{family: {entry: sha256}}`` for the tree at ``root`` (this one by
+default): run on a ``git archive`` of a parent commit it gives the
+hashes ``tests/test_lowered_programs.py`` pins (PR 46: the commit before
+planes stated their own shape and chains came in two kinds)."""
+
+import hashlib
+import importlib.util
+import json
+import os
+import sys
+
+FAMILIES = {"gpt2": "test_rehearsal", "ouro": "test_ouro_family",
+            "sambay": "test_sambay_family", "gated_moe":
+            "test_gated_moe_family", "latent_moe": "test_latent_moe_family",
+            "retention": "test_retention_family"}
+GEOMETRY = {"max_len": 64, "max_slots": 2, "block_tokens": 8,
+            "cache_blocks": 0, "prefix_reuse": False}
+ENTRIES = ("decode_chunk_4", "prefill_8", "prefill_32")
+
+
+def _tiny(root, module):
+    path = os.path.join(root, "chipbench", "tests", module + ".py")
+    spec = importlib.util.spec_from_file_location("_tiny_" + module, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return dict(mod.TINY)
+
+
+def programs(root, only=None):
+    """{family: {entry: sha256 of the lowered StableHLO}}."""
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    import jax.numpy as jnp
+    import numpy as np
+    from chipbench import families
+    from paddle_tpu.observability.metrics import MetricsRegistry
+    from paddle_tpu.serving import batched_decode as bd
+
+    out = {}
+    for name, module in FAMILIES.items():
+        if only and name != only:
+            continue
+        cfg = _tiny(root, module)
+        cfg.setdefault("family", name)
+        family = families.of(cfg, "serve")
+        params = family.make_params(cfg, GEOMETRY["max_len"], 1)
+        eng = family.serving_engine(params, cfg, MetricsRegistry(),
+                                    dict(GEOMETRY))
+        arch = eng.arch
+        texts = {"decode_chunk_4": bd.make_decode_chunk(arch, 4).lower(
+            eng._p, eng._pk, eng._pv, eng._last, eng._pos,
+            jnp.asarray(eng._table), eng._state).as_text()}
+        row = jnp.asarray(eng._table[0]) if arch.planes else jnp.asarray(
+            np.zeros(0, np.int32))
+        for width in (8, 32):
+            zero = np.int32(0)
+            texts[f"prefill_{width}"] = bd.make_prefill(arch, width).lower(
+                eng._p, eng._pk, eng._pv, eng._last, eng._pos, zero, row,
+                jnp.zeros((width,), jnp.int32), zero, np.int32(width), zero,
+                zero, eng._state).as_text()
+        out[name] = {k: hashlib.sha256(v.encode()).hexdigest()[:16]
+                     for k, v in texts.items()}
+    return out
+
+
+# (window rows, K/V rows of the pool, query heads a K/V head, lower
+# bound, dtype): the loop form for one row and for several, the grid form
+MOSAIC = {"one_row_loop": (1, 16, 1, None, "bfloat16"),
+          "group_6_window_loop": (1, 8, 6, 64, "bfloat16"),
+          "verify_window_grid_12_heads": (5, 12, 1, None, "bfloat16"),
+          "float32_pool_group_4": (1, 16, 4, None, "float32")}
+
+
+def mosaic(root):
+    """{geometry: sha256 of the Mosaic paged kernel's jaxpr} (and the
+    latent sibling's), file names and line numbers left out."""
+    import re
+
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import paged_attention as pa
+
+    def text(fn, *args):
+        jaxpr = str(jax.make_jaxpr(fn)(*args))
+        return re.sub(r"(/[\w.\-]+)+\.py(:\d+)?(:\d+)?", "<file>", jaxpr)
+
+    out = {}
+    for name, (W, hk, group, window, dtype) in MOSAIC.items():
+        pool = jnp.zeros((9, 8, hk, 128), dtype)
+        out[name] = text(
+            lambda q, k, v, t, p: pa.paged_attention_pallas(
+                q, k, v, t, p, interpret=False, group=group, window=window),
+            jnp.zeros((3, W, hk * group, 128), jnp.bfloat16), pool, pool,
+            jnp.zeros((3, 4), jnp.int32), jnp.zeros((3, W), jnp.int32))
+    out["latent"] = text(
+        lambda q, k, t, p: pa.paged_attention_pallas(
+            q, k, None, t, p, interpret=False, value_lanes=128, scale=0.1),
+        jnp.zeros((3, 1, 4, 256), jnp.bfloat16),
+        jnp.zeros((9, 8, 256), jnp.bfloat16), jnp.zeros((3, 4), jnp.int32),
+        jnp.zeros((3, 1), jnp.int32))
+    return {k: hashlib.sha256(v.encode()).hexdigest()[:16]
+            for k, v in out.items()}
+
+
+if __name__ == "__main__":
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    root = os.path.abspath(sys.argv[1]) if len(sys.argv) > 1 else \
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    print(json.dumps(dict(programs(root), mosaic=mosaic(root)), indent=1))

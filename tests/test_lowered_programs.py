@@ -1,0 +1,79 @@
+"""The six architectures that are not ``SinkWindowMoE`` lower to the
+programs they lowered to before planes stated their own shape, chains
+came in two kinds, the paged kernels took a sink and value lanes of
+their own, and ``routed_ffn`` took the shared expert by statement: the
+decode chunk, a narrow and a wide prefill piece of each (StableHLO, at
+each family's tiny size), and the Mosaic paged kernels' jaxprs, against
+hashes taken on a ``git archive`` of the parent commit with
+``tests/lowered_programs.py`` (PR 46; the same installation).  A PR that
+means to change one of them takes its hashes anew, the same way, and
+says which program changed and why."""
+
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import lowered_programs  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PARENT = {
+    "gpt2": {
+        "decode_chunk_4": "b3be1f063b0a4596",
+        "prefill_8": "299ee30588d4facc",
+        "prefill_32": "1f17ef3a3133fdc7"
+    },
+    "ouro": {
+        "decode_chunk_4": "d6b00908a90e1564",
+        "prefill_8": "00bc623174b47d7b",
+        "prefill_32": "364800b7aed23fda"
+    },
+    "sambay": {
+        "decode_chunk_4": "f97351e8f47d5000",
+        "prefill_8": "d45310d8a5ec3d88",
+        "prefill_32": "36ddf57ea2958758"
+    },
+    "gated_moe": {
+        "decode_chunk_4": "eb3c24c55d6590be",
+        "prefill_8": "23406f22b284a463",
+        "prefill_32": "e076ada18f394c62"
+    },
+    "latent_moe": {
+        "decode_chunk_4": "8af80918f20fbcd1",
+        "prefill_8": "251bc626e049cc76",
+        "prefill_32": "4102561b75c4db2e"
+    },
+    "retention": {
+        "decode_chunk_4": "ea43177f94722d62",
+        "prefill_8": "6423ceab36f723d4",
+        "prefill_32": "75fe99c4f114c472"
+    }
+}
+MOSAIC = {
+    "one_row_loop": "b901ca0e439058e8",
+    "group_6_window_loop": "28a8568bb7d057f3",
+    "verify_window_grid_12_heads": "e35ccddc63a17f48",
+    "float32_pool_group_4": "e526874d5c2a9bbb",
+    "latent": "b4812bf81d7ed767"
+}
+
+
+@pytest.fixture(scope="module")
+def mine():
+    return {}
+
+
+@pytest.mark.parametrize("entry", lowered_programs.ENTRIES)
+@pytest.mark.parametrize("family", list(PARENT))
+def test_the_lowered_program_is_the_parents(family, entry, mine):
+    if family not in mine:
+        mine.update(lowered_programs.programs(ROOT, only=family))
+    assert mine[family][entry] == PARENT[family][entry]
+
+
+@pytest.mark.parametrize("geometry", list(MOSAIC))
+def test_the_mosaic_kernels_jaxpr_is_the_parents(geometry, mine):
+    if "mosaic" not in mine:
+        mine["mosaic"] = lowered_programs.mosaic(ROOT)
+    assert mine["mosaic"][geometry] == MOSAIC[geometry]

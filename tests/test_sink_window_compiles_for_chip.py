@@ -1,0 +1,184 @@
+"""What ``mimo25.long_reason`` runs, compiled for a TPU v5e that is
+described and not attached, at the cell's own geometry (24 slots x
+13,312 positions, 64 query heads of 192 lanes over 4 or 8 K/V heads,
+keys stored at 256 lanes over values of 128, a window of 128 with a
+sink): the Mosaic paged kernel on both kinds of plane, the dense
+spelling one K/V head at a time for a 512-row piece, the grouped product
+at 16 experts of ``[4096, 2048]``, and the whole decode chunk and widest
+prefill piece of the seven held layers.  What interpret mode cannot show
+(a 192-lane key is where Mosaic objects: it is stored at 256).  Nothing
+runs: a compile that passes is no chip run."""
+
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+SLOTS, NB, B = 24, 416, 32
+FULL_BLOCKS = 1 + SLOTS * NB
+WINDOW_BLOCKS = 1 + SLOTS * 21
+# (window rows, pool blocks, query heads a K/V head, lower bound, sink)
+PLANES = {
+    "decode_full_plane_group_16": (1, FULL_BLOCKS, 16, None, False),
+    "decode_window_plane_group_8_sink": (1, WINDOW_BLOCKS, 8, 128, True),
+    "narrow_piece_window_plane_sink": (4, WINDOW_BLOCKS, 8, 128, True),
+    "narrow_piece_full_plane": (4, FULL_BLOCKS, 16, None, False),
+}
+
+
+@pytest.mark.parametrize("plane", list(PLANES))
+def test_paged_kernel_compiles_for_v5e(plane, one_chip):
+    """Keys of 256 stored lanes, values of 128, 8 pool rows (the 4 heads
+    of a full plane padded): the loop form, the pools in place."""
+    from paddle_tpu.kernels import paged_attention as pa
+
+    W, blocks, group, window, sink = PLANES[plane]
+    S = 1 if W > 1 else SLOTS
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pk = arg((blocks, B, 8, pa.key_lanes(192)), jnp.bfloat16)
+    pv = arg((blocks, B, 8, 128), jnp.bfloat16)
+    assert pa._block_is_sliceable(pk)
+    args = [arg((S, W, 64, 256), jnp.bfloat16), pk, pv,
+            arg((S, NB), jnp.int32), arg((S, W), jnp.int32)]
+    if sink:
+        args.append(arg((64,), jnp.float32))
+    compiled = jax.jit(
+        lambda q, k, v, t, p, s=None: pa.paged_attention_pallas(
+            q, k, v, t, p, interpret=False, group=group, window=window,
+            scale=192 ** -0.5, sink=s)).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert "paged_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind,blocks,group,window", [
+    ("full", FULL_BLOCKS, 16, None), ("window", WINDOW_BLOCKS, 8, 128)])
+def test_a_512_row_piece_attends_one_head_at_a_time(kind, blocks, group,
+                                                    window, one_chip):
+    """The dense spelling of a 512-row piece over a chain of 13,312
+    positions: ``attend`` takes the by-head form (the scores of all 64
+    heads at once are 1.7 GB, 3.5 with the padded rows), a head's scores
+    are under 0.5 GB, and a window plane gathers 21 entries, not 416."""
+    from paddle_tpu.kernels import paged_attention as pa
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hk = 64 // group
+    q = arg((1, 512, 64, 192), jnp.bfloat16)
+    pk = arg((blocks, B, 8, 256), jnp.bfloat16)
+    assert pa._dense_score_bytes(q, pk, arg((1, NB), jnp.int32),
+                                 group) > pa.DENSE_SCORE_BYTES
+    compiled = jax.jit(lambda q, k, v, t, p, s: pa.attend(
+        q, k, v, t, p, group=group, window=window,
+        sink=s if window else None)).lower(
+        q, pk, arg((blocks, B, 8, 128), jnp.bfloat16),
+        arg((1, NB), jnp.int32), arg((1, 512), jnp.int32),
+        arg((64,), jnp.float32)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    one_head = 4 * 512 * group * (NB * B if window is None else 21 * B)
+    assert temp < 3.5 * one_head + (64 << 20), (temp, one_head)
+    assert temp < (1536 << 20) // hk * 4
+
+
+def test_grouped_matmul_compiles_at_16_experts_of_4096_by_2048(one_chip):
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul_pallas
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for rows, (k, n) in ((24 * 8, (4096, 2048)), (512 * 8, (4096, 2048)),
+                         (24 * 8, (2048, 4096))):
+        compiled = jax.jit(lambda x, w, s: grouped_matmul_pallas(
+            x, w, s, interpret=False)).lower(
+            arg((rows, k), jnp.bfloat16), arg((16, k, n), jnp.bfloat16),
+            arg((16,), jnp.int32)).compile()
+        assert "grouped_matmul" in compiled.as_text()
+
+
+def _cell():
+    import json
+
+    from chipbench import families
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench/configs/mimo-v2.5.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "chipbench/traffic/long_reason.json")) as f:
+        mix = json.load(f)
+    return cfg, mix, families.of(cfg, "serve")
+
+
+@pytest.mark.parametrize("entry", ["decode", "prefill_512"])
+def test_the_cells_executables_compile_and_fit_a_v5e(entry, one_chip,
+                                                     monkeypatch):
+    """The decode chunk and the widest prefill piece of the seven held
+    layers at 24 slots x 13,312 positions, from shapes alone: weights
+    6.86 GB, the pools of both kinds, and temporaries that leave room on
+    a chip of 15.75 GiB."""
+    import numpy as np
+
+    from paddle_tpu.serving import batched_decode as bd
+    from paddle_tpu.serving import kvcache as kv
+
+    cfg, mix, family = _cell()
+    arch = family._arch(cfg)
+    geo = mix["engine"]
+    S, T, Bt = geo["max_slots"], geo["max_len"], geo["block_tokens"]
+    nb = T // Bt
+    per_slot = kv.window_blocks(cfg["sliding_window"], bd.PREFILL_PIECE, Bt)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params, _ = jax.eval_shape(lambda: family.make_params_unsettled(cfg, 0))
+    params = {k: arg(v.shape, v.dtype) for k, v in params.items()}
+    weights = sum(int(np.prod(v.shape)) * 2 for v in params.values())
+    assert 6.8e9 < weights < 6.9e9
+    pk, pv = [], []
+    for i in range(len(arch.planes)):
+        blocks = 1 + S * (per_slot if arch.chain_kind(i) else nb)
+        ks, vs = arch.plane_block_shapes(i, Bt, jnp.bfloat16)
+        pk.append(arg((blocks,) + ks, jnp.bfloat16))
+        pv.append(arg((blocks,) + vs, jnp.bfloat16))
+    pool = sum(int(np.prod(a.shape)) * 2 for a in pk + pv)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots = arg((S,), jnp.int32)
+    if entry == "decode":
+        lowered = bd.make_decode_chunk(arch, 4).lower(
+            params, tuple(pk), tuple(pv), slots, slots,
+            arg((S, 2, nb), jnp.int32))
+    else:
+        scalar = arg((), jnp.int32)
+        lowered = bd.make_prefill(arch, 512).lower(
+            params, tuple(pk), tuple(pv), slots, slots, scalar,
+            arg((2, nb), jnp.int32), arg((512,), jnp.int32), scalar, scalar,
+            scalar, scalar)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("paged_attention") >= (7 if entry == "decode" else 0)
+    assert "grouped_matmul" in text
+    mem = compiled.memory_analysis()
+    # the pools and the slot scalars are donated: aliased, not copied
+    assert mem.alias_size_in_bytes >= pool
+    total = weights + pool + mem.temp_size_in_bytes
+    assert total < 14.5 * 2 ** 30, (weights, pool, mem.temp_size_in_bytes)
