@@ -45,9 +45,13 @@ lanes): the full plane (4 K/V heads in the 8 rows ``pool_rows`` gives,
 heads, 8 query heads each; the table's entries under a slot's window are
 the trash block, as the engine's window chains leave them), against the
 PUBLISHED bytes (``chipbench/mixed_kv_bytes.py``: 2,560 and 5,120 B a
-position); and one layer's attention of a 512-row prefill piece at the
-end of a 9,000-token chain, both kinds (the dense spelling one K/V head
-at a time).
+position); and one layer's attention of a 512-row prefill piece that
+ends at 512 | 3,500 | 9,000 positions, both kinds, through ``attend``
+(the chain walk of ``kernels/chain_attention.py`` since PR 47) beside the
+dense spelling.  ``--only rungs`` times the same at ``think_decode``'s and
+``chat_moe``'s planes by rung (128 | 256 | 512 rows over chains of 2,048
+positions), the rule at zero so that every one walks: what set
+``kernels.paged_attention.CHAIN_SCORE_BYTES``.
 
 Refuses unless JAX finds a TPU: a number from a CPU run is no device
 metric.
@@ -144,12 +148,31 @@ MIXED = {
                                ctx=(3000, 12000), kind="window"),
     "long_reason_full_piece": dict(S=1, W=512, NB=416, blocks=9985, rows=8,
                                    hk=4, group=16, window=None, sink=False,
-                                   live=1, ctx=(9000, 9000), kind="full"),
+                                   live=1, ctx=(9000, 9000), kind="full",
+                                   lanes=(192, 256, 128)),
     "long_reason_window_piece": dict(S=1, W=512, NB=416, blocks=505, rows=8,
                                      hk=8, group=8, window=128, sink=True,
                                      live=1, ctx=(9000, 9000),
-                                     kind="window"),
+                                     kind="window", lanes=(192, 256, 128),
+                                     released=True),
 }
+# where a piece of long_reason ends: one that starts a prompt, the cell's
+# mean, a long context
+PIECE_CONTEXTS = (512, 3500, 9000)
+
+# one layer's attention of a prefill piece through ``attend`` at the
+# other paged cells' planes, by rung: the dense spelling beside the walk
+# (``--only rungs``); `hk` K/V rows of 128 lanes in `rows` pool rows
+RUNGS = {
+    f"{cell}_{plane}_piece_{w}": dict(g, W=w, window=window)
+    for cell, g in (
+        ("think_decode", dict(NB=64, blocks=3073, rows=16, hk=10, group=4,
+                              ctx=1500, dtype="float32")),
+        ("chat_moe", dict(NB=64, blocks=6145, rows=8, hk=8, group=6,
+                          ctx=1500, dtype="bfloat16")))
+    for plane, window in (("full", None),
+                          ("window", 512 if cell == "think_decode" else 4096))
+    for w in (128, 256, 512)}
 
 
 def _config(name):
@@ -328,6 +351,8 @@ def measure_mixed(name, calls, peak, seed):
     S, W, NB, B = g["S"], g["W"], g["NB"], BLOCK_TOKENS
     cfg = _config("mimo-v2.5")
     rng = np.random.default_rng(seed)
+    if W > 1:
+        return _mixed_piece(name, calls, peak, rng)
     pool_k = np.zeros((g["blocks"], B, g["rows"], 256), np.float32)
     pool_k[:, :, :g["hk"], :192] = rng.standard_normal(
         (g["blocks"], B, g["hk"], 192), np.float32) * 0.5
@@ -361,36 +386,150 @@ def measure_mixed(name, calls, peak, seed):
     out = {"geometry": name, **{k: g[k] for k in (
         "S", "W", "NB", "rows", "hk", "group", "window", "sink", "live")},
         "live_blocks": live_blocks}
-    if W == 1:
-        qs = jnp.pad(args[0], ((0, 0),) * 3 + ((0, 64),))
-        fn = jax.jit(lambda q_, *a: pa.paged_attention_pallas(
-            q_, *a, interpret=False, out_dtype=jnp.float32,
-            scale=192 ** -0.5, **how))
-        us = _timed(fn, (qs,) + args[1:], calls)
-        live = pos.max(axis=1) >= 0
-        got = np.asarray(fn(qs, *args[1:]))[live]
-        with jax.default_matmul_precision("highest"):
-            want = np.asarray(jax.jit(lambda q_, *a: pa.paged_attention_ref(
-                q_, *a, out_dtype=jnp.float32, scale=192 ** -0.5,
-                **how))(qs, *args[1:]))[live]
-        out.update(rel_err_vs_xla_ref=float(
-            np.abs(got - want).max() / np.abs(want).max()),
-            us_a_live_block=us / live_blocks)
-    else:
+    qs = jnp.pad(args[0], ((0, 0),) * 3 + ((0, 64),))
+    fn = jax.jit(lambda q_, *a: pa.paged_attention_pallas(
+        q_, *a, interpret=False, out_dtype=jnp.float32,
+        scale=192 ** -0.5, **how))
+    us = _timed(fn, (qs,) + args[1:], calls)
+    live = pos.max(axis=1) >= 0
+    got = np.asarray(fn(qs, *args[1:]))[live]
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(lambda q_, *a: pa.paged_attention_ref(
+            q_, *a, out_dtype=jnp.float32, scale=192 ** -0.5,
+            **how))(qs, *args[1:]))[live]
+    out.update(rel_err_vs_xla_ref=float(
+        np.abs(got - want).max() / np.abs(want).max()),
+        us_a_live_block=us / live_blocks, us_a_call=us,
+        roofline_pct=100.0 * least * 1e6 / us)
+    return out
+
+
+def _mixed_piece(name, calls, peak, rng):
+    """One layer's attention of a 512-row piece on a plane of
+    ``mimo25.long_reason`` through ``attend``, by where the piece ends
+    (``PIECE_CONTEXTS``), beside the dense spelling."""
+    import jax.numpy as jnp
+
+    from chipbench import mixed_kv_bytes
+
+    g = MIXED[name]
+    cfg = _config("mimo-v2.5")
+    sink = (jnp.asarray(rng.uniform(4.0, 6.5, 64), jnp.float32)
+            if g["sink"] else None)
+
+    def least(ctx):
         # a piece's rows all read the chain once: the bytes of its last
-        # row, the operations of all of them
+        # row (a window plane: the window and the piece), the operations
+        # of all of them
         ops = sum(mixed_kv_bytes.paged_call(cfg, g["kind"], n)[0]
-                  for n in contexts)
-        nbytes = mixed_kv_bytes.paged_call(cfg, g["kind"], contexts[-1])[1]
+                  for n in range(ctx - g["W"] + 1, ctx + 1))
+        nbytes = mixed_kv_bytes.paged_call(cfg, g["kind"], ctx)[1]
         if g["window"]:
-            nbytes = (W + g["window"]) * mixed_kv_bytes.position_bytes(
-                cfg, "window")
-        least = max(nbytes / peak["hbm_bytes_per_s"],
-                    ops / peak["bf16_flops_per_s"])
-        fn = jax.jit(lambda *a: pa.attend(*a, **how))
-        us, _ = _busy_us(lambda last, *a: fn(*a), None, args, calls)
-        out.update(us_a_row=us / W)
-    out.update(us_a_call=us, roofline_pct=100.0 * least * 1e6 / us)
+            nbytes = (min(g["W"] + g["window"], ctx)
+                      * mixed_kv_bytes.position_bytes(cfg, "window"))
+        return max(nbytes / peak["hbm_bytes_per_s"],
+                   ops / peak["bf16_flops_per_s"])
+
+    out = {"geometry": name, **{k: g[k] for k in (
+        "S", "W", "NB", "rows", "hk", "group", "window", "sink")}}
+    out.update(_piece(g, rng,
+                      dict(group=g["group"], window=g["window"], sink=sink),
+                      PIECE_CONTEXTS, calls, least, None))
+    return out
+
+
+def _piece_args(g, rng, ctx):
+    """A plane's pools and ONE slot's piece of ``W`` rows that ends at
+    position ``ctx - 1``: ``hk`` K/V heads in ``rows`` pool rows, keys of
+    ``lanes[0]`` lanes stored at ``lanes[1]`` over values of
+    ``lanes[2]`` (128 all three unless stated); with ``released`` the
+    table's entries under the piece's window name the trash block."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    W, NB, B, hk = g["W"], g["NB"], BLOCK_TOKENS, g["hk"]
+    dk, dks, dv = g.get("lanes", (128, 128, 128))
+    pool_k = np.zeros((g["blocks"], B, g["rows"], dks), np.float32)
+    pool_k[:, :, :hk, :dk] = rng.standard_normal(
+        (g["blocks"], B, hk, dk), np.float32) * 0.5
+    pool_v = np.zeros((g["blocks"], B, g["rows"], dv), np.float32)
+    pool_v[:, :, :hk] = rng.standard_normal(
+        (g["blocks"], B, hk, dv), np.float32) * 0.5
+    q = rng.standard_normal((1, W, hk * g["group"], dk), np.float32) * 0.5
+    table = np.zeros((1, NB), np.int32)
+    pos = (ctx - W + np.arange(W, dtype=np.int32))[None]
+    lo = (max(int(pos[0, 0]) - g["window"] + 1, 0) // B
+          if g.get("released") else 0)
+    n = (ctx - 1) // B + 1
+    table[0, lo:n] = rng.permutation(np.arange(1, g["blocks"]))[:n - lo]
+    return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(pool_k, jnp.bfloat16),
+            jnp.asarray(pool_v, jnp.bfloat16), jnp.asarray(table),
+            jnp.asarray(pos))
+
+
+def _piece(g, rng, how, contexts, calls, least, rule):
+    """One layer's attention of a piece of ``g`` by where it ends, through
+    ``attend`` with ``CHAIN_SCORE_BYTES`` at ``rule`` (``None``: as the
+    module has it) beside the dense spelling (the rule out of reach):
+    device busy microseconds a call, and their worst difference on the
+    chip (the dense one at the highest matmul precision)."""
+    import jax
+
+    import numpy as np
+
+    from paddle_tpu.kernels import paged_attention as pa
+
+    def spelled(rule):
+        def fn(*a):
+            # read at trace time, like every constant of the module
+            keep = pa.CHAIN_SCORE_BYTES
+            pa.CHAIN_SCORE_BYTES = keep if rule is None else rule
+            try:
+                return pa.attend(*a, **how)
+            finally:
+                pa.CHAIN_SCORE_BYTES = keep
+        return jax.jit(fn)
+
+    walk, dense = spelled(rule), spelled(float("inf"))
+    out = {}
+    for ctx in contexts:
+        args = _piece_args(g, rng, ctx)
+        us, _ = _busy_us(lambda last, *a: walk(*a), None, args, calls)
+        us_dense, _ = _busy_us(lambda last, *a: dense(*a), None, args,
+                               calls)
+        got = np.asarray(walk(*args), np.float32)
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(dense(*args), np.float32)
+        out[f"ctx_{ctx}"] = {
+            "us_a_call": us, "us_a_row": us / args[0].shape[1],
+            "us_dense": us_dense,
+            "roofline_pct": 100.0 * least(ctx) * 1e6 / us if least else None,
+            "rel_err_vs_dense": float(np.abs(got - want).max()
+                                      / np.abs(want).max())}
+    return out
+
+
+def measure_rung(name, calls, seed):
+    """One layer's attention of a prefill piece of ``think_decode`` or
+    ``chat_moe`` through ``attend``: the dense spelling beside the walk,
+    whichever ``attend`` chooses."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.kernels import paged_attention as pa
+
+    g = RUNGS[name]
+    W, NB, B = g["W"], g["NB"], BLOCK_TOKENS
+    rng = np.random.default_rng(seed)
+    how = dict(group=g["group"], window=g["window"],
+               out_dtype=jnp.dtype(g["dtype"]))
+    out = {"geometry": name, **{k: g[k] for k in (
+        "W", "NB", "rows", "hk", "group", "window")},
+        "dense_score_bytes": 4 * W * g["group"] * g["rows"] * NB * B,
+        "attend_is": ("walk" if pa.walks_chain(W, g["group"] * g["rows"],
+                                               NB * B) else "dense")}
+    # the walk whatever the rule says, beside the dense spelling
+    out.update(_piece(g, rng, how, (g["ctx"],), calls, None, 0))
     return out
 
 
@@ -556,6 +695,8 @@ def main():
         names = [n for n in names if n != "writes"] + list(WRITES)
     if "mixed" in names:
         names = [n for n in names if n != "mixed"] + list(MIXED)
+    if "rungs" in names:
+        names = [n for n in names if n != "rungs"] + list(RUNGS)
     if "latent" in names:
         names = ([n for n in names if n != "latent"] + list(LATENT)
                  + list(PIECES))
@@ -571,6 +712,8 @@ def main():
                     line = measure_mixed(name, args.calls, peak, args.seed)
                 elif name in PIECES:
                     line = measure_piece(name, args.calls, args.seed)
+                elif name in RUNGS:
+                    line = measure_rung(name, args.calls, args.seed)
                 else:
                     line = measure(name, args.calls, peak, args.seed)
             except Exception as e:  # noqa: BLE001 - a geometry Mosaic refuses

@@ -9,7 +9,7 @@ the engine's ``serving.hbm_high_water_bytes`` gauge holds).
 
     chiprun -- python3 benchmarks/prefill_walk.py --cell brumby14b.doc_continue \
         [--widths 128,256,512,1024] [--context 2048] [--pieces 6] \
-        [--out chiprun_out/prefill_walk.jsonl]
+        [--spelling rule|dense|walk] [--out chiprun_out/prefill_walk.jsonl]
 
 The engine is the cell's own (``chipbench``'s family module builds the
 weights from ``--seed`` and the engine from the traffic file's
@@ -18,7 +18,12 @@ size.  A piece of ``W`` real rows is sent for slot 0 at position
 ``--context``, so it attends a chain of that many cached positions (or
 advances a state that old); the rows before it are whatever the pool
 holds, which costs what real rows cost.  The width need not be a rung of
-the engine's ladder: what a rung WOULD cost is the question.  One
+the engine's ladder: what a rung WOULD cost is the question.
+``--spelling`` says how a wide window over a K/V plane attends: as
+``kernels.paged_attention.attend``'s rule has it (the default), every one
+``dense`` (the rule out of reach) or every one a ``walk`` of the chain
+(the rule at zero); it is the module's constant that is set, before
+anything compiles: the program has no such option.  One
 process a cell (a second cell's weights do not fit beside the first's):
 put the calls of several cells in one ``chiprun`` command.
 Refuses unless JAX finds a TPU: a number from a CPU run is no device
@@ -99,6 +104,7 @@ def measure(eng, cell, width, args, vocab):
     ms = 1e3 * (time.perf_counter() - t0) / args.pieces
     stats = eng.stats()
     line = {"cell": cell, "width": width, "context": args.context,
+            "spelling": args.spelling,
             "pieces": args.pieces, "ms_a_piece": round(ms, 4),
             "ms_a_row": round(ms / width, 6),
             "compile_s": round(eng.compile_seconds[label], 2),
@@ -119,6 +125,9 @@ def main(argv=None):
     ap.add_argument("--pieces", type=int, default=6)
     ap.add_argument("--seed", type=int, default=43)
     ap.add_argument("--top", type=int, default=8)
+    ap.add_argument("--spelling", default="rule",
+                    choices=("rule", "dense", "walk"),
+                    help="how a wide window over a K/V plane attends")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
 
@@ -126,8 +135,13 @@ def main(argv=None):
 
     from chipbench import device, families
     from chipbench import run as bench_run
+    from paddle_tpu.kernels import paged_attention
     from paddle_tpu.observability.metrics import MetricsRegistry
 
+    if args.spelling != "rule":
+        # read at trace time, like every constant of that module
+        paged_attention.CHAIN_SCORE_BYTES = (
+            0 if args.spelling == "walk" else float("inf"))
     if jax.default_backend() != "tpu":
         raise SystemExit("prefill_walk: no TPU here; a number from a CPU "
                          "run is no device metric")

@@ -3,13 +3,14 @@
 
 One op, two targets, one numerics oracle: every fused op class
 (``flash_attention``, ``fused_ce``, ``paged_attention``,
-``grouped_matmul``, ``retention``) resolves
+``chain_attention``, ``grouped_matmul``, ``retention``) resolves
 through :mod:`.registry` to ``pallas_tpu`` (the Mosaic kernels — native
 on TPU, interpret mode in CPU tests) or ``xla_ref`` (:mod:`.xla_ref` —
 the shape-complete pure-XLA reference every backend is tested against,
 with the documented cross-backend tolerances in ``ORACLE_TOL``).  How a
-serving row attends through the block table (streaming kernel or dense
-gather, by window width) is :func:`.paged_attention.attend`'s to decide.
+serving row attends through the block table (streaming kernel, dense
+gather or a walk of the chain in tiles, by the call's shapes) is
+:func:`.paged_attention.attend`'s to decide.
 
 Selection: ``PADDLE_TPU_KERNEL_BACKEND=auto|pallas_tpu|xla_ref``
 (global), ``PADDLE_TPU_KERNEL_BACKEND_<OP>`` (per op class), explicit
@@ -28,6 +29,7 @@ from .registry import (
 from .xla_ref import ORACLE_TOL, oracle_tol
 from . import xla_ref  # registers the oracle backend
 from . import paged_attention  # registers the paged-attention op class
+from . import chain_attention  # registers a wide window's chain walk
 from . import grouped_matmul  # registers the grouped matrix product
 from . import retention  # registers power retention's step and chunk
 

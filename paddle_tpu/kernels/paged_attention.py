@@ -8,18 +8,30 @@ block, one physical block (or a small group) in flight at a time, the
 logical view ``pool[table] -> [S, T, h, dh]`` never built.
 
 The serving step (``serving/batched_decode._Cache``) calls ONE
-function, :func:`attend`, which chooses the spelling from what it can
-observe at trace time and from nothing else (no environment variable,
-no tuner, no file):
+function, :func:`attend`, which chooses among THREE spellings from the
+shapes it can observe at trace time and from nothing else (no
+environment variable, no tuner, no file, no architecture's name):
 
-* a window of ``DENSE_WINDOW`` rows or more (a prefill piece) gathers
-  the chain ONCE and attends it densely: the ``xla_ref`` spelling with
-  one step over the whole chain;
-* a narrower window (decode's ``W = 1``, a speculative ``k + 1``, a
-  narrow prefill piece) streams blocks through whatever the registry
-  resolves for ``paged_attention``: the Mosaic kernel on a TPU (its loop
-  or its grid form by ``_block_is_sliceable``), the ``xla_ref`` scan
-  elsewhere.
+* a window narrower than ``DENSE_WINDOW`` rows (decode's ``W = 1``, a
+  speculative ``k + 1``, a narrow prefill piece) STREAMS blocks through
+  whatever the registry resolves for ``paged_attention``: the Mosaic
+  kernel on a TPU (its loop or its grid form by
+  ``_block_is_sliceable``), the ``xla_ref`` scan elsewhere;
+* a wider window (a prefill piece) gathers the chain ONCE and attends
+  it DENSELY (``dense_window``): the ``xla_ref`` spelling with one step
+  over the whole chain, float32 scores ``[rows, NB x B]`` through HBM
+  whatever the context.  That is the fastest spelling while the scores
+  stay in the chip's fast memory (a 32-row piece over 768 positions is
+  6 MB of them);
+* a wide window over a K/V plane whose dense scores would reach
+  ``CHAIN_SCORE_BYTES`` (``walks_chain``: folded rows x the chain's
+  positions x 4 bytes) WALKS its chain instead: the ``chain_attention``
+  op class (``kernels/chain_attention.py``), a blocked online-softmax
+  walk in tiles from the plane's lower bound up to the context and no
+  further, the scores never outside VMEM; on a TPU a Mosaic kernel (HLO
+  name ``chain_attention``), off it the dense spelling above, so a CPU
+  program lowers to what it always lowered to.  A LATENT plane
+  (``pool_v=None``) keeps the dense spelling whatever its size.
 
 Calling convention (both backends)::
 
@@ -138,7 +150,9 @@ from ..ops.pallas_attention import LSE_LANES
 from .registry import register_kernel, resolve
 from .xla_ref import NEG_INF
 
-__all__ = ["attend", "DENSE_WINDOW", "DENSE_SCORE_BYTES", "key_lanes",
+__all__ = ["attend", "CHAIN_SCORE_BYTES", "DENSE_WINDOW", "DENSE_SCORE_BYTES",
+           "dense_entries", "dense_window", "key_lanes", "walks_chain",
+           "window_entries",
            "latent_attention_pallas", "latent_lanes", "paged_attention_ref", "paged_attention_pallas",
            "pool_rows", "softmax_updates", "write"]
 
@@ -150,15 +164,30 @@ __all__ = ["attend", "DENSE_WINDOW", "DENSE_SCORE_BYTES", "key_lanes",
 # PR 26).
 DENSE_WINDOW = 8
 
-# A dense window makes float32 scores ``[S, W x group, rows, NB x B]``
-# over the whole chain (the pool's rows, those ``pool_rows`` added among
-# them).  Past this many bytes (a 512-row piece of 64 query heads over a
-# chain of 13,312 positions is 1.7 GB a layer, 3.5 GB with the rows
-# ``pool_rows`` pads a 4-head plane to) it goes one K/V head at a time
-# over the heads the plane really has, and a plane with a lower bound
-# gathers the entries its window can see and no others
-# (``_dense_by_head``).  Read at trace time, like ``DENSE_WINDOW``; the
-# widest dense window of the cells that never pass it is 0.3 GB.
+# From this many bytes of dense float32 scores up (``walks_chain``:
+# folded rows x the chain's positions x 4) a wide window over a K/V
+# plane WALKS its chain in tiles instead (``kernels/chain_attention.py``:
+# the Mosaic flash walk on a TPU; off it the op class resolves to the
+# dense spelling, so nothing changes there).  The chip's VMEM: while the
+# scores fit it the one dense step is the fastest spelling, past it they
+# go through HBM.  Measured alone on the chip, one layer's attention of
+# a piece at 1,500 positions of a 2,048-position chain, dense | walk in
+# us (my chip runs, PR 47; benchmarks/paged_walk.py --only rungs,
+# RESULTS.md): chat_moe's planes (8 K/V rows x group 6) 128 rows, 48 MiB:
+# 82 | 86; 256 rows, 96 MiB: 153 | 157; 512 rows, 192 MiB: 913 | 315;
+# think_decode's (10 K/V rows in 16 pool rows x group 4) 128 rows, 64
+# MiB: 101 | 86 full, 46 window; 256 rows, 128 MiB: 636 | 131; 512 rows,
+# 256 MiB: 1,198 | 237.  mimo25.long_reason's 512-row pieces over 13,312
+# positions are 3.5 GB: 7,430 | 390 to 1,650 by context.
+CHAIN_SCORE_BYTES = 128 << 20
+
+# The dense spelling (``dense_window``: what the chip ran before the
+# walk, and what a program off the TPU runs still) makes float32 scores
+# ``[S, W x group, rows, NB x B]`` over the whole chain (the pool's
+# rows, those ``pool_rows`` added among them).  Past this many bytes it
+# goes one K/V head at a time over the heads the plane really has, and a
+# plane with a lower bound gathers the entries its window can see and no
+# others (``_dense_by_head``).  Read at trace time, like ``DENSE_WINDOW``.
 DENSE_SCORE_BYTES = 1 << 30
 
 # Blocks the Mosaic loop of two rows or more keeps in VMEM: the one whose
@@ -189,12 +218,16 @@ def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
     ``None`` the plane is a LATENT one (module docstring): ``pool_k
     [blocks, B, L]``, ``q [S, W, h, L]`` -> ``[S, W, h, value_lanes]``.
 
-    The spelling follows the window's width and the platform, both seen
-    at trace time (module docstring): ``W >= DENSE_WINDOW`` is the
-    ``xla_ref`` spelling with ONE step over the whole chain (a lower
-    bound is a mask there); a narrower window streams blocks with online
-    softmax through the backend the registry resolves (the Mosaic loop
-    starts at the window's first block).
+    The spelling follows the call's shapes and the platform, both seen
+    at trace time (module docstring): under ``DENSE_WINDOW`` rows the
+    window streams blocks with online softmax through the backend the
+    registry resolves for ``paged_attention`` (the Mosaic loop starts at
+    the window's first block); from there up it is the ``xla_ref``
+    spelling with ONE step over the whole chain (``dense_window``; a
+    lower bound is a mask there); and a K/V plane whose dense scores
+    would reach ``CHAIN_SCORE_BYTES`` (``walks_chain``) walks its chain
+    in tiles through the ``chain_attention`` op class, which off the TPU
+    is that same dense step.
 
     A K array may store MORE lanes than a key has (``key_lanes``: a key
     of 192 lanes in 256) and a V array other lanes than the K array: the
@@ -202,10 +235,7 @@ def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
     by the query's OWN width unless ``scale`` says otherwise, and the
     context has the V array's lanes.  ``sink [h]`` float32 is one more
     logit a query head in every row's softmax, which takes mass and adds
-    no value (module docstring).  A dense window whose float32 scores
-    over the whole chain would pass ``DENSE_SCORE_BYTES`` goes one K/V
-    head at a time, over the window's own entries where the plane has a
-    lower bound (``_dense_by_head``)."""
+    no value (module docstring)."""
     how = dict(group=group, window=window, scale=scale, out_dtype=out_dtype)
     if pool_v is None:
         how["value_lanes"] = value_lanes
@@ -216,14 +246,32 @@ def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
                     + ((0, pool_k.shape[-1] - q.shape[-1]),))
     if sink is not None:
         how["sink"] = sink
+    if pool_v is not None and walks_chain(
+            q.shape[1], _folded_rows(q, pool_k, group),
+            table.shape[1] * pool_k.shape[1]):
+        return resolve("chain_attention").impl.call(
+            q, pool_k, pool_v, table, pos, **how)
     if q.shape[1] >= DENSE_WINDOW:
-        if pool_v is not None and _dense_score_bytes(
-                q, pool_k, table, group) > DENSE_SCORE_BYTES:
-            return _dense_by_head(q, pool_k, pool_v, table, pos, **how)
-        return resolve("paged_attention", backend="xla_ref").impl.call(
-            q, pool_k, pool_v, table, pos, block_step=table.shape[1], **how)
+        return dense_window(q, pool_k, pool_v, table, pos, **how)
     return resolve("paged_attention").impl.call(q, pool_k, pool_v, table,
                                                 pos, **how)
+
+
+def dense_window(q, pool_k, pool_v, table, pos, block_step=None,
+                 interpret=None, **how):
+    """The dense spelling of a wide window: the ``xla_ref`` paged
+    spelling with ONE step over the whole chain or, for a K/V plane
+    whose float32 scores would pass ``DENSE_SCORE_BYTES``, one K/V head
+    at a time (``_dense_by_head``).  Also the ``xla_ref`` backend of the
+    ``chain_attention`` op class (``kernels/chain_attention.py``), whose
+    signature it keeps: ``block_step`` and ``interpret`` are ignored."""
+    del block_step, interpret
+    if pool_v is not None and _score_bytes(
+            q.shape[1], _folded_rows(q, pool_k, how.get("group", 1)),
+            table.shape[1] * pool_k.shape[1]) > DENSE_SCORE_BYTES:
+        return _dense_by_head(q, pool_k, pool_v, table, pos, **how)
+    return resolve("paged_attention", backend="xla_ref").impl.call(
+        q, pool_k, pool_v, table, pos, block_step=table.shape[1], **how)
 
 
 def pool_rows(heads, dtype):
@@ -270,6 +318,40 @@ def softmax_updates(rows):
     return 1
 
 
+def walks_chain(width, rows, positions):
+    """Whether ``attend`` sends a window of ``width`` positions over a
+    K/V plane to the ``chain_attention`` op class: ``rows`` the folded
+    query rows of ONE position (slots x the K/V group x the pool's rows,
+    those ``pool_rows`` added among them: what the dense step scores),
+    ``positions`` the chain's capacity (``NB x B``).  Stated here for
+    whoever counts what a piece attends
+    (``ServingEngine._count_prefill_entries``): the engine does not
+    guess."""
+    return (width >= DENSE_WINDOW
+            and _score_bytes(width, rows, positions) >= CHAIN_SCORE_BYTES)
+
+
+def window_entries(entries, block, width, window):
+    """Table entries that hold a key some row of a window of ``width``
+    ascending rows may attend: the chain's ``entries``, or with a lower
+    bound those of its ``window + width - 1`` positions (a block more at
+    either end)."""
+    if window is None:
+        return entries
+    return min(entries, (width + window - 2) // block + 2)
+
+
+def dense_entries(width, rows, entries, block, window):
+    """Table entries the DENSE spelling of a window gathers and scores
+    (``dense_window``): the whole chain whatever the context, or, one
+    K/V head at a time (past ``DENSE_SCORE_BYTES``), a lower bound's own
+    entries.  ``rows`` as ``walks_chain`` takes them.  What a chain walk
+    is measured against (``ServingEngine._count_prefill_entries``)."""
+    if _score_bytes(width, rows, entries * block) > DENSE_SCORE_BYTES:
+        return window_entries(entries, block, width, window)
+    return entries
+
+
 def _fold_group(q, pos, group, rows):
     """``q [S, W, hk * group, dh]`` -> ``[S, W * group, rows, dh]``, ``pos``
     repeated to match, and the inverse to apply to the context: query
@@ -311,11 +393,17 @@ def _fold_sink(sink, group, rows, W):
     return jnp.tile(sk, (W, 1))
 
 
-def _dense_score_bytes(q, pool_k, table, group):
-    """Bytes of the float32 scores the one-step dense spelling makes."""
-    S, W = q.shape[:2]
-    return (4 * S * W * group * pool_k.shape[2]
-            * table.shape[1] * pool_k.shape[1])
+def _score_bytes(width, rows, positions):
+    """Bytes of the float32 scores the one-step dense spelling makes of
+    a window of ``width`` positions, ``rows`` folded query rows each,
+    over a chain of ``positions``."""
+    return 4 * width * rows * positions
+
+
+def _folded_rows(q, pool_k, group):
+    """The folded query rows of ONE position of a call, as the dense
+    step scores them: slots x the K/V group x the pool's rows."""
+    return q.shape[0] * group * pool_k.shape[2]
 
 
 def _dense_by_head(q, pool_k, pool_v, table, pos, group=1, window=None,
@@ -342,7 +430,7 @@ def _dense_by_head(q, pool_k, pool_v, table, pos, group=1, window=None,
     tbl, n = table.astype(jnp.int32), NB
     first = jnp.zeros((S,), jnp.int32)
     if window is not None:
-        n = min(NB, (W + window - 2) // B + 2)
+        n = window_entries(NB, B, W, window)
         first = jnp.clip(jnp.maximum(pos[:, 0] - window + 1, 0) // B,
                          0, NB - n)
         tbl = jax.vmap(lambda row, f: jax.lax.dynamic_slice_in_dim(

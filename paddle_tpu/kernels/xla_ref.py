@@ -56,6 +56,15 @@ ORACLE_TOL = {
     # three bfloat16 pieces; the bounds are paged_attention's
     ("paged_latent_attention", "float32"): {"fwd": 2e-4, "grad": None},
     ("paged_latent_attention", "bfloat16"): {"fwd": 2e-2, "grad": None},
+    # a wide window's chain walk against the dense spelling it replaces
+    # on the chip: the flash kernels' blocked online softmax over the
+    # gathered chain (float32: reassociation only; bfloat16: the scores
+    # out of bfloat16 operands and ``p`` rounded once to bfloat16 for the
+    # one pass that weighs the values, 2^-9 relative a weight, which is
+    # what the dense einsum at the chip's default precision rounds too);
+    # the bounds are paged_attention's
+    ("chain_attention", "float32"): {"fwd": 2e-4, "grad": None},
+    ("chain_attention", "bfloat16"): {"fwd": 2e-2, "grad": None},
     # the grouped matrix product is inference-only: the two backends
     # multiply the same rows by the same matrices and differ by the
     # order of one float32 sum over k (float32: a few ulp of a sum of k

@@ -570,6 +570,13 @@ class ServingEngine:
             (w, n, arch.plane_block_bytes(bound[w], 1, itemsize),
              arch.plane_rows_per_entry(bound[w]))
             for w, n in arch.plane_reads]
+        # (window, calls, folded query rows a position) of a prefill
+        # piece's calls on K/V planes, for _count_prefill_entries
+        self._piece_reads = [] if arch.latent_planes else [
+            (w, n, arch.plane_rows_per_entry(bound[w])
+             * arch.plane_block_shapes(
+                 bound[w], self.block_tokens, self.compute_dtype)[0][1])
+            for w, n in arch.plane_reads]
         # span attributes that say in which form attention runs, for an
         # architecture with latent planes or with retention layers
         self._form_attrs = (
@@ -626,6 +633,36 @@ class ServingEngine:
             help="seconds decoding requests spent from one advance to "
                  "the next (stalled + their chunks): stalled_seconds' "
                  "denominator").inc(max(0.0, live))
+
+    def _count_prefill_entries(self, pieces):
+        """Prefill pieces were dispatched: for every call that WALKS its
+        chain (``kernels.paged_attention.walks_chain``: a wide window
+        over a K/V plane), the table entries it had to visit (from the
+        entry of the first row's lower bound to the entry of the piece's
+        last position) beside the entries the dense spelling of the same
+        call gathers and scores whatever the context
+        (``paged_attention.dense_entries``: the whole chain, or a lower
+        bound's own entries where it goes one K/V head at a time)."""
+        B, NB = self.block_tokens, self.blocks_per_slot
+        attended = dense = 0
+        for w, _, at, _ in pieces:
+            for window, n, rows in self._piece_reads:
+                if not _paged.walks_chain(w, rows, NB * B):
+                    continue
+                first = 0 if window is None else max(at - window + 1, 0)
+                last = min(at + w, NB * B) - 1
+                attended += n * (last // B - first // B + 1)
+                dense += n * _paged.dense_entries(w, rows, NB, B, window)
+        if dense:
+            text = ("table entries of the prefill pieces' chain walks, a "
+                    "call: kind=attended from a piece's lower bound to its "
+                    "last position, kind=chain what the dense spelling of "
+                    "the call gathers (the whole chain, or a lower bound's "
+                    "own entries one K/V head at a time)")
+            self._reg.counter("serving.prefill_entries", kind="attended",
+                              help=text).inc(attended)
+            self._reg.counter("serving.prefill_entries", kind="chain",
+                              help=text).inc(dense)
 
     def _count_paged_entries(self):
         """A decode chunk is about to run: of the ``max_slots x
@@ -1682,6 +1719,7 @@ class ServingEngine:
             "serving.prefill_real_tokens",
             help="prompt-suffix tokens prefill computed, padding left "
                  "out").inc(suffix)
+        self._count_prefill_entries(pieces)
         for w, _, at, _ in pieces:
             self._reg.counter(
                 "serving.prefill_pieces", width=w,

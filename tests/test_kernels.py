@@ -1188,15 +1188,16 @@ def test_global_env_fallback_counted_once_per_resolution(monkeypatch):
     assert count() == c0 + 2
 
 
-def test_two_backends_five_op_classes_and_any_platform_is_served():
+def test_two_backends_six_op_classes_and_any_platform_is_served():
     """What the registry holds since the GPU lowerings and the gather op
-    class went and the grouped matrix product and retention came: two
-    backends, five op classes, an auto order for the TPU and the CPU; a platform with
-    no order of its own is served by the oracle for every op class."""
+    class went and the grouped matrix product, retention and a wide
+    window's chain walk came: two backends, six op classes, an auto order
+    for the TPU and the CPU; a platform with no order of its own is
+    served by the oracle for every op class."""
     assert kernels.BACKENDS == ("pallas_tpu", "xla_ref")
     assert sorted(kernels.registered_op_classes()) == [
-        "flash_attention", "fused_ce", "grouped_matmul", "paged_attention",
-        "retention"]
+        "chain_attention", "flash_attention", "fused_ce", "grouped_matmul",
+        "paged_attention", "retention"]
     assert set(kernels.AUTO_ORDER) == {"tpu", "cpu"}
     for op in kernels.registered_op_classes():
         assert {b for b, _, _ in available_backends(op)} == set(

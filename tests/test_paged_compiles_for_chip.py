@@ -110,6 +110,56 @@ LATENT = {
 }
 
 
+# the prefill rungs of the other paged cells by what ``attend`` chooses on
+# a TPU: (window rows, pool blocks, pool rows, K/V rows, query rows a K/V
+# row, lower bound, dtype of the context, walks its chain)
+RUNGS = {
+    "think_decode_512_rows_window_plane": (512, 3073, 16, 10, 4, 512,
+                                           jnp.float32, True),
+    "think_decode_256_rows_full_plane": (256, 3073, 16, 10, 4, None,
+                                         jnp.float32, True),
+    "think_decode_128_rows_stay_dense": (128, 3073, 16, 10, 4, None,
+                                         jnp.float32, False),
+    "chat_moe_512_rows_full_plane": (512, 6145, 8, 8, 6, None, None, True),
+    "chat_moe_512_rows_window_4096": (512, 6145, 8, 8, 6, 4096, None, True),
+    "chat_moe_256_rows_stay_dense": (256, 6145, 8, 8, 6, None, None, False),
+    "agent_turns_32_rows_stay_dense": (32, 705, 16, 16, 1, None, None,
+                                       False),
+}
+
+
+@pytest.mark.parametrize("rung", list(RUNGS))
+def test_a_prefill_rung_walks_or_stays_dense_for_v5e(rung, one_chip,
+                                                     monkeypatch):
+    """``attend`` on a TPU at the rungs of ``think_decode`` and
+    ``chat_moe`` (chains of 2,048 positions): dense float32 scores of 128
+    MiB or more walk the chain (``chain_attention``, a Mosaic call and no
+    float32 score in HBM), smaller ones keep the dense step and no Mosaic
+    call at all."""
+    from paddle_tpu.kernels import paged_attention as pa
+
+    W, blocks, rows, hk, group, window, out, walks = RUNGS[rung]
+    NB = 24 if blocks == 705 else 64
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert pa.walks_chain(W, group * rows, NB * 32) == walks
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(lambda q, k, v, t, p: pa.attend(
+        q, k, v, t, p, group=group, window=window, out_dtype=out)).lower(
+        arg((1, W, hk * group, 128), jnp.bfloat16),
+        arg((blocks, 32, rows, 128), jnp.bfloat16),
+        arg((blocks, 32, rows, 128), jnp.bfloat16),
+        arg((1, NB), jnp.int32), arg((1, W), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert ("chain_attention" in text) == walks
+    assert ("tpu_custom_call" in text) == walks
+    scores = 4 * W * group * rows * NB * 32
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (scores // 4 if walks else 3 * scores), (temp, scores)
+
+
 @pytest.mark.parametrize("geometry", list(LATENT))
 def test_latent_kernel_compiles_for_v5e(geometry, one_chip):
     """The pool enters the kernel in place (no pool-sized temporary), the

@@ -204,16 +204,23 @@ def test_prefill_runs_the_lm_head_on_one_row(width):
             f"tensor<{DM}x{DM}xf32>") in dots
 
 
-def test_wide_windows_attend_densely_narrow_ones_stream(monkeypatch):
-    """The attention spelling follows the window's width at trace time:
-    from ``DENSE_WINDOW`` rows up one step over the whole chain of the
-    ``xla_ref`` spelling, below it whatever the registry resolves with
-    its own default geometry.  The choice lives with the kernel
-    (``kernels.paged_attention.attend``), not in the serving step."""
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_wide_windows_attend_densely_narrow_ones_stream(monkeypatch,
+                                                        platform):
+    """The attention spelling follows the call's SHAPES at trace time:
+    under ``DENSE_WINDOW`` rows whatever the registry resolves with its
+    own default geometry; from there up one step over the whole chain of
+    the ``xla_ref`` spelling; and from ``CHAIN_SCORE_BYTES`` of dense
+    scores up (folded rows x the chain's positions) the ``chain_attention``
+    op class, which is the Mosaic walk on a TPU and the same dense step
+    off it.  A latent plane stays dense whatever its size.  The choice
+    lives with the kernel (``kernels.paged_attention.attend``), not in
+    the serving step."""
     from paddle_tpu.kernels import paged_attention as _pa
 
     calls = []
     real = _pa.resolve
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
 
     def spy(op, backend=None, **kw):
         ker = real(op, backend=backend, **kw)
@@ -221,20 +228,48 @@ def test_wide_windows_attend_densely_narrow_ones_stream(monkeypatch):
         class Impl:
             @staticmethod
             def call(q, *a, block_step=None, **k):
-                calls.append((q.shape[1], backend, block_step))
+                calls.append((op, q.shape[1], ker.backend, block_step))
+                if ker.backend == "pallas_tpu":
+                    return q            # no chip here: the choice is the test
                 return ker.impl.call(q, *a, block_step=block_step, **k)
 
         return type("K", (), {"impl": Impl, "backend": ker.backend})
 
     monkeypatch.setattr(_pa, "resolve", spy)
-    pool = jnp.zeros((6, B, NH, DM // NH), jnp.float32)
+    dh = DM // NH
+    pool = jnp.zeros((6, B, NH, dh), jnp.float32)
     table = jnp.zeros((1, NB), jnp.int32)
-    for w in (1, _pa.DENSE_WINDOW - 1, _pa.DENSE_WINDOW, 4 * _pa.DENSE_WINDOW):
-        q = jnp.zeros((1, w, NH, DM // NH), jnp.float32)
+    dense_w = _pa.DENSE_WINDOW
+    for w in (1, dense_w - 1, dense_w, 4 * dense_w):
+        q = jnp.zeros((1, w, NH, dh), jnp.float32)
         _pa.attend(q, pool, pool, table, jnp.zeros((1, w), jnp.int32))
-    assert calls == [(1, None, None), (_pa.DENSE_WINDOW - 1, None, None),
-                     (_pa.DENSE_WINDOW, "xla_ref", NB),
-                     (4 * _pa.DENSE_WINDOW, "xla_ref", NB)]
+    # the rule: the same shapes walk once their dense scores pass it
+    assert not _pa.walks_chain(4 * dense_w, NH, NB * B)
+    monkeypatch.setattr(_pa, "CHAIN_SCORE_BYTES",
+                        4 * 4 * dense_w * NH * NB * B)
+    assert _pa.walks_chain(4 * dense_w, NH, NB * B)
+    for w in (dense_w - 1, dense_w, 4 * dense_w):
+        q = jnp.zeros((1, w, NH, dh), jnp.float32)
+        _pa.attend(q, pool, pool, table, jnp.zeros((1, w), jnp.int32))
+    # a latent plane of the same size keeps the dense spelling
+    _pa.attend(jnp.zeros((1, 4 * dense_w, NH, dh), jnp.float32),
+               jnp.zeros((6, B, dh), jnp.float32), None, table,
+               jnp.zeros((1, 4 * dense_w), jnp.int32), value_lanes=dh)
+    streams = "pallas_tpu" if platform == "tpu" else "xla_ref"
+    dense = ("paged_attention", "xla_ref", NB)
+    walk = ([("chain_attention", 4 * dense_w, "pallas_tpu", None)]
+            if platform == "tpu" else
+            [("chain_attention", 4 * dense_w, "xla_ref", None),
+             dense[:1] + (4 * dense_w,) + dense[1:]])
+    assert calls == [
+        ("paged_attention", 1, streams, None),
+        ("paged_attention", dense_w - 1, streams, None),
+        dense[:1] + (dense_w,) + dense[1:],
+        dense[:1] + (4 * dense_w,) + dense[1:],
+        ("paged_attention", dense_w - 1, streams, None),
+        dense[:1] + (dense_w,) + dense[1:],
+        *walk,
+        dense[:1] + (4 * dense_w,) + dense[1:]]
 
 
 def test_engine_counts_pieces_real_and_padded_tokens(monkeypatch):

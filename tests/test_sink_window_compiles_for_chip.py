@@ -2,14 +2,15 @@
 described and not attached, at the cell's own geometry (24 slots x
 13,312 positions, 64 query heads of 192 lanes over 4 or 8 K/V heads,
 keys stored at 256 lanes over values of 128, a window of 128 with a
-sink): the Mosaic paged kernel on both kinds of plane, the dense
-spelling one K/V head at a time for a 512-row piece, the grouped product
+sink): the Mosaic paged kernel on both kinds of plane, the chain walk
+of a 512-row piece (``kernels/chain_attention.py``), the grouped product
 at 16 experts of ``[4096, 2048]``, and the whole decode chunk and widest
 prefill piece of the seven held layers.  What interpret mode cannot show
 (a 192-lane key is where Mosaic objects: it is stored at 256).  Nothing
 runs: a compile that passes is no chip run."""
 
 import os
+import re
 
 import pytest
 
@@ -72,32 +73,38 @@ def test_paged_kernel_compiles_for_v5e(plane, one_chip):
 
 @pytest.mark.parametrize("kind,blocks,group,window", [
     ("full", FULL_BLOCKS, 16, None), ("window", WINDOW_BLOCKS, 8, 128)])
-def test_a_512_row_piece_attends_one_head_at_a_time(kind, blocks, group,
-                                                    window, one_chip):
-    """The dense spelling of a 512-row piece over a chain of 13,312
-    positions: ``attend`` takes the by-head form (the scores of all 64
-    heads at once are 1.7 GB, 3.5 with the padded rows), a head's scores
-    are under 0.5 GB, and a window plane gathers 21 entries, not 416."""
+def test_a_512_row_piece_walks_its_chain(kind, blocks, group, window,
+                                         one_chip, monkeypatch):
+    """A 512-row piece over a chain of 13,312 positions: on a TPU
+    ``attend`` takes the chain walk (``chain_attention``, whose name the
+    decode kernel's readers must not find) at both plane geometries, the
+    key stored at 256 lanes over values of 128, the 4 heads of a full
+    plane in 8 pool rows, a window of 128 with a sink.  What it keeps in
+    HBM is the gathered chain (41 MB of a full plane's 4 heads, 21 + 11
+    entries of a window plane's) and the folded queries: no float32
+    score, where the dense spelling held 0.45 GB a K/V head."""
     from paddle_tpu.kernels import paged_attention as pa
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    hk = 64 // group
     q = arg((1, 512, 64, 192), jnp.bfloat16)
     pk = arg((blocks, B, 8, 256), jnp.bfloat16)
-    assert pa._dense_score_bytes(q, pk, arg((1, NB), jnp.int32),
-                                 group) > pa.DENSE_SCORE_BYTES
+    assert pa.walks_chain(512, group * 8, NB * B)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = jax.jit(lambda q, k, v, t, p, s: pa.attend(
         q, k, v, t, p, group=group, window=window,
         sink=s if window else None)).lower(
         q, pk, arg((blocks, B, 8, 128), jnp.bfloat16),
         arg((1, NB), jnp.int32), arg((1, 512), jnp.int32),
         arg((64,), jnp.float32)).compile()
+    # the readers of the decode kernel look at an instruction's NAME
+    names = re.findall(r"%([\w.\-]+) = ", compiled.as_text())
+    assert any("chain_attention" in n for n in names)
+    assert not any("paged_attention" in n for n in names)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    one_head = 4 * 512 * group * (NB * B if window is None else 21 * B)
-    assert temp < 3.5 * one_head + (64 << 20), (temp, one_head)
-    assert temp < (1536 << 20) // hk * 4
+    one_head_of_scores = 4 * 512 * group * NB * B
+    assert temp < one_head_of_scores // 2 < 256 << 20, temp
 
 
 def test_grouped_matmul_compiles_at_16_experts_of_4096_by_2048(one_chip):
@@ -176,8 +183,13 @@ def test_the_cells_executables_compile_and_fit_a_v5e(entry, one_chip,
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("paged_attention") >= (7 if entry == "decode" else 0)
+    assert ("chain_attention" in text) == (entry != "decode")
     assert "grouped_matmul" in text
     mem = compiled.memory_analysis()
+    if entry != "decode":
+        # the piece's temporaries under what the dense spelling's were
+        # (0.45 GiB, a K/V head's float32 scores over the whole chain)
+        assert mem.temp_size_in_bytes < 0.45 * 2 ** 30, mem.temp_size_in_bytes
     # the pools and the slot scalars are donated: aliased, not copied
     assert mem.alias_size_in_bytes >= pool
     total = weights + pool + mem.temp_size_in_bytes
