@@ -41,6 +41,13 @@ decode step saturated across many requests:
 * **Chunked decode** — ``decode_chunk`` steps run per device call
   (one ``lax.scan``), amortizing dispatch + host sync.  EOS is detected
   on the host after the chunk.
+* **One chunk in flight** — chunk n + 1 is sent before chunk n's
+  tokens are read (``_decode``: ``_dispatch`` / ``_collect``), so the
+  device goes from one chunk to the next while the host fetches, emits,
+  polls the queue and builds the next table.  An end the host can
+  foresee (``max_new``) never rides a chunk more; an EOS hit rides one,
+  its rows thrown away (docs/serving.md "The driver loop keeps one
+  chunk in flight").
 * **SLO-aware scheduling** — the CONTROL half of the goodput loop
   (``serving.scheduler``; PR 11 shipped the measurement half): the
   queue is admitted by least predicted-TTFT slack and requests that
@@ -78,6 +85,12 @@ from . import scheduler as _sched
 from . import speculative as _spec
 
 __all__ = ["Request", "ServingEngine"]
+
+
+# One decode chunk between its dispatch and its collect: the tokens and
+# the stack's tallies its executable returned (on the device until read),
+# {slot: request} of the rows live in it as of dispatch, and when it was sent
+_Chunk = collections.namedtuple("_Chunk", "toks counts reqs sent_t")
 
 
 class Request:
@@ -430,6 +443,10 @@ class ServingEngine:
         # when each slot's request last advanced (first token, then the
         # end of every chunk): serving.stalled_seconds counts from it
         self._slot_advanced = [0.0] * self.max_slots
+        # the decode chunks sent and not yet read, oldest first: at most
+        # the one waited for and one queued behind it (_decode)
+        self._chunks = collections.deque()
+        self._collected_t = 0.0       # when the last chunk's tokens arrived
         self._spec = (_spec.SpecState(self, draft_params, draft_n_layer,
                                       spec_k) if spec_on else None)
 
@@ -612,22 +629,24 @@ class ServingEngine:
             counter=("serving.driver_seconds", labels), event=event,
             **attrs)
 
-    def _account_stall(self, t0, t1):
-        """A decode chunk ran over ``[t0, t1]``: every live request was
-        last advanced at its previous chunk's end (or its first token),
-        waited until ``t0`` while the driver did something else, and is
-        advanced again at ``t1``."""
+    def _account_stall(self, slots, t0, t1):
+        """A decode chunk's clock pair is ``[t0, t1]``: every request it
+        advanced (``slots``) was last advanced at its previous chunk's
+        collect (or its first token), waited until ``t0`` while the
+        device had no chunk of its to run, and is advanced again at
+        ``t1``.  Between back-to-back chunks ``t0`` IS the previous
+        collect, so nothing is stalled."""
         stalled = live = 0.0
-        for s, req in enumerate(self._slots):
-            if req is not None:
-                stalled += t0 - self._slot_advanced[s]
-                live += t1 - self._slot_advanced[s]
-                self._slot_advanced[s] = t1
+        for s in slots:
+            stalled += t0 - self._slot_advanced[s]
+            live += t1 - self._slot_advanced[s]
+            self._slot_advanced[s] = t1
         self._reg.counter(
             "serving.stalled_seconds",
             help="seconds decoding requests waited between their chunks "
-                 "while the driver did something else (admission, "
-                 "another request's prefill, emit)").inc(max(0.0, stalled))
+                 "while the device had none of theirs to run (admission, "
+                 "another request's prefill; an emit only where no chunk "
+                 "was queued behind the one read)").inc(max(0.0, stalled))
         self._reg.counter(
             "serving.live_seconds",
             help="seconds decoding requests spent from one advance to "
@@ -664,12 +683,13 @@ class ServingEngine:
             self._reg.counter("serving.prefill_entries", kind="chain",
                               help=text).inc(dense)
 
-    def _count_paged_entries(self):
-        """A decode chunk is about to run: of the ``max_slots x
+    def _count_paged_entries(self, contexts):
+        """A decode chunk is being sent for ``contexts``, ``[(slot, keys
+        its first step attends)]`` of the rows live in it (``prompt +
+        _sent``: the slot's DISPATCHED position): of the ``max_slots x
         blocks_per_slot`` table entries each paged-attention call spans,
-        how many hold a key its first step attends (position ``prompt +
-        tokens - 1`` and everything before it down to the plane's lower
-        bound, in the live slots only), as the mean over the calls a
+        how many hold such a key (the last and everything before it down
+        to the plane's lower bound), as the mean over the calls a
         token makes; the query rows a call sends through each and the
         softmax updates the kernel makes for them; and the K/V bytes
         those calls have to read.  For retention layers, which have no
@@ -681,15 +701,13 @@ class ServingEngine:
                      "wrote in place: live slots x retention layers x the "
                      "chunk's steps (a slot that finishes inside a chunk "
                      "rides it out on the device)").inc(
-                         self.active_slots * self.arch.retention_layers
+                         len(contexts) * self.arch.retention_layers
                          * self.decode_chunk)
         if not self.arch.planes:
             return                  # no table entry, no K/V byte to count
         B = self.block_tokens
         live = streamed = shared = rows_live = updates = 0
         live_by_kind = {"full": 0, "window": 0}
-        contexts = [(s, req.prompt.shape[0] + len(req.tokens))
-                    for s, req in enumerate(self._slots) if req is not None]
         # only the trie hands two slots one block
         sharing = self.prefix_trie is not None and len(contexts) > 1
         for window, n, token_bytes, rows in self._plane_reads:
@@ -879,14 +897,20 @@ class ServingEngine:
     def idle(self):
         with self._qlock:
             pending = bool(self._queue) or self._inflight > 0
-        return not pending and self.active_slots == 0
+        # a chunk in flight is work outstanding, whoever its rows were for
+        return (not pending and self.active_slots == 0
+                and not self._chunks)
 
     def step(self):
         """One scheduler iteration: admit queued requests into free slots
-        (scheduler-ordered, bucketed suffix prefill), then run one
-        batched decode chunk.  Returns the number of requests finished
-        this iteration (shed requests count — they completed, with
-        ``error`` set).
+        (scheduler-ordered, bucketed suffix prefill), SEND decode chunks
+        until one is queued behind the one waited for (while a slot is
+        still live after them), then READ the oldest chunk's tokens
+        (``_decode``).  So the tokens a chunk computes reach their
+        requests one iteration after it was sent, and ``idle`` is False
+        while any are on the device.  Returns the number of requests
+        finished this iteration (shed requests count — they completed,
+        with ``error`` set).
 
         A device error mid-step leaves the donated pool unusable, so
         it is fatal: the engine aborts — every queued and in-flight
@@ -900,7 +924,7 @@ class ServingEngine:
             try:
                 with self._span("serving.admit", "admit"):
                     finished = self._admit()
-                if self.active_slots:
+                if self.active_slots or self._chunks:
                     finished += self._decode()
             except Exception as e:
                 self._abort(e)
@@ -927,6 +951,10 @@ class ServingEngine:
                     self._spec.release(self, s)
             self._table[:] = 0
             self._free = list(range(self.max_slots))
+            # a chunk in flight dies with the engine (the donated pool it
+            # runs on is not usable again): every request it names is
+            # failed here or had finished before
+            self._chunks.clear()
             for req in pending:
                 req.error = exc
                 req.finish_t = time.perf_counter()
@@ -1171,16 +1199,25 @@ class ServingEngine:
             ).inc()
         return fn
 
-    def _device_table(self):
+    def _device_table(self, live):
         """The block table a decode chunk reads: ``[max_slots, NB]``, or
         ``[max_slots, 2, NB]`` under two kinds of chain (kind 0 the whole
-        chains, kind 1 the window planes': ``arch.chain_kind``)."""
+        chains, kind 1 the window planes': ``arch.chain_kind``); with no
+        plane the live mask ``[max_slots]``.  A slot not among ``live``
+        gets the dead row (every entry the trash block) in every kind:
+        a slot whose request ends inside the chunks already sent still
+        holds its blocks on the host, and must not be stepped again."""
         import jax.numpy as jnp
 
+        keep = np.zeros(self.max_slots, np.int32)
+        keep[list(live)] = 1
+        if self._table.ndim == 1:
+            return jnp.asarray(self._table * keep)
         if not self._windowed:
-            return jnp.asarray(self._table)
+            return jnp.asarray(self._table * keep[:, None])
         return jnp.asarray(np.stack(
-            [self._table, self.window_chains.table], axis=1))
+            [self._table, self.window_chains.table], axis=1)
+            * keep[:, None, None])
 
     def _advance_window(self, slot, first_pos, last_pos):
         """Rows ``first_pos .. last_pos`` of ``slot`` are next: its
@@ -1224,9 +1261,66 @@ class ServingEngine:
             self._reg.gauge("serving.blocks_in_use").set(
                 self._blocks_in_use())
 
+    def _sent(self, slot, req):
+        """Tokens of ``req``, live in ``slot``, that exist: those emitted
+        and the steps of the chunks in flight that name it.  THE
+        definition of where a slot is: its dispatched position (the
+        position its next chunk's first step writes) is ``prompt + _sent
+        - 1``, and it needs another chunk while ``_sent < max_new``.
+        ``len(req.tokens)`` alone is one chunk behind once a chunk is in
+        flight."""
+        return len(req.tokens) + self.decode_chunk * sum(
+            c.reqs.get(slot) is req for c in self._chunks)
+
+    def _runs_on(self):
+        """The slots a chunk sent NOW would step, ``{slot: request}``:
+        those whose request does not end by ``max_new`` inside the chunks
+        already sent.  An end the host can foresee never rides a chunk
+        more; an ``eos_id`` hit, which nobody can foresee, rides one."""
+        return {s: req for s, req in enumerate(self._slots)
+                if req is not None and self._sent(s, req) < req.max_new}
+
     def _decode(self):
+        """The decode half of a driver iteration, with ONE chunk kept in
+        flight: send chunks until one is queued behind the one waited for
+        (as long as some slot is still live after those sent), then read
+        the OLDEST.  In steady decode chunk n + 1 is on the device's
+        queue before chunk n's tokens are asked for, so the device goes
+        from one to the next while the host fetches, emits, polls the
+        queue and builds the next table.  The depth is a fact of the
+        mechanism, not a setting: what is sent beyond the chunk waited
+        for is computed for requests that may have ended (an ``eos_id``)
+        and delays an arrival's admission by a chunk."""
         if self._spec is not None:
+            # the host decides every round's acceptance: a serial loop
             return self._spec_decode()
+        finished = 0
+        while len(self._chunks) < 2 and (reqs := self._runs_on()):
+            # fault injection point (PADDLE_TPU_FAULT=slot_death:n): the
+            # n-th decode chunk kills one active request mid-decode — its
+            # slot and KV blocks must be reclaimed and the driver survive.
+            # What is in flight is read first, so that nobody is failed
+            # with tokens of theirs still on the device
+            if _faults.maybe_fault("serving.decode") == "slot_death":
+                while self._chunks:
+                    finished += self._collect()
+                self._kill_one_slot()
+                continue
+            self._dispatch(reqs)
+        if self._chunks:
+            finished += self._collect()
+        return finished
+
+    def _dispatch(self, reqs):
+        """The FIRST half of a decode chunk for the rows ``reqs``
+        (``_runs_on``), everything the host does
+        before the device can run it: move the window chains, build and
+        upload the table, count what the chunk will read, call the
+        executable and start its results' copies to the host.  Every
+        quantity "at the chunk's start" is taken at the slots' dispatched
+        positions (``_sent``).  Nothing here waits for the device: the
+        pool, the slot scalars and the state it passes are the previous
+        call's outputs, futures while that call runs."""
         if self._decode_fn is None:
             self._decode_fn = self._aot_with_mem_telemetry(
                 _bd.make_decode_chunk(self.arch, chunk=self.decode_chunk,
@@ -1236,76 +1330,110 @@ class ServingEngine:
                 "serving.decode_compiles",
                 help="decode-chunk executables built (one per engine)",
             ).inc()
-        import jax.numpy as jnp
-
-        # fault injection point (PADDLE_TPU_FAULT=slot_death:n): the
-        # n-th decode chunk kills one active request mid-decode — its
-        # slot and KV blocks must be reclaimed and the driver survive
-        if _faults.maybe_fault("serving.decode") == "slot_death":
-            self._kill_one_slot()
-            if not self.active_slots:
-                return 0
-        # one-time AOT compile lands here, outside the timed window the
-        # predictor consumes
-        if self._windowed:
-            # the chunk's steps write positions pos .. pos + chunk - 1 of
-            # each live slot (none past its request's last): the window
-            # chains move there first
-            for s, req in enumerate(self._slots):
-                if req is not None:
-                    at = req.prompt.shape[0] + len(req.tokens) - 1
+        ahead = "1" if self._chunks else "0"
+        with self._span("serving.dispatch", "decode",
+                        steps=self.decode_chunk, active=len(reqs),
+                        ahead=ahead):
+            # keys each row's first step attends: its own position last
+            contexts = [(s, req.prompt.shape[0] + self._sent(s, req))
+                        for s, req in reqs.items()]
+            if self._windowed:
+                # the chunk's steps write positions pos .. pos + chunk - 1
+                # of each live slot (none past its request's last): the
+                # window chains move there first.  A block given back
+                # here may be one a chunk in flight still reads: whoever
+                # is handed it writes it in a LATER call, and the device
+                # runs the calls in order
+                for s, ctx in contexts:
+                    req = reqs[s]
                     self._advance_window(
-                        s, at, min(at + self.decode_chunk,
-                                   req.prompt.shape[0] + req.max_new) - 1)
-            self._reg.gauge("serving.blocks_in_use").set(
-                self._blocks_in_use())
-        tbl = self._device_table()
-        self._decode_fn.prepare(self._p, self._pk, self._pv, self._last,
-                                self._pos, tbl, self._state)
-        self._count_paged_entries()
-        # the chunk call and its blocking token fetch: one span, whose
-        # clock pair is the per-chunk-call latency histogram (ISSUE 7
-        # TTFT/TPOT decomposition), the per-step wall, the predictor's
-        # sample and every live request's stall accounting
+                        s, ctx - 1, min(ctx - 1 + self.decode_chunk,
+                                        req.prompt.shape[0] + req.max_new)
+                        - 1)
+                self._reg.gauge("serving.blocks_in_use").set(
+                    self._blocks_in_use())
+            tbl = self._device_table(reqs)
+            # one-time AOT compile lands here, outside the chunk's clock
+            # pair, which the predictor consumes
+            self._decode_fn.prepare(self._p, self._pk, self._pv,
+                                    self._last, self._pos, tbl, self._state)
+            self._count_paged_entries(contexts)
+            sent_t = time.perf_counter()
+            (self._pk, self._pv, self._last, self._pos, toks,
+             self._state, counts) = self._decode_fn(
+                 self._p, self._pk, self._pv, self._last, self._pos, tbl,
+                 self._state)
+            # queued behind the compute, not asked for after it
+            toks.copy_to_host_async()
+            if self.arch.count_names:
+                counts.copy_to_host_async()
+        self._reg.counter(
+            "serving.chunks_dispatched", ahead=ahead,
+            help="decode chunks sent to the device: ahead=1 while another "
+                 "was still unread (the device goes from that one to this "
+                 "with no host in between), ahead=0 with nothing in "
+                 "flight (the first after an admission or an idle "
+                 "stretch)").inc()
+        self._chunks.append(_Chunk(toks, counts, reqs, sent_t))
+
+    def _collect(self):
+        """The SECOND half, of the oldest chunk in flight: wait for its
+        tokens (and what its stack tallied: there by then), emit them to
+        the requests of ITS snapshot, release and finish what ended.  A
+        row whose request is no longer the slot's (an ``eos_id`` hit in
+        the chunk before, read after this one was sent) was computed for
+        nothing: its tokens are dropped and counted.
+
+        The chunk's CLOCK PAIR is ``(max(its dispatch, the previous
+        chunk's collect), this collect)``: the interval in which the
+        device could be running it.  Between back-to-back chunks that is
+        collect to collect: the chunk's time on the device plus whatever
+        host latency was not hidden under it."""
+        c = self._chunks.popleft()
+        # ONE live span a chunk, around the wait, with THAT chunk's facts
         with self._span("serving.decode_chunk", "decode",
                         histogram="serving.decode_chunk",
                         steps=self.decode_chunk,
-                        active=self.active_slots,
+                        active=len(c.reqs),
                         passes=self.arch.passes,
                         state_layers=len(self._state),
                         plane_reads=self._reads_per_token,
                         moe_layers=self.arch.moe_layers,
                         experts_held=self.arch.experts_held,
                         **self._form_attrs) as sp:
-            (self._pk, self._pv, self._last, self._pos, toks,
-             self._state, counts) = self._decode_fn(
-                 self._p, self._pk, self._pv, self._last, self._pos, tbl,
-                 self._state)
             with self._span("serving.fetch", "fetch", of="decode"):
-                toks = np.asarray(toks)  # host sync: [chunk, S]
+                toks = np.asarray(c.toks)  # host sync: [chunk, S]
                 if self.arch.count_names:
-                    self._count_tallies("decode", [counts])
-        t0, t1 = sp.t0, sp.t1
+                    self._count_tallies("decode", [c.counts])
+        t0, t1 = max(c.sent_t, self._collected_t), sp.t1
+        self._collected_t = t1
         wall = t1 - t0
         self._reg.histogram("serving.step_seconds").observe(
             wall / self.decode_chunk)
         self.predictor.observe_chunk(wall, self.decode_chunk)
-        self._account_stall(t0, t1)
+        live = {s: req for s, req in c.reqs.items()
+                if self._slots[s] is req}
+        self._reg.counter(
+            "serving.rider_slot_steps",
+            help="slot-steps of whole chunks computed for a request that "
+                 "had ended before the chunk was read (an eos_id hit in "
+                 "the chunk before it): thrown away").inc(
+                     (len(c.reqs) - len(live)) * self.decode_chunk)
+        self._account_stall(live, t0, t1)
         if self._tracer.enabled:
             # per-request chunk windows feed only the finish-time lane
             # emission, which is skipped when tracing is off — don't
             # grow the lists on the disabled hot path
-            for req in self._slots:
-                if req is not None:
-                    req.chunks.append((t0, t1))
+            for req in live.values():
+                req.chunks.append((t0, t1))
         emitted = 0
         finished = 0
         with self._span("serving.emit", "emit") as em:
             now = em.t0
             for j in range(self.decode_chunk):
-                for s, req in enumerate(self._slots):
-                    if req is None:
-                        continue
+                for s, req in live.items():
+                    if self._slots[s] is not req:
+                        continue            # ended at an earlier step
                     tok = int(toks[j, s])
                     req.tokens.append(tok)
                     emitted += 1
@@ -1399,7 +1527,9 @@ class ServingEngine:
         t0, t1 = rnd.t0, rnd.t1
         wall = t1 - t0
         active = self.active_slots
-        self._account_stall(t0, t1)
+        self._account_stall(
+            [s for s, req in enumerate(self._slots) if req is not None],
+            t0, t1)
         if self._tracer.enabled:
             for req in self._slots:
                 if req is not None:
@@ -1586,8 +1716,10 @@ class ServingEngine:
         reference shared blocks, allocate the private tail (LRU-evicting
         cached chains under pressure), run the bucketed SUFFIX prefill
         (with the copy-on-write fork folded in), then register the
-        prompt's full blocks in the trie.  Returns 1 when the request
-        finished at prefill (immediate EOS / max_new == 1), else 0."""
+        prompt's full blocks in the trie.  Returns the requests finished
+        meanwhile: this one if it finished at prefill (immediate EOS /
+        max_new == 1), and those that ended in a chunk in flight, which
+        is read while the pieces run."""
         import jax.numpy as jnp
 
         pool, trie = self.kv_pool, self.prefix_trie
@@ -1663,6 +1795,14 @@ class ServingEngine:
             self._pk, self._pv, first = self._run_pieces(
                 self._prefill_fn, self._p, self._pk, self._pv, slot,
                 row_d, pieces, cow=(cow_src, cow_dst), tally=tally)
+            # the pieces are queued BEHIND a chunk in flight (the pools
+            # they take are its outputs).  Its tokens are read while the
+            # pieces run, not after them: a request that ends in it is
+            # finished on time, and its clock pair ends where the
+            # device left it for the pieces
+            finished = 0
+            while self._chunks:
+                finished += self._collect()
             with self._span("serving.fetch", "fetch", of="prefill"):
                 first = int(np.asarray(first))  # host sync
                 if self.arch.count_names:
@@ -1672,7 +1812,9 @@ class ServingEngine:
             self._count_latent_positions(
                 "prefill", self.arch.latent_planes * sum(
                     n * at + n * (n + 1) // 2 for _, _, at, n in pieces))
-        t_p0, now = sp.t0, sp.t1
+        # the prefill's clock pair, as a chunk's: from when the device
+        # could start it
+        t_p0, now = max(sp.t0, self._collected_t), sp.t1
         # the CoW source was held only for the copy window
         if cow is not None:
             pool.deref(cow[0])
@@ -1764,9 +1906,9 @@ class ServingEngine:
             # _release_slot re-appended the slot; the caller's _free
             # bookkeeping is already consistent (slot was popped there)
             self._finish(req, now)
-            return 1
+            return finished + 1
         self._slots[slot] = req
-        return 0
+        return finished
 
     def _shed(self, req):
         """Fail a request the scheduler refused (cannot meet its e2e

@@ -605,7 +605,7 @@ def test_paged_entries_shared_against_a_numpy_count(params, monkeypatch):
     eng._table[0, :5] = [3, 4, 5, 6, 7]       # 18 keys: 5 entries
     eng._table[1, :3] = [3, 4, 9]             # 9 keys: 3 entries, 2 shared
     eng._table[2, :2] = [3, 4]                # not live: not counted
-    eng._count_paged_entries()
+    eng._count_paged_entries([(0, 18), (1, 9)])
     assert reg.value("serving.paged_entries_live") == 8
     assert reg.value("serving.paged_entries_shared") == 4
     planes, steps = TINY["layers"], eng.decode_chunk

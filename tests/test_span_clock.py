@@ -20,8 +20,8 @@ from paddle_tpu.observability.metrics import MetricsRegistry
 from paddle_tpu.serving import ServingEngine
 
 DRIVER_SPANS = {"serving.idle", "serving.step", "serving.admit",
-                "serving.prefill", "serving.decode_chunk", "serving.fetch",
-                "serving.emit"}
+                "serving.prefill", "serving.dispatch",
+                "serving.decode_chunk", "serving.fetch", "serving.emit"}
 
 
 @pytest.fixture(scope="module")
@@ -254,12 +254,13 @@ def test_driver_phases_sum_to_the_driver_threads_wall(params):
     # much of the window a loaded host gives the thread is not asserted
     assert sum(phases.values()) <= wall + 0.06
     assert 0 < stats["serving.stalled_seconds"] <= stats["serving.live_seconds"]
-    # the chunk histogram is the decode spans' own durations: their self
-    # seconds and their fetches'
+    # the chunk histogram is the collect spans' own durations: their
+    # fetches' and their self seconds, which phase=decode holds beside the
+    # dispatch spans'
     chunks = stats["serving.decode_chunk"]
-    assert chunks["sum"] == pytest.approx(
-        phases["serving.driver_seconds{phase=decode}"]
-        + phases["serving.driver_seconds{of=decode,phase=fetch}"], rel=1e-6)
+    fetched = phases["serving.driver_seconds{of=decode,phase=fetch}"]
+    assert fetched < chunks["sum"] < (
+        fetched + phases["serving.driver_seconds{phase=decode}"])
     # what lies between the phase spans is small, and counted
     assert phases["serving.driver_seconds{phase=loop}"] < 0.1 * wall
     assert stats["serving.prefill_seconds"]["count"] == 6
@@ -311,35 +312,63 @@ def test_a_speculative_round_says_what_it_committed(params):
     assert sum(e["args"]["accepted"] for e in emits) == eng._spec.accepted
 
 
+class _Late:
+    """A first token that takes its time to reach the host: what a slow
+    prefill on the device looks like to the driver, which dispatches the
+    pieces at once and waits in the fetch."""
+
+    def __init__(self, value, seconds):
+        self.value, self.seconds = value, seconds
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.seconds)
+        return np.asarray(self.value)
+
+
 def test_a_prefill_between_two_chunks_is_stalled_time(params):
     eng, reg = _engine(params)
     eng.generate_many([_prompt(0, 5), _prompt(1, 12)], max_new_tokens=8)
-    first = eng.submit(_prompt(2, 5), max_new_tokens=24)
-    eng.step()  # admits `first`, runs its first chunk
-    before = eng.stats()
-    bucket = eng.bucket_for(12)
-    fast = eng._prefill_fn(bucket)
+    t = trace.Tracer(enabled=True, registry=None)  # keeps Request.chunks
+    old = trace.set_tracer(t)
+    try:
+        first = eng.submit(_prompt(2, 5), max_new_tokens=24)
+        eng.step()  # admits `first`, sends two chunks, reads the first
+        assert len(eng._chunks) == 1 and len(first.chunks) == 1
+        before = eng.stats()
+        bucket = eng.bucket_for(12)
+        fast = eng._prefill_fn(bucket)
 
-    def slow(*args):
-        time.sleep(0.25)
-        return fast(*args)
+        def slow(*args):
+            out = fast(*args)
+            return out[:4] + (_Late(out[4], 0.25),) + out[5:]
 
-    slow.prepare = fast.prepare
-    eng._prefill_fns[bucket] = slow
-    second = eng.submit(_prompt(3, 12), max_new_tokens=8)
-    eng.step()  # the slow prefill, then a chunk for both
-    after = eng.stats()
+        slow.prepare = fast.prepare
+        eng._prefill_fns[bucket] = slow
+        second = eng.submit(_prompt(3, 12), max_new_tokens=8)
+        # the pieces go behind the chunk in flight, which is read while
+        # they run; then the slow first token; then a chunk for both
+        eng.step()
+        after = eng.stats()
+    finally:
+        trace.set_tracer(old)
     prefill_wall = second.prefill_t1 - second.prefill_t0
     assert prefill_wall >= 0.25
+    # the prefill's clock pair starts where the chunk in flight ended
+    assert second.prefill_t0 == first.chunks[1][1]
     stalled = (after["serving.stalled_seconds"]
                - before["serving.stalled_seconds"])
     live = after["serving.live_seconds"] - before["serving.live_seconds"]
     # `first` waited through the whole prefill (and the bookkeeping around
     # it); `second` went from its first token straight into the chunk
     assert prefill_wall <= stalled <= prefill_wall + 0.1
-    chunk_wall = (after["serving.decode_chunk"]["sum"]
-                  - before["serving.decode_chunk"]["sum"])
-    assert live == pytest.approx(stalled + 2 * chunk_wall, rel=1e-6)
+    # back to back (the chunk in flight): nothing stalled, the pair starts
+    # at the previous collect; across the admission: at the dispatch
+    assert first.chunks[1][0] == first.chunks[0][1]
+    assert first.chunks[2][0] >= second.first_token_t
+    assert first.chunks[2] == second.chunks[0]
+    pairs = first.chunks[1:] + second.chunks
+    assert live == pytest.approx(
+        stalled + sum(t1 - t0 for t0, t1 in pairs), rel=1e-6)
     eng.run_until_idle()
     assert first.done and second.done
 
