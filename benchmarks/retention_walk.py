@@ -16,7 +16,7 @@ slots (8 K/V heads of 8,320 stored rows of 128 lanes, float32: 545 MB),
 and 512 rows that continues a prompt, one of 512 rows that STARTS one
 (``fresh``: the state is never read), and pieces of 64 rows of which 40
 and of 512 of which 300 are real.  A piece goes through ``serving/
-batched_decode._Cache.retain``, which hands it to the kernel in the
+batched_decode._Cache.advance``, which hands it to the kernel in the
 calls ``kernels.retention.chunk_rows`` names (one since PR 44, four of
 128 rows a 512-row piece before), ``us_a_piece`` their sum.  The state is
 donated and threaded from call to call, as the engine does it.
@@ -70,18 +70,19 @@ def _device_us(fn, state, args, calls, each=1):
 
 
 def _piece(S, z, slot, fresh, q, k, v, lg, valid):
-    """A piece as the engine hands it to the kernel: ``_Cache.retain``
+    """A piece as the engine hands it to the kernel: ``_Cache.advance``
     over a window of one slot, which starts a prompt where its first
     position is 0."""
     import jax.numpy as jnp
 
+    from paddle_tpu.kernels import retention
     from paddle_tpu.serving.batched_decode import _Cache
 
     at = jnp.arange(q.shape[0])[None]
     cache = _Cache(None, None, None, None, jnp.where(fresh, 0, 1) + at,
                    writable=valid[None], slot=slot)
-    y, planes = cache.retain(((), (), ((S, z),)), 0, q[None], k[None],
-                             v[None], lg[None])
+    y, planes = cache.advance(((), (), ((S, z),)), 0, retention, q[None],
+                              k[None], v[None], lg[None])
     return (y[0],) + planes[2][0]
 
 

@@ -1,0 +1,210 @@
+"""Mamba-2's step kernel and chunked form alone on the chip, one JSON
+line a geometry: device microseconds a call (the step: the Mosaic call
+``ssm_step`` of a profiler trace, as ``flash_walk`` takes them, beside
+the whole call's busy time with its XLA rows; the chunked form, XLA
+einsums: the busy union of the call's operations), the call's share of
+its roofline (``chipbench/ssm_moe_bytes.py``: a live slot's state of one
+layer read once and written once, 2 x 2,097,152 B, against the
+operations), the worst error against the row-by-row oracle on the chip
+and, for the step, whether a dead slot's state came back bit-equal.
+
+    chiprun -- python3 benchmarks/ssm_walk.py \\
+        [--only step_18_live,piece_512] [--calls 20] [--out chiprun_out/ssm_walk.jsonl]
+
+The geometry is ``nemotron3n.chat_ssm``'s: ONE layer's state of 40 slots
+(``[32, 128, 128]`` float32 each: 84 MB) and its tails; a decode step
+with 1, 8, 18, 28 and 40 slots live (which ones is drawn from
+``--seed``), a prefill piece of 8, 32, 128, 256 and 512 rows that
+continues a prompt, one of 512 rows that STARTS one, and a piece of 512
+of which 300 are real.  The state is donated and threaded from call to
+call, as the engine does it.  This walk is what decided that the
+chunked form stays XLA (PERF.md section 6, PR 51).  Refuses unless JAX
+finds a TPU: a number from a CPU run is no device metric.
+"""
+
+import argparse
+import json
+import os
+import sys
+import tempfile
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+CONFIG = "nemotron-3-nano-30b-a3b"
+SLOTS = 40
+# name -> ("step", live slots) or ("piece", rows, real rows[, fresh])
+GEOMETRIES = {
+    "step_1_live": ("step", 1), "step_8_live": ("step", 8),
+    "step_18_live": ("step", 18), "step_28_live": ("step", 28),
+    "step_40_live": ("step", 40),
+    "piece_8": ("piece", 8, 8), "piece_32": ("piece", 32, 32),
+    "piece_128": ("piece", 128, 128), "piece_256": ("piece", 256, 256),
+    "piece_512": ("piece", 512, 512),
+    "piece_512_fresh": ("piece", 512, 512, True),
+    "piece_512_of_which_300": ("piece", 512, 300),
+}
+
+
+def _seconds(trace_dir):
+    """(Mosaic calls, their seconds, the busy union of every operation)
+    on the chip's operations line of the newest trace under
+    ``trace_dir``; a ``while`` counts as what it holds."""
+    from chipbench import trace_reduce
+
+    calls, mosaic, spans = 0, 0, []
+    for events in trace_reduce.chip_ops(trace_reduce.load(trace_dir)).values():
+        for start, end, _, hlo in events:
+            if "tpu_custom_call" in hlo:
+                calls += 1
+                mosaic += end - start
+            spans.append((start, end))
+    busy, at = 0, 0
+    for start, end in sorted(spans):
+        busy += max(0, end - max(start, at))
+        at = max(at, end)
+    return calls, mosaic * 1e-9, busy * 1e-9
+
+
+def _device_us(fn, state, args, calls):
+    import jax
+
+    y, *state = fn(*state, *args)  # compile, warm
+    jax.block_until_ready(y)
+    with tempfile.TemporaryDirectory(prefix="ssm_walk") as td:
+        with jax.profiler.trace(td):
+            for _ in range(calls):
+                y, *state = fn(*state, *args)
+            jax.block_until_ready(y)
+        n, mosaic, busy = _seconds(td)
+    return n, 1e6 * mosaic / calls, 1e6 * busy / calls
+
+
+def measure(name, calls, seed, peak, cfg):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import ssm_moe_bytes
+    from paddle_tpu.kernels import ssm
+
+    size = ssm_moe_bytes.sizes(cfg)
+    H, P = size["ssm_heads"], size["ssm_head_dim"]
+    G, N, taps = size["ssm_groups"], size["ssm_state"], size["taps"]
+    width = size["conv_channels"]
+    rng = np.random.default_rng(seed)
+    bf16 = jnp.bfloat16
+    layer = dict(
+        conv_w=jnp.asarray(rng.uniform(-0.5, 0.5, (width, taps)), bf16),
+        conv_b=jnp.asarray(rng.uniform(-0.5, 0.5, width), bf16),
+        dt_bias=jnp.asarray(np.log(np.expm1(np.exp(rng.uniform(
+            np.log(1e-3), np.log(0.1), H)))), bf16),
+        A_log=jnp.asarray(np.log(rng.uniform(1, 16, H)), bf16),
+        D=jnp.ones((H,), bf16), heads=H, groups=G,
+        chunk_size=size["chunk_size"])
+    s_shape, t_shape = ssm.state_shapes(H, P, G, N, taps)
+    make = jax.jit(lambda k: (
+        jax.random.normal(k, (SLOTS,) + s_shape, jnp.float32),
+        jax.random.normal(k, (SLOTS,) + t_shape, bf16)))
+    rows = lambda n: (jnp.asarray(rng.normal(size=(n, width)), bf16),  # noqa
+                      jnp.asarray(rng.normal(size=(n, H)), bf16))
+    kind, *shape = GEOMETRIES[name]
+    out = {"geometry": name}
+    if kind == "step":
+        live = shape[0]
+        valid = np.zeros(SLOTS, bool)
+        valid[rng.choice(SLOTS, live, replace=False)] = True
+        args = (*rows(SLOTS), jnp.asarray(valid))
+        fn = jax.jit(lambda S, t, *a: ssm.ssm_step_pallas(S, t, *a, **layer),
+                     donate_argnums=(0, 1))
+        ref = jax.jit(lambda S, t, *a: ssm.ssm_step_ref(S, t, *a, **layer))
+        least = live * ssm_moe_bytes.least_seconds(
+            *ssm_moe_bytes.step(cfg), peak)
+        dead = int(np.flatnonzero(~valid)[0]) if live < SLOTS else None
+        out.update(live_slots=live)
+    else:
+        n, real, fresh = (shape + [False])[:3]
+        slot = int(rng.integers(SLOTS))
+        args = (jnp.int32(slot), jnp.asarray(fresh), *rows(n),
+                jnp.arange(n) < real)
+        fn = jax.jit(lambda S, t, *a: ssm.ssm_chunk(S, t, *a, **layer),
+                     donate_argnums=(0, 1))
+        # the oracle: the same rows one step at a time
+        def by_rows(S, t, slot, fresh, xbc, dt, valid):
+            keep = jnp.where(fresh, 0.0, 1.0)
+            S = S.at[slot].multiply(keep)
+            t = t.at[slot].multiply(keep.astype(t.dtype))
+            at = jnp.arange(SLOTS) == slot
+
+            def one(carry, row):
+                S, t = carry
+                x, d, v = row
+                y, S, t = ssm.ssm_step_ref(
+                    S, t, jnp.broadcast_to(x, (SLOTS, width)),
+                    jnp.broadcast_to(d, (SLOTS, H)), at & v, **layer)
+                return (S, t), y[slot]
+
+            (S, t), y = jax.lax.scan(one, (S, t), (xbc, dt, valid))
+            return y, S, t
+
+        ref = jax.jit(by_rows)
+        least = ssm_moe_bytes.least_seconds(
+            *ssm_moe_bytes.piece(cfg, n), peak)
+        dead = (slot + 1) % SLOTS
+        out.update(rows=n, real_rows=real, fresh=fresh)
+    state = make(jax.random.PRNGKey(seed))
+    before = None if dead is None else np.asarray(state[0][dead, 0, :8])
+    want_y, want_S, want_t = ref(*state, *args)
+    y, S, t = fn(*state, *args)
+    real_rows = slice(None) if kind == "step" else slice(0, shape[1])
+    err = float(jnp.max(jnp.abs(y[real_rows] - want_y[real_rows]))
+                / jnp.max(jnp.abs(want_y[real_rows])))
+    err_S = float(jnp.max(jnp.abs(S - want_S)) / jnp.max(jnp.abs(want_S)))
+    tails = bool(jnp.array_equal(t, want_t))
+    untouched = None if dead is None else bool(
+        np.array_equal(np.asarray(S[dead, 0, :8]), before))
+    del want_y, want_S, want_t
+    n_mosaic, mosaic_us, busy_us = _device_us(fn, (S, t), args, calls)
+    kernel_us = mosaic_us if kind == "step" else busy_us
+    out.update(us_a_call=busy_us, mosaic_calls=n_mosaic // calls,
+               roofline_pct=100e6 * least / kernel_us,
+               worst_error=err, worst_state_error=err_S, tails_equal=tails,
+               dead_slot_untouched=untouched)
+    if kind == "step":
+        out.update(us_the_kernel=mosaic_us, us_a_live_slot=mosaic_us / live)
+    return out
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--only", default="")
+    ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=51)
+    ap.add_argument("--out", default="")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    if jax.default_backend() != "tpu":
+        print(f"ssm_walk: needs a TPU, JAX found "
+              f"{jax.default_backend()!r}", file=sys.stderr)
+        return 2
+    from chipbench import flops
+    from chipbench import run as bench_run
+
+    peak = flops.peaks(jax.devices()[0].device_kind)
+    cfg = bench_run._read_json(bench_run.HERE, "configs", CONFIG + ".json")
+    names = [n for n in args.only.split(",") if n] or list(GEOMETRIES)
+    lines = []
+    for name in names:
+        lines.append(json.dumps(measure(name, args.calls, args.seed, peak,
+                                        cfg)))
+        print(lines[-1], flush=True)
+    if args.out:
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "a") as f:
+            f.write("\n".join(lines) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -3,7 +3,7 @@
 
 One op, two targets, one numerics oracle: every fused op class
 (``flash_attention``, ``fused_ce``, ``paged_attention``,
-``chain_attention``, ``grouped_matmul``, ``retention``) resolves
+``chain_attention``, ``grouped_matmul``, ``retention``, ``ssm``) resolves
 through :mod:`.registry` to ``pallas_tpu`` (the Mosaic kernels — native
 on TPU, interpret mode in CPU tests) or ``xla_ref`` (:mod:`.xla_ref` —
 the shape-complete pure-XLA reference every backend is tested against,
@@ -32,6 +32,7 @@ from . import paged_attention  # registers the paged-attention op class
 from . import chain_attention  # registers a wide window's chain walk
 from . import grouped_matmul  # registers the grouped matrix product
 from . import retention  # registers power retention's step and chunk
+from . import ssm  # registers Mamba-2's step and chunked form
 
 __all__ = [
     "AUTO_ORDER", "BACKENDS", "GLOBAL_ENV", "TIMED_RUN_ENV",

@@ -60,7 +60,7 @@ donates them:
   once, by one contraction over all the piece's rows.
   ``chunk_rows(width)`` names the calls of a window (one; a window wider
   than any rung of the prefill ladder is consecutive calls, the state
-  threaded through, which ``serving/batched_decode._Cache.retain``
+  threaded through, which ``serving/batched_decode._Cache.advance``
   makes).
 
 Inference only (no VJP).

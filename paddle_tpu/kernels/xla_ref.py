@@ -75,6 +75,16 @@ ORACLE_TOL = {
     # arithmetic in another order; the chunk kernel multiplies float32
     # operands as two bfloat16 pieces each (16 bits: 2e-5 relative)
     ("retention", "float32"): {"fwd": 2e-4, "grad": None},
+    # Mamba-2's recurrence is inference-only and float32 whatever the
+    # rows' dtype (the state, the decay and the update are float32 on
+    # both sides): the step kernel sums ``S C`` down the sublanes in
+    # another order than the oracle's lane sum, and the chunked form
+    # reassociates a chunk's rows (the quadratic form, then the state)
+    # against the scan: a few ulp of sums of ``N`` and of ``chunk_size``
+    # products.  bfloat16 rows are upcast before anything is multiplied,
+    # so the bound is float32's
+    ("ssm", "float32"): {"fwd": 2e-4, "grad": None},
+    ("ssm", "bfloat16"): {"fwd": 2e-4, "grad": None},
 }
 
 

@@ -58,7 +58,7 @@ adds to what the compiled decode chunk and prefill piece return as their
 last output (``arch.count_names`` names the entries; an architecture
 with none never calls it and its programs have no such output).
 
-Seven architectures are here: ``Gpt2`` (the block of
+Eight architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -89,7 +89,7 @@ the latent's width; its routed layers are ``GatedMoE``'s
 per-head form.  ``PowerRetention`` has NO plane at all (``planes ==
 ()``): every layer's mixer is power retention (arXiv:2507.04239), whose
 memory of the context is a state of fixed size a slot a K/V head, read
-and written in place for the live slots only (``attend.retain``,
+and written in place for the live slots only (``attend.advance``,
 ``kernels/retention.py``); ``models/retention_reference.py`` is its
 plain reference, in the quadratic form.  ``SinkWindowMoE`` is the first
 whose planes are NOT alike: full planes of few K/V heads beside window
@@ -99,16 +99,24 @@ window planes; what the others state once (``kv_heads``,
 ``rows_per_entry``) it states a plane (``plane_*``), and its window
 planes' chains may hold only their window (``window_chains``);
 ``models/sink_window_moe_reference.py`` is its plain reference.
+``MambaMoE`` is the first whose layers are ONE sub-layer each, a mixer
+OR an FFN by a pattern string: Mamba-2 mixers whose state MATRIX a head
+is advanced in place (``kernels/ssm.py``), routed FFNs of un-gated
+``relu ** 2`` experts (``routed_ffn``'s ``form``), and position-free
+attention over two K/V heads; ``models/ssm_moe_reference.py`` is its
+plain reference.
 
 An architecture whose state is large asks for it IN PLACE::
 
-    y, planes = attend.retain(planes, i, q, k, v, lg, eps=...)
+    y, planes = attend.advance(planes, i, kernel, *rows, **how)
 
-hands state layer ``i``'s arrays WHOLE to ``kernels.retention`` (a
-decode step: every slot's row and ``valid``; a piece: the one slot's
-rows), which advances the live slots' state where it lies and returns
-the rows' outputs; no ``[S, ...]`` copy of the state exists on either
-side, where ``state`` / ``put_state`` gather and scatter one.
+hands state layer ``i``'s arrays WHOLE to ``kernel`` (``kernels.
+retention`` with rows ``q, k, v, lg``; ``kernels.ssm`` with rows ``xbc,
+dt``; a decode step: every slot's row and ``valid``; a piece: the one
+slot's rows and whether it starts a prompt), which advances the live
+slots' state where it lies and returns the rows' outputs; no ``[S,
+...]`` copy of the state exists on either side, where ``state`` /
+``put_state`` gather and scatter one.
 """
 
 import jax
@@ -117,6 +125,7 @@ import numpy as np
 
 from ..kernels import paged_attention as _paged
 from ..kernels import retention as _retention
+from ..kernels import ssm as _ssm
 from ..kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 # the named scopes of the lowered program (op_name metadata, read by
 # ``observability.trace.device_scopes``): the entry points run a stack
@@ -125,8 +134,9 @@ from ..kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
-           "LatentMoE", "PowerRetention", "SinkWindowMoE", "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
-           "MOE_COUNTS", "STACK_SCOPE"]
+           "LatentMoE", "PowerRetention", "SinkWindowMoE", "MambaMoE",
+           "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
+           "MOE_COUNTS", "EXPERT_FORMS", "STACK_SCOPE"]
 
 
 class Architecture:
@@ -153,6 +163,9 @@ class Architecture:
     retention_layers = 0
     # planes whose attention has a learned sink logit a query head
     sink_planes = 0
+    # layers whose mixer is a state-space recurrence advanced in place
+    # (``kernels/ssm.py``)
+    ssm_layers = 0
 
     def __init__(self, n_layer, n_head, d_model, passes=1, head_dim=None):
         if head_dim is None:
@@ -817,8 +830,23 @@ MOE_COUNTS = ("moe_rows", "moe_assignments_held", "moe_experts_touched",
               "moe_expert_visits")
 
 
+# an expert's form: the matrices it holds, in the order they are applied,
+# and how each routed one lies, ``[count, k, n]`` (False) or TRANSPOSED,
+# ``[count, n, k]`` (True): an ``n`` that is not whole lane tiles (1,856
+# is 14.5) is held as the matrix's major axis (kernels/grouped_matmul.py)
+EXPERT_FORMS = {
+    "gated_silu": (("gate", False), ("up", False), ("down", False)),
+    "relu2": (("up", True), ("down", False)),
+}
+
+
+def _relu2(x, up, down):
+    """The un-gated FFN ``relu(x W_up) ** 2 W_down``."""
+    return jnp.square(jax.nn.relu(x @ up)) @ down
+
+
 def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
-               normalise=True, bias=True, shared=True):
+               normalise=True, bias=True, shared=True, form="gated_silu"):
     """The routed FFN both routed architectures run, over rows ``h [...,
     d]``: ``(y, counts)``.  ``w(name)`` gives the layer's ``router.w
     [d, width]`` (and ``router.bias`` where ``bias``), ``shared_gate.w``,
@@ -827,8 +855,14 @@ def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
     ``experts_down.w [count, e, d]``; ``experts = (first, count)`` is
     this chip's share of the router's width; ``score``, ``normalise``
     and ``scale`` are ``route``'s.  ``shared`` is the architecture's
-    statement: one gated MLP added for every row (``shared_*.w``), or
-    (``False``) none, and then no such matrix is read.
+    statement: one MLP added for every row (``shared_*.w``), or
+    (``False``) none, and then no such matrix is read.  ``form`` is the
+    architecture's too (``EXPERT_FORMS``): ``"gated_silu"``, ``(silu(x
+    W_gate) * (x W_up)) W_down`` in three grouped products, or
+    ``"relu2"``, ``relu(x W_up) ** 2 W_down`` in two (no ``*_gate.w`` is
+    read); ``EXPERT_FORMS`` says beside each matrix whether the routed
+    experts hold it transposed; the shared expert has the routed
+    experts' form.
 
     Every row is routed over all the router's experts, the held ones add
     their weighted part for the rows that selected them and the shared
@@ -839,6 +873,9 @@ def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
     ``MOE_COUNTS``: the live rows, the row-expert pairs that fell on a
     held expert, the held experts with at least one live row, and
     ``count``."""
+    if form not in EXPERT_FORMS:
+        raise ValueError(f"routed_ffn: form {form!r} is not one of "
+                         f"{sorted(EXPERT_FORMS)}")
     f32, i32 = jnp.float32, jnp.int32
     first, count = experts
     k, d = top_k, h.shape[-1]
@@ -859,11 +896,19 @@ def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
         sizes = jnp.sum(expert[:, None] == jnp.arange(count, dtype=i32),
                         axis=0, dtype=i32)
         gathered = rows[order // k]
+    transposed = dict(EXPERT_FORMS[form])
+
+    def product(x, name):
+        return _grouped_matmul(x, w(f"experts_{name}.w"), sizes,
+                               transpose_rhs=transposed[name])
+
     with sublayer("moe.experts"):
-        act = (jax.nn.silu(_grouped_matmul(gathered, w("experts_gate.w"),
-                                           sizes))
-               * _grouped_matmul(gathered, w("experts_up.w"), sizes))
-        out = _grouped_matmul(act, w("experts_down.w"), sizes)
+        if form == "gated_silu":
+            act = (jax.nn.silu(product(gathered, "gate"))
+                   * product(gathered, "up"))
+        else:
+            act = jnp.square(jax.nn.relu(product(gathered, "up")))
+        out = product(act, "down")
         # back to (row, selection) order, weighted; a pair that is
         # not held has a zero row of the product and a zero weight
         back = jnp.zeros_like(order).at[order].set(
@@ -873,8 +918,11 @@ def routed_ffn(w, h, attend, experts, top_k, scale=1.0, score="sigmoid",
     part = None
     if shared:
         with sublayer("moe.shared"):
-            part = _gated_silu(h, w("shared_gate.w"), w("shared_up.w"),
-                               w("shared_down.w"))
+            if form == "gated_silu":
+                part = _gated_silu(h, w("shared_gate.w"), w("shared_up.w"),
+                                   w("shared_down.w"))
+            else:
+                part = _relu2(h, w("shared_up.w"), w("shared_down.w"))
     counts = jnp.stack([jnp.sum(valid, dtype=i32),
                         jnp.sum(held, dtype=i32),
                         jnp.sum(sizes > 0, dtype=i32),
@@ -903,11 +951,12 @@ def _moe_gauges(arch, params):
     """The ``serving.moe_*`` gauges of a routed architecture."""
     if not arch.moe_layers:
         return {}
-    last = arch.n_layer - 1
+    last = arch.last_routed
+    matrices = [name for name, _ in EXPERT_FORMS[arch.expert_form]]
     one_expert = sum(
         int(np.prod(np.shape(params[f"block{last}_experts_{m}.w"])[1:]))
         * params[f"block{last}_experts_{m}.w"].dtype.itemsize
-        for m in ("gate", "up", "down"))
+        for m in matrices)
     return {
         "moe_layers": (arch.moe_layers, "layers whose FFN is routed"),
         "moe_experts_held": (
@@ -918,29 +967,39 @@ def _moe_gauges(arch, params):
             "here or not)"),
         "moe_top_k": (arch.top_k, "experts a row selects"),
         "moe_expert_bytes": (
-            one_expert, "bytes of ONE routed expert's three matrices"),
+            one_expert, f"bytes of ONE routed expert's "
+            f"{ {2: 'two', 3: 'three'}[len(matrices)]} matrices"),
     }
 
 
 class _Routed:
-    """What both routed architectures say of their share of the router's
+    """What the routed architectures say of their share of the router's
     experts (``self.experts = (first, count)``, ``router_width``,
-    ``top_k``, ``dense_layers``): the counts their stacks tally, the
-    span attributes and gauges of the engine, and the check that the
-    parameters hold that share."""
+    ``top_k``; which layers are routed: ``moe_layers`` of them, the last
+    ``last_routed``, by default all after ``dense_layers`` leading dense
+    ones; and the experts' form, ``expert_form`` of ``EXPERT_FORMS``):
+    the counts their stacks tally, the span attributes and gauges of the
+    engine, and the check that the parameters hold that share."""
 
     count_names = MOE_COUNTS
+    expert_form = "gated_silu"
 
     @property
     def moe_layers(self):
         return self.n_layer - self.dense_layers
 
     @property
+    def last_routed(self):
+        """A routed layer's index: the one whose parameters are read for
+        what a routed layer holds."""
+        return self.n_layer - 1
+
+    @property
     def experts_held(self):
         return self.experts[1] if self.moe_layers else 0
 
     def _check_experts(self, params):
-        last = self.n_layer - 1
+        last = self.last_routed
         held = np.shape(params[f"block{last}_experts_down.w"])[0]
         width = np.shape(params[f"block{last}_router.w"])[1]
         if (held, width) != (self.experts[1], self.router_width):
@@ -1601,6 +1660,242 @@ class SinkWindowMoE(_Routed, Architecture):
                               p["lm_head.w"],
                               preferred_element_type=jnp.float32)
 
+class MambaMoE(_Routed, Architecture):
+    """Pre-normed layers that are ONE sub-layer each, by a pattern
+    string: ``M`` a Mamba-2 mixer, ``E`` a routed FFN of un-gated
+    ``relu ** 2`` experts, ``*`` grouped-query attention with NO
+    positional signal (the ``nemotron_h`` layout, arXiv:2504.03624;
+    ``models/ssm_moe_reference.py`` writes the equations down and lists
+    what the published configuration has no key for).  Every layer is ``x
+    <- x + f(RMSNorm(x))``; one RMSNorm before the untied head; the table
+    is not scaled; positions come from the recurrences alone.
+
+    **``M``** (arXiv:2405.21060): ``[z | xBC | dt] = u W_in`` (widths
+    ``H P | H P + 2 G N | H``); ``kernels/ssm.py`` does the rest IN PLACE
+    through ``attend.advance`` (the convolution of ``conv_taps`` taps
+    over ``xBC`` with the slot's tails, SiLU, ``delta = softplus(dt +
+    dt_bias)``, the state ``S [H, P, N]`` float32 under a scalar decay a
+    head, ``y = S C + D x``): a decode step hands the layer's arrays
+    whole to the step kernel, which reads and writes the live slots'
+    state where it lies; a prefill piece advances its one slot in the
+    chunked form at ``chunk_size`` rows.  Then the gated group norm: ``y
+    <- y * silu(z)``, RMSNorm over each of the ``G`` groups of lanes
+    (the gate BEFORE the norm, one gain of ``H P``), ``out = y W_out``.
+    A slot holds ``kernels.ssm.state_shapes``: the state and ``conv_taps
+    - 1`` rows of ``xBC`` in the compute dtype (``state_spec``).
+
+    **``*``**: ``n_head`` query heads of ``head_dim`` lanes over
+    ``kv_heads`` K/V heads (16 a K/V head in the published model), causal
+    softmax at ``head_dim ** -0.5`` over the whole context, no rotary, no
+    bias; one full plane a ``*`` layer (``pool_rows`` of its K/V heads a
+    position: what a position STORES is the gauge
+    ``kv_stored_bytes_per_token`` beside ``kv_bytes_per_token``).
+
+    **``E``**: ``routed_ffn`` at ``form="relu2"``: ``route`` with sigmoid
+    scores, a bias that selects only, ``top_k`` of ``router_width``,
+    weights normalised over the selected (``norm_topk``) and scaled by
+    ``route_scale``; ``experts = (first, count)`` this chip's share; the
+    shared expert the same form at its own width.  The stack tallies
+    ``MOE_COUNTS``.
+
+    Parameter names: ``tok_emb.w [V, d]``, ``norm_f.scale``, ``lm_head.w
+    [d, V]``; per layer ``block{i}_norm.scale``; an ``M`` layer
+    ``ssm_in.w [d, 2 H P + 2 G N + H]``, ``ssm_conv.w [H P + 2 G N,
+    taps]``, ``ssm_conv.b``, ``ssm_dt.b``, ``ssm_A_log.w``, ``ssm_D.w``
+    (``[H]`` each), ``ssm_norm.scale [H P]``, ``ssm_out.w [H P, d]``; a
+    ``*`` layer ``att_qkv.w [d, (n_head + 2 kv_heads) head_dim]`` (q | k |
+    v), ``att_out.w [n_head head_dim, d]``; an ``E`` layer ``router.w [d,
+    router_width]``, ``router.bias``, ``shared_up.w [d, s]``,
+    ``shared_down.w [s, d]``, ``experts_up.w [count, e, d]`` (TRANSPOSED:
+    ``routed_ffn``), ``experts_down.w [count, e, d]``.
+    """
+
+    name = "mamba_moe"
+    expert_form = "relu2"
+
+    def __init__(self, pattern, n_head, kv_heads, head_dim, d_model,
+                 ssm_heads, ssm_head_dim, ssm_groups, ssm_state, conv_taps,
+                 router_width, top_k, experts, route_scale=1.0,
+                 norm_topk=True, chunk_size=128, eps=1e-5):
+        super().__init__(len(pattern), n_head, d_model, head_dim=head_dim)
+        bad = sorted(set(pattern) - set("ME*"))
+        if bad:
+            raise ValueError(f"{self.name}: pattern characters {bad}; a "
+                             f"layer is 'M', 'E' or '*'")
+        if n_head % kv_heads:
+            raise ValueError(f"{self.name}: kv_heads {kv_heads} must "
+                             f"divide n_head {n_head}")
+        self.pattern = str(pattern)
+        self._kv_heads = int(kv_heads)
+        self.ssm_heads, self.ssm_head_dim = int(ssm_heads), int(ssm_head_dim)
+        self.ssm_groups, self.ssm_state = int(ssm_groups), int(ssm_state)
+        self.conv_taps, self.chunk_size = int(conv_taps), int(chunk_size)
+        # refuses a geometry the state's layout cannot hold
+        _ssm.heads_per_row(self.ssm_heads, self.ssm_head_dim,
+                           self.ssm_groups)
+        self.router_width, self.top_k = int(router_width), int(top_k)
+        self.experts = _check_share(self.name, experts, router_width, top_k)
+        self.route_scale, self.norm_topk = float(route_scale), bool(norm_topk)
+        self.eps = eps
+        of = lambda kind: {i: n for n, i in enumerate(          # noqa: E731
+            i for i, c in enumerate(self.pattern) if c == kind)}
+        self.state_of, self.plane_of = of("M"), of("*")
+
+    @property
+    def kv_heads(self):
+        return self._kv_heads
+
+    @property
+    def planes(self):
+        return (None,) * len(self.plane_of)
+
+    @property
+    def rows_per_entry(self):
+        return self.n_head // self.kv_heads
+
+    @property
+    def ssm_layers(self):
+        return len(self.state_of)
+
+    @property
+    def moe_layers(self):
+        return self.pattern.count("E")
+
+    @property
+    def last_routed(self):
+        return self.pattern.rindex("E")
+
+    @property
+    def ssm_inner(self):
+        return self.ssm_heads * self.ssm_head_dim
+
+    def pool_block_shape(self, block_tokens, dtype):
+        return (block_tokens, _paged.pool_rows(self.kv_heads, dtype),
+                self.head_dim)
+
+    def state_spec(self, dtype):
+        S, tail = _ssm.state_shapes(self.ssm_heads, self.ssm_head_dim,
+                                    self.ssm_groups, self.ssm_state,
+                                    self.conv_taps)
+        return (((S, jnp.float32), (tail, jnp.dtype(dtype))),
+                ) * self.ssm_layers
+
+    def gauges(self, params):
+        dtype = params["tok_emb.w"].dtype
+        stored = sum(int(np.prod(shape[1:]))
+                     for i in range(len(self.planes))
+                     for shape in self.plane_block_shapes(i, 1, dtype))
+        return dict(_moe_gauges(self, params) if self.moe_layers else {}, **{
+            "ssm_layers": (self.ssm_layers, "layers whose mixer is a "
+                           "Mamba-2 recurrence (a state a slot, advanced "
+                           "in place; no K/V plane)"),
+            "ssm_state_bytes_per_slot": (
+                self.state_bytes_per_slot(dtype), "bytes one slot holds "
+                "beside the pool: the float32 state and the convolution's "
+                "tails of every Mamba-2 layer"),
+            "kv_stored_bytes_per_token": (
+                stored * dtype.itemsize, "bytes a cached position STORES "
+                "across its planes: kernels.paged_attention.pool_rows of "
+                "K/V heads a plane (kv_bytes_per_token is what the model "
+                "caches of it)"),
+        })
+
+    def check_params(self, params, max_len):
+        need = ["tok_emb.w", "norm_f.scale", "lm_head.w"]
+        for kind, names in (("M", ("ssm_in.w", "ssm_conv.w", "ssm_conv.b",
+                                   "ssm_dt.b", "ssm_A_log.w", "ssm_D.w",
+                                   "ssm_norm.scale", "ssm_out.w")),
+                            ("*", ("att_qkv.w", "att_out.w")),
+                            ("E", ("router.w", "router.bias", "shared_up.w",
+                                   "shared_down.w", "experts_up.w",
+                                   "experts_down.w"))):
+            if kind in self.pattern:
+                last = self.pattern.rindex(kind)
+                need += [f"block{last}_norm.scale"] + [
+                    f"block{last}_{n}" for n in names]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+        if "M" in self.pattern:
+            last = self.pattern.rindex("M")
+            want = (2 * self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+                    + self.ssm_heads)
+            got = np.shape(params[f"block{last}_ssm_in.w"])[1]
+            if got != want:
+                raise ValueError(
+                    f"{self.name}: layer {last} projects to {got} lanes; z "
+                    f"| xBC | dt of {self.ssm_heads} heads of "
+                    f"{self.ssm_head_dim} in {self.ssm_groups} groups of "
+                    f"state {self.ssm_state} is {want}")
+        if self.moe_layers:
+            self._check_experts(params)
+
+    def embed(self, p, toks, pos):
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks]      # no positional encoding
+
+    def _mamba(self, w, h, planes, attend, n_state):
+        f32 = jnp.float32
+        inner, G = self.ssm_inner, self.ssm_groups
+        xbc_end = 2 * inner + 2 * G * self.ssm_state
+        with sublayer("mixer"):
+            zxd = h @ w("ssm_in.w")
+            y, planes = attend.advance(
+                planes, n_state, _ssm, zxd[..., inner:xbc_end],
+                zxd[..., xbc_end:], conv_w=w("ssm_conv.w"),
+                conv_b=w("ssm_conv.b"), dt_bias=w("ssm_dt.b"),
+                A_log=w("ssm_A_log.w"), D=w("ssm_D.w"),
+                heads=self.ssm_heads, groups=G, chunk_size=self.chunk_size)
+            # the gate BEFORE the norm, the norm a group
+            y = y * jax.nn.silu(zxd[..., :inner].astype(f32))
+            yg = y.reshape(*y.shape[:-1], G, inner // G)
+            yg = yg * jax.lax.rsqrt(
+                jnp.mean(jnp.square(yg), axis=-1, keepdims=True) + self.eps)
+            y = yg.reshape(y.shape).astype(h.dtype) * w("ssm_norm.scale")
+            return y @ w("ssm_out.w"), planes
+
+    def _attention(self, w, h, planes, attend, plane):
+        hk, dh = self.kv_heads, self.head_dim
+        lead = h.shape[:-1]
+        with sublayer("attn.proj"):
+            qkv = h @ w("att_qkv.w")
+            nq, nk = self.n_head * dh, hk * dh
+            q = qkv[..., :nq].reshape(*lead, self.n_head, dh)
+            k = qkv[..., nq:nq + nk].reshape(*lead, hk, dh)
+            v = qkv[..., nq + nk:].reshape(*lead, hk, dh)
+        with sublayer("attn.core"):
+            ctx, planes = attend(planes, plane, 0, q, k, v,
+                                 group=self.rows_per_entry, scale=dh ** -0.5)
+        with sublayer("attn.proj"):
+            return ctx.reshape(*lead, -1) @ w("att_out.w"), planes
+
+    def stack(self, p, x, pos, planes, attend):
+        for i, kind in enumerate(self.pattern):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            with sublayer("norm"):
+                h = _rms(x, w("norm.scale"), self.eps)
+            if kind == "M":
+                mix, planes = self._mamba(w, h, planes, attend,
+                                          self.state_of[i])
+            elif kind == "*":
+                mix, planes = self._attention(w, h, planes, attend,
+                                              self.plane_of[i])
+            else:
+                mix, counts = routed_ffn(
+                    w, h, attend, self.experts, self.top_k,
+                    self.route_scale, normalise=self.norm_topk,
+                    form=self.expert_form)
+                attend.tally(counts)
+            x = x + mix
+        return x, planes
+
+    def head(self, p, x):
+        with sublayer("head"):
+            return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
+                              p["lm_head.w"],
+                              preferred_element_type=jnp.float32)
+
 
 class PowerRetention(Architecture):
     """Pre-normed layers of POWER RETENTION and a gated SiLU FFN (the
@@ -1621,7 +1916,7 @@ class PowerRetention(Architecture):
     **A layer.**  ``q``, ``k`` are RMS-normed over a head's lanes and
     rotated (the halves convention of ``_rope``, all lanes), the gate is
     ``lg = log sigmoid(h W_g + b_g)``, one a K/V head, in float32, and
-    ``attend.retain`` does the rest: a decode step decays the live
+    ``attend.advance`` does the rest: a decode step decays the live
     slots' state, adds ``phi(k) v^T`` and reads it through ``phi(q)``; a
     prefill piece attends itself in the quadratic form and the state
     before it through ``phi(q)``.  ``degree`` is 2: the features ``phi``
@@ -1731,8 +2026,8 @@ class PowerRetention(Architecture):
                                preferred_element_type=f32)
                     + w("att_gate.b").astype(f32))
             with sublayer("attn.core"):
-                y, planes = attend.retain(planes, i, q, k, v, lg,
-                                          eps=self.eps)
+                y, planes = attend.advance(planes, i, _retention, q, k, v,
+                                           lg, eps=self.eps)
             with sublayer("attn.proj"):
                 x = x + (y.reshape(*x.shape[:-1], -1).astype(x.dtype)
                          @ w("att_out.w"))

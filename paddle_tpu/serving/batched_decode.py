@@ -88,7 +88,6 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import paged_attention as _paged
-from ..kernels import retention as _retention
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
@@ -196,12 +195,16 @@ class _Cache:
     left; without ``slot`` the state arrays' rows ARE the call's slots.
     The state side traces nothing unless an architecture calls it.
 
-    ``retain(planes, i, q, k, v, lg, **how) -> (y, planes')`` is the
-    state side IN PLACE (power retention, ``kernels/retention.py``): the
-    arrays of state layer ``i`` go to the kernel whole, with ``valid`` (a
-    step) or ``slot`` and whether the piece starts a prompt (a window),
-    and come back advanced for the live slots only; nothing is gathered
-    by slot and nothing scattered back.
+    ``advance(planes, i, kernel, *rows, **how) -> (y, planes')`` is the
+    state side IN PLACE, the ONE call every recurrence whose state is
+    large makes (power retention: ``kernels/retention.py``, rows ``q, k,
+    v, lg``; Mamba-2: ``kernels/ssm.py``, rows ``xbc, dt``): the arrays
+    of state layer ``i`` go whole to ``kernel.step(*arrays, *rows, valid,
+    **how)`` (a decode step) or to ``kernel.chunk(*arrays, slot, fresh,
+    *rows, valid, **how)`` (a window: the one slot's rows, ``fresh``
+    where the piece starts a prompt, one call for each of
+    ``kernel.chunk_rows(width)``), and come back advanced for the live
+    slots only; nothing is gathered by slot and nothing scattered back.
 
     An architecture with NO plane (``arch.planes == ()``) has no table:
     ``table``, ``blk`` and ``off`` are ``None``, calling the cache is an
@@ -272,31 +275,31 @@ class _Cache:
                      pool_v and pool_v[:plane] + (pv,) + pool_v[plane + 1:]
                      ) + planes[2:]
 
-    def retain(self, planes, i, q, k, v, lg, **how):
-        S, z = planes[2][i]
+    def advance(self, planes, i, kernel, *rows, **how):
+        arrays = planes[2][i]
         if self.step:
-            y, S, z = _retention.step(S, z, q, k, v, lg, self.valid, **how)
+            y, *arrays = kernel.step(*arrays, *rows, self.valid, **how)
         elif self.slot is None:
             raise ValueError(
-                "retain: a window of several slots (a verify window) has "
-                "no in-place form: kernels.retention.chunk advances ONE "
-                "slot's state over a piece")
+                "advance: a window of several slots (a verify window) has "
+                "no in-place form: a kernel's chunk advances ONE slot's "
+                "state over a piece")
         else:
             # a window is ONE kernel call (the kernel walks its rows in
             # tiles); one wider than any rung is consecutive calls, the
             # state threaded through in place: only the first can start
             # a prompt, and each honours its own rows' limit
             fresh, ys, at = self.pos[0, 0] == 0, [], 0
-            for rows in _retention.chunk_rows(q.shape[1]):
-                cut = slice(at, at + rows)
-                y, S, z = _retention.chunk(
-                    S, z, self.slot, fresh, q[0, cut], k[0, cut],
-                    v[0, cut], lg[0, cut], self.valid[0, cut], **how)
+            for n in kernel.chunk_rows(rows[0].shape[1]):
+                cut = slice(at, at + n)
+                y, *arrays = kernel.chunk(
+                    *arrays, self.slot, fresh, *(r[0, cut] for r in rows),
+                    self.valid[0, cut], **how)
                 ys.append(y)
-                fresh, at = False, at + rows
+                fresh, at = False, at + n
             y = (ys[0] if len(ys) == 1 else jnp.concatenate(ys))[None]
         return y, planes[:2] + (
-            planes[2][:i] + ((S, z),) + planes[2][i + 1:],)
+            planes[2][:i] + (tuple(arrays),) + planes[2][i + 1:],)
 
     def tally(self, counts):
         self.counts = (counts if isinstance(self.counts, tuple)

@@ -604,7 +604,9 @@ class ServingEngine:
             if arch.retention_layers else
             dict(kv_kinds=1 + self._windowed,
                  sink_planes=arch.sink_planes)
-            if arch.sink_planes else {})
+            if arch.sink_planes else
+            dict(ssm_layers=arch.ssm_layers)
+            if arch.ssm_layers else {})
 
     @property
     def _tracer(self):

@@ -1,16 +1,19 @@
-"""What the six architectures that are NOT ``SinkWindowMoE`` lower to:
+"""What the seven architectures that are NOT ``MambaMoE`` lower to:
 the StableHLO text of their decode chunk and of a wide and a narrow
 prefill piece at the tiny size of each family's own test
 (``chipbench/tests/test_<family>_family.py::TINY``), hashed; and the
-jaxpr of the Mosaic paged kernels, which the CPU's programs do not hold
-(``mosaic``).
+jaxpr of the Mosaic paged kernels (``mosaic``) and of the Mosaic grouped
+product at the three routed cells' widths (``grouped``), which the CPU's
+programs do not hold.
 
     python tests/lowered_programs.py [root]
 
 prints ``{family: {entry: sha256}}`` for the tree at ``root`` (this one by
 default): run on a ``git archive`` of a parent commit it gives the
 hashes ``tests/test_lowered_programs.py`` pins (PR 46: the commit before
-planes stated their own shape and chains came in two kinds)."""
+planes stated their own shape and chains came in two kinds; PR 51 added
+``SinkWindowMoE``'s, taken on its parent, when ``routed_ffn`` took the
+expert's form and ``_Cache.retain`` became ``advance``)."""
 
 import hashlib
 import importlib.util
@@ -21,7 +24,8 @@ import sys
 FAMILIES = {"gpt2": "test_rehearsal", "ouro": "test_ouro_family",
             "sambay": "test_sambay_family", "gated_moe":
             "test_gated_moe_family", "latent_moe": "test_latent_moe_family",
-            "retention": "test_retention_family"}
+            "retention": "test_retention_family",
+            "sink_window_moe": "test_sink_window_moe_family"}
 GEOMETRY = {"max_len": 64, "max_slots": 2, "block_tokens": 8,
             "cache_blocks": 0, "prefix_reuse": False}
 ENTRIES = ("decode_chunk_4", "prefill_8", "prefill_32")
@@ -113,8 +117,46 @@ def mosaic(root):
             for k, v in out.items()}
 
 
+# (experts held, d, expert width, rows) of the three routed cells the
+# benchmark had before ``MambaMoE``: a decode step's rows and a prefill
+# piece's, the up product ``[d, e]`` and the down product ``[e, d]``
+GROUPED = {"trinitylp_32x3072x3072": (32, 3072, 3072),
+           "dsv2lite_16x2048x1408": (16, 2048, 1408),
+           "mimo25_16x4096x2048": (16, 4096, 2048)}
+
+
+def grouped(root):
+    """{geometry: sha256 of the Mosaic grouped product's jaxpr}: the
+    CPU's programs resolve ``grouped_matmul`` to the oracle, so the
+    kernel the chip runs is pinned here, at the published widths (shapes
+    only: nothing is computed)."""
+    import re
+
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    out = {}
+    for name, (g, d, e) in GROUPED.items():
+        for rows in (48, 512):
+            for which, (k, n) in (("up", (d, e)), ("down", (e, d))):
+                jaxpr = str(jax.make_jaxpr(
+                    lambda x, w, sizes: gm.grouped_matmul_pallas(
+                        x, w, sizes, interpret=False))(
+                    jax.ShapeDtypeStruct((rows, k), jnp.bfloat16),
+                    jax.ShapeDtypeStruct((g, k, n), jnp.bfloat16),
+                    jax.ShapeDtypeStruct((g,), jnp.int32)))
+                out[f"{name}_{which}_{rows}_rows"] = re.sub(
+                    r"(/[\w.\-]+)+\.py(:\d+)?(:\d+)?", "<file>", jaxpr)
+    return {k: hashlib.sha256(v.encode()).hexdigest()[:16]
+            for k, v in out.items()}
+
+
 if __name__ == "__main__":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     root = os.path.abspath(sys.argv[1]) if len(sys.argv) > 1 else \
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    print(json.dumps(dict(programs(root), mosaic=mosaic(root)), indent=1))
+    print(json.dumps(dict(programs(root), mosaic=mosaic(root),
+                          grouped=grouped(root)), indent=1))

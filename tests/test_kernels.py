@@ -1035,7 +1035,21 @@ _GROUPED_CASES = {
     "k1408_n2048_16_groups": (64, 1408, 2048,
                               [5, 0, 0, 30, 0, 0, 0, 0, 11, 0, 0, 0, 0, 0,
                                0, 2]),
+    # the routed experts of serving.arch.MambaMoE at the published
+    # widths: 2688 -> 1856 (14.5 lane tiles: held transposed, panels of
+    # 640 lanes, the last overhanging the matrix; as it lies, a block
+    # equal to the array, which no caller has) and back over k = 1856
+    "k2688_n1856_not_whole_lane_tiles": (48, 2688, 1856,
+                                         [0, 9, 0, 17, 1, 0, 20, 0]),
+    "k1856_n2688_not_whole_lane_tiles": (48, 1856, 2688,
+                                         [5, 0, 30, 0, 0, 11, 0, 2]),
+    "n200_two_panels_one_overhanging": (40, 72, 200, [3, 0, 17, 9, 2]),
 }
+# the same cases with the matrices held [g, n, k] (``transpose_rhs``):
+# what an architecture does with a width that is not whole lane tiles
+_GROUPED_TRANSPOSED = ("uneven_with_an_empty_group",
+                       "k2688_n1856_not_whole_lane_tiles",
+                       "n200_two_panels_one_overhanging")
 
 
 def _grouped_truth(lhs, rhs, sizes):
@@ -1072,6 +1086,158 @@ def test_grouped_matmul_backends_agree_with_the_truth(case, dtype):
     assert np.abs(ref - want).max() <= tol
     assert np.abs(mosaic - ref).max() <= tol
     assert not mosaic[sum(sizes):].any() and not ref[sum(sizes):].any()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", _GROUPED_TRANSPOSED)
+def test_grouped_matmul_with_the_matrices_held_transposed(case, dtype):
+    """``transpose_rhs``: the matrices as ``[g, n, k]``, both backends
+    against the NumPy loop and each other within ``ORACLE_TOL``."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    m, k, n, sizes = _GROUPED_CASES[case]
+    rng = np.random.default_rng(51)
+    lhs = jnp.asarray(rng.normal(size=(m, k)), dtype)
+    rhs = jnp.asarray(rng.normal(size=(len(sizes), n, k)) / np.sqrt(k), dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+    want = _grouped_truth(lhs, jnp.swapaxes(rhs, 1, 2), sizes)
+    ref = np.asarray(get_kernel("grouped_matmul", "xla_ref").impl.call(
+        lhs, rhs, gs, transpose_rhs=True), np.float32)
+    mosaic = np.asarray(jax.jit(lambda *a: gm.grouped_matmul_pallas(
+        *a, interpret=True, block_m=32, transpose_rhs=True))(lhs, rhs, gs),
+        np.float32)
+    assert mosaic.shape == ref.shape == (m, n)
+    tol = oracle_tol("grouped_matmul", dtype, "fwd") * max(
+        np.abs(want).max(), 1.0)
+    assert np.abs(ref - want).max() <= tol
+    assert np.abs(mosaic - ref).max() <= tol
+    assert not mosaic[sum(sizes):].any() and not ref[sum(sizes):].any()
+
+
+def test_grouped_matmul_panels_for_a_width_that_is_not_whole_lane_tiles():
+    from paddle_tpu.kernels.grouped_matmul import _block_n
+
+    # whole lane tiles: the widest divisor within PANEL_BYTES, as before
+    assert _block_n(3072, 3072, 2) == 512 and _block_n(2048, 1408, 2) == 128
+    assert _block_n(1856, 2688, 2) == 896
+    # 1,856 = 14.5 tiles, held transposed: three panels of 640 cover
+    # 1,920, the fewest lanes past the matrix of any panel within
+    # PANEL_BYTES; as it lies, a block equal to the array, as before
+    assert _block_n(2688, 1856, 2, overhang=True) == 640
+    assert _block_n(2688, 1856, 2) == 1856
+    assert _block_n(72, 200, 4, overhang=True) == 128
+    assert _block_n(72, 200, 4) == 200
+    assert _block_n(64, 24, 4, overhang=True) == _block_n(64, 24, 4) == 24
+    assert _block_n(3072, 3072, 2, overhang=True) == 512
+
+
+# -- Mamba-2's step and chunked form (kernels/ssm.py) --------------------------
+
+# (slots, heads, head lanes, groups, state, taps): a row of the state
+# that holds several heads of one group, a row a head, one group
+_SSM_CASES = {
+    "four_heads_a_lane_row": (5, 8, 16, 2, 32, 4),
+    "a_head_a_lane_row": (3, 4, 128, 2, 16, 4),
+    "two_heads_a_row_one_group": (4, 2, 64, 1, 128, 3),
+}
+
+
+def _ssm_layer(rng, heads, lanes, groups, state, taps, dtype):
+    width = heads * lanes + 2 * groups * state
+    f = lambda *s: jnp.asarray(rng.normal(size=s), dtype)       # noqa: E731
+    return width, dict(
+        conv_w=0.4 * f(width, taps), conv_b=0.1 * f(width),
+        dt_bias=f(heads) - 3.0, D=f(heads),
+        A_log=jnp.asarray(rng.uniform(0.0, 2.7, heads), dtype),
+        heads=heads, groups=groups)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_SSM_CASES))
+def test_ssm_step_backends_agree_and_leave_dead_slots_alone(case, dtype):
+    """The Mosaic step kernel (interpret mode) against the oracle within
+    ``ORACLE_TOL``: outputs, the state in its packed layout and the
+    convolution's tails; a slot that is not valid reads zeros and keeps
+    both to the bit."""
+    from paddle_tpu.kernels import ssm
+
+    slots, heads, lanes, groups, state, taps = _SSM_CASES[case]
+    rng = np.random.default_rng(51)
+    width, layer = _ssm_layer(rng, heads, lanes, groups, state, taps, dtype)
+    s_shape, t_shape = ssm.state_shapes(heads, lanes, groups, state, taps)
+    S = jnp.asarray(rng.normal(size=(slots,) + s_shape), jnp.float32)
+    tail = jnp.asarray(rng.normal(size=(slots,) + t_shape), dtype)
+    xbc = jnp.asarray(rng.normal(size=(slots, width)), dtype)
+    dt = jnp.asarray(rng.normal(size=(slots, heads)), dtype)
+    valid = jnp.arange(slots) % 3 != 1
+    want = ssm.ssm_step_ref(S, tail, xbc, dt, valid, **layer)
+    got = jax.jit(lambda *a: ssm.ssm_step_pallas(
+        *a, interpret=True, **layer))(S, tail, xbc, dt, valid)
+    tol = oracle_tol("ssm", dtype, "fwd")
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * max(np.abs(b).max(), 1.0)
+    dead = ~np.asarray(valid)
+    assert not np.asarray(got[0])[dead].any()
+    assert np.array_equal(np.asarray(got[1])[dead], np.asarray(S)[dead])
+    assert np.array_equal(np.asarray(got[2], np.float32)[dead],
+                          np.asarray(tail, np.float32)[dead])
+    # the packed layout holds S[h, p, n]: pack and unpack are inverses
+    S4 = ssm.unpack(S, heads, groups)
+    assert S4.shape == (slots, heads, lanes, state)
+    assert np.array_equal(np.asarray(ssm.pack(S4, groups)), np.asarray(S))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows,real,chunk", [(20, 17, 8), (32, 32, 8),
+                                             (8, 3, 128), (48, 40, 16)])
+def test_ssm_chunked_form_is_the_scan(rows, real, chunk, dtype):
+    """The chunked form (``(C B^T . L) X`` within a chunk, the state
+    across chunks) against the recurrence row by row (``ssm_scan_ref``)
+    within ``ORACLE_TOL``, across chunk boundaries, with a suffix of rows
+    that are not real, from a held state and from a fresh one."""
+    from paddle_tpu.kernels import ssm
+
+    slots, heads, lanes, groups, state, taps = _SSM_CASES[
+        "four_heads_a_lane_row"]
+    rng = np.random.default_rng(rows)
+    width, layer = _ssm_layer(rng, heads, lanes, groups, state, taps, dtype)
+    s_shape, t_shape = ssm.state_shapes(heads, lanes, groups, state, taps)
+    S = jnp.asarray(rng.normal(size=(slots,) + s_shape), jnp.float32)
+    tail = jnp.asarray(rng.normal(size=(slots,) + t_shape), dtype)
+    xbc = jnp.asarray(rng.normal(size=(rows, width)), dtype)
+    dt = jnp.asarray(rng.normal(size=(rows, heads)), dtype)
+    valid = jnp.arange(rows) < real
+    tol = oracle_tol("ssm", dtype, "fwd")
+    for fresh in (False, True):
+        y, Sn, tn = jax.jit(lambda *a: ssm.ssm_chunk(
+            *a, chunk_size=chunk, **layer))(
+            S, tail, jnp.int32(2), jnp.bool_(fresh), xbc, dt, valid)
+        # the same rows through the scan, letter for letter
+        keep = 0.0 if fresh else 1.0
+        t0 = tail[2] * jnp.asarray(keep, tail.dtype)
+        a = ssm._conv(jnp.concatenate([t0, xbc]), layer["conv_w"],
+                      layer["conv_b"])
+        x, B, C, delta, A = ssm._parts(
+            a, dt, layer["dt_bias"], layer["A_log"], heads, groups,
+            heads * lanes)
+        per = heads // groups
+        want, S_want = ssm.ssm_scan_ref(
+            ssm.unpack(S[2], heads, groups) * keep, x[:real],
+            jnp.repeat(B, per, axis=1)[:real],
+            jnp.repeat(C, per, axis=1)[:real], delta[:real], A,
+            layer["D"].astype(jnp.float32))
+        want = np.asarray(want).reshape(real, -1)
+        assert np.abs(np.asarray(y)[:real] - want).max() <= tol * max(
+            np.abs(want).max(), 1.0)
+        S_want = np.asarray(ssm.pack(S_want, groups))
+        assert np.abs(np.asarray(Sn[2]) - S_want).max() <= tol * max(
+            np.abs(S_want).max(), 1.0)
+        # the tails end at the last REAL row; other slots are untouched
+        rows_seen = np.asarray(jnp.concatenate([t0, xbc]), np.float32)
+        assert np.array_equal(np.asarray(tn[2], np.float32),
+                              rows_seen[real:real + taps - 1])
+        assert np.array_equal(np.asarray(Sn)[:2], np.asarray(S)[:2])
 
 
 def test_grouped_matmul_work_items_name_the_pairs_that_hold_a_row():
@@ -1188,16 +1354,17 @@ def test_global_env_fallback_counted_once_per_resolution(monkeypatch):
     assert count() == c0 + 2
 
 
-def test_two_backends_six_op_classes_and_any_platform_is_served():
+def test_two_backends_seven_op_classes_and_any_platform_is_served():
     """What the registry holds since the GPU lowerings and the gather op
-    class went and the grouped matrix product, retention and a wide
-    window's chain walk came: two backends, six op classes, an auto order
+    class went and the grouped matrix product, retention, a wide window's
+    chain walk and Mamba-2's recurrence came: two backends, seven op
+    classes, an auto order
     for the TPU and the CPU; a platform with no order of its own is
     served by the oracle for every op class."""
     assert kernels.BACKENDS == ("pallas_tpu", "xla_ref")
     assert sorted(kernels.registered_op_classes()) == [
         "chain_attention", "flash_attention", "fused_ce", "grouped_matmul",
-        "paged_attention", "retention"]
+        "paged_attention", "retention", "ssm"]
     assert set(kernels.AUTO_ORDER) == {"tpu", "cpu"}
     for op in kernels.registered_op_classes():
         assert {b for b, _, _ in available_backends(op)} == set(

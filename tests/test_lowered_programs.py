@@ -1,11 +1,15 @@
-"""The six architectures that are not ``SinkWindowMoE`` lower to the
+"""The seven architectures that are not ``MambaMoE`` lower to the
 programs they lowered to before planes stated their own shape, chains
 came in two kinds, the paged kernels took a sink and value lanes of
 their own, and ``routed_ffn`` took the shared expert by statement: the
 decode chunk, a narrow and a wide prefill piece of each (StableHLO, at
-each family's tiny size), and the Mosaic paged kernels' jaxprs, against
+each family's tiny size), the Mosaic paged kernels' jaxprs and the Mosaic
+grouped product's at the three routed cells' published widths, against
 hashes taken on a ``git archive`` of the parent commit with
-``tests/lowered_programs.py`` (PR 46; the same installation).  A PR that
+``tests/lowered_programs.py`` (PR 46; the same installation;
+``SinkWindowMoE``'s on the parent of PR 51, which gave ``routed_ffn`` the
+expert's form, ``grouped_matmul`` panels that overhang and ``_Cache`` ONE
+in-place call for every recurrence).  A PR that
 means to change one of them takes its hashes anew, the same way, and
 says which program changed and why."""
 
@@ -48,6 +52,11 @@ PARENT = {
         "decode_chunk_4": "ea43177f94722d62",
         "prefill_8": "6423ceab36f723d4",
         "prefill_32": "75fe99c4f114c472"
+    },
+    "sink_window_moe": {
+        "decode_chunk_4": "9d7913e1cd9270d3",
+        "prefill_8": "5bf10dc93f438d22",
+        "prefill_32": "7d64388882701c16"
     }
 }
 MOSAIC = {
@@ -56,6 +65,23 @@ MOSAIC = {
     "verify_window_grid_12_heads": "e35ccddc63a17f48",
     "float32_pool_group_4": "e526874d5c2a9bbb",
     "latent": "b4812bf81d7ed767"
+}
+# the Mosaic grouped product at the three routed cells' published widths
+# (a decode step's rows and a piece's, up and down), on the parent of PR
+# 51, which gave it panels that overhang and matrices held transposed
+GROUPED = {
+    "trinitylp_32x3072x3072_up_48_rows": "00d1b578cd392d30",
+    "trinitylp_32x3072x3072_down_48_rows": "00d1b578cd392d30",
+    "trinitylp_32x3072x3072_up_512_rows": "2a2ca7c1fd0ce8b3",
+    "trinitylp_32x3072x3072_down_512_rows": "2a2ca7c1fd0ce8b3",
+    "dsv2lite_16x2048x1408_up_48_rows": "25a79479efc51d4a",
+    "dsv2lite_16x2048x1408_down_48_rows": "78aa1ea6f63c9d5b",
+    "dsv2lite_16x2048x1408_up_512_rows": "09fb850d13ff86e6",
+    "dsv2lite_16x2048x1408_down_512_rows": "80f5ed0f002dcd17",
+    "mimo25_16x4096x2048_up_48_rows": "4f3ec407dd0f49b2",
+    "mimo25_16x4096x2048_down_48_rows": "0ee2d2a262fa323d",
+    "mimo25_16x4096x2048_up_512_rows": "8d83b3ac645a1d84",
+    "mimo25_16x4096x2048_down_512_rows": "4213e76a66d92722"
 }
 
 
@@ -77,3 +103,10 @@ def test_the_mosaic_kernels_jaxpr_is_the_parents(geometry, mine):
     if "mosaic" not in mine:
         mine["mosaic"] = lowered_programs.mosaic(ROOT)
     assert mine["mosaic"][geometry] == MOSAIC[geometry]
+
+
+@pytest.mark.parametrize("geometry", list(GROUPED))
+def test_the_mosaic_grouped_products_jaxpr_is_the_parents(geometry, mine):
+    if "grouped" not in mine:
+        mine["grouped"] = lowered_programs.grouped(ROOT)
+    assert mine["grouped"][geometry] == GROUPED[geometry]

@@ -261,7 +261,7 @@ def test_a_wide_piece_in_bfloat16_stays_within_the_familys_margin(
 
 @pytest.mark.parametrize("real", [512, 200, 128, 3])
 def test_retain_over_a_wide_piece_is_one_chunk_call_to_the_bit(real):
-    """``_Cache.retain`` hands a 512-row window to ``kernels.retention.
+    """``_Cache.advance`` hands a 512-row window to ``kernels.retention.
     chunk`` in ONE call (the kernel walks the rows in tiles of
     ``CHUNK_ROWS`` itself) and equals that call to the bit; four threaded
     128-row calls, the form before PR 44, give the same within float32
@@ -286,7 +286,7 @@ def test_retain_over_a_wide_piece_is_one_chunk_call_to_the_bit(real):
         cache = _bd._Cache(None, None, None, None,
                            start + jnp.arange(W)[None],
                            writable=valid[None], slot=jnp.int32(1))
-        y, planes = cache.retain(((), (), ((S, z),)), 0, q, k, v, lg)
+        y, planes = cache.advance(((), (), ((S, z),)), 0, _retention, q, k, v, lg)
         return (y[0],) + planes[2][0]
 
     @functools.partial(jax.jit, static_argnames=("calls", "rows"))

@@ -76,7 +76,7 @@ def test_retention_chunk_compiles_for_v5e(rows, one_chip):
 
 @pytest.mark.parametrize("rows", [256, 512])
 def test_a_wide_piece_is_one_chunk_call_in_place_for_v5e(rows, one_chip):
-    """A wide prefill window through ``_Cache.retain``: ONE Mosaic call
+    """A wide prefill window through ``_Cache.advance``: ONE Mosaic call
     a layer, named ``retention_chunk`` (the kernel walks the rows in
     tiles of ``CHUNK_ROWS`` itself), the slots' state aliased in place
     with no copy of it."""
@@ -90,7 +90,7 @@ def test_a_wide_piece_is_one_chunk_call_in_place_for_v5e(rows, one_chip):
         at = jnp.arange(rows)[None]
         cache = _Cache(None, None, None, None, start + at,
                        writable=at < n, slot=slot)
-        y, planes = cache.retain(((), (), ((S, z),)), 0, q, k, v, lg)
+        y, planes = cache.advance(((), (), ((S, z),)), 0, rt, q, k, v, lg)
         return (y,) + planes[2][0]
 
     real = rt.chunk
