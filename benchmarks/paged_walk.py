@@ -13,11 +13,19 @@ runs: ``agent_turns`` (24 slots x 24 entries, 16 heads of 128, one row
 a block), ``reason_decode`` (one pass's plane of the folded pool, 10
 slots), ``think_decode``'s full and window-512 planes (48 slots, a K/V
 group of 4 folded into four rows a block), ``chat_moe``'s (96 slots, 8
-K/V heads of 128, a group of 6, window 4096 and none), the speculative
+K/V heads of 128, a group of 6, window 4096 and none),
+``nemotron3n.chat_ssm``'s attention plane (40 slots, 2 K/V heads in 8
+pool rows, a group of 16), the speculative
 verify window (5 rows at consecutive positions) and the 12-head pool that
 takes the grid form, alone and under a verify window.  Live slots and their
 contexts are drawn from ``--seed`` in the range each cell's traffic
 reaches; a dead slot has a row of trash and ``pos = -1``.
+
+``--entries 1,2,4,8`` times every geometry that runs the Mosaic loop of
+two folded rows or more once at each count of table entries an
+iteration (``kernels.paged_attention.entries_per_iteration`` overridden
+HERE only; without it, once at what the rule gives); a line carries
+``entries_an_iteration`` and whether it was forced.
 
 ``--only writes`` (or any ``write_*`` name) times the K/V WRITE alone
 (``kernels.paged_attention.write`` into a donated pool, the device's busy
@@ -45,7 +53,10 @@ lanes): the full plane (4 K/V heads in the 8 rows ``pool_rows`` gives,
 heads, 8 query heads each; the table's entries under a slot's window are
 the trash block, as the engine's window chains leave them), against the
 PUBLISHED bytes (``chipbench/mixed_kv_bytes.py``: 2,560 and 5,120 B a
-position); and one layer's attention of a 512-row prefill piece that
+position; ``long_reason_full_short`` is the full plane at chains of
+19-94 live blocks, beside the 94-375 of ``long_reason_full``: the two
+together give what a slot costs whatever its length and what a block
+costs); and one layer's attention of a 512-row prefill piece that
 ends at 512 | 3,500 | 9,000 positions, both kinds, through ``attend``
 (the chain walk of ``kernels/chain_attention.py`` since PR 47) beside the
 dense spelling.  ``--only rungs`` times the same at ``think_decode``'s and
@@ -58,6 +69,7 @@ metric.
 """
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -91,6 +103,11 @@ GEOMETRIES = {
     "chat_moe_window": dict(S=96, W=1, NB=64, blocks=6145, rows=8, dh=128,
                             group=6, window=4096, live=20, ctx=(64, 2000),
                             config="trinity-large-preview"),
+    # nemotron3n.chat_ssm's attention planes: 2 K/V heads in the 8 rows
+    # ``pool_rows`` gives, 16 query heads a K/V head, 40 slots x 80 entries
+    "chat_ssm_full": dict(S=40, W=1, NB=80, blocks=3201, rows=8, dh=128,
+                          group=16, window=None, live=20, ctx=(100, 2500),
+                          hk=2, config="nemotron-3-nano-30b-a3b"),
     "verify_window": dict(S=24, W=5, NB=24, blocks=705, rows=16, dh=128,
                           group=1, window=None, live=5, ctx=(520, 760),
                           config="cerebras-gpt-1.3b"),
@@ -143,6 +160,11 @@ MIXED = {
     "long_reason_full": dict(S=24, W=1, NB=416, blocks=9985, rows=8, hk=4,
                              group=16, window=None, sink=False, live=10,
                              ctx=(3000, 12000), kind="full"),
+    # the full plane at the cell's shorter chains (19-94 live blocks a
+    # slot): what a slot costs whatever its length shows here
+    "long_reason_full_short": dict(S=24, W=1, NB=416, blocks=9985, rows=8,
+                                   hk=4, group=16, window=None, sink=False,
+                                   live=10, ctx=(600, 3000), kind="full"),
     "long_reason_window": dict(S=24, W=1, NB=416, blocks=505, rows=8, hk=8,
                                group=8, window=128, sink=True, live=10,
                                ctx=(3000, 12000), kind="window"),
@@ -192,6 +214,8 @@ def case(name, seed):
     shape = (g["blocks"], B, g["rows"], g["dh"])
     pool_k = rng.standard_normal(shape, np.float32) * 0.5
     pool_v = rng.standard_normal(shape, np.float32) * 0.5
+    if "hk" in g:               # the rows ``pool_rows`` added hold zeros
+        pool_k[:, :, g["hk"]:] = pool_v[:, :, g["hk"]:] = 0.0
     heads = _kv_rows(g) * g["group"]
     q = rng.standard_normal((S, W, heads, g["dh"]), np.float32) * 0.5
     table = np.zeros((S, NB), np.int32)
@@ -220,6 +244,8 @@ def _kv_rows(g):
     lanes (a pair of ``think_decode``'s heads of 64)."""
     if g["group"] == 1:
         return g["rows"]
+    if "hk" in g:
+        return g["hk"]
     from chipbench import hybrid_bytes
 
     size = hybrid_bytes.sizes(_config(g["config"]))
@@ -235,6 +261,14 @@ def least_seconds(name, attended, peak):
 
     g = GEOMETRIES[name]
     cfg = _config(g["config"])
+    if cfg.get("family") == "ssm_moe":
+        # ONE plane's call: the K and V the model caches of a position
+        from chipbench import ssm_moe_bytes
+
+        planes = ssm_moe_bytes.sizes(cfg)["attention_layers"]
+        ops, nbytes = (n / planes
+                       for n in ssm_moe_bytes.attention(cfg, attended))
+        return flops.roofline_seconds(ops, nbytes, peak)
     if g["group"] > 1:
         size = hybrid_bytes.sizes(cfg)
         ops = 6 * size["heads"] * size["head_dim"] * attended
@@ -672,11 +706,66 @@ def measure_write(name, calls, seed):
             "us_a_step": us * g["a_step"], "written_exactly": ok}
 
 
+def _shares_a_fold(name):
+    """Whether the geometry runs the Mosaic loop of two rows or more (a
+    K/V plane whose pool Mosaic slices, decode or a narrow window)."""
+    g = GEOMETRIES.get(name) or MIXED.get(name)
+    return bool(g and g["W"] * g["group"] > 1 and g["W"] < 8
+                and g["rows"] % 8 == 0)
+
+
+@contextlib.contextmanager
+def _entries(forced):
+    """``kernels.paged_attention.entries_per_iteration`` answering
+    ``forced`` while a geometry is traced, or as the module has it (``None``); yields what it answers
+    for a geometry by name."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import paged_attention as pa
+
+    keep = pa.entries_per_iteration
+    if forced is not None:
+        pa.entries_per_iteration = lambda *a: forced
+
+    def answer(name):
+        g = GEOMETRIES.get(name) or MIXED[name]
+        dk, dv = (256, 128) if name in MIXED else (g["dh"], g["dh"])
+        return pa.entries_per_iteration(
+            BLOCK_TOKENS, g["rows"], dk, dv,
+            g["W"] * g["group"] * g["rows"], jnp.bfloat16,
+            pa.window_entries(g["NB"], BLOCK_TOKENS, g["W"], g["window"]))
+
+    try:
+        yield answer
+    finally:
+        pa.entries_per_iteration = keep
+
+
+def _measure(name, args, peak):
+    if name in WRITES:
+        return measure_write(name, args.calls, args.seed)
+    if name in LATENT:
+        return measure_latent(name, args.calls, peak, args.seed)
+    if name in MIXED:
+        return measure_mixed(name, args.calls, peak, args.seed)
+    if name in PIECES:
+        return measure_piece(name, args.calls, args.seed)
+    if name in RUNGS:
+        return measure_rung(name, args.calls, args.seed)
+    return measure(name, args.calls, peak, args.seed)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--only", default="",
                     help="comma-separated geometry names (default: all "
                          "the kernel's); `writes`: the K/V writes")
+    ap.add_argument("--entries", default="",
+                    help="comma-separated table entries an iteration of "
+                         "the loop of two rows or more (1,2,4,8): each K/V "
+                         "geometry that runs it is timed once at each, "
+                         "`entries_per_iteration` overridden here only "
+                         "(default: what the rule gives, once)")
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--seed", type=int, default=33)
     ap.add_argument("--out", default="chiprun_out/paged_walk.jsonl")
@@ -701,23 +790,20 @@ def main():
         names = ([n for n in names if n != "latent"] + list(LATENT)
                  + list(PIECES))
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    forced = [int(n) for n in args.entries.split(",") if n]
     with open(args.out, "a") as f:
-        for name in names:
+        for name, entries in ((name, n) for name in names
+                              for n in (forced if _shares_a_fold(name)
+                                        and forced else [None])):
             try:
-                if name in WRITES:
-                    line = measure_write(name, args.calls, args.seed)
-                elif name in LATENT:
-                    line = measure_latent(name, args.calls, peak, args.seed)
-                elif name in MIXED:
-                    line = measure_mixed(name, args.calls, peak, args.seed)
-                elif name in PIECES:
-                    line = measure_piece(name, args.calls, args.seed)
-                elif name in RUNGS:
-                    line = measure_rung(name, args.calls, args.seed)
-                else:
-                    line = measure(name, args.calls, peak, args.seed)
+                with _entries(entries) as rule:
+                    line = _measure(name, args, peak)
+                    if _shares_a_fold(name):
+                        line["entries_an_iteration"] = rule(name)
+                        line["entries_forced"] = entries is not None
             except Exception as e:  # noqa: BLE001 - a geometry Mosaic refuses
-                line = {"geometry": name, "error": repr(e)[:400]}
+                line = {"geometry": name, "entries_an_iteration": entries,
+                        "error": repr(e)[:400]}
             line["device"] = dev.device_kind
             text = json.dumps(line)
             print(text, flush=True)

@@ -133,8 +133,14 @@ Backends:
   (a K/V group's rows among them): their scores are one MXU pass, the
   mask, the running maximum, the ``exp`` and the sums are one update
   over one array that holds the rows on its sublanes, and ``p * v`` is
-  a second MXU pass for all of them (``softmax_updates``); the loop
-  makes a block's scores while the block before it is weighed.  A row
+  a second MXU pass for all of them (``softmax_updates``).  The loop of
+  two rows or more takes ``G`` consecutive table entries an iteration
+  (``entries_per_iteration``: a rule on the shapes, 8 on a chain of 416
+  entries, 1 on a window's handful): their blocks land side by side in
+  one buffer and are ONE block of ``G x B`` tokens to the scores, the
+  update and the value product, and a group's scores are made while
+  the group before it is weighed (``loop_iterations`` states the trip
+  count for whoever counts it).  A row
   with ``pos < 0`` has no visible key and returns zeros; a slot of such
   rows (a dead slot, as ``batched_decode._Cache`` names it) costs one
   empty grid step.  Registered available on real TPU only (off-TPU the
@@ -153,7 +159,8 @@ from .xla_ref import NEG_INF
 __all__ = ["attend", "CHAIN_SCORE_BYTES", "DENSE_WINDOW", "DENSE_SCORE_BYTES",
            "dense_entries", "dense_window", "key_lanes", "walks_chain",
            "window_entries",
-           "latent_attention_pallas", "latent_lanes", "paged_attention_ref", "paged_attention_pallas",
+           "entries_per_iteration", "latent_attention_pallas", "latent_lanes",
+           "loop_iterations", "paged_attention_ref", "paged_attention_pallas",
            "pool_rows", "softmax_updates", "write"]
 
 # From this window width up a window gathers its slot's chain once and
@@ -190,13 +197,26 @@ CHAIN_SCORE_BYTES = 128 << 20
 # others (``_dense_by_head``).  Read at trace time, like ``DENSE_WINDOW``.
 DENSE_SCORE_BYTES = 1 << 30
 
-# Blocks the Mosaic loop of two rows or more keeps in VMEM: the one whose
-# values are weighed, the one whose scores are made, and two on their way
-# from the pool.  Measured alone on the chip at four rows a block
+# Buffers the Mosaic loop of two rows or more keeps in VMEM, each a group
+# of ``entries_per_iteration`` blocks: the one whose values are weighed,
+# the one whose scores are made, and two on their way from the pool.
+# Measured alone on the chip at four rows a block and one block a buffer
 # (think_decode's full plane; benchmarks/paged_walk.py, PERF.md PR 35):
 # two buffers 0.70 us a live block (a copy's latency, not its bytes),
 # three or four 0.62, four with the scores a block ahead 0.41, six 0.42.
+# Read again at the groups PR 52 gave the buffers (0.40 at that plane's
+# two entries; long_reason's full plane 0.466 at one entry a buffer, 0.304
+# at eight: ``entries_per_iteration`` has the table): what more copies in
+# flight did not buy, more entries a copy's wait did.
 DEPTH = 4
+
+# The loop of two rows or more takes up to MAX_ENTRIES table entries an
+# iteration (``entries_per_iteration``, which has the chip's table): a
+# group no more than one GROUP_SHARE-th of the entries a slot's chain can
+# have live, within LOOP_VMEM_BYTES of Mosaic's default scoped 16 MiB.
+MAX_ENTRIES = 8
+GROUP_SHARE = 32
+LOOP_VMEM_BYTES = 12 << 20
 
 # Table entries of a LATENT plane the Mosaic loop takes in one iteration
 # (``latent_attention_pallas``).  An iteration costs its chain's latency
@@ -316,6 +336,94 @@ def softmax_updates(rows):
     if rows < 1:
         raise ValueError(f"paged_attention: {rows} rows a block")
     return 1
+
+
+def entries_per_iteration(B, h, dh, dv, N, dtype, live):
+    """Table entries ONE iteration of the Mosaic loop of two folded rows
+    or more takes (``paged_attention_pallas``'s ``shared_loop_kernel``),
+    from the shapes the kernel sees at trace time and from nothing else:
+    the largest power of two up to ``MAX_ENTRIES`` that is no more than
+    ``live // GROUP_SHARE`` (``live`` the table entries a slot's chain
+    can have live in one call: the table's ``NB``, or under a lower
+    bound its ``window_entries``) and whose VMEM (``_loop_vmem_bytes``)
+    fits ``LOOP_VMEM_BYTES``; 1 where that leaves nothing.  ONE
+    algorithm that wants another parameter by geometry.
+
+    Why a share of the chain.  An iteration costs its chain of latencies
+    (copy, MXU, lane reduction, ``exp``, MXU) whatever it holds, so G
+    entries an iteration divide that; but a slot pays up to ``G - 1``
+    entries past its chain's end, scored and weighed under a zero weight,
+    and its first group's copies with nothing to run under them, once
+    each whatever its length.  Alone on the chip, us a live block at G =
+    1 | 2 | 4 | 8 (bfloat16 pools, blocks of 32 tokens;
+    benchmarks/paged_walk.py --entries, seed 33, my chip run, PR 52;
+    RESULTS.md; a slot's live blocks in brackets):
+
+    =====================================================  =====  =====  =====  =====
+    long_reason_full: 4 heads in 8 rows, 16 query rows a
+    K/V row, K 256 lanes, V 128, NB 416 [205]              0.466  0.338  0.312  0.304
+    the same at shorter chains [49]                        0.488  0.370  0.357  0.374
+    long_reason_window: 8 heads, 8 rows, window 128, the
+    sink, 5 entries at most [4.9]; us a call                31.6   32.5   40.7   39.7
+    think_decode full: 10 heads in 16 rows, 4 rows, NB 64
+    [36]                                                   0.402  0.397  0.420  0.448
+    think_decode window 512: 17 entries at most [16.6]     0.458  0.472  0.543  0.652
+    chat_moe: 8 heads, 6 rows, NB 64 [35] (window 4096
+    alike)                                                 0.351  0.249  0.259  0.256
+    chat_ssm: 2 heads in 8 rows, 16 rows, NB 80 [49]       0.510  0.316  0.296  0.301
+    =====================================================  =====  =====  =====  =====
+
+    The full plane's two rows together: a call costs 1.4 us a live slot
+    and 0.459 a block at G = 1, 4.5 and 0.282 at G = 8 (0.24 is its
+    stored bytes at the HBM peak).  Where the work of a block already
+    costs what its chain does (think_decode's 262 KB and 512 score lanes
+    a block) or a slot has a handful of live blocks, a group only adds
+    its tail; the rule gives 8 | 8 | 1 | 2 | 1 | 2 | 2, the best of each
+    row but the second and the last (G = 4 there is 5% and 6% faster)."""
+    G = MAX_ENTRIES
+    while G > 1 and (G * GROUP_SHARE > live or _loop_vmem_bytes(
+            G, B, h, dh, dv, N, dtype) > LOOP_VMEM_BYTES):
+        G //= 2
+    return G
+
+
+def _loop_vmem_bytes(G, B, h, dh, dv, N, dtype):
+    """VMEM the loop of two rows or more holds at ``G`` entries an
+    iteration: the K and V buffers, and a ``[N, G * B * h]`` array of
+    scores four times in float32 (the scratch the next group's wait in,
+    the scores being made, ``s`` and ``p`` of the update) and as
+    ``_weigh``'s three bfloat16 pieces (a float32 pool: V as float32
+    instead)."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = G * B * h
+    buffers = DEPTH * lanes * (dh + dv) * item
+    weigh = 6 * N * lanes if item < 4 else 4 * lanes * dv
+    return buffers + 16 * N * lanes + weigh
+
+
+def loop_iterations(entries, rows, block_shapes, dtype, NB, window=None):
+    """Iterations the Mosaic loop makes over ``entries`` live table
+    entries of ONE slot's chain in one decode call (from the plane's
+    first live entry: a group starts there) that sends ``rows`` query
+    rows through each: ``ceil(entries / G)`` at the ``G`` of
+    ``entries_per_iteration`` for two rows or more of a K/V plane whose
+    pool Mosaic slices; ``entries`` for one row and for the grid form
+    (an entry an iteration, a step); a latent plane's groups of
+    ``LATENT_BLOCKS``.  ``block_shapes`` as the architecture states a
+    plane's (``plane_block_shapes``: ``(K, V)`` block shapes ``[B, h,
+    lanes]``, or ``(rows,)`` for a latent plane), ``NB`` the table's
+    entries a slot, ``window`` the plane's lower bound.  Stated here for
+    whoever counts the kernel's iterations
+    (``serving.paged_iterations_live``): the engine does not guess."""
+    if len(block_shapes) == 1:
+        G = min(LATENT_BLOCKS, NB)
+    else:
+        (B, h, dh), (_, _, dv) = block_shapes
+        if rows == 1 or not _rows_are_sliceable(h, dtype):
+            return entries
+        G = entries_per_iteration(B, h, dh, dv, rows * h, dtype,
+                                  window_entries(NB, B, 1, window))
+    return -(-entries // G)
 
 
 def walks_chain(width, rows, positions):
@@ -617,7 +725,11 @@ def _block_is_sliceable(pool):
     It refuses a packed (sub-32-bit) pool whose head count does not fill
     its sublane tiles: "Slice shape along dimension 2 must be aligned to
     tiling (8), but is 12" (bf16, 12 heads; 6 and 20 alike)."""
-    return pool.dtype.itemsize >= 4 or pool.shape[2] % 8 == 0
+    return _rows_are_sliceable(pool.shape[2], pool.dtype)
+
+
+def _rows_are_sliceable(rows, dtype):
+    return jnp.dtype(dtype).itemsize >= 4 or rows % 8 == 0
 
 
 def _matmul(a, b, dims):
@@ -712,12 +824,15 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     goes as the three bfloat16 pieces that sum to it exactly, stacked on
     the row axis (their products with ``v`` are exact in f32); for a
     float32 pool at ``HIGHEST``.  No transpose, no weight rounded to
-    bfloat16, no key left out.  What a block then costs is the LATENCY
-    of its chain (copy, MXU, lane reduction, ``exp``, MXU), not its
-    work, so the loop form keeps ``DEPTH`` copies ahead and two chains
-    in flight (``shared_loop_kernel``: block ``i + 1``'s scores and row
-    maxima are made while block ``i`` is weighed); the grid form, whose
-    pipeline is Pallas's, makes one after the other.  With ONE row (``W
+    bfloat16, no key left out.  What an iteration then costs is the
+    LATENCY of its chain (copy, MXU, lane reduction, ``exp``, MXU), not
+    its work, so the loop form takes ``G`` consecutive table entries an
+    iteration as one block of ``G x B`` tokens
+    (``entries_per_iteration``, which has the chip's table), keeps
+    ``DEPTH`` groups' copies ahead and two chains in flight
+    (``shared_loop_kernel``: group ``g + 1``'s scores and row maxima are
+    made while group ``g`` is weighed); the grid form, whose pipeline is
+    Pallas's, makes one block after the other.  With ONE row (``W
     = 1`` and no group: plain decode) there is nothing to share and the
     body is the per-row program it always was: the score a lane
     reduction of ``k * q``, ``m`` and ``l`` lane-replicated, ``p * v``
@@ -725,8 +840,9 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     faster there in the loop form and 15% slower in the grid form, whose
     12 heads do not fill a sublane tile and have to be repacked for the
     MXU: ``benchmarks/paged_walk.py``, PERF.md PR 33).  ``block_step``
-    is accepted for signature parity and ignored: this spelling streams
-    exactly one block per iteration by construction.
+    is accepted for signature parity and ignored: how many blocks an
+    iteration takes is this spelling's own (one for one row and in the
+    grid form, ``entries_per_iteration`` for two rows or more).
 
     The V array may have other lanes than the K array (``dv`` of
     ``pool_v``; scores over ``dh``, values, ``acc`` and the output over
@@ -748,6 +864,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             scale=scale, out_dtype=out_dtype, interpret=interpret)
     if sink is not None:
         sink = _fold_sink(sink, group, pool_k.shape[2], q.shape[1])
+    width = q.shape[1]              # positions, before a group is folded in
     q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
     out_dtype = q.dtype if out_dtype is None else out_dtype
     S, W, h, dh = q.shape
@@ -785,6 +902,9 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     # ``st`` below is the three refs ``(m_ref, l_ref, acc_ref)``.
     N = W * h
     f32 = jnp.float32
+    # table entries an iteration of the loop of two rows or more
+    G = entries_per_iteration(B, h, dh, dv, N, pool_k.dtype,
+                              window_entries(NB, B, width, window))
 
     def init(m_ref, l_ref, acc_ref, sink_ref=None):
         if sink_ref is None:
@@ -828,13 +948,17 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         l_ref[0] = jnp.broadcast_to(l2, (h, LSE_LANES))
 
     def scores(i, kb, s_id, pos_ref, q_ref):
-        """Every row's masked scores against block ``i`` of slot
-        ``s_id``'s chain, ``[N, B * h]``, and each row's maximum."""
+        """Every row's masked scores against the tokens of ``kb [T, h,
+        dh]``, which start at entry ``i`` of slot ``s_id``'s chain (one
+        block, or the ``G`` consecutive entries of a group: consecutive
+        entries hold consecutive positions), ``[N, T * h]``, and each
+        row's maximum."""
+        T = kb.shape[0]
         # every row against every token and head of the block in ONE MXU
         # pass; a row's own head is every h-th lane
         dt = jnp.promote_types(kb.dtype, q_ref.dtype)
         s = _matmul(q_ref[0].reshape(N, dh).astype(dt),
-                   kb.reshape(B * h, dh).astype(dt), ((1,), (1,))) * scale
+                   kb.reshape(T * h, dh).astype(dt), ((1,), (1,))) * scale
         # row (w, j) keeps the lanes of head j whose token its position
         # lets through: token t <= at is lane < (at + 1) * h
         row = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
@@ -842,7 +966,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         for w in range(1, W):
             top = jnp.where(row >= w * h,
                             (pos_ref[s_id, w] + 1 - i * B) * h, top)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (N, B * h), 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (N, T * h), 1)
         keep = (jax.lax.rem(lane, h) == jax.lax.rem(row, h)) & (lane < top)
         if window is not None:
             keep &= lane >= top - window * h
@@ -860,7 +984,8 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         # the product below sums over all of them
         p = jnp.exp(s - jnp.where(m2 == NEG_INF, 0.0, m2))
         l2 = l_ref[...][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + _weigh(p, vb.reshape(B * h, dv))
+        acc_ref[...] = acc_ref[...] * alpha + _weigh(
+            p, vb.reshape(vb.shape[0] * h, dv))
         m_ref[...] = jnp.broadcast_to(m2, (N, LSE_LANES))
         l_ref[...] = jnp.broadcast_to(l2, (N, LSE_LANES))
 
@@ -925,59 +1050,85 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     def shared_loop_kernel(tbl, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                            k_buf, v_buf, sem, s_ref, peak_ref, *st,
                            sink_ref=None):
-        """The loop over ``[f_s, n_s)`` for two rows or more.  With both
-        products on the MXU a block is a CHAIN of latencies (copy, MXU,
-        lane reduction, ``exp``, MXU) and not much work, so the loop
-        keeps two chains in flight: while block ``i``'s values are
-        weighed, block ``i + 1``'s scores and row maxima are made and
-        left in scratch for the next iteration, and ``DEPTH`` copies run
-        ahead (K is needed a block before V)."""
+        """The loop over ``[f_s, n_s)`` for two rows or more, ``G``
+        table entries an iteration (``entries_per_iteration``).  With
+        both products on the MXU an iteration is a CHAIN of latencies
+        (copy, MXU, lane reduction, ``exp``, MXU) and not much work, so
+        a group of ``G`` consecutive entries lands side by side in ONE
+        buffer ``[G * B, h, dh]`` and is scored, masked and weighed as
+        one block of ``G * B`` tokens (consecutive entries hold
+        consecutive positions), and the loop keeps two chains in
+        flight: while group ``g``'s values are weighed, group ``g +
+        1``'s scores and row maxima are made and left in scratch for
+        the next iteration, and ``DEPTH`` groups' copies run ahead (K is
+        needed a group before V); the last group, which has no next, is
+        weighed after the loop (a slot's cost whatever its length, which
+        a chain of a few entries feels: benchmarks/paged_walk.py,
+        ``long_reason_full_short``).  Group ``g`` holds entries ``f_s + g *
+        G ..``; those of the last group past ``n_s`` are fetched too
+        (the index clamped to the table's last entry: whatever block it
+        names holds finite values, which every row's mask weighs zero),
+        so that no lane of a buffer holds what was never written."""
         s_id = pl.program_id(0)
-        n = live_entries(pos_ref, s_id)
         first = first_entry(pos_ref, s_id)
+        groups = jax.lax.div(
+            jnp.maximum(live_entries(pos_ref, s_id) - first, 0) + G - 1, G)
 
-        def copy(i, plane):
+        def copies(g, plane):
             hbm, buf = ((k_hbm, k_buf), (v_hbm, v_buf))[plane]
-            slot = jax.lax.rem(i - first, DEPTH)
-            return pltpu.make_async_copy(hbm.at[tbl[s_id, i]], buf.at[slot],
-                                         sem.at[plane, slot])
+            slot = jax.lax.rem(g, DEPTH)
+            return [pltpu.make_async_copy(
+                hbm.at[tbl[s_id, jnp.minimum(first + g * G + j, NB - 1)]],
+                buf.at[slot, pl.ds(j * B, B)], sem.at[plane, slot, j])
+                for j in range(G)]
 
-        def score(i):
-            s, peak = scores(i, k_buf[jax.lax.rem(i - first, DEPTH)], s_id,
-                             pos_ref, q_ref)
+        def score(g):
+            s, peak = scores(first + g * G, k_buf[jax.lax.rem(g, DEPTH)],
+                             s_id, pos_ref, q_ref)
             s_ref[...] = s
             peak_ref[...] = jnp.broadcast_to(peak, (N, LSE_LANES))
 
         init(*st, sink_ref)
         for ahead in range(DEPTH - 1):
-            @pl.when(first + ahead < n)
+            @pl.when(ahead < groups)
             def _start(ahead=ahead):
                 for plane in (0, 1):
-                    copy(first + ahead, plane).start()
+                    for c in copies(ahead, plane):
+                        c.start()
 
-        @pl.when(first < n)
+        @pl.when(groups > 0)
         def _first():
-            copy(first, 0).wait()
-            score(first)
+            for c in copies(0, 0):
+                c.wait()
+            score(0)
 
-        def block(i, _):
-            @pl.when(i + DEPTH - 1 < n)
+        def group(g, _):
+            @pl.when(g + DEPTH - 1 < groups)
             def _ahead():
                 for plane in (0, 1):
-                    copy(i + DEPTH - 1, plane).start()
+                    for c in copies(g + DEPTH - 1, plane):
+                        c.start()
 
-            @pl.when(i + 1 < n)
-            def _next_k():
-                copy(i + 1, 0).wait()
-
-            copy(i, 1).wait()
+            for c in copies(g + 1, 0):
+                c.wait()
+            for c in copies(g, 1):
+                c.wait()
             s, peak = s_ref[...], peak_ref[...][:, :1]
-            # the last block's scores are made once more and dropped:
-            # no branch between the two chains
-            score(jnp.minimum(i + 1, n - 1))
-            update(s, peak, v_buf[jax.lax.rem(i - first, DEPTH)], *st)
+            score(g + 1)
+            update(s, peak, v_buf[jax.lax.rem(g, DEPTH)], *st)
 
-        jax.lax.fori_loop(first, n, block, None)
+        # every group but the last is weighed while the next one's
+        # scores are made, no branch between the two chains; the last
+        # has no next and is weighed alone
+        jax.lax.fori_loop(0, groups - 1, group, None)
+
+        @pl.when(groups > 0)
+        def _last():
+            for c in copies(groups - 1, 1):
+                c.wait()
+            update(s_ref[...], peak_ref[...][:, :1],
+                   v_buf[jax.lax.rem(groups - 1, DEPTH)], *st)
+
         finish(o_ref, *st)
 
     def grid_kernel(tbl, pos_ref, q_ref, k_ref, v_ref, o_ref, *st,
@@ -1010,14 +1161,17 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
         grid, semantics = (S,), ("parallel",)
         kv_spec = pl.BlockSpec(memory_space=pl.ANY)
         if W == 1:
-            kernel, deep, ahead = loop_kernel, 2, []
+            kernel = loop_kernel
+            scratch = [pltpu.VMEM((2, B, h, dh), pool_k.dtype),
+                       pltpu.VMEM((2, B, h, dv), pool_v.dtype),
+                       pltpu.SemaphoreType.DMA((2, 2))] + stats
         else:
-            kernel, deep = shared_loop_kernel, DEPTH
-            ahead = [pltpu.VMEM((N, B * h), jnp.float32),
-                     pltpu.VMEM((N, LSE_LANES), jnp.float32)]
-        scratch = [pltpu.VMEM((deep, B, h, dh), pool_k.dtype),
-                   pltpu.VMEM((deep, B, h, dv), pool_v.dtype),
-                   pltpu.SemaphoreType.DMA((2, deep))] + ahead + stats
+            kernel = shared_loop_kernel
+            scratch = [pltpu.VMEM((DEPTH, G * B, h, dh), pool_k.dtype),
+                       pltpu.VMEM((DEPTH, G * B, h, dv), pool_v.dtype),
+                       pltpu.SemaphoreType.DMA((2, DEPTH, G)),
+                       pltpu.VMEM((N, G * B * h), jnp.float32),
+                       pltpu.VMEM((N, LSE_LANES), jnp.float32)] + stats
     else:
         def last_live(s, nb, tbl, pos):
             i = jnp.minimum(nb, jnp.maximum(live_entries(pos, s) - 1, 0))

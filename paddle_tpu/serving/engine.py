@@ -578,14 +578,16 @@ class ServingEngine:
                             **dict(labels)).set(value)
         # (window, calls, bytes a cached position) of the paged calls a
         # token makes, for _count_paged_entries
-        # and the query rows a call sends through an entry, each the
-        # fact of a plane with that bound
+        # and the query rows a call sends through an entry and the
+        # plane's block shapes, each the fact of a plane with that bound
         bound = {}
         for i, w in enumerate(arch.planes):
             bound.setdefault(w, i)
         self._plane_reads = [
             (w, n, arch.plane_block_bytes(bound[w], 1, itemsize),
-             arch.plane_rows_per_entry(bound[w]))
+             arch.plane_rows_per_entry(bound[w]),
+             arch.plane_block_shapes(bound[w], self.block_tokens,
+                                     self.compute_dtype))
             for w, n in arch.plane_reads]
         # (window, calls, folded query rows a position) of a prefill
         # piece's calls on K/V planes, for _count_prefill_entries
@@ -692,9 +694,10 @@ class ServingEngine:
         blocks_per_slot`` table entries each paged-attention call spans,
         how many hold such a key (the last and everything before it down
         to the plane's lower bound), as the mean over the calls a
-        token makes; the query rows a call sends through each and the
-        softmax updates the kernel makes for them; and the K/V bytes
-        those calls have to read.  For retention layers, which have no
+        token makes; the query rows a call sends through each, the
+        softmax updates the kernel makes for them and the iterations its
+        loop makes over them; and the K/V bytes those calls have to
+        read.  For retention layers, which have no
         table: the states the chunk's steps read and write."""
         if self.arch.retention_layers:
             self._reg.counter(
@@ -708,19 +711,23 @@ class ServingEngine:
         if not self.arch.planes:
             return                  # no table entry, no K/V byte to count
         B = self.block_tokens
-        live = streamed = shared = rows_live = updates = 0
+        live = streamed = shared = rows_live = updates = iterations = 0
         live_by_kind = {"full": 0, "window": 0}
         # only the trie hands two slots one block
         sharing = self.prefix_trie is not None and len(contexts) > 1
-        for window, n, token_bytes, rows in self._plane_reads:
+        for window, n, token_bytes, rows, shapes in self._plane_reads:
             chains = []
             for s, ctx in contexts:                   # ctx keys attended
                 first = 0 if window is None else max(ctx - window, 0)
-                entries = n * ((ctx - 1) // B - first // B + 1)
+                one = (ctx - 1) // B - first // B + 1     # a call's
+                entries = n * one
                 live += entries
                 live_by_kind["full" if window is None else "window"] += entries
                 rows_live += entries * rows
                 updates += entries * _paged.softmax_updates(rows)
+                iterations += n * _paged.loop_iterations(
+                    one, rows, shapes, self.compute_dtype,
+                    self.blocks_per_slot, window)
                 streamed += n * (ctx - first) * token_bytes
                 if sharing:
                     chains.append(
@@ -790,6 +797,16 @@ class ServingEngine:
                  "call sends through an entry): paged_rows_live's "
                  "denominator",
         ).inc(updates / self._reads_per_token)
+        self._reg.counter(
+            "serving.paged_iterations_live",
+            help="iterations the paged kernel's loop made over the "
+                 "entries of paged_entries_live "
+                 "(kernels.paged_attention.loop_iterations of each live "
+                 "slot's entries: a group of table entries an iteration "
+                 "where rows share a fold or the plane is a latent one, "
+                 "an entry an iteration else); the mean over a token's "
+                 "calls",
+        ).inc(iterations / self._reads_per_token)
         self._reg.counter(
             "serving.paged_entries_total",
             help="block-table entries a paged-attention call spans "

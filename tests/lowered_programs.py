@@ -77,11 +77,14 @@ def programs(root, only=None):
 
 
 # (window rows, K/V rows of the pool, query heads a K/V head, lower
-# bound, dtype): the loop form for one row and for several, the grid form
-MOSAIC = {"one_row_loop": (1, 16, 1, None, "bfloat16"),
-          "group_6_window_loop": (1, 8, 6, 64, "bfloat16"),
-          "verify_window_grid_12_heads": (5, 12, 1, None, "bfloat16"),
-          "float32_pool_group_4": (1, 16, 4, None, "float32")}
+# bound, dtype, table entries a slot): the loop form for one row and for
+# several (one table entry an iteration on a table of 4, eight on one of
+# 416: ``entries_per_iteration``), the grid form
+MOSAIC = {"one_row_loop": (1, 16, 1, None, "bfloat16", 4),
+          "group_6_window_loop": (1, 8, 6, 64, "bfloat16", 4),
+          "verify_window_grid_12_heads": (5, 12, 1, None, "bfloat16", 4),
+          "float32_pool_group_4": (1, 16, 4, None, "float32", 4),
+          "group_16_loop_8_entries": (1, 8, 16, None, "bfloat16", 416)}
 
 
 def mosaic(root):
@@ -100,13 +103,13 @@ def mosaic(root):
         return re.sub(r"(/[\w.\-]+)+\.py(:\d+)?(:\d+)?", "<file>", jaxpr)
 
     out = {}
-    for name, (W, hk, group, window, dtype) in MOSAIC.items():
+    for name, (W, hk, group, window, dtype, NB) in MOSAIC.items():
         pool = jnp.zeros((9, 8, hk, 128), dtype)
         out[name] = text(
             lambda q, k, v, t, p: pa.paged_attention_pallas(
                 q, k, v, t, p, interpret=False, group=group, window=window),
             jnp.zeros((3, W, hk * group, 128), jnp.bfloat16), pool, pool,
-            jnp.zeros((3, 4), jnp.int32), jnp.zeros((3, W), jnp.int32))
+            jnp.zeros((3, NB), jnp.int32), jnp.zeros((3, W), jnp.int32))
     out["latent"] = text(
         lambda q, k, t, p: pa.paged_attention_pallas(
             q, k, None, t, p, interpret=False, value_lanes=128, scale=0.1),

@@ -367,6 +367,8 @@ def test_engine_counts_the_table_entries_a_paged_call_must_visit():
         chunks = max(chunks, k)
     st = eng.stats()
     assert st["serving.paged_entries_live"] == live
+    # one row a block: an entry an iteration
+    assert st["serving.paged_iterations_live"] == live
     assert st["serving.paged_entries_total"] == chunks * _SLOTS * (T // B)
     assert 0 < live < st["serving.paged_entries_total"]
 

@@ -59,11 +59,19 @@ PARENT = {
         "prefill_32": "7d64388882701c16"
     }
 }
+# PR 52 gave the loop of two rows or more G table entries an iteration
+# (``entries_per_iteration``): ``group_6_window_loop`` and
+# ``float32_pool_group_4`` (G = 1 on their table of 4: the group's
+# buffers, copies and trip count are spelled anew, the last group is
+# weighed after the loop) were taken again on
+# that PR's tree and ``group_16_loop_8_entries`` (G = 8) added; the
+# one-row loop, the grid form and the latent kernel are the parent's.
 MOSAIC = {
     "one_row_loop": "b901ca0e439058e8",
-    "group_6_window_loop": "28a8568bb7d057f3",
+    "group_6_window_loop": "8efea920881500cc",
     "verify_window_grid_12_heads": "e35ccddc63a17f48",
-    "float32_pool_group_4": "e526874d5c2a9bbb",
+    "float32_pool_group_4": "4a9625e5270b0b63",
+    "group_16_loop_8_entries": "8353fc8f25108d45",
     "latent": "b4812bf81d7ed767"
 }
 # the Mosaic grouped product at the three routed cells' published widths

@@ -70,12 +70,26 @@ GEOMETRIES = {
     "chat_moe_under_a_verify_window": (96, 5, 64, 6145, 8, 128, 6, 4096),
     "think_decode_float32_pool": (48, 1, 64, 3073, 16, 128, 4, None),
 }
+# table entries an iteration of the loop of two rows or more at the G
+# ``entries_per_iteration`` gives each (PR 52; 1 where not listed: one
+# row, the grid form, tables of 24 entries, a window of 512's 17 live
+# entries, VMEM under a verify window): whatever the rule gives has to
+# fit Mosaic's scoped VMEM, which only this compile shows
+ENTRIES = {
+    "think_decode_full_plane": 2,
+    "chat_moe_window_plane": 2,
+    "chat_moe_full_plane": 2,
+    "group_6_window_4096_long_chains": 4,
+    "chat_moe_under_a_verify_window": 2,
+    "think_decode_float32_pool": 2,
+}
 
 
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
 def test_paged_kernel_compiles_for_v5e(geometry, one_chip):
     from paddle_tpu.kernels.paged_attention import (
-        _block_is_sliceable, paged_attention_pallas)
+        _block_is_sliceable, entries_per_iteration, loop_iterations,
+        paged_attention_pallas, window_entries)
 
     S, W, NB, blocks, hk, dh, group, window = GEOMETRIES[geometry]
 
@@ -85,6 +99,14 @@ def test_paged_kernel_compiles_for_v5e(geometry, one_chip):
     pool = arg((blocks, 32, hk, dh),
                jnp.float32 if "float32_pool" in geometry else jnp.bfloat16)
     assert _block_is_sliceable(pool) == ("grid_form" not in geometry)
+    entries = ENTRIES.get(geometry, 1)
+    if W * group > 1 and _block_is_sliceable(pool):
+        assert entries_per_iteration(
+            32, hk, dh, dh, W * group * hk, pool.dtype,
+            window_entries(NB, 32, W, window)) == entries
+    if W == 1:
+        assert loop_iterations(41, group, ((32, hk, dh),) * 2, pool.dtype,
+                               NB, window) == -(-41 // entries)
     compiled = jax.jit(
         lambda *a: paged_attention_pallas(
             *a, interpret=False, group=group, window=window)).lower(

@@ -65,10 +65,14 @@ CASES = [("decode_full_sink", 1, 4, None, True),
          ("piece_window_no_sink", 8, 2, 6, False)]
 
 
-# the by-head spelling is a dense window's
+# the by-head spelling is a dense window's; a streamed window's Mosaic
+# loop at 2, 4 and 8 table entries an iteration too (the rule gives
+# these chains of 12 entries one: ``entries_per_iteration``)
 SPELLED = [(sp,) + c for c in CASES for sp in
            ("ref", "mosaic", "attend", "by_head")
            if sp != "by_head" or c[1] >= pa.DENSE_WINDOW]
+SPELLED += [(f"mosaic_{g}_entries",) + c for c in CASES for g in (2, 4, 8)
+            if c[1] < pa.DENSE_WINDOW]
 
 
 @pytest.mark.parametrize("spelling,name,W,group,window,with_sink", SPELLED)
@@ -82,6 +86,9 @@ def test_sink_and_value_lanes_against_a_plain_softmax(
                sink=jnp.asarray(sink) if with_sink else None)
     if spelling == "by_head":
         monkeypatch.setattr(pa, "DENSE_SCORE_BYTES", 0)
+    if spelling.endswith("_entries"):
+        monkeypatch.setattr(pa, "entries_per_iteration",
+                            lambda *a: int(spelling.split("_")[1]))
     if spelling in ("attend", "by_head"):
         # the caller's form: the key's own lanes, no scale stated
         got = pa.attend(jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),

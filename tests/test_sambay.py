@@ -347,6 +347,9 @@ def test_gauges_spans_and_the_streamed_bytes(params, monkeypatch):
     assert st["serving.paged_rows_live"] == (
         st["serving.paged_entries_live"] * eng.arch.rows_per_entry)
     assert st["serving.paged_updates_live"] == st["serving.paged_entries_live"]
+    # tables of 4 entries: the rule gives the shared fold one an iteration
+    assert st["serving.paged_iterations_live"] == st[
+        "serving.paged_entries_live"]
 
 
 @pytest.mark.parametrize("refused", ["prefix_reuse", "draft"])

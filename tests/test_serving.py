@@ -186,8 +186,12 @@ def test_engine_counts_rows_and_softmax_updates_of_live_entries(
     (``arch.rows_per_entry``, the K/V group the kernel folds into its
     window); ``serving.paged_updates_live`` the softmax updates the paged
     kernel makes for them, as its own module states them
-    (``softmax_updates``: one for all the rows of a block)."""
-    from paddle_tpu.kernels.paged_attention import softmax_updates
+    (``softmax_updates``: one for all the rows of a block), and
+    ``serving.paged_iterations_live`` the iterations its loop makes over
+    them (``loop_iterations``: a table of 8 entries gives every form an
+    entry an iteration)."""
+    from paddle_tpu.kernels.paged_attention import (loop_iterations,
+                                                    softmax_updates)
     from paddle_tpu.serving.arch import Gpt2
 
     class Grouped(Gpt2):
@@ -205,6 +209,34 @@ def test_engine_counts_rows_and_softmax_updates_of_live_entries(
     assert st["serving.paged_entries_live"] == 2 + 3
     assert st["serving.paged_rows_live"] == (2 + 3) * rows
     assert st["serving.paged_updates_live"] == (2 + 3) * updates
+    shapes = eng.arch.plane_block_shapes(0, 4, eng.compute_dtype)
+    assert st["serving.paged_iterations_live"] == sum(
+        loop_iterations(n, rows, shapes, eng.compute_dtype,
+                        eng.blocks_per_slot) for n in (2, 3)) == 2 + 3
+
+
+@pytest.mark.parametrize("rows,want", [(4, 1 + 2), (1, 2 + 3)])
+def test_engine_counts_a_group_of_entries_as_one_iteration(
+        params, rows, want, monkeypatch):
+    """Where the kernel's rule gives a group of table entries an
+    iteration the engine counts what the module states, a slot at a
+    time: chains of 2 and 3 live entries in groups of 2 are 1 + 2
+    iterations; one row a block keeps an entry an iteration."""
+    from paddle_tpu.kernels import paged_attention as pa
+    from paddle_tpu.serving.arch import Gpt2
+
+    class Grouped(Gpt2):
+        rows_per_entry = rows
+
+    monkeypatch.setattr(pa, "entries_per_iteration", lambda *a: 2)
+    eng = ServingEngine(params, arch=Grouped(NL, NH, DM), max_len=T,
+                        max_slots=2, decode_chunk=4, min_bucket=4,
+                        block_tokens=4, prefix_reuse=False)
+    eng.generate_many([np.arange(1, 6), np.arange(1, 10)],
+                      max_new_tokens=5)
+    st = eng.stats()
+    assert st["serving.paged_entries_live"] == 2 + 3
+    assert st["serving.paged_iterations_live"] == want
 
 
 def test_bf16_weights_serve_in_bf16_and_match(params):

@@ -35,12 +35,15 @@ def one_chip():
 SLOTS, NB, B = 24, 416, 32
 FULL_BLOCKS = 1 + SLOTS * NB
 WINDOW_BLOCKS = 1 + SLOTS * 21
-# (window rows, pool blocks, query heads a K/V head, lower bound, sink)
+# (window rows, pool blocks, query heads a K/V head, lower bound, sink,
+# table entries an iteration of the shared-fold loop: eight on a full
+# plane's chain of 416, one where a window of 128 keeps 5 or 6 live;
+# 64 rows a K/V row under a 4-row piece leave VMEM for four)
 PLANES = {
-    "decode_full_plane_group_16": (1, FULL_BLOCKS, 16, None, False),
-    "decode_window_plane_group_8_sink": (1, WINDOW_BLOCKS, 8, 128, True),
-    "narrow_piece_window_plane_sink": (4, WINDOW_BLOCKS, 8, 128, True),
-    "narrow_piece_full_plane": (4, FULL_BLOCKS, 16, None, False),
+    "decode_full_plane_group_16": (1, FULL_BLOCKS, 16, None, False, 8),
+    "decode_window_plane_group_8_sink": (1, WINDOW_BLOCKS, 8, 128, True, 1),
+    "narrow_piece_window_plane_sink": (4, WINDOW_BLOCKS, 8, 128, True, 1),
+    "narrow_piece_full_plane": (4, FULL_BLOCKS, 16, None, False, 2),
 }
 
 
@@ -50,8 +53,11 @@ def test_paged_kernel_compiles_for_v5e(plane, one_chip):
     of a full plane padded): the loop form, the pools in place."""
     from paddle_tpu.kernels import paged_attention as pa
 
-    W, blocks, group, window, sink = PLANES[plane]
+    W, blocks, group, window, sink, entries = PLANES[plane]
     S = 1 if W > 1 else SLOTS
+    assert pa.entries_per_iteration(
+        B, 8, pa.key_lanes(192), 128, W * group * 8, jnp.bfloat16,
+        pa.window_entries(NB, B, W, window)) == entries
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -85,6 +85,29 @@ def test_ssm_chunked_form_compiles_for_v5e(rows, one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def test_the_attention_planes_decode_call_compiles_at_the_rules_entries(
+        one_chip):
+    """2 K/V heads in the 8 rows ``pool_rows`` gives, 16 query rows a
+    K/V row, tables of 80 entries: the rule gives the shared-fold loop
+    two table entries an iteration (``entries_per_iteration``), and the
+    kernel compiles at them with the pools in place."""
+    from paddle_tpu.kernels import paged_attention as pa
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    nb, blocks = 2560 // 32, 1 + SLOTS * 2560 // 32
+    assert pa.pool_rows(2, BF16) == 8
+    assert pa.entries_per_iteration(32, 8, 128, 128, 16 * 8, BF16, nb) == 2
+    pool = arg((blocks, 32, 8, 128), BF16)
+    compiled = jax.jit(lambda *a: pa.paged_attention_pallas(
+        *a, interpret=False, group=16)).lower(
+        arg((SLOTS, 1, 32, 128), BF16), pool, pool,
+        arg((SLOTS, nb), jnp.int32), arg((SLOTS, 1), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert "paged_attention" in compiled.as_text()
+
+
 def test_grouped_matmul_compiles_at_16_experts_1856_wide(one_chip):
     """The up product with the matrices held ``[16, 1856, 2688]``
     (``transpose_rhs``) and the down product over ``k`` 1,856: neither
