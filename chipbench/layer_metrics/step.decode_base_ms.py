@@ -1,0 +1,19 @@
+"""Intercept of the least-squares line of a decode step's clock-pair time
+against the rows its chunk stepped, over EVERY chunk of the window
+(``serving.chunk_fit``; ``chipbench/tail_account.py::chunk_fit``): the step
+with no live slot, which does not move with the mix of chunks as the median
+(``step.decode_ms``) does.  None where the rows never varied."""
+
+from chipbench import tail_account
+
+NAME = "step.decode_base_ms"
+LAYER = "Decode/prefill step"
+UNIT = "ms"
+MOVES = "tpot_p90_ms"
+SOURCE = "program_span"
+RUNNERS = ("serve",)
+
+
+def read(facts):
+    fit = tail_account.chunk_fit(facts["stats"])
+    return None if fit is None else 1e3 * fit[0]

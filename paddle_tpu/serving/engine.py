@@ -92,6 +92,12 @@ __all__ = ["Request", "ServingEngine"]
 # {slot: request} of the rows live in it as of dispatch, and when it was sent
 _Chunk = collections.namedtuple("_Chunk", "toks counts reqs sent_t")
 
+# finished requests whose accounts the engine keeps for the tail's
+# make-up: the histograms' reservoir
+TPOT_RING = 4096
+# name prefixes of the gauges stats() publishes from that ring
+_TAIL_GAUGES = ("serving.tpot_p90_seconds", "serving.tpot_tail_")
+
 
 class Request:
     """One submitted generation request and its (eventual) result.
@@ -111,7 +117,9 @@ class Request:
                  "admit_t", "prefill_t0", "prefill_t1", "bucket",
                  "chunks", "slo_ok", "ttft_slo_s", "e2e_slo_s",
                  "shed", "sheddable", "prefix_hit",
-                 "spec_proposed", "spec_accepted", "_done")
+                 "spec_proposed", "spec_accepted",
+                 "chunk_s", "stall_prefill_s", "stall_host_s", "steps",
+                 "slot_steps", "_done")
 
     def __init__(self, rid, prompt, max_new, eos_id,
                  ttft_slo_s=None, e2e_slo_s=None, sheddable=True):
@@ -151,6 +159,18 @@ class Request:
         # draft tokens proposed for / accepted by this request
         self.spec_proposed = 0
         self.spec_accepted = 0
+        # the account of its time per output token, fed at every collect
+        # (ServingEngine._account_stall) whether the tracer is on or
+        # off: first token -> finish is chunk_s (its chunks' clock
+        # pairs) + stall_prefill_s (waits inside another request's
+        # prefill clock pair) + stall_host_s (the rest of its waits);
+        # the steps its chunks ran, and the rows the device stepped
+        # beside it x those steps
+        self.chunk_s = 0.0
+        self.stall_prefill_s = 0.0
+        self.stall_host_s = 0.0
+        self.steps = 0
+        self.slot_steps = 0
         self._done = threading.Event()
 
     @property
@@ -443,6 +463,14 @@ class ServingEngine:
         # when each slot's request last advanced (first token, then the
         # end of every chunk): serving.stalled_seconds counts from it
         self._slot_advanced = [0.0] * self.max_slots
+        # the seconds inside prefill clock pairs so far, and what that
+        # read when each slot last advanced: the difference at a chunk's
+        # start is the part of the slot's wait that prompts' pieces took
+        self._prefill_s = 0.0
+        self._slot_prefill_s = [0.0] * self.max_slots
+        # the accounts of the requests that finished, TPOT_RING most
+        # recent: stats() publishes the slowest fifth's make-up from it
+        self._tpot_ring = collections.deque(maxlen=TPOT_RING)
         # the decode chunks sent and not yet read, oldest first: at most
         # the one waited for and one queued behind it (_decode)
         self._chunks = collections.deque()
@@ -633,29 +661,70 @@ class ServingEngine:
             counter=("serving.driver_seconds", labels), event=event,
             **attrs)
 
-    def _account_stall(self, slots, t0, t1):
+    def _account_stall(self, live, t0, t1, steps, rode):
         """A decode chunk's clock pair is ``[t0, t1]``: every request it
-        advanced (``slots``) was last advanced at its previous chunk's
-        collect (or its first token), waited until ``t0`` while the
-        device had no chunk of its to run, and is advanced again at
-        ``t1``.  Between back-to-back chunks ``t0`` IS the previous
-        collect, so nothing is stalled."""
-        stalled = live = 0.0
-        for s in slots:
-            stalled += t0 - self._slot_advanced[s]
-            live += t1 - self._slot_advanced[s]
+        advanced (``live``, ``{slot: request}``) was last advanced at its
+        previous chunk's collect (or its first token), waited until
+        ``t0`` while the device had no chunk of its to run, and is
+        advanced again at ``t1``.  Between back-to-back chunks ``t0`` IS
+        the previous collect, so nothing is stalled.  The chunk ran
+        ``steps`` steps for ``rode`` rows (the live ones and those whose
+        request had ended by the time it was read).
+
+        Each request's account (``Request.chunk_s`` ...) grows by the
+        chunk and by the wait, split in the part that lay inside some
+        request's prefill clock pair and the rest; the chunk's step
+        against its rows feeds ``serving.chunk_fit``.  Returns the
+        longest wait a live request met before this chunk and the
+        prefill's part of it, in seconds."""
+        chunk = t1 - t0
+        stalled = live_s = longest = longest_prefill = 0.0
+        for s, req in live.items():
+            wait = t0 - self._slot_advanced[s]
+            # the pairs lie inside the wait: min() only against rounding
+            in_prefill = min(self._prefill_s - self._slot_prefill_s[s], wait)
+            req.chunk_s += chunk
+            req.stall_prefill_s += in_prefill
+            req.stall_host_s += wait - in_prefill
+            req.steps += steps
+            req.slot_steps += rode * steps
+            if wait > longest:
+                longest, longest_prefill = wait, in_prefill
+            stalled += wait
+            live_s += t1 - self._slot_advanced[s]
             self._slot_advanced[s] = t1
+            self._slot_prefill_s[s] = self._prefill_s
         self._reg.counter(
             "serving.stalled_seconds",
             help="seconds decoding requests waited between their chunks "
-                 "while the device had none of theirs to run (admission, "
-                 "another request's prefill; an emit only where no chunk "
-                 "was queued behind the one read)").inc(max(0.0, stalled))
+                 "while the device had none of theirs to run: inside "
+                 "another request's prefill clock pair (its pieces and "
+                 "its first-token fetch: a request's stall_prefill_s) or "
+                 "behind the driver's own work (the queue pick, the trie, "
+                 "block allocation, the table and the dispatch; an emit "
+                 "only where no chunk was queued behind the one read: "
+                 "stall_host_s)").inc(max(0.0, stalled))
         self._reg.counter(
             "serving.live_seconds",
             help="seconds decoding requests spent from one advance to "
-                 "the next (stalled + their chunks): stalled_seconds' "
-                 "denominator").inc(max(0.0, live))
+                 "the next (both parts of their waits + their chunks): "
+                 "stalled_seconds' denominator").inc(max(0.0, live_s))
+        # w = base + slope x a over every chunk of the window: five sums
+        w = chunk / steps
+        for key, v in (("n", 1.0), ("a", rode), ("aa", rode * rode),
+                       ("w", w), ("aw", rode * w)):
+            self._reg.counter(
+                "serving.chunk_fit", sum=key,
+                help="sums over the decode chunks collected, for the "
+                     "least-squares line of a step's seconds (w: clock "
+                     "pair / steps) against the rows the chunk stepped "
+                     "(a): n, a, a*a, w, a*w").inc(v)
+        self._reg.gauge(
+            "serving.longest_stall_seconds",
+            help="the longest single wait before a chunk that any live "
+                 "request met since the last accounting reset"
+        ).set_max(longest)
+        return longest, longest_prefill
 
     def _count_prefill_entries(self, pieces):
         """Prefill pieces were dispatched: for every call that WALKS its
@@ -1438,7 +1507,8 @@ class ServingEngine:
                  "had ended before the chunk was read (an eos_id hit in "
                  "the chunk before it): thrown away").inc(
                      (len(c.reqs) - len(live)) * self.decode_chunk)
-        self._account_stall(live, t0, t1)
+        stall, stall_prefill = self._account_stall(
+            live, t0, t1, self.decode_chunk, len(c.reqs))
         if self._tracer.enabled:
             # per-request chunk windows feed only the finish-time lane
             # emission, which is skipped when tracing is off — don't
@@ -1447,7 +1517,10 @@ class ServingEngine:
                 req.chunks.append((t0, t1))
         emitted = 0
         finished = 0
-        with self._span("serving.emit", "emit") as em:
+        # how long this chunk's rows had waited for it, and for what: on
+        # the profiler's host plane an idle gap can be laid against it
+        with self._span("serving.emit", "emit", stall_ms=stall * 1e3,
+                        stall_prefill_ms=stall_prefill * 1e3) as em:
             now = em.t0
             for j in range(self.decode_chunk):
                 for s, req in live.items():
@@ -1462,8 +1535,6 @@ class ServingEngine:
                         self._finish(req, now)
                         finished += 1
         self._reg.counter("serving.tokens").inc(emitted)
-        if wall > 0:
-            self._reg.gauge("serving.tok_s").set(emitted / wall)
         self._reg.gauge("serving.slots_active").set(self.active_slots)
         return finished
 
@@ -1546,17 +1617,19 @@ class ServingEngine:
         t0, t1 = rnd.t0, rnd.t1
         wall = t1 - t0
         active = self.active_slots
-        self._account_stall(
-            [s for s, req in enumerate(self._slots) if req is not None],
-            t0, t1)
+        live = {s: req for s, req in enumerate(self._slots)
+                if req is not None}
+        # a round verifies k + 1 positions a slot: its steps
+        stall, stall_prefill = self._account_stall(live, t0, t1, k + 1,
+                                                   len(live))
         if self._tracer.enabled:
-            for req in self._slots:
-                if req is not None:
-                    req.chunks.append((t0, t1))
+            for req in live.values():
+                req.chunks.append((t0, t1))
         emitted = 0
         finished = 0
         round_acc = 0
-        with self._span("serving.emit", "emit") as em:
+        with self._span("serving.emit", "emit", stall_ms=stall * 1e3,
+                        stall_prefill_ms=stall_prefill * 1e3) as em:
             now = em.t0
             for s, req in enumerate(self._slots):
                 if req is None:
@@ -1599,8 +1672,6 @@ class ServingEngine:
             # (the chunk-latency sample) closed: its emit span says it
             em.set(emitted=emitted, accepted=round_acc)
         self._reg.counter("serving.tokens").inc(emitted)
-        if wall > 0:
-            self._reg.gauge("serving.tok_s").set(emitted / wall)
         if sp.proposed:
             self._reg.gauge(
                 "serving.spec_accept_rate",
@@ -1867,7 +1938,11 @@ class ServingEngine:
         req.prefill_t0, req.prefill_t1 = t_p0, now
         self.predictor.observe_prefill(bucket, now - t_p0)
         req.first_token_t = now
+        # this prompt's clock pair is a prefill stall of every slot that
+        # waits through it, and no part of the slot's own account
+        self._prefill_s += now - t_p0
         self._slot_advanced[slot] = now
+        self._slot_prefill_s[slot] = self._prefill_s
         req.tokens.append(first)
         self._reg.counter("serving.admitted").inc()
         self._reg.counter("serving.tokens").inc()
@@ -1956,7 +2031,19 @@ class ServingEngine:
         self._reg.histogram("serving.e2e_seconds").observe(req.e2e)
         self._judge_slo(req, now)
         self._emit_request_trace(req)
+        account = None
+        if len(req.tokens) > 1 and req.error is None:
+            decode_s, gaps = now - req.first_token_t, len(req.tokens) - 1
+            self._reg.histogram(
+                "serving.tpot_seconds",
+                help="a finished request's time per output token: first "
+                     "token -> finish over the tokens after the first",
+            ).observe(decode_s / gaps)
+            account = (decode_s, gaps, req.chunk_s, req.stall_prefill_s,
+                       req.stall_host_s, req.steps, req.slot_steps)
         with self._qlock:
+            if account is not None:
+                self._tpot_ring.append(account)
             self._completed.append(req)
         req._done.set()
 
@@ -1976,17 +2063,26 @@ class ServingEngine:
             if self._spec is not None:
                 self._spec.proposed = 0
                 self._spec.accepted = 0
+            self._tpot_ring.clear()
         for nm in ("serving.slo_violations", "serving.goodput_tok_s",
                    "serving.shed_total", "serving.prefix_hit_rate",
                    "serving.prefix_hit_tokens", "serving.prefill_tokens",
                    "serving.prefill_real_tokens", "serving.cow_copies",
                    "serving.spec_accept_rate", "serving.spec_draft_ms",
-                   "serving.spec_rollback_blocks"):
+                   "serving.spec_rollback_blocks",
+                   # the stall share and the tail's account cover the
+                   # same window, whoever resets the registry or does not
+                   "serving.stalled_seconds", "serving.live_seconds",
+                   "serving.tpot_seconds", "serving.longest_stall_seconds"):
             m = self._reg.get(nm)
             if m is not None:
                 m.reset()
-        for m in self._reg.metrics("serving.prefill_pieces"):
-            m.reset()
+        for prefix in ("serving.prefill_pieces", "serving.chunk_fit"):
+            for m in self._reg.metrics(prefix):
+                m.reset()
+        # what stats() made of the ring: gone with it
+        for prefix in _TAIL_GAUGES:
+            self._reg.clear(prefix)
 
     def _judge_slo(self, req, now):
         """SLO verdict at completion: a TTFT or e2e budget breach counts
@@ -2049,11 +2145,19 @@ class ServingEngine:
         spec_attrs = ({"spec_proposed": req.spec_proposed,
                        "spec_accepted": req.spec_accepted}
                       if req.spec_proposed else {})
+        # what the chunks beneath it add up to (nothing to say of a
+        # request that ended at its first token)
+        account = ({"tpot_ms": 1e3 * (req.finish_t - req.first_token_t)
+                    / (len(req.tokens) - 1),
+                    "chunk_s": req.chunk_s,
+                    "stall_prefill_s": req.stall_prefill_s,
+                    "stall_host_s": req.stall_host_s, "steps": req.steps}
+                   if len(req.tokens) > 1 else {})
         tr.add_span("serving.request", req.submit_t, req.finish_t,
                     cat="serving", lane=lane, timer=False, rid=req.rid,
                     prompt_len=int(req.prompt.shape[0]),
                     tokens=len(req.tokens),
-                    prefix_hit=req.prefix_hit, **spec_attrs)
+                    prefix_hit=req.prefix_hit, **spec_attrs, **account)
         tr.add_span("serving.req.queue", req.submit_t, req.admit_t,
                     cat="serving", lane=lane, timer=False, rid=req.rid)
         if req.prefill_t0 is not None:
@@ -2090,6 +2194,55 @@ class ServingEngine:
         ends[i] = req.finish_t
         return i
 
+    def _publish_tail(self):
+        """The ring of finished requests' accounts as gauges: the 90th
+        percentile of their time per output token, and over the TAIL SET
+        (those at or above the ring's 80th percentile, the slowest fifth,
+        which the 90th percentile bisects) the sums whose ratios are the
+        tail's factors.  With T seconds, N tokens after the first, K
+        steps and C seconds inside chunks, T / N = (C / K) x (K / N) x
+        (T / C): the step at the load the tail met x the steps a token
+        cost x what waiting added.  Nothing is published from an empty
+        ring."""
+        with self._qlock:
+            ring = list(self._tpot_ring)
+        if not ring:
+            return
+        rank = _obs.Histogram._rank
+        tpot = [decode_s / gaps for decode_s, gaps, *_ in ring]
+        ranked = sorted(tpot)
+        cut = rank(ranked, 80)
+        tail = [a for a, v in zip(ring, tpot) if v >= cut]
+        _, gaps, chunk_s, prefill_s, host_s, steps, slot_steps = (
+            sum(col) for col in zip(*tail))
+        gauge = self._reg.gauge
+        gauge("serving.tpot_p90_seconds",
+              help="90th percentile (nearest rank) of the finished "
+                   "requests' time per output token, over the last "
+                   f"{TPOT_RING} since the accounting reset").set(
+                       rank(ranked, 90))
+        tail_help = ("over the finished requests at or above the 80th "
+                     "percentile of time per output token (the slowest "
+                     "fifth): ")
+        gauge("serving.tpot_tail_requests",
+              help=tail_help + "how many they are").set(len(tail))
+        gauge("serving.tpot_tail_tokens",
+              help=tail_help + "their tokens after the first").set(gaps)
+        gauge("serving.tpot_tail_steps",
+              help=tail_help + "the steps their chunks ran").set(steps)
+        gauge("serving.tpot_tail_slot_steps",
+              help=tail_help + "the rows their chunks stepped x the "
+                               "steps").set(slot_steps)
+        for part, v in (("chunk", chunk_s), ("stall_prefill", prefill_s),
+                        ("stall_host", host_s)):
+            gauge("serving.tpot_tail_seconds", part=part,
+                  help=tail_help + "their seconds from first token to "
+                       "finish: inside their chunks' clock pairs, waiting "
+                       "inside another request's prefill clock pair, "
+                       "waiting for the rest").set(v)
+
     def stats(self):
-        """Snapshot of the engine's ``serving.*`` metrics."""
+        """Snapshot of the engine's ``serving.*`` metrics, the tail's
+        make-up (``_publish_tail``) computed first."""
+        self._publish_tail()
         return self._reg.snapshot(prefix="serving.")

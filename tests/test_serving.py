@@ -280,7 +280,7 @@ def test_serving_metrics_exposed(params):
         assert r.ttft is not None and r.e2e is not None
         assert 0 <= r.ttft <= r.e2e
     text = _obs.get_registry().to_text()
-    for frag in ("serving_ttft_seconds", "serving_tok_s",
+    for frag in ("serving_ttft_seconds", "serving_tpot_seconds",
                  "serving_queue_depth", "serving_admitted"):
         assert frag in text, frag
 
