@@ -8,7 +8,7 @@ three the training step's ``breakdown`` names).  Three layers under one
 ``lax.scan``, because the scan decides what the compiler fuses where.
 
     chiprun -- python3 benchmarks/ffn_walk.py [--only present,op] \
-        [--calls 20] [--out chiprun_out/ffn_walk.jsonl]
+        [--step-shapes] [--calls 20] [--out chiprun_out/ffn_walk.jsonl]
 
 A layer-micro-batch is ``[4096, 1536] x [1536, 6144]``, the GELU on
 ``[4096, 6144]``, ``x [6144, 1536]``, bfloat16: 77.3 GFLOP a product,
@@ -18,8 +18,15 @@ registry's op does now, ``rational`` the op's formula with ``erf``
 written out as the clamped rational polynomial XLA uses for float32
 (what one ``erf`` instruction costs the chip against 25 plain
 operations), ``op_once`` the op with its output behind an optimization
-barrier (no second evaluation inside the second product: a row for the
-next PR, not what the op does).  A last line holds the op's error on the chip over every
+barrier (no second evaluation inside the second product: what a plain
+barrier gets), ``op_read`` the op with the two products as the
+scan-remat engine lowers them since PR 54 (``mul(_reads_saved=True)``:
+the operand behind a barrier in the forward alone, taken over the rows
+as they stand).  ``--step-shapes`` is the training step's geometry: rows
+``[2, 2048, .]`` through ``mul``'s flattening and the scan inside a
+two-iteration outer loop, where the compiler makes the step's choices
+and not the plain walk's (with flat ``[4096, .]`` rows ``op_read`` and
+a barrier on the flat operand are the same program).  A last line holds the op's error on the chip over every
 finite bfloat16 value against the float64 function.  Refuses unless JAX
 finds a TPU: a number from a CPU run is no device metric.
 """
@@ -35,6 +42,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ROWS, D_MODEL, D_FF = 4096, 1536, 6144
 LAYERS = 3  # of one scan: the times are a layer's
+OUTER = 2  # micro-batches of --step-shapes' outer loop
 TOP = 9  # instructions a line names
 
 # XLA's float32 erf (after Eigen): x P(x^2) / Q(x^2), x clamped to where
@@ -81,25 +89,27 @@ def _rational_gelu(x):
     return gelu(x)
 
 
-def activations():
+def ways():
+    """{way: (activation, whether the products read their operand)}."""
     import jax
 
     from paddle_tpu.ops import activation_ops
 
+    op = lambda h: activation_ops.gelu(h)["Out"]
     return {
-        "present": lambda h: jax.nn.gelu(h, approximate=False),
-        "op": lambda h: activation_ops.gelu(h)["Out"],
-        "rational": _rational_gelu,
+        "present": (lambda h: jax.nn.gelu(h, approximate=False), False),
+        "op": (op, False),
+        "rational": (_rational_gelu, False),
         # the op, its output behind a barrier: the second product has to
         # READ the activations the first one's epilogue wrote, where the
         # compiler otherwise evaluates the GELU again on its operand side
-        # (what ONE evaluation a forward would be worth: PERF.md section 7)
-        "op_once": lambda h: jax.lax.optimization_barrier(
-            activation_ops.gelu(h)["Out"]),
+        "op_once": (lambda h: jax.lax.optimization_barrier(op(h)), False),
+        # what the scan-remat engine does since PR 54
+        "op_read": (op, True),
     }
 
 
-def stack(act):
+def stack(act, reading=False):
     """``LAYERS`` FFN layers under one ``lax.scan``, as the scan-remat
     engine runs a uniform Program: ``mul``, then bias add and activation
     in one checkpointed segment, then ``mul``, bias and the residual.
@@ -111,18 +121,23 @@ def stack(act):
 
     from paddle_tpu.ops.math_ops import mul
 
+    def product(x, w):
+        return mul(x, w, x_num_col_dims=x.ndim - 1,
+                   _reads_saved=reading)["Out"]
+
     def body(x, layer):
         w1, b1, w2, b2 = layer
-        h = mul(x, w1)["Out"]
+        h = product(x, w1)
         a = jax.checkpoint(lambda h, b1: act(h + b1))(h, b1)
-        return x + (mul(a, w2)["Out"] + b2), None
+        return x + (product(a, w2) + b2), None
 
     return lambda x, layers: jax.lax.scan(body, x, layers)[0]
 
 
-def _timed(fn, args, calls):
+def _timed(fn, args, calls, layers=LAYERS):
     """(busy microseconds a layer, {instruction: microseconds a layer})
-    over ``calls`` calls of ``fn``, from the device's own clock."""
+    over ``calls`` calls of ``fn``, each ``layers`` layer-micro-batches,
+    from the device's own clock."""
     import jax
 
     from chipbench import trace_reduce
@@ -142,11 +157,11 @@ def _timed(fn, args, calls):
         if " while " not in name:  # a loop holds what is counted below
             by_name[name] = by_name.get(name, 0) + end - start
     top = sorted(by_name.items(), key=lambda kv: -kv[1])[:TOP]
-    a_layer = 1e-3 / (calls * LAYERS)
+    a_layer = 1e-3 / (calls * layers)
     return busy * a_layer, {name: ns * a_layer for name, ns in top}
 
 
-def measure(name, act, calls):
+def measure(name, way, calls, step_shapes=False):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -160,17 +175,27 @@ def measure(name, act, calls):
     # these keep the pre-activations of order one through the layers
     layers = (bf16((LAYERS, D_MODEL, D_FF), 0.03), bf16((LAYERS, D_FF), 0.1),
               bf16((LAYERS, D_FF, D_MODEL), 0.002), bf16((LAYERS, D_MODEL), 0.1))
-    x, dy = bf16((ROWS, D_MODEL), 1.0), bf16((ROWS, D_MODEL), 1.0)
-    ffn = stack(act)
+    rows = (OUTER, 2, ROWS // 2) if step_shapes else (ROWS,)
+    x, dy = bf16(rows + (D_MODEL,), 1.0), bf16(rows + (D_MODEL,), 1.0)
+    ffn = stack(*way)
 
     def both(x, layers, ct):
         y, vjp = jax.vjp(ffn, x, layers)
         return y, vjp(ct)
 
-    fwd_us, fwd_ops = _timed(jax.jit(ffn), (x, layers), calls)
-    both_us, both_ops = _timed(jax.jit(both), (x, layers, dy), calls)
-    return {"variant": name, "layers": LAYERS, "fwd_us": fwd_us,
-            "fwd_bwd_us": both_us, "fwd_ops_us": fwd_ops,
+    def looped(fn):
+        # a micro-batch's forward and backward inside the loop, as the
+        # accumulation loop has them
+        return lambda x, layers, *ct: jax.lax.map(
+            lambda rows: fn(rows[0], layers, *rows[1:]), (x, *ct))
+
+    n = LAYERS
+    if step_shapes:
+        ffn, both, n = looped(ffn), looped(both), LAYERS * OUTER
+    fwd_us, fwd_ops = _timed(jax.jit(ffn), (x, layers), calls, n)
+    both_us, both_ops = _timed(jax.jit(both), (x, layers, dy), calls, n)
+    return {"variant": name, "layers": LAYERS, "step_shapes": step_shapes,
+            "fwd_us": fwd_us, "fwd_bwd_us": both_us, "fwd_ops_us": fwd_ops,
             "fwd_bwd_ops_us": both_ops}
 
 
@@ -216,6 +241,9 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--only", default="",
                     help="comma-separated variants (default: all)")
+    ap.add_argument("--step-shapes", action="store_true",
+                    help="rows [2, 2048, .] and an outer loop over "
+                         "micro-batches, as the training step has them")
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--out", default="chiprun_out/ffn_walk.jsonl")
     args = ap.parse_args()
@@ -225,11 +253,12 @@ def main():
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         sys.exit(f"ffn_walk times the chip; JAX found {dev.platform}")
-    acts = activations()
+    acts = ways()
     names = [n for n in args.only.split(",") if n] or list(acts)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "a") as f:
-        lines = [measure(name, acts[name], args.calls) for name in names]
+        lines = [measure(name, acts[name], args.calls, args.step_shapes)
+                 for name in names]
         for line in lines + [accuracy()]:
             line["device"] = dev.device_kind
             text = json.dumps(line)

@@ -409,8 +409,11 @@ def _apply_activation_spec(ctx, name, spec, val):
         return val     # not kill the trace; spec-conflict lint names it
 
 
-def run_block_ops(ctx, block, ops, env, inside_grad_prefix=False):
-    """Trace-time evaluation of a list of OpDescs over a name->array env."""
+def run_block_ops(ctx, block, ops, env, inside_grad_prefix=False,
+                  reading=()):
+    """Trace-time evaluation of a list of OpDescs over a name->array env.
+    ``reading`` is the scan-remat body's: the outputs of the ``mul`` ops
+    that take the reading form (``ops/math_ops.py::_mul_reading``)."""
     act_specs = (
         _activation_shard_specs(ctx.program)
         if ctx.program is not None
@@ -444,6 +447,8 @@ def run_block_ops(ctx, block, ops, env, inside_grad_prefix=False):
         attrs = dict(op.attrs)
         if impl.stateful_rng and "_key" not in attrs:
             attrs["_key"] = ctx.next_op_key()
+        if reading and op.type == "mul" and op.outputs["Out"][0] in reading:
+            attrs["_reads_saved"] = True
         pin_names = ()
         if act_specs:
             pin_names = tuple(
@@ -1304,6 +1309,24 @@ class Executor:
                                         (ops_j, wrap_, out_j,
                                          tuple(sorted(uses_j)), nr, nr_j))
                                     nr += nr_j
+                                # a product whose left operand a wrapped
+                                # sub-segment of the same iteration made
+                                # READS it: the compiler is otherwise
+                                # free to evaluate a cheap producer again
+                                # on the product's operand side
+                                # (docs/memory.md)
+                                made_wrapped = set()
+                                products_reading = set()
+                                for ops_j, wrap_, out_j, *_ in plan_subs:
+                                    if wrap_:
+                                        made_wrapped.update(out_j)
+                                        continue
+                                    products_reading.update(
+                                        op_.outputs["Out"][0]
+                                        for op_ in ops_j
+                                        if op_.type == "mul"
+                                        and op_.inputs["X"][0]
+                                        in made_wrapped)
 
                                 shared_env = {n: e[n] for n in shared_names}
                                 xs_stacked = {
@@ -1397,7 +1420,8 @@ class Executor:
                                             fctx._op_counter = cj
                                             run_block_ops(
                                                 fctx, block, ops_j, e2,
-                                                inside_grad_prefix=True)
+                                                inside_grad_prefix=True,
+                                                reading=products_reading)
                                             continue
                                         tags = (
                                             frozenset(carry_map)
@@ -1456,6 +1480,12 @@ class Executor:
                                     "executor.scan_remat_groups",
                                     help="remat segment groups executed as "
                                          "lax.scan over layers").inc()
+                                reg.counter(
+                                    "executor.products_reading_saved",
+                                    help="products of a scan-remat body "
+                                         "lowered to READ the output of a "
+                                         "checkpointed sub-segment").inc(
+                                    len(products_reading))
                                 if fsdp_gather:
                                     reg.counter(
                                         "executor.fsdp_groups",
@@ -1468,7 +1498,8 @@ class Executor:
                                      "xs": len(xs_names),
                                      "shared": len(shared_names),
                                      "fsdp": len(fsdp_gather),
-                                     "offload": off_mode})
+                                     "offload": off_mode,
+                                     "reading": sorted(products_reading)})
                                 return True
                             except Exception as exc:
                                 # classification/trace failure: restore the

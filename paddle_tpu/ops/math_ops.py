@@ -58,17 +58,58 @@ _register_elementwise("min", jnp.minimum)
 _register_elementwise("pow", jnp.power)
 
 
-@register_op("mul")
-def mul(X, Y, x_num_col_dims=1, y_num_col_dims=1, **_):
+def _mul(X, Y, x_num_col_dims, y_num_col_dims, whole=False):
     """Flattening matmul (reference mul_op.cc): X collapses to 2-D at
-    x_num_col_dims, Y at y_num_col_dims; result regains X's leading dims."""
-    x2 = X.reshape((int(np.prod(X.shape[:x_num_col_dims])), -1))
-    y2 = Y.reshape((int(np.prod(Y.shape[:y_num_col_dims])), -1))
-    out = jnp.dot(x2, y2, preferred_element_type=_acc_type(X))
-    if out.dtype != X.dtype:
-        out = out.astype(X.dtype)
-    out_shape = X.shape[:x_num_col_dims] + Y.shape[y_num_col_dims:]
-    return {"Out": out.reshape(out_shape)}
+    x_num_col_dims, Y at y_num_col_dims; result regains X's leading dims.
+    ``whole`` takes the product over X as it stands where the flattening
+    would only fold X's leading dims into rows: the same contraction
+    with no reshape on either side of it."""
+    if not (whole and x_num_col_dims == X.ndim - 1
+            and (Y.ndim, y_num_col_dims) == (2, 1)):
+        X = X.reshape((int(np.prod(X.shape[:x_num_col_dims])), -1))
+        Y = Y.reshape((int(np.prod(Y.shape[:y_num_col_dims])), -1))
+    out = jnp.dot(X, Y, preferred_element_type=_acc_type(X))
+    return out if out.dtype == X.dtype else out.astype(X.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _mul_reading(X, Y, x_num_col_dims, y_num_col_dims):
+    """``_mul`` whose left operand is READ.  The barrier makes the
+    compiler materialise X once, where it is otherwise free to evaluate
+    a cheap elementwise producer again inside the product's operand
+    fusion (0.31 ms a [4096, 6144] GELU whatever it holds: PERF.md
+    section 6, PR 54).  The product is ``whole``, forward and backward:
+    a reshape between a producer and the product un-fuses the producer,
+    and the writes into the scan's saved stacks with it, from the
+    epilogue of the product before.  The barrier is on the forward's
+    operand alone and the residuals are the operands as they came, so
+    what a scan saves and every gradient keep their values."""
+    return _mul(jax.lax.optimization_barrier(X), Y, x_num_col_dims,
+                y_num_col_dims, whole=True)
+
+
+def _mul_reading_fwd(X, Y, x_num_col_dims, y_num_col_dims):
+    return _mul_reading(X, Y, x_num_col_dims, y_num_col_dims), (X, Y)
+
+
+def _mul_reading_bwd(x_num_col_dims, y_num_col_dims, operands, g):
+    return jax.vjp(
+        lambda X, Y: _mul(X, Y, x_num_col_dims, y_num_col_dims, whole=True),
+        *operands)[1](g)
+
+
+_mul_reading.defvjp(_mul_reading_fwd, _mul_reading_bwd)
+
+
+@register_op("mul")
+def mul(X, Y, x_num_col_dims=1, y_num_col_dims=1, _reads_saved=False, **_):
+    """``_mul``.  ``_reads_saved`` is the scan-remat engine's
+    (``core/executor.py``): X is the output of a checkpointed
+    sub-segment of the scanned body, and the product reads it."""
+    product = _mul_reading if _reads_saved else _mul
+    out = product(X, Y, x_num_col_dims, y_num_col_dims)
+    return {"Out": out.reshape(
+        X.shape[:x_num_col_dims] + Y.shape[y_num_col_dims:])}
 
 
 @register_op("matmul")

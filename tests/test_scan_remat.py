@@ -308,3 +308,66 @@ def test_scan_remat_composes_with_run_steps():
         pt.core.scope._scope_stack.pop()
     np.testing.assert_array_equal(np.asarray(fetched).ravel(),
                                   np.asarray(seq).ravel())
+
+
+# -- the reading product (ISSUE 54) -----------------------------------------
+
+def _product_and_grads(reading, dtype, scanned):
+    """Value and both gradients of two products in a row through ``mul``,
+    reading or plain, under ``lax.scan`` or unrolled, on rows ``[2, 6, .]``
+    (so the reading form is the one taken over the rows as they stand)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.math_ops import mul
+
+    rng = np.random.default_rng(54)
+    x = jnp.asarray(rng.normal(size=(2, 6, 16)), dtype)
+    ws = jnp.asarray(rng.normal(size=(2, 16, 16)) * 0.3, dtype)
+
+    def product(x, w):
+        return mul(x, w, x_num_col_dims=2, _reads_saved=reading)["Out"]
+
+    def net(x, ws):
+        if scanned:
+            return jax.lax.scan(lambda c, w: (product(c, w), None), x, ws)[0]
+        for k in range(ws.shape[0]):
+            x = product(x, ws[k])
+        return x
+
+    def loss(x, ws):
+        return (net(x, ws).astype(jnp.float32) ** 2).sum()
+
+    out = jax.jit(net)(x, ws)
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1)))(x, ws)
+    return [np.asarray(v.astype(jnp.float32)) for v in (out,) + grads]
+
+
+@pytest.mark.parametrize("scanned", [True, False],
+                         ids=["scanned", "unrolled"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_reading_product_bit_equal_to_mul(dtype, scanned):
+    """The barrier is the identity on values and the backward is the
+    transpose of the same product on the operands as they came."""
+    plain = _product_and_grads(False, dtype, scanned)
+    reading = _product_and_grads(True, dtype, scanned)
+    for name, p, r in zip(("out", "dx", "dw"), plain, reading):
+        np.testing.assert_array_equal(p, r, err_msg=name)
+
+
+@pytest.mark.parametrize("policy,want", [("selective", 2), (None, 0)],
+                         ids=["selective", "none"])
+def test_products_reading_saved_counter(policy, want):
+    """A scanned GPT body under `selective` holds two products whose
+    ``X`` a checkpointed sub-segment made: ``ffn1`` after LayerNorm 2
+    and ``ffn2`` after the bias add and the GELU (q, k, v read the
+    CARRY, which the previous iteration made).  Both layers share the
+    one body; with no remat segments no product reads."""
+    from paddle_tpu.observability import get_registry
+
+    counter = get_registry().counter("executor.products_reading_saved")
+    before = counter.value
+    _losses, _grads, exe = _step_grads(*_build(policy))
+    assert counter.value - before == want
+    if policy:
+        assert exe.last_remat_plan[0]["count"] == 2
