@@ -97,12 +97,16 @@ second calling convention of the same functions::
                             a position's value is its row's first
                             ``value_lanes`` lanes
 
-``pool_rows``, ``group`` and ``window`` do not apply (a group or a lower
-bound is refused).  ``attend`` chooses as above: dense from
-``DENSE_WINDOW`` rows up, else the ``xla_ref`` scan or, on a TPU, the
+``pool_rows`` and ``group`` do not apply (a group is refused); a
+``window`` is the lower bound it is on a K/V plane.  ``attend`` chooses
+as above: dense from ``DENSE_WINDOW`` rows up (under a lower bound: of
+the ``window_entries`` the window can see and no more,
+``_dense_latent_window``), else the ``xla_ref`` scan or, on a TPU, the
 sibling Mosaic kernel ``latent_attention_pallas`` (HLO name
 ``paged_latent_attention``): the loop below with the head mask gone and
-``LATENT_BLOCKS`` table entries an iteration.
+``LATENT_BLOCKS`` table entries an iteration, from the group of the
+window's first live entry.  A plane whose rows are SELECTED by an
+indexer is ``kernels/sparse_attention.py``'s.
 
 Numerics conventions match the flash kernels (f32 scores via
 ``preferred_element_type``, ``NEG_INF`` masking, f32 ``(m, l, acc)``
@@ -286,6 +290,8 @@ def dense_window(q, pool_k, pool_v, table, pos, block_step=None,
     ``chain_attention`` op class (``kernels/chain_attention.py``), whose
     signature it keeps: ``block_step`` and ``interpret`` are ignored."""
     del block_step, interpret
+    if pool_v is None and how.get("window") is not None:
+        return _dense_latent_window(q, pool_k, table, pos, **how)
     if pool_v is not None and _score_bytes(
             q.shape[1], _folded_rows(q, pool_k, how.get("group", 1)),
             table.shape[1] * pool_k.shape[1]) > DENSE_SCORE_BYTES:
@@ -411,11 +417,12 @@ def loop_iterations(entries, rows, block_shapes, dtype, NB, window=None):
     (an entry an iteration, a step); a latent plane's groups of
     ``LATENT_BLOCKS``.  ``block_shapes`` as the architecture states a
     plane's (``plane_block_shapes``: ``(K, V)`` block shapes ``[B, h,
-    lanes]``, or ``(rows,)`` for a latent plane), ``NB`` the table's
+    lanes]``, or block shapes ``[B, lanes]`` with no head axis for a
+    latent plane), ``NB`` the table's
     entries a slot, ``window`` the plane's lower bound.  Stated here for
     whoever counts the kernel's iterations
     (``serving.paged_iterations_live``): the engine does not guess."""
-    if len(block_shapes) == 1:
+    if len(block_shapes[0]) == 2:
         G = min(LATENT_BLOCKS, NB)
     else:
         (B, h, dh), (_, _, dv) = block_shapes
@@ -574,6 +581,38 @@ def _dense_by_head(q, pool_k, pool_v, table, pos, group=1, window=None,
     return jnp.moveaxis(ctx, 0, 2).reshape(S, W, h, dv).astype(out_dtype)
 
 
+def _dense_latent_window(q, pool, table, pos, window, value_lanes, scale=None,
+                         out_dtype=None, group=1):
+    """The dense spelling of a wide window over a LATENT plane under a
+    lower bound: a slot gathers the ``window_entries`` table entries that
+    hold a key some row of the window can see (``_dense_by_head``'s
+    slice: from the entry of ``pos[s, 0] - window + 1``; the entries
+    under it may name blocks given back long ago) and no more, and every
+    head reads the gathered rows whole: scores ``[S, W, h, entries x
+    B]``, never the chain's."""
+    dv = _latent_plane(q, pool, group, value_lanes)
+    S, W, h, L = q.shape
+    B, NB = pool.shape[1], table.shape[1]
+    out_dtype = q.dtype if out_dtype is None else out_dtype
+    if scale is None:
+        scale = 1.0 / float(L) ** 0.5
+    n = window_entries(NB, B, W, window)
+    first = jnp.clip(jnp.maximum(pos[:, 0] - window + 1, 0) // B, 0, NB - n)
+    tbl = jax.vmap(lambda row, f: jax.lax.dynamic_slice_in_dim(row, f, n))(
+        table.astype(jnp.int32), first)
+    rows = pool[tbl].reshape(S, n * B, L)
+    tok = first[:, None] * B + jnp.arange(n * B, dtype=jnp.int32)[None]
+    keep = ((tok[:, None, :] <= pos[:, :, None])
+            & (tok[:, None, :] > pos[:, :, None] - window))[:, :, None, :]
+    s = jnp.einsum("swhd,std->swht", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(keep, s, NEG_INF)
+    p = jnp.where(keep, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    l = jnp.sum(p, axis=-1)
+    ctx = jnp.einsum("swht,std->swhd", p, rows[..., :dv].astype(jnp.float32))
+    return (ctx / jnp.where(l == 0.0, 1.0, l)[..., None]).astype(out_dtype)
+
+
 def _normalize_block_step(block_step, nb, w=1):
     if block_step is None:
         # measured default: single-token decode (W=1) is fastest
@@ -587,16 +626,16 @@ def _normalize_block_step(block_step, nb, w=1):
 
 # -- xla_ref: the block-scan oracle ------------------------------------------
 
-def _latent_plane(q, pool, group, window, value_lanes):
+def _latent_plane(q, pool, group, value_lanes):
     """The checks of a latent call (``pool_v is None``); returns the
     value lanes."""
     if pool.ndim != 3 or q.shape[-1] != pool.shape[-1]:
         raise ValueError(
             f"paged_attention: a latent plane is [blocks, B, L] and its "
             f"queries [S, W, h, L]; got {pool.shape} and {q.shape}")
-    if group != 1 or window is not None:
+    if group != 1:
         raise ValueError("paged_attention: a latent plane has one row all "
-                         "the heads read whole: no group, no window")
+                         "the heads read whole: no group")
     if not value_lanes or not 0 < value_lanes <= pool.shape[-1]:
         raise ValueError(f"paged_attention: value_lanes {value_lanes} of a "
                          f"latent row of {pool.shape[-1]} lanes")
@@ -623,7 +662,7 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
             raise ValueError("paged_attention: a latent plane has no sink")
         sink = _fold_sink(sink, group, pool_k.shape[2], q.shape[1])[None]
     if latent:
-        dv = _latent_plane(q, pool_k, group, window, value_lanes)
+        dv = _latent_plane(q, pool_k, group, value_lanes)
         unfold = lambda ctx: ctx
         qk, pv = "swhd,std->swht", "swht,std->swhd"
 
@@ -860,8 +899,9 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     if pool_v is None:
         return latent_attention_pallas(
             q, pool_k, table, pos,
-            _latent_plane(q, pool_k, group, window, value_lanes),
-            scale=scale, out_dtype=out_dtype, interpret=interpret)
+            _latent_plane(q, pool_k, group, value_lanes),
+            scale=scale, out_dtype=out_dtype, interpret=interpret,
+            window=window)
     if sink is not None:
         sink = _fold_sink(sink, group, pool_k.shape[2], q.shape[1])
     width = q.shape[1]              # positions, before a group is folded in
@@ -1210,7 +1250,7 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
 
 def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
                             out_dtype=None, interpret=None,
-                            blocks=LATENT_BLOCKS):
+                            blocks=LATENT_BLOCKS, window=None):
     """The Mosaic kernel of a LATENT plane, a sibling of the loop above
     under its own name (``paged_latent_attention``): ``pool [blocks, B,
     L]`` holds ONE row a cached position, every one of the ``h`` query
@@ -1220,8 +1260,9 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
     value_lanes]`` comes back.
 
     Grid ``(S,)``, one step a slot, a loop over the slot's LIVE entries
-    (``n_s`` as above; a latent plane has no lower bound) taken
-    ``blocks`` at a time: one iteration copies ``blocks`` table entries
+    (``n_s`` as above; with a ``window`` from the group that holds the
+    first entry some row's lower bound lets through, the keys under a
+    row's bound masked) taken ``blocks`` at a time: one iteration copies ``blocks`` table entries
     side by side into one ``[blocks * B, L]`` buffer (``DEPTH`` such
     buffers, ``DEPTH - 1`` groups on their way), scores all ``N = W *
     h`` rows against it in ONE MXU pass ``[N, L] x [blocks * B, L]^T``,
@@ -1259,6 +1300,14 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
             top = jnp.maximum(top, pos_ref[s_id, w])
         live = jnp.minimum(jax.lax.div(jnp.maximum(top, -1) + B, B), NB)
         groups = jax.lax.div(live + G - 1, G)
+        # the first group a row's lower bound lets through; the Python
+        # constant 0 without a window
+        g0 = 0
+        if window is not None:
+            low = pos_ref[s_id, 0]
+            for w in range(1, W):
+                low = jnp.minimum(low, pos_ref[s_id, w])
+            g0 = jax.lax.div(jnp.maximum(low - window + 1, 0), G * B)
 
         def copies(g):
             slot = jax.lax.rem(g, DEPTH)
@@ -1277,7 +1326,10 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
             for w in range(1, W):
                 at = jnp.where(row >= w * h, pos_ref[s_id, w], at)
             tok = g * T + jax.lax.broadcasted_iota(jnp.int32, (N, T), 1)
-            s = jnp.where(tok <= at, s, NEG_INF)
+            keep = tok <= at
+            if window is not None:
+                keep &= tok > at - window
+            s = jnp.where(keep, s, NEG_INF)
             s_ref[...] = s
             peak_ref[...] = jnp.broadcast_to(
                 jnp.max(s, axis=-1, keepdims=True), (N, LSE_LANES))
@@ -1286,16 +1338,16 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         for ahead in range(DEPTH - 1):
-            @pl.when(ahead < groups)
+            @pl.when(g0 + ahead < groups)
             def _start(ahead=ahead):
-                for c in copies(ahead):
+                for c in copies(g0 + ahead):
                     c.start()
 
-        @pl.when(groups > 0)
+        @pl.when(groups > g0)
         def _first():
-            for c in copies(0):
+            for c in copies(g0):
                 c.wait()
-            score(0)
+            score(g0)
 
         def group(g, _):
             @pl.when(g + DEPTH - 1 < groups)
@@ -1325,7 +1377,7 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
             m_ref[...] = jnp.broadcast_to(m2, (N, LSE_LANES))
             l_ref[...] = jnp.broadcast_to(l2, (N, LSE_LANES))
 
-        jax.lax.fori_loop(0, groups, group, None)
+        jax.lax.fori_loop(g0, groups, group, None)
         l = l_ref[...][:, :1]
         o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
             o_ref.dtype)

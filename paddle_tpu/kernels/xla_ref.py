@@ -85,6 +85,15 @@ ORACLE_TOL = {
     # so the bound is float32's
     ("ssm", "float32"): {"fwd": 2e-4, "grad": None},
     ("ssm", "bfloat16"): {"fwd": 2e-4, "grad": None},
+    # a learned indexer's scores (no softmax: float32 sums of relu'd
+    # products over the index heads) and the attention of the rows it
+    # selects (one softmax over the gathered rows): inference-only, one
+    # backend so far; a second one reassociates the head sum and the
+    # softmax as paged_latent_attention's does, whose bounds these are
+    ("index_scores", "float32"): {"fwd": 2e-4, "grad": None},
+    ("index_scores", "bfloat16"): {"fwd": 2e-2, "grad": None},
+    ("sparse_latent_attention", "float32"): {"fwd": 2e-4, "grad": None},
+    ("sparse_latent_attention", "bfloat16"): {"fwd": 2e-2, "grad": None},
 }
 
 

@@ -36,7 +36,16 @@ scalar inside a loop over passes.  ``how`` is what
 nothing is written: a READ-ONLY attend of a plane another layer wrote.
 With ``v`` alone ``None`` the plane is a latent one (``pool_arrays``
 1): ``k [..., values]`` is the one row written, and ``how`` carries
-``value_lanes``.
+``value_lanes`` (and a ``window`` where the plane has a lower bound).
+A latent plane whose rows an INDEXER selects is attended through::
+
+    ctx, planes = attend.sparse(planes, plane, q, row, q_idx, w_idx, k_idx,
+                                topk=, value_lanes=, scale=)
+
+which writes the row and the index key ``k_idx`` (the plane's two
+arrays, one block id), scores every cached position's key with ``q_idx
+[..., H_I, d_I]`` and ``w_idx [..., H_I]``, and attends the ``topk``
+rows of largest score.
 
 It has a STATE side for per-slot recurrent state, ``planes``' third
 member, one tuple of arrays per state layer::
@@ -58,7 +67,7 @@ adds to what the compiled decode chunk and prefill piece return as their
 last output (``arch.count_names`` names the entries; an architecture
 with none never calls it and its programs have no such output).
 
-Eight architectures are here: ``Gpt2`` (the block of
+Nine architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -104,7 +113,14 @@ OR an FFN by a pattern string: Mamba-2 mixers whose state MATRIX a head
 is advanced in place (``kernels/ssm.py``), routed FFNs of un-gated
 ``relu ** 2`` experts (``routed_ffn``'s ``form``), and position-free
 attention over two K/V heads; ``models/ssm_moe_reference.py`` is its
-plain reference.
+plain reference.  ``SparseLatentMoE`` shares ``LatentMoE``'s base
+(``_Latent``) and is the first whose latent planes have TWO shapes by
+layer type, one of them with a SECOND array that is no V (the index
+keys of a learned indexer, ``second_array``): a full layer's query
+attends the ``index_topk`` cached positions its indexer scores highest
+(``attend.sparse``, ``kernels/sparse_attention.py``), a sliding layer's
+a window of latent rows (``attend(.., pool_v=None, window=)``);
+``models/sparse_latent_moe_reference.py`` is its plain reference.
 
 An architecture whose state is large asks for it IN PLACE::
 
@@ -135,6 +151,7 @@ from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
            "LatentMoE", "PowerRetention", "SinkWindowMoE", "MambaMoE",
+           "SparseLatentMoE",
            "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
            "MOE_COUNTS", "EXPERT_FORMS", "STACK_SCOPE"]
 
@@ -161,6 +178,9 @@ class Architecture:
     attn_form = None
     # layers whose mixer is power retention (a state, no plane)
     retention_layers = 0
+    # latent planes that hold an index key beside the row and are attended
+    # at the positions an indexer selects (``SparseLatentMoE``)
+    index_planes = 0
     # planes whose attention has a learned sink logit a query head
     sink_planes = 0
     # layers whose mixer is a state-space recurrence advanced in place
@@ -271,6 +291,12 @@ class Architecture:
         """Values of one position a write puts into EACH of the plane's
         pool arrays."""
         return (self.written_values,) * self.pool_arrays
+
+    def second_array(self, plane):
+        """Where plane ``plane``'s SECOND pool array lies among the
+        engine's second arrays (one for each plane that has two, in the
+        planes' order), or ``None`` for a plane of one array."""
+        return plane if self.pool_arrays == 2 else None
 
     def plane_block_bytes(self, plane, block_tokens, itemsize):
         return self.kv_block_bytes(block_tokens, itemsize)
@@ -387,12 +413,16 @@ class Gpt2(Architecture):
                               preferred_element_type=jnp.float32)
 
 
-def _rms(x, scale, eps):
+def _rms(x, scale, eps, gain=None):
     # x / sqrt(mean(x^2) + eps) * scale, statistics in f32 (the published
-    # RMSNorm upcasts, normalizes, casts back, then scales)
+    # RMSNorm upcasts, normalizes, casts back, then scales); ``gain`` (a
+    # Python float) multiplies the normalized row while it is float32
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(ms + eps)).astype(x.dtype) * scale
+    xn = x32 * jax.lax.rsqrt(ms + eps)
+    if gain is not None:
+        xn = xn * gain
+    return xn.astype(x.dtype) * scale
 
 
 def _rope_angles(pos, head_dim, theta):
@@ -1219,7 +1249,83 @@ def yarn_inv_freq(lanes, theta, factor, original, beta_fast, beta_slow):
     return (extra / factor * (1.0 - keep) + extra * keep).astype(np.float32)
 
 
-class LatentMoE(_Routed, Architecture):
+class _Latent(_Routed, Architecture):
+    """What the architectures that cache ONE latent row a position
+    share (``LatentMoE``, ``SparseLatentMoE``): planes of one array with
+    no head axis that every head reads whole, queries ABSORBED into the
+    cached row's width, a pre-normed stack whose FFN is dense in the
+    first ``dense_layers`` layers and routed after (``routed_ffn`` as
+    ``route_how`` states it), table not scaled, head untied.  A subclass
+    gives ``_angles(pos)`` (what its ``_attention`` takes as ``rope``)
+    and ``_attention(w, i, x, rope, planes, attend) -> (a, planes)``."""
+
+    pool_arrays = 1
+    attn_form = "absorbed"
+    # what ``routed_ffn`` takes beyond the share: the architecture's own
+    route_how = {}
+
+    @property
+    def kv_heads(self):
+        # ONE cached row, which every head reads
+        return 1
+
+    @property
+    def latent_planes(self):
+        return self.n_layer
+
+    @property
+    def latent_reads(self):
+        """``((planes, bound), ...)``: the latent planes by how many
+        cached positions a row reads of them at most (``None``: every
+        one up to its own), for whoever counts positions read."""
+        return ((self.latent_planes, None),)
+
+    @staticmethod
+    def _query_rows(q, kvb, rope, nope, spare):
+        """The heads' queries as rows of the cached row's width: ``W_UK``
+        absorbed into ``q``'s position-free lanes (``kvb [rank, h, nope |
+        v]``), its rotary lanes rotated, ``spare`` zero lanes up to what
+        the pool stores."""
+        q_lat = jnp.einsum("...hn,rhn->...hr", q[..., :nope],
+                           kvb[..., :nope])
+        return jnp.concatenate(
+            [q_lat, _rope(q[..., nope:], *rope)]
+            + ([jnp.zeros((*q.shape[:-1], spare), q.dtype)]
+               if spare else []), axis=-1)
+
+    def embed(self, p, toks, pos):
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks]
+
+    def stack(self, p, x, pos, planes, attend):
+        with sublayer("attn.proj"):
+            rope = self._angles(pos)
+        for i in range(self.n_layer):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            a, planes = self._attention(w, i, x, rope, planes, attend)
+            x = x + a
+            with sublayer("norm"):
+                m = _rms(x, w("norm2.scale"), self.eps)
+            if i < self.dense_layers:
+                with sublayer("ffn"):
+                    ff = _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
+                                     w("ffn_down.w"))
+            else:
+                ff, counts = routed_ffn(
+                    w, m, attend, self.experts, self.top_k,
+                    self.route_scale, **self.route_how)
+                attend.tally(counts)
+            x = x + ff
+        return x, planes
+
+    def head(self, p, x):
+        with sublayer("head"):
+            return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
+                              p["lm_head.w"],
+                              preferred_element_type=jnp.float32)
+
+
+class LatentMoE(_Latent):
     """Pre-normed layers of LATENT attention and a dense or ROUTED
     gated-SiLU FFN (the ``deepseek_v2`` layout, arXiv:2405.04434;
     ``models/latent_moe_reference.py`` writes the equations down in
@@ -1270,8 +1376,7 @@ class LatentMoE(_Routed, Architecture):
     """
 
     name = "latent_moe"
-    pool_arrays = 1
-    attn_form = "absorbed"
+    route_how = dict(score="softmax", normalise=False, bias=False)
 
     def __init__(self, n_layer, n_head, d_model, rank, nope_dim, rope_dim,
                  v_dim, dense_layers, router_width, top_k, experts,
@@ -1304,17 +1409,8 @@ class LatentMoE(_Routed, Architecture):
         self.lanes = _paged.latent_lanes(self.written_values)
 
     @property
-    def kv_heads(self):
-        # ONE cached row, which every head reads
-        return 1
-
-    @property
     def rows_per_entry(self):
         return self.n_head
-
-    @property
-    def latent_planes(self):
-        return self.n_layer
 
     @property
     def written_values(self):
@@ -1360,10 +1456,6 @@ class LatentMoE(_Routed, Architecture):
         if self.moe_layers:
             self._check_experts(params)
 
-    def embed(self, p, toks, pos):
-        with sublayer("embed"):
-            return p["tok_emb.w"][toks]
-
     def _angles(self, pos):
         ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(self.inv_freq)
         ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]
@@ -1381,13 +1473,8 @@ class LatentMoE(_Routed, Architecture):
             k_pe = _rope(kva[..., None, rank:], *rope)[..., 0, :]
             kvb = w("att_kvb.w").reshape(rank, self.n_head, -1)
             # absorb W_UK into the query: a row of the cached row's width
-            q_lat = jnp.einsum("...hn,rhn->...hr", q[..., :nope],
-                               kvb[..., :nope])
-            spare = self.lanes - self.written_values
-            q_row = jnp.concatenate(
-                [q_lat, _rope(q[..., nope:], *rope)]
-                + ([jnp.zeros((*lead, self.n_head, spare), q.dtype)]
-                   if spare else []), axis=-1)
+            q_row = self._query_rows(q, kvb, rope, nope,
+                                     self.lanes - self.written_values)
             row = jnp.concatenate([c, k_pe], axis=-1)
         with sublayer("attn.core"):
             u, planes = attend(planes, i, 0, q_row, row, None,
@@ -1396,33 +1483,267 @@ class LatentMoE(_Routed, Architecture):
             o = jnp.einsum("...hr,rhv->...hv", u, kvb[..., nope:])
             return o.reshape(*lead, -1) @ w("att_out.w"), planes
 
-    def stack(self, p, x, pos, planes, attend):
-        with sublayer("attn.proj"):
-            rope = self._angles(pos)
-        for i in range(self.n_layer):
-            w = lambda nm: p[f"block{i}_{nm}"]
-            a, planes = self._attention(w, i, x, rope, planes, attend)
-            x = x + a
-            with sublayer("norm"):
-                m = _rms(x, w("norm2.scale"), self.eps)
-            if i < self.dense_layers:
-                with sublayer("ffn"):
-                    ff = _gated_silu(m, w("ffn_gate.w"), w("ffn_up.w"),
-                                     w("ffn_down.w"))
-            else:
-                ff, counts = routed_ffn(
-                    w, m, attend, self.experts, self.top_k,
-                    self.route_scale, score="softmax", normalise=False,
-                    bias=False)
-                attend.tally(counts)
-            x = x + ff
-        return x, planes
 
-    def head(self, p, x):
-        with sublayer("head"):
-            return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
-                              p["lm_head.w"],
-                              preferred_element_type=jnp.float32)
+class SparseLatentMoE(_Latent):
+    """Pre-normed layers of latent attention in TWO geometries by layer
+    type, one of them SPARSE by a learned indexer, and a dense or routed
+    gated-SiLU FFN beside a shared expert (the ``dots3_note`` layout's
+    language model, whose attention keys are DeepSeek-V3.2's plus one
+    ``swa_*`` copy; ``models/sparse_latent_moe_reference.py`` writes the
+    equations down per head and lists what the published configuration
+    has no key for).
+
+    ``layer_types[i]`` is ``"full"`` or ``"sliding"``; ``full`` and
+    ``sliding`` are each a geometry ``{heads, q_rank, rank, nope, rope,
+    v, theta}``.  Every layer projects its normed input to a QUERY
+    LATENT ``c_q = r_q RMS_q(a W_qa)`` (``q_rank`` values) and a K/V
+    latent ``c = r_kv RMS_kv(..)`` beside one rotary key, with ``r = (d /
+    rank) ** 0.5`` (the lora rescale), reads the cached rows through
+    absorbed queries (``_Latent``), and gates each head's context by
+    ``sigmoid(a W_g)`` before ``W_o``.
+
+    **What is cached, by plane** (``plane_block_shapes``).  A SLIDING
+    plane holds one array of ``rank + rope`` values a position (1,024 +
+    64 in ``latent_lanes`` = 1,152 stored), attended under the lower
+    bound ``window`` (a query sees itself and the ``window - 1`` rows
+    before it: ``attend(.., pool_v=None, window=)``).  A FULL plane
+    holds TWO arrays under one block id: the latent row (512 + 64 in
+    640) and the INDEX KEY ``k_I = rope(LayerNorm(a W_Ik))``
+    (``index_dim`` values).  Its attention is ``attend.sparse``
+    (``kernels/sparse_attention.py``): ``I(t, j) = sum_h w_h relu(q_Ih .
+    k_I(j))`` over every cached position with ``q_I = c_q W_Iqb``
+    (``index_heads`` heads) and ``w = float32(a W_Iw) index_heads^-1/2
+    index_dim^-1/2``, the ``index_topk`` positions of largest ``I``, and
+    ONE softmax over those rows for all the heads.  While a table holds
+    no more than ``index_topk`` positions the call is the dense one.
+    ``window_chains``: an engine without a prefix trie keeps, of a
+    sliding plane, the blocks its window can still see.
+
+    The first ``dense_layers`` layers have a dense FFN, the others the
+    routed one (``routed_ffn``: sigmoid scores, a bias that selects
+    only, the selected weights normalised where ``norm_topk``, times
+    ``route_scale``) beside ONE shared expert of the experts' width.
+
+    Parameter names: ``tok_emb.w [V, d]``, ``norm_f.scale``, ``lm_head.w
+    [d, V]``; per layer ``block{i}_norm1.scale``, ``att_qa.w [d,
+    q_rank]``, ``att_qnorm.scale``, ``att_qb.w [q_rank, h * (nope +
+    rope)]``, ``att_kva.w [d, rank + rope]``, ``att_kvnorm.scale``,
+    ``att_kvb.w [rank, h * (nope + v)]``, ``att_gate.w [d, h]``,
+    ``att_out.w [h * v, d]``, ``norm2.scale``; a full layer ``idx_qb.w
+    [q_rank, index_heads * index_dim]``, ``idx_k.w [d, index_dim]``,
+    ``idx_knorm.scale``, ``idx_knorm.bias``, ``idx_w.w [d,
+    index_heads]``; the FFN's as ``LatentMoE``'s, with ``router.bias``.
+    """
+
+    name = "sparse_latent_moe"
+    window_chains = True
+    INDEX_EPS = 1e-6
+
+    def __init__(self, layer_types, d_model, full, sliding, window,
+                 index_heads, index_dim, index_topk, dense_layers,
+                 router_width, top_k, experts, route_scale=1.0,
+                 norm_topk=True, eps=1e-5):
+        bad = sorted(set(layer_types) - {"full", "sliding"})
+        if bad or not layer_types:
+            raise ValueError(f"{self.name}: layer types {bad}; a layer is "
+                             f"'full' or 'sliding'")
+        self.layer_types = tuple(layer_types)
+        self._geo = {"full": dict(full), "sliding": dict(sliding)}
+        first = self._geo[self.layer_types[0]]
+        super().__init__(len(layer_types), first["heads"], d_model,
+                         head_dim=first["nope"] + first["rope"])
+        for kind, g in self._geo.items():
+            if g["rope"] % 2:
+                raise ValueError(f"{self.name}: rotary positions need an "
+                                 f"even rope width, {kind} has {g['rope']}")
+            g["lanes"] = _paged.latent_lanes(g["rank"] + g["rope"])
+            g["scale"] = float(g["nope"] + g["rope"]) ** -0.5
+            g["r_q"] = (d_model / g["q_rank"]) ** 0.5
+            g["r_kv"] = (d_model / g["rank"]) ** 0.5
+        if not 0 <= dense_layers <= self.n_layer:
+            raise ValueError(f"{self.name}: dense_layers {dense_layers} "
+                             f"of {self.n_layer} layers")
+        if index_topk < 1 or window < 1:
+            raise ValueError(f"{self.name}: index_topk {index_topk} and "
+                             f"window {window} must be >= 1")
+        if self._geo["full"]["rope"] > index_dim:
+            raise ValueError(f"{self.name}: the indexer rotates the full "
+                             f"layers' {self._geo['full']['rope']} lanes "
+                             f"of {index_dim}")
+        self.window, self.index_topk = int(window), int(index_topk)
+        self.index_heads, self.index_dim = int(index_heads), int(index_dim)
+        self.dense_layers = int(dense_layers)
+        self.router_width, self.top_k = int(router_width), int(top_k)
+        self.experts = _check_share(self.name, experts, router_width, top_k)
+        self.route_scale, self.eps = float(route_scale), eps
+        self.route_how = dict(score="sigmoid", normalise=bool(norm_topk),
+                              bias=True, shared=True)
+
+    def _of(self, plane):
+        return self._geo[self.layer_types[plane]]
+
+    @property
+    def planes(self):
+        return tuple(self.window if kind == "sliding" else None
+                     for kind in self.layer_types)
+
+    @property
+    def index_planes(self):
+        """Planes that hold an index key beside the latent row."""
+        return self.layer_types.count("full")
+
+    @property
+    def latent_reads(self):
+        return ((self.index_planes, self.index_topk),
+                (self.n_layer - self.index_planes, self.window))
+
+    def plane_rows_per_entry(self, plane):
+        return self._of(plane)["heads"]
+
+    def plane_block_shapes(self, plane, block_tokens, dtype):
+        row = (block_tokens, self._of(plane)["lanes"])
+        if self.layer_types[plane] == "sliding":
+            return (row,)
+        return (row, (block_tokens, self.index_dim))
+
+    def plane_written_values(self, plane):
+        g = self._of(plane)
+        if self.layer_types[plane] == "sliding":
+            return (g["rank"] + g["rope"],)
+        return (g["rank"] + g["rope"], self.index_dim)
+
+    def plane_block_bytes(self, plane, block_tokens, itemsize):
+        # what the model caches of a position: the published values
+        return block_tokens * sum(self.plane_written_values(plane)) * itemsize
+
+    def second_array(self, plane):
+        if self.layer_types[plane] == "sliding":
+            return None
+        return self.layer_types[:plane].count("full")
+
+    def gauges(self, params):
+        out = dict(_moe_gauges(self, params), **{
+            "latent_planes": (self.latent_planes, "planes that cache ONE "
+                              "latent row a position (no head axis, no V "
+                              "array)"),
+            "index_planes": (self.index_planes, "of them, planes that hold "
+                             "an index key beside the row and are attended "
+                             "at the positions their indexer selects"),
+            "latent_window_planes": (
+                self.n_layer - self.index_planes, "of them, planes "
+                "attended under the lower bound latent_window"),
+            "latent_window": (self.window, "positions a sliding plane's "
+                              "query attends (itself among them)"),
+            "index_topk": (self.index_topk, "cached positions a full "
+                           "plane's query attends at most"),
+            "index_lanes_stored": (self.index_dim, "lanes of an index key"),
+            "kv_stored_bytes_per_token": (
+                sum(int(np.prod(shape[1:])) for i in range(self.n_layer)
+                    for shape in self.plane_block_shapes(i, 1, None))
+                * params["tok_emb.w"].dtype.itemsize,
+                "bytes the pool arrays STORE of one cached token across "
+                "its planes: rows up to the 128-lane tile, the index keys "
+                "among them"),
+        })
+        for kind in sorted(set(self.layer_types)):
+            g, labels = self._geo[kind], (("kind", kind),)
+            out[("latent_lanes_stored", labels)] = (
+                g["lanes"], "lanes a pool row of a plane of this kind "
+                "holds: rank + rotary lanes and zeros up to the tile")
+            out[("latent_rank", labels)] = (
+                g["rank"], "lanes of a cached row that are the normed "
+                "latent: the values")
+        return out
+
+    def check_params(self, params, max_len):
+        need = ["tok_emb.w", "norm_f.scale", "lm_head.w"]
+        for i, kind in enumerate(self.layer_types):
+            need += [f"block{i}_{k}" for k in (
+                "norm1.scale", "att_qa.w", "att_qnorm.scale", "att_qb.w",
+                "att_kva.w", "att_kvnorm.scale", "att_kvb.w", "att_gate.w",
+                "att_out.w", "norm2.scale")]
+            if kind == "full":
+                need += [f"block{i}_{k}" for k in (
+                    "idx_qb.w", "idx_k.w", "idx_knorm.scale",
+                    "idx_knorm.bias", "idx_w.w")]
+            need += [f"block{i}_{k}" for k in (
+                ("ffn_down.w",) if i < self.dense_layers else
+                ("router.w", "router.bias", "shared_down.w",
+                 "experts_down.w"))]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+        for i in range(self.n_layer):
+            g = self._of(i)
+            want = {"att_qb.w": (g["q_rank"],
+                                 g["heads"] * (g["nope"] + g["rope"])),
+                    "att_kva.w": (self.d_model, g["rank"] + g["rope"]),
+                    "att_kvb.w": (g["rank"],
+                                  g["heads"] * (g["nope"] + g["v"]))}
+            for k, shape in want.items():
+                got = tuple(np.shape(params[f"block{i}_{k}"]))
+                if got != shape:
+                    raise ValueError(
+                        f"{self.name}: layer {i} ({self.layer_types[i]}) "
+                        f"holds {k} {got}; its geometry says {shape}")
+        if self.moe_layers:
+            self._check_experts(params)
+
+    def _angles(self, pos):
+        return {kind: _rope_angles(pos, g["rope"], g["theta"])
+                for kind, g in self._geo.items() if kind in self.layer_types}
+
+    def _attention(self, w, i, x, ropes, planes, attend):
+        kind = self.layer_types[i]
+        g, rope = self._geo[kind], ropes[kind]
+        rank, nope, h = g["rank"], g["nope"], g["heads"]
+        with sublayer("norm"):
+            a = _rms(x, w("norm1.scale"), self.eps)
+        lead = a.shape[:-1]
+        with sublayer("attn.proj"):
+            # the lora rescale rides the norm's float32 row: 5 ** 0.5 and
+            # 10 ** 0.5 are not bfloat16 numbers
+            c_q = _rms(a @ w("att_qa.w"), w("att_qnorm.scale"), self.eps,
+                       gain=g["r_q"])
+            q = (c_q @ w("att_qb.w")).reshape(*lead, h, -1)
+            kva = a @ w("att_kva.w")                  # [.., rank | rope]
+            c = _rms(kva[..., :rank], w("att_kvnorm.scale"), self.eps,
+                     gain=g["r_kv"])
+            k_pe = _rope(kva[..., None, rank:], *rope)[..., 0, :]
+            kvb = w("att_kvb.w").reshape(rank, h, -1)
+            q_row = self._query_rows(q, kvb, rope, nope,
+                                     g["lanes"] - rank - g["rope"])
+            row = jnp.concatenate([c, k_pe], axis=-1)
+            gate = jax.nn.sigmoid(a @ w("att_gate.w"))           # [.., h]
+            if kind == "full":
+                r = g["rope"]
+                q_i = (c_q @ w("idx_qb.w")).reshape(
+                    *lead, self.index_heads, self.index_dim)
+                q_i = jnp.concatenate(
+                    [_rope(q_i[..., :r], *rope), q_i[..., r:]], axis=-1)
+                k_i = _ln(a @ w("idx_k.w"), w("idx_knorm.scale"),
+                          w("idx_knorm.bias"), self.INDEX_EPS)
+                k_i = jnp.concatenate(
+                    [_rope(k_i[..., None, :r], *rope)[..., 0, :],
+                     k_i[..., r:]], axis=-1)
+                w_i = jnp.matmul(
+                    a, w("idx_w.w"), preferred_element_type=jnp.float32
+                ) * (self.index_heads ** -0.5 * self.index_dim ** -0.5)
+        with sublayer("attn.core"):
+            if kind == "full":
+                u, planes = attend.sparse(
+                    planes, i, q_row, row, q_i, w_i, k_i,
+                    topk=self.index_topk, value_lanes=rank,
+                    scale=g["scale"])
+            else:
+                u, planes = attend(planes, i, 0, q_row, row, None,
+                                   value_lanes=rank, scale=g["scale"],
+                                   window=self.window)
+        with sublayer("attn.proj"):
+            o = jnp.einsum("...hr,rhv->...hv", u, kvb[..., nope:])
+            o = o * gate[..., None]
+            return o.reshape(*lead, -1) @ w("att_out.w"), planes
 
 
 class SinkWindowMoE(_Routed, Architecture):

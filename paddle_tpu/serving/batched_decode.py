@@ -88,6 +88,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import paged_attention as _paged
+from ..kernels import sparse_attention as _sparse
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
@@ -159,9 +160,12 @@ class _Cache:
     rows of the per-slot state.
 
     ``planes = (pool_k, pool_v, state)``: one pool array a plane
-    (``pool_v`` is ``()`` where a plane is ONE array, ``arch.pool_arrays
-    == 1``: called with ``vh=None, kh=`` the latent row, it writes that
-    array only and hands ``attend`` ``pool_v=None``), one
+    (``pool_v`` holds the SECOND array of the planes that have one, in
+    order, ``arch.second_array(plane)`` its index or ``None``: ``()``
+    where every plane is ONE array; called with ``vh=None, kh=`` the
+    latent row, it writes that array only and hands ``attend``
+    ``pool_v=None``; ``sparse`` is the call of a plane whose second
+    array holds index keys), one
     tuple of ``[max_slots, ...]`` arrays a state layer (``()`` for an
     architecture that holds none).  A stack that runs ``arch.passes``
     times folds its passes into the block axis: a plane's array holds
@@ -241,16 +245,30 @@ class _Cache:
             return self.live
         return self.table[:, 0] != 0
 
-    def __call__(self, planes, plane, i_pass, qh, kh, vh, **how):
+    def _plane(self, planes, plane, i_pass):
+        """Plane ``plane``'s arrays (the second ``None`` where it has
+        one array), the second's index in ``pool_v``, and the table and
+        write blocks of its kind of chain (of pass ``i_pass``)."""
         pool_k, pool_v = planes[:2]
         kind = self.arch.chain_kind(plane) if len(self.tables) > 1 else 0
         tbl, b = self.tables[kind], self.blks[kind]
         if self.arch.passes > 1:
             shift = i_pass * (pool_k[plane].shape[0] // self.arch.passes)
             tbl, b = tbl + shift, b + shift
-        # a latent plane (arch.pool_arrays == 1) has no V array: pool_v is
-        # () and the one row written is kh
-        pk, pv = pool_k[plane], pool_v[plane] if pool_v else None
+        # a plane of ONE array (a latent plane) has no second: pool_v
+        # holds the second arrays of the planes that have one, in order
+        j = self.arch.second_array(plane)
+        return pool_k[plane], None if j is None else pool_v[j], j, tbl, b
+
+    @staticmethod
+    def _written(planes, plane, pk, j, pv):
+        pool_k, pool_v = planes[:2]
+        return (pool_k[:plane] + (pk,) + pool_k[plane + 1:],
+                pool_v if j is None else pool_v[:j] + (pv,) + pool_v[j + 1:]
+                ) + planes[2:]
+
+    def __call__(self, planes, plane, i_pass, qh, kh, vh, **how):
+        pk, pv, j, tbl, b = self._plane(planes, plane, i_pass)
         if kh is not None:
             # every write lands before the attention below: the
             # write-before-attend discipline, one scatter per plane
@@ -271,9 +289,24 @@ class _Cache:
             ctx = ctx[:, 0]
         if kh is None:
             return ctx, planes
-        return ctx, (pool_k[:plane] + (pk,) + pool_k[plane + 1:],
-                     pool_v and pool_v[:plane] + (pv,) + pool_v[plane + 1:]
-                     ) + planes[2:]
+        return ctx, self._written(planes, plane, pk, j, pv)
+
+    def sparse(self, planes, plane, qh, row, q_idx, w_idx, k_idx, **how):
+        """A plane whose rows an INDEXER selects
+        (``kernels/sparse_attention.py``): writes the latent ``row`` and
+        the index key ``k_idx`` of every position under ONE block id (the
+        plane's two arrays), then scores the chain's index keys with
+        ``q_idx``, ``w_idx``, selects and attends (``sparse_attend``;
+        ``how``: ``topk``, ``value_lanes``, ``scale``)."""
+        pk, pi, j, tbl, b = self._plane(planes, plane, 0)
+        with sublayer("cache"):
+            pk = _paged.write(pk, b, self.off, row)
+            pi = _paged.write(pi, b, self.off, k_idx)
+        lead = (lambda a: a[:, None]) if self.step else (lambda a: a)
+        ctx = _sparse.sparse_attend(lead(qh), pk, pi, tbl, self.pos4,
+                                    lead(q_idx), lead(w_idx), **how)
+        return (ctx[:, 0] if self.step else ctx), self._written(
+            planes, plane, pk, j, pi)
 
     def advance(self, planes, i, kernel, *rows, **how):
         arrays = planes[2][i]

@@ -627,7 +627,11 @@ class ServingEngine:
         # span attributes that say in which form attention runs, for an
         # architecture with latent planes or with retention layers
         self._form_attrs = (
-            dict(latent_planes=arch.latent_planes, attn_form=arch.attn_form)
+            dict(latent_planes=arch.latent_planes, attn_form=arch.attn_form,
+                 **(dict(index_planes=arch.index_planes,
+                         latent_window_planes=(arch.latent_planes
+                                               - arch.index_planes))
+                    if arch.index_planes else {}))
             if arch.latent_planes else
             dict(retention_layers=arch.retention_layers,
                  attn_form=arch.attn_form)
@@ -809,11 +813,9 @@ class ServingEngine:
         if self.arch.latent_planes:
             # every step of the chunk attends one position more, a slot
             # that finishes inside the chunk rides it out on the device
-            steps = self.decode_chunk
             self._count_latent_positions(
-                "decode", self.arch.latent_planes * sum(
-                    steps * ctx + steps * (steps - 1) // 2
-                    for _, ctx in contexts))
+                "decode", [(ctx, self.decode_chunk) for _, ctx in contexts],
+                calls=self.decode_chunk)
         self._reg.counter(
             "serving.paged_entries_live",
             help="block-table entries a paged-attention call had to "
@@ -889,13 +891,50 @@ class ServingEngine:
                  "plane's window, a shared plane once a reader), at "
                  "every decode chunk's first step").inc(streamed)
 
-    def _count_latent_positions(self, phase, positions):
+    def _count_latent_positions(self, phase, runs, calls):
+        """``runs``: ``[(positions the first row attends at most, rows)]``
+        of consecutive rows, each attending one position more than the
+        row before it (a slot's steps of a decode chunk, a prefill
+        piece's real rows); ``calls`` the attention calls a latent plane
+        made for them.  Counted by how many cached positions a plane
+        lets a row read (``arch.latent_reads``: every one, an indexer's
+        ``index_topk``, a window)."""
+        read = scored = picked = 0
+        index_planes = self.arch.index_planes
+        for first, rows in runs:
+            at = np.arange(first, first + rows, dtype=np.int64)
+            for n, bound in self.arch.latent_reads:
+                read += n * int((at if bound is None
+                                 else np.minimum(at, bound)).sum())
+            if index_planes:
+                scored += int(at.sum())
+                picked += int(np.minimum(at, self.arch.index_topk).sum())
         self._reg.counter(
             "serving.latent_positions_read", phase=phase,
             help="cached positions the latent planes' attention read: "
                  "positions attended x latent planes, every step of a "
                  "decode chunk (phase=decode), every real row of a "
-                 "prefill piece (phase=prefill)").inc(positions)
+                 "prefill piece (phase=prefill); a plane under a lower "
+                 "bound or an indexer counts what it lets a row attend"
+        ).inc(read)
+        if not index_planes:
+            return
+        self._reg.counter(
+            "serving.index_positions_scored", phase=phase,
+            help="cached positions whose index key a full plane's "
+                 "indexer scored: every position up to the row's own x "
+                 "index planes").inc(index_planes * scored)
+        self._reg.counter(
+            "serving.sparse_positions_attended", phase=phase,
+            help="cached positions the full planes' attention read after "
+                 "the selection: min(positions, index_topk) x index "
+                 "planes").inc(index_planes * picked)
+        self._reg.counter(
+            "serving.latent_window_calls", phase=phase,
+            help="attention calls on latent planes under a lower bound: "
+                 "a decode chunk's steps or a prefill's pieces x such "
+                 "planes").inc(
+                     calls * (self.arch.latent_planes - index_planes))
 
     def _count_tallies(self, phase, counts):
         """What the compiled steps of one decode chunk or one
@@ -1900,8 +1939,8 @@ class ServingEngine:
         if self.arch.latent_planes:
             # real row j of a piece at ``at`` attends at + j + 1 positions
             self._count_latent_positions(
-                "prefill", self.arch.latent_planes * sum(
-                    n * at + n * (n + 1) // 2 for _, _, at, n in pieces))
+                "prefill", [(at + 1, n) for _, _, at, n in pieces],
+                calls=len(pieces))
         # the prefill's clock pair, as a chunk's: from when the device
         # could start it
         t_p0, now = max(sp.t0, self._collected_t), sp.t1

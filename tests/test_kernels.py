@@ -1147,10 +1147,17 @@ def test_latent_attend_chooses_dense_from_eight_rows_up():
         want = _latent_dense(q, pool, tbl, np.asarray(pos), dv, 0.2)
         rows = np.asarray(pos) >= 0
         assert np.abs(got - want)[rows].max() <= 2e-4 * np.abs(want).max()
-    with pytest.raises(ValueError, match="no group, no window"):
+    with pytest.raises(ValueError, match="no group"):
         attend(q, pool, None, tbl, pos, value_lanes=dv, group=2)
-    with pytest.raises(ValueError, match="no group, no window"):
-        attend(q, pool, None, tbl, pos, value_lanes=dv, window=4)
+    # a lower bound is served since PR 55 (tests/test_sparse_latent_moe.py
+    # holds it to a NumPy softmax): a window wider than any context is no
+    # bound at all, a narrow one moves the rows that had more to see
+    wide = np.asarray(attend(q, pool, None, tbl, pos, value_lanes=dv,
+                             scale=0.2, window=1 << 20))
+    assert np.abs(wide - got)[rows].max() <= 2e-4 * np.abs(want).max()
+    narrow = np.asarray(attend(q, pool, None, tbl, pos, value_lanes=dv,
+                               scale=0.2, window=2))
+    assert np.abs(narrow - got)[np.asarray(pos) >= 4].max() > 1e-2
     with pytest.raises(ValueError, match="value_lanes"):
         attend(q, pool, None, tbl, pos)
     with pytest.raises(ValueError, match="latent plane is"):
@@ -1518,22 +1525,29 @@ def test_global_env_fallback_counted_once_per_resolution(monkeypatch):
     assert count() == c0 + 2
 
 
-def test_two_backends_seven_op_classes_and_any_platform_is_served():
+def test_two_backends_nine_op_classes_and_any_platform_is_served():
     """What the registry holds since the GPU lowerings and the gather op
     class went and the grouped matrix product, retention, a wide window's
-    chain walk and Mamba-2's recurrence came: two backends, seven op
-    classes, an auto order
+    chain walk, Mamba-2's recurrence, a learned indexer's scores and the
+    attention of the rows it selects came: two backends, nine op classes
+    (the last two in the oracle's backend only), an auto order
     for the TPU and the CPU; a platform with no order of its own is
     served by the oracle for every op class."""
+    one_backend = {"index_scores", "sparse_latent_attention"}
     assert kernels.BACKENDS == ("pallas_tpu", "xla_ref")
-    assert sorted(kernels.registered_op_classes()) == [
+    assert sorted(kernels.registered_op_classes()) == sorted([
         "chain_attention", "flash_attention", "fused_ce", "grouped_matmul",
-        "paged_attention", "retention", "ssm"]
+        "paged_attention", "retention", "ssm", *one_backend])
     assert set(kernels.AUTO_ORDER) == {"tpu", "cpu"}
     for op in kernels.registered_op_classes():
-        assert {b for b, _, _ in available_backends(op)} == set(
-            kernels.BACKENDS)
+        assert {b for b, _, _ in available_backends(op)} == (
+            {"xla_ref"} if op in one_backend else set(kernels.BACKENDS))
         assert resolve_name(op, platform="gpu") == "xla_ref"
+        assert resolve_name(op, platform="tpu") in kernels.BACKENDS
+        for dtype in ("float32", "bfloat16"):
+            if (op, dtype) in kernels.ORACLE_TOL:
+                assert kernels.oracle_tol(op, dtype) > 0
+    assert all((op, "float32") in kernels.ORACLE_TOL for op in one_backend)
     with pytest.raises(ValueError, match="unknown kernel backend"):
         resolve_name("flash_attention", "triton")
 
