@@ -8,15 +8,22 @@ avoid against its operations), and what the same rows cost the DENSE
 latent call over the whole chain, which the selection replaces.
 
     chiprun -- python3 benchmarks/sparse_walk.py \\
-        [--only decode_10x33k,piece_512] [--calls 10] [--out chiprun_out/sparse_walk.jsonl]
+        [--only decode_10x33k,piece_512] [--live 2,4,6] [--calls 10] \\
+        [--out chiprun_out/sparse_walk.jsonl]
 
 The geometry is the cell's: ONE full plane (latent rows of 640 stored
 lanes and index keys of 128 lanes, 15,249 blocks of 32) and one sliding
-plane (1,152 lanes), chains of 1,064 entries; a decode step of 1, 8 and
-10 slots at 33,000 positions, prefill pieces of 32, 128 and 512 rows
-that end at 33,000, the three steps of the sparse call each alone, and
-the sliding plane's decode step and 512-row piece.  This walk is what
-decides whether the gather stays XLA's (PERF.md section 6, PR 55).
+plane (1,152 lanes), chains of 1,064 entries; a decode step of 1, 2, 4,
+5, 6, 8 and 10 slots at 33,000 positions (the call's line in its TABLE's
+slots), prefill pieces of 32, 128 and 512 rows that end at 33,000, the
+three steps of the sparse call each alone, and the sliding plane's decode
+step and 512-row piece.  ``--live n[,n...]`` adds, for each ``n``, the
+ten-slot decode step with ``n`` slots live (the others as the engine
+leaves a released slot: a table row of zeros, ``pos`` -1; the live ones
+spread over the table, not packed): the call's line in its LIVE slots,
+which is the table's line before PR 56 and ``slots_run(n, 10)``'s after.
+This walk is what decides whether the gather stays XLA's (PERF.md
+section 6, PR 55) and which slot counts the call keeps (PR 56).
 Refuses unless JAX finds a TPU: a number from a CPU run is no device
 metric.
 """
@@ -33,8 +40,10 @@ NB, B, TOPK, CONTEXT = 1064, 32, 2048, 33000
 BLOCKS = 1 + 10 * NB + 4608
 # name -> (what, slots, rows)
 GEOMETRIES = {
-    "decode_1x33k": ("sparse", 1, 1), "decode_8x33k": ("sparse", 8, 1),
-    "decode_10x33k": ("sparse", 10, 1),
+    "decode_1x33k": ("sparse", 1, 1), "decode_2x33k": ("sparse", 2, 1),
+    "decode_4x33k": ("sparse", 4, 1), "decode_5x33k": ("sparse", 5, 1),
+    "decode_6x33k": ("sparse", 6, 1),
+    "decode_8x33k": ("sparse", 8, 1), "decode_10x33k": ("sparse", 10, 1),
     "piece_32": ("sparse", 1, 32), "piece_128": ("sparse", 1, 128),
     "piece_512": ("sparse", 1, 512),
     "indexer_alone_10x33k": ("scores", 10, 1),
@@ -88,7 +97,7 @@ def _config():
         return json.load(f)
 
 
-def measure(name, calls, seed, peak):
+def measure(name, calls, seed, peak, live=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -108,7 +117,14 @@ def measure(name, calls, seed, peak):
     pos = jnp.asarray(
         CONTEXT - W + np.arange(W)[None] - 7 * np.arange(S)[:, None],
         jnp.int32)
-    contexts = [int(n) for n in (np.asarray(pos) + 1).reshape(-1)]
+    if live is not None:
+        # the other slots as a released slot's rows are: zeros, pos -1
+        dead = np.ones(S, bool)
+        dead[np.sort(rng.permutation(S)[:live])] = False
+        table = jnp.where(dead[:, None], 0, table)
+        pos = jnp.where(dead[:, None], -1, pos)
+        name = f"{name}_live{live}"
+    contexts = [int(n) for n in (np.asarray(pos) + 1).reshape(-1) if n > 0]
     if what == "window":
         pool = jax.random.normal(k1, (BLOCKS, B, 1152), bf)
         q = jax.random.normal(k2, (S, W, 64, 1152), bf) * 0.05
@@ -155,7 +171,8 @@ def measure(name, calls, seed, peak):
     busy_us, mosaic_us = _device_us(fn, args, calls)
     least = max(nbytes / peak["hbm_bytes_per_s"],
                 ops / peak["bf16_flops_per_s"])
-    return {"geometry": name, "what": what, "slots": S, "rows": W,
+    return {"geometry": name, "what": what, "slots": S,
+            "live": S if live is None else live, "rows": W,
             "context": CONTEXT, "us_a_call": busy_us,
             "mosaic_us_a_call": mosaic_us, "ops": int(ops),
             "bytes": int(nbytes),
@@ -168,6 +185,9 @@ def measure(name, calls, seed, peak):
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--only", default="")
+    ap.add_argument("--live", default="",
+                    help="live slot counts of the ten-slot decode step, "
+                         "each a line more (decode_10x33k_live<n>)")
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--seed", type=int, default=55)
     ap.add_argument("--out", default="chiprun_out/sparse_walk.jsonl")
@@ -182,11 +202,14 @@ def main(argv=None):
     from chipbench import flops
 
     peak = flops.peaks(jax.devices()[0].device_kind)
-    names = [n for n in args.only.split(",") if n] or list(GEOMETRIES)
+    lives = [int(n) for n in args.live.split(",") if n]
+    names = [n for n in args.only.split(",") if n] or (
+        [] if lives else list(GEOMETRIES))
+    runs = [(n, None) for n in names] + [("decode_10x33k", n) for n in lives]
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "a") as out:
-        for name in names:
-            line = json.dumps(measure(name, args.calls, args.seed, peak))
+        for name, live in runs:
+            line = json.dumps(measure(name, args.calls, args.seed, peak, live))
             print(line, flush=True)
             out.write(line + "\n")
     return 0

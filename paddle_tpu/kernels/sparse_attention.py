@@ -32,6 +32,19 @@ A table that cannot hold more than ``topk`` positions never binds the
 selection: :func:`sparse_attend` then IS ``paged_attention.attend`` on
 the latent array (and lowers to it), and no index key is read.
 
+WHAT A DEAD SLOT COSTS.  A decode step's table has a row a slot, and a
+released slot's row is zeros with ``pos = -1``.  The three steps have
+static shapes, so run on the table they gather 1,064 blocks of keys for
+such a slot, score 34,048 positions, select among as many minus
+infinities and gather 2,048 rows of block 0: a dead slot costs what a
+live one does, and more (ten slots of 34,048 positions take 1,051 us
+with all live and 1,015 - 1,403 with 9 - 1 live: benchmarks/
+sparse_walk.py ``--live``, PERF.md PR 56).  :func:`sparse_attend`
+therefore packs the LIVE slots to the front and takes, under one
+``lax.switch``, the branch that runs the three steps for
+:func:`slots_run` of them: a dead slot beyond that count costs nothing,
+one within it what it cost before.
+
 Both op classes have the ``xla_ref`` backend only: the gather is XLA's
 (``pool[block, offset]``), in pieces of query rows so that a 512-row
 prefill piece never holds more than ``GATHER_BYTES`` of gathered rows or
@@ -47,8 +60,8 @@ from .registry import register_kernel, resolve
 from .xla_ref import NEG_INF
 
 __all__ = ["COMPACT_LANES", "GATHER_BYTES", "INDEX_SCORE_BYTES",
-           "SELECT_BYTES", "index_scores",
-           "select_positions", "sparse_attend", "sparse_latent_attention"]
+           "SELECT_BYTES", "index_scores", "select_positions",
+           "slots_run", "sparse_attend", "sparse_latent_attention"]
 
 # what one piece of query rows may hold of float32 per-head index scores
 # ``[rows, H_I, T]`` before the heads are reduced, and of gathered latent
@@ -245,6 +258,31 @@ def sparse_latent_attention(q, pool, table, pos, sel, value_lanes,
         out_dtype=out_dtype)
 
 
+def slots_run(live, slots):
+    """The slots a decode step's call runs its three steps for when
+    ``live`` of the table's ``slots`` are live: half the table (rounded
+    up) where that holds them, the table where it does not, none for
+    none (0 | 5 | 10 of ten slots).  The call costs a fixed part and a
+    part a slot it runs (84 + 88 us a slot at 34,048 positions, dead or
+    live: benchmarks/sparse_walk.py, PERF.md PR 56), so finer counts
+    would save more of it; but every count is one more compiled copy of
+    the three steps a full plane in the decode chunk, 3.3 s of a WARM
+    start (and 10 s of a cold one) in ``dots3np.doc_qa_32k``, whose
+    start-up may grow by 7 s: one count beside the table's."""
+    if live <= 0:
+        return 0
+    half = -(-slots // 2)
+    return half if live <= half else slots
+
+
+def _three_steps(pool, pool_idx, q, table, pos, q_idx, w_idx, *, topk,
+                 value_lanes, scale, out_dtype):
+    sel = select_positions(
+        index_scores(q_idx, w_idx, pool_idx, table, pos), topk)
+    return sparse_latent_attention(q, pool, table, pos, sel, value_lanes,
+                                   scale=scale, out_dtype=out_dtype)
+
+
 def sparse_attend(q, pool, pool_idx, table, pos, q_idx, w_idx, topk,
                   value_lanes, scale=None, out_dtype=None):
     """One full layer's attention through the table, the one call the
@@ -252,15 +290,55 @@ def sparse_attend(q, pool, pool_idx, table, pos, q_idx, w_idx, topk,
     the ``topk`` positions, the gathered rows attended.  Where the table
     holds no more than ``topk`` positions every live one is selected
     whatever its score: the call is ``paged_attention.attend`` on the
-    latent plane."""
+    latent plane.
+
+    A table of several slots (a decode step) pays for the slots that are
+    LIVE (any row at ``pos >= 0``), not for its rows: the live slots'
+    small operands (``q``, ``table``, ``pos``, ``q_idx``, ``w_idx``) are
+    packed to the front in their order, ``lax.switch`` takes the branch
+    of ``slots_run(live, S)`` slots, which is the three steps on that
+    many packed slots, and the result goes back to the slots' own rows;
+    a dead slot's rows are zeros, and a step with no live slot does
+    nothing.  Each slot's rows are independent in all three steps, so a
+    live slot's output is the bits of the call on that slot alone.  The
+    pools are operands the branches only read."""
     if table.shape[1] * pool.shape[1] <= topk:
         return _paged.attend(q, pool, None, table, pos,
                              value_lanes=value_lanes, scale=scale,
                              out_dtype=out_dtype)
-    sel = select_positions(
-        index_scores(q_idx, w_idx, pool_idx, table, pos), topk)
-    return sparse_latent_attention(q, pool, table, pos, sel, value_lanes,
-                                   scale=scale, out_dtype=out_dtype)
+    how = dict(topk=topk, value_lanes=value_lanes, scale=scale,
+               out_dtype=q.dtype if out_dtype is None else out_dtype)
+    S = table.shape[0]
+    if S == 1:
+        return _three_steps(pool, pool_idx, q, table, pos, q_idx, w_idx,
+                            **how)
+    # a stable partition of the slots, live first: where slot s goes ...
+    alive = jnp.any(pos >= 0, axis=1)
+    n_live = jnp.sum(alive, dtype=jnp.int32)
+    to = jnp.where(alive, jnp.cumsum(alive, dtype=jnp.int32) - 1,
+                   n_live + jnp.cumsum(~alive, dtype=jnp.int32) - 1)
+    # ... and which slot packed row r holds (a one-hot [S, S], no sort)
+    at = jnp.arange(S, dtype=jnp.int32)
+    of = jnp.sum(jnp.where(to[None, :] == at[:, None], at[None, :], 0),
+                 axis=1)
+    packed = tuple(a[of] for a in (q, table, pos, q_idx, w_idx))
+    counts = sorted({slots_run(n, S) for n in range(1, S + 1)})
+
+    def none(pool, pool_idx, *packed):
+        return jnp.zeros(q.shape[:-1] + (int(value_lanes),),
+                         how["out_dtype"])
+
+    def first(b):
+        def run(pool, pool_idx, *packed):
+            out = _three_steps(pool, pool_idx, *(a[:b] for a in packed),
+                               **how)
+            return jnp.pad(out, ((0, S - b),) + ((0, 0),) * (out.ndim - 1))
+        return run
+
+    out = jax.lax.switch(
+        jnp.sum(n_live > jnp.asarray([0] + counts[:-1]), dtype=jnp.int32),
+        [none] + [first(b) for b in counts], pool, pool_idx, *packed)
+    return out[to]
 
 
 class _IndexScoresXlaRef:

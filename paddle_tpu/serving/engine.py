@@ -74,6 +74,7 @@ import numpy as np
 
 from ..kernels import paged_attention as _paged
 from ..kernels import retention as _retention
+from ..kernels import sparse_attention as _sparse
 from ..observability import flight as _flight
 from ..observability import metrics as _obs
 from ..observability import trace as _trace
@@ -816,6 +817,7 @@ class ServingEngine:
             self._count_latent_positions(
                 "decode", [(ctx, self.decode_chunk) for _, ctx in contexts],
                 calls=self.decode_chunk)
+            self._count_sparse_slots(len(contexts))
         self._reg.counter(
             "serving.paged_entries_live",
             help="block-table entries a paged-attention call had to "
@@ -935,6 +937,27 @@ class ServingEngine:
                  "a decode chunk's steps or a prefill's pieces x such "
                  "planes").inc(
                      calls * (self.arch.latent_planes - index_planes))
+
+    def _count_sparse_slots(self, live):
+        """A decode chunk's sparse calls (one a full plane a step, where
+        the table is wider than ``index_topk``: ``sparse_attend``) for
+        ``live`` slots: the slots that were live and the slots the calls
+        ran their three steps for (``sparse_attention.slots_run``)."""
+        calls = self.arch.index_planes * self.decode_chunk
+        if (not calls or self.blocks_per_slot * self.block_tokens
+                <= self.arch.index_topk):
+            return
+        self._reg.counter(
+            "serving.sparse_slots_live",
+            help="live slots of the decode chunks' sparse calls: live "
+                 "slots x steps x index planes").inc(live * calls)
+        self._reg.counter(
+            "serving.sparse_slots_run",
+            help="slots the decode chunks' sparse calls scored, selected "
+                 "and gathered for (sparse_attention.slots_run of the "
+                 "live ones) x steps x index planes: sparse_slots_live's "
+                 "denominator").inc(
+                     _sparse.slots_run(live, self.max_slots) * calls)
 
     def _count_tallies(self, phase, counts):
         """What the compiled steps of one decode chunk or one

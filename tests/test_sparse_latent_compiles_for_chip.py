@@ -11,6 +11,7 @@ and the attention of the gathered rows for a decode step and for a
 and the whole decode chunk and widest prefill piece of the five held
 layers.  Nothing runs: a compile that passes is no chip run."""
 
+import functools
 import json
 import os
 import re
@@ -37,6 +38,12 @@ def one_chip():
 
 SLOTS, NB, B, TOPK = 10, 1064, 32, 2048
 BLOCKS = 1 + SLOTS * NB + 4608
+
+
+def _branches(text):
+    """The branch count of every conditional of a compiled text."""
+    return [len(c.split(",")) for c in re.findall(
+        r" conditional\(.*branch_computations=\{([^}]*)\}", text)]
 
 
 def _arg(one_chip):
@@ -87,7 +94,11 @@ def test_the_sparse_call_compiles_for_v5e(slots, rows, one_chip):
     """Index scores over 34,048 positions, the exact top 2,048 and the
     gathered rows attended by 128 heads: a decode step of 10 slots and a
     512-row piece, whose pieces of query rows keep the temporaries under
-    a gigabyte."""
+    a gigabyte.  The decode step's three steps stand under ONE
+    conditional, a branch a count of ``slots_run`` (none first), and in
+    every branch that runs them the instructions carry the three named
+    scopes the ``dsa.*`` readers and the ``step.*_busy_share`` kinds join
+    on; a piece has one slot and no conditional."""
     from paddle_tpu.kernels import sparse_attention as sp
 
     arg = _arg(one_chip)
@@ -100,9 +111,19 @@ def test_the_sparse_call_compiles_for_v5e(slots, rows, one_chip):
         arg((slots, rows), jnp.int32),
         arg((slots, rows, 64, 128), jnp.bfloat16),
         arg((slots, rows, 64), jnp.float32)).compile()
-    names = re.findall(r"%([\w.\-]+) = ", compiled.as_text())
+    text = compiled.as_text()
+    names = re.findall(r"%([\w.\-]+) = ", text)
     assert not any("paged_latent_attention" in n for n in names)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    counts = sorted({sp.slots_run(n, slots) for n in range(slots + 1)})
+    ran = range(1, len(counts)) if slots > 1 else ()
+    assert _branches(text) == ([len(counts)] if slots > 1 else [])
+    scoped = set(re.findall(
+        r"op_name=\"[^\"]*/branch_(\d+)_fun/(paged_index_scores|"
+        r"index_select|paged_sparse_latent_attention)/", text))
+    assert scoped == {(str(i), scope) for i in ran
+                      for scope in ("paged_index_scores", "index_select",
+                                    "paged_sparse_latent_attention")}
 
 
 def test_grouped_matmul_compiles_at_32_experts_of_5120_by_1536(one_chip):
@@ -130,13 +151,10 @@ def _cell():
     return cfg, mix, families.of(cfg, "serve")
 
 
-@pytest.mark.parametrize("entry", ["decode", "prefill_512"])
-def test_the_cells_executables_compile_and_fit_a_v5e(entry, one_chip,
-                                                     monkeypatch):
-    """The decode chunk and the widest prefill piece of the five held
-    layers at 10 slots x 34,048 positions with the trie's 4,608 blocks,
-    from shapes alone: weights 8.17 GB, the pool 4.87 GB, and temporaries
-    that leave room on a chip of 15.75 GiB."""
+@functools.lru_cache(maxsize=None)
+def _compile_entry(entry, one_chip):
+    """``(compiled, weight bytes, pool bytes)`` of the cell's decode chunk
+    or widest prefill piece, from shapes alone, once a module."""
     import numpy as np
 
     from paddle_tpu.serving import batched_decode as bd
@@ -160,17 +178,27 @@ def test_the_cells_executables_compile_and_fit_a_v5e(entry, one_chip,
                for s in shapes if len(s) > 1)
     pool = sum(int(np.prod(a.shape)) * 2 for a in pk + pv)
     assert pool == blocks * 319_488
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     slots = arg((S,), jnp.int32)
-    if entry == "decode":
-        lowered = bd.make_decode_chunk(arch, 4).lower(
-            params, pk, pv, slots, slots, arg((S, nb), jnp.int32))
-    else:
-        scalar = arg((), jnp.int32)
-        lowered = bd.make_prefill(arch, 512).lower(
-            params, pk, pv, slots, slots, scalar, arg((nb,), jnp.int32),
-            arg((512,), jnp.int32), scalar, scalar, scalar, scalar)
-    compiled = lowered.compile()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        if entry == "decode":
+            lowered = bd.make_decode_chunk(arch, 4).lower(
+                params, pk, pv, slots, slots, arg((S, nb), jnp.int32))
+        else:
+            scalar = arg((), jnp.int32)
+            lowered = bd.make_prefill(arch, 512).lower(
+                params, pk, pv, slots, slots, scalar, arg((nb,), jnp.int32),
+                arg((512,), jnp.int32), scalar, scalar, scalar, scalar)
+        return lowered.compile(), weights, pool
+
+
+@pytest.mark.parametrize("entry", ["decode", "prefill_512"])
+def test_the_cells_executables_compile_and_fit_a_v5e(entry, one_chip):
+    """The decode chunk and the widest prefill piece of the five held
+    layers at 10 slots x 34,048 positions with the trie's 4,608 blocks,
+    from shapes alone: weights 8.17 GB, the pool 4.87 GB, and temporaries
+    that leave room on a chip of 15.75 GiB."""
+    compiled, weights, pool = _compile_entry(entry, one_chip)
     text = compiled.as_text()
     assert ("paged_latent_attention" in text) == (entry == "decode")
     assert "grouped_matmul" in text
@@ -180,3 +208,25 @@ def test_the_cells_executables_compile_and_fit_a_v5e(entry, one_chip,
     total = weights + pool + mem.temp_size_in_bytes
     print(entry, "temp", mem.temp_size_in_bytes, "total", total)
     assert total < 15.0 * 2 ** 30, (weights, pool, mem.temp_size_in_bytes)
+
+
+def test_the_decode_chunk_reads_its_pools_in_place_under_the_branches(
+        one_chip):
+    """The cell's whole decode chunk with the sparse calls' branches:
+    one conditional a full plane, three branches each (none, 5 and 10
+    slots), the pools operands the branches only read: no copy
+    of a pool array anywhere in the compiled text, the pools still
+    aliased to the outputs, and temporaries within 16 MiB of what the
+    chunk held before the call packed its live slots (211,302,912 B: the
+    packed queries and a branch's own intermediates), so the whole stays
+    at 79% of the chip."""
+    compiled, weights, pool = _compile_entry("decode", one_chip)
+    text = compiled.as_text()
+    assert _branches(text) == [3, 3]
+    pools = r"bf16\[%d,%d,(?:640|128|1152)\]" % (BLOCKS, B)
+    assert re.search(pools, text)
+    assert not re.search(r"= %s\S* copy\(" % pools, text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < 211_302_912 + (16 << 20)
+    assert weights + pool + mem.temp_size_in_bytes < 0.80 * 15.75 * 2 ** 30

@@ -7,6 +7,8 @@ a window smaller than the contexts, index keys in a second array of the
 full planes only.  Float32 through the cache has to agree with the
 reference's full forward at every generated position."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -552,6 +554,11 @@ def test_the_engine_serves_shared_heads_forks_and_counts(params, monkeypatch):
     scored = st["serving.index_positions_scored{phase=decode}"]
     picked = st["serving.sparse_positions_attended{phase=decode}"]
     assert picked == nfull * steps * TINY["index_topk"] < scored
+    # one request at a time in a table of two slots: the sparse calls ran
+    # for one slot where one was live
+    assert st["serving.sparse_slots_live"] == nfull * steps
+    assert st["serving.sparse_slots_run"] == nfull * steps * sparse.slots_run(
+        1, 2) == nfull * steps
 
 
 def test_a_copy_on_write_fork_copies_the_index_keys(params):
@@ -626,3 +633,121 @@ def test_the_selection_is_exact_without_a_sort(shape, k):
             tied = np.flatnonzero(row == want[-1])
             mine = [j for j in live if row[j] == want[-1]]
             assert mine == tied[:len(mine)].tolist()
+
+
+# what a decode step's table looks like to ``sparse_attend``: which slots
+# are dead (a table row of zeros, every row at ``pos = -1``)
+DEAD = {
+    "none": lambda S: np.zeros(S, bool),
+    "first": lambda S: np.arange(S) == 0,
+    "last": lambda S: np.arange(S) == S - 1,
+    "alternating": lambda S: np.arange(S) % 2 == 1,
+    "all_but_one": lambda S: np.arange(S) != S // 2,
+    "all": lambda S: np.ones(S, bool),
+}
+_SP = dict(nb=6, blk=8, topk=12, L=128, V=16, heads=4, h_idx=2, d_idx=16)
+
+
+@functools.lru_cache(maxsize=None)
+def _sparse_call():
+    return jax.jit(lambda *a: sparse.sparse_attend(
+        *a, topk=_SP["topk"], value_lanes=_SP["V"], scale=0.3))
+
+
+def _sparse_table(S, W, dead, seed=0):
+    """``sparse_attend``'s operands for ``S`` slots of ``W`` rows, bf16
+    pools as the cell's, the slots of ``dead`` as a released slot is; at
+    ``W > 1`` the first live slot's first two rows are dead too."""
+    rng = np.random.default_rng(seed)
+    nb, blk = _SP["nb"], _SP["blk"]
+    blocks = 1 + S * nb + 3
+    bf = jnp.bfloat16
+    pool = jnp.asarray(rng.normal(size=(blocks, blk, _SP["L"])), bf)
+    idx = jnp.asarray(rng.normal(size=(blocks, blk, _SP["d_idx"])), bf)
+    table = np.stack([rng.permutation(blocks - 1)[:nb] + 1
+                      for _ in range(S)]).astype(np.int32)
+    pos = (rng.integers(_SP["topk"] + W, nb * blk, size=(S, 1)) - W
+           + np.arange(W)[None]).astype(np.int32)
+    table[dead], pos[dead] = 0, -1
+    if W > 1 and not dead.all():
+        pos[np.flatnonzero(~dead)[0], :2] = -1
+    q = jnp.asarray(rng.normal(size=(S, W, _SP["heads"], _SP["L"])), bf)
+    qi = jnp.asarray(
+        rng.normal(size=(S, W, _SP["h_idx"], _SP["d_idx"])), bf)
+    wi = jnp.asarray(rng.normal(size=(S, W, _SP["h_idx"])), jnp.float32)
+    return q, pool, idx, jnp.asarray(table), jnp.asarray(pos), qi, wi
+
+
+@pytest.mark.parametrize("W", [1, 4])
+@pytest.mark.parametrize("S", [1, 3, 5, 10])
+@pytest.mark.parametrize("pattern", list(DEAD))
+def test_the_sparse_call_runs_for_the_live_slots(pattern, S, W):
+    """Dead slots in every pattern of a small table: every live row is,
+    to the bit, the row of the call made on its slot alone (what the
+    call was before it packed its live slots), every dead row zeros."""
+    dead = DEAD[pattern](S)
+    q, pool, idx, table, pos, qi, wi = _sparse_table(S, W, dead, seed=S + W)
+    got = np.asarray(_sparse_call()(q, pool, idx, table, pos, qi, wi))
+    assert got.shape == (S, W, _SP["heads"], _SP["V"])
+    rows_live = np.asarray(pos) >= 0
+    assert not got[~rows_live].astype(np.float32).any()
+    for s in np.flatnonzero(~dead):
+        one = slice(s, s + 1)
+        alone = np.asarray(_sparse_call()(
+            q[one], pool, idx, table[one], pos[one], qi[one], wi[one]))
+        assert alone[0][rows_live[s]].astype(np.float32).any()
+        assert got[s].tobytes() == alone[0].tobytes(), (pattern, S, W, s)
+
+
+def _conds(jaxpr):
+    """Every ``cond`` equation of a jaxpr, its sub-jaxprs' too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _conds(sub)
+
+
+@pytest.mark.parametrize("S", [3, 5, 10])
+def test_one_conditional_a_call_a_branch_a_slot_count_and_no_sort(S):
+    """The lowered call holds ONE ``case`` with as many branches as
+    ``slots_run`` has values (none among them), and packs its live slots
+    with no sort; a table of one slot (a prefill piece) holds none."""
+    args = _sparse_table(S, 1, np.zeros(S, bool))
+    text = _sparse_call().lower(*args).as_text()
+    assert text.count("stablehlo.case") == 1
+    assert "stablehlo.sort" not in text and "chlo.top_k" not in text
+    (cond,) = _conds(jax.make_jaxpr(_sparse_call())(*args).jaxpr)
+    assert len(cond.params["branches"]) == len(
+        {sparse.slots_run(n, S) for n in range(S + 1)})
+    one = _sparse_call().lower(*_sparse_table(1, 4, np.zeros(1, bool)))
+    assert "stablehlo.case" not in one.as_text()
+
+
+@pytest.mark.parametrize("S", [1, 2, 3, 4, 5, 7, 9, 10, 12, 16, 33, 64])
+def test_slots_run_holds_the_live_slots_in_a_few_counts(S):
+    """Monotone in the live slots, at least as many, the table's at
+    most and AT all of them live, none for none; two counts at most
+    beside none whatever the table (5 and 10 of ten)."""
+    run = [sparse.slots_run(n, S) for n in range(S + 1)]
+    assert run[0] == 0 and run[S] == S
+    assert all(a <= b for a, b in zip(run, run[1:]))
+    assert all(n <= r <= S for n, r in enumerate(run))
+    assert len(set(run[1:])) <= 2
+    if S == 10:
+        assert sorted(set(run)) == [0, 5, 10]
+
+
+@pytest.mark.parametrize("stats,want", [
+    ({}, None),                                      # a parent: no counter
+    ({"serving.sparse_slots_live": 0.0}, None),
+    ({"serving.sparse_slots_live": 28.0, "serving.sparse_slots_run": 40.0},
+     70.0),
+    ({"serving.sparse_slots_live": 28.0, "serving.sparse_slots_run": 100.0},
+     28.0),
+])
+def test_the_run_slot_live_share_reads_the_engines_counters(stats, want):
+    from chipbench import run as bench_run
+
+    reader = bench_run.load_reader("dsa.run_slot_live_share")
+    assert reader.read({"stats": stats}) == want
