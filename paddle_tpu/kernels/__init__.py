@@ -4,7 +4,7 @@
 One op, two targets, one numerics oracle: every fused op class
 (``flash_attention``, ``fused_ce``, ``paged_attention``,
 ``chain_attention``, ``grouped_matmul``, ``retention``, ``ssm``,
-``index_scores``, ``sparse_latent_attention``) resolves
+``delta_rule``, ``index_scores``, ``sparse_latent_attention``) resolves
 through :mod:`.registry` to ``pallas_tpu`` (the Mosaic kernels — native
 on TPU, interpret mode in CPU tests; the last two op classes, a learned
 indexer's scores and the attention of the rows it selects, have no such
@@ -36,6 +36,7 @@ from . import chain_attention  # registers a wide window's chain walk
 from . import grouped_matmul  # registers the grouped matrix product
 from . import retention  # registers power retention's step and chunk
 from . import ssm  # registers Mamba-2's step and chunked form
+from . import delta  # registers the gated delta rule's step and WY form
 from . import sparse_attention  # registers an indexer's scores and rows
 
 __all__ = [

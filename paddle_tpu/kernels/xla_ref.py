@@ -85,6 +85,12 @@ ORACLE_TOL = {
     # so the bound is float32's
     ("ssm", "float32"): {"fwd": 2e-4, "grad": None},
     ("ssm", "bfloat16"): {"fwd": 2e-4, "grad": None},
+    # the gated delta rule the same way: float32 on both sides whatever
+    # the rows' dtype; the step kernel takes ``Sb^T k`` and ``Sb^T q`` in
+    # one pass (``o`` from ``Sb`` and ``u``, not from the written state)
+    # and the WY form solves a tile's rows together against the scan
+    ("delta_rule", "float32"): {"fwd": 2e-4, "grad": None},
+    ("delta_rule", "bfloat16"): {"fwd": 2e-4, "grad": None},
     # a learned indexer's scores (no softmax: float32 sums of relu'd
     # products over the index heads) and the attention of the rows it
     # selects (one softmax over the gathered rows): inference-only, one

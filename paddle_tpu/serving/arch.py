@@ -67,7 +67,7 @@ adds to what the compiled decode chunk and prefill piece return as their
 last output (``arch.count_names`` names the entries; an architecture
 with none never calls it and its programs have no such output).
 
-Nine architectures are here: ``Gpt2`` (the block of
+Ten architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -121,6 +121,14 @@ attends the ``index_topk`` cached positions its indexer scores highest
 (``attend.sparse``, ``kernels/sparse_attention.py``), a sliding layer's
 a window of latent rows (``attend(.., pool_v=None, window=)``);
 ``models/sparse_latent_moe_reference.py`` is its plain reference.
+``DeltaMoE`` is the first whose recurrence is a DELTA RULE: three layers
+in four advance a state matrix a head that first forgets, a key lane at
+a time, then writes only what it does not hold of the key yet
+(``kernels/delta.py``), the fourth is ``GatedMoE``'s position-free gated
+plane, every layer is routed, and because it holds BOTH a state and a
+plane it is what a prefix hit over recurrent state was built for
+(``serving/engine.py``: one snapshot at the end of a shared head); its
+plain reference is ``chipbench/families/delta_moe_reference.py``.
 
 An architecture whose state is large asks for it IN PLACE::
 
@@ -139,6 +147,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import delta as _delta_rule
 from ..kernels import paged_attention as _paged
 from ..kernels import retention as _retention
 from ..kernels import ssm as _ssm
@@ -151,7 +160,7 @@ from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
            "LatentMoE", "PowerRetention", "SinkWindowMoE", "MambaMoE",
-           "SparseLatentMoE",
+           "SparseLatentMoE", "DeltaMoE",
            "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
            "MOE_COUNTS", "EXPERT_FORMS", "STACK_SCOPE"]
 
@@ -186,6 +195,9 @@ class Architecture:
     # layers whose mixer is a state-space recurrence advanced in place
     # (``kernels/ssm.py``)
     ssm_layers = 0
+    # layers whose mixer is a gated delta rule advanced in place
+    # (``kernels/delta.py``)
+    delta_layers = 0
 
     def __init__(self, n_layer, n_head, d_model, passes=1, head_dim=None):
         if head_dim is None:
@@ -2362,5 +2374,234 @@ class PowerRetention(Architecture):
     def head(self, p, x):
         with sublayer("head"):
             return jnp.matmul(_rms(x, p["norm_f.scale"], self.norm_eps),
+                              p["lm_head.w"],
+                              preferred_element_type=jnp.float32)
+
+
+class DeltaMoE(_Routed, Architecture):
+    """Pre-normed layers of a GATED DELTA RULE or of gated position-free
+    grouped-query attention, every one followed by a routed gated-SiLU
+    FFN (the ``solar_open2`` layout: Kimi Delta Attention,
+    arXiv:2510.26692, three layers in four;
+    ``chipbench/families/delta_moe_reference.py`` writes the equations
+    down and lists what the published configuration has no key for).
+    Every layer is ``x <- x + Mix(RMS(x))``, ``x <- x + MoE(RMS(x))``; one
+    RMSNorm before the untied head; the table is not scaled; NO rotary
+    anywhere: positions come from the recurrences alone.
+
+    **A layer of ``gqa_layers``**: ``GatedMoE``'s full plane with no q/k
+    norm: ``n_head`` query heads of ``head_dim`` over ``kv_heads`` K/V
+    heads, causal softmax over the whole context at ``head_dim ** -0.5``,
+    the joined heads gated lane by lane, ``ctx * sigmoid(a W_g)``, before
+    the output projection.  One full plane such a layer.
+
+    **Every other layer** (``kernels/delta.py`` does the recurrence IN
+    PLACE through ``attend.advance``): ``q, k, v = a W_q, a W_k, a W_v``
+    (``delta_heads`` heads of ``delta_head_dim`` each), a causal
+    depthwise convolution of ``conv_taps`` taps and SiLU, q and k of
+    length 1; the LOG decay of every key lane, float32, ``g = -exp(A_log)
+    softplus((a W_fa) W_fb + dt_bias)``; the write strength ``beta =
+    beta_scale sigmoid(a W_b)`` (``beta_scale`` 2 allows ``I - beta k
+    k^T`` a negative eigenvalue); then per head RMSNorm of the read over
+    its ``delta_head_dim`` lanes, the gate ``sigmoid((a W_ga) W_gb +
+    b_g)`` lane by lane, ``W_o``.  A slot holds ``kernels.delta.
+    state_shapes``: the float32 state and ``conv_taps - 1`` rows of ``q |
+    k | v`` in the compute dtype (``state_spec``).
+
+    **The FFN**: ``routed_ffn``: ``route`` with sigmoid scores, a bias
+    that selects only, ``top_k`` of ``router_width``, weights normalised
+    over the selected (``norm_topk``) and scaled by ``route_scale``;
+    ``experts = (first, count)`` this chip's share; one shared expert of
+    the routed experts' form.  The stack tallies ``MOE_COUNTS``.
+
+    Parameter names: ``tok_emb.w [V, d]``, ``norm_f.scale``, ``lm_head.w
+    [d, V]``; per layer ``block{i}_norm1.scale`` (before the mixer),
+    ``norm2.scale`` (before the FFN), ``router.w [d, router_width]``,
+    ``router.bias``, ``shared_gate.w``, ``shared_up.w [d, e]``,
+    ``shared_down.w [e, d]``, ``experts_gate.w``, ``experts_up.w [count,
+    d, e]``, ``experts_down.w [count, e, d]``; a GQA layer ``att_q.w``
+    and ``att_gate.w [d, n_head head_dim]``, ``att_k.w`` and ``att_v.w
+    [d, kv_heads head_dim]``, ``att_out.w``; a delta layer ``delta_q.w``,
+    ``delta_k.w``, ``delta_v.w [d, H D]``, ``delta_conv.w [3 H D,
+    taps]``, ``delta_fa.w [d, r]``, ``delta_fb.w [r, H D]``,
+    ``delta_dt.b [H D]``, ``delta_A_log.w [H]``, ``delta_beta.w [d, H]``,
+    ``delta_ga.w [d, r]``, ``delta_gb.w [r, H D]``, ``delta_gb.b [H D]``
+    (the one bias), ``delta_onorm.scale [D]``, ``delta_out.w [H D, d]``.
+    """
+
+    name = "delta_moe"
+    dense_layers = 0
+
+    def __init__(self, n_layer, gqa_layers, n_head, kv_heads, head_dim,
+                 d_model, delta_heads, delta_head_dim, conv_taps,
+                 router_width, top_k, experts, route_scale=1.0,
+                 norm_topk=True, beta_scale=2.0, eps=1e-5):
+        super().__init__(n_layer, n_head, d_model, head_dim=head_dim)
+        gqa = tuple(sorted(int(i) for i in gqa_layers))
+        if not gqa or gqa[0] < 0 or gqa[-1] >= self.n_layer \
+                or len(set(gqa)) != len(gqa):
+            raise ValueError(f"{self.name}: gqa_layers {list(gqa_layers)} "
+                             f"must name at least one of the {self.n_layer} "
+                             f"layers, each once")
+        if n_head % kv_heads:
+            raise ValueError(f"{self.name}: kv_heads {kv_heads} must "
+                             f"divide n_head {n_head}")
+        self.gqa_layers = gqa
+        self._kv_heads = int(kv_heads)
+        self.delta_heads = int(delta_heads)
+        self.delta_head_dim = int(delta_head_dim)
+        self.conv_taps = int(conv_taps)
+        self.router_width, self.top_k = int(router_width), int(top_k)
+        self.experts = _check_share(self.name, experts, router_width, top_k)
+        self.route_scale, self.norm_topk = float(route_scale), bool(norm_topk)
+        self.beta_scale, self.eps = float(beta_scale), eps
+        self.plane_of = {i: n for n, i in enumerate(gqa)}
+        self.state_of = {i: n for n, i in enumerate(
+            i for i in range(self.n_layer) if i not in self.plane_of)}
+
+    @property
+    def kv_heads(self):
+        return self._kv_heads
+
+    @property
+    def planes(self):
+        return (None,) * len(self.plane_of)
+
+    @property
+    def rows_per_entry(self):
+        return self.n_head // self.kv_heads
+
+    @property
+    def delta_layers(self):
+        return len(self.state_of)
+
+    def pool_block_shape(self, block_tokens, dtype):
+        return (block_tokens, _paged.pool_rows(self.kv_heads, dtype),
+                self.head_dim)
+
+    def state_spec(self, dtype):
+        S, tail = _delta_rule.state_shapes(
+            self.delta_heads, self.delta_head_dim, self.conv_taps)
+        return (((S, jnp.float32), (tail, jnp.dtype(dtype))),
+                ) * self.delta_layers
+
+    def gauges(self, params):
+        dtype = params["tok_emb.w"].dtype
+        return dict(_moe_gauges(self, params), **{
+            "delta_layers": (self.delta_layers, "layers whose mixer is a "
+                             "gated delta rule (a state a slot, advanced "
+                             "in place; no K/V plane)"),
+            "delta_heads": (self.delta_heads, "heads of a delta layer, "
+                            "each a float32 state of delta_head_dim ** 2"),
+            "delta_state_bytes_per_slot": (
+                self.state_bytes_per_slot(dtype), "bytes one slot holds "
+                "beside the pool: the float32 state and the convolution's "
+                "tails of every delta layer (what ONE snapshot holds)"),
+        })
+
+    def check_params(self, params, max_len):
+        need = ["tok_emb.w", "norm_f.scale", "lm_head.w"]
+        every = ("norm1.scale", "norm2.scale", "router.w", "router.bias",
+                 "shared_down.w", "experts_down.w")
+        for layers, names in (
+                (self.plane_of, ("att_q.w", "att_k.w", "att_v.w",
+                                 "att_gate.w", "att_out.w")),
+                (self.state_of, ("delta_q.w", "delta_k.w", "delta_v.w",
+                                 "delta_conv.w", "delta_fa.w", "delta_fb.w",
+                                 "delta_dt.b", "delta_A_log.w",
+                                 "delta_beta.w", "delta_ga.w", "delta_gb.w",
+                                 "delta_gb.b", "delta_onorm.scale",
+                                 "delta_out.w"))):
+            if layers:
+                last = max(layers)
+                need += [f"block{last}_{n}" for n in every + names]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+        if self.state_of:
+            last = max(self.state_of)
+            want = self.delta_heads * self.delta_head_dim
+            got = np.shape(params[f"block{last}_delta_k.w"])[1]
+            if got != want:
+                raise ValueError(
+                    f"{self.name}: layer {last} projects keys to {got} "
+                    f"lanes; {self.delta_heads} heads of "
+                    f"{self.delta_head_dim} is {want}")
+        self._check_experts(params)
+
+    def embed(self, p, toks, pos):
+        with sublayer("embed"):
+            return p["tok_emb.w"][toks]      # no positional encoding
+
+    def _attention(self, w, a, planes, attend, plane):
+        f32 = jnp.float32
+        lead = a.shape[:-1]
+        kv = (*lead, self.kv_heads, self.head_dim)
+        with sublayer("attn.proj"):
+            q = self.heads(a @ w("att_q.w"))
+            k = (a @ w("att_k.w")).reshape(kv)
+            v = (a @ w("att_v.w")).reshape(kv)
+            gate = jax.nn.sigmoid((a @ w("att_gate.w")).astype(f32))
+        with sublayer("attn.core"):
+            ctx, planes = attend(planes, plane, 0, q, k, v,
+                                 group=self.rows_per_entry,
+                                 scale=self.head_dim ** -0.5)
+            # the head gate, lane by lane
+            o = (ctx.reshape(*lead, -1).astype(f32) * gate).astype(a.dtype)
+        with sublayer("attn.proj"):
+            return o @ w("att_out.w"), planes
+
+    def _delta(self, w, a, planes, attend, n_state):
+        f32 = jnp.float32
+        H, D = self.delta_heads, self.delta_head_dim
+        lead = a.shape[:-1]
+        with sublayer("mixer"):
+            decay = jnp.matmul(a @ w("delta_fa.w"), w("delta_fb.w"),
+                               preferred_element_type=f32)
+            g = -(jnp.repeat(jnp.exp(w("delta_A_log.w").astype(f32)), D)
+                  * jax.nn.softplus(decay + w("delta_dt.b").astype(f32)))
+            beta = self.beta_scale * jax.nn.sigmoid(jnp.matmul(
+                a, w("delta_beta.w"), preferred_element_type=f32))
+            o, planes = attend.advance(
+                planes, n_state, _delta_rule, a @ w("delta_q.w"),
+                a @ w("delta_k.w"), a @ w("delta_v.w"), g, beta,
+                conv_w=w("delta_conv.w"), heads=H)
+            # the read is normed a head, then gated lane by lane
+            o = o.reshape(*lead, H, D)
+            o = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), axis=-1, keepdims=True) + self.eps)
+            o = o * w("delta_onorm.scale").astype(f32)
+            gate = jax.nn.sigmoid(
+                jnp.matmul(a @ w("delta_ga.w"), w("delta_gb.w"),
+                           preferred_element_type=f32)
+                + w("delta_gb.b").astype(f32))
+            o = (o.reshape(*lead, H * D) * gate).astype(a.dtype)
+            return o @ w("delta_out.w"), planes
+
+    def stack(self, p, x, pos, planes, attend):
+        for i in range(self.n_layer):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            with sublayer("norm"):
+                a = _rms(x, w("norm1.scale"), self.eps)
+            if i in self.plane_of:
+                mix, planes = self._attention(w, a, planes, attend,
+                                              self.plane_of[i])
+            else:
+                mix, planes = self._delta(w, a, planes, attend,
+                                          self.state_of[i])
+            x = x + mix
+            with sublayer("norm"):
+                m = _rms(x, w("norm2.scale"), self.eps)
+            ff, counts = routed_ffn(w, m, attend, self.experts, self.top_k,
+                                    self.route_scale,
+                                    normalise=self.norm_topk)
+            attend.tally(counts)
+            x = x + ff
+        return x, planes
+
+    def head(self, p, x):
+        with sublayer("head"):
+            return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
                               p["lm_head.w"],
                               preferred_element_type=jnp.float32)

@@ -92,7 +92,7 @@ from ..kernels import sparse_attention as _sparse
 from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["paged_step_logits", "make_decode_chunk", "make_prefill",
-           "make_verify_window", "PREFILL_PIECE", "RUNG_STEP",
+           "make_verify_window", "make_state_copy", "PREFILL_PIECE", "RUNG_STEP",
            "STREAM_ROWS", "prefill_rungs", "piece_widths"]
 
 # the widest window one prefill call computes; a longer suffix is
@@ -520,6 +520,27 @@ def _copy_block(planes, src, dst, passes):
                 c = c.at[dst + i * per].set(c[src + i * per])
             out.append(c)
     return tuple(out)
+
+
+def make_state_copy(donate=True):
+    """Build the two device-side copies of a prefix hit over recurrent
+    state: ``fn(dst, src, to, of) -> dst'`` writes row ``of`` of every
+    array of ``src`` onto row ``to`` of the matching array of ``dst``
+    (both ``arch.state_spec``'s structure, any leading sizes), ``dst``
+    donated.  TAKING a snapshot is ``fn(snapshots, state, row, slot)``,
+    queued behind the prefill piece that ended on the block boundary;
+    RESTORING one is ``fn(state, snapshots, slot, row)``, queued before
+    the suffix's first piece, which then reads the slot's rows as a piece
+    that continues a prompt does."""
+
+    def copy(dst, src, to, of):
+        with sublayer("cache"):
+            return jax.tree.map(
+                lambda d, s: jax.lax.dynamic_update_index_in_dim(
+                    d, jax.lax.dynamic_index_in_dim(s, of, 0, False), to, 0),
+                dst, src)
+
+    return jax.jit(copy, donate_argnums=(0,) if donate else ())
 
 
 def make_verify_window(arch, k, donate=True):

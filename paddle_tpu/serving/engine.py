@@ -96,6 +96,15 @@ _Chunk = collections.namedtuple("_Chunk", "toks counts reqs sent_t")
 # finished requests whose accounts the engine keeps for the tail's
 # make-up: the histograms' reservoir
 TPOT_RING = 4096
+# the state snapshots' share of the prefix cache: their arrays may hold
+# one byte for every SNAPSHOT_SHARE bytes of K/V that cache_blocks blocks
+# hold.  A snapshot is what makes a cached chain worth reading again for
+# an architecture with recurrent state, and one is worth the whole chain
+# under it, so the share is set by what a chip can spare, not by what a
+# snapshot saves: an eighth is 11 snapshots of 13 MB beside four cached
+# heads of 65,536 tokens (1.2 GB of K/V), room for every head and for the
+# snapshots that prompts ending on a block boundary leave behind them
+SNAPSHOT_SHARE = 8
 # name prefixes of the gauges stats() publishes from that ring
 _TAIL_GAUGES = ("serving.tpot_p90_seconds", "serving.tpot_tail_")
 
@@ -227,8 +236,13 @@ class ServingEngine:
              lists them), the per-slot state it holds beside the pool,
              and the forward the compiled entry points run
              (docs/serving.md "Architectures").  One that holds
-             recurrent state refuses ``prefix_reuse=True`` and a draft.  ``ServingEngine(params, arch=Gpt2(L, h,
-             d, eps), ...)`` is the positional spelling exactly.
+             recurrent state refuses a draft; with ``prefix_reuse=True``
+             it is served from ONE state snapshot a cached head, taken
+             where a prefill piece ended on a block boundary
+             (docs/serving.md "Prefix reuse over recurrent state"),
+             unless it has no plane at all (no pool, no trie: refused).
+             ``ServingEngine(params, arch=Gpt2(L, h, d, eps), ...)`` is
+             the positional spelling exactly.
     max_len  per-slot logical KV capacity; every request needs
              ``len(prompt) + max_new_tokens <= max_len``.
     max_slots     concurrent sequences in the batched step.
@@ -333,14 +347,13 @@ class ServingEngine:
         self._rungs = _bd.prefill_rungs(self.min_bucket, self.max_len)
         arch.check_params(params, self.max_len)
         state_spec = arch.state_spec(self.compute_dtype)
-        if state_spec and prefix_reuse:
+        if state_spec and prefix_reuse and not arch.planes:
             raise ValueError(
-                f"prefix_reuse=True cannot serve {arch.name!r}: "
-                f"{len(state_spec)} of its layers hold recurrent state "
-                f"beside the pool, and a request that skips a cached "
-                f"prefix needs that state AT THE HIT'S BOUNDARY; the "
-                f"trie keeps K/V blocks only (no state snapshot at block "
-                f"boundaries).  Pass prefix_reuse=False")
+                f"prefix_reuse=True cannot serve {arch.name!r}: all "
+                f"{len(state_spec)} of its layers hold recurrent state and "
+                f"none a K/V plane, so there is no block pool and no trie "
+                f"whose node could name a state snapshot (a trie with no "
+                f"blocks is not built).  Pass prefix_reuse=False")
         if not arch.planes and cache_blocks:
             raise ValueError(
                 f"cache_blocks={cache_blocks} cannot serve {arch.name!r}: "
@@ -421,7 +434,27 @@ class ServingEngine:
             # table and no block to count; a slot is all a request
             # holds, and max_len bounds its positions and nothing else
             num_blocks, self.blocks_per_slot, self.kv_pool = 1, 0, None
-        self.prefix_trie = (_kv.PrefixTrie(self.kv_pool, self.cache_blocks)
+        # A PREFIX HIT OVER RECURRENT STATE.  An architecture that holds
+        # state beside its planes is served from snapshots: a prefill
+        # leaves ONE, at the last block boundary on which one of its
+        # pieces ends (a device-side copy of the slot's row of every
+        # state array into row r of the snapshot arrays, queued behind
+        # that piece); the trie node at that depth names r; a hit is cut
+        # back to the deepest matched node that has one and the slot's
+        # rows are written from it before the suffix's first piece.  The
+        # budget is in bytes and follows the cache's: the snapshot arrays
+        # may hold 1 / SNAPSHOT_SHARE of what cache_blocks blocks hold of
+        # K/V, in whole snapshots, at least one.
+        state_slot = arch.state_bytes_per_slot(self.compute_dtype)
+        self._state_slot_bytes = state_slot
+        self.snapshot_rows = 0
+        if prefix_reuse and state_spec:
+            self.snapshot_rows = max(1, int(
+                self.cache_blocks * self.block_tokens
+                * arch.kv_bytes_per_token(self.compute_dtype.itemsize)
+                // SNAPSHOT_SHARE // state_slot))
+        self.prefix_trie = (_kv.PrefixTrie(self.kv_pool, self.cache_blocks,
+                                           self.snapshot_rows)
                             if prefix_reuse else None)
         self.prefix_reuse = bool(prefix_reuse)
         # one array a plane of arch.planes (a layer, unless the
@@ -452,6 +485,13 @@ class ServingEngine:
         self._state = tuple(
             tuple(jnp.zeros((self.max_slots,) + tuple(shp), dt)
                   for shp, dt in layer) for layer in state_spec)
+        # the snapshots a trie node may name: the state arrays again, a
+        # row a snapshot
+        self._snap = tuple(
+            tuple(jnp.zeros((self.snapshot_rows,) + tuple(shp), dt)
+                  for shp, dt in layer)
+            for layer in (state_spec if self.snapshot_rows else ()))
+        self._snap_evicted = 0      # of the trie's evictions, published
         self._last = jnp.zeros((self.max_slots,), jnp.int32)
         self._pos = jnp.zeros((self.max_slots,), jnp.int32)
         # host-side block table: unused entries -> trash block 0.  With
@@ -566,7 +606,6 @@ class ServingEngine:
             help="paged-attention calls one token makes (a plane that "
                  "several layers read counts once a reader)").set(
                      self._reads_per_token)
-        state_slot = arch.state_bytes_per_slot(self.compute_dtype)
         self._reg.gauge(
             "serving.state_bytes_per_slot",
             help="bytes of recurrent state a slot holds beside the pool "
@@ -575,6 +614,22 @@ class ServingEngine:
             "serving.state_bytes",
             help="bytes the per-slot state arrays hold on the device",
         ).set(state_slot * self.max_slots)
+        if self.snapshot_rows:
+            self._reg.gauge(
+                "serving.state_snapshot_bytes",
+                help="bytes the state-snapshot arrays hold on the device: "
+                     "snapshot rows x state_bytes_per_slot (the budget: 1 / "
+                     "SNAPSHOT_SHARE of the K/V bytes of cache_blocks)",
+            ).set(state_slot * self.snapshot_rows)
+            # compiled here, not at the first hit: a hit's first is in
+            # the serving window
+            self._snap_take = self._aot_with_mem_telemetry(
+                _bd.make_state_copy(self._donate), "state_snapshot_take")
+            self._snap_restore = self._aot_with_mem_telemetry(
+                _bd.make_state_copy(self._donate), "state_snapshot_restore")
+            zero = np.int32(0)
+            self._snap_take.prepare(self._snap, self._state, zero, zero)
+            self._snap_restore.prepare(self._state, self._snap, zero, zero)
         self._reg.gauge(
             "serving.stack_passes",
             help="times the stack runs over the same weights for one "
@@ -641,7 +696,9 @@ class ServingEngine:
                  sink_planes=arch.sink_planes)
             if arch.sink_planes else
             dict(ssm_layers=arch.ssm_layers)
-            if arch.ssm_layers else {})
+            if arch.ssm_layers else
+            dict(delta_layers=arch.delta_layers)
+            if arch.delta_layers else {})
 
     @property
     def _tracer(self):
@@ -781,6 +838,14 @@ class ServingEngine:
                      "chunk's steps (a slot that finishes inside a chunk "
                      "rides it out on the device)").inc(
                          len(contexts) * self.arch.retention_layers
+                         * self.decode_chunk)
+        if self.arch.delta_layers:
+            self._reg.counter(
+                "serving.delta_slot_steps",
+                help="states a decode chunk's delta-rule calls read and "
+                     "wrote in place: live slots x delta layers x the "
+                     "chunk's steps").inc(
+                         len(contexts) * self.arch.delta_layers
                          * self.decode_chunk)
         if not self.arch.planes:
             return                  # no table entry, no K/V byte to count
@@ -1309,7 +1374,8 @@ class ServingEngine:
         return out
 
     def _run_pieces(self, fn_of, params, pk, pv, slot, row, pieces,
-                    cow=(0, 0), compile_only=False, tally=None):
+                    cow=(0, 0), compile_only=False, tally=None,
+                    snapshot=None):
         """Dispatch ``pieces`` in order through the prefill executables
         ``fn_of(width)``, each attending what the earlier ones wrote;
         the CoW fork rides in the first.  Nothing is fetched: returns
@@ -1318,7 +1384,9 @@ class ServingEngine:
         one a piece (two kinds of chain: the window planes' row moves
         from piece to piece).  ``compile_only`` builds what is not compiled yet
         and runs nothing.  ``tally`` (a list) receives what each piece's
-        stack counted, on the device too."""
+        stack counted, on the device too.  ``snapshot = (i, r)`` queues,
+        behind piece ``i``, the copy of the slot's state into row ``r`` of
+        the snapshot arrays."""
         first = None
         rows = row if isinstance(row, list) else [row] * len(pieces)
         for i, (w, toks, at, n) in enumerate(pieces):
@@ -1333,6 +1401,10 @@ class ServingEngine:
                  self._state, counts) = fn_of(w)(*args)
                 if tally is not None:
                     tally.append(counts)
+                if snapshot is not None and snapshot[0] == i:
+                    self._snap = self._snap_take(
+                        self._snap, self._state, np.int32(snapshot[1]),
+                        np.int32(slot))
         return pk, pv, first
 
     def _prefill_fn(self, bucket):
@@ -1381,6 +1453,17 @@ class ServingEngine:
                      "their slot went on: every later query's lower bound "
                      "had passed them").inc(freed)
 
+    def _count_snapshot_evictions(self):
+        """Publish what the trie dropped since the last call."""
+        gone = self.prefix_trie.snapshot_evictions - self._snap_evicted
+        if gone:
+            self._snap_evicted += gone
+            self._reg.counter(
+                "serving.state_snapshot_evictions",
+                help="snapshots dropped: with their evicted node, or for "
+                     "their row (least recently used, no live slot on "
+                     "the chain)").inc(gone)
+
     def _blocks_in_use(self):
         return (self.kv_pool.blocks_in_use
                 + (self.window_chains.pool.blocks_in_use
@@ -1407,6 +1490,8 @@ class ServingEngine:
             # blocks this slot shared with the trie are now trie-only:
             # re-apply the cache capacity budget
             self.prefix_trie.enforce_budget()
+            if self.snapshot_rows:
+                self._count_snapshot_evictions()
         if self.kv_pool is not None:
             self._reg.gauge("serving.blocks_in_use").set(
                 self._blocks_in_use())
@@ -1878,11 +1963,17 @@ class ServingEngine:
         p_len = req.prompt.shape[0]
         # an architecture with no plane holds no block: an empty row
         shared, priv, cow, hit = [], [], None, 0
+        hit_row = take = None
         cow_src = cow_dst = 0
         row = np.zeros(self.blocks_per_slot, np.int32)
         if pool is not None:
             n_total = -(-(p_len + req.max_new) // self.block_tokens)
-            if trie is not None:
+            if self.snapshot_rows:
+                # recurrent state: the hit is cut back to the deepest
+                # matched node that has a snapshot, whole blocks only
+                shared, hit_row, hit = trie.match_state(req.prompt,
+                                                        p_len - 1)
+            elif trie is not None:
                 shared, cow, hit = trie.match(req.prompt, p_len - 1)
             # hold every matched block across the eviction/alloc window so
             # LRU pressure can never free a chain we are about to attend
@@ -1915,6 +2006,28 @@ class ServingEngine:
         bucket = sum(w for w, *_ in pieces)
         req.bucket = bucket
         req.prefix_hit = start
+        if self.snapshot_rows:
+            # ONE snapshot a prefill: at the last block boundary on which
+            # one of its pieces ends (none: it leaves none)
+            ends = [(i, at + n) for i, (_, _, at, n) in enumerate(pieces)
+                    if (at + n) % self.block_tokens == 0]
+            to = trie.reserve_snapshot() if ends else None
+            if to is not None:
+                take, take_depth = (ends[-1][0], to), ends[-1][1]
+            if hit_row is not None:
+                # the slot's rows are the snapshot's before the suffix's
+                # first piece, which does not start a prompt (start > 0)
+                self._state = self._snap_restore(
+                    self._state, self._snap, np.int32(slot),
+                    np.int32(hit_row))
+                self._reg.counter(
+                    "serving.state_snapshot_hits",
+                    help="admissions that started from a state snapshot "
+                         "(the hit cut back to its node)").inc()
+                self._reg.counter(
+                    "serving.state_restored_bytes",
+                    help="bytes of state copied from snapshots into "
+                         "slots' rows").inc(self._state_slot_bytes)
         if self._windowed:
             # a row a piece: before each piece the window chain gives
             # back what its first row cannot see and allocates what its
@@ -1942,11 +2055,14 @@ class ServingEngine:
                         plane_reads=self._reads_per_token,
                         moe_layers=self.arch.moe_layers,
                         experts_held=self.arch.experts_held,
+                        **(dict(state_hit_tokens=start)
+                           if self.snapshot_rows else {}),
                         **self._form_attrs) as sp:
             tally = []
             self._pk, self._pv, first = self._run_pieces(
                 self._prefill_fn, self._p, self._pk, self._pv, slot,
-                row_d, pieces, cow=(cow_src, cow_dst), tally=tally)
+                row_d, pieces, cow=(cow_src, cow_dst), tally=tally,
+                snapshot=take)
             # the pieces are queued BEHIND a chunk in flight (the pools
             # they take are its outputs).  Its tokens are read while the
             # pieces run, not after them: a request that ends in it is
@@ -1997,6 +2113,14 @@ class ServingEngine:
             # become reusable by the next identical prefix)
             trie.insert(req.prompt, [int(b) for b in row[:p_len
                                                          // self.block_tokens]])
+            if take is not None and trie.attach_snapshot(
+                    req.prompt, take_depth, take[1]):
+                self._reg.counter(
+                    "serving.state_snapshots_taken",
+                    help="state snapshots a trie node came to name (one a "
+                         "prefill at most: the last block boundary on "
+                         "which one of its pieces ended)").inc()
+            self._count_snapshot_evictions()
         req.prefill_t0, req.prefill_t1 = t_p0, now
         self.predictor.observe_prefill(bucket, now - t_p0)
         req.first_token_t = now
@@ -2023,6 +2147,13 @@ class ServingEngine:
                 "serving.prefill_pieces", width=w,
                 help="prefill window calls dispatched, by width (an "
                      "admission is one or more pieces)").inc()
+            if self.arch.delta_layers:
+                self._reg.counter(
+                    "serving.delta_piece_rows", width=w,
+                    help="rows of the prefill pieces' delta-rule calls, by "
+                         "the piece's width (ONE call a layer a piece, "
+                         "padding included: a call computes its width), a "
+                         "layer").inc(w)
             if self.arch.retention_layers:
                 for i, rows in enumerate(_retention.chunk_rows(w)):
                     self._reg.counter(

@@ -219,7 +219,8 @@ class _Node:
     physical block id, children keyed by their chunk tuple, and the LRU
     clock."""
 
-    __slots__ = ("chunk", "block", "children", "parent", "last_used")
+    __slots__ = ("chunk", "block", "children", "parent", "last_used",
+                 "snapshot")
 
     def __init__(self, chunk, block, parent):
         self.chunk = chunk          # tuple of block_tokens ints
@@ -227,6 +228,7 @@ class _Node:
         self.children = {}          # chunk tuple -> _Node
         self.parent = parent        # _Node or the trie root sentinel
         self.last_used = 0
+        self.snapshot = None        # row of the snapshot arrays, or None
 
 
 class PrefixTrie:
@@ -239,14 +241,27 @@ class PrefixTrie:
     ``insert`` registers a finished prefill's full prompt blocks.
     ``evict_lru``/``enforce_budget`` drop least-recently-used
     UNREFERENCED leaves (refcount 1 — held by nobody but the trie);
-    chains shared with live slots are never evicted."""
+    chains shared with live slots are never evicted.
 
-    def __init__(self, pool, capacity_blocks):
+    ``snapshot_rows`` (0: none) is the number of rows the engine's
+    state-snapshot arrays have: the trie of an architecture that holds
+    recurrent state beside its planes.  ``reserve_snapshot`` hands out a
+    row before a prefill runs, ``attach_snapshot`` gives it to the node at
+    the snapshot's depth once the prompt's blocks are inserted, and
+    ``match_state`` / ``peek_hit`` cut every hit back to the deepest
+    matched node that has one (no copy-on-write fork: K/V past that node
+    is recomputed into private blocks)."""
+
+    def __init__(self, pool, capacity_blocks, snapshot_rows=0):
         self.pool = pool
         self.capacity_blocks = int(capacity_blocks)
         self._root = _Node(None, None, None)
         self._clock = 0
         self._nodes = 0
+        self.snapshot_rows = int(snapshot_rows)
+        self._free_rows = list(range(self.snapshot_rows - 1, -1, -1))
+        self._snapshots = {}        # row -> the node that names it
+        self.snapshot_evictions = 0
 
     def __len__(self):
         return self._nodes
@@ -257,7 +272,7 @@ class PrefixTrie:
 
     @staticmethod
     def _chunks(tokens, block_tokens):
-        toks = [int(t) for t in tokens]
+        toks = np.asarray(tokens).tolist()
         return [tuple(toks[i:i + block_tokens])
                 for i in range(0, len(toks) - block_tokens + 1,
                                block_tokens)]
@@ -314,22 +329,86 @@ class PrefixTrie:
             cow = (best.block, best_j)
         return shared, cow, len(shared) * B + best_j
 
+    def _path(self, tokens, limit):
+        """The nodes of the exact full-chunk matches of ``tokens`` within
+        ``limit`` tokens, root first."""
+        B = self.pool.block_tokens
+        # one conversion for the whole prompt: a comprehension over 65,536
+        # NumPy scalars is milliseconds of the driver's time an admission
+        toks = np.asarray(tokens).tolist()
+        node, path, i = self._root, [], 0
+        while i + B <= limit and i + B <= len(toks):
+            node = node.children.get(tuple(toks[i:i + B]))
+            if node is None:
+                break
+            path.append(node)
+            i += B
+        return path
+
+    def _to_snapshot(self, path):
+        """``path`` cut back to its deepest node that has a snapshot."""
+        while path and path[-1].snapshot is None:
+            path.pop()
+        return path
+
+    def match_state(self, tokens, limit):
+        """:meth:`match` for an architecture that holds recurrent state:
+        the hit is cut back to the deepest matched node that HAS a
+        snapshot (none: no hit), whole blocks only.  Returns
+        ``(shared_bids, snapshot_row, hit_tokens)``; touches the nodes of
+        the hit (LRU)."""
+        path = self._to_snapshot(self._path(tokens, limit))
+        now = self._tick()
+        for node in path:
+            node.last_used = now
+        return ([n.block for n in path],
+                path[-1].snapshot if path else None,
+                len(path) * self.pool.block_tokens)
+
+    def reserve_snapshot(self):
+        """A row of the snapshot arrays for a snapshot about to be taken:
+        a free one, else the row of the least recently used snapshot whose
+        node no live slot references (refcount 1), which loses it; ``None``
+        where every snapshot's chain is in use."""
+        if not self._free_rows:
+            idle = [n for n in self._snapshots.values()
+                    if self.pool.refcount(n.block) == 1]
+            if not idle:
+                return None
+            self._drop_snapshot(min(idle, key=lambda n: n.last_used))
+        return self._free_rows.pop()
+
+    def attach_snapshot(self, tokens, depth_tokens, row):
+        """Row ``row`` holds the state after ``tokens[:depth_tokens]`` (a
+        whole number of blocks): the node at that depth names it.  Where
+        the node is not there (evicted already) or has a snapshot, the row
+        goes back; returns whether it was attached."""
+        path = self._path(tokens, depth_tokens)
+        if (len(path) * self.pool.block_tokens != depth_tokens or not path
+                or path[-1].snapshot is not None):
+            self._free_rows.append(row)
+            return False
+        path[-1].snapshot = row
+        self._snapshots[row] = path[-1]
+        return True
+
+    def _drop_snapshot(self, node):
+        self._free_rows.append(node.snapshot)
+        del self._snapshots[node.snapshot]
+        node.snapshot = None
+        self.snapshot_evictions += 1
+
     def peek_hit(self, tokens, limit):
         """Prompt tokens a :meth:`match` would serve from the cache,
         WITHOUT touching LRU clocks or returning block references — the
         scheduler's prediction probe (estimating a queued request's
         prefill must not distort eviction order)."""
         B = self.pool.block_tokens
-        toks = [int(t) for t in tokens]
-        node = self._root
-        i = 0
-        while i + B <= limit and i + B <= len(toks):
-            child = node.children.get(tuple(toks[i:i + B]))
-            if child is None:
-                break
-            node = child
-            i += B
-        tail = toks[i:min(len(toks), i + B)]
+        path = self._path(tokens, limit)
+        if self.snapshot_rows:
+            return len(self._to_snapshot(path)) * B
+        node, i = (path[-1] if path else self._root), len(path) * B
+        tail = [int(t) for t in tokens[i:i + B]]
         room = limit - i
         best_j = 0
         if tail and room > 0:
@@ -384,6 +463,8 @@ class PrefixTrie:
         return out
 
     def _evict_node(self, node):
+        if node.snapshot is not None:
+            self._drop_snapshot(node)   # a snapshot dies with its node
         del node.parent.children[node.chunk]
         self._nodes -= 1
         self.pool.deref(node.block)  # -> free list (refcount was 1)
@@ -437,3 +518,5 @@ class PrefixTrie:
             self.pool.deref(nd.block)
         self._root.children.clear()
         self._nodes = 0
+        self._free_rows = list(range(self.snapshot_rows - 1, -1, -1))
+        self._snapshots = {}

@@ -13,7 +13,10 @@ default): run on a ``git archive`` of a parent commit it gives the
 hashes ``tests/test_lowered_programs.py`` pins (PR 46: the commit before
 planes stated their own shape and chains came in two kinds; PR 51 added
 ``SinkWindowMoE``'s, taken on its parent, when ``routed_ffn`` took the
-expert's form and ``_Cache.retain`` became ``advance``)."""
+expert's form and ``_Cache.retain`` became ``advance``; PR 57 added
+``MambaMoE``'s and ``SparseLatentMoE``'s, taken on its parent, and
+``DeltaMoE``'s own, taken on its tree: a family the root does not have
+is left out)."""
 
 import hashlib
 import importlib.util
@@ -25,7 +28,10 @@ FAMILIES = {"gpt2": "test_rehearsal", "ouro": "test_ouro_family",
             "sambay": "test_sambay_family", "gated_moe":
             "test_gated_moe_family", "latent_moe": "test_latent_moe_family",
             "retention": "test_retention_family",
-            "sink_window_moe": "test_sink_window_moe_family"}
+            "sink_window_moe": "test_sink_window_moe_family",
+            "ssm_moe": "test_ssm_moe_family",
+            "sparse_latent_moe": "test_sparse_latent_moe_family",
+            "delta_moe": "test_delta_moe_family"}
 GEOMETRY = {"max_len": 64, "max_slots": 2, "block_tokens": 8,
             "cache_blocks": 0, "prefix_reuse": False}
 ENTRIES = ("decode_chunk_4", "prefill_8", "prefill_32")
@@ -53,6 +59,9 @@ def programs(root, only=None):
     for name, module in FAMILIES.items():
         if only and name != only:
             continue
+        if not os.path.isfile(os.path.join(root, "chipbench", "tests",
+                                           module + ".py")):
+            continue                # a family this root does not have yet
         cfg = _tiny(root, module)
         cfg.setdefault("family", name)
         family = families.of(cfg, "serve")

@@ -1,4 +1,6 @@
-"""The seven architectures that are not ``MambaMoE`` lower to the
+"""The nine older architectures lower to the programs they lowered to
+before PR 57 (the last three families of ``PARENT`` say when theirs were
+taken); the first seven of them lower to the
 programs they lowered to before planes stated their own shape, chains
 came in two kinds, the paged kernels took a sink and value lanes of
 their own, and ``routed_ffn`` took the shared expert by statement: the
@@ -57,6 +59,25 @@ PARENT = {
         "decode_chunk_4": "9d7913e1cd9270d3",
         "prefill_8": "5bf10dc93f438d22",
         "prefill_32": "7d64388882701c16"
+    },
+    # PR 57 (a prefix hit over recurrent state, a tenth architecture):
+    # these two on its parent, where MambaMoE and SparseLatentMoE lower
+    # to the same text as on its tree
+    "ssm_moe": {
+        "decode_chunk_4": "a042daf520d62106",
+        "prefill_8": "0854d83fbaa33533",
+        "prefill_32": "80620ef582bea7c4"
+    },
+    "sparse_latent_moe": {
+        "decode_chunk_4": "6733abda6836e104",
+        "prefill_8": "287d8ba9d93ccdba",
+        "prefill_32": "dd3dfc63810476d8"
+    },
+    # DeltaMoE's own, on PR 57's tree: what a later PR is held to
+    "delta_moe": {
+        "decode_chunk_4": "646ca35220fb9a7c",
+        "prefill_8": "6d8aaf3d82c9807d",
+        "prefill_32": "cf6b7f589af080ca"
     }
 }
 # PR 52 gave the loop of two rows or more G table entries an iteration

@@ -6,7 +6,7 @@ memory units) on the serving path, against its plain reference
 decode through the cache, two slots of different lengths, a slot
 released and admitted again; the comparison bites on each line of the
 mathematics left out and on a lower matmul precision; the engine refuses
-what recurrent state makes impossible; the published sizes count 3,852M
+a draft, which recurrent state makes impossible; the published sizes count 3,852M
 parameters.
 
 Tiny sizes (d 64, 8 layers so that all five mixers occur, 4 heads over 2
@@ -352,18 +352,16 @@ def test_gauges_spans_and_the_streamed_bytes(params, monkeypatch):
         "serving.paged_entries_live"]
 
 
-@pytest.mark.parametrize("refused", ["prefix_reuse", "draft"])
+@pytest.mark.parametrize("refused", ["draft"])
 def test_what_recurrent_state_makes_impossible_is_refused(params, refused):
+    """A draft: the state cannot be rolled back.  (``prefix_reuse`` was
+    refused too until a trie node could name a state snapshot: that case
+    is ``test_state_prefix_hit.py``'s now.)"""
     p = params["float32"]
-    if refused == "prefix_reuse":
-        with pytest.raises(ValueError, match="prefix_reuse=True cannot serve "
-                           "'sambay'.*state AT THE HIT'S BOUNDARY"):
-            ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B)
-    else:
-        with pytest.raises(ValueError, match="speculative decoding cannot "
-                           "serve 'sambay'.*rolled back"):
-            ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
-                          prefix_reuse=False, draft_params=dict(p))
+    with pytest.raises(ValueError, match="speculative decoding cannot "
+                       "serve 'sambay'.*rolled back"):
+        ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
+                      prefix_reuse=False, draft_params=dict(p))
 
 
 def test_the_published_sizes_count_3852m_parameters():

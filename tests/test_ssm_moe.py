@@ -214,9 +214,12 @@ def test_state_planes_gauges_and_counters(served):
 
 def test_refusals_say_why():
     params = make(0)
-    with pytest.raises(ValueError, match="hold recurrent state"):
-        pt.serving.ServingEngine(params, arch=arch(), max_len=64,
-                                 prefix_reuse=True)
+    # prefix_reuse=True is served from state snapshots since PR 57
+    # (tests/test_state_prefix_hit.py); a draft still is not
+    from paddle_tpu.serving.speculative import validate_draft
+
+    with pytest.raises(ValueError, match="rolled back"):
+        validate_draft(params, params, arch(), 64)
     with pytest.raises(ValueError, match="pattern characters"):
         MambaMoE("MEX", HEADS, KV, DH, D, H, P, G, N, TAPS, WIDTH, TOP_K,
                  (0, 4))
