@@ -41,7 +41,10 @@ every plane: 18 in ``think_decode``.
 12 slots x 288 entries of 32 x 640 lanes, 16 query rows a cached row) at
 one, two, four, eight and sixteen table entries an iteration, against 32 x 576 x
 2 B / 819 GB/s = 0.045 us a live block; and a 128-row prefill piece over
-an 8,300-token chain BOTH ways: absorbed (the queries against the
+an 8,300-token chain BOTH ways (after the rows ``latent_shared_*``: 1, 3
+and 6 live slots over 1, 2 and 4 documents' heads of 256 blocks, the
+kernel told which chains start alike beside the same call told nothing:
+us a call and a live slot, the bytes fetched): absorbed (the queries against the
 gathered latents, what ``serving.arch.LatentMoE`` runs) and expanded
 (the gathered latents through ``W_kvb`` to per-head K and V first).
 
@@ -150,6 +153,12 @@ LATENT = {f"latent_doc_qa_decode_{n}_a_copy": dict(
     S=12, W=1, NB=288, blocks=4609, lanes=640, heads=16, value_lanes=512,
     live=10, ctx=(8300, 9100), entries=n, config="deepseek-v2-lite")
     for n in (1, 2, 4, 8, 16)}
+# the same plane where slots share a document's head (the cell's four
+# heads of 8,192 tokens = 256 blocks, a question of 100-900 tokens after
+# it): `live` slots over `docs` documents, slot i on document i % docs
+SHARED = {f"latent_shared_{live}_live_{docs}_docs": dict(
+    LATENT["latent_doc_qa_decode_8_a_copy"], live=live, docs=docs)
+    for live in (1, 3, 6) for docs in (1, 2, 4) if docs == 1 or live > 1}
 PIECES = {"latent_doc_qa_piece_absorbed": "absorbed",
           "latent_doc_qa_piece_expanded": "expanded"}
 SCALE = 0.11472
@@ -314,6 +323,27 @@ def measure(name, calls, peak, seed):
         "rel_err_vs_xla_ref": err}
 
 
+def _latent_plane(g, rng):
+    """A latent plane of geometry ``g`` with nobody live yet: queries and
+    pool (the values a position holds, zeros in the lanes past them), an
+    empty table, positions at -1, the free blocks in a seeded order."""
+    import numpy as np
+
+    from chipbench import latent_bytes
+
+    S, B, L = g["S"], BLOCK_TOKENS, g["lanes"]
+    values = latent_bytes.sizes(_config(g["config"]))["values_per_position"]
+    pool = np.zeros((g["blocks"], B, L), np.float32)
+    pool[..., :values] = rng.standard_normal(
+        (g["blocks"], B, values), np.float32) * 0.5
+    q = np.zeros((S, g["W"], g["heads"], L), np.float32)
+    q[..., :values] = rng.standard_normal(
+        (S, g["W"], g["heads"], values), np.float32) * 0.5
+    return (q, pool, np.zeros((S, g["NB"]), np.int32),
+            np.full((S, g["W"]), -1, np.int32),
+            rng.permutation(np.arange(1, g["blocks"])))
+
+
 def measure_latent(name, calls, peak, seed):
     import jax
     import jax.numpy as jnp
@@ -325,19 +355,10 @@ def measure_latent(name, calls, peak, seed):
         latent_attention_pallas, paged_attention_ref)
 
     g = LATENT[name]
-    S, NB, B, L = g["S"], g["NB"], BLOCK_TOKENS, g["lanes"]
+    S, B = g["S"], BLOCK_TOKENS
     cfg = _config(g["config"])
-    values = latent_bytes.sizes(cfg)["values_per_position"]
     rng = np.random.default_rng(seed)
-    pool = np.zeros((g["blocks"], B, L), np.float32)
-    pool[..., :values] = rng.standard_normal(
-        (g["blocks"], B, values), np.float32) * 0.5
-    q = np.zeros((S, g["W"], g["heads"], L), np.float32)
-    q[..., :values] = rng.standard_normal(
-        (S, g["W"], g["heads"], values), np.float32) * 0.5
-    table = np.zeros((S, NB), np.int32)
-    pos = np.full((S, g["W"]), -1, np.int32)
-    free = rng.permutation(np.arange(1, g["blocks"]))
+    q, pool, table, pos, free = _latent_plane(g, rng)
     contexts = []
     for s in rng.choice(S, g["live"], replace=False):
         ctx = int(rng.integers(g["ctx"][0], g["ctx"][1] + 1))
@@ -367,6 +388,63 @@ def measure_latent(name, calls, peak, seed):
         "us_a_call": us, "us_a_live_block": us / live_blocks,
         "roofline_pct": 100.0 * least * 1e6 / us,
         "rel_err_vs_xla_ref": err}
+
+
+def measure_shared(name, calls, seed):
+    """The latent kernel at ``live`` slots over ``docs`` documents' heads
+    (``SHARED``), told which chains start alike (``shared_runs``) beside
+    the same call told nothing: us a call and a live slot each way, the
+    bytes each fetches, and that the two answer alike."""
+    import jax
+    import jax.numpy as jnp
+
+    import numpy as np
+
+    from chipbench import latent_bytes
+    from paddle_tpu.kernels.paged_attention import (
+        latent_attention_pallas, shared_runs)
+
+    g = SHARED[name]
+    S, B = g["S"], BLOCK_TOKENS
+    values = latent_bytes.sizes(_config(g["config"]))["values_per_position"]
+    rng = np.random.default_rng(seed)
+    q, pool, table, pos, free = _latent_plane(g, rng)
+    head = 8192 // B
+    heads, free = free[:g["docs"] * head].reshape(g["docs"], head), free[
+        g["docs"] * head:]
+    for i, s in enumerate(sorted(rng.choice(S, g["live"], replace=False))):
+        ctx = int(rng.integers(g["ctx"][0], g["ctx"][1] + 1))
+        n = (ctx - 1) // B + 1
+        table[s, :head] = heads[i % g["docs"]]
+        table[s, head:n], free = free[:n - head], free[n - head:]
+        pos[s] = ctx - 1
+    runs = shared_runs(table, np.maximum(pos[:, 0], 0) // B, g["heads"])
+    live_blocks = int(np.sum(pos[:, 0] // B + 1))
+    fetched = live_blocks - int(np.sum(
+        runs[:, 0] * np.maximum(runs[:, 1] - 1, 0)))
+    args = (jnp.asarray(q, jnp.bfloat16), jnp.asarray(pool, jnp.bfloat16),
+            jnp.asarray(table), jnp.asarray(pos))
+    how = dict(scale=SCALE, out_dtype=jnp.float32, interpret=False)
+    alone = jax.jit(lambda *a: latent_attention_pallas(
+        *a, g["value_lanes"], **how))
+    told = jax.jit(lambda *a: latent_attention_pallas(
+        *a[:4], g["value_lanes"], shared=a[4], **how))
+    us_alone = _timed(alone, args, calls)
+    us_told = _timed(told, args + (jnp.asarray(runs),), calls)
+    live = pos[:, 0] >= 0
+    want = np.asarray(alone(*args))[live]
+    got = np.asarray(told(*args, jnp.asarray(runs)))[live]
+    block_bytes = B * values * 2
+    return {"geometry": name, **{k: g[k] for k in (
+        "S", "NB", "lanes", "heads", "live", "docs")},
+        "runs": [[int(r[0]), int(r[1])] for r in runs if r[1]],
+        "live_blocks": live_blocks, "fetched_blocks": fetched,
+        "us_a_call_alone": us_alone, "us_a_call_told": us_told,
+        "us_a_live_slot_alone": us_alone / g["live"],
+        "us_a_live_slot_told": us_told / g["live"],
+        "bytes_alone": live_blocks * block_bytes,
+        "bytes_told": fetched * block_bytes,
+        "max_abs_diff": float(np.abs(got - want).max())}
 
 
 def measure_mixed(name, calls, peak, seed):
@@ -746,6 +824,8 @@ def _measure(name, args, peak):
         return measure_write(name, args.calls, args.seed)
     if name in LATENT:
         return measure_latent(name, args.calls, peak, args.seed)
+    if name in SHARED:
+        return measure_shared(name, args.calls, args.seed)
     if name in MIXED:
         return measure_mixed(name, args.calls, peak, args.seed)
     if name in PIECES:
@@ -788,7 +868,7 @@ def main():
         names = [n for n in names if n != "rungs"] + list(RUNGS)
     if "latent" in names:
         names = ([n for n in names if n != "latent"] + list(LATENT)
-                 + list(PIECES))
+                 + list(SHARED) + list(PIECES))
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     forced = [int(n) for n in args.entries.split(",") if n]
     with open(args.out, "a") as f:

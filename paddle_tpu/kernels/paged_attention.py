@@ -106,7 +106,12 @@ sibling Mosaic kernel ``latent_attention_pallas`` (HLO name
 ``paged_latent_attention``): the loop below with the head mask gone and
 ``LATENT_BLOCKS`` table entries an iteration, from the group of the
 window's first live entry.  A plane whose rows are SELECTED by an
-indexer is ``kernels/sparse_attention.py``'s.
+indexer is ``kernels/sparse_attention.py``'s.  Where a prefix trie hands
+several live slots one document's blocks, the host says so
+(``shared_runs``: which slots' chains START alike, ``shared=`` beside
+the table, data) and the Mosaic kernel fetches such a run ONCE for the
+slots that share it; every other spelling ignores it, and the result is
+the same.
 
 Numerics conventions match the flash kernels (f32 scores via
 ``preferred_element_type``, ``NEG_INF`` masking, f32 ``(m, l, acc)``
@@ -155,6 +160,7 @@ Backends:
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.pallas_attention import LSE_LANES
 from .registry import register_kernel, resolve
@@ -165,7 +171,7 @@ __all__ = ["attend", "CHAIN_SCORE_BYTES", "DENSE_WINDOW", "DENSE_SCORE_BYTES",
            "window_entries",
            "entries_per_iteration", "latent_attention_pallas", "latent_lanes",
            "loop_iterations", "paged_attention_ref", "paged_attention_pallas",
-           "pool_rows", "softmax_updates", "write"]
+           "pool_rows", "shared_runs", "softmax_updates", "write"]
 
 # From this window width up a window gathers its slot's chain once and
 # attends it densely instead of streaming blocks.  W rows then share one
@@ -232,9 +238,16 @@ LOOP_VMEM_BYTES = 12 << 20
 # entries past a chain's end where eight fetch seven.
 LATENT_BLOCKS = 8
 
+# Query rows ONE walk of the latent kernel stacks where several slots
+# share a run of table entries (``shared_runs``): the slots' rows go
+# against one buffer in the same two MXU passes, and the float32 scores,
+# the three pieces of ``p`` and the weighed values of a stack are VMEM
+# temporaries (at 256 rows of 512 value lanes some 4 MB).
+STACK_ROWS = 256
+
 
 def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
-           out_dtype=None, value_lanes=None, sink=None):
+           out_dtype=None, value_lanes=None, sink=None, shared=None):
     """One layer's attention THROUGH the block table, the one call the
     serving step makes: ``q [S, W, h, dh]``, ``pos [S, W]`` ->
     ``[S, W, h, dh]``; ``group``, ``window``, ``scale`` and
@@ -259,10 +272,15 @@ def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
     by the query's OWN width unless ``scale`` says otherwise, and the
     context has the V array's lanes.  ``sink [h]`` float32 is one more
     logit a query head in every row's softmax, which takes mass and adds
-    no value (module docstring)."""
+    no value (module docstring).  ``shared`` (``shared_runs``'s array, or
+    ``None``: nothing is traced) tells a LATENT plane's streaming call
+    which slots' chains start alike; whatever it says, the result is the
+    call's without it."""
     how = dict(group=group, window=window, scale=scale, out_dtype=out_dtype)
     if pool_v is None:
         how["value_lanes"] = value_lanes
+        if shared is not None and q.shape[1] < DENSE_WINDOW:
+            how["shared"] = shared
     elif q.shape[-1] < pool_k.shape[-1]:
         if scale is None:
             how["scale"] = 1.0 / float(q.shape[-1]) ** 0.5
@@ -644,7 +662,8 @@ def _latent_plane(q, pool, group, value_lanes):
 
 def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
                         interpret=None, group=1, window=None, scale=None,
-                        out_dtype=None, value_lanes=None, sink=None):
+                        out_dtype=None, value_lanes=None, sink=None,
+                        shared=None):
     """The oracle spelling: ``lax.scan`` over the block chain with
     online-softmax carry — per step only ``block_step`` physical blocks
     are gathered (``[S, block_step*B, h, dh]``), never the ``T``-wide
@@ -654,8 +673,10 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
     with one cached row for all the heads, its values that row's first
     ``value_lanes`` lanes.  A ``sink`` is where the online softmax
     STARTS: a row's maximum at its head's sink logit and its sum at 1,
-    as if it had seen one key with that score and a zero value."""
-    del interpret
+    as if it had seen one key with that score and a zero value.
+    ``shared`` says what a kernel may fetch once and changes no result:
+    ignored here, where every slot gathers its own chain."""
+    del interpret, shared
     latent = pool_v is None
     if sink is not None:
         if latent:
@@ -801,7 +822,8 @@ def _weigh(p, vb):
 
 def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
                            interpret=None, group=1, window=None, scale=None,
-                           out_dtype=None, value_lanes=None, sink=None):
+                           out_dtype=None, value_lanes=None, sink=None,
+                           shared=None):
     """The Mosaic kernel: it visits the LIVE entries of each slot's chain
     and no others.  The block TABLE and the query POSITIONS are the
     scalar-prefetch arguments (SMEM).  Slot ``s`` has ``n_s = clip(max_w
@@ -901,7 +923,10 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
             q, pool_k, table, pos,
             _latent_plane(q, pool_k, group, value_lanes),
             scale=scale, out_dtype=out_dtype, interpret=interpret,
-            window=window)
+            window=window, shared=shared)
+    if shared is not None:
+        raise ValueError("paged_attention: only a latent plane's call "
+                         "takes shared runs")
     if sink is not None:
         sink = _fold_sink(sink, group, pool_k.shape[2], q.shape[1])
     width = q.shape[1]              # positions, before a group is folded in
@@ -1248,9 +1273,86 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     return unfold(ctx)
 
 
+# ``latent_attention_pallas``'s calls that were told of shared runs, by
+# everything their kernel's body reads: one jitted function a geometry
+_TOLD_CALLS = {}
+
+
+def _latent_entries(blocks, NB):
+    """Table entries an iteration of the latent kernel's loop takes on a
+    table of ``NB``: ``blocks``, ``LATENT_BLOCKS`` if None."""
+    return max(1, min(int(LATENT_BLOCKS if blocks is None else blocks), NB))
+
+
+def _stack_slots(slots, rows):
+    """Slots whose rows ONE walk of the latent kernel may stack: what
+    ``STACK_ROWS`` holds of ``rows`` query rows a slot, two at least, no
+    more than the call has."""
+    return min(int(slots), max(2, STACK_ROWS // int(rows)))
+
+
+def _stack_widths(slots, rows):
+    """The widths (in slots) a shared run's walk is compiled at: 2, 3 and
+    their doublings under ``_stack_slots``, and that; a run of ``n``
+    slots takes the narrowest that holds them (the MXU's passes grow
+    with the stacked rows: alone on the chip a run of 256 entries costs
+    17 us walked by one slot, 22 by two stacked, 30 by four, 44 by
+    eight; PERF.md, PR 58)."""
+    cap = _stack_slots(slots, rows)
+    return sorted({w << i for w in (2, 3) for i in range(cap.bit_length())
+                   if w << i < cap} | {cap})
+
+
+def shared_runs(table, whole, rows, blocks=None):
+    """Which live slots' chains START alike, for the latent kernel to
+    fetch such a run once (``latent_attention_pallas``'s ``shared``), from
+    the host's table: ``table [S, NB]`` (NumPy), ``whole [S]`` the entries
+    of each slot's chain that lie WHOLE under its position (0 for a slot
+    that is not live; a block two tables name is immutable, and only
+    entries every member has behind it are shared), ``rows`` the query
+    rows a slot sends (``_stack_slots`` bounds a run's members).
+
+    Returns ``[S, 2 + S]`` int32, a row a slot: ``[n, members, ids..]``.
+    ``n`` is the run's length in table entries, a multiple of ``blocks``
+    (the kernel's entries an iteration), for EVERY slot of a run and 0
+    for a slot in none; ``members`` is the count of the run's slots in
+    the row of its LEADER (its lowest slot, whose grid step walks the run
+    before any member's own) and 0 elsewhere, ``ids`` those slots
+    ascending, the leader first.  Greedy from the lowest pending slot:
+    of the others, by how far each agrees with it, the ``k`` that agree
+    longest share their shortest agreement, and the ``k`` is taken that
+    saves most fetches (``n x k``); who is left over is grouped next.  A
+    copy-on-write fork ends the agreement at the forked entry."""
+    table, whole = np.asarray(table), np.asarray(whole)
+    S, NB = table.shape
+    G = _latent_entries(blocks, NB)
+    cap = _stack_slots(S, rows)
+    out = np.zeros((S, 2 + S), np.int32)
+    pending = [s for s in range(S) if whole[s] >= G]
+    while len(pending) > 1:
+        lead, rest = pending[0], np.asarray(pending[1:])
+        differ = table[rest] != table[lead]
+        agree = np.where(differ.any(1), differ.argmax(1), NB)
+        agree = np.minimum(agree, np.minimum(whole[rest], whole[lead]))
+        agree = agree // G * G
+        order = np.argsort(-agree, kind="stable")[:cap - 1]
+        saved = agree[order] * np.arange(1, len(order) + 1)
+        k = int(saved.argmax()) + 1
+        n = int(agree[order[k - 1]])
+        if n == 0:
+            pending = pending[1:]
+            continue
+        members = [lead] + sorted(int(s) for s in rest[order[:k]])
+        out[members, 0] = n
+        out[lead, 1] = len(members)
+        out[lead, 2:2 + len(members)] = members
+        pending = [s for s in pending if s not in members]
+    return out
+
+
 def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
-                            out_dtype=None, interpret=None,
-                            blocks=LATENT_BLOCKS, window=None):
+                            out_dtype=None, interpret=None, blocks=None,
+                            window=None, shared=None):
     """The Mosaic kernel of a LATENT plane, a sibling of the loop above
     under its own name (``paged_latent_attention``): ``pool [blocks, B,
     L]`` holds ONE row a cached position, every one of the ``h`` query
@@ -1262,7 +1364,8 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
     Grid ``(S,)``, one step a slot, a loop over the slot's LIVE entries
     (``n_s`` as above; with a ``window`` from the group that holds the
     first entry some row's lower bound lets through, the keys under a
-    row's bound masked) taken ``blocks`` at a time: one iteration copies ``blocks`` table entries
+    row's bound masked) taken ``blocks`` at a time (``LATENT_BLOCKS`` if
+    None): one iteration copies ``blocks`` table entries
     side by side into one ``[blocks * B, L]`` buffer (``DEPTH`` such
     buffers, ``DEPTH - 1`` groups on their way), scores all ``N = W *
     h`` rows against it in ONE MXU pass ``[N, L] x [blocks * B, L]^T``,
@@ -1277,7 +1380,23 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
     there, the trash block for an entry never used: finite values under
     a zero weight), so that no lane of the buffer holds what was never
     written.  A slot whose rows are all at ``pos < 0`` fetches nothing
-    and returns zeros."""
+    and returns zeros.
+
+    **A run several slots share is fetched ONCE** (``shared [S, 2 + S]``
+    int32, ``shared_runs``'s; DATA: whatever it holds, one program).
+    The grid step of a run's leader first walks the run's entries ``[0,
+    n)`` of its own table row with the members' rows STACKED (``members x
+    N`` rows against the one buffer: the same two MXU passes and the same
+    one update an iteration, at the narrowest of ``_stack_widths`` that
+    holds the members) and leaves each member's running ``(m, l, acc)``
+    in VMEM scratch, which lives across the grid's steps (so the grid is
+    ``arbitrary``); a member's own walk then STARTS from that state at
+    entry ``n`` instead of from nothing at entry 0, as the loop above
+    starts at a sink.  A row folds the same groups of entries in the same
+    order with or without a run, so its output is the same.  A slot in
+    no run (a row of zeros) walks ``[0, n_s)`` as ever.  Only decode rows
+    of a plane attended whole take a run: with a ``window`` or ``W > 1``
+    ``shared`` is dropped and the program is the one without it."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1286,14 +1405,28 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
     out_dtype = q.dtype if out_dtype is None else out_dtype
     S, W, h, L = q.shape
     B, NB = pool.shape[1], table.shape[1]
-    G = max(1, min(int(blocks), NB))
+    G = _latent_entries(blocks, NB)
     T, N, dv = G * B, W * h, int(value_lanes)
     if scale is None:
         scale = 1.0 / float(L) ** 0.5
     f32 = jnp.float32
+    # only decode rows of a plane attended whole take a run (a Python
+    # flag: the body below reads nothing of the traced array)
+    told = shared is not None and window is None and W == 1 and S > 1
+    # rows the softmax's scratch holds: one slot's, or the widest stack's
+    widths = _stack_widths(S, N) if told else []
+    R = N * (widths[-1] if widths else 1)
 
-    def kernel(tbl, pos_ref, q_ref, pool_hbm, o_ref, buf, sem, s_ref,
-               peak_ref, m_ref, l_ref, acc_ref):
+    def rows(ref, n):
+        return ref if n == ref.shape[0] else ref.at[pl.ds(0, n)]
+
+    def kernel(tbl, pos_ref, *refs):
+        if not told:
+            (q_ref, pool_hbm, o_ref, buf, sem, s_ref, peak_ref, m_ref,
+             l_ref, acc_ref) = refs
+        else:
+            (shr, q_ref, pool_hbm, o_ref, buf, sem, s_ref, peak_ref, m_ref,
+             l_ref, acc_ref, qs_ref, m_run, l_run, acc_run) = refs
         s_id = pl.program_id(0)
         top = pos_ref[s_id, 0]
         for w in range(1, W):
@@ -1316,92 +1449,164 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
                 buf.at[slot, pl.ds(j * B, B)], sem.at[slot, j])
                 for j in range(G)]
 
-        def score(g):
-            kb = buf[jax.lax.rem(g, DEPTH)]                    # [T, L]
-            dt = jnp.promote_types(kb.dtype, q_ref.dtype)
-            s = _matmul(q_ref[0].astype(dt), kb.astype(dt),
-                        ((1,), (1,))) * scale                  # [N, T]
-            row = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
-            at = jnp.full((N, 1), pos_ref[s_id, 0])
-            for w in range(1, W):
-                at = jnp.where(row >= w * h, pos_ref[s_id, w], at)
-            tok = g * T + jax.lax.broadcasted_iota(jnp.int32, (N, T), 1)
-            keep = tok <= at
-            if window is not None:
-                keep &= tok > at - window
-            s = jnp.where(keep, s, NEG_INF)
-            s_ref[...] = s
-            peak_ref[...] = jnp.broadcast_to(
-                jnp.max(s, axis=-1, keepdims=True), (N, LSE_LANES))
+        def fresh(n):
+            for ref, start in ((m_ref, NEG_INF), (l_ref, 0.0),
+                               (acc_ref, 0.0)):
+                ref = rows(ref, n)
+                ref[...] = jnp.full(ref.shape, start, f32)
 
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        for ahead in range(DEPTH - 1):
-            @pl.when(g0 + ahead < groups)
-            def _start(ahead=ahead):
-                for c in copies(g0 + ahead):
-                    c.start()
+        def walk(n, query, at_rows, g0, groups):
+            """Rows ``[0, n)`` of the softmax's scratch folded over the
+            groups ``[g0, groups)`` of THIS slot's table row: ``query()``
+            their ``[n, L]`` rows, ``at_rows()`` ``[(first row, position)]``
+            ascending."""
+            s_w, peak_w, m_w, l_w, acc_w = (
+                rows(r, n) for r in (s_ref, peak_ref, m_ref, l_ref, acc_ref))
 
-        @pl.when(groups > g0)
-        def _first():
-            for c in copies(g0):
-                c.wait()
-            score(g0)
+            def score(g):
+                kb = buf[jax.lax.rem(g, DEPTH)]                # [T, L]
+                dt = jnp.promote_types(kb.dtype, q_ref.dtype)
+                s = _matmul(query().astype(dt), kb.astype(dt),
+                            ((1,), (1,))) * scale              # [n, T]
+                row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+                (_, at), *later = at_rows()
+                at = jnp.full((n, 1), at)
+                for first, at_row in later:
+                    at = jnp.where(row >= first, at_row, at)
+                tok = g * T + jax.lax.broadcasted_iota(jnp.int32, (n, T), 1)
+                keep = tok <= at
+                if window is not None:
+                    keep &= tok > at - window
+                s = jnp.where(keep, s, NEG_INF)
+                s_w[...] = s
+                peak_w[...] = jnp.broadcast_to(
+                    jnp.max(s, axis=-1, keepdims=True), (n, LSE_LANES))
 
-        def group(g, _):
-            @pl.when(g + DEPTH - 1 < groups)
-            def _ahead():
-                for c in copies(g + DEPTH - 1):
-                    c.start()
+            for ahead in range(DEPTH - 1):
+                @pl.when(g0 + ahead < groups)
+                def _start(ahead=ahead):
+                    for c in copies(g0 + ahead):
+                        c.start()
 
-            @pl.when(g + 1 < groups)
-            def _next():
-                for c in copies(g + 1):
+            @pl.when(groups > g0)
+            def _first():
+                for c in copies(g0):
                     c.wait()
+                score(g0)
 
-            s, peak = s_ref[...], peak_ref[...][:, :1]
-            # the last group's scores are made once more and dropped: no
-            # branch between the two chains
-            score(jnp.minimum(g + 1, groups - 1))
-            m = m_ref[...][:, :1]
-            m2 = jnp.maximum(m, peak)
-            alpha = jnp.exp(m - m2)
-            # a lane a row does not keep weighs EXACTLY zero, also while
-            # the row has seen no key (the loop above)
-            p = jnp.exp(s - jnp.where(m2 == NEG_INF, 0.0, m2))
-            l2 = (l_ref[...][:, :1] * alpha
-                  + jnp.sum(p, axis=-1, keepdims=True))
-            acc_ref[...] = acc_ref[...] * alpha + _weigh(
-                p, buf[jax.lax.rem(g, DEPTH)][:, :dv])
-            m_ref[...] = jnp.broadcast_to(m2, (N, LSE_LANES))
-            l_ref[...] = jnp.broadcast_to(l2, (N, LSE_LANES))
+            def group(g, _):
+                @pl.when(g + DEPTH - 1 < groups)
+                def _ahead():
+                    for c in copies(g + DEPTH - 1):
+                        c.start()
 
-        jax.lax.fori_loop(g0, groups, group, None)
-        l = l_ref[...][:, :1]
-        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype)
+                @pl.when(g + 1 < groups)
+                def _next():
+                    for c in copies(g + 1):
+                        c.wait()
 
-    ctx = pl.pallas_call(
+                s, peak = s_w[...], peak_w[...][:, :1]
+                # the last group's scores are made once more and dropped:
+                # no branch between the two chains
+                score(jnp.minimum(g + 1, groups - 1))
+                m = m_w[...][:, :1]
+                m2 = jnp.maximum(m, peak)
+                alpha = jnp.exp(m - m2)
+                # a lane a row does not keep weighs EXACTLY zero, also
+                # while the row has seen no key (the loop above)
+                p = jnp.exp(s - jnp.where(m2 == NEG_INF, 0.0, m2))
+                l2 = (l_w[...][:, :1] * alpha
+                      + jnp.sum(p, axis=-1, keepdims=True))
+                acc_w[...] = acc_w[...] * alpha + _weigh(
+                    p, buf[jax.lax.rem(g, DEPTH)][:, :dv])
+                m_w[...] = jnp.broadcast_to(m2, (n, LSE_LANES))
+                l_w[...] = jnp.broadcast_to(l2, (n, LSE_LANES))
+
+            jax.lax.fori_loop(g0, groups, group, None)
+
+        if not told:
+            fresh(N)
+            walk(N, lambda: q_ref[0],
+                 lambda: [(w * h, pos_ref[s_id, w]) for w in range(W)],
+                 g0, groups)
+        else:
+            # a run this slot leads: its members' rows stacked over the
+            # run's entries, each member's state kept for its own walk
+            run, count = jax.lax.div(shr[s_id, 0], G), shr[s_id, 1]
+            for under, width in zip([1] + widths, widths):
+                @pl.when((run > 0) & (count > under) & (count <= width))
+                def _run(width=width):
+                    n = width * N
+                    ids = [jnp.where(j < count, shr[s_id, 2 + j], s_id)
+                           for j in range(width)]
+                    for j, member in enumerate(ids):
+                        qs_ref[pl.ds(j * N, N)] = q_ref[member]
+                    fresh(n)
+                    walk(n, lambda: rows(qs_ref, n)[...],
+                         lambda: [(j * N, pos_ref[member, 0])
+                                  for j, member in enumerate(ids)], 0, run)
+                    for j, member in enumerate(ids):
+                        @pl.when(j < count)
+                        def _keep(j=j, member=member):
+                            for kept, ref in ((m_run, m_ref), (l_run, l_ref),
+                                              (acc_run, acc_ref)):
+                                kept[member] = ref[pl.ds(j * N, N)]
+
+            @pl.when(run == 0)
+            def _alone():
+                fresh(N)
+
+            @pl.when(run > 0)
+            def _resume():
+                for kept, ref in ((m_run, m_ref), (l_run, l_ref),
+                                  (acc_run, acc_ref)):
+                    ref[pl.ds(0, N)] = kept[s_id]
+
+            walk(N, lambda: q_ref[s_id], lambda: [(0, pos_ref[s_id, 0])],
+                 run, groups)
+        l = rows(l_ref, N)[...][:, :1]
+        o_ref[0] = (rows(acc_ref, N)[...]
+                    / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    scratch = [
+        pltpu.VMEM((DEPTH, T, L), pool.dtype),
+        pltpu.SemaphoreType.DMA((DEPTH, G)),
+        pltpu.VMEM((R, T), f32), pltpu.VMEM((R, LSE_LANES), f32),
+        pltpu.VMEM((R, LSE_LANES), f32),
+        pltpu.VMEM((R, LSE_LANES), f32), pltpu.VMEM((R, dv), f32)]
+    prefetch = [table.astype(jnp.int32), pos.astype(jnp.int32)]
+    q_spec = pl.BlockSpec((1, N, L), lambda s, *_: (s, 0, 0))
+    if told:
+        # every slot's rows stay in VMEM for whoever leads a run, and
+        # the members' states between their leader's step and their own
+        prefetch.append(shared.astype(jnp.int32))
+        q_spec = pl.BlockSpec((S, N, L), lambda s, *_: (0, 0, 0))
+        scratch += [pltpu.VMEM((R, L), q.dtype),
+                    pltpu.VMEM((S, N, LSE_LANES), f32),
+                    pltpu.VMEM((S, N, LSE_LANES), f32),
+                    pltpu.VMEM((S, N, dv), f32)]
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(S,),
-            in_specs=[pl.BlockSpec((1, N, L), lambda s, *_: (s, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            num_scalar_prefetch=len(prefetch), grid=(S,),
+            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, N, dv), lambda s, *_: (s, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((DEPTH, T, L), pool.dtype),
-                pltpu.SemaphoreType.DMA((DEPTH, G)),
-                pltpu.VMEM((N, T), f32), pltpu.VMEM((N, LSE_LANES), f32),
-                pltpu.VMEM((N, LSE_LANES), f32),
-                pltpu.VMEM((N, LSE_LANES), f32), pltpu.VMEM((N, dv), f32)]),
+            scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((S, N, dv), out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("arbitrary" if told else "parallel",)),
         interpret=bool(interpret),
         name="paged_latent_attention",
-    )(table.astype(jnp.int32), pos.astype(jnp.int32), q.reshape(S, N, L),
-      pool)
+    )
+    if told:
+        # a walk a stack width is several times the body to trace and to
+        # lower: every plane of a program calls ONE function, made once
+        # (the first such call's, kept by everything the body reads)
+        call = _TOLD_CALLS.setdefault(
+            (q.shape, str(q.dtype), pool.shape[1:], str(pool.dtype), NB, G,
+             dv, float(scale), str(jnp.dtype(out_dtype)), bool(interpret),
+             tuple(widths)), jax.jit(call))
+    ctx = call(*prefetch, q.reshape(S, N, L), pool)
     return ctx.reshape(S, W, h, dv)
 
 

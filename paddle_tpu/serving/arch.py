@@ -185,6 +185,11 @@ class Architecture:
     pool_arrays = 2
     latent_planes = 0
     attn_form = None
+    # whether the decode calls of its planes can fetch ONCE a run of
+    # table entries several live slots share (latent planes attended
+    # whole: ``kernels.paged_attention.shared_runs``); an engine with a
+    # prefix trie then says at every dispatch which slots share what
+    shares_runs = False
     # layers whose mixer is power retention (a state, no plane)
     retention_layers = 0
     # latent planes that hold an index key beside the row and are attended
@@ -1389,6 +1394,8 @@ class LatentMoE(_Latent):
 
     name = "latent_moe"
     route_how = dict(score="softmax", normalise=False, bias=False)
+    # every plane is latent and attended whole
+    shares_runs = True
 
     def __init__(self, n_layer, n_head, d_model, rank, nope_dim, rope_dim,
                  v_dim, dense_layers, router_width, top_k, experts,

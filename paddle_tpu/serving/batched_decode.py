@@ -216,15 +216,21 @@ class _Cache:
     which the entry points take from the engine where the others take
     the table.
 
+    ``shared`` (a decode step of an architecture that ``shares_runs``,
+    from an engine with a prefix trie; else ``None`` and nothing is
+    traced) is ``kernels.paged_attention.shared_runs``'s array: which
+    live slots' chains start alike, for a latent plane attended whole to
+    fetch such a run once.  It rides beside ``table`` as data.
+
     ``tally(counts)`` adds an int32 vector (``arch.count_names`` says
     what its entries are) to ``counts``, which the entry points return
     beside their tokens; ``()`` for a stack that tallies nothing."""
 
     def __init__(self, arch, table, blk, off, pos, writable=None,
-                 slot=None, live=None):
+                 slot=None, live=None, shared=None):
         self.arch, self.off = arch, off
         self.pos, self.writable, self.slot = pos, writable, slot
-        self.live = live
+        self.live, self.shared = live, shared
         self.counts = ()
         self.step = pos.ndim == 1
         # two kinds of chain (``[S, 2, NB]``): a table and the rows'
@@ -281,6 +287,8 @@ class _Cache:
                 pk = _paged.write(pk, b, self.off, kh)
                 if pv is not None:
                     pv = _paged.write(pv, b, self.off, vh)
+        if self.shared is not None:
+            how["shared"] = self.shared     # ``attend`` knows who takes it
         # attend THROUGH the table: row j attends <= pos_j inside the
         # paged_attention op class, the [S, T, h, dh] view never exists
         ctx = _paged.attend(qh[:, None] if self.step else qh, pk, pv, tbl,
@@ -373,7 +381,8 @@ def _blocks(table, rows, entry, writable=None):
     return tuple(one(table[:, k]) for k in range(table.shape[1]))
 
 
-def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
+def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=(),
+                      shared=None):
     """One decode step for S independent slots through the block table.
 
     tok [S] int32 current tokens, t [S] int32 per-slot positions,
@@ -390,6 +399,7 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
     advances the per-slot ``state`` of the live slots (``table[:, 0] !=
     0``) and returns ``(logits [S, vocab] f32, pool_k', pool_v',
     state', counts)``, ``counts`` what the stack tallied (``_Cache``).
+    ``shared``: which slots' chains start alike (``_Cache``), or ``None``.
     """
     if not arch.planes:
         tw = jnp.maximum(t, 0)
@@ -403,7 +413,7 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=()):
         with sublayer("cache"):
             blk = _blocks(table, jnp.arange(S), tw // B)  # [S] write block
         x = arch.embed(p, tok, tw)                           # [S, d]
-        cache = _Cache(arch, table, blk, tw % B, t)
+        cache = _Cache(arch, table, blk, tw % B, t, shared=shared)
     with jax.named_scope(STACK_SCOPE):
         x, (pool_k, pool_v, state) = arch.stack(
             p, x, tw, (pool_k, pool_v, state), cache)
@@ -414,9 +424,9 @@ def make_decode_chunk(arch, chunk, donate=True):
     """Build the batched decode executable: ``chunk`` greedy steps for
     every slot in one device call, for ``arch`` (an ``Architecture``).
 
-    ``fn(params, pool_k, pool_v, last_tok, pos, table, state=()) ->
-    (pool_k', pool_v', last_tok', pos', toks [chunk, S] int32, state',
-    counts)`` — ``toks[j]`` is the token each slot emitted at its
+    ``fn(params, pool_k, pool_v, last_tok, pos, table, state=(),
+    shared=None) -> (pool_k', pool_v', last_tok', pos', toks [chunk, S]
+    int32, state', counts)`` — ``toks[j]`` is the token each slot emitted at its
     ``pos+j``'th position; ``counts`` is what the stack tallied, summed
     over the chunk's steps (int32 ``[len(arch.count_names)]``; ``()`` and
     no output of the lowered program for an architecture that tallies
@@ -424,14 +434,17 @@ def make_decode_chunk(arch, chunk, donate=True):
     (``arch.state_spec``; ``()`` and no argument of the lowered program
     for an architecture that holds none) are donated (updated in place
     on TPU); the table is a small host-fed int32 array (data, not
-    donated).  Callers must replace their references with the outputs.
+    donated), and so is ``shared`` (``_Cache``; ``None`` and no argument
+    of the lowered program unless the engine passes one).  Callers must
+    replace their references with the outputs.
     """
 
-    def decode_chunk(p, pool_k, pool_v, last_tok, pos, table, state=()):
+    def decode_chunk(p, pool_k, pool_v, last_tok, pos, table, state=(),
+                     shared=None):
         def body(carry, _):
             pk, pv, st, tok, t = carry
             logits, pk, pv, st, counts = paged_step_logits(
-                p, tok, t, pk, pv, table, arch, st)
+                p, tok, t, pk, pv, table, arch, st, shared)
             with sublayer("head"):
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (pk, pv, st, nxt, t + 1), (nxt, counts)

@@ -818,7 +818,23 @@ class ServingEngine:
             self._reg.counter("serving.prefill_entries", kind="chain",
                               help=text).inc(dense)
 
-    def _count_paged_entries(self, contexts):
+    def _shared_runs(self, contexts):
+        """Which of the live slots' chains START alike (``contexts`` as
+        ``_count_paged_entries`` takes them), for the decode calls of an
+        architecture whose planes fetch such a run once
+        (``arch.shares_runs``) in an engine whose trie hands several slots
+        one block: ``kernels.paged_attention.shared_runs`` over the
+        host's table, the entries whole under each slot's position; else
+        ``None``, and the decode chunk is the program without it."""
+        if not self.arch.shares_runs or self.prefix_trie is None:
+            return None
+        whole = np.zeros(self.max_slots, np.int64)
+        for s, ctx in contexts:
+            whole[s] = (ctx - 1) // self.block_tokens
+        return _paged.shared_runs(self._table, whole,
+                                  self.arch.rows_per_entry)
+
+    def _count_paged_entries(self, contexts, runs=None):
         """A decode chunk is being sent for ``contexts``, ``[(slot, keys
         its first step attends)]`` of the rows live in it (``prompt +
         _sent``: the slot's DISPATCHED position): of the ``max_slots x
@@ -828,8 +844,10 @@ class ServingEngine:
         token makes; the query rows a call sends through each, the
         softmax updates the kernel makes for them and the iterations its
         loop makes over them; and the K/V bytes those calls have to
-        read.  For retention layers, which have no
-        table: the states the chunk's steps read and write."""
+        read; with ``runs`` (``_shared_runs``) the entries the calls
+        were told to fetch, a shared run once.  For retention layers,
+        which have no table: the states the chunk's steps read and
+        write."""
         if self.arch.retention_layers:
             self._reg.counter(
                 "serving.retention_slot_steps",
@@ -890,6 +908,19 @@ class ServingEngine:
                  "the plane's lower bound up to each one's position at "
                  "the chunk's start; the mean over a token's calls)",
         ).inc(live / self._reads_per_token)
+        if runs is not None:
+            # a run's leader row holds its members' count: every member
+            # after the first is spared the run's entries, a full plane
+            again = int(np.sum(runs[:, 0] * np.maximum(runs[:, 1] - 1, 0)))
+            self._reg.counter(
+                "serving.paged_entries_fetched", phase="decode",
+                help="of paged_entries_live, the entries the decode calls "
+                     "were told to fetch: a run of entries several live "
+                     "slots share (kernels.paged_attention.shared_runs) "
+                     "once for all of them").inc(
+                         (live - again * sum(
+                             n for window, n, *_ in self._plane_reads
+                             if window is None)) / self._reads_per_token)
         if self._windowed:
             for kind, entries in live_by_kind.items():
                 self._reg.counter(
@@ -1556,6 +1587,8 @@ class ServingEngine:
         positions (``_sent``).  Nothing here waits for the device: the
         pool, the slot scalars and the state it passes are the previous
         call's outputs, futures while that call runs."""
+        import jax.numpy as jnp
+
         if self._decode_fn is None:
             self._decode_fn = self._aot_with_mem_telemetry(
                 _bd.make_decode_chunk(self.arch, chunk=self.decode_chunk,
@@ -1588,16 +1621,20 @@ class ServingEngine:
                 self._reg.gauge("serving.blocks_in_use").set(
                     self._blocks_in_use())
             tbl = self._device_table(reqs)
+            # which chains start alike rides beside the table, as data
+            runs = self._shared_runs(contexts)
+            told = () if runs is None else (jnp.asarray(runs),)
             # one-time AOT compile lands here, outside the chunk's clock
             # pair, which the predictor consumes
             self._decode_fn.prepare(self._p, self._pk, self._pv,
-                                    self._last, self._pos, tbl, self._state)
-            self._count_paged_entries(contexts)
+                                    self._last, self._pos, tbl, self._state,
+                                    *told)
+            self._count_paged_entries(contexts, runs)
             sent_t = time.perf_counter()
             (self._pk, self._pv, self._last, self._pos, toks,
              self._state, counts) = self._decode_fn(
                  self._p, self._pk, self._pv, self._last, self._pos, tbl,
-                 self._state)
+                 self._state, *told)
             # queued behind the compute, not asked for after it
             toks.copy_to_host_async()
             if self.arch.count_names:

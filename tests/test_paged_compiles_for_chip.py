@@ -219,6 +219,34 @@ def test_latent_kernel_compiles_for_v5e(geometry, one_chip):
     assert written.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("slots", [12, 2])
+def test_latent_kernel_told_shared_runs_compiles_for_v5e(slots, one_chip):
+    """The decode call of ``dsv2lite.doc_qa_8k`` told which chains start
+    alike (``shared [S, 2 + S]``: one more scalar-prefetch operand, DATA):
+    one Mosaic call under the same name, no pool-sized temporary, the
+    stacks of 2 to 12 slots' rows within Mosaic's scoped VMEM."""
+    from paddle_tpu.kernels.paged_attention import (
+        _stack_widths, latent_attention_pallas)
+
+    S, W, NB, blocks, lanes, _, dv, rows = LATENT["doc_qa_decode"]
+    assert _stack_widths(12, rows) == [2, 3, 4, 6, 8, 12]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, p, t, at, runs: latent_attention_pallas(
+            q, p, t, at, dv, scale=0.11472, interpret=False,
+            shared=runs)).lower(
+            arg((slots, W, rows, lanes), jnp.bfloat16),
+            arg((blocks, 32, lanes), jnp.bfloat16),
+            arg((slots, NB), jnp.int32), arg((slots, W), jnp.int32),
+            arg((slots, 2 + slots), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert compiled.as_text().count("paged_latent_attention") >= 1
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
 def test_a_latent_row_of_576_lanes_is_refused_by_mosaic(one_chip):
     """Why the plane stores 640 lanes: Mosaic slices a block out of the
     pool on 128-lane tiles only, and the device holds a 576-lane row in
