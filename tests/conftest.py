@@ -15,6 +15,14 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+# Tier-1 checks the programs' logic, their lowering and their HLO, not
+# XLA:CPU's code generator: compile at backend optimisation level 0 with
+# LLVM's expensive passes off.  They are a third of the suite's compile
+# CPU for code that then runs for milliseconds; the HLO passes, the SPMD
+# partitioner and every lowered text are what they were; and two
+# spellings of one arithmetic then give the same bits, where the
+# optimiser contracts and re-associates each program its own way.
+jax.config.update("jax_disable_most_optimizations", True)
 
 import numpy as np
 import pytest
@@ -38,3 +46,19 @@ def fresh_programs():
     pt.core.scope._scope_stack.pop()
     pt.core.program.switch_main_program(prev_main)
     pt.core.program.switch_startup_program(prev_startup)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described ``v5e:2x2``: what the
+    ``compiles_for_chip`` files compile for, without a chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])

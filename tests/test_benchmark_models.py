@@ -8,7 +8,7 @@ import numpy as np
 
 from paddle_tpu.models import alexnet, googlenet, smallnet
 
-from test_book import train_steps
+from tiny import train_steps
 
 
 def test_alexnet():

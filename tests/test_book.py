@@ -21,21 +21,7 @@ from paddle_tpu.models import (
     vgg,
     word2vec,
 )
-
-
-def train_steps(outs, feeds, steps=5, extra_fetch=()):
-    """Run `steps` batches of identical data; return loss per step."""
-    exe = pt.Executor()
-    exe.run(pt.default_startup_program())
-    fetch = [outs["avg_cost"]] + list(extra_fetch)
-    losses = []
-    for _ in range(steps):
-        vals = exe.run(feed=feeds, fetch_list=fetch)
-        losses.append(float(np.asarray(vals[0]).ravel()[0]))
-    losses = np.asarray(losses)
-    assert np.isfinite(losses).all(), losses
-    assert losses[-1] < losses[0], f"loss did not decrease: {losses}"
-    return losses
+from tiny import train_steps
 
 
 def ragged_int(batch, max_len, high, rng):

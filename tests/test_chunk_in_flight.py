@@ -12,36 +12,34 @@ import time
 import numpy as np
 import pytest
 
-import test_retention_arch as tr
-import test_sambay as ts
-import test_serving as tsv
-import test_sink_window_moe as tw
+import tiny
 from paddle_tpu.models import transformer
 from paddle_tpu.observability import trace
 from paddle_tpu.observability.metrics import MetricsRegistry
 from paddle_tpu.resilience import faults
 from paddle_tpu.serving import ServingEngine
 
-T = 64
+T, VOCAB = 64, tiny.BUILT_VOCAB
+NL, NH, DM = (tiny.gpt2.sizes[k] for k in ("layers", "heads", "d"))
 
 
 @pytest.fixture(scope="module")
 def gpt2():
-    return tsv._make_params(max_len=T)
+    return tiny.gpt2_built(max_len=T)
 
 
 def _gpt2_engine(params, **kw):
     kw.setdefault("max_slots", 3)
     kw.setdefault("registry", MetricsRegistry())
-    return ServingEngine(params, tsv.NL, tsv.NH, tsv.DM, max_len=T,
+    return ServingEngine(params, NL, NH, DM, max_len=T,
                          decode_chunk=4, min_bucket=4, block_tokens=4, **kw)
 
 
 def _alone(params, prompt, n_new):
     """``transformer.generate`` on the one prompt: the plain reference."""
     ref, _ = transformer.generate(
-        params, np.asarray(prompt)[None], max_len=T, n_layer=tsv.NL,
-        n_head=tsv.NH, d_model=tsv.DM, return_logits=False)
+        params, np.asarray(prompt)[None], max_len=T, n_layer=NL,
+        n_head=NH, d_model=DM, return_logits=False)
     return np.asarray(ref)[0][:len(prompt) + n_new]
 
 
@@ -64,28 +62,31 @@ def _engines(kind, gpt2, monkeypatch):
     """Two engines of one geometry over one set of weights, and prompts
     for them: (in flight, one at a time, prompts)."""
     if kind == "gpt2_trie":
-        head = np.arange(1, 11) % tsv.VOCAB      # two full blocks and a half
+        head = np.arange(1, 11) % VOCAB      # two full blocks and a half
         rng = np.random.default_rng(48)
-        prompts = [np.concatenate([head, rng.integers(1, tsv.VOCAB, n)])
+        prompts = [np.concatenate([head, rng.integers(1, VOCAB, n)])
                    for n in (3, 7, 2, 5, 9, 4, 6)]
         make = lambda: _gpt2_engine(gpt2, prefix_reuse=True)     # noqa: E731
     elif kind == "sink_window":
-        p = tw._share(tw._init(tw.jax.random.PRNGKey(46), tw.TINY,
-                               tw.jnp.float32), *tw.TINY["share"])
+        p = tiny.sink_window_moe.held(tiny.sink_window_moe.init())["float32"]
         prompts = [(3 * np.arange(n) + n) % 128 for n in
                    (21, 11, 13, 5, 17, 9, 12)]
-        make = lambda: tw._engine(p, monkeypatch)[0]             # noqa: E731
+        make = lambda: tiny.sink_window_moe.engine(          # noqa: E731
+            p, monkeypatch)[0]
     elif kind == "sambay":
-        p = ts._init(ts.jax.random.PRNGKey(32), ts.TINY, ts.jnp.float32)
+        p = tiny.sambay.init()
         prompts = [(5 * np.arange(n) + n) % 128 for n in
                    (21, 11, 17, 6, 9, 13, 4)]
-        make = lambda: ts._engine(p, monkeypatch, max_slots=3)[0]  # noqa: E731
+        make = lambda: tiny.sambay.engine(                   # noqa: E731
+            p, monkeypatch, max_slots=3)[0]
     else:
-        p = tr.make(0)
+        p = tiny.retention.init()
         rng = np.random.default_rng(1)
-        prompts = [rng.integers(0, tr.V, n, dtype=np.int32)
+        prompts = [rng.integers(0, tiny.retention.sizes["rows"], n,
+                                dtype=np.int32)
                    for n in (140, 5, 17, 61, 9, 33, 12)]
-        make = lambda: tr.engine(p, compute_dtype="float32")     # noqa: E731
+        make = lambda: tiny.retention.engine(                # noqa: E731
+            p, compute_dtype="float32")[0]
     return make(), _one_at_a_time(make()), prompts
 
 
@@ -138,12 +139,12 @@ def test_an_eos_hit_rides_one_chunk_and_emits_none_of_it(gpt2):
     hit was read still steps the slot; its tokens are dropped, its steps
     counted, and the request admitted into the slot next is exact."""
     rng = np.random.default_rng(5)
-    b, c = (rng.integers(1, tsv.VOCAB, n) for n in (7, 6))
+    b, c = (rng.integers(1, VOCAB, n) for n in (7, 6))
     # an eos inside the SECOND chunk (tokens 5..8 of the request; the
     # prefill gives token 0), which the chain has not emitted before:
     # greedy chains over random weights soon repeat, so look for a prompt
     for _ in range(50):
-        a = rng.integers(1, tsv.VOCAB, 5)
+        a = rng.integers(1, VOCAB, 5)
         full = _alone(gpt2, a, 20)
         gen = list(full[len(a):])
         hits = [i for i in (5, 6, 7) if gen[i] not in gen[:i]]
@@ -182,7 +183,7 @@ def test_a_slot_is_where_the_device_says_and_an_end_gets_the_dead_row(gpt2):
     first write is never past ``prompt + max_new - 2``: also where
     ``prompt + max_new`` is ``max_len``."""
     rng = np.random.default_rng(9)
-    prompts = [rng.integers(1, tsv.VOCAB, n) for n in (T - 9, 6, 11)]
+    prompts = [rng.integers(1, VOCAB, n) for n in (T - 9, 6, 11)]
     max_new = [9, 13, 7]                       # the first ends AT max_len
     eng = _gpt2_engine(gpt2, prefix_reuse=False)
     table_of = eng._device_table
@@ -217,7 +218,7 @@ def test_stop_drains_what_is_in_flight(gpt2):
     """(d): ``stop(drain=True)`` returns with every token read."""
     eng = _gpt2_engine(gpt2, prefix_reuse=False)
     rng = np.random.default_rng(3)
-    prompts = [rng.integers(1, tsv.VOCAB, 5) for _ in range(5)]
+    prompts = [rng.integers(1, VOCAB, 5) for _ in range(5)]
     eng.start()
     reqs = [eng.submit(p, max_new_tokens=15) for p in prompts]
     eng.stop(drain=True)
@@ -234,7 +235,7 @@ def test_an_abort_with_a_chunk_in_flight_fails_everyone_and_leaks_nothing(
     """(d): the engine dies with a chunk on the device: nobody hangs."""
     eng = _gpt2_engine(gpt2, max_slots=2, prefix_reuse=False)
     rng = np.random.default_rng(4)
-    reqs = [eng.submit(rng.integers(1, tsv.VOCAB, 5), max_new_tokens=15)
+    reqs = [eng.submit(rng.integers(1, VOCAB, 5), max_new_tokens=15)
             for _ in range(3)]
     eng.step()
     assert len(eng._chunks) == 1 and not eng.idle
@@ -258,7 +259,7 @@ def test_a_slot_death_reads_what_is_in_flight_first(gpt2):
     are exact, no block leaks."""
     eng = _gpt2_engine(gpt2, prefix_reuse=False)
     rng = np.random.default_rng(14)
-    prompts = [rng.integers(1, tsv.VOCAB, 5) for _ in range(5)]
+    prompts = [rng.integers(1, VOCAB, 5) for _ in range(5)]
     os.environ["PADDLE_TPU_FAULT"] = "slot_death:3"
     faults.reset()
     try:
@@ -340,7 +341,7 @@ def test_one_decode_chunk_span_a_chunk_with_that_chunks_rows(gpt2):
     reg = MetricsRegistry()
     eng = _gpt2_engine(gpt2, prefix_reuse=False, registry=reg)
     rng = np.random.default_rng(6)
-    prompts = [rng.integers(1, tsv.VOCAB, n) for n in (5, 9, 4, 7, 6)]
+    prompts = [rng.integers(1, VOCAB, n) for n in (5, 9, 4, 7, 6)]
     t = _traced(lambda: eng.generate_many(prompts,
                                           max_new_tokens=MAX_NEW[:5]))
     sent = sorted(t.events(name="serving.dispatch"), key=lambda e: e["ts"])

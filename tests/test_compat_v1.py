@@ -7,7 +7,7 @@ import numpy as np
 import paddle_tpu as pt
 from paddle_tpu.compat import v1
 
-from test_book import train_steps
+from tiny import train_steps
 
 
 def test_v1_smallnet_config_trains():

@@ -19,20 +19,6 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
 SLOTS, H, D, TAPS = 16, 64, 128, 4
 BF16, F32 = jnp.bfloat16, jnp.float32
 

@@ -19,7 +19,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-import paddle_tpu as pt  # noqa: E402
+import tiny  # noqa: E402
 from chipbench.families import delta_moe as family  # noqa: E402
 from chipbench.families import delta_moe_reference as ref  # noqa: E402
 from paddle_tpu.kernels import delta  # noqa: E402
@@ -27,82 +27,14 @@ from paddle_tpu.kernels.xla_ref import oracle_tol  # noqa: E402
 from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
 from paddle_tpu.serving import batched_decode as _bd  # noqa: E402
 from paddle_tpu.serving.arch import DeltaMoE  # noqa: E402
+from tiny import delta_moe as fam  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = json.load(open(os.path.join(
     ROOT, "chipbench", "configs", "solar-open2-250b.json")))
-# the published layout at a width the CPU can run: one period G D D D, 2
-# K/V heads under 4 query heads, 4 of 16 experts held (4..7), top 3
-TINY = {"hidden_size": 64, "num_hidden_layers": 4, "gqa_layers": [0],
-        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-        "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16,
-                               "num_heads": 4, "num_kv_heads": None},
-        "moe_intermediate_size": 40, "n_routed_experts": 4,
-        "router_width": 16, "experts_first": 4, "num_experts_per_tok": 3,
-        "routed_scaling_factor": 1, "norm_topk_prob": True,
-        "kda_allow_neg_eigval": True, "rms_norm_eps": 1e-5,
-        "vocab_size": 97, "compute_dtype": "float32"}
-V, B, PIECE = TINY["vocab_size"], 8, 32
+TINY = tiny.delta_config()
+V, PIECE = TINY["vocab_size"], 32
 LAYOUT = family._layout(TINY)
-
-
-def make(seed, held=(4, 4), cfg=TINY):
-    """Seeded float32 parameters under ``DeltaMoE``'s names, holding the
-    experts ``held = (first, count)`` of the router's 16: every share
-    draws the SAME 16 experts and holds its own."""
-    rng = np.random.default_rng(seed)
-    first, count = held
-    whole = family.shapes(dict(cfg, n_routed_experts=cfg["router_width"]))
-    p = {}
-    for name, shape in whole.items():
-        kind = name.split("_", 1)[-1]
-        if kind.endswith(".scale"):
-            a = np.ones(shape)
-        elif kind == "delta_conv.w":
-            a = rng.uniform(-0.5, 0.5, shape)
-        elif kind == "delta_A_log.w":
-            a = np.log(rng.uniform(1, 16, shape))
-        elif kind == "delta_dt.b":
-            step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), shape))
-            a = step + np.log(-np.expm1(-step))
-        elif kind in ("router.w", "delta_beta.w"):
-            a = 0.5 * rng.normal(size=shape)
-        else:
-            a = (1.0 if name == "tok_emb.w" else 0.15) * rng.normal(
-                size=shape)
-        if kind.startswith("experts_"):
-            a = a[first:first + count]
-        p[name] = a.astype(np.float32)
-    return p
-
-
-def arch(held=(4, 4)):
-    return family._arch(dict(TINY, experts_first=held[0],
-                             n_routed_experts=held[1]))
-
-
-def engine(params, reg=None, **kw):
-    kw.setdefault("prefix_reuse", False)
-    # three snapshot rows: an eighth of 256 blocks of 2 KiB over 19,200 B
-    kw.setdefault("cache_blocks", 256 if kw["prefix_reuse"] else 0)
-    return pt.serving.ServingEngine(
-        params, arch=arch(), max_len=400, max_slots=3, block_tokens=B,
-        registry=reg or MetricsRegistry(), **kw)
-
-
-def gaps(params, prompts, outs, held=(4, 4), **switches):
-    """The worst gap, a request, between a generated token's reference
-    logit and the reference's maximum."""
-    layout = LAYOUT[:6] + (held, LAYOUT[7])
-    worst = []
-    for prompt, full in zip(prompts, outs):
-        full = np.asarray(full)
-        assert np.array_equal(full[:len(prompt)], prompt)
-        lg = ref.forward(params, full[None], *layout, **switches)[0]
-        at = lg[len(prompt) - 1:len(full) - 1]
-        worst.append(float(np.max(
-            at.max(-1) - at[np.arange(len(at)), full[len(prompt):]])))
-    return worst
 
 
 # -- the counts ---------------------------------------------------------------
@@ -224,7 +156,7 @@ def test_the_reference_walks_one_position_at_a_time_and_in_blocks(
         monkeypatch):
     """Cutting a sequence into blocks of rows (the state and the
     convolution's rows carried) changes nothing."""
-    params = make(5)
+    params = fam.init(5)
     tokens = np.random.default_rng(0).integers(0, V, (1, 90), np.int32)
     whole = ref.forward(params, tokens, *LAYOUT)
     monkeypatch.setattr(ref, "ROWS", 32)
@@ -237,9 +169,9 @@ def test_the_reference_walks_one_position_at_a_time_and_in_blocks(
 
 @pytest.fixture(scope="module")
 def served():
-    params = make(0)
+    params = fam.init(0)
     reg = MetricsRegistry()
-    eng = engine(params, reg, compute_dtype="float32")
+    eng = fam.engine(params, registry=reg, compute_dtype="float32")[0]
     rng = np.random.default_rng(1)
     # more prompts than slots, so slots are reused; one and several
     # pieces, every rung, a prompt that ends on a tile's boundary
@@ -252,7 +184,7 @@ def served():
 def test_engine_through_pieces_and_decode_is_the_reference(served):
     params, _, _, prompts, outs = served
     # float32 end to end: greedy tokens ARE the reference's argmax
-    assert max(gaps(params, prompts, outs)) <= 1e-4
+    assert max(tiny.gaps(fam, params, prompts, outs)) <= 1e-4
 
 
 @pytest.mark.parametrize("switch", [
@@ -263,7 +195,7 @@ def test_engine_through_pieces_and_decode_is_the_reference(served):
     {"lost": (300,)}], ids=lambda s: next(iter(s)))
 def test_each_line_of_the_layers_is_seen_by_the_comparison(served, switch):
     params, _, _, prompts, outs = served
-    assert max(gaps(params, prompts, outs, **switch)) > 0.01
+    assert max(tiny.gaps(fam, params, prompts, outs, **switch)) > 0.01
 
 
 @pytest.fixture(scope="module")
@@ -273,10 +205,12 @@ def hit():
     snapshot."""
     old, _bd.PREFILL_PIECE = _bd.PREFILL_PIECE, PIECE
     try:
-        params = make(0)
+        params = fam.init(0)
         reg = MetricsRegistry()
-        eng = engine(params, reg, compute_dtype="float32",
-                     prefix_reuse=True, min_bucket=8)
+        # three snapshot rows: an eighth of 256 blocks of 2 KiB over 19,200 B
+        eng = fam.engine(params, registry=reg, compute_dtype="float32",
+                         prefix_reuse=True, cache_blocks=256,
+                         min_bucket=8)[0]
         rng = np.random.default_rng(7)
         heads = [rng.integers(0, V, 2 * PIECE, dtype=np.int32)
                  for _ in range(2)]
@@ -296,21 +230,21 @@ def test_a_hit_that_starts_from_a_snapshot_is_the_reference(hit):
     st = eng.stats()
     assert st["serving.state_snapshot_hits"] == 4
     assert st["serving.prefix_hit_tokens"] >= 4 * len(heads[0])
-    assert max(gaps(params, prompts, outs)) <= 1e-4
+    assert max(tiny.gaps(fam, params, prompts, outs)) <= 1e-4
 
 
 def test_a_zeroed_or_a_swapped_snapshot_is_seen_by_the_comparison(hit):
     params, _, heads, prompts, outs = hit
     at = len(heads[0])
-    assert min(gaps(params, prompts, outs, lost=(at,))) > 0.01
+    assert min(tiny.gaps(fam, params, prompts, outs, lost=(at,))) > 0.01
     # the OTHER head's snapshot restored: its state before position ``at``
     for mine, other in ((0, 1), (1, 0)):
         states = []
         ref.trunk(params, np.concatenate([heads[other], prompts[0][:1]]),
                   *LAYOUT, capture=(at, states))
         assert len(states) == 3
-        assert gaps(params, prompts[mine:mine + 1], outs[mine:mine + 1],
-                    inject=(at, states))[0] > 0.01
+        assert tiny.gaps(fam, params, prompts[mine:mine + 1],
+                         outs[mine:mine + 1], inject=(at, states))[0] > 0.01
 
 
 def test_the_shares_of_a_routed_layer_sum_to_the_uncut_layer():
@@ -318,9 +252,9 @@ def test_the_shares_of_a_routed_layer_sum_to_the_uncut_layer():
     shared expert counted ONCE, add up to the layer that holds all 16."""
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.normal(size=(40, TINY["hidden_size"])), jnp.float32)
-    whole = ref.routed_ffn(make(2, (0, 16)), 1, x, 3, (0, 16), 1.0)
-    parts = sum(ref.routed_ffn(make(2, (f, 2)), 1, x, 3, (f, 2), 1.0,
-                               shared=f == 0) for f in range(0, 16, 2))
+    whole = ref.routed_ffn(fam.init(2, share=(0, 16)), 1, x, 3, (0, 16), 1.0)
+    parts = sum(ref.routed_ffn(fam.init(2, share=(f, 2)), 1, x, 3, (f, 2),
+                               1.0, shared=f == 0) for f in range(0, 16, 2))
     np.testing.assert_allclose(np.asarray(parts), np.asarray(whole),
                                rtol=1e-5, atol=1e-6)
     # and the program's routed layer is the reference's, share by share
@@ -330,7 +264,7 @@ def test_the_shares_of_a_routed_layer_sum_to_the_uncut_layer():
         valid = jnp.ones((40,), bool)
 
     for first in (0, 6):
-        p = make(2, (first, 2))
+        p = fam.init(2, share=(first, 2))
         u = ref._rms(x, 1.0, 1e-5)
         got, _ = routed_ffn(lambda n: jnp.asarray(p[f"block1_{n}"]), u,
                             Rows, (first, 2), 3)
@@ -353,22 +287,22 @@ def test_gauges_counters_and_refusals(served):
     assert [[a.shape for a in layer] for layer in eng._state] == [
         [(3, 4, 16, 16), (3, 3, 192)]] * 3
     with pytest.raises(ValueError, match="rolled back"):
-        engine(make(0), prefix_reuse=False, draft_params=make(0))
+        fam.engine(fam.init(0), prefix_reuse=False, draft_params=fam.init(0))
     with pytest.raises(ValueError, match="gqa_layers"):
         DeltaMoE(4, (), 4, 2, 16, 64, 4, 16, 4, 16, 3, (0, 4))
     with pytest.raises(ValueError, match="projects keys to"):
-        bad = make(0)
+        bad = fam.init(0)
         bad["block3_delta_k.w"] = bad["block3_delta_k.w"][:, :-1]
-        arch().check_params(bad, 64)
+        fam.arch().check_params(bad, 64)
     with pytest.raises(ValueError, match="hold 4 experts"):
-        arch((0, 8)).check_params(make(0), 64)
+        fam.arch(share=(0, 8)).check_params(fam.init(0), 64)
 
 
 def test_bfloat16_engine_stays_within_a_margin_of_the_reference():
     params = {k: np.asarray(jnp.asarray(v, jnp.bfloat16).astype(jnp.float32))
-              for k, v in make(4).items()}
+              for k, v in fam.init(4).items()}
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, V, n, dtype=np.int32) for n in (200, 33)]
-    outs = engine(params, compute_dtype="bfloat16").generate_many(
+    outs = fam.engine(params, compute_dtype="bfloat16")[0].generate_many(
         prompts, max_new_tokens=10)
-    assert max(gaps(params, prompts, outs)) < 0.3
+    assert max(tiny.gaps(fam, params, prompts, outs)) < 0.3

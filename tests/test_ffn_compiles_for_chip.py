@@ -16,7 +16,6 @@ the step's compiler makes another choice than the plain layer's, and
 the scan-remat engine's reading product (ISSUE 54) is held to it."""
 
 import collections
-import os
 import re
 
 import pytest
@@ -25,20 +24,6 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 ROWS, D_MODEL, D_FF = 4096, 1536, 6144
-
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
 
 
 def _op(h):

@@ -12,20 +12,17 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+import tiny  # noqa: E402
 from paddle_tpu.models import gated_moe_reference as ref  # noqa: E402
 from paddle_tpu.observability import trace  # noqa: E402
 from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 from paddle_tpu.serving import arch as arch_mod  # noqa: E402
-from paddle_tpu.serving import batched_decode as _bd  # noqa: E402
 from paddle_tpu.serving.arch import GatedMoE  # noqa: E402
+from tiny import gated_moe as fam  # noqa: E402
 
-# heads of 32 where d / heads is 16; 16 experts, top 4, 4 held (4..7)
-TINY = {"d": 64, "heads": 4, "kv_heads": 2, "dh": 32, "f": 128, "e": 48,
-        "experts": 16, "top_k": 4, "share": (4, 4), "window": 8,
-        "types": ("window", "window", "window", "window", "full"),
-        "dense": 1, "rows": 128, "scale": 2.448}
-T, B, PIECE = 48, 4, 8
+TINY = fam.sizes
+T, B, PIECE = fam.max_len, fam.block_tokens, fam.piece
 TOL = 2e-4
 # bfloat16 engine against the float32 reference on the same bfloat16
 # weights, judged by the margin of each generated token under the
@@ -33,143 +30,16 @@ TOL = 2e-4
 # of deviation 1.4; 0.25 is five times the worst seen (0.047)
 BF16_MARGIN = 0.25
 
-def _init(key, z, dtype, experts=None):
-    """Seeded weights under ``GatedMoE``'s names: matrices at 0.2 (a
-    width of 64 then gives activations of order one), the router at 0.3
-    so that its scores spread without saturating, gains near one before a sub-layer and
-    ``1 / sqrt(2 layers)`` after it; ``experts`` stacked per layer."""
-    n = len(z["types"])
-    experts = z["experts"] if experts is None else experts
-    keys = iter(jax.random.split(key, 24 * n + 4))
-    d, dh, e = z["d"], z["dh"], z["e"]
-    q, kv = z["heads"] * dh, z["kv_heads"] * dh
-
-    def normal(*shape, scale=0.2):
-        return (scale * jax.random.normal(next(keys), shape)).astype(dtype)
-
-    branch = (2 * n) ** -0.5
-    p = {"tok_emb.w": normal(z["rows"], d, scale=0.1),
-         "norm_f.scale": 1 + normal(d), "lm_head.w": normal(d, z["rows"])}
-    for i in range(n):
-        b = f"block{i}_"
-        p.update({
-            b + "norm1.scale": 1 + normal(d), b + "norm3.scale": 1 + normal(d),
-            b + "norm2.scale": branch * (1 + normal(d)),
-            b + "norm4.scale": branch * (1 + normal(d)),
-            b + "att_q.w": normal(d, q), b + "att_gate.w": normal(d, q),
-            b + "att_k.w": normal(d, kv), b + "att_v.w": normal(d, kv),
-            b + "att_out.w": normal(q, d),
-            b + "att_qnorm.scale": 1 + normal(dh),
-            b + "att_knorm.scale": 1 + normal(dh)})
-        if i < z["dense"]:
-            p.update({b + "ffn_gate.w": normal(d, z["f"]),
-                      b + "ffn_up.w": normal(d, z["f"]),
-                      b + "ffn_down.w": normal(z["f"], d)})
-        else:
-            p.update({
-                b + "router.w": normal(d, z["experts"], scale=0.3),
-                b + "router.bias": normal(z["experts"], scale=0.05),
-                b + "shared_gate.w": normal(d, e),
-                b + "shared_up.w": normal(d, e),
-                b + "shared_down.w": normal(e, d),
-                b + "experts_gate.w": normal(experts, d, e),
-                b + "experts_up.w": normal(experts, d, e),
-                b + "experts_down.w": normal(experts, e, d)})
-    return p
-
-
-def _share(p, first, count):
-    """The parameters a chip holding experts ``first .. first + count -
-    1`` has: the stacked experts sliced, everything else whole."""
-    return {k: (v[first:first + count] if "_experts_" in k else v)
-            for k, v in p.items()}
-
 
 @pytest.fixture(scope="module")
 def uncut():
     """All 16 experts, float32."""
-    return _init(jax.random.PRNGKey(34), TINY, jnp.float32)
+    return fam.init()
 
 
 @pytest.fixture(scope="module")
 def params(uncut):
-    held = _share(uncut, *TINY["share"])
-    return {"float32": held,
-            "bfloat16": {k: v.astype(jnp.bfloat16) for k, v in held.items()}}
-
-
-def _arch(share=TINY["share"], z=TINY):
-    return GatedMoE(z["types"], z["heads"], z["kv_heads"], z["dh"], z["d"],
-                    window=z["window"], dense_layers=z["dense"],
-                    router_width=z["experts"], top_k=z["top_k"],
-                    experts=share, route_scale=z["scale"])
-
-
-def _engine(p, monkeypatch, **kw):
-    monkeypatch.setattr(_bd, "PREFILL_PIECE", PIECE)
-    reg = MetricsRegistry()
-    kw.setdefault("max_slots", 2)
-    kw.setdefault("prefix_reuse", False)
-    eng = ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
-                        decode_chunk=4, min_bucket=4, donate=False,
-                        registry=reg, **kw)
-    return eng, reg
-
-
-def _reference(p, tokens, share=TINY["share"], **switches):
-    z = TINY
-    return np.asarray(ref.forward(
-        p, np.asarray(tokens)[None], z["types"], z["heads"], z["kv_heads"],
-        z["window"], z["dense"], z["top_k"], share, z["scale"],
-        **switches))[0]
-
-
-def _through_the_cache(eng, prompts, n_new):
-    """Each prompt into a slot of its own, prefilled in the pieces the
-    engine would dispatch (bucket padding and all), then ``n_new``
-    greedy decode steps for ALL slots at once.  Returns per slot
-    (tokens, logits at every position from the prompt's last on) and
-    the counts every call tallied."""
-    arch = eng.arch
-    S, nb = len(prompts), T // B
-    table = jnp.asarray(1 + np.arange(S * nb).reshape(S, nb), jnp.int32)
-
-    @jax.jit
-    def window(p, pk, pv, toks, at, n, row):
-        x, pk, pv, _, counts = _bd._window_forward(
-            p, pk, pv, toks[None], at[None], (at + n - 1)[None], row[None],
-            arch)
-        return arch.head(p, x[0])[n - 1], pk, pv, counts
-
-    @jax.jit
-    def step(p, pk, pv, tok, at):
-        lg, pk, pv, _, counts = _bd.paged_step_logits(p, tok, at, pk, pv,
-                                                      table, arch)
-        return lg, pk, pv, counts
-
-    pk, pv = eng._pk, eng._pv
-    logits, tallied = [[] for _ in prompts], []
-    for s, prompt in enumerate(prompts):
-        pieces = eng._pieces(np.asarray(prompt), 0)
-        assert len(pieces) >= 2 and pieces[-1][0] > pieces[-1][3]
-        for _w, padded, at, n in pieces:
-            lg, pk, pv, counts = window(eng._p, pk, pv, padded,
-                                        jnp.int32(at), jnp.int32(n), table[s])
-            tallied.append(("prefill", n, np.asarray(counts)))
-        logits[s].append(lg)
-    toks = [list(p_) for p_ in prompts]
-    for _ in range(n_new):
-        last = jnp.asarray([int(jnp.argmax(l[-1])) for l in logits],
-                           jnp.int32)
-        at = jnp.asarray([len(t_) for t_ in toks], jnp.int32)
-        for s in range(S):
-            toks[s].append(int(last[s]))
-        lg, pk, pv, counts = step(eng._p, pk, pv, last, at)
-        tallied.append(("decode", S, np.asarray(counts)))
-        for s in range(S):
-            logits[s].append(lg[s])
-    return ([(np.asarray(t_), np.asarray(jnp.stack(l), np.float32))
-             for t_, l in zip(toks, logits)], tallied)
+    return fam.held(uncut, ("float32", "bfloat16"))
 
 
 PROMPTS = [np.arange(3, 3 + 21) % 128, (7 * np.arange(11) + 5) % 128]
@@ -187,15 +57,11 @@ def served(params):
     try:
         out = {}
         for dt in ("float32", "bfloat16"):
-            eng, _ = _engine(params[dt], mp)
-            out[dt] = _through_the_cache(eng, PROMPTS, 14)
+            eng, _ = fam.engine(params[dt], mp)
+            out[dt] = tiny.through_the_cache(eng, PROMPTS, 14)
         return out
     finally:
         mp.undo()
-
-
-def _positions(prompt_len, lg):
-    return slice(prompt_len - 1, prompt_len - 1 + len(lg))
 
 
 def test_float32_through_the_cache_agrees_with_the_reference(served, params):
@@ -203,44 +69,14 @@ def test_float32_through_the_cache_agrees_with_the_reference(served, params):
     steps, contexts that pass the window: logits at every position."""
     for (toks, lg), prompt in zip(served["float32"][0], PROMPTS):
         assert len(toks) > TINY["window"] + len(prompt) // 2
-        want = _reference(params["float32"], toks)[_positions(len(prompt), lg)]
+        want = fam.reference(params["float32"], toks)[
+            tiny.positions(len(prompt), lg)]
         assert np.abs(lg - want).max() < TOL
 
 
 def _reference_counts(p, served_dtype):
-    """What each call of ``_through_the_cache`` should have tallied, from
-    the float32 reference's own selections at the same positions: ``[(n
-    rows x layers, pairs on a held expert, held experts touched, held
-    experts x layers)]`` in the calls' order."""
-    first, count = TINY["share"]
-    layers = len(TINY["types"]) - TINY["dense"]
-    runs, tallied = served_dtype
-    sels = []
-    for toks, _ in runs:
-        seen = []
-        _reference(p, toks, seen=seen)
-        sels.append(np.stack([np.asarray(s)[0] for s in seen]))  # [L, t, k]
-
-    def tally(sel, n):                                           # [L, n, k]
-        held = (sel >= first) & (sel < first + count)
-        return [n * layers, int(held.sum()),
-                sum(len(np.unique(sel[l][held[l]])) for l in range(layers)),
-                count * layers]
-
-    out, calls = [], iter(tallied)
-    for s, prompt in enumerate(PROMPTS):        # the prefill pieces
-        at = 0
-        while at < len(prompt):
-            phase, n, _ = next(calls)
-            assert phase == "prefill"
-            out.append(tally(sels[s][:, at:at + n], n))
-            at += n
-    for j, (phase, n, _) in enumerate(calls):   # the decode steps
-        assert phase == "decode"
-        out.append(tally(np.stack(
-            [sels[s][:, len(PROMPTS[s]) + j] for s in range(len(PROMPTS))],
-            axis=1), n))
-    return out
+    return tiny.reference_counts(fam, p, served_dtype, PROMPTS,
+                                 len(TINY["types"]) - TINY["dense"])
 
 
 def test_bfloat16_through_the_cache_stays_within_the_margin(served, params):
@@ -259,7 +95,7 @@ def test_bfloat16_through_the_cache_stays_within_the_margin(served, params):
              if got[0] == "decode"]
     compared = left_out = 0
     for (toks, lg), prompt in zip(runs, PROMPTS):
-        want = _reference(p, toks)[_positions(len(prompt), lg)]
+        want = fam.reference(p, toks)[tiny.positions(len(prompt), lg)]
         gen = toks[len(prompt):]
         gap = want[:len(gen)].max(-1) - want[np.arange(len(gen)), gen]
         # token j + 1 was chosen from the logits of decode step j
@@ -284,9 +120,9 @@ def test_each_line_left_out_fails_the_float32_comparison(served, params,
                                                          omission):
     worst = 0.0
     for (toks, lg), prompt in zip(served["float32"][0], PROMPTS):
-        want = _reference(params["float32"], toks, **OMISSIONS[omission])
+        want = fam.reference(params["float32"], toks, **OMISSIONS[omission])
         worst = max(worst, float(np.abs(
-            lg - want[_positions(len(prompt), lg)]).max()))
+            lg - want[tiny.positions(len(prompt), lg)]).max()))
     assert worst > 200 * TOL, worst
 
 
@@ -298,27 +134,6 @@ def test_the_counts_a_step_returns_equal_a_numpy_count(served, params):
     want = _reference_counts(params["float32"], served["float32"])
     got = [list(counts) for _, _, counts in served["float32"][1]]
     assert got == want
-
-
-class _Rows:
-    """The cache interface's ``valid`` for a routed layer called on its
-    own."""
-
-    def __init__(self, valid):
-        self.valid = valid
-
-
-def _routed_alone(p, i, x, share, valid=None):
-    """``arch.routed_ffn`` as ``GatedMoE`` calls it, layer ``i`` on rows
-    ``x [n, d]`` for the share ``share`` of the uncut parameters ``p``."""
-    arch = _arch(share)
-    held = _share(p, *share)
-    rows = _Rows(jnp.ones(x.shape[:-1], bool) if valid is None else valid)
-    h = arch_mod._rms(x, held[f"block{i}_norm3.scale"], arch.eps)
-    y, counts = arch_mod.routed_ffn(
-        lambda nm: held[f"block{i}_{nm}"], h, rows, arch.experts, arch.top_k,
-        arch.route_scale)
-    return np.asarray(y), np.asarray(counts)
 
 
 def test_the_shares_add_up_to_the_uncut_layer(uncut):
@@ -334,7 +149,7 @@ def test_the_shares_add_up_to_the_uncut_layer(uncut):
         routed=False))[0]
     parts, pairs = [], 0
     for first in range(0, z["experts"], 4):
-        y, counts = _routed_alone(uncut, i, x, (first, 4))
+        y, counts = tiny.routed_alone(fam, uncut, i, x, (first, 4))
         parts.append(y - shared)
         pairs += counts[1]
     assert pairs == 24 * z["top_k"]          # every pair is some chip's
@@ -404,9 +219,9 @@ def test_no_token_is_dropped_however_uneven_the_routing(uncut, routing):
     p[f"block{i}_router.bias"] = jnp.asarray(bias)
     x = jax.random.normal(jax.random.PRNGKey(2), (40, z["d"]))
     valid = jnp.arange(40) < 37              # three rows of padding
-    want = np.asarray(ref.routed_ffn(_share(p, *share), i, x[None],
+    want = np.asarray(ref.routed_ffn(tiny.share(p, *share), i, x[None],
                                      z["top_k"], share, z["scale"]))[0]
-    y, counts = _routed_alone(p, i, x, share, valid)
+    y, counts = tiny.routed_alone(fam, p, i, x, share, valid)
     assert np.abs(y - want)[:37].max() < TOL
     if routing == "all_rows_to_one_held_set":
         assert list(counts) == [37, 37 * 4, 4, 4]
@@ -415,7 +230,7 @@ def test_no_token_is_dropped_however_uneven_the_routing(uncut, routing):
     else:
         assert counts[2] <= 3
     # a padding row touches no expert: the shared expert alone
-    alone = np.asarray(ref.routed_ffn(_share(p, *share), i, x[None],
+    alone = np.asarray(ref.routed_ffn(tiny.share(p, *share), i, x[None],
                                       z["top_k"], share, z["scale"],
                                       routed=False))[0]
     assert np.abs(y - alone)[37:].max() < TOL
@@ -428,7 +243,7 @@ def test_engine_serves_three_requests_over_two_slots(params, monkeypatch,
     """The whole engine: admission, pieces, decode chunks, a slot
     released and admitted again; the routing counters against the
     engine's own bookkeeping."""
-    eng, reg = _engine(params[dtype], monkeypatch)
+    eng, reg = fam.engine(params[dtype], monkeypatch)
     prompts = [PROMPTS[0], PROMPTS[1], (5 * np.arange(17) + 1) % 128]
     tracer = trace.Tracer(enabled=True)
     old = trace.get_tracer()
@@ -440,7 +255,7 @@ def test_engine_serves_three_requests_over_two_slots(params, monkeypatch,
     for prompt, full in zip(prompts, outs):
         n_p = len(prompt)
         assert np.array_equal(full[:n_p], prompt)
-        want = _reference(params[dtype], full)[n_p - 1:len(full) - 1]
+        want = fam.reference(params[dtype], full)[n_p - 1:len(full) - 1]
         gap = want.max(-1) - want[np.arange(len(want)), full[n_p:]]
         if dtype == "float32":
             assert gap.max() < limit, gap.max()
@@ -484,7 +299,7 @@ def test_a_prefix_hit_over_window_and_full_planes(params, monkeypatch):
     prefix (whole blocks shared, the partial one forked copy-on-write)
     decodes what it decodes alone."""
     p = params["float32"]
-    eng, reg = _engine(p, monkeypatch, prefix_reuse=True, cache_blocks=12)
+    eng, reg = fam.engine(p, monkeypatch, prefix_reuse=True, cache_blocks=12)
     head = (3 * np.arange(18) + 2) % 128
     first = np.concatenate([head, [9, 8, 7]])
     second = np.concatenate([head, [1, 2, 3, 4, 5]])
@@ -493,7 +308,7 @@ def test_a_prefix_hit_over_window_and_full_planes(params, monkeypatch):
     st = eng.stats()
     assert st["serving.prefix_hit_rate"] > 0
     assert st.get("serving.cow_copies", 0) >= 1
-    want = _reference(p, out)[len(second) - 1:len(out) - 1]
+    want = fam.reference(p, out)[len(second) - 1:len(out) - 1]
     gap = want.max(-1) - want[np.arange(len(want)), out[len(second):]]
     assert gap.max() < 1e-3, gap.max()
     assert len(out) > len(second) + TINY["window"] // 2
@@ -503,7 +318,7 @@ def test_a_draft_model_is_refused(params):
     p = params["float32"]
     with pytest.raises(ValueError, match="speculative decoding serves the "
                        "GPT-2 block only.*'gated_moe'"):
-        ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
+        ServingEngine(p, arch=fam.arch(), max_len=T, block_tokens=B,
                       draft_params=dict(p))
 
 
@@ -511,7 +326,7 @@ def test_heads_wider_than_d_model_over_n_head_build_an_engine(params):
     """The head size is the architecture's own: 4 heads of 32 on a model
     64 wide (d / heads would be 16), and 5 heads, which do not divide
     it."""
-    eng = ServingEngine(params["float32"], arch=_arch(), max_len=T,
+    eng = ServingEngine(params["float32"], arch=fam.arch(), max_len=T,
                         block_tokens=B, prefix_reuse=False,
                         registry=MetricsRegistry())
     assert eng.arch.head_dim == 32 != eng.arch.d_model // eng.arch.n_head

@@ -14,23 +14,17 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+import tiny  # noqa: E402
 from paddle_tpu.kernels import paged_attention as paged  # noqa: E402
 from paddle_tpu.models import latent_moe_reference as ref  # noqa: E402
 from paddle_tpu.observability import trace  # noqa: E402
-from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 from paddle_tpu.serving import arch as arch_mod  # noqa: E402
-from paddle_tpu.serving import batched_decode as _bd  # noqa: E402
 from paddle_tpu.serving.arch import LatentMoE  # noqa: E402
+from tiny import latent_moe as fam  # noqa: E402
 
-# 4 heads of 16 | 8 query lanes and 16 value lanes over a latent of 32;
-# 16 experts, top 3, 4 held (4..7), the shared MLP 2 x 24 wide; a dense
-# layer and three routed ones; YaRN factor 4 over an original 16
-TINY = {"d": 64, "heads": 4, "nope": 16, "rope": 8, "v": 16, "rank": 32,
-        "f": 128, "e": 24, "shared": 48, "experts": 16, "top_k": 3,
-        "share": (4, 4), "layers": 4, "dense": 1, "rows": 128, "scale": 1.0,
-        "yarn": (10000.0, 4.0, 16, 32.0, 1.0, 0.707, 0.707)}
-T, B, PIECE = 48, 4, 8
+TINY = fam.sizes
+T, B, PIECE = fam.max_len, fam.block_tokens, fam.piece
 TOL = 2e-4
 # bfloat16 engine against the float32 reference on the same bfloat16
 # weights, judged by the margin of each generated token under the
@@ -40,143 +34,15 @@ TOL = 2e-4
 BF16_MARGIN = 0.25
 
 
-def _init(key, z, dtype, experts=None):
-    """Seeded weights under ``LatentMoE``'s names: matrices at 0.2 (a
-    width of 64 then gives activations of order one), the router at 0.5
-    so that its softmax spreads, gains near one; ``experts`` stacked per
-    layer."""
-    n = z["layers"]
-    experts = z["experts"] if experts is None else experts
-    keys = iter(jax.random.split(key, 24 * n + 4))
-    d, e, h = z["d"], z["e"], z["heads"]
-
-    def normal(*shape, scale=0.2):
-        return (scale * jax.random.normal(next(keys), shape)).astype(dtype)
-
-    p = {"tok_emb.w": normal(z["rows"], d, scale=1.0),
-         "norm_f.scale": 1 + normal(d), "lm_head.w": normal(d, z["rows"])}
-    for i in range(n):
-        b = f"block{i}_"
-        p.update({
-            b + "norm1.scale": 1 + normal(d), b + "norm2.scale": 1 + normal(d),
-            b + "att_q.w": normal(d, h * (z["nope"] + z["rope"])),
-            b + "att_kva.w": normal(d, z["rank"] + z["rope"]),
-            b + "att_kvnorm.scale": 1 + normal(z["rank"]),
-            b + "att_kvb.w": normal(z["rank"], h * (z["nope"] + z["v"])),
-            b + "att_out.w": normal(h * z["v"], d, scale=0.1)})
-        if i < z["dense"]:
-            p.update({b + "ffn_gate.w": normal(d, z["f"]),
-                      b + "ffn_up.w": normal(d, z["f"]),
-                      b + "ffn_down.w": normal(z["f"], d, scale=0.1)})
-        else:
-            p.update({
-                b + "router.w": normal(d, z["experts"], scale=0.5),
-                b + "shared_gate.w": normal(d, z["shared"]),
-                b + "shared_up.w": normal(d, z["shared"]),
-                b + "shared_down.w": normal(z["shared"], d, scale=0.1),
-                b + "experts_gate.w": normal(experts, d, e),
-                b + "experts_up.w": normal(experts, d, e),
-                b + "experts_down.w": normal(experts, e, d)})
-    return p
-
-
-def _share(p, first, count):
-    return {k: (v[first:first + count] if "_experts_" in k else v)
-            for k, v in p.items()}
-
-
 @pytest.fixture(scope="module")
 def uncut():
     """All 16 experts, float32."""
-    return _init(jax.random.PRNGKey(40), TINY, jnp.float32)
+    return fam.init()
 
 
 @pytest.fixture(scope="module")
 def params(uncut):
-    held = _share(uncut, *TINY["share"])
-    return {"float32": held,
-            "bfloat16": {k: v.astype(jnp.bfloat16) for k, v in held.items()}}
-
-
-def _arch(share=TINY["share"], z=TINY):
-    theta, factor, original, fast, slow, m, m_all = z["yarn"]
-    return LatentMoE(z["layers"], z["heads"], z["d"], rank=z["rank"],
-                     nope_dim=z["nope"], rope_dim=z["rope"], v_dim=z["v"],
-                     dense_layers=z["dense"], router_width=z["experts"],
-                     top_k=z["top_k"], experts=share, route_scale=z["scale"],
-                     rope_theta=theta, rope_factor=factor,
-                     rope_original=original, beta_fast=fast, beta_slow=slow,
-                     mscale=m, mscale_all_dim=m_all)
-
-
-def _engine(p, monkeypatch, **kw):
-    monkeypatch.setattr(_bd, "PREFILL_PIECE", PIECE)
-    reg = MetricsRegistry()
-    kw.setdefault("max_slots", 2)
-    kw.setdefault("prefix_reuse", False)
-    eng = ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
-                        decode_chunk=4, min_bucket=4, donate=False,
-                        registry=reg, **kw)
-    return eng, reg
-
-
-def _layout(share=TINY["share"], z=TINY):
-    return (z["layers"], z["heads"], z["rank"], z["nope"], z["rope"], z["v"],
-            z["dense"], z["top_k"], share, z["scale"], z["yarn"])
-
-
-def _reference(p, tokens, share=TINY["share"], **switches):
-    return np.asarray(ref.forward(p, np.asarray(tokens)[None],
-                                  *_layout(share), **switches))[0]
-
-
-def _through_the_cache(eng, prompts, n_new):
-    """Each prompt into a slot of its own, prefilled in the pieces the
-    engine would dispatch (bucket padding and all), then ``n_new``
-    greedy decode steps for ALL slots at once.  Returns per slot
-    (tokens, logits at every position from the prompt's last on) and
-    the counts every call tallied."""
-    arch = eng.arch
-    S, nb = len(prompts), T // B
-    table = jnp.asarray(1 + np.arange(S * nb).reshape(S, nb), jnp.int32)
-
-    @jax.jit
-    def window(p, pk, pv, toks, at, n, row):
-        x, pk, pv, _, counts = _bd._window_forward(
-            p, pk, pv, toks[None], at[None], (at + n - 1)[None], row[None],
-            arch)
-        return arch.head(p, x[0])[n - 1], pk, pv, counts
-
-    @jax.jit
-    def step(p, pk, pv, tok, at):
-        lg, pk, pv, _, counts = _bd.paged_step_logits(p, tok, at, pk, pv,
-                                                      table, arch)
-        return lg, pk, pv, counts
-
-    pk, pv = eng._pk, eng._pv
-    assert pv == ()
-    logits, tallied = [[] for _ in prompts], []
-    for s, prompt in enumerate(prompts):
-        pieces = eng._pieces(np.asarray(prompt), 0)
-        assert len(pieces) >= 2 and pieces[-1][0] > pieces[-1][3]
-        for _w, padded, at, n in pieces:
-            lg, pk, pv, counts = window(eng._p, pk, pv, padded,
-                                        jnp.int32(at), jnp.int32(n), table[s])
-            tallied.append(("prefill", n, np.asarray(counts)))
-        logits[s].append(lg)
-    toks = [list(p_) for p_ in prompts]
-    for _ in range(n_new):
-        last = jnp.asarray([int(jnp.argmax(l[-1])) for l in logits],
-                           jnp.int32)
-        at = jnp.asarray([len(t_) for t_ in toks], jnp.int32)
-        for s in range(S):
-            toks[s].append(int(last[s]))
-        lg, pk, pv, counts = step(eng._p, pk, pv, last, at)
-        tallied.append(("decode", S, np.asarray(counts)))
-        for s in range(S):
-            logits[s].append(lg[s])
-    return ([(np.asarray(t_), np.asarray(jnp.stack(l), np.float32))
-             for t_, l in zip(toks, logits)], tallied)
+    return fam.held(uncut, ("float32", "bfloat16"))
 
 
 PROMPTS = [np.arange(3, 3 + 21) % 128, (7 * np.arange(11) + 5) % 128]
@@ -194,15 +60,11 @@ def served(params):
     try:
         out = {}
         for dt in ("float32", "bfloat16"):
-            eng, _ = _engine(params[dt], mp)
-            out[dt] = _through_the_cache(eng, PROMPTS, 14)
+            eng, _ = fam.engine(params[dt], mp)
+            out[dt] = tiny.through_the_cache(eng, PROMPTS, 14)
         return out
     finally:
         mp.undo()
-
-
-def _positions(prompt_len, lg):
-    return slice(prompt_len - 1, prompt_len - 1 + len(lg))
 
 
 def test_float32_through_the_latent_cache_agrees_with_the_reference(
@@ -213,7 +75,8 @@ def test_float32_through_the_latent_cache_agrees_with_the_reference(
     original = TINY["yarn"][2]
     for (toks, lg), prompt in zip(served["float32"][0], PROMPTS):
         assert len(toks) > original + 4
-        want = _reference(params["float32"], toks)[_positions(len(prompt), lg)]
+        want = fam.reference(params["float32"], toks)[
+            tiny.positions(len(prompt), lg)]
         assert np.abs(lg - want).max() < TOL
 
 
@@ -222,7 +85,7 @@ def test_absorbed_attention_equals_the_per_head_form(params):
     the latent, values the latent's own lanes, ``u W_UV`` after, equals
     keys and values made per head from the latent (float32, 1e-5)."""
     z, p = TINY, params["float32"]
-    arch = _arch()
+    arch = fam.arch()
     rng = np.random.default_rng(0)
     t, h, rank, nope = 19, z["heads"], z["rank"], z["nope"]
     q = rng.standard_normal((t, h, nope + z["rope"])).astype(np.float32)
@@ -257,37 +120,8 @@ def test_absorbed_attention_equals_the_per_head_form(params):
 
 
 def _reference_counts(p, served_dtype):
-    """What each call of ``_through_the_cache`` should have tallied, from
-    the float32 reference's own selections at the same positions."""
-    first, count = TINY["share"]
-    layers = TINY["layers"] - TINY["dense"]
-    runs, tallied = served_dtype
-    sels = []
-    for toks, _ in runs:
-        seen = []
-        _reference(p, toks, seen=seen)
-        sels.append(np.stack([np.asarray(s)[0] for s in seen]))  # [L, t, k]
-
-    def tally(sel, n):                                           # [L, n, k]
-        held = (sel >= first) & (sel < first + count)
-        return [n * layers, int(held.sum()),
-                sum(len(np.unique(sel[l][held[l]])) for l in range(layers)),
-                count * layers]
-
-    out, calls = [], iter(tallied)
-    for s, prompt in enumerate(PROMPTS):        # the prefill pieces
-        at = 0
-        while at < len(prompt):
-            phase, n, _ = next(calls)
-            assert phase == "prefill"
-            out.append(tally(sels[s][:, at:at + n], n))
-            at += n
-    for j, (phase, n, _) in enumerate(calls):   # the decode steps
-        assert phase == "decode"
-        out.append(tally(np.stack(
-            [sels[s][:, len(PROMPTS[s]) + j] for s in range(len(PROMPTS))],
-            axis=1), n))
-    return out
+    return tiny.reference_counts(fam, p, served_dtype, PROMPTS,
+                                 TINY["layers"] - TINY["dense"])
 
 
 def test_bfloat16_through_the_cache_stays_within_the_margin(served, params):
@@ -305,7 +139,7 @@ def test_bfloat16_through_the_cache_stays_within_the_margin(served, params):
              if got[0] == "decode"]
     compared = left_out = 0
     for (toks, lg), prompt in zip(runs, PROMPTS):
-        want = _reference(p, toks)[_positions(len(prompt), lg)]
+        want = fam.reference(p, toks)[tiny.positions(len(prompt), lg)]
         gen = toks[len(prompt):]
         gap = want[:len(gen)].max(-1) - want[np.arange(len(gen)), gen]
         keep = np.array([True] + steps[:len(gen) - 1])
@@ -330,9 +164,9 @@ def test_each_line_left_out_fails_the_float32_comparison(served, params,
                                                          omission):
     worst = 0.0
     for (toks, lg), prompt in zip(served["float32"][0], PROMPTS):
-        want = _reference(params["float32"], toks, **OMISSIONS[omission])
+        want = fam.reference(params["float32"], toks, **OMISSIONS[omission])
         worst = max(worst, float(np.abs(
-            lg - want[_positions(len(prompt), lg)]).max()))
+            lg - want[tiny.positions(len(prompt), lg)]).max()))
     assert worst > 100 * TOL, worst
 
 
@@ -340,27 +174,6 @@ def test_the_counts_a_step_returns_equal_a_numpy_count(served, params):
     want = _reference_counts(params["float32"], served["float32"])
     got = [list(counts) for _, _, counts in served["float32"][1]]
     assert got == want
-
-
-class _Rows:
-    """The cache interface's ``valid`` for a routed layer called on its
-    own."""
-
-    def __init__(self, valid):
-        self.valid = valid
-
-
-def _routed_alone(p, i, x, share, valid=None):
-    """``arch.routed_ffn`` as ``LatentMoE`` calls it, layer ``i`` on rows
-    ``x [n, d]`` for the share ``share`` of the uncut parameters."""
-    arch = _arch(share)
-    held = _share(p, *share)
-    rows = _Rows(jnp.ones(x.shape[:-1], bool) if valid is None else valid)
-    h = arch_mod._rms(x, held[f"block{i}_norm2.scale"], arch.eps)
-    y, counts = arch_mod.routed_ffn(
-        lambda nm: held[f"block{i}_{nm}"], h, rows, arch.experts, arch.top_k,
-        arch.route_scale, score="softmax", normalise=False, bias=False)
-    return np.asarray(y), np.asarray(counts)
 
 
 def test_the_shares_add_up_to_the_uncut_layer(uncut):
@@ -375,7 +188,7 @@ def test_the_shares_add_up_to_the_uncut_layer(uncut):
         routed=False))[0]
     parts, pairs = [], 0
     for first in range(0, z["experts"], 4):
-        y, counts = _routed_alone(uncut, i, x, (first, 4))
+        y, counts = tiny.routed_alone(fam, uncut, i, x, (first, 4))
         parts.append(y - shared)
         pairs += counts[1]
     assert pairs == 24 * z["top_k"]          # every pair is some chip's
@@ -463,15 +276,15 @@ def test_no_token_is_dropped_however_uneven_the_routing(uncut, routing):
     push[:, held_columns] = sign * 40.0 * column[:, None]
     p[f"block{i}_router.w"] = p[f"block{i}_router.w"] + jnp.asarray(push)
     valid = jnp.arange(40) < 37              # three rows of padding
-    want = np.asarray(ref.routed_ffn(_share(p, *share), i, x[None],
+    want = np.asarray(ref.routed_ffn(tiny.share(p, *share), i, x[None],
                                      z["top_k"], share, z["scale"]))[0]
-    y, counts = _routed_alone(p, i, x, share, valid)
+    y, counts = tiny.routed_alone(fam, p, i, x, share, valid)
     assert np.abs(y - want)[:37].max() < TOL
     if routing == "all_rows_to_one_held_set":
         assert list(counts) == [37, 37 * 3, 3, 4]
     else:
         assert list(counts) == [37, 0, 0, 4]
-    alone = np.asarray(ref.routed_ffn(_share(p, *share), i, x[None],
+    alone = np.asarray(ref.routed_ffn(tiny.share(p, *share), i, x[None],
                                       z["top_k"], share, z["scale"],
                                       routed=False))[0]
     assert np.abs(y - alone)[37:].max() < TOL
@@ -485,7 +298,7 @@ def test_engine_serves_three_requests_over_two_slots(params, monkeypatch,
     released and admitted again; the gauges of the latent plane, the
     routing counters and the latent positions against a NumPy count of
     the engine's own spans."""
-    eng, reg = _engine(params[dtype], monkeypatch)
+    eng, reg = fam.engine(params[dtype], monkeypatch)
     prompts = [PROMPTS[0], PROMPTS[1], (5 * np.arange(17) + 1) % 128]
     tracer = trace.Tracer(enabled=True)
     old = trace.get_tracer()
@@ -497,7 +310,7 @@ def test_engine_serves_three_requests_over_two_slots(params, monkeypatch,
     for prompt, full in zip(prompts, outs):
         n_p = len(prompt)
         assert np.array_equal(full[:n_p], prompt)
-        want = _reference(params[dtype], full)[n_p - 1:len(full) - 1]
+        want = fam.reference(params[dtype], full)[n_p - 1:len(full) - 1]
         gap = want.max(-1) - want[np.arange(len(want)), full[n_p:]]
         if dtype == "float32":
             assert gap.max() < limit, gap.max()
@@ -563,7 +376,7 @@ def test_a_prefix_hit_on_a_shared_head_then_a_fork(params, monkeypatch):
     reference says; while both are live the decode calls count the
     shared head's entries as shared."""
     p = params["float32"]
-    eng, reg = _engine(p, monkeypatch, prefix_reuse=True, cache_blocks=12)
+    eng, reg = fam.engine(p, monkeypatch, prefix_reuse=True, cache_blocks=12)
     head = (3 * np.arange(18) + 2) % 128
     first = np.concatenate([head, [9, 8, 7]])
     second = np.concatenate([head, [1, 2, 3, 4, 5]])
@@ -573,14 +386,14 @@ def test_a_prefix_hit_on_a_shared_head_then_a_fork(params, monkeypatch):
     st = eng.stats()
     assert st["serving.prefix_hit_rate"] > 0
     assert st.get("serving.cow_copies", 0) >= 1
-    want = _reference(p, out)[len(second) - 1:len(out) - 1]
+    want = fam.reference(p, out)[len(second) - 1:len(out) - 1]
     gap = want.max(-1) - want[np.arange(len(want)), out[len(second):]]
     assert gap.max() < 1e-3, gap.max()
     assert st["serving.paged_entries_shared"] == 0   # one live slot a time
     # two live slots over one head: its four whole blocks are named twice
     outs = eng.generate_many([second, third], max_new_tokens=[8, 8])
     for prompt, full in zip((second, third), outs):
-        want = _reference(p, full)[len(prompt) - 1:len(full) - 1]
+        want = fam.reference(p, full)[len(prompt) - 1:len(full) - 1]
         gap = want.max(-1) - want[np.arange(len(want)), full[len(prompt):]]
         assert gap.max() < 1e-3, gap.max()
     st = eng.stats()
@@ -594,8 +407,8 @@ def test_paged_entries_shared_against_a_numpy_count(params, monkeypatch):
     """``serving.paged_entries_shared``: of the table entries the decode
     calls visit, those whose block two live slots name, against a count
     over a table written by hand."""
-    eng, reg = _engine(params["float32"], monkeypatch, prefix_reuse=True,
-                       cache_blocks=12, max_slots=3)
+    eng, reg = fam.engine(params["float32"], monkeypatch, prefix_reuse=True,
+                          cache_blocks=12, max_slots=3)
 
     class _Req:
         def __init__(self, n):
@@ -617,7 +430,7 @@ def test_a_draft_model_is_refused(params):
     p = params["float32"]
     with pytest.raises(ValueError, match="speculative decoding serves the "
                        "GPT-2 block only.*'latent_moe'"):
-        ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
+        ServingEngine(p, arch=fam.arch(), max_len=T, block_tokens=B,
                       draft_params=dict(p))
 
 
@@ -637,7 +450,7 @@ def test_kv_bytes_per_token_of_the_other_four_is_unchanged():
     for arch in (gpt, loop, samba, moe):
         assert arch.pool_arrays == 2 and arch.latent_planes == 0
         assert arch.written_values == arch.kv_heads * arch.head_dim
-    latent = _arch()
+    latent = fam.arch()
     assert latent.pool_arrays == 1
     assert latent.kv_bytes_per_token(2) == 4 * 2 * 128
 

@@ -6,21 +6,14 @@ same call told nothing (interpret mode), the host's runs from tables
 written by hand, and ``serving.paged_entries_fetched`` held to a hand
 count."""
 
-import os
-import sys
 import types
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
-sys.path.insert(0, os.path.dirname(__file__))
-
-import test_latent_moe as tiny  # noqa: E402
-from paddle_tpu.kernels import paged_attention as paged  # noqa: E402
-
-from test_latent_moe import params, uncut  # noqa: E402,F401 - fixtures
+from paddle_tpu.kernels import paged_attention as paged
+from tiny import latent_moe as fam
 
 B, NB, H, L, DV, G = 4, 12, 4, 128, 64, 2
 
@@ -212,6 +205,11 @@ def test_the_hosts_runs_from_hand_made_table_rows(name):
         assert r[1] or not r[2:].any()
 
 
+@pytest.fixture(scope="module")
+def params():
+    return fam.held(fam.init())
+
+
 class _Req:
     def __init__(self, n):
         self.prompt, self.tokens = np.zeros(n, np.int32), []
@@ -222,7 +220,7 @@ def test_paged_entries_fetched_against_a_hand_count(params, monkeypatch):
     less a shared run's for every member after its first; and the runs
     the engine sends, over a table written by hand."""
     monkeypatch.setattr(paged, "LATENT_BLOCKS", 2)
-    eng, reg = tiny._engine(params["float32"], monkeypatch,
+    eng, reg = fam.engine(params["float32"], monkeypatch,
                             prefix_reuse=True, cache_blocks=12, max_slots=4)
     eng._slots = [_Req(18), _Req(13), None, _Req(22)]
     eng._table[0, :5] = [3, 4, 5, 6, 7]       # 18 keys: 5 entries
@@ -240,7 +238,7 @@ def test_paged_entries_fetched_against_a_hand_count(params, monkeypatch):
     assert reg.value("serving.paged_entries_fetched",
                      phase="decode") == 5 + 4 + 6 - 4
     # an engine without a trie tells nothing and counts nothing
-    eng, reg = tiny._engine(params["float32"], monkeypatch, max_slots=4)
+    eng, reg = fam.engine(params["float32"], monkeypatch, max_slots=4)
     assert eng._shared_runs(contexts) is None
     eng._slots = [_Req(18), _Req(13), None, _Req(22)]
     eng._count_paged_entries(contexts)
@@ -264,7 +262,7 @@ def test_two_slots_on_one_head_decode_through_the_told_kernel(
         types.SimpleNamespace(impl=types.SimpleNamespace(call=mosaic))
         if op == "paged_attention" and not kw else real(op, **kw)))
     p = params["float32"]
-    eng, reg = tiny._engine(p, monkeypatch, prefix_reuse=True,
+    eng, reg = fam.engine(p, monkeypatch, prefix_reuse=True,
                             cache_blocks=12)
     head = (3 * np.arange(18) + 2) % 128
     second = np.concatenate([head, [1, 2, 3, 4, 5]])
@@ -274,7 +272,7 @@ def test_two_slots_on_one_head_decode_through_the_told_kernel(
     outs = eng.generate_many([second, third], max_new_tokens=[8, 8])
     assert any(seen)
     for prompt, full in zip((second, third), outs):
-        want = tiny._reference(p, full)[len(prompt) - 1:len(full) - 1]
+        want = fam.reference(p, full)[len(prompt) - 1:len(full) - 1]
         gap = want.max(-1) - want[np.arange(len(want)), full[len(prompt):]]
         assert gap.max() < 1e-3, gap.max()
     st = eng.stats()

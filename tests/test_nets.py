@@ -6,7 +6,7 @@ import numpy as np
 import paddle_tpu as pt
 from paddle_tpu import layers, nets
 
-from test_book import train_steps
+from tiny import train_steps
 
 
 def test_img_conv_bn_pool_and_separable():

@@ -18,17 +18,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.models import ouro_reference as ref
 from paddle_tpu.models.transformer import infer_compute_dtype
 from paddle_tpu.observability import trace
 from paddle_tpu.observability.metrics import MetricsRegistry
 from paddle_tpu.serving import ServingEngine, speculative
 from paddle_tpu.serving import batched_decode as _bd
 from paddle_tpu.serving.arch import Gpt2, LoopedRmsRope
+from tiny import ouro as fam
 
-VOCAB, NL, NH, DM, FF, PASSES, T, B = 128, 2, 4, 64, 96, 3, 64, 4
-EPS, THETA = 1e-6, 1e6
-PIECE = 8           # the piece width these tests give the engine
+VOCAB, NL, NH, DM, PASSES = (fam.sizes[k] for k in (
+    "rows", "layers", "heads", "d", "passes"))
+T, B = fam.max_len, fam.block_tokens
+PIECE = fam.piece   # the piece width these tests give the engine
 # float32 engine against the float32 reference: the two differ in
 # reduction order (the cache attends block by block with an online
 # softmax, a window's matmuls reduce in another shape) and in the CPU
@@ -42,46 +43,6 @@ TOL = 1e-4
 # gap seen 0.33 (mean 0.05) on logits of deviation 1.6; twice that, and
 # still 6 times under the least fault
 BF16_MARGIN = 0.7
-
-
-def _params(dtype=jnp.float32, n_layer=NL):
-    rng = np.random.default_rng(28)
-
-    def w(*shape, scale=0.2):
-        return jnp.asarray(rng.normal(0.0, scale, shape), dtype)
-
-    p = {"tok_emb.w": w(VOCAB, DM, scale=1.0), "norm_f.scale": 1 + w(DM),
-         "exit_gate.w": w(DM, 1), "exit_gate.b": w(1),
-         "lm_head.w": w(DM, VOCAB)}
-    for i in range(n_layer):
-        for nm, shape in (("att_q", (DM, DM)), ("att_k", (DM, DM)),
-                          ("att_v", (DM, DM)), ("att_out", (DM, DM)),
-                          ("ffn_gate", (DM, FF)), ("ffn_up", (DM, FF)),
-                          ("ffn_down", (FF, DM))):
-            p[f"block{i}_{nm}.w"] = w(*shape)
-        for nm in ("norm1", "norm2", "norm3", "norm4"):
-            p[f"block{i}_{nm}.scale"] = 1 + w(DM)
-    return p
-
-
-def _arch(passes=PASSES, cls=LoopedRmsRope, n_layer=NL):
-    return cls(n_layer, NH, DM, passes, eps=EPS, rope_theta=THETA)
-
-
-def _engine(params, monkeypatch, arch=None, **kw):
-    monkeypatch.setattr(_bd, "PREFILL_PIECE", PIECE)
-    reg = MetricsRegistry()
-    kw.setdefault("max_slots", 3)
-    eng = ServingEngine(params, arch=arch or _arch(), max_len=T,
-                        block_tokens=B, decode_chunk=4, min_bucket=4,
-                        donate=False, registry=reg, **kw)
-    return eng, reg
-
-
-def _reference(params, tokens, **kw):
-    kw.setdefault("passes", PASSES)
-    return np.asarray(ref.logits(params, np.asarray(tokens)[None], NL, NH,
-                                 eps=EPS, rope_theta=THETA, **kw))[0]
 
 
 def _row(first, n=T // B):
@@ -159,8 +120,8 @@ def served():
     and a bucket of 4) and N_NEW greedy tokens, and the reference's."""
     mp = pytest.MonkeyPatch()
     try:
-        params = _params()
-        eng, _ = _engine(params, mp)
+        params = fam.init()
+        eng, _ = fam.engine(params, mp)
         got, toks, _ = _served_logits(eng, PROMPT, N_NEW)
         full = np.concatenate([PROMPT, toks])
         return params, got, toks, full
@@ -169,7 +130,7 @@ def served():
 
 
 def _want(params, full, **kw):
-    return _reference(params, full, **kw)[len(PROMPT) - 1:len(full) - 1]
+    return fam.reference(params, full, **kw)[len(PROMPT) - 1:len(full) - 1]
 
 
 def test_float32_logits_equal_the_reference_at_every_position(served):
@@ -184,7 +145,7 @@ def test_engine_tokens_equal_the_reference_greedy_chain(monkeypatch, served):
     """Tokens through ``generate_many`` (the driver loop, the trie, the
     compiled executables with the pool folded by pass)."""
     params, _got, toks, _full = served
-    eng, reg = _engine(params, monkeypatch)
+    eng, reg = fam.engine(params, monkeypatch)
     out = eng.generate_many([PROMPT], max_new_tokens=N_NEW)[0]
     assert list(out[len(PROMPT):]) == toks
     assert reg.value("serving.prefill_pieces", width=PIECE) == 2
@@ -196,7 +157,7 @@ def test_bfloat16_engine_within_its_margin_and_outside_float32s(
     here) fails the float32 tolerance; the bf16 engine has its own."""
     params, _got, _toks, full = served
     p16 = {k: jnp.asarray(v, jnp.bfloat16) for k, v in params.items()}
-    eng, _ = _engine(p16, monkeypatch)
+    eng, _ = fam.engine(p16, monkeypatch)
     assert eng.compute_dtype == jnp.bfloat16
     # teacher-forced on the float32 chain, so that positions compare
     first, pk, pv = _prefill(eng, eng._pk, eng._pv, _row(1), PROMPT, 0)
@@ -229,7 +190,7 @@ class _SharedPlane(LoopedRmsRope):
 def test_the_comparison_bites_on_a_plane_shared_by_the_passes(
         monkeypatch, served):
     params, _got, _toks, full = served
-    eng, _ = _engine(params, monkeypatch, arch=_arch(cls=_SharedPlane))
+    eng, _ = fam.engine(params, monkeypatch, arch=fam.arch(cls=_SharedPlane))
     got, _t, _ = _served_logits(eng, PROMPT, N_NEW)
     # the first logits come from one window per piece, whose later
     # pieces already attend the wrong pass's rows
@@ -242,7 +203,7 @@ def test_shared_prefix_and_copy_on_write_give_the_unshared_logits(
     request C its first 6, forking the second block copy-on-write in
     every plane of every pass: same logits as prefilling alone."""
     params, _got, _toks, _full = served
-    eng, _ = _engine(params, monkeypatch)
+    eng, _ = fam.engine(params, monkeypatch)
     rng = np.random.default_rng(6)
     _first, pk, pv = _prefill(eng, eng._pk, eng._pv, _row(1), PROMPT, 0)
     for shared, fork in ((8, None), (6, (2, 60))):
@@ -262,7 +223,7 @@ def test_shared_prefix_and_copy_on_write_give_the_unshared_logits(
                                        start=shared, pools=pools)
         np.testing.assert_allclose(got, alone, atol=TOL, rtol=0)
         assert toks2 == toks
-        want = _reference(params, np.concatenate([prompt, toks]))
+        want = fam.reference(params, np.concatenate([prompt, toks]))
         np.testing.assert_allclose(
             got, want[len(prompt) - 1:len(prompt) + 3], atol=TOL, rtol=0)
 
@@ -271,7 +232,7 @@ def test_engine_serves_prefix_traffic_token_identical(monkeypatch, served):
     """Two waves through the driver: a cold prompt, the same again (full
     blocks hit), a fork inside a cached block, an unrelated one."""
     params, *_ = served
-    eng, reg = _engine(params, monkeypatch)
+    eng, reg = fam.engine(params, monkeypatch)
     rng = np.random.default_rng(7)
     prompts = [PROMPT, PROMPT.copy(),
                np.concatenate([PROMPT[:6],
@@ -280,10 +241,12 @@ def test_engine_serves_prefix_traffic_token_identical(monkeypatch, served):
     outs = eng.generate_many(prompts[:1], max_new_tokens=6)
     outs += eng.generate_many(prompts[1:], max_new_tokens=6)
     for p, o in zip(prompts, outs):
-        chain = list(p)
-        for _ in range(6):
-            chain.append(int(_reference(params, chain)[-1].argmax()))
-        assert list(o) == chain
+        # the reference's greedy chain, read off ONE forward over what
+        # the engine wrote: by causality token j + 1 of a chain that
+        # agrees so far is the argmax at position j
+        assert list(o[:len(p)]) == list(p) and len(o) == len(p) + 6
+        want = fam.reference(params, o)[len(p) - 1:len(o) - 1].argmax(-1)
+        assert list(o[len(p):]) == list(want)
     st = eng.stats()
     assert st["serving.prefix_hit_rate"] > 0
     assert st["serving.cow_copies"] >= 1
@@ -303,11 +266,11 @@ class _Unlooped(LoopedRmsRope):
 @pytest.mark.parametrize("passes", [1, PASSES])
 def test_the_looped_forward_equals_the_same_stack_unlooped(
         monkeypatch, passes):
-    params = _params()
+    params = fam.init()
     out = []
     for cls in (LoopedRmsRope, _Unlooped):
-        eng, _ = _engine(params, monkeypatch,
-                         arch=_arch(passes=passes, cls=cls))
+        eng, _ = fam.engine(params, monkeypatch,
+                            arch=fam.arch(passes=passes, cls=cls))
         out.append(_served_logits(eng, PROMPT, 3)[0])
     np.testing.assert_allclose(out[0], out[1], atol=TOL, rtol=0)
 
@@ -332,21 +295,21 @@ def _lowered_counts(arch, params):
 
 
 def test_the_lowered_programs_do_not_grow_with_the_passes():
-    params = _params()
-    one = _lowered_counts(_arch(passes=1), params)
-    four = _lowered_counts(_arch(passes=4), params)
+    params = fam.init()
+    one = _lowered_counts(fam.arch(passes=1), params)
+    four = _lowered_counts(fam.arch(passes=4), params)
     assert one == four
     dots, loops = one[0]
     # seven projections a layer and the head, besides the attention's
     assert dots >= 7 * NL + 1 and loops >= 2
     # and a stack unrolled in Python does grow: the count means something
-    assert _lowered_counts(_arch(passes=4, cls=_Unlooped),
+    assert _lowered_counts(fam.arch(passes=4, cls=_Unlooped),
                            params)[0][0] > dots
 
 
 def test_pool_is_sized_in_bytes_from_the_planes(monkeypatch):
-    params = _params(jnp.bfloat16)
-    eng, reg = _engine(params, monkeypatch, max_slots=2, cache_blocks=5)
+    params = fam.init(dtype=jnp.bfloat16)
+    eng, reg = fam.engine(params, monkeypatch, max_slots=2, cache_blocks=5)
     blocks = 1 + 2 * (T // B) + 5
     assert eng.kv_pool.num_blocks == blocks
     assert reg.value("serving.kv_blocks_total") == blocks - 1
@@ -362,7 +325,7 @@ def test_pool_is_sized_in_bytes_from_the_planes(monkeypatch):
 
 def test_spans_carry_the_passes(monkeypatch, served):
     params, *_ = served
-    eng, _ = _engine(params, monkeypatch)
+    eng, _ = fam.engine(params, monkeypatch)
     tracer = trace.Tracer(enabled=True)
     monkeypatch.setattr(trace, "_TRACER", tracer, raising=False)
     trace.set_tracer(tracer)
@@ -380,28 +343,28 @@ def test_what_is_refused_says_what_is_missing():
     with pytest.raises(ValueError, match="leave the stack at different "
                                          "passes"):
         LoopedRmsRope(NL, NH, DM, PASSES, early_exit_threshold=0.9)
-    params = _params()
+    params = fam.init()
     with pytest.raises(ValueError, match="exit_gate.w"):
         ServingEngine({k: v for k, v in params.items()
-                       if not k.startswith("exit_gate")}, arch=_arch(),
+                       if not k.startswith("exit_gate")}, arch=fam.arch(),
                       max_len=T, block_tokens=B)
     with pytest.raises(ValueError, match="GPT-2 block only.*3 passes"):
-        speculative.validate_draft(params, params, _arch(), T)
+        speculative.validate_draft(params, params, fam.arch(), T)
     with pytest.raises(ValueError, match="GPT-2 block only"):
-        ServingEngine(params, arch=_arch(), max_len=T, block_tokens=B,
+        ServingEngine(params, arch=fam.arch(), max_len=T, block_tokens=B,
                       draft_params=params)
     with pytest.raises(ValueError, match="not both"):
-        ServingEngine(params, NL, NH, DM, arch=_arch())
+        ServingEngine(params, NL, NH, DM, arch=fam.arch())
     with pytest.raises(ValueError, match="needs an architecture"):
         ServingEngine(params)
 
 
 def test_infer_compute_dtype_answers_for_the_new_names():
-    p16 = _params(jnp.bfloat16)
+    p16 = fam.init(dtype=jnp.bfloat16)
     # float32 norm scales, gate and embedding must not promote the decode
     mixed = {k: (v if k.endswith(".w") and (k.startswith("block")
                                             or k.startswith("lm_head"))
                  else jnp.asarray(v, jnp.float32)) for k, v in p16.items()}
     assert infer_compute_dtype(mixed) == jnp.bfloat16
-    assert infer_compute_dtype(_params()) == jnp.float32
+    assert infer_compute_dtype(fam.init()) == jnp.float32
     assert isinstance(Gpt2(2, 2, 32), Gpt2) and Gpt2(2, 2, 32).kv_planes == 2

@@ -6,26 +6,10 @@ form; ``kernels/paged_attention._block_is_sliceable``).  Nothing runs:
 a compile that passes is no chip run.  One file, the topology inside a
 fixture (one process may hold the TPU's library)."""
 
-import os
-
 import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
-
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
 
 
 # (slots, window rows, table entries a slot, pool blocks, K/V heads of

@@ -12,8 +12,7 @@ jax = pytest.importorskip("jax")
 from chipbench import run as bench_run  # noqa: E402
 from paddle_tpu.kernels import paged_attention as pa  # noqa: E402
 from paddle_tpu.serving import batched_decode as bd  # noqa: E402
-
-import test_prefill_window as tpw  # noqa: E402
+from tiny import gpt2  # noqa: E402
 
 READ = bench_run.load_reader("prefill.attended_entry_share").read
 ATTENDED = "serving.prefill_entries{kind=attended}"
@@ -40,10 +39,9 @@ def test_nothing_to_read_is_none(stats):
 
 def _admit(monkeypatch, rule, lens):
     monkeypatch.setattr(pa, "CHAIN_SCORE_BYTES", rule)
-    eng, _ = tpw._engine(tpw._params("float32"), monkeypatch,
-                         prefix_reuse=False)
+    eng, _ = gpt2.engine(gpt2.init(), monkeypatch, prefix_reuse=False)
     rng = np.random.default_rng(3)
-    eng.generate_many([rng.integers(1, tpw.VOCAB, n, dtype=np.int32)
+    eng.generate_many([rng.integers(1, gpt2.sizes["rows"], n, dtype=np.int32)
                        for n in lens], max_new_tokens=2)
     return eng.stats()
 
@@ -57,7 +55,7 @@ def test_engine_counts_what_the_walking_pieces_visit(monkeypatch):
     # 8 rows at 0 | 8 at 0, 8 at 8 (19 = 8 + 8 + 4): entries 0..1, 0..1,
     # 0..3 of 16, in each of the two planes
     assert stats[ATTENDED] == 2 * (2 + 2 + 4)
-    assert stats[CHAIN] == 2 * 3 * tpw.NB
+    assert stats[CHAIN] == 2 * 3 * (gpt2.max_len // gpt2.block_tokens)
     assert READ({"stats": stats}) == 100.0 * 8 / 48
 
 

@@ -26,12 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import test_gated_moe as tg
-import test_latent_moe as tl
-import test_ouro as to
-import test_prefill_window as tw
-import test_retention_arch as tr
-import test_sambay as ts
+import tiny
 from paddle_tpu.kernels import retention as _retention
 from paddle_tpu.observability.metrics import MetricsRegistry
 from paddle_tpu.serving import ServingEngine
@@ -126,44 +121,29 @@ def test_no_mix_reaches_more_widths_than_on_the_ladder_it_replaced(mix):
 N, T_WIDE, SLOT = 500, 640, 1
 
 
-def _gpt2(dtype, monkeypatch):
-    # the position table has to cover the longer slot
-    monkeypatch.setattr(tw, "T", T_WIDE)
-    return tw._params(dtype), tw.ARCH, tw.VOCAB, 0.05
+def _served(family, margin, **cut):
+    """``(parameters, architecture, vocabulary rows, bfloat16 margin)``
+    of ``family``'s tiny model: a routed family's share of float32
+    weights cast, as its own tests cast them."""
+    def case(dtype, monkeypatch):
+        if "share" in family.sizes:
+            p = family.held(family.init(**cut), (dtype,))[dtype]
+        else:
+            p = {k: jnp.asarray(v, dtype)
+                 for k, v in family.init(dtype=dtype, **cut).items()}
+        return p, family.arch(), family.rows, margin
+    return case
 
 
-def _ouro(dtype, monkeypatch):
-    return to._params(jnp.dtype(dtype)), to._arch(), to.VOCAB, to.BF16_MARGIN
-
-
-def _sambay(dtype, monkeypatch):
-    # its window (8) is far inside the piece: the piece spans many
-    return (ts._init(jax.random.PRNGKey(32), ts.TINY, jnp.dtype(dtype)),
-            ts._arch(), ts.TINY["rows"], 0.7)
-
-
-def _gated_moe(dtype, monkeypatch):
-    p = tg._share(tg._init(jax.random.PRNGKey(34), tg.TINY, jnp.float32),
-                  *tg.TINY["share"])
-    return ({k: v.astype(dtype) for k, v in p.items()}, tg._arch(),
-            tg.TINY["rows"], tg.BF16_MARGIN)
-
-
-def _latent_moe(dtype, monkeypatch):
-    p = tl._share(tl._init(jax.random.PRNGKey(40), tl.TINY, jnp.float32),
-                  *tl.TINY["share"])
-    return ({k: v.astype(dtype) for k, v in p.items()}, tl._arch(),
-            tl.TINY["rows"], tl.BF16_MARGIN)
-
-
-def _retention_arch(dtype, monkeypatch):
-    return ({k: jnp.asarray(v, dtype) for k, v in tr.make(0).items()},
-            tr.arch(), tr.V, 0.1)
-
-
-ARCHS = {"gpt2": _gpt2, "looped": _ouro, "sambay": _sambay,
-         "gated_moe": _gated_moe, "latent_moe": _latent_moe,
-         "retention": _retention_arch}
+# gpt2: the position table has to cover the longer slot; sambay: its
+# window (8) is far inside the piece, which spans many; the margins are
+# each family's own tests'
+ARCHS = {"gpt2": _served(tiny.gpt2, 0.05, max_len=T_WIDE),
+         "looped": _served(tiny.ouro, 0.7),
+         "sambay": _served(tiny.sambay, 0.7),
+         "gated_moe": _served(tiny.gated_moe, 0.25),
+         "latent_moe": _served(tiny.latent_moe, 0.25),
+         "retention": _served(tiny.retention, 0.1)}
 
 
 def _engine(params, arch, piece, monkeypatch):
@@ -340,25 +320,25 @@ def test_a_piece_straddles_a_fork_and_a_hit_ends_between_rungs(monkeypatch):
     blocks, then 16 rows of which 8 are real); one that shares 12 (a
     block edge) prefills 21 tokens in one piece of 32.  Both give the
     tokens an engine with no prefix cache gives."""
-    monkeypatch.setattr(tw, "T", 96)
-    params = tw._params("float32")
+    gpt2 = tiny.gpt2
+    params = gpt2.init(max_len=96)
 
     def engine(reuse):
         monkeypatch.setattr(_bd, "PREFILL_PIECE", 32)
         reg = MetricsRegistry()
-        return ServingEngine(params, arch=tw.ARCH, max_len=96, max_slots=2,
+        return ServingEngine(params, arch=gpt2.arch(), max_len=96, max_slots=2,
                              block_tokens=4, decode_chunk=4, min_bucket=4,
                              donate=False, registry=reg,
                              prefix_reuse=reuse), reg
 
     rng = np.random.default_rng(7)
-    base = rng.integers(1, tw.VOCAB, 40, dtype=np.int32)
+    base = rng.integers(1, gpt2.rows, 40, dtype=np.int32)
     forked = np.concatenate([base[:14],
-                             rng.integers(1, tw.VOCAB, 40, dtype=np.int32)])
-    forked[14] = (base[14] + 1) % tw.VOCAB or 1      # diverge mid-block
+                             rng.integers(1, gpt2.rows, 40, dtype=np.int32)])
+    forked[14] = (base[14] + 1) % gpt2.rows or 1      # diverge mid-block
     edge = np.concatenate([base[:12],
-                           rng.integers(1, tw.VOCAB, 21, dtype=np.int32)])
-    edge[12] = (base[12] + 1) % tw.VOCAB or 1
+                           rng.integers(1, gpt2.rows, 21, dtype=np.int32)])
+    edge[12] = (base[12] + 1) % gpt2.rows or 1
     prompts = [base, forked, edge]
     on, reg = engine(True)
     assert on._rungs == [4, 16, 32]

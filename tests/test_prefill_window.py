@@ -15,48 +15,13 @@ import pytest
 
 from paddle_tpu.kernels import oracle_tol
 from paddle_tpu.observability import trace
-from paddle_tpu.observability.metrics import MetricsRegistry
-from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving import batched_decode as _bd
-from paddle_tpu.serving.arch import Gpt2
+from tiny import gpt2 as fam
 
-VOCAB, NL, NH, DM, T, B = 61, 2, 2, 32, 64, 4
-EPS = 1e-5
-PIECE = 8           # the piece width these tests give the engine
+VOCAB, NL, NH, DM = (fam.sizes[k] for k in ("rows", "layers", "heads", "d"))
+T, B = fam.max_len, fam.block_tokens
 NB = T // B
-ARCH = Gpt2(NL, NH, DM, EPS)
-
-
-def _params(dtype):
-    """Random weights under the serving names: the comparison is between
-    two spellings of one forward, so nothing has to be trained."""
-    rng = np.random.default_rng(11)
-
-    def w(*shape, scale=0.2):
-        return jnp.asarray(rng.normal(0.0, scale, shape), dtype)
-
-    p = {"tok_emb.w": w(VOCAB, DM), "pos_emb.w.w": w(T, DM),
-         "ln_f.scale": 1 + w(DM), "ln_f.bias": w(DM),
-         "lm_head.w": w(DM, VOCAB)}
-    for i in range(NL):
-        for nm, shape in (("att_q", (DM, DM)), ("att_k", (DM, DM)),
-                          ("att_v", (DM, DM)), ("att_out", (DM, DM)),
-                          ("ffn1", (DM, 4 * DM)), ("ffn2", (4 * DM, DM))):
-            p[f"block{i}_{nm}.w"] = w(*shape)
-            p[f"block{i}_{nm}.b"] = w(shape[1], scale=0.05)
-        for ln in ("ln1", "ln2"):
-            p[f"block{i}_{ln}.scale"] = 1 + w(DM)
-            p[f"block{i}_{ln}.bias"] = w(DM)
-    return p
-
-
-def _engine(params, monkeypatch, **kw):
-    monkeypatch.setattr(_bd, "PREFILL_PIECE", PIECE)
-    reg = MetricsRegistry()
-    eng = ServingEngine(params, NL, NH, DM, max_len=T, max_slots=3,
-                        block_tokens=B, decode_chunk=4, min_bucket=4,
-                        donate=False, registry=reg, **kw)
-    return eng, reg
+ARCH = fam.arch()
 
 
 def _noise_pool(eng, seed):
@@ -115,7 +80,7 @@ CASES = [
                          ids=[c[0] for c in CASES])
 def test_window_prefill_equals_the_token_steps(monkeypatch, dtype, name,
                                                suffix, start, fork):
-    eng, _reg = _engine(_params(dtype), monkeypatch)
+    eng, _reg = fam.engine(fam.init(dtype=dtype), monkeypatch)
     rng = np.random.default_rng(suffix * 31 + start)
     prompt = rng.integers(1, VOCAB, start + suffix, dtype=np.int32)
     # the slot's chain: blocks 9.. in a shuffled order, the rest trash
@@ -186,7 +151,7 @@ def test_prefill_runs_the_lm_head_on_one_row(width):
     """The lowered prefill of any width holds exactly one matmul against
     ``lm_head.w`` ([d, vocab]; no other operand has that shape), and its
     left operand has one row."""
-    p = _params("float32")
+    p = fam.init()
     pool = tuple(jnp.zeros((12, B, NH, DM // NH), jnp.float32)
                  for _ in range(NL))
     fn = _bd.make_prefill(ARCH, width, donate=False)
@@ -276,7 +241,7 @@ def test_engine_counts_pieces_real_and_padded_tokens(monkeypatch):
     """After admissions of known suffix lengths the counters read what
     the lengths imply, one ``serving.prefill`` span covers an admission
     whatever its pieces, and no executable is wider than a piece."""
-    eng, reg = _engine(_params("float32"), monkeypatch, prefix_reuse=False)
+    eng, reg = fam.engine(fam.init(), monkeypatch, prefix_reuse=False)
     lens = [3, 8, 13, 19, 24]     # 4 | 8 | 8+8 | 8+8+4 | 8+8+8
     widths = {4: 2, 8: 8}
     t = trace.Tracer(enabled=True, registry=None)
@@ -313,6 +278,6 @@ def test_engine_counts_pieces_real_and_padded_tokens(monkeypatch):
     (1, [4]), (4, [4]), (5, [8]), (8, [8]), (9, [8, 4]), (16, [8, 8]),
     (21, [8, 8, 8]), (29, [8, 8, 8, 8])])
 def test_piece_widths_are_buckets_up_to_the_piece(monkeypatch, n, widths):
-    eng, _reg = _engine(_params("float32"), monkeypatch)
+    eng, _reg = fam.engine(fam.init(), monkeypatch)
     assert eng._piece_widths(n) == widths
     assert eng.bucket_for(n) == sum(widths)

@@ -57,6 +57,23 @@ def test_nothing_names_what_was_deleted():
     assert hits == [], "\n".join(hits[:20])
 
 
+def test_no_test_module_imports_a_test_module():
+    """A family's tiny model is an entry of ``tests/tiny.py`` and what
+    test files share lives in a helper module (``tiny``, ``op_test``,
+    ``kernel_cases``): an importer builds the imported file's fixtures
+    again in another worker and knows its sizes by name (PR 59)."""
+    tests = os.path.join(REPO, "tests")
+    hits = []
+    for name in sorted(os.listdir(tests)):
+        if not re.fullmatch(r"test_.*\.py", name):
+            continue
+        with open(os.path.join(tests, name), encoding="utf-8") as fh:
+            hits += [f"tests/{name}:{lineno}: {line.strip()}"
+                     for lineno, line in enumerate(fh, 1)
+                     if re.match(r"(import|from) test_", line)]
+    assert hits == [], "\n".join(hits)
+
+
 def test_the_command_line_that_remains_runs():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.pathsep.join(

@@ -14,67 +14,20 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 import paddle_tpu as pt  # noqa: E402
-from paddle_tpu.models import retention_reference as ref  # noqa: E402
+import tiny  # noqa: E402
 from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
 from paddle_tpu.serving.arch import PowerRetention  # noqa: E402
+from tiny import retention as fam  # noqa: E402
 
-L, H, HK, D, DH, F, V = 3, 6, 2, 64, 16, 128, 97
-THETA = 1e6
-
-
-def make(seed, std=0.08):
-    rng = np.random.default_rng(seed)
-
-    def n(*shape):
-        return (std * rng.normal(size=shape)).astype(np.float32)
-
-    ones = lambda k: np.ones(k, np.float32)                      # noqa: E731
-    p = {"tok_emb.w": n(V, D), "lm_head.w": n(D, V), "norm_f.scale": ones(D)}
-    for i in range(L):
-        b = f"block{i}_"
-        horizon = np.array([8.0, 90.0])
-        p.update({
-            b + "att_q.w": n(D, H * DH), b + "att_k.w": n(D, HK * DH),
-            b + "att_v.w": n(D, HK * DH), b + "att_out.w": n(H * DH, D),
-            b + "att_gate.w": n(D, HK),
-            b + "att_gate.b": np.log(horizon - 1).astype(np.float32),
-            b + "att_qnorm.scale": ones(DH), b + "att_knorm.scale": ones(DH),
-            b + "norm1.scale": ones(D), b + "norm2.scale": ones(D),
-            b + "ffn_gate.w": n(D, F), b + "ffn_up.w": n(D, F),
-            b + "ffn_down.w": n(F, D)})
-    return p
-
-
-def arch():
-    return PowerRetention(L, H, HK, D, DH, F, rope_theta=THETA)
-
-
-def engine(params, reg=None, **kw):
-    return pt.serving.ServingEngine(
-        params, arch=arch(), max_len=400, max_slots=3, prefix_reuse=False,
-        cache_blocks=0, registry=reg or MetricsRegistry(), **kw)
-
-
-def gaps(params, prompts, outs, **switches):
-    """The worst gap, a request, between a generated token's reference
-    logit and the reference's maximum."""
-    worst = []
-    for prompt, full in zip(prompts, outs):
-        full = np.asarray(full)
-        assert np.array_equal(full[:len(prompt)], prompt)
-        lg = np.asarray(ref.forward(params, full[None], L, H, HK, THETA,
-                                    **switches))[0]
-        at = lg[len(prompt) - 1:len(full) - 1]
-        worst.append(float(np.max(
-            at.max(-1) - at[np.arange(len(at)), full[len(prompt):]])))
-    return worst
+L, H, HK, D, DH, F, V = (fam.sizes[k] for k in (
+    "layers", "heads", "kv_heads", "d", "dh", "f", "rows"))
 
 
 @pytest.fixture(scope="module")
 def served():
-    params = make(0)
+    params = fam.init(0)
     reg = MetricsRegistry()
-    eng = engine(params, reg, compute_dtype="float32")
+    eng = fam.engine(params, registry=reg, compute_dtype="float32")[0]
     rng = np.random.default_rng(1)
     # more prompts than slots, so slots are reused; one and several
     # pieces, every bucket width, a prompt that ends on a piece boundary
@@ -87,7 +40,7 @@ def served():
 def test_engine_through_pieces_and_decode_is_the_reference(served):
     params, eng, _, prompts, outs = served
     # float32 end to end: greedy tokens ARE the reference's argmax
-    assert max(gaps(params, prompts, outs)) <= 1e-4
+    assert max(tiny.gaps(fam, params, prompts, outs)) <= 1e-4
 
 
 @pytest.mark.parametrize("switch", [
@@ -95,21 +48,21 @@ def test_engine_through_pieces_and_decode_is_the_reference(served):
     {"rotary": False}, {"piece": 128}, {"grouped": False}])
 def test_each_line_of_the_layer_is_seen_by_the_comparison(served, switch):
     params, _, _, prompts, outs = served
-    assert max(gaps(params, prompts, outs, **switch)) > 0.01
+    assert max(tiny.gaps(fam, params, prompts, outs, **switch)) > 0.01
 
 
 def test_a_reused_slot_starts_from_zeros(served):
     params, eng, _, prompts, _ = served
     # the same prompt alone in a fresh engine gives the same tokens as it
     # gave in a slot that an earlier, longer request had left its state in
-    again = engine(params, compute_dtype="float32").generate_many(
+    again = fam.engine(params, compute_dtype="float32")[0].generate_many(
         [prompts[3]], max_new_tokens=12)
     assert np.array_equal(again[0], served[4][3])
 
 
 def test_no_plane_no_pool_no_table(served):
     _, eng, reg, _, _ = served
-    assert arch().planes == () and arch().kv_planes == 0
+    assert fam.arch().planes == () and fam.arch().kv_planes == 0
     assert eng.kv_pool is None and eng.prefix_trie is None
     assert eng._pk == () and eng._pv == ()
     assert eng._table.shape == (3,) and not eng._table.any()
@@ -147,12 +100,12 @@ def test_retention_calls_count_the_pieces_that_continue_a_prompt(monkeypatch):
     from paddle_tpu.serving import batched_decode as _bd
 
     monkeypatch.setattr(_bd, "PREFILL_PIECE", 32)
-    params, reg = make(0), MetricsRegistry()
-    eng = engine(params, reg, compute_dtype="float32")
+    params, reg = fam.init(0), MetricsRegistry()
+    eng = fam.engine(params, registry=reg, compute_dtype="float32")[0]
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, V, n, dtype=np.int32) for n in (70, 20)]
     outs = eng.generate_many(prompts, max_new_tokens=4)
-    assert max(gaps(params, prompts, outs)) <= 1e-4
+    assert max(tiny.gaps(fam, params, prompts, outs)) <= 1e-4
     stats = eng.stats()
     # 70 tokens: pieces of 32, 32 and 8 rows; 20: one of 32
     assert stats["serving.retention_calls{fresh=1}"] == 2
@@ -162,24 +115,25 @@ def test_retention_calls_count_the_pieces_that_continue_a_prompt(monkeypatch):
 
 
 def test_state_spec_is_two_float32_arrays_a_layer():
-    spec = arch().state_spec(jnp.bfloat16)
+    spec = fam.arch().state_spec(jnp.bfloat16)
     assert len(spec) == L
     assert spec[0] == (((HK, 144, DH), jnp.float32), ((HK, 144), jnp.float32))
-    assert arch().attn_form == "retention" and arch().retention_layers == L
+    assert fam.arch().attn_form == "retention"
+    assert fam.arch().retention_layers == L
 
 
 def test_refusals_say_why():
-    params = make(0)
+    params = fam.init(0)
     with pytest.raises(ValueError, match="hold recurrent state"):
-        pt.serving.ServingEngine(params, arch=arch(), max_len=64,
+        pt.serving.ServingEngine(params, arch=fam.arch(), max_len=64,
                                  prefix_reuse=True)
     with pytest.raises(ValueError, match="no block pool"):
-        pt.serving.ServingEngine(params, arch=arch(), max_len=64,
+        pt.serving.ServingEngine(params, arch=fam.arch(), max_len=64,
                                  prefix_reuse=False, cache_blocks=8)
     from paddle_tpu.serving.speculative import validate_draft
 
     with pytest.raises(ValueError, match="state rolled back"):
-        validate_draft(params, params, arch(), 64)
+        validate_draft(params, params, fam.arch(), 64)
     with pytest.raises(ValueError, match="degree 3"):
         PowerRetention(L, H, HK, D, DH, F, degree=3)
     with pytest.raises(ValueError, match="FFN is 128 wide"):
@@ -188,12 +142,12 @@ def test_refusals_say_why():
 
 def test_bfloat16_engine_stays_within_a_margin_of_the_reference():
     params = {k: np.asarray(jnp.asarray(v, jnp.bfloat16).astype(jnp.float32))
-              for k, v in make(4).items()}
+              for k, v in fam.init(4).items()}
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, V, n, dtype=np.int32) for n in (200, 33)]
-    outs = engine(params, compute_dtype="bfloat16").generate_many(
+    outs = fam.engine(params, compute_dtype="bfloat16")[0].generate_many(
         prompts, max_new_tokens=10)
-    assert max(gaps(params, prompts, outs)) < 0.05
+    assert max(tiny.gaps(fam, params, prompts, outs)) < 0.05
 
 
 def test_the_published_model_counts_14_77_billion_parameters():

@@ -7,8 +7,9 @@ O(1)-per-layer remat temps (the t=16k capacity path).  These tests pin:
 
 - all three ``memory_optimize`` policies x accum {1, 2} COMPILE AND RUN
   on a small transformer under JAX_PLATFORMS=cpu;
-- the LOSS is bit-exact vs the unrematted step in every configuration
-  (forward math unchanged, dropout keys reproduced through the scan);
+- the LOSS agrees with the unrematted step's to float32's last digit in
+  every configuration (``LOSS_RTOL``; forward math unchanged) and to the
+  bit with dropout on (the keys reproduced through the scan);
 - GRADIENTS are bit-exact vs the unrematted step for the full/compact
   policies when XLA fusion is disabled (subprocess), and within a few
   f32 ulps otherwise — XLA fuses the checkpoint-island boundaries
@@ -20,6 +21,7 @@ O(1)-per-layer remat temps (the t=16k capacity path).  These tests pin:
 - the structural matcher (core/ir.py) groups what it should.
 """
 
+import functools
 import os
 import subprocess
 import sys
@@ -41,6 +43,15 @@ from paddle_tpu.models import transformer
 # module docstring); NOT a model-accuracy tolerance
 ULP_ATOL = 5e-7
 ULP_RTOL = 5e-6
+# the loss of a rematerialised step against the unrematted one: the scan
+# body and the unrolled layers are two programs, and whether XLA:CPU
+# gives them the same bits is its code generator's business (it does
+# with LLVM's optimiser on; with it off, as the tests compile, ``full``
+# and ``compact`` differ from the baseline in the loss's last float32
+# digit: 4.050076 against 4.050077, 2.4e-7 relative).  A policy
+# that changed the arithmetic (a layer left out, a dropout key replayed
+# wrong) moves the loss in its second digit.
+LOSS_RTOL = 1e-6
 
 
 def _build(policy, accum=1, drop=0.0, n_layer=2, seed=11):
@@ -86,16 +97,24 @@ def _step_grads(main, startup, loss, steps=1):
         pt.core.scope._scope_stack.pop()
 
 
+@functools.lru_cache(maxsize=None)
+def _unrematted(accum):
+    """Two steps of the unrematted model: what every policy at this
+    ``accum`` is compared with, trained once."""
+    return _step_grads(*_build(None, accum), steps=2)[:2]
+
+
 @pytest.mark.parametrize("accum", [1, 2])
 @pytest.mark.parametrize("policy", ["full", "selective", "compact"])
 def test_remat_policy_compiles_and_loss_bit_exact(policy, accum):
-    """Every policy x accum compiles, runs, keeps the loss BIT-EXACT vs
-    the unrematted step across optimizer steps, and keeps gradients
-    within a few f32 ulps (fusion reassociation only)."""
-    base_losses, base_grads, _ = _step_grads(*_build(None, accum), steps=2)
+    """Every policy x accum compiles, runs, keeps the loss to float32's
+    last digit (``LOSS_RTOL``) vs the unrematted step across optimizer
+    steps, and keeps gradients within a few f32 ulps (fusion
+    reassociation only)."""
+    base_losses, base_grads = _unrematted(accum)
     opt_losses, opt_grads, exe = _step_grads(*_build(policy, accum), steps=2)
     for b, o in zip(base_losses, opt_losses):
-        np.testing.assert_array_equal(b, o)
+        np.testing.assert_allclose(o, b, rtol=LOSS_RTOL, atol=0)
     assert set(base_grads) == set(opt_grads)
     for n in base_grads:
         np.testing.assert_allclose(opt_grads[n], base_grads[n],
@@ -140,10 +159,15 @@ def test_scan_engine_bit_identical_to_barrier_fallback():
 
 
 _NO_FUSION_PROBE = textwrap.dedent("""
+    import jax
     import numpy as np
     import paddle_tpu as pt
     from paddle_tpu.core.program import GRAD_SUFFIX
     from paddle_tpu.models import transformer
+
+    # as tests/conftest.py: the claim is about the two graphs, not about
+    # what LLVM's optimiser makes of each
+    jax.config.update("jax_disable_most_optimizations", True)
 
     def build(policy, accum):
         pt.core.unique_name.reset()
@@ -270,7 +294,8 @@ def test_scan_groups_selective_and_compact():
 
 def test_scan_remat_env_kill_switch():
     """PADDLE_TPU_SCAN_REMAT=0 must route every segment through the
-    barrier fallback and still train (loss bit-exact vs baseline)."""
+    barrier fallback and still train (the loss to float32's last digit
+    vs baseline: ``LOSS_RTOL``)."""
     base_losses, _, _ = _step_grads(*_build(None))
     try:
         os.environ["PADDLE_TPU_SCAN_REMAT"] = "0"
@@ -278,7 +303,8 @@ def test_scan_remat_env_kill_switch():
         assert not exe.last_remat_plan
     finally:
         os.environ.pop("PADDLE_TPU_SCAN_REMAT", None)
-    np.testing.assert_array_equal(base_losses[0], losses[0])
+    np.testing.assert_allclose(losses[0], base_losses[0], rtol=LOSS_RTOL,
+                               atol=0)
 
 
 def test_scan_remat_composes_with_run_steps():

@@ -8,32 +8,19 @@ import time
 import numpy as np
 import pytest
 
-import paddle_tpu as pt
+import tiny
 from paddle_tpu.models import transformer
 from paddle_tpu.observability import metrics as _obs
 from paddle_tpu.serving import ServingEngine
 
 
-def _make_params(vocab=50, n_layer=2, n_head=2, d_model=32, max_len=32,
-                 dtype="float32", seed=7):
-    """Randomly initialized flagship weights (serving doesn't need a
-    trained model: greedy chains over random weights are deterministic)."""
-    main, startup = pt.Program(), pt.Program()
-    with pt.program_guard(main, startup):
-        transformer.build(vocab_size=vocab, n_layer=n_layer, n_head=n_head,
-                          d_model=d_model, max_len=max_len,
-                          dropout_rate=0.0, dtype=dtype)
-    exe = pt.Executor()
-    exe.run(startup)
-    return transformer.extract_params(program=main)
-
-
-VOCAB, NL, NH, DM, T = 50, 2, 2, 32, 32
+VOCAB, T = tiny.BUILT_VOCAB, 32
+NL, NH, DM = (tiny.gpt2.sizes[k] for k in ("layers", "heads", "d"))
 
 
 @pytest.fixture
 def params():
-    return _make_params(VOCAB, NL, NH, DM, T)
+    return tiny.gpt2_built(VOCAB, T)
 
 
 @pytest.fixture(autouse=True)

@@ -15,105 +15,29 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+import tiny  # noqa: E402
 from paddle_tpu.kernels import paged_attention as paged  # noqa: E402
 from paddle_tpu.kernels import sparse_attention as sparse  # noqa: E402
 from paddle_tpu.models import sparse_latent_moe_reference as ref  # noqa: E402
 from paddle_tpu.observability import trace  # noqa: E402
-from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
-from paddle_tpu.serving import ServingEngine  # noqa: E402
-from paddle_tpu.serving import arch as arch_mod  # noqa: E402
 from paddle_tpu.serving import batched_decode as _bd  # noqa: E402
 from paddle_tpu.serving.arch import SparseLatentMoE  # noqa: E402
+from tiny import sparse_latent_moe as fam  # noqa: E402
 
-FULL = {"heads": 4, "q_rank": 24, "rank": 16, "nope": 8, "rope": 8, "v": 8,
-        "theta": 8e7}
-SLIDING = {"heads": 2, "q_rank": 16, "rank": 32, "nope": 12, "rope": 8,
-           "v": 8, "theta": 5e4}
-TINY = {"d": 64, "f": 96, "e": 24, "experts": 16, "top_k": 4,
-        "share": (4, 4), "window": 9, "index_heads": 3, "index_dim": 16,
-        "index_topk": 12, "scale": 1.0,
-        "types": ("full", "full", "sliding", "sliding", "sliding"),
-        "dense": 1, "rows": 128}
-T, B, PIECE, SLOTS = 64, 4, 8, 3
+TINY = fam.sizes
+FULL, SLIDING = TINY["full"], TINY["sliding"]
+T, B, PIECE = fam.max_len, fam.block_tokens, fam.piece
 TOL = 3e-4
-
-
-def _shapes(z=TINY, experts=None, types=None):
-    return ref.param_shapes(
-        z["d"], z["rows"], z["f"], z["e"], z["experts"],
-        z["experts"] if experts is None else experts,
-        z["types"] if types is None else types, FULL, SLIDING,
-        z["index_heads"], z["index_dim"], z["dense"])
-
-
-def _init(key, dtype):
-    """Seeded weights under ``SparseLatentMoE``'s names: matrices at 0.2
-    (widths of 16-64 then give activations of order one), gains near one,
-    the index LayerNorm's bias and the router's around zero."""
-    shapes = _shapes()
-    keys = iter(jax.random.split(key, len(shapes)))
-    branch = (2 * len(TINY["types"])) ** -0.5
-    p = {}
-    for name, shape in shapes.items():
-        k = next(keys)
-        if name.endswith(".scale"):
-            p[name] = 1 + 0.2 * jax.random.normal(k, shape)
-        elif name.endswith(".bias"):
-            p[name] = 0.05 * jax.random.normal(k, shape)
-        else:
-            scale = 1.0 if name == "tok_emb.w" else 0.3 if name.endswith(
-                "router.w") else 0.2 * branch if name.endswith(
-                    ("att_out.w", "ffn_down.w")) else 0.2
-            p[name] = scale * jax.random.normal(k, shape)
-    return {k: v.astype(dtype) for k, v in p.items()}
-
-
-def _share(p, first, count):
-    return {k: (v[first:first + count] if "_experts_" in k else v)
-            for k, v in p.items()}
 
 
 @pytest.fixture(scope="module")
 def uncut():
-    return _init(jax.random.PRNGKey(55), jnp.float32)
+    return fam.init()
 
 
 @pytest.fixture(scope="module")
 def params(uncut):
-    return _share(uncut, *TINY["share"])
-
-
-def _arch(share=TINY["share"], z=TINY):
-    return SparseLatentMoE(
-        z["types"], z["d"], FULL, SLIDING, window=z["window"],
-        index_heads=z["index_heads"], index_dim=z["index_dim"],
-        index_topk=z["index_topk"], dense_layers=z["dense"],
-        router_width=z["experts"], top_k=z["top_k"], experts=share,
-        route_scale=z["scale"])
-
-
-def _engine(p, monkeypatch, **kw):
-    monkeypatch.setattr(_bd, "PREFILL_PIECE", PIECE)
-    reg = MetricsRegistry()
-    kw.setdefault("max_slots", SLOTS)
-    kw.setdefault("prefix_reuse", False)
-    kw.setdefault("decode_chunk", 4)
-    eng = ServingEngine(p, arch=_arch(), max_len=T, block_tokens=B,
-                        min_bucket=4, donate=False, registry=reg, **kw)
-    return eng, reg
-
-
-def _layout(share=TINY["share"], z=TINY):
-    return dict(layer_types=z["types"], full=FULL, sliding=SLIDING,
-                window=z["window"], index_heads=z["index_heads"],
-                index_dim=z["index_dim"], index_topk=z["index_topk"],
-                dense_layers=z["dense"], top_k=z["top_k"], experts=share,
-                route_scale=z["scale"])
-
-
-def _reference(p, tokens, share=TINY["share"], **switches):
-    return np.asarray(ref.forward(p, np.asarray(tokens)[None],
-                                  **dict(_layout(share), **switches)))[0]
+    return fam.held(uncut)["float32"]
 
 
 PROMPTS = [np.arange(3, 3 + 21) % 128, (7 * np.arange(11) + 5) % 128,
@@ -124,93 +48,6 @@ ADMIT_AT = (0, 0, 12)
 STEPS = 30
 
 
-def _through_the_cache(eng, prompts=PROMPTS, admit_at=ADMIT_AT, steps=STEPS):
-    """Each prompt into a slot of its own before decode step
-    ``admit_at[s]``, prefilled in the pieces the engine would dispatch,
-    then greedy decode steps for ALL slots at once (a slot not admitted
-    yet is a dead one).  The full planes go through whole chains; the
-    sliding planes through the ENGINE'S OWN window chains where it has
-    them, else through whole chains too.  Returns per slot (tokens,
-    logits at every position from the prompt's last on) and the window
-    blocks slot 0 gave back that another slot was handed while slot 0
-    still decoded."""
-    arch, chains = eng.arch, eng.window_chains
-    S, nb = len(prompts), T // B
-    whole = 1 + np.arange(S * nb, dtype=np.int32).reshape(S, nb)
-    live = np.zeros(S, bool)
-
-    def rows(s):
-        if chains is None:
-            return jnp.asarray(whole[s])
-        return jnp.asarray(np.stack([whole[s], chains.table[s]]))
-
-    def table():
-        full = np.where(live[:, None], whole, 0).astype(np.int32)
-        if chains is None:
-            return jnp.asarray(full)
-        return jnp.asarray(np.stack([full, chains.table[:S]], axis=1))
-
-    @jax.jit
-    def window(p, pk, pv, toks, at, n, row):
-        x, pk, pv, _, _ = _bd._window_forward(
-            p, pk, pv, toks[None], at[None], (at + n - 1)[None], row[None],
-            arch)
-        return arch.head(p, x[0]), pk, pv
-
-    @jax.jit
-    def step(p, pk, pv, tok, at, tbl):
-        lg, pk, pv, _, _ = _bd.paged_step_logits(p, tok, at, pk, pv, tbl,
-                                                 arch)
-        return lg, pk, pv
-
-    pk, pv = eng._pk, eng._pv
-    logits = [[] for _ in prompts]
-    pieces_logits = [[] for _ in prompts]
-    toks = [list(p_) for p_ in prompts]
-    given_back, reused = set(), set()
-    for j in range(steps):
-        for s, prompt in enumerate(prompts):
-            if admit_at[s] != j:
-                continue
-            pieces = eng._pieces(np.asarray(prompt), 0)
-            assert len(pieces) >= 2
-            for _w, padded, at, n in pieces:
-                if chains is not None:
-                    chains.advance(s, at, at + n - 1)
-                    if s:
-                        reused |= given_back & set(
-                            chains.table[s][chains.table[s] > 0].tolist())
-                lg, pk, pv = window(eng._p, pk, pv, padded, jnp.int32(at),
-                                    jnp.int32(n), rows(s))
-                pieces_logits[s].append(np.asarray(lg[:n]))
-            live[s] = True
-            logits[s].append(lg[n - 1])
-        last = np.zeros(S, np.int32)
-        at = np.zeros(S, np.int32)
-        for s in range(S):
-            if live[s]:
-                last[s] = int(jnp.argmax(logits[s][-1]))
-                at[s] = len(toks[s])
-                toks[s].append(int(last[s]))
-                if chains is not None:
-                    before = set(chains.table[s][chains.table[s] > 0].tolist())
-                    chains.advance(s, int(at[s]), int(at[s]))
-                    now = set(chains.table[s][chains.table[s] > 0].tolist())
-                    if s == 0:
-                        given_back |= before - now
-                    else:
-                        reused |= given_back & (now - before)
-        lg, pk, pv = step(eng._p, pk, pv, jnp.asarray(last), jnp.asarray(at),
-                          table())
-        for s in range(S):
-            if live[s]:
-                logits[s].append(lg[s])
-    return ([(np.asarray(t_), np.asarray(jnp.stack(l), np.float32),
-              np.concatenate(pl))
-             for t_, l, pl in zip(toks, logits, pieces_logits)], reused,
-            (pk, pv))
-
-
 @pytest.fixture(scope="module")
 def served(params):
     """The float32 logits through the cache under window chains, and
@@ -219,16 +56,13 @@ def served(params):
     try:
         out = {}
         for name, reuse in (("windowed", False), ("whole", True)):
-            eng, _ = _engine(params, mp, prefix_reuse=reuse)
+            eng, _ = fam.engine(params, mp, prefix_reuse=reuse)
             assert (eng.window_chains is not None) == (not reuse)
-            out[name] = _through_the_cache(eng)
+            out[name] = tiny.through_the_window_chains(
+                eng, PROMPTS, ADMIT_AT, STEPS)
         return out
     finally:
         mp.undo()
-
-
-def _positions(prompt_len, lg):
-    return slice(prompt_len - 1, prompt_len - 1 + len(lg))
 
 
 @pytest.mark.parametrize("chains", ["windowed", "whole"])
@@ -242,7 +76,7 @@ def test_float32_through_the_cache_agrees_with_the_reference(served, params,
     prompt = PROMPTS[slot]
     assert len(toks) > 3 * TINY["window"]
     assert len(toks) > 2 * TINY["index_topk"]
-    want = _reference(params, toks)[_positions(len(prompt), lg)]
+    want = fam.reference(params, toks)[tiny.positions(len(prompt), lg)]
     assert np.abs(lg - want).max() < TOL
 
 
@@ -254,7 +88,7 @@ def test_a_prompt_in_pieces_across_the_index_topk_boundary(served, params):
     toks, _, rows = served["whole"][0][0]
     n = len(PROMPTS[0])
     assert PIECE < TINY["index_topk"] < 2 * PIECE < n
-    want = _reference(params, toks)[:n]
+    want = fam.reference(params, toks)[:n]
     assert np.abs(rows - want).max() < TOL
 
 
@@ -284,9 +118,9 @@ def test_each_assumed_line_changed_in_the_reference_is_seen(served, params,
                                                             omission):
     worst = 0.0
     for (toks, lg, _), prompt in zip(served["whole"][0], PROMPTS):
-        want = _reference(params, toks, **OMISSIONS[omission])
+        want = fam.reference(params, toks, **OMISSIONS[omission])
         worst = max(worst, float(np.abs(
-            lg - want[_positions(len(prompt), lg)]).max()))
+            lg - want[tiny.positions(len(prompt), lg)]).max()))
     assert worst > 30 * TOL, worst
 
 
@@ -398,23 +232,6 @@ def test_a_latent_plane_under_a_lower_bound(width, window):
         assert paged.window_entries(nb, B, width, window) < nb
 
 
-class _Rows:
-    """What ``routed_ffn`` asks of the cache interface."""
-
-    def __init__(self, valid):
-        self.valid = valid
-
-
-def _routed_alone(p, i, x, share):
-    arch = _arch(share)
-    w = lambda nm: _share(p, *share)[f"block{i}_{nm}"]
-    y, counts = arch_mod.routed_ffn(
-        w, arch_mod._rms(x, w("norm2.scale"), arch.eps),
-        _Rows(jnp.ones(x.shape[:-1], bool)), share, arch.top_k,
-        arch.route_scale, **arch.route_how)
-    return np.asarray(y), np.asarray(counts)
-
-
 def test_the_shares_add_up_to_the_uncut_layer(uncut):
     """The routed parts of all four shares (4 x 4 experts) and the shared
     expert counted once are the uncut reference's layer output."""
@@ -427,7 +244,7 @@ def test_the_shares_add_up_to_the_uncut_layer(uncut):
                                        routed=False))
     parts, pairs = [], 0
     for first in range(0, z["experts"], 4):
-        y, counts = _routed_alone(uncut, i, x, (first, 4))
+        y, counts = tiny.routed_alone(fam, uncut, i, x, (first, 4))
         parts.append(y - shared)
         pairs += counts[1]
     assert pairs == 24 * z["top_k"]          # every pair is some chip's
@@ -484,8 +301,8 @@ def test_the_engine_serves_shared_heads_forks_and_counts(params, monkeypatch):
     past ``index_topk``), a partial block forked copy-on-write with its
     index keys, and the counters and span attributes that say what is
     stored and what is attended."""
-    eng, reg = _engine(params, monkeypatch, prefix_reuse=True,
-                       cache_blocks=16, max_slots=2)
+    eng, reg = fam.engine(params, monkeypatch, prefix_reuse=True,
+                          cache_blocks=16, max_slots=2)
     head = (3 * np.arange(26) + 2) % 128
     first = np.concatenate([head, [9, 8, 7]])
     second = np.concatenate([head, [1, 2, 3, 4, 5]])
@@ -502,7 +319,7 @@ def test_the_engine_serves_shared_heads_forks_and_counts(params, monkeypatch):
              if e["name"] == "serving.prefill"]
     assert fills[1]["prefix_hit"] == len(head) > 2 * TINY["index_topk"]
     assert st.get("serving.cow_copies", 0) >= 1
-    want = _reference(params, out)[len(second) - 1:len(out) - 1]
+    want = fam.reference(params, out)[len(second) - 1:len(out) - 1]
     gap = want.max(-1) - want[np.arange(len(want)), out[len(second):]]
     assert gap.max() < 1e-3, gap.max()
     # the suffix's rows select inside the shared head: the reference's own
@@ -564,7 +381,7 @@ def test_the_engine_serves_shared_heads_forks_and_counts(params, monkeypatch):
 def test_a_copy_on_write_fork_copies_the_index_keys(params):
     """``make_prefill``'s leading copy: block ``src`` lands on ``dst`` in
     EVERY array of every plane, the full planes' index keys among them."""
-    arch = _arch()
+    arch = fam.arch()
     rng = np.random.default_rng(3)
     shapes = [arch.plane_block_shapes(i, B, jnp.float32) for i in range(5)]
     pk = tuple(jnp.asarray(rng.normal(size=(6,) + s[0]), jnp.float32)
@@ -593,10 +410,10 @@ def test_refusals(params):
     bad = dict(params)
     bad.pop("block1_idx_w.w")
     with pytest.raises(ValueError, match="parameters lack block1_idx_w.w"):
-        _arch().check_params(bad, T)
+        fam.arch().check_params(bad, T)
     bad = dict(params, **{"block2_att_kva.w": params["block0_att_kva.w"]})
     with pytest.raises(ValueError, match="layer 2 .sliding. holds att_kva.w"):
-        _arch().check_params(bad, T)
+        fam.arch().check_params(bad, T)
     with pytest.raises(ValueError, match="no group"):
         paged.attend(jnp.zeros((1, 1, 4, 128)), jnp.zeros((2, 4, 128)), None,
                      jnp.zeros((1, 2), jnp.int32), jnp.zeros((1, 1), jnp.int32),

@@ -16,102 +16,29 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-import paddle_tpu as pt  # noqa: E402
+import tiny  # noqa: E402
 from paddle_tpu.kernels import ssm  # noqa: E402
 from paddle_tpu.models import ssm_moe_reference as ref  # noqa: E402
 from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
 from paddle_tpu.serving import arch as _arch  # noqa: E402
 from paddle_tpu.serving.arch import MambaMoE  # noqa: E402
+from tiny import ssm_moe as fam  # noqa: E402
 
-PATTERN = "MEM*EME"
-D, V = 48, 97
-HEADS, KV, DH = 8, 2, 16                  # attention: 4 query heads a K/V head
-H, P, G, N, TAPS = 8, 8, 2, 16, 4         # Mamba-2: 4 heads a group
-E, SHARED, WIDTH, TOP_K = 40, 72, 16, 3   # experts 40 wide, 16 routed, top 3
-SCALE = 2.5
-INNER, CONV = H * P, H * P + 2 * G * N
-
-
-def make(seed, held=(4, 4), std=0.08):
-    """Seeded float32 parameters under ``MambaMoE``'s names, holding the
-    experts ``held = (first, count)`` of the router's 16."""
-    rng = np.random.default_rng(seed)
-
-    def n(*shape):
-        return (std * rng.normal(size=shape)).astype(np.float32)
-
-    ones = lambda k: np.ones(k, np.float32)                      # noqa: E731
-    first, count = held
-    p = {"tok_emb.w": n(V, D), "lm_head.w": n(D, V), "norm_f.scale": ones(D)}
-    for i, kind in enumerate(PATTERN):
-        b = f"block{i}_"
-        p[b + "norm.scale"] = ones(D)
-        if kind == "M":
-            step = np.exp(rng.uniform(np.log(0.01), np.log(0.3), H))
-            p.update({
-                b + "ssm_in.w": n(D, 2 * INNER + 2 * G * N + H) * 4,
-                b + "ssm_conv.w": rng.uniform(-0.5, 0.5, (CONV, TAPS)).astype(
-                    np.float32),
-                b + "ssm_conv.b": rng.uniform(-0.5, 0.5, CONV).astype(
-                    np.float32),
-                b + "ssm_dt.b": (step + np.log(-np.expm1(-step))).astype(
-                    np.float32),
-                b + "ssm_A_log.w": np.log(rng.uniform(1, 16, H)).astype(
-                    np.float32),
-                b + "ssm_D.w": ones(H), b + "ssm_norm.scale": ones(INNER),
-                b + "ssm_out.w": n(INNER, D)})
-        elif kind == "*":
-            p.update({b + "att_qkv.w": n(D, (HEADS + 2 * KV) * DH) * 3,
-                      b + "att_out.w": n(HEADS * DH, D)})
-        else:
-            # every share draws the SAME 16 experts and holds its own
-            up, down = n(WIDTH, E, D) * 3, n(WIDTH, E, D) * 3
-            p.update({b + "router.w": n(D, WIDTH) * 5,
-                      b + "router.bias": n(WIDTH),
-                      b + "shared_up.w": n(D, SHARED) * 3,
-                      b + "shared_down.w": n(SHARED, D),
-                      b + "experts_up.w": up[first:first + count],
-                      b + "experts_down.w": down[first:first + count]})
-    return p
-
-
-def arch(held=(4, 4), **kw):
-    return MambaMoE(PATTERN, HEADS, KV, DH, D, ssm_heads=H, ssm_head_dim=P,
-                    ssm_groups=G, ssm_state=N, conv_taps=TAPS,
-                    router_width=WIDTH, top_k=TOP_K, experts=held,
-                    route_scale=SCALE, chunk_size=8, **kw)
-
-
-LAYOUT = (PATTERN, HEADS, KV, H, G, TOP_K)
-
-
-def engine(params, reg=None, **kw):
-    return pt.serving.ServingEngine(
-        params, arch=arch(), max_len=400, max_slots=3, prefix_reuse=False,
-        cache_blocks=0, block_tokens=8, registry=reg or MetricsRegistry(),
-        **kw)
-
-
-def gaps(params, prompts, outs, held=(4, 4), **switches):
-    """The worst gap, a request, between a generated token's reference
-    logit and the reference's maximum."""
-    worst = []
-    for prompt, full in zip(prompts, outs):
-        full = np.asarray(full)
-        assert np.array_equal(full[:len(prompt)], prompt)
-        lg = np.asarray(ref.forward(params, full[None], *LAYOUT, held, SCALE,
-                                    **switches))[0]
-        at = lg[len(prompt) - 1:len(full) - 1]
-        worst.append(float(np.max(
-            at.max(-1) - at[np.arange(len(at)), full[len(prompt):]])))
-    return worst
+Z = fam.sizes
+PATTERN, D, V = Z["pattern"], Z["d"], Z["rows"]
+# attention: 4 query heads a K/V head; Mamba-2: 4 heads a group
+HEADS, KV, DH = Z["heads"], Z["kv_heads"], Z["dh"]
+H, P, G, N, TAPS = (Z[k] for k in ("ssm_heads", "ssm_dh", "groups", "state",
+                                   "taps"))
+E, WIDTH, TOP_K, SCALE = Z["e"], Z["experts"], Z["top_k"], Z["scale"]
+CONV = H * P + 2 * G * N
 
 
 @pytest.fixture(scope="module")
 def served():
-    params = make(0)
+    params = fam.init(0)
     reg = MetricsRegistry()
-    eng = engine(params, reg, compute_dtype="float32")
+    eng = fam.engine(params, registry=reg, compute_dtype="float32")[0]
     rng = np.random.default_rng(1)
     # more prompts than slots, so slots are reused; one and several
     # chunks, every bucket width, a prompt that ends on a chunk boundary
@@ -124,7 +51,7 @@ def served():
 def test_engine_through_pieces_and_decode_is_the_reference(served):
     params, _, _, prompts, outs = served
     # float32 end to end: greedy tokens ARE the reference's argmax
-    assert max(gaps(params, prompts, outs)) <= 1e-4
+    assert max(tiny.gaps(fam, params, prompts, outs)) <= 1e-4
 
 
 @pytest.mark.parametrize("switch", [
@@ -136,7 +63,7 @@ def test_engine_through_pieces_and_decode_is_the_reference(served):
     {"lost": np.array([300], np.int32)}], ids=lambda s: next(iter(s)))
 def test_each_line_of_the_layers_is_seen_by_the_comparison(served, switch):
     params, _, _, prompts, outs = served
-    assert max(gaps(params, prompts, outs, **switch)) > 0.01
+    assert max(tiny.gaps(fam, params, prompts, outs, **switch)) > 0.01
 
 
 def test_a_reused_slot_starts_from_a_zero_state_and_zero_tails(served):
@@ -144,7 +71,7 @@ def test_a_reused_slot_starts_from_a_zero_state_and_zero_tails(served):
     # the same prompt alone in a fresh engine gives the same tokens as it
     # gave in a slot that an earlier, longer request had left its state
     # and its convolution's rows in
-    again = engine(params, compute_dtype="float32").generate_many(
+    again = fam.engine(params, compute_dtype="float32")[0].generate_many(
         [prompts[3]], max_new_tokens=12)
     assert np.array_equal(again[0], outs[3])
 
@@ -156,21 +83,19 @@ def test_a_prompt_of_several_pieces_threads_state_and_tails(monkeypatch):
     from paddle_tpu.serving import batched_decode as _bd
 
     monkeypatch.setattr(_bd, "PREFILL_PIECE", 32)
-    params, reg = make(0), MetricsRegistry()
-    eng = engine(params, reg, compute_dtype="float32")
+    params, reg = fam.init(0), MetricsRegistry()
+    eng = fam.engine(params, registry=reg, compute_dtype="float32")[0]
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, V, n, dtype=np.int32) for n in (70, 20)]
     outs = eng.generate_many(prompts, max_new_tokens=4)
-    assert max(gaps(params, prompts, outs)) <= 1e-4
+    assert max(tiny.gaps(fam, params, prompts, outs)) <= 1e-4
     # a reference that forgets either at a piece boundary is another
     # function on the rows the engine generated: the agreement above is
     # of state and tails carried over the boundaries at 32 and 64
-    full = np.asarray(outs[0])[None]
-    sound = ref.forward(params, full, *LAYOUT, (4, 4), SCALE)[0, 69:]
+    sound = fam.reference(params, outs[0])[69:]
     for switch in ({"state_every": 32}, {"tails_every": 32}):
-        other = ref.forward(params, full, *LAYOUT, (4, 4), SCALE,
-                            **switch)[0, 69:]
-        assert float(jnp.abs(other - sound).max()) > 0.01, switch
+        other = fam.reference(params, outs[0], **switch)[69:]
+        assert float(np.abs(other - sound).max()) > 0.01, switch
     stats = eng.stats()
     # 70 tokens: pieces of 32, 32 and 8 rows; 20: one of 32
     assert stats["serving.prefill_pieces{width=32}"] == 3
@@ -179,7 +104,7 @@ def test_a_prompt_of_several_pieces_threads_state_and_tails(monkeypatch):
 
 def test_state_planes_gauges_and_counters(served):
     _, eng, reg, _, _ = served
-    a = arch()
+    a = fam.arch()
     assert (a.ssm_layers, a.moe_layers, len(a.planes)) == (3, 3, 1)
     assert a.kv_planes == 1 and a.rows_per_entry == 4
     assert a.last_routed == 6 and a.experts_held == 4
@@ -204,7 +129,7 @@ def test_state_planes_gauges_and_counters(served):
     # 300 rows are one piece of 400 (the widest rung, capped at max_len)
     assert stats["serving.prefill_pieces{width=400}"] == 1
     # bfloat16 pool: 2 K/V heads are stored as 8 rows
-    gauges = arch().gauges({"tok_emb.w": jnp.zeros((1, 1), jnp.bfloat16),
+    gauges = fam.arch().gauges({"tok_emb.w": jnp.zeros((1, 1), jnp.bfloat16),
                             **{f"block6_experts_{m}.w": jnp.zeros(
                                 (4, E, D), jnp.bfloat16)
                                for m in ("up", "down")}})
@@ -213,13 +138,13 @@ def test_state_planes_gauges_and_counters(served):
 
 
 def test_refusals_say_why():
-    params = make(0)
+    params = fam.init(0)
     # prefix_reuse=True is served from state snapshots since PR 57
     # (tests/test_state_prefix_hit.py); a draft still is not
     from paddle_tpu.serving.speculative import validate_draft
 
     with pytest.raises(ValueError, match="rolled back"):
-        validate_draft(params, params, arch(), 64)
+        validate_draft(params, params, fam.arch(), 64)
     with pytest.raises(ValueError, match="pattern characters"):
         MambaMoE("MEX", HEADS, KV, DH, D, H, P, G, N, TAPS, WIDTH, TOP_K,
                  (0, 4))
@@ -229,9 +154,9 @@ def test_refusals_say_why():
     with pytest.raises(ValueError, match="projects to"):
         bad = dict(params)
         bad["block5_ssm_in.w"] = bad["block5_ssm_in.w"][:, :-1]
-        arch().check_params(bad, 64)
+        fam.arch().check_params(bad, 64)
     with pytest.raises(ValueError, match="hold 4 experts"):
-        arch(held=(0, 8)).check_params(params, 64)
+        fam.arch(share=(0, 8)).check_params(params, 64)
     with pytest.raises(ValueError, match="not one of"):
         _arch.routed_ffn(None, jnp.zeros((1, 4)), None, (0, 1), 1,
                          form="gelu")
@@ -239,19 +164,12 @@ def test_refusals_say_why():
 
 def test_bfloat16_engine_stays_within_a_margin_of_the_reference():
     params = {k: np.asarray(jnp.asarray(v, jnp.bfloat16).astype(jnp.float32))
-              for k, v in make(4).items()}
+              for k, v in fam.init(4).items()}
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, V, n, dtype=np.int32) for n in (200, 33)]
-    outs = engine(params, compute_dtype="bfloat16").generate_many(
+    outs = fam.engine(params, compute_dtype="bfloat16")[0].generate_many(
         prompts, max_new_tokens=10)
-    assert max(gaps(params, prompts, outs)) < 0.1
-
-
-class _Rows:
-    """What ``routed_ffn`` reads of the cache: which rows are real."""
-
-    def __init__(self, valid):
-        self.valid = valid
+    assert max(tiny.gaps(fam, params, prompts, outs)) < 0.1
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer():
@@ -261,19 +179,19 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     (all 16); and a share's own result against the reference given the
     same share."""
     i, rows = 1, 24
-    whole = make(7, held=(0, WIDTH))
+    whole = fam.init(7, share=(0, WIDTH))
     # rows of unit RMS: the reference's layer norms what it is given (a
     # unit gain here), the program's is handed normed rows
     h = np.random.default_rng(3).normal(size=(rows, D))
     h = jnp.asarray(h / np.sqrt(np.mean(h * h, axis=-1, keepdims=True)),
                     jnp.float32)
-    valid = _Rows(jnp.ones((rows,), bool))
+    valid = tiny.Rows(jnp.ones((rows,), bool))
     w = lambda p: (lambda name: jnp.asarray(p[f"block{i}_{name}"]))  # noqa
     uncut = ref.routed_ffn({k: jnp.asarray(v) for k, v in whole.items()},
                            i, h[None], TOP_K, (0, WIDTH), SCALE)
     parts, shared = [], None
     for first in range(0, WIDTH, 2):
-        share = make(7, held=(first, 2))
+        share = fam.init(7, share=(first, 2))
         routed, counts = _arch.routed_ffn(
             w(share), h, valid, (first, 2), TOP_K, SCALE, shared=False,
             form="relu2")

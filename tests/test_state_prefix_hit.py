@@ -11,19 +11,13 @@ PR 57 the engine refused ``prefix_reuse=True`` for all three:
 ``test_sambay.py`` and ``test_ssm_moe.py`` pinned the refusal, and those
 cases are these.)"""
 
-import os
-import sys
-
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-import test_delta_moe  # noqa: E402
-import test_sambay  # noqa: E402
-import test_ssm_moe  # noqa: E402
+import tiny  # noqa: E402
 from paddle_tpu.observability.metrics import MetricsRegistry  # noqa: E402
 from paddle_tpu.serving import ServingEngine  # noqa: E402
 from paddle_tpu.serving import batched_decode as _bd  # noqa: E402
@@ -33,16 +27,15 @@ B, PIECE, T = 8, 32, 192
 HEAD = 2 * PIECE
 
 
+CASES = {"sambay": tiny.sambay, "mamba_moe": tiny.ssm_moe,
+         "delta_moe": tiny.delta_moe}
+
+
 def _case(name):
-    """``(parameters, architecture, vocabulary rows)`` at the tiny size of
-    the architecture's own test."""
-    if name == "sambay":
-        return (test_sambay._init(jax.random.PRNGKey(32), test_sambay.TINY,
-                                  jnp.float32),
-                test_sambay._arch(), test_sambay.TINY["rows"])
-    if name == "mamba_moe":
-        return test_ssm_moe.make(0), test_ssm_moe.arch(), test_ssm_moe.V
-    return test_delta_moe.make(0), test_delta_moe.arch(), test_delta_moe.V
+    """``(parameters, architecture, vocabulary rows)`` at the family's
+    tiny size."""
+    fam = CASES[name]
+    return fam.init(), fam.arch(), fam.rows
 
 
 def _engine_of(params, arch, rows=2, **kw):
@@ -64,9 +57,14 @@ def _engine_of(params, arch, rows=2, **kw):
 def _next_logits(eng, slot):
     """The logits of ``slot``'s first decode step, from the engine's own
     arrays as its prefill left them (nothing is written back)."""
-    return np.asarray(_bd.paged_step_logits(
+    if not hasattr(eng, "_test_step"):     # one program an engine
+        eng._test_step = jax.jit(
+            lambda p, last, pos, pk, pv, table, state:
+            _bd.paged_step_logits(p, last, pos, pk, pv, table, eng.arch,
+                                  state)[0])
+    return np.asarray(eng._test_step(
         eng._p, eng._last, eng._pos, eng._pk, eng._pv,
-        jnp.asarray(eng._table), eng.arch, eng._state)[0][slot])
+        jnp.asarray(eng._table), eng._state)[slot])
 
 
 def _serve(eng, prompt, max_new=8):
@@ -81,7 +79,7 @@ def _serve(eng, prompt, max_new=8):
     return req, logits, np.asarray(req.result(timeout=0))
 
 
-@pytest.mark.parametrize("name", ["sambay", "mamba_moe", "delta_moe"])
+@pytest.mark.parametrize("name", list(CASES))
 def test_a_prefix_hit_starts_from_one_state_snapshot(name, monkeypatch):
     monkeypatch.setattr(_bd, "PREFILL_PIECE", PIECE)
     params, arch, vocab = _case(name)

@@ -13,7 +13,7 @@ import os
 import numpy as np
 import pytest
 
-import test_serving as tsv
+import tiny
 from chipbench import run as bench_run
 from chipbench import tail_account
 from paddle_tpu.models import transformer
@@ -22,7 +22,8 @@ from paddle_tpu.observability.metrics import MetricsRegistry
 from paddle_tpu.serving import ServingEngine, depth_draft
 from paddle_tpu.serving import engine as engine_mod
 
-T = 64
+T, VOCAB = 64, tiny.BUILT_VOCAB
+NL, NH, DM = (tiny.gpt2.sizes[k] for k in ("layers", "heads", "d"))
 READERS = ("serve.tpot_p90_ms", "tail.tpot_ms", "tail.step_ms",
            "tail.live_slots", "tail.steps_per_token",
            "tail.prefill_stall_share", "tail.host_stall_share",
@@ -32,7 +33,7 @@ READERS = ("serve.tpot_p90_ms", "tail.tpot_ms", "tail.step_ms",
 
 @pytest.fixture(scope="module")
 def gpt2():
-    return tsv._make_params(max_len=T)
+    return tiny.gpt2_built(max_len=T)
 
 
 @pytest.fixture
@@ -46,7 +47,7 @@ def tracer():
 def _engine(params, **kw):
     kw.setdefault("max_slots", 3)
     kw.setdefault("registry", MetricsRegistry())
-    return ServingEngine(params, tsv.NL, tsv.NH, tsv.DM, max_len=T,
+    return ServingEngine(params, NL, NH, DM, max_len=T,
                          decode_chunk=4, min_bucket=4, block_tokens=4, **kw)
 
 
@@ -68,7 +69,7 @@ def _serve_with_arrivals(eng, prompts, max_new, eos=None):
 
 def _prompts(seed, lens, head=None):
     rng = np.random.default_rng(seed)
-    tails = [rng.integers(1, tsv.VOCAB, n) for n in lens]
+    tails = [rng.integers(1, VOCAB, n) for n in lens]
     return tails if head is None else [np.concatenate([head, t])
                                       for t in tails]
 
@@ -98,7 +99,7 @@ LENS = [3, 7, 2, 5, 9, 4, 6]
 @pytest.mark.parametrize("kind", ["plain", "trie", "spec"])
 def test_first_token_to_finish_is_chunks_and_the_two_waits(kind, gpt2,
                                                            tracer):
-    head = np.arange(1, 11) % tsv.VOCAB if kind == "trie" else None
+    head = np.arange(1, 11) % VOCAB if kind == "trie" else None
     kw = dict(prefix_reuse=kind != "plain")
     if kind == "spec":
         kw.update(draft_params=depth_draft(gpt2, 1), spec_k=3)
@@ -163,10 +164,10 @@ def test_an_eos_hit_that_rides_a_chunk_keeps_its_account(gpt2, tracer):
     nothing and adds nothing to the finished account."""
     rng = np.random.default_rng(5)
     for _ in range(50):
-        a = rng.integers(1, tsv.VOCAB, 5)
+        a = rng.integers(1, VOCAB, 5)
         ref, _ = transformer.generate(
-            gpt2, np.asarray(a)[None], max_len=T, n_layer=tsv.NL,
-            n_head=tsv.NH, d_model=tsv.DM, return_logits=False)
+            gpt2, np.asarray(a)[None], max_len=T, n_layer=NL,
+            n_head=NH, d_model=DM, return_logits=False)
         gen = list(np.asarray(ref)[0][len(a):len(a) + 20])
         hits = [i for i in (5, 6, 7) if gen[i] not in gen[:i]]
         if hits:
@@ -190,7 +191,7 @@ def test_an_eos_hit_that_rides_a_chunk_keeps_its_account(gpt2, tracer):
 
 def test_stats_publishes_the_tail_and_its_factors_multiply(gpt2, tracer):
     eng = _engine(gpt2, prefix_reuse=True)
-    head = np.arange(1, 11) % tsv.VOCAB
+    head = np.arange(1, 11) % VOCAB
     reqs = _serve_with_arrivals(
         eng, _prompts(3, LENS + [8, 3, 5], head), MAX_NEW + [8, 12, 2])
     st = eng.stats()

@@ -9,7 +9,7 @@ from jax.sharding import PartitionSpec as P
 import paddle_tpu as pt
 from paddle_tpu.models import transformer
 
-from test_book import train_steps
+from tiny import train_steps
 
 
 def _lm_batch(rng, batch, seq, vocab):
