@@ -23,10 +23,12 @@ barrier gets), ``op_read`` the op with the two products as the
 scan-remat engine lowers them since PR 54 (``mul(_reads_saved=True)``:
 the operand behind a barrier in the forward alone, taken over the rows
 as they stand).  ``--step-shapes`` is the training step's geometry: rows
-``[2, 2048, .]`` through ``mul``'s flattening and the scan inside a
-two-iteration outer loop, where the compiler makes the step's choices
-and not the plain walk's (with flat ``[4096, .]`` rows ``op_read`` and
-a barrier on the flat operand are the same program).  A last line holds the op's error on the chip over every
+``[2, 2048, .]`` through ``mul`` and the scan inside a two-iteration
+outer loop, where the compiler makes the step's choices and not the
+plain walk's (with flat ``[4096, .]`` rows ``op_read`` and a barrier on
+the flat operand are the same program).  Since PR 60 ``mul`` takes such
+rows as they stand in EVERY way, so the other ways' step-shape lines of
+``RESULTS.md`` (PR 54: through the flattening) are the parent's.  A last line holds the op's error on the chip over every
 finite bfloat16 value against the float64 function.  Refuses unless JAX
 finds a TPU: a number from a CPU run is no device metric.
 """

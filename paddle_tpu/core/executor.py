@@ -410,10 +410,12 @@ def _apply_activation_spec(ctx, name, spec, val):
 
 
 def run_block_ops(ctx, block, ops, env, inside_grad_prefix=False,
-                  reading=()):
+                  reading=(), over_rows=None):
     """Trace-time evaluation of a list of OpDescs over a name->array env.
-    ``reading`` is the scan-remat body's: the outputs of the ``mul`` ops
-    that take the reading form (``ops/math_ops.py::_mul_reading``)."""
+    ``reading`` and ``over_rows`` are the scan-remat body's: the outputs
+    of the ``mul`` ops that take the reading form
+    (``ops/math_ops.py::_mul_reading``), and a set that receives the
+    outputs of those lowered with no flattening (``folds_rows_only``)."""
     act_specs = (
         _activation_shard_specs(ctx.program)
         if ctx.program is not None
@@ -447,8 +449,16 @@ def run_block_ops(ctx, block, ops, env, inside_grad_prefix=False,
         attrs = dict(op.attrs)
         if impl.stateful_rng and "_key" not in attrs:
             attrs["_key"] = ctx.next_op_key()
-        if reading and op.type == "mul" and op.outputs["Out"][0] in reading:
-            attrs["_reads_saved"] = True
+        if op.type == "mul":
+            if op.outputs["Out"][0] in reading:
+                attrs["_reads_saved"] = True
+            if over_rows is not None:
+                from ..ops.math_ops import folds_rows_only
+
+                if folds_rows_only(ins["X"], ins["Y"],
+                                   attrs.get("x_num_col_dims", 1),
+                                   attrs.get("y_num_col_dims", 1)):
+                    over_rows.add(op.outputs["Out"][0])
         pin_names = ()
         if act_specs:
             pin_names = tuple(
@@ -1327,6 +1337,9 @@ class Executor:
                                         if op_.type == "mul"
                                         and op_.inputs["X"][0]
                                         in made_wrapped)
+                                # filled as the body is traced: the
+                                # rule is the operands' shapes'
+                                products_over_rows = set()
 
                                 shared_env = {n: e[n] for n in shared_names}
                                 xs_stacked = {
@@ -1421,7 +1434,8 @@ class Executor:
                                             run_block_ops(
                                                 fctx, block, ops_j, e2,
                                                 inside_grad_prefix=True,
-                                                reading=products_reading)
+                                                reading=products_reading,
+                                                over_rows=products_over_rows)
                                             continue
                                         tags = (
                                             frozenset(carry_map)
@@ -1441,7 +1455,8 @@ class Executor:
                                                         BLOCK_INPUT_TAG)
                                             run_block_ops(
                                                 fctx, block, _ops, e3,
-                                                inside_grad_prefix=True)
+                                                inside_grad_prefix=True,
+                                                over_rows=products_over_rows)
                                             return {n: e3[n] for n in _out
                                                     if n in e3}
 
@@ -1486,6 +1501,12 @@ class Executor:
                                          "lowered to READ the output of a "
                                          "checkpointed sub-segment").inc(
                                     len(products_reading))
+                                reg.counter(
+                                    "executor.products_over_rows",
+                                    help="products of a scan-remat body "
+                                         "lowered over X as it stands, "
+                                         "with no flattening").inc(
+                                    len(products_over_rows))
                                 if fsdp_gather:
                                     reg.counter(
                                         "executor.fsdp_groups",
@@ -1499,7 +1520,9 @@ class Executor:
                                      "shared": len(shared_names),
                                      "fsdp": len(fsdp_gather),
                                      "offload": off_mode,
-                                     "reading": sorted(products_reading)})
+                                     "reading": sorted(products_reading),
+                                     "over_rows":
+                                         sorted(products_over_rows)})
                                 return True
                             except Exception as exc:
                                 # classification/trace failure: restore the

@@ -58,18 +58,36 @@ _register_elementwise("min", jnp.minimum)
 _register_elementwise("pow", jnp.power)
 
 
-def _mul(X, Y, x_num_col_dims, y_num_col_dims, whole=False):
-    """Flattening matmul (reference mul_op.cc): X collapses to 2-D at
-    x_num_col_dims, Y at y_num_col_dims; result regains X's leading dims.
-    ``whole`` takes the product over X as it stands where the flattening
-    would only fold X's leading dims into rows: the same contraction
-    with no reshape on either side of it."""
-    if not (whole and x_num_col_dims == X.ndim - 1
-            and (Y.ndim, y_num_col_dims) == (2, 1)):
-        X = X.reshape((int(np.prod(X.shape[:x_num_col_dims])), -1))
-        Y = Y.reshape((int(np.prod(Y.shape[:y_num_col_dims])), -1))
+def folds_rows_only(X, Y, x_num_col_dims, y_num_col_dims):
+    """Whether ``mul``'s flattening would only fold X's leading dims into
+    rows: the contraction is then over X's last dim as X stands."""
+    return (X.ndim > 2 and x_num_col_dims == X.ndim - 1
+            and (Y.ndim, y_num_col_dims) == (2, 1))
+
+
+def _dot(X, Y):
     out = jnp.dot(X, Y, preferred_element_type=_acc_type(X))
     return out if out.dtype == X.dtype else out.astype(X.dtype)
+
+
+def _mul_flat(X, Y, x_num_col_dims, y_num_col_dims):
+    """Flattening matmul (reference mul_op.cc): X collapses to 2-D at
+    x_num_col_dims, Y at y_num_col_dims."""
+    return _dot(X.reshape((int(np.prod(X.shape[:x_num_col_dims])), -1)),
+                Y.reshape((int(np.prod(Y.shape[:y_num_col_dims])), -1)))
+
+
+def _mul(X, Y, x_num_col_dims, y_num_col_dims):
+    """``mul_op``'s product.  Rows that only fold (``folds_rows_only``)
+    take it over X as it stands, forward and backward, whoever made X:
+    the same contraction with no reshape on either side of it.  A
+    reshape between a product and its neighbours un-fuses them on the
+    chip: the bias add becomes a pass of its own, and a scan's saved
+    stack is written by a second pass that adds the bias again
+    (docs/memory.md; PERF.md section 6, PRs 54 and 60)."""
+    if folds_rows_only(X, Y, x_num_col_dims, y_num_col_dims):
+        return _dot(X, Y)
+    return _mul_flat(X, Y, x_num_col_dims, y_num_col_dims)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -78,14 +96,11 @@ def _mul_reading(X, Y, x_num_col_dims, y_num_col_dims):
     compiler materialise X once, where it is otherwise free to evaluate
     a cheap elementwise producer again inside the product's operand
     fusion (0.31 ms a [4096, 6144] GELU whatever it holds: PERF.md
-    section 6, PR 54).  The product is ``whole``, forward and backward:
-    a reshape between a producer and the product un-fuses the producer,
-    and the writes into the scan's saved stacks with it, from the
-    epilogue of the product before.  The barrier is on the forward's
-    operand alone and the residuals are the operands as they came, so
-    what a scan saves and every gradient keep their values."""
+    section 6, PR 54).  The barrier is on the forward's operand alone
+    and the residuals are the operands as they came, so what a scan
+    saves and every gradient keep their values."""
     return _mul(jax.lax.optimization_barrier(X), Y, x_num_col_dims,
-                y_num_col_dims, whole=True)
+                y_num_col_dims)
 
 
 def _mul_reading_fwd(X, Y, x_num_col_dims, y_num_col_dims):
@@ -94,7 +109,7 @@ def _mul_reading_fwd(X, Y, x_num_col_dims, y_num_col_dims):
 
 def _mul_reading_bwd(x_num_col_dims, y_num_col_dims, operands, g):
     return jax.vjp(
-        lambda X, Y: _mul(X, Y, x_num_col_dims, y_num_col_dims, whole=True),
+        lambda X, Y: _mul(X, Y, x_num_col_dims, y_num_col_dims),
         *operands)[1](g)
 
 

@@ -50,6 +50,75 @@ def test_mul_flatten():
     check_grad("mul", {"X": x, "Y": y}, "Y")
 
 
+# -- the product over the rows as they stand (ISSUE 60) ---------------------
+
+def _value_and_grads(product, x, y, cols):
+    import jax
+    import jax.numpy as jnp
+
+    def out(x, y):
+        return product(x, y, cols, 1).reshape(x.shape[:cols] + y.shape[1:])
+
+    def loss(x, y):
+        return (out(x, y).astype(jnp.float32) ** 2).sum()
+
+    got = (jax.jit(out)(x, y),) + jax.jit(jax.grad(loss, (0, 1)))(x, y)
+    return [np.asarray(v.astype(jnp.float32)) for v in got]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(2, 6, 16), (2, 3, 4, 16), (1, 5, 16)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_mul_over_rows_is_the_flat_product(shape, dtype):
+    """Rows that only fold take the product over X as it stands: the
+    flat form's value and both gradients, to the bit on the CPU."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import math_ops
+
+    x = jnp.asarray(rng.randn(*shape), dtype)
+    y = jnp.asarray(rng.randn(shape[-1], 24) * 0.3, dtype)
+    cols = len(shape) - 1
+    assert math_ops.folds_rows_only(x, y, cols, 1)
+    flat = _value_and_grads(math_ops._mul_flat, x, y, cols)
+    whole = _value_and_grads(math_ops._mul, x, y, cols)
+    for name, f, w in zip(("out", "dx", "dy"), flat, whole):
+        np.testing.assert_array_equal(f, w, err_msg=name)
+
+
+@pytest.mark.parametrize("x_shape,y_shape,cols,y_cols,flattens", [
+    ((2, 6, 16), (16, 8), 2, 1, False),
+    ((2, 3, 4, 16), (16, 8), 3, 1, False),
+    ((6, 16), (16, 8), 1, 1, False),         # its own flattening
+    ((2, 3, 4, 4), (48, 8), 1, 1, True),     # [N, C, H, W] into an fc
+    ((2, 3, 4, 4), (16, 8), 2, 1, True),     # folds trailing dims too
+    ((2, 6, 16), (4, 4, 8), 2, 2, True),     # a 3-D Y
+], ids=["3d-rows", "4d-rows", "2d", "nchw", "4d-mid", "y3d"])
+def test_which_products_flatten(x_shape, y_shape, cols, y_cols, flattens):
+    """The rule is the shape's: the traced ``mul`` holds a reshape only
+    where the flattening does more than fold X's leading dims."""
+    import jax
+
+    from paddle_tpu.ops import math_ops
+
+    x = rng.randn(*x_shape).astype(np.float32)
+    y = rng.randn(*y_shape).astype(np.float32)
+    out = run_op("mul", {"X": x, "Y": y},
+                 attrs={"x_num_col_dims": cols, "y_num_col_dims": y_cols})
+    want = (x.reshape(int(np.prod(x_shape[:cols])), -1)
+            @ y.reshape(int(np.prod(y_shape[:y_cols])), -1))
+    np.testing.assert_allclose(
+        np.asarray(out["Out"]),
+        want.reshape(x_shape[:cols] + y_shape[y_cols:]), rtol=1e-5,
+        atol=1e-5)
+    jaxpr = jax.make_jaxpr(lambda x, y: math_ops.mul(
+        x, y, x_num_col_dims=cols, y_num_col_dims=y_cols)["Out"])(x, y)
+    assert any(e.primitive.name == "reshape"
+               for e in jaxpr.eqns) == flattens, jaxpr
+    assert math_ops.folds_rows_only(x, y, cols, y_cols) == (
+        not flattens and x.ndim > 2)
+
+
 def test_matmul_transpose():
     x = rng.randn(3, 4).astype(np.float32)
     y = rng.randn(5, 4).astype(np.float32)

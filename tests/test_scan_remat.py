@@ -397,3 +397,63 @@ def test_products_reading_saved_counter(policy, want):
     assert counter.value - before == want
     if policy:
         assert exe.last_remat_plan[0]["count"] == 2
+
+
+# -- the product over the rows as they stand (ISSUE 60) ---------------------
+
+def _mlp_2d(policy):
+    """Four ``fc`` + LayerNorm layers on 2-D rows: a scanned body whose
+    products have nothing to fold."""
+    pt.core.unique_name.reset()
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        h = pt.layers.data(name="x", shape=[16], dtype="float32")
+        y = pt.layers.data(name="y", shape=[1], dtype="float32")
+        for _ in range(4):
+            h = pt.layers.layer_norm(pt.layers.fc(h, size=16, act="relu"))
+        cost = pt.layers.mean(
+            pt.layers.square_error_cost(pt.layers.fc(h, size=1), y))
+        pt.optimizer.SGD(learning_rate=0.1).minimize(cost)
+    pt.memory_optimize(main, policy=policy)
+    return main, startup, cost
+
+
+def _run_mlp_2d(policy):
+    main, startup, cost = _mlp_2d(policy)
+    scope = pt.Scope()
+    pt.core.scope._scope_stack.append(scope)
+    try:
+        exe = pt.Executor()
+        exe.run(startup, scope=scope)
+        rng = np.random.default_rng(60)
+        exe.run(main, feed={"x": rng.normal(size=(8, 16)).astype("float32"),
+                            "y": rng.normal(size=(8, 1)).astype("float32")},
+                fetch_list=[cost], scope=scope)
+        return exe
+    finally:
+        pt.core.scope._scope_stack.pop()
+
+
+@pytest.mark.parametrize("run,over_rows,reading", [
+    (lambda: _step_grads(*_build("selective"))[2], 6, 2),
+    (lambda: _step_grads(*_build("full"))[2], 6, 0),
+    (lambda: _run_mlp_2d("selective"), 0, 0),
+], ids=["gpt-selective", "gpt-full", "fc-2d"])
+def test_products_over_rows_counter(run, over_rows, reading):
+    """A scanned GPT body holds six products on rows ``[b, t, .]`` (q,
+    k, v, out, ``ffn1``, ``ffn2``), every one lowered with no
+    flattening wherever its segment sits, wrapped (``full``) or not;
+    two of them read under `selective`.  Both layers share the one
+    body.  A body of 2-D ``fc``s is scanned too and keeps the flat path:
+    the rule is the shape's."""
+    from paddle_tpu.observability import get_registry
+
+    counters = [get_registry().counter("executor.products_" + name)
+                for name in ("over_rows", "reading_saved")]
+    before = [c.value for c in counters]
+    exe = run()
+    assert [c.value - b for c, b in zip(counters, before)] == [over_rows,
+                                                               reading]
+    (group,) = exe.last_remat_plan
+    assert len(group["over_rows"]) == over_rows
+    assert set(group["reading"]) <= set(group["over_rows"])
