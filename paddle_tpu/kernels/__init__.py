@@ -4,11 +4,13 @@
 One op, two targets, one numerics oracle: every fused op class
 (``flash_attention``, ``fused_ce``, ``paged_attention``,
 ``chain_attention``, ``grouped_matmul``, ``retention``, ``ssm``,
-``delta_rule``, ``index_scores``, ``sparse_latent_attention``) resolves
+``delta_rule``, ``index_scores``, ``sparse_latent_attention``,
+``block_scores``, ``block_sparse_attention``) resolves
 through :mod:`.registry` to ``pallas_tpu`` (the Mosaic kernels — native
-on TPU, interpret mode in CPU tests; the last two op classes, a learned
-indexer's scores and the attention of the rows it selects, have no such
-backend yet) or ``xla_ref`` (:mod:`.xla_ref` —
+on TPU, interpret mode in CPU tests; the last four op classes, a learned
+indexer's scores and the attention of the rows it selects, a K/V plane's
+block scores and the attention of the blocks they select, have no such
+backend yet: the last walks its selected table through ``paged_attention``) or ``xla_ref`` (:mod:`.xla_ref` —
 the shape-complete pure-XLA reference every backend is tested against,
 with the documented cross-backend tolerances in ``ORACLE_TOL``).  How a
 serving row attends through the block table (streaming kernel, dense
@@ -38,6 +40,7 @@ from . import retention  # registers power retention's step and chunk
 from . import ssm  # registers Mamba-2's step and chunked form
 from . import delta  # registers the gated delta rule's step and WY form
 from . import sparse_attention  # registers an indexer's scores and rows
+from . import block_sparse_attention  # registers block scores and their read
 
 __all__ = [
     "AUTO_ORDER", "BACKENDS", "GLOBAL_ENV", "TIMED_RUN_ENV",

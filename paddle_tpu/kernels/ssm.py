@@ -45,7 +45,14 @@ when the caller donates them; the signatures are what
 
 ``layer`` is ``conv_w [C, taps]``, ``conv_b [C]``, ``dt_bias``,
 ``A_log``, ``D`` (each ``[H]``), ``heads``, ``groups`` and
-``chunk_size``.  ``ssm_scan_ref`` is the recurrence row by row, what
+``chunk_size``.  A layer with NO convolution passes ``conv_w=None``
+(``conv_b`` is then not read): its rows enter the recurrence as they
+are, with no activation, and its tails are ``[slots, 0, C]``
+(``state_shapes(.., taps=1)``).  That is how a recurrence of CONSTANT
+decay rides these two calls (Lightning Attention-2,
+``serving.arch.SparseLightning``: ``x = v``, ``B = k``, ``C = q``, a
+group a head, ``dt`` zeros under ``dt_bias = log(e - 1)`` so that
+``delta`` is 1, ``A_log`` the log of the head's slope, ``D`` zeros).  ``ssm_scan_ref`` is the recurrence row by row, what
 both are tested against.  Inference only (no VJP).
 """
 
@@ -122,10 +129,19 @@ def chunk(S, tail, slot, fresh, xbc, dt, valid, **layer):
 
 # -- what both calls share ---------------------------------------------------
 
+def _taps(conv_w):
+    """Taps of the layer's convolution; 1 for a layer that has none."""
+    return 1 if conv_w is None else conv_w.shape[1]
+
+
 def _conv(rows, conv_w, conv_b):
     """``silu(conv + b)`` float32 ``[..., W, C]`` of ``rows [..., taps - 1
-    + W, C]`` (the tails, then the call's rows)."""
+    + W, C]`` (the tails, then the call's rows); the rows as they are,
+    with no activation, for a layer with NO convolution (``conv_w``
+    ``None``: its tails hold no row)."""
     f32 = jnp.float32
+    if conv_w is None:
+        return rows.astype(f32)
     taps = conv_w.shape[1]
     W = rows.shape[-2] - taps + 1
     cw = conv_w.astype(f32)
@@ -232,7 +248,7 @@ def ssm_chunk(S, tail, slot, fresh, xbc, dt, valid, *, conv_w, conv_b,
               dt_bias, A_log, D, heads, groups, chunk_size=128):
     """The chunked form (module docstring), both backends'."""
     f32 = jnp.float32
-    W, taps = xbc.shape[0], conv_w.shape[1]
+    W, taps = xbc.shape[0], _taps(conv_w)
     inner = _inner(S)
     with jax.named_scope(CHUNK_SCOPE):
         keep = jnp.where(fresh, 0, 1)

@@ -100,6 +100,13 @@ ORACLE_TOL = {
     ("index_scores", "bfloat16"): {"fwd": 2e-2, "grad": None},
     ("sparse_latent_attention", "float32"): {"fwd": 2e-4, "grad": None},
     ("sparse_latent_attention", "bfloat16"): {"fwd": 2e-2, "grad": None},
+    # a K/V plane's block scores (a softmax a head over the compressed
+    # keys) and the attention of the blocks they select: the oracle's
+    # backend only; the walk of the selected table is paged_attention's
+    ("block_scores", "float32"): {"fwd": 2e-4, "grad": None},
+    ("block_scores", "bfloat16"): {"fwd": 2e-2, "grad": None},
+    ("block_sparse_attention", "float32"): {"fwd": 2e-4, "grad": None},
+    ("block_sparse_attention", "bfloat16"): {"fwd": 2e-2, "grad": None},
 }
 
 

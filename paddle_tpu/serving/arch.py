@@ -67,7 +67,7 @@ adds to what the compiled decode chunk and prefill piece return as their
 last output (``arch.count_names`` names the entries; an architecture
 with none never calls it and its programs have no such output).
 
-Ten architectures are here: ``Gpt2`` (the block of
+Eleven architectures are here: ``Gpt2`` (the block of
 ``models/transformer.py``: pre-LayerNorm, learned absolute positions,
 GELU FFN, biases; arithmetic and dtypes exactly those the engine always
 served) and ``LoopedRmsRope`` (RMSNorm before AND after each sub-layer,
@@ -129,6 +129,16 @@ plane, every layer is routed, and because it holds BOTH a state and a
 plane it is what a prefix hit over recurrent state was built for
 (``serving/engine.py``: one snapshot at the end of a shared head); its
 plain reference is ``chipbench/families/delta_moe_reference.py``.
+``SparseLightning`` is the first whose K/V planes are read in BLOCKS a
+query selects: a layer of block-sparse grouped-query attention caches,
+beside K and V, a plane of COMPRESSED keys at another rate than one row a
+position (``attend.block_sparse``, ``kernels/block_sparse_attention.py``:
+dense under ``dense_len``, the selected blocks as a shorter table a K/V
+head from there on), its other layers a recurrence of CONSTANT decay a
+head through ``kernels/ssm.py`` (Lightning Attention-2), the table, every
+residual branch and the head's input scaled by the model's own
+constants; its plain reference is
+``chipbench/families/sparse_lightning_reference.py``.
 
 An architecture whose state is large asks for it IN PLACE::
 
@@ -147,6 +157,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import block_sparse_attention as _block_sparse
 from ..kernels import delta as _delta_rule
 from ..kernels import paged_attention as _paged
 from ..kernels import retention as _retention
@@ -160,7 +171,7 @@ from ..observability.trace import STACK_SCOPE, sublayer
 
 __all__ = ["Architecture", "Gpt2", "LoopedRmsRope", "SambaY", "GatedMoE",
            "LatentMoE", "PowerRetention", "SinkWindowMoE", "MambaMoE",
-           "SparseLatentMoE", "DeltaMoE",
+           "SparseLatentMoE", "DeltaMoE", "SparseLightning",
            "route", "routed_ffn", "yarn_inv_freq", "yarn_mscale",
            "MOE_COUNTS", "EXPERT_FORMS", "STACK_SCOPE"]
 
@@ -203,6 +214,11 @@ class Architecture:
     # layers whose mixer is a gated delta rule advanced in place
     # (``kernels/delta.py``)
     delta_layers = 0
+    # layers whose K/V plane is read in the blocks a query selects, and
+    # layers whose mixer is a recurrence of constant decay
+    # (``SparseLightning``)
+    sparse_layers = 0
+    lightning_layers = 0
 
     def __init__(self, n_layer, n_head, d_model, passes=1, head_dim=None):
         if head_dim is None:
@@ -2612,3 +2628,327 @@ class DeltaMoE(_Routed, Architecture):
             return jnp.matmul(_rms(x, p["norm_f.scale"], self.eps),
                               p["lm_head.w"],
                               preferred_element_type=jnp.float32)
+
+
+class SparseLightning(Architecture):
+    """Pre-normed layers of BLOCK-SPARSE grouped-query attention (``S``)
+    or of a recurrence of constant decay (``L``), each followed by a
+    dense gated-SiLU FFN (the ``minicpm_sala`` layout: InfLLM-V2,
+    arXiv:2509.24663, in one layer of four; Lightning Attention-2,
+    arXiv:2401.04658, in the others;
+    ``chipbench/families/sparse_lightning_reference.py`` writes the
+    equations down and lists what the published configuration has no key
+    for).  ``h0 = embed_scale E[id]``; a layer is ``h += r Mix(RMS(h))``,
+    ``h += r FFN(RMS(h))`` with ``r = residual_scale``; ``logits = W_head
+    (RMS(h) head_scale)``, the head untied.
+
+    **``S``** (``attend.block_sparse``): ``n_head`` query heads of
+    ``head_dim`` over ``kv_heads`` K/V heads, NO rotary, scores at
+    ``head_dim ** -0.5``, the joined heads gated lane by lane, ``ctx *
+    sigmoid(a W_gate)``, before ``W_o``.  A query at a position under
+    ``sparse["dense_len"]`` attends the whole chain; from there on the
+    ``init_blocks + topk + window_blocks`` blocks its K/V head's queries
+    select on the layer's COMPRESSED keys (the mean of every ``2 stride``
+    keys, a row every ``stride`` positions).  A layer holds TWO planes:
+    K and V (``pool_rows`` of its K/V heads a position), and the
+    compressed keys, one array of ``B / stride`` rows a block under the
+    same block ids; the K/V planes come first in ``planes``.
+
+    **``L``** (``kernels/ssm.py`` IN PLACE through ``attend.advance``):
+    ``q, k, v = a W_q, a W_k, a W_v`` (``lin_heads`` heads of
+    ``lin_head_dim``), RMSNorm a head on q and k, rotary on both, then
+    ``S_t = exp(-s_h) S_{t-1} + k_t^T v_t``, ``o_t = (q_t D ** -0.5)
+    S_t``: Mamba-2's state matrix under one scalar decay with ``x = v``,
+    ``B = k``, ``C = q``, a group a head, ``delta`` 1, ``A = -s_h`` the
+    head's slope in THIS layer (``slopes``, a row a layer: constants, not
+    parameters), ``D`` 0 and no convolution; RMSNorm a head of the read,
+    the gate ``sigmoid(a W_gate)`` lane by lane, ``W_o``.  A slot holds
+    ``kernels.ssm.state_shapes`` at one tap: the float32 state ``[H, D,
+    D]`` and tails of no row.
+
+    The stack tallies ``count_names``: the (row, K/V head) pairs that
+    read densely and that selected, the blocks the latter attended and
+    had cached, the compressed rows written.
+
+    Parameter names: ``tok_emb.w [V, d]``, ``norm_f.scale``, ``lm_head.w
+    [d, V]``; per layer ``block{i}_norm1.scale``, ``norm2.scale``,
+    ``ffn_gate.w``, ``ffn_up.w [d, f]``, ``ffn_down.w [f, d]``; an ``S``
+    layer ``att_q.w`` and ``att_gate.w [d, n_head head_dim]``, ``att_k.w``
+    and ``att_v.w [d, kv_heads head_dim]``, ``att_out.w``; an ``L`` layer
+    ``lin_q.w``, ``lin_k.w``, ``lin_v.w``, ``lin_gate.w [d, H D]``,
+    ``lin_out.w [H D, d]``, ``lin_qnorm.scale``, ``lin_knorm.scale``,
+    ``lin_onorm.scale [D]``.
+    """
+
+    name = "sparse_lightning"
+    COUNTS = (("sparse_calls", (("form", "dense"),)),
+              ("sparse_calls", (("form", "sparse"),)),
+              "sparse_blocks_selected", "sparse_blocks_live",
+              "compressed_rows_written")
+    SPARSE_KEYS = ("stride", "block", "topk", "init_blocks", "window_blocks",
+                   "dense_len")
+
+    def __init__(self, mixers, n_head, kv_heads, head_dim, d_model,
+                 lin_heads, lin_head_dim, slopes, sparse, embed_scale=1.0,
+                 residual_scale=1.0, head_scale=1.0, rope_theta=10000.0,
+                 chunk_size=128, eps=1e-6):
+        super().__init__(len(mixers), n_head, d_model, head_dim=head_dim)
+        bad = sorted(set(mixers) - set("SL"))
+        if bad:
+            raise ValueError(f"{self.name}: mixers {bad}; a layer is 'S' "
+                             f"or 'L'")
+        if n_head % kv_heads:
+            raise ValueError(f"{self.name}: kv_heads {kv_heads} must "
+                             f"divide n_head {n_head}")
+        self.mixers = "".join(mixers)
+        self._kv_heads = int(kv_heads)
+        self.lin_heads, self.lin_head_dim = int(lin_heads), int(lin_head_dim)
+        self.sparse = {k: int(sparse[k]) for k in self.SPARSE_KEYS}
+        z = self.sparse
+        if int(sparse.get("kernel", 2 * z["stride"])) != 2 * z["stride"] \
+                or z["block"] % z["stride"]:
+            raise ValueError(
+                f"{self.name}: a compressed key is the mean of 2 strides "
+                f"(kernel {sparse.get('kernel')}, stride {z['stride']}) and "
+                f"a block of {z['block']} holds whole strides")
+        held = _block_sparse.selected_blocks(
+            z["init_blocks"], z["topk"], z["window_blocks"])
+        if z["dense_len"] < held * z["block"]:
+            raise ValueError(
+                f"{self.name}: dense_len {z['dense_len']} must hold the "
+                f"{held} blocks of {z['block']} a query selects")
+        of = lambda kind: {i: n for n, i in enumerate(          # noqa: E731
+            i for i, c in enumerate(self.mixers) if c == kind)}
+        self.plane_of, self.state_of = of("S"), of("L")
+        self.slopes = np.asarray(slopes, np.float32).reshape(
+            len(self.state_of), self.lin_heads)
+        if self.state_of and not (self.slopes > 0).all():
+            raise ValueError(f"{self.name}: a slope is a positive constant "
+                             f"(A_log is its logarithm)")
+        # refuses a geometry the state's layout cannot hold
+        _ssm.heads_per_row(self.lin_heads, self.lin_head_dim, self.lin_heads)
+        self.embed_scale = float(embed_scale)
+        self.residual_scale = float(residual_scale)
+        self.head_scale = float(head_scale)
+        self.rope_theta, self.chunk_size = float(rope_theta), int(chunk_size)
+        self.eps = eps
+
+    @property
+    def kv_heads(self):
+        return self._kv_heads
+
+    @property
+    def count_names(self):
+        # only an ``S`` layer tallies
+        return self.COUNTS if self.plane_of else ()
+
+    @property
+    def sparse_layers(self):
+        return len(self.plane_of)
+
+    @property
+    def lightning_layers(self):
+        return len(self.state_of)
+
+    @property
+    def planes(self):
+        """The ``S`` layers' K/V planes, then their compressed planes."""
+        return (None,) * (2 * self.sparse_layers)
+
+    @property
+    def plane_reads(self):
+        # a token's paged calls walk the K/V planes
+        return ((None, self.sparse_layers),) if self.sparse_layers else ()
+
+    @property
+    def rows_per_entry(self):
+        return self.n_head // self.kv_heads
+
+    def _compressed(self, plane):
+        return plane >= self.sparse_layers
+
+    def plane_block_shapes(self, plane, block_tokens, dtype):
+        if self._compressed(plane):
+            stride = self.sparse["stride"]
+            if block_tokens % stride or self.sparse["block"] % block_tokens:
+                raise ValueError(
+                    f"{self.name}: block_tokens {block_tokens} must hold "
+                    f"whole strides of {stride} and divide a selection "
+                    f"block of {self.sparse['block']}")
+            return ((block_tokens // stride,
+                     self.kv_heads * self.head_dim),)
+        return ((block_tokens, _paged.pool_rows(self.kv_heads, dtype),
+                 self.head_dim),) * 2
+
+    def plane_written_values(self, plane):
+        values = self.kv_heads * self.head_dim
+        return (values,) if self._compressed(plane) else (values,) * 2
+
+    def second_array(self, plane):
+        return None if self._compressed(plane) else plane
+
+    def plane_block_bytes(self, plane, block_tokens, itemsize):
+        values = self.kv_heads * self.head_dim * itemsize
+        if self._compressed(plane):
+            return block_tokens * values // self.sparse["stride"]
+        return 2 * block_tokens * values
+
+    def kv_bytes_per_token(self, itemsize):
+        """K and V of the ``S`` layers and their share of a compressed
+        row (one every ``stride`` positions)."""
+        stride = self.sparse["stride"]
+        return sum(self.plane_block_bytes(i, stride, itemsize)
+                   for i in range(len(self.planes))) // stride
+
+    def state_spec(self, dtype):
+        S, tail = _ssm.state_shapes(self.lin_heads, self.lin_head_dim,
+                                    self.lin_heads, self.lin_head_dim, 1)
+        return (((S, jnp.float32), (tail, jnp.dtype(dtype))),
+                ) * self.lightning_layers
+
+    def gauges(self, params):
+        dtype = params["tok_emb.w"].dtype
+        stored = self.sparse_layers * (
+            2 * _paged.pool_rows(self.kv_heads, dtype) * self.head_dim
+            + self.kv_heads * self.head_dim // self.sparse["stride"])
+        z = self.sparse
+        return {
+            "sparse_layers": (self.sparse_layers, "layers whose K/V plane "
+                              "is read in the blocks a query selects past "
+                              "dense_len (a compressed-key plane beside it)"),
+            "lightning_layers": (self.lightning_layers, "layers whose mixer "
+                                 "is a recurrence of constant decay (a "
+                                 "state a slot, advanced in place; no K/V "
+                                 "plane)"),
+            "sparse_blocks_per_query": (
+                _block_sparse.selected_blocks(
+                    z["init_blocks"], z["topk"], z["window_blocks"]),
+                "blocks of sparse block positions a (query row, K/V head) "
+                "attends past dense_len: init + topk + window"),
+            "lightning_state_bytes_per_slot": (
+                self.state_bytes_per_slot(dtype), "bytes one slot holds "
+                "beside the pool: the float32 state of every lightning "
+                "layer (what ONE snapshot holds)"),
+            "kv_stored_bytes_per_token": (
+                stored * dtype.itemsize, "bytes a cached position STORES "
+                "across its planes: kernels.paged_attention.pool_rows of "
+                "K/V heads a K/V plane and its share of a compressed row "
+                "(kv_bytes_per_token is what the model caches of it)"),
+        }
+
+    def check_params(self, params, max_len):
+        need = ["tok_emb.w", "norm_f.scale", "lm_head.w"]
+        every = ("norm1.scale", "norm2.scale", "ffn_gate.w", "ffn_up.w",
+                 "ffn_down.w")
+        for layers, names in (
+                (self.plane_of, ("att_q.w", "att_k.w", "att_v.w",
+                                 "att_gate.w", "att_out.w")),
+                (self.state_of, ("lin_q.w", "lin_k.w", "lin_v.w",
+                                 "lin_gate.w", "lin_out.w",
+                                 "lin_qnorm.scale", "lin_knorm.scale",
+                                 "lin_onorm.scale"))):
+            if layers:
+                last = max(layers)
+                need += [f"block{last}_{n}" for n in every + names]
+        missing = [k for k in need if k not in params]
+        if missing:
+            raise ValueError(f"{self.name}: parameters lack "
+                             f"{', '.join(missing)}")
+        if self.state_of:
+            last = max(self.state_of)
+            want = self.lin_heads * self.lin_head_dim
+            got = np.shape(params[f"block{last}_lin_k.w"])[1]
+            if got != want:
+                raise ValueError(
+                    f"{self.name}: layer {last} projects keys to {got} "
+                    f"lanes; {self.lin_heads} heads of {self.lin_head_dim} "
+                    f"is {want}")
+
+    def embed(self, p, toks, pos):
+        with sublayer("embed"):
+            rows = p["tok_emb.w"][toks]
+            return (rows.astype(jnp.float32) * self.embed_scale).astype(
+                rows.dtype)
+
+    def _attention(self, w, a, planes, attend, plane):
+        f32 = jnp.float32
+        lead = a.shape[:-1]
+        kv = (*lead, self.kv_heads, self.head_dim)
+        with sublayer("attn.proj"):
+            q = self.heads(a @ w("att_q.w"))
+            k = (a @ w("att_k.w")).reshape(kv)
+            v = (a @ w("att_v.w")).reshape(kv)
+            gate = jax.nn.sigmoid((a @ w("att_gate.w")).astype(f32))
+        with sublayer("attn.core"):
+            ctx, planes, counts = attend.block_sparse(
+                planes, plane, self.sparse_layers + plane, q, k, v,
+                group=self.rows_per_entry, scale=self.head_dim ** -0.5,
+                **self.sparse)
+            attend.tally(counts)
+            o = (ctx.reshape(*lead, -1).astype(f32) * gate).astype(a.dtype)
+        with sublayer("attn.proj"):
+            return o @ w("att_out.w"), planes
+
+    def _lightning(self, w, a, rope, planes, attend, n_state):
+        f32 = jnp.float32
+        H, D = self.lin_heads, self.lin_head_dim
+        lead = a.shape[:-1]
+        with sublayer("mixer"):
+            heads = lambda name: (a @ w(name)).reshape(*lead, H, D)  # noqa
+            q = _rope(_rms(heads("lin_q.w"), w("lin_qnorm.scale"), self.eps),
+                      *rope)
+            k = _rope(_rms(heads("lin_k.w"), w("lin_knorm.scale"), self.eps),
+                      *rope)
+            q = (q.astype(f32) * D ** -0.5).astype(a.dtype)
+            # x = v | B = k | C = q, a group a head; delta = softplus(0 +
+            # log(e - 1)) = 1, A = -slope
+            xbc = jnp.concatenate([a @ w("lin_v.w"), k.reshape(*lead, H * D),
+                                   q.reshape(*lead, H * D)], axis=-1)
+            one = jnp.full((H,), np.log(np.e - 1.0), f32)
+            o, planes = attend.advance(
+                planes, n_state, _ssm, xbc, jnp.zeros((*lead, H), f32),
+                conv_w=None, conv_b=None, dt_bias=one,
+                A_log=jnp.asarray(np.log(self.slopes[n_state])),
+                D=jnp.zeros((H,), f32), heads=H, groups=H,
+                chunk_size=self.chunk_size)
+            # the read is normed a head, then gated lane by lane
+            o = o.reshape(*lead, H, D)
+            o = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), axis=-1, keepdims=True) + self.eps)
+            o = o * w("lin_onorm.scale").astype(f32)
+            gate = jax.nn.sigmoid((a @ w("lin_gate.w")).astype(f32))
+            o = (o.reshape(*lead, H * D) * gate).astype(a.dtype)
+            return o @ w("lin_out.w"), planes
+
+    def _scaled(self, x, branch):
+        """``x + residual_scale * branch``, the product in float32."""
+        return x + (branch.astype(jnp.float32) * self.residual_scale
+                    ).astype(x.dtype)
+
+    def stack(self, p, x, pos, planes, attend):
+        if self.state_of:
+            with sublayer("mixer"):
+                rope = _rope_angles(pos, self.lin_head_dim, self.rope_theta)
+        for i, kind in enumerate(self.mixers):
+            w = lambda nm: p[f"block{i}_{nm}"]
+            with sublayer("norm"):
+                a = _rms(x, w("norm1.scale"), self.eps)
+            if kind == "S":
+                mix, planes = self._attention(w, a, planes, attend,
+                                              self.plane_of[i])
+            else:
+                mix, planes = self._lightning(w, a, rope, planes, attend,
+                                              self.state_of[i])
+            x = self._scaled(x, mix)
+            with sublayer("norm"):
+                m = _rms(x, w("norm2.scale"), self.eps)
+            with sublayer("ffn"):
+                x = self._scaled(x, _gated_silu(
+                    m, w("ffn_gate.w"), w("ffn_up.w"), w("ffn_down.w")))
+        return x, planes
+
+    def head(self, p, x):
+        with sublayer("head"):
+            return jnp.matmul(
+                _rms(x, p["norm_f.scale"], self.eps, gain=self.head_scale),
+                p["lm_head.w"], preferred_element_type=jnp.float32)

@@ -87,6 +87,7 @@ first-token logits against the token steps in both dtypes).
 import jax
 import jax.numpy as jnp
 
+from ..kernels import block_sparse_attention as _blocks_sparse
 from ..kernels import paged_attention as _paged
 from ..kernels import sparse_attention as _sparse
 from ..observability.trace import STACK_SCOPE, sublayer
@@ -315,6 +316,83 @@ class _Cache:
                                     lead(q_idx), lead(w_idx), **how)
         return (ctx[:, 0] if self.step else ctx), self._written(
             planes, plane, pk, j, pi)
+
+    def block_sparse(self, planes, plane, plane_c, qh, kh, vh, *, dense_len,
+                     stride, block, topk, init_blocks, window_blocks,
+                     group, scale):
+        """A K/V plane whose queries SELECT blocks past ``dense_len``
+        (``kernels/block_sparse_attention.py``): writes K and V, then the
+        compressed keys that the call's rows complete into ``plane_c``
+        (one array under the same block ids, a row where its window
+        ends: the mean of the last ``2 stride`` keys as the pool holds
+        them, the trash block for a row that completes none), then
+        attends: a row at a position under ``dense_len`` the whole chain
+        (``paged_attention.attend``), a row from there on the blocks it
+        selects, each call made only where such a row is live.  A table
+        that cannot reach ``dense_len`` traces the dense call alone.
+        Returns ``(ctx, planes', counts)``, ``counts`` int32 ``[5]``:
+        (row, K/V head) pairs that read densely and that selected, the
+        blocks those selected and the blocks they had cached, the
+        compressed rows written."""
+        pk, pv, j, tbl, b = self._plane(planes, plane, 0)
+        pc = planes[0][plane_c]
+        B, hk = pk.shape[1], kh.shape[-2]
+        if block % B or B % stride or pc.shape[1] * stride != B:
+            raise ValueError(
+                f"block_sparse: pool blocks of {B} positions must divide a "
+                f"selection block of {block} and hold whole strides of "
+                f"{stride} ({pc.shape[1]} compressed rows a block)")
+        pos = self.pos[:, None] if self.step else self.pos
+        with sublayer("cache"):
+            pk = _paged.write(pk, b, self.off, kh)
+            pv = _paged.write(pv, b, self.off, vh)
+            # the compressed rows whose window ends on one of the call's
+            # real rows: candidates first // stride .., at most one a
+            # stride of the window and one more
+            real = self.valid[:, None] if self.step else self.valid
+            first = jnp.maximum(pos[:, :1], 0)
+            cand = first // stride + jnp.arange(
+                1 if self.step else pos.shape[1] // stride + 1)
+            end = stride * (cand + 1) - 1
+            last = jnp.max(jnp.where(real, pos, -1), axis=1, keepdims=True)
+            done = (cand >= 1) & (end >= first) & (end <= last)
+            rows = _blocks_sparse.compressed_rows(pk, tbl, cand, stride, hk)
+            per = B // stride
+            at = jnp.take_along_axis(
+                tbl, jnp.minimum(cand // per, tbl.shape[1] - 1), axis=1)
+            pc = _paged.write(pc, jnp.where(done, at, 0), cand % per, rows)
+        how = dict(group=group, scale=scale)
+        q4 = qh[:, None] if self.step else qh
+        if tbl.shape[1] * B <= dense_len:
+            ctx = _paged.attend(q4, pk, pv, tbl, self.pos4, **how)
+            dense, sparse = self.pos4 >= 0, jnp.zeros_like(self.pos4, bool)
+        else:
+            dense = (self.pos4 >= 0) & (self.pos4 < dense_len)
+            sparse = self.pos4 >= dense_len
+            ctx = jax.lax.cond(
+                jnp.any(dense),
+                lambda q, pk, pv, tbl, at: _paged.attend(
+                    q, pk, pv, tbl, at, **how),
+                lambda q, *_: jnp.zeros_like(q),
+                q4, pk, pv, tbl, jnp.where(dense, self.pos4, -1))
+            # (a backend's reading of a row at -1 is its own: the rows
+            # are taken from the call that was made for them)
+            ctx = jnp.where(sparse[..., None, None], _blocks_sparse.attend(
+                q4, pk, pv, pc, tbl, jnp.where(sparse, self.pos4, -1),
+                stride=stride, block=block, topk=topk,
+                init_blocks=init_blocks, window_blocks=window_blocks, **how),
+                ctx)
+        n = lambda m: jnp.sum(m, dtype=jnp.int32)              # noqa: E731
+        counts = jnp.stack([
+            hk * n(dense), hk * n(sparse),
+            hk * n(sparse) * _blocks_sparse.selected_blocks(
+                init_blocks, topk, window_blocks),
+            hk * n(jnp.where(sparse, self.pos4 // block + 1, 0)),
+            n(done)])
+        planes = self._written(planes, plane, pk, j, pv)
+        planes = (planes[0][:plane_c] + (pc,) + planes[0][plane_c + 1:],
+                  ) + planes[1:]
+        return (ctx[:, 0] if self.step else ctx), planes, counts
 
     def advance(self, planes, i, kernel, *rows, **how):
         arrays = planes[2][i]

@@ -259,6 +259,16 @@ class ServingEngine:
     cache_blocks  prefix-cache capacity budget: blocks the trie may
              keep alive beyond live requests (LRU-evicted under
              pressure).  Default ``2 * ceil(max_len / block_tokens)``.
+    pool_blocks  blocks the pool holds beside the trash block, where the
+             deployment states them.  Default (``None``): every slot's
+             worst-case chain and the cache budget, so that admission
+             can always allocate.  A deployment whose slots share long
+             cached documents and own short tails states less (32 slots
+             of 132,352 positions would reserve 66,176 blocks for chains
+             that are 2,048 shared blocks and 20 of their own): a request
+             whose chain finds no room once the trie has evicted what it
+             may waits for a slot to finish (``PoolExhausted``: it keeps
+             its place in the queue).  At least one whole chain.
     prefix_reuse  False disables the trie (every request pays full
              prefill — the PR-2 spelling; bit-exactness is gated in
              BOTH modes).
@@ -303,7 +313,7 @@ class ServingEngine:
                  registry=None, ttft_slo_s=None, e2e_slo_s=None,
                  block_tokens=16, cache_blocks=None, prefix_reuse=True,
                  scheduler="slo", draft_params=None, draft_n_layer=None,
-                 draft_n_head=None, spec_k=4, arch=None):
+                 draft_n_head=None, spec_k=4, arch=None, pool_blocks=None):
         import jax
         import jax.numpy as jnp
 
@@ -401,6 +411,16 @@ class ServingEngine:
                       + self.cache_blocks)
         if spec_on:
             num_blocks += self.max_slots * self.blocks_per_slot
+        if pool_blocks is not None:
+            if spec_on or not (self.blocks_per_slot <= pool_blocks
+                               < num_blocks - 1):
+                raise ValueError(
+                    f"pool_blocks {pool_blocks}: a stated pool holds at "
+                    f"least one whole chain ({self.blocks_per_slot} blocks) "
+                    f"and fewer than the {num_blocks - 1} that every slot's "
+                    f"worst case and the cache budget reserve; a draft's "
+                    f"scratch chains are not stated")
+            num_blocks = 1 + int(pool_blocks)
         # TWO KINDS OF CHAIN.  A plane attended under a lower bound
         # needs, of a slot's positions, the last ``window`` only.  Where
         # the architecture says its window planes may hold just that
@@ -698,7 +718,11 @@ class ServingEngine:
             dict(ssm_layers=arch.ssm_layers)
             if arch.ssm_layers else
             dict(delta_layers=arch.delta_layers)
-            if arch.delta_layers else {})
+            if arch.delta_layers else
+            dict(sparse_layers=arch.sparse_layers,
+                 lightning_layers=arch.lightning_layers,
+                 sparse_topk=arch.sparse["topk"])
+            if arch.sparse_layers or arch.lightning_layers else {})
 
     @property
     def _tracer(self):
@@ -864,6 +888,16 @@ class ServingEngine:
                      "wrote in place: live slots x delta layers x the "
                      "chunk's steps").inc(
                          len(contexts) * self.arch.delta_layers
+                         * self.decode_chunk)
+        if self.arch.lightning_layers:
+            self._reg.counter(
+                "serving.lightning_calls", fresh="0", phase="decode",
+                help="calls of the constant-decay recurrence a slot: live "
+                     "slots x lightning layers x a decode chunk's steps "
+                     "(phase=decode), a prefill piece x lightning layers "
+                     "(phase=prefill; fresh=1 started a prompt and never "
+                     "read the slot's state)").inc(
+                         len(contexts) * self.arch.lightning_layers
                          * self.decode_chunk)
         if not self.arch.planes:
             return                  # no table entry, no K/V byte to count
@@ -1062,13 +1096,18 @@ class ServingEngine:
         counters ``serving.<name>{phase}``."""
         total = np.sum([np.asarray(c) for c in counts], axis=0)
         for name, value in zip(self.arch.count_names, total):
+            # a name may carry labels: (name, ((label, value), ...))
+            name, labels = name if isinstance(name, tuple) else (name, ())
             self._reg.counter(
-                "serving." + name, phase=phase,
+                "serving." + name, phase=phase, **dict(labels),
                 help="what the architecture's stack tallied a step, "
                      "summed over steps (arch.count_names; arch.GatedMoE: "
                      "live rows, row-expert pairs on a held expert, held "
                      "experts with a live row, held experts visited, each "
-                     "x routed layers)").inc(int(value))
+                     "x routed layers; arch.SparseLightning: (row, K/V "
+                     "head) pairs that read densely or selected, the "
+                     "blocks selected and cached, compressed rows "
+                     "written)").inc(int(value))
 
     # -- request intake ---------------------------------------------------
     def submit(self, prompt, max_new_tokens=16, eos_id=None,
@@ -2191,6 +2230,15 @@ class ServingEngine:
                          "the piece's width (ONE call a layer a piece, "
                          "padding included: a call computes its width), a "
                          "layer").inc(w)
+            if self.arch.lightning_layers:
+                self._reg.counter(
+                    "serving.lightning_calls", fresh=str(int(at == 0)),
+                    phase="prefill").inc(self.arch.lightning_layers)
+                self._reg.counter(
+                    "serving.lightning_piece_rows", width=w,
+                    help="rows of the prefill pieces' constant-decay "
+                         "calls, by the piece's width (ONE call a layer a "
+                         "piece, padding included), a layer").inc(w)
             if self.arch.retention_layers:
                 for i, rows in enumerate(_retention.chunk_rows(w)):
                     self._reg.counter(

@@ -15,8 +15,8 @@ planes stated their own shape and chains came in two kinds; PR 51 added
 ``SinkWindowMoE``'s, taken on its parent, when ``routed_ffn`` took the
 expert's form and ``_Cache.retain`` became ``advance``; PR 57 added
 ``MambaMoE``'s and ``SparseLatentMoE``'s, taken on its parent, and
-``DeltaMoE``'s own, taken on its tree: a family the root does not have
-is left out)."""
+``DeltaMoE``'s own, taken on its tree; PR 61 added ``SparseLightning``'s
+own, taken on its tree: a family the root does not have is left out)."""
 
 import hashlib
 import importlib.util
@@ -31,7 +31,8 @@ FAMILIES = {"gpt2": "test_rehearsal", "ouro": "test_ouro_family",
             "sink_window_moe": "test_sink_window_moe_family",
             "ssm_moe": "test_ssm_moe_family",
             "sparse_latent_moe": "test_sparse_latent_moe_family",
-            "delta_moe": "test_delta_moe_family"}
+            "delta_moe": "test_delta_moe_family",
+            "sparse_lightning": "test_sparse_lightning_family"}
 GEOMETRY = {"max_len": 64, "max_slots": 2, "block_tokens": 8,
             "cache_blocks": 0, "prefix_reuse": False}
 ENTRIES = ("decode_chunk_4", "prefill_8", "prefill_32")

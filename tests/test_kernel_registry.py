@@ -97,16 +97,18 @@ def test_global_env_fallback_counted_once_per_resolution(monkeypatch):
     assert count() == c0 + 2
 
 
-def test_two_backends_ten_op_classes_and_any_platform_is_served():
+def test_two_backends_twelve_op_classes_and_any_platform_is_served():
     """What the registry holds since the GPU lowerings and the gather op
     class went and the grouped matrix product, retention, a wide window's
     chain walk, Mamba-2's recurrence, the gated delta rule, a learned
-    indexer's scores and the attention of the rows it selects came: two
-    backends, ten op classes (the last two in the oracle's backend only),
-    an auto order
+    indexer's scores and the attention of the rows it selects, a K/V
+    plane's block scores and the attention of the blocks they select came:
+    two backends, twelve op classes (the last four in the oracle's backend
+    only), an auto order
     for the TPU and the CPU; a platform with no order of its own is
     served by the oracle for every op class."""
-    one_backend = {"index_scores", "sparse_latent_attention"}
+    one_backend = {"index_scores", "sparse_latent_attention",
+                   "block_scores", "block_sparse_attention"}
     assert kernels.BACKENDS == ("pallas_tpu", "xla_ref")
     assert sorted(kernels.registered_op_classes()) == sorted([
         "chain_attention", "delta_rule", "flash_attention", "fused_ce",

@@ -446,3 +446,72 @@ def test_engine_prefix_hit_reduces_prefill_tokens():
         return eng.stats()["serving.prefill_tokens"]
 
     assert served_prefill_tokens(True) < served_prefill_tokens(False)
+
+
+# -- engine-level: a pool the deployment states ------------------------------
+
+def test_a_stated_pool_makes_an_admission_wait_at_the_front_and_finish():
+    """``pool_blocks`` under what three slots' chains ask for: the
+    request whose chain finds no room is put back at the FRONT of the
+    queue (``PoolExhausted``), is admitted when a slot's blocks come
+    back, and every request ends with the tokens the engine's own rule
+    (every slot's worst case beside the cache) gives."""
+    from paddle_tpu.serving.kvcache import PoolExhausted
+
+    params = _make_params()
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(1, VOCAB, (14,)).astype(np.int32)
+               for _ in range(5)]
+    kw = dict(max_len=T, max_slots=3, decode_chunk=4, min_bucket=4,
+              block_tokens=4, cache_blocks=4)
+    roomy = ServingEngine(params, NL, NH, DM, **kw)
+    want = roomy.generate_many(prompts, max_new_tokens=14)
+    # a chain of 28 positions is 7 blocks: 12 hold one and most of a second
+    eng = ServingEngine(params, NL, NH, DM, pool_blocks=12, **kw)
+    assert eng.kv_pool.num_blocks == 13 < roomy.kv_pool.num_blocks == 29
+    waited, admitted = [], []
+    prefill_into = eng._prefill_into
+
+    def spy(slot, req):
+        try:
+            out = prefill_into(slot, req)
+        except PoolExhausted:
+            waited.append((req, eng.active_slots))
+            raise
+        admitted.append(req)
+        return out
+
+    eng._prefill_into = spy
+    got = eng.generate_many(prompts, max_new_tokens=14)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    # it waited while another slot was live, never with none (that is
+    # fatal), and kept its place: admissions are in the order submitted
+    assert waited and all(live >= 1 for _, live in waited)
+    assert len(admitted) == len(prompts)
+    for req, prompt in zip(admitted, prompts):
+        np.testing.assert_array_equal(req.prompt, prompt)
+    assert eng.kv_pool.blocks_in_use == len(eng.prefix_trie) <= 12
+
+
+@pytest.mark.parametrize("how, more", [
+    ("under one whole chain", dict(pool_blocks=7)),
+    ("no less than the engine's own rule", dict(pool_blocks=28)),
+    ("beside a draft's scratch chains", dict(pool_blocks=12, draft=True)),
+])
+def test_a_stated_pool_is_refused(how, more):
+    """8 blocks a chain, 3 slots and a cache budget of 4: the rule's pool
+    is 28 blocks beside the trash block."""
+    from paddle_tpu.serving import speculative
+
+    params = _make_params()
+    kw = dict(max_len=T, max_slots=3, decode_chunk=4, min_bucket=4,
+              block_tokens=4, cache_blocks=4,
+              pool_blocks=more["pool_blocks"])
+    if more.get("draft"):
+        assert speculative.spec_enabled()
+        kw.update(draft_params=params, draft_n_layer=NL, draft_n_head=NH)
+    with pytest.raises(ValueError, match="a stated pool holds"):
+        ServingEngine(params, NL, NH, DM, **kw)
+    ServingEngine(params, NL, NH, DM, **dict(
+        kw, pool_blocks=8, draft_params=None))

@@ -78,6 +78,12 @@ PARENT = {
         "decode_chunk_4": "646ca35220fb9a7c",
         "prefill_8": "6d8aaf3d82c9807d",
         "prefill_32": "cf6b7f589af080ca"
+    },
+    # SparseLightning's own, on PR 61's tree: what a later PR is held to
+    "sparse_lightning": {
+        "decode_chunk_4": "d4869b1b24cfc387",
+        "prefill_8": "0c21f95ca63026a1",
+        "prefill_32": "6d10dcd904c8bda3"
     }
 }
 # PR 52 gave the loop of two rows or more G table entries an iteration
