@@ -67,6 +67,20 @@ dense spelling.  ``--only rungs`` times the same at ``think_decode``'s and
 positions), the rule at zero so that every one walks: what set
 ``kernels.paged_attention.CHAIN_SCORE_BYTES``.
 
+``--only slab`` (or any ``sala_*`` name) times the walk of
+``sala9b.doc_qa_128k``'s HEAD-MAJOR planes (``kernels/block_sparse_attention
+.walk``: one table a (row, K/V head) of 97 selected blocks, the 16 query
+rows of the K/V head against its own ``[64, 128]`` slabs of K and of V,
+the Mosaic kernel ``paged_slab_attention``): a decode step of 16 live
+slots (32 tables) and of 32, a 512-row prefill piece (1,024 tables, eight
+calls), us a call, us a table ENTRY, the share of the HBM peak the bytes
+fetched make (32 KB an entry), the error against the block scan; with
+``--entries 4,8,16,32`` the decode rows once at each count of slabs an
+iteration.  ``write_sala_*`` (under ``--only writes``) times a
+position's K write through the slab view
+(``block_sparse_attention.write``) beside the scatter at ``(blk, :, off,
+:)`` of the plane as it lies.
+
 Refuses unless JAX finds a TPU: a number from a CPU run is no device
 metric.
 """
@@ -144,6 +158,25 @@ WRITES = {
 WRITES.update({name + "_partial": dict(g, partial=True)
                for name, g in list(WRITES.items())
                if g["heads"] < g["rows"]})
+# sala9b.doc_qa_128k's head-major planes ([blocks, 2, 64, 128]): a
+# position's 2 heads through the slab view, and `_strided` the scatter at
+# (blk, :, off, :) beside it; 4 writes a step (K and V of 2 planes)
+WRITES.update({
+    f"write_sala_{name}{how}": dict(blocks=10241, heads=2, index=index,
+                                    a_step=4, head_major=how or "_slabs")
+    for name, index in (("step", (32,)), ("piece", (1, 512)))
+    for how in ("", "_strided")})
+
+# the walk of sala9b.doc_qa_128k's head-major planes: `tables` (row, K/V
+# head) tables of 97 selected blocks each (init 1 + topk 64 + window 32),
+# 16 query rows a table, slabs of 64 x 128 lanes, K and V apart
+SLAB = {
+    "sala_slab_decode_16_live": dict(S=16, W=1),
+    "sala_slab_decode_32_live": dict(S=32, W=1),
+    "sala_slab_piece_512": dict(S=1, W=512),
+}
+SLAB_PLANE = dict(blocks=10241, hk=2, B=64, D=128, group=16, selected=97,
+                  NB=2068)
 
 
 # the latent plane of dsv2lite.doc_qa_8k: 10 of 12 slots live at
@@ -715,6 +748,68 @@ def measure_piece(name, calls, seed):
                 np.abs(a - b).max() / np.abs(b).max())}
 
 
+def measure_slab(name, calls, peak, seed):
+    """The walk of a head-major plane at ``sala9b.doc_qa_128k``'s sizes:
+    every row past the dense length, its K/V heads' selections drawn apart
+    (ascending, the last two its own block and the one before), through
+    ``block_sparse_attention.walk``."""
+    import jax
+    import jax.numpy as jnp
+
+    import numpy as np
+
+    from paddle_tpu.kernels import block_sparse_attention as bsa
+
+    g, z = SLAB[name], SLAB_PLANE
+    S, W, hk, B, D, n = g["S"], g["W"], z["hk"], z["B"], z["D"], z["selected"]
+    rng = np.random.default_rng(seed)
+    shape = (z["blocks"], hk, B, D)
+    pool_k, pool_v = (jnp.asarray(rng.standard_normal(shape, np.float32)
+                                  * 0.5, jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((S, W, hk * z["group"], D),
+                                        np.float32) * 0.5, jnp.bfloat16)
+    # a chain of distinct blocks a slot; a row selects among those under
+    # its own block
+    own = 2050 + (np.arange(W) // B if W > 1 else np.zeros(W, np.int64))
+    chains = np.stack([rng.permutation(np.arange(1, z["blocks"]))[:z["NB"]]
+                       for _ in range(S)])
+    sel = np.stack([np.sort(np.concatenate([
+        rng.choice(own[w] - 1, n - 2, replace=False), [own[w] - 1, own[w]]]))
+        for _ in range(S) for w in range(W) for _ in range(hk)])
+    sel = sel.reshape(S, W, hk, n)
+    ids = jnp.asarray(np.take_along_axis(chains[:, None, None, :], sel, -1),
+                      jnp.int32)
+    at = jnp.asarray(np.broadcast_to(
+        (n - 1) * B + (np.arange(W) % B if W > 1 else 37), (S, W)), jnp.int32)
+    how = dict(group=z["group"], scale=D ** -0.5, out_dtype=jnp.float32)
+    fn = jax.jit(lambda *a: bsa.walk(*a, **how))
+    args = (q, pool_k, pool_v, ids, at)
+    tables = S * W * hk
+    if tables <= bsa.TABLE_ROWS:
+        us = _timed(fn, args, calls)
+    else:           # several Mosaic calls under one map: the device's busy
+        us, _ = _busy_us(lambda last, *a: fn(*a), None, args, calls)
+    got = np.asarray(fn(*args))
+    keep = bsa._paged.attend
+    bsa._paged.attend = lambda *a, **k: bsa._paged.paged_attention_ref(
+        *a, **k)
+    try:
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(lambda *a: bsa.walk(*a, **how))(*args))
+    finally:
+        bsa._paged.attend = keep
+    entries = tables * n
+    fetched = entries * 2 * B * D * 2
+    return {"geometry": name, "S": S, "W": W, "tables": tables,
+            "entries_a_table": n, "query_rows_a_table": z["group"],
+            "slab": [B, D], "us_a_call": us, "us_a_table": us / tables,
+            "us_an_entry": us / entries, "bytes_fetched": fetched,
+            "hbm_share_pct": 100.0 * fetched / peak["hbm_bytes_per_s"]
+            / (us * 1e-6),
+            "rel_err_vs_xla_ref": float(np.abs(got - want).max()
+                                        / np.abs(want).max())}
+
+
 def _busy_us(fn, pool, args, calls):
     """Device microseconds a call of ``pool = fn(pool, *args)``: the
     busy seconds of a profiler trace over ``calls`` queued calls (a loop
@@ -762,6 +857,8 @@ def measure_write(name, calls, seed):
     off = jnp.asarray(off.reshape(index), jnp.int32)
     rows = jnp.asarray(rng.standard_normal((*index, heads, 128)),
                        jnp.bfloat16)
+    if g.get("head_major"):
+        return _measure_slab_write(name, g, blk, off, rows, calls)
     pool = jnp.zeros((g["blocks"], B, g["rows"], 128), jnp.bfloat16)
     if g.get("partial"):
         spelt = lambda p, b, o, r: p.at[b, o, :heads].set(r)  # noqa: E731
@@ -784,9 +881,38 @@ def measure_write(name, calls, seed):
             "us_a_step": us * g["a_step"], "written_exactly": ok}
 
 
+def _measure_slab_write(name, g, blk, off, rows, calls):
+    """A position's heads into a head-major pool ``[blocks, heads, 64,
+    128]``: through the slab view or at ``(blk, :, off, :)``."""
+    import jax
+    import jax.numpy as jnp
+
+    import numpy as np
+
+    from paddle_tpu.kernels import block_sparse_attention as bsa
+
+    heads, n = g["heads"], int(np.prod(g["index"]))
+    off = off * 2 + (blk % 2)       # `measure_write` drew offsets under 32
+    pool = jnp.zeros((g["blocks"], heads, 64, 128), jnp.bfloat16)
+    spelt = (bsa.write if g["head_major"] == "_slabs" else
+             lambda p, b, o, r: p.at[b, :, o].set(r))  # noqa: E731
+    us, pool = _busy_us(jax.jit(spelt, donate_argnums=0), pool,
+                        (blk, off, rows), calls)
+    ok = bool(jnp.array_equal(pool[blk, :, off], rows)
+              and int(jnp.count_nonzero(pool)) == int(
+                  jnp.count_nonzero(rows)))
+    return {"geometry": name, "blocks": g["blocks"], "heads": heads,
+            "index": list(g["index"]), "rows_written": n,
+            "spelling": g["head_major"].strip("_"), "us_a_write": us,
+            "us_a_row": us / n, "writes_a_step": g["a_step"],
+            "us_a_step": us * g["a_step"], "written_exactly": ok}
+
+
 def _shares_a_fold(name):
     """Whether the geometry runs the Mosaic loop of two rows or more (a
     K/V plane whose pool Mosaic slices, decode or a narrow window)."""
+    if name in SLAB:
+        return True
     g = GEOMETRIES.get(name) or MIXED.get(name)
     return bool(g and g["W"] * g["group"] > 1 and g["W"] < 8
                 and g["rows"] % 8 == 0)
@@ -806,6 +932,11 @@ def _entries(forced):
         pa.entries_per_iteration = lambda *a: forced
 
     def answer(name):
+        if name in SLAB:
+            z = SLAB_PLANE
+            return pa.entries_per_iteration(
+                z["B"], 1, z["D"], z["D"], z["group"], jnp.bfloat16,
+                z["selected"])
         g = GEOMETRIES.get(name) or MIXED[name]
         dk, dv = (256, 128) if name in MIXED else (g["dh"], g["dh"])
         return pa.entries_per_iteration(
@@ -832,6 +963,8 @@ def _measure(name, args, peak):
         return measure_piece(name, args.calls, args.seed)
     if name in RUNGS:
         return measure_rung(name, args.calls, args.seed)
+    if name in SLAB:
+        return measure_slab(name, args.calls, peak, args.seed)
     return measure(name, args.calls, peak, args.seed)
 
 
@@ -866,6 +999,8 @@ def main():
         names = [n for n in names if n != "mixed"] + list(MIXED)
     if "rungs" in names:
         names = [n for n in names if n != "rungs"] + list(RUNGS)
+    if "slab" in names:
+        names = [n for n in names if n != "slab"] + list(SLAB)
     if "latent" in names:
         names = ([n for n in names if n != "latent"] + list(LATENT)
                  + list(SHARED) + list(PIECES))

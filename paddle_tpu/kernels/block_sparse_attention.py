@@ -43,9 +43,22 @@ scope of its own:
   TPU) walks it as it walks any chain; because the selection ends with
   the query's own block, position ``t`` is the last block's ``t % block``
   -th and every other selected block lies whole under it.  No gathered
-  copy of K or V exists.  The plane stores its K/V heads on one block's
-  rows, so the call of K/V head ``j`` carries zeros in the other heads'
-  query rows and keeps head ``j``'s context.
+  copy of K or V exists.
+
+**The plane is stored HEAD-MAJOR**: ``pool_k`` and ``pool_v [blocks, H_kv,
+B, D]``, a block one ``[B, D]`` slab a K/V head and no row beside them
+(``kernels.paged_attention.pool_rows`` is not asked: what the pool stores
+is what the model caches).  ``pool.reshape(blocks * H_kv, B, D)`` is a
+view in which entry ``id * H_kv + j`` is head ``j``'s slab of block
+``id``, so the table of (row, K/V head ``j``) holds ``ids * H_kv + j``,
+its query is that head's ``group`` rows alone, and the walk fetches the
+16 KB of K and of V it reads and nothing of another head
+(:func:`walk`; ``paged_attention.attend``'s slab plane).  A row the
+serving step attends DENSELY (under its dense length) walks its whole
+chain the same way, head by head, with the same program
+(:func:`dense_attention`): one layout serves both sides of the switch.
+A position's K (or V) of all the heads is ONE scatter through the same
+view (:func:`write`).
 
 :func:`attend` is the one call the serving step makes.  It runs the
 three steps only where some row has ``pos >= 0`` (``lax.cond``), and a
@@ -65,8 +78,8 @@ from .sparse_attention import (_in_pieces, _piece_rows, select_positions,
                                slots_run)
 
 __all__ = ["SCORE_BYTES", "TABLE_ROWS", "attend", "block_scores",
-           "block_sparse_attention", "compressed_rows", "select_blocks",
-           "selected_blocks"]
+           "block_sparse_attention", "compressed_rows", "dense_attention",
+           "select_blocks", "selected_blocks", "walk", "write"]
 
 # what one piece of query rows may hold of float32 scores on the
 # compressed rows ``[rows, H, NC]``, and the (row, K/V head) tables one
@@ -80,17 +93,32 @@ def selected_blocks(init_blocks, topk, window_blocks):
     return int(init_blocks) + int(topk) + int(window_blocks)
 
 
-def compressed_rows(pool_k, table, rows, stride, heads):
+def write(pool, blk, off, rows):
+    """``rows [*blk.shape, H_kv, D]`` written into the head-major ``pool
+    [blocks, H_kv, B, D]`` at ``(blk, :, off, :)``: ONE scatter through
+    the slab view, a position's row of head ``j`` at ``(blk * H_kv + j,
+    off)`` (``paged_attention.write``'s plane with no head axis)."""
+    blocks, hk, B, D = pool.shape
+    slab = blk[..., None] * hk + jnp.arange(hk, dtype=blk.dtype)
+    return _paged.write(pool.reshape(blocks * hk, B, D), slab,
+                        off[..., None], rows).reshape(pool.shape)
+
+
+def compressed_rows(pool_k, table, rows, stride):
     """The compressed keys ``rows [S, n]`` (``c``: the mean of the keys at
     ``stride (c - 1) .. stride (c + 1) - 1``) of the chains ``table [S,
-    NB]``, read from ``pool_k [blocks, B, rows, D]`` as it holds them:
-    ``[S, n, heads * D]`` in the pool's dtype, the mean in float32."""
-    B = pool_k.shape[1]
+    NB]``, read from ``pool_k [blocks, H_kv, B, D]`` as it holds them
+    (through the slab view, as the write and the walk reach it: a gather
+    of the plane as it lies has the compiler copy the whole pool into
+    another layout, 2.3 ms a decode step at 10,241 blocks; PERF.md, PR
+    63): ``[S, n, H_kv * D]`` in the pool's dtype, the mean in float32."""
+    blocks, hk, B, D = pool_k.shape
     at = (rows[..., None] - 1) * stride + jnp.arange(2 * stride)   # [S, n, w]
     at = jnp.clip(at, 0, table.shape[1] * B - 1)
     blk = jnp.take_along_axis(table[:, None, :], at // B, axis=-1)
-    keys = pool_k[blk, at % B][..., :heads, :].astype(jnp.float32)
-    mean = jnp.mean(keys, axis=2)                          # [S, n, heads, D]
+    slab = blk[..., None] * hk + jnp.arange(hk, dtype=blk.dtype)
+    keys = pool_k.reshape(blocks * hk, B, D)[slab, (at % B)[..., None]]
+    mean = jnp.mean(keys.astype(jnp.float32), axis=2)      # [S, n, H_kv, D]
     return mean.reshape(*mean.shape[:2], -1).astype(pool_k.dtype)
 
 
@@ -157,16 +185,74 @@ def select_blocks(scores, pos, *, block, topk, init_blocks, window_blocks):
         return jnp.concatenate([lead, picked, local], axis=-1)
 
 
+def walk(q, pool_k, pool_v, ids, at, *, group, scale=None, out_dtype=None):
+    """``q [S, W, H, D]`` through the tables ``ids [S, W, H_kv, n]`` (pool
+    blocks, ascending positions) of the head-major plane ``pool_k``,
+    ``pool_v [blocks, H_kv, B, D]``: K/V head ``j`` of a row attends the
+    keys at ``<= at [S, W]``, counted along ITS table (``-1``: nothing,
+    and zeros come back), its ``group`` query rows against head ``j``'s
+    slabs alone, ``TABLE_ROWS`` tables a call of ``paged_attention.attend``
+    -> ``[S, W, H, D]``."""
+    S, W, H, D = q.shape
+    blocks, hk, B, _ = pool_k.shape
+    if H != hk * group or pool_k.shape != pool_v.shape:
+        raise ValueError(
+            f"block_sparse: {H} query heads in groups of {group} over a "
+            f"head-major plane [blocks, H_kv, B, D]; got {pool_k.shape} "
+            f"and {pool_v.shape}")
+    rows = S * W * hk
+    slabs = (pool_k.reshape(blocks * hk, B, D),
+             pool_v.reshape(blocks * hk, B, D))
+    tbl = (ids.astype(jnp.int32) * hk
+           + jnp.arange(hk, dtype=jnp.int32)[:, None]).reshape(rows, -1)
+    qj = q.reshape(rows, 1, group, D)
+    atj = jnp.repeat(at.reshape(S * W), hk)[:, None]
+
+    def tables(qp, tp, ap):
+        return _paged.attend(qp, *slabs, tp, ap, scale=scale,
+                             out_dtype=out_dtype)
+
+    if rows <= TABLE_ROWS:
+        ctx = tables(qj, tbl, atj)
+    else:
+        pieces = -(-rows // TABLE_ROWS)
+        spare = pieces * TABLE_ROWS - rows
+        cut = lambda a, fill: jnp.pad(                           # noqa: E731
+            a, ((0, spare),) + ((0, 0),) * (a.ndim - 1),
+            constant_values=fill).reshape(pieces, TABLE_ROWS, *a.shape[1:])
+        ctx = jax.lax.map(lambda p: tables(*p), (
+            cut(qj, 0), cut(tbl, 0), cut(atj, -1)))
+        ctx = ctx.reshape(pieces * TABLE_ROWS, 1, group, D)[:rows]
+    # a row that attends nothing reads zeros whatever the walk's backend
+    # makes of a table of trash blocks
+    return jnp.where((at >= 0)[:, :, None, None], ctx.reshape(S, W, H, D), 0)
+
+
+def dense_attention(q, pool_k, pool_v, table, pos, *, group, entries=None,
+                    scale=None, out_dtype=None):
+    """The rows that attend their chain WHOLE (``pos [S, W]`` under the
+    caller's dense length; ``-1``: a row that attends nothing here and
+    reads zeros): the first ``entries`` entries of ``table [S, NB]`` (all
+    of them if None), every K/V head its own slabs, through the walk the
+    selected blocks take (:func:`walk`) -> ``[S, W, H, D]``."""
+    S, W = pos.shape
+    hk = pool_k.shape[1]
+    n = table.shape[1] if entries is None else min(entries, table.shape[1])
+    ids = jnp.broadcast_to(table[:, None, None, :n], (S, W, hk, n))
+    return walk(q, pool_k, pool_v, ids, pos, group=group, scale=scale,
+                out_dtype=out_dtype)
+
+
 def block_sparse_attention_ref(q, pool_k, pool_v, table, pos, sel, *, group,
                                block, scale=None, out_dtype=None):
     """``q [S, W, H, D]`` over the blocks ``sel [S, W, H_kv, n]`` (ascending,
     the last the query's own) of the chains ``table [S, NB]``, masked ``<=
-    pos``: the selected blocks as a table a (row, K/V head), walked by
-    ``paged_attention.attend`` (module docstring) -> ``[S, W, H, D]``."""
+    pos``: the selected blocks as a table a (row, K/V head), walked over
+    that head's slabs of the head-major plane (:func:`walk`; module
+    docstring) -> ``[S, W, H, D]``."""
     S, W, H, D = q.shape
-    hk, B, n = H // group, pool_k.shape[1], sel.shape[-1]
+    hk, B, n = H // group, pool_k.shape[2], sel.shape[-1]
     per = block // B                  # pool blocks a selected block
-    out_dtype = q.dtype if out_dtype is None else out_dtype
     with jax.named_scope("paged_block_sparse_attention"):
         live = pos >= 0
         entries = (jnp.maximum(sel, 0)[..., None] * per
@@ -175,41 +261,9 @@ def block_sparse_attention_ref(q, pool_k, pool_v, table, pos, sel, *, group,
             table.astype(jnp.int32)[:, None, None, :], entries, axis=-1)
         ids = jnp.where((live[:, :, None] & (sel[..., -1] >= 0))[..., None],
                         ids, 0)
-        # the kernel's loop takes a group of entries an iteration: pad
-        # the table to whole groups with the trash block, past ``at``
-        pad = (-ids.shape[-1]) % _paged.MAX_ENTRIES
-        ids = jnp.pad(ids, ((0, 0),) * 3 + ((0, pad),))
         at = jnp.where(live, (n - 1) * block + pos % block, -1)
-        # K/V head j's call: zeros in the other heads' query rows
-        mine = jnp.eye(hk, dtype=q.dtype)[None, None, :, :, None, None]
-        qj = (q.reshape(S, W, 1, hk, group, D) * mine).reshape(
-            S * W * hk, 1, H, D)
-        tbl = ids.reshape(S * W * hk, -1)
-        atj = jnp.repeat(at.reshape(S * W), hk)[:, None]
-
-        def walk(qp, tp, ap):
-            return _paged.attend(qp, pool_k, pool_v, tp, ap, group=group,
-                                 scale=scale, out_dtype=out_dtype)
-
-        rows = S * W * hk
-        if rows <= TABLE_ROWS:
-            ctx = walk(qj, tbl, atj)
-        else:
-            pieces = -(-rows // TABLE_ROWS)
-            spare = pieces * TABLE_ROWS - rows
-            cut = lambda a, fill: jnp.pad(                       # noqa: E731
-                a, ((0, spare),) + ((0, 0),) * (a.ndim - 1),
-                constant_values=fill).reshape(pieces, TABLE_ROWS,
-                                              *a.shape[1:])
-            ctx = jax.lax.map(lambda p: walk(*p), (
-                cut(qj, 0), cut(tbl, 0), cut(atj, -1)))
-            ctx = ctx.reshape(pieces * TABLE_ROWS, 1, H, D)[:rows]
-        ctx = ctx.reshape(S, W, hk, hk, group, D)
-        own = jnp.arange(hk)
-        # a row that attends nothing reads zeros whatever the walk's
-        # backend makes of a table of trash blocks
-        return jnp.where(live[:, :, None, None],
-                         ctx[:, :, own, own].reshape(S, W, H, D), 0)
+        return walk(q, pool_k, pool_v, ids, at, group=group, scale=scale,
+                    out_dtype=out_dtype)
 
 
 def block_sparse_attention(q, pool_k, pool_v, table, pos, sel, **how):

@@ -113,6 +113,23 @@ the table, data) and the Mosaic kernel fetches such a run ONCE for the
 slots that share it; every other spelling ignores it, and the result is
 the same.
 
+**A slab plane** (``pool_k [num_blocks, B, dk]`` WITH ``pool_v
+[num_blocks, B, dv]``; ``kernels/block_sparse_attention.py``) is the
+latent convention with the values in an array of their own: no head axis,
+every query head of ``q [S, W, h, dk]`` reads the one slab a table entry
+names, ``ctx [S, W, h, dv]``.  It is how a plane stored HEAD-MAJOR
+(``[blocks, hk, B, dh]``: ``serving.arch.SparseLightning``'s K/V planes)
+is walked one K/V head at a time: ``pool.reshape(blocks * hk, B, dh)`` is
+a view in which entry ``table[i] * hk + j`` is head ``j``'s ``[B, dh]``
+slab, and the ``group`` query heads of that K/V head are the call's
+``h``.  No row is added (``pool_rows`` does not apply), no lane belongs
+to another head, a group is refused.  ``attend`` always STREAMS such a
+call (its caller makes one table a (row, K/V head), ``W = 1``): the
+``xla_ref`` scan or, on a TPU, the latent kernel's loop with a second
+buffer for V (``latent_attention_pallas(.., pool_v=)``, HLO name
+``paged_slab_attention``) at the table entries an iteration that
+``entries_per_iteration`` gives a slab.
+
 Numerics conventions match the flash kernels (f32 scores via
 ``preferred_element_type``, ``NEG_INF`` masking, f32 ``(m, l, acc)``
 online-softmax state, one normalization at the end with the
@@ -227,6 +244,11 @@ DEPTH = 4
 MAX_ENTRIES = 8
 GROUP_SHARE = 32
 LOOP_VMEM_BYTES = 12 << 20
+# What the lightest block of ``entries_per_iteration``'s table holds (K
+# and V of 32 tokens x 8 rows x 128 lanes in bfloat16).  A SLAB, one
+# head's rows alone, holds a fraction of it, and the rule counts slabs in
+# entries of this size.
+ENTRY_BYTES = 128 << 10
 
 # Table entries of a LATENT plane the Mosaic loop takes in one iteration
 # (``latent_attention_pallas``).  An iteration costs its chain's latency
@@ -288,6 +310,10 @@ def attend(q, pool_k, pool_v, table, pos, group=1, window=None, scale=None,
                     + ((0, pool_k.shape[-1] - q.shape[-1]),))
     if sink is not None:
         how["sink"] = sink
+    if pool_v is not None and pool_k.ndim == 3:
+        # a slab plane (module docstring): one table a (row, K/V head)
+        return resolve("paged_attention").impl.call(
+            q, pool_k, pool_v, table, pos, **how)
     if pool_v is not None and walks_chain(
             q.shape[1], _folded_rows(q, pool_k, group),
             table.shape[1] * pool_k.shape[1]):
@@ -403,9 +429,27 @@ def entries_per_iteration(B, h, dh, dv, N, dtype, live):
     costs what its chain does (think_decode's 262 KB and 512 score lanes
     a block) or a slot has a handful of live blocks, a group only adds
     its tail; the rule gives 8 | 8 | 1 | 2 | 1 | 2 | 2, the best of each
-    row but the second and the last (G = 4 there is 5% and 6% faster)."""
-    G = MAX_ENTRIES
-    while G > 1 and (G * GROUP_SHARE > live or _loop_vmem_bytes(
+    row but the second and the last (G = 4 there is 5% and 6% faster).
+
+    A SLAB (``h`` ``None``: one K/V head's ``[B, dh]`` of K and ``[B, dv]``
+    of V with NO head axis, the walk of a head-major plane) is a fetch of a
+    fraction of those blocks' bytes for the same chain of latencies: 32
+    KB at 64 tokens of 128 lanes, where the lightest row of the table
+    holds 128 KB (``ENTRY_BYTES``).  The rule counts slabs in entries of
+    that size, in the cap and in the share of the chain alike: ``light =
+    ENTRY_BYTES // slab bytes`` (a power of two, 1 at least) slabs weigh
+    as one entry, so a table of 97 selected blocks takes 8 slabs an
+    iteration (2 by the count of entries alone: 0.3 us of latency a slab
+    where its bytes are 0.04) and a chain of 128 takes 16.  Every plane
+    with a head axis has ``light = 1``: the table above is what it was."""
+    light = 1
+    if h is None:
+        h = 1
+        light = max(1, ENTRY_BYTES // (
+            B * (dh + dv) * jnp.dtype(dtype).itemsize))
+        light = 1 << (light.bit_length() - 1)
+    G = MAX_ENTRIES * light
+    while G > 1 and (G * GROUP_SHARE > live * light or _loop_vmem_bytes(
             G, B, h, dh, dv, N, dtype) > LOOP_VMEM_BYTES):
         G //= 2
     return G
@@ -433,10 +477,12 @@ def loop_iterations(entries, rows, block_shapes, dtype, NB, window=None):
     ``entries_per_iteration`` for two rows or more of a K/V plane whose
     pool Mosaic slices; ``entries`` for one row and for the grid form
     (an entry an iteration, a step); a latent plane's groups of
-    ``LATENT_BLOCKS``.  ``block_shapes`` as the architecture states a
-    plane's (``plane_block_shapes``: ``(K, V)`` block shapes ``[B, h,
-    lanes]``, or block shapes ``[B, lanes]`` with no head axis for a
-    latent plane), ``NB`` the table's
+    ``LATENT_BLOCKS``; a slab plane's groups of the same rule at one head.
+    ``block_shapes`` as the kernel sees a plane's blocks: ``(K, V)`` block
+    shapes ``[B, h, lanes]`` (what ``plane_block_shapes`` states; ``h``
+    ``None`` for the slabs of a plane stored head-major and walked a head
+    at a time), or block shapes ``[B, lanes]`` with no head axis for a
+    latent plane; ``NB`` the table's
     entries a slot, ``window`` the plane's lower bound.  Stated here for
     whoever counts the kernel's iterations
     (``serving.paged_iterations_live``): the engine does not guess."""
@@ -444,6 +490,11 @@ def loop_iterations(entries, rows, block_shapes, dtype, NB, window=None):
         G = min(LATENT_BLOCKS, NB)
     else:
         (B, h, dh), (_, _, dv) = block_shapes
+        if h is None:
+            G = min(NB, entries_per_iteration(
+                B, None, dh, dv, rows, dtype,
+                window_entries(NB, B, 1, window)))
+            return -(-entries // G)
         if rows == 1 or not _rows_are_sliceable(h, dtype):
             return entries
         G = entries_per_iteration(B, h, dh, dv, rows * h, dtype,
@@ -644,9 +695,10 @@ def _normalize_block_step(block_step, nb, w=1):
 
 # -- xla_ref: the block-scan oracle ------------------------------------------
 
-def _latent_plane(q, pool, group, value_lanes):
-    """The checks of a latent call (``pool_v is None``); returns the
-    value lanes."""
+def _latent_plane(q, pool, group, value_lanes, pool_v=None):
+    """The checks of a call on a plane with no head axis: a latent one
+    (``pool_v is None``) or a slab plane (the latent convention with its
+    values ``pool_v [blocks, B, dv]``); returns the value lanes."""
     if pool.ndim != 3 or q.shape[-1] != pool.shape[-1]:
         raise ValueError(
             f"paged_attention: a latent plane is [blocks, B, L] and its "
@@ -654,6 +706,12 @@ def _latent_plane(q, pool, group, value_lanes):
     if group != 1:
         raise ValueError("paged_attention: a latent plane has one row all "
                          "the heads read whole: no group")
+    if pool_v is not None:
+        if pool_v.ndim != 3 or pool_v.shape[:2] != pool.shape[:2]:
+            raise ValueError(
+                f"paged_attention: a slab plane's V array is [blocks, B, "
+                f"dv] beside K's {pool.shape}; got {pool_v.shape}")
+        return pool_v.shape[-1]
     if not value_lanes or not 0 < value_lanes <= pool.shape[-1]:
         raise ValueError(f"paged_attention: value_lanes {value_lanes} of a "
                          f"latent row of {pool.shape[-1]} lanes")
@@ -675,21 +733,27 @@ def paged_attention_ref(q, pool_k, pool_v, table, pos, block_step=None,
     STARTS: a row's maximum at its head's sink logit and its sum at 1,
     as if it had seen one key with that score and a zero value.
     ``shared`` says what a kernel may fetch once and changes no result:
-    ignored here, where every slot gathers its own chain."""
+    ignored here, where every slot gathers its own chain.  A slab plane
+    (``pool_k`` and ``pool_v`` with no head axis) is the latent lines
+    with the values gathered from their own array."""
     del interpret, shared
-    latent = pool_v is None
+    latent = pool_k.ndim == 3
     if sink is not None:
         if latent:
-            raise ValueError("paged_attention: a latent plane has no sink")
+            raise ValueError("paged_attention: a latent plane has no sink"
+                             if pool_v is None else
+                             "paged_attention: a slab plane has no sink")
         sink = _fold_sink(sink, group, pool_k.shape[2], q.shape[1])[None]
     if latent:
-        dv = _latent_plane(q, pool_k, group, value_lanes)
+        dv = _latent_plane(q, pool_k, group, value_lanes, pool_v)
         unfold = lambda ctx: ctx
         qk, pv = "swhd,std->swht", "swht,std->swhd"
 
         def gather(blk, n):
             kb = pool_k[blk].reshape(S, n * B, dh)
-            return kb, kb[..., :dv]
+            if pool_v is None:
+                return kb, kb[..., :dv]
+            return kb, pool_v[blk].reshape(S, n * B, dv)
     else:
         q, pos, unfold = _fold_group(q, pos, group, pool_k.shape[2])
         qk, pv, dv = "swhd,sthd->swht", "swht,sthd->swhd", pool_v.shape[-1]
@@ -927,6 +991,19 @@ def paged_attention_pallas(q, pool_k, pool_v, table, pos, block_step=None,
     if shared is not None:
         raise ValueError("paged_attention: only a latent plane's call "
                          "takes shared runs")
+    if pool_k.ndim == 3:
+        # a slab plane: the latent kernel's loop with V's own buffer, at
+        # the entries an iteration the rule gives one head's rows
+        if sink is not None:
+            raise ValueError("paged_attention: a slab plane has no sink")
+        dv = _latent_plane(q, pool_k, group, value_lanes, pool_v)
+        (_, W, h, dk), B, NB = q.shape, pool_k.shape[1], table.shape[1]
+        return latent_attention_pallas(
+            q, pool_k, table, pos, dv, scale=scale, out_dtype=out_dtype,
+            interpret=interpret, window=window, pool_v=pool_v,
+            blocks=entries_per_iteration(
+                B, None, dk, dv, W * h, pool_k.dtype,
+                window_entries(NB, B, W, window)))
     if sink is not None:
         sink = _fold_sink(sink, group, pool_k.shape[2], q.shape[1])
     width = q.shape[1]              # positions, before a group is folded in
@@ -1352,7 +1429,7 @@ def shared_runs(table, whole, rows, blocks=None):
 
 def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
                             out_dtype=None, interpret=None, blocks=None,
-                            window=None, shared=None):
+                            window=None, shared=None, pool_v=None):
     """The Mosaic kernel of a LATENT plane, a sibling of the loop above
     under its own name (``paged_latent_attention``): ``pool [blocks, B,
     L]`` holds ONE row a cached position, every one of the ``h`` query
@@ -1396,7 +1473,17 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
     order with or without a run, so its output is the same.  A slot in
     no run (a row of zeros) walks ``[0, n_s)`` as ever.  Only decode rows
     of a plane attended whole take a run: with a ``window`` or ``W > 1``
-    ``shared`` is dropped and the program is the one without it."""
+    ``shared`` is dropped and the program is the one without it.
+
+    **A slab plane** (``pool_v [blocks, B, value_lanes]``: the values in
+    an array of their own, module docstring) is the same loop under the
+    name ``paged_slab_attention``: a group's V slabs are copied beside
+    its K slabs into a second buffer, awaited where the group is weighed
+    (K is needed a group before V), and ``p`` is weighed against them
+    instead of the key rows' first lanes.  Tokens stay on sublanes and no
+    lane belongs to another head, so the scores of ``N`` query rows
+    against ``blocks`` slabs are ``[N, blocks * B]`` and nothing more.
+    It takes no shared runs."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1412,6 +1499,8 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
     f32 = jnp.float32
     # only decode rows of a plane attended whole take a run (a Python
     # flag: the body below reads nothing of the traced array)
+    if pool_v is not None and shared is not None:
+        raise ValueError("paged_attention: a slab plane takes no shared runs")
     told = shared is not None and window is None and W == 1 and S > 1
     # rows the softmax's scratch holds: one slot's, or the widest stack's
     widths = _stack_widths(S, N) if told else []
@@ -1421,7 +1510,10 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
         return ref if n == ref.shape[0] else ref.at[pl.ds(0, n)]
 
     def kernel(tbl, pos_ref, *refs):
-        if not told:
+        if pool_v is not None:
+            (q_ref, pool_hbm, v_hbm, o_ref, buf, sem, s_ref, peak_ref,
+             m_ref, l_ref, acc_ref, v_buf, v_sem) = refs
+        elif not told:
             (q_ref, pool_hbm, o_ref, buf, sem, s_ref, peak_ref, m_ref,
              l_ref, acc_ref) = refs
         else:
@@ -1447,6 +1539,17 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
             return [pltpu.make_async_copy(
                 pool_hbm.at[tbl[s_id, jnp.minimum(g * G + j, NB - 1)]],
                 buf.at[slot, pl.ds(j * B, B)], sem.at[slot, j])
+                for j in range(G)]
+
+        def values(g):
+            """Group ``g``'s V slabs on their way (a slab plane's; a
+            latent row's values are lanes of the row ``copies`` brings)."""
+            if pool_v is None:
+                return []
+            slot = jax.lax.rem(g, DEPTH)
+            return [pltpu.make_async_copy(
+                v_hbm.at[tbl[s_id, jnp.minimum(g * G + j, NB - 1)]],
+                v_buf.at[slot, pl.ds(j * B, B)], v_sem.at[slot, j])
                 for j in range(G)]
 
         def fresh(n):
@@ -1485,7 +1588,8 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
             for ahead in range(DEPTH - 1):
                 @pl.when(g0 + ahead < groups)
                 def _start(ahead=ahead):
-                    for c in copies(g0 + ahead):
+                    g = g0 + ahead
+                    for c in copies(g) + values(g):
                         c.start()
 
             @pl.when(groups > g0)
@@ -1497,7 +1601,8 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
             def group(g, _):
                 @pl.when(g + DEPTH - 1 < groups)
                 def _ahead():
-                    for c in copies(g + DEPTH - 1):
+                    ahead = g + DEPTH - 1
+                    for c in copies(ahead) + values(ahead):
                         c.start()
 
                 @pl.when(g + 1 < groups)
@@ -1505,6 +1610,8 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
                     for c in copies(g + 1):
                         c.wait()
 
+                for c in values(g):
+                    c.wait()
                 s, peak = s_w[...], peak_w[...][:, :1]
                 # the last group's scores are made once more and dropped:
                 # no branch between the two chains
@@ -1518,7 +1625,8 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
                 l2 = (l_w[...][:, :1] * alpha
                       + jnp.sum(p, axis=-1, keepdims=True))
                 acc_w[...] = acc_w[...] * alpha + _weigh(
-                    p, buf[jax.lax.rem(g, DEPTH)][:, :dv])
+                    p, buf[jax.lax.rem(g, DEPTH)][:, :dv] if pool_v is None
+                    else v_buf[jax.lax.rem(g, DEPTH)])
                 m_w[...] = jnp.broadcast_to(m2, (n, LSE_LANES))
                 l_w[...] = jnp.broadcast_to(l2, (n, LSE_LANES))
 
@@ -1576,6 +1684,11 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
         pltpu.VMEM((R, LSE_LANES), f32), pltpu.VMEM((R, dv), f32)]
     prefetch = [table.astype(jnp.int32), pos.astype(jnp.int32)]
     q_spec = pl.BlockSpec((1, N, L), lambda s, *_: (s, 0, 0))
+    pools = [pool]
+    if pool_v is not None:
+        pools.append(pool_v)
+        scratch += [pltpu.VMEM((DEPTH, T, dv), pool_v.dtype),
+                    pltpu.SemaphoreType.DMA((DEPTH, G))]
     if told:
         # every slot's rows stay in VMEM for whoever leads a run, and
         # the members' states between their leader's step and their own
@@ -1589,14 +1702,16 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch), grid=(S,),
-            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)
+                                 for _ in pools],
             out_specs=pl.BlockSpec((1, N, dv), lambda s, *_: (s, 0, 0)),
             scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((S, N, dv), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary" if told else "parallel",)),
         interpret=bool(interpret),
-        name="paged_latent_attention",
+        name=("paged_latent_attention" if pool_v is None
+              else "paged_slab_attention"),
     )
     if told:
         # a walk a stack width is several times the body to trace and to
@@ -1606,7 +1721,7 @@ def latent_attention_pallas(q, pool, table, pos, value_lanes, scale=None,
             (q.shape, str(q.dtype), pool.shape[1:], str(pool.dtype), NB, G,
              dv, float(scale), str(jnp.dtype(out_dtype)), bool(interpret),
              tuple(widths)), jax.jit(call))
-    ctx = call(*prefetch, q.reshape(S, N, L), pool)
+    ctx = call(*prefetch, q.reshape(S, N, L), *pools)
     return ctx.reshape(S, W, h, dv)
 
 

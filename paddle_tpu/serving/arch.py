@@ -320,6 +320,13 @@ class Architecture:
         return (tuple(self.pool_block_shape(block_tokens, dtype)),
                 ) * self.pool_arrays
 
+    def plane_tokens_axis(self, plane):
+        """The axis of plane ``plane``'s block shapes that counts
+        POSITIONS: 0 (``[block_tokens, ...]``) unless a plane's block is
+        laid out otherwise (``SparseLightning``'s K/V planes are
+        head-major, ``[kv_heads, block_tokens, head_dim]``: 1)."""
+        return 0
+
     def plane_written_values(self, plane):
         """Values of one position a write puts into EACH of the plane's
         pool arrays."""
@@ -2650,7 +2657,10 @@ class SparseLightning(Architecture):
     ``init_blocks + topk + window_blocks`` blocks its K/V head's queries
     select on the layer's COMPRESSED keys (the mean of every ``2 stride``
     keys, a row every ``stride`` positions).  A layer holds TWO planes:
-    K and V (``pool_rows`` of its K/V heads a position), and the
+    K and V, stored HEAD-MAJOR (a block is ``[kv_heads, B, head_dim]``:
+    one ``[B, head_dim]`` slab a K/V head and no row beside them, so a
+    head's walk fetches its own slabs alone,
+    ``kernels/block_sparse_attention.py``), and the
     compressed keys, one array of ``B / stride`` rows a block under the
     same block ids; the K/V planes come first in ``planes``.
 
@@ -2777,8 +2787,11 @@ class SparseLightning(Architecture):
                     f"block of {self.sparse['block']}")
             return ((block_tokens // stride,
                      self.kv_heads * self.head_dim),)
-        return ((block_tokens, _paged.pool_rows(self.kv_heads, dtype),
-                 self.head_dim),) * 2
+        # head-major: a K/V head's [B, D] slab is what its walk fetches
+        return ((self.kv_heads, block_tokens, self.head_dim),) * 2
+
+    def plane_tokens_axis(self, plane):
+        return 0 if self._compressed(plane) else 1
 
     def plane_written_values(self, plane):
         values = self.kv_heads * self.head_dim
@@ -2808,10 +2821,12 @@ class SparseLightning(Architecture):
 
     def gauges(self, params):
         dtype = params["tok_emb.w"].dtype
-        stored = self.sparse_layers * (
-            2 * _paged.pool_rows(self.kv_heads, dtype) * self.head_dim
-            + self.kv_heads * self.head_dim // self.sparse["stride"])
         z = self.sparse
+        stored = sum(
+            int(np.prod(shape)) for i in range(len(self.planes))
+            for shape in self.plane_block_shapes(i, z["stride"], dtype)
+        ) // z["stride"]
+        _, B, D = self.plane_block_shapes(0, z["block"], dtype)[0]
         return {
             "sparse_layers": (self.sparse_layers, "layers whose K/V plane "
                               "is read in the blocks a query selects past "
@@ -2831,9 +2846,14 @@ class SparseLightning(Architecture):
                 "layer (what ONE snapshot holds)"),
             "kv_stored_bytes_per_token": (
                 stored * dtype.itemsize, "bytes a cached position STORES "
-                "across its planes: kernels.paged_attention.pool_rows of "
-                "K/V heads a K/V plane and its share of a compressed row "
+                "across its planes, from their block shapes: a K/V plane's "
+                "heads and its share of a compressed row "
                 "(kv_bytes_per_token is what the model caches of it)"),
+            "sparse_walk_bytes_per_block": (
+                2 * B * D * dtype.itemsize, "bytes the walk of ONE K/V "
+                "head copies for one selected block of sparse block "
+                "positions, K and V: that head's slabs of a head-major "
+                "plane's blocks"),
         }
 
     def check_params(self, params, max_len):

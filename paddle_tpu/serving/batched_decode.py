@@ -321,14 +321,18 @@ class _Cache:
                      stride, block, topk, init_blocks, window_blocks,
                      group, scale):
         """A K/V plane whose queries SELECT blocks past ``dense_len``
-        (``kernels/block_sparse_attention.py``): writes K and V, then the
+        (``kernels/block_sparse_attention.py``; the plane is stored
+        HEAD-MAJOR, ``[blocks, H_kv, B, D]``, as the architecture's
+        ``plane_block_shapes`` declares it): writes K and V (one scatter
+        each, no row beside the heads'), then the
         compressed keys that the call's rows complete into ``plane_c``
         (one array under the same block ids, a row where its window
         ends: the mean of the last ``2 stride`` keys as the pool holds
         them, the trash block for a row that completes none), then
         attends: a row at a position under ``dense_len`` the whole chain
-        (``paged_attention.attend``), a row from there on the blocks it
-        selects, each call made only where such a row is live.  A table
+        head by head (``dense_attention``), a row from there on the blocks
+        it selects, both through one walk of a K/V head's slabs, each call
+        made only where such a row is live.  A table
         that cannot reach ``dense_len`` traces the dense call alone.
         Returns ``(ctx, planes', counts)``, ``counts`` int32 ``[5]``:
         (row, K/V head) pairs that read densely and that selected, the
@@ -336,16 +340,17 @@ class _Cache:
         compressed rows written."""
         pk, pv, j, tbl, b = self._plane(planes, plane, 0)
         pc = planes[0][plane_c]
-        B, hk = pk.shape[1], kh.shape[-2]
-        if block % B or B % stride or pc.shape[1] * stride != B:
+        B, hk = pc.shape[1] * stride, kh.shape[-2]
+        if pk.shape[1:3] != (hk, B) or block % B or B % stride:
             raise ValueError(
-                f"block_sparse: pool blocks of {B} positions must divide a "
-                f"selection block of {block} and hold whole strides of "
-                f"{stride} ({pc.shape[1]} compressed rows a block)")
+                f"block_sparse: a head-major plane [blocks, {hk}, B, D] "
+                f"whose blocks of B positions divide a selection block of "
+                f"{block} and hold whole strides of {stride} "
+                f"({pc.shape[1]} compressed rows a block); got {pk.shape}")
         pos = self.pos[:, None] if self.step else self.pos
         with sublayer("cache"):
-            pk = _paged.write(pk, b, self.off, kh)
-            pv = _paged.write(pv, b, self.off, vh)
+            pk = _blocks_sparse.write(pk, b, self.off, kh)
+            pv = _blocks_sparse.write(pv, b, self.off, vh)
             # the compressed rows whose window ends on one of the call's
             # real rows: candidates first // stride .., at most one a
             # stride of the window and one more
@@ -356,7 +361,7 @@ class _Cache:
             end = stride * (cand + 1) - 1
             last = jnp.max(jnp.where(real, pos, -1), axis=1, keepdims=True)
             done = (cand >= 1) & (end >= first) & (end <= last)
-            rows = _blocks_sparse.compressed_rows(pk, tbl, cand, stride, hk)
+            rows = _blocks_sparse.compressed_rows(pk, tbl, cand, stride)
             per = B // stride
             at = jnp.take_along_axis(
                 tbl, jnp.minimum(cand // per, tbl.shape[1] - 1), axis=1)
@@ -364,15 +369,17 @@ class _Cache:
         how = dict(group=group, scale=scale)
         q4 = qh[:, None] if self.step else qh
         if tbl.shape[1] * B <= dense_len:
-            ctx = _paged.attend(q4, pk, pv, tbl, self.pos4, **how)
+            ctx = _blocks_sparse.dense_attention(q4, pk, pv, tbl, self.pos4,
+                                                 **how)
             dense, sparse = self.pos4 >= 0, jnp.zeros_like(self.pos4, bool)
         else:
             dense = (self.pos4 >= 0) & (self.pos4 < dense_len)
             sparse = self.pos4 >= dense_len
+            # a dense row's keys lie in the entries under dense_len
             ctx = jax.lax.cond(
                 jnp.any(dense),
-                lambda q, pk, pv, tbl, at: _paged.attend(
-                    q, pk, pv, tbl, at, **how),
+                lambda q, pk, pv, tbl, at: _blocks_sparse.dense_attention(
+                    q, pk, pv, tbl, at, entries=-(-dense_len // B), **how),
                 lambda q, *_: jnp.zeros_like(q),
                 q4, pk, pv, tbl, jnp.where(dense, self.pos4, -1))
             # (a backend's reading of a row at -1 is its own: the rows
@@ -485,7 +492,7 @@ def paged_step_logits(p, tok, t, pool_k, pool_v, table, arch, state=(),
         cache = _Cache(arch, None, None, None, t, live=table != 0)
     else:
         S = tok.shape[0]
-        B = pool_k[0].shape[1]
+        B = pool_k[0].shape[1 + arch.plane_tokens_axis(0)]
         T = table.shape[-1] * B
         tw = jnp.clip(t, 0, T - 1)
         with sublayer("cache"):
@@ -582,7 +589,7 @@ def _window_forward(p, pool_k, pool_v, toks, pos, limit, table, arch,
         x = arch.embed(p, toks, Pw)
         cache = _Cache(arch, None, None, None, P, writable, slot)
     else:
-        B = pool_k[0].shape[1]
+        B = pool_k[0].shape[1 + arch.plane_tokens_axis(0)]
         T = table.shape[-1] * B
         Pw = jnp.clip(P, 0, T - 1)
         writable = P <= limit[:, None]

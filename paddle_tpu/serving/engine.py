@@ -667,19 +667,18 @@ class ServingEngine:
                  "pool_rows added, the lanes latent_lanes added, as zeros",
         ).set(sum(sum(arch.plane_written_values(i))
                   for i in range(len(arch.planes)))
-              / sum(int(np.prod(a.shape[2:])) for a in self._pk + self._pv)
+              / sum(int(np.prod(shape)) // shape[arch.plane_tokens_axis(i)]
+                    for i in range(len(arch.planes))
+                    for shape in arch.plane_block_shapes(
+                        i, self.block_tokens, self.compute_dtype))
               if self._pk else 0.0)
         self._reg.gauge(
             "serving.kv_pool_bytes",
             help="bytes the paged pool holds on the device: planes x "
                  "blocks (trash included) x block bytes",
         ).set(sum(a.nbytes for a in self._pk + self._pv))
-        # what the architecture itself wants shown (a routed FFN's share
-        # of the experts: arch.GatedMoE); nothing for most
-        for name, (value, text) in arch.gauges(self._p).items():
-            name, labels = name if isinstance(name, tuple) else (name, ())
-            self._reg.gauge("serving." + name, help=text,
-                            **dict(labels)).set(value)
+        self._arch_gauges = arch.gauges(self._p)
+        self._publish_arch_gauges()
         # (window, calls, bytes a cached position) of the paged calls a
         # token makes, for _count_paged_entries
         # and the query rows a call sends through an entry and the
@@ -687,19 +686,29 @@ class ServingEngine:
         bound = {}
         for i, w in enumerate(arch.planes):
             bound.setdefault(w, i)
+
+        def seen(i):
+            """Plane ``i``'s block shapes as the paged kernel sees them:
+            a head-major plane (``plane_tokens_axis`` 1) is walked a K/V
+            head at a time, slabs ``[B, None, lanes]`` with no head axis
+            (``paged_attention.loop_iterations``)."""
+            shapes = arch.plane_block_shapes(i, self.block_tokens,
+                                             self.compute_dtype)
+            if arch.plane_tokens_axis(i):
+                shapes = tuple((B, None, lanes) for _, B, lanes in shapes)
+            return shapes
+
         self._plane_reads = [
             (w, n, arch.plane_block_bytes(bound[w], 1, itemsize),
-             arch.plane_rows_per_entry(bound[w]),
-             arch.plane_block_shapes(bound[w], self.block_tokens,
-                                     self.compute_dtype))
+             arch.plane_rows_per_entry(bound[w]), seen(bound[w]))
             for w, n in arch.plane_reads]
         # (window, calls, folded query rows a position) of a prefill
-        # piece's calls on K/V planes, for _count_prefill_entries
+        # piece's calls on K/V planes, for _count_prefill_entries (a
+        # head-major plane has no wide spelling: its rows walk slabs)
         self._piece_reads = [] if arch.latent_planes else [
-            (w, n, arch.plane_rows_per_entry(bound[w])
-             * arch.plane_block_shapes(
-                 bound[w], self.block_tokens, self.compute_dtype)[0][1])
-            for w, n in arch.plane_reads]
+            (w, n, arch.plane_rows_per_entry(bound[w]) * seen(bound[w])[0][1])
+            for w, n in arch.plane_reads
+            if not arch.plane_tokens_axis(bound[w])]
         # span attributes that say in which form attention runs, for an
         # architecture with latent planes or with retention layers
         self._form_attrs = (
@@ -2519,8 +2528,21 @@ class ServingEngine:
                        "inside another request's prefill clock pair, "
                        "waiting for the rest").set(v)
 
+    def _publish_arch_gauges(self):
+        """What the architecture itself wants shown (a routed FFN's share
+        of the experts: ``arch.GatedMoE``; what a walk's copy moves:
+        ``arch.SparseLightning``); nothing for most.  Facts of the engine,
+        not of a window: set when it is built and again by ``stats``, so a
+        registry zeroed in between (a benchmark's warm pass) shows them."""
+        for name, (value, text) in self._arch_gauges.items():
+            name, labels = name if isinstance(name, tuple) else (name, ())
+            self._reg.gauge("serving." + name, help=text,
+                            **dict(labels)).set(value)
+
     def stats(self):
         """Snapshot of the engine's ``serving.*`` metrics, the tail's
-        make-up (``_publish_tail``) computed first."""
+        make-up (``_publish_tail``) and the architecture's own gauges
+        published first."""
         self._publish_tail()
+        self._publish_arch_gauges()
         return self._reg.snapshot(prefix="serving.")

@@ -16,7 +16,8 @@ planes stated their own shape and chains came in two kinds; PR 51 added
 expert's form and ``_Cache.retain`` became ``advance``; PR 57 added
 ``MambaMoE``'s and ``SparseLatentMoE``'s, taken on its parent, and
 ``DeltaMoE``'s own, taken on its tree; PR 61 added ``SparseLightning``'s
-own, taken on its tree: a family the root does not have is left out)."""
+own, taken on its tree, and PR 63, which stored its K/V planes head-major,
+took them again on its own: a family the root does not have is left out)."""
 
 import hashlib
 import importlib.util

@@ -79,11 +79,13 @@ PARENT = {
         "prefill_8": "6d8aaf3d82c9807d",
         "prefill_32": "cf6b7f589af080ca"
     },
-    # SparseLightning's own, on PR 61's tree: what a later PR is held to
+    # SparseLightning's own, taken again on PR 63's tree (its K/V planes
+    # head-major, both sides of the dense length through one walk of a K/V
+    # head's slabs): what a later PR is held to
     "sparse_lightning": {
-        "decode_chunk_4": "d4869b1b24cfc387",
-        "prefill_8": "0c21f95ca63026a1",
-        "prefill_32": "6d10dcd904c8bda3"
+        "decode_chunk_4": "abe56f5496e22aa7",
+        "prefill_8": "54679723a889b5f4",
+        "prefill_32": "d97d13667746c370"
     }
 }
 # PR 52 gave the loop of two rows or more G table entries an iteration

@@ -120,9 +120,13 @@ def test_paged_group_of_a_slot_with_no_live_row_fetches_nothing(monkeypatch):
     assert not np.asarray(got, np.float32).any()
 
 
-# the rule at the serving cells' decode geometries (blocks of 32 tokens,
-# bfloat16): (K/V rows, K lanes, V lanes, folded rows a K/V row, table
-# entries, lower bound) -> entries an iteration
+# the rule at the serving cells' decode geometries (bfloat16; blocks of
+# 32 tokens but the slabs', 64): (K/V rows, K lanes, V lanes, folded rows
+# a K/V row, table entries, lower bound) -> entries an iteration.  A SLAB
+# (one K/V head's rows alone, no head axis: ``sala9b.doc_qa_128k``'s
+# head-major planes, 32 KB a slab of K and V) weighs a quarter of
+# ``ENTRY_BYTES``: its 97 selected blocks take 8 an iteration, a chain of
+# 128 entries under the dense length 16.
 _ENTRY_RULE = {
     "long_reason_full": ((8, 256, 128, 16, 416, None), 8),
     "long_reason_window_5_live_entries": ((8, 256, 128, 8, 416, 128), 1),
@@ -132,6 +136,9 @@ _ENTRY_RULE = {
     "chat_ssm": ((8, 128, 128, 16, 80, None), 2),
     "verify_window_under_group_4_fits_vmem": ((16, 128, 128, 20, 416, None),
                                               2),
+    "slab_97_selected_blocks": ((None, 128, 128, 16, 97, None), 8),
+    "slab_chain_under_the_dense_length": ((None, 128, 128, 16, 128, None),
+                                          16),
 }
 
 
@@ -139,18 +146,26 @@ _ENTRY_RULE = {
 def test_entries_per_iteration_follows_the_shapes(geometry):
     """A power of two up to ``MAX_ENTRIES``, no more than a
     ``GROUP_SHARE``-th of the entries a chain can have live, within the
-    loop's VMEM; ``loop_iterations`` is the kernel's trip count at it."""
+    loop's VMEM, a slab counted by the share of ``ENTRY_BYTES`` it holds;
+    ``loop_iterations`` is the kernel's trip count at it."""
     from paddle_tpu.kernels import paged_attention as pa
 
     (h, dk, dv, rows, NB, window), want = _ENTRY_RULE[geometry]
-    live = pa.window_entries(NB, 32, 1, window)
-    got = pa.entries_per_iteration(32, h, dk, dv, rows * h, jnp.bfloat16,
-                                   live)
-    assert got == want
-    assert got * pa.GROUP_SHARE <= live or got == 1
+    slab = h is None            # no head axis
+    B = 64 if slab else 32
+    light = pa.ENTRY_BYTES // (B * (dk + dv) * 2) if slab else 1
+    live = pa.window_entries(NB, B, 1, window)
+    folded = rows * (h or 1)
+    got = pa.entries_per_iteration(B, h, dk, dv, folded, jnp.bfloat16, live)
+    assert got == want and light == (4 if slab else 1)
+    assert got * pa.GROUP_SHARE <= live * light or got == 1
+    assert got <= pa.MAX_ENTRIES * light
+    if slab:        # ONE K/V head WITH a head axis is no slab
+        assert pa.entries_per_iteration(
+            B, 1, dk, dv, folded, jnp.bfloat16, live) == {97: 2, 128: 4}[NB]
     assert got == 1 or pa._loop_vmem_bytes(
-        got, 32, h, dk, dv, rows * h, jnp.bfloat16) <= pa.LOOP_VMEM_BYTES
-    shapes = ((32, h, dk), (32, h, dv))
+        got, B, h or 1, dk, dv, folded, jnp.bfloat16) <= pa.LOOP_VMEM_BYTES
+    shapes = ((B, h, dk), (B, h, dv))
     for entries in (0, 1, got, got + 1, 5 * got + 3):
         assert pa.loop_iterations(entries, rows, shapes, jnp.bfloat16, NB,
                                   window) == -(-entries // got)

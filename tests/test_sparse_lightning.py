@@ -245,6 +245,35 @@ def test_state_in_bfloat16_fails_the_tolerance():
     assert run(True) >= 1e-3 * scale
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_kv_planes_pool_holds_kv_heads_rows_a_position(model, dtype):
+    """A K/V plane is stored head-major, ``[blocks, kv_heads, B, head_dim]``
+    whatever the dtype (no row ``pool_rows`` would add): what a position
+    STORES is what the model caches of it, a walk's copy of one selected
+    block is one head's slab of K and of V, and both gauges are facts of
+    the engine that a zeroed registry shows again."""
+    cfg, params = model
+    cfg = dict(cfg, compute_dtype=dtype)
+    eng = _engine(cfg, {k: v.astype(dtype) for k, v in params.items()})
+    arch, item = eng.arch, jnp.dtype(dtype).itemsize
+    hk, dh = cfg["num_key_value_heads"], cfg["head_dim"]
+    for plane in range(arch.sparse_layers):
+        assert arch.plane_block_shapes(plane, B, dtype) == ((hk, B, dh),) * 2
+        assert arch.plane_tokens_axis(plane) == 1
+        assert eng._pk[plane].shape[1:] == (hk, B, dh) == eng._pv[
+            plane].shape[1:]
+    assert arch.plane_tokens_axis(arch.sparse_layers) == 0
+    st = eng.stats()
+    assert (st["serving.kv_stored_bytes_per_token"]
+            == st["serving.kv_bytes_per_token"]
+            == arch.kv_bytes_per_token(item)
+            == 2 * (2 * hk * dh + hk * dh // SPARSE["kernel_stride"]) * item)
+    assert st["serving.kv_write_fill"] == 1.0
+    eng._reg.reset(prefix="serving.")
+    assert (eng.stats()["serving.sparse_walk_bytes_per_block"]
+            == 2 * SPARSE["block_size"] * dh * item)
+
+
 def test_parameter_count_at_the_published_configuration():
     """9.48B by the layer equations, to the digit; 2,820,544,768 held."""
     with open(os.path.join(ROOT, "chipbench", "configs",
